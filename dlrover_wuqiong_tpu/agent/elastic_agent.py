@@ -259,9 +259,9 @@ class ElasticAgent:
         env.setdefault("DWT_PROC_ROLE", "trainer")
         # one compile-cache dir across worker generations and warm
         # children: the restarted worker must read what the pool wrote
-        from ..auto.compile_cache import default_cache_dir
+        from ..auto.compile_cache import CACHE_DIR_ENV, resolve_cache_dir
 
-        env.setdefault(NodeEnv.COMPILE_CACHE_DIR, default_cache_dir())
+        env.setdefault(CACHE_DIR_ENV, resolve_cache_dir())
         if self._rollback_before >= 0:
             # one-shot: the relaunched worker resumes from the newest
             # committed ckpt BEFORE the spike step, then the ceiling clears
@@ -338,22 +338,36 @@ class ElasticAgent:
             return ""
 
     def _stop_worker(self, timeout: float = 30.0):
+        """Stop the worker and wait until its whole process GROUP is
+        gone (the worker leads its own session): an accelerator stays
+        held until the last process that opened it has exited, and a
+        worker that died on its own (`os._exit`, a signal) can leave
+        children behind — the next generation must not be launched onto
+        devices the old one still holds."""
         if self._worker is None:
             return
         proc = self._worker.proc
-        if proc.poll() is None:
+        pgid = proc.pid
+
+        def _signal_group(sig) -> bool:
             try:
-                os.killpg(proc.pid, signal.SIGTERM)
+                os.killpg(pgid, sig)
+                return True
             except ProcessLookupError:
-                pass
-            try:
-                proc.wait(timeout=timeout)
-            except subprocess.TimeoutExpired:
-                try:
-                    os.killpg(proc.pid, signal.SIGKILL)
-                except ProcessLookupError:
-                    pass
-                proc.wait(timeout=10)
+                return False  # nobody left in the group
+
+        deadline = time.monotonic() + timeout
+        alive = _signal_group(signal.SIGTERM)
+        while alive and time.monotonic() < deadline:
+            proc.poll()  # reap the leader, or its zombie keeps the group
+            time.sleep(0.05)
+            alive = _signal_group(0)
+        if alive:
+            _signal_group(signal.SIGKILL)
+            proc.wait(timeout=10)
+            while _signal_group(0) and \
+                    time.monotonic() < deadline + 10:
+                time.sleep(0.05)
         self._worker = None
 
     def _start_heartbeat(self):
@@ -514,11 +528,10 @@ class ElasticAgent:
         world_devices = outcome.num_processes * outcome.local_world_size
 
         def _wait_and_warm():
-            from ..auto.compile_cache import default_cache_dir
+            from ..auto.compile_cache import resolve_cache_dir
             from ..auto.warm_pool import WarmPool, load_current_spec
 
-            cache_dir = os.getenv(NodeEnv.COMPILE_CACHE_DIR,
-                                  default_cache_dir())
+            cache_dir = resolve_cache_dir()
             deadline = time.monotonic() + spec_wait_s
             while time.monotonic() < deadline and not self._stopped.is_set() \
                     and generation == self._warm_generation:

@@ -11,9 +11,12 @@ pairwise sweep to isolate the faulty node and flag stragglers.
 
 from __future__ import annotations
 
+import json
 import os
+import subprocess
+import sys
 import time
-from typing import Optional, Tuple
+from typing import Tuple
 
 from ..common.constants import RendezvousName
 from ..common.log import get_logger
@@ -95,6 +98,28 @@ def run_check_workload(matmul_size: int = 2048) -> Tuple[bool, float]:
         return False, 0.0
 
 
+def run_check_child(timeout: float = 300.0) -> Tuple[bool, float]:
+    """`run_check_workload` in a short-lived child process.
+
+    The agent never touches JAX: an accelerator belongs to one process
+    at a time, and a parent that ran the probe itself would still hold
+    the chips when it launches the worker.  The child opens the devices,
+    prints its verdict as one JSON line and exits — the devices are free
+    again before this returns.  A child that dies or hangs is an
+    unhealthy node."""
+    try:
+        proc = subprocess.run(
+            [sys.executable, "-m", "dlrover_wuqiong_tpu.agent.node_check"],
+            capture_output=True, text=True, timeout=timeout)
+        out = json.loads(proc.stdout.strip().splitlines()[-1])
+        logger.info("node check child: healthy=%s elapsed=%.3fs",
+                    out["healthy"], out["elapsed"])
+        return bool(out["healthy"]), float(out["elapsed"])
+    except (subprocess.TimeoutExpired, ValueError, IndexError, KeyError):
+        logger.exception("node check child failed")
+        return False, 0.0
+
+
 def run_network_check(agent, rounds: int = 2,
                       timeout: float = 300.0) -> bool:
     """Drive `rounds` sweeps of the pairwise check through the master.
@@ -104,7 +129,7 @@ def run_network_check(agent, rounds: int = 2,
     """
     for r in range(rounds):
         outcome = agent.rendezvous(name=RendezvousName.NETWORK_CHECK)
-        healthy, elapsed = run_check_workload()
+        healthy, elapsed = run_check_child(timeout)
         agent.mc.report_network_check_result(healthy, elapsed)
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
@@ -120,3 +145,9 @@ def run_network_check(agent, rounds: int = 2,
         if stragglers:
             logger.warning("stragglers detected: %s", stragglers)
     return success
+
+
+if __name__ == "__main__":
+    _healthy, _elapsed = run_check_workload()
+    print(json.dumps({"healthy": _healthy, "elapsed": _elapsed}),
+          flush=True)

@@ -76,7 +76,7 @@ def _source_line(eqn) -> str:
 
 def _sub_jaxprs(eqn):
     """(sub_jaxpr, invars_for_binders) pairs for eqns that nest jaxprs."""
-    import jax.core as core
+    import jax.extend.core as core
 
     name = eqn.primitive.name
     if name == "cond":
@@ -138,7 +138,7 @@ def check_collective_in_cond(fn_or_jaxpr, *args) -> List[Finding]:
 
 def _walk_varying(jaxpr, varying: Dict, manual_axes: FrozenSet[str],
                   findings: List[Finding]) -> None:
-    import jax.core as core
+    import jax.extend.core as core
 
     def axes_of(v) -> FrozenSet[str]:
         if isinstance(v, core.Literal):
@@ -151,22 +151,19 @@ def _walk_varying(jaxpr, varying: Dict, manual_axes: FrozenSet[str],
             if eqn.invars else frozenset()
 
         if name == "shard_map":
-            mesh = eqn.params.get("mesh")
-            auto = eqn.params.get("auto", frozenset()) or frozenset()
-            mesh_axes = frozenset(getattr(mesh, "axis_names", ()) or ())
-            manual = (mesh_axes - frozenset(auto)) | manual_axes
+            manual = frozenset(eqn.params["manual_axes"]) | manual_axes
             body = eqn.params["jaxpr"]
             body = body.jaxpr if hasattr(body, "jaxpr") else body
-            in_names = eqn.params.get("in_names") or \
-                eqn.params.get("in_specs") or ()
+            in_specs = eqn.params["in_specs"]
             sub_env: Dict = {}
-            for i, bv in enumerate(body.invars):
-                axes: FrozenSet[str] = in_axes
-                if i < len(in_names) and isinstance(in_names[i], dict):
-                    axes = axes | frozenset(
-                        a for names in in_names[i].values()
-                        for a in names)
-                sub_env[bv] = axes & manual
+            for bv, spec in zip(body.invars, in_specs):
+                # a PartitionSpec entry is None, an axis name, or a
+                # tuple of axis names
+                named = frozenset(
+                    a for entry in spec if entry is not None
+                    for a in (entry if isinstance(entry, tuple)
+                              else (entry,)))
+                sub_env[bv] = (in_axes | named) & manual
             _walk_varying(body, sub_env, manual, findings)
             out_axes = manual  # conservative: shard outputs vary
             for ov in eqn.outvars:
@@ -431,8 +428,8 @@ def self_audit(n_devices: int = 8) -> List[Finding]:
                      "labels": jax.ShapeDtypeStruct(shape, jnp.int32)}
             case = audit_step(res.train_step, res.state, batch)
         except RuntimeError as e:
-            # environment gap (e.g. pipeline shard_map needs jax >= 0.6)
-            # — report the skip loudly rather than claiming coverage
+            # a case this environment cannot build — report the skip
+            # loudly rather than claiming coverage
             skipped.append(f"{tag}: {e}")
             continue
         for f in case:

@@ -38,7 +38,6 @@ import dataclasses
 import hashlib
 import json
 import os
-import tempfile
 import time
 from typing import Any, Dict, Optional, Tuple
 
@@ -86,31 +85,34 @@ counters = CacheCounters()
 _listeners_installed = False
 _enabled_dir: Optional[str] = None
 
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-def default_cache_dir() -> str:
-    """Stable-across-restarts location; DWT_COMPILE_CACHE_DIR overrides."""
-    explicit = os.getenv("DWT_COMPILE_CACHE_DIR", "")
-    if explicit:
-        return explicit
-    try:
-        import getpass
 
-        user = getpass.getuser()
-    except Exception:  # noqa: BLE001 — no passwd entry in some containers
-        user = str(os.getuid()) if hasattr(os, "getuid") else "dwt"
-    return os.path.join(tempfile.gettempdir(), f"dwt-compile-cache-{user}")
+def resolve_cache_dir() -> str:
+    """THE compile-cache location, for every process of a job.
+
+    `JAX_COMPILATION_CACHE_DIR` when set — JAX itself reads that variable
+    at import, so nothing here has to (or may) point it elsewhere —
+    otherwise `<checkout>/.jax_cache`.  The path is part of XLA's cache
+    key, so it is always a FIXED path: never a temp name, pid or time.
+    Agent, worker generations, warm-pool children and the master's scale
+    planner all resolve through here, so they agree without passing the
+    directory around.
+    """
+    return os.environ.get(CACHE_DIR_ENV) or _CHECKOUT_CACHE_DIR
 
 
 def _install_listeners() -> None:
     global _listeners_installed
     if _listeners_installed:
         return
-    try:
-        from jax._src import monitoring
-    except ImportError:  # pragma: no cover — private API moved
-        logger.debug("jax monitoring unavailable; cache counters disabled")
-        _listeners_installed = True
-        return
+    # private, but the only event surface jax 0.9 has for cache hits; an
+    # ImportError here must surface — restart assertions (chip_smoke.py's
+    # elastic phase, the warm-pool e2e) read these counters
+    from jax._src import monitoring
 
     def _export(name: str, value: float = 1.0):
         # mirror into the shared Prometheus registry so /metrics and the
@@ -144,37 +146,40 @@ def _install_listeners() -> None:
     _listeners_installed = True
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None
-                            ) -> Optional[str]:
-    """Point JAX's persistent compilation cache at a restart-stable dir.
+def enable_persistent_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache at `resolve_cache_dir()`.
 
     Idempotent; returns the active dir, or None when disabled
-    (DWT_COMPILE_CACHE=0).  Re-pointing to a different dir resets JAX's
-    cache singleton (it binds the dir on first use).  The min-time and
-    min-size floors are dropped so the sub-second CPU-mesh compiles the
-    tests exercise take the same persist path as multi-minute TPU ones.
+    (DWT_COMPILE_CACHE=0).  JAX's own config already holds
+    `JAX_COMPILATION_CACHE_DIR` when the process started with it; the
+    config is written only when it does not hold the resolved dir (the
+    variable was unset, or a test moved it after import), and then JAX's
+    cache singleton — which binds its dir on first use — is reset.  The
+    min-time and min-size floors are dropped so the sub-second CPU-mesh
+    compiles the tests exercise take the same persist path as
+    multi-minute TPU ones.
     """
     global _enabled_dir
     if os.getenv("DWT_COMPILE_CACHE", "1") == "0":
+        # the variable that places the cache is JAX's own and switches
+        # JAX's cache on by itself (the agent exports it to every
+        # worker): "disabled" has to be said to JAX, not just skipped
+        import jax
+
+        jax.config.update("jax_enable_compilation_cache", False)
         return None
-    cache_dir = cache_dir or default_cache_dir()
+    cache_dir = resolve_cache_dir()
     if _enabled_dir == cache_dir:
         return cache_dir
     os.makedirs(cache_dir, exist_ok=True)
     import jax
 
-    if _enabled_dir is not None and _enabled_dir != cache_dir:
-        # the cache object binds its dir lazily on first compile — a
-        # re-point after that must tear the singleton down or writes keep
-        # landing in the old dir
-        try:
-            from jax._src import compilation_cache as _cc
+    if jax.config.jax_compilation_cache_dir != cache_dir:
+        from jax._src import compilation_cache as _cc
 
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — private API; best-effort
-            logger.debug("compilation cache reset unavailable",
-                         exc_info=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+        _cc.reset_cache()
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_enable_compilation_cache", True)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _install_listeners()

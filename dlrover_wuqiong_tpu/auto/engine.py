@@ -114,15 +114,25 @@ def generate_candidates(num_devices: int, n_head: int = 0,
 
 
 def _device_roofline(device) -> Tuple[float, float]:
-    """(peak_flops, hbm_bytes_per_s) for the scoring model."""
-    kind = getattr(device, "device_kind", "cpu").lower()
+    """(peak_flops, hbm_bytes_per_s) for the scoring model.
+
+    A device kind that is not in the table is an error, never a default:
+    a made-up roofline ranks candidates by fiction.  The "cpu" row is
+    nominal — the CPU mesh only has to rank candidates relative to each
+    other (tests); it is not a statement about any host."""
+    kind = device.device_kind.lower()
     table = {
         "tpu v5 lite": (197e12, 819e9), "tpu v5e": (197e12, 819e9),
         "tpu v5": (459e12, 1228e9), "tpu v5p": (459e12, 2765e9),
         "tpu v4": (275e12, 1228e9),
         "tpu v6 lite": (918e12, 1640e9), "tpu v6e": (918e12, 1640e9),
+        "cpu": (1e12, 100e9),
     }
-    return table.get(kind, (1e12, 100e9))
+    if kind not in table:
+        raise ValueError(
+            f"no roofline for device kind {device.device_kind!r}; known: "
+            f"{sorted(table)} — add its published peaks, do not guess")
+    return table[kind]
 
 
 def score_candidate(cand: Candidate, model, optimizer, sample_batch: Dict,

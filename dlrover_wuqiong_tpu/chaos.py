@@ -513,7 +513,7 @@ def preempt(total_steps: int = 600, dt: float = 0.1,
     if model:
         extra_env["DWT_COMPILE_CACHE"] = "1" if compile_cache else "0"
         if cache_dir:
-            extra_env["DWT_COMPILE_CACHE_DIR"] = cache_dir
+            extra_env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     t_start = time.monotonic()
     cli, work, ckpt_dir, marker, job = _launch_standalone(
         "preempt", _PREEMPT_WORKER,
@@ -846,7 +846,7 @@ with led.window("compile"):
     res.train_step.lower(res.state, ab).compile()
 h1, m1 = counters.snapshot()
 extra.update(start_hits=h1 - h0, start_misses=m1 - m0)
-pool = WarmPool(os.environ["DWT_COMPILE_CACHE_DIR"])
+pool = WarmPool(os.environ["JAX_COMPILATION_CACHE_DIR"])
 knobs = {"interval": interval0, "cur_k": 1, "pending_k": None,
          "last_id": 0}
 
@@ -1093,7 +1093,7 @@ def preempt_adaptive(total_steps: int = 600, dt: float = 0.05,
         # the kill burst is a preemption storm, not a crash loop: keep
         # relaunching through 3 consecutive SIGKILLs (same as baseline)
         DWT_CTX_RELAUNCH_ALWAYS="1",
-        DWT_COMPILE_CACHE="1", DWT_COMPILE_CACHE_DIR=cache,
+        DWT_COMPILE_CACHE="1", JAX_COMPILATION_CACHE_DIR=cache,
         PYTHONPATH=os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))) + os.pathsep +
         os.environ.get("PYTHONPATH", ""))
@@ -3210,8 +3210,8 @@ def perf_regress() -> Dict:
     Three invariants, all on the REAL BaselineStore + RegressionSentinel
     (seeded synthetic windows, so the drill is hermetic and fast):
 
-    1. QUIET: shared-tunnel-scale +-10% noise around the baseline never
-       fires — the MAD bound absorbs normal drift.
+    1. QUIET: +-10% run-to-run noise around the baseline never fires —
+       the MAD bound absorbs normal drift.
     2. THROTTLED: a sustained ~1.5x step-time slowdown whose extra wall
        sits in the collective category fires `perf-regression` after
        EXACTLY M consecutive beyond-bound windows, once per excursion,

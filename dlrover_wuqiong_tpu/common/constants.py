@@ -30,10 +30,6 @@ class NodeEnv:
     # loss-spike rollback: resume from the newest committed ckpt whose
     # step precedes this value (set one-shot by the agent on relaunch)
     ROLLBACK_BEFORE_STEP = "DWT_ROLLBACK_BEFORE_STEP"
-    # warm re-mesh: persistent XLA compile cache shared by the agent, its
-    # workers across restarts, and the warm-pool children
-    # (auto/compile_cache.py)
-    COMPILE_CACHE_DIR = "DWT_COMPILE_CACHE_DIR"
 
 
 class NodeType:

@@ -28,7 +28,7 @@ bench.py's single-line JSON and streamed per-round by
 
 CPU-only by construction: nothing here touches an accelerator (client
 procs never import jax — verified by test_fleet_bench), so the numbers
-are tunnel-independent and comparable across machines.
+do not depend on any device link.
 """
 
 from __future__ import annotations

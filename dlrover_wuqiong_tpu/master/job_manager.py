@@ -79,9 +79,9 @@ class WarmMeshPolicy:
     def __init__(self, cache_dir: Optional[str] = None,
                  devices_per_node_fn: Optional[Callable[[], int]] = None):
         if cache_dir is None:
-            from ..auto.compile_cache import default_cache_dir
+            from ..auto.compile_cache import resolve_cache_dir
 
-            cache_dir = default_cache_dir()
+            cache_dir = resolve_cache_dir()
         self.cache_dir = cache_dir
         self._devices_per_node_fn = devices_per_node_fn or (lambda: 1)
 

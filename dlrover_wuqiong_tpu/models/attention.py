@@ -14,7 +14,7 @@ model definition runs single-chip, GSPMD-sharded, or context-parallel
 
 from __future__ import annotations
 
-from ..ops.flash_attention import mha
+from ..ops.flash_attention import _on_tpu, mha
 
 
 def attend(q, k, v, cfg, causal: bool = True):
@@ -27,4 +27,12 @@ def attend(q, k, v, cfg, causal: bool = True):
         fn = ring_attention if impl == "ring" else ulysses_attention
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         return fn(qt, kt, vt, mesh, causal=causal).transpose(0, 2, 1, 3)
+    if mesh is not None and mesh.size > 1 and _on_tpu():
+        # the Pallas kernels need a shard_map on a multi-device mesh; the
+        # jnp reference off-TPU is partitioned by GSPMD like any other op
+        from ..parallel.long_context import sharded_flash_attention
+
+        qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        return sharded_flash_attention(qt, kt, vt, mesh, causal=causal
+                                       ).transpose(0, 2, 1, 3)
     return mha(q, k, v, causal=causal)

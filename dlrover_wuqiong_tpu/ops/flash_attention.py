@@ -43,10 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..common.log import get_logger
 
@@ -60,25 +57,26 @@ LOG2E = 1.4426950408889634  # exp(x) == exp2(x * LOG2E); folding LOG2E
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    # a backend that fails to initialise raises from here: training on
+    # the jnp reference because the chip did not come up is not a mode
+    return jax.default_backend() == "tpu"
 
 
 def _compiler_params(*semantics, vmem_limit: Optional[int] = None):
-    if pltpu is None:  # pragma: no cover
-        return None
     kw = {}
     if vmem_limit is not None:
         # the fused multi-head kernels hold q/k/v/o blocks for ALL heads
         # plus per-head f32 scratch: past the 16MB default scoped limit,
         # well inside v5e's 128MB physical VMEM
         kw["vmem_limit_bytes"] = vmem_limit
-    # jax < 0.6 spells it TPUCompilerParams; same fields either way
-    params_cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return params_cls(dimension_semantics=semantics, **kw)
+    return pltpu.CompilerParams(dimension_semantics=semantics, **kw)
+
+
+def _out_struct(shape, dtype, like):
+    """Kernel output struct.  Inside a shard_map (the only way a Mosaic
+    kernel runs on a multi-device mesh) the outputs vary over the same
+    manual axes as the operands; outside one the set is empty."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _dot(a, b):
@@ -265,17 +263,18 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
             pl.BlockSpec((pack, 1, block_q), lambda b, i, j: (b, 0, i)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
+            _out_struct((bh, sq, d), q.dtype, q),
+            _out_struct((bh, 1, sq), jnp.float32, q),
         ),
         scratch_shapes=[
             pltpu.VMEM((pack, block_q, 1), jnp.float32),
             pltpu.VMEM((pack, block_q, 1), jnp.float32),
             pltpu.VMEM((pack, block_q, d), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          vmem_limit=100 * 1024 * 1024),
         interpret=interpret,
+        name="dwt_fa_fwd",
     )(q, k, v)
     return o, lse
 
@@ -499,13 +498,14 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                       bspec_row],
             out_specs=(bspec_q, bspec_k, bspec_k),
             out_shape=(
-                jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-                jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+                _out_struct((bh, sq, d), q.dtype, q),
+                _out_struct((bh, sk, d), k.dtype, q),
+                _out_struct((bh, sk, d), v.dtype, q),
             ),
             compiler_params=_compiler_params(
                 "parallel", vmem_limit=100 * 1024 * 1024),
             interpret=interpret,
+            name="dwt_fa_bwd_fused",
         )(*ops)
 
     dq = pl.pallas_call(
@@ -515,12 +515,12 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         grid=(bh // pack, num_q, num_kv),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=pl.BlockSpec((pack, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((pack, block_q, d), jnp.float32)]
-        if pltpu is not None else [],
+        out_shape=_out_struct((bh, sq, d), q.dtype, q),
+        scratch_shapes=[pltpu.VMEM((pack, block_q, d), jnp.float32)],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          vmem_limit=100 * 1024 * 1024),
         interpret=interpret,
+        name="dwt_fa_bwd_dq",
     )(*ops)
 
     # dkv grid: kv outer, q inner — same operands, transposed index maps
@@ -539,16 +539,17 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             pl.BlockSpec((pack, block_k, d), lambda b, j, i: (b, j, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
+            _out_struct((bh, sk, d), k.dtype, q),
+            _out_struct((bh, sk, d), v.dtype, q),
         ),
         scratch_shapes=[
             pltpu.VMEM((pack, block_k, d), jnp.float32),
             pltpu.VMEM((pack, block_k, d), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          vmem_limit=100 * 1024 * 1024),
         interpret=interpret,
+        name="dwt_fa_bwd_dkv",
     )(*ops)
     return dq, dk, dv
 

@@ -25,12 +25,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pl = None
-    pltpu = None
+from jax.experimental import pallas as pl
 
 BLOCK = 256
 
@@ -57,10 +52,9 @@ def fp8_dense_override() -> Optional[bool]:
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+    # a backend that fails to initialise raises from here (it is not
+    # "not a TPU")
+    return jax.default_backend() == "tpu"
 
 
 # ------------------------------------------------------------- int8 blockwise
@@ -92,7 +86,7 @@ def quantize_int8_blockwise(x: jax.Array, block: int = BLOCK
         flat = jnp.pad(flat, (0, pad))
     rows = flat.size // block
     tiled = flat.reshape(rows, block)
-    if _on_tpu() and pl is not None and rows % 8 == 0:
+    if _on_tpu() and rows % 8 == 0:
         grid = (rows // 8,)
         q, s = pl.pallas_call(
             _quant_kernel,
@@ -102,6 +96,7 @@ def quantize_int8_blockwise(x: jax.Array, block: int = BLOCK
                        pl.BlockSpec((8, 1), lambda i: (i, 0))),
             out_shape=(jax.ShapeDtypeStruct((rows, block), jnp.int8),
                        jax.ShapeDtypeStruct((rows, 1), jnp.float32)),
+            name="dwt_int8_quant",
         )(tiled)
         return q, s
     xf = tiled.astype(jnp.float32)
@@ -116,7 +111,7 @@ def dequantize_int8_blockwise(q: jax.Array, scale: jax.Array,
                               dtype=jnp.float32) -> jax.Array:
     """Inverse of quantize_int8_blockwise."""
     rows, block = q.shape
-    if _on_tpu() and pl is not None and rows % 8 == 0:
+    if _on_tpu() and rows % 8 == 0:
         x = pl.pallas_call(
             _dequant_kernel,
             grid=(rows // 8,),
@@ -124,6 +119,7 @@ def dequantize_int8_blockwise(q: jax.Array, scale: jax.Array,
                       pl.BlockSpec((8, 1), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((8, block), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((rows, block), jnp.float32),
+            name="dwt_int8_dequant",
         )(q, scale)
     else:
         x = q.astype(jnp.float32) * scale

@@ -37,23 +37,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.flash_attention import flash_attention
 
-try:  # moved out of jax.experimental in newer versions
-    from jax import shard_map as _raw_shard_map  # type: ignore
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _raw_shard_map(f, mesh=_context_mesh(mesh),
-                              in_specs=in_specs,
-                              out_specs=out_specs, check_vma=True)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        # legacy jax: no get_abstract_mesh, so pp-nesting cannot happen —
-        # keep check_rep=False (True would reject the Pallas custom-VJP
-        # kernels that lack replication rules on that version)
-        return _raw_shard_map(f, mesh=_context_mesh(mesh),
-                              in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=_context_mesh(mesh), in_specs=in_specs,
+                         out_specs=out_specs, check_vma=True)
 
 
 def _context_mesh(mesh: "Mesh"):
@@ -67,7 +53,7 @@ def _context_mesh(mesh: "Mesh"):
 _BATCH_AXES = ("dp", "fsdp")  # mesh data axes (parallel/mesh.py AXIS_ORDER)
 
 
-def _qkv_spec(mesh: Mesh, seq_axis: str, batch_size: int) -> P:
+def _qkv_spec(mesh: Mesh, seq_axis: Optional[str], batch_size: int) -> P:
     """(b, h, S, d) spec: seq over `seq_axis`, batch over the mesh's data
     axes.  Leaving batch unsharded would all-gather the global batch to every
     device at the shard_map boundary and redundantly compute attention over
@@ -81,6 +67,29 @@ def _qkv_spec(mesh: Mesh, seq_axis: str, batch_size: int) -> P:
             batch.append(a)
             div *= n
     return P(tuple(batch) if batch else None, None, seq_axis, None)
+
+
+def sharded_flash_attention(q, k, v, mesh: Mesh, causal: bool = True,
+                            sm_scale: Optional[float] = None):
+    """`flash_attention` for (b, h, S, d) operands that live on a mesh of
+    more than one device.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under any sharded strategy the
+    Pallas call has to sit inside a shard_map: batch over the mesh's
+    data axes, heads over `tp` when they divide, the sequence whole —
+    each device runs the kernel on its own (b_local, h_local) slab, no
+    collective.  A sequence sharded over `sp` by GSPMD is gathered at
+    the boundary (ring/ulysses are the paths that keep it sharded)."""
+    spec = _qkv_spec(mesh, None, q.shape[0])
+    tp = mesh.shape.get("tp", 1)
+    if tp > 1 and q.shape[1] % tp == 0:
+        spec = P(spec[0], "tp", None, None)
+    fn = shard_map(
+        functools.partial(flash_attention, causal=causal,
+                          sm_scale=sm_scale),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    return fn(q, k, v)
 
 
 # ------------------------------------------------------------- lse utilities

@@ -171,10 +171,8 @@ def context_mesh(mesh):
     by parallel/long_context.py (ring/Ulysses inside the pipeline) and
     parallel/pipeline.py (pipeline inside the DiLoCo dp body).
     """
-    try:
-        from jax.sharding import get_abstract_mesh
-    except ImportError:  # pragma: no cover — legacy jax: no nesting
-        return mesh
+    from jax.sharding import get_abstract_mesh
+
     ctx = get_abstract_mesh()
     if ctx is not None and getattr(ctx, "axis_names", None) and \
             any("manual" in str(t).lower() for t in

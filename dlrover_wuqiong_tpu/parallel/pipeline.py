@@ -51,11 +51,6 @@ from ..common.log import get_logger
 
 logger = get_logger("pipeline")
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.8 style
-except ImportError:  # pragma: no cover
-    _shard_map = None
-
 
 def _pvary_pp(tree):
     """Mark a scan carry as pp-varying for VMA-tracked (nested) contexts.
@@ -63,16 +58,10 @@ def _pvary_pp(tree):
     Under check_vma=True the scan carry must enter with the same varying-
     axes type it leaves with (ppermute/axis_index make it {V:pp}); outside
     VMA tracking pvary is a no-op."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        try:
-            return jax.tree.map(
-                lambda x: pcast(x, ("pp",), to="varying"), tree)
-        except Exception:  # noqa: BLE001 — fall through to pvary
-            pass
     try:
-        return jax.tree.map(lambda x: jax.lax.pvary(x, ("pp",)), tree)
-    except Exception:  # noqa: BLE001 — older jax without either
+        return jax.tree.map(
+            lambda x: jax.lax.pcast(x, ("pp",), to="varying"), tree)
+    except Exception:  # noqa: BLE001 — no VMA tracking here: a no-op
         return tree
 
 
@@ -83,15 +72,13 @@ def _pp_shard_map(f, mesh, in_specs, out_specs):
     must be the context AbstractMesh and VMA tracking must be ON — the
     pp x ring-SP closure showed that an inner shard_map's transpose
     silently corrupts gradients without it (tests pin grad exactness)."""
-    if _shard_map is None:  # pragma: no cover
-        raise RuntimeError("pipeline parallelism needs jax.shard_map with "
-                           "axis_names support (jax >= 0.6)")
     from .mesh import context_mesh
 
     ctx = context_mesh(mesh)
     nested = ctx is not mesh
-    return _shard_map(f, mesh=ctx, in_specs=in_specs, out_specs=out_specs,
-                      axis_names={"pp"}, check_vma=nested)
+    return jax.shard_map(f, mesh=ctx, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={"pp"},
+                         check_vma=nested)
 
 
 def schedule_ticks(schedule: str, num_microbatches: int, pp: int,

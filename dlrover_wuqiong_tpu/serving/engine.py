@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..auto.compile_cache import (
+    CACHE_DIR_ENV,
     TRACE_ENV_VARS,
     canonicalize,
     note_train_step_served,
@@ -209,7 +210,7 @@ class ServingEngine:
         # registry note: warm restarts can tell whether this topology was
         # compiled by a prior process (tools/warm_report.py aggregates)
         note_train_step_served(
-            cache_dir or os.getenv("DWT_COMPILE_CACHE_DIR", ""),
+            cache_dir or os.getenv(CACHE_DIR_ENV, ""),
             self.cache_key,
             {"kind": "serve", "spec": dataclasses.asdict(spec)})
         S = spec.max_slots

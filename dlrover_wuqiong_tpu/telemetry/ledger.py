@@ -38,7 +38,7 @@ from typing import Dict, Optional
 #: drill assertions all key on these names.
 LEDGER_STATES = (
     "productive",        # fused-window device time doing real steps
-    "dispatch_overhead",  # per-dispatch tunnel/runtime overhead share
+    "dispatch_overhead",  # per-dispatch runtime overhead share
     "data_stall",        # blocked on next(stager) / host input pipeline
     "ckpt_stage",        # blocked on D2H staging into shm
     "ckpt_persist",      # blocked waiting on a prior async persist

@@ -127,10 +127,7 @@ class ElasticContext:
         """
         import jax
 
-        from ..auto.compile_cache import (
-            active_cache_dir,
-            default_cache_dir,
-        )
+        from ..auto.compile_cache import resolve_cache_dir
         from ..auto.warm_pool import (
             WarmPool,
             WarmSpec,
@@ -147,7 +144,7 @@ class ElasticContext:
             logger.info("warm restarts unavailable: model not in the "
                         "warm-pool registry (gpt/llama)")
             return None
-        cache_dir = active_cache_dir() or default_cache_dir()
+        cache_dir = resolve_cache_dir()
         if fused_steps is None:
             # default to the K the result runs with (the trainer's
             # auto-tuned K when fusion is on) — a warm entry at the wrong

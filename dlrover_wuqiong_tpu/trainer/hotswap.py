@@ -30,11 +30,8 @@ journaled phase ladder, a survivor owns the work each phase names:
   until the transition leaves the ladder, then resumes under the new
   world/round.
 
-Donation rule (CLAUDE.md): hydrated bytes headed for a donating step
-must be laundered through one jitted identity copy before any donation
-path touches them — the cutover hook owns device placement and is the
-place to do it (checkpoint/engine.py restore_pytree is the sanctioned
-launderer).
+The cutover hook owns device placement of the hydrated bytes
+(checkpoint/engine.py restore_pytree is the sanctioned route).
 
 Acks ride ``report_mesh_transition_phase`` (CRITICAL + idem — the
 master journals each ack before answering); the state poll rides the

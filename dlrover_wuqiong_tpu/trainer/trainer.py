@@ -504,31 +504,51 @@ class Trainer:
                 applied["fused_steps_requested"] = k_req
         self.policy_applied.append(applied)
 
-    def _prewarm_fused_k(self, k: int) -> bool:
-        """True when switching the fused driver to K will hit the compile
-        cache.  Without a warm-pool cache dir there is nothing to consult
-        (tests / standalone runs) — allow the cutover.  Otherwise derive
-        the target spec from the published current spec at the new K:
-        ready entry → go; else kick an async warm compile and stay at the
-        current K until a later boundary finds it ready."""
-        cache_dir = os.getenv("DWT_COMPILE_CACHE_DIR", "")
+    def _prewarm(self, what: str, **spec_changes) -> bool:
+        """True when the executable this run's published warm spec
+        becomes under `spec_changes` is ready in the pool — or can never
+        be: no persistent cache, no spec published by THIS run (the dir
+        is shared, a leftover file may be another job's), or a platform
+        whose devices this process holds (`can_warm`: a warm child could
+        not open them) — then the caller cuts over and compiles in
+        place.  Otherwise kick an async warm compile and report False
+        until a later boundary finds the entry ready."""
+        cache_dir = getattr(self.res, "_cache_dir", None)
         if not cache_dir:
             return True
-        from ..auto.warm_pool import WarmPool, load_current_spec
+        from ..auto.warm_pool import (
+            WarmPool,
+            can_warm,
+            load_current_spec,
+            model_spec,
+        )
 
+        spec = load_current_spec(cache_dir)
+        if spec is None or \
+                spec.model != model_spec(self.res.model) or \
+                spec.n_devices != self.res.mesh.size or \
+                spec.batch_shape != [self.args.global_batch_size,
+                                     self.args.seq_len]:
+            return True
+        if not can_warm(spec.platform):
+            logger.info("%s: no warm child for platform %r (this process "
+                        "holds its devices) — cutting over, the step "
+                        "compiles in place", what, spec.platform)
+            return True
+        spec = dataclasses.replace(spec, **spec_changes)
         if self._warm_pool is None:
             self._warm_pool = WarmPool(cache_dir)
-        spec = load_current_spec(cache_dir)
-        if spec is None:
-            return True  # nothing published: no warm entry to wait for
-        if int(getattr(spec, "fused_steps", 1)) != k:
-            spec = dataclasses.replace(spec, fused_steps=k)
         if self._warm_pool._ready_entry_for(spec.spec_key()) is not None:
             return True
         self._warm_pool.warm_async(spec)
-        logger.info("policy: warming fused_steps=%d in the pool — cutover "
-                    "deferred until the entry is ready", k)
+        logger.info("%s: warming in the pool — cutover deferred until "
+                    "the entry is ready", what)
         return False
+
+    def _prewarm_fused_k(self, k: int) -> bool:
+        """True when switching the fused driver to K may proceed (the
+        pool holds the K-wide executable, or nothing can warm it)."""
+        return self._prewarm(f"policy fused_steps={k}", fused_steps=k)
 
     # ------------------------------------------------- variant autotuner
 
@@ -692,25 +712,9 @@ class Trainer:
             mode = (k, env_signature())
         if mode in self._compiled_modes:
             return True
-        cache_dir = os.getenv("DWT_COMPILE_CACHE_DIR", "")
-        if not cache_dir:
-            return True
-        from ..auto.warm_pool import WarmPool, load_current_spec
-
-        if self._warm_pool is None:
-            self._warm_pool = WarmPool(cache_dir)
-        spec = load_current_spec(cache_dir)
-        if spec is None:
-            return True
-        spec = dataclasses.replace(
-            spec, fused_steps=k,
-            trace_env=self._variant_full_env(variant))
-        if self._warm_pool._ready_entry_for(spec.spec_key()) is not None:
-            return True
-        self._warm_pool.warm_async(spec)
-        logger.info("tuner: warming variant %r in the pool — cutover "
-                    "deferred until the entry is ready", variant.name)
-        return False
+        return self._prewarm(f"tuner variant {variant.name!r}",
+                             fused_steps=k,
+                             trace_env=self._variant_full_env(variant))
 
     # ------------------------------------------------------------- schedule
 
@@ -1084,6 +1088,11 @@ class Trainer:
                             # the real step, not the async dispatch
                             float(metrics["loss"])
                             step_time_s = time.perf_counter() - t0
+                    if self.profiler.closes_at(s0):
+                        # the opt-in trace window ends with this block:
+                        # dispatch is async, so wait for the device or the
+                        # trace holds only the block's first milliseconds
+                        jax.block_until_ready(metrics)
                 blk_s = time.monotonic() - t_blk0
                 if env_mode not in self._compiled_modes:
                     # first dispatch at this (fusion width, variant env)

@@ -36,10 +36,16 @@ class StepProfiler:
 
     def __init__(self, trace_dir: Optional[str] = None,
                  start_step: int = -1, end_step: int = -1,
-                 registry=None, job_name: str = "dwt"):
+                 registry=None, job_name: str = "dwt",
+                 device_only: bool = False):
+        """`device_only`: leave the Python tracer out of the trace — for
+        windows whose only reader is the xplane op split (the perf
+        observatory).  Every Python call of every thread otherwise lands
+        in the file, which the pure-Python reducer then has to walk."""
         self.trace_dir = trace_dir
         self.start_step = start_step
         self.end_step = end_step
+        self._device_only = device_only
         self._tracing = False
         self._job = job_name
         self.last_profile = None  # OpProfile of the latest closed window
@@ -68,13 +74,25 @@ class StepProfiler:
                 and step == self.start_step):
             import jax
 
-            jax.profiler.start_trace(self.trace_dir)
+            options = None
+            if self._device_only:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir,
+                                     profiler_options=options)
             self._tracing = True
             logger.info("jax.profiler trace started at step %d → %s",
                         step, self.trace_dir)
 
+    def closes_at(self, step: int) -> bool:
+        """True when leaving `step(step)` will stop the trace.  Dispatch
+        is asynchronous: the caller must wait for the device work it
+        wants in the trace BEFORE leaving, or the file holds the first
+        few milliseconds of it."""
+        return self._tracing and step >= self.end_step
+
     def _maybe_stop_trace(self, step: int):
-        if self._tracing and step >= self.end_step:
+        if self.closes_at(step):
             import jax
 
             jax.profiler.stop_trace()
@@ -90,9 +108,13 @@ class StepProfiler:
         try:
             prof = parse_trace_dir(self.trace_dir)
         except Exception:  # noqa: BLE001 — observability must not kill train
-            logger.warning("xplane parse failed", exc_info=True)
+            logger.exception("xplane parse FAILED for %s — this window "
+                             "has no op profile", self.trace_dir)
             return
         if prof is None:
+            logger.error("trace window left no parseable xplane file "
+                         "under %s — this window has no op profile",
+                         self.trace_dir)
             return
         self.last_profile = prof
         # fresh window: drop last window's series (op names churn between

@@ -179,6 +179,8 @@ def parse_xspace(path: str) -> List[_Plane]:
 # HLO-name prefixes → category (checked on the lowercased, wrapped_/suffix-
 # stripped event name).  hlo_category stats, when present (TPU), win.
 _PREFIX_CATEGORIES = (
+    # the Pallas kernels carry stable names (ops/flash_attention.py)
+    ("attention", ("dwt_fa_",)),
     ("collective", ("all-reduce", "all-gather", "all-to-all",
                     "reduce-scatter", "collective-permute",
                     "collective-broadcast", "ragged-all-to-all")),
@@ -202,8 +204,16 @@ _HLO_CATEGORY_MAP = (
 )
 
 
+def _instruction_name(name: str) -> str:
+    """A TPU device trace names each op event by its whole HLO
+    instruction (`%fusion.12 = f32[8]{0} fusion(...)`); the part before
+    ` = ` is the instruction's name, which is all the other backends
+    give."""
+    return name.split(" = ", 1)[0] if name.startswith("%") else name
+
+
 def _normalize(name: str) -> str:
-    n = name.lower()
+    n = _instruction_name(name).lower()
     if n.startswith("wrapped_"):
         n = n[len("wrapped_"):]
     n = n.split(".")[0].split("%")[-1].strip()
@@ -218,12 +228,15 @@ def categorize(name: str, hlo_category: str = "") -> Optional[str]:
             if any(k in hc for k in keys):
                 return cat
         return "fused" if "fusion" in hc else "other"
+    name = _instruction_name(name)
     if not name or name.startswith("$") or "(" in name or ":" in name:
         return None  # host-side python / runtime artifacts
     n = _normalize(name)
     for cat, prefixes in _PREFIX_CATEGORIES:
         if any(n.startswith(p) for p in prefixes):
             return cat
+    if n.endswith("fusion"):  # multiply_reduce_fusion, ...
+        return "fused"
     # bare HLO instruction names are [a-z0-9-_]; anything else is host noise
     if not n or not all(c.isalnum() or c in "-_" for c in n):
         return None
@@ -267,12 +280,18 @@ def summarize_planes(planes: List[_Plane]) -> OpProfile:
     device_planes = [p for p in planes if "/device:" in p.name]
     use = device_planes or planes
     agg: Dict[Tuple[str, str], List[float]] = {}
+    seen = 0
     for plane in use:
         hlo_stat_ids = {i for i, n in plane.stat_names.items()
                         if n == "hlo_category"}
-        for line in plane.lines:
+        # a TPU device plane lays the SAME device time out several times
+        # (Steps, XLA Modules, XLA Ops, Async XLA Ops, TC Overlay): only
+        # the per-op line is summed, or every second counts twice
+        op_lines = [ln for ln in plane.lines if ln.name == "XLA Ops"]
+        for line in op_lines or plane.lines:
             if line.name == "python":
                 continue
+            seen += len(line.events)
             for ev in line.events:
                 name = plane.event_names.get(ev.metadata_id, "")
                 hlo_cat = next(
@@ -290,6 +309,10 @@ def summarize_planes(planes: List[_Plane]) -> OpProfile:
         prof.categories[cat] = prof.categories.get(cat, 0.0) + sec
         prof.ops.append(OpEntry(name, cat, sec, cnt))
     prof.ops.sort(key=lambda o: -o.total_s)
+    if seen and not prof.ops:
+        logger.error("xplane: %d events on %d plane(s) and not one "
+                     "recognised as a device op — the op split of this "
+                     "window is EMPTY, not zero", seen, len(use))
     return prof
 
 
@@ -304,7 +327,7 @@ def parse_trace_dir(trace_dir: str) -> Optional[OpProfile]:
         try:
             planes.extend(parse_xspace(pb))
         except Exception:  # noqa: BLE001 — torn/foreign file: skip, not fail
-            logger.warning("unparseable xplane file %s", pb, exc_info=True)
+            logger.error("unparseable xplane file %s", pb, exc_info=True)
     if not planes:
         return None
     return summarize_planes(planes)

@@ -55,6 +55,18 @@ class TestCandidateGeneration:
         assert strat["checkpoint"] == {"enabled": True}
 
 
+def test_unknown_device_kind_has_no_roofline():
+    """A device the table does not know is an error, never a default."""
+    import types
+
+    from dlrover_wuqiong_tpu.auto.engine import _device_roofline
+
+    assert _device_roofline(
+        types.SimpleNamespace(device_kind="TPU v5 lite")) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no roofline"):
+        _device_roofline(types.SimpleNamespace(device_kind="TPU v99"))
+
+
 class TestScoring:
     def _model_batch(self):
         cfg = dataclasses.replace(GPTConfig.nano(), dtype=jnp.float32,

@@ -10,7 +10,6 @@ import numpy as np
 import optax
 import pytest
 
-from version_gates import requires_pinned_host
 
 from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
 from dlrover_wuqiong_tpu.models.gpt import GPT, GPTConfig
@@ -77,7 +76,6 @@ class TestStableBF16:
         assert losses[-1] < losses[0], losses
 
 
-@requires_pinned_host
 class TestOptimizerOffload:
     def test_moments_land_in_host_memory(self):
         cfg = GPTConfig.nano()
@@ -115,7 +113,6 @@ class TestOptimizerOffload:
             np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-@requires_pinned_host
 class TestSlowOffloadLinkGuard:
     """r4 verdict weak #5: offload strategies on a slow host link must
     warn at resolve time with the measured rate, not silently regress."""

@@ -10,10 +10,16 @@ import json
 import os
 import subprocess
 import sys
+import tempfile
 
-import pytest
 
-from version_gates import requires_multiprocess_cpu
+
+def _socket_dir():
+    """A SHORT control-socket dir: AF_UNIX paths cap at 107 bytes, and
+    pytest's tmp_path (deeper still under xdist) plus the longest socket
+    name overruns it."""
+    return tempfile.mkdtemp(prefix="dwt-e2e-")
+
 
 WORKER_SCRIPT = r"""
 import os, sys, time
@@ -64,7 +70,7 @@ def test_crash_restart_resume(tmp_path):
     env.update({
         "JAX_PLATFORMS": "cpu",
         "DWT_JOB_NAME": "e2e1",
-        "DWT_SOCKET_DIR": str(tmp_path / "sockets"),
+        "DWT_SOCKET_DIR": _socket_dir(),
         "DWT_CTX_NODE_HEARTBEAT_TIMEOUT": "600",
     })
     proc = subprocess.run(
@@ -199,14 +205,13 @@ def _base_env(tmp_path, job):
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=2",
         "DWT_JOB_NAME": job,
-        "DWT_SOCKET_DIR": str(tmp_path / "sockets"),
+        "DWT_SOCKET_DIR": _socket_dir(),
         "DWT_CTX_NODE_HEARTBEAT_TIMEOUT": "600",
         "DWT_RESTART_DEBOUNCE_SECS": "2",
     })
     return env
 
 
-@requires_multiprocess_cpu
 def test_jax_world_crash_restart_resume(tmp_path):
     """Real-mesh elasticity: 2 hosts x 2 virtual devices, fsdp=4 sharded
     TrainState; rank-0 worker crashes after the step-3 commit; both agents
@@ -258,7 +263,6 @@ def test_jax_world_crash_restart_resume(tmp_path):
                 a.kill()
 
 
-@requires_multiprocess_cpu
 def test_jax_world_scale_up(tmp_path):
     """Membership change: a world of 1 node is joined by a second node;
     the running agent restarts its worker into the 2-node world
@@ -312,7 +316,6 @@ def test_jax_world_scale_up(tmp_path):
                 a.kill()
 
 
-@requires_multiprocess_cpu
 def test_jax_world_slice_loss(tmp_path):
     """Multi-slice failure domain (SURVEY §2.5 DCN row; reference node
     groups dist_job_manager.py:88): a whole node group — agent AND its

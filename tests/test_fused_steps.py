@@ -164,15 +164,13 @@ class TestFusedEquivalence:
                 strategy=[("data_parallel", {"size": 2}),
                           ("local_sgd", {"sync_every": 4}), ("fsdp", {})],
                 fused_steps=4)
-        from dlrover_wuqiong_tpu.common.util import has_jax_shard_map
-
-        if has_jax_shard_map():  # the lazily-built driver refuses too
-            res_ls = auto_accelerate(
-                _model(), optimizer=optax.adam(1e-2),
-                strategy=[("data_parallel", {"size": 2}),
-                          ("local_sgd", {"sync_every": 4}), ("fsdp", {})])
-            with pytest.raises(ValueError, match="local_sgd"):
-                res_ls.fused_train_step(4)
+        # the lazily-built driver refuses too
+        res_ls = auto_accelerate(
+            _model(), optimizer=optax.adam(1e-2),
+            strategy=[("data_parallel", {"size": 2}),
+                      ("local_sgd", {"sync_every": 4}), ("fsdp", {})])
+        with pytest.raises(ValueError, match="local_sgd"):
+            res_ls.fused_train_step(4)
 
 
 class TestAutoTunePolicy:

@@ -13,7 +13,6 @@ import pytest
 
 from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
 from dlrover_wuqiong_tpu.models.gpt import GPT, GPTConfig
-from version_gates import requires_pinned_host, requires_shard_map
 from dlrover_wuqiong_tpu.parallel.local_sgd import (
     DiLoCoState,
     LocalSGDConfig,
@@ -44,7 +43,6 @@ def _group_params(state, g):
 
 
 class TestDiLoCo:
-    @requires_shard_map
     def test_groups_diverge_then_sync(self):
         res, batch = _setup(sync_every=4)
         state = res.state
@@ -67,7 +65,6 @@ class TestDiLoCo:
             np.testing.assert_allclose(a, b, atol=1e-6)
             np.testing.assert_allclose(a, w, atol=1e-6)
 
-    @requires_shard_map
     def test_loss_decreases_across_rounds(self):
         res, batch = _setup(sync_every=2)
         state = res.state
@@ -87,7 +84,6 @@ class TestDiLoCo:
                             devices=jax.devices())
 
 
-@requires_shard_map
 class TestReduceMethods:
     def test_gta_gates_disagreement(self):
         """Components with opposite signs across replicas are zeroed."""
@@ -125,7 +121,6 @@ class TestReduceMethods:
         np.testing.assert_allclose(np.asarray(fn(d)), [[3.0], [3.0]])
 
 
-@requires_shard_map
 class TestDiLoCoGradAccum:
     """local_sgd x grad_accum (round-3 rejection, now closed): gradients
     accumulate inside each replica group's inner step, so the composition
@@ -191,7 +186,6 @@ class TestDiLoCoGradAccum:
         assert np.isfinite(float(m["loss"]))
 
 
-@requires_shard_map
 class TestDiLoCoStableBF16:
     """local_sgd x stable_bf16 (round-4 rejection, closed): bf16 inner
     params with Kahan/master precision, the outer sync re-anchoring the
@@ -238,8 +232,6 @@ class TestDiLoCoStableBF16:
             np.testing.assert_allclose(a, b, atol=1e-6)
 
 
-@requires_shard_map
-@requires_pinned_host
 class TestDiLoCoOptimizerOffload:
     """local_sgd x optimizer_offload (round-4 rejection, closed): stacked
     inner moments live in pinned_host between steps."""

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from version_gates import requires_shard_map
 
 from dlrover_wuqiong_tpu.ops.flash_attention import _attention_reference
 from dlrover_wuqiong_tpu.parallel.long_context import (
@@ -126,7 +125,6 @@ class TestSequenceParallelTraining:
     sequence)."""
 
     @pytest.mark.parametrize("impl", ["ulysses", "ring"])
-    @requires_shard_map
     def test_sp_training_matches_fsdp(self, impl):
         import optax
 

@@ -246,7 +246,7 @@ class TestAutoAccelerate:
         # shard boundaries before the cross-shard reduce, so the two
         # shardings are different bf16 rounding schedules, and adamw's
         # rsqrt amplifies the gap step over step (measured 3.7% at step
-        # 1 → 10.3% at step 4 on jax 0.4.37 XLA:CPU).  rtol covers that
+        # 1 → 10.3% at step 4 on XLA:CPU).  rtol covers that
         # compounding; the parity claim that survives bf16 is that both
         # runs optimize the same trajectory shape.
         np.testing.assert_allclose(l1, l2, rtol=0.15)

@@ -128,7 +128,7 @@ class TestBaselineStore:
 
 
 class TestRegressionSentinel:
-    def test_quiet_tunnel_noise_never_fires(self):
+    def test_quiet_noise_never_fires(self):
         import random
 
         st = BaselineStore()

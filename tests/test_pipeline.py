@@ -13,7 +13,6 @@ import numpy as np
 import optax
 import pytest
 
-from version_gates import requires_shard_map
 
 from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
 from dlrover_wuqiong_tpu.models.gpt import GPT, GPTConfig
@@ -35,7 +34,6 @@ def _pp_mesh(pp=2, fsdp=1, tp=1):
     return build_mesh(MeshPlan(pp=pp, fsdp=fsdp, tp=tp), jax.devices()[:n])
 
 
-@requires_shard_map
 class TestPipelineApply:
     def test_matches_sequential_scan(self):
         """The staged pipeline must be numerically identical to running the
@@ -61,7 +59,6 @@ class TestPipelineApply:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5)
 
-    @requires_shard_map
     def test_grads_match_sequential(self):
         mesh = _pp_mesh(pp=2)
         L, B, T, C = 2, 4, 8, 16
@@ -105,7 +102,6 @@ class TestInterleavedSchedule:
 
         return w, x, block, seq
 
-    @requires_shard_map
     def test_matches_sequential(self):
         mesh = _pp_mesh(pp=2)
         w, x, block, seq = self._toy()
@@ -117,7 +113,6 @@ class TestInterleavedSchedule:
         np.testing.assert_allclose(np.asarray(got), np.asarray(seq(w, x)),
                                    atol=1e-5)
 
-    @requires_shard_map
     def test_grads_match_sequential(self):
         mesh = _pp_mesh(pp=2)
         w, x, block, seq = self._toy()
@@ -183,7 +178,6 @@ class TestOneFOneB:
         return loss, grads
 
     @pytest.mark.parametrize("pp", [2, 4])
-    @requires_shard_map
     def test_matches_autodiff(self, pp):
         mesh, w, hp, x, tgt, block, head_loss = self._setup(pp)
         with mesh:
@@ -211,7 +205,6 @@ class TestOneFOneB:
         np.testing.assert_allclose(np.asarray(d_w), np.asarray(rd_w),
                                    atol=1e-5)
 
-    @requires_shard_map
     def test_gpt_value_and_grad_matches_dense(self):
         """PipelinedLM.value_and_grad (1f1b) vs autodiff on the dense GPT —
         including the tied-wte grad that sums embed+head contributions."""
@@ -239,7 +232,6 @@ class TestOneFOneB:
                 np.asarray(jax.tree.leaves(flat[k])[0]),
                 np.asarray(jax.tree.leaves(dense_grads[k])[0]), atol=5e-3)
 
-    @requires_shard_map
     def test_1f1b_compiled_memory_below_gpipe(self):
         """The O(pp) stash must show up as lower temp memory than GPipe's
         O(M) residuals when M >> pp (compiled on the CPU mesh)."""
@@ -270,7 +262,6 @@ class TestOneFOneB:
         assert fb_tmp < gp_tmp, (fb_tmp, gp_tmp)
 
 
-@requires_shard_map
 class TestPipelinedLM:
     def _gpt_cfg(self):
         return dataclasses.replace(GPTConfig.nano(), dtype=jnp.float32,
@@ -321,7 +312,6 @@ class TestPipelinedLM:
 
 
 class TestPipelineTraining:
-    @requires_shard_map
     def test_auto_accelerate_pp_trains(self):
         """pp=2 x fsdp=2 end-to-end: loss decreases over steps."""
         cfg = dataclasses.replace(GPTConfig.nano(), remat=False,
@@ -349,7 +339,6 @@ class TestPipelineTraining:
 
     @pytest.mark.parametrize("schedule,vstages",
                              [("1f1b", 1), ("interleaved", 2)])
-    @requires_shard_map
     def test_auto_accelerate_schedules_train(self, schedule, vstages):
         """pp=2 end-to-end under each non-default schedule: loss decreases
         and tp composition holds (tp=2 exercises GSPMD inside the stage)."""
@@ -375,7 +364,6 @@ class TestPipelineTraining:
             losses.append(float(m["loss"]))
         assert losses[-1] < losses[0], losses
 
-    @requires_shard_map
     def test_generic_adapter_model_stages(self):
         """Arbitrary layer-stack models pipeline via the adapter hooks."""
         import flax.linen as nn
@@ -434,7 +422,6 @@ class TestPipelineTraining:
 
     @pytest.mark.parametrize("schedule,vstages",
                              [("gpipe", 1), ("interleaved", 2)])
-    @requires_shard_map
     def test_moe_through_pipeline(self, schedule, vstages):
         """MoE models pipeline: the router aux loss crosses the schedule
         as an explicit scalar and matches the dense model's."""
@@ -469,7 +456,6 @@ class TestPipelineTraining:
         ce = float(cross_entropy_loss(logits, batch["labels"]))
         assert float(loss) > ce
 
-    @requires_shard_map
     def test_moe_pipeline_trains_e2e(self):
         cfg = dataclasses.replace(GPTConfig.nano(), remat=False,
                                   use_flash_attention=False,
@@ -492,7 +478,6 @@ class TestPipelineTraining:
 
     @pytest.mark.parametrize("schedule,vstages",
                              [("1f1b", 1), ("interleaved", 2)])
-    @requires_shard_map
     def test_schedules_compose_with_grad_accum(self, schedule, vstages):
         """Outer grad-accum microbatches wrap the pipeline's inner ones."""
         cfg = dataclasses.replace(GPTConfig.nano(), remat=False,
@@ -515,7 +500,6 @@ class TestPipelineTraining:
             losses.append(float(m["loss"]))
         assert losses[-1] < losses[0], losses
 
-    @requires_shard_map
     def test_pp_sp_gspmd_composes(self):
         """Sequence parallel in gspmd mode (XLA-inserted collectives)
         composes with the pipeline — only ring/ulysses are rejected."""
@@ -540,7 +524,6 @@ class TestPipelineTraining:
         assert losses[-1] < losses[0], losses
 
     @pytest.mark.parametrize("impl", ["ring", "ulysses"])
-    @requires_shard_map
     def test_pp_sp_ring_ulysses_grads_match_plain_pp(self, impl):
         """pp x ring/ulysses SP (round-4 closure): the attention shard_map
         nests inside the pipeline's manual-pp body (context AbstractMesh +
@@ -572,7 +555,6 @@ class TestPipelineTraining:
                                                     atol=1e-6),
             base, sp)
 
-    @requires_shard_map
     def test_1f1b_ring_sp_grads_match_and_train(self):
         """ring-SP inside the MANUAL 1f1b backward: gradient-exact vs
         plain 1f1b, and training steps."""
@@ -610,7 +592,6 @@ class TestPipelineTraining:
             losses.append(float(m["loss"]))
         assert losses[-1] < losses[0], losses
 
-    @requires_shard_map
     def test_llama_trains_under_1f1b(self):
         """The 1f1b value_and_grad path handles the Llama family (untied
         embed/head key split) too."""
@@ -633,7 +614,6 @@ class TestPipelineTraining:
             losses.append(float(m["loss"]))
         assert losses[-1] < losses[0], losses
 
-    @requires_shard_map
     def test_moe_pp_ep_composes(self):
         """Expert parallelism composes with the pipeline: experts shard
         over ep inside the stage while layers shard over pp."""
@@ -666,7 +646,6 @@ class TestPipelineTraining:
                           ("local_sgd", {"sync_every": 2})],
                 devices=jax.devices()[:4])
 
-    @requires_shard_map
     def test_moe_1f1b_composes_and_matches_gpipe(self):
         """MoE x 1f1b (round-3 rejection, now closed): the manual backward
         seeds the router aux-loss cotangent (1/M per microbatch), so the
@@ -716,7 +695,6 @@ class TestOneFOneBCustomHeadLoss:
     through ('pipeline_parallel', {'head_loss': fn}); whole-batch
     loss_fn stays rejected with a message pointing here."""
 
-    @requires_shard_map
     def test_label_smoothed_head_loss_matches_gpipe_equivalent(self):
         import flax.linen as nn
 

@@ -34,7 +34,6 @@ import sys
 
 import pytest
 
-from version_gates import requires_pinned_host
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 V5P_HBM_GIB = 95.0
@@ -71,7 +70,6 @@ class TestScale8B:
         # module docstring) is far inside one v5p's HBM
         assert r["arg_gib"] + 6.0 < V5P_HBM_GIB, r
 
-    @requires_pinned_host
     def test_fsdp8_tp2_bf16_offload_compiles_and_fits(self):
         """fsdp8 x tp2 with bf16 params (stable master) + host moments."""
         r = _run_fit(16, {
@@ -107,7 +105,6 @@ class TestScaleAbstract:
             Llama(cfg), optimizer=optax.adamw(3e-4), strategy=strategy,
             materialize=False, devices=jax.devices()[:n_dev]).state
 
-    @requires_pinned_host
     def test_offload_moments_are_pinned_host_at_8b(self):
         import jax
 

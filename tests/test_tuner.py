@@ -797,7 +797,7 @@ def test_winner_cutover_zero_cold_compiles(tmp_path):
     for var in ("DWT_FA_NO_FUSED", "DWT_FA_PACK", "DWT_FA_STREAMED",
                 "DWT_FP8_DENSE", "DWT_REMAT_POLICY"):
         env.pop(var, None)
-    env["DWT_COMPILE_CACHE_DIR"] = str(tmp_path / "cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
         [sys.executable, str(script)], env=env, cwd=REPO,

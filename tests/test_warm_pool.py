@@ -97,6 +97,100 @@ class TestCacheKeyInvalidation:
         assert k1 == k2
 
 
+_RESOLVER_PROBE = r"""
+import json, os, sys
+import jax
+updates = []
+_orig = jax.config.update
+jax.config.update = lambda k, v: (updates.append(k), _orig(k, v))[1]
+from dlrover_wuqiong_tpu.auto.compile_cache import (
+    enable_persistent_cache, pool_dir, registry_dir, resolve_cache_dir)
+from dlrover_wuqiong_tpu.auto.warm_pool import WarmPool
+from dlrover_wuqiong_tpu.master.job_manager import WarmMeshPolicy
+active = enable_persistent_cache()
+print(json.dumps({
+    "resolved": resolve_cache_dir(), "active": active,
+    "jax": jax.config.jax_compilation_cache_dir,
+    "dir_updates": updates.count("jax_compilation_cache_dir"),
+    "pool": WarmPool().pool, "policy": WarmMeshPolicy().cache_dir,
+    "pool_dir": pool_dir(active), "registry_dir": registry_dir(active)}))
+"""
+
+
+class TestCacheDirResolver:
+    """ONE resolver places the cache for every process of a job:
+    JAX_COMPILATION_CACHE_DIR when set — and then no code points JAX
+    anywhere else — otherwise the fixed <checkout>/.jax_cache."""
+
+    def _probe(self, env_dir):
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+        proc = subprocess.run([sys.executable, "-c", _RESOLVER_PROBE],
+                              env=env, cwd="/", capture_output=True,
+                              text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        return json.loads(proc.stdout.strip().splitlines()[-1])
+
+    @pytest.mark.parametrize("placed", [True, False])
+    def test_every_caller_agrees(self, tmp_path, placed):
+        want = str(tmp_path / "cc") if placed else \
+            os.path.join(REPO, ".jax_cache")
+        got = self._probe(want if placed else "")
+        assert got["resolved"] == got["active"] == got["jax"] == want
+        assert got["policy"] == want  # master's scale planner
+        assert got["pool"] == os.path.join(want, "warm-pool") \
+            == got["pool_dir"]      # agent / trainer warm pools
+        assert got["registry_dir"] == os.path.join(want, "framework-keys")
+        # placed from outside: JAX read the variable itself, nothing
+        # here may write the directory; unset: exactly one write
+        assert got["dir_updates"] == (0 if placed else 1)
+
+    def test_agent_exports_the_resolved_dir_to_workers(self, monkeypatch,
+                                                       tmp_path):
+        from dlrover_wuqiong_tpu.auto.compile_cache import (
+            CACHE_DIR_ENV,
+            resolve_cache_dir,
+        )
+
+        monkeypatch.delenv(CACHE_DIR_ENV, raising=False)
+        assert resolve_cache_dir() == os.path.join(REPO, ".jax_cache")
+        monkeypatch.setenv(CACHE_DIR_ENV, str(tmp_path))
+        assert resolve_cache_dir() == str(tmp_path)
+        assert WarmPool().cache_dir == str(tmp_path)
+
+    def test_disabled_cache_is_disabled_in_jax_too(self, tmp_path):
+        """DWT_COMPILE_CACHE=0 must win over the placement variable —
+        which switches JAX's own cache on all by itself."""
+        env = dict(os.environ, JAX_PLATFORMS="cpu", DWT_COMPILE_CACHE="0",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"))
+        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+        code = ("import jax, jax.numpy as jnp\n"
+                "from dlrover_wuqiong_tpu.auto.compile_cache import "
+                "enable_persistent_cache\n"
+                "assert enable_persistent_cache() is None\n"
+                "jax.jit(lambda x: x * 2)(jnp.ones(4)).block_until_ready()")
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert not os.path.exists(tmp_path / "cc") or \
+            not os.listdir(tmp_path / "cc")
+
+
+def test_no_warm_child_for_a_platform_whose_devices_are_held(tmp_path):
+    """One process per chip: a warm child would have to open devices the
+    training process holds — none is started, and one line says so."""
+    pool = WarmPool(str(tmp_path))
+    spec = WarmSpec(n_devices=1, strategy=[["fsdp", {}]],
+                    model={"kind": "gpt", "config": {"n_layer": 2}},
+                    batch_shape=[8, 32], platform="tpu")
+    assert pool.warm_async(spec) is None
+    assert not [n for n in os.listdir(pool.pool)
+                if n.endswith((".inflight", ".spec.json"))]
+
+
 class TestAutoAccelerateKey:
     """The key as computed by the real resolve path."""
 
@@ -115,7 +209,7 @@ class TestAutoAccelerateKey:
 
     def test_same_build_same_key_and_registry_warms(self, tmp_path,
                                                     monkeypatch):
-        monkeypatch.setenv("DWT_COMPILE_CACHE_DIR", str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         r1 = self._build(8, strategy=[("fsdp", {})])
         r2 = self._build(8, strategy=[("fsdp", {})])
         assert r1.cache_key == r2.cache_key
@@ -124,7 +218,7 @@ class TestAutoAccelerateKey:
         assert r1.strategy_spec == [["fsdp", {}]]
 
     def test_mesh_and_env_change_key(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("DWT_COMPILE_CACHE_DIR", str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         r8 = self._build(8, strategy=[("fsdp", {})])
         r4 = self._build(4, strategy=[("fsdp", {})])
         assert r8.cache_key != r4.cache_key
@@ -133,7 +227,7 @@ class TestAutoAccelerateKey:
         assert r8b.cache_key != r8.cache_key
 
     def test_auto_path_spells_out_plan(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("DWT_COMPILE_CACHE_DIR", str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         r = self._build(8)  # no strategy → auto_plan
         assert ["fsdp", {"size": 8}] in r.strategy_spec
 
@@ -310,7 +404,7 @@ def _run_restart_worker(tmp_path, cache_dir, n_dev):
     script.write_text(_RESTART_WORKER)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
-    env["DWT_COMPILE_CACHE_DIR"] = str(cache_dir)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
         [sys.executable, str(script), str(n_dev)], env=env, cwd=REPO,

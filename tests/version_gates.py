@@ -1,67 +1,21 @@
-"""Capability gates for features this container's jax cannot run.
+"""Helpers the tests share that answer for the INSTALLED libraries by
+probing them, never by version string."""
 
-The container ships jax 0.4.37; two feature families genuinely cannot
-run on it (ISSUE 3 satellite — report them as skips with a reason, not
-failures):
-
-- ``requires_shard_map`` — pipeline parallelism, local_sgd/DiLoCo and
-  ring/ulysses context-parallel attention build on the manual-axes
-  `jax.shard_map(axis_names=...)` API (jax >= 0.6;
-  parallel/pipeline.py:87 raises RuntimeError without it).
-- ``requires_pinned_host`` — optimizer_offload parks moments in
-  `pinned_host` memory; this jax's CPU backend only addresses
-  `unpinned_host`, so the offload shardings cannot even build
-  (trainer/train_step.py train_state_shardings).
-
-Both probes live in `common/util.py` so the dryrun gate
-(__graft_entry__.py) and the tests share one definition.
-"""
-
-import jax
-import pytest
-
-from dlrover_wuqiong_tpu.common.util import (
-    has_jax_shard_map,
-    has_multiprocess_cpu,
-    has_pinned_host_memory,
-)
-
-requires_shard_map = pytest.mark.skipif(
-    not has_jax_shard_map(),
-    reason="needs jax>=0.6 shard_map(axis_names=...) — container has "
-           f"jax {jax.__version__} (feature genuinely cannot run)")
 
 def shard_index_set(arr):
-    """Distinct shard indices of a jax Array, as hashable tuples.
-
-    `{s.index for s in arr.addressable_shards}` breaks on python < 3.12
-    (slices are unhashable) — the sharding feature works fine, only the
-    set idiom didn't; this helper keeps those assertions runnable."""
+    """Distinct shard indices of a jax Array, as hashable tuples."""
     return {tuple((sl.start, sl.stop, sl.step) for sl in s.index)
             for s in arr.addressable_shards}
 
 
-requires_pinned_host = pytest.mark.skipif(
-    not has_pinned_host_memory(),
-    reason="optimizer_offload needs a pinned_host memory kind; this "
-           f"backend on jax {jax.__version__} only addresses "
-           "unpinned_host (feature genuinely cannot run)")
-
-requires_multiprocess_cpu = pytest.mark.skipif(
-    not has_multiprocess_cpu(),
-    reason="multi-process SPMD is not implemented on the CPU backend "
-           f"before jax 0.5 (container has {jax.__version__}); the "
-           "jax.distributed e2e drills genuinely cannot run")
-
-
 def optax_belief_uses_stale_mu() -> bool:
     """True when this optax's AdaBelief computes the prediction error
-    against the PRE-update EMA (``g - state.mu``), as optax 0.2.x does —
-    the paper (and our sparse kernel, embedding/sparse_optim.py) uses the
-    POST-update EMA (``g - m_t``), so an exact match is impossible under
-    such an optax.  Probed numerically (one scalar step from zero state
-    distinguishes the two closed forms) rather than by version string, so
-    the gate answers for whatever optax is actually installed."""
+    against the PRE-update EMA (``g - state.mu``), as the installed optax
+    does — the paper (and our sparse kernel, embedding/sparse_optim.py)
+    uses the POST-update EMA (``g - m_t``), so an exact match is
+    impossible under such an optax.  Probed numerically (one scalar step
+    from zero state distinguishes the two closed forms), so the gate
+    answers for whatever optax is actually installed."""
     import jax.numpy as jnp
     import optax
 

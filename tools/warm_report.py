@@ -6,7 +6,7 @@ runs in milliseconds, safe from cron/CI):
     python tools/warm_report.py [cache_dir]
     python tools/warm_report.py --cache-dir DIR
 
-cache_dir defaults to DWT_COMPILE_CACHE_DIR, else the framework default
+cache_dir defaults to JAX_COMPILATION_CACHE_DIR, else the framework default
 (/tmp/dwt-compile-cache-<user>).  Fields:
 
 - warm_meshes: ready warm-pool entries (mesh, device count, compile_s,
@@ -87,14 +87,14 @@ def main(argv=None) -> int:
 
     def _offline(vals):
         from dlrover_wuqiong_tpu.auto.compile_cache import (
-            default_cache_dir)
+            resolve_cache_dir)
 
         # the historical positional form (`warm_report.py DIR`) keeps
         # working alongside the flag (tests/test_warm_pool.py drives it)
         positional = [a for a in argv if not a.startswith("-")]
         cache_dir = (vals.get("--cache-dir")
                      or (positional[0] if positional
-                         else default_cache_dir()))
+                         else resolve_cache_dir()))
         return _report(cache_dir)
 
     def _no_live(addr, vals):
