@@ -39,6 +39,7 @@ from .compile_cache import (
     train_step_cache_key,
 )
 from .tuner import env_signature as _tuner_env_signature
+from ..telemetry import spans as tspans
 from ..parallel.sharding import ShardingPlanner
 from ..trainer.train_step import (
     TrainState,
@@ -487,118 +488,127 @@ def auto_accelerate(
     optimizer steps.  Any K (the auto-tuned one included) is also
     available lazily via `result.fused_train_step(k)` without rebuilding.
     """
-    devices = list(devices if devices is not None else jax.devices())
-    # Level-1 warm restarts: every build compiles through the persistent
-    # XLA cache, so a restart on the same topology deserializes from disk
-    # instead of recompiling (idempotent; DWT_COMPILE_CACHE=0 disables)
-    cache_dir = enable_persistent_cache()
-    num_params = num_params_hint
-    if num_params is None and hasattr(model, "config") and \
-            hasattr(model.config, "num_params"):
-        num_params = model.config.num_params()
-    ctx = resolve_strategy(strategy, len(devices), num_params, seq_len,
-                           hbm_per_device=detect_hbm_per_device(devices))
-    if accum_steps:
-        ctx.accum_steps = accum_steps
-    # resolve-time lint gate: an impossible donation request fails HERE,
-    # before model init burns work on a doomed config (strategy-matrix
-    # convention; graftlint donation-alias)
-    donate = resolve_donation(ctx.extra, donate)
-    if fused_steps > 1 and ctx.extra.get("local_sgd") is not None:
-        # strategy-matrix convention: incompatibilities error at resolve
-        # time, before any parameter init
-        raise ValueError(
-            "fused_steps > 1 does not compose with local_sgd — the DiLoCo "
-            "step's outer sync counts dispatches, and a K-step fusion "
-            "would scan across sync boundaries; run unfused "
-            "(fused_steps=1)")
-    overrides = ctx.model_overrides(model)
-    if overrides:
-        # rebuild the model with the strategy's amp/remat/flash flags
-        model = _with_config(model, **overrides)
-        logger.info("strategy overrides model config: %s",
-                    {k: getattr(v, "__name__", v)
-                     for k, v in overrides.items()})
-    _warn_slow_offload_link(ctx, devices, num_params)
-    mesh = build_mesh(ctx.plan, devices)
-    planner = ShardingPlanner(mesh)
-    if ctx.plan.ep > 1:
-        planner.with_moe()
-    sp_impl = ctx.extra.get("sp_impl", "ulysses")
-    has_attn_cfg = hasattr(model, "config") and \
-        dataclasses.is_dataclass(model.config) and \
-        any(f.name == "attn_impl"
-            for f in dataclasses.fields(model.config))
-    if mesh.size > 1 and has_attn_cfg:
-        # every multi-device plan hands the attention dispatch its mesh:
-        # the Pallas kernels cannot be partitioned by GSPMD and run under
-        # a shard_map over it (models/attention.py)
-        model = _with_config(model, mesh=mesh)
-    if ctx.plan.sp > 1 and sp_impl != "gspmd" and has_attn_cfg:
-        # context-parallel attention: ring (ppermute) or Ulysses (all-to-all)
-        heads = getattr(model.config, "n_head",
-                        getattr(model.config, "num_heads", None))
-        if sp_impl == "ulysses" and heads and heads % ctx.plan.sp:
+    # two spans, because a seeded or resumed run throws the init away
+    # and keeps the plan: `accelerate:plan`, then `accelerate:init_state`
+    with tspans.span("accelerate:plan"):
+        devices = list(devices if devices is not None else jax.devices())
+        # Level-1 warm restarts: every build compiles through the persistent
+        # XLA cache, so a restart on the same topology deserializes from disk
+        # instead of recompiling (idempotent; DWT_COMPILE_CACHE=0 disables)
+        cache_dir = enable_persistent_cache()
+        num_params = num_params_hint
+        if num_params is None and hasattr(model, "config") and \
+                hasattr(model.config, "num_params"):
+            num_params = model.config.num_params()
+        ctx = resolve_strategy(strategy, len(devices), num_params, seq_len,
+                               hbm_per_device=detect_hbm_per_device(devices))
+        if accum_steps:
+            ctx.accum_steps = accum_steps
+        # resolve-time lint gate: an impossible donation request fails HERE,
+        # before model init burns work on a doomed config (strategy-matrix
+        # convention; graftlint donation-alias)
+        donate = resolve_donation(ctx.extra, donate)
+        if fused_steps > 1 and ctx.extra.get("local_sgd") is not None:
+            # strategy-matrix convention: incompatibilities error at resolve
+            # time, before any parameter init
             raise ValueError(
-                f"ulysses sequence parallel needs heads ({heads}) divisible "
-                f"by sp={ctx.plan.sp}; use impl='ring' or adjust sp")
-        model = _with_config(model, attn_impl=sp_impl)
-        logger.info("sequence parallel: %s attention over sp=%d", sp_impl,
-                    ctx.plan.sp)
+                "fused_steps > 1 does not compose with local_sgd — the "
+                "DiLoCo step's outer sync counts dispatches, and a K-step "
+                "fusion would scan across sync boundaries; run unfused "
+                "(fused_steps=1)")
+        overrides = ctx.model_overrides(model)
+        if overrides:
+            # rebuild the model with the strategy's amp/remat/flash flags
+            model = _with_config(model, **overrides)
+            logger.info("strategy overrides model config: %s",
+                        {k: getattr(v, "__name__", v)
+                         for k, v in overrides.items()})
+        _warn_slow_offload_link(ctx, devices, num_params)
+        mesh = build_mesh(ctx.plan, devices)
+        planner = ShardingPlanner(mesh)
+        if ctx.plan.ep > 1:
+            planner.with_moe()
+        sp_impl = ctx.extra.get("sp_impl", "ulysses")
+        has_attn_cfg = hasattr(model, "config") and \
+            dataclasses.is_dataclass(model.config) and \
+            any(f.name == "attn_impl"
+                for f in dataclasses.fields(model.config))
+        if mesh.size > 1 and has_attn_cfg:
+            # every multi-device plan hands the attention dispatch its mesh:
+            # the Pallas kernels cannot be partitioned by GSPMD and run under
+            # a shard_map over it (models/attention.py)
+            model = _with_config(model, mesh=mesh)
+        if ctx.plan.sp > 1 and sp_impl != "gspmd" and has_attn_cfg:
+            # context-parallel attention: ring (ppermute) or Ulysses
+            # (all-to-all)
+            heads = getattr(model.config, "n_head",
+                            getattr(model.config, "num_heads", None))
+            if sp_impl == "ulysses" and heads and heads % ctx.plan.sp:
+                raise ValueError(
+                    f"ulysses sequence parallel needs heads ({heads}) "
+                    f"divisible by sp={ctx.plan.sp}; use impl='ring' or "
+                    f"adjust sp")
+            model = _with_config(model, attn_impl=sp_impl)
+            logger.info("sequence parallel: %s attention over sp=%d", sp_impl,
+                        ctx.plan.sp)
 
-    # the trace-defining model config, captured before pipeline wrapping
-    # hides it (PipelinedLM's stage slicing is keyed via ctx.extra)
-    cfg_for_key = getattr(model, "config", None)
+        # the trace-defining model config, captured before pipeline wrapping
+        # hides it (PipelinedLM's stage slicing is keyed via ctx.extra)
+        cfg_for_key = getattr(model, "config", None)
 
-    if ctx.plan.pp > 1:
-        # stage-sliced GPipe pipeline over the pp axis (parallel/pipeline.py)
-        from ..parallel.pipeline import PipelinedLM, PipelineShardingPlanner
+        if ctx.plan.pp > 1:
+            # stage-sliced GPipe pipeline over the pp axis
+            # (parallel/pipeline.py)
+            from ..parallel.pipeline import (
+                PipelinedLM,
+                PipelineShardingPlanner,
+            )
 
-        # pp x ring/ulysses SP composes: the attention's inner shard_map
-        # nests inside the pipeline's manual-pp body via the context
-        # AbstractMesh with VMA tracking (parallel/long_context.py
-        # _context_mesh) — the long-context 70B configuration's layout
-        # (MoE composes with every schedule incl. 1f1b — the manual
-        # backward seeds the router aux cotangent, parallel/pipeline.py)
-        n_layer = getattr(model.config, "n_layer",
-                          getattr(model.config, "num_layers", None))
-        if n_layer is None or n_layer % ctx.plan.pp:
-            raise ValueError(
-                f"pipeline_parallel needs layers ({n_layer}) divisible by "
-                f"pp={ctx.plan.pp}")
-        from ..parallel.pipeline import default_pp_microbatches
+            # pp x ring/ulysses SP composes: the attention's inner shard_map
+            # nests inside the pipeline's manual-pp body via the context
+            # AbstractMesh with VMA tracking (parallel/long_context.py
+            # _context_mesh) — the long-context 70B configuration's layout
+            # (MoE composes with every schedule incl. 1f1b — the manual
+            # backward seeds the router aux cotangent, parallel/pipeline.py)
+            n_layer = getattr(model.config, "n_layer",
+                              getattr(model.config, "num_layers", None))
+            if n_layer is None or n_layer % ctx.plan.pp:
+                raise ValueError(
+                    f"pipeline_parallel needs layers ({n_layer}) divisible by "
+                    f"pp={ctx.plan.pp}")
+            from ..parallel.pipeline import default_pp_microbatches
 
-        microbatches = ctx.extra.get("pp_microbatches") or \
-            default_pp_microbatches(ctx.accum_steps, ctx.plan.pp)
-        pp_schedule = ctx.extra.get("pp_schedule", "gpipe")
-        pp_virtual = ctx.extra.get("pp_virtual_stages", 1)
-        if pp_schedule == "1f1b" and loss_fn is not None:
-            raise ValueError(
-                "pipeline schedule '1f1b' cannot honor a whole-batch "
-                "(params, batch) loss_fn — its backward seeds PER-"
-                "MICROBATCH head vjps in-schedule.  Pass a per-microbatch "
-                "head loss instead: ('pipeline_parallel', {'head_loss': "
-                "fn(head_params, h, labels) -> scalar}), or use "
-                "schedule='gpipe'/'interleaved'")
-        if ctx.extra.get("local_sgd") is not None:
-            # reject HERE, before PipelinedLM wrapping and the (possibly
-            # many-GB) init_params below burn work on a doomed config
-            raise ValueError(
-                "local_sgd does not compose with pipeline_parallel — the "
-                "pipeline's PARTIALLY-manual shard_map ({pp} with other "
-                "axes GSPMD) cannot nest under the DiLoCo dp-manual body: "
-                "the partitioner rejects re-binding the parent's dp axis "
-                "(ring/ulysses SP nests fine because it goes FULLY manual "
-                "inside)")
-        model = PipelinedLM(model, mesh, microbatches,
-                            schedule=pp_schedule,
-                            virtual_stages=pp_virtual,
-                            head_loss_fn=ctx.extra.get("pp_head_loss"))
-        planner = PipelineShardingPlanner(planner)
-        logger.info("pipeline parallel: %d stages x %d layers, %d "
-                    "microbatches, schedule=%s%s", ctx.plan.pp,
-                    n_layer // ctx.plan.pp, microbatches, pp_schedule,
-                    f" v={pp_virtual}" if pp_virtual > 1 else "")
+            microbatches = ctx.extra.get("pp_microbatches") or \
+                default_pp_microbatches(ctx.accum_steps, ctx.plan.pp)
+            pp_schedule = ctx.extra.get("pp_schedule", "gpipe")
+            pp_virtual = ctx.extra.get("pp_virtual_stages", 1)
+            if pp_schedule == "1f1b" and loss_fn is not None:
+                raise ValueError(
+                    "pipeline schedule '1f1b' cannot honor a whole-batch "
+                    "(params, batch) loss_fn — its backward seeds PER-"
+                    "MICROBATCH head vjps in-schedule.  Pass a per-microbatch "
+                    "head loss instead: ('pipeline_parallel', {'head_loss': "
+                    "fn(head_params, h, labels) -> scalar}), or use "
+                    "schedule='gpipe'/'interleaved'")
+            if ctx.extra.get("local_sgd") is not None:
+                # reject HERE, before PipelinedLM wrapping and the (possibly
+                # many-GB) init_params below burn work on a doomed config
+                raise ValueError(
+                    "local_sgd does not compose with pipeline_parallel — the "
+                    "pipeline's PARTIALLY-manual shard_map ({pp} with other "
+                    "axes GSPMD) cannot nest under the DiLoCo dp-manual body: "
+                    "the partitioner rejects re-binding the parent's dp axis "
+                    "(ring/ulysses SP nests fine because it goes FULLY manual "
+                    "inside)")
+            model = PipelinedLM(model, mesh, microbatches,
+                                schedule=pp_schedule,
+                                virtual_stages=pp_virtual,
+                                head_loss_fn=ctx.extra.get("pp_head_loss"))
+            planner = PipelineShardingPlanner(planner)
+            logger.info("pipeline parallel: %d stages x %d layers, %d "
+                        "microbatches, schedule=%s%s", ctx.plan.pp,
+                        n_layer // ctx.plan.pp, microbatches, pp_schedule,
+                        f" v={pp_virtual}" if pp_virtual > 1 else "")
 
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     optimizer = optimizer or optax.adamw(3e-4)
@@ -632,7 +642,10 @@ def auto_accelerate(
         p_abs = jax.eval_shape(_init_params, rng)
         p_sh = planner.param_shardings(p_abs)
         assert_no_host_out_shardings(p_sh, where="local_sgd param init")
-        params = jax.jit(_init_params, out_shardings=p_sh)(rng)
+        with tspans.span("accelerate:init_state"):
+            # as in the other two branches: the span waits for the device
+            params = jax.block_until_ready(
+                jax.jit(_init_params, out_shardings=p_sh)(rng))
         # DiLoCo two-level training (parallel/local_sgd.py): the dp axis
         # becomes the replica-group axis that only syncs every H steps
         from ..parallel.local_sgd import (
@@ -718,11 +731,18 @@ def auto_accelerate(
             # graftlint enforces the invariant: the tree handed to jit
             # must be device-kind (host-kind-out-shardings check).
             assert_no_host_out_shardings(dev_sh, where="offload state init")
-            state = jax.jit(_create_state, out_shardings=dev_sh)(rng)
-            state = jax.device_put(state, state_sh)
+            with tspans.span("accelerate:init_state"):
+                state = jax.jit(_create_state, out_shardings=dev_sh)(rng)
+                state = jax.block_until_ready(
+                    jax.device_put(state, state_sh))
         else:
             assert_no_host_out_shardings(state_sh, where="state init")
-            state = jax.jit(_create_state, out_shardings=state_sh)(rng)
+            with tspans.span("accelerate:init_state"):
+                # dispatch is asynchronous: the span ends when the state
+                # is there, or the init's time lands on whoever waits
+                # next — in every branch that opens this span
+                state = jax.block_until_ready(
+                    jax.jit(_create_state, out_shardings=state_sh)(rng))
         vg_fn = None
         if ctx.plan.pp > 1 and ctx.extra.get("pp_schedule") == "1f1b":
             # manual fwd/bwd interleave replaces autodiff-through-apply

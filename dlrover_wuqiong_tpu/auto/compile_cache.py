@@ -34,10 +34,12 @@ that omits them would claim a warm entry the XLA layer then misses.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
 import json
 import os
+import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
@@ -82,6 +84,25 @@ class CacheCounters:
 
 
 counters = CacheCounters()
+
+# what JAX spent getting each program ready, one record per
+# `jax.monitoring` duration event: {"name", "fun_name", "t_mono" (start),
+# "dur_s"}.  `jax:trace` (Python -> jaxpr), `jax:lower` (jaxpr -> MLIR),
+# `jax:backend_compile` (XLA compile OR the load out of the persistent
+# cache, whichever served it) and, inside the latter, `jax:cache_load`
+# (the retrieval alone).  The first dispatch of the step is made of
+# these; a set-up reader sums the ones with the step's `fun_name`.  A
+# ring of its own (drop-oldest): eager helpers emit hundreds of them and
+# must not push a control-plane span out of telemetry/spans.py's buffer.
+_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax:trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax:lower",
+    "/jax/core/compile/backend_compile_duration": "jax:backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax:cache_load",
+}
+durations: "collections.deque[Dict[str, Any]]" = collections.deque(
+    maxlen=16384)
+_unnamed = threading.local()
 _listeners_installed = False
 _enabled_dir: Optional[str] = None
 
@@ -140,6 +161,23 @@ def _install_listeners() -> None:
         if name.endswith("/compile_time_saved_sec") and secs > 0:
             counters.time_saved_s += secs
             _export("dwt_compile_cache_time_saved_seconds", secs)
+        short = _DURATION_EVENTS.get(name)
+        if short is None:
+            return
+        rec = {"name": short, "fun_name": str(kw.get("fun_name", "")),
+               "t_mono": time.monotonic() - secs, "dur_s": secs}
+        # the retrieval event carries no name and fires inside the
+        # compile it served, on the compiling thread: it is that
+        # function's.  Any thread compiles (loop, pump, eval, drain), so
+        # the unnamed record waits in a thread-local and nobody walks
+        # the shared ring
+        if short == "jax:cache_load":
+            _unnamed.load = rec
+        elif short == "jax:backend_compile":
+            load = _unnamed.__dict__.pop("load", None)
+            if load is not None:
+                load["fun_name"] = rec["fun_name"]
+        durations.append(rec)
 
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)

@@ -42,6 +42,7 @@ from ..common.constants import CheckpointConstant
 from ..common.log import get_logger
 from ..common.multi_process import SharedLock, SharedQueue
 from ..common.storage import CheckpointStorage, get_checkpoint_storage
+from ..telemetry import spans as tspans
 from .integrity import DIGEST_ALGO, build_manifest, digest_bytes, \
     write_manifest
 from .shm_handler import SharedMemoryHandler, sweep_stale_segments
@@ -265,7 +266,10 @@ class AsyncCheckpointSaver:
                 continue
             if etype == _SAVE_EVENT:
                 try:
-                    self.save_step_checkpoint(event["step"], event["path"])
+                    with tspans.span("ckpt:persist",
+                                     {"step": event["step"]}):
+                        self.save_step_checkpoint(event["step"],
+                                                  event["path"])
                 except Exception:  # noqa: BLE001
                     logger.exception("async save of step %s failed",
                                      event.get("step"))
@@ -371,6 +375,13 @@ class AsyncCheckpointSaver:
     def _save_shard_locked(self, handler: SharedMemoryHandler, step: int,
                            sdir: str, local_rank: int) -> bool:
         header = handler.load_header()
+        if header is None or header.get("step") != step:
+            # this mapping may be of a segment that is gone: the writer
+            # unlinks and recreates it to grow, and a stale-segment sweep
+            # can reap it between two worker generations.  Look the name
+            # up again before calling the step missing.
+            handler.close()
+            header = handler.load_header()
         if header is None:
             logger.warning("no shm data for local rank %d", local_rank)
             return False

@@ -229,10 +229,12 @@ class CheckpointEngine:
         return err
 
     def _drain(self, prev: Optional[threading.Thread], snapshot: Any,
-               step: int, extra: Dict, storage_path: Optional[str]):
+               step: int, extra: Dict, storage_path: Optional[str],
+               trace: Optional[Dict] = None):
         """Background: wait out the predecessor staging (the segment must
         stay whole — one writer at a time), then snapshot → shm (batched
-        async D2H), then hand off."""
+        async D2H), then hand off.  `trace` is the saving thread's span
+        context: `ckpt:drain` hangs under its `ckpt:save`."""
         try:
             if prev is not None and prev.is_alive():
                 t0 = time.monotonic()
@@ -240,7 +242,9 @@ class CheckpointEngine:
                 waited = time.monotonic() - t0
                 with self._drain_lock:
                     self._chain_wait_s += waited
-            self._stage_locked(snapshot, step, extra)
+            with tspans.extract(trace), \
+                    tspans.span("ckpt:drain", {"step": step}):
+                self._stage_locked(snapshot, step, extra)
             if storage_path is not None:
                 self._event_queue.put(CheckpointEvent.save(step,
                                                            storage_path))
@@ -279,7 +283,8 @@ class CheckpointEngine:
             # restore a stale segment left over from an unrelated job run
             extra.setdefault("_ckpt_dir", path or self.checkpoint_dir)
             try:
-                snapshot = self._device_snapshot(state)
+                with tspans.span("ckpt:snapshot"):
+                    snapshot = self._device_snapshot(state)
             except Exception as e:  # noqa: BLE001
                 # state too big to double-buffer in HBM (e.g. GPT-2 xl +
                 # AdamW on a 16GB chip): fall back to synchronous staging
@@ -309,7 +314,8 @@ class CheckpointEngine:
                 self._drain_pending += 1
             self._drain_thread = threading.Thread(
                 target=self._drain, args=(prev, snapshot, step, extra,
-                                          storage_path),
+                                          storage_path,
+                                          tspans.current_trace()),
                 daemon=True, name="dwt-ckpt-drain")
             self._drain_thread.start()
             blocked = time.monotonic() - t0

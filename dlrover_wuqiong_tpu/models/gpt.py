@@ -194,9 +194,12 @@ class GPT(nn.Module):
         for i in range(cfg.n_layer):
             x = block(cfg, name=f"h_{i}")(x, deterministic)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
-        # weight-tied lm head (einsum against wte)
+        # weight-tied lm head (einsum against wte); it sits in no flax
+        # module, so the scope is what names its ops in the compiled step
+        # (analysis/hlo_scopes.py)
         wte = self.variables["params"]["wte"]["embedding"]
-        logits = jnp.einsum("bte,ve->btv", x, wte.astype(cfg.dtype))
+        with jax.named_scope("head"):
+            logits = jnp.einsum("bte,ve->btv", x, wte.astype(cfg.dtype))
         if return_hidden:  # e.g. a value head on the trunk (rl/ppo.py)
             return logits, x
         return logits
@@ -226,6 +229,9 @@ def _ce(logits, targets, ignore_index):
     return _ce_fwd(logits, targets, ignore_index)[0]
 
 
+# both rules open the `loss` scope themselves: the backward rule is
+# traced from the transpose, outside any scope the caller had open
+@jax.named_scope("loss")
 def _ce_fwd(logits, targets, ignore_index):
     valid = targets != ignore_index
     safe_targets = jnp.where(valid, targets, 0)
@@ -239,6 +245,7 @@ def _ce_fwd(logits, targets, ignore_index):
     return loss, (logits, safe_targets, valid, lse, n_valid)
 
 
+@jax.named_scope("loss")
 def _ce_bwd(ignore_index, res, g):
     logits, safe_targets, valid, lse, n_valid = res
     scale = (g * valid / n_valid).astype(jnp.float32)[..., None]

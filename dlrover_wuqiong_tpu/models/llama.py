@@ -201,8 +201,9 @@ class Llama(nn.Module):
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"layers_{i}")(x, cos, sin)
         x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          name="lm_head")(x)
+        with jax.named_scope("head"):  # as models/gpt.py names its head
+            logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                              dtype=cfg.dtype, name="lm_head")(x)
         return logits
 
     def init_params(self, rng, batch: int = 1, seq: int = 8):

@@ -56,9 +56,11 @@ from .perf import (  # noqa: F401
     RegressionSentinel,
     executable_key,
     get_observatory,
+    keep_step_executable,
     latest_snapshot,
     reset_observatory,
     set_observatory,
+    step_executables,
 )
 from .recorder import (  # noqa: F401
     FLIGHT_SCHEMA_VERSION,
@@ -85,6 +87,8 @@ from .spans import (  # noqa: F401
     dump_chrome_trace,
     env_context,
     extract,
+    hot_span,
+    hot_spans_snapshot,
     inject,
     set_process_role,
     span,

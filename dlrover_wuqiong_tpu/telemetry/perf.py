@@ -46,6 +46,7 @@ exercise the exact firing logic without a backend.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -595,3 +596,43 @@ def latest_snapshot() -> Optional[Dict]:
     """The flight recorder's embed hook (telemetry/recorder.py flush)."""
     obs = get_observatory()
     return obs.snapshot() if obs is not None else None
+
+
+# the compiled step programs this process runs, by mode (fused K,
+# trace-env signature), for a reader that wants their text afterwards
+# (analysis/hlo_scopes.py turns it into instruction -> scope).  What the
+# loop keeps is the way back to the executable, never the executable
+# and never an array: `jitted.lower(...).compile()` on the shapes,
+# dtypes and shardings of a dispatch is answered by JAX's in-memory
+# caches, nothing is traced, lowered or compiled again.  A struct names
+# its sharding only where the array was committed to it — jit keys an
+# uncommitted argument as unspecified, and a struct that pinned it
+# would miss the cache and compile.  `lower` walks every leaf in Python
+# (11 ms at GPT-2 124M's 450 leaves, 51–67 ms at XL's 1,740; PERF.md,
+# PR 24), so it runs when someone asks, off the loop.
+_step_executables: Dict[Any, Callable[[], Any]] = {}
+
+
+def keep_step_executable(mode: Any, jitted: Any, state: Any,
+                         batch: Any) -> None:
+    import jax
+
+    # on the loop only (aval, sharding) per leaf, ~1 us each; building
+    # the structs costs ten times that and waits for whoever asks
+    leaves, treedef = jax.tree.flatten((state, batch))
+    like = [(jax.typeof(x),
+             x.sharding if getattr(x, "committed", False) else None)
+            for x in leaves]
+
+    def find():
+        args = treedef.unflatten(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, weak_type=a.weak_type,
+                                 sharding=s) for a, s in like)
+        return jitted.lower(*args).compile()
+
+    _step_executables[mode] = functools.cache(find)
+
+
+def step_executables() -> Dict[Any, Any]:
+    """{mode: jax.stages.Compiled} of every step program kept so far."""
+    return {mode: find() for mode, find in list(_step_executables.items())}

@@ -17,7 +17,25 @@ Perfetto format).
 
 Clocks: span *durations* are ``time.monotonic`` intervals; span *start
 timestamps* are ``time.time`` so spans from different processes align on
-one timeline (the one sanctioned cross-process use of wall clock).
+one timeline (the one sanctioned cross-process use of wall clock), with
+the monotonic start beside it (``t_mono``) for readers inside the
+process that cut spans to a window stamped on that clock.
+
+The profiler's clock: in a process that has imported JAX every span also
+opens a ``jax.profiler.TraceAnnotation`` of its name, so it lands on the
+host plane of whichever profiler trace is running (the benchmark's,
+`StepProfiler`'s, the perf observatory's) beside ``XLA Ops``.  A process
+that never imported JAX (agent, master, launcher) has no profiler and
+must not load one; there the annotation is skipped.
+
+Two rings.  `span()` writes the full record into the bounded buffer and
+the flight recorder: control plane, checkpoint and set-up spans, a few
+per boundary at most.  `hot_span()` is for what runs every optimizer
+step (`trainer:iteration` and its children, the metrics pump): a tuple
+in a ring of its own that never reaches the flight recorder, so ten
+thousand iterations push out neither a set-up span nor a control-plane
+one.  Both kinds nest in one per-thread stack, so a full span opened
+under a hot one names it as its parent.
 
 Child processes spawned mid-span inherit the active context through
 ``DWT_TRACE_ID`` / ``DWT_TRACE_PARENT`` (see `env_context`); the spawned
@@ -27,7 +45,9 @@ side picks them up lazily on its first span.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import os
+import sys
 import threading
 import time
 import uuid
@@ -43,6 +63,12 @@ _MAX_SPANS = 2048
 
 _BUFFER: "deque[Dict]" = deque(maxlen=_MAX_SPANS)
 _BUFFER_LOCK = threading.Lock()
+
+#: ring of per-step records (drop-oldest): (name, t_mono, dur_s, span_id,
+#: parent_span, trace_id, thread id).  Appends are atomic under the GIL.
+_MAX_HOT_SPANS = 32768
+_HOT: "deque[tuple]" = deque(maxlen=_MAX_HOT_SPANS)
+_HOT_IDS = itertools.count(1)
 
 _TLS = threading.local()
 
@@ -117,6 +143,18 @@ def env_context():
     yield env
 
 
+def _annotation(name: str):
+    """An entered `jax.profiler.TraceAnnotation`, or None in a process
+    that has not imported JAX (importing it here would hand the agent
+    and the master a runtime they must stay clear of)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 def _record(rec: Dict):
     with _BUFFER_LOCK:
         _BUFFER.append(rec)
@@ -139,21 +177,67 @@ def span(name: str, attrs: Optional[Dict] = None):
         "role": process_role(),
         "pid": os.getpid(),
         "t_wall": time.time(),
+        "t_mono": time.monotonic(),
         "dur_s": 0.0,
         "attrs": dict(attrs or {}),
         "status": "ok",
     }
     stack.append({"trace_id": rec["trace_id"], "span_id": rec["span_id"]})
-    t0 = time.monotonic()
+    ann = _annotation(name)
     try:
         yield rec
     except BaseException:
         rec["status"] = "error"
         raise
     finally:
-        rec["dur_s"] = time.monotonic() - t0
+        rec["dur_s"] = time.monotonic() - rec["t_mono"]
+        if ann is not None:
+            ann.__exit__(None, None, None)
         stack.pop()
         _record(rec)
+
+
+class hot_span:
+    """`with hot_span(name):` — the light span of the per-step path.
+
+    Same nesting, same ids on the thread's stack and the same profiler
+    annotation as `span()`, but the record is one tuple in `_HOT`: no
+    dict, no uuid, no lock, no flight-recorder event."""
+
+    __slots__ = ("name", "_ann", "_t0", "_ctx", "_parent")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        stack = _stack()
+        parent = stack[-1] if stack else None
+        sid = f"h{next(_HOT_IDS):x}"
+        self._parent = parent.get("span_id", "") if parent else ""
+        self._ctx = {"trace_id": parent["trace_id"] if parent else sid,
+                     "span_id": sid}
+        stack.append(self._ctx)
+        self._ann = _annotation(self.name)
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.monotonic() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _stack().pop()
+        _HOT.append((self.name, self._t0, dur, self._ctx["span_id"],
+                     self._parent, self._ctx["trace_id"],
+                     threading.get_ident()))
+        return False
+
+
+def hot_spans_snapshot() -> List[Dict]:
+    """The per-step ring as dicts, oldest first: name, t_mono, dur_s,
+    span_id, parent_span, trace_id, tid."""
+    keys = ("name", "t_mono", "dur_s", "span_id", "parent_span",
+            "trace_id", "tid")
+    return [dict(zip(keys, rec)) for rec in list(_HOT)]
 
 
 def span_event(name: str, attrs: Optional[Dict] = None):
@@ -171,6 +255,7 @@ def spans_snapshot() -> List[Dict]:
 def clear_spans():
     with _BUFFER_LOCK:
         _BUFFER.clear()
+    _HOT.clear()
 
 
 def dump_chrome_trace(path: str, extra_spans: Optional[List[Dict]] = None,
@@ -204,6 +289,13 @@ def dump_chrome_trace(path: str, extra_spans: Optional[List[Dict]] = None,
             "args": dict(inst.get("args") or {}),
         })
     buffered = spans_snapshot() if include_buffer else []
+    if include_buffer:
+        # the per-step ring keeps the monotonic clock only: anchor it to
+        # the wall here, as a flight dump's envelope does
+        to_wall = time.time() - time.monotonic()  # graftlint: disable=wall-clock-duration -- the wall/monotonic anchor of an export, not elapsed-time math
+        buffered += [{**rec, "t_wall": rec["t_mono"] + to_wall,
+                      "role": process_role(), "pid": os.getpid()}
+                     for rec in hot_spans_snapshot()]
     for rec in (extra_spans or []) + buffered:
         events.append({
             "name": rec["name"],

@@ -43,6 +43,7 @@ class TrainState(NamedTuple):
                    opt_state=optimizer.init(params))
 
 
+@jax.named_scope("accum")  # names the scan in the compiled step
 def accumulate_grads(grad_fn, params, batch, accum_steps: int):
     """Mean loss + mean grads over the leading microbatch axis of `batch`.
 
@@ -121,14 +122,18 @@ def make_train_step(
             loss, grads = accumulate_grads(
                 lambda micro: _grads(state.params, micro), state.params,
                 batch, accum_steps)
-        opt_in = state.opt_state
-        if opt_host_shardings is not None:
-            opt_in = jax.device_put(opt_in, opt_device_shardings)
-        updates, opt_state = optimizer.update(grads, opt_in, state.params)
-        if opt_host_shardings is not None:
-            opt_state = jax.device_put(opt_state, opt_host_shardings)
-        params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        # outside any flax module: the scope names the clip, the update
+        # and the norm in the compiled step (analysis/hlo_scopes.py)
+        with jax.named_scope("optimizer"):
+            opt_in = state.opt_state
+            if opt_host_shardings is not None:
+                opt_in = jax.device_put(opt_in, opt_device_shardings)
+            updates, opt_state = optimizer.update(grads, opt_in,
+                                                  state.params)
+            if opt_host_shardings is not None:
+                opt_state = jax.device_put(opt_state, opt_host_shardings)
+            params = optax.apply_updates(state.params, updates)
+            gnorm = optax.global_norm(grads)
         new_state = TrainState(state.step + 1, params, opt_state)
         return new_state, {"loss": loss, "grad_norm": gnorm}
 

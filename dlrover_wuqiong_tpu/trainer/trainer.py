@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, Iterable, Optional
 import numpy as np
 
 from ..common.log import get_logger
+from ..telemetry import spans as tspans
 
 logger = get_logger("trainer")
 
@@ -279,6 +280,14 @@ class Trainer:
                  train_data: Any, eval_data: Any = None,
                  optimizer=None, loss_fn: Optional[Callable] = None,
                  callbacks: Optional[list] = None):
+        # construction is a large share of a restart (the plan, a sharded
+        # state init, the checkpoint engine): its own span and children
+        with tspans.span("trainer:build"):
+            self._build(model, args, train_data, eval_data, optimizer,
+                        loss_fn, callbacks)
+
+    def _build(self, model, args, train_data, eval_data, optimizer,
+               loss_fn, callbacks):
         import optax
 
         self.model = model
@@ -312,10 +321,11 @@ class Trainer:
 
         from ..checkpoint.checkpointer import FlashCheckpointer
 
-        self.ckpt = FlashCheckpointer(
-            os.path.join(args.output_dir, "checkpoints"),
-            job_name=os.getenv("DWT_JOB_NAME", "dwt"),
-            wire_dtype=args.ckpt_wire_dtype)
+        with tspans.span("ckpt:open"):
+            self.ckpt = FlashCheckpointer(
+                os.path.join(args.output_dir, "checkpoints"),
+                job_name=os.getenv("DWT_JOB_NAME", "dwt"),
+                wire_dtype=args.ckpt_wire_dtype)
 
         from ..utils.profiler import StepProfiler
 
@@ -874,11 +884,24 @@ class Trainer:
         fusion lives here; that sync also flushes the fused block's
         device work into any open perf window's trace.  Reads trainer
         state but never writes it — results flow back through the pump's
-        lock-guarded fields."""
+        lock-guarded fields (and `_readback_mark`, which only this
+        consumer touches once the loop runs)."""
         step = job["step"]
-        # metrics is an executable OUTPUT: donation-immune, safe to read
-        # after the main thread has dispatched the next fusion
-        loss = float(job["metrics"]["loss"])
+        with tspans.extract(job.get("trace")):
+            with tspans.hot_span("pump:readback"):
+                # metrics is an executable OUTPUT: donation-immune, safe
+                # to read after the main thread has dispatched the next
+                # fusion
+                loss = float(job["metrics"]["loss"])
+                t_read = time.monotonic()
+            with tspans.hot_span("pump:report"):
+                self._report_boundary(job, step, loss, t_read)
+        return loss
+
+    def _report_boundary(self, job: Dict[str, Any], step: int, loss: float,
+                         t_read: float) -> None:
+        """What follows the readback at a logging boundary: perf-window
+        close, the log line, master reports, tuner credit, callbacks."""
         snap = None
         pw = job.get("pw")
         if pw is not None:
@@ -887,8 +910,13 @@ class Trainer:
             # PerfSnapshot, update the baseline, run the regression
             # sentinel, and ship it on the buffered latest-SENT-wins verb
             snap = self._perf.close(pw)
-        tps = job["steps"] * job["tokens_per_step"] / \
-            max(job["dt_s"], 1e-9)
+        # the readback returns when the device has finished `step`: the
+        # rate between two of them is the device's, where the loop's own
+        # clock only times dispatches that run ahead of it
+        prev_step, prev_t = self._readback_mark
+        self._readback_mark = (step, t_read)
+        tps = (step - prev_step) * job["tokens_per_step"] / \
+            max(t_read - prev_t, 1e-9)
         logger.info("step %d loss=%.4f tokens/s=%.0f", step, loss, tps)
         self.ctx.report_step(step)
         self.ctx.report_loss(step, loss)
@@ -910,7 +938,6 @@ class Trainer:
                 float(snap.get("step_time_s") or 0.0), loss=loss)
         for cb in self.callbacks:
             cb(step, {"loss": loss, "tokens_per_sec": tps})
-        return loss
 
     # ---------------------------------------------------------------- train
 
@@ -921,6 +948,7 @@ class Trainer:
 
         from ..auto.tuner import env_signature
         from ..telemetry.ledger import get_ledger
+        from ..telemetry.perf import keep_step_executable
         from ..telemetry.recorder import get_recorder
 
         a = self.args
@@ -965,8 +993,6 @@ class Trainer:
 
         last_loss = float("nan")
         metrics = None
-        t_log = time.monotonic()
-        steps_since_log = 0
         self._preempted = False
         prev_sigterm = None
         if a.graceful_preemption:
@@ -979,6 +1005,9 @@ class Trainer:
         stager = None
         step_time_s = 0.0
         step = start_step
+        # (step, instant) of the last loss readback: tokens/s is device
+        # progress from one readback to the next, taken on the pump
+        self._readback_mark = (start_step, time.monotonic())
         # goodput ledger: the trainer owns productive / dispatch_overhead /
         # data_stall / compile / rework; the checkpoint engine credits
         # ckpt_stage/persist + restore tiers; master_client credits
@@ -994,179 +1023,197 @@ class Trainer:
             self, enabled=a.async_metrics and not self.callbacks)
         try:
             while step < a.max_steps and not self._preempted:
-                t_iter0 = time.monotonic()
-                if fused_k is None and step - start_step >= 2:
-                    # two unfused steps measured (the first compiles):
-                    # decide K, then fuse the rest of the run
-                    fused_k = self._autotune_fused_k(step_time_s)
-                if self._policy_pending_k is not None and \
-                        fused_k is not None:
-                    # fusion-boundary K cutover: only once the warm pool
-                    # holds a ready entry at the new K (never a cold
-                    # compile mid-run); the stager rebuilds below at the
-                    # new width, K=1 falls back to the unfused path
-                    if self._policy_pending_k == fused_k:
-                        self._policy_pending_k = None
-                    elif self._prewarm_fused_k(self._policy_pending_k):
-                        logger.info("policy: fused_steps %d -> %d at "
-                                    "boundary %d", fused_k,
-                                    self._policy_pending_k, step)
-                        fused_k = self._policy_pending_k
-                        self._policy_pending_k = None
-                        stager = None
-                if self._tuner is not None and fused_k is not None:
-                    # variant cutover at the boundary, warm-pool gated —
-                    # only after the K auto-tune settles (the unfused
-                    # measurement steps must not race an env flip)
-                    self._maybe_apply_variant(fused_k)
-                self._fused_k_active = fused_k or 0
-                if fused_k is not None and fused_k > 1 and stager is None:
-                    from ..data.elastic_dataset import FusedBatchStager
+                with tspans.hot_span("trainer:iteration"):
+                    t_iter0 = time.monotonic()
+                    if fused_k is None and step - start_step >= 2:
+                        # two unfused steps measured (the first compiles):
+                        # decide K, then fuse the rest of the run
+                        fused_k = self._autotune_fused_k(step_time_s)
+                    if self._policy_pending_k is not None and \
+                            fused_k is not None:
+                        # fusion-boundary K cutover: only once the warm pool
+                        # holds a ready entry at the new K (never a cold
+                        # compile mid-run); the stager rebuilds below at the
+                        # new width, K=1 falls back to the unfused path
+                        if self._policy_pending_k == fused_k:
+                            self._policy_pending_k = None
+                        elif self._prewarm_fused_k(self._policy_pending_k):
+                            logger.info("policy: fused_steps %d -> %d at "
+                                        "boundary %d", fused_k,
+                                        self._policy_pending_k, step)
+                            fused_k = self._policy_pending_k
+                            self._policy_pending_k = None
+                            stager = None
+                    if self._tuner is not None and fused_k is not None:
+                        # variant cutover at the boundary, warm-pool gated —
+                        # only after the K auto-tune settles (the unfused
+                        # measurement steps must not race an env flip)
+                        self._maybe_apply_variant(fused_k)
+                    self._fused_k_active = fused_k or 0
+                    if fused_k is not None and fused_k > 1 and stager is None:
+                        from ..data.elastic_dataset import FusedBatchStager
 
-                    stager = iter(FusedBatchStager(
-                        lambda s: dict(self._batch_at(self.train_data, s)),
-                        self.res.place_fused_batch, fused_k,
-                        step, a.max_steps,
-                        place_single=self.res.place_batch))
-                with led.window("data_stall"):
-                    if stager is not None:
-                        s0, k_eff, batch = next(stager)
-                    else:
-                        s0, k_eff = step, 1
-                        batch = self.res.place_batch(
-                            dict(self._batch_at(self.train_data, step)))
-                data_s = time.monotonic() - t_iter0
-                if self._tune_listener is not None and \
-                        s0 % a.tune_config_steps == 0:
-                    tuned = self._tune_listener.poll()
-                    if tuned:
-                        self._apply_tuned_config(tuned)
-                if a.policy_steps and self.ctx.mc is not None and \
-                        s0 % a.policy_steps == 0:
-                    self._poll_policy()
-                    self._poll_mesh_transition()
-                pw = None
-                env_mode = (k_eff, env_signature())
-                if self._perf is not None and a.logging_steps and \
-                        (s0 + k_eff) % a.logging_steps == 0 and \
-                        env_mode in self._compiled_modes and \
-                        self._pump.windows_inflight() == 0 and \
-                        (self._tuner is None or
-                         self._tuner.current().name ==
-                         self._variant_active) and \
-                        not self._user_trace_active(s0, k_eff):
-                    # perf window: only on a boundary that already carries
-                    # the logging readback (that sync flushes the fused
-                    # block's device work into the trace — zero NEW
-                    # readbacks), never on the compile dispatch (compile
-                    # wall is not a step-time baseline), never while the
-                    # opt-in trace window is live or a pump-held window is
-                    # still closing (jax traces can't nest), and — when
-                    # tuning — only while execution matches the tuner's
-                    # current candidate, so a deferred cutover never
-                    # credits the old variant's windows to the new one.
-                    # maybe_open applies the every-Nth cadence and the
-                    # <1%-overhead self-limit.
-                    self._perf.key = self._perf_key(k_eff)
-                    pw = self._perf.maybe_open(s0, k_eff)
-                prof_before = self.profiler.last_profile
-                t_blk0 = time.monotonic()
-                with self.profiler.step(s0):
-                    if k_eff > 1:
-                        self.state, metrics = self.res.fused_train_step(
-                            k_eff)(self.state, batch)
-                    else:
-                        t0 = time.perf_counter()
-                        # width-1 through the variant-aware fused cache:
-                        # identical to train_step until a DWT_FA_* cutover
-                        # changes the env signature, which must retrace
-                        # instead of reusing the old trace
-                        self.state, metrics = self.res.fused_train_step(1)(
-                            self.state, batch)
-                        if fused_k is None:
-                            # auto-tune measurement: sync so the timing is
-                            # the real step, not the async dispatch
-                            float(metrics["loss"])
-                            step_time_s = time.perf_counter() - t0
-                    if self.profiler.closes_at(s0):
-                        # the opt-in trace window ends with this block:
-                        # dispatch is async, so wait for the device or the
-                        # trace holds only the block's first milliseconds
-                        jax.block_until_ready(metrics)
-                blk_s = time.monotonic() - t_blk0
-                if env_mode not in self._compiled_modes:
-                    # first dispatch at this (fusion width, variant env)
-                    # traces+compiles
-                    self._compiled_modes.add(env_mode)
-                    led.account("compile", blk_s)
-                    credited_blk = blk_s
-                else:
-                    credited_blk = min(blk_s, self._dispatch_overhead_s())
-                    led.account("dispatch_overhead", credited_blk)
-                if self.profiler.last_profile is not prof_before:
-                    # a trace window just closed: surface slow collectives
-                    self.ctx.report_op_profile(
-                        self.profiler.last_profile.collective_evidence())
-                step = s0 + k_eff
-                steps_since_log += k_eff
-                hooks_excl_s = 0.0  # save/eval time: credited elsewhere
-                # (engine ledger states) or left to the other_s residual
-                # ---- boundary hooks: K divides every active cadence, so
-                # these fire exactly as in the unfused loop ----
-                if a.logging_steps and step % a.logging_steps == 0:
-                    # the boundary's host work — the ONE readback per
-                    # fusion, the perf-window close, the master reports
-                    # and the callbacks — goes to the metrics pump so the
-                    # next fused dispatch overlaps it instead of
-                    # serializing behind the sync.  Ledger CREDITS stayed
-                    # above on this thread; the pump only ships the
-                    # snapshot dict taken here at the boundary.
-                    dt = time.monotonic() - t_log
-                    t_log = time.monotonic()
-                    # re-read the live batch size: the master may retune it
-                    tokens_per_step = a.seq_len * getattr(
-                        self.train_data, "batch_size", a.global_batch_size)
-                    self._pump.submit({
-                        "step": step, "metrics": metrics, "pw": pw,
-                        "dt_s": dt, "steps": steps_since_log,
-                        "tokens_per_step": tokens_per_step,
-                        "ledger": led.snapshot(),
-                        "tune_variant": self._variant_active,
-                    })
+                        stager = iter(FusedBatchStager(
+                            lambda s: dict(self._batch_at(self.train_data, s)),
+                            self.res.place_fused_batch, fused_k,
+                            step, a.max_steps,
+                            place_single=self.res.place_batch))
+                    with tspans.hot_span("trainer:data"), \
+                            led.window("data_stall"):
+                        if stager is not None:
+                            s0, k_eff, batch = next(stager)
+                        else:
+                            s0, k_eff = step, 1
+                            batch = self.res.place_batch(
+                                dict(self._batch_at(self.train_data, step)))
+                    data_s = time.monotonic() - t_iter0
+                    if self._tune_listener is not None and \
+                            s0 % a.tune_config_steps == 0:
+                        tuned = self._tune_listener.poll()
+                        if tuned:
+                            self._apply_tuned_config(tuned)
+                    if a.policy_steps and self.ctx.mc is not None and \
+                            s0 % a.policy_steps == 0:
+                        with tspans.hot_span("trainer:policy_poll"):
+                            self._poll_policy()
+                            self._poll_mesh_transition()
                     pw = None
-                    steps_since_log = 0
-                saved = False
-                if a.save_steps and step % a.save_steps == 0:
-                    t_h = time.monotonic()
-                    self._save(step)
-                    hooks_excl_s += time.monotonic() - t_h
-                    saved = True
-                if a.flash_stage_steps and not saved and \
-                        step % a.flash_stage_steps == 0:
-                    # shm staging (save_to_memory): the agent's
-                    # save-on-failure persists this boundary if the next
-                    # fusion never completes
-                    from ..checkpoint.checkpointer import StorageType
+                    env_mode = (k_eff, env_signature())
+                    if self._perf is not None and a.logging_steps and \
+                            (s0 + k_eff) % a.logging_steps == 0 and \
+                            env_mode in self._compiled_modes and \
+                            self._pump.windows_inflight() == 0 and \
+                            (self._tuner is None or
+                             self._tuner.current().name ==
+                             self._variant_active) and \
+                            not self._user_trace_active(s0, k_eff):
+                        # perf window: only on a boundary that already carries
+                        # the logging readback (that sync flushes the fused
+                        # block's device work into the trace — zero NEW
+                        # readbacks), never on the compile dispatch (compile
+                        # wall is not a step-time baseline), never while the
+                        # opt-in trace window is live or a pump-held window is
+                        # still closing (jax traces can't nest), and — when
+                        # tuning — only while execution matches the tuner's
+                        # current candidate, so a deferred cutover never
+                        # credits the old variant's windows to the new one.
+                        # maybe_open applies the every-Nth cadence and the
+                        # <1%-overhead self-limit.
+                        self._perf.key = self._perf_key(k_eff)
+                        pw = self._perf.maybe_open(s0, k_eff)
+                    prof_before = self.profiler.last_profile
+                    t_blk0 = time.monotonic()
+                    # the span times the dispatch CALL, which returns
+                    # before the device has run the step; the step
+                    # annotation groups this dispatch's device work in a
+                    # profiler trace
+                    with tspans.hot_span("trainer:dispatch"), \
+                            jax.profiler.StepTraceAnnotation(
+                                "train", step_num=s0), \
+                            self.profiler.step(s0):
+                        if k_eff > 1:
+                            self.state, metrics = self.res.fused_train_step(
+                                k_eff)(self.state, batch)
+                        else:
+                            t0 = time.perf_counter()
+                            # width-1 through the variant-aware fused cache:
+                            # identical to train_step until a DWT_FA_* cutover
+                            # changes the env signature, which must retrace
+                            # instead of reusing the old trace
+                            self.state, metrics = self.res.fused_train_step(1)(
+                                self.state, batch)
+                            if fused_k is None:
+                                # auto-tune measurement: sync so the timing is
+                                # the real step, not the async dispatch
+                                float(metrics["loss"])
+                                step_time_s = time.perf_counter() - t0
+                        if self.profiler.closes_at(s0):
+                            # the opt-in trace window ends with this block:
+                            # dispatch is async, so wait for the device or the
+                            # trace holds only the block's first milliseconds
+                            jax.block_until_ready(metrics)
+                    blk_s = time.monotonic() - t_blk0
+                    if env_mode not in self._compiled_modes:
+                        # first dispatch at this (fusion width, variant env)
+                        # traces+compiles
+                        self._compiled_modes.add(env_mode)
+                        led.account("compile", blk_s)
+                        credited_blk = blk_s
+                        # for a reader of the step's text, afterwards
+                        # (telemetry/perf.py): shapes and shardings only,
+                        # no array is kept; ~2 ms at 1,740 leaves, once
+                        keep_step_executable(
+                            env_mode, self.res.fused_train_step(k_eff),
+                            self.state, batch)
+                    else:
+                        credited_blk = min(blk_s, self._dispatch_overhead_s())
+                        led.account("dispatch_overhead", credited_blk)
+                    if self.profiler.last_profile is not prof_before:
+                        # a trace window just closed: surface slow collectives
+                        self.ctx.report_op_profile(
+                            self.profiler.last_profile.collective_evidence())
+                    step = s0 + k_eff
+                    hooks_excl_s = 0.0  # save/eval time: credited elsewhere
+                    # (engine ledger states) or left to the other_s residual
+                    # ---- boundary hooks: K divides every active cadence, so
+                    # these fire exactly as in the unfused loop ----
+                    if a.logging_steps and step % a.logging_steps == 0:
+                        # the boundary's host work — the ONE readback per
+                        # fusion, the perf-window close, the master reports
+                        # and the callbacks — goes to the metrics pump so the
+                        # next fused dispatch overlaps it instead of
+                        # serializing behind the sync.  Ledger CREDITS stayed
+                        # above on this thread; the pump only ships the
+                        # snapshot dict taken here at the boundary.
+                        # re-read the live batch size: the master may retune it
+                        tokens_per_step = a.seq_len * getattr(
+                            self.train_data, "batch_size", a.global_batch_size)
+                        with tspans.hot_span("trainer:log_submit"):
+                            self._pump.submit({
+                                "step": step, "metrics": metrics, "pw": pw,
+                                "tokens_per_step": tokens_per_step,
+                                "ledger": led.snapshot(),
+                                "tune_variant": self._variant_active,
+                                # the pump's spans hang under this one
+                                "trace": tspans.current_trace(),
+                            })
+                        pw = None
+                    saved = False
+                    if a.save_steps and step % a.save_steps == 0:
+                        t_h = time.monotonic()
+                        with tspans.hot_span("trainer:save"):
+                            self._save(step)
+                        hooks_excl_s += time.monotonic() - t_h
+                        saved = True
+                    if a.flash_stage_steps and not saved and \
+                            step % a.flash_stage_steps == 0:
+                        # shm staging (save_to_memory): the agent's
+                        # save-on-failure persists this boundary if the next
+                        # fusion never completes
+                        from ..checkpoint.checkpointer import StorageType
 
-                    t_h = time.monotonic()
-                    self.ckpt.save_checkpoint(
-                        step, self.state, storage_type=StorageType.MEMORY)
-                    hooks_excl_s += time.monotonic() - t_h
-                if a.eval_steps and self.eval_data is not None and \
-                        step % a.eval_steps == 0:
-                    t_h = time.monotonic()
-                    eval_loss = self.evaluate()
-                    hooks_excl_s += time.monotonic() - t_h
-                    logger.info("step %d eval_loss=%.4f", step, eval_loss)
-                # remainder of the iteration is the fused window itself:
-                # wall - data stall - credited dispatch/compile - hook time
-                # (saves are credited by the engine as ckpt_stage/persist;
-                # eval falls to the other_s residual by design)
-                window_s = max(0.0, (time.monotonic() - t_iter0) - data_s
-                               - credited_blk - hooks_excl_s)
-                led.account(
-                    "rework" if s0 < self._rework_until else "productive",
-                    window_s)
+                        t_h = time.monotonic()
+                        with tspans.hot_span("trainer:stage"):
+                            self.ckpt.save_checkpoint(
+                                step, self.state,
+                                storage_type=StorageType.MEMORY)
+                        hooks_excl_s += time.monotonic() - t_h
+                    if a.eval_steps and self.eval_data is not None and \
+                            step % a.eval_steps == 0:
+                        t_h = time.monotonic()
+                        with tspans.hot_span("trainer:eval"):
+                            eval_loss = self.evaluate()
+                        hooks_excl_s += time.monotonic() - t_h
+                        logger.info("step %d eval_loss=%.4f", step, eval_loss)
+                    # remainder of the iteration is the fused window itself:
+                    # wall - data stall - credited dispatch/compile - hook time
+                    # (saves are credited by the engine as ckpt_stage/persist;
+                    # eval falls to the other_s residual by design)
+                    window_s = max(0.0, (time.monotonic() - t_iter0) - data_s
+                                   - credited_blk - hooks_excl_s)
+                    led.account(
+                        "rework" if s0 < self._rework_until else "productive",
+                        window_s)
             if self._preempted and step < a.max_steps:
                 logger.info("preempted at fusion boundary %d — saving and "
                             "exiting", step)

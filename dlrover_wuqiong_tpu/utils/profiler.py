@@ -65,7 +65,9 @@ class StepProfiler:
             dt = time.perf_counter() - t0
             self._reg.observe("dwt_train_step_seconds", dt,
                               {"job": self._job},
-                              help="host-observed train step wall time")
+                              help="wall time of the train step's dispatch "
+                                   "call (asynchronous: not the device's "
+                                   "step time)")
             self._reg.gauge("dwt_train_last_step", step, {"job": self._job})
             self._maybe_stop_trace(step)
 

@@ -546,6 +546,29 @@ class TestTrustBoundary:
         assert not os.path.exists(marker)
         ck.close()
 
+    def test_saver_finds_a_recreated_segment_by_name(self, tmp_path):
+        """Between two worker generations the staging segment can be
+        unlinked and made anew under the same name (a stale-segment sweep
+        reaps a dead creator's; a writer that needs more room recreates
+        it).  The agent's saver still maps the old one: it must look the
+        name up again before it calls the new step missing."""
+        ckpt_dir = str(tmp_path / "remap")
+        ck = FlashCheckpointer(ckpt_dir, job_name="t-tb-remap",
+                               standalone=True)
+        self._commit(ck, 1, 1.0)  # the saver has mapped the segment
+        saver = AsyncCheckpointSaver.get_ckpt_saver()
+        assert saver._shm_handlers[0].load_header()["step"] == 1
+        ck.engine._shm_handler.unlink()  # reaped ...
+        writer = SharedMemoryHandler(0, "t-tb-remap")  # ... next generation
+        try:
+            writer.save_state_dict({"w": np.full((8, 8), 2.0, np.float32),
+                                    "step": np.int64(2)}, step=2)
+            saver.save_step_checkpoint(2, ckpt_dir, commit_timeout=3)
+            assert read_last_step(ckpt_dir) == 2
+        finally:
+            writer.unlink()
+            ck.close()
+
     def test_replica_blob_verification(self):
         from dlrover_wuqiong_tpu.checkpoint.shm_handler import (
             verify_segment_blob,

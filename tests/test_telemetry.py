@@ -224,6 +224,114 @@ class TestSpans:
             assert key in evt
 
 
+class TestHotSpans:
+    """The per-step ring (PR 24): light records on the monotonic clock
+    that never reach the flight recorder and evict no full span."""
+
+    def test_every_span_carries_a_monotonic_start(self):
+        t0 = time.monotonic()
+        with tspans.span("full") as rec:
+            with tspans.hot_span("light"):
+                pass
+        t1 = time.monotonic()
+        assert t0 <= rec["t_mono"] <= t1 and rec["t_wall"] > 0
+        (hot,) = tspans.hot_spans_snapshot()
+        assert set(hot) == {"name", "t_mono", "dur_s", "span_id",
+                            "parent_span", "trace_id", "tid"}
+        assert rec["t_mono"] <= hot["t_mono"] <= t1
+        assert hot["t_mono"] + hot["dur_s"] <= \
+            rec["t_mono"] + rec["dur_s"]
+
+    def test_annotation_is_harmless_with_no_profiler_running(self):
+        import jax  # noqa: F401 — imported: the annotation path is live
+
+        with tspans.hot_span("outer"):
+            with tspans.span("inner"):
+                tspans.span_event("mark")
+        with pytest.raises(KeyError):
+            with tspans.hot_span("raises"):
+                raise KeyError("x")
+        # the stack unwound: a later span starts a fresh trace
+        assert tspans.current_trace() is None
+        assert [s["name"] for s in tspans.hot_spans_snapshot()] == \
+            ["outer", "raises"]
+
+    def test_no_jax_is_imported_for_an_annotation(self):
+        """The agent and the master record spans and never load JAX."""
+        code = ("import sys\n"
+                "from dlrover_wuqiong_tpu.telemetry import spans\n"
+                "with spans.span('a'):\n"
+                "    with spans.hot_span('b'):\n"
+                "        pass\n"
+                "assert 'jax' not in sys.modules, 'jax was imported'\n"
+                "print(len(spans.spans_snapshot()),"
+                " len(spans.hot_spans_snapshot()))\n")
+        out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.split() == ["1", "1"]
+
+    def test_ten_thousand_iterations_evict_nothing_else(self):
+        with tspans.span("trainer:build"):
+            tspans.span_event("accelerate:init_state")
+        with tspans.span("rpc:report_heartbeat"):
+            pass
+        full_before = [s["span_id"] for s in tspans.spans_snapshot()]
+        ring_before = len(get_recorder())
+        for _ in range(10_000):
+            with tspans.hot_span("trainer:iteration"):
+                with tspans.hot_span("trainer:data"):
+                    pass
+                with tspans.hot_span("trainer:dispatch"):
+                    pass
+        assert [s["span_id"] for s in tspans.spans_snapshot()] == \
+            full_before
+        # not one of them went through the flight recorder's ring
+        assert len(get_recorder()) == ring_before
+        assert not any(e["name"].startswith("trainer:iter")
+                       for e in get_recorder().snapshot())
+        hot = tspans.hot_spans_snapshot()
+        assert 0 < len(hot) <= tspans._MAX_HOT_SPANS  # bounded
+        assert hot[-1]["name"] == "trainer:iteration"
+
+    def test_parent_ids_cross_the_pump_thread(self):
+        """What the Trainer does at a logging boundary: the job carries
+        the submitting span's context, the pump adopts it."""
+        seen = {}
+
+        def pump(trace):
+            with tspans.extract(trace):
+                with tspans.hot_span("pump:readback"):
+                    with tspans.span("perf:window") as rec:
+                        seen.update(rec)
+
+        with tspans.hot_span("trainer:iteration"):
+            with tspans.hot_span("trainer:log_submit"):
+                t = threading.Thread(target=pump,
+                                     args=(tspans.current_trace(),))
+                t.start()
+                t.join()
+        by_name = {s["name"]: s for s in tspans.hot_spans_snapshot()}
+        it, sub, rb = (by_name[n] for n in (
+            "trainer:iteration", "trainer:log_submit", "pump:readback"))
+        assert sub["parent_span"] == it["span_id"]
+        assert rb["parent_span"] == sub["span_id"]
+        assert rb["tid"] != it["tid"]
+        assert it["trace_id"] == sub["trace_id"] == rb["trace_id"] == \
+            seen["trace_id"]
+        assert seen["parent_span"] == rb["span_id"]  # full under hot
+
+    def test_chrome_dump_carries_the_per_step_ring(self, tmp_path):
+        with tspans.hot_span("trainer:iteration"):
+            pass
+        path = str(tmp_path / "trace.json")
+        assert tspans.dump_chrome_trace(path) == 1
+        (evt,) = json.loads(open(path).read())["traceEvents"]
+        assert evt["name"] == "trainer:iteration"
+        assert evt["ts"] / 1e6 == pytest.approx(time.time(), abs=60)  # wall
+        assert tspans.dump_chrome_trace(path, include_buffer=False) == 0
+
+
 # ---------------------------------------------------------------- recorder
 
 

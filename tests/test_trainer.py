@@ -5,6 +5,7 @@ flash ckpt save/resume → eval → callbacks.
 """
 
 import dataclasses
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -276,3 +277,242 @@ class TestWireDtypeTrainer:
         assert out["final_step"] == 6
         assert int(np.asarray(jax.tree.leaves(tr2.state.step)[0])) == 6
         tr2.ckpt.close()
+
+
+def _compiles():
+    """How often this process has lowered or compiled, and how often
+    it has asked the persistent cache."""
+    from dlrover_wuqiong_tpu.auto import compile_cache
+
+    return (sum(d["name"] in ("jax:backend_compile", "jax:lower")
+                for d in compile_cache.durations),
+            compile_cache.counters.hits + compile_cache.counters.misses)
+
+
+class TestStepTracing:
+    """PR 24: a short `Trainer.train()` leaves the set-up spans, one
+    `trainer:iteration` per step with its children, the `jax:*` duration
+    records of the step's own function, and the step's `Compiled` —
+    kept without compiling anything."""
+
+    STEPS = 12
+
+    @pytest.fixture
+    def trained(self, tmp_path):
+        from dlrover_wuqiong_tpu.auto import compile_cache
+        from dlrover_wuqiong_tpu.telemetry import perf
+        from dlrover_wuqiong_tpu.telemetry import spans as tspans
+
+        tspans.clear_spans()
+        compile_cache.durations.clear()
+        perf._step_executables.clear()
+        logged = []
+
+        class _Tap(logging.Handler):
+            def emit(self, record):
+                if isinstance(record.msg, str) and \
+                        record.msg.startswith("step %d loss="):
+                    logged.append(record.args)
+
+        tap = _Tap()
+        logging.getLogger("dwt.trainer").addHandler(tap)
+        args = TrainingArgs(
+            output_dir=str(tmp_path), max_steps=self.STEPS,
+            global_batch_size=8, seq_len=32, warmup_steps=1,
+            logging_steps=4, save_steps=8, flash_stage_steps=4,
+            fused_steps=1, perf_window_every=0, save_on_exit=False,
+            strategy=[("fsdp", {})])
+        tr = Trainer(_model(), args, _data)
+        try:
+            out = tr.train()
+            tr.ckpt.wait_staging(60)
+        finally:
+            logging.getLogger("dwt.trainer").removeHandler(tap)
+        yield tr, out, logged
+        tr.ckpt.close()
+
+    def test_build_spans_and_one_iteration_per_step(self, trained):
+        from dlrover_wuqiong_tpu.telemetry import spans as tspans
+
+        full = {}
+        for s in tspans.spans_snapshot():
+            full.setdefault(s["name"], []).append(s)
+        (build,) = full["trainer:build"]
+        for child in ("accelerate:plan", "accelerate:init_state",
+                      "ckpt:open"):
+            (rec,) = full[child]
+            assert rec["parent_span"] == build["span_id"], child
+            assert build["t_mono"] <= rec["t_mono"] and \
+                rec["t_mono"] + rec["dur_s"] <= \
+                build["t_mono"] + build["dur_s"]
+        hot = tspans.hot_spans_snapshot()
+        its = [s for s in hot if s["name"] == "trainer:iteration"]
+        assert len(its) == self.STEPS
+        ids = {s["span_id"] for s in its}
+        for name, n in (("trainer:data", self.STEPS),
+                        ("trainer:dispatch", self.STEPS),
+                        ("trainer:log_submit", 3), ("trainer:save", 1),
+                        ("trainer:stage", 2)):
+            hits = [s for s in hot if s["name"] == name]
+            assert len(hits) == n, name
+            assert all(s["parent_span"] in ids for s in hits), name
+        # the pump's spans hang under the boundary that submitted them
+        subs = {s["span_id"] for s in hot
+                if s["name"] == "trainer:log_submit"}
+        for name in ("pump:readback", "pump:report"):
+            hits = [s for s in hot if s["name"] == name]
+            assert len(hits) == 3 and \
+                all(s["parent_span"] in subs for s in hits), name
+        # the engine's spans: snapshot under save under the loop's hook,
+        # the drain (another thread) under the save that started it
+        hooks = {s["span_id"] for s in hot
+                 if s["name"] in ("trainer:save", "trainer:stage")}
+        saves = {s["span_id"] for s in full["ckpt:save"]}
+        assert len(saves) == 3
+        assert all(s["parent_span"] in hooks for s in full["ckpt:save"])
+        assert all(s["parent_span"] in saves
+                   for s in full["ckpt:snapshot"] + full["ckpt:drain"])
+        assert len(full["ckpt:drain"]) == 3
+        assert len(full["ckpt:persist"]) == 1  # the one DISK save
+
+    def test_jax_duration_records_name_the_step(self, trained):
+        from dlrover_wuqiong_tpu.auto import compile_cache
+
+        mine = [d for d in compile_cache.durations
+                if d["fun_name"] in ("train_step", "jit(train_step)")]
+        names = [d["name"] for d in mine]
+        for name in ("jax:trace", "jax:lower", "jax:backend_compile"):
+            assert name in names, names
+        assert all(d["dur_s"] >= 0 and d["t_mono"] > 0 for d in mine)
+        # a retrieval, where the cache served the step, is named after
+        # the compile it served
+        loads = [d for d in compile_cache.durations
+                 if d["name"] == "jax:cache_load"]
+        assert all(d["fun_name"] for d in loads)
+
+    def test_duration_listener_names_loads_under_threads(self):
+        """Every thread compiles (loop, pump, eval, drain), and the
+        listener runs inside JAX's compile path: a retrieval takes the
+        name of its own thread's compile, and nothing raises into it."""
+        import threading
+
+        from jax._src import monitoring
+
+        from dlrover_wuqiong_tpu.auto import compile_cache
+
+        compile_cache._install_listeners()
+        compile_cache.durations.clear()
+        failed = []
+
+        def compiler(tag):
+            try:
+                for _ in range(1500):
+                    monitoring.record_event_duration_secs(
+                        "/jax/compilation_cache/cache_retrieval_time_sec",
+                        1e-3)
+                    monitoring.record_event_duration_secs(
+                        "/jax/core/compile/backend_compile_duration",
+                        2e-3, fun_name=tag)
+            except Exception as e:  # noqa: BLE001
+                failed.append(e)
+
+        threads = [threading.Thread(target=compiler, args=(f"f{i}",))
+                   for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert not failed, failed
+        recs = list(compile_cache.durations)
+        assert len(recs) == 4 * 1500 * 2
+        named = {}
+        for r in recs:
+            named.setdefault((r["name"], r["fun_name"]), 0)
+            named[r["name"], r["fun_name"]] += 1
+        assert named == {(n, f"f{i}"): 1500 for i in range(4)
+                         for n in ("jax:cache_load", "jax:backend_compile")}
+        compile_cache.durations.clear()
+
+    def test_keeping_the_compiled_step_compiles_nothing(self, trained):
+        from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+        from dlrover_wuqiong_tpu.auto import compile_cache
+        from dlrover_wuqiong_tpu.telemetry.perf import step_executables
+
+        from dlrover_wuqiong_tpu.telemetry import perf
+
+        # the loop kept the way back to the executable; the state it was
+        # given has been donated eleven times since
+        (find,) = perf._step_executables.values()
+        assert not hasattr(find, "as_text")
+        before = _compiles()
+        (mode,) = step_executables()
+        compiled = step_executables()[mode]  # found once, then kept
+        assert _compiles() == before
+        assert mode[0] == 1 and compiled is step_executables()[mode]
+        table = scope_table(compiled.as_text())
+        scopes = set(table.values())
+        assert "optimizer" in scopes and "fwd/loss" in scopes
+        assert any(s.startswith("bwd/GPT/head") for s in scopes)
+
+    def test_kept_step_pins_no_array_without_donation(self, tmp_path,
+                                                      monkeypatch):
+        """With donation off (optimizer_offload resolves to that) no
+        later step frees what the first dispatch returned, so the slot
+        must hold shapes and shardings only: the first state's buffers
+        are collectable once the loop has moved on, and the executable
+        is still found without compiling."""
+        import gc
+        import weakref
+
+        import jax
+
+        from dlrover_wuqiong_tpu.telemetry import perf
+
+        perf._step_executables.clear()
+        first = []
+        keep = perf.keep_step_executable
+
+        def tap(mode, jitted, state, batch):
+            first.extend(weakref.ref(x)
+                         for x in jax.tree.leaves((state, batch)))
+            keep(mode, jitted, state, batch)
+
+        monkeypatch.setattr(perf, "keep_step_executable", tap)
+        args = TrainingArgs(
+            output_dir=str(tmp_path), max_steps=3, global_batch_size=8,
+            seq_len=32, warmup_steps=1, logging_steps=0, save_steps=0,
+            fused_steps=1, perf_window_every=0, save_on_exit=False,
+            strategy=[("fsdp", {}), ("optimizer_offload", {})])
+        tr = Trainer(_model(), args, _data)
+        try:
+            tr.train()
+            gc.collect()
+            assert first and not [r for r in first if r() is not None]
+            before = _compiles()
+            (compiled,) = perf.step_executables().values()
+            assert _compiles() == before
+            assert "optimizer" in compiled.as_text()
+        finally:
+            tr.ckpt.close()
+
+    def test_logged_tokens_per_s_is_readback_to_readback(self, trained):
+        _, out, logged = trained
+        assert [a[0] for a in logged] == [4, 8, 12]
+        assert out["stopped_at"] == self.STEPS
+        # 8 x 32 tokens a step on a CPU: thousands a second, not the
+        # millions the dispatch clock used to print
+        for step, loss, tps in logged:
+            assert np.isfinite(loss) and 0 < tps < 1e6, (step, tps)
+
+    def test_tokens_per_s_counts_device_progress(self, trained):
+        """10 steps of 256 tokens between two readbacks 2 s apart are
+        1280 tokens/s, however fast the loop dispatched them; the same
+        figure reaches the callbacks."""
+        tr, _, logged = trained
+        got = []
+        tr.callbacks = [lambda step, m: got.append((step, m))]
+        tr._readback_mark = (100, 10.0)
+        job = {"tokens_per_step": 256, "ledger": {}, "pw": None}
+        tr._report_boundary(job, 110, 1.5, 12.0)
+        assert tr._readback_mark == (110, 12.0)
+        assert got == [(110, {"loss": 1.5, "tokens_per_sec": 1280.0})]
