@@ -24,18 +24,23 @@ may shift with XLA point releases); counts are pinned exactly.
 Budget provenance (GPTConfig vocab=256, n_layer=2, n_head=4, n_embd=64,
 block=32, 118,528 params, f32, 8 virtual CPU devices):
 
-- ``fsdp`` (mesh fsdp8): every param (13 leaves) is gathered for fwd and
-  for bwd and every grad reduce-scattered, each lowered to all-reduce on
-  CPU, plus the loss/grad-norm scalar reductions — 65 all-reduce,
-  ~2.78 MB/step measured.
-- ``dp-tp`` (mesh dp4xtp2): grads all-reduce over dp (13 leaves) + tp
-  activation reductions + scalar reductions = 28 all-reduce; the tp=2
-  attention/mlp boundary contributes 12 collective-permutes (CPU's
-  expansion of the tp all-gathers), ~1.4 MB/step total measured.
+- ``fsdp`` (mesh fsdp8): the model pins its residual stream to the batch
+  layout (parallel/sharding.pin_activation), so the partitioner gathers
+  the kernels (19 all-gather, 0.86 MB) and sums weight gradients and the
+  loss/grad-norm scalars (3 all-reduce, 0.47 MB); the `wte` lookup and
+  its scatter-add are the 2 all-to-all — ~1.35 MB/step measured.  An
+  all-to-all count that grows with depth, or an all-reduce of
+  [batch, seq, *], is the residual stream leaving that layout.
+- ``dp-tp`` (mesh dp4xtp2): grads all-reduce over dp + tp activation
+  reductions + scalar reductions = 15 all-reduce (0.47 MB); the tp=2
+  attention/mlp boundary contributes 8 collective-permutes and 8
+  all-to-alls (CPU's expansion of the tp re-layouts), ~0.70 MB/step
+  total measured.
 """
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -47,13 +52,29 @@ COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter",
                   "collective-permute", "all-to-all")
 
 _OP_RE = re.compile(
-    r"=\s*((?:\([^)]*\)|[a-z0-9]+\[[^\]]*\](?:\{[^}]*\})?))\s+"
-    r"(all-gather|all-reduce|reduce-scatter|collective-permute|"
+    r"=\s*(.*?)\s(all-gather|all-reduce|reduce-scatter|collective-permute|"
     r"all-to-all)(?:-start)?\(")
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1,
                 "f8e5m2": 1, "s64": 8, "u64": 8, "s32": 4, "u32": 4,
                 "s16": 2, "u16": 2, "s8": 1, "u8": 1, "pred": 1}
+
+
+def iter_collectives(hlo_text: str):
+    """(op, [(dtype, dims), ...], op_name) of every collective instruction
+    in an optimized-HLO dump, one output shape per tuple element; async
+    `-start` halves count once, `-done` is ignored.  Line by line, so a
+    TPU layout's parentheses (`{0:T(1024)(128)}`) inside a tuple do not
+    end the match."""
+    for line in hlo_text.splitlines():
+        m = _OP_RE.search(line)
+        if m is None:
+            continue
+        shapes = [(dt, tuple(int(d) for d in dims.split(",") if d))
+                  for dt, dims in _SHAPE_RE.findall(m.group(1))]
+        name = _OP_NAME_RE.search(line)
+        yield m.group(2), shapes, name.group(1) if name else ""
 
 
 def count_collectives(hlo_text: str) -> Dict[str, Dict[str, int]]:
@@ -64,18 +85,11 @@ def count_collectives(hlo_text: str) -> Dict[str, Dict[str, int]]:
     bound for gathers.
     """
     out: Dict[str, Dict[str, int]] = {}
-    for m in _OP_RE.finditer(hlo_text):
-        shape_txt, op = m.group(1), m.group(2)
-        nbytes = 0
-        for dt, dims in _SHAPE_RE.findall(shape_txt):
-            n = 1
-            for d in dims.split(","):
-                if d:
-                    n *= int(d)
-            nbytes += n * _DTYPE_BYTES.get(dt, 4)
+    for op, shapes, _ in iter_collectives(hlo_text):
         ent = out.setdefault(op, {"count": 0, "bytes": 0})
         ent["count"] += 1
-        ent["bytes"] += nbytes
+        ent["bytes"] += sum(math.prod(dims) * _DTYPE_BYTES.get(dt, 4)
+                            for dt, dims in shapes)
     return out
 
 
@@ -89,11 +103,14 @@ BUDGETS: Dict[str, Dict] = {
         "strategy": [("fsdp", {})],
         "accum": 1,
         "ops": {
-            "all-reduce": {"max_count": 26, "max_bytes": 2_920_000},
-            # the step returns the state on the shardings it came in
-            # with (trainer/train_step.py): the replicated LayerNorm /
-            # bias leaves are gathered back, a few hundred bytes each
-            "all-gather": {"max_count": 42, "max_bytes": 11_300},
+            "all-reduce": {"max_count": 3, "max_bytes": 498_000},
+            # the kernels, gathered where they are used; and the step
+            # returns the state on the shardings it came in with
+            # (trainer/train_step.py)
+            "all-gather": {"max_count": 19, "max_bytes": 905_000},
+            # the wte gather and its scatter-add: the count must not
+            # grow with depth
+            "all-to-all": {"max_count": 2, "max_bytes": 17_300},
         },
     },
     "dp-tp": {
@@ -101,11 +118,10 @@ BUDGETS: Dict[str, Dict] = {
                      ("tensor_parallel", {"size": 2})],
         "accum": 1,
         "ops": {
-            "all-reduce": {"max_count": 13, "max_bytes": 830_000},
-            "collective-permute": {"max_count": 8, "max_bytes": 550_000},
-            # activations re-laid out between the dp and tp shardings —
-            # ROADMAP S5 reads this before the first four-chip number
-            "all-to-all": {"max_count": 8, "max_bytes": 413_000},
+            "all-reduce": {"max_count": 15, "max_bytes": 497_000},
+            "collective-permute": {"max_count": 8, "max_bytes": 138_000},
+            # activations re-laid out between the dp and tp shardings
+            "all-to-all": {"max_count": 8, "max_bytes": 103_500},
         },
     },
 }

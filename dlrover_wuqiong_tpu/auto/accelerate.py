@@ -40,7 +40,7 @@ from .compile_cache import (
 )
 from .tuner import env_signature as _tuner_env_signature
 from ..telemetry import spans as tspans
-from ..parallel.sharding import ShardingPlanner
+from ..parallel.sharding import ShardingPlanner, activation_spec
 from ..trainer.train_step import (
     TrainState,
     make_lm_loss,
@@ -101,6 +101,12 @@ class StrategyContext:
 @register_strategy("zero2")
 @register_strategy("zero3")
 def _s_fsdp(ctx: StrategyContext, cfg: Dict, num_devices: int):
+    """Parameters, gradients and optimizer state sharded over "fsdp"
+    (parallel/sharding._add_fsdp); the batch over ("dp", "fsdp").  The
+    residual stream is guaranteed to stay in that batch layout
+    (parallel/sharding.activation_spec, stated by the model at each block's
+    entry), so a dense layer gathers its kernel and multiplies the chip's own
+    tokens: no activation crosses chips inside a block."""
     ctx.plan.fsdp = cfg.get("size", 0) or 0  # 0 → fill remaining
 
 
@@ -778,10 +784,13 @@ def auto_accelerate(
             fused_steps=k)
 
     cache_key = _key_for(fused_steps)
+    # the activation layout the model states in its trace
+    # (parallel/sharding.activation_spec); None: one device, nothing stated
+    act = str(activation_spec(getattr(cfg_for_key, "mesh", None)))
     cache_warm = note_train_step_served(
         cache_dir, cache_key,
         meta={"mesh": ctx.plan.describe(), "n_devices": len(devices),
-              "fused_steps": fused_steps})
+              "act": act, "fused_steps": fused_steps})
     strategy_spec = _jsonable_strategy(strategy, ctx)
     if sample_batch is not None and strategy_spec is not None and \
             cache_dir is not None:
@@ -790,8 +799,8 @@ def auto_accelerate(
         # without a sample_batch: ElasticContext.enable_warm_restarts)
         _publish_warm_spec(cache_dir, model, strategy_spec, devices,
                            sample_batch, ctx.accum_steps, fused_steps)
-    logger.info("auto_accelerate: mesh=%s params=%s accum=%d "
-                "cache_key=%s%s", ctx.plan.describe(),
+    logger.info("auto_accelerate: mesh=%s act=%s params=%s accum=%d "
+                "cache_key=%s%s", ctx.plan.describe(), act,
                 f"{num_params:,}" if num_params else "?", ctx.accum_steps,
                 cache_key, " (warm)" if cache_warm else "")
     return AccelerateResult(

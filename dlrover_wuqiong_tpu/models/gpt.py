@@ -18,6 +18,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..parallel.sharding import pin_activation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -139,6 +140,12 @@ class Block(nn.Module):
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
+        # the residual stream lives in the batch layout: stated once a
+        # block, INSIDE it, so that remat's recomputed forward and the
+        # backward (the cotangent gets the same constraint) carry it too,
+        # and a sharded plan gathers kernels, not activations; no mesh
+        # (one device): x itself
+        x = pin_activation(x, cfg.mesh)
         # checkpoint_name marks the save/offload anchors for the
         # "save_names"/"offload_names" remat policies (ops/remat.py);
         # identity under every other policy

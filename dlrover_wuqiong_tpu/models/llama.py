@@ -16,6 +16,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..parallel.sharding import pin_activation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,6 +165,8 @@ class LlamaBlock(nn.Module):
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
+        # the residual stream in the batch layout, as models/gpt.py's Block
+        x = pin_activation(x, cfg.mesh)
         # save/offload anchors for the *_names remat policies (ops/remat.py)
         attn = LlamaAttention(cfg, name="attention")(
             RMSNorm(cfg.rms_eps, cfg.dtype, name="input_norm")(x), cos, sin)

@@ -13,10 +13,26 @@ P("tp", None) (XLA inserts the reduce-scatter/all-reduce the mappings.py
 autograd functions implement by hand).  FSDP (ZeRO-3) adds sharding of every
 param along "fsdp".  This module maps parameter *path patterns* → specs, the
 single source of truth used by trainers and the checkpoint engine.
+
+Activations.  Parameter specs alone leave the partitioner free to choose
+where activations live, and it moves whichever operand is cheaper by its own
+count: under fsdp it kept the kernels sharded on their contracting
+dimension, re-laid the residual stream from batch-sharded to feature-sharded
+and all-reduced full-batch activations in every dense layer (PERF.md,
+PR 25).  So every sharded plan GUARANTEES one layout for the residual
+stream, `activation_spec`: batch over ("dp", "fsdp"), sequence over "sp"
+when sp > 1, features whole (replicated over "tp", as Megatron keeps it).
+The model states it — `pin_activation(x, cfg.mesh)` at the entry of each
+block (models/gpt.py `Block`, models/llama.py `LlamaBlock`), inside the
+block so that remat's recomputed forward and the backward carry it — and a
+dense layer is left one cheap choice: all-gather the kernel, multiply the
+chip's own tokens, sum the weight gradient across chips.  On one device the
+model has no mesh and the helper returns its argument: no op is traced.
 """
 
 from __future__ import annotations
 
+import math
 import re
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -121,8 +137,6 @@ def _add_fsdp(spec: P, shape: Tuple[int, ...], mesh: Mesh,
     if "fsdp" in [a for part in spec if part for a in
                   (part if isinstance(part, tuple) else (part,))]:
         return spec
-    import math
-
     if math.prod(shape) < min_size:
         return spec
     parts = list(spec) + [None] * (len(shape) - len(spec))
@@ -208,3 +222,42 @@ def constrain(x, mesh: Mesh, spec: P):
     import jax
 
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def activation_spec(mesh: Optional[Mesh],
+                    shape: Sequence[int] = (0, 0, 0)) -> Optional[P]:
+    """Where a (batch, sequence, features) activation lives on `mesh`:
+    `ShardingPlanner.batch_spec` — batch over ("dp", "fsdp"), sequence
+    over "sp" when sp > 1, features whole.  A dimension its axes do not
+    divide (a batch-of-one init) is left to the partitioner.  So is the
+    batch inside an enclosing shard_map: a pipeline stage's microbatch
+    or a DiLoCo group's share is that map's to lay out.  Without a
+    `shape` every dimension counts as divisible: the layout by itself.
+    None when there is nothing to state: no mesh, or a single device.
+    """
+    if mesh is None or mesh.size == 1:
+        return None
+    from jax.sharding import get_abstract_mesh
+
+    parts = list(ShardingPlanner(mesh).batch_spec(len(shape), seq_axis=1))
+    for i, (axes, dim) in enumerate(zip(parts, shape)):
+        axes = axes if isinstance(axes, tuple) else (axes,)
+        if dim % math.prod(mesh.shape[a] for a in axes if a):
+            parts[i] = P.UNCONSTRAINED
+    if get_abstract_mesh().manual_axes:
+        parts[0] = P.UNCONSTRAINED
+    return P(*parts)
+
+
+def pin_activation(x, mesh: Optional[Mesh]):
+    """State the layout of a residual-stream activation (`activation_spec`)
+    so that a dense layer leaves the partitioner one cheap choice: gather
+    the kernel, multiply the chip's own tokens, sum the weight gradient.
+    Without a mesh of several devices this returns `x` itself — no op
+    enters the jaxpr."""
+    spec = activation_spec(mesh, x.shape)
+    if spec is None:
+        return x
+    from .mesh import context_mesh
+
+    return constrain(x, context_mesh(mesh), spec)

@@ -10,7 +10,10 @@ be read back without a chip (on-chip-measurement guide §2).  Nothing
 runs, so nothing here is a statement about results or speed.
 """
 
+import contextlib
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -19,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from dlrover_wuqiong_tpu.analysis.hlo_budget import iter_collectives
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
 from dlrover_wuqiong_tpu.ops import quantization as qz
 
@@ -34,16 +38,24 @@ def topo():
         pytest.skip(f"cannot describe a v5e topology here: {e!r}")
 
 
-@pytest.fixture(autouse=True)
-def _no_persistent_cache():
+@contextlib.contextmanager
+def _cache_off():
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    with _cache_off():
+        yield
 
 
 def _compile(fn, *args):
@@ -135,3 +147,77 @@ def test_attention_on_a_four_chip_mesh_is_shard_mapped(topo, monkeypatch):
 
     with pytest.raises(NotImplementedError, match="shard_map"):
         _compile(lambda q, k, v: fa.mha(q, k, v), x, x, x)
+
+
+# ------------------------------------------------- the sharded step's layout
+
+XL_BATCH, XL_SEQ, XL_WIDTH = 16, 1024, 1600
+
+
+@pytest.fixture(scope="module")
+def xl_fsdp4_steps(topo):
+    """{depth: Compiled} of `gpt2_xl.fsdp4_steady`'s step at 2 and 3
+    layers: XL widths, batch 16 x 1024, full remat, Trainer's optimizer,
+    fsdp over the described 2x2 (about 25 s a compile)."""
+    import optax
+
+    from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
+    from dlrover_wuqiong_tpu.models.gpt import GPT, GPTConfig
+
+    steps = {}
+    with pytest.MonkeyPatch.context() as mp, _cache_off():
+        mp.setenv("DWT_COMPILE_CACHE", "0")
+        mp.setattr(fa, "_on_tpu", lambda: True)
+        mp.setattr("dlrover_wuqiong_tpu.models.attention._on_tpu",
+                   lambda: True)
+        for depth in (2, 3):
+            cfg = GPTConfig(n_layer=depth, n_head=25, n_embd=XL_WIDTH,
+                            remat=True, remat_policy="full")
+            res = auto_accelerate(
+                GPT(cfg), strategy=[("fsdp", {})], devices=topo.devices,
+                optimizer=optax.chain(optax.clip_by_global_norm(1.0),
+                                      optax.adamw(3e-4, weight_decay=0.1)),
+                materialize=False, seq_len=XL_SEQ)
+            assert res.model.config.mesh is res.mesh
+            ids = jax.ShapeDtypeStruct((XL_BATCH, XL_SEQ), jnp.int32,
+                                       sharding=res.batch_sharding_fn(2))
+            steps[depth] = res.train_step.lower(
+                res.state, {"input_ids": ids, "labels": ids}).compile()
+    return steps
+
+
+def test_fsdp4_all_to_alls_do_not_grow_with_depth(xl_fsdp4_steps):
+    """The `wte` lookup and its scatter-add re-lay the embedding once a
+    step; a block re-lays nothing (a residual stream left to the
+    partitioner costs 8 all-to-alls a layer)."""
+    counts = {depth: sum(op == "all-to-all" for op, _, _ in
+                         iter_collectives(step.as_text()))
+              for depth, step in xl_fsdp4_steps.items()}
+    assert counts == {2: 2, 3: 2}
+
+
+@pytest.mark.parametrize("depth", [2, 3])
+def test_fsdp4_blocks_move_weights_not_activations(xl_fsdp4_steps, depth):
+    found = list(iter_collectives(xl_fsdp4_steps[depth].as_text()))
+    in_blocks = [c for c in found if re.search(r"/h_\d+/", c[2])]
+    assert in_blocks
+    kernel_dims = {XL_WIDTH, 3 * XL_WIDTH, 4 * XL_WIDTH}
+    for op, shapes, name in in_blocks:
+        # weight-shaped only: a kernel gathered where it is used, bias
+        # gradients summed — never [batch, seq, *]
+        assert op in ("all-gather", "all-reduce") and all(
+            len(dims) <= 2 and set(dims) <= kernel_dims
+            for _, dims in shapes), (op, shapes, name)
+    # the compiler's unnamed collectives (gradient sums, padded gathers):
+    # nothing of rank 3 or more beyond a chip's share of one activation
+    share = XL_BATCH // 4 * XL_SEQ * XL_WIDTH
+    for op, shapes, name in found:
+        assert all(len(dims) < 3 or math.prod(dims) <= share
+                   for _, dims in shapes), (op, shapes, name)
+
+
+def test_fsdp4_step_temporaries_fit(xl_fsdp4_steps):
+    """A step whose dense layers all-reduce full-batch activations holds
+    2.92 GiB of temporaries at two layers."""
+    temp = xl_fsdp4_steps[2].memory_analysis().temp_size_in_bytes
+    assert temp < 1.2 * 2 ** 30, temp / 2 ** 30
