@@ -32,6 +32,12 @@ matmul decides what a fusion costs), else the longest common prefix of
 its instructions' scopes, else — where they agree on nothing — its own
 `op_name`, which is its root's: what it produces.  An instruction with
 no `op_name` (parameters, compiler-made copies) maps to "".
+
+One op loses its path on the way: the TPU compiler puts kernels of its
+own in place of a `lax.ragged_dot` and writes ITS name over the traced
+one (`%ragged-dot-none.7`, `%ragged-dot-metadata`, op_name
+`ragged-dot-none`).  Those map to the scope `ragged_dot`: what they are
+is all that is left to say of them.
 """
 
 from __future__ import annotations
@@ -162,5 +168,7 @@ def scope_table(hlo_text: str) -> Dict[str, str]:
                 # constant from another scope is enough): the fusion's
                 # own metadata stands, which is its root's — what it
                 # produces
+            if not scope and ins["name"].startswith("ragged-dot"):
+                scope = "ragged_dot"
             table[ins["name"]] = scope
     return table

@@ -1,5 +1,11 @@
 """Llama-family model (RMSNorm, RoPE, SwiGLU, GQA), TPU-first.
 
+Two fields of `LlamaConfig` reach the Llama-shaped MoE families through
+the same block (OLMoE: both): `moe` puts `models/moe.py`'s expert layer
+in every block's feed-forward slot (expert width `intermediate_size`),
+`qk_norm` an RMSNorm over the whole q and k projections before the split
+into heads and before RoPE.
+
 Parity: the reference's flagship workloads are GLM/Llama-class LMs via atorch
 (`BASELINE.json` configs: Llama-3 8B auto_accelerate, Llama-3 70B Megatron
 flash-ckpt).  Native flax implementation with names matched to
@@ -42,6 +48,12 @@ class LlamaConfig:
     fp8: bool = False
     fp8_filter: tuple = ("q_proj", "k_proj", "v_proj", "o_proj",
                          "gate_proj", "up_proj", "down_proj")
+    # a models/moe.py MoEConfig: every layer's feed-forward is that
+    # expert layer, each expert a SwiGLU of width `intermediate_size`
+    moe: Any = None
+    # RMSNorm (own scale, `rms_eps`) over all of q's and all of k's
+    # features, before the heads are split and before RoPE (OLMoE)
+    qk_norm: bool = False
 
     @classmethod
     def nano(cls):
@@ -65,7 +77,12 @@ class LlamaConfig:
     def num_params(self) -> int:
         h, i = self.hidden_size, self.intermediate_size
         kv = self.num_kv_heads * self.head_dim
-        per_layer = h * h + 2 * h * kv + h * h + 3 * h * i + 2 * h
+        ffn = 3 * h * i
+        if self.moe is not None:  # E experts and the router
+            ffn = self.moe.num_experts * (ffn + h)
+        per_layer = h * h + 2 * h * kv + h * h + ffn + 2 * h
+        if self.qk_norm:
+            per_layer += h + kv
         return (2 * self.vocab_size * h + self.num_layers * per_layer + h)
 
 
@@ -115,9 +132,14 @@ class LlamaAttention(nn.Module):
         B, T, C = x.shape
         hd = cfg.head_dim
         q = dense(cfg, cfg.num_heads * hd, "q_proj", use_bias=False)(
-            x).reshape(B, T, cfg.num_heads, hd)
-        k = dense(cfg, cfg.num_kv_heads * hd, "k_proj", use_bias=False)(
-            x).reshape(B, T, cfg.num_kv_heads, hd)
+            x)
+        k = dense(cfg, cfg.num_kv_heads * hd, "k_proj", use_bias=False)(x)
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
+                k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
+        q = q.reshape(B, T, cfg.num_heads, hd)
+        k = k.reshape(B, T, cfg.num_kv_heads, hd)
         v = dense(cfg, cfg.num_kv_heads * hd, "v_proj", use_bias=False)(
             x).reshape(B, T, cfg.num_kv_heads, hd)
         q = apply_rope(q, cos, sin)
@@ -171,8 +193,20 @@ class LlamaBlock(nn.Module):
         attn = LlamaAttention(cfg, name="attention")(
             RMSNorm(cfg.rms_eps, cfg.dtype, name="input_norm")(x), cos, sin)
         x = x + checkpoint_name(attn, "attn_out")
-        h = LlamaMLP(cfg, name="feed_forward")(
-            RMSNorm(cfg.rms_eps, cfg.dtype, name="post_attn_norm")(x))
+        if cfg.moe is not None:
+            from .moe import MoEMLP
+
+            # the auxiliary terms are the MEAN over the layers (every
+            # layer sows its own and make_lm_loss sums what is sown)
+            moe = dataclasses.replace(
+                cfg.moe,
+                aux_loss_weight=cfg.moe.aux_loss_weight / cfg.num_layers,
+                z_loss_weight=cfg.moe.z_loss_weight / cfg.num_layers)
+            ffn = MoEMLP(cfg.hidden_size, cfg.intermediate_size, moe,
+                         name="feed_forward")
+        else:
+            ffn = LlamaMLP(cfg, name="feed_forward")
+        h = ffn(RMSNorm(cfg.rms_eps, cfg.dtype, name="post_attn_norm")(x))
         return x + checkpoint_name(h, "mlp_out")
 
 

@@ -12,14 +12,27 @@ GSPMD inserts the all-to-alls from the shardings; an explicit shard_map
 dispatch is unnecessary on TPU, which is exactly the "GSPMD over hand-written
 collectives" design stance (SURVEY.md §7).
 
-Load-balancing aux loss follows Switch Transformer (mean fraction * mean
-router prob per expert, scaled by E^2).
+Load-balancing aux loss: Switch Transformer's by default (top-1 fraction *
+mean router prob per expert, scaled by E^2); `aux_loss="topk"` is the
+top-k form OLMoE/Mixtral train with (HF `load_balancing_loss_func`):
+E * sum_i f_i P_i, f_i the share of tokens that have expert i among their
+k (so the f_i sum to k).  `z_loss_weight` adds the router z-loss
+mean(logsumexp(logits)^2) (ST-MoE; OLMoE trains with 0.001).  Both are
+sown as `moe_aux_loss`, which `make_lm_loss` adds to the cross-entropy.
+
+What the layer counts (no token is timed, nothing syncs): each call sows
+`moe_tokens_per_expert` (E,) and `moe_dropped` (assignments that reached
+no expert: 0 by construction in the grouped path).  The step program
+returns them reduced (`collect_moe_stats`) beside `loss`, and the
+Trainer's metrics pump reads them where it already reads the loss and
+passes them on as it does any scalar a step counts (log line, callbacks,
+a `trainer:step_metrics` span event).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -33,10 +46,23 @@ class MoEConfig:
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
     dtype: Any = jnp.bfloat16
-    # "capacity": GShard dense dispatch (einsum, drops overflow tokens)
+    # "capacity": GShard dense dispatch (einsum, drops overflow tokens).
+    #   Its (tokens, E, C) combine tensor and boolean twin grow with
+    #   tokens^2 * top_k: at OLMoE's 64 experts top-8 it is 2.7 GB of
+    #   float32 at 8,192 tokens and 10.7 GB at 16,384 — past a few
+    #   thousand tokens a step only "grouped" exists
     # "grouped": dropless sort + grouped-GEMM via lax.ragged_dot (parity
     #   atorch modules/moe/grouped_gemm_moe.py)
     impl: str = "capacity"
+    # renormalise the k chosen gates to sum to 1 (Switch/GShard, Mixtral);
+    # False keeps the softmax's own values (OLMoE: `norm_topk_prob: false`)
+    # and exists in the grouped path only: MoEMLP refuses it on "capacity"
+    norm_topk_prob: bool = True
+    # "switch": top-1 fraction x mean prob x E^2 | "topk": HF
+    # load_balancing_loss_func, E * sum_i f_i P_i over top-k membership
+    aux_loss: str = "switch"
+    # router z-loss mean(logsumexp(logits)^2); 0 = none
+    z_loss_weight: float = 0.0
 
 
 def top_k_gating(logits: jax.Array, k: int, capacity: int,
@@ -85,55 +111,112 @@ def top_k_gating(logits: jax.Array, k: int, capacity: int,
     return combine, dispatch
 
 
+def route_top_k(probs: jax.Array, top_k: int, norm_topk_prob: bool = True
+                ) -> Tuple[jax.Array, jax.Array]:
+    """(gates (T, k), experts (T, k)) of the k largest router probs."""
+    gates, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, experts
+
+
+def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
+                    w_gate: jax.Array, w_in: jax.Array, w_down: jax.Array
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """The dropless expert pass for a routing already made: returns
+    (out (T, d), group_sizes (E,)).  Scopes: `dispatch` (sort, gather),
+    `experts` (grouped matmuls, gating product), `combine` (weighting,
+    scatter-add)."""
+    T, top_k = experts.shape
+    E = w_in.shape[0]
+    with jax.named_scope("dispatch"):
+        flat_expert = experts.reshape(-1)              # (T*k,)
+        order = jnp.argsort(flat_expert)               # stable per expert
+        token_idx = order // top_k                     # source token of row
+        group_sizes = jnp.bincount(flat_expert, length=E)
+        xs = tokens[token_idx].astype(w_in.dtype)      # (T*k, d) sorted
+    with jax.named_scope("experts"):
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, group_sizes)) * \
+            jax.lax.ragged_dot(xs, w_in, group_sizes)
+        ys = jax.lax.ragged_dot(h, w_down, group_sizes)    # (T*k, d)
+    with jax.named_scope("combine"):
+        flat_gates = gates.reshape(-1)[order].astype(ys.dtype)
+        out = jax.ops.segment_sum(ys * flat_gates[:, None], token_idx,
+                                  num_segments=T)
+    return out.astype(tokens.dtype), group_sizes
+
+
 def grouped_moe(tokens: jax.Array, probs: jax.Array, w_gate: jax.Array,
-                w_in: jax.Array, w_down: jax.Array, top_k: int
-                ) -> jax.Array:
+                w_in: jax.Array, w_down: jax.Array, top_k: int,
+                norm_topk_prob: bool = True) -> jax.Array:
     """Dropless MoE via sort + grouped GEMM (`jax.lax.ragged_dot`).
 
     Parity: reference `atorch/atorch/modules/moe/grouped_gemm_moe.py` —
     tokens sorted by expert, one grouped matmul per projection, no
-    capacity limit so nothing is dropped.  On TPU `ragged_dot` lowers to
-    the MXU's grouped-matmul path; the sort/unsort are cheap gathers.
+    capacity limit so nothing is dropped.
+
+    What a v5e trace showed (jax 0.9.0, OLMoE's 163,840 rows in 64
+    groups of 2048 x 1024; PERF.md, PR 26): the TPU compiler puts
+    grouped-matmul kernels of its own in place of each `ragged_dot`
+    (`ragged-dot-none.N`, nine a step: three forward, their six
+    transposes), 5.9-6.5 ms each, together 57% of the bf16 peak.  The
+    top-k and the sort are cheap (under 3 ms); the SCATTER-ADDS are not:
+    `segment_sum` in the combine and the transpose of the gather into
+    expert order take 12 ms each, as long as two of the grouped matmuls.
 
     tokens (T, d); probs (T, E) router softmax; w_gate/w_in (E, d, f);
     w_down (E, f, d).  Returns (T, d).
     """
-    T, d = tokens.shape
-    E = probs.shape[-1]
-    gates, experts = jax.lax.top_k(probs, top_k)       # (T, k) each
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates, experts = route_top_k(probs, top_k, norm_topk_prob)
+    return grouped_experts(tokens, gates, experts, w_gate, w_in, w_down)[0]
 
-    flat_expert = experts.reshape(-1)                  # (T*k,)
-    order = jnp.argsort(flat_expert)                   # stable per expert
-    token_idx = order // top_k                         # source token of row
-    group_sizes = jnp.bincount(flat_expert, length=E)
 
-    xs = tokens[token_idx].astype(w_in.dtype)          # (T*k, d) sorted
-    h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, group_sizes)) * \
-        jax.lax.ragged_dot(xs, w_in, group_sizes)
-    ys = jax.lax.ragged_dot(h, w_down, group_sizes)    # (T*k, d)
-
-    flat_gates = gates.reshape(-1)[order].astype(ys.dtype)
-    out = jax.ops.segment_sum(ys * flat_gates[:, None], token_idx,
-                              num_segments=T)
-    return out.astype(tokens.dtype)
+def _aux_loss(cfg: MoEConfig, logits, probs, experts):
+    """The layer's auxiliary loss terms, weighted, as one scalar."""
+    E = cfg.num_experts
+    if cfg.aux_loss == "topk":
+        # f_i: share of tokens with expert i among their k (sums to k)
+        f = jnp.bincount(experts.reshape(-1), length=E).astype(
+            jnp.float32) / probs.shape[0]
+        aux = (f * probs.mean(0)).sum() * E
+    elif cfg.aux_loss == "switch":
+        top1 = jax.nn.one_hot(jnp.argmax(probs, -1), E, dtype=jnp.float32)
+        aux = (top1.mean(0) * probs.mean(0)).sum() * E ** 2
+    else:
+        raise ValueError(f"unknown MoEConfig.aux_loss {cfg.aux_loss!r}")
+    aux = aux * cfg.aux_loss_weight
+    if cfg.z_loss_weight:
+        z = jax.scipy.special.logsumexp(logits, axis=-1)
+        aux = aux + cfg.z_loss_weight * jnp.mean(z * z)
+    return aux
 
 
 class MoEMLP(nn.Module):
-    """Drop-in MLP replacement: router + E stacked SwiGLU/GELU experts.
+    """Drop-in MLP replacement: router + E stacked SwiGLU experts.
 
     Expert weights are (E, d, h)/(E, h, d) so the `ep` mesh axis shards the
     leading dim (MOE_RULES in parallel/sharding.py); dispatch/combine einsums
     let GSPMD place the all-to-alls on ICI.
+
+    Scopes in the compiled step (analysis/hlo_scopes.py): everything under
+    `moe`; `router` (the flax Dense's own name), `aux`, and in the grouped
+    path `dispatch`, `experts`, `combine`.
     """
 
     hidden: int
     ffn: int
     moe: MoEConfig
 
+    # keep it one method: flax names a helper method's ops
+    # `<module>._helper`, in the middle of the scopes above
     @nn.compact
+    @jax.named_scope("moe")
     def __call__(self, x):  # x: (B, T, d)
         cfg = self.moe
+        if cfg.impl != "grouped" and not cfg.norm_topk_prob:
+            raise ValueError(
+                "MoEConfig.norm_topk_prob=False needs impl='grouped': "
+                "top_k_gating always renormalises the k gates")
         B, T, d = x.shape
         tokens = x.reshape(B * T, d)
         n_tok = B * T
@@ -154,29 +237,45 @@ class MoEMLP(nn.Module):
             "experts_w_down", nn.initializers.normal(0.02),
             (cfg.num_experts, self.ffn, d)).astype(cfg.dtype)
 
-        probs = jax.nn.softmax(logits, axis=-1)
-        # Switch-style load-balance loss (shared by both impls)
-        top1 = jax.nn.one_hot(jnp.argmax(probs, -1), cfg.num_experts,
-                              dtype=jnp.float32)
-        aux = (top1.mean(0) * probs.mean(0)).sum() * cfg.num_experts ** 2
-        self.sow("intermediates", "moe_aux_loss",
-                 aux * cfg.aux_loss_weight)
+        with jax.named_scope("router"):
+            probs = jax.nn.softmax(logits, axis=-1)
+        gates = experts = None
+        if cfg.impl == "grouped" or cfg.aux_loss == "topk":
+            with jax.named_scope("dispatch"):
+                gates, experts = route_top_k(probs, cfg.top_k,
+                                             cfg.norm_topk_prob)
+        with jax.named_scope("aux"):
+            self.sow("intermediates", "moe_aux_loss",
+                     _aux_loss(cfg, logits, probs, experts))
 
         if cfg.impl == "grouped":
-            out = grouped_moe(tokens, probs, w_gate, w_in, w_out,
-                              cfg.top_k)
-            return out.reshape(B, T, d)
-
-        combine, dispatch = top_k_gating(logits, cfg.top_k, capacity)
-        # dispatch: (T, E, C) x (T, d) -> (E, C, d)
-        xe = jnp.einsum("tec,td->ecd", dispatch.astype(cfg.dtype),
-                        tokens.astype(cfg.dtype))
-        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, w_gate)) * \
-            jnp.einsum("ecd,edf->ecf", xe, w_in)
-        ye = jnp.einsum("ecf,efd->ecd", h, w_out)
-        # combine back: (T, E, C) x (E, C, d) -> (T, d)
-        out = jnp.einsum("tec,ecd->td", combine.astype(cfg.dtype), ye)
+            out, counts = grouped_experts(tokens, gates, experts, w_gate,
+                                          w_in, w_out)
+        else:
+            combine, dispatch = top_k_gating(logits, cfg.top_k, capacity)
+            # dispatch: (T, E, C) x (T, d) -> (E, C, d)
+            xe = jnp.einsum("tec,td->ecd", dispatch.astype(cfg.dtype),
+                            tokens.astype(cfg.dtype))
+            h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, w_gate)) * \
+                jnp.einsum("ecd,edf->ecf", xe, w_in)
+            ye = jnp.einsum("ecf,efd->ecd", h, w_out)
+            # combine back: (T, E, C) x (E, C, d) -> (T, d)
+            out = jnp.einsum("tec,ecd->td", combine.astype(cfg.dtype), ye)
+            counts = dispatch.sum(axis=(0, 2), dtype=jnp.int32)
+        # counted, not timed: rows each expert ran, and the assignments
+        # (token, one of its k) that reached none
+        self.sow("intermediates", "moe_tokens_per_expert", counts)
+        self.sow("intermediates", "moe_dropped",
+                 n_tok * cfg.top_k - counts.sum())
         return out.reshape(B, T, d)
+
+
+def _sown(intermediates, name: str):
+    """The leaves sown under `name`, whatever module path they sit on."""
+    for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
+        if name in [getattr(p, "key", getattr(p, "name", None))
+                    for p in path]:
+            yield leaf
 
 
 def collect_moe_aux_loss(intermediates) -> jax.Array:
@@ -184,9 +283,22 @@ def collect_moe_aux_loss(intermediates) -> jax.Array:
     collection — any other sown diagnostic (attention stats, logging
     metrics) must not silently become a loss term."""
     total = jnp.zeros((), jnp.float32)
-    leaves = jax.tree_util.tree_flatten_with_path(intermediates)[0]
-    for path, leaf in leaves:
-        keys = [getattr(p, "key", getattr(p, "name", None)) for p in path]
-        if "moe_aux_loss" in keys:
-            total = total + jnp.sum(leaf)
+    for leaf in _sown(intermediates, "moe_aux_loss"):
+        total = total + jnp.sum(leaf)
     return total
+
+
+def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
+    """What the MoE layers of one forward pass counted, reduced to two
+    scalars — or {} for a model with no such layer: the worst layer's
+    `max_i(tokens_i) / mean_i(tokens_i)` and the dropped assignments of
+    all layers."""
+    counts = [n.astype(jnp.float32)
+              for n in _sown(intermediates, "moe_tokens_per_expert")]
+    if not counts:
+        return {}
+    loads = [n.max() / jnp.maximum(n.mean(), 1.0) for n in counts]
+    dropped = [jnp.sum(d) for d in _sown(intermediates, "moe_dropped")]
+    return {"moe_load_max_over_mean": jnp.max(jnp.stack(loads)),
+            "moe_dropped": jnp.sum(jnp.stack(dropped))}
+

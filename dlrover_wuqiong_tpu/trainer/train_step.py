@@ -110,18 +110,26 @@ def make_train_step(
     """
 
     def _grads(params, batch):
+        """(loss, grads, stats): a loss that also counts (make_lm_loss:
+        the MoE layers' load) hands its counters out beside the loss,
+        and they ride in the step's metrics."""
         if value_and_grad_fn is not None:
-            return value_and_grad_fn(params, batch)
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        return loss, grads
+            return (*value_and_grad_fn(params, batch), {})
+        with_stats = getattr(loss_fn, "with_stats", None)
+        if with_stats is None:
+            return (*jax.value_and_grad(loss_fn)(params, batch), {})
+        (loss, stats), grads = jax.value_and_grad(
+            with_stats, has_aux=True)(params, batch)
+        return loss, grads, stats
 
     def train_step(state: TrainState, batch):
         if accum_steps == 1:
-            loss, grads = _grads(state.params, batch)
-        else:
+            loss, grads, stats = _grads(state.params, batch)
+        else:  # counters of the last microbatch alone would mislead: none
+            stats = {}
             loss, grads = accumulate_grads(
-                lambda micro: _grads(state.params, micro), state.params,
-                batch, accum_steps)
+                lambda micro: _grads(state.params, micro)[:2],
+                state.params, batch, accum_steps)
         # outside any flax module: the scope names the clip, the update
         # and the norm in the compiled step (analysis/hlo_scopes.py)
         with jax.named_scope("optimizer"):
@@ -135,7 +143,7 @@ def make_train_step(
             params = optax.apply_updates(state.params, updates)
             gnorm = optax.global_norm(grads)
         new_state = TrainState(state.step + 1, params, opt_state)
-        return new_state, {"loss": loss, "grad_norm": gnorm}
+        return new_state, {"loss": loss, "grad_norm": gnorm, **stats}
 
     # offloaded opt states: donation would let XLA alias a pinned_host
     # input buffer onto a device-memory output (same shape/dtype) and the
@@ -168,6 +176,8 @@ def make_train_step(
             "grad_norm": stacked["grad_norm"][-1],
             "losses": stacked["loss"],
             "grad_norms": stacked["grad_norm"],
+            **{k: v[-1] for k, v in stacked.items()
+               if k not in ("loss", "grad_norm")},
         }
         return state, metrics
 
@@ -271,19 +281,29 @@ def train_state_shardings(state_like: TrainState, planner: ShardingPlanner,
 def make_lm_loss(model_apply: Callable) -> Callable:
     """Standard causal-LM loss over a batch dict {input_ids, labels}.
 
-    Collects sown auxiliary losses (MoE load-balancing) when present."""
+    Collects sown auxiliary losses (MoE load-balancing, router z-loss)
+    when present.  `loss_fn.with_stats(params, batch) -> (loss, stats)` is
+    the same loss with what the MoE layers counted (`collect_moe_stats`;
+    {} for a dense model): `make_train_step` differentiates that one and
+    returns the counters in the step's metrics."""
     from ..models.gpt import cross_entropy_loss
 
-    def loss_fn(params, batch):
+    def with_stats(params, batch):
         logits, updates = model_apply(
             {"params": params}, batch["input_ids"],
             mutable=["intermediates"])
         loss = cross_entropy_loss(logits, batch["labels"])
         inter = updates.get("intermediates", {})
+        stats = {}
         if inter:
-            from ..models.moe import collect_moe_aux_loss
+            from ..models.moe import collect_moe_aux_loss, collect_moe_stats
 
             loss = loss + collect_moe_aux_loss(inter)
-        return loss
+            stats = collect_moe_stats(inter)
+        return loss, stats
 
+    def loss_fn(params, batch):
+        return with_stats(params, batch)[0]
+
+    loss_fn.with_stats = with_stats
     return loss_fn

@@ -35,6 +35,10 @@ from ..telemetry import spans as tspans
 
 logger = get_logger("trainer")
 
+# the step's metrics the loop already handles by name; whatever else a
+# step returns is a counted scalar the metrics pump passes on as it is
+_STEP_SERIES = ("loss", "grad_norm", "losses", "grad_norms")
+
 
 @dataclasses.dataclass
 class TrainingArgs:
@@ -894,14 +898,25 @@ class Trainer:
                 # fusion
                 loss = float(job["metrics"]["loss"])
                 t_read = time.monotonic()
+                # whatever else the step counted (make_train_step: the
+                # loss's `with_stats`) is a scalar output of the same
+                # executable as the loss: there once the loss is
+                counted = {k: float(v) for k, v in job["metrics"].items()
+                           if k not in _STEP_SERIES}
             with tspans.hot_span("pump:report"):
-                self._report_boundary(job, step, loss, t_read)
+                self._report_boundary(job, step, loss, t_read, counted)
         return loss
 
     def _report_boundary(self, job: Dict[str, Any], step: int, loss: float,
-                         t_read: float) -> None:
+                         t_read: float,
+                         counted: Optional[Dict[str, float]] = None) -> None:
         """What follows the readback at a logging boundary: perf-window
-        close, the log line, master reports, tuner credit, callbacks."""
+        close, the log line, master reports, tuner credit, callbacks.
+        `counted` (the step's scalars beside loss and grad_norm, e.g. an
+        MoE model's expert load) gets a log line of its own, rides in
+        the callbacks' dict and is kept as one `trainer:step_metrics`
+        span event."""
+        counted = counted or {}
         snap = None
         pw = job.get("pw")
         if pw is not None:
@@ -918,6 +933,11 @@ class Trainer:
         tps = (step - prev_step) * job["tokens_per_step"] / \
             max(t_read - prev_t, 1e-9)
         logger.info("step %d loss=%.4f tokens/s=%.0f", step, loss, tps)
+        if counted:
+            logger.info("step %d %s", step, " ".join(
+                f"{k}={v:.4g}" for k, v in counted.items()))
+            tspans.span_event("trainer:step_metrics",
+                              {"step": step, **counted})
         self.ctx.report_step(step)
         self.ctx.report_loss(step, loss)
         if self.ctx.mc is not None:
@@ -937,7 +957,7 @@ class Trainer:
             self._tuner.note_window(
                 float(snap.get("step_time_s") or 0.0), loss=loss)
         for cb in self.callbacks:
-            cb(step, {"loss": loss, "tokens_per_sec": tps})
+            cb(step, {"loss": loss, "tokens_per_sec": tps, **counted})
 
     # ---------------------------------------------------------------- train
 

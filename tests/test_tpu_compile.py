@@ -221,3 +221,79 @@ def test_fsdp4_step_temporaries_fit(xl_fsdp4_steps):
     2.92 GiB of temporaries at two layers."""
     temp = xl_fsdp4_steps[2].memory_analysis().temp_size_in_bytes
     assert temp < 1.2 * 2 ** 30, temp / 2 ** 30
+
+
+# ------------------------------------------------- OLMoE's step on one chip
+
+@pytest.fixture(scope="module")
+def olmoe_step(topo):
+    """`olmoe_1b_7b.steady`'s step as its configuration file builds it —
+    published widths, depth 1, all 64 experts, the cell's batch of 4096-
+    token sequences, Trainer's optimizer — compiled for ONE described
+    v5e chip (about 50 s)."""
+    import optax
+
+    from benchmark import cells
+    from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
+
+    cell = cells.load_cell("olmoe_1b_7b.steady")
+    model = cells.load_module("models", "olmoe").build(cell["config"])
+    with pytest.MonkeyPatch.context() as mp, _cache_off():
+        mp.setenv("DWT_COMPILE_CACHE", "0")
+        mp.setattr(fa, "_on_tpu", lambda: True)
+        mp.setattr("dlrover_wuqiong_tpu.models.attention._on_tpu",
+                   lambda: True)
+        res = auto_accelerate(
+            model, strategy=[("fsdp", {})], devices=topo.devices[:1],
+            optimizer=optax.chain(optax.clip_by_global_norm(1.0),
+                                  optax.adamw(3e-4, weight_decay=0.1)),
+            materialize=False, seq_len=cell["seq_len"])
+        ids = jax.ShapeDtypeStruct(
+            (cell["global_batch"], cell["seq_len"]), jnp.int32,
+            sharding=res.batch_sharding_fn(2))
+        step = res.train_step.lower(
+            res.state, {"input_ids": ids, "labels": ids}).compile()
+    return cell, model, step
+
+
+def test_olmoe_step_fits_one_chip_and_fills_it(olmoe_step):
+    """State + temporaries: under 90% of the chip's 16 GB (the batch is
+    the largest that is), over 75% (a smaller cell leaves the chip
+    empty)."""
+    cell, model, step = olmoe_step
+    assert model.config.num_params() == 625_616_896
+    m = step.memory_analysis()
+    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    assert 0.75 * 16e9 < live < 0.90 * 16e9, live / 1e9
+    # the state is donated: 12 B a parameter in, the same out
+    assert m.alias_size_in_bytes >= 12 * model.config.num_params()
+
+
+def test_olmoe_step_runs_the_kernels_at_d128_t4096(olmoe_step):
+    cell, _, step = olmoe_step
+    text = step.as_text()
+    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"):
+        assert kernel in text, kernel
+    # (batch x 16 heads, 4096, 128) in the kernels' layout
+    assert f"bf16[{cell['global_batch'] * 16},4096,128]" in text
+
+
+def test_olmoe_step_keeps_its_scopes_and_names_the_grouped_matmuls(
+        olmoe_step):
+    """The TPU compiler puts kernels of its own in place of
+    `lax.ragged_dot` and writes its name over the traced one; the scope
+    table calls them `ragged_dot`, everything else keeps the program's
+    scopes."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+
+    table = scope_table(olmoe_step[2].as_text())
+    ragged = [n for n in table if n.startswith("ragged-dot-none")]
+    assert len(ragged) == 9  # three forward, their six transposes
+    assert {table[n] for n in ragged} == {"ragged_dot"}
+    scopes = set(table.values())
+    for part in ("feed_forward/moe/router", "feed_forward/moe/dispatch",
+                 "feed_forward/moe/experts", "feed_forward/moe/combine",
+                 "feed_forward/moe/aux", "attention/qk_norm/q_norm",
+                 "attention/q_proj", "Llama/head", "loss", "optimizer"):
+        assert any(part in s for s in scopes), part
