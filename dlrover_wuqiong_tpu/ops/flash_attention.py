@@ -15,14 +15,26 @@ Design (FA2 scheme, canonical Mosaic structure):
   `@pl.when` initializes it on the first KV step and finalizes o/lse on the
   last.
 - causal masking is bottom-right aligned (a query at position i attends to
-  keys k_idx <= i + (sk - sq)); fully-masked KV blocks skip compute via
-  `@pl.when`.
+  keys k_idx <= i + (sk - sq)).  Nothing above the diagonal is computed,
+  at two grains: a grid block that lies above it never runs (`@pl.when`),
+  and INSIDE a block it crosses only the score tiles at or below it are
+  (`_causal_bands`: a static, trace-time loop over `_CAUSAL_TILE`-aligned
+  slices of the resident block, only the crossed tiles masked).  At
+  T = 1024 — one block each way, where the grid skips nothing — that
+  is 3 of 4 tiles; `causal_tile_count` gives the count for any call.
+  The tile set is a pure function of (block_q, block_k, offset, tile): a
+  single block takes any offset sk - sq, several blocks take it when they
+  are square and sq - sk is a multiple of them (the crossed blocks then
+  all sit at offset 0); block_q != block_k or a ragged offset keeps the
+  whole-block mask by grid position.  A non-causal call is the whole-block
+  program, untouched.
 - backward: two kernels — dq (grid: q outer, kv inner) and dk/dv (grid: kv
   outer, q inner) — each recomputing p = exp(s - lse) per tile IN
   TRANSPOSED SPACE (queries in lanes) so the (sq, sk) attention matrix
   never hits HBM and the per-row lse/delta broadcast without relayouts.
   delta = rowsum(dO ∘ O) is one fused XLA reduce into the row-major
-  (bh, 1, sq) layout the kernels consume.
+  (bh, 1, sq) layout the kernels consume.  One block each way takes ONE
+  fused kernel instead (dq, dk and dv from a single recompute of p).
 - head_dim runs natively when lane-aligned (d % 8 == 0, e.g. GPT-2's 64);
   otherwise it is zero-padded to the 128 boundary.  lse lives as (bh, sq)
   f32 everywhere — residuals, kernel outputs and inputs — with a cheap
@@ -90,32 +102,280 @@ def _dot_t(a, b):
                                preferred_element_type=jnp.float32)
 
 
-def _causal_mask_block(qi, ki, block_q, block_k, kv_offset):
-    # (block_q, 1) >= (1, block_k) broadcast: one VPU pass over the block,
-    # vs two materialized 2D iotas + compare (3 extra full passes)
-    q_idx = qi * block_q + kv_offset + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, 1), 0)
-    k_idx = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_k), 1)
-    return q_idx >= k_idx
+def _rel_mask(nq, nk, delta, transposed=False):
+    """Causal mask of an (nq, nk) score piece whose local entry (r, c) is
+    kept iff r + delta >= c — (nk, nq), queries in lanes, if transposed.
+
+    (nq, 1) >= (1, nk) broadcast: one VPU pass over the piece, vs two
+    materialized 2D iotas + compare (3 extra full passes).  `delta` is a
+    Python int for a tile inside a block, a traced scalar for a whole
+    block placed by its grid position.
+    """
+    if transposed:
+        return delta + jax.lax.broadcasted_iota(
+            jnp.int32, (1, nq), 1) >= jax.lax.broadcasted_iota(
+                jnp.int32, (nk, 1), 0)
+    return delta + jax.lax.broadcasted_iota(
+        jnp.int32, (nq, 1), 0) >= jax.lax.broadcasted_iota(
+            jnp.int32, (1, nk), 1)
+
+
+# ------------------------------------------- causal tiles inside one block
+
+
+# side of the score tiles inside a block the diagonal crosses.  Swept on
+# the chip over {128, 256, 512} at (d = 64, T = 1024, pack 8 and pack 4)
+# and (d = 128, T = 4096): with n tiles a side a block computes (n+1)/2n
+# of its square, but a smaller tile feeds the MXU shorter operands, and
+# every piece is traced and lowered again for every layer of a model.
+# 512 and 256 run within 1.5% of each other at all three shapes (128 is
+# slower); 512 has 3 pieces a head where 256 has 7, which keeps a warm
+# `setup_s` where it was.  One tile serves all three, so it is a
+# constant, not yet a function of the head size (PERF.md section 6,
+# PR 27, has the times).
+_CAUSAL_TILE = 512
+
+
+def _causal_tile(block_q: int, block_k: int,
+                 tile: Optional[int] = None) -> Optional[int]:
+    """The tile these block sizes are cut into — `tile` if given (tests,
+    sweeps), else `_CAUSAL_TILE`; None = the block stays whole (a side
+    the tile does not divide, or nothing above one tile)."""
+    tile = tile or _CAUSAL_TILE
+    if block_q % tile or block_k % tile or max(block_q, block_k) <= tile:
+        return None
+    return tile
+
+
+def _diag_offset(num_q: int, num_kv: int, block_q: int, block_k: int,
+                 kv_offset: int) -> Optional[int]:
+    """`off` such that EVERY block the diagonal crosses keeps its local
+    entry (r, c) iff r + off >= c, when the grid alone decides that:
+    one block each way (off = sk - sq), or square blocks and sq - sk a
+    multiple of them (the crossed blocks are the qi + kv_offset/block ==
+    ki ones, off = 0).  None otherwise (block_q != block_k, a ragged
+    kv_offset): the offset then depends on the grid position, and the
+    kernels keep the whole-block mask."""
+    if num_q == 1 and num_kv == 1:
+        return kv_offset
+    if block_q == block_k and kv_offset % block_q == 0:
+        return 0
+    return None
+
+
+def _causal_bands(block_q: int, block_k: int, off: int,
+                  tile: Optional[int]):
+    """The score tiles of one (block_q, block_k) block whose entry (r, c)
+    is kept iff r + off >= c, one band per query tile:
+    [(q0, q1, k_full, k_end)] — every row of [q0, q1) sees the keys
+    [0, k_full), some see [k_full, k_end) (the only part that is masked),
+    none sees [k_end, block_k) (never computed).
+
+    THE source of what the causal kernels compute: the forward and dq
+    loops are built from it, dk/dv and the fused backward from its
+    transpose (`_causal_bands_t`), `causal_tile_count` sums it.  Without
+    a tile (`_causal_tile` gave none) the block is one band, all masked."""
+    if not tile:
+        return [(0, block_q, 0, block_k)]
+    nk = block_k // tile
+    return [(q0, q0 + tile,
+             tile * min(nk, max(0, (q0 + off + 1) // tile)),
+             tile * min(nk, max(0, -(-(q0 + tile + off) // tile))))
+            for q0 in range(0, block_q, tile)]
+
+
+def _causal_bands_t(block_q: int, block_k: int, off: int,
+                    tile: Optional[int]):
+    """The same tiles by key tile: [(k0, k1, q_start, q_full)] — no query
+    before q_start sees a key of [k0, k1), some of [q_start, q_full) do
+    (masked), every one of [q_full, block_q) sees them all."""
+    if not tile:
+        return [(0, block_k, 0, block_q)]
+    nq = block_q // tile
+    return [(k0, k0 + tile,
+             tile * min(nq, max(0, (k0 - off) // tile)),
+             tile * min(nq, max(0, -(-(k0 + tile - 1 - off) // tile))))
+            for k0 in range(0, block_k, tile)]
+
+
+def causal_tile_count(sq: int, sk: int, block_q: int = 1024,
+                      block_k: int = 1024, tile: Optional[int] = None):
+    """(score tiles a causal call computes, tiles in its sq x sk square),
+    in tiles of `_causal_tile`'s side (whole blocks where it gives none).
+
+    Static, like the decision itself: grid blocks above the diagonal
+    never run, blocks below it run whole, and a block the diagonal
+    crosses runs the tiles of `_causal_bands`."""
+    block_q = _fit_block(sq, block_q) or sq
+    block_k = _fit_block(sk, block_k) or sk
+    tile = _causal_tile(block_q, block_k, tile)
+    num_q, num_kv, kv_offset = sq // block_q, sk // block_k, sk - sq
+    off = _diag_offset(num_q, num_kv, block_q, block_k, kv_offset)
+    if not tile or off is None:
+        tq, tk, off = block_q, block_k, None  # count whole blocks
+    else:
+        tq = tk = tile
+    per_block = (block_q // tq) * (block_k // tk)
+    done = 0
+    for qi in range(num_q):
+        for ki in range(num_kv):
+            lo = qi * block_q + kv_offset - ki * block_k  # r - c at (0, 0)
+            if lo + block_q <= 0:        # run is False: above the diagonal
+                continue
+            if lo >= block_k - 1 or off is None:  # below it, or kept whole
+                done += per_block
+            else:
+                done += sum((q1 - q0) // tq * (k_end // tk) for
+                            q0, q1, _, k_end in
+                            _causal_bands(block_q, block_k, off, tile))
+    return done, num_q * num_kv * per_block
+
+
+def _block_work(mask_block: bool, by_keys: bool, transposed: bool,
+                block_q: int, block_k: int, diag_off: Optional[int],
+                tile: Optional[int], qi, ki, kv_offset: int):
+    """What a kernel computes of its resident block, as
+    [(lo, hi, [(piece_lo, piece_hi, mask | None), ...]), ...]: bands of
+    queries whose pieces are key ranges (forward, dq), or with `by_keys`
+    bands of keys whose pieces are query ranges (dk/dv, fused).
+
+    A block off the diagonal (`mask_block` False) is one band of one
+    unmasked piece: the program it always was.  A block on it is the
+    tiles of `_causal_bands`, only the pieces the diagonal crosses
+    masked — or, where no static offset exists (`diag_off` None), one
+    whole-block piece masked by its grid position (qi, ki)."""
+    n_band, n_piece = (block_k, block_q) if by_keys else (block_q, block_k)
+    if not mask_block:
+        return [(0, n_band, [(0, n_piece, None)])]
+    if diag_off is None:
+        return [(0, n_band, [(0, n_piece, _rel_mask(
+            block_q, block_k, qi * block_q + kv_offset - ki * block_k,
+            transposed))])]
+    masks = {}  # off == 0: every crossed tile has the same mask
+
+    def mask(q_lo, q_hi, k_lo, k_hi):
+        key = (q_hi - q_lo, k_hi - k_lo, diag_off + q_lo - k_lo)
+        if key not in masks:
+            masks[key] = _rel_mask(*key, transposed)
+        return masks[key]
+
+    work = []
+    if by_keys:
+        for k0, k1, q_start, q_full in _causal_bands_t(
+                block_q, block_k, diag_off, tile):
+            pieces = []
+            if q_full > q_start:
+                pieces.append((q_start, q_full,
+                               mask(q_start, q_full, k0, k1)))
+            if block_q > q_full:
+                pieces.append((q_full, block_q, None))
+            work.append((k0, k1, pieces))
+    else:
+        for q0, q1, k_full, k_end in _causal_bands(
+                block_q, block_k, diag_off, tile):
+            pieces = [(0, k_full, None)] if k_full else []
+            if k_end > k_full:
+                pieces.append((k_full, k_end,
+                               mask(q0, q1, k_full, k_end)))
+            work.append((q0, q1, pieces))
+    return work
+
+
+def _rows(lo: int, hi: int, n: int):
+    """Index of rows [lo, hi) of an n-row ref dim: nothing at all when
+    that is the whole dim (the program a whole-block kernel always had)."""
+    return () if (lo, hi) == (0, n) else (slice(lo, hi),)
+
+
+def _lanes(lo: int, hi: int, n: int):
+    """The same for the (1, n) lse / delta rows: a lane slice."""
+    return () if (lo, hi) == (0, n) else (slice(None), slice(lo, hi))
+
+
+# heads per iteration of the loop over a tiled block's packed heads: pairs
+# ran as fast as or faster than 1, 4 and all of them (unrolled) at all
+# three swept shapes (PERF.md section 6, PR 27)
+_HEAD_GROUP = 2
+
+
+def _each_head(pack: int, work, body):
+    """body(hh) for each packed head.  A block cut into tiles runs them in
+    a `fori_loop`, `_HEAD_GROUP` heads an iteration: the tiles of a head
+    are traced, lowered and held as instructions once or twice, not
+    `pack` times (unrolled, they added a quarter to a warm `setup_s`, and
+    a several-block kernel ran at half the speed).  A block computed
+    whole keeps the Python loop it always had."""
+    if len(work) == 1 or pack <= _HEAD_GROUP:
+        for hh in range(pack):
+            body(hh)
+        return
+
+    def _group(i, carry):
+        for g in range(_HEAD_GROUP):
+            body(i * _HEAD_GROUP + g)
+        return carry
+
+    jax.lax.fori_loop(0, pack // _HEAD_GROUP, _group, 0)
+
+
+class _BandState(dict):
+    """Stands in for the forward's VMEM scratch where a band's softmax
+    state never outlives the band: values, keyed by the rows alone (the
+    head in front may be a loop index)."""
+
+    def __setitem__(self, idx, value):
+        super().__setitem__(idx[1:], value)
+
+    def __getitem__(self, idx):
+        return super().__getitem__(idx[1:])
 
 
 # ------------------------------------------------------------- forward kernel
 
 
-def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                   m_scr, l_scr, acc_scr, *,
+def _fold(op, xs):
+    """The pieces of one band of rows, folded by `op` to the width of the
+    narrowest (lane slices at multiples of the tile: free), so that a row
+    statistic over them costs ONE cross-lane reduction, as a whole
+    block's does; one per piece made the tiled forward slower than the
+    whole one (PERF.md section 6, PR 27)."""
+    if len(xs) == 1:
+        return xs[0]
+    w = min(x.shape[1] for x in xs)
+    return functools.reduce(op, [x[:, j:j + w] for x in xs
+                                 for j in range(0, x.shape[1], w)])
+
+
+def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                    num_kv: int, causal: bool, sm_scale: float,
-                   block_q: int, block_k: int, kv_offset: int, pack: int):
+                   block_q: int, block_k: int, kv_offset: int, pack: int,
+                   diag_off: Optional[int] = None,
+                   tile: Optional[int] = None):
     """Packed forward: refs carry `pack` heads in the leading dim.
 
     Leading-dim indexing (ref[hh]) is a free address offset (unlike lane
     slicing), so packing amortizes per-grid-step fixed costs and generates
     the causal mask once for all packed heads.
+
+    A block the diagonal crosses is computed by query tile (`_block_work`):
+    a tile's scores against the keys every row of it sees, unmasked, and
+    against the one key tile the diagonal crosses, masked; the keys beyond
+    are never touched.  The loop is Python, unrolled at trace time over
+    static slices: sublane slices of q/k/v and of the scratch at multiples
+    of the tile cost nothing.
+
+    The softmax state (m, l, acc) lives in VMEM scratch across the KV
+    sweep.  A causal call whose keys are ONE block is given none: each
+    query tile is a plain softmax over the static prefix it sees, its
+    state stays values (dicts keyed like the scratch) and its o and lse
+    are written where they are computed.
     """
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     single = num_kv == 1  # whole KV sweep in one step: no online state
+    direct = not scratch
+    m_scr, l_scr, acc_scr = scratch or (
+        _BandState(), _BandState(), _BandState())
 
     if causal:
         # block fully masked when its first key exceeds the last query's reach
@@ -123,58 +383,101 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     else:
         run = True
 
-    if not single:
+    def _empty(rows, n):
+        m_scr[rows] = jnp.full((n, 1), NEG_INF, jnp.float32)
+        l_scr[rows] = jnp.zeros((n, 1), jnp.float32)
+        acc_scr[rows] = jnp.zeros((n, o_ref.shape[-1]), jnp.float32)
+
+    def _finish(rows, lanes):
+        l = l_scr[rows]
+        l_safe = jnp.where(l > 0, l, 1.0)
+        o_ref[rows] = (acc_scr[rows] / l_safe).astype(o_ref.dtype)
+        # empty key set → logsumexp = -inf (matches the jnp reference
+        # path and long_context._merge_partials' isfinite handling).
+        # m is in log2 units (LOG2E folded into the q pre-scale) —
+        # convert back so the public lse stays natural-log.
+        lse = jnp.where(l > 0, m_scr[rows] * (1.0 / LOG2E)
+                        + jnp.log(l_safe), -jnp.inf)
+        # lse lives as (bh, 1, sq) in HBM — a (…, sq, 1) f32 array pads
+        # its minor dim 128x in the tiled layout (~150MB of padding
+        # traffic per call at the bench shape); with sq in lanes the
+        # padding is 8x of a tiny array, and the (rows, 1) -> (1, rows)
+        # relayout happens once per query tile in VMEM
+        lse_ref[lanes] = lse.T
+
+    if not direct and not single:
         @pl.when(ki == 0)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
-    elif causal and kv_offset < 0:
-        # single-step path skips the init, but with sq > sk a q block can be
-        # FULLY masked (run=False): _inner never writes the scratch while
-        # _finalize still reads it — seed the empty-key values so it
-        # finalizes to o=0, lse=-inf instead of stale VMEM
+    elif direct and kv_offset < 0:
+        # with sq > sk a q block can be FULLY masked (run=False): _inner
+        # never runs — it ends on the empty-key values, o=0, lse=-inf
         @pl.when(jnp.logical_not(run))
-        def _init_masked():
-            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-            l_scr[...] = jnp.zeros_like(l_scr)
-            acc_scr[...] = jnp.zeros_like(acc_scr)
+        def _masked():
+            for hh in range(pack):
+                _empty((hh,), block_q)
+                _finish((hh,), (hh,))
 
     def _inner(mask_block: bool):
-        mask = (_causal_mask_block(qi, ki, block_q, block_k, kv_offset)
-                if mask_block else None)
-        for hh in range(pack):
-            # pre-scale q (block_q x d) instead of s (block_q x block_k):
-            # one fewer full VPU pass over the score matrix.  LOG2E folds
-            # here too: s lives in log2 units, every exp below is a bare
-            # exp2, and only the final lse converts back to natural log.
-            q = (q_ref[hh].astype(jnp.float32)
-                 * (sm_scale * LOG2E)).astype(q_ref.dtype)
-            k = k_ref[hh]                              # (block_k, d)
-            v = v_ref[hh]
-            # bf16 MXU multiply, f32 accumulate — never cast operands up
-            s = _dot_t(q, k)                           # (block_q, block_k)
-            if mask_block:
-                s = jnp.where(mask, s, NEG_INF)
-            if single:
-                m_new = s.max(axis=-1, keepdims=True)
-                p = jnp.exp2(s - m_new)
-                if mask_block and kv_offset < 0:
-                    p = jnp.where(s <= NEG_INF, 0.0, p)
-                m_scr[hh] = m_new
-                l_scr[hh] = p.sum(axis=-1, keepdims=True)
-                acc_scr[hh] = _dot(p.astype(v.dtype), v)
-                continue
-            m_prev = m_scr[hh]                         # (block_q, 1)
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp2(s - m_new)
-            if mask_block and kv_offset < 0:
-                # rows can be fully masked only when sq > sk: exp(0)=1 junk
-                p = jnp.where(s <= NEG_INF, 0.0, p)
+        work = _block_work(mask_block, False, False, block_q, block_k,
+                           diag_off, tile, qi, ki, kv_offset)
+
+        def _head(hh):
+            for q0, q1, pieces in work:
+                rows = (hh,) + _rows(q0, q1, block_q)
+                if pieces:
+                    _band(hh, rows, pieces)
+                else:  # sq > sk in one block: no row of the tile sees a key
+                    _empty(rows, q1 - q0)
+                if direct:
+                    _finish(rows, (hh,) + _lanes(q0, q1, block_q))
+
+        _each_head(pack, work, _head)
+
+    def _band(hh, rows, pieces):
+        # pre-scale q (block_q x d) instead of s (block_q x block_k):
+        # one fewer full VPU pass over the score matrix.  LOG2E folds
+        # here too: s lives in log2 units, every exp below is a bare
+        # exp2, and only the final lse converts back to natural log.
+        q = (q_ref[rows].astype(jnp.float32)
+             * (sm_scale * LOG2E)).astype(q_ref.dtype)
+        kv = [(k_ref[(hh,) + _rows(k0, k1, block_k)],
+               v_ref[(hh,) + _rows(k0, k1, block_k)])
+              for k0, k1, _ in pieces]
+        # bf16 MXU multiply, f32 accumulate — never cast operands up
+        ss = [_dot_t(q, k) for k, _ in kv]
+        ss = [s if mask is None else jnp.where(mask, s, NEG_INF)
+              for s, (_, _, mask) in zip(ss, pieces)]
+        m_prev = None if single else m_scr[rows]           # (rows, 1)
+        m_new = _fold(jnp.maximum, ss).max(axis=-1, keepdims=True)
+        if not single:
+            m_new = jnp.maximum(m_prev, m_new)
+        ps = [jnp.exp2(s - m_new) for s in ss]
+        if kv_offset < 0:
+            # rows can be fully masked only when sq > sk: exp(0)=1 junk
+            ps = [p if mask is None else jnp.where(s <= NEG_INF, 0.0, p)
+                  for p, s, (_, _, mask) in zip(ps, ss, pieces)]
+        if not single:
             alpha = jnp.exp2(m_prev - m_new)
-            m_scr[hh] = m_new
-            l_scr[hh] = l_scr[hh] * alpha + p.sum(axis=-1, keepdims=True)
-            acc_scr[hh] = acc_scr[hh] * alpha + _dot(p.astype(v.dtype), v)
+        m_scr[rows] = m_new
+
+        # thunks: a whole block's ops stay in the order they always had
+        # (scratch read, then the reduction or the dot)
+        def l_new():
+            return _fold(jnp.add, ps).sum(axis=-1, keepdims=True)
+
+        def pv():
+            return functools.reduce(jnp.add, [
+                _dot(p.astype(v.dtype), v) for p, (_, v) in zip(ps, kv)])
+
+        if single:
+            l_scr[rows] = l_new()
+            acc_scr[rows] = pv()
+        else:
+            l_scr[rows] = l_scr[rows] * alpha + l_new()
+            acc_scr[rows] = acc_scr[rows] * alpha + pv()
 
     if causal:
         # only blocks straddling the diagonal pay for mask generation
@@ -193,24 +496,11 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         def _compute():
             _inner(False)
 
-    @pl.when(ki == num_kv - 1)
-    def _finalize():
-        for hh in range(pack):
-            l = l_scr[hh]
-            l_safe = jnp.where(l > 0, l, 1.0)
-            o_ref[hh] = (acc_scr[hh] / l_safe).astype(o_ref.dtype)
-            # empty key set → logsumexp = -inf (matches the jnp reference
-            # path and long_context._merge_partials' isfinite handling).
-            # m is in log2 units (LOG2E folded into the q pre-scale) —
-            # convert back so the public lse stays natural-log.
-            lse = jnp.where(l > 0, m_scr[hh] * (1.0 / LOG2E)
-                            + jnp.log(l_safe), -jnp.inf)
-            # lse lives as (bh, 1, sq) in HBM — a (…, sq, 1) f32 array pads
-            # its minor dim 128x in the tiled layout (~150MB of padding
-            # traffic per call at the bench shape); with sq in lanes the
-            # padding is 8x of a tiny array, and the (block_q, 1) ->
-            # (1, block_q) relayout happens once per q block in VMEM
-            lse_ref[hh] = lse.T
+    if not direct:
+        @pl.when(ki == num_kv - 1)
+        def _finalize():
+            for hh in range(pack):
+                _finish((hh,), (hh,))
 
 
 def _fit_pack(bh: int) -> int:
@@ -236,9 +526,25 @@ def _fit_pack(bh: int) -> int:
     return 1
 
 
+def _causal_plan(causal: bool, num_q: int, num_kv: int, block_q: int,
+                 block_k: int, kv_offset: int, tile: Optional[int]) -> dict:
+    """The kernels' static `diag_off` / `tile`; nothing for a non-causal
+    call, whose kernels are the whole-block program."""
+    if not causal:
+        return {}
+    return {"diag_off": _diag_offset(num_q, num_kv, block_q, block_k,
+                                     kv_offset),
+            "tile": _causal_tile(block_q, block_k, tile)}
+
+
 def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
-                       block_q: int, block_k: int, interpret: bool):
-    """q: (bh, sq, d), k/v: (bh, sk, d) → (o, lse (bh, 1, sq) f32)."""
+                       block_q: int, block_k: int, interpret: bool,
+                       tile: Optional[int] = None):
+    """q: (bh, sq, d), k/v: (bh, sk, d) → (o, lse (bh, 1, sq) f32).
+
+    `tile` overrides `_causal_tile` (tests and sweeps: a tile the size of
+    the block is the whole-block mask); no caller of the package sets it.
+    """
     bh, sq, d = q.shape
     sk = k.shape[1]
     block_q = min(block_q, sq)
@@ -249,7 +555,9 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
 
     kernel = functools.partial(
         _fa_fwd_kernel, num_kv=num_kv, causal=causal, sm_scale=sm_scale,
-        block_q=block_q, block_k=block_k, kv_offset=sk - sq, pack=pack)
+        block_q=block_q, block_k=block_k, kv_offset=sk - sq, pack=pack,
+        **_causal_plan(causal, sq // block_q, num_kv, block_q, block_k,
+                       sk - sq, tile))
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -266,7 +574,9 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
             _out_struct((bh, sq, d), q.dtype, q),
             _out_struct((bh, 1, sq), jnp.float32, q),
         ),
-        scratch_shapes=[
+        # softmax state across the KV sweep; a causal call with one KV
+        # block ends every query tile where it computes it
+        scratch_shapes=[] if causal and num_kv == 1 else [
             pltpu.VMEM((pack, block_q, 1), jnp.float32),
             pltpu.VMEM((pack, block_q, 1), jnp.float32),
             pltpu.VMEM((pack, block_q, d), jnp.float32),
@@ -280,15 +590,6 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
 
 
 # ------------------------------------------------------------ backward kernels
-
-
-def _causal_mask_block_t(qi, ki, block_q, block_k, kv_offset):
-    """Transposed-space causal mask: (block_k, block_q), queries in lanes."""
-    q_idx = qi * block_q + kv_offset + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_q), 1)
-    k_idx = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, 1), 0)
-    return q_idx >= k_idx
 
 
 def _dot_c0(a, b):
@@ -326,7 +627,9 @@ def _p_transposed(q, k, lse, mask, sm_scale):
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dq_scr, *, num_kv: int, causal: bool,
                       sm_scale: float, block_q: int, block_k: int,
-                      kv_offset: int, pack: int):
+                      kv_offset: int, pack: int,
+                      diag_off: Optional[int] = None,
+                      tile: Optional[int] = None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -340,14 +643,25 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         run = True
 
     def _inner(mask_block: bool):
-        mask = (_causal_mask_block_t(qi, ki, block_q, block_k, kv_offset)
-                if mask_block else None)
-        for hh in range(pack):
-            k = k_ref[hh]
-            pT = _p_transposed(q_ref[hh], k, lse_ref[hh], mask, sm_scale)
-            dpT = _dot_t(v_ref[hh], do_ref[hh])    # (block_k, block_q)
-            dsT = (pT * (dpT - delta_ref[hh]) * sm_scale).astype(k.dtype)
-            dq_scr[hh] += _dot_c0(dsT, k)          # (block_q, d)
+        # by query tile, like the forward: each dq tile is added to once
+        # per piece, its keys the prefix the tile sees
+        work = _block_work(mask_block, False, True, block_q, block_k,
+                           diag_off, tile, qi, ki, kv_offset)
+        def _head(hh):
+            for q0, q1, pieces in work:
+                rows = (hh,) + _rows(q0, q1, block_q)
+                lanes = (hh,) + _lanes(q0, q1, block_q)
+                for k0, k1, mask in pieces:
+                    keys = (hh,) + _rows(k0, k1, block_k)
+                    k = k_ref[keys]
+                    pT = _p_transposed(q_ref[rows], k, lse_ref[lanes], mask,
+                                       sm_scale)
+                    dpT = _dot_t(v_ref[keys], do_ref[rows])  # (keys, rows)
+                    dsT = (pT * (dpT - delta_ref[lanes])
+                           * sm_scale).astype(k.dtype)
+                    dq_scr[rows] += _dot_c0(dsT, k)          # (rows, d)
+
+        _each_head(pack, work, _head)
 
     if causal:
         diag = (qi * block_q + kv_offset < (ki + 1) * block_k) & run
@@ -374,7 +688,9 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_scr, dv_scr, *, num_q: int,
                        causal: bool, sm_scale: float, block_q: int,
-                       block_k: int, kv_offset: int, pack: int):
+                       block_k: int, kv_offset: int, pack: int,
+                       diag_off: Optional[int] = None,
+                       tile: Optional[int] = None):
     ki = pl.program_id(1)
     qi = pl.program_id(2)
 
@@ -389,18 +705,27 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         run = True
 
     def _inner(mask_block: bool):
-        mask = (_causal_mask_block_t(qi, ki, block_q, block_k, kv_offset)
-                if mask_block else None)
-        for hh in range(pack):
-            q = q_ref[hh]
-            do = do_ref[hh]
-            pT = _p_transposed(q, k_ref[hh], lse_ref[hh], mask,
-                               sm_scale).astype(q.dtype)
-            dv_scr[hh] += _dot(pT, do)             # (block_k, d)
-            dpT = _dot_t(v_ref[hh], do)
-            dsT = (pT.astype(jnp.float32)
-                   * (dpT - delta_ref[hh]) * sm_scale).astype(q.dtype)
-            dk_scr[hh] += _dot(dsT, q)             # (block_k, d)
+        # by key tile: its queries are the suffix that sees it
+        work = _block_work(mask_block, True, True, block_q, block_k,
+                           diag_off, tile, qi, ki, kv_offset)
+        def _head(hh):
+            for k0, k1, pieces in work:
+                keys = (hh,) + _rows(k0, k1, block_k)
+                for q0, q1, mask in pieces:
+                    rows = (hh,) + _rows(q0, q1, block_q)
+                    lanes = (hh,) + _lanes(q0, q1, block_q)
+                    q = q_ref[rows]
+                    do = do_ref[rows]
+                    pT = _p_transposed(q, k_ref[keys], lse_ref[lanes], mask,
+                                       sm_scale).astype(q.dtype)
+                    dv_scr[keys] += _dot(pT, do)             # (keys, d)
+                    dpT = _dot_t(v_ref[keys], do)
+                    dsT = (pT.astype(jnp.float32)
+                           * (dpT - delta_ref[lanes])
+                           * sm_scale).astype(q.dtype)
+                    dk_scr[keys] += _dot(dsT, q)             # (keys, d)
+
+        _each_head(pack, work, _head)
 
     if causal:
         diag = (qi * block_q + kv_offset < (ki + 1) * block_k) & run
@@ -426,9 +751,11 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dk_ref, dv_ref, *, causal: bool,
+                         dq_ref, dk_ref, dv_ref, *dq_scr, causal: bool,
                          sm_scale: float, block_q: int, block_k: int,
-                         kv_offset: int, pack: int):
+                         kv_offset: int, pack: int,
+                         diag_off: Optional[int] = None,
+                         tile: Optional[int] = None):
     """Single-block fused backward: dq, dk AND dv in one pass.
 
     Only legal when the whole sequence fits one block each way (num_q ==
@@ -438,25 +765,67 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     steps (the reason the split kernels exist).  At the 1k-context bench
     shape this saves 2 of the split path's 7 dots (the second S and dP
     recomputes) and one full exp pass over the score matrix.
+
+    Causal, the block is computed by key tile (`_block_work`): a tile's
+    dk and dv come out whole from the queries that see it, and only dq
+    accumulates across tiles, in the one f32 scratch `dq_scr` (absent
+    from a call that computes its block whole).  Still one pass and five
+    dots, each on the tiles at or below the diagonal.
     """
-    mask = (_causal_mask_block_t(0, 0, block_q, block_k, kv_offset)
-            if causal else None)
-    for hh in range(pack):
-        q = q_ref[hh]
-        k = k_ref[hh]
-        do = do_ref[hh]
-        pT = _p_transposed(q, k, lse_ref[hh], mask, sm_scale)  # (bk, bq)
-        pTb = pT.astype(q.dtype)
-        dv_ref[hh] = _dot(pTb, do).astype(dv_ref.dtype)        # (bk, d)
-        dpT = _dot_t(v_ref[hh], do)                            # (bk, bq)
-        dsT = (pT * (dpT - delta_ref[hh]) * sm_scale).astype(q.dtype)
-        dk_ref[hh] = _dot(dsT, q).astype(dk_ref.dtype)         # (bk, d)
-        dq_ref[hh] = _dot_c0(dsT, k).astype(dq_ref.dtype)      # (bq, d)
+    work = _block_work(causal, True, True, block_q, block_k, diag_off, tile,
+                       0, 0, kv_offset)
+    # the first key tile is seen by every query that sees any: it sets
+    # dq's rows, the later tiles add to them
+    seen_from = work[0][2][0][0] if work[0][2] else block_q
+
+    def _head(hh):
+        for k0, k1, pieces in work:
+            keys = (hh,) + _rows(k0, k1, block_k)
+            k = dk = dv = None
+            for q0, q1, mask in pieces:
+                rows = (hh,) + _rows(q0, q1, block_q)
+                lanes = (hh,) + _lanes(q0, q1, block_q)
+                last = q1 == pieces[-1][1]
+                q = q_ref[rows]
+                k = k_ref[keys] if k is None else k
+                do = do_ref[rows]
+                pT = _p_transposed(q, k, lse_ref[lanes], mask,
+                                   sm_scale)                 # (keys, rows)
+                dvp = _dot(pT.astype(q.dtype), do)           # (keys, d)
+                dv = dvp if dv is None else dv + dvp
+                if last:
+                    dv_ref[keys] = dv.astype(dv_ref.dtype)
+                dpT = _dot_t(v_ref[keys], do)                # (keys, rows)
+                dsT = (pT * (dpT - delta_ref[lanes])
+                       * sm_scale).astype(q.dtype)
+                dkp = _dot(dsT, q)                           # (keys, d)
+                dk = dkp if dk is None else dk + dkp
+                if last:
+                    dk_ref[keys] = dk.astype(dk_ref.dtype)
+                dqp = _dot_c0(dsT, k)                        # (rows, d)
+                if not dq_scr:
+                    dq_ref[rows] = dqp.astype(dq_ref.dtype)
+                elif k0 == 0:
+                    dq_scr[0][q0:q1] = dqp
+                else:
+                    dq_scr[0][q0:q1] += dqp
+            if not pieces:  # sq > sk: keys no query sees
+                dk_ref[keys] = jnp.zeros((k1 - k0,) + dk_ref.shape[2:],
+                                         dk_ref.dtype)
+                dv_ref[keys] = jnp.zeros((k1 - k0,) + dv_ref.shape[2:],
+                                         dv_ref.dtype)
+        if dq_scr:
+            if seen_from:  # sq > sk: queries that see no key
+                dq_scr[0][:seen_from] = jnp.zeros(
+                    (seen_from,) + dq_scr[0].shape[1:], jnp.float32)
+            dq_ref[hh] = dq_scr[0][...].astype(dq_ref.dtype)
+
+    _each_head(pack, work, _head)
 
 
 def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                         block_q: int, block_k: int, interpret: bool,
-                        glse=None):
+                        glse=None, tile: Optional[int] = None):
     """All operands flat (bh, s, d); lse (bh, 1, sq) f32. Returns dq, dk, dv.
 
     The kernels recompute p in TRANSPOSED space (queries in lanes) so the
@@ -471,6 +840,8 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     num_q = sq // block_q
     num_kv = sk // block_k
     pack = _fit_pack(bh)
+    plan = _causal_plan(causal, num_q, num_kv, block_q, block_k, kv_offset,
+                        tile)
 
     # delta = rowsum(dO ∘ O) — cheap fused reduce; (bh, 1, sq) row-major
     # layout avoids the 128x lane padding a (bh, sq, 1) array would pay
@@ -488,11 +859,13 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         bspec_q = pl.BlockSpec((pack, block_q, d), lambda b: (b, 0, 0))
         bspec_k = pl.BlockSpec((pack, block_k, d), lambda b: (b, 0, 0))
         bspec_row = pl.BlockSpec((pack, 1, block_q), lambda b: (b, 0, 0))
+        tiled = causal and len(_causal_bands_t(
+            block_q, block_k, plan["diag_off"], plan["tile"])) > 1
         return pl.pallas_call(
             functools.partial(
                 _fa_bwd_fused_kernel, causal=causal, sm_scale=sm_scale,
                 block_q=block_q, block_k=block_k, kv_offset=kv_offset,
-                pack=pack),
+                pack=pack, **plan),
             grid=(bh // pack,),
             in_specs=[bspec_q, bspec_k, bspec_k, bspec_q, bspec_row,
                       bspec_row],
@@ -502,6 +875,9 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                 _out_struct((bh, sk, d), k.dtype, q),
                 _out_struct((bh, sk, d), v.dtype, q),
             ),
+            # dq across key tiles, one head at a time
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
+            if tiled else [],
             compiler_params=_compiler_params(
                 "parallel", vmem_limit=100 * 1024 * 1024),
             interpret=interpret,
@@ -511,7 +887,8 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, num_kv=num_kv, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
-                          block_k=block_k, kv_offset=kv_offset, pack=pack),
+                          block_k=block_k, kv_offset=kv_offset, pack=pack,
+                          **plan),
         grid=(bh // pack, num_q, num_kv),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=pl.BlockSpec((pack, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -531,7 +908,8 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, num_q=num_q, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
-                          block_k=block_k, kv_offset=kv_offset, pack=pack),
+                          block_k=block_k, kv_offset=kv_offset, pack=pack,
+                          **plan),
         grid=(bh // pack, num_kv, num_q),
         in_specs=[qspec_t, kspec_t, kspec_t, qspec_t, rowspec_t, rowspec_t],
         out_specs=(
@@ -582,9 +960,14 @@ def flash_attention(q, k, v, causal: bool = True,
     """Multi-head attention, FA2-style.
 
     Args: q (b, h, sq, d); k, v (b, h, sk, d).  Returns (b, h, sq, d).
-    `bwd_block_q`/`bwd_block_k` tile the dq/dkv backward kernels
-    independently (0 = inherit block_q/block_k — swept best at the bench
-    shape, README table).
+    `block_q`/`block_k` are the GRID's blocks, capped at the sequence: at
+    T <= 1024 the grid is one block each way, which is what lets the
+    backward be the one fused kernel.  What a causal call skips below
+    that grain is not the caller's to set: the kernels cut a block the
+    diagonal crosses into `_CAUSAL_TILE` tiles themselves (module
+    docstring).  `bwd_block_q`/`bwd_block_k` block the dq/dkv backward
+    kernels independently (0 = inherit block_q/block_k; no chip run of
+    this repository has measured another choice — PERF.md section 6).
     """
     out, _ = _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k,
                      bwd_block_q, bwd_block_k)

@@ -1,0 +1,249 @@
+"""The causal kernels compute only the score tiles at or below the
+diagonal of a block (`ops/flash_attention._causal_bands`): the tile set
+against a brute-force mask, its count at the benchmark cells' shapes,
+and interpret-mode parity of the tiled forward, fused backward and split
+backward with the plain reference and its `jax.grad`.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_wuqiong_tpu.ops import flash_attention as fa
+
+
+# ------------------------------------------------------------ the tile set
+
+BLOCKS = [  # block_q, block_k, off, tile
+    (1024, 1024, 0, 256), (1024, 1024, 0, 128), (1024, 1024, 0, 512),
+    (128, 128, 0, 32), (64, 128, 64, 32), (128, 64, -64, 32),
+    (128, 64, -32, 32), (96, 96, 7, 16), (96, 64, -41, 16),
+    (64, 64, 0, 64), (64, 64, 0, None), (96, 96, 0, 64),
+]
+
+
+def _kept(block_q, block_k, off):
+    r = np.arange(block_q)[:, None]
+    c = np.arange(block_k)[None, :]
+    return r + off >= c
+
+
+@pytest.mark.parametrize("block_q,block_k,off,tile", BLOCKS)
+def test_bands_cover_the_kept_entries_and_mask_only_the_crossed(
+        block_q, block_k, off, tile):
+    kept = _kept(block_q, block_k, off)
+    seen = np.zeros_like(kept)
+    tile = fa._causal_tile(block_q, block_k, tile)  # None: does not divide
+    for q0, q1, k_full, k_end in fa._causal_bands(block_q, block_k, off,
+                                                  tile):
+        assert kept[q0:q1, :k_full].all()        # computed, never masked
+        assert not kept[q0:q1, k_end:].any()     # never computed
+        seen[q0:q1] = True
+        if tile:
+            # each crossed tile really is crossed
+            for k0 in range(k_full, k_end, tile):
+                part = kept[q0:q1, k0:k0 + tile]
+                assert part.any() and not part.all() or off % tile
+    assert seen.all()
+
+
+@pytest.mark.parametrize("block_q,block_k,off,tile", BLOCKS)
+def test_bands_by_key_are_the_same_tiles(block_q, block_k, off, tile):
+    def rect(bands, by_keys):
+        done = np.zeros((block_q, block_k), int)   # 1 unmasked, 2 masked
+        for lo, hi, a, b in bands:
+            if by_keys:
+                done[a:b, lo:hi], done[b:, lo:hi] = 2, 1
+            else:
+                done[lo:hi, :a], done[lo:hi, a:b] = 1, 2
+        return done
+
+    tile = fa._causal_tile(block_q, block_k, tile)
+    by_q = rect(fa._causal_bands(block_q, block_k, off, tile), False)
+    by_k = rect(fa._causal_bands_t(block_q, block_k, off, tile), True)
+    np.testing.assert_array_equal(by_q, by_k)
+    kept = _kept(block_q, block_k, off)
+    assert kept[by_k == 1].all() and not kept[by_k == 0].any()
+
+
+@pytest.mark.parametrize("name,sq,want", [
+    ("gpt2_124m.steady", 1024, (3, 4)),
+    ("gpt2_xl.fsdp4_steady", 1024, (3, 4)),
+    # 6 blocks below the diagonal whole, 4 on it at 3 of 4, 6 skipped
+    ("olmoe_1b_7b.steady", 4096, (6 * 4 + 4 * 3, 64)),
+])
+def test_tile_count_at_the_cells_shapes(name, sq, want):
+    assert fa._causal_tile(1024, 1024) == 512
+    assert fa.causal_tile_count(sq, sq) == want
+    # the finer tile of the sweep: 10 of 16 at T = 1024
+    assert fa.causal_tile_count(sq, sq, tile=256) == {
+        1024: (10, 16), 4096: (6 * 16 + 4 * 10, 256)}[sq]
+
+
+@pytest.mark.parametrize("sq,sk,block_q,block_k,tile,want", [
+    (1024, 1024, 1024, 1024, 128, (36, 64)),
+    (1024, 1024, 1024, 1024, 512, (3, 4)),
+    (1024, 1024, 1024, 1024, 1024, (1, 1)),      # the whole-block mask
+    (4096, 4096, 1024, 1024, 512, (6 * 4 + 4 * 3, 64)),
+    (256, 256, 64, 128, 16, (6, 8)),     # ragged blocks: counted whole
+    (64, 128, 64, 128, 32, (2 * 3 + 1, 8)),      # kv_offset 64
+    (128, 64, 128, 64, 32, (1 + 2, 8)),          # kv_offset -64
+])
+def test_tile_count_follows_the_bands(sq, sk, block_q, block_k, tile, want):
+    assert fa.causal_tile_count(sq, sk, block_q, block_k, tile) == want
+
+
+def test_blocks_too_small_for_the_tile_stay_whole():
+    assert fa._causal_tile(512, 512) is None
+    assert fa._causal_tile(768, 768) is None
+    assert fa._causal_tile(2048, 1024) == 512
+    assert fa.causal_tile_count(512, 512) == (1, 1)
+
+
+# ------------------------------------------------- interpret-mode parity
+
+# sq, sk, block_q, block_k, tile, causal, bh, d
+CASES = [
+    # one block each way, every pack, both head sizes
+    (128, 128, 128, 128, 64, True, 8, 64),
+    (128, 128, 128, 128, 32, True, 4, 128),
+    (128, 128, 128, 128, 32, True, 2, 64),
+    (128, 128, 128, 128, 16, True, 3, 64),
+    # several blocks with a diagonal
+    (256, 256, 64, 64, 16, True, 2, 64),
+    (256, 256, 128, 128, 32, True, 4, 128),
+    # kv_offset > 0: one block, and blocks whose diagonal is shifted
+    (64, 128, 64, 128, 32, True, 2, 64),
+    (128, 256, 64, 64, 16, True, 2, 64),
+    # kv_offset < 0: rows that see no key at all
+    (128, 64, 128, 64, 32, True, 2, 64),
+    (256, 128, 64, 64, 16, True, 2, 64),
+    # no static offset (block_q != block_k): the whole-block mask stays
+    (256, 256, 64, 128, 16, True, 2, 64),
+    # a tile as large as the block is the whole-block mask
+    (128, 128, 128, 128, 128, True, 2, 64),
+    # non-causal: nothing to skip
+    (128, 128, 64, 64, 16, False, 2, 64),
+    (128, 128, 128, 128, 32, False, 8, 64),
+]
+
+
+def _inputs(sq, sk, bh, d, seed=0):
+    kq, kk, kv, kg, kl = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(kq, (bh, sq, d), jnp.float32)
+    k = jax.random.normal(kk, (bh, sk, d), jnp.float32)
+    v = jax.random.normal(kv, (bh, sk, d), jnp.float32)
+    g = jax.random.normal(kg, (bh, sq, d), jnp.float32)
+    gl = jax.random.normal(kl, (bh, 1, sq), jnp.float32)
+    return q, k, v, g, gl
+
+
+def _reference(q, k, v, g, gl, causal, scale):
+    """o, lse and the gradients of sum(o * g) + sum(lse * gl) by
+    `jax.grad` of the plain reference (the lse variant: a row that sees
+    no key gives o = 0 there, not NaN)."""
+    def loss(q, k, v):
+        o, lse = fa._reference_with_lse(q[None], k[None], v[None], causal,
+                                        scale)
+        extra = 0.0 if gl is None else (lse[0] * gl[:, 0]).sum()
+        return (o[0] * g).sum() + extra, (o[0], lse[0])
+
+    (_, (o, lse)), grads = jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return o, lse, grads
+
+
+@pytest.mark.parametrize("sq,sk,block_q,block_k,tile,causal,bh,d", CASES)
+def test_tiled_forward_matches_reference(sq, sk, block_q, block_k, tile,
+                                         causal, bh, d):
+    q, k, v, g, _ = _inputs(sq, sk, bh, d)
+    scale = d ** -0.5
+    o, lse = fa._fa_forward_pallas(q, k, v, causal, scale, block_q, block_k,
+                                   interpret=True, tile=tile)
+    ro, rlse, _ = _reference(q, k, v, g, None, causal, scale)
+    np.testing.assert_allclose(o, ro, atol=2e-5)
+    np.testing.assert_allclose(lse[:, 0], rlse, atol=2e-5)
+
+
+# one block each way takes the fused kernel, or with `split` the dq and
+# dk/dv kernels on the same block; several blocks take those two anyway
+BWD_CASES = [c + (False,) for c in CASES if c[:2] == c[2:4]] \
+    + [c + (True,) for c in CASES]
+
+
+@pytest.mark.parametrize("sq,sk,block_q,block_k,tile,causal,bh,d,split",
+                         BWD_CASES)
+def test_tiled_backward_matches_reference(sq, sk, block_q, block_k, tile,
+                                          causal, bh, d, split,
+                                          monkeypatch):
+    if split:
+        monkeypatch.setenv("DWT_FA_NO_FUSED", "1")
+    q, k, v, g, _ = _inputs(sq, sk, bh, d, seed=1)
+    scale = d ** -0.5
+    o, lse = fa._fa_forward_pallas(q, k, v, causal, scale, block_q, block_k,
+                                   interpret=True, tile=tile)
+    dq, dk, dv = fa._fa_backward_pallas(
+        q, k, v, o, lse, g, causal, scale, block_q, block_k, interpret=True,
+        tile=tile)
+    _, _, (rq, rk, rv) = _reference(q, k, v, g, None, causal, scale)
+    np.testing.assert_allclose(dq, rq, atol=5e-4)
+    np.testing.assert_allclose(dk, rk, atol=5e-4)
+    np.testing.assert_allclose(dv, rv, atol=5e-4)
+
+
+@pytest.mark.parametrize("sq,sk,block_q,block_k,tile,split", [
+    (128, 128, 128, 128, 32, False),
+    (128, 128, 128, 128, 32, True),
+    (256, 256, 64, 64, 16, True),
+    (64, 128, 64, 128, 32, False),
+    (128, 256, 64, 64, 16, True),
+])
+def test_tiled_backward_takes_the_lse_cotangent(sq, sk, block_q, block_k,
+                                                tile, split, monkeypatch):
+    """`flash_attention_with_lse`'s second cotangent, folded into delta,
+    reaches every tile's ds."""
+    if split:
+        monkeypatch.setenv("DWT_FA_NO_FUSED", "1")
+    q, k, v, g, gl = _inputs(sq, sk, 2, 64, seed=2)
+    o, lse = fa._fa_forward_pallas(q, k, v, True, 0.125, block_q, block_k,
+                                   interpret=True, tile=tile)
+    dq, dk, dv = fa._fa_backward_pallas(
+        q, k, v, o, lse, g, True, 0.125, block_q, block_k, interpret=True,
+        glse=gl, tile=tile)
+    _, _, (rq, rk, rv) = _reference(q, k, v, g, gl, True, 0.125)
+    assert float(jnp.abs(gl).max()) > 1.0
+    np.testing.assert_allclose(dq, rq, atol=5e-4)
+    np.testing.assert_allclose(dk, rk, atol=5e-4)
+    np.testing.assert_allclose(dv, rv, atol=5e-4)
+
+
+def _dots(jaxpr) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _dots(sub)
+    return n
+
+
+@pytest.mark.parametrize("causal,tile,fwd,bwd", [
+    (False, None, 2, 5),          # whole block: the program it always was
+    (True, 1024, 2 + 2, 5),       # whole-block mask
+    (True, 256, 2 + 2 * 7, 5 * 7),  # 4 crossed + 3 unmasked pieces a head
+    (True, 512, 2 + 2 * 3, 5 * 3),
+])
+def test_dots_traced_at_gpt2_shape(causal, tile, fwd, bwd):
+    """One block each way at T = 1024: the kernels trace one set of dots
+    per piece the tile set has (the causal forward also traces, and never
+    runs, the branch for a block below the diagonal), and a non-causal
+    call traces what it always did."""
+    x = jax.ShapeDtypeStruct((1, 1024, 64), jnp.bfloat16)
+    row = jax.ShapeDtypeStruct((1, 1, 1024), jnp.float32)
+    f = jax.make_jaxpr(lambda q, k, v: fa._fa_forward_pallas(
+        q, k, v, causal, 0.125, 1024, 1024, False, tile=tile))(x, x, x)
+    assert _dots(f.jaxpr) == fwd
+    b = jax.make_jaxpr(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
+        q, k, v, o, l, do, causal, 0.125, 1024, 1024, False, tile=tile))(
+            x, x, x, x, row, x)
+    assert _dots(b.jaxpr) == bwd

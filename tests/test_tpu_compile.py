@@ -62,24 +62,29 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-# (bh, T, d): GPT-2's heads at the default 8-head pack; a d=128 model's
-# 4k context at pack 4 (the same kernel body, half the unroll — the
-# pack-8 compile of this shape alone takes ~40 s)
-SHAPES = [(8, 1024, 64), (4, 4096, 128)]
+# (bh, T, d, causal): GPT-2's heads at the default 8-head pack; GPT-2 XL's
+# 100 heads a chip, which pack by 4; a d=128 model's 4k context at pack 4
+# (the same kernel body, half the unroll — the pack-8 compile of this
+# shape alone takes ~40 s); and ring attention's later steps, which are
+# not causal.  The causal ones are cut into tiles below the diagonal
+# (`fa._causal_bands`): static slices Mosaic has to take.
+SHAPES = [(8, 1024, 64, True), (4, 1024, 64, True), (4, 4096, 128, True),
+          (4, 1024, 64, False)]
 
 
-@pytest.mark.parametrize("bh,t,d", SHAPES)
-def test_attention_forward_and_backward_compile(topo, bh, t, d):
+@pytest.mark.parametrize("bh,t,d,causal", SHAPES)
+def test_attention_forward_and_backward_compile(topo, bh, t, d, causal):
     one = SingleDeviceSharding(topo.devices[0])
     x = jax.ShapeDtypeStruct((bh, t, d), jnp.bfloat16, sharding=one)
     lse = jax.ShapeDtypeStruct((bh, 1, t), jnp.float32, sharding=one)
     blk = min(1024, t)
     sc = d ** -0.5
+    assert fa.causal_tile_count(t, t) == {1024: (3, 4), 4096: (36, 64)}[t]
     fwd = _compile(lambda q, k, v: fa._fa_forward_pallas(
-        q, k, v, True, sc, blk, blk, False), x, x, x)
+        q, k, v, causal, sc, blk, blk, False), x, x, x)
     assert "dwt_fa_fwd" in fwd and "tpu_custom_call" in fwd
     bwd = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
-        q, k, v, o, l, do, True, sc, blk, blk, False), x, x, x, x, lse, x)
+        q, k, v, o, l, do, causal, sc, blk, blk, False), x, x, x, x, lse, x)
     if t == blk:  # one block each way: the fused dq+dk+dv kernel
         assert "dwt_fa_bwd_fused" in bwd
     else:
