@@ -38,6 +38,7 @@ line of stdout is the contract line:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import glob
 import json
 import os
@@ -170,7 +171,6 @@ def phase_kernel(cfg, batch: int, attn_batch: int = 4):
     import optax
 
     from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
-    from dlrover_wuqiong_tpu.auto.tuner import variant_env
     from dlrover_wuqiong_tpu.models.gpt import GPT
     from dlrover_wuqiong_tpu.ops.flash_attention import (
         _attention_reference,
@@ -192,19 +192,19 @@ def phase_kernel(cfg, batch: int, attn_batch: int = 4):
     (_, ref_out), ref_grads = jax.jit(jax.value_and_grad(
         ref_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
 
-    def fa_loss(q, k, v):
-        out = flash_attention(q, k, v, True, None)
-        return (out.astype(jnp.float32) * g.astype(jnp.float32)).sum(), out
-
     errs = {}
     ok = True
-    # default = the single-block fused backward at T=1024; DWT_FA_NO_FUSED
-    # (flipped through the tuner's sanctioned setter) = the split dq and
-    # dk/dv kernels — together every Pallas attention kernel there is
-    for name, env in (("fused", {}), ("split", {"DWT_FA_NO_FUSED": "1"})):
-        with variant_env(env):
-            (_, out), grads = jax.jit(jax.value_and_grad(
-                fa_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    # one block each way (the default at T=1024) = the fused backward;
+    # blocks of half the sequence, a 2 x 2 grid = the split dq and dk/dv
+    # kernels — together every Pallas attention kernel there is
+    for name, blk in (("fused", t), ("split", t // 2)):
+        def fa_loss(q, k, v):
+            out = flash_attention(q, k, v, True, None, blk, blk)
+            return (out.astype(jnp.float32)
+                    * g.astype(jnp.float32)).sum(), out
+
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            fa_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         fwd = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref_out)))
         rel = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))
                      / jnp.max(jnp.abs(b)))
@@ -215,18 +215,21 @@ def phase_kernel(cfg, batch: int, attn_batch: int = 4):
 
     # the model's own train step, lowered (no compile): each attention
     # kernel is in it as a tpu_custom_call — `_use_pallas` did not send
-    # the model down the jnp reference
-    res = auto_accelerate(GPT(cfg), optimizer=optax.adamw(3e-4),
-                          strategy=[("fsdp", {})], seq_len=t,
-                          materialize=False)
-    bsh = res.batch_sharding_fn(2, None, 0)
-    ab = {name: jax.ShapeDtypeStruct((batch, t), jnp.int32, sharding=bsh)
-          for name in ("input_ids", "labels")}
+    # the model down the jnp reference.  At its own context the grid is
+    # one block each way (fused); at twice that context it is 2 x 2
+    # (split), the way a long-context model reaches those kernels
     counts = {}
-    for name, env in (("fused", {}), ("split", {"DWT_FA_NO_FUSED": "1"})):
-        with variant_env(env):
-            counts[name] = kernel_counts(
-                res.fused_train_step(1).lower(res.state, ab).as_text())
+    for name, seq in (("fused", t), ("split", 2 * t)):
+        res = auto_accelerate(
+            GPT(dataclasses.replace(cfg, block_size=seq)),
+            optimizer=optax.adamw(3e-4), strategy=[("fsdp", {})],
+            seq_len=seq, materialize=False)
+        bsh = res.batch_sharding_fn(2, None, 0)
+        ab = {key: jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                        sharding=bsh)
+              for key in ("input_ids", "labels")}
+        counts[name] = kernel_counts(
+            res.train_step.lower(res.state, ab).as_text())
     n = cfg.n_layer
     in_step = (counts["fused"]["dwt_fa_fwd"] >= n
                and counts["fused"]["dwt_fa_bwd_fused"] >= n

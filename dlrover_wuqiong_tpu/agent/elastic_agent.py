@@ -254,8 +254,8 @@ class ElasticAgent:
         # spans (restore tiers, rpc verbs) parent under this agent's trace
         from ..telemetry import spans as tspans
 
-        with tspans.env_context() as trace_env:
-            env.update(trace_env)
+        with tspans.env_context() as span_env:
+            env.update(span_env)
         env.setdefault("DWT_PROC_ROLE", "trainer")
         # one compile-cache dir across worker generations and warm
         # children: the restarted worker must read what the pool wrote

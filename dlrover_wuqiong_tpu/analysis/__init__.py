@@ -29,7 +29,7 @@ contract.  Six engines share one finding model + rule catalog
 CLI: ``python -m dlrover_wuqiong_tpu.analysis [--engine
 jaxpr|ast|protocol|concurrency|schema|hlo|all] [--format json|sarif]
 [--update-lock] [path...]`` — single-line JSON (or SARIF) summary on
-stdout (bench.py contract), file:line findings on stderr, exit 1 on
+stdout (one-line report contract), file:line findings on stderr, exit 1 on
 any non-warning finding.  This module and the
 ast/protocol/concurrency/schema engines import no jax so
 ``__graft_entry__.py`` can pre-flight them before any backend

@@ -2,7 +2,7 @@
 
 Parity: reference `dlrover/python/elastic_agent/diagnosis/
 diagnosis_agent.py:1` runs its checks inside the agent loop; here the
-same contract is a standalone gate shaped like bench.py: ONE JSON line
+same contract is a standalone gate: ONE JSON line
 on stdout (machine-readable for CI/driver), human findings on stderr,
 exit code 1 when any rule is violated.
 
@@ -42,7 +42,7 @@ def _default_paths() -> List[str]:
     pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     root = os.path.dirname(pkg)
     cand = [pkg] + [os.path.join(root, p)
-                    for p in ("tests", "examples", "tools", "bench.py",
+                    for p in ("tests", "examples", "tools",
                               "__graft_entry__.py")]
     return [p for p in cand if os.path.exists(p)]
 
@@ -197,7 +197,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         # same exit-code semantics so CI gates identically.
         print(json.dumps(to_sarif(findings)))
         return 1 if gating else 0
-    # bench.py contract: exactly one JSON line on stdout.  Schema
+    # report contract: exactly one JSON line on stdout.  Schema
     # evolution is ADD-ONLY (tests/test_analysis.py pins it); the
     # ``schema`` section only appears when the schema engine ran.
     record = {

@@ -5,11 +5,10 @@ operators (node_check.py:1, error_monitor.py:1 run AFTER a failure);
 redesign: the four costliest TPU bug classes in this codebase are visible
 in the source text, so they are enforced BEFORE a chip is touched:
 
-- ``env-at-trace``    — a ``DWT_*`` env read inside a function of a
-  compute-path module changes the emitted HLO at TRACE time; any such
-  toggle must be folded into the framework cache key
-  (auto/compile_cache.py:52 ``TRACE_ENV_VARS``), else two processes with
-  different values claim each other's warm entries (CLAUDE.md).
+- ``env-at-trace``    — an env read inside a function of a
+  compute-path module changes the emitted HLO at TRACE time behind an
+  unchanged Python call: the traced program is a function of its
+  arguments, so there is no such read, of any name (CLAUDE.md).
 - ``donated-reuse``   — ``train_step`` / ``apply_sparse_update`` DONATE
   their state inputs; code that reads the same variable after passing it
   in observes a dead buffer (CLAUDE.md: copy first in tests).
@@ -48,8 +47,8 @@ This module is import-light on purpose: NO jax, NO package siblings —
 initialization.  Suppressions: a line containing ``graftlint:
 disable=<checker>`` silences that checker for that line (the in-tree
 self-lint must pass with suppressions reserved for intentional,
-documented cases — e.g. bench.py's measured per-step driver, whose
-whole point is the per-step sync the rule exists to catch).
+documented cases — e.g. a measured per-step driver, whose whole point
+is the per-step sync the rule exists to catch).
 """
 
 from __future__ import annotations
@@ -57,7 +56,7 @@ from __future__ import annotations
 import ast
 import os
 import re
-from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from .findings import Finding
 
@@ -82,7 +81,6 @@ DONATING_CALLS: Dict[str, Tuple[Tuple[int, ...], Tuple[str, ...]]] = {
 
 _CITE_RE = re.compile(r"[\w/\.-]+\.(?:py|cc|h|proto|md):\d+|\bparity\b",
                       re.IGNORECASE)
-_ENV_PREFIX = "DWT_"
 
 # v2 suppression grammar lives in findings.py (shared with the protocol
 # engine); reason-less disables are themselves findings — see
@@ -102,65 +100,36 @@ def _dotted(node: ast.AST) -> Optional[str]:
     return None
 
 
-def _env_var_read(node: ast.Call) -> Optional[str]:
-    """The env-var name when `node` reads one via os.getenv / environ.get."""
-    func = node.func
-    name = func.attr if isinstance(func, ast.Attribute) else (
-        func.id if isinstance(func, ast.Name) else "")
-    if name == "getenv" or (
-            name == "get" and isinstance(func, ast.Attribute)
-            and _dotted(func.value) in ("os.environ", "environ")):
-        if node.args and isinstance(node.args[0], ast.Constant) \
-                and isinstance(node.args[0].value, str):
-            return node.args[0].value
-    return None
-
-
-def _env_var_subscript(node: ast.Subscript) -> Optional[str]:
-    if _dotted(node.value) in ("os.environ", "environ") and \
-            isinstance(node.slice, ast.Constant) and \
-            isinstance(node.slice.value, str):
-        return node.slice.value
-    return None
-
-
-def trace_env_key_vars(package_roots: Iterable[str]) -> Optional[Set[str]]:
-    """Parse TRACE_ENV_VARS out of auto/compile_cache.py (AST, no import).
-
-    Looks under each scanned root, then next to this file's own package —
-    so fixtures can ship their own key-builder and the in-repo scan always
-    finds the real one.
-    """
-    candidates = [os.path.join(r, "auto", "compile_cache.py")
-                  for r in package_roots]
-    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    candidates.append(os.path.join(here, "auto", "compile_cache.py"))
-    for path in candidates:
-        if not os.path.isfile(path):
-            continue
-        try:
-            tree = ast.parse(open(path).read())
-        except (OSError, SyntaxError):
-            continue
-        for node in tree.body:
-            if isinstance(node, ast.Assign) and any(
-                    isinstance(t, ast.Name) and t.id == "TRACE_ENV_VARS"
-                    for t in node.targets):
-                if isinstance(node.value, (ast.Tuple, ast.List, ast.Set)):
-                    return {e.value for e in node.value.elts
-                            if isinstance(e, ast.Constant)
-                            and isinstance(e.value, str)}
-    return None
+def _env_var_read(node: ast.AST) -> Optional[str]:
+    """The env-var name (or "<computed>") when `node` is os.getenv(...),
+    os.environ.get(...) or os.environ[...]; None for anything else."""
+    name = None
+    if isinstance(node, ast.Call):
+        func = node.func
+        fn = func.attr if isinstance(func, ast.Attribute) else (
+            func.id if isinstance(func, ast.Name) else "")
+        if (fn == "getenv" or (
+                fn == "get" and isinstance(func, ast.Attribute)
+                and _dotted(func.value) in ("os.environ", "environ"))) \
+                and node.args:
+            name = node.args[0]
+    elif isinstance(node, ast.Subscript) and \
+            _dotted(node.value) in ("os.environ", "environ"):
+        name = node.slice
+    if name is None:
+        return None
+    if isinstance(name, ast.Constant) and isinstance(name.value, str):
+        return name.value
+    return "<computed>"
 
 
 # --------------------------------------------------------- env-at-trace
 
 
 def check_env_at_trace(path: str, tree: ast.Module,
-                       source_lines: Sequence[str],
-                       key_vars: Set[str]) -> List[Finding]:
-    """DWT_* env reads inside functions of a compute-path module must be
-    in the compile-cache key set — they are trace-time HLO inputs."""
+                       source_lines: Sequence[str]) -> List[Finding]:
+    """No env read inside a function of a compute-path module: it would
+    be a trace-time HLO input that no argument, and so no key, shows."""
     posix = path.replace(os.sep, "/")
     parts = posix.split("/")
     in_compute = (any(d in parts[:-1] for d in COMPUTE_DIRS)
@@ -173,92 +142,22 @@ def check_env_at_trace(path: str, tree: ast.Module,
         for child in ast.iter_child_nodes(node):
             child_in_func = in_func or isinstance(
                 child, (ast.FunctionDef, ast.AsyncFunctionDef))
-            var = None
-            if isinstance(child, ast.Call):
-                var = _env_var_read(child)
-            elif isinstance(child, ast.Subscript):
-                var = _env_var_subscript(child)
-            if var and var.startswith(_ENV_PREFIX) and child_in_func \
-                    and var not in key_vars \
+            var = _env_var_read(child)
+            if var and child_in_func \
                     and not _suppressed(source_lines, child.lineno,
                                         "env-at-trace"):
                 findings.append(Finding(
                     "env-at-trace",
-                    f"{var} read inside a compute-path function but absent "
-                    f"from TRACE_ENV_VARS (auto/compile_cache.py) — two "
-                    f"processes with different values would share one "
-                    f"framework cache key over different HLO",
+                    f"{var} read from the environment inside a "
+                    f"compute-path function — the traced program would "
+                    f"change behind an unchanged call; decide from the "
+                    f"arguments (shapes, dtypes, config, mesh, backend)",
                     path, child.lineno,
-                    rule="trace-time env toggles must be in the compile "
-                         "cache key"))
+                    rule="the traced program is a function of its "
+                         "arguments"))
             visit(child, child_in_func)
 
     visit(tree, in_func=False)
-    return findings
-
-
-# ---------------------------------------------- env-flip-outside-tuner
-
-#: the ONLY files allowed to write TRACE_ENV_VARS names into os.environ —
-#: the variant autotuner's sanctioned writer (_set_trace_env /
-#: variant_env / apply_variant own save-restore and the compile-cache
-#: re-key discipline).
-TUNER_FILES = ("auto/tuner.py",)
-
-
-def check_env_flip_outside_tuner(path: str, tree: ast.Module,
-                                 source_lines: Sequence[str],
-                                 key_vars: Set[str]) -> List[Finding]:
-    """Raw os.environ WRITES of TRACE_ENV_VARS names outside the tuner.
-
-    A DWT_FA_* value is part of the executable identity (it rides the
-    compile-cache key and the perf-observatory executable key): a raw
-    ``os.environ[...] = ...`` / ``.pop`` / ``.setdefault`` / ``del``
-    outside auto/tuner.py flips the trace env without the save-restore,
-    validation and re-key bookkeeping the sanctioned writer provides —
-    the fused cache and warm pool then disagree with the process env.
-    Route every flip through ``variant_env`` (scoped) or
-    ``apply_variant`` (cutover).  Tests are exempt (they pin behavior
-    under both values).
-    """
-    posix = path.replace(os.sep, "/")
-    parts = posix.split("/")
-    if "tests" in parts or parts[-1].startswith("test_"):
-        return []
-    if any(posix.endswith(f) for f in TUNER_FILES):
-        return []
-    if not key_vars:
-        return []
-    findings: List[Finding] = []
-    for node in ast.walk(tree):
-        var, how = None, ""
-        if isinstance(node, ast.Subscript) and \
-                isinstance(node.ctx, (ast.Store, ast.Del)):
-            v = _env_var_subscript(node)
-            if v in key_vars:
-                var = v
-                how = ("del os.environ[...]"
-                       if isinstance(node.ctx, ast.Del)
-                       else "os.environ[...] = ...")
-        elif isinstance(node, ast.Call):
-            func = node.func
-            if isinstance(func, ast.Attribute) and \
-                    _dotted(func.value) in ("os.environ", "environ") and \
-                    func.attr in ("pop", "setdefault"):
-                if node.args and isinstance(node.args[0], ast.Constant) \
-                        and node.args[0].value in key_vars:
-                    var = node.args[0].value
-                    how = f"os.environ.{func.attr}(...)"
-        if var and not _suppressed(source_lines, node.lineno,
-                                   "env-flip-outside-tuner"):
-            findings.append(Finding(
-                "env-flip-outside-tuner",
-                f"{how} writes trace-time toggle {var} outside the "
-                f"variant autotuner — raw flips skip save-restore and "
-                f"the compile-cache re-key; use auto/tuner.py "
-                f"variant_env (scoped) or apply_variant (cutover)",
-                path, node.lineno,
-                rule="the tuner owns TRACE_ENV_VARS writes"))
     return findings
 
 
@@ -745,7 +644,7 @@ def check_docstring_citation(path: str, tree: ast.Module,
     """Package modules with code must cite their reference (`file:line`).
 
     Scoped to files living inside a python package (a dir with
-    __init__.py) — bench.py / tools/ scripts document themselves freely.
+    __init__.py) — tools/ scripts document themselves freely.
     """
     parts = path.replace(os.sep, "/").split("/")
     if parts[-1] == "__init__.py" or "tests" in parts:
@@ -854,16 +753,12 @@ def iter_python_files(paths: Sequence[str]) -> List[str]:
 
 
 def run_paths(paths: Sequence[str],
-              checkers: Optional[Sequence[str]] = None,
-              key_vars: Optional[Set[str]] = None
+              checkers: Optional[Sequence[str]] = None
               ) -> Tuple[List[Finding], int]:
     """Run the AST engine over files/dirs; returns (findings, files_scanned).
 
-    `checkers` filters by name; `key_vars` overrides the TRACE_ENV_VARS
-    set (parsed from auto/compile_cache.py when None).
+    `checkers` filters by name.
     """
-    if key_vars is None:
-        key_vars = trace_env_key_vars(paths) or set()
     files = iter_python_files(paths)
     findings: List[Finding] = []
     for path in files:
@@ -876,10 +771,7 @@ def run_paths(paths: Sequence[str],
         lines = source.splitlines()
         rel = os.path.relpath(path)
         if not checkers or "env-at-trace" in checkers:
-            findings.extend(check_env_at_trace(rel, tree, lines, key_vars))
-        if not checkers or "env-flip-outside-tuner" in checkers:
-            findings.extend(check_env_flip_outside_tuner(
-                rel, tree, lines, key_vars))
+            findings.extend(check_env_at_trace(rel, tree, lines))
         if not checkers or "donated-reuse" in checkers:
             findings.extend(check_donated_reuse(rel, tree, lines))
         if not checkers or "blocking-readback" in checkers:

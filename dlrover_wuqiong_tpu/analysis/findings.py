@@ -132,16 +132,9 @@ RULE_CATALOG: Dict[str, Dict[str, str]] = {
     # ---- ast engine (intra-file pattern rules, jax-free)
     "env-at-trace": {
         "engine": "ast", "severity": "error",
-        "rationale": "os.getenv of a trace-time toggle (DWT_FA_*) inside "
-                     "jitted code bakes one process's env into shared HLO; "
-                     "read toggles at module scope and close over them",
-    },
-    "env-flip-outside-tuner": {
-        "engine": "ast", "severity": "error",
-        "rationale": "raw os.environ writes of TRACE_ENV_VARS names skip "
-                     "the tuner's save-restore and compile-cache re-key — "
-                     "flip variants only through auto/tuner.py "
-                     "variant_env/apply_variant",
+        "rationale": "an env read inside a compute-path function changes "
+                     "the HLO behind an unchanged call; the traced program "
+                     "is a function of its arguments — no name is exempt",
     },
     "donated-reuse": {
         "engine": "ast", "severity": "error",
@@ -164,12 +157,6 @@ RULE_CATALOG: Dict[str, Dict[str, str]] = {
         "engine": "ast", "severity": "error",
         "rationale": "fork from a JAX-initialized process deadlocks XLA "
                      "runtime threads; spawn, never fork",
-    },
-    "cache-key-env": {
-        "engine": "ast", "severity": "error",
-        "rationale": "a framework cache key over a jitted step must fold in "
-                     "the trace-time env toggles or warm entries are claimed "
-                     "for HLO the XLA layer then misses",
     },
     "unverified-restore": {
         "engine": "ast", "severity": "error",

@@ -24,7 +24,7 @@ preserved inside lists):
   `schema-field-no-sentinel`.
 - ``registries``: the ADD-ONLY tuples (`LEDGER_STATES`,
   `SERVE_STATES`/`SERVE_COUNTERS`, `PERF_SNAPSHOT_KEYS`/
-  `PERF_EVENT_KEYS`, `TIMELINE_EVENT_KEYS`, `TRACE_ENV_VARS`).
+  `PERF_EVENT_KEYS`, `TIMELINE_EVENT_KEYS`).
 - ``verbs``: the protocol engine's JOURNALED/IDEM sets plus the client
   verb classes recovered from `_call_buffered`/`_call_polling` call
   sites (`agent/master_client.py`).
@@ -71,7 +71,6 @@ REGISTRY_SPECS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("telemetry/serving.py", ("SERVE_STATES", "SERVE_COUNTERS")),
     ("telemetry/perf.py", ("PERF_SNAPSHOT_KEYS", "PERF_EVENT_KEYS")),
     ("telemetry/timeline.py", ("TIMELINE_EVENT_KEYS",)),
-    ("auto/compile_cache.py", ("TRACE_ENV_VARS",)),
 )
 
 #: where the journaled/idem verb-class sets live (set literals).

@@ -38,7 +38,6 @@ from .compile_cache import (
     note_train_step_served,
     train_step_cache_key,
 )
-from .tuner import env_signature as _tuner_env_signature
 from ..telemetry import spans as tspans
 from ..parallel.sharding import ShardingPlanner, activation_spec
 from ..trainer.train_step import (
@@ -321,51 +320,33 @@ class AccelerateResult:
     fused_steps: int = 1
     _fused_factory: Any = None   # k -> jitted fused step (None: local_sgd)
     _fused_key_fn: Any = None    # k -> framework cache key
-    _fused_cache: Dict[tuple, Callable] = dataclasses.field(
+    _fused_cache: Dict[int, Callable] = dataclasses.field(
         default_factory=dict)
     _cache_dir: Optional[str] = None
-    # trace-env values (TRACE_ENV_VARS order) the build-time `train_step`
-    # was traced under: the jit cache keys on function+signature, NOT on
-    # env, so a DWT_FA_* flip would silently reuse the old trace — the
-    # fused cache folds the CURRENT signature and rebuilds through the
-    # factory on mismatch (the CLAUDE.md "framework cache key must fold
-    # trace-time env toggles" rule, applied in-process)
-    _build_env_sig: Any = None
 
     def fused_train_step(self, fused_steps: int) -> Callable:
         """The K-step fused driver `step(state, batches)` for this build.
 
         `batches` leaves carry a leading fused axis of size K (stack K
         per-step batches with `data.elastic_dataset.stack_batches`, place
-        with `place_fused_batch`).  Built lazily and cached per
-        (K, trace-env): each K is a distinct compile, and so is each
-        trace-env variant (DWT_FA_* layout, DWT_FP8_DENSE quant,
-        DWT_REMAT_POLICY) — the toggles are read at TRACE time, so a
-        variant cutover (auto/tuner.py) MUST retrace through the factory
-        rather than reuse a jit entry traced under the old env (K and the
-        env values both change the HLO — auto/compile_cache.py)."""
+        with `place_fused_batch`).  Built lazily and cached per K: each K
+        is a distinct compile (K changes the HLO — auto/compile_cache.py);
+        K = 1 IS `train_step`."""
         k = int(fused_steps)
-        env_sig = _tuner_env_signature()
-        if k <= 1 and (self._build_env_sig is None
-                       or env_sig == self._build_env_sig):
+        if k <= 1:
             return self.train_step
         if self._fused_factory is None:
-            if k <= 1:
-                return self.train_step  # local_sgd: no variant rebuilds
             raise ValueError(
                 "fused_steps > 1 does not compose with local_sgd — the "
                 "DiLoCo step's outer sync counts dispatches, and a K-step "
                 "fusion would scan across sync boundaries; run unfused "
                 "(fused_steps=1)")
-        cache_key = (max(k, 1), env_sig)
-        fn = self._fused_cache.get(cache_key)
+        fn = self._fused_cache.get(k)
         if fn is None:
-            fn = self._fused_factory(max(k, 1))
-            self._fused_cache[cache_key] = fn
+            fn = self._fused_factory(k)
+            self._fused_cache[k] = fn
             if self._fused_key_fn is not None:
-                # _key_for reads TRACE_ENV_VARS at call time: the
-                # registered framework key already carries this variant
-                key = self._fused_key_fn(max(k, 1))
+                key = self._fused_key_fn(k)
                 note_train_step_served(
                     self._cache_dir, key,
                     meta={"mesh": self.strategy.plan.describe(),
@@ -810,8 +791,7 @@ def auto_accelerate(
         cache_key=cache_key, cache_warm=cache_warm,
         strategy_spec=strategy_spec,
         fused_steps=fused_steps, _fused_factory=_step_factory,
-        _fused_key_fn=_key_for, _cache_dir=cache_dir,
-        _build_env_sig=_tuner_env_signature())
+        _fused_key_fn=_key_for, _cache_dir=cache_dir)
 
 
 def _jsonable_strategy(strategy: Optional[Sequence],

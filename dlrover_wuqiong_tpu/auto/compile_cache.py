@@ -19,17 +19,14 @@ Two layers, deliberately separate:
 
 - The framework layer is `train_step_cache_key`: a stable digest of
   everything the *trace* depends on — mesh axis sizes, the resolved
-  strategy context, the final (post-override) model config, donation,
-  and the trace-time env toggles (`TRACE_ENV_VARS` — DWT_FA_* pick
-  kernel paths at trace time, CLAUDE.md).  XLA's own key cannot be
-  computed without tracing; this one can, so the warm pool
-  (auto/warm_pool.py) and the master's scale planner can reason about
-  "is this mesh already compiled?" before any worker exists.
-
-Key gotcha captured here once: env toggles that select kernel paths are
-read at TRACE time, so two processes with different DWT_FA_* values
-produce different HLO under the SAME python call — any framework key
-that omits them would claim a warm entry the XLA layer then misses.
+  strategy context, the final (post-override) model config, donation
+  and the fused-step count.  The traced program is a function of those
+  arguments alone (nothing under ops/, models/, parallel/ reads the
+  environment inside a function: graftlint env-at-trace), so the key
+  needs nothing else.  XLA's own key cannot be computed without
+  tracing; this one can, so the warm pool (auto/warm_pool.py) and the
+  master's scale planner can reason about "is this mesh already
+  compiled?" before any worker exists.
 """
 
 from __future__ import annotations
@@ -46,24 +43,6 @@ from typing import Any, Dict, Optional, Tuple
 from ..common.log import get_logger
 
 logger = get_logger("compile_cache")
-
-# trace-time env toggles that change the emitted HLO (kernel path picks,
-# CLAUDE.md): part of the framework cache key, and forwarded verbatim to
-# warm-pool children so speculative compiles match the worker's trace.
-# DWT_FA_PACK picks the flash-attention sublane pack width at trace time
-# (ops/flash_attention.py:225) — found missing by graftlint's env-at-trace
-# checker; the analysis/ self-lint keeps this tuple honest from here on.
-# DWT_FP8_DENSE routes the name-filtered dense projections through the
-# fp8 matmul (ops/quantization.py fp8_dense_override — numerics-changing,
-# tuner-gated behind TrainingArgs.tune_numerics) and DWT_REMAT_POLICY
-# overrides the model's remat policy (ops/remat.py trace_remat_policy);
-# both are read at TRACE time inside the model body, so registering them
-# here is what makes every fp8/remat variant a distinct compile-cache
-# key.  This tuple must stay a literal: graftlint parses it by AST
-# (analysis/ast_engine.py trace_env_key_vars) to source the protected
-# name set for env-flip-outside-tuner and env-at-trace.
-TRACE_ENV_VARS = ("DWT_FA_NO_FUSED", "DWT_FA_PACK", "DWT_FA_STREAMED",
-                  "DWT_FP8_DENSE", "DWT_REMAT_POLICY")
 
 # one registry sidecar + one pool directory per cache dir
 _REGISTRY_SUBDIR = "framework-keys"
@@ -281,7 +260,7 @@ def train_step_cache_key(plan_sizes: Dict[str, int],
     """Digest of everything the train-step trace depends on.
 
     Same config → same key; changed mesh shape, strategy, model config,
-    donation, fused-step count K, or a TRACE_ENV_VARS toggle → different
+    donation or fused-step count K → different
     key (tests/test_warm_pool.py pins the invalidation matrix).
     `fused_steps` changes the HLO (the K-step scan wraps the whole step,
     trainer/train_step.py) so K=1 and K=8 are distinct compiles.
@@ -295,7 +274,6 @@ def train_step_cache_key(plan_sizes: Dict[str, int],
         "donate": bool(donate),
         "accum": int(accum_steps),
         "fused": int(fused_steps),
-        "env": {k: os.getenv(k, "") for k in TRACE_ENV_VARS},
         "backend": backend or jax.default_backend(),
         "jax": jax.__version__,
     }

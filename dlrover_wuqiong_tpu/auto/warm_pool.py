@@ -54,7 +54,6 @@ from typing import Any, Dict, List, Optional
 from ..common.log import get_logger
 from .compile_cache import (
     CACHE_DIR_ENV,
-    TRACE_ENV_VARS,
     pool_dir,
     resolve_cache_dir,
 )
@@ -91,14 +90,6 @@ class WarmSpec:
     # quant are all in the serving compile-cache key, so a replacement
     # decode worker after `chaos serve-drain` finds its programs warm).
     serve: Optional[Dict] = None
-    # ADD-ONLY: trace-time env toggles (TRACE_ENV_VARS names only) the
-    # child applies — through the tuner's sanctioned setter — before its
-    # first trace.  The toggles change the emitted HLO, so a variant
-    # candidate (auto/tuner.py) is a DIFFERENT compile from the default:
-    # carrying them in the spec makes spec_key/dedup variant-aware and
-    # lets the autotuner pre-warm every candidate before cutover.  None
-    # means "inherit the parent's env" (the pre-tuner behavior).
-    trace_env: Optional[Dict] = None
 
     def to_json(self) -> str:
         return json.dumps(dataclasses.asdict(self), sort_keys=True)
@@ -306,19 +297,7 @@ class WarmPool:
         env = dict(os.environ)
         env[CACHE_DIR_ENV] = self.cache_dir
         # the child re-derives platform/XLA_FLAGS from the spec before
-        # touching the backend; trace-time toggles must match the worker
-        for var in TRACE_ENV_VARS:
-            if os.getenv(var):
-                env[var] = os.environ[var]
-        if getattr(spec, "trace_env", None) is not None:
-            # spec-pinned variant: the spec's view wins over inheritance
-            # (an empty-string value means "unset" — tuner semantics)
-            for var in TRACE_ENV_VARS:
-                val = spec.trace_env.get(var, "")
-                if val:
-                    env[var] = str(val)
-                else:
-                    env.pop(var, None)
+        # touching the backend
         pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         pythonpath = env.get("PYTHONPATH", "")
@@ -471,14 +450,6 @@ def _child_main(spec_path: str) -> int:
     """
     with open(spec_path) as f:
         spec = WarmSpec.from_json(f.read())
-    if getattr(spec, "trace_env", None):
-        # variant candidate: apply the spec's trace toggles through the
-        # tuner's sanctioned setter BEFORE the backend/first trace — the
-        # toggles are read at trace time and pick kernel paths
-        from .tuner import apply_variant
-
-        apply_variant({k: str(v) for k, v in spec.trace_env.items()
-                       if k in TRACE_ENV_VARS})
     if not can_warm(spec.platform):
         raise RuntimeError(
             f"warm child refused for platform {spec.platform!r}: the "
@@ -591,7 +562,6 @@ def _child_main(spec_path: str) -> int:
             "fused_steps": fused,
             "compile_s": round(time.monotonic() - t0, 2),
             "already_cached": (h1 - h0) > 0 and (m1 - m0) == 0,
-            "trace_env": dict(getattr(spec, "trace_env", None) or {}),
             "ready": True,
             "ts": time.time(),
         }

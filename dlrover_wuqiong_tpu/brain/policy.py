@@ -341,52 +341,3 @@ class PolicyEngine:
         "before" side; the "after" fills on the next observe_perf."""
         self._perf_before = self._last_perf
         self._perf_after = None
-
-
-# ------------------------------------------------------------ tuner bridge
-
-
-def tuner_decision_effects(decisions: List[Dict]) -> List[Dict]:
-    """PolicyDecision-style history rows for variant-autotuner cutovers.
-
-    The autotuner (auto/tuner.py) measures its own before/after — the
-    interleaved perf-window medians of the incumbent and the winner — so
-    unlike a master-side decision its effect needs no ``observe_perf``
-    round trip: each row embeds an ``effect`` shaped exactly like
-    ``PolicyEngine.decision_effect()`` output ({decision_id, before,
-    after}) and lands in the trainer's ``policy_applied`` log next to the
-    master's rows, so post-mortem tooling reads one history (rows with
-    ``kind == "tuner"`` are local decisions, journal-free by design: the
-    winner is durable in tuning.json, not in the master journal).
-
-    Loss-divergence REVERTS ride the same bridge with ``kind ==
-    "tuner-revert"`` (the tuner's kind passes through): their rows carry
-    the disqualified variant (``reverted``) and the measured
-    loss-vs-reference evidence, so an fp8 candidate thrown out of the
-    search is auditable in the same history as the eventual winner.
-    """
-    out: List[Dict] = []
-    for d in decisions:
-        did = str(d.get("decision_id", ""))
-        row = {
-            "decision_id": did,
-            "kind": str(d.get("kind") or "tuner"),
-            "variant": str(d.get("variant", "")),
-            "env": dict(d.get("env") or {}),
-            "fused_steps": int(d.get("fused_steps") or 0),
-            "windows": int(d.get("windows") or 0),
-            "effect": {
-                "decision_id": did,
-                "before": dict(d.get("before") or {}),
-                "after": dict(d.get("after") or {}),
-            },
-        }
-        if d.get("shape_class"):
-            row["shape_class"] = str(d["shape_class"])
-        if d.get("reverted"):  # divergence-guard evidence
-            row["reverted"] = str(d["reverted"])
-            for k in ("loss", "loss_ref", "loss_bound"):
-                if k in d:
-                    row[k] = float(d[k])
-        out.append(row)
-    return out

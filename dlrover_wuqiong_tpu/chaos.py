@@ -3216,14 +3216,14 @@ def perf_regress() -> Dict:
        sits in the collective category fires `perf-regression` after
        EXACTLY M consecutive beyond-bound windows, once per excursion,
        and attributes the moved category.
-    3. KEY ISOLATION: flipping a TRACE_ENV_VARS toggle (through the
-       tuner's sanctioned `variant_env`) changes the executable key
-       (a different executable is a new baseline, never a false
-       regression), and the published store survives an atomic write +
-       reload round-trip with identical stats.
-    4. TUNER CUTOVER: after a variant cutover the sentinel judges the
-       new key against its OWN fresh baseline — step times that fired
-       under the old key never fire post-cutover.
+    3. KEY ISOLATION: another argument of `executable_key` (the fused
+       width K) changes the executable key (a different executable is
+       a new baseline, never a false regression), and the published
+       store survives an atomic write + reload round-trip with
+       identical stats.
+    4. CUTOVER: after a K cutover the sentinel judges the new key
+       against its OWN fresh baseline — step times that fired under
+       the old key never fire post-cutover.
     """
     import random
     import shutil
@@ -3262,22 +3262,13 @@ def perf_regress() -> Dict:
             e = window(0.16, 0.56)
             if e is not None:
                 fired.append((i + 1, e))
-        # 3) key isolation across a trace-env flip + store round-trip —
-        #    flipped through the tuner's sanctioned scoped writer
-        #    (auto/tuner.py; graftlint env-flip-outside-tuner forbids
-        #    raw os.environ writes of TRACE_ENV_VARS names).  The flip
-        #    exercises the ISSUE-16 quant axis (DWT_FP8_DENSE) — the
-        #    numerics-changing variant must re-key exactly like the
-        #    layout-neutral DWT_FA_* toggles
-        from .auto.tuner import variant_env
-
-        with variant_env({"DWT_FP8_DENSE": "1"}):
-            flipped = executable_key("drill-fingerprint", 8, "cpu")
-        # 4) tuner cutover: the flipped variant is a NEW executable key,
-        #    so its windows land on a FRESH baseline — step times that
-        #    would be deep beyond-bound under the OLD key (the throttled
-        #    phase already fired on them) must never fire the sentinel
-        #    after a cutover
+        # 3) key isolation across a fused-K change + store round-trip
+        flipped = executable_key("drill-fingerprint", 4, "cpu")
+        # 4) cutover: the other width is a NEW executable key, so its
+        #    windows land on a FRESH baseline — step times that would be
+        #    deep beyond-bound under the OLD key (the throttled phase
+        #    already fired on them) must never fire the sentinel after a
+        #    cutover
         cutover_events = []
         n_cut = 0
         for i in range(4 * m_consec):
@@ -3299,7 +3290,7 @@ def perf_regress() -> Dict:
             fired_total=len(fired),
             fired_kind=fired[0][1]["kind"] if fired else "",
             attributed_category=fired[0][1]["category"] if fired else "",
-            key_changed_on_env_flip=flipped != key,
+            key_changed_on_k_change=flipped != key,
             cutover_windows=4 * m_consec,
             cutover_fired=len(cutover_events),
             cutover_baseline_n=int((store.stats(flipped) or
@@ -3313,7 +3304,7 @@ def perf_regress() -> Dict:
             and fired[0][0] == m_consec
             and fired[0][1]["kind"] == "perf-regression"
             and fired[0][1]["category"] == "collective"
-            and report["key_changed_on_env_flip"]
+            and report["key_changed_on_k_change"]
             and not cutover_events
             and report["cutover_baseline_n"] > 0
             and report["baseline_roundtrip"])

@@ -3,15 +3,14 @@
 Parity: no single reference counterpart — the reference leans on
 `torch.cuda.synchronize()`.  Here every timing or liveness probe funnels
 through these helpers: `sync_tree` (one-dispatch whole-tree host
-readback — a readback is a correct sync on any backend; bench.py and
-the checkpoint timers), `measure_h2d_gbps` (the resolve-time host-link
+readback — a readback is a correct sync on any backend; the
+checkpoint timers), `measure_h2d_gbps` (the resolve-time host-link
 probe behind auto/accelerate.py's offload warnings),
 `measure_dispatch_overhead_s` (the fixed cost of one jit dispatch, which
 sizes the fused K-step driver) and `is_oom_error` (typed
-RESOURCE_EXHAUSTED detection shared by bench.py fallbacks and
-auto/engine.py candidate scoring).  What the probes read on a given
-machine is printed by `chip_smoke.py`'s train phase; no number is
-quoted here.
+RESOURCE_EXHAUSTED detection for auto/engine.py candidate scoring).
+What the probes read on a given machine is printed by
+`chip_smoke.py`'s train phase; no number is quoted here.
 """
 
 from __future__ import annotations
@@ -36,8 +35,8 @@ def retry_call(fn: Callable[[], Any], *,
     Parity: reference `dlrover/python/common/grpc.py` `retry_grpc_request`
     decorator — generalized so every control-plane touch (RpcClient,
     MasterClient degraded-mode probes, kv_store_wait polling,
-    multi_process IPC dials, checkpoint replica fetches, bench.py backend
-    init) shares ONE policy instead of five hand-rolled loops.
+    multi_process IPC dials, checkpoint replica fetches) shares ONE
+    policy instead of five hand-rolled loops.
 
     `fn` is called with no arguments.  A raised exception that is an
     instance of `retry_on` is retried until either `attempts` total calls
@@ -53,7 +52,7 @@ def retry_call(fn: Callable[[], Any], *,
     synchronizing into retry storms.  The delay is additionally clipped
     to the remaining deadline.  `on_retry(n_retries, exc, delay_s)` fires
     before each sleep — callers use it for logging and for tearing down
-    poisoned state (bench.py drops the dead backend client there).
+    poisoned state.
 
     `label` (e.g. the rpc verb) opens a ``retry:<label>`` trace span
     covering the whole bounded loop, with the retry count in its attrs
@@ -123,7 +122,7 @@ def sync_tree(tree: Any) -> float:
     dispatch cost hundreds of times and inflate the metric the caller is
     measuring).  The first call per tree structure
     compiles — callers timing a window should warm the helper on a
-    same-structure tree first (bench.py does).
+    same-structure tree first.
 
     Returns the (meaningless) sum so callers can assert it is finite if
     they want an extra liveness check.

@@ -20,7 +20,7 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
-from ..ops.quantization import Fp8Einsum, fp8_dense_override
+from ..ops.quantization import Fp8Einsum
 
 
 class Fp8Dense(nn.Module):
@@ -46,21 +46,9 @@ class Fp8Dense(nn.Module):
 
 
 def fp8_selected(cfg, name: str) -> bool:
-    """Module filter: does this projection fall under the fp8 strategy?
-
-    The trace-time DWT_FP8_DENSE toggle (ops/quantization.py
-    fp8_dense_override, a TRACE_ENV_VARS name flipped only by the variant
-    autotuner) overrides the config flag; the name filter always applies,
-    so a forced-on variant quantizes exactly the projections the
-    ("amp", {"fp8": True}) strategy would.  Parameter names/shapes are
-    identical either way — a tuner cutover swaps executables, never
-    state.
-    """
+    """Module filter: does this projection fall under the fp8 strategy?"""
     flt: Tuple[str, ...] = getattr(cfg, "fp8_filter", ())
-    on = fp8_dense_override()
-    if on is None:
-        on = bool(getattr(cfg, "fp8", False))
-    return on and any(p in name for p in flt)
+    return bool(getattr(cfg, "fp8", False)) and any(p in name for p in flt)
 
 
 def dense(cfg, features: int, name: str, use_bias: bool = True):

@@ -182,7 +182,7 @@ class GPT(nn.Module):
         x = tok + pos
         block = Block
         if cfg.remat:
-            from ..ops.remat import resolve_remat_policy, trace_remat_policy
+            from ..ops.remat import resolve_remat_policy
 
             # prevent_cse=True: the layers run in a python loop (not
             # scan), and without the CSE barrier XLA merges the
@@ -191,12 +191,10 @@ class GPT(nn.Module):
             # AND activation temps with remat on/off)
             from ..ops.remat import MODEL_CHECKPOINT_NAMES
 
-            # trace_remat_policy: DWT_REMAT_POLICY (tuner-owned trace
-            # toggle) overrides the config policy at trace time
             block = nn.remat(
                 Block, prevent_cse=True,
                 policy=resolve_remat_policy(
-                    trace_remat_policy(cfg.remat_policy),
+                    cfg.remat_policy,
                     cfg.remat_names or MODEL_CHECKPOINT_NAMES))
         for i in range(cfg.n_layer):
             x = block(cfg, name=f"h_{i}")(x, deterministic)

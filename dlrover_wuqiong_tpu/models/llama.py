@@ -222,18 +222,16 @@ class Llama(nn.Module):
         cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
         block = LlamaBlock
         if cfg.remat:
-            from ..ops.remat import resolve_remat_policy, trace_remat_policy
+            from ..ops.remat import resolve_remat_policy
 
             # prevent_cse=True — see models/gpt.py: python-loop layers
             # need the CSE barrier or XLA undoes the remat
             from ..ops.remat import MODEL_CHECKPOINT_NAMES
 
-            # trace_remat_policy: DWT_REMAT_POLICY (tuner-owned trace
-            # toggle) overrides the config policy at trace time
             block = nn.remat(
                 LlamaBlock, prevent_cse=True, static_argnums=(),
                 policy=resolve_remat_policy(
-                    trace_remat_policy(cfg.remat_policy),
+                    cfg.remat_policy,
                     cfg.remat_names or MODEL_CHECKPOINT_NAMES))
         for i in range(cfg.num_layers):
             x = block(cfg, name=f"layers_{i}")(x, cos, sin)

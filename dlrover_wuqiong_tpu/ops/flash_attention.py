@@ -48,7 +48,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
@@ -506,22 +505,10 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 def _fit_pack(bh: int) -> int:
     """Heads packed per grid step: largest of 8/4/2/1 dividing bh.
 
-    DWT_FA_PACK overrides the preference order's head (sweep hook).  The
-    override is clamped to 8: kernel VMEM scratch scales linearly with
-    pack against the fixed 100MB vmem_limit, and an oversized value would
-    fail at Mosaic compile time with an opaque error (ADVICE r4)."""
-    import os
-
-    try:
-        pref = int(os.getenv("DWT_FA_PACK", "8"))
-    except ValueError:  # empty/garbage env value: fall back, don't abort
-        pref = 8
-    if pref > 8:
-        logger.warning("DWT_FA_PACK=%d exceeds the VMEM-safe maximum of 8 "
-                       "— clamping", pref)
-        pref = 8
-    for p in (pref, 8, 4, 2):
-        if p >= 1 and bh % p == 0:
+    8 is the ceiling: kernel VMEM scratch scales linearly with pack
+    against the fixed 100MB vmem_limit (ADVICE r4)."""
+    for p in (8, 4, 2):
+        if bh % p == 0:
             return p
     return 1
 
@@ -855,7 +842,7 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     rowspec = pl.BlockSpec((pack, 1, block_q), lambda b, i, j: (b, 0, i))
     ops = [q, k, v, do, lse, delta]
 
-    if num_q == 1 and num_kv == 1 and not os.getenv("DWT_FA_NO_FUSED"):
+    if num_q == 1 and num_kv == 1:
         bspec_q = pl.BlockSpec((pack, block_q, d), lambda b: (b, 0, 0))
         bspec_k = pl.BlockSpec((pack, block_k, d), lambda b: (b, 0, 0))
         bspec_row = pl.BlockSpec((pack, 1, block_q), lambda b: (b, 0, 0))
@@ -1010,11 +997,8 @@ def _use_streamed(sq, sk) -> bool:
     memory on big shapes: the 8B AOT fit proof (tests/test_scale_8b.py)
     compiles on a virtual CPU mesh, where dense attention would dominate
     `memory_analysis()` with buffers the Pallas path never allocates.
-    DWT_FA_STREAMED=1/0 forces the choice; the default switches at the
-    point where a per-head score matrix reaches 2048^2 (16MB f32)."""
-    env = os.getenv("DWT_FA_STREAMED")
-    if env is not None:
-        return env == "1"
+    The switch is the point where a per-head score matrix reaches
+    2048^2 (16MB f32)."""
     return sq * sk >= 2048 * 2048
 
 

@@ -19,7 +19,6 @@ TPU redesign:
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -28,27 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BLOCK = 256
-
-
-def fp8_dense_override() -> Optional[bool]:
-    """Trace-time fp8-dense toggle (DWT_FP8_DENSE — a TRACE_ENV_VARS name).
-
-    "1" forces the name-filtered dense projections onto the fp8 matmul
-    path, "0" forces them off, unset/"" defers to the model config's
-    `fp8` flag.  Read at TRACE time inside the model's `dense()` factory
-    (models/fp8.py), so the value is part of the emitted HLO and rides
-    every framework cache key (auto/compile_cache.py TRACE_ENV_VARS) —
-    which is what lets the variant autotuner A/B fp8 against bf16 as a
-    warm-pooled cutover instead of a model rebuild.  Only the tuner's
-    sanctioned writers may flip it (graftlint env-flip-outside-tuner);
-    fp8 changes the loss trajectory, so the trainer gates this axis
-    behind the explicit `tune_numerics` opt-in plus a loss-divergence
-    guard.
-    """
-    value = os.environ.get("DWT_FP8_DENSE", "")
-    if value == "":
-        return None
-    return value == "1"
 
 
 def _on_tpu() -> bool:

@@ -25,30 +25,12 @@ their attention/MLP block outputs ("attn_out", "mlp_out").
 
 from __future__ import annotations
 
-import os
 from typing import Optional, Sequence
 
 import jax
 
 #: annotation names the in-tree models emit (models/gpt.py Block)
 MODEL_CHECKPOINT_NAMES = ("attn_out", "mlp_out")
-
-
-def trace_remat_policy(default: Optional[str]) -> Optional[str]:
-    """Trace-time remat-policy override (DWT_REMAT_POLICY, TRACE_ENV_VARS).
-
-    Unset/"" defers to the config policy; any other value replaces it,
-    validated by `resolve_remat_policy` (unknown names raise at trace
-    time, before any step runs).  The models read this inside their
-    `nn.remat` wrapping, so the value changes the emitted HLO and rides
-    every framework cache key (auto/compile_cache.py) — the variant
-    autotuner searches the policy ladder as warm-pooled cutovers without
-    a model rebuild.  Remat is numerically neutral (same math, different
-    save/recompute split), so unlike DWT_FP8_DENSE this axis needs no
-    numerics opt-in.  Only the tuner's sanctioned writers flip it
-    (graftlint env-flip-outside-tuner).
-    """
-    return os.environ.get("DWT_REMAT_POLICY", "") or default
 
 
 def resolve_remat_policy(policy: Optional[str],

@@ -29,7 +29,7 @@ independent, which makes a request's tokens a pure function of
 (weights, prompt, seed) — independent of batch composition and slot
 churn (the equivalence invariant tests/test_serving.py pins).
 
-The engine's ``cache_key`` folds spec + model + quant + TRACE_ENV_VARS
+The engine's ``cache_key`` folds spec + model + quant
 into the framework compile-cache registry (auto/compile_cache.py), and
 `auto/warm_pool.py` accepts a ``serve`` WarmSpec field to AOT-compile
 these programs ahead of a cutover.
@@ -49,7 +49,6 @@ import numpy as np
 
 from ..auto.compile_cache import (
     CACHE_DIR_ENV,
-    TRACE_ENV_VARS,
     canonicalize,
     note_train_step_served,
 )
@@ -86,14 +85,11 @@ class ServeSpec:
 def serve_step_cache_key(model_config: Any, spec: ServeSpec,
                          backend: Optional[str] = None) -> str:
     """Digest of everything the serving trace depends on (the serving
-    counterpart of auto/compile_cache.train_step_cache_key — same
-    TRACE_ENV_VARS rule: two processes with different DWT_FA_* values
-    emit different HLO from the same python call)."""
+    counterpart of auto/compile_cache.train_step_cache_key)."""
     payload = {
         "kind": "serve",
         "model": canonicalize(model_config),
         "spec": canonicalize(spec),
-        "env": {k: os.getenv(k, "") for k in TRACE_ENV_VARS},
         "backend": backend or jax.default_backend(),
         "jax": jax.__version__,
     }

@@ -152,7 +152,7 @@ class SlotScheduler:
 
 
 class LocalServer:
-    """In-process serving front (bench.py, tests, __graft_entry__):
+    """In-process serving front (tests, __graft_entry__):
     submit requests, run windows until drained, collect results."""
 
     def __init__(self, engine):

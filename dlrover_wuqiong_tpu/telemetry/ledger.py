@@ -135,7 +135,7 @@ _LEDGER_LOCK = threading.Lock()
 
 def get_ledger() -> GoodputLedger:
     """Process-global ledger (trainer, checkpoint engine, master client
-    and bench all credit the same instance)."""
+    all credit the same instance)."""
     global _LEDGER
     with _LEDGER_LOCK:
         if _LEDGER is None:

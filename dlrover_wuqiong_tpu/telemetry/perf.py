@@ -5,8 +5,8 @@ Parity: reference `atorch/dev/xpu_timer/common/manager.cc` (always-on
 kernel/collective timing exported to Prometheus) and the Brain-side
 anomaly intent of `dlrover/python/master/stats/reporter.py` — but the
 reference detects *hangs*, not *slow*: a job that silently loses 15%
-throughput (a DWT_FA_* env drift, a retrace storm, a degraded remat
-choice after a re-mesh) passes every liveness check it has.
+throughput (a retrace storm, a degraded remat choice after a
+re-mesh) passes every liveness check it has.
 
 TPU redesign: per-op host hooks (LD_PRELOAD shims) don't exist on TPU,
 so the observatory samples instead of intercepting — every N fusion
@@ -20,8 +20,7 @@ split (utils/xplane.py) plus host step-time into a `PerfSnapshot` dict:
   skipped until that overhead amortizes below ``overhead_budget`` (1%)
   of wall;
 - snapshots are keyed by the FULL executable identity — strategy
-  fingerprint, fused-K, backend and the trace-time env toggles
-  (auto/compile_cache.py TRACE_ENV_VARS) — because each of those changes
+  fingerprint, fused-K and backend — because each of those changes
   the HLO, and comparing step times across different executables is how
   perf dashboards lie;
 - the baseline store (``$ckpt_dir/perf/baseline.json``) keeps ROBUST
@@ -102,30 +101,14 @@ def _mad(xs: List[float], med: Optional[float] = None) -> float:
     return _median([abs(x - m) for x in xs])
 
 
-# fallback when auto/compile_cache is unimportable (it is jax-free today;
-# this guards the jax-free smoke against a future jax import there)
-_TRACE_ENV_FALLBACK = ("DWT_FA_NO_FUSED", "DWT_FA_PACK", "DWT_FA_STREAMED",
-                       "DWT_FP8_DENSE", "DWT_REMAT_POLICY")
-
-
 def executable_key(strategy_fingerprint: str, fused_steps: int,
                    backend: str) -> str:
-    """Digest of the full executable identity a step time belongs to.
-
-    Folds the same trace-time env toggles as the framework compile-cache
-    key (auto/compile_cache.py train_step_cache_key): two processes with
-    different DWT_FA_* values run DIFFERENT HLO from the same python
-    call, and their step times must never share a baseline row.
-    """
-    try:
-        from ..auto.compile_cache import TRACE_ENV_VARS
-    except Exception:  # noqa: BLE001 — keep the sentinel math importable
-        TRACE_ENV_VARS = _TRACE_ENV_FALLBACK
+    """Digest of the full executable identity a step time belongs to:
+    step times of different HLO must never share a baseline row."""
     blob = json.dumps({
         "strategy": str(strategy_fingerprint),
         "fused": int(fused_steps),
         "backend": str(backend),
-        "env": {k: os.environ.get(k, "") for k in TRACE_ENV_VARS},
     }, sort_keys=True)
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
 
@@ -193,21 +176,6 @@ class BaselineStore:
     def category_medians(self, key: str) -> Dict[str, float]:
         return {cat: _median(xs)
                 for cat, xs in self._row(key)["categories"].items() if xs}
-
-    def aggregate_categories(self) -> Dict[str, float]:
-        """Per-category medians SUMMED across every executable key — the
-        coarse op-category profile (matmul vs collective vs host) of the
-        whole run so far.  The variant autotuner orders its candidate
-        matrix by this split (auto/tuner.py order_variants, ROADMAP 4d):
-        a matmul-bound profile tries quant variants first, a
-        collective-bound one tries pack/stream first.  Empty until some
-        key has categorized windows — the tuner then falls back to
-        declaration order."""
-        out: Dict[str, float] = {}
-        for key in list(self._load()["keys"]):
-            for cat, med in self.category_medians(key).items():
-                out[cat] = out.get(cat, 0.0) + med
-        return out
 
     # ----------------------------------------------------------- publish
     def publish(self) -> bool:
@@ -319,9 +287,9 @@ class _Window:
         self.t_run0 = t_run0
         # executable key CAPTURED at open time: `close` may run on the
         # trainer's metrics-pump thread while the main loop re-keys the
-        # observatory for a variant cutover (auto/tuner.py) — the window
-        # must fold into the baseline row of the executable it measured,
-        # not whichever key is current when the pump drains it
+        # observatory for a fused-K cutover — the window must fold into
+        # the baseline row of the executable it measured, not whichever
+        # key is current when the pump drains it
         self.key = key
 
 
@@ -373,16 +341,6 @@ class PerfObservatory:
         self._last_event: Optional[Dict] = None
         self._cache_seen: Optional[Tuple[int, int]] = None
         self._snapshot: Optional[Dict] = None
-        # active autotuner variant name ("" = untuned/default run) —
-        # written by the trainer at cutover, read by the pump's close()
-        self._tuned_variant = ""
-
-    def set_tuned_variant(self, name: str) -> None:
-        """Label snapshots with the variant-autotuner's active choice
-        (auto/tuner.py) so PerfQuery/flight consumers can attribute a
-        step-time shift to a cutover instead of a regression."""
-        with self._lock:
-            self._tuned_variant = str(name)
 
     # ----------------------------------------------------------- helpers
     def _registry(self):
@@ -568,7 +526,9 @@ class PerfObservatory:
                 # across processes by the latest-SENT-wins verb (never
                 # duration math)
                 "captured_at": time.time(),
-                "tuned_variant": self._tuned_variant,
+                # wire surface (schema.lock.json PERF_SNAPSHOT_KEYS):
+                # an older master still reads the key; nothing tunes
+                "tuned_variant": "",
             }
             self._snapshot = snap
         return snap
@@ -598,8 +558,8 @@ def latest_snapshot() -> Optional[Dict]:
     return obs.snapshot() if obs is not None else None
 
 
-# the compiled step programs this process runs, by mode (fused K,
-# trace-env signature), for a reader that wants their text afterwards
+# the compiled step programs this process runs, by mode (fused K),
+# for a reader that wants their text afterwards
 # (analysis/hlo_scopes.py turns it into instruction -> scope).  What the
 # loop keeps is the way back to the executable, never the executable
 # and never an array: `jitted.lower(...).compile()` on the shapes,

@@ -84,20 +84,10 @@ class TrainingArgs:
                 f"{self.perf_window_every} (>= 0), perf_regress_windows="
                 f"{self.perf_regress_windows} (>= 1), perf_overhead_budget="
                 f"{self.perf_overhead_budget} (in (0, 1])")
-        if self.tune_variants < 0 or not 0.0 <= self.tune_hysteresis < 1.0:
+        if self.tune_variants != 0:
             raise ValueError(
-                f"bad autotuner knobs: tune_variants={self.tune_variants} "
-                f"(>= 0; 0 = off), tune_hysteresis={self.tune_hysteresis} "
-                f"(in [0, 1))")
-        if self.tune_loss_bound <= 0.0:
-            raise ValueError(
-                f"tune_loss_bound must be > 0 (relative divergence "
-                f"margin), got {self.tune_loss_bound}")
-        if self.tune_numerics and self.tune_variants <= 0:
-            raise ValueError(
-                "tune_numerics requires the autotuner "
-                "(tune_variants > 0) — the fp8 quant axis only runs "
-                "under the loss-divergence guard")
+                f"tune_variants accepts 0 only (nothing tunes variants), "
+                f"got {self.tune_variants}")
     profile_trace_dir: str = ""              # jax.profiler window target
     profile_start_step: int = -1
     profile_end_step: int = -1
@@ -136,25 +126,8 @@ class TrainingArgs:
     perf_window_every: int = 8
     perf_regress_windows: int = 3            # M consecutive beyond-MAD
     perf_overhead_budget: float = 0.01       # max profiling wall fraction
-    # online variant autotuner (auto/tuner.py): N > 0 A/B-measures the
-    # DWT_FA_* variant space with N perf-observatory windows per
-    # candidate, interleaved (chip-load drift is ±10% run to run —
-    # CLAUDE.md), each candidate pre-compiled through the warm pool
-    # before its first measured window, winner persisted to
-    # $ckpt_dir/perf/tuning.json so later runs start tuned.  0 = off.
-    # Requires the perf observatory (perf_window_every > 0).
+    # 0 only: benchmark/traffic/*.json pass the key; goes with it (ROADMAP D14)
     tune_variants: int = 0
-    tune_hysteresis: float = 0.05            # challenger must win by this
-    # opt-in the NUMERICS-CHANGING quant axis (fp8 dense matmul via
-    # DWT_FP8_DENSE) into the search.  Unlike the layout-neutral
-    # DWT_FA_*/remat axes, fp8 changes the loss trajectory, so it only
-    # runs under the tuner's loss-divergence guard: a measured window
-    # whose loss rises more than tune_loss_bound (relative) above the
-    # rolling reference median auto-reverts the variant — cut back to
-    # the incumbent at the same boundary, revert journaled as a
-    # PolicyDecision-style entry.  False = fp8 never enters the search.
-    tune_numerics: bool = False
-    tune_loss_bound: float = 0.05            # relative divergence margin
     # overlap the logging boundary's host work (metrics readback, perf
     # window close, master reports) with the next fused dispatch via the
     # metrics pump thread; False = inline (sync).  User callbacks force
@@ -344,7 +317,7 @@ class Trainer:
         # PerfSnapshot.  The baseline lives next to the checkpoints
         # ($ckpt_dir/perf/baseline.json) so it survives restarts with the
         # run, keyed by the full executable identity — a strategy / K /
-        # backend / trace-env change never pollutes another key's stats.
+        # backend change never pollutes another key's stats.
         self._perf = None
         if args.perf_window_every > 0:
             from ..telemetry.perf import PerfObservatory, set_observatory
@@ -378,17 +351,6 @@ class Trainer:
         self._policy_pending_k: Optional[int] = None
         self._warm_pool = None
         self.policy_applied: list = []
-
-        # online variant autotuner (auto/tuner.py): search only when no
-        # winner is persisted for this executable FAMILY — later runs
-        # start tuned.  Needs the perf observatory (windows are the
-        # scorer's only signal).
-        self._tuner = None
-        self._tuner_reported = 0  # decisions surfaced so far (reverts
-        # land mid-search, the winner at the end — incremental count)
-        self._variant_active = "default"
-        if args.tune_variants > 0 and self._perf is not None:
-            self._init_tuner()
 
         # device-queue liveness probe → master hang localization
         self._prober = None
@@ -518,15 +480,16 @@ class Trainer:
                 applied["fused_steps_requested"] = k_req
         self.policy_applied.append(applied)
 
-    def _prewarm(self, what: str, **spec_changes) -> bool:
-        """True when the executable this run's published warm spec
-        becomes under `spec_changes` is ready in the pool — or can never
-        be: no persistent cache, no spec published by THIS run (the dir
-        is shared, a leftover file may be another job's), or a platform
-        whose devices this process holds (`can_warm`: a warm child could
-        not open them) — then the caller cuts over and compiles in
-        place.  Otherwise kick an async warm compile and report False
-        until a later boundary finds the entry ready."""
+    def _prewarm_fused_k(self, k: int) -> bool:
+        """True when switching the fused driver to K may proceed: the
+        K-wide executable of this run's published warm spec is ready in
+        the pool — or can never be: no persistent cache, no spec
+        published by THIS run (the dir is shared, a leftover file may be
+        another job's), or a platform whose devices this process holds
+        (`can_warm`: a warm child could not open them) — then the caller
+        cuts over and compiles in place.  Otherwise kick an async warm
+        compile and report False until a later boundary finds the entry
+        ready."""
         cache_dir = getattr(self.res, "_cache_dir", None)
         if not cache_dir:
             return True
@@ -545,190 +508,19 @@ class Trainer:
                                      self.args.seq_len]:
             return True
         if not can_warm(spec.platform):
-            logger.info("%s: no warm child for platform %r (this process "
-                        "holds its devices) — cutting over, the step "
-                        "compiles in place", what, spec.platform)
+            logger.info("policy fused_steps=%d: no warm child for platform "
+                        "%r (this process holds its devices) — cutting "
+                        "over, the step compiles in place", k, spec.platform)
             return True
-        spec = dataclasses.replace(spec, **spec_changes)
+        spec = dataclasses.replace(spec, fused_steps=k)
         if self._warm_pool is None:
             self._warm_pool = WarmPool(cache_dir)
         if self._warm_pool._ready_entry_for(spec.spec_key()) is not None:
             return True
         self._warm_pool.warm_async(spec)
-        logger.info("%s: warming in the pool — cutover deferred until "
-                    "the entry is ready", what)
+        logger.info("policy fused_steps=%d: warming in the pool — cutover "
+                    "deferred until the entry is ready", k)
         return False
-
-    def _prewarm_fused_k(self, k: int) -> bool:
-        """True when switching the fused driver to K may proceed (the
-        pool holds the K-wide executable, or nothing can warm it)."""
-        return self._prewarm(f"policy fused_steps={k}", fused_steps=k)
-
-    # ------------------------------------------------- variant autotuner
-
-    def _model_dims_fingerprint(self) -> str:
-        """Width×depth fingerprint of the model config for shape_class
-        ("d768x12"); "" when the model exposes no recognized dims."""
-        cfg = getattr(self.model, "config", None)
-        if cfg is None:
-            return ""
-        width = getattr(cfg, "n_embd", None) or \
-            getattr(cfg, "hidden_size", None)
-        depth = getattr(cfg, "n_layer", None) or \
-            getattr(cfg, "num_layers", None)
-        if not width or not depth:
-            return ""
-        return f"d{int(width)}x{int(depth)}"
-
-    def _init_tuner(self) -> None:
-        """Start tuned when a winner is persisted for this executable
-        family (strategy + backend, excluding the tunables); otherwise
-        build the interleaved search over the widened variant space.
-        Corrupt/missing tuning.json falls through to re-learn (the store
-        tolerates it) — never fatal.
-
-        Winner lookup is PER-SHAPE first (batch × seq × model dims —
-        ROADMAP 4c): the exact-geometry winner is preferred, the
-        family-wide winner serves unseen shapes, and v1 shapeless stores
-        keep serving as the fallback without re-learning.  The search
-        space adds the remat-policy ladder when the model remats and
-        the fp8 quant axis behind `tune_numerics` (loss-divergence
-        guard armed via `tune_loss_bound`); candidate ORDER comes from
-        the baseline store's op-category split (ROADMAP 4d) — a
-        matmul-bound profile tries quant first, a collective-bound one
-        pack/stream first.
-        """
-        import jax
-
-        from ..auto import tuner as vt
-
-        a = self.args
-        backend = jax.default_backend()
-        family = vt.family_key(self._strategy_fingerprint(), backend)
-        shape = vt.shape_class(a.global_batch_size, a.seq_len,
-                               self._model_dims_fingerprint())
-        store = vt.TuningStore(
-            vt.tuning_path(os.path.join(a.output_dir, "checkpoints")))
-        winner = store.lookup(family, shape)
-        if winner is not None:
-            # apply before the first dispatch: the fused cache re-keys on
-            # the env signature, so this retraces exactly once and the
-            # compile credit below keeps it out of the baselines
-            env = winner.get("exe_env") or winner.get("env") or {}
-            vt.apply_variant({str(k): str(v) for k, v in env.items()})
-            self._variant_active = str(winner.get("variant") or "default")
-            if self._perf is not None:
-                self._perf.set_tuned_variant(self._variant_active)
-            k_win = int(winner.get("fused_steps") or 0)
-            cad = self._hook_cadence()
-            if k_win > 1 and a.fused_steps == 0 and \
-                    (not cad or cad % k_win == 0):
-                a.fused_steps = k_win  # skip the K re-measurement too
-            logger.info("tuner: starting on persisted winner %r "
-                        "(family %s, shape %s%s)", self._variant_active,
-                        family, shape,
-                        "" if winner.get("shape_class") == shape
-                        else " via family fallback")
-            return
-        cfg = getattr(self.model, "config", None)
-        remat_policies = ()
-        if cfg is not None and getattr(cfg, "remat", False):
-            # only non-offload policies: offload variants change the
-            # host-transfer profile, not a pure compute trade — keep the
-            # online ladder to the HBM-resident policies
-            remat_policies = ("dots", "save_names")
-        hint = None
-        if self._perf is not None:
-            hint = self._perf.store.aggregate_categories() or None
-        self._tuner = vt.VariantAutotuner(
-            vt.default_variants(backend, numerics=a.tune_numerics,
-                                remat_policies=remat_policies),
-            store=store, family=family,
-            windows_per_variant=a.tune_variants,
-            hysteresis=a.tune_hysteresis,
-            shape_class=shape,
-            loss_bound=a.tune_loss_bound if a.tune_numerics else 0.0,
-            category_hint=hint)
-        self._tuner.bind_executable_context(
-            strategy_fingerprint=self._strategy_fingerprint(),
-            fused_steps=max(a.fused_steps, 1), backend=backend)
-
-    def _variant_full_env(self, variant) -> Dict[str, str]:
-        """Full TRACE_ENV_VARS assignment for a variant — vars the
-        variant leaves alone map to "" so `apply_variant` DELETES them
-        (unset is a distinct value: DWT_FA_STREAMED unset means the
-        sequence-length heuristic, not off)."""
-        from ..auto.compile_cache import TRACE_ENV_VARS
-
-        return {k: str(variant.env.get(k, "")) for k in TRACE_ENV_VARS}
-
-    def _maybe_apply_variant(self, fused_k) -> None:
-        """Fusion-boundary variant cutover, following the tuner's
-        interleave schedule.  The next candidate pre-warms through the
-        warm pool (its env rides WarmSpec.trace_env — every variant is a
-        distinct compile-cache key), and the env flip happens only when
-        the entry is ready, so no measured window ever pays a cold
-        compile.  When the search settles, the decision surfaces as
-        PolicyDecision-style history (policy_applied + a node event)
-        with the measured before/after medians."""
-        tuner = self._tuner
-        if tuner is None:
-            return
-        with tuner._lock:
-            pending = list(tuner.decisions[self._tuner_reported:])
-        if pending:
-            # incremental: loss-divergence REVERTS land mid-search, the
-            # winner at the end — each surfaces exactly once
-            self._tuner_reported += len(pending)
-            from ..brain.policy import tuner_decision_effects
-
-            effects = tuner_decision_effects(pending)
-            self.policy_applied.extend(effects)
-            if effects and self.ctx.mc is not None:
-                import json as _json
-
-                for eff in effects:
-                    try:  # telemetry never kills the run
-                        self.ctx.mc.report_node_event(
-                            "tuner-decision",
-                            _json.dumps(eff, sort_keys=True),
-                            level="info")
-                    except Exception:  # noqa: BLE001
-                        pass
-        desired = tuner.current()
-        if desired.name == self._variant_active:
-            return
-        if not self._prewarm_variant(desired, fused_k):
-            return  # entry still compiling: stay put, poll next boundary
-        from ..auto.tuner import apply_variant
-
-        apply_variant(self._variant_full_env(desired))
-        self._variant_active = desired.name
-        if self._perf is not None:
-            self._perf.set_tuned_variant(desired.name)
-        tuner.cutover(desired)
-        if desired.fused_steps and fused_k is not None and \
-                desired.fused_steps != (fused_k or 1):
-            # K rides the existing policy cutover path (stager rebuild,
-            # cadence clamp) — same boundary discipline as a DWT_FA_* flip
-            self._policy_pending_k = int(desired.fused_steps)
-
-    def _prewarm_variant(self, variant, fused_k) -> bool:
-        """True when the variant's executable is already live here (its
-        (K, env) mode was dispatched before) or the warm pool holds a
-        ready entry.  No cache dir / no published spec → allow: the
-        compile-credit path still keeps the first dispatch out of the
-        perf windows via _compiled_modes."""
-        from ..auto.tuner import env_signature, variant_env
-
-        k = int(variant.fused_steps or (fused_k or 1))
-        with variant_env(self._variant_full_env(variant)):
-            mode = (k, env_signature())
-        if mode in self._compiled_modes:
-            return True
-        return self._prewarm(f"tuner variant {variant.name!r}",
-                             fused_steps=k,
-                             trace_env=self._variant_full_env(variant))
 
     # ------------------------------------------------------------- schedule
 
@@ -835,8 +627,7 @@ class Trainer:
     # ----------------------------------------------------- perf observatory
 
     def _strategy_fingerprint(self) -> str:
-        """Strategy identity shared by the perf baseline key and the
-        tuner's family key — excludes the tunables (env, K)."""
+        """Strategy identity of the perf baseline key (K excluded)."""
         try:
             return repr((self.res.strategy.plan.describe(),
                          self.res.strategy_spec))
@@ -845,10 +636,10 @@ class Trainer:
 
     def _perf_key(self, fused_k: int) -> str:
         """Executable identity for the perf baseline — the same facts that
-        key the compile cache (strategy fingerprint, fused-K, backend,
-        trace-env toggles), so baseline stats never mix executables and a
-        tuner cutover lands on a NEW key instead of firing the regression
-        sentinel against the old variant's baseline."""
+        key the compile cache (strategy fingerprint, fused-K, backend),
+        so baseline stats never mix executables and a K cutover lands on
+        a NEW key instead of firing the regression sentinel against the
+        old width's baseline."""
         import jax
 
         from ..telemetry.perf import executable_key
@@ -911,7 +702,7 @@ class Trainer:
                          t_read: float,
                          counted: Optional[Dict[str, float]] = None) -> None:
         """What follows the readback at a logging boundary: perf-window
-        close, the log line, master reports, tuner credit, callbacks.
+        close, the log line, master reports, callbacks.
         `counted` (the step's scalars beside loss and grad_norm, e.g. an
         MoE model's expert load) gets a log line of its own, rides in
         the callbacks' dict and is kept as one `trainer:step_metrics`
@@ -947,15 +738,6 @@ class Trainer:
                 self.ctx.mc.report_goodput_ledger(job["ledger"])
             except Exception:  # noqa: BLE001
                 pass
-        if snap and self._tuner is not None and \
-                job.get("tune_variant") == self._variant_active:
-            # credit the window to the variant that actually executed it
-            # (note_window is lock-guarded); the returned next candidate
-            # is picked up by the main loop's boundary poll.  The loss
-            # rides along for the numerics divergence guard — it is the
-            # SAME already-read boundary loss, zero new device syncs.
-            self._tuner.note_window(
-                float(snap.get("step_time_s") or 0.0), loss=loss)
         for cb in self.callbacks:
             cb(step, {"loss": loss, "tokens_per_sec": tps, **counted})
 
@@ -966,7 +748,6 @@ class Trainer:
 
         import jax
 
-        from ..auto.tuner import env_signature
         from ..telemetry.ledger import get_ledger
         from ..telemetry.perf import keep_step_executable
         from ..telemetry.recorder import get_recorder
@@ -1033,8 +814,8 @@ class Trainer:
         # ckpt_stage/persist + restore tiers; master_client credits
         # degraded.  All accounting happens HERE at fusion boundaries from
         # host-side timers — never inside the jitted step, never via an
-        # extra device readback.  Modes are (K, trace-env signature): a
-        # variant cutover's first dispatch is a compile, not overhead.
+        # extra device readback.  Modes are fusion widths K: a K
+        # cutover's first dispatch is a compile, not overhead.
         self._compiled_modes: set = set()
         # callbacks are synchronous user hooks (request_stop, config
         # pushes assert their effect on the NEXT fusion) — their presence
@@ -1064,11 +845,6 @@ class Trainer:
                             fused_k = self._policy_pending_k
                             self._policy_pending_k = None
                             stager = None
-                    if self._tuner is not None and fused_k is not None:
-                        # variant cutover at the boundary, warm-pool gated —
-                        # only after the K auto-tune settles (the unfused
-                        # measurement steps must not race an env flip)
-                        self._maybe_apply_variant(fused_k)
                     self._fused_k_active = fused_k or 0
                     if fused_k is not None and fused_k > 1 and stager is None:
                         from ..data.elastic_dataset import FusedBatchStager
@@ -1098,14 +874,10 @@ class Trainer:
                             self._poll_policy()
                             self._poll_mesh_transition()
                     pw = None
-                    env_mode = (k_eff, env_signature())
                     if self._perf is not None and a.logging_steps and \
                             (s0 + k_eff) % a.logging_steps == 0 and \
-                            env_mode in self._compiled_modes and \
+                            k_eff in self._compiled_modes and \
                             self._pump.windows_inflight() == 0 and \
-                            (self._tuner is None or
-                             self._tuner.current().name ==
-                             self._variant_active) and \
                             not self._user_trace_active(s0, k_eff):
                         # perf window: only on a boundary that already carries
                         # the logging readback (that sync flushes the fused
@@ -1113,10 +885,7 @@ class Trainer:
                         # readbacks), never on the compile dispatch (compile
                         # wall is not a step-time baseline), never while the
                         # opt-in trace window is live or a pump-held window is
-                        # still closing (jax traces can't nest), and — when
-                        # tuning — only while execution matches the tuner's
-                        # current candidate, so a deferred cutover never
-                        # credits the old variant's windows to the new one.
+                        # still closing (jax traces can't nest).
                         # maybe_open applies the every-Nth cadence and the
                         # <1%-overhead self-limit.
                         self._perf.key = self._perf_key(k_eff)
@@ -1136,11 +905,7 @@ class Trainer:
                                 k_eff)(self.state, batch)
                         else:
                             t0 = time.perf_counter()
-                            # width-1 through the variant-aware fused cache:
-                            # identical to train_step until a DWT_FA_* cutover
-                            # changes the env signature, which must retrace
-                            # instead of reusing the old trace
-                            self.state, metrics = self.res.fused_train_step(1)(
+                            self.state, metrics = self.res.train_step(
                                 self.state, batch)
                             if fused_k is None:
                                 # auto-tune measurement: sync so the timing is
@@ -1153,17 +918,17 @@ class Trainer:
                             # trace holds only the block's first milliseconds
                             jax.block_until_ready(metrics)
                     blk_s = time.monotonic() - t_blk0
-                    if env_mode not in self._compiled_modes:
-                        # first dispatch at this (fusion width, variant env)
+                    if k_eff not in self._compiled_modes:
+                        # first dispatch at this fusion width
                         # traces+compiles
-                        self._compiled_modes.add(env_mode)
+                        self._compiled_modes.add(k_eff)
                         led.account("compile", blk_s)
                         credited_blk = blk_s
                         # for a reader of the step's text, afterwards
                         # (telemetry/perf.py): shapes and shardings only,
                         # no array is kept; ~2 ms at 1,740 leaves, once
                         keep_step_executable(
-                            env_mode, self.res.fused_train_step(k_eff),
+                            k_eff, self.res.fused_train_step(k_eff),
                             self.state, batch)
                     else:
                         credited_blk = min(blk_s, self._dispatch_overhead_s())
@@ -1193,7 +958,6 @@ class Trainer:
                                 "step": step, "metrics": metrics, "pw": pw,
                                 "tokens_per_step": tokens_per_step,
                                 "ledger": led.snapshot(),
-                                "tune_variant": self._variant_active,
                                 # the pump's spans hang under this one
                                 "trace": tspans.current_trace(),
                             })

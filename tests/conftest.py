@@ -12,7 +12,15 @@ if "xla_force_host_platform_device_count" not in xla_flags:
     os.environ["XLA_FLAGS"] = (
         xla_flags + " --xla_force_host_platform_device_count=8"
     ).strip()
-os.environ.setdefault("DWT_SOCKET_DIR", "/tmp/dwt-test/sockets")
+# one job name and one socket dir per xdist worker: the checkpoint shm
+# segment (`<job>_ckpt_shm_<rank>`) and the saver's sockets are named by
+# job, and `AsyncCheckpointSaver.reset()` unlinks the segment BY NAME —
+# under the default name a reset in one worker's test took away what
+# another worker's Trainer had just staged
+_worker = os.environ.get("PYTEST_XDIST_WORKER", "")
+_suffix = f"-{_worker}" if _worker else ""
+os.environ.setdefault("DWT_JOB_NAME", "dwt" + _suffix)
+os.environ.setdefault("DWT_SOCKET_DIR", "/tmp/dwt-test/sockets" + _suffix)
 
 # env var for subprocesses, config for this process (covers a jax that
 # something imported before conftest ran)

@@ -21,7 +21,6 @@ from jax.experimental.shard_map import shard_map
 
 from dlrover_wuqiong_tpu.analysis.ast_engine import (
     run_paths,
-    trace_env_key_vars,
 )
 from dlrover_wuqiong_tpu.analysis.findings import (
     Finding,
@@ -280,31 +279,29 @@ def _scan_source(tmp_path, relpath, source, **kw):
 
 
 class TestEnvAtTrace:
-    def test_unkeyed_env_read_flagged(self, tmp_path):
+    @pytest.mark.parametrize("read", [
+        'os.getenv("XLA_FAKE_TOGGLE")',
+        'os.environ.get("HOME")',
+        'os.environ["DWT_FAKE_TOGGLE"]',
+        'os.getenv("<computed>" and x)',
+    ])
+    def test_any_env_read_in_a_compute_function_flagged(self, tmp_path,
+                                                        read):
+        """No registry of exempt names: whatever the variable is called,
+        a compute-path function does not read it."""
         found = _scan_source(
-            tmp_path, "pkg/ops/kern.py", """\
+            tmp_path, "pkg/ops/kern.py", f"""\
             '''Parity: ref.py:1'''
             import os
 
             def build_kernel(x):
-                if os.getenv("DWT_FAKE_TOGGLE"):
+                if {read}:
                     return x
                 return x + 1
-            """, key_vars={"DWT_FA_STREAMED"})
+            """)
         assert [f.checker for f in found] == ["env-at-trace"]
-        assert "DWT_FAKE_TOGGLE" in found[0].message
+        assert read.split('"')[1] in found[0].message
         assert found[0].line == 5
-
-    def test_keyed_env_read_clean(self, tmp_path):
-        found = _scan_source(
-            tmp_path, "pkg/ops/kern.py", """\
-            '''Parity: ref.py:1'''
-            import os
-
-            def build_kernel(x):
-                return os.environ.get("DWT_FAKE_TOGGLE")
-            """, key_vars={"DWT_FAKE_TOGGLE"})
-        assert found == []
 
     def test_module_level_and_non_compute_reads_exempt(self, tmp_path):
         found = _scan_source(
@@ -314,124 +311,16 @@ class TestEnvAtTrace:
 
             def pick():
                 return os.getenv("DWT_JOB_NAME")
-            """, key_vars=set())
-        assert found == []
-
-    def test_key_vars_parsed_from_repo(self):
-        vars_ = trace_env_key_vars([
-            os.path.join(REPO_ROOT, "dlrover_wuqiong_tpu")])
-        # the DWT_FA_PACK omission was graftlint's first real catch —
-        # pin the kernel-path toggles plus the ISSUE-16 tuner axes
-        # (fp8 dense + remat policy) in the key set
-        assert {"DWT_FA_NO_FUSED", "DWT_FA_PACK", "DWT_FA_STREAMED",
-                "DWT_FP8_DENSE", "DWT_REMAT_POLICY"} <= vars_
-
-
-class TestEnvFlipOutsideTuner:
-    """env-flip-outside-tuner: raw os.environ writes of TRACE_ENV_VARS
-    names belong to auto/tuner.py (variant_env / apply_variant)."""
-
-    def test_raw_writes_flagged(self, tmp_path):
-        found = _scan_source(
-            tmp_path, "pkg/runtime/flip.py", """\
-            '''Parity: ref.py:1'''
-            import os
-
-            def go():
-                os.environ["DWT_FA_STREAMED"] = "1"
-                os.environ.pop("DWT_FA_NO_FUSED", None)
-                os.environ.setdefault("DWT_FA_PACK", "4")
-                del os.environ["DWT_FA_STREAMED"]
-            """,
-            checkers=["env-flip-outside-tuner"],
-            key_vars={"DWT_FA_STREAMED", "DWT_FA_NO_FUSED",
-                      "DWT_FA_PACK"})
-        assert [f.checker for f in found] == \
-            ["env-flip-outside-tuner"] * 4
-        assert sorted(f.line for f in found) == [5, 6, 7, 8]
-        assert "variant_env" in found[0].message
-
-    def test_tuner_file_and_tests_exempt(self, tmp_path):
-        src = """\
-            '''Parity: ref.py:1'''
-            import os
-
-            def _set(name, value):
-                os.environ["DWT_FA_STREAMED"] = value
-            """
-        for rel in ("pkg/auto/tuner.py", "pkg/tests/test_flip.py",
-                    "pkg/test_flip.py"):
-            found = _scan_source(
-                tmp_path / rel.replace("/", "_"), rel, src,
-                checkers=["env-flip-outside-tuner"],
-                key_vars={"DWT_FA_STREAMED"})
-            assert found == [], rel
-
-    def test_non_key_vars_and_reads_clean(self, tmp_path):
-        found = _scan_source(
-            tmp_path, "pkg/runtime/flip.py", """\
-            '''Parity: ref.py:1'''
-            import os
-
-            def go():
-                os.environ["DWT_JOB_NAME"] = "j"       # not a trace var
-                v = os.environ.get("DWT_FA_STREAMED")  # read, not write
-                return v
-            """,
-            checkers=["env-flip-outside-tuner"],
-            key_vars={"DWT_FA_STREAMED"})
-        assert found == []
-
-    def test_suppression_honored(self, tmp_path):
-        found = _scan_source(
-            tmp_path, "pkg/runtime/flip.py", """\
-            '''Parity: ref.py:1'''
-            import os
-
-            def go():
-                os.environ["DWT_FA_PACK"] = "4"  \
-# graftlint: disable=env-flip-outside-tuner -- fixture exercises raw flip
-            """,
-            checkers=["env-flip-outside-tuner"],
-            key_vars={"DWT_FA_PACK"})
-        assert found == []
-
-    def test_newly_registered_name_flagged_via_lint_time_sourcing(
-            self, tmp_path):
-        """Registering a NEW name in TRACE_ENV_VARS is all it takes for
-        the rule to cover it: key_vars are parsed from the linted tree's
-        own auto/compile_cache.py at LINT TIME (no hardcoded list), so a
-        raw write of the new toggle is flagged while the same write in
-        the tuner module stays exempt."""
-        # key-builder at <root>/auto/compile_cache.py — exactly where
-        # trace_env_key_vars looks under each scanned root
-        (tmp_path / "auto").mkdir()
-        (tmp_path / "runtime").mkdir()
-        for d in ("auto", "runtime"):
-            (tmp_path / d / "__init__.py").touch()
-        (tmp_path / "auto" / "compile_cache.py").write_text(
-            textwrap.dedent("""\
-            '''Parity: ref.py:1'''
-            TRACE_ENV_VARS = ("DWT_FA_NO_FUSED", "DWT_NEW_TOGGLE")
-            """))
-        bad = textwrap.dedent("""\
-            '''Parity: ref.py:1'''
-            import os
-
-            def go():
-                os.environ["DWT_NEW_TOGGLE"] = "1"
             """)
-        (tmp_path / "runtime" / "flip.py").write_text(bad)
-        # the good twin: byte-identical write, but in the tuner module —
-        # the ONE sanctioned writer stays exempt
-        (tmp_path / "auto" / "tuner.py").write_text(bad)
-        # key_vars=None -> auto-sourced from the fixture tree itself
-        findings, _ = run_paths(
-            [str(tmp_path)], checkers=["env-flip-outside-tuner"])
-        assert [(f.checker, f.line) for f in findings] == \
-            [("env-flip-outside-tuner", 5)]
-        assert findings[0].path.endswith("runtime/flip.py")
-        assert "DWT_NEW_TOGGLE" in findings[0].message
+        assert found == []
+        found = _scan_source(
+            tmp_path, "pkg/ops/consts.py", """\
+            '''Parity: ref.py:1'''
+            import os
+
+            CACHE = os.getenv("DWT_FAKE_DIR")
+            """)
+        assert found == []
 
 
 class TestWallClockDuration:
@@ -986,7 +875,7 @@ class TestSelfLint:
     def test_ast_engine_repo_clean(self):
         paths = [os.path.join(REPO_ROOT, p)
                  for p in ("dlrover_wuqiong_tpu", "tests", "examples",
-                           "tools", "bench.py", "__graft_entry__.py")]
+                           "tools", "__graft_entry__.py")]
         findings, n_files = run_paths([p for p in paths
                                        if os.path.exists(p)])
         assert n_files > 100

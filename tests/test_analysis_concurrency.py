@@ -594,7 +594,7 @@ class TestConcurrencySelfLint:
     def test_concurrency_engine_repo_clean(self):
         paths = [os.path.join(REPO_ROOT, p)
                  for p in ("dlrover_wuqiong_tpu", "tests", "examples",
-                           "tools", "bench.py", "__graft_entry__.py")]
+                           "tools", "__graft_entry__.py")]
         findings, n_files = run_paths([p for p in paths
                                        if os.path.exists(p)])
         assert findings == [], "\n".join(f.format() for f in findings)

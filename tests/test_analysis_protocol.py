@@ -779,7 +779,7 @@ class TestProtocolSelfLint:
     def test_protocol_engine_repo_clean(self):
         paths = [os.path.join(REPO_ROOT, p)
                  for p in ("dlrover_wuqiong_tpu", "tests", "examples",
-                           "tools", "bench.py", "__graft_entry__.py")]
+                           "tools", "__graft_entry__.py")]
         findings, n_files = run_paths([p for p in paths
                                        if os.path.exists(p)])
         assert n_files > 100

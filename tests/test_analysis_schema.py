@@ -192,7 +192,7 @@ class TestExtraction:
         counts = surface_counts(surface)
         assert counts["messages"] >= 68
         assert counts["fields"] >= 211
-        assert counts["registries"] >= 7
+        assert counts["registries"] >= 6
         assert counts["verbs"]["journaled"] >= 13
         assert counts["journal_kinds_written"] >= 16
         assert counts["snapshot_exported"] >= 8

@@ -52,6 +52,18 @@ def test_ckpt_corrupt_zero_silent_restores():
     assert report["flight"]["ledger"]["restore_storage"] > 0
 
 
+def test_perf_regress_keys_a_cutover_by_an_argument():
+    """A different executable is a new baseline (the key moves with an
+    ARGUMENT of `executable_key`, the fused width), and step times that
+    fired under the old key never fire after the cutover."""
+    report = chaos.perf_regress()
+    assert report["ok"], report
+    assert report["fired_after_windows"] == 3 and report["fired_total"] == 1
+    assert report["attributed_category"] == "collective"
+    assert report["key_changed_on_k_change"]
+    assert report["cutover_fired"] == 0 and report["cutover_baseline_n"] > 0
+
+
 def test_cli_policy_prior_flag(capsys, monkeypatch):
     """`--policy-prior PATH` routes to preempt-adaptive ONLY (other
     scenarios keep their zero-arg contract) and both `--policy-prior P`

@@ -143,7 +143,8 @@ with open(marker, "w") as f:
                "node": int(os.getenv("DWT_NODE_ID", "-1")),
                "restart": restart, "ospid": os.getpid()}, f)
 
-TOTAL = 30 if mode == "slice" else 8
+paced = mode in ("slice", "scale")
+TOTAL = 30 if paced else 8
 loss_log = os.path.join(marker_dir, f"losses_r{restart}_p{pid}.jsonl")
 for _ in range(start, TOTAL):
     state, m = res.train_step(state, batch)
@@ -153,8 +154,8 @@ for _ in range(start, TOTAL):
     ck.save_checkpoint(step, state, storage_type=StorageType.DISK)
     ck.wait_latest_checkpoint(60)
     ctx.report_step(step, force=True)
-    if mode == "slice":
-        time.sleep(0.2)  # widen the externally-injected kill window
+    if paced:
+        time.sleep(0.2)  # widen the externally-injected kill / join window
     if mode == "crash" and restart == 0 and pid == 0 and step == 3:
         os._exit(17)  # injected fault AFTER step-3 commit
 
@@ -279,7 +280,10 @@ def test_jax_world_scale_up(tmp_path):
     try:
         import time as _t
         _t.sleep(2.0)
-        agents.append(_spawn_agent(0, script, [ckpt_dir, markers, "plain"],
+        # "scale": paced like "slice" — eight unpaced steps can be over
+        # before a loaded host has brought node 1's agent up, and then no
+        # world ever has two processes
+        agents.append(_spawn_agent(0, script, [ckpt_dir, markers, "scale"],
                                    port, env, nnodes="1:2"))
         # wait until node 0 trains alone, then add node 1
         deadline = _t.time() + 180
@@ -295,7 +299,7 @@ def test_jax_world_scale_up(tmp_path):
         while _t.time() < deadline and not commit_marker.exists():
             _t.sleep(0.5)
         assert commit_marker.exists(), "solo worker never committed"
-        agents.append(_spawn_agent(1, script, [ckpt_dir, markers, "plain"],
+        agents.append(_spawn_agent(1, script, [ckpt_dir, markers, "scale"],
                                    port, env, nnodes="1:2"))
         for a in agents:
             out, _ = a.communicate(timeout=420)

@@ -166,19 +166,29 @@ def test_tiled_forward_matches_reference(sq, sk, block_q, block_k, tile,
     np.testing.assert_allclose(lse[:, 0], rlse, atol=2e-5)
 
 
-# one block each way takes the fused kernel, or with `split` the dq and
-# dk/dv kernels on the same block; several blocks take those two anyway
-BWD_CASES = [c + (False,) for c in CASES if c[:2] == c[2:4]] \
-    + [c + (True,) for c in CASES]
+# one block each way takes the fused kernel; several blocks take the dq
+# and dk/dv kernels.  Every one-block case runs both ways: as it is, and
+# with its blocks halved (a 2 x 2 grid over the same arrays)
+def _halved(case):
+    sq, sk, block_q, block_k, tile, *rest = case
+    return (sq, sk, block_q // 2, block_k // 2,
+            min(tile, min(block_q, block_k) // 4), *rest)
+
+
+_ONE_BLOCK = [c for c in CASES if c[:2] == c[2:4]]
+BWD_CASES = [c + (False,) for c in _ONE_BLOCK] \
+    + [(_halved(c) if c in _ONE_BLOCK else c) + (True,) for c in CASES]
+
+
+def _is_split(sq, sk, block_q, block_k) -> bool:
+    return sq // block_q > 1 or sk // block_k > 1
 
 
 @pytest.mark.parametrize("sq,sk,block_q,block_k,tile,causal,bh,d,split",
                          BWD_CASES)
 def test_tiled_backward_matches_reference(sq, sk, block_q, block_k, tile,
-                                          causal, bh, d, split,
-                                          monkeypatch):
-    if split:
-        monkeypatch.setenv("DWT_FA_NO_FUSED", "1")
+                                          causal, bh, d, split):
+    assert split == _is_split(sq, sk, block_q, block_k)
     q, k, v, g, _ = _inputs(sq, sk, bh, d, seed=1)
     scale = d ** -0.5
     o, lse = fa._fa_forward_pallas(q, k, v, causal, scale, block_q, block_k,
@@ -194,17 +204,16 @@ def test_tiled_backward_matches_reference(sq, sk, block_q, block_k, tile,
 
 @pytest.mark.parametrize("sq,sk,block_q,block_k,tile,split", [
     (128, 128, 128, 128, 32, False),
-    (128, 128, 128, 128, 32, True),
+    (128, 128, 64, 64, 16, True),
     (256, 256, 64, 64, 16, True),
     (64, 128, 64, 128, 32, False),
     (128, 256, 64, 64, 16, True),
 ])
 def test_tiled_backward_takes_the_lse_cotangent(sq, sk, block_q, block_k,
-                                                tile, split, monkeypatch):
+                                                tile, split):
     """`flash_attention_with_lse`'s second cotangent, folded into delta,
     reaches every tile's ds."""
-    if split:
-        monkeypatch.setenv("DWT_FA_NO_FUSED", "1")
+    assert split == _is_split(sq, sk, block_q, block_k)
     q, k, v, g, gl = _inputs(sq, sk, 2, 64, seed=2)
     o, lse = fa._fa_forward_pallas(q, k, v, True, 0.125, block_q, block_k,
                                    interpret=True, tile=tile)

@@ -451,47 +451,53 @@ class TestStreamedAttention:
         np.testing.assert_allclose(o_s, o_r, atol=2e-5)
         np.testing.assert_allclose(lse_s, lse_r, atol=2e-5)
 
+    @pytest.mark.parametrize("sq,sk", [(2048, 2048), (1024, 4096)])
     @pytest.mark.parametrize("causal", [True, False])
-    def test_streamed_grads_match_dense_path(self, causal, monkeypatch):
-        """End-to-end through flash_attention's custom VJP: forcing the
-        streamed path must give the same grads as the dense fallback."""
+    def test_streamed_grads_match_dense_path(self, causal, sq, sk):
+        """End-to-end through flash_attention's custom VJP: a shape over
+        the 2048^2 threshold takes the streamed path, and must give the
+        grads of the dense reference."""
         from dlrover_wuqiong_tpu.ops import flash_attention as fa
 
+        assert fa._use_streamed(sq, sk)
         key = jax.random.PRNGKey(12)
         kq, kk, kv = jax.random.split(key, 3)
-        q = jax.random.normal(kq, (1, 2, 256, 32), jnp.float32)
-        k = jax.random.normal(kk, (1, 2, 256, 32), jnp.float32)
-        v = jax.random.normal(kv, (1, 2, 256, 32), jnp.float32)
+        q = jax.random.normal(kq, (1, 1, sq, 16), jnp.float32)
+        k = jax.random.normal(kk, (1, 1, sk, 16), jnp.float32)
+        v = jax.random.normal(kv, (1, 1, sk, 16), jnp.float32)
 
         def loss(q, k, v):
             return (fa.flash_attention(q, k, v, causal=causal) ** 2).sum()
 
-        monkeypatch.setenv("DWT_FA_STREAMED", "0")
-        g_dense = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        monkeypatch.setenv("DWT_FA_STREAMED", "1")
+        def dense(q, k, v):
+            return (fa._attention_reference(q, k, v, causal, 0.25) ** 2).sum()
+
         g_str = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        g_dense = jax.grad(dense, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(g_str, g_dense):
             np.testing.assert_allclose(a, b, atol=3e-4)
 
-    def test_streamed_lse_cotangent(self, monkeypatch):
+    def test_streamed_lse_cotangent(self):
         """flash_attention_with_lse differentiates through BOTH outputs on
         the streamed path (the ring-attention building block)."""
         from dlrover_wuqiong_tpu.ops import flash_attention as fa
 
         key = jax.random.PRNGKey(13)
         kq, kk, kv = jax.random.split(key, 3)
-        q = jax.random.normal(kq, (1, 2, 128, 32), jnp.float32)
-        k = jax.random.normal(kk, (1, 2, 128, 32), jnp.float32)
-        v = jax.random.normal(kv, (1, 2, 128, 32), jnp.float32)
+        q = jax.random.normal(kq, (1, 1, 2048, 16), jnp.float32)
+        k = jax.random.normal(kk, (1, 1, 2048, 16), jnp.float32)
+        v = jax.random.normal(kv, (1, 1, 2048, 16), jnp.float32)
 
         def loss(q, k, v):
             o, lse = fa.flash_attention_with_lse(q, k, v, causal=True)
             return (o ** 2).sum() + (lse ** 2).sum()
 
-        monkeypatch.setenv("DWT_FA_STREAMED", "0")
-        g_dense = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        monkeypatch.setenv("DWT_FA_STREAMED", "1")
+        def dense(q, k, v):
+            o, lse = fa._reference_with_lse(q, k, v, True, 0.25)
+            return (o ** 2).sum() + (lse ** 2).sum()
+
         g_str = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        g_dense = jax.grad(dense, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(g_str, g_dense):
             np.testing.assert_allclose(a, b, atol=3e-4)
 

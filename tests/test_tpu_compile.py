@@ -91,16 +91,14 @@ def test_attention_forward_and_backward_compile(topo, bh, t, d, causal):
         assert "dwt_fa_bwd_dq" in bwd and "dwt_fa_bwd_dkv" in bwd
 
 
-def test_attention_split_backward_compiles_at_gpt2_shape(topo, monkeypatch):
-    """DWT_FA_NO_FUSED — the tuner's `no-fused` candidate — takes the dq
-    and dk/dv kernels where the default takes the fused one."""
-    monkeypatch.setenv("DWT_FA_NO_FUSED", "1")
-    monkeypatch.setenv("DWT_FA_PACK", "4")  # the tuner's `pack4`, and fast
+def test_attention_split_backward_compiles_at_gpt2_shape(topo):
+    """Blocks of 512 at T = 1024 are a 2 x 2 grid: the backward takes the
+    dq and dk/dv kernels where one block each way takes the fused one."""
     one = SingleDeviceSharding(topo.devices[0])
-    x = jax.ShapeDtypeStruct((8, 1024, 64), jnp.bfloat16, sharding=one)
-    lse = jax.ShapeDtypeStruct((8, 1, 1024), jnp.float32, sharding=one)
+    x = jax.ShapeDtypeStruct((4, 1024, 64), jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((4, 1, 1024), jnp.float32, sharding=one)
     bwd = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
-        q, k, v, o, l, do, True, 0.125, 1024, 1024, False),
+        q, k, v, o, l, do, True, 0.125, 512, 512, False),
         x, x, x, x, lse, x)
     assert "dwt_fa_bwd_dq" in bwd and "dwt_fa_bwd_dkv" in bwd
 

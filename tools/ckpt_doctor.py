@@ -9,7 +9,7 @@ Walks every generation under a checkpoint dir (posix or object store),
 verifies each against its committed manifest (checkpoint/integrity.py:
 manifest presence, per-rank meta digests, shard-file digests, and with
 --deep per-leaf digests to pinpoint WHICH tensor a corruption hit), and
-prints ONE JSON line on stdout (bench.py contract — machine-readable for
+prints ONE JSON line on stdout (machine-readable for
 CI and cron'd health checks on real TPU runs); human detail goes to
 stderr.  `--repair` moves failing generations to the `.quarantine/`
 sidecar — never deletes — and repoints the tracker at the newest

@@ -1,7 +1,7 @@
 """Thin CI wrapper for graftlint (`python tools/lint.py [args...]`).
 
-Same contract as bench.py: one JSON line on stdout, details on stderr,
-non-zero exit on findings.  `--changed` is the fast pre-commit mode
+One JSON line on stdout, details on stderr, non-zero exit on
+findings.  `--changed` is the fast pre-commit mode
 (git-changed .py files through the jax-free
 ast+protocol+concurrency+schema engines); `--format sarif` swaps the
 stdout line for a SARIF 2.1.0 document for CI annotation;

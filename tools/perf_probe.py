@@ -1,22 +1,18 @@
 """On-chip step decomposition probe.
 
-Times the bench step's components in isolation on the real TPU so kernel
-work targets the measured-largest bucket instead of guesses.  Sync follows
-the bench.py rules (host readback; chain iterations on carried values).
+Times a GPT-2 step's components in isolation on the real TPU so kernel
+work targets the measured-largest bucket instead of guesses.  Sync is a
+host readback; iterations chain on carried values.
 A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_sweep|head|model|opt|step|lib|
-dispatch|fa-variants|quant-variants|rpc] ...  (no args = step/attn/head/
-model/opt).  One JSON line per probe as it finishes, then ONE summary line
+dispatch|rpc] ...  (no args = step/attn/head/model/opt).  One JSON line
+per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
 `dispatch` measures the fused-vs-unfused dispatch-overhead win of
 the K-step driver (trainer/train_step.py) in THIS environment;
-`fa-variants` A/B-measures the DWT_FA_* kernel-variant matrix
-interleaved (same-session, chip drift) via the tuner's scorer;
-`quant-variants` races the dense-matmul precision ladder (f32/bf16
-vs the fp8 kernel the tuner's quant axis swaps in) the same way.
 `rpc` streams per-round control-plane RPCs/s per verb class against a
 per-frame-fsync and a group-commit master, rounds interleaved.
 """
@@ -393,144 +389,6 @@ def probe_dispatch(k: int = 8, steps: int = 32):
           auto_k=auto_fused_steps(t_fused, overhead_s=step_overhead))
 
 
-def probe_fa_variants(rounds: int = 3):
-    """Interleaved A/B over the DWT_FA_* kernel-variant matrix (ISSUE 15).
-
-    The flash-attention fwd+bwd microbench, compiled ONCE per variant
-    under its scoped env flip (auto/tuner.py `variant_env` — the toggles
-    are read at TRACE time, so each variant needs its own jit trace,
-    compiled before any timing), then measured in interleaved rounds:
-    run-to-run noise can exceed a variant's effect, so same-session
-    interleave is the only honest comparison.  Inner repeats chain
-    inside one jit call so the per-dispatch cost is amortized out of
-    short samples.  Scoring reuses the
-    tuner's `InterleavedScorer` (median per candidate, hysteresis keeps
-    the incumbent on a tie) — the probe and the online tuner agree by
-    construction.  On CPU the toggles lower to the reference path and
-    near-equal medians are the expected negative result."""
-    from dlrover_wuqiong_tpu.auto import tuner as vt
-    from dlrover_wuqiong_tpu.ops.flash_attention import flash_attention
-
-    if jax.default_backend() == "tpu":
-        q, k, v = _qkv()
-    else:  # runnable anywhere: nano shape keeps the CPU reference fast
-        ks = jax.random.split(jax.random.PRNGKey(5), 3)
-        q, k, v = (jax.random.normal(key, (2, 2, 128, 64), jnp.bfloat16)
-                   for key in ks)
-
-    def _make_fwdbwd():
-        # a FRESH jitted function object per variant: jit caches on
-        # function identity + signature, never on env, so sharing one
-        # would silently reuse the first variant's trace
-        @jax.jit
-        def fwdbwd(args):
-            q, k, v = args
-
-            def loss(q, k, v):
-                return flash_attention(q, k, v, causal=True).astype(
-                    jnp.float32).sum()
-
-            for _ in range(INNER):
-                dq, dk, dv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-                q, k, v = (dq.astype(q.dtype), dk.astype(k.dtype),
-                           dv.astype(v.dtype))
-            return (q, k, v)
-
-        return fwdbwd
-
-    cands = [var for var in vt.default_variants(jax.default_backend())
-             if not var.fused_steps]  # fused-K is the trainer's axis
-    compiled = {}
-    for var in cands:
-        env = {name: str(var.env.get(name, ""))
-               for name in vt.TRACE_ENV_VARS}
-        fn = _make_fwdbwd()
-        with vt.variant_env(env):  # scoped flip: trace under THIS env
-            arg = fn((q, k, v))
-        _sync(arg)
-        compiled[var.name] = fn
-
-    scorer = vt.InterleavedScorer([var.name for var in cands],
-                                  min_samples=rounds)
-    while not scorer.complete():
-        name = scorer.next_candidate()
-        # already traced: measurement needs no env (read at trace time)
-        t = _time(compiled[name], (q, k, v), iters=2, warmup=1) / INNER
-        scorer.note(name, t)
-    meds = scorer.medians()
-    winner, decided = scorer.winner(incumbent="default")
-    _emit_raw({"probe": "fa_variants", "winner": winner,
-               "decided": decided, "rounds": rounds, "interleaved": True,
-               "medians_ms": {n: round(t * 1e3, 3)
-                              for n, t in sorted(meds.items())}})
-
-
-def probe_quant_variants(rounds: int = 3):
-    """Interleaved A/B over the dense-matmul precision ladder (ISSUE 16).
-
-    f32 vs bf16 vs fp8 (ops/quantization.py fp8_matmul — e4m3 fwd, e5m2
-    bwd) on one projection-shaped fwd+bwd matmul, the op the online
-    tuner's quant axis (DWT_FP8_DENSE) swaps inside the dense blocks.
-    Same discipline as `fa-variants`: a FRESH jitted function per
-    candidate (jit caches on function identity, never on the captured
-    kernel), INNER repeats chained inside one dispatch so the dispatch
-    cost amortizes out, and `InterleavedScorer` medians over
-    same-session interleaved rounds.  On CPU the
-    fp8 path lowers to dequantized f32 emulation and typically LOSES —
-    that honest negative result is exactly why the online tuner, not a
-    static flag, owns the decision on real hardware."""
-    from dlrover_wuqiong_tpu.auto import tuner as vt
-    from dlrover_wuqiong_tpu.ops.quantization import fp8_matmul
-
-    if jax.default_backend() == "tpu":
-        m = n = kdim = 4096
-    else:  # runnable anywhere: small shape keeps CPU emulation fast
-        m = n = kdim = 256
-    ka, kb = jax.random.split(jax.random.PRNGKey(7))
-    a32 = jax.random.normal(ka, (m, kdim), jnp.float32)
-    b32 = jax.random.normal(kb, (kdim, n), jnp.float32)
-
-    def _make(mm, dtype):
-        a, b = a32.astype(dtype), b32.astype(dtype)
-
-        @jax.jit
-        def fwdbwd(args):
-            a, b = args
-
-            def loss(a, b):
-                return mm(a, b).astype(jnp.float32).sum()
-
-            for _ in range(INNER):
-                da, db = jax.grad(loss, argnums=(0, 1))(a, b)
-                a, b = da.astype(a.dtype), db.astype(b.dtype)
-            return (a, b)
-
-        return fwdbwd, (a, b)
-
-    cands = {
-        "dense-f32": _make(jnp.matmul, jnp.float32),
-        "dense-bf16": _make(jnp.matmul, jnp.bfloat16),
-        "fp8": _make(lambda a, b: fp8_matmul(a, b, jnp.bfloat16),
-                     jnp.bfloat16),
-    }
-    for fn, args in cands.values():  # compile before any timing
-        _sync(fn(args))
-
-    scorer = vt.InterleavedScorer(list(cands), min_samples=rounds)
-    while not scorer.complete():
-        name = scorer.next_candidate()
-        fn, args = cands[name]
-        t = _time(fn, args, iters=2, warmup=1) / INNER
-        scorer.note(name, t)
-    meds = scorer.medians()
-    winner, decided = scorer.winner(incumbent="dense-bf16")
-    _emit_raw({"probe": "quant_variants", "winner": winner,
-               "decided": decided, "rounds": rounds, "interleaved": True,
-               "mnk": [m, n, kdim],
-               "medians_ms": {name: round(t * 1e3, 3)
-                              for name, t in sorted(meds.items())}})
-
-
 def probe_splash():
     """jax splash-attention (newer vmapped MQA-style kernel) — causal."""
     try:
@@ -669,8 +527,6 @@ ALL = {"attn": probe_attn, "attn_sweep": probe_attn_sweep, "lib": probe_lib,
        "splash": probe_splash, "dots": probe_dots,
        "head": probe_head, "model": probe_model, "opt": probe_opt,
        "step": probe_step, "dispatch": probe_dispatch,
-       "fa-variants": probe_fa_variants,
-       "quant-variants": probe_quant_variants,
        "rpc": probe_rpc}
 
 

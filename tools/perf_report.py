@@ -5,7 +5,6 @@ Three sources, one schema family (telemetry/perf.py PERF_SNAPSHOT_KEYS):
     python tools/perf_report.py [--addr HOST:PORT]    # live master RPC
     python tools/perf_report.py --flight CKPT_DIR     # offline dumps
     python tools/perf_report.py --baseline CKPT_DIR   # baseline store
-    python tools/perf_report.py --tuning CKPT_DIR     # autotuner winners
 
 Live mode pulls the master's per-node latest PerfSnapshot aggregation
 (each node's BUFFERED latest-SENT-wins PerfSnapshotReport —
@@ -21,18 +20,6 @@ Offline ``--baseline`` reads the versioned perf-baseline store at
 $CKPT_DIR/perf/baseline.json (atomic tmp+rename publishes, robust
 median+MAD per executable key) and reports the rolling stats the
 regression sentinel judges against.
-
-Offline ``--tuning`` reads the variant-autotuner winner store at
-$CKPT_DIR/perf/tuning.json (auto/tuner.py TuningStore — same atomic
-publish discipline) and reports the persisted winner per executable
-family: variant name, its env/fused-K, the measured per-candidate
-medians, the winner's full executable key, and (schema 2) the
-per-geometry winners under each family's ``shape_classes`` map —
-the flat family fields stay the shape-agnostic fallback, so
-pre-shape consumers keep working unchanged.  Live mode carries the
-same signal per node: every PerfQuery snapshot includes the ADD-ONLY
-``tuned_variant`` field, surfaced as the report's ``tuned_variants``
-map.
 """
 
 import os
@@ -123,54 +110,10 @@ def _from_baseline(path: str) -> dict:
             "schema": int(data.get("schema", 0)), "keys": keys}
 
 
-def _from_tuning(path: str) -> dict:
-    from dlrover_wuqiong_tpu.auto.tuner import TuningStore, tuning_path
-
-    # accept the checkpoint dir (store lives at perf/tuning.json under
-    # it) or a direct path to the json
-    cand = path if os.path.isfile(path) else tuning_path(path)
-    if not os.path.isfile(cand):
-        raise FileNotFoundError(
-            f"--tuning: no autotuner winner store at {cand!r}")
-    rows = TuningStore(cand).rows()
-
-    def _rec(r):
-        return {
-            "variant": str(r.get("variant", "")),
-            "env": dict(r.get("env") or {}),
-            "fused_steps": int(r.get("fused_steps") or 0),
-            "windows": int(r.get("windows") or 0),
-            "executable_key": str(r.get("executable_key", "")),
-            "shape_class": str(r.get("shape_class", "")),
-            "medians_s": {name: round(float(m), 6) for name, m in
-                          sorted((r.get("medians") or {}).items())},
-        }
-
-    # v2 nested store: the family winner's fields stay FLAT in the
-    # row (report schema is ADD-ONLY — pre-shape consumers keep
-    # reading winners[fam]["variant"]) with the per-geometry winners
-    # under "shape_classes"
-    families = {}
-    n_shapes = 0
-    for fam in sorted(rows):
-        row = rows[fam]
-        winner = row.get("winner") or {}
-        shapes = row.get("shapes") or {}
-        n_shapes += len(shapes)
-        families[fam] = dict(_rec(winner),
-                             shape_classes={s: _rec(r) for s, r
-                                            in sorted(shapes.items())})
-    return {"source": "tuning", "path": cand,
-            "families": len(families), "shape_classes": n_shapes,
-            "winners": families}
-
-
 def main(argv=None) -> int:
     from dlrover_wuqiong_tpu.common.report_cli import run_report
 
     def _offline(v):
-        if v.get("--tuning"):
-            return _from_tuning(v["--tuning"])
         if v.get("--baseline"):
             return _from_baseline(v["--baseline"])
         if v.get("--flight"):
@@ -182,9 +125,9 @@ def main(argv=None) -> int:
         offline=_offline,
         live=lambda addr, v: _from_master(addr),
         no_addr_error="no master address: pass --addr, set "
-                      "DWT_MASTER_ADDR, or use --flight/--baseline/"
-                      "--tuning CKPT_DIR",
-        value_flags=("--flight", "--baseline", "--tuning"))
+                      "DWT_MASTER_ADDR, or use --flight/--baseline "
+                      "CKPT_DIR",
+        value_flags=("--flight", "--baseline"))
 
 
 if __name__ == "__main__":
