@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import glob
 import json
 import os
@@ -126,11 +127,28 @@ def training_args(out_dir: str, cfg, batch: int, steps: int):
         flash_stage_steps=5, seed=SEED)
 
 
+_KERNELS = ("tpu_custom_call", "dwt_fa_fwd", "dwt_fa_bwd_fused",
+            "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv")
+
+
 def kernel_counts(text: str):
-    return {"tpu_custom_call": text.count("tpu_custom_call"),
-            **{n: text.count(n) for n in (
-                "dwt_fa_fwd", "dwt_fa_bwd_fused", "dwt_fa_bwd_dq",
-                "dwt_fa_bwd_dkv")}}
+    """How often a step calls each attention kernel, from its lowered (or
+    compiled) text.  A `jax.jit` inside the step (the direct route's
+    kernel wrappers) is lowered ONCE, as a private function that every
+    layer calls: a name found in such a body counts once for each call
+    site of the function, through any depth of calls."""
+    parts = re.split(r"func\.func (?:\w+ )?@([\w.]+)", text)
+    bodies = dict(zip(parts[1::2], parts[2::2]))
+
+    @functools.lru_cache(maxsize=None)
+    def runs(fn: str) -> int:  # times a step runs `fn`; main has no caller
+        sites = {g: body.count(f"call @{fn}(")
+                 for g, body in bodies.items()}
+        return sum(n * runs(g) for g, n in sites.items() if n) or 1
+
+    return {n: parts[0].count(n) + sum(
+        body.count(n) * runs(fn) for fn, body in bodies.items())
+        for n in _KERNELS}
 
 
 def tree_bit_equal(a, b) -> bool:
@@ -174,7 +192,9 @@ def phase_kernel(cfg, batch: int, attn_batch: int = 4):
     from dlrover_wuqiong_tpu.models.gpt import GPT
     from dlrover_wuqiong_tpu.ops.flash_attention import (
         _attention_reference,
+        attention_route,
         flash_attention,
+        flash_attention_projected,
     )
 
     t_phase = time.monotonic()
@@ -210,6 +230,54 @@ def phase_kernel(cfg, batch: int, attn_batch: int = 4):
                      / jnp.max(jnp.abs(b)))
                for a, b in zip(grads, ref_grads)]
         errs[name] = {"fwd_max_abs": fwd, "grad_rel_dq_dk_dv": rel}
+        ok = ok and np.isfinite(fwd) and fwd <= 2e-2 and \
+            all(np.isfinite(r) and r <= 5e-2 for r in rel)
+
+    # the projections' own (b, t, h*d) layout, which the model takes
+    # where its heads fall on lane slabs: c_attn's q, k and v side by
+    # side in ONE array at this model's shape (two heads of 64 a slab,
+    # the fused backward), and q, k, v apart at d = 128 over twice the
+    # block (a head a slab, the dq and dk/dv kernels)
+    def heads_first(x, h):          # (b, t, h*d) -> (b, h, t, d)
+        return x.reshape(*x.shape[:2], h, -1).transpose(0, 2, 1, 3)
+
+    d128 = (2, 2 * 1024, 4 * 128)
+    for name, n_head, proj in (
+            ("direct_qkv", h, (jnp.concatenate([
+                x.transpose(0, 2, 1, 3).reshape(attn_batch, t, h * d)
+                for x in (q, k, v)], axis=-1),)),
+            ("direct_d128", 4, tuple(
+                jax.random.normal(kx, d128, jnp.bfloat16)
+                for kx in jax.random.split(jax.random.PRNGKey(SEED + 1),
+                                           3)))):
+        width = proj[0].shape[-1] // (3 // len(proj))
+        gp = jax.random.normal(jax.random.PRNGKey(SEED + 2),
+                               proj[0].shape[:2] + (width,), jnp.bfloat16)
+        assert attention_route(n_head, width // n_head)[0] == "direct"
+
+        def direct_loss(proj):
+            out = flash_attention_projected(proj, n_head)
+            return (out.astype(jnp.float32)
+                    * gp.astype(jnp.float32)).sum(), out
+
+        def plain_loss(proj):
+            parts = proj if len(proj) == 3 else jnp.split(proj[0], 3, -1)
+            out = _attention_reference(
+                *(heads_first(x.astype(jnp.float32), n_head)
+                  for x in parts), True,
+                1.0 / float(np.sqrt(width // n_head)))
+            out = out.transpose(0, 2, 1, 3).reshape(gp.shape)
+            return (out * gp.astype(jnp.float32)).sum(), out
+
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            direct_loss, has_aux=True))(proj)
+        (_, want), want_grads = jax.jit(jax.value_and_grad(
+            plain_loss, has_aux=True))(proj)
+        fwd = float(jnp.max(jnp.abs(out.astype(jnp.float32) - want)))
+        rel = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+                     / jnp.max(jnp.abs(b)))
+               for a, b in zip(grads, want_grads)]
+        errs[name] = {"fwd_max_abs": fwd, "grad_rel": rel}
         ok = ok and np.isfinite(fwd) and fwd <= 2e-2 and \
             all(np.isfinite(r) and r <= 5e-2 for r in rel)
 

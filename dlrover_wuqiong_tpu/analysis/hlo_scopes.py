@@ -172,3 +172,36 @@ def scope_table(hlo_text: str) -> Dict[str, str]:
                 scope = "ragged_dot"
             table[ins["name"]] = scope
     return table
+
+
+# opcodes that move data and compute nothing; a fusion of nothing else
+# (beside the parameters, constants and bitcasts every fusion holds) is
+# a re-layout too
+_MOVES = frozenset({"copy", "transpose", "reshape", "slice", "concatenate",
+                    "pad", "dynamic-slice", "dynamic-update-slice"})
+_FREE = frozenset({"parameter", "constant", "bitcast", "tuple",
+                   "get-tuple-element", "broadcast", "iota"})
+
+
+def relayouts(hlo_text: str, under: str, outside=()) -> Dict[str, str]:
+    """{instruction name: opcode} of the device ops whose scope holds the
+    component `under`, holds none of `outside`, and that only MOVE data:
+    a `copy`, `transpose` or materialised `reshape` of the module, or a
+    fusion of nothing but such ops — the compiled program's own count of
+    the splits, cuts to heads, transposes and joins around a kernel.
+    Kernels (`custom-call`) are never among them."""
+    comps = parse_computations(hlo_text)
+    bodies = {name: {i["opcode"] for i in body} - _FREE
+              for name, body in comps.items()}
+    opcodes = {i["name"]: i for body in comps.values() for i in body}
+    found: Dict[str, str] = {}
+    for name, scope in scope_table(hlo_text).items():
+        parts = scope.split("/")
+        if under not in parts or any(o in parts for o in outside):
+            continue
+        ins = opcodes[name]
+        moved = bodies.get(ins["calls"], {""}) if ins["opcode"] == "fusion" \
+            else {ins["opcode"]}
+        if moved and moved <= _MOVES:
+            found[name] = ins["opcode"]
+    return found

@@ -93,23 +93,23 @@ class CausalSelfAttention(nn.Module):
         cfg = self.config
         B, T, C = x.shape
         qkv = dense(cfg, 3 * C, "c_attn")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, T, cfg.n_head, cfg.head_dim)
-        k = k.reshape(B, T, cfg.n_head, cfg.head_dim)
-        v = v.reshape(B, T, cfg.n_head, cfg.head_dim)
         if cfg.use_flash_attention:
-            from .attention import attend
+            from .attention import attend_projected
 
-            y = attend(q, k, v, cfg, causal=True)
+            # c_attn's output as it is: where the heads fall on lane
+            # slabs the kernels index it, and nothing is split or
+            # transposed (models/attention.py)
+            y = attend_projected((qkv,), cfg.n_head, cfg, causal=True)
         else:
+            q, k, v = (t.reshape(B, T, cfg.n_head, cfg.head_dim)
+                       for t in jnp.split(qkv, 3, axis=-1))
             att = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
                 jnp.float32(cfg.head_dim)).astype(cfg.dtype)
             mask = jnp.tril(jnp.ones((T, T), bool))
             att = jnp.where(mask, att, jnp.finfo(att.dtype).min)
             att = jax.nn.softmax(att.astype(jnp.float32),
                                  axis=-1).astype(cfg.dtype)
-            y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
-        y = y.reshape(B, T, C)
+            y = jnp.einsum("bhqk,bkhd->bqhd", att, v).reshape(B, T, C)
         y = dense(cfg, C, "c_proj")(y)
         if cfg.dropout > 0:
             y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)

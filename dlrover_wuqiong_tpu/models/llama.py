@@ -107,18 +107,27 @@ def rope_freqs(head_dim: int, max_seq: int, theta: float):
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
-def apply_rope(x, cos, sin, positions=None):
-    """x: (b, s, h, d); rotate pairs (even, odd interleave by halves)."""
-    b, s, h, d = x.shape
-    if positions is None:
-        c = cos[:s][None, :, None, :]
-        si = sin[:s][None, :, None, :]
-    else:
-        c = cos[positions][:, :, None, :]
-        si = sin[positions][:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * c - x2 * si, x2 * c + x1 * si], axis=-1)
-    return out.astype(x.dtype)
+def apply_rope(x, cos, sin):
+    """Rotate each head's pairs (even, odd interleaved by halves).  x is
+    (b, s, h, d), or the projections' own (b, s, h*d) with the heads side
+    by side and never cut apart — the one rotation of both layouts.
+
+    A head's two halves trade places by two rolls of the last axis and a
+    select (a roll never wraps into a lane that is kept; over one head's
+    d the two rolls are the same swap), and the sign rides on the sine:
+    two products and one sum an element."""
+    s, lanes, half = x.shape[1], x.shape[-1], cos.shape[-1]
+    heads = lanes // (2 * half)
+    c = jnp.tile(jnp.concatenate([cos[:s], cos[:s]], axis=-1), (1, heads))
+    si = jnp.tile(jnp.concatenate([-sin[:s], sin[:s]], axis=-1),
+                  (1, heads))
+    if x.ndim == 4:
+        c, si = c[:, None], si[:, None]
+    x32 = x.astype(jnp.float32)
+    second = (jnp.arange(lanes) // half) % 2 == 1  # a head's upper half
+    partner = jnp.where(second, jnp.roll(x32, half, axis=-1),
+                        jnp.roll(x32, -half, axis=-1))
+    return (x32 * c + partner * si).astype(x.dtype)
 
 
 class LlamaAttention(nn.Module):
@@ -126,6 +135,7 @@ class LlamaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin):
+        from .attention import attend, attend_projected, goes_direct
         from .fp8 import dense
 
         cfg = self.config
@@ -138,20 +148,26 @@ class LlamaAttention(nn.Module):
             with jax.named_scope("qk_norm"):
                 q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
                 k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
-        q = q.reshape(B, T, cfg.num_heads, hd)
-        k = k.reshape(B, T, cfg.num_kv_heads, hd)
-        v = dense(cfg, cfg.num_kv_heads * hd, "v_proj", use_bias=False)(
-            x).reshape(B, T, cfg.num_kv_heads, hd)
+        v = dense(cfg, cfg.num_kv_heads * hd, "v_proj", use_bias=False)(x)
+        # where a head is a lane slab the kernels index q, k and v in the
+        # projections' (B, T, heads*hd), and nothing here cuts them to
+        # heads (models/attention.py); every other call does, as it
+        # always did, before the rotation
+        direct = cfg.use_flash_attention and goes_direct(
+            cfg, cfg.num_heads, hd, T)
+        if not direct:
+            q = q.reshape(B, T, cfg.num_heads, hd)
+            k = k.reshape(B, T, cfg.num_kv_heads, hd)
+            v = v.reshape(B, T, cfg.num_kv_heads, hd)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        # GQA: repeat kv heads
         rep = cfg.num_heads // cfg.num_kv_heads
-        if rep > 1:
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        if cfg.use_flash_attention:
-            from .attention import attend
-
+        if rep > 1:  # GQA: repeat kv heads
+            k, v = (jnp.repeat(t.reshape(B, T, cfg.num_kv_heads, hd), rep,
+                               axis=2).reshape(q.shape) for t in (k, v))
+        if direct:
+            y = attend_projected((q, k, v), cfg.num_heads, cfg, causal=True)
+        elif cfg.use_flash_attention:
             y = attend(q, k, v, cfg, causal=True)
         else:
             att = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(

@@ -32,14 +32,44 @@ Design (FA2 scheme, canonical Mosaic structure):
   outer, q inner) — each recomputing p = exp(s - lse) per tile IN
   TRANSPOSED SPACE (queries in lanes) so the (sq, sk) attention matrix
   never hits HBM and the per-row lse/delta broadcast without relayouts.
-  delta = rowsum(dO ∘ O) is one fused XLA reduce into the row-major
-  (bh, 1, sq) layout the kernels consume.  One block each way takes ONE
-  fused kernel instead (dq, dk and dv from a single recompute of p).
-- head_dim runs natively when lane-aligned (d % 8 == 0, e.g. GPT-2's 64);
-  otherwise it is zero-padded to the 128 boundary.  lse lives as (bh, sq)
-  f32 everywhere — residuals, kernel outputs and inputs — with a cheap
-  in-kernel (block_q, 1) <-> (block_q,) relayout instead of padded HBM
-  traffic; the causal mask is one broadcast compare, not 2D iotas.
+  One block each way takes ONE fused kernel instead (dq, dk and dv from
+  a single recompute of p).
+- TWO LAYOUTS, one set of kernels.  TRANSPOSED: `flash_attention` /
+  `flash_attention_with_lse` take (b, h, s, d) and index the flat
+  (b*h, s, d) arrays a group of `_fit_pack` heads a grid step; head_dim
+  runs natively when lane-aligned (d % 8 == 0), else zero-padded to the
+  128 boundary.  At d = 64 those arrays are stored padded to 128 lanes:
+  every read, write and transpose around them moves twice its bytes.
+  DIRECT: `flash_attention_projected` takes the projections' own
+  (b, s, h*d) output — GPT-2's whole `c_attn` (b, s, 3*h*d) array,
+  handed in three times — and a second set of BlockSpecs (`_Slabs`,
+  `_block_specs`) walks it where it lies: a grid step is one batch row,
+  a run of positions and ONE 128-lane SLAB, q's, k's and v's slabs found
+  by a column offset.  o, dq, dk and dv are written the same way, so the
+  output projection and the backward of the input projection take them
+  as they are and the compiled step holds no split, cut to heads or
+  transpose around the kernels.  `attention_route(h, d)` says which
+  layout a shape takes: direct when the heads fall on slab boundaries —
+  a head is a slab (d % 128 == 0), or two heads of 64 share one and
+  their number is even — transposed otherwise (GPT-2 XL's 25 heads,
+  ring and Ulysses, the shard_map of a mesh).
+- two heads in a slab are told apart by a lane MASK, never by a slice
+  (`_own`, `_join`): a 64-lane slice, roll or concatenate at a non-128
+  offset is a relayout a head (a kernel built that way once ran slower
+  than the transposes and the flat kernel together: PERF.md section 6,
+  PR 29), while every matmul of the d = 64 kernels is 64 wide — half an
+  MXU — so a 128-wide operand
+  whose other head's lanes are zero costs the same passes and adds
+  exactly 0 to the sum.  q (and dO, where it is contracted over d) is
+  masked before the contraction; a product against the slab's unmasked
+  k or v is right in the head's own lanes, which one select a slab
+  keeps.  The body is one: `slab_heads` 1 traces none of it.
+- lse lives as (bh, 1, sq) f32 everywhere — residuals, kernel outputs and
+  inputs, both layouts — with a cheap in-kernel (block_q, 1) <->
+  (1, block_q) relayout instead of padded HBM traffic; the causal mask is
+  one broadcast compare, not 2D iotas.  delta is computed outside into
+  the same form, except where two heads share a slab: there the backward
+  kernels take o and sum each head's lanes themselves (`_delta_rows`).
 - on non-TPU backends a jnp reference path keeps tests runnable; the kernels
   themselves are additionally tested in interpret mode.
 """
@@ -48,7 +78,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -319,14 +349,73 @@ def _each_head(pack: int, work, body):
 
 class _BandState(dict):
     """Stands in for the forward's VMEM scratch where a band's softmax
-    state never outlives the band: values, keyed by the rows alone (the
-    head in front may be a loop index)."""
+    state never outlives the band: values, keyed by the rows alone where
+    the head in front is a loop index, by head and rows where it is
+    static (the two heads of a slab are both live until the band ends)."""
+
+    @staticmethod
+    def _key(idx):
+        return idx if isinstance(idx[0], int) else idx[1:]
 
     def __setitem__(self, idx, value):
-        super().__setitem__(idx[1:], value)
+        super().__setitem__(self._key(idx), value)
 
     def __getitem__(self, idx):
-        return super().__getitem__(idx[1:])
+        return super().__getitem__(self._key(idx))
+
+
+# ------------------------------------------------- two heads in one slab
+#
+# A unit of the kernels' work is what `ref[u]` holds: one head in the
+# transposed layout (`slab_heads` 1: nothing below traces anything), or
+# one 128-lane SLAB of the projections' own layout, which at d = 64 holds
+# TWO heads side by side (`slab_heads` 2).  The two are told apart by a
+# lane MASK, never by a slice: an operand whose other head's lanes are
+# zero contracts over d to the one head's scores at the MXU passes the
+# 64-wide operand took, and a product against an unmasked operand is
+# right in the head's own lanes, which a select keeps.
+
+
+def _head(u, a: int, slab_heads: int):
+    """Index of head `a` of unit `u` in the per-head arrays (lse, delta,
+    softmax state)."""
+    return u if slab_heads == 1 else u * slab_heads + a
+
+
+def _own_lanes(shape, a: int):
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return (lane < shape[-1] // 2) == (a == 0)
+
+
+def _own(x, a: int, slab_heads: int):
+    """`x` with the lanes of the slab's other head zeroed."""
+    if slab_heads == 1:
+        return x
+    return jnp.where(_own_lanes(x.shape, a), x, jnp.zeros_like(x))
+
+
+def _join(xs, width: int):
+    """One (rows, width) value from one (rows, width) or (rows, 1) value
+    per head of the slab: each head's own lanes."""
+    if len(xs) == 1:
+        return xs[0]
+    lo, hi = xs
+    shape = (lo.shape[0], width)
+    return jnp.where(_own_lanes(shape, 0), jnp.broadcast_to(lo, shape),
+                     jnp.broadcast_to(hi, shape))
+
+
+def _delta_rows(aux_ref, do, a: int, rows, lanes, slab_heads: int,
+                from_o: bool):
+    """delta = rowsum(dO ∘ O) of head `a` as a (1, rows) row: read from
+    the (bh, 1, sq) array the wrapper computed (`aux_ref` is delta), or
+    with `from_o` computed here from the slab's o block (`aux_ref` is o;
+    `do` the slab's dO rows): the head's own lanes of the product, summed
+    across lanes, relaid once as the forward relays its lse."""
+    if not from_o:
+        return aux_ref[lanes]
+    prod = do.astype(jnp.float32) * aux_ref[rows].astype(jnp.float32)
+    return _own(prod, a, slab_heads).sum(axis=-1, keepdims=True).T
 
 
 # ------------------------------------------------------------- forward kernel
@@ -349,8 +438,9 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                    num_kv: int, causal: bool, sm_scale: float,
                    block_q: int, block_k: int, kv_offset: int, pack: int,
                    diag_off: Optional[int] = None,
-                   tile: Optional[int] = None):
-    """Packed forward: refs carry `pack` heads in the leading dim.
+                   tile: Optional[int] = None, slab_heads: int = 1):
+    """Packed forward: refs carry `pack` units in the leading dim, each a
+    head or a slab of `slab_heads` heads (above).
 
     Leading-dim indexing (ref[hh]) is a free address offset (unlike lane
     slicing), so packing amortizes per-grid-step fixed costs and generates
@@ -364,17 +454,19 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     of the tile cost nothing.
 
     The softmax state (m, l, acc) lives in VMEM scratch across the KV
-    sweep.  A causal call whose keys are ONE block is given none: each
-    query tile is a plain softmax over the static prefix it sees, its
-    state stays values (dicts keyed like the scratch) and its o and lse
-    are written where they are computed.
+    sweep: m and l a head, acc a unit.  A causal call whose keys are ONE
+    block is given none: each query tile is a plain softmax over the
+    static prefix it sees, its state stays values (dicts keyed like the
+    scratch) and its o and lse are written where they are computed.
     """
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     single = num_kv == 1  # whole KV sweep in one step: no online state
-    direct = not scratch
+    stateless = not scratch
     m_scr, l_scr, acc_scr = scratch or (
         _BandState(), _BandState(), _BandState())
+    width = o_ref.shape[-1]
+    heads = range(slab_heads)
 
     if causal:
         # block fully masked when its first key exceeds the last query's reach
@@ -382,101 +474,117 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     else:
         run = True
 
-    def _empty(rows, n):
-        m_scr[rows] = jnp.full((n, 1), NEG_INF, jnp.float32)
-        l_scr[rows] = jnp.zeros((n, 1), jnp.float32)
-        acc_scr[rows] = jnp.zeros((n, o_ref.shape[-1]), jnp.float32)
+    def _empty(hh, rows, n):
+        for a in heads:
+            head = (_head(hh, a, slab_heads),) + rows
+            m_scr[head] = jnp.full((n, 1), NEG_INF, jnp.float32)
+            l_scr[head] = jnp.zeros((n, 1), jnp.float32)
+        acc_scr[(hh,) + rows] = jnp.zeros((n, width), jnp.float32)
 
-    def _finish(rows, lanes):
-        l = l_scr[rows]
-        l_safe = jnp.where(l > 0, l, 1.0)
-        o_ref[rows] = (acc_scr[rows] / l_safe).astype(o_ref.dtype)
-        # empty key set → logsumexp = -inf (matches the jnp reference
-        # path and long_context._merge_partials' isfinite handling).
-        # m is in log2 units (LOG2E folded into the q pre-scale) —
-        # convert back so the public lse stays natural-log.
-        lse = jnp.where(l > 0, m_scr[rows] * (1.0 / LOG2E)
-                        + jnp.log(l_safe), -jnp.inf)
-        # lse lives as (bh, 1, sq) in HBM — a (…, sq, 1) f32 array pads
-        # its minor dim 128x in the tiled layout (~150MB of padding
-        # traffic per call at the bench shape); with sq in lanes the
-        # padding is 8x of a tiny array, and the (rows, 1) -> (1, rows)
-        # relayout happens once per query tile in VMEM
-        lse_ref[lanes] = lse.T
+    def _finish(hh, rows, lanes):
+        ls = [l_scr[(_head(hh, a, slab_heads),) + rows] for a in heads]
+        safe = [jnp.where(l > 0, l, 1.0) for l in ls]
+        o_ref[(hh,) + rows] = (acc_scr[(hh,) + rows]
+                               / _join(safe, width)).astype(o_ref.dtype)
+        for a, l, l_safe in zip(heads, ls, safe):
+            head = _head(hh, a, slab_heads)
+            # empty key set → logsumexp = -inf (matches the jnp reference
+            # path and long_context._merge_partials' isfinite handling).
+            # m is in log2 units (LOG2E folded into the q pre-scale) —
+            # convert back so the public lse stays natural-log.
+            lse = jnp.where(l > 0, m_scr[(head,) + rows] * (1.0 / LOG2E)
+                            + jnp.log(l_safe), -jnp.inf)
+            # lse lives as (bh, 1, sq) in HBM — a (…, sq, 1) f32 array
+            # pads its minor dim 128x in the tiled layout (~150MB of
+            # padding traffic per call at the bench shape); with sq in
+            # lanes the padding is 8x of a tiny array, and the (rows, 1)
+            # -> (1, rows) relayout happens once per query tile in VMEM
+            lse_ref[(head,) + lanes] = lse.T
 
-    if not direct and not single:
+    if not stateless and not single:
         @pl.when(ki == 0)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
-    elif direct and kv_offset < 0:
+    elif stateless and kv_offset < 0:
         # with sq > sk a q block can be FULLY masked (run=False): _inner
         # never runs — it ends on the empty-key values, o=0, lse=-inf
         @pl.when(jnp.logical_not(run))
         def _masked():
             for hh in range(pack):
-                _empty((hh,), block_q)
-                _finish((hh,), (hh,))
+                _empty(hh, (), block_q)
+                _finish(hh, (), ())
 
     def _inner(mask_block: bool):
         work = _block_work(mask_block, False, False, block_q, block_k,
                            diag_off, tile, qi, ki, kv_offset)
 
-        def _head(hh):
+        def _unit(hh):
             for q0, q1, pieces in work:
-                rows = (hh,) + _rows(q0, q1, block_q)
+                rows = _rows(q0, q1, block_q)
                 if pieces:
                     _band(hh, rows, pieces)
                 else:  # sq > sk in one block: no row of the tile sees a key
-                    _empty(rows, q1 - q0)
-                if direct:
-                    _finish(rows, (hh,) + _lanes(q0, q1, block_q))
+                    _empty(hh, rows, q1 - q0)
+                if stateless:
+                    _finish(hh, rows, _lanes(q0, q1, block_q))
 
-        _each_head(pack, work, _head)
+        _each_head(pack, work, _unit)
 
     def _band(hh, rows, pieces):
         # pre-scale q (block_q x d) instead of s (block_q x block_k):
         # one fewer full VPU pass over the score matrix.  LOG2E folds
         # here too: s lives in log2 units, every exp below is a bare
         # exp2, and only the final lse converts back to natural log.
-        q = (q_ref[rows].astype(jnp.float32)
+        q = (q_ref[(hh,) + rows].astype(jnp.float32)
              * (sm_scale * LOG2E)).astype(q_ref.dtype)
         kv = [(k_ref[(hh,) + _rows(k0, k1, block_k)],
                v_ref[(hh,) + _rows(k0, k1, block_k)])
               for k0, k1, _ in pieces]
-        # bf16 MXU multiply, f32 accumulate — never cast operands up
-        ss = [_dot_t(q, k) for k, _ in kv]
-        ss = [s if mask is None else jnp.where(mask, s, NEG_INF)
-              for s, (_, _, mask) in zip(ss, pieces)]
-        m_prev = None if single else m_scr[rows]           # (rows, 1)
-        m_new = _fold(jnp.maximum, ss).max(axis=-1, keepdims=True)
-        if not single:
-            m_new = jnp.maximum(m_prev, m_new)
-        ps = [jnp.exp2(s - m_new) for s in ss]
-        if kv_offset < 0:
-            # rows can be fully masked only when sq > sk: exp(0)=1 junk
-            ps = [p if mask is None else jnp.where(s <= NEG_INF, 0.0, p)
-                  for p, s, (_, _, mask) in zip(ps, ss, pieces)]
-        if not single:
-            alpha = jnp.exp2(m_prev - m_new)
-        m_scr[rows] = m_new
+        alphas, pvs = [], []
+        for a in heads:
+            head = (_head(hh, a, slab_heads),) + rows
+            # bf16 MXU multiply, f32 accumulate — never cast operands up
+            qa = _own(q, a, slab_heads)
+            ss = [_dot_t(qa, k) for k, _ in kv]
+            ss = [s if mask is None else jnp.where(mask, s, NEG_INF)
+                  for s, (_, _, mask) in zip(ss, pieces)]
+            m_prev = None if single else m_scr[head]       # (rows, 1)
+            m_new = _fold(jnp.maximum, ss).max(axis=-1, keepdims=True)
+            if not single:
+                m_new = jnp.maximum(m_prev, m_new)
+            ps = [jnp.exp2(s - m_new) for s in ss]
+            if kv_offset < 0:
+                # rows can be fully masked only when sq > sk: exp(0)=1 junk
+                ps = [p if mask is None else jnp.where(s <= NEG_INF, 0.0, p)
+                      for p, s, (_, _, mask) in zip(ps, ss, pieces)]
+            if not single:
+                alphas.append(jnp.exp2(m_prev - m_new))
+            m_scr[head] = m_new
 
-        # thunks: a whole block's ops stay in the order they always had
-        # (scratch read, then the reduction or the dot)
-        def l_new():
-            return _fold(jnp.add, ps).sum(axis=-1, keepdims=True)
+            # thunks: a whole block's ops stay in the order they always
+            # had (scratch read, then the reduction or the dot)
+            def l_new(ps=ps):
+                return _fold(jnp.add, ps).sum(axis=-1, keepdims=True)
 
-        def pv():
-            return functools.reduce(jnp.add, [
-                _dot(p.astype(v.dtype), v) for p, (_, v) in zip(ps, kv)])
+            def pv(ps=ps):
+                return functools.reduce(jnp.add, [
+                    _dot(p.astype(v.dtype), v) for p, (_, v) in zip(ps, kv)])
 
+            pvs.append(pv)
+            if single:
+                l_scr[head] = l_new()
+            else:
+                l_scr[head] = l_scr[head] * alphas[-1] + l_new()
+
+        # a head's p against the slab's v is right in its own lanes
+        acc = (hh,) + rows
         if single:
-            l_scr[rows] = l_new()
-            acc_scr[rows] = pv()
+            acc_scr[acc] = _join([pv() for pv in pvs], width)
         else:
-            l_scr[rows] = l_scr[rows] * alpha + l_new()
-            acc_scr[rows] = acc_scr[rows] * alpha + pv()
+            acc_scr[acc] = acc_scr[acc] * _join(alphas, width) + _join(
+                [pv() for pv in pvs], width)
 
     if causal:
         # only blocks straddling the diagonal pay for mask generation
@@ -495,11 +603,11 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         def _compute():
             _inner(False)
 
-    if not direct:
+    if not stateless:
         @pl.when(ki == num_kv - 1)
         def _finalize():
             for hh in range(pack):
-                _finish((hh,), (hh,))
+                _finish(hh, (), ())
 
 
 def _fit_pack(bh: int) -> int:
@@ -524,48 +632,99 @@ def _causal_plan(causal: bool, num_q: int, num_kv: int, block_q: int,
             "tile": _causal_tile(block_q, block_k, tile)}
 
 
+class _Slabs(NamedTuple):
+    """Where the kernels find the heads in the projections' own
+    (b, s, lanes) arrays: a batch row's heads lie side by side in
+    `per_row` slabs of `width` lanes, `heads` heads each; q's, k's and
+    v's first slab in its array is `offsets` (one array handed in three
+    times has all three: GPT-2's `c_attn` output)."""
+    per_row: int
+    heads: int
+    width: int
+    offsets: Tuple[int, int, int] = (0, 0, 0)
+
+
+def _block_specs(slabs: Optional[_Slabs], pack: int, d: int):
+    """(operand, row): the BlockSpec of a (block, d) piece of q/k/v/o/dO
+    and of a (1, block_q) piece of lse/delta, both as functions of the
+    grid axis (after the first) that walks the sequence, None = the one
+    block.  The first grid axis walks groups of `pack` heads of the
+    transposed (bh, s, d) arrays, or with `slabs` the slabs of every
+    batch row of (b, s, lanes) arrays; lse and delta are (bh, 1, s) in
+    both."""
+    def at(ij, axis):
+        return 0 if axis is None else ij[axis]
+
+    def operand(block, axis, first_slab=0):
+        if slabs is None:
+            return pl.BlockSpec((pack, block, d),
+                                lambda g, *ij: (g, at(ij, axis), 0))
+        n = slabs.per_row
+        return pl.BlockSpec(
+            (1, block, slabs.width),
+            lambda g, *ij: (g // n, at(ij, axis), first_slab + g % n))
+
+    def row(block_q, axis):
+        heads = pack * (slabs.heads if slabs else 1)
+        return pl.BlockSpec((heads, 1, block_q),
+                            lambda g, *ij: (g, 0, at(ij, axis)))
+
+    return operand, row
+
+
+def _geometry(q, slabs: Optional[_Slabs]):
+    """(heads in all, lanes a unit, units a grid step, grid steps along
+    the first axis, lanes of o's rows) of a call on `q`'s layout."""
+    if slabs is None:
+        bh, _, d = q.shape
+        pack = _fit_pack(bh)
+        return bh, d, pack, bh // pack, d
+    groups = q.shape[0] * slabs.per_row
+    return (groups * slabs.heads, slabs.width, 1, groups,
+            slabs.per_row * slabs.width)
+
+
 def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
                        block_q: int, block_k: int, interpret: bool,
-                       tile: Optional[int] = None):
-    """q: (bh, sq, d), k/v: (bh, sk, d) → (o, lse (bh, 1, sq) f32).
+                       tile: Optional[int] = None,
+                       slabs: Optional[_Slabs] = None):
+    """q: (bh, sq, d), k/v: (bh, sk, d) → (o, lse (bh, 1, sq) f32); with
+    `slabs`, q/k/v: (b, s, lanes) as `_Slabs` says → (o (b, sq, h*d),
+    lse (b*h, 1, sq)).
 
     `tile` overrides `_causal_tile` (tests and sweeps: a tile the size of
     the block is the whole-block mask); no caller of the package sets it.
     """
-    bh, sq, d = q.shape
-    sk = k.shape[1]
+    sq, sk = q.shape[1], k.shape[1]
+    bh, d, pack, groups, lanes = _geometry(q, slabs)
+    heads = slabs.heads if slabs else 1
+    qo, ko, vo = slabs.offsets if slabs else (0, 0, 0)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     num_kv = sk // block_k
-    pack = _fit_pack(bh)
-    grid = (bh // pack, sq // block_q, num_kv)
+    grid = (groups, sq // block_q, num_kv)
+    operand, row = _block_specs(slabs, pack, d)
 
     kernel = functools.partial(
         _fa_fwd_kernel, num_kv=num_kv, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, kv_offset=sk - sq, pack=pack,
         **_causal_plan(causal, sq // block_q, num_kv, block_q, block_k,
-                       sk - sq, tile))
+                       sk - sq, tile), **_slab_heads(slabs))
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((pack, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((pack, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((pack, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((pack, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((pack, 1, block_q), lambda b, i, j: (b, 0, i)),
-        ),
+        in_specs=[operand(block_q, 0, qo), operand(block_k, 1, ko),
+                  operand(block_k, 1, vo)],
+        out_specs=(operand(block_q, 0), row(block_q, 0)),
         out_shape=(
-            _out_struct((bh, sq, d), q.dtype, q),
+            _out_struct(q.shape[:2] + (lanes,), q.dtype, q),
             _out_struct((bh, 1, sq), jnp.float32, q),
         ),
         # softmax state across the KV sweep; a causal call with one KV
         # block ends every query tile where it computes it
         scratch_shapes=[] if causal and num_kv == 1 else [
-            pltpu.VMEM((pack, block_q, 1), jnp.float32),
-            pltpu.VMEM((pack, block_q, 1), jnp.float32),
+            pltpu.VMEM((pack * heads, block_q, 1), jnp.float32),
+            pltpu.VMEM((pack * heads, block_q, 1), jnp.float32),
             pltpu.VMEM((pack, block_q, d), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
@@ -574,6 +733,12 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
         name="dwt_fa_fwd",
     )(q, k, v)
     return o, lse
+
+
+def _slab_heads(slabs: Optional[_Slabs]) -> dict:
+    """The kernels' static `slab_heads`; nothing for the transposed
+    layout, whose unit is a head."""
+    return {} if slabs is None else {"slab_heads": slabs.heads}
 
 
 # ------------------------------------------------------------ backward kernels
@@ -616,9 +781,11 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       sm_scale: float, block_q: int, block_k: int,
                       kv_offset: int, pack: int,
                       diag_off: Optional[int] = None,
-                      tile: Optional[int] = None):
+                      tile: Optional[int] = None, slab_heads: int = 1,
+                      delta_from_o: bool = False):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    heads = range(slab_heads)
 
     @pl.when(ki == 0)
     def _init():
@@ -634,21 +801,30 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # per piece, its keys the prefix the tile sees
         work = _block_work(mask_block, False, True, block_q, block_k,
                            diag_off, tile, qi, ki, kv_offset)
-        def _head(hh):
+        def _unit(hh):
             for q0, q1, pieces in work:
                 rows = (hh,) + _rows(q0, q1, block_q)
-                lanes = (hh,) + _lanes(q0, q1, block_q)
+                lanes = [(_head(hh, a, slab_heads),)
+                         + _lanes(q0, q1, block_q) for a in heads]
                 for k0, k1, mask in pieces:
                     keys = (hh,) + _rows(k0, k1, block_k)
                     k = k_ref[keys]
-                    pT = _p_transposed(q_ref[rows], k, lse_ref[lanes], mask,
-                                       sm_scale)
-                    dpT = _dot_t(v_ref[keys], do_ref[rows])  # (keys, rows)
-                    dsT = (pT * (dpT - delta_ref[lanes])
-                           * sm_scale).astype(k.dtype)
-                    dq_scr[rows] += _dot_c0(dsT, k)          # (rows, d)
+                    dsTs = []
+                    for a in heads:
+                        pT = _p_transposed(
+                            _own(q_ref[rows], a, slab_heads), k,
+                            lse_ref[lanes[a]], mask, sm_scale)
+                        v, do = v_ref[keys], do_ref[rows]
+                        dpT = _dot_t(v, _own(do, a, slab_heads))
+                        delta = _delta_rows(delta_ref, do, a, rows, lanes[a],
+                                            slab_heads, delta_from_o)
+                        dsTs.append((pT * (dpT - delta)
+                                     * sm_scale).astype(k.dtype))
+                    dq_scr[rows] += _join(
+                        [_dot_c0(dsT, k) for dsT in dsTs],
+                        dq_scr.shape[-1])                    # (rows, d)
 
-        _each_head(pack, work, _head)
+        _each_head(pack, work, _unit)
 
     if causal:
         diag = (qi * block_q + kv_offset < (ki + 1) * block_k) & run
@@ -677,9 +853,11 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        causal: bool, sm_scale: float, block_q: int,
                        block_k: int, kv_offset: int, pack: int,
                        diag_off: Optional[int] = None,
-                       tile: Optional[int] = None):
+                       tile: Optional[int] = None, slab_heads: int = 1,
+                       delta_from_o: bool = False):
     ki = pl.program_id(1)
     qi = pl.program_id(2)
+    heads = range(slab_heads)
 
     @pl.when(qi == 0)
     def _init():
@@ -695,24 +873,36 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # by key tile: its queries are the suffix that sees it
         work = _block_work(mask_block, True, True, block_q, block_k,
                            diag_off, tile, qi, ki, kv_offset)
-        def _head(hh):
+        def _unit(hh):
             for k0, k1, pieces in work:
                 keys = (hh,) + _rows(k0, k1, block_k)
                 for q0, q1, mask in pieces:
                     rows = (hh,) + _rows(q0, q1, block_q)
-                    lanes = (hh,) + _lanes(q0, q1, block_q)
+                    lanes = [(_head(hh, a, slab_heads),)
+                             + _lanes(q0, q1, block_q) for a in heads]
+                    # each head's q and dO with the other's lanes zeroed:
+                    # the products over the rows then add up to the slab
                     q = q_ref[rows]
+                    qs = [_own(q, a, slab_heads) for a in heads]
                     do = do_ref[rows]
-                    pT = _p_transposed(q, k_ref[keys], lse_ref[lanes], mask,
-                                       sm_scale).astype(q.dtype)
-                    dv_scr[keys] += _dot(pT, do)             # (keys, d)
-                    dpT = _dot_t(v_ref[keys], do)
-                    dsT = (pT.astype(jnp.float32)
-                           * (dpT - delta_ref[lanes])
-                           * sm_scale).astype(q.dtype)
-                    dk_scr[keys] += _dot(dsT, q)             # (keys, d)
+                    dos = [_own(do, a, slab_heads) for a in heads]
+                    k = k_ref[keys]
+                    pTs = [_p_transposed(qs[a], k, lse_ref[lanes[a]], mask,
+                                         sm_scale).astype(q.dtype)
+                           for a in heads]
+                    dv_scr[keys] += functools.reduce(jnp.add, [
+                        _dot(pTs[a], dos[a]) for a in heads])  # (keys, d)
+                    v = v_ref[keys]
+                    dpTs = [_dot_t(v, dos[a]) for a in heads]
+                    dsTs = [(pTs[a].astype(jnp.float32)
+                             * (dpTs[a] - _delta_rows(
+                                 delta_ref, do, a, rows, lanes[a],
+                                 slab_heads, delta_from_o))
+                             * sm_scale).astype(q.dtype) for a in heads]
+                    dk_scr[keys] += functools.reduce(jnp.add, [
+                        _dot(dsTs[a], qs[a]) for a in heads])  # (keys, d)
 
-        _each_head(pack, work, _head)
+        _each_head(pack, work, _unit)
 
     if causal:
         diag = (qi * block_q + kv_offset < (ki + 1) * block_k) & run
@@ -742,7 +932,8 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          sm_scale: float, block_q: int, block_k: int,
                          kv_offset: int, pack: int,
                          diag_off: Optional[int] = None,
-                         tile: Optional[int] = None):
+                         tile: Optional[int] = None, slab_heads: int = 1,
+                         delta_from_o: bool = False):
     """Single-block fused backward: dq, dk AND dv in one pass.
 
     Only legal when the whole sequence fits one block each way (num_q ==
@@ -757,39 +948,51 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk and dv come out whole from the queries that see it, and only dq
     accumulates across tiles, in the one f32 scratch `dq_scr` (absent
     from a call that computes its block whole).  Still one pass and five
-    dots, each on the tiles at or below the diagonal.
+    dots a head, each on the tiles at or below the diagonal.
     """
     work = _block_work(causal, True, True, block_q, block_k, diag_off, tile,
                        0, 0, kv_offset)
     # the first key tile is seen by every query that sees any: it sets
     # dq's rows, the later tiles add to them
     seen_from = work[0][2][0][0] if work[0][2] else block_q
+    heads = range(slab_heads)
+    width = dq_ref.shape[-1]
 
-    def _head(hh):
+    def _unit(hh):
         for k0, k1, pieces in work:
             keys = (hh,) + _rows(k0, k1, block_k)
             k = dk = dv = None
             for q0, q1, mask in pieces:
                 rows = (hh,) + _rows(q0, q1, block_q)
-                lanes = (hh,) + _lanes(q0, q1, block_q)
+                lanes = [(_head(hh, a, slab_heads),)
+                         + _lanes(q0, q1, block_q) for a in heads]
                 last = q1 == pieces[-1][1]
                 q = q_ref[rows]
+                qs = [_own(q, a, slab_heads) for a in heads]
                 k = k_ref[keys] if k is None else k
                 do = do_ref[rows]
-                pT = _p_transposed(q, k, lse_ref[lanes], mask,
-                                   sm_scale)                 # (keys, rows)
-                dvp = _dot(pT.astype(q.dtype), do)           # (keys, d)
+                dos = [_own(do, a, slab_heads) for a in heads]
+                pTs = [_p_transposed(qs[a], k, lse_ref[lanes[a]], mask,
+                                     sm_scale) for a in heads]  # (keys, rows)
+                dvp = functools.reduce(jnp.add, [
+                    _dot(pTs[a].astype(q.dtype), dos[a])
+                    for a in heads])                         # (keys, d)
                 dv = dvp if dv is None else dv + dvp
                 if last:
                     dv_ref[keys] = dv.astype(dv_ref.dtype)
-                dpT = _dot_t(v_ref[keys], do)                # (keys, rows)
-                dsT = (pT * (dpT - delta_ref[lanes])
-                       * sm_scale).astype(q.dtype)
-                dkp = _dot(dsT, q)                           # (keys, d)
+                v = v_ref[keys]
+                dsTs = [(pTs[a] * (_dot_t(v, dos[a]) - _delta_rows(
+                    delta_ref, do, a, rows, lanes[a], slab_heads,
+                    delta_from_o)) * sm_scale).astype(q.dtype)
+                        for a in heads]
+                dkp = functools.reduce(jnp.add, [
+                    _dot(dsTs[a], qs[a]) for a in heads])    # (keys, d)
                 dk = dkp if dk is None else dk + dkp
                 if last:
                     dk_ref[keys] = dk.astype(dk_ref.dtype)
-                dqp = _dot_c0(dsT, k)                        # (rows, d)
+                # against the slab's k, right in the head's own lanes
+                dqp = _join([_dot_c0(dsT, k) for dsT in dsTs],
+                            width)                           # (rows, d)
                 if not dq_scr:
                     dq_ref[rows] = dqp.astype(dq_ref.dtype)
                 elif k0 == 0:
@@ -807,45 +1010,62 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     (seen_from,) + dq_scr[0].shape[1:], jnp.float32)
             dq_ref[hh] = dq_scr[0][...].astype(dq_ref.dtype)
 
-    _each_head(pack, work, _head)
+    _each_head(pack, work, _unit)
 
 
 def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                         block_q: int, block_k: int, interpret: bool,
-                        glse=None, tile: Optional[int] = None):
-    """All operands flat (bh, s, d); lse (bh, 1, sq) f32. Returns dq, dk, dv.
+                        glse=None, tile: Optional[int] = None,
+                        slabs: Optional[_Slabs] = None):
+    """All operands flat (bh, s, d), or with `slabs` (b, s, lanes) as
+    `_fa_forward_pallas` takes and gives them; lse (bh, 1, sq) f32.
+    Returns dq, dk, dv in o's layout.
 
     The kernels recompute p in TRANSPOSED space (queries in lanes) so the
     per-row lse/delta broadcast natively — see `_p_transposed`.  delta and
     the optional lse cotangent `glse` (bh, 1, sq) fold together outside
     (d lse / d s = p, so ds = p * (dp - delta + glse))."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
+    sq, sk = q.shape[1], k.shape[1]
+    bh, d, pack, groups, lanes = _geometry(q, slabs)
+    qo, ko, vo = slabs.offsets if slabs else (0, 0, 0)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     kv_offset = sk - sq
     num_q = sq // block_q
     num_kv = sk // block_k
-    pack = _fit_pack(bh)
-    plan = _causal_plan(causal, num_q, num_kv, block_q, block_k, kv_offset,
-                        tile)
+    plan = dict(_causal_plan(causal, num_q, num_kv, block_q, block_k,
+                             kv_offset, tile), **_slab_heads(slabs))
 
-    # delta = rowsum(dO ∘ O) — cheap fused reduce; (bh, 1, sq) row-major
-    # layout avoids the 128x lane padding a (bh, sq, 1) array would pay
-    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
-        -1)[:, None, :]
-    if glse is not None:
-        delta = delta - glse
+    operand, row = _block_specs(slabs, pack, d)
+    # delta = rowsum(dO ∘ O) a head — cheap fused reduce; (bh, 1, sq)
+    # row-major layout avoids the 128x lane padding a (bh, sq, 1) array
+    # would pay.  Two heads a slab: the kernels take o and sum each
+    # head's lanes themselves (`_delta_rows`) — as an XLA reduce over
+    # half a lane tile into a sequence-minor result it is a relayout of
+    # the f32 product first, four passes over it where the kernel makes
+    # one over o
+    delta_from_o = slabs is not None and slabs.heads > 1
+    if delta_from_o:
+        assert glse is None
+        plan["delta_from_o"] = True
+        delta, delta_spec = o, operand
+    else:
+        delta_spec = row
+        delta = do.astype(jnp.float32) * o.astype(jnp.float32)
+        if slabs is None:
+            delta = delta.sum(-1)[:, None, :]
+        else:
+            delta = delta.reshape(o.shape[0], sq, bh // o.shape[0], -1).sum(
+                -1).transpose(0, 2, 1).reshape(bh, 1, sq)
+        if glse is not None:
+            delta = delta - glse
 
-    qspec = pl.BlockSpec((pack, block_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((pack, block_k, d), lambda b, i, j: (b, j, 0))
-    rowspec = pl.BlockSpec((pack, 1, block_q), lambda b, i, j: (b, 0, i))
+    dq_shape = _out_struct(q.shape[:2] + (lanes,), q.dtype, q)
+    dk_shape = _out_struct(k.shape[:2] + (lanes,), k.dtype, q)
+    dv_shape = _out_struct(v.shape[:2] + (lanes,), v.dtype, q)
     ops = [q, k, v, do, lse, delta]
 
     if num_q == 1 and num_kv == 1:
-        bspec_q = pl.BlockSpec((pack, block_q, d), lambda b: (b, 0, 0))
-        bspec_k = pl.BlockSpec((pack, block_k, d), lambda b: (b, 0, 0))
-        bspec_row = pl.BlockSpec((pack, 1, block_q), lambda b: (b, 0, 0))
         tiled = causal and len(_causal_bands_t(
             block_q, block_k, plan["diag_off"], plan["tile"])) > 1
         return pl.pallas_call(
@@ -853,16 +1073,14 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                 _fa_bwd_fused_kernel, causal=causal, sm_scale=sm_scale,
                 block_q=block_q, block_k=block_k, kv_offset=kv_offset,
                 pack=pack, **plan),
-            grid=(bh // pack,),
-            in_specs=[bspec_q, bspec_k, bspec_k, bspec_q, bspec_row,
-                      bspec_row],
-            out_specs=(bspec_q, bspec_k, bspec_k),
-            out_shape=(
-                _out_struct((bh, sq, d), q.dtype, q),
-                _out_struct((bh, sk, d), k.dtype, q),
-                _out_struct((bh, sk, d), v.dtype, q),
-            ),
-            # dq across key tiles, one head at a time
+            grid=(groups,),
+            in_specs=[operand(block_q, None, qo), operand(block_k, None, ko),
+                      operand(block_k, None, vo), operand(block_q, None),
+                      row(block_q, None), delta_spec(block_q, None)],
+            out_specs=(operand(block_q, None), operand(block_k, None),
+                       operand(block_k, None)),
+            out_shape=(dq_shape, dk_shape, dv_shape),
+            # dq across key tiles, one unit at a time
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
             if tiled else [],
             compiler_params=_compiler_params(
@@ -876,10 +1094,12 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                           sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, kv_offset=kv_offset, pack=pack,
                           **plan),
-        grid=(bh // pack, num_q, num_kv),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-        out_specs=pl.BlockSpec((pack, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=_out_struct((bh, sq, d), q.dtype, q),
+        grid=(groups, num_q, num_kv),
+        in_specs=[operand(block_q, 0, qo), operand(block_k, 1, ko),
+                  operand(block_k, 1, vo), operand(block_q, 0),
+                  row(block_q, 0), delta_spec(block_q, 0)],
+        out_specs=operand(block_q, 0),
+        out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((pack, block_q, d), jnp.float32)],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          vmem_limit=100 * 1024 * 1024),
@@ -888,25 +1108,17 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     )(*ops)
 
     # dkv grid: kv outer, q inner — same operands, transposed index maps
-    qspec_t = pl.BlockSpec((pack, block_q, d), lambda b, j, i: (b, i, 0))
-    kspec_t = pl.BlockSpec((pack, block_k, d), lambda b, j, i: (b, j, 0))
-    rowspec_t = pl.BlockSpec((pack, 1, block_q), lambda b, j, i: (b, 0, i))
-
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, num_q=num_q, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, kv_offset=kv_offset, pack=pack,
                           **plan),
-        grid=(bh // pack, num_kv, num_q),
-        in_specs=[qspec_t, kspec_t, kspec_t, qspec_t, rowspec_t, rowspec_t],
-        out_specs=(
-            pl.BlockSpec((pack, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((pack, block_k, d), lambda b, j, i: (b, j, 0)),
-        ),
-        out_shape=(
-            _out_struct((bh, sk, d), k.dtype, q),
-            _out_struct((bh, sk, d), v.dtype, q),
-        ),
+        grid=(groups, num_kv, num_q),
+        in_specs=[operand(block_q, 1, qo), operand(block_k, 0, ko),
+                  operand(block_k, 0, vo), operand(block_q, 1),
+                  row(block_q, 1), delta_spec(block_q, 1)],
+        out_specs=(operand(block_k, 0), operand(block_k, 0)),
+        out_shape=(dk_shape, dv_shape),
         scratch_shapes=[
             pltpu.VMEM((pack, block_k, d), jnp.float32),
             pltpu.VMEM((pack, block_k, d), jnp.float32),
@@ -1249,13 +1461,128 @@ def _fa_lse_bwd(causal, sm_scale, block_q, block_k, bwd_block_q,
 flash_attention_with_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)
 
 
-def mha(q, k, v, causal: bool = True, sm_scale: Optional[float] = None):
-    """Convenience wrapper accepting (b, s, h, d) layout (flax convention).
+# ------------------------------------- the projections' own (b, s, h*d) layout
 
-    The transposes to (b, h, s, d) cost ~1ms/layer at the bench shape; a
-    fused kernel taking (b, s, h*d) directly was built and measured SLOWER
-    (lane slices at non-128 offsets relayout per head: ~7.2ms vs 5.6ms
-    fwd+bwd), so the transpose + flat-kernel route stays.
+
+def attention_route(n_head: int, head_dim: int) -> Tuple[str, int]:
+    """Which layout a model's attention hands the kernels, from its
+    shape alone: ("direct", heads a slab) when the heads fall on 128-lane
+    slab boundaries of the projections' (b, s, h*d) output — a head is a
+    slab or more (d % 128 == 0), or two heads of 64 share one and there
+    is an even number of them; ("transposed", 0) otherwise: the
+    (b, h, s, d) arrays of `flash_attention`.
+
+    The counter of this decision, as `causal_tile_count` is of the
+    tiles: `projected_ok` asks it for the dispatcher, and
+    tests/test_program_from_arguments.py pins it for the benchmark's
+    cells."""
+    if head_dim % 128 == 0:
+        return "direct", 1
+    if head_dim == 64 and n_head % 2 == 0:
+        return "direct", 2
+    return "transposed", 0
+
+
+_PROJECTED_BLOCK = 1024  # the direct calls' preferred block, q and keys
+
+
+def projected_ok(n_head: int, head_dim: int, seq: int) -> bool:
+    """Whether `flash_attention_projected` takes a self-attention of this
+    shape: the heads on slab boundaries (`attention_route`), on the TPU,
+    at a sequence a block fits (`_use_pallas`).  The one predicate of the
+    direct route: `models/attention.attend_projected` adds only where the
+    call runs (the mesh), and the entry itself refuses what this does."""
+    return (attention_route(n_head, head_dim)[0] == "direct"
+            and _use_pallas(seq, seq, head_dim, _PROJECTED_BLOCK,
+                            _PROJECTED_BLOCK))
+
+
+def _projected_slabs(proj, n_head: int) -> Tuple[_Slabs, int]:
+    """(`_Slabs` of a direct call, its head size).  `proj` is (qkv,),
+    one (b, s, 3*h*d) array of q, k and v side by side, or (q, k, v),
+    (b, s, h*d) each."""
+    lanes = proj[0].shape[-1] // (3 if len(proj) == 1 else 1)
+    d = lanes // n_head
+    route, heads = attention_route(n_head, d)
+    if route != "direct":
+        raise ValueError(
+            f"{n_head} heads of {d} do not fall on slab boundaries: "
+            f"take flash_attention on (b, h, s, d)")
+    per_row = n_head // heads
+    offsets = (0, per_row, 2 * per_row) if len(proj) == 1 else (0, 0, 0)
+    return _Slabs(per_row, heads, lanes // per_row, offsets), d
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def flash_attention_projected(proj, n_head: int, causal: bool = True,
+                              sm_scale: Optional[float] = None):
+    """Attention on the projections' own layout: `proj` = (qkv,), one
+    (b, s, 3*h*d) array (GPT-2's `c_attn` output, never split), or
+    (q, k, v), (b, s, h*d) each → (b, s, h*d), which the output
+    projection takes as it is.  The cotangent comes back in the same
+    form.  No array is split, reshaped to heads or transposed on the
+    way: the kernels' BlockSpecs index the slabs where they lie
+    (`_Slabs`).
+
+    For calls `projected_ok` takes; any other raises ValueError (the
+    caller asks first: `models/attention.attend_projected`)."""
+    return _fa_projected_fwd(proj, n_head, causal, sm_scale)[0]
+
+
+def _projected_operands(proj):
+    return proj * 3 if len(proj) == 1 else proj
+
+
+# A model's layers call the kernels with the same shapes and the same
+# static plan: behind `jax.jit` the kernel body is traced to a jaxpr and
+# lowered to Mosaic ONCE a step program, not once a layer (each is a
+# few hundred equations walked in Python).  XLA inlines the calls, so
+# the compiled step is the one it would have been.
+_STATIC = ("causal", "sm_scale", "block_q", "block_k", "interpret", "slabs")
+_projected_forward = jax.jit(_fa_forward_pallas, static_argnames=_STATIC)
+_projected_backward = jax.jit(_fa_backward_pallas, static_argnames=_STATIC)
+
+
+def _projected_plan(proj, n_head, causal, sm_scale) -> dict:
+    slabs, d = _projected_slabs(proj, n_head)
+    seq = proj[0].shape[1]
+    if not projected_ok(n_head, d, seq):
+        raise ValueError(
+            f"no direct kernel off the TPU or at a sequence of {seq}: "
+            f"take flash_attention on (b, h, s, d)")
+    block = _fit_block(seq, _PROJECTED_BLOCK)
+    return dict(causal=causal, sm_scale=_resolve_scale(sm_scale, d),
+                block_q=block, block_k=block, interpret=False, slabs=slabs)
+
+
+def _fa_projected_fwd(proj, n_head, causal, sm_scale):
+    o, lse = _projected_forward(
+        *_projected_operands(proj),
+        **_projected_plan(proj, n_head, causal, sm_scale))
+    return o, (proj, o, lse)
+
+
+def _fa_projected_bwd(n_head, causal, sm_scale, res, g):
+    proj, o, lse = res
+    grads = _projected_backward(
+        *_projected_operands(proj), o, lse, g,
+        **_projected_plan(proj, n_head, causal, sm_scale))
+    if len(proj) == 1:  # c_attn's cotangent: dq, dk, dv side by side
+        return ((jnp.concatenate(grads, axis=-1),),)
+    return (tuple(grads),)
+
+
+flash_attention_projected.defvjp(_fa_projected_fwd, _fa_projected_bwd)
+
+
+def mha(q, k, v, causal: bool = True, sm_scale: Optional[float] = None):
+    """`flash_attention` on the (b, s, h, d) layout (flax convention): the
+    transposed route, for callers whose heads do not fall on slab
+    boundaries (`attention_route`) or that hold q, k and v by head
+    already.  Its transposes to (b, h, s, d) and back are materialised
+    copies of arrays whose minor dimension, at d = 64, is stored padded
+    to 128 lanes; a model whose shape allows it goes through
+    `flash_attention_projected` and pays none of them.
     """
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

@@ -2,7 +2,9 @@
 diagonal of a block (`ops/flash_attention._causal_bands`): the tile set
 against a brute-force mask, its count at the benchmark cells' shapes,
 and interpret-mode parity of the tiled forward, fused backward and split
-backward with the plain reference and its `jax.grad`.
+backward with the plain reference and its `jax.grad` — on the transposed
+(bh, s, d) layout and on the projections' own (b, s, h*d), one head or
+two a lane slab.
 """
 
 import jax
@@ -128,6 +130,30 @@ CASES = [
     (128, 128, 128, 128, 32, False, 8, 64),
 ]
 
+# the same kernels on the projections' own (b, s, h*d) layout
+# (`fa._Slabs`): CASES' columns, then how the heads reach the kernel —
+# "qkv": q, k and v side by side in ONE (b, s, 3*h*d) array, as GPT-2's
+# c_attn leaves them; "q,k,v": three (b, s, h*d) arrays — and the heads
+# a batch row has (bh / heads rows).  d = 64: two heads a slab, told
+# apart by lane masks; d = 128: a head a slab
+DIRECT = [
+    # one block each way (the fused backward), tiled and whole
+    (128, 128, 128, 128, 32, True, 8, 64, "qkv", 4),
+    (128, 128, 128, 128, 128, True, 4, 64, "q,k,v", 2),
+    (128, 128, 128, 128, 32, True, 4, 128, "q,k,v", 2),
+    (128, 128, 128, 128, 64, True, 6, 128, "qkv", 3),
+    # several blocks with a diagonal (the dq and dk/dv kernels)
+    (256, 256, 64, 64, 16, True, 8, 64, "qkv", 4),
+    (256, 256, 128, 128, 32, True, 2, 128, "q,k,v", 2),
+    # sq != sk: shifted diagonal, rows that see no key
+    (64, 128, 64, 128, 32, True, 4, 64, "q,k,v", 2),
+    (256, 128, 64, 64, 16, True, 2, 64, "q,k,v", 2),
+    # non-causal
+    (128, 128, 64, 64, 16, False, 4, 64, "qkv", 4),
+    (128, 128, 128, 128, 32, False, 2, 128, "q,k,v", 1),
+]
+CASES = [c + (None, 0) for c in CASES] + DIRECT
+
 
 def _inputs(sq, sk, bh, d, seed=0):
     kq, kk, kv, kg, kl = jax.random.split(jax.random.PRNGKey(seed), 5)
@@ -137,6 +163,46 @@ def _inputs(sq, sk, bh, d, seed=0):
     g = jax.random.normal(kg, (bh, sq, d), jnp.float32)
     gl = jax.random.normal(kl, (bh, 1, sq), jnp.float32)
     return q, k, v, g, gl
+
+
+def _projected(x, heads):
+    """(bh, s, d) by head -> (b, s, h*d), the heads side by side."""
+    bh, s, d = x.shape
+    return x.reshape(bh // heads, heads, s, d).transpose(0, 2, 1, 3).reshape(
+        bh // heads, s, heads * d)
+
+
+def _by_head(x, heads):
+    b, s, lanes = x.shape
+    return x.reshape(b, s, heads, lanes // heads).transpose(
+        0, 2, 1, 3).reshape(b * heads, s, lanes // heads)
+
+
+def _kernels(form, heads, q, k, v):
+    """(forward, backward) on (bh, s, d) arrays whatever layout the
+    kernels are handed: `fa._fa_forward_pallas` / `_fa_backward_pallas`
+    themselves, or the same two behind the projected layout."""
+    if form is None:
+        return fa._fa_forward_pallas, fa._fa_backward_pallas
+    proj = tuple(_projected(x, heads) for x in (q, k, v))
+    if form == "qkv":
+        proj = (jnp.concatenate(proj, axis=-1),)
+    slabs, d = fa._projected_slabs(proj, heads)
+    assert d == q.shape[-1]
+    assert slabs.heads == (2 if d == 64 else 1)
+    ops = fa._projected_operands(proj)
+
+    def forward(q, k, v, *args, **kw):
+        o, lse = fa._fa_forward_pallas(*ops, *args, slabs=slabs, **kw)
+        return _by_head(o, heads), lse
+
+    def backward(q, k, v, o, lse, g, *args, **kw):
+        grads = fa._fa_backward_pallas(
+            *ops, _projected(o, heads), lse, _projected(g, heads), *args,
+            slabs=slabs, **kw)
+        return tuple(_by_head(x, heads) for x in grads)
+
+    return forward, backward
 
 
 def _reference(q, k, v, g, gl, causal, scale):
@@ -154,13 +220,15 @@ def _reference(q, k, v, g, gl, causal, scale):
     return o, lse, grads
 
 
-@pytest.mark.parametrize("sq,sk,block_q,block_k,tile,causal,bh,d", CASES)
+@pytest.mark.parametrize(
+    "sq,sk,block_q,block_k,tile,causal,bh,d,form,heads", CASES)
 def test_tiled_forward_matches_reference(sq, sk, block_q, block_k, tile,
-                                         causal, bh, d):
+                                         causal, bh, d, form, heads):
     q, k, v, g, _ = _inputs(sq, sk, bh, d)
     scale = d ** -0.5
-    o, lse = fa._fa_forward_pallas(q, k, v, causal, scale, block_q, block_k,
-                                   interpret=True, tile=tile)
+    forward, _ = _kernels(form, heads, q, k, v)
+    o, lse = forward(q, k, v, causal, scale, block_q, block_k,
+                     interpret=True, tile=tile)
     ro, rlse, _ = _reference(q, k, v, g, None, causal, scale)
     np.testing.assert_allclose(o, ro, atol=2e-5)
     np.testing.assert_allclose(lse[:, 0], rlse, atol=2e-5)
@@ -178,22 +246,25 @@ def _halved(case):
 _ONE_BLOCK = [c for c in CASES if c[:2] == c[2:4]]
 BWD_CASES = [c + (False,) for c in _ONE_BLOCK] \
     + [(_halved(c) if c in _ONE_BLOCK else c) + (True,) for c in CASES]
+# "qkv" holds q and k in one array: sq == sk
+assert all(c[0] == c[1] for c in CASES if c[8] == "qkv")
 
 
 def _is_split(sq, sk, block_q, block_k) -> bool:
     return sq // block_q > 1 or sk // block_k > 1
 
 
-@pytest.mark.parametrize("sq,sk,block_q,block_k,tile,causal,bh,d,split",
-                         BWD_CASES)
+@pytest.mark.parametrize(
+    "sq,sk,block_q,block_k,tile,causal,bh,d,form,heads,split", BWD_CASES)
 def test_tiled_backward_matches_reference(sq, sk, block_q, block_k, tile,
-                                          causal, bh, d, split):
+                                          causal, bh, d, form, heads, split):
     assert split == _is_split(sq, sk, block_q, block_k)
     q, k, v, g, _ = _inputs(sq, sk, bh, d, seed=1)
     scale = d ** -0.5
-    o, lse = fa._fa_forward_pallas(q, k, v, causal, scale, block_q, block_k,
-                                   interpret=True, tile=tile)
-    dq, dk, dv = fa._fa_backward_pallas(
+    forward, backward = _kernels(form, heads, q, k, v)
+    o, lse = forward(q, k, v, causal, scale, block_q, block_k,
+                     interpret=True, tile=tile)
+    dq, dk, dv = backward(
         q, k, v, o, lse, g, causal, scale, block_q, block_k, interpret=True,
         tile=tile)
     _, _, (rq, rk, rv) = _reference(q, k, v, g, None, causal, scale)

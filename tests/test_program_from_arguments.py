@@ -77,25 +77,280 @@ def test_streamed_fallback_switches_at_2048_squared(sq, sk, streamed):
     assert fa._use_streamed(sq, sk) is streamed
 
 
-@pytest.mark.parametrize("b,h,t,d,pack,grid,fused,tiles", [
-    (24, 12, 1024, 64, 8, (1, 1), True, (3, 4)),      # gpt2_124m.steady
-    (4, 25, 1024, 64, 4, (1, 1), True, (3, 4)),       # gpt2_xl, per chip
-    (5, 16, 4096, 128, 8, (4, 4), False, (36, 64)),   # olmoe_1b_7b.steady
+@pytest.mark.parametrize("heads,d,route", [
+    (12, 64, ("direct", 2)),      # gpt2_124m.steady: two heads a slab
+    (16, 128, ("direct", 1)),     # olmoe_1b_7b.steady: a head a slab
+    (25, 64, ("transposed", 0)),  # gpt2_xl, per chip: 12.5 slabs
+    (2, 64, ("direct", 2)), (1, 64, ("transposed", 0)),
+    (8, 256, ("direct", 1)),      # a head two slabs wide
+    (12, 80, ("transposed", 0)), (16, 96, ("transposed", 0)),
+    (4, 32, ("transposed", 0)),   # h*d % 128 == 0 is not enough
 ])
-def test_the_cells_attention_plans(monkeypatch, b, h, t, d, pack, grid,
-                                   fused, tiles):
-    """PERF.md section 5's prose, pinned: what each benchmark cell's
-    attention traces to on the chip, from its shape alone."""
+def test_the_route_is_the_shape_of_the_heads(heads, d, route):
+    assert fa.attention_route(heads, d) == route
+
+
+@pytest.mark.parametrize("on_tpu,h,d,t,why", [
+    (False, 12, 64, 1024, "off the TPU"),
+    (True, 12, 64, 4099, "a sequence of 4099"),   # no block tiles it
+    (True, 25, 64, 1024, "slab boundaries"),
+])
+def test_the_direct_entry_refuses_what_projected_ok_does(monkeypatch,
+                                                         on_tpu, h, d, t,
+                                                         why):
+    """`flash_attention_projected` has the dispatcher's own guard: a
+    call `projected_ok` does not take raises, and never reaches a kernel
+    with no block or a TPU lowering off the TPU."""
+    monkeypatch.setattr(fa, "_on_tpu", lambda: on_tpu)
+    assert not fa.projected_ok(h, d, t)
+    qkv = jax.ShapeDtypeStruct((2, t, 3 * h * d), jnp.bfloat16)
+    with pytest.raises(ValueError, match=why):
+        jax.eval_shape(lambda x: fa.flash_attention_projected((x,), h),
+                       qkv)
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    calls = _pallas_calls(_attention_grad_jaxpr(b, h, t, d).jaxpr)
-    groups = b * h // pack
+    assert fa.projected_ok(12, 64, 1024) and fa.projected_ok(16, 128, 4096)
+
+
+def _model_attention_jaxpr(b, h, t, d, form):
+    """The attention a model of this shape traces on one device: through
+    `models/attention.attend_projected`, from the projections' own
+    layout — `form` "qkv" as models/gpt.py hands it, "q,k,v" as
+    models/llama.py does."""
+    from dlrover_wuqiong_tpu.models.attention import attend_projected
+    from dlrover_wuqiong_tpu.models.gpt import GPTConfig
+
+    cfg = GPTConfig(n_head=h, n_embd=h * d)
+    n = 3 if form == "qkv" else 1
+    x = jax.ShapeDtypeStruct((b, t, n * h * d), jnp.bfloat16)
+    proj = (x,) * (4 - n)
+    return jax.make_jaxpr(jax.grad(
+        lambda proj: attend_projected(proj, h, cfg).astype(
+            jnp.float32).sum()))(proj)
+
+
+def _primitives(jaxpr) -> set:
+    """Every primitive traced outside the kernels' own bodies."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found |= _primitives(sub)
+    return found
+
+
+# pack and grid changed for the two direct cells in PR 29: a grid step
+# is ONE lane slab of one batch row where it was 8 heads of the
+# transposed array (124M: 24 x 6 slabs of two heads, was 288 / 8 groups;
+# OLMoE: 5 x 16 slabs of one head, was 80 / 8), because a slab is what a
+# BlockSpec can address in the projections' layout without a lane slice
+@pytest.mark.parametrize("b,h,t,d,form,route,groups,grid,fused,tiles", [
+    (24, 12, 1024, 64, "qkv", "direct", 24 * 6, (1, 1), True,
+     (3, 4)),                                          # gpt2_124m.steady
+    (4, 25, 1024, 64, "qkv", "transposed", 100 // 4, (1, 1), True,
+     (3, 4)),                                          # gpt2_xl, per chip
+    (5, 16, 4096, 128, "q,k,v", "direct", 5 * 16, (4, 4), False,
+     (36, 64)),                                        # olmoe_1b_7b.steady
+])
+def test_the_cells_attention_plans(monkeypatch, b, h, t, d, form, route,
+                                   groups, grid, fused, tiles):
+    """PERF.md section 5's prose, pinned: what each benchmark cell's
+    attention traces to on the chip, from its shape alone — the route,
+    the kernels and their grids, and whether anything is split, cut to
+    heads or transposed around them."""
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    jaxpr = _model_attention_jaxpr(b, h, t, d, form).jaxpr
+    assert fa.attention_route(h, d)[0] == route
     want = [("dwt_fa_fwd", (groups,) + grid)]
     want += [("dwt_fa_bwd_fused", (groups,))] if fused else \
         [("dwt_fa_bwd_dq", (groups,) + grid),
          ("dwt_fa_bwd_dkv", (groups,) + grid)]
-    assert sorted(calls) == sorted(want)
-    assert fa._fit_pack(b * h) == pack
+    assert sorted(_pallas_calls(jaxpr)) == sorted(want)
     assert fa.causal_tile_count(t, t) == tiles
+    relaid = _primitives(jaxpr) & {"transpose", "split", "reshape",
+                                   "slice"}
+    if route == "transposed":
+        assert fa._fit_pack(b * h) == b * h // groups
+        assert relaid == {"transpose", "split", "reshape"}
+    elif form == "qkv":
+        # two heads a slab: delta is the kernel's too; all that is left
+        # is the join of dq, dk and dv into c_attn's cotangent
+        assert relaid == set() and "concatenate" in _primitives(jaxpr)
+    else:
+        # a head a slab: delta is one reduce of (b, t, h, d) over d,
+        # turned to the kernels' (b*h, 1, t) as a (b, t, h) array
+        assert relaid == {"reshape", "transpose"}
+        assert "concatenate" not in _primitives(jaxpr)
+
+
+@pytest.mark.parametrize("h,d,form", [
+    (3, 64, "qkv"),      # an odd number of heads of 64
+    (3, 64, "q,k,v"),
+    (2, 80, "qkv"),      # h*d % 128 != 0
+    (4, 32, "q,k,v"),    # h*d % 128 == 0, four heads a slab
+])
+def test_shapes_off_the_slab_fall_back_and_still_match(monkeypatch, h, d,
+                                                       form):
+    """`attend_projected` on a shape `attention_route` calls transposed:
+    the kernels (interpret mode) on (b*h, t, d) arrays behind the split,
+    the cut to heads and the transposes, against the plain reference."""
+    import functools
+
+    from dlrover_wuqiong_tpu.models.attention import attend_projected
+    from dlrover_wuqiong_tpu.models.gpt import GPTConfig
+
+    assert fa.attention_route(h, d)[0] == "transposed"
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    for name in ("_fa_forward_pallas", "_fa_backward_pallas"):
+        kernel = getattr(fa, name)
+        monkeypatch.setattr(fa, name, functools.partial(
+            lambda kernel, *a, **kw: kernel(
+                *a, **{**kw, "interpret": True}), kernel))
+    b, t = 2, 128
+    keys = jax.random.split(jax.random.PRNGKey(h * d), 4)
+    q, k, v, g = (jax.random.normal(kx, (b, t, h * d), jnp.float32)
+                  for kx in keys)
+    proj = (jnp.concatenate([q, k, v], -1),) if form == "qkv" else (q, k, v)
+    cfg = GPTConfig(n_head=h, n_embd=h * d)
+
+    def heads(x):
+        return x.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+
+    def plain(proj):
+        q, k, v = proj if len(proj) == 3 else jnp.split(proj[0], 3, -1)
+        o = fa._attention_reference(heads(q), heads(k), heads(v), True,
+                                    d ** -0.5)
+        o = o.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+        return (o * g).sum(), o
+
+    def model(proj):
+        o = attend_projected(proj, h, cfg)
+        return (o * g).sum(), o
+
+    packed = b * h // fa._fit_pack(b * h)
+    assert sorted(_pallas_calls(jax.make_jaxpr(jax.grad(
+        model, has_aux=True))(proj).jaxpr)) == [
+            ("dwt_fa_bwd_fused", (packed,)), ("dwt_fa_fwd", (packed, 1, 1))]
+    got, o = jax.grad(model, has_aux=True)(proj)
+    want, want_o = jax.grad(plain, has_aux=True)(proj)
+    np.testing.assert_allclose(o, want_o, atol=2e-5)
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(a, w, atol=5e-4)
+
+
+def _rope_written_out(x, cos, sin):
+    """RoPE as the formula reads, a head at a time on (b, s, h, d): the
+    head's halves x1, x2 -> (x1 cos - x2 sin, x2 cos + x1 sin).  Written
+    apart from `models/llama.apply_rope` (it is what that function was
+    before PR 29)."""
+    s = x.shape[1]
+    c, si = cos[:s][None], sin[:s][None]
+    heads = []
+    for a in range(x.shape[2]):
+        x1, x2 = jnp.split(x[:, :, a].astype(jnp.float32), 2, axis=-1)
+        heads.append(jnp.concatenate(
+            [x1 * c - x2 * si, x2 * c + x1 * si], axis=-1))
+    return jnp.stack(heads, axis=2).astype(x.dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("layout", ["heads", "flat"])
+@pytest.mark.parametrize("h,d", [(16, 128), (3, 64), (2, 16)])
+def test_rope_is_the_written_out_rotation_in_both_layouts(h, d, layout,
+                                                          dtype):
+    """The one `apply_rope` of models/llama.py, on (b, s, h, d) and on
+    the projections' own (b, s, h*d), against the per-head formula: the
+    same two products and one sum an element, so the same bits."""
+    from dlrover_wuqiong_tpu.models.llama import apply_rope, rope_freqs
+
+    b, s = 2, 24
+    cos, sin = rope_freqs(d, 32, 10000.0)
+    x = jax.random.normal(jax.random.PRNGKey(h + d), (b, s, h, d), dtype)
+    want = _rope_written_out(x, cos, sin)
+    got = apply_rope(x if layout == "heads" else x.reshape(b, s, h * d),
+                     cos, sin)
+    assert got.dtype == dtype
+    assert got.shape == (x.shape if layout == "heads" else (b, s, h * d))
+    np.testing.assert_array_equal(
+        np.asarray(got.reshape(x.shape), np.float32),
+        np.asarray(want, np.float32))
+    # and it rotates: position 0 stays, every pair keeps its length
+    np.testing.assert_array_equal(np.asarray(got[:, 0], np.float32),
+                                  np.asarray(x.reshape(got.shape)[:, 0],
+                                             np.float32))
+    if dtype == jnp.float32:
+        g = got.reshape(x.shape)
+        np.testing.assert_allclose(
+            g[..., :d // 2] ** 2 + g[..., d // 2:] ** 2,
+            x[..., :d // 2] ** 2 + x[..., d // 2:] ** 2, rtol=1e-5,
+            atol=1e-6)
+
+
+def test_llama_off_the_direct_route_rotates_a_head_at_a_time(monkeypatch):
+    """A Llama whose attention does not go direct (here: off the TPU, on
+    a tp mesh that shards the heads) cuts q and k to (b, s, h, d) BEFORE
+    the rotation, as it always did: the rolls run along one head's d,
+    which no mesh axis shards, so the partitioner adds no collective
+    over the written-out formula's program."""
+    import optax
+
+    from dlrover_wuqiong_tpu.analysis.hlo_budget import iter_collectives
+    from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
+    from dlrover_wuqiong_tpu.models import llama
+
+    def collectives():
+        res = auto_accelerate(
+            llama.Llama(llama.LlamaConfig.nano()),
+            optimizer=optax.adamw(1e-2), materialize=False,
+            strategy=[("fsdp", {}), ("tensor_parallel", {"size": 2})])
+        ids = jax.ShapeDtypeStruct((8, 64), jnp.int32,
+                                   sharding=res.batch_sharding_fn(2))
+        text = res.train_step.lower(
+            res.state, {"input_ids": ids, "labels": ids}).compile(
+            ).as_text()
+        return sorted(op for op, _, _ in iter_collectives(text))
+
+    def split_and_join(x, cos, sin):  # apply_rope as it was before PR 29
+        c = cos[:x.shape[1]][None, :, None, :]
+        si = sin[:x.shape[1]][None, :, None, :]
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate([x1 * c - x2 * si, x2 * c + x1 * si],
+                               axis=-1).astype(x.dtype)
+
+    def whole_row_then_cut(x, cos, sin, rope=llama.apply_rope):
+        b, s, h, d = x.shape
+        return rope(x.reshape(b, s, h * d), cos, sin).reshape(x.shape)
+
+    monkeypatch.setenv("DWT_COMPILE_CACHE", "0")
+    ours = collectives()
+    monkeypatch.setattr(llama, "apply_rope", split_and_join)
+    assert ours == collectives() and ours
+    # what the order of cut and rotation saves: rolled along the whole
+    # row, whose lanes tp shards, every roll trades halos (48 more)
+    monkeypatch.setattr(llama, "apply_rope", whole_row_then_cut)
+    assert collectives().count("collective-permute") \
+        > ours.count("collective-permute")
+
+
+@pytest.mark.parametrize("text,want", [
+    # compiled HLO, or a lowered step that calls the kernels in line
+    ('custom_call @tpu_custom_call dwt_fa_fwd\n' * 3, 3),
+    # the direct route's lowered step: the wrapper behind `jax.jit` is
+    # ONE private function, called a layer — through a remat body too
+    ('func.func public @main() {\n call @_fa_fwd(%0)\n call @layer(%1)\n'
+     ' call @layer(%2)\n}\n'
+     'func.func private @layer() {\n call @_fa_fwd(%0)\n}\n'
+     'func.func private @_fa_fwd() {\n custom_call @tpu_custom_call '
+     'dwt_fa_fwd\n}\n'
+     'func.func private @_fa_fwd_1() {\n custom_call @tpu_custom_call '
+     'dwt_fa_fwd\n}\n', 3 + 1),
+])
+def test_the_smoke_counts_a_kernel_once_for_each_call_site(text, want):
+    """`chip_smoke.kernel_counts`: what `--phase kernel` and `--phase
+    train` hold to `>= n_layer` on both routes."""
+    import chip_smoke
+
+    assert chip_smoke.kernel_counts(text)["dwt_fa_fwd"] == want
 
 
 # ----------------------------------- (B) the environment is in none of it

@@ -68,23 +68,46 @@ def _compile(fn, *args):
 # shape alone takes ~40 s); and ring attention's later steps, which are
 # not causal.  The causal ones are cut into tiles below the diagonal
 # (`fa._causal_bands`): static slices Mosaic has to take.
-SHAPES = [(8, 1024, 64, True), (4, 1024, 64, True), (4, 4096, 128, True),
-          (4, 1024, 64, False)]
+# The last column: 0 = the transposed (bh, t, d) arrays; else the heads
+# of a batch row of the projections' own (b, t, h*d) layout, indexed
+# where they lie — gpt2_124m.steady's whole batch of c_attn outputs
+# (q, k and v in one array, two heads of 64 a lane slab, masks in the
+# kernel) and olmoe_1b_7b.steady's (a head a slab, four blocks each way).
+SHAPES = [(8, 1024, 64, True, 0), (4, 1024, 64, True, 0),
+          (4, 4096, 128, True, 0), (4, 1024, 64, False, 0),
+          (24 * 12, 1024, 64, True, 12), (5 * 16, 4096, 128, True, 16)]
 
 
-@pytest.mark.parametrize("bh,t,d,causal", SHAPES)
-def test_attention_forward_and_backward_compile(topo, bh, t, d, causal):
+@pytest.mark.parametrize("bh,t,d,causal,heads", SHAPES)
+def test_attention_forward_and_backward_compile(topo, bh, t, d, causal,
+                                                heads):
     one = SingleDeviceSharding(topo.devices[0])
     x = jax.ShapeDtypeStruct((bh, t, d), jnp.bfloat16, sharding=one)
     lse = jax.ShapeDtypeStruct((bh, 1, t), jnp.float32, sharding=one)
     blk = min(1024, t)
     sc = d ** -0.5
     assert fa.causal_tile_count(t, t) == {1024: (3, 4), 4096: (36, 64)}[t]
+    kw = {}
+    if heads:
+        b, lanes = bh // heads, heads * d
+        fused = d == 64  # GPT-2's c_attn; OLMoE projects q, k, v apart
+        x = jax.ShapeDtypeStruct((b, t, lanes), jnp.bfloat16, sharding=one)
+        qkv = jax.ShapeDtypeStruct((b, t, 3 * lanes), jnp.bfloat16,
+                                   sharding=one)
+        slabs, _ = fa._projected_slabs((qkv,) if fused else (x,) * 3, heads)
+        assert slabs == ((6, 2, 128, (0, 6, 12)) if fused
+                         else (16, 1, 128, (0, 0, 0)))
+        kw = {"slabs": slabs}
+    q = qkv if heads and fused else x
     fwd = _compile(lambda q, k, v: fa._fa_forward_pallas(
-        q, k, v, causal, sc, blk, blk, False), x, x, x)
+        q, k, v, causal, sc, blk, blk, False, **kw), q, q, q)
     assert "dwt_fa_fwd" in fwd and "tpu_custom_call" in fwd
     bwd = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
-        q, k, v, o, l, do, causal, sc, blk, blk, False), x, x, x, x, lse, x)
+        q, k, v, o, l, do, causal, sc, blk, blk, False, **kw),
+        q, q, q, x, lse, x)
+    if heads:  # nothing is cut to heads or turned on the way in or out
+        assert f"bf16[{bh},{t},{d}]" not in fwd + bwd
+        assert f"bf16[{b},{t},{lanes}]" in bwd
     if t == blk:  # one block each way: the fused dq+dk+dv kernel
         assert "dwt_fa_bwd_fused" in bwd
     else:
@@ -189,6 +212,23 @@ def xl_fsdp4_steps(topo):
     return steps
 
 
+@pytest.mark.parametrize("depth", [2, 3])
+def test_fsdp4_attention_stays_on_the_transposed_route(xl_fsdp4_steps,
+                                                       depth):
+    """25 heads of 64 are 12.5 lane slabs: XL's attention keeps the
+    (b*h, t, d) arrays inside its shard_map — 4 rows x 25 heads a chip —
+    and the kernel calls it always had: a layer's forward, its
+    recomputed forward and its fused backward."""
+    assert fa.attention_route(25, 64) == ("transposed", 0)
+    text = xl_fsdp4_steps[depth].as_text()
+    calls = re.findall(r"= \(?([^=]*?)\)? custom-call\([^\n]*"
+                       r"op_name=\"[^\"]*/(dwt_fa_\w+)/", text)
+    names = [name for _, name in calls]
+    assert names.count("dwt_fa_fwd") == 2 * depth
+    assert names.count("dwt_fa_bwd_fused") == depth
+    assert all("bf16[100,1024,64]" in shapes for shapes, _ in calls)
+
+
 def test_fsdp4_all_to_alls_do_not_grow_with_depth(xl_fsdp4_steps):
     """The `wte` lookup and its scatter-add re-lay the embedding once a
     step; a block re-lays nothing (a residual stream left to the
@@ -226,21 +266,19 @@ def test_fsdp4_step_temporaries_fit(xl_fsdp4_steps):
     assert temp < 1.2 * 2 ** 30, temp / 2 ** 30
 
 
-# ------------------------------------------------- OLMoE's step on one chip
+# ------------------------------------- the control's step on one chip
 
-@pytest.fixture(scope="module")
-def olmoe_step(topo):
-    """`olmoe_1b_7b.steady`'s step as its configuration file builds it —
-    published widths, depth 1, all 64 experts, the cell's batch of 4096-
-    token sequences, Trainer's optimizer — compiled for ONE described
-    v5e chip (about 50 s)."""
+def _one_chip_step(topo, name, model_file):
+    """Cell `name`'s step as its configuration file builds it, the
+    cell's batch, Trainer's optimizer, compiled for ONE described v5e
+    chip: (cell, model, Compiled)."""
     import optax
 
     from benchmark import cells
     from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
 
-    cell = cells.load_cell("olmoe_1b_7b.steady")
-    model = cells.load_module("models", "olmoe").build(cell["config"])
+    cell = cells.load_cell(name)
+    model = cells.load_module("models", model_file).build(cell["config"])
     with pytest.MonkeyPatch.context() as mp, _cache_off():
         mp.setenv("DWT_COMPILE_CACHE", "0")
         mp.setattr(fa, "_on_tpu", lambda: True)
@@ -257,6 +295,41 @@ def olmoe_step(topo):
         step = res.train_step.lower(
             res.state, {"input_ids": ids, "labels": ids}).compile()
     return cell, model, step
+
+
+@pytest.fixture(scope="module")
+def gpt2_124m_step(topo):
+    """`gpt2_124m.steady`'s step (about 30 s)."""
+    return _one_chip_step(topo, "gpt2_124m.steady", "gpt")
+
+
+def test_124m_step_moves_nothing_around_its_attention_kernels(
+        gpt2_124m_step):
+    """The compiled program's own count of the splits, cuts to heads
+    and transposes under the `attn` scope outside the dense layers and
+    the kernels (`hlo_scopes.relayouts`): the transposed route had 192 a
+    step (168 copies, 12 reshapes, 12 slicing fusions).  What stays is
+    the join of dq, dk and dv into c_attn's cotangent, one a layer."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import relayouts
+
+    cell, _, step = gpt2_124m_step
+    text = step.as_text()
+    moved = relayouts(text, "attn", outside=("c_attn", "c_proj"))
+    assert sorted(moved.values()) == ["fusion"] * 12, moved
+    assert all("dynamic-update-slice" in name for name in moved)
+    b, t = cell["global_batch"], cell["seq_len"]
+    # the kernels read c_attn's output and write c_proj's input
+    assert text.count("dwt_fa_fwd") and f"bf16[{b * 12},{t},64]" not in text
+    assert f"operand_layout_constraints={{bf16[{b},{t},2304]" in text
+
+
+# ------------------------------------------------- OLMoE's step on one chip
+
+@pytest.fixture(scope="module")
+def olmoe_step(topo):
+    """`olmoe_1b_7b.steady`'s step — published widths, depth 1, all 64
+    experts, the cell's batch of 4096-token sequences (about 50 s)."""
+    return _one_chip_step(topo, "olmoe_1b_7b.steady", "olmoe")
 
 
 def test_olmoe_step_fits_one_chip_and_fills_it(olmoe_step):
@@ -278,8 +351,26 @@ def test_olmoe_step_runs_the_kernels_at_d128_t4096(olmoe_step):
     text = step.as_text()
     for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"):
         assert kernel in text, kernel
-    # (batch x 16 heads, 4096, 128) in the kernels' layout
-    assert f"bf16[{cell['global_batch'] * 16},4096,128]" in text
+    # a head is a lane slab: the kernels index the projections' own
+    # (batch, 4096, 16 x 128), nothing is laid out by head
+    b = cell["global_batch"]
+    assert fa.attention_route(16, 128) == ("direct", 1)
+    assert f"operand_layout_constraints={{bf16[{b},4096,2048]" in text
+    assert f"bf16[{b * 16},4096,128]" not in text
+
+
+def test_olmoe_step_moves_little_around_its_attention_kernels(olmoe_step):
+    """`hlo_scopes.relayouts` under `attention` outside the projections
+    and QK-norm.  What stays: RoPE's half-swap on the (b, t, h*d) rows
+    (the two lane rolls of q and of k, four slicing fusions and two
+    copies forward, six slices backward) and delta's turn to
+    (b*h, 1, t) (a copy and a reshape of a (b, t, h) array)."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import relayouts
+
+    moved = relayouts(olmoe_step[2].as_text(), "attention", outside=(
+        "q_proj", "k_proj", "v_proj", "o_proj", "qk_norm"))
+    assert sorted(moved.values()) == \
+        ["copy"] + ["fusion"] * 6 + ["reshape"] + ["slice"] * 6, moved
 
 
 def test_olmoe_step_keeps_its_scopes_and_names_the_grouped_matmuls(

@@ -6,8 +6,8 @@ host readback; iterations chain on carried values.
 A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
-Usage: python tools/perf_probe.py [attn|attn_sweep|head|model|opt|step|lib|
-dispatch|rpc] ...  (no args = step/attn/head/model/opt).  One JSON line
+Usage: python tools/perf_probe.py [attn|attn_sweep|attn_direct|head|model|opt|
+step|lib|dispatch|rpc] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -115,10 +115,44 @@ def probe_attn(block_q=1024, block_k=1024, tag="attn"):
           ideal_fwdbwd_ms=round(7 * mm / 155e12 * 1e3, 2))
 
 
+def probe_attn_direct():
+    """The same heads on the two routes of `attention_route`, a layer's
+    attention as a model runs it, forward + backward: c_attn's
+    (B, T, 3*H*D) output through `flash_attention_projected` (the
+    kernels index it, two heads a lane slab), and through the split,
+    the cut to heads and `mha`'s transposes (the transposed route)."""
+    from dlrover_wuqiong_tpu.ops.flash_attention import (
+        flash_attention_projected,
+        mha,
+    )
+
+    qkv = jax.random.normal(jax.random.PRNGKey(0), (B, T, 3 * E),
+                            jnp.bfloat16)
+
+    def direct(qkv):
+        return flash_attention_projected((qkv,), H)
+
+    def transposed(qkv):
+        q, k, v = (x.reshape(B, T, H, D) for x in jnp.split(qkv, 3, -1))
+        return mha(q, k, v).reshape(B, T, E)
+
+    for tag, attn in (("attn_direct", direct),
+                      ("attn_transposed", transposed)):
+        @jax.jit
+        def fwdbwd(qkv, attn=attn):
+            for _ in range(INNER):
+                qkv = jax.grad(lambda x: attn(x).astype(
+                    jnp.float32).sum())(qkv)
+            return qkv
+
+        _emit(tag, _time(fwdbwd, qkv, iters=5) / INNER, heads=[H, D])
+
+
 def probe_attn_sweep():
     for bq, bk in [(1024, 1024), (512, 1024), (512, 512), (256, 512),
                    (256, 256), (128, 128)]:
         probe_attn(bq, bk, tag=f"attn_{bq}x{bk}")
+    probe_attn_direct()
 
 
 def probe_lib():
@@ -522,7 +556,8 @@ def probe_rpc(rounds=2, clients=48, procs=4, duration_s=1.5,
                    round(gc_mean / base_mean, 2) if base_mean else 0.0})
 
 
-ALL = {"attn": probe_attn, "attn_sweep": probe_attn_sweep, "lib": probe_lib,
+ALL = {"attn": probe_attn, "attn_sweep": probe_attn_sweep,
+       "attn_direct": probe_attn_direct, "lib": probe_lib,
        "remat": probe_remat,
        "splash": probe_splash, "dots": probe_dots,
        "head": probe_head, "model": probe_model, "opt": probe_opt,
