@@ -42,6 +42,7 @@ from ..telemetry import spans as tspans
 from ..parallel.sharding import ShardingPlanner, activation_spec
 from ..trainer.train_step import (
     TrainState,
+    leave_untouched,
     make_lm_loss,
     make_train_step,
     train_state_shardings,
@@ -477,6 +478,9 @@ def auto_accelerate(
     """
     # two spans, because a seeded or resumed run throws the init away
     # and keeps the plan: `accelerate:plan`, then `accelerate:init_state`
+    # leaves the model keeps in its parameter tree and the optimizer must
+    # leave alone; read before a strategy wraps the model
+    untrained = tuple(getattr(model, "untrained_params", ()))
     with tspans.span("accelerate:plan"):
         devices = list(devices if devices is not None else jax.devices())
         # Level-1 warm restarts: every build compiles through the persistent
@@ -599,6 +603,8 @@ def auto_accelerate(
 
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     optimizer = optimizer or optax.adamw(3e-4)
+    if untrained:
+        optimizer = leave_untouched(optimizer, untrained)
     stable_bf16_cfg = ctx.extra.get("stable_bf16")
     if stable_bf16_cfg is not None:
         from ..optimizers.bf16_stable import stable_bf16

@@ -54,6 +54,12 @@ class LlamaConfig:
     # RMSNorm (own scale, `rms_eps`) over all of q's and all of k's
     # features, before the heads are split and before RoPE (OLMoE)
     qk_norm: bool = False
+    # an explicit head size where heads x size is not the hidden size
+    # (q and o are then hidden x heads*size); 0 = hidden_size // num_heads
+    attn_head_dim: int = 0
+    # False: q and k are not rotated (a hybrid whose other layers carry
+    # the positions)
+    rope: bool = True
 
     @classmethod
     def nano(cls):
@@ -72,17 +78,30 @@ class LlamaConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
+
+    def attention_params(self) -> int:
+        """q, k, v, o and the QK-norm's scales; no block norm."""
+        h, q = self.hidden_size, self.num_heads * self.head_dim
+        kv = self.num_kv_heads * self.head_dim
+        return 2 * h * q + 2 * h * kv + (q + kv if self.qk_norm else 0)
+
+    def ffn_params(self) -> int:
+        """The feed-forward slot: a SwiGLU, or `moe`'s expert layer — the
+        router over all experts, the experts HELD here, a selection bias,
+        a shared expert — each expert `intermediate_size` wide."""
+        h, i = self.hidden_size, self.intermediate_size
+        if self.moe is None:
+            return 3 * h * i
+        m = self.moe
+        mats = 3 if m.expert_act == "swiglu" else 2
+        return (m.num_experts * h + m.held * mats * h * i
+                + (m.num_experts if m.selection_bias else 0)
+                + 2 * h * m.shared_width)
 
     def num_params(self) -> int:
-        h, i = self.hidden_size, self.intermediate_size
-        kv = self.num_kv_heads * self.head_dim
-        ffn = 3 * h * i
-        if self.moe is not None:  # E experts and the router
-            ffn = self.moe.num_experts * (ffn + h)
-        per_layer = h * h + 2 * h * kv + h * h + ffn + 2 * h
-        if self.qk_norm:
-            per_layer += h + kv
+        h = self.hidden_size
+        per_layer = self.attention_params() + self.ffn_params() + 2 * h
         return (2 * self.vocab_size * h + self.num_layers * per_layer + h)
 
 
@@ -159,8 +178,9 @@ class LlamaAttention(nn.Module):
             q = q.reshape(B, T, cfg.num_heads, hd)
             k = k.reshape(B, T, cfg.num_kv_heads, hd)
             v = v.reshape(B, T, cfg.num_kv_heads, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cfg.rope:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         rep = cfg.num_heads // cfg.num_kv_heads
         if rep > 1:  # GQA: repeat kv heads
             k, v = (jnp.repeat(t.reshape(B, T, cfg.num_kv_heads, hd), rep,

@@ -1,0 +1,140 @@
+"""Mamba-2 mixer (state-space layer) over `ops/ssd.py`'s chunked scan.
+
+    [z | xBC | dt] = u @ W_in           d_inner | d_inner + 2*G*N | H
+    xBC = silu(causal_depthwise_conv1d(xBC) + b_conv)
+    [x | B | C] = xBC                   x (T,H,P); B, C (T,G,N)
+    y   = ssd_scan(x, softplus(dt + dt_bias), -exp(A_log), B, C, D)
+    y   = RMSNorm over each group of d_inner/G features of y * silu(z),
+          one (d_inner,) scale
+    out = y @ W_out
+
+d_inner = heads x head_dim: the projections' widths are given, not
+derived from an expansion factor.  Scopes, under the module's own name:
+`in_proj`, `conv`, `ssd`, `gate_norm`, `out_proj`.  Parameter names are
+matched by `parallel/sharding.py` (the two projections as dense
+kernels, everything else replicated).
+
+Parity: none — the reference's model zoo (atorch) is attention-only; the
+equations are `nemotron_h`'s, as benchmark/reference_nemotron_h.py
+writes them out.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops.ssd import ssd_scan
+
+
+@dataclasses.dataclass(frozen=True)
+class Mamba2Config:
+    hidden_size: int = 256
+    num_heads: int = 8
+    head_dim: int = 32
+    n_groups: int = 2
+    state_size: int = 16
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    # initialiser settings of dt_bias, not a clamp in the forward pass:
+    # softplus(dt_bias) is drawn log-uniform in [dt_min, dt_max], >= floor
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+    @property
+    def d_inner(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.state_size
+
+    def num_params(self) -> int:
+        h, di = self.hidden_size, self.d_inner
+        return (h * (di + self.conv_dim + self.num_heads)  # in_proj
+                + (self.conv_kernel + 1) * self.conv_dim   # conv + bias
+                + 3 * self.num_heads                       # dt_bias A_log D
+                + di + di * h)                             # gate_norm out
+
+
+def _dt_bias_init(cfg: Mamba2Config):
+    def init(key, shape, dtype=jnp.float32):
+        u = jax.random.uniform(key, shape, jnp.float32)
+        dt = jnp.exp(u * (math.log(cfg.dt_max) - math.log(cfg.dt_min))
+                     + math.log(cfg.dt_min))
+        dt = jnp.maximum(dt, cfg.dt_floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)  # softplus^-1
+    return init
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+                   ).astype(dtype)
+
+
+def _conv_init(width: int):
+    bound = 1.0 / math.sqrt(width)  # a depthwise filter's fan-in
+
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    config: Mamba2Config
+
+    @nn.compact
+    def __call__(self, u):  # (B, T, hidden)
+        cfg = self.config
+        bsz, t, _ = u.shape
+        di, gn = cfg.d_inner, cfg.n_groups * cfg.state_size
+        proj = nn.Dense(di + cfg.conv_dim + cfg.num_heads, use_bias=False,
+                        dtype=cfg.dtype, name="in_proj")(u)
+        z, xbc, dt = jnp.split(proj, [di, di + cfg.conv_dim], axis=-1)
+
+        kernel = self.param("conv_kernel", _conv_init(cfg.conv_kernel),
+                            (cfg.conv_kernel, cfg.conv_dim))
+        bias = self.param("conv_bias", _conv_init(cfg.conv_kernel),
+                          (cfg.conv_dim,))
+        with jax.named_scope("conv"):
+            # the filter's LAST tap multiplies the current step; k shifted
+            # products, which XLA fuses into one pass
+            k = cfg.conv_kernel
+            padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+            conv = sum(padded[:, j:j + t] * kernel[j].astype(cfg.dtype)
+                       for j in range(k))
+            xbc = jax.nn.silu(conv + bias.astype(cfg.dtype))
+        x, b_mat, c_mat = jnp.split(xbc, [di, di + gn], axis=-1)
+
+        dt_bias = self.param("dt_bias", _dt_bias_init(cfg),
+                             (cfg.num_heads,))
+        a_log = self.param("A_log", _a_log_init, (cfg.num_heads,))
+        d_skip = self.param("D", nn.initializers.ones, (cfg.num_heads,))
+        # `ssd_scan` opens the `ssd` scope itself; the step sizes and the
+        # decay rates are float32 from here on
+        with jax.named_scope("ssd"):
+            dlt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            a = -jnp.exp(a_log.astype(jnp.float32))
+        y = ssd_scan(
+            x.reshape(bsz, t, cfg.num_heads, cfg.head_dim), dlt, a,
+            b_mat.reshape(bsz, t, cfg.n_groups, cfg.state_size),
+            c_mat.reshape(bsz, t, cfg.n_groups, cfg.state_size),
+            d_skip, chunk=cfg.chunk_size, dtype=cfg.dtype)
+
+        scale = self.param("gate_norm_scale", nn.initializers.ones, (di,))
+        with jax.named_scope("gate_norm"):
+            y = y.reshape(bsz, t, di) * jax.nn.silu(z.astype(jnp.float32))
+            grouped = y.reshape(bsz, t, cfg.n_groups, di // cfg.n_groups)
+            grouped = grouped * jax.lax.rsqrt(
+                jnp.mean(grouped * grouped, -1, keepdims=True) + cfg.eps)
+            y = (grouped.reshape(bsz, t, di) * scale).astype(cfg.dtype)
+        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                        name="out_proj")(y)

@@ -27,11 +27,21 @@ returns them reduced (`collect_moe_stats`) beside `loss`, and the
 Trainer's metrics pump reads them where it already reads the loss and
 passes them on as it does any scalar a step counts (log line, callbacks,
 a `trainer:step_metrics` span event).
+
+A chip's share of a wider deployment (`experts_held` < `num_experts`):
+the layer is told which experts it holds, routes over all of them and
+computes its own experts' part of the result on the same grouped path,
+with one group a HELD expert (`grouped_experts`); what the absent
+experts would have added is left out, and nothing stands in for the
+chips that hold them or for the exchange with them.  Such a layer also
+sows `moe_rows_held` and `moe_rows_absent` (assignments to experts held
+/ not held here); an assignment to an absent expert is not a drop.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -59,10 +69,62 @@ class MoEConfig:
     # and exists in the grouped path only: MoEMLP refuses it on "capacity"
     norm_topk_prob: bool = True
     # "switch": top-1 fraction x mean prob x E^2 | "topk": HF
-    # load_balancing_loss_func, E * sum_i f_i P_i over top-k membership
+    # load_balancing_loss_func, E * sum_i f_i P_i over top-k membership |
+    # "none": no auxiliary term is computed or sown (grouped path)
     aux_loss: str = "switch"
     # router z-loss mean(logsumexp(logits)^2); 0 = none
     z_loss_weight: float = 0.0
+    # The fields below exist in the grouped path only: MoEMLP refuses
+    # every one of them, off its default, on "capacity" (top_k_gating is
+    # softmax + renormalised SwiGLU dispatch over all experts, nothing
+    # else), as it refuses norm_topk_prob=False there.
+    # "softmax" over the router's outputs | "sigmoid" of each (the gates
+    # are then normalised by sum + 1e-20 where norm_topk_prob is set)
+    score_func: str = "softmax"
+    # a (num_experts,) variable `selection_bias` added to the scores for
+    # the CHOICE of the k experts only; the gates are the scores without
+    # it.  It receives no gradient (it enters through a top-k alone); the
+    # model names it in `untrained_params`: the optimizer leaves it alone
+    selection_bias: bool = False
+    # the out-of-band rule that sets `selection_bias` (auxiliary-loss-free
+    # balancing, arXiv:2408.15664, in its error-proportional form): after
+    # a step, bias_i += rate * clip((even - load_i) / even, -1, 1), load_i
+    # the step's assignments to expert i of ALL num_experts (held here or
+    # not) and even their mean.  At most `rate` a step, so an expert with
+    # twice its share or none moves as under the sign form, and one near
+    # its share hardly moves.  The layer sows that step as
+    # `moe_selection_bias_step`; `make_train_step` adds it to the variable
+    # (`collect_param_steps`), the optimizer still leaves it alone.
+    # 0 = the rule is not run
+    bias_update_rate: float = 0.0
+    # the k gates are multiplied by this after normalisation
+    routed_scaling: float = 1.0
+    # an expert's form: "swiglu" (silu(x Wg) * (x Wi)) Wd | "relu2"
+    # relu(x Wi)^2 Wd, no gate matrix
+    expert_act: str = "swiglu"
+    # width of one always-on relu2 expert beside the routed ones, on
+    # every token (scope `shared`; with expert_act="relu2"); 0 = none
+    shared_width: int = 0
+    # how many of the num_experts this layer holds, experts first_expert
+    # .. first_expert + experts_held - 1; 0 = all of them
+    experts_held: int = 0
+    first_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    def grouped_only_fields(self) -> dict:
+        """The fields the capacity path cannot honour, off their defaults."""
+        off = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)
+               if f.name in ("score_func", "selection_bias", "routed_scaling",
+                             "bias_update_rate",
+                             "expert_act", "shared_width", "experts_held",
+                             "first_expert", "norm_topk_prob")
+               and getattr(self, f.name) != f.default}
+        if self.aux_loss == "none":
+            off["aux_loss"] = "none"
+        return off
 
 
 def top_k_gating(logits: jax.Array, k: int, capacity: int,
@@ -111,34 +173,81 @@ def top_k_gating(logits: jax.Array, k: int, capacity: int,
     return combine, dispatch
 
 
-def route_top_k(probs: jax.Array, top_k: int, norm_topk_prob: bool = True
-                ) -> Tuple[jax.Array, jax.Array]:
-    """(gates (T, k), experts (T, k)) of the k largest router probs."""
-    gates, experts = jax.lax.top_k(probs, top_k)
+def route_top_k(probs: jax.Array, top_k: int, norm_topk_prob: bool = True,
+                bias: Optional[jax.Array] = None, floor: bool = True,
+                scaling: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """(gates (T, k), experts (T, k)) of the k largest router scores —
+    of `probs + bias` where a selection bias is given; the gates are
+    `probs` at the chosen experts, WITHOUT the bias.  Normalised gates
+    are divided by max(sum, 1e-9) (`floor`), or by sum + 1e-20 (the
+    sigmoid router's published form)."""
+    if bias is None:
+        gates, experts = jax.lax.top_k(probs, top_k)
+    else:
+        _, experts = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias), top_k)
+        gates = jnp.take_along_axis(probs, experts, axis=-1)
     if norm_topk_prob:
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        total = gates.sum(-1, keepdims=True)
+        gates = gates / (jnp.maximum(total, 1e-9) if floor
+                         else total + 1e-20)
+    if scaling != 1.0:
+        gates = gates * scaling
     return gates, experts
 
 
 def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
-                    w_gate: jax.Array, w_in: jax.Array, w_down: jax.Array
+                    w_gate: Optional[jax.Array], w_in: jax.Array,
+                    w_down: jax.Array, first_expert: int = 0,
+                    num_experts: Optional[int] = None
                     ) -> Tuple[jax.Array, jax.Array]:
     """The dropless expert pass for a routing already made: returns
-    (out (T, d), group_sizes (E,)).  Scopes: `dispatch` (sort, gather),
+    (out (T, d), group_sizes (held,)).  Scopes: `dispatch` (sort, gather),
     `experts` (grouped matmuls, gating product), `combine` (weighting,
-    scatter-add)."""
+    scatter-add).  `w_gate=None`: relu^2 experts.
+
+    The weights hold `held = w_in.shape[0]` experts, numbers
+    `first_expert ..` of the `num_experts` that `experts` (T, k) names.
+    Fewer than all of them is a chip's share: an assignment to an absent
+    expert is taken out BEFORE the sort.  It gets no group (`group_sizes`
+    has one entry a held expert), its place in the static (T*k)-row
+    buffer lies behind every held row, it gathers no token (the index is
+    out of range: a row of zeros, and in the backward pass a scatter
+    that drops it) and is scattered to none.  No group of `ragged_dot`
+    writes those places, and what the TPU's grouped kernels leave there
+    is not zero (a NaN by step 20, PERF.md section 6, PR 31): each
+    product's places behind the held rows are set to zero, and so, by
+    the same mask's transpose, are those of its cotangent."""
     T, top_k = experts.shape
     E = w_in.shape[0]
+    share = num_experts is not None and E < num_experts
     with jax.named_scope("dispatch"):
         flat_expert = experts.reshape(-1)              # (T*k,)
+        if share:
+            flat_expert = flat_expert - first_expert
+            flat_expert = jnp.where(
+                (flat_expert >= 0) & (flat_expert < E), flat_expert, E)
         order = jnp.argsort(flat_expert)               # stable per expert
         token_idx = order // top_k                     # source token of row
         group_sizes = jnp.bincount(flat_expert, length=E)
-        xs = tokens[token_idx].astype(w_in.dtype)      # (T*k, d) sorted
+        if share:
+            held_row = jnp.arange(T * top_k) < group_sizes.sum()
+            token_idx = jnp.where(held_row, token_idx, T)
+            xs = tokens.at[token_idx].get(mode="fill", fill_value=0)
+        else:
+            xs = tokens[token_idx]
+        xs = xs.astype(w_in.dtype)                     # (T*k, d) sorted
+
+    def grouped(lhs, rhs):
+        out = jax.lax.ragged_dot(lhs, rhs, group_sizes)
+        return jnp.where(held_row[:, None], out, 0) if share else out
+
     with jax.named_scope("experts"):
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, group_sizes)) * \
-            jax.lax.ragged_dot(xs, w_in, group_sizes)
-        ys = jax.lax.ragged_dot(h, w_down, group_sizes)    # (T*k, d)
+        if w_gate is None:  # relu^2: no gate matrix
+            h = jnp.square(jax.nn.relu(grouped(xs, w_in)))
+        else:
+            h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_in)
+        ys = grouped(h, w_down)                        # (T*k, d)
     with jax.named_scope("combine"):
         flat_gates = gates.reshape(-1)[order].astype(ys.dtype)
         out = jax.ops.segment_sum(ys * flat_gates[:, None], token_idx,
@@ -192,15 +301,17 @@ def _aux_loss(cfg: MoEConfig, logits, probs, experts):
 
 
 class MoEMLP(nn.Module):
-    """Drop-in MLP replacement: router + E stacked SwiGLU experts.
+    """Drop-in MLP replacement: router + stacked experts (SwiGLU, or
+    relu^2 without a gate matrix), and where `shared_width` is set one
+    always-on relu^2 expert beside them.
 
-    Expert weights are (E, d, h)/(E, h, d) so the `ep` mesh axis shards the
-    leading dim (MOE_RULES in parallel/sharding.py); dispatch/combine einsums
-    let GSPMD place the all-to-alls on ICI.
+    Expert weights are (held, d, h)/(held, h, d) so the `ep` mesh axis
+    shards the leading dim (MOE_RULES in parallel/sharding.py);
+    dispatch/combine einsums let GSPMD place the all-to-alls on ICI.
 
     Scopes in the compiled step (analysis/hlo_scopes.py): everything under
-    `moe`; `router` (the flax Dense's own name), `aux`, and in the grouped
-    path `dispatch`, `experts`, `combine`.
+    `moe`; `router` (the flax Dense's own name), `aux`, `shared`, and in
+    the grouped path `dispatch`, `experts`, `combine`.
     """
 
     hidden: int
@@ -213,10 +324,18 @@ class MoEMLP(nn.Module):
     @jax.named_scope("moe")
     def __call__(self, x):  # x: (B, T, d)
         cfg = self.moe
-        if cfg.impl != "grouped" and not cfg.norm_topk_prob:
+        if cfg.impl != "grouped" and cfg.grouped_only_fields():
             raise ValueError(
-                "MoEConfig.norm_topk_prob=False needs impl='grouped': "
-                "top_k_gating always renormalises the k gates")
+                f"MoEConfig fields {cfg.grouped_only_fields()} need "
+                f"impl='grouped': top_k_gating is a softmax router with "
+                f"renormalised gates over SwiGLU experts that are all "
+                f"held, and nothing else")
+        if cfg.shared_width and cfg.expert_act != "relu2":
+            raise ValueError("a shared expert exists in the relu2 form "
+                             "only: no model here has another")
+        if cfg.bias_update_rate and not cfg.selection_bias:
+            raise ValueError("bias_update_rate sets the selection bias: "
+                             "there is none")
         B, T, d = x.shape
         tokens = x.reshape(B * T, d)
         n_tok = B * T
@@ -229,28 +348,58 @@ class MoEMLP(nn.Module):
 
         w_in = self.param(
             "experts_w_in", nn.initializers.normal(0.02),
-            (cfg.num_experts, d, self.ffn)).astype(cfg.dtype)
-        w_gate = self.param(
-            "experts_w_gate", nn.initializers.normal(0.02),
-            (cfg.num_experts, d, self.ffn)).astype(cfg.dtype)
+            (cfg.held, d, self.ffn)).astype(cfg.dtype)
+        w_gate = None
+        if cfg.expert_act == "swiglu":
+            w_gate = self.param(
+                "experts_w_gate", nn.initializers.normal(0.02),
+                (cfg.held, d, self.ffn)).astype(cfg.dtype)
+        elif cfg.expert_act != "relu2":
+            raise ValueError(f"unknown MoEConfig.expert_act "
+                             f"{cfg.expert_act!r}")
         w_out = self.param(
             "experts_w_down", nn.initializers.normal(0.02),
-            (cfg.num_experts, self.ffn, d)).astype(cfg.dtype)
+            (cfg.held, self.ffn, d)).astype(cfg.dtype)
+        bias = None
+        if cfg.selection_bias:
+            # small and not zero, so that a seeded model's choice of
+            # experts differs from its scores' order
+            bias = self.param("selection_bias",
+                              nn.initializers.normal(0.02),
+                              (cfg.num_experts,))
 
         with jax.named_scope("router"):
-            probs = jax.nn.softmax(logits, axis=-1)
+            if cfg.score_func == "softmax":
+                probs = jax.nn.softmax(logits, axis=-1)
+            elif cfg.score_func == "sigmoid":
+                probs = jax.nn.sigmoid(logits)
+            else:
+                raise ValueError(f"unknown MoEConfig.score_func "
+                                 f"{cfg.score_func!r}")
         gates = experts = None
         if cfg.impl == "grouped" or cfg.aux_loss == "topk":
             with jax.named_scope("dispatch"):
-                gates, experts = route_top_k(probs, cfg.top_k,
-                                             cfg.norm_topk_prob)
-        with jax.named_scope("aux"):
-            self.sow("intermediates", "moe_aux_loss",
-                     _aux_loss(cfg, logits, probs, experts))
+                gates, experts = route_top_k(
+                    probs, cfg.top_k, cfg.norm_topk_prob, bias=bias,
+                    floor=cfg.score_func == "softmax",
+                    scaling=cfg.routed_scaling)
+        if cfg.aux_loss != "none":
+            with jax.named_scope("aux"):
+                self.sow("intermediates", "moe_aux_loss",
+                         _aux_loss(cfg, logits, probs, experts))
+        if cfg.bias_update_rate:
+            with jax.named_scope("dispatch"):
+                load = jnp.bincount(experts.reshape(-1),
+                                    length=cfg.num_experts)
+                even = n_tok * cfg.top_k / cfg.num_experts
+                self.sow("intermediates", "moe_selection_bias_step",
+                         cfg.bias_update_rate * jnp.clip(
+                             (even - load) / even, -1.0, 1.0))
 
         if cfg.impl == "grouped":
-            out, counts = grouped_experts(tokens, gates, experts, w_gate,
-                                          w_in, w_out)
+            out, counts = grouped_experts(
+                tokens, gates, experts, w_gate, w_in, w_out,
+                first_expert=cfg.first_expert, num_experts=cfg.num_experts)
         else:
             combine, dispatch = top_k_gating(logits, cfg.top_k, capacity)
             # dispatch: (T, E, C) x (T, d) -> (E, C, d)
@@ -265,8 +414,21 @@ class MoEMLP(nn.Module):
         # counted, not timed: rows each expert ran, and the assignments
         # (token, one of its k) that reached none
         self.sow("intermediates", "moe_tokens_per_expert", counts)
-        self.sow("intermediates", "moe_dropped",
-                 n_tok * cfg.top_k - counts.sum())
+        dropped = n_tok * cfg.top_k - counts.sum()
+        if cfg.held < cfg.num_experts:
+            # the rest of the token's k went to experts on other chips:
+            # counted, and not a drop
+            self.sow("intermediates", "moe_rows_held", counts.sum())
+            self.sow("intermediates", "moe_rows_absent", dropped)
+            dropped = jnp.zeros((), dropped.dtype)
+        self.sow("intermediates", "moe_dropped", dropped)
+        if cfg.shared_width:
+            with jax.named_scope("shared"):
+                dense = functools.partial(nn.Dense, use_bias=False,
+                                          dtype=cfg.dtype)
+                h = jnp.square(jax.nn.relu(
+                    dense(cfg.shared_width, name="shared_up_proj")(tokens)))
+                out = out + dense(d, name="shared_down_proj")(h)
         return out.reshape(B, T, d)
 
 
@@ -288,17 +450,37 @@ def collect_moe_aux_loss(intermediates) -> jax.Array:
     return total
 
 
+def collect_param_steps(intermediates) -> Dict[str, Any]:
+    """The steps the layers of one forward pass ask for on variables the
+    optimizer leaves alone, as a tree shaped like the part of `params`
+    they touch ({} where no layer runs such a rule): today each sown
+    `moe_selection_bias_step` goes to its own layer's `selection_bias`
+    (MoEConfig.bias_update_rate)."""
+    steps: Dict[str, Any] = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
+        keys = [getattr(p, "key", getattr(p, "name", None)) for p in path]
+        if "moe_selection_bias_step" not in keys:
+            continue
+        node = steps
+        for key in keys[:keys.index("moe_selection_bias_step")]:
+            node = node.setdefault(key, {})
+        node["selection_bias"] = leaf
+    return steps
+
+
 def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
-    """What the MoE layers of one forward pass counted, reduced to two
+    """What the MoE layers of one forward pass counted, reduced to
     scalars — or {} for a model with no such layer: the worst layer's
-    `max_i(tokens_i) / mean_i(tokens_i)` and the dropped assignments of
-    all layers."""
+    `max_i(tokens_i) / mean_i(tokens_i)`, the dropped assignments of all
+    layers, and where the layers hold a share of their experts the
+    assignments to experts held / not held here, all layers."""
     counts = [n.astype(jnp.float32)
               for n in _sown(intermediates, "moe_tokens_per_expert")]
     if not counts:
         return {}
     loads = [n.max() / jnp.maximum(n.mean(), 1.0) for n in counts]
-    dropped = [jnp.sum(d) for d in _sown(intermediates, "moe_dropped")]
+    sums = {name: [jnp.sum(v) for v in _sown(intermediates, name)]
+            for name in ("moe_dropped", "moe_rows_held", "moe_rows_absent")}
     return {"moe_load_max_over_mean": jnp.max(jnp.stack(loads)),
-            "moe_dropped": jnp.sum(jnp.stack(dropped))}
-
+            **{name: jnp.sum(jnp.stack(v)) for name, v in sums.items()
+               if v}}

@@ -67,6 +67,13 @@ TRANSFORMER_RULES: List[Rule] = [
     # MLP down: row-parallel
     (r".*(mlp|ffn|feed_forward).*(down_proj|c_proj|fc2|w2)/kernel$",
      P("tp", "fsdp")),
+    # a state-space mixer's two projections (models/mamba2.py): column-
+    # then row-parallel, as an MLP's; its filter, its per-head leaves and
+    # an expert router's selection bias are small and replicated
+    (r".*mamba.*in_proj/kernel$", P("fsdp", "tp")),
+    (r".*mamba.*out_proj/kernel$", P("tp", "fsdp")),
+    (r".*mamba.*/(conv_kernel|conv_bias|A_log|D|dt_bias)$", P()),
+    (r".*selection_bias$", P()),
     # lm head: vocab-parallel
     (r".*(lm_head|output_proj)/kernel$", P("fsdp", "tp")),
     # biases follow their kernel's output dim

@@ -19,6 +19,7 @@ stay exactly reachable at fusion boundaries.
 from __future__ import annotations
 
 import functools
+import re
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -27,7 +28,7 @@ import optax
 from jax.sharding import Mesh
 
 from ..common.log import get_logger
-from ..parallel.sharding import ShardingPlanner
+from ..parallel.sharding import ShardingPlanner, path_of
 
 logger = get_logger("train_step")
 
@@ -41,6 +42,37 @@ class TrainState(NamedTuple):
     def create(cls, params, optimizer: optax.GradientTransformation):
         return cls(step=jnp.zeros((), jnp.int32), params=params,
                    opt_state=optimizer.init(params))
+
+
+def leave_untouched(optimizer: optax.GradientTransformation,
+                    patterns: Tuple[str, ...]) -> optax.GradientTransformation:
+    """`optimizer` with a zero update for every parameter leaf whose WHOLE
+    path matches one of `patterns` (regular expressions over
+    `layers_1/feed_forward/selection_bias`, as the sharding rules are):
+    neither a step nor weight decay reaches it.  For a model's
+    `untrained_params`: a variable that rides in the parameter tree,
+    because the train state carries no other collection, and is set by a
+    rule of its own, or by none.  The optimizer's state keeps its shape,
+    so every sharding rule that reads it as parameter-shaped still does."""
+    compiled = [re.compile(p) for p in patterns]
+
+    def update(grads, state, params=None):
+        updates, state = optimizer.update(grads, state, params)
+        updates = jax.tree_util.tree_map_with_path(
+            lambda path, u: jnp.zeros_like(u)
+            if any(c.fullmatch(path_of(path)) for c in compiled) else u,
+            updates)
+        return updates, state
+
+    return optax.GradientTransformation(optimizer.init, update)
+
+
+def _add_steps(params, steps):
+    """`params` with `steps` (a tree over some of its leaves) added."""
+    if not isinstance(steps, dict):
+        return params + steps.astype(params.dtype)
+    return {**params, **{k: _add_steps(params[k], v)
+                         for k, v in steps.items()}}
 
 
 @jax.named_scope("accum")  # names the scan in the compiled step
@@ -112,7 +144,10 @@ def make_train_step(
     def _grads(params, batch):
         """(loss, grads, stats): a loss that also counts (make_lm_loss:
         the MoE layers' load) hands its counters out beside the loss,
-        and they ride in the step's metrics."""
+        and they ride in the step's metrics.  `stats["param_steps"]`,
+        where the loss gives one, is no counter: a tree shaped like part
+        of `params`, added to them after the optimizer's update (a rule
+        run out of band on variables the optimizer leaves alone)."""
         if value_and_grad_fn is not None:
             return (*value_and_grad_fn(params, batch), {})
         with_stats = getattr(loss_fn, "with_stats", None)
@@ -142,6 +177,10 @@ def make_train_step(
                 opt_state = jax.device_put(opt_state, opt_host_shardings)
             params = optax.apply_updates(state.params, updates)
             gnorm = optax.global_norm(grads)
+        steps = stats.pop("param_steps", None)
+        if steps:
+            with jax.named_scope("out_of_band"):
+                params = _add_steps(params, steps)
         new_state = TrainState(state.step + 1, params, opt_state)
         return new_state, {"loss": loss, "grad_norm": gnorm, **stats}
 
@@ -296,10 +335,17 @@ def make_lm_loss(model_apply: Callable) -> Callable:
         inter = updates.get("intermediates", {})
         stats = {}
         if inter:
-            from ..models.moe import collect_moe_aux_loss, collect_moe_stats
+            from ..models.moe import (
+                collect_moe_aux_loss,
+                collect_moe_stats,
+                collect_param_steps,
+            )
 
             loss = loss + collect_moe_aux_loss(inter)
             stats = collect_moe_stats(inter)
+            steps = collect_param_steps(inter)
+            if steps:
+                stats["param_steps"] = steps
         return loss, stats
 
     def loss_fn(params, batch):

@@ -391,3 +391,80 @@ def test_olmoe_step_keeps_its_scopes_and_names_the_grouped_matmuls(
                  "feed_forward/moe/aux", "attention/qk_norm/q_norm",
                  "attention/q_proj", "Llama/head", "loss", "optimizer"):
         assert any(part in s for s in scopes), part
+
+
+# ------------------------------------- Nemotron-3-Nano's step on one chip
+
+@pytest.fixture(scope="module")
+def nemotron_step(topo):
+    """`nemotron3_nano_30b_a3b.steady`'s step — published widths, nine
+    layers (4 Mamba-2, 4 expert, 1 attention), 8 of 128 experts held, the
+    cell's batch of 8192-token sequences, full recomputation (about
+    50 s)."""
+    return _one_chip_step(topo, "nemotron3_nano_30b_a3b.steady",
+                          "nemotron_h")
+
+
+def test_nemotron_step_fits_one_chip_and_fills_it(nemotron_step):
+    """State + temporaries: under 90% of the chip's 16 GB (the batch is
+    the largest that is: 12.4 / 13.7 / 15.0 GB live at 1 / 2 / 3
+    sequences), far over the 25% a cell has to fill."""
+    cell, model, step = nemotron_step
+    assert model.config.num_params() == 666_963_456
+    assert cell["global_batch"] == 2 and cell["seq_len"] == 8192
+    m = step.memory_analysis()
+    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    assert 0.25 * 16 * 2 ** 30 < 0.80 * 16e9 < live < 0.90 * 16e9, live / 1e9
+    assert m.alias_size_in_bytes >= 12 * model.config.num_params()
+
+
+def test_nemotron_step_runs_the_kernels_at_d128_t8192(nemotron_step):
+    cell, _, step = nemotron_step
+    text = step.as_text()
+    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"):
+        assert kernel in text, kernel
+    # 32 heads of 128 on a hidden size of 2688: a head is a lane slab, the
+    # kernels index the projections' own (batch, 8192, 32 x 128)
+    b = cell["global_batch"]
+    assert fa.attention_route(32, 128) == ("direct", 1)
+    assert f"operand_layout_constraints={{bf16[{b},8192,4096]" in text
+    assert f"bf16[{b * 32},8192,128]" not in text
+
+
+def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
+        nemotron_step):
+    """Every scope the per-layer metrics read is in the compiled step.
+    The expert layers run the whole layer's path (the compiler's
+    grouped-matmul kernels over the sorted T*k = 98,304-row buffer) on 8
+    groups: every weight operand holds the 8 held experts, none the
+    published 128, and the group sizes are 8 numbers — an assignment to
+    an absent expert has no group.  Nothing in the step holds other ops
+    (a `while`, a `conditional`), which a device trace would count
+    beside the ops they ran."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+
+    cell, _, step = nemotron_step
+    text = step.as_text()
+    scopes = set(scope_table(text).values())
+    for part in ("mamba/in_proj", "mamba/conv", "mamba/ssd",
+                 "mamba/gate_norm", "mamba/out_proj",
+                 "feed_forward/moe/shared", "feed_forward/moe/router",
+                 "feed_forward/moe/dispatch", "feed_forward/moe/experts",
+                 "feed_forward/moe/combine", "attention/q_proj",
+                 "attention/o_proj", "NemotronH/head", "loss", "optimizer"):
+        assert any(part in s for s in scopes), part
+    assert not any("moe/aux" in s for s in scopes)  # no auxiliary loss
+    rows = cell["global_batch"] * 8192 * 6
+    kernels = re.findall(r"%ragged-dot-none\.\d+ = bf16\[([\d,]+)\]", text)
+    assert kernels and set(kernels) == {
+        f"{rows},1856", f"{rows},2688", "8,2688,1856", "8,1856,2688"}
+    assert "[128,2688,1856]" not in text and "[128,1856,2688]" not in text
+    # 128 numbers appear where the bias's rule counts every expert's
+    # load (dispatch) and steps the bias (out_of_band), nowhere else
+    assert "s32[8]" in text
+    wide = [ln for ln in text.splitlines() if "s32[128]" in ln]
+    assert wide and all("moe/dispatch" in ln or "out_of_band" in ln
+                        for ln in wide if "op_name=" in ln)
+    assert any("out_of_band" in s for s in scopes)
+    assert " while(" not in text and " conditional(" not in text
