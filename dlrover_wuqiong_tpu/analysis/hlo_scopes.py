@@ -205,3 +205,28 @@ def relayouts(hlo_text: str, under: str, outside=()) -> Dict[str, str]:
         if moved and moved <= _MOVES:
             found[name] = ins["opcode"]
     return found
+
+
+def instructions_of(hlo_text: str, opcode: str, under: str
+                    ) -> Dict[str, str]:
+    """{instruction name: result shape} of every instruction of one
+    `opcode` — a device op of its own or one inside a fusion — whose own
+    scope holds the path `under` (`"moe/combine"`; `""`: anywhere): the
+    compiled program's own count of, say, the scatters a layer still
+    makes.  The shape is the text before the opcode
+    (`bf16[163840,2048]{1,0:...}`; a scatter's result has its operand's
+    shape)."""
+    found: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        rest = m.group(2)
+        op = _OPCODE.search(rest)
+        if not op or op.group(1) != opcode:
+            continue
+        name = _OP_NAME.search(rest)
+        scope = scope_of(name.group(1).replace("\\'", "'")) if name else ""
+        if not under or f"/{under}/" in f"/{scope}/":
+            found[m.group(1)] = rest[:op.start(1)].strip()
+    return found

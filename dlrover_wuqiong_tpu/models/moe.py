@@ -196,28 +196,108 @@ def route_top_k(probs: jax.Array, top_k: int, norm_topk_prob: bool = True,
     return gates, experts
 
 
+def _by_assignment(rows: jax.Array, inv: jax.Array,
+                   held_rows: jax.Array) -> jax.Array:
+    """rows (T*k, d) in expert order -> (k, T, d), each assignment's own
+    row: a gather through the sort's inverse.  An assignment whose row
+    lies behind the held rows (its expert is on another chip) reads
+    zeros, whatever that place holds.  The mask comes BEFORE any cast:
+    the TPU compiler then fuses cast, weighting and sum into one pass
+    over the gathered rows; a cast first is written out at full size
+    (3.2 ms a pass at OLMoE's shape, PERF.md section 6, PR 32)."""
+    return jnp.where((inv < held_rows)[..., None], rows[inv],
+                     jnp.zeros((), rows.dtype))
+
+
+@jax.custom_vjp
+def dispatch(tokens: jax.Array, order: jax.Array, inv: jax.Array,
+             held_rows: jax.Array) -> jax.Array:
+    """tokens (T, d) -> rows (T*k, d) in expert order: row r is the
+    token of assignment `order[r]`, token `order[r] // k`.  `inv` (k, T)
+    is the inverse of `order`: `inv[j, t]` is the row of assignment
+    t*k + j (k leads, so that a sum over a token's k rows adds k whole
+    (T, d) slabs).  `held_rows`: how many rows belong to a group.
+
+    `dispatch` and `combine` are each other's transposes and each one's
+    backward pass is the other, so rows move by gathers in both
+    directions: a token's k rows are found through `inv` and summed,
+    none is scattered.  A backward pass is traced under the named scopes
+    of the forward call, so its instructions keep the caller's scope."""
+    return tokens[order // inv.shape[0]]
+
+
+def _dispatch_fwd(tokens, order, inv, held_rows):
+    return dispatch(tokens, order, inv, held_rows), (order, inv, held_rows)
+
+
+def _dispatch_bwd(res, d_rows):
+    return combine(d_rows, None, *res), None, None, None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(rows: jax.Array, gates: Optional[jax.Array], order: jax.Array,
+            inv: jax.Array, held_rows: jax.Array) -> jax.Array:
+    """rows (T*k, d) in expert order, gates (T, k) or None (all ones) ->
+    (T, d): the sum of each token's k rows, weighted in the same pass,
+    accumulated in float32 (`dispatch` says what `order`, `inv` and
+    `held_rows` are)."""
+    picked = _by_assignment(rows, inv, held_rows).astype(jnp.float32)
+    if gates is not None:
+        picked = picked * gates.T[..., None]
+    return picked.sum(0).astype(rows.dtype)
+
+
+def _combine_fwd(rows, gates, order, inv, held_rows):
+    return (combine(rows, gates, order, inv, held_rows),
+            (rows, gates, order, inv, held_rows))
+
+
+def _combine_bwd(res, d_out):
+    rows, gates, order, inv, held_rows = res
+    d_rows = dispatch(d_out, order, inv, held_rows)
+    if gates is None:
+        return d_rows, None, None, None, None
+    # <row, its token's cotangent> in expert order, where both lie (a
+    # pass over the buffer, no second gather of rows), then back to
+    # (T, k) through `inv` as T*k numbers
+    dots = (rows.astype(jnp.float32) * d_rows).sum(-1)
+    d_gates = jnp.where(inv < held_rows, dots[inv], 0.0).T
+    flat_gates = gates.reshape(-1)[order]
+    d_rows = (d_rows * flat_gates[:, None]).astype(rows.dtype)
+    return d_rows, d_gates.astype(gates.dtype), None, None, None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
                     w_gate: Optional[jax.Array], w_in: jax.Array,
                     w_down: jax.Array, first_expert: int = 0,
                     num_experts: Optional[int] = None
                     ) -> Tuple[jax.Array, jax.Array]:
     """The dropless expert pass for a routing already made: returns
-    (out (T, d), group_sizes (held,)).  Scopes: `dispatch` (sort, gather),
-    `experts` (grouped matmuls, gating product), `combine` (weighting,
-    scatter-add).  `w_gate=None`: relu^2 experts.
+    (out (T, d), group_sizes (held,)).  Scopes: `dispatch` (sort, its
+    inverse, gather), `experts` (grouped matmuls, gating product),
+    `combine` (gather through the inverse, weighting, sum over k), and
+    their backward passes under the same two (`dispatch`, `combine`
+    above).  `w_gate=None`: relu^2 experts.
 
     The weights hold `held = w_in.shape[0]` experts, numbers
     `first_expert ..` of the `num_experts` that `experts` (T, k) names.
     Fewer than all of them is a chip's share: an assignment to an absent
     expert is taken out BEFORE the sort.  It gets no group (`group_sizes`
-    has one entry a held expert), its place in the static (T*k)-row
-    buffer lies behind every held row, it gathers no token (the index is
-    out of range: a row of zeros, and in the backward pass a scatter
-    that drops it) and is scattered to none.  No group of `ragged_dot`
-    writes those places, and what the TPU's grouped kernels leave there
-    is not zero (a NaN by step 20, PERF.md section 6, PR 31): each
-    product's places behind the held rows are set to zero, and so, by
-    the same mask's transpose, are those of its cotangent."""
+    has one entry a held expert) and its place in the static (T*k)-row
+    buffer lies behind every held row.  No group of `ragged_dot` writes
+    those places, and what the TPU's grouped kernels leave there is not
+    zero (a NaN by step 20, PERF.md section 6, PR 31).  Two things keep
+    it out of every result and gradient: the first product's places
+    behind the held rows are set to zero (so the activation's are, and
+    by the mask's transpose those of its cotangent), and the sums over a
+    token's k rows read no place behind them (`_by_assignment`), be it of
+    the last product or of the rows' own gradient."""
     T, top_k = experts.shape
     E = w_in.shape[0]
     share = num_experts is not None and E < num_experts
@@ -228,14 +308,13 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
             flat_expert = jnp.where(
                 (flat_expert >= 0) & (flat_expert < E), flat_expert, E)
         order = jnp.argsort(flat_expert)               # stable per expert
-        token_idx = order // top_k                     # source token of row
+        # the inverse of a permutation is its argsort: T*k integers (on
+        # the chip a third of what scattering them costs, PERF.md PR 32)
+        inv = jnp.argsort(order).reshape(T, top_k).T
         group_sizes = jnp.bincount(flat_expert, length=E)
-        if share:
-            held_row = jnp.arange(T * top_k) < group_sizes.sum()
-            token_idx = jnp.where(held_row, token_idx, T)
-            xs = tokens.at[token_idx].get(mode="fill", fill_value=0)
-        else:
-            xs = tokens[token_idx]
+        held_rows = group_sizes.sum()
+        held_row = jnp.arange(T * top_k) < held_rows
+        xs = dispatch(tokens, order, inv, held_rows)
         xs = xs.astype(w_in.dtype)                     # (T*k, d) sorted
 
     def grouped(lhs, rhs):
@@ -247,11 +326,10 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
             h = jnp.square(jax.nn.relu(grouped(xs, w_in)))
         else:
             h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_in)
-        ys = grouped(h, w_down)                        # (T*k, d)
+        # (T*k, d); no mask here: `combine` reads the held rows alone
+        ys = jax.lax.ragged_dot(h, w_down, group_sizes)
     with jax.named_scope("combine"):
-        flat_gates = gates.reshape(-1)[order].astype(ys.dtype)
-        out = jax.ops.segment_sum(ys * flat_gates[:, None], token_idx,
-                                  num_segments=T)
+        out = combine(ys, gates, order, inv, held_rows)
     return out.astype(tokens.dtype), group_sizes
 
 
@@ -265,13 +343,18 @@ def grouped_moe(tokens: jax.Array, probs: jax.Array, w_gate: jax.Array,
     capacity limit so nothing is dropped.
 
     What a v5e trace showed (jax 0.9.0, OLMoE's 163,840 rows in 64
-    groups of 2048 x 1024; PERF.md, PR 26): the TPU compiler puts
-    grouped-matmul kernels of its own in place of each `ragged_dot`
+    groups of 2048 x 1024; PERF.md, PR 26 and PR 32): the TPU compiler
+    puts grouped-matmul kernels of its own in place of each `ragged_dot`
     (`ragged-dot-none.N`, nine a step: three forward, their six
     transposes), 5.9-6.5 ms each, together 57% of the bf16 peak.  The
-    top-k and the sort are cheap (under 3 ms); the SCATTER-ADDS are not:
-    `segment_sum` in the combine and the transpose of the gather into
-    expert order take 12 ms each, as long as two of the grouped matmuls.
+    top-k and the two sorts are cheap (under 0.5 ms together), and so is
+    the gather into expert order (1.0 ms: its 84 MB source is staged in
+    fast memory).  What costs is moving the 671 MB row buffer back: as
+    SCATTER-ADDS (`segment_sum` in the combine, the transpose of the
+    gather in the backward pass) 12 ms each, as long as two of the
+    grouped matmuls; as the gathers through the sort's inverse that
+    `dispatch` and `combine` are now, 5.6 ms each (a row gather from
+    HBM runs at 34 ns a 4 KB row) and 1.0 ms for the sum over k.
 
     tokens (T, d); probs (T, E) router softmax; w_gate/w_in (E, d, f);
     w_down (E, f, d).  Returns (T, d).

@@ -10,6 +10,7 @@ import re
 import pytest
 
 from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
+    instructions_of,
     parse_computations,
     scope_of,
     scope_table,
@@ -205,3 +206,53 @@ def test_remat_and_accumulation_keep_the_parts():
     for ins in (i for body in comps.values() for i in body
                 if i["opcode"] in ("dot", "convolution")):
         assert _part(scope_of(ins["op_name"])) in ("attn", "mlp", "head")
+
+
+def test_instructions_of_lists_one_opcode_under_a_scope_with_its_shape():
+    # inside fused computations as well as at module level
+    assert instructions_of(_HAND_HLO, "multiply", "h/mlp") == {
+        "multiply.1": "f32[8]{0}"}
+    assert instructions_of(_HAND_HLO, "multiply", "optimizer") == {
+        "multiply.8": "f32[8]{0}"}
+    assert set(instructions_of(_HAND_HLO, "multiply", "")) == {
+        "multiply.1", "multiply.8"}
+    assert instructions_of(_HAND_HLO, "fusion", "h/mlp") == {}
+    assert instructions_of(_HAND_HLO, "custom-call", "h/attn") == {
+        "dwt_fa_fwd.3": "bf16[8,256]{1,0}"}
+    # a path matches whole components: `h/ml` is not `h/mlp`
+    assert instructions_of(_HAND_HLO, "multiply", "h/ml") == {}
+
+
+@pytest.mark.parametrize("held,num_experts", [(8, 8), (2, 8)])
+def test_the_dropless_layer_scatters_no_row_forward_or_backward(
+        held, num_experts):
+    """`instructions_of` on a real MoE layer's forward + backward,
+    compiled here: under `moe/dispatch` and `moe/combine` the only
+    scatter left counts group sizes (integers), and the rows move by
+    four gathers — two each way, the backward passes' under the scope of
+    the call they are the backward of."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_wuqiong_tpu.models.moe import MoEConfig, MoEMLP
+
+    cfg = MoEConfig(num_experts=num_experts, top_k=2, impl="grouped",
+                    dtype=jnp.float32, expert_act="relu2", aux_loss="none",
+                    experts_held=held if held < num_experts else 0)
+    layer = MoEMLP(hidden=48, ffn=24, moe=cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 48))
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    text = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(jnp.sin(layer.apply({"params": p}, x))),
+        argnums=(0, 1))).lower(params, x).compile().as_text()
+    rows = re.compile(r"f32\[[\d,]+,48\]")  # T*k rows of the width, 48
+    for scope in ("moe/dispatch", "moe/combine"):
+        # what is scattered: group sizes, the top-k's gates into (T, E)
+        scattered = instructions_of(text, "scatter", scope).values()
+        assert not [s for s in scattered if re.match(r"f32\[\d+,48\]", s)]
+        gathered = [s for s in instructions_of(text, "gather", scope).values()
+                    if rows.match(s)]
+        assert len(gathered) == 2, (scope, gathered)
+    everywhere = [s for s in instructions_of(text, "gather", "").values()
+                  if rows.match(s)]
+    assert len(everywhere) == 4

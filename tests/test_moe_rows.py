@@ -1,0 +1,215 @@
+"""The dropless path's two row movements (`models/moe.dispatch`,
+`models/moe.combine`): a pair of transposes, each the other's backward
+pass, both gathers through the sort and its inverse.  Held here, on the
+CPU in float32, against the forms plain autodiff gives (`tokens[idx]` and
+its scatter-add, `segment_sum` of a weighted copy), for every expert held
+and for a chip's share of them."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_wuqiong_tpu.models.moe import combine, dispatch, grouped_experts
+
+T, K, D, F = 48, 6, 16, 12
+
+# (experts the weights hold, first of them, experts the router names)
+HOLDINGS = {"all_8": (8, 0, 8), "share_8_of_128_at_0": (8, 0, 128),
+            "share_8_of_128_at_40": (8, 40, 128)}
+
+
+def _routing(kind, held, first, num_experts):
+    """experts (T, K), a token's K all different."""
+    t = np.arange(T)[:, None]
+    j = np.arange(K)[None, :]
+    if kind == "even":
+        experts = (t * K + j) % num_experts
+    elif kind == "one_expert":  # every token's first choice is the same
+        rest = (t * (K - 1) + j - 1) % (num_experts - 1)
+        experts = np.where(j == 0, first + 1,
+                           (first + 2 + rest) % num_experts)
+    elif kind == "none_held":   # a share that receives no row
+        experts = (first + held + (t * K + j) % (num_experts - held)) \
+            % num_experts
+    else:
+        raise ValueError(kind)
+    assert all(len(set(row)) == K for row in experts.tolist())
+    return jnp.asarray(experts, jnp.int32)
+
+
+CASES = [pytest.param(h, r, id=f"{h}-{r}")
+         for h in HOLDINGS for r in ("even", "one_expert", "none_held")
+         if not (h == "all_8" and r == "none_held")]
+
+
+def _sorted(holding, routing):
+    """What `grouped_experts` computes before it moves a row: (order,
+    inv, held_rows, token_idx with the absent rows' index out of range,
+    group_sizes)."""
+    held, first, num_experts = HOLDINGS[holding]
+    flat = _routing(routing, held, first, num_experts).reshape(-1) - first
+    flat = jnp.where((flat >= 0) & (flat < held), flat, held)
+    order = jnp.argsort(flat)
+    inv = jnp.argsort(order).reshape(T, K).T
+    sizes = jnp.bincount(flat, length=held)
+    held_rows = sizes.sum()
+    if routing == "none_held":
+        assert int(held_rows) == 0
+    elif holding != "all_8":
+        assert 0 < int(held_rows) < T * K
+    token_idx = jnp.where(jnp.arange(T * K) < held_rows, order // K, T)
+    return order, inv, held_rows, token_idx, sizes
+
+
+def _draw(*shapes, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+    return [jax.random.normal(k, s, jnp.float32)
+            for k, s in zip(keys, shapes)]
+
+
+def _close(got, want):
+    """To 1e-6 of the largest entry (float32 sums in another order)."""
+    want = np.asarray(want)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0,
+                               atol=1e-6 * max(1.0, np.abs(want).max(initial=0)))
+
+
+@pytest.mark.parametrize("holding,routing", CASES)
+def test_dispatch_is_the_plain_gather_and_its_scatter_add(holding, routing):
+    order, inv, held_rows, token_idx, _ = _sorted(holding, routing)
+    tokens, d_rows = _draw((T, D), (T * K, D))
+    held = np.arange(T * K) < int(held_rows)
+
+    def plain(x):
+        return x.at[token_idx].get(mode="fill", fill_value=0)
+
+    got, vjp = jax.vjp(lambda x: dispatch(x, order, inv, held_rows), tokens)
+    want, plain_vjp = jax.vjp(plain, tokens)
+    _close(got[held], want[held])
+    _close(vjp(d_rows)[0], plain_vjp(d_rows)[0])
+
+
+@pytest.mark.parametrize("holding,routing", CASES)
+def test_combine_is_segment_sum_of_the_weighted_rows(holding, routing):
+    order, inv, held_rows, token_idx, _ = _sorted(holding, routing)
+    rows, gates, d_out = _draw((T * K, D), (T, K), (T, D), seed=1)
+    held = np.arange(T * K) < int(held_rows)
+
+    def plain(ys, g):
+        return jax.ops.segment_sum(ys * g.reshape(-1)[order][:, None],
+                                   token_idx, num_segments=T)
+
+    got, vjp = jax.vjp(
+        lambda ys, g: combine(ys, g, order, inv, held_rows), rows, gates)
+    want, plain_vjp = jax.vjp(plain, rows, gates)
+    _close(got, want)
+    (d_rows, d_gates), (want_rows, want_gates) = vjp(d_out), plain_vjp(d_out)
+    _close(d_gates, want_gates)
+    # behind the held rows the plain form's gradient is zero and this
+    # one's is the token's cotangent: `grouped_experts` masks every
+    # product there, and the mask's transpose takes it out
+    _close(d_rows[held], want_rows[held])
+    assert np.isfinite(np.asarray(d_rows)).all()
+
+
+@pytest.mark.parametrize("holding,routing", CASES)
+def test_each_ones_backward_pass_is_the_other(holding, routing):
+    order, inv, held_rows, _, _ = _sorted(holding, routing)
+    tokens, rows, gates = _draw((T, D), (T * K, D), (T, K), seed=2)
+    flat_gates = gates.reshape(-1)[order][:, None]
+
+    _, vjp = jax.vjp(lambda x: dispatch(x, order, inv, held_rows), tokens)
+    _close(vjp(rows)[0], combine(rows, None, order, inv, held_rows))
+    _, vjp = jax.vjp(lambda ys: combine(ys, None, order, inv, held_rows),
+                     rows)
+    _close(vjp(tokens)[0], dispatch(tokens, order, inv, held_rows))
+    _, vjp = jax.vjp(lambda ys: combine(ys, gates, order, inv, held_rows),
+                     rows)
+    _close(vjp(tokens)[0],
+           dispatch(tokens, order, inv, held_rows) * flat_gates)
+    # transposes: <dispatch(x), r> = <x, combine(r)> over the held rows
+    held = (jnp.arange(T * K) < held_rows)[:, None]
+    lhs = jnp.vdot(dispatch(tokens, order, inv, held_rows) * held, rows)
+    rhs = jnp.vdot(tokens, combine(rows, None, order, inv, held_rows))
+    np.testing.assert_allclose(float(lhs), float(rhs), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("holding,routing", CASES)
+def test_what_lies_behind_the_held_rows_is_never_read(holding, routing):
+    """No group writes the row buffer behind the held rows, and the TPU's
+    grouped kernels leave there whatever was there: a NaN planted in
+    those places of the rows, and of the rows' gradient, reaches no
+    token's sum and no gate's gradient."""
+    order, inv, held_rows, _, _ = _sorted(holding, routing)
+    rows, gates, d_out = _draw((T * K, D), (T, K), (T, D), seed=3)
+    tail = (jnp.arange(T * K) >= held_rows)[:, None]
+    dirty = jnp.where(tail, jnp.nan, rows)
+    clean = jnp.where(tail, 0.0, rows)
+
+    def run(ys):
+        out, vjp = jax.vjp(
+            lambda g: combine(ys, g, order, inv, held_rows), gates)
+        return out, vjp(d_out)[0]
+
+    for got, want in zip(run(dirty), run(clean)):
+        assert np.isfinite(np.asarray(got)).all()
+        _close(got, want)
+    _, vjp = jax.vjp(lambda x: dispatch(x, order, inv, held_rows), d_out)
+    assert np.isfinite(np.asarray(vjp(dirty)[0])).all()
+    _close(vjp(dirty)[0], vjp(clean)[0])
+
+
+def _scatter_form(tokens, gates, experts, w_in, w_down, first, num_experts):
+    """`grouped_experts` (relu^2) as it was before the rows moved by
+    gathers: plain indexing and `segment_sum`, differentiated by JAX."""
+    held = w_in.shape[0]
+    flat = experts.reshape(-1) - first
+    flat = jnp.where((flat >= 0) & (flat < held), flat, held)
+    order = jnp.argsort(flat)
+    sizes = jnp.bincount(flat, length=held)
+    held_row = jnp.arange(T * K) < sizes.sum()
+    token_idx = jnp.where(held_row, order // K, T)
+    xs = tokens.at[token_idx].get(mode="fill", fill_value=0)
+
+    def grouped(lhs, rhs):
+        return jnp.where(held_row[:, None],
+                         jax.lax.ragged_dot(lhs, rhs, sizes), 0)
+
+    ys = grouped(jnp.square(jax.nn.relu(grouped(xs, w_in))), w_down)
+    flat_gates = gates.reshape(-1)[order]
+    return jax.ops.segment_sum(ys * flat_gates[:, None], token_idx,
+                               num_segments=T)
+
+
+@pytest.mark.parametrize("holding,routing", CASES)
+def test_the_expert_pass_keeps_its_value_and_every_gradient(holding,
+                                                            routing):
+    held, first, num_experts = HOLDINGS[holding]
+    experts = _routing(routing, held, first, num_experts)
+    tokens, gates, w_in, w_down = _draw(
+        (T, D), (T, K), (held, D, F), (held, F, D), seed=4)
+    w_in, w_down = 0.2 * w_in, 0.2 * w_down  # results of order one
+
+    def new(*args):
+        out, sizes = grouped_experts(args[0], args[1], experts, None,
+                                     args[2], args[3], first_expert=first,
+                                     num_experts=num_experts)
+        return jnp.sum(jnp.sin(out)), (out, sizes)
+
+    def old(*args):
+        out = _scatter_form(args[0], args[1], experts, args[2], args[3],
+                            first, num_experts)
+        return jnp.sum(jnp.sin(out)), out
+
+    args = (tokens, gates, w_in, w_down)
+    (_, (got, sizes)), got_g = jax.value_and_grad(
+        new, argnums=(0, 1, 2, 3), has_aux=True)(*args)
+    (_, want), want_g = jax.value_and_grad(
+        old, argnums=(0, 1, 2, 3), has_aux=True)(*args)
+    _close(got, want)
+    for g, w in zip(got_g, want_g):
+        _close(g, w)
+    assert sizes.shape == (held,)
+    if routing == "none_held":
+        assert int(sizes.sum()) == 0 and not np.asarray(got).any()

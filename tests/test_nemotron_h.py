@@ -8,6 +8,7 @@ nano size on the CPU, float32 on both sides.
 
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -344,11 +345,12 @@ def test_the_shares_parts_add_up_to_the_uncut_layer():
 
 
 def test_a_share_runs_the_one_grouped_path_over_the_held_experts():
-    """A share is the whole layer's path, not a second one: the same sort
+    """A share is the whole layer's path, not a second one: the same sort,
+    the same inverse of it, the same row gathers (`dispatch`, `combine`)
     and `ragged_dot`, whose groups are the HELD experts alone (2 here,
     never the published 8) — an absent assignment has no group, and its
-    place in the row buffer gathers no token (an index past the last
-    one, filled with zeros) and is scattered to none."""
+    place in the row buffer lies behind the held rows, where the sums
+    over a token's k rows do not read."""
     moe = _moe(experts_held=2, first_expert=4)
     layer, params, x = _expert_layer(moe)
     text = str(jax.make_jaxpr(
@@ -358,10 +360,16 @@ def test_a_share_runs_the_one_grouped_path_over_the_held_experts():
             _expert_layer(_moe())[1], x))
     for traced in (text, whole):
         assert traced.count("ragged_dot_general[") == 2  # relu2: no gate
-        assert traced.count(" sort[") == 1
+        # the assignments' sort and the inverse of its permutation
+        assert traced.count("jit[name=argsort") == 2
+        assert traced.count("custom_vjp_call[") == 2
+        assert traced.count("name=dispatch") == 1
+        assert traced.count("name=combine") == 1
+        # no row is scattered; the one scatter counts the groups' sizes
+        assert len(re.findall(r" = scatter[-\w]*\[", traced)) == 1
+        assert traced.count("mode=GatherScatterMode.PROMISE_IN_BOUNDS") == 2
     assert "f32[2,32,24]" in text and "f32[8,32,24]" not in text
     assert "i32[2]" in text and "i32[8]" not in text      # group_sizes
-    assert "GatherScatterMode.FILL_OR_DROP" in text
     assert "f32[8,32,24]" in whole and "i32[8]" in whole
 
 

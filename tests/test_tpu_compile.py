@@ -10,6 +10,7 @@ be read back without a chip (on-chip-measurement guide §2).  Nothing
 runs, so nothing here is a statement about results or speed.
 """
 
+import collections
 import contextlib
 import math
 import os
@@ -467,4 +468,69 @@ def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
     assert wide and all("moe/dispatch" in ln or "out_of_band" in ln
                         for ln in wide if "op_name=" in ln)
     assert any("out_of_band" in s for s in scopes)
+    assert " while(" not in text and " conditional(" not in text
+
+
+# ------------------------- the dropless path's row movements, both cells
+
+@pytest.mark.parametrize("fixture,layers,passes", [
+    ("olmoe_step", 1, 1),       # one expert layer, no recomputation
+    ("nemotron_step", 4, 2),    # four, each forward pass run twice
+])
+def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
+                                                 passes):
+    """The static counter of `models/moe.dispatch` / `combine`: in the
+    compiled step no `scatter` under `moe/dispatch` or `moe/combine` has
+    a row buffer for its operand (rank 2, the model's width: the parent
+    had two a layer, the combine's `segment_sum` and the transpose of
+    the gather into expert order; what is left counts group sizes and
+    expert loads, integers).  The rows move by four gathers a layer —
+    into expert order (T*k, d) and back by assignment (k, T, d), forward
+    and backward — and the two only the backward passes emit carry the
+    scope of the call they are the backward of, as every such gather in
+    the step does: none is left without a scope.  The grouped matmuls
+    see the same buffers as before, and nothing holds other ops."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
+        instructions_of, scope_table)
+
+    cell, _, step = request.getfixturevalue(fixture)
+    text = step.as_text()
+    width, k = (cell["config"][key] for key in
+                ("hidden_size", "num_experts_per_tok"))
+    tokens = cell["global_batch"] * cell["seq_len"]
+    in_order = f"bf16[{tokens * k},{width}]"
+    by_assignment = f"bf16[{k},{tokens},{width}]"
+
+    def shapes(opcode, under):
+        return sorted(s.split("{")[0] for s in
+                      instructions_of(text, opcode, under).values())
+
+    for scope in ("moe/dispatch", "moe/combine"):
+        assert {s.split("[")[0] for s in shapes("scatter", scope)} \
+            <= {"s32"}, scope
+
+    def row_gathers(under):
+        return [s for s in shapes("gather", under)
+                if s in (in_order, by_assignment)]
+
+    assert row_gathers("moe/dispatch") == sorted(
+        [in_order] * layers * passes + [by_assignment] * layers)
+    # (a recomputed forward pass stops at the rows: nothing in the
+    # backward pass reads the sum it would make of them)
+    assert row_gathers("moe/combine") == sorted(
+        [by_assignment] * layers + [in_order] * layers)
+    assert len(row_gathers("")) == layers * (passes + 3)
+
+    # the compiler's grouped-matmul kernels, by the buffer each writes:
+    # 9 a step at OLMoE, 32 at the hybrid (44 with their metadata ops)
+    kernels = collections.Counter(re.findall(
+        r"%ragged-dot-none[.\d]* = bf16\[([\d,]+)\]", text))
+    rows = tokens * k
+    assert kernels == {
+        "olmoe_step": {f"{rows},1024": 3, f"{rows},2048": 3,
+                       "64,2048,1024": 2, "64,1024,2048": 1},
+        "nemotron_step": {f"{rows},1856": 12, f"{rows},2688": 12,
+                          "8,2688,1856": 4, "8,1856,2688": 4}}[fixture]
+    ragged = [n for n in scope_table(text) if n.startswith("ragged-dot")]
+    assert len(ragged) == {"olmoe_step": 11, "nemotron_step": 44}[fixture]
     assert " while(" not in text and " conditional(" not in text
