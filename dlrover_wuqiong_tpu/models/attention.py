@@ -36,6 +36,13 @@ def goes_direct(cfg, n_head: int, head_dim: int, seq: int) -> bool:
     return on_one_device and projected_ok(n_head, head_dim, seq)
 
 
+def softmax_scale(cfg):
+    """The scale the kernels are handed: `cfg.attn_scale` where a config
+    has the field and sets it (Granite's `attention_multiplier`), else
+    None, which every entry reads as 1/sqrt(head size)."""
+    return getattr(cfg, "attn_scale", 0.0) or None
+
+
 def attend_projected(proj, n_head: int, cfg, causal: bool = True):
     """Self-attention on the projections' own layout: `proj` is (qkv,),
     one (b, T, 3*h*d) array of q, k and v side by side (`c_attn`'s
@@ -61,7 +68,8 @@ def attend_projected(proj, n_head: int, cfg, causal: bool = True):
     lanes = proj[0].shape[-1] // (3 if len(proj) == 1 else 1)
     d = lanes // n_head
     if goes_direct(cfg, n_head, d, t):
-        return flash_attention_projected(proj, n_head, causal)
+        return flash_attention_projected(proj, n_head, causal,
+                                         softmax_scale(cfg))
     if len(proj) == 1:
         proj = jnp.split(proj[0], 3, axis=-1)
     q, k, v = (x.reshape(b, t, n_head, d) for x in proj)
@@ -76,18 +84,21 @@ def attend(q, k, v, cfg, causal: bool = True):
     the jnp reference off it)."""
     impl = getattr(cfg, "attn_impl", "flash")
     mesh = getattr(cfg, "mesh", None)
+    scale = softmax_scale(cfg)
     if impl in ("ring", "ulysses") and mesh is not None:
         from ..parallel.long_context import ring_attention, ulysses_attention
 
         fn = ring_attention if impl == "ring" else ulysses_attention
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        return fn(qt, kt, vt, mesh, causal=causal).transpose(0, 2, 1, 3)
+        return fn(qt, kt, vt, mesh, causal=causal,
+                  sm_scale=scale).transpose(0, 2, 1, 3)
     if mesh is not None and mesh.size > 1 and _on_tpu():
         # the Pallas kernels need a shard_map on a multi-device mesh; the
         # jnp reference off-TPU is partitioned by GSPMD like any other op
         from ..parallel.long_context import sharded_flash_attention
 
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        return sharded_flash_attention(qt, kt, vt, mesh, causal=causal
-                                       ).transpose(0, 2, 1, 3)
-    return mha(q, k, v, causal=causal)
+        return sharded_flash_attention(
+            qt, kt, vt, mesh, causal=causal,
+            sm_scale=scale).transpose(0, 2, 1, 3)
+    return mha(q, k, v, causal=causal, sm_scale=scale)

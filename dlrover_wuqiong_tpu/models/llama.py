@@ -60,6 +60,9 @@ class LlamaConfig:
     # False: q and k are not rotated (a hybrid whose other layers carry
     # the positions)
     rope: bool = True
+    # the softmax's scale where it is not 1/sqrt(head size) (Granite's
+    # `attention_multiplier`); 0 = 1/sqrt(head size)
+    attn_scale: float = 0.0
 
     @classmethod
     def nano(cls):
@@ -190,8 +193,9 @@ class LlamaAttention(nn.Module):
         elif cfg.use_flash_attention:
             y = attend(q, k, v, cfg, causal=True)
         else:
-            att = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(
-                jnp.float32) / jnp.sqrt(jnp.float32(hd))
+            att = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+            att = att * cfg.attn_scale if cfg.attn_scale else \
+                att / jnp.sqrt(jnp.float32(hd))
             mask = jnp.tril(jnp.ones((T, T), bool))
             att = jnp.where(mask, att, -jnp.inf)
             att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)

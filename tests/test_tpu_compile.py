@@ -471,6 +471,88 @@ def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
     assert " while(" not in text and " conditional(" not in text
 
 
+# --------------------------------- granite-4.0-h-micro's step on one chip
+
+@pytest.fixture(scope="module")
+def granite_step(topo):
+    """`granite4_h_micro.steady`'s step — published widths, one period
+    (nine Mamba-2 layers at ONE state group and chunk 256, one attention
+    layer, a SwiGLU of 8192 behind each), the tied head on an eighth of
+    the table, one 8192-token sequence, full recomputation (about
+    60 s)."""
+    return _one_chip_step(topo, "granite4_h_micro.steady", "granite_hybrid")
+
+
+def test_granite_step_fits_one_chip_by_the_rule_and_fills_it(granite_step):
+    """State + temporaries under 90% of the chip's 16 GB (PR 26's rule)
+    at the shipped sizes, the reading the configuration file records for
+    its memory rung (a): 12.47 GB, of which 9.27 GB is donated state.  A
+    second sequence doubles the 3.2 GB of temporaries: over."""
+    cell, model, step = granite_step
+    assert model.config.num_params() == 772_160_448
+    assert (cell["global_batch"], cell["seq_len"],
+            model.config.chunk_size, model.config.n_groups) == \
+        (1, 8192, 256, 1)
+    m = step.memory_analysis()
+    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    rung = cell["config"]["train"]["memory_rung"]
+    assert live / 1e9 == pytest.approx(
+        rung["live_GB"]["a: 1 x 8192, chunk 256"], abs=0.05)
+    assert 0.25 * 16 * 2 ** 30 < 0.75 * 16e9 < live < \
+        rung["limit_GB"] * 1e9 == 0.90 * 16e9, live / 1e9
+    assert live + m.temp_size_in_bytes > rung["limit_GB"] * 1e9
+    assert m.alias_size_in_bytes >= 12 * model.config.num_params()
+
+
+def test_granite_step_holds_its_scopes_and_no_op_that_holds_others(
+        granite_step):
+    """Every scope the cell's scopes file names is in the compiled step,
+    the tied head's product under `head`; and nothing in it holds other
+    ops (a `while`, a `conditional`), which a device trace would count
+    beside the ops they ran — at nine scans of 32 chunks each."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+
+    text = granite_step[2].as_text()
+    scopes = set(scope_table(text).values())
+    for part in ("mamba/in_proj", "mamba/conv", "mamba/ssd",
+                 "mamba/gate_norm", "mamba/out_proj",
+                 "feed_forward/gate_proj", "feed_forward/up_proj",
+                 "feed_forward/down_proj", "attention/q_proj",
+                 "attention/k_proj", "attention/v_proj", "attention/o_proj",
+                 "input_norm", "post_mixer_norm", "GraniteHybrid/head",
+                 "loss", "optimizer"):
+        assert any(part in s for s in scopes), part
+    assert not any("lm_head" in s or "moe" in s for s in scopes)
+    assert " while(" not in text and " conditional(" not in text
+
+
+def test_granite_step_runs_the_kernels_direct_at_32_heads_of_64(
+        granite_step):
+    """The first grouped-query call at d = 64 through a model: 32 query
+    heads are 16 lane slabs of two, so after the four-fold repeat of k
+    and v the kernels index the projections' own (1, 8192, 32 x 64) —
+    the DIRECT route, over 8 x 8 blocks (split backward), nothing laid
+    out by head.  Recorded, not required (either route computes the
+    same): what moves data under `attention` outside the projections is
+    six (1, 8192, 2048) copies — the repeats of k and v in the forward
+    pass and in its recomputation, and a re-layout of dk and dv (as the
+    kernel wrote them for 32 heads) before their sum over each group of
+    four (ROADMAP M3)."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import relayouts
+
+    text = granite_step[2].as_text()
+    assert fa.attention_route(32, 64) == ("direct", 2)
+    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"):
+        assert kernel in text, kernel
+    assert "dwt_fa_bwd_fused" not in text
+    assert "operand_layout_constraints={bf16[1,8192,2048]" in text
+    assert "bf16[32,8192,64]" not in text
+    moved = relayouts(text, "attention", outside=(
+        "q_proj", "k_proj", "v_proj", "o_proj"))
+    assert sorted(moved.values()) == ["copy"] * 6, moved
+
+
 # ------------------------- the dropless path's row movements, both cells
 
 @pytest.mark.parametrize("fixture,layers,passes", [
