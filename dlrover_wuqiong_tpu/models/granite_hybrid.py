@@ -103,7 +103,8 @@ class GraniteHybridConfig:
             head_dim=self.mamba_head_dim, n_groups=self.n_groups,
             state_size=self.state_size, conv_kernel=self.conv_kernel,
             chunk_size=self.chunk_size, eps=self.rms_eps, dtype=self.dtype,
-            dt_min=self.dt_min, dt_max=self.dt_max, dt_floor=self.dt_floor)
+            mesh=self.mesh, dt_min=self.dt_min, dt_max=self.dt_max,
+            dt_floor=self.dt_floor)
 
     def num_params(self) -> int:
         h, llama = self.hidden_size, self.attention_config()

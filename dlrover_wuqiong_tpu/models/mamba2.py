@@ -10,9 +10,16 @@
 
 d_inner = heads x head_dim: the projections' widths are given, not
 derived from an expansion factor.  Scopes, under the module's own name:
-`in_proj`, `conv`, `ssd`, `gate_norm`, `out_proj`.  Parameter names are
-matched by `parallel/sharding.py` (the two projections as dense
-kernels, everything else replicated).
+`in_proj`, `conv`, `ssd` (the step sizes, the decay rates and all of the
+scan: on one TPU device the `dwt_ssd_*` kernels' custom calls, forward,
+recomputed and backward, carry it), `gate_norm`, `out_proj`.  Parameter
+names are matched by `parallel/sharding.py` (the two projections as
+dense kernels, everything else replicated).
+
+Which route the scan takes is the call's shapes and where it runs,
+nothing else: `ops/ssd.scan_route` reads the shapes and the backend,
+`scans_on_one_device` the mesh (a Mosaic kernel cannot be partitioned by
+GSPMD, so a mixer on a mesh of several devices keeps the plain form).
 
 Parity: none — the reference's model zoo (atorch) is attention-only; the
 equations are `nemotron_h`'s, as benchmark/reference_nemotron_h.py
@@ -29,7 +36,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.ssd import ssd_scan
+from ..ops.ssd import ssd_scan, ssd_scan_plain
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,6 +50,7 @@ class Mamba2Config:
     chunk_size: int = 128
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    mesh: Any = None  # the model config's (set by auto_accelerate)
     # initialiser settings of dt_bias, not a clamp in the forward pass:
     # softplus(dt_bias) is drawn log-uniform in [dt_min, dt_max], >= floor
     dt_min: float = 0.001
@@ -63,6 +71,12 @@ class Mamba2Config:
                 + (self.conv_kernel + 1) * self.conv_dim   # conv + bias
                 + 3 * self.num_heads                       # dt_bias A_log D
                 + di + di * h)                             # gate_norm out
+
+
+def scans_on_one_device(cfg: Mamba2Config) -> bool:
+    """Whether the mixer's scan runs where `ops/ssd.ssd_scan` may take
+    its kernels (as `models/attention.goes_direct` reads the mesh)."""
+    return cfg.mesh is None or cfg.mesh.size == 1
 
 
 def _dt_bias_init(cfg: Mamba2Config):
@@ -123,7 +137,8 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssd"):
             dlt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
             a = -jnp.exp(a_log.astype(jnp.float32))
-        y = ssd_scan(
+        scan = ssd_scan if scans_on_one_device(cfg) else ssd_scan_plain
+        y = scan(
             x.reshape(bsz, t, cfg.num_heads, cfg.head_dim), dlt, a,
             b_mat.reshape(bsz, t, cfg.n_groups, cfg.state_size),
             c_mat.reshape(bsz, t, cfg.n_groups, cfg.state_size),

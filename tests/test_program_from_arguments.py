@@ -353,6 +353,126 @@ def test_the_smoke_counts_a_kernel_once_for_each_call_site(text, want):
     assert chip_smoke.kernel_counts(text)["dwt_fa_fwd"] == want
 
 
+# ------------------------------------------ the Mamba-2 scan's route
+
+
+GRANITE = (64, 64, 1, 128, 256, 8192)    # granite4_h_micro.steady
+NEMOTRON = (64, 64, 8, 128, 128, 8192)   # nemotron3_nano_30b_a3b.steady
+
+
+@pytest.mark.parametrize("on_tpu,shape,route", [
+    (True, GRANITE, ("kernel", 16)),     # ONE group: 16 of its 64 heads
+    (True, NEMOTRON, ("kernel", 8)),     # a group of 8 a grid step
+    (True, (8, 128, 2, 128, 128, 2048), ("kernel", 4)),   # a head a slab
+    (True, (8, 32, 1, 128, 128, 1024), ("kernel", 8)),    # four a slab
+    (False, GRANITE, ("plain", 0)),      # off the TPU
+    (False, NEMOTRON, ("plain", 0)),
+    (True, (64, 64, 1, 128, 64, 8192), ("plain", 0)),     # chunk < a lane
+    (True, (64, 64, 1, 128, 192, 8448), ("plain", 0)),    # no multiple
+    (True, (8, 16, 2, 8, 16, 64), ("plain", 0)),          # nano() sizes
+    (True, (64, 48, 1, 128, 256, 8192), ("plain", 0)),    # heads off slabs
+    (True, (64, 16, 1, 128, 256, 8192), ("plain", 0)),    # eight a slab
+    (True, (6, 64, 2, 128, 128, 1024), ("plain", 0)),     # 3 heads a group
+    (True, (64, 64, 1, 64, 256, 8192), ("plain", 0)),     # state < a lane
+    (True, (64, 64, 1, 128, 256, 8000), ("plain", 0)),    # ragged: refused
+    (True, (4096, 64, 1, 128, 256, 8192), ("plain", 0)),  # states over VMEM
+])
+def test_the_scans_route_is_its_shapes_and_the_backend(monkeypatch, on_tpu,
+                                                       shape, route):
+    """`ops/ssd.scan_route(h, p, g, n, chunk, t)`: the static counter of
+    which scan calls run `dwt_ssd_fwd` / `dwt_ssd_bwd`, and with how
+    many heads a grid step."""
+    from dlrover_wuqiong_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: on_tpu)
+    assert ssd.scan_route(*shape) == route
+
+
+def _mixer_grad_jaxpr(mesh):
+    from dlrover_wuqiong_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer
+
+    cfg = Mamba2Config(hidden_size=64, num_heads=8, head_dim=64, n_groups=2,
+                       state_size=128, chunk_size=128, mesh=mesh)
+    mixer = Mamba2Mixer(cfg)
+    u = jax.ShapeDtypeStruct((2, 512, 64), jnp.bfloat16)
+    params = jax.eval_shape(lambda: mixer.init(
+        jax.random.PRNGKey(0), jnp.zeros(u.shape, u.dtype))["params"])
+    return jax.make_jaxpr(jax.grad(lambda p, u: mixer.apply(
+        {"params": p}, u).astype(jnp.float32).sum()))(params, u)
+
+
+def test_a_mixer_on_one_tpu_device_scans_in_the_kernels(monkeypatch):
+    """One forward and one backward kernel a mixer, over (batch rows,
+    chunks, blocks of heads): 2 x 4 x 2 at two groups of four heads."""
+    from dlrover_wuqiong_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
+    assert ssd.scan_route(8, 64, 2, 128, 128, 512) == ("kernel", 4)
+    assert sorted(_pallas_calls(_mixer_grad_jaxpr(None).jaxpr)) == [
+        ("dwt_ssd_bwd", (2, 4, 2)), ("dwt_ssd_fwd", (2, 4, 2))]
+
+
+def test_a_mixer_on_a_mesh_of_several_devices_keeps_the_plain_scan(
+        monkeypatch):
+    """A Mosaic kernel cannot be partitioned by GSPMD (PR 22): where the
+    model config carries a mesh of more than one device the mixer takes
+    `ssd_scan_plain`, whatever `scan_route` says of the shapes; a mesh
+    of one device is one device."""
+    from jax.sharding import Mesh
+
+    from dlrover_wuqiong_tpu.models.mamba2 import (
+        Mamba2Config, scans_on_one_device)
+    from dlrover_wuqiong_tpu.ops import ssd
+
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
+    two = Mesh(np.array(jax.devices()[:2]), ("fsdp",))
+    one = Mesh(np.array(jax.devices()[:1]), ("fsdp",))
+    assert not scans_on_one_device(Mamba2Config(mesh=two))
+    assert scans_on_one_device(Mamba2Config(mesh=one))
+    assert scans_on_one_device(Mamba2Config())
+    assert _pallas_calls(_mixer_grad_jaxpr(two).jaxpr) == []
+    assert len(_pallas_calls(_mixer_grad_jaxpr(one).jaxpr)) == 2
+
+
+@pytest.mark.parametrize("dtype,sha", [
+    (jnp.float32,
+     "8a984bb94ddcdc4f436c9c961286e605a9f18602ff27cf5058797de82fab862f"),
+    (jnp.bfloat16,
+     "a1e5403d701f02775bb281de6bf864b02452b1d0981a9f831bcde84ea05f3c52"),
+])
+def test_a_mixer_off_the_tpu_lowers_to_the_plain_scans_text(dtype, sha):
+    """The fallback is the `jax.numpy` body PR 31 wrote, word for word:
+    a `Mamba2Mixer`'s loss and gradient lower on the CPU to the text
+    they lowered to before the kernels were there (the sha256 of that
+    text, taken from the parent commit's checkout, PR 34), and
+    `ssd_scan` there is `ssd_scan_plain`."""
+    import hashlib
+
+    from dlrover_wuqiong_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer
+    from dlrover_wuqiong_tpu.ops import ssd
+
+    cfg = Mamba2Config(hidden_size=48, num_heads=8, head_dim=16, n_groups=2,
+                       state_size=8, chunk_size=16, dtype=dtype)
+    mixer = Mamba2Mixer(cfg)
+    u = jax.ShapeDtypeStruct((2, 64, 48), jnp.float32)
+    params = jax.eval_shape(lambda: mixer.init(
+        jax.random.PRNGKey(1), jnp.zeros(u.shape))["params"])
+
+    def loss(p, u):
+        return mixer.apply({"params": p}, u).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, u).as_text()
+    assert "pallas" not in text and "custom_call" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == sha
+    args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in (
+        (2, 64, 8, 16), (2, 64, 8), (8,), (2, 64, 2, 8), (2, 64, 2, 8),
+        (8,))]
+    lowered = [jax.jit(lambda *a, fn=fn: fn(*a, chunk=16, dtype=dtype)
+                       ).lower(*args).as_text()
+               for fn in (ssd.ssd_scan, ssd.ssd_scan_plain)]
+    assert lowered[0] == lowered[1]
+
+
 # ----------------------------------- (B) the environment is in none of it
 
 RETIRED = [("DWT_FA_PACK", "4"), ("DWT_FA_NO_FUSED", "1"),

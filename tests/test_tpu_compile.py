@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from dlrover_wuqiong_tpu.analysis.hlo_budget import iter_collectives
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
 from dlrover_wuqiong_tpu.ops import quantization as qz
+from dlrover_wuqiong_tpu.ops import ssd
 
 
 @pytest.fixture(scope="module")
@@ -285,6 +286,7 @@ def _one_chip_step(topo, name, model_file):
         mp.setattr(fa, "_on_tpu", lambda: True)
         mp.setattr("dlrover_wuqiong_tpu.models.attention._on_tpu",
                    lambda: True)
+        mp.setattr(ssd, "_on_tpu", lambda: True)
         res = auto_accelerate(
             model, strategy=[("fsdp", {})], devices=topo.devices[:1],
             optimizer=optax.chain(optax.clip_by_global_norm(1.0),
@@ -485,9 +487,12 @@ def granite_step(topo):
 
 def test_granite_step_fits_one_chip_by_the_rule_and_fills_it(granite_step):
     """State + temporaries under 90% of the chip's 16 GB (PR 26's rule)
-    at the shipped sizes, the reading the configuration file records for
-    its memory rung (a): 12.47 GB, of which 9.27 GB is donated state.  A
-    second sequence doubles the 3.2 GB of temporaries: over."""
+    at the shipped sizes: 12.11 GB, of which 9.27 GB is donated state —
+    0.36 GB under the reading the configuration file records for its
+    memory rung (a), 12.47 GB, taken on the plain scan (PR 33): the
+    kernels keep a layer's 537 MB decay tensor out of HBM and save 67 MB
+    of entering states.  A second sequence doubles the 2.8 GB of
+    temporaries: over."""
     cell, model, step = granite_step
     assert model.config.num_params() == 772_160_448
     assert (cell["global_batch"], cell["seq_len"],
@@ -497,8 +502,8 @@ def test_granite_step_fits_one_chip_by_the_rule_and_fills_it(granite_step):
     live = m.argument_size_in_bytes + m.temp_size_in_bytes \
         + m.output_size_in_bytes - m.alias_size_in_bytes
     rung = cell["config"]["train"]["memory_rung"]
-    assert live / 1e9 == pytest.approx(
-        rung["live_GB"]["a: 1 x 8192, chunk 256"], abs=0.05)
+    assert live / 1e9 == pytest.approx(12.11, abs=0.05)
+    assert live / 1e9 < rung["live_GB"]["a: 1 x 8192, chunk 256"] - 0.3
     assert 0.25 * 16 * 2 ** 30 < 0.75 * 16e9 < live < \
         rung["limit_GB"] * 1e9 == 0.90 * 16e9, live / 1e9
     assert live + m.temp_size_in_bytes > rung["limit_GB"] * 1e9
@@ -616,3 +621,122 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
     ragged = [n for n in scope_table(text) if n.startswith("ragged-dot")]
     assert len(ragged) == {"olmoe_step": 11, "nemotron_step": 44}[fixture]
     assert " while(" not in text and " conditional(" not in text
+
+
+# ------------------------------ the scan's kernels in both hybrids' steps
+
+def _scan_arguments(topo, b, t, h, p, g, n):
+    """`ssd_scan`'s six arguments as bf16 / float32 shapes on one
+    described chip."""
+    one = SingleDeviceSharding(topo.devices[0])
+    return [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((b, t, h, p), jnp.bfloat16), ((b, t, h), jnp.float32),
+        ((h,), jnp.float32), ((b, t, g, n), jnp.bfloat16),
+        ((b, t, g, n), jnp.bfloat16), ((h,), jnp.float32))]
+
+
+def _square_tiles(text, under, side):
+    """Lines of the instructions scoped `under` that name an array whose
+    two minor axes are both `side` long."""
+    found = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not name or under not in name.group(1):
+            continue
+        for dims in re.findall(r"\w+\[([\d,]+)\]",
+                               line.split(" metadata=")[0]):
+            if dims.split(",")[-2:] == [str(side)] * 2:
+                found.append(line.strip()[:160])
+                break
+    return found
+
+
+@pytest.mark.parametrize("fixture,layers,heads_block", [
+    ("granite_step", 9, 16),    # ONE group of 64 heads, chunk 256
+    ("nemotron_step", 4, 8),    # 8 groups of 8, chunk 128
+])
+def test_hybrid_step_scans_in_its_kernels_and_holds_no_decay_tensor(
+        request, topo, monkeypatch, fixture, layers, heads_block):
+    """The static counters of the scan's route.  Every scan of the step
+    runs the kernels: three custom calls a layer — `dwt_ssd_fwd` in the
+    forward pass, again in its recomputation, `dwt_ssd_bwd` in the
+    backward pass — 27 at granite's nine layers, 12 at the other
+    hybrid's four.  Each carries the scope `mamba/ssd` in all three
+    phases, so `benchmark/program.part_of` puts it in `ssm_scan` by the
+    class's `ssm_parts`: `step.ssm_scan_ms` holds the kernels, and
+    `kernel.ssd_roofline` (a count from shapes over that time) cannot
+    pass 100% for work that fell to `step.unscoped_ms`.  No instruction
+    under `mamba/ssd` names an array with two chunk-length minor axes:
+    the (L x L) decay tensor, C B^T and their product never reach HBM
+    (the plain form, compiled alone for the same chip, names them).
+    None of the kernels is named `dwt_fa_*` (`kernel.attn_ms` sums
+    those): the attention layer's own four are all there are.  Nothing
+    holds other ops."""
+    import json
+
+    from benchmark import cells, program
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+
+    cell, model, step = request.getfixturevalue(fixture)
+    text = step.as_text()
+    cfg = model.config.mamba_config()
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
+    assert ssd.scan_route(cfg.num_heads, cfg.head_dim, cfg.n_groups,
+                          cfg.state_size, cfg.chunk_size,
+                          cell["seq_len"]) == ("kernel", heads_block)
+    table = scope_table(text)
+    calls = {n: s for n, s in table.items() if n.startswith("dwt_ssd_")}
+    by_kernel = collections.Counter(
+        (n.split(".")[0], s.split("/")[0]) for n, s in calls.items())
+    assert by_kernel == {("dwt_ssd_fwd", "fwd"): layers,
+                         ("dwt_ssd_fwd", "recompute"): layers,
+                         ("dwt_ssd_bwd", "bwd"): layers}
+    assert len(calls) == 3 * layers == {9: 27, 4: 12}[layers]
+    assert len(re.findall(r"custom-call\([^\n]*dwt_ssd_", text)) \
+        == len(calls)
+    with open(os.path.join(cells.HERE, "models", cell["config"][
+            "model_class"] + ".scopes.json")) as f:
+        rules = json.load(f)
+    for name, scope in calls.items():
+        assert "/mamba/ssd/" in f"/{scope}/", (name, scope)
+        assert program.part_of(scope, rules["ssm_parts"]) == "ssm_scan"
+        assert program.part_of(scope, rules["parts"]) == "ssm"
+    scopes = set(table.values())
+    assert any("mamba/conv" in s for s in scopes)
+    assert any("mamba/ssd" in s for s in scopes)
+
+    assert _square_tiles(text, "mamba/ssd", cfg.chunk_size) == []
+    shapes = _scan_arguments(
+        topo, cell["global_batch"], cell["seq_len"], cfg.num_heads,
+        cfg.head_dim, cfg.n_groups, cfg.state_size)
+    plain = _compile(lambda *a: ssd.ssd_scan_plain(
+        *a, chunk=cfg.chunk_size, dtype=jnp.bfloat16), *shapes)
+    assert _square_tiles(plain, "ssd", cfg.chunk_size)
+
+    attention = [n for n in table if n.startswith("dwt_fa_")]
+    assert sorted(n.split(".")[0] for n in attention) == [
+        "dwt_fa_bwd_dkv", "dwt_fa_bwd_dq", "dwt_fa_fwd", "dwt_fa_fwd"]
+    assert " while(" not in text and " conditional(" not in text
+
+
+@pytest.mark.parametrize("b,t,h,p,g,n,chunk", [
+    (1, 8192, 64, 64, 1, 128, 256),     # granite4_h_micro.steady
+    (2, 8192, 64, 64, 8, 128, 128),     # nemotron3_nano_30b_a3b.steady
+    (1, 2048, 8, 128, 2, 128, 128),     # a head a lane slab
+    (1, 1024, 8, 32, 1, 128, 128),      # four heads a slab
+])
+def test_scan_kernels_compile_at_the_cells_shapes(topo, b, t, h, p, g, n,
+                                                  chunk):
+    """`dwt_ssd_fwd` and `dwt_ssd_bwd` alone, a few seconds a shape: what
+    the interpret-mode tests (tests/test_ssd_kernel.py) cannot see."""
+    shapes = _scan_arguments(topo, b, t, h, p, g, n)
+    hb = ssd._heads_block(h // g, p)
+
+    def scan(*a):
+        return ssd._scan_kernels(*a, chunk, jnp.bfloat16, hb)
+
+    assert "dwt_ssd_fwd" in _compile(scan, *shapes)
+    text = _compile(jax.grad(lambda *a: scan(*a).sum(),
+                             argnums=tuple(range(6))), *shapes)
+    assert "dwt_ssd_fwd" in text and "dwt_ssd_bwd" in text
+    assert _square_tiles(text, "", chunk) == []
