@@ -45,17 +45,14 @@ import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from .findings import Finding
+from .hlo_scopes import read_instruction
 
 #: collective op names counted in the optimized HLO (async `-start`
 #: halves count once; `-done` is ignored).
 COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter",
                   "collective-permute", "all-to-all")
 
-_OP_RE = re.compile(
-    r"=\s*(.*?)\s(all-gather|all-reduce|reduce-scatter|collective-permute|"
-    r"all-to-all)(?:-start)?\(")
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
-_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1,
                 "f8e5m2": 1, "s64": 8, "u64": 8, "s32": 4, "u32": 4,
                 "s16": 2, "u16": 2, "s8": 1, "u8": 1, "pred": 1}
@@ -64,17 +61,17 @@ _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1,
 def iter_collectives(hlo_text: str):
     """(op, [(dtype, dims), ...], op_name) of every collective instruction
     in an optimized-HLO dump, one output shape per tuple element; async
-    `-start` halves count once, `-done` is ignored.  Line by line, so a
-    TPU layout's parentheses (`{0:T(1024)(128)}`) inside a tuple do not
-    end the match."""
-    for line in hlo_text.splitlines():
-        m = _OP_RE.search(line)
-        if m is None:
+    `-start` halves count once, `-done` is ignored.  Line by line
+    through `hlo_scopes.read_instruction`, whose shape is the text before
+    the opcode: a TPU layout's parentheses (`{0:T(1024)(128)}`) inside a
+    tuple do not end it."""
+    for ins in filter(None, map(read_instruction, hlo_text.splitlines())):
+        op = ins["opcode"].removesuffix("-start")
+        if op not in COLLECTIVE_OPS:
             continue
         shapes = [(dt, tuple(int(d) for d in dims.split(",") if d))
-                  for dt, dims in _SHAPE_RE.findall(m.group(1))]
-        name = _OP_NAME_RE.search(line)
-        yield m.group(2), shapes, name.group(1) if name else ""
+                  for dt, dims in _SHAPE_RE.findall(ins["shape"])]
+        yield op, shapes, ins["op_name"]
 
 
 def count_collectives(hlo_text: str) -> Dict[str, Dict[str, int]]:

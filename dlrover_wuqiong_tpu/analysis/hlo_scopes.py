@@ -1,4 +1,4 @@
-"""From optimized HLO text to a table {instruction name: scope}.
+"""From optimized HLO text to a table {instruction name: owner}.
 
 Parity: no reference counterpart — the reference reads per-op time off
 `torch.profiler`'s module hierarchy; on TPU a profiler event is one HLO
@@ -6,10 +6,10 @@ instruction (`%fusion.2183`), whose name says nothing of what it holds.
 What it holds is in the compiled module's text: every instruction
 carries the `op_name` it was traced under (flax's module path, the
 `jax.named_scope`s of models/ and trainer/train_step.py, autodiff's
-`jvp(...)` / `transpose(...)` wrappers).  This parser, beside
-`hlo_budget.py` (which counts collectives in the same text), turns that
-text into the table a trace reducer needs to say "this fusion is the
-MLP's backward".
+`jvp(...)` / `transpose(...)` wrappers).  This parser turns that text
+into the table a trace reducer needs to say "this fusion is the MLP's
+backward"; `read_instruction` is the package's one reader of an HLO
+line (`hlo_budget.iter_collectives` counts collectives through it).
 
 A scope is the `op_name` normalised (`scope_of`):
 
@@ -19,19 +19,44 @@ A scope is the `op_name` normalised (`scope_of`):
 - a transform wraps the scope it was applied under: `transpose(jvp(X))`
   -> leading `bwd` and the scope `X`, `jvp(X)` -> leading `fwd` and `X`;
   a rematerialised forward (`rematted_computation`, `remat`) ->
-  `recompute`;
+  `recompute`; the tracer names the differentiated function once a
+  transform (`transpose(jvp(GPT))/jvp(GPT)/checkpoint/...`): the repeat
+  is dropped, so a module's forward, recomputed and backward
+  instructions agree on one path;
 - `h_<i>` -> `h` (`layers_<i>` -> `layers`), so the blocks share their
-  scopes.
+  scopes;
+- several names joined by `;` (instructions the compiler merged): the
+  common scope of the pieces.
 
-`jit(train_step)/transpose(jvp(GPT))/h_3/mlp/c_fc/dot_general` becomes
-`bwd/GPT/h/mlp/c_fc`, `jit(train_step)/transpose(jvp(loss))/exp` becomes
+`jit(train_step)/jvp(GPT)/h_3/mlp/c_fc/dot_general` becomes
+`fwd/GPT/h/mlp/c_fc`, `jit(train_step)/transpose(jvp(loss))/exp` becomes
 `bwd/loss`, `jit(train_step)/optimizer/mul` becomes `optimizer`.
 
-A fusion takes the scope of the `dot` / `convolution` it holds (the
-matmul decides what a fusion costs), else the longest common prefix of
-its instructions' scopes, else — where they agree on nothing — its own
-`op_name`, which is its root's: what it produces.  An instruction with
-no `op_name` (parameters, compiler-made copies) maps to "".
+Who owns an instruction, and how the table came to say so (`owners`,
+the entry's `via`).  The model's root is whatever holds the block axis
+(`GPT` in `GPT/h/...`); alone it says nothing, so it counts as no name:
+
+- `own`: the instruction's own scope; the two halves of an async pair
+  (`copy-start` / `copy-done`, `slice-*`, `all-gather-*`) share one;
+- a fusion (and whatever else `calls` a computation it runs: an
+  `async-start`): `matmul`, the scope of the `dot` / `convolution` it holds
+  (the matmul decides what a fusion costs); else `common`, the longest
+  common prefix of its instructions' scopes where that names something
+  below the root and its block axis; else `root`, the fusion's own
+  `op_name`, which is its root instruction's: what it produces; else
+  `members`, the scope most of its instructions carry.  `members` keeps
+  every scope they carried;
+- no name at all (compiler-made copies, async halves, re-layouts):
+  `consumer`, the scope its users agree on, as a fusion's instructions
+  agree (a re-layout is made FOR whoever asked), else `producer`, its
+  first operand's that has one, both through chains of such
+  instructions; a parameter staged for users that disagree goes to the
+  scope most of them carry; else `none`.
+
+Beside the owner a `kind`: `move` for an opcode that moves data and
+computes nothing (`_MOVES`, the async copy and slice halves) and for a
+fusion of nothing else, `compute` for the rest.  `scope_table` is the
+{name: scope} view of the same table.
 
 One op loses its path on the way: the TPU compiler puts kernels of its
 own in place of a `lax.ragged_dot` and writes ITS name over the traced
@@ -43,18 +68,21 @@ is all that is left to say of them.
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Optional
+from collections import Counter
+from typing import Dict, Iterable, List, Optional
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 # the opcode is the first lower-case word followed by "(" after the
 # result shape; layouts hold T(8,128) and S(1), never a lower-case call
 _OPCODE = re.compile(r"(?:^|[\s})\]])([a-z][a-z0-9\-]*)\(")
+_OPERAND = re.compile(r"%([\w.\-]+)")
 _CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _WRAPPER = re.compile(r"^([\w\-]+)\((.*)\)$")
 _CALL_FRAMES = frozenset({"jit", "pjit"})
 _BLOCK = re.compile(r"^(h|layers)_\d+$")  # models/gpt.py, models/llama.py
+_BLOCK_AXES = frozenset({"h", "layers"})
 _CONTROL = frozenset({"while", "body", "cond", "checkpoint", "closed_call"})
 _RECOMPUTE = frozenset({"rematted_computation", "remat", "remat2"})
 _MATMUL = frozenset({"dot", "convolution"})
@@ -63,6 +91,9 @@ _PHASES = ("fwd", "bwd", "recompute")
 
 def scope_of(op_name: str) -> str:
     """The normalised scope of one `op_name` (module docstring)."""
+    if ";" in op_name:
+        return _common_scope([s for s in map(scope_of, op_name.split(";"))
+                              if s])
     if not op_name:
         return ""
     parts = op_name.split("/")
@@ -86,6 +117,8 @@ def scope_of(op_name: str) -> str:
         if part in _RECOMPUTE:
             phase = "recompute"
             continue
+        if wrapper and path and path[-1] == part:
+            continue  # transpose(jvp(GPT))/jvp(GPT): one function, twice
         block = _BLOCK.match(part)
         path.append(block.group(1) if block else part)
         last_is_primitive = not wrapper
@@ -94,27 +127,69 @@ def scope_of(op_name: str) -> str:
     return "/".join(([phase] if phase else []) + path)
 
 
-def _common_scope(scopes: List[str]) -> str:
+def _split(scope: str):
+    """(phase, path) of a scope."""
+    parts = scope.split("/")
+    return (parts[0], parts[1:]) if parts[0] in _PHASES else ("", parts)
+
+
+def _common_scope(scopes: Iterable[str]) -> str:
     """Longest common prefix of the paths; the phase survives only where
     all agree (a fusion of the MLP's forward and backward is still the
     MLP's)."""
     phases, paths = set(), []
     for scope in scopes:
-        parts = scope.split("/")
-        phase = parts[0] if parts[0] in _PHASES else ""
+        phase, path = _split(scope)
         phases.add(phase)
-        paths.append(parts[1:] if phase else parts)
+        paths.append(path)
     out = []
     for level in zip(*paths):
         if any(p != level[0] for p in level):
             break
         out.append(level[0])
     phase = phases.pop() if len(phases) == 1 else ""
-    return "/".join(([phase] if phase else []) + [p for p in out if p])
+    out = [p for p in out if p]
+    return "/".join(([phase] if phase else []) + out) if out else ""
+
+
+def read_instruction(line: str) -> Optional[dict]:
+    """One line of HLO text as {"name", "shape", "opcode", "operands",
+    "op_name", "calls"}, or None where the line is no instruction.  The
+    shape is the text before the opcode (`bf16[8,64]{1,0:T(8,128)(2,1)}`,
+    a tuple's in its parentheses), the operands the `%names` inside the
+    call's own parentheses."""
+    m = _INSTRUCTION.match(line)
+    if not m:
+        return None
+    rest = m.group(2)
+    op = _OPCODE.search(rest)
+    shape, operands = "", []
+    if op:
+        shape = rest[:op.start(1)].strip()
+        # the call's closing parenthesis: operands printed with their
+        # types hold layouts, T(8,128), of their own
+        start = op.end()
+        end = rest.find(")", start)
+        while end != -1 and \
+                rest.count("(", start, end) > rest.count(")", start, end):
+            end = rest.find(")", end + 1)
+        if end != -1:
+            operands = _OPERAND.findall(rest, start, end)
+    name = _OP_NAME.search(rest)
+    calls = _CALLS.search(rest)
+    return {
+        "name": m.group(1),
+        "shape": shape,
+        "opcode": op.group(1) if op else "",
+        "operands": operands,
+        "op_name": name.group(1).replace("\\'", "'") if name else "",
+        "calls": calls.group(1) if calls else "",
+    }
 
 
 def parse_computations(hlo_text: str) -> Dict[str, List[dict]]:
-    """{computation: [{"name", "opcode", "op_name", "calls"}, ...]}."""
+    """{computation: [instruction, ...]}, each as `read_instruction`
+    gives it."""
     comps: Dict[str, List[dict]] = {}
     current: Optional[List[dict]] = None
     for line in hlo_text.splitlines():
@@ -126,52 +201,10 @@ def parse_computations(hlo_text: str) -> Dict[str, List[dict]]:
         if line.startswith("}"):
             current = None
             continue
-        m = _INSTRUCTION.match(line)
-        if not m:
-            continue
-        rest = m.group(2)
-        op = _OPCODE.search(rest)
-        name = _OP_NAME.search(rest)
-        calls = _CALLS.search(rest)
-        current.append({
-            "name": m.group(1),
-            "opcode": op.group(1) if op else "",
-            "op_name": name.group(1).replace("\\'", "'") if name else "",
-            "calls": calls.group(1) if calls else "",
-        })
+        ins = read_instruction(line)
+        if ins:
+            current.append(ins)
     return comps
-
-
-def scope_table(hlo_text: str) -> Dict[str, str]:
-    """{instruction name: scope} for every instruction of the module
-    that can run as a device op (those inside a fused computation run as
-    their fusion and are left out)."""
-    comps = parse_computations(hlo_text)
-    fused = {ins["calls"] for body in comps.values() for ins in body
-             if ins["opcode"] == "fusion" and ins["calls"]}
-    table: Dict[str, str] = {}
-    for cname, body in comps.items():
-        if cname in fused:
-            continue
-        for ins in body:
-            scope = scope_of(ins["op_name"])
-            if ins["opcode"] == "fusion":
-                inner = comps.get(ins["calls"], [])
-                matmul = [scope_of(i["op_name"]) for i in inner
-                          if i["opcode"] in _MATMUL and i["op_name"]]
-                named = matmul or [scope_of(i["op_name"]) for i in inner
-                                   if i["op_name"]]
-                common = _common_scope(named) if named else ""
-                if common not in _PHASES and common:
-                    scope = common
-                # else its instructions agree on nothing (one stray
-                # constant from another scope is enough): the fusion's
-                # own metadata stands, which is its root's — what it
-                # produces
-            if not scope and ins["name"].startswith("ragged-dot"):
-                scope = "ragged_dot"
-            table[ins["name"]] = scope
-    return table
 
 
 # opcodes that move data and compute nothing; a fusion of nothing else
@@ -179,31 +212,161 @@ def scope_table(hlo_text: str) -> Dict[str, str]:
 # a re-layout too
 _MOVES = frozenset({"copy", "transpose", "reshape", "slice", "concatenate",
                     "pad", "dynamic-slice", "dynamic-update-slice"})
+_ASYNC_MOVES = frozenset({"copy-start", "copy-done", "slice-start",
+                          "slice-done"})
 _FREE = frozenset({"parameter", "constant", "bitcast", "tuple",
                    "get-tuple-element", "broadcast", "iota"})
 
 
+def _names_something(scope: str, roots: frozenset) -> bool:
+    """Whether a scope holds a component below the model's root and its
+    block axis (`GPT/h/mlp`, `loss`; not `GPT/h`, not `GPT`)."""
+    path = _split(scope)[1]
+    for i, part in enumerate(path):
+        if part in roots:
+            return any(p not in _BLOCK_AXES for p in path[i + 1:])
+    return any(path)
+
+
+def _bare(scope: str, roots: frozenset) -> bool:
+    """No name, or the model's root alone, which says nothing."""
+    path = _split(scope)[1]
+    return not path or path[-1] in roots
+
+
+def _fusion_owner(inner: List[tuple], roots: frozenset, own: str) -> tuple:
+    """(scope, via, members) of a fusion from the (opcode, scope) of the
+    named instructions it holds and its `own` name."""
+    votes = Counter(s for _, s in inner)
+    members = list(votes)
+    matmul = {s for op, s in inner if op in _MATMUL}
+    for via, candidates in (("matmul", matmul), ("common", members)):
+        common = _common_scope(candidates)
+        if common and _names_something(common, roots):
+            return common, via, members
+    if own:
+        return own, "root", members
+    for scope, _ in votes.most_common():
+        if not _bare(scope, roots):
+            return scope, "members", members
+    return "", "none", members
+
+
+def _resolve(comps: Dict[str, List[dict]]) -> Dict[str, dict]:
+    # a fusion, and an async op's start, run the computation they call
+    called = {ins["calls"] for body in comps.values() for ins in body
+              if ins["calls"]}
+    top = {ins["name"]: ins for cname, body in comps.items()
+           if cname not in called for ins in body}
+    scopes = {name: scope_of(name) for name in {
+        ins["op_name"] for body in comps.values() for ins in body}}
+    roots = frozenset(path[i - 1] for path in (
+        _split(s)[1] for s in set(scopes.values()))
+        for i in range(1, len(path)) if path[i] in _BLOCK_AXES)
+    held = {cname: ({i["opcode"] for i in comps[cname]} - _FREE,
+                    [(i["opcode"], scopes[i["op_name"]]) for i in comps[cname]
+                     if scopes[i["op_name"]]])
+            for cname in called if cname in comps}
+
+    def named(ins: dict) -> str:
+        scope = scopes[ins["op_name"]]
+        if not scope and ins["name"].startswith("ragged-dot"):
+            return "ragged_dot"
+        return "" if _bare(scope, roots) else scope
+
+    table: Dict[str, dict] = {}
+    users: Dict[str, List[str]] = {}
+    for name, ins in top.items():
+        for operand in ins["operands"]:
+            users.setdefault(operand, []).append(name)
+        scope, via, members = named(ins), "own", []
+        if ins["calls"]:
+            opcodes, inner = held.get(ins["calls"], (set(), []))
+            scope, via, members = _fusion_owner(inner, roots, scope)
+            moves = bool(opcodes) and opcodes <= _MOVES
+        else:
+            moves = ins["opcode"] in _MOVES or ins["opcode"] in _ASYNC_MOVES
+        table[name] = {"scope": scope, "via": via if scope else "none",
+                       "kind": "move" if moves else "compute",
+                       "members": members}
+    # the two halves of an async pair are one op
+    for name, ins in top.items():
+        if not (ins["opcode"].endswith("-done") and ins["operands"]):
+            continue
+        start, done = table.get(ins["operands"][0]), table[name]
+        if start is None or \
+                not top[ins["operands"][0]]["opcode"].endswith("-start"):
+            continue
+        done["kind"] = start["kind"]
+        if bool(start["scope"]) != bool(done["scope"]):
+            named_half = start if start["scope"] else done
+            for half in (start, done):
+                half["scope"], half["via"] = named_half["scope"], "own"
+    # text order is a schedule: operands stand before their users
+    made_from: Dict[str, str] = {}
+    for name, ins in top.items():
+        made_from[name] = table[name]["scope"] or next(
+            (made_from[o] for o in ins["operands"] if made_from.get(o)), "")
+    asked_for: Dict[str, str] = {}
+    most_ask: Dict[str, str] = {}
+    for name in reversed(top):
+        own = table[name]["scope"]
+        for asks, majority in ((asked_for, False), (most_ask, True)):
+            found = [] if own else [asks[u] for u in users.get(name, ())
+                                    if asks.get(u)]
+            common = _common_scope(found)
+            if len(set(found)) > 1 and \
+                    not _names_something(common, roots):
+                common = Counter(found).most_common(1)[0][0] \
+                    if majority else ""
+            asks[name] = own or common
+    for name, entry in table.items():
+        if entry["scope"]:
+            continue
+        for via, scope in (("consumer", asked_for[name]),
+                           ("producer", made_from[name]),
+                           ("consumer", most_ask[name])):
+            if scope:
+                entry["scope"], entry["via"] = scope, via
+                break
+    return table
+
+
+def owners(hlo_text: str) -> Dict[str, dict]:
+    """{instruction name: {"scope", "via", "kind", "members"}} for every
+    instruction of the module that can run as a device op (those inside
+    a fused computation run as their fusion and are left out): who owns
+    it, how the table came to say so, whether it only moves data, and
+    the scopes a fusion's instructions carried (module docstring)."""
+    return _resolve(parse_computations(hlo_text))
+
+
+def scope_table(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: scope}: `owners`' first column."""
+    return {name: entry["scope"]
+            for name, entry in owners(hlo_text).items()}
+
+
 def relayouts(hlo_text: str, under: str, outside=()) -> Dict[str, str]:
     """{instruction name: opcode} of the device ops whose scope holds the
-    component `under`, holds none of `outside`, and that only MOVE data:
-    a `copy`, `transpose` or materialised `reshape` of the module, or a
-    fusion of nothing but such ops — the compiled program's own count of
-    the splits, cuts to heads, transposes and joins around a kernel.
-    Kernels (`custom-call`) are never among them."""
+    component `under`, holds none of `outside`, and that only MOVE data
+    (`owners`' kind): a `copy`, `transpose` or materialised `reshape` of
+    the module or made for it, or a fusion of nothing but such ops — the
+    compiled program's own count of the splits, cuts to heads, transposes
+    and joins around a kernel.  The compiler's async copies and slices
+    are not among them (they stage an operand in another memory, in its
+    own layout), nor are kernels (`custom-call`)."""
     comps = parse_computations(hlo_text)
-    bodies = {name: {i["opcode"] for i in body} - _FREE
-              for name, body in comps.items()}
-    opcodes = {i["name"]: i for body in comps.values() for i in body}
+    opcodes = {i["name"]: i["opcode"] for body in comps.values()
+               for i in body}
     found: Dict[str, str] = {}
-    for name, scope in scope_table(hlo_text).items():
-        parts = scope.split("/")
+    for name, entry in _resolve(comps).items():
+        parts = entry["scope"].split("/")
         if under not in parts or any(o in parts for o in outside):
             continue
-        ins = opcodes[name]
-        moved = bodies.get(ins["calls"], {""}) if ins["opcode"] == "fusion" \
-            else {ins["opcode"]}
-        if moved and moved <= _MOVES:
-            found[name] = ins["opcode"]
+        if entry["kind"] == "move" and \
+                not opcodes[name].endswith(("-start", "-done")):
+            found[name] = opcodes[name]
     return found
 
 
@@ -217,16 +380,10 @@ def instructions_of(hlo_text: str, opcode: str, under: str
     (`bf16[163840,2048]{1,0:...}`; a scatter's result has its operand's
     shape)."""
     found: Dict[str, str] = {}
-    for line in hlo_text.splitlines():
-        m = _INSTRUCTION.match(line)
-        if not m:
+    for ins in filter(None, map(read_instruction, hlo_text.splitlines())):
+        if ins["opcode"] != opcode:
             continue
-        rest = m.group(2)
-        op = _OPCODE.search(rest)
-        if not op or op.group(1) != opcode:
-            continue
-        name = _OP_NAME.search(rest)
-        scope = scope_of(name.group(1).replace("\\'", "'")) if name else ""
+        scope = scope_of(ins["op_name"])
         if not under or f"/{under}/" in f"/{scope}/":
-            found[m.group(1)] = rest[:op.start(1)].strip()
+            found[ins["name"]] = ins["shape"]
     return found

@@ -98,13 +98,6 @@ class MetricRegistry:
         with self._lock:
             return self._gauges.get(name, {}).get(_labels_key(labels))
 
-    def drop_gauge(self, name: str):
-        """Remove every series of a gauge family — for windowed metrics
-        whose label sets change between publishes (stale series would
-        otherwise export forever and grow cardinality unboundedly)."""
-        with self._lock:
-            self._gauges.pop(name, None)
-
     def get_counter(self, name: str,
                     labels: Optional[Dict[str, str]] = None) -> float:
         with self._lock:

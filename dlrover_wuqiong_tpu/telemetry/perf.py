@@ -384,7 +384,6 @@ class PerfObservatory:
                                         "fused_k": fused_k})
         span_ctx.__enter__()
         prof = StepProfiler(trace_dir=tdir, start_step=step, end_step=step,
-                            registry=self._registry(), job_name=self._job,
                             device_only=True)
         ctx = prof.step(step)
         try:

@@ -6,16 +6,19 @@ orchestration + timeline dump) and the xpu_timer runtime-timing intent
 to Prometheus).
 
 TPU redesign: heavyweight tracing is `jax.profiler` (XPlane/TensorBoard
-format) started for a bounded step window; lightweight always-on timing is
-a host-side per-step stopwatch feeding the shared MetricRegistry (the
-device timeline inside a jit step is XLA's domain — per-op host hooks like
-LD_PRELOAD shims don't exist on TPU, the trace viewer covers that instead).
+format) started for a bounded step window, whose op-category split
+(`utils/xplane.py`) stays on the profiler as `last_profile` for the perf
+observatory's sentinel.  The always-on step timing is the Trainer's own
+per-step spans (`telemetry/spans.py`: `trainer:iteration`,
+`trainer:dispatch`): a worker's Prometheus series land in a registry only
+the master exports, so this module writes none (the device timeline
+inside a jit step is XLA's domain — per-op host hooks like LD_PRELOAD
+shims don't exist on TPU, the trace viewer covers that instead).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
 from typing import Optional
 
 from ..common.log import get_logger
@@ -24,7 +27,7 @@ logger = get_logger("profiler")
 
 
 class StepProfiler:
-    """Windowed jax.profiler trace + always-on step timing.
+    """Windowed jax.profiler trace around the steps of a loop.
 
     Usage:
         prof = StepProfiler(trace_dir="/tmp/trace", start_step=10,
@@ -36,7 +39,6 @@ class StepProfiler:
 
     def __init__(self, trace_dir: Optional[str] = None,
                  start_step: int = -1, end_step: int = -1,
-                 registry=None, job_name: str = "dwt",
                  device_only: bool = False):
         """`device_only`: leave the Python tracer out of the trace — for
         windows whose only reader is the xplane op split (the perf
@@ -47,28 +49,14 @@ class StepProfiler:
         self.end_step = end_step
         self._device_only = device_only
         self._tracing = False
-        self._job = job_name
         self.last_profile = None  # OpProfile of the latest closed window
-        if registry is None:
-            from ..master.metrics import get_registry
-
-            registry = get_registry()
-        self._reg = registry
 
     @contextlib.contextmanager
     def step(self, step: int):
         self._maybe_start_trace(step)
-        t0 = time.perf_counter()
         try:
             yield
         finally:
-            dt = time.perf_counter() - t0
-            self._reg.observe("dwt_train_step_seconds", dt,
-                              {"job": self._job},
-                              help="wall time of the train step's dispatch "
-                                   "call (asynchronous: not the device's "
-                                   "step time)")
-            self._reg.gauge("dwt_train_last_step", step, {"job": self._job})
             self._maybe_stop_trace(step)
 
     def _maybe_start_trace(self, step: int):
@@ -103,8 +91,9 @@ class StepProfiler:
             self._publish_op_profile()
 
     def _publish_op_profile(self):
-        """xpu_timer parity: per-op-category latencies from the XPlane →
-        MetricRegistry (→ Prometheus) + diagnosis evidence."""
+        """xpu_timer parity: per-op-category latencies from the XPlane,
+        kept as `last_profile` (the sentinel's input, diagnosis
+        evidence) and logged."""
         from .xplane import parse_trace_dir
 
         try:
@@ -119,21 +108,6 @@ class StepProfiler:
                          self.trace_dir)
             return
         self.last_profile = prof
-        # fresh window: drop last window's series (op names churn between
-        # windows; stale top-10 entries must not export forever)
-        self._reg.drop_gauge("dwt_op_seconds")
-        self._reg.drop_gauge("dwt_op_category_seconds")
-        for cat, sec in sorted(prof.categories.items()):
-            self._reg.gauge("dwt_op_category_seconds", sec,
-                            {"job": self._job, "category": cat},
-                            help="device time per op category in the last "
-                                 "trace window (xplane)")
-        for op in prof.top(k=10):
-            self._reg.gauge("dwt_op_seconds", op.total_s,
-                            {"job": self._job, "op": op.name,
-                             "category": op.category},
-                            help="device time of the hottest ops in the "
-                                 "last trace window (xplane)")
         logger.info(
             "op profile: %s",
             " ".join(f"{c}={s * 1e3:.2f}ms"

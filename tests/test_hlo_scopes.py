@@ -9,9 +9,12 @@ import re
 
 import pytest
 
+from dlrover_wuqiong_tpu.analysis.hlo_budget import iter_collectives
 from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
     instructions_of,
+    owners,
     parse_computations,
+    relayouts,
     scope_of,
     scope_table,
 )
@@ -24,10 +27,25 @@ from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
      "bwd/GPT/h/attn/c_attn"),
     ("jit(train_step)/transpose(jvp(GPT))/jvp(GPT)/checkpoint/"
      "rematted_computation/h_0/mlp/c_proj/dot_general",
-     "recompute/GPT/GPT/h/mlp/c_proj"),
+     "recompute/GPT/h/mlp/c_proj"),
     # backward of a checkpointed block: `checkpoint` alone is a wrapper
     ("jit(train_step)/transpose(jvp(GPT))/jvp(GPT)/checkpoint/h_0/ln_1/"
-     "mul", "bwd/GPT/GPT/h/ln_1"),
+     "mul", "bwd/GPT/h/ln_1"),
+    # the tracer names the differentiated function once a transform: the
+    # repeat goes, under the accumulation loop too
+    ("jit(train_step)/accum/while/body/closed_call/transpose(jvp(GPT))/"
+     "jvp(GPT)/checkpoint/rematted_computation/h_1/attn/c_proj/add",
+     "recompute/accum/GPT/h/attn/c_proj"),
+    ("jit(train_step)/transpose(jvp(NemotronH))/jvp(NemotronH)/checkpoint/"
+     "layers_3/norm/mul", "bwd/NemotronH/layers/norm"),
+    # a module that holds one of its own name is a path, not a repeat
+    ("jit(train_step)/jvp(GPT)/h_0/mlp/mlp/add", "fwd/GPT/h/mlp/mlp"),
+    # names the compiler joined: what the pieces agree on
+    ("jit(train_step)/jvp(GPT)/h_0/mlp/c_fc/dot_general;"
+     "jit(train_step)/jvp(GPT)/h_0/mlp/c_proj/add", "fwd/GPT/h/mlp"),
+    ("jit(train_step)/jvp(GPT)/h_0/mlp/c_fc/mul;"
+     "jit(train_step)/transpose(jvp(GPT))/h_0/mlp/c_fc/mul",
+     "GPT/h/mlp/c_fc"),
     ("jit(train_step)/jvp(GPT)/head/bte,ve->btv/dot_general",
      "fwd/GPT/head/bte,ve->btv"),
     ("jit(train_step)/jvp(loss)/jit(take_along_axis)/gather", "fwd/loss"),
@@ -114,16 +132,211 @@ def test_a_fusion_takes_its_matmul_else_the_common_prefix():
     assert table["fusion.7"] == "fwd/GPT/h/mlp/c_fc"
     # forward and backward of one scope: the phase goes, the scope stays
     assert table["multiply_add_fusion"] == "GPT/h/mlp"
-    # two scopes that agree on nothing: the fusion's own op_name stands
-    # (none here), as it does where one stray instruction empties the
-    # prefix of an otherwise single-scope fusion
-    assert table["fusion.9"] == ""
+    # two scopes that agree on nothing: the fusion's own op_name stands,
+    # as it does where one stray instruction empties the prefix of an
+    # otherwise single-scope fusion (fusion.11); none here, so the scope
+    # most of its instructions carry, the first of equals
+    assert table["fusion.9"] == "bwd/GPT/h/ln_1"
     assert table["fusion.11"] == "optimizer"
     assert table["all-reduce-start.1"] == "optimizer"
     assert table["dwt_fa_fwd.3"] == "fwd/GPT/h/attn"
-    assert table["copy.5"] == "" and table["Arg_1.2"] == ""
+    # no name of their own: made for whoever reads them
+    assert table["copy.5"] == "GPT/h/mlp"
+    assert table["Arg_1.2"] == "fwd/GPT/h/mlp/c_fc"
     # instructions inside a fused computation run as their fusion
     assert "convolution.1" not in table and "exp.1" not in table
+
+
+_X = "jit(train_step)/jvp(X)/layers_0"
+_X_BWD = "jit(train_step)/transpose(jvp(X))"
+_OWNERS_HLO = f"""\
+HloModule jit_train_step, entry_computation_layout={{()->f32[]}}
+
+%fused_mix (p0: f32[8]) -> f32[8] {{
+  %p0 = f32[8]{{0}} parameter(0)
+  %negate.1 = f32[8]{{0}} negate(%p0), metadata={{op_name="{_X}/norm/neg"}}
+  ROOT %multiply.1 = f32[8]{{0}} multiply(%negate.1, %negate.1), metadata={{op_name="{_X}/mlp/up/mul"}}
+}}
+
+%fused_remat (p0: f32[8]) -> f32[8] {{
+  %p0 = f32[8]{{0}} parameter(0)
+  %exp.2 = f32[8]{{0}} exponential(%p0), metadata={{op_name="{_X_BWD}/jvp(X)/checkpoint/rematted_computation/layers_1/mlp/up/exp"}}
+  ROOT %multiply.2 = f32[8]{{0}} multiply(%exp.2, %p0), metadata={{op_name="{_X_BWD}/layers_1/mlp/up/mul"}}
+}}
+
+%fused_moves (p0: f32[2,4]) -> f32[4,2] {{
+  %p0 = f32[2,4]{{1,0}} parameter(0)
+  %copy.3 = f32[2,4]{{0,1}} copy(%p0), metadata={{op_name="{_X}/attn/transpose"}}
+  %bitcast.3 = f32[2,4]{{0,1}} bitcast(%copy.3)
+  ROOT %transpose.3 = f32[4,2]{{1,0}} transpose(%bitcast.3), dimensions={{1,0}}, metadata={{op_name="{_X}/attn/transpose"}}
+}}
+
+%fused_moves_and_adds (p0: f32[2,4]) -> f32[4,2] {{
+  %p0 = f32[2,4]{{1,0}} parameter(0)
+  %add.4 = f32[2,4]{{1,0}} add(%p0, %p0), metadata={{op_name="{_X}/attn/add"}}
+  ROOT %transpose.4 = f32[4,2]{{1,0}} transpose(%add.4), dimensions={{1,0}}, metadata={{op_name="{_X}/attn/transpose"}}
+}}
+
+%fused_root_only (p0: f32[8]) -> f32[8] {{
+  %p0 = f32[8]{{0}} parameter(0)
+  ROOT %add.5 = f32[8]{{0}} add(%p0, %p0), metadata={{op_name="jit(train_step)/jvp(X)/add"}}
+}}
+
+%async_computation.1 (p0: f32[8]) -> f32[4] {{
+  %p0 = f32[8]{{0}} parameter(0)
+  ROOT %slice.6 = f32[4]{{0:S(1)}} slice(%p0), slice={{[0:4]}}
+}}
+
+ENTRY %main.1 (Arg_0.1: f32[8], Arg_1.2: f32[2,4]) -> (f32[8], f32[8]) {{
+  %Arg_0.1 = f32[8]{{0}} parameter(0)
+  %Arg_1.2 = f32[2,4]{{1,0}} parameter(1)
+  %mix = f32[8]{{0}} fusion(%Arg_0.1), kind=kLoop, calls=%fused_mix, metadata={{op_name="{_X}/mlp/up/mul"}}
+  %copy.10 = f32[8]{{0:T(256)}} copy(%mix)
+  %copy.11 = f32[8]{{0:T(512)}} copy(%mix)
+  %reshape.12 = f32[2,4]{{1,0}} reshape(%mix)
+  %transpose.13 = f32[4,2]{{1,0}} transpose(%reshape.12), dimensions={{1,0}}
+  %copy.14 = f32[4,2]{{0,1}} copy(%transpose.13)
+  %kernel.1 = f32[8]{{0}} custom-call(%copy.10, %copy.11, %copy.14), custom_call_target="tpu_custom_call", metadata={{op_name="{_X_BWD}/layers_0/attn/pallas_call"}}
+  %update.1 = f32[8]{{0}} multiply(%copy.11, %kernel.1), metadata={{op_name="jit(train_step)/optimizer/mul"}}
+  %copy-start.20 = (f32[8]{{0:S(1)}}, f32[8]{{0}}, u32[]{{:S(2)}}) copy-start(%Arg_0.1)
+  %copy-done.20 = f32[8]{{0:S(1)}} copy-done(%copy-start.20)
+  %slice-start.21 = ((f32[8]{{0}}), f32[4]{{0:S(1)}}, s32[]{{:S(2)}}) slice-start(%Arg_0.1), slice={{[0:4]}}, metadata={{op_name="jit(train_step)/jvp(X)/wte/slice"}}
+  %slice-done.21 = f32[4]{{0:S(1)}} slice-done(%slice-start.21)
+  %slice-start.22 = ((f32[8]{{0}}), f32[4]{{0:S(1)}}, s32[]{{:S(2)}}) async-start(%mix), calls=%async_computation.1
+  %slice-done.22 = f32[4]{{0:S(1)}} async-done(%slice-start.22)
+  %kernel.2 = f32[4]{{0}} custom-call(%slice-done.22), custom_call_target="tpu_custom_call", metadata={{op_name="{_X}/attn/pallas_call"}}
+  %remat = f32[8]{{0}} fusion(%copy-done.20), kind=kLoop, calls=%fused_remat
+  %moves = f32[4,2]{{1,0}} fusion(%Arg_1.2), kind=kLoop, calls=%fused_moves
+  %moves_and_adds = f32[4,2]{{1,0}} fusion(%Arg_1.2), kind=kLoop, calls=%fused_moves_and_adds
+  %root_only = f32[8]{{0}} fusion(%remat), kind=kLoop, calls=%fused_root_only, metadata={{op_name="jit(train_step)/jvp(X)/add"}}
+  %joined = f32[8]{{0}} add(%root_only, %root_only), metadata={{op_name="{_X}/mlp/up/add;{_X}/mlp/down/add"}}
+  %copy.30 = f32[8]{{0}} copy(%Arg_0.1)
+  ROOT %tuple.1 = (f32[8]{{0}}, f32[8]{{0}}) tuple(%update.1, %copy.30)
+}}
+"""
+
+
+@pytest.fixture(scope="module")
+def owned():
+    return owners(_OWNERS_HLO)
+
+
+def test_a_copy_between_two_scopes_is_made_for_its_reader(owned):
+    # producer fwd/X/layers/mlp/up, reader the backward attention kernel
+    assert owned["copy.10"] == {"scope": "bwd/X/layers/attn",
+                                "via": "consumer", "kind": "move",
+                                "members": []}
+
+
+def test_readers_that_disagree_leave_a_copy_to_its_producer(owned):
+    # read by the kernel and by the optimizer's update
+    assert owned["copy.11"]["scope"] == "fwd/X/layers/mlp/up"
+    assert owned["copy.11"]["via"] == "producer"
+
+
+@pytest.mark.parametrize("start,done,scope,via", [
+    # no name on either half: the pair is made for the fusion that reads it
+    ("copy-start.20", "copy-done.20", "X/layers/mlp/up", "consumer"),
+    # the name is on the start alone: the done takes it
+    ("slice-start.21", "slice-done.21", "fwd/X/wte", "own"),
+    # the chip's text: `async-start` calls the computation it runs, and
+    # moves data where that holds nothing but a slice
+    ("slice-start.22", "slice-done.22", "fwd/X/layers/attn", "consumer"),
+])
+def test_the_halves_of_an_async_pair_share_an_owner(owned, start, done,
+                                                    scope, via):
+    for half in (start, done):
+        assert owned[half]["scope"] == scope, half
+        assert owned[half]["via"] == via, half
+        assert owned[half]["kind"] == "move", half
+
+
+def test_a_chain_of_nameless_ops_resolves_to_its_end_phase_included(owned):
+    for name in ("reshape.12", "transpose.13", "copy.14"):
+        assert owned[name]["scope"] == "bwd/X/layers/attn", name
+        assert owned[name]["via"] == "consumer", name
+
+
+def test_recomputed_and_backward_members_of_one_module_agree(owned):
+    """`recompute/X/X/layers/mlp/up` against `bwd/X/layers/mlp/up` stopped
+    at the root; with the repeat dropped the module stands, without a
+    phase."""
+    assert owned["remat"]["scope"] == "X/layers/mlp/up"
+    assert owned["remat"]["via"] == "common"
+    assert owned["remat"]["members"] == ["recompute/X/layers/mlp/up",
+                                         "bwd/X/layers/mlp/up"]
+
+
+def test_a_fusion_across_two_modules_is_its_roots_and_lists_both(owned):
+    # the members agree on the root and the block axis: that says nothing
+    assert owned["mix"]["scope"] == "fwd/X/layers/mlp/up"
+    assert owned["mix"]["via"] == "root"
+    assert owned["mix"]["members"] == ["fwd/X/layers/norm",
+                                       "fwd/X/layers/mlp/up"]
+
+
+def test_the_models_root_alone_is_no_owner(owned):
+    # every member and the fusion itself say `fwd/X`: its reader decides
+    assert owned["root_only"]["scope"] == "fwd/X/layers/mlp"
+    assert owned["root_only"]["via"] == "consumer"
+    assert owned["root_only"]["members"] == ["fwd/X"]
+    assert owned["joined"] == {"scope": "fwd/X/layers/mlp", "via": "own",
+                               "kind": "compute", "members": []}
+
+
+@pytest.mark.parametrize("name,kind", [
+    ("moves", "move"),             # copy, bitcast, transpose
+    ("moves_and_adds", "compute"),  # the same with one add
+    ("copy.10", "move"), ("reshape.12", "move"), ("kernel.1", "compute"),
+    ("update.1", "compute"), ("copy-start.20", "move"),
+])
+def test_kind_tells_what_only_moves_data(owned, name, kind):
+    assert owned[name]["kind"] == kind
+
+
+def test_what_nothing_names_and_nothing_reads_is_left_unowned(owned):
+    # a parameter copied into the step's outputs
+    assert owned["copy.30"] == {"scope": "", "via": "none", "kind": "move",
+                                "members": []}
+    # what runs inside a called computation runs as its caller
+    assert "slice.6" not in owned and "multiply.1" not in owned
+    assert scope_table(_OWNERS_HLO) == {n: e["scope"]
+                                        for n, e in owned.items()}
+
+
+def test_relayouts_counts_the_copies_made_for_a_module():
+    """An inherited scope counts as an own one: the three copies and the
+    chain's reshape and transpose are the kernel's; the copy its
+    producer keeps is the MLP's; an async pair stages, it re-lays
+    nothing."""
+    assert relayouts(_OWNERS_HLO, "attn") == {
+        "copy.10": "copy", "reshape.12": "reshape",
+        "transpose.13": "transpose", "copy.14": "copy", "moves": "fusion"}
+    assert relayouts(_OWNERS_HLO, "mlp") == {"copy.11": "copy"}
+    assert relayouts(_OWNERS_HLO, "mlp", outside=("up",)) == {}
+
+
+def test_operands_are_read_past_printed_types_and_layouts():
+    comps = parse_computations(_OWNERS_HLO)
+    entry = {i["name"]: i for i in comps["main.1"]}
+    assert entry["kernel.1"]["operands"] == ["copy.10", "copy.11", "copy.14"]
+    assert entry["copy-start.20"]["shape"] == \
+        "(f32[8]{0:S(1)}, f32[8]{0}, u32[]{:S(2)})"
+    assert entry["Arg_0.1"]["operands"] == []
+    typed = parse_computations(
+        "ENTRY %e (a: f32[8]) -> f32[8] {\n"
+        "  %a = f32[8]{0:T(8,128)(2,1)} parameter(0)\n"
+        "  ROOT %s = (f32[8]{0}, f32[8]{0}) all-reduce-start("
+        "f32[8]{0:T(8,128)(2,1)} %a, f32[8]{0} %a), replica_groups={}\n"
+        "}\n")["e"]
+    assert typed[1]["operands"] == ["a", "a"]
+
+
+def test_collectives_are_read_through_the_same_line_reader():
+    assert list(iter_collectives(_HAND_HLO)) == [
+        ("all-reduce", [("f32", (8,)), ("f32", (8,))],
+         "jit(train_step)/optimizer/reduce_sum")]
+    assert list(iter_collectives(_OWNERS_HLO)) == []
 
 
 def _lower(remat: bool, accum: int = 1) -> str:

@@ -312,13 +312,16 @@ def test_124m_step_moves_nothing_around_its_attention_kernels(
     and transposes under the `attn` scope outside the dense layers and
     the kernels (`hlo_scopes.relayouts`): the transposed route had 192 a
     step (168 copies, 12 reshapes, 12 slicing fusions).  What stays is
-    the join of dq, dk and dv into c_attn's cotangent, one a layer."""
+    the join of dq, dk and dv into c_attn's cotangent: two
+    dynamic-update-slice fusions a layer under `attn` (the second
+    carries no name of its own and is its reader's, the first's: PR 35
+    counts it), the third under `c_attn`."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import relayouts
 
     cell, _, step = gpt2_124m_step
     text = step.as_text()
     moved = relayouts(text, "attn", outside=("c_attn", "c_proj"))
-    assert sorted(moved.values()) == ["fusion"] * 12, moved
+    assert sorted(moved.values()) == ["fusion"] * 24, moved
     assert all("dynamic-update-slice" in name for name in moved)
     b, t = cell["global_batch"], cell["seq_len"]
     # the kernels read c_attn's output and write c_proj's input
@@ -366,14 +369,16 @@ def test_olmoe_step_moves_little_around_its_attention_kernels(olmoe_step):
     """`hlo_scopes.relayouts` under `attention` outside the projections
     and QK-norm.  What stays: RoPE's half-swap on the (b, t, h*d) rows
     (the two lane rolls of q and of k, four slicing fusions and two
-    copies forward, six slices backward) and delta's turn to
-    (b*h, 1, t) (a copy and a reshape of a (b, t, h) array)."""
+    copies forward, six slices backward), delta's turn to
+    (b*h, 1, t) (a copy and a reshape of a (b, t, h) array) and, nameless
+    between two bitcasts of the backward pass and so its reader's since
+    PR 35, a copy of a (b*t/8, 8, h, d) float32 array."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import relayouts
 
     moved = relayouts(olmoe_step[2].as_text(), "attention", outside=(
         "q_proj", "k_proj", "v_proj", "o_proj", "qk_norm"))
     assert sorted(moved.values()) == \
-        ["copy"] + ["fusion"] * 6 + ["reshape"] + ["slice"] * 6, moved
+        ["copy"] * 2 + ["fusion"] * 6 + ["reshape"] + ["slice"] * 6, moved
 
 
 def test_olmoe_step_keeps_its_scopes_and_names_the_grouped_matmuls(
@@ -556,6 +561,59 @@ def test_granite_step_runs_the_kernels_direct_at_32_heads_of_64(
     moved = relayouts(text, "attention", outside=(
         "q_proj", "k_proj", "v_proj", "o_proj"))
     assert sorted(moved.values()) == ["copy"] * 6, moved
+
+
+# --------------------------- who owns the device ops of the four steps
+
+_STEPS = [("gpt2_124m_step", "GPT"), ("olmoe_step", "Llama"),
+          ("nemotron_step", "NemotronH"), ("granite_step", "GraniteHybrid")]
+
+
+def _owned(step):
+    """(owners, {name: instruction}) of a compiled step's text."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
+        owners, parse_computations)
+
+    text = step.as_text()
+    return owners(text), {i["name"]: i for body in
+                          parse_computations(text).values() for i in body}
+
+
+@pytest.mark.parametrize("fixture,root", _STEPS)
+def test_every_device_op_of_the_step_has_an_owner(request, fixture, root):
+    """`hlo_scopes.owners` leaves no instruction that can run as a device
+    op (`copy*`, `slice-*`, fusions, `reduce-window`, custom calls) with
+    `via: none` — but the copies of what nothing names and only the
+    step's outputs read: the step counter, a constant it returns."""
+    table, ins = _owned(request.getfixturevalue(fixture)[2])
+    unowned = [n for n, e in table.items() if e["via"] == "none"
+               and (ins[n]["opcode"] in ("fusion", "reduce-window",
+                                         "custom-call")
+                    or ins[n]["opcode"].startswith(("copy", "slice-")))]
+    assert len(unowned) <= 2, unowned
+    for name in unowned:
+        assert ins[name]["opcode"] == "copy", name
+        assert {ins[o]["opcode"] for o in ins[name]["operands"]} <= {
+            "parameter", "constant"}, name
+    assert sum(e["via"] in ("consumer", "producer")
+               for e in table.values()) > 500
+
+
+@pytest.mark.parametrize("fixture,root", _STEPS)
+def test_no_fusion_of_the_step_falls_to_the_models_root(request, fixture,
+                                                        root):
+    """A fusion whose members agree on the model's name alone takes its
+    root instruction's scope or its members'; recomputed and backward
+    instructions of one module agree on the module (no scope holds the
+    root twice)."""
+    table, ins = _owned(request.getfixturevalue(fixture)[2])
+    fusions = {n: e for n, e in table.items()
+               if ins[n]["opcode"] == "fusion"}
+    assert len(fusions) > 100
+    for name, entry in fusions.items():
+        assert entry["scope"].split("/")[-1] != root, (name, entry)
+    assert not [e["scope"] for e in table.values()
+                if f"{root}/{root}" in e["scope"]]
 
 
 # ------------------------- the dropless path's row movements, both cells
