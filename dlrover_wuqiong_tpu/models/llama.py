@@ -239,7 +239,7 @@ class LlamaBlock(nn.Module):
             # the auxiliary terms are the MEAN over the layers (every
             # layer sows its own and make_lm_loss sums what is sown)
             moe = dataclasses.replace(
-                cfg.moe,
+                cfg.moe, mesh=cfg.mesh,
                 aux_loss_weight=cfg.moe.aux_loss_weight / cfg.num_layers,
                 z_loss_weight=cfg.moe.z_loss_weight / cfg.num_layers)
             ffn = MoEMLP(cfg.hidden_size, cfg.intermediate_size, moe,

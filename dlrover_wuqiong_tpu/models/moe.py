@@ -36,6 +36,16 @@ experts would have added is left out, and nothing stands in for the
 chips that hold them or for the exchange with them.  Such a layer also
 sows `moe_rows_held` and `moe_rows_absent` (assignments to experts held
 / not held here); an assignment to an absent expert is not a drop.
+
+Which kernels run the grouped products is `ops/grouped_matmul.gmm_route`'s
+to say, from the call's shapes, mesh and backend: a whole layer fills
+its T*k-row buffer and keeps `lax.ragged_dot` (the TPU compiler's own
+grouped kernels, 57% of peak on OLMoE's full buffer); a SHARE on one
+TPU device runs `dwt_gmm` / `dwt_tgmm`, whose grid walks only the row
+tiles that hold a held row (the compiler's kernels walk the whole
+buffer: at 8 of 128 experts 93% of it is empty).  A share also sows
+`moe_gmm_tiles` (row tiles its grouped products walk, row tiles of the
+buffer), which `collect_moe_stats` reduces to `moe_gmm_tiles_share`.
 """
 
 from __future__ import annotations
@@ -47,6 +57,8 @@ from typing import Any, Dict, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..ops.grouped_matmul import gmm_route, grouped_matmul, row_tiles
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +121,7 @@ class MoEConfig:
     # .. first_expert + experts_held - 1; 0 = all of them
     experts_held: int = 0
     first_expert: int = 0
+    mesh: Any = None  # the model config's (set by auto_accelerate)
 
     @property
     def held(self) -> int:
@@ -276,7 +289,7 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
                     w_gate: Optional[jax.Array], w_in: jax.Array,
                     w_down: jax.Array, first_expert: int = 0,
-                    num_experts: Optional[int] = None
+                    num_experts: Optional[int] = None, mesh=None
                     ) -> Tuple[jax.Array, jax.Array]:
     """The dropless expert pass for a routing already made: returns
     (out (T, d), group_sizes (held,)).  Scopes: `dispatch` (sort, its
@@ -290,9 +303,12 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
     Fewer than all of them is a chip's share: an assignment to an absent
     expert is taken out BEFORE the sort.  It gets no group (`group_sizes`
     has one entry a held expert) and its place in the static (T*k)-row
-    buffer lies behind every held row.  No group of `ragged_dot` writes
-    those places, and what the TPU's grouped kernels leave there is not
-    zero (a NaN by step 20, PERF.md section 6, PR 31).  Two things keep
+    buffer lies behind every held row.  No group of a grouped product
+    writes those places (`ops/grouped_matmul.grouped_matmul`: on one TPU
+    device, `mesh` None or of size 1, a share's products run kernels
+    that never visit a row tile behind the held rows; everything else is
+    `lax.ragged_dot`), and what the TPU's grouped kernels leave there is
+    not zero (a NaN by step 20, PERF.md section 6, PR 31).  Two things keep
     it out of every result and gradient: the first product's places
     behind the held rows are set to zero (so the activation's are, and
     by the mask's transpose those of its cotangent), and the sums over a
@@ -318,7 +334,7 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
         xs = xs.astype(w_in.dtype)                     # (T*k, d) sorted
 
     def grouped(lhs, rhs):
-        out = jax.lax.ragged_dot(lhs, rhs, group_sizes)
+        out = grouped_matmul(lhs, rhs, group_sizes, num_experts, mesh)
         return jnp.where(held_row[:, None], out, 0) if share else out
 
     with jax.named_scope("experts"):
@@ -327,7 +343,7 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
         else:
             h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_in)
         # (T*k, d); no mask here: `combine` reads the held rows alone
-        ys = jax.lax.ragged_dot(h, w_down, group_sizes)
+        ys = grouped_matmul(h, w_down, group_sizes, num_experts, mesh)
     with jax.named_scope("combine"):
         out = combine(ys, gates, order, inv, held_rows)
     return out.astype(tokens.dtype), group_sizes
@@ -482,7 +498,8 @@ class MoEMLP(nn.Module):
         if cfg.impl == "grouped":
             out, counts = grouped_experts(
                 tokens, gates, experts, w_gate, w_in, w_out,
-                first_expert=cfg.first_expert, num_experts=cfg.num_experts)
+                first_expert=cfg.first_expert, num_experts=cfg.num_experts,
+                mesh=cfg.mesh)
         else:
             combine, dispatch = top_k_gating(logits, cfg.top_k, capacity)
             # dispatch: (T, E, C) x (T, d) -> (E, C, d)
@@ -504,6 +521,12 @@ class MoEMLP(nn.Module):
             self.sow("intermediates", "moe_rows_held", counts.sum())
             self.sow("intermediates", "moe_rows_absent", dropped)
             dropped = jnp.zeros((), dropped.dtype)
+            # how far the grouped products follow the held rows: row
+            # tiles they walk, row tiles of the buffer (no sync)
+            rows = n_tok * cfg.top_k
+            self.sow("intermediates", "moe_gmm_tiles", jnp.stack(row_tiles(
+                counts, rows, gmm_route((rows, d), w_in.shape,
+                                        cfg.num_experts, cfg.mesh))))
         self.sow("intermediates", "moe_dropped", dropped)
         if cfg.shared_width:
             with jax.named_scope("shared"):
@@ -556,7 +579,8 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     scalars — or {} for a model with no such layer: the worst layer's
     `max_i(tokens_i) / mean_i(tokens_i)`, the dropped assignments of all
     layers, and where the layers hold a share of their experts the
-    assignments to experts held / not held here, all layers."""
+    assignments to experts held / not held here, all layers, and the
+    share of the row buffers' tiles their grouped products walk."""
     counts = [n.astype(jnp.float32)
               for n in _sown(intermediates, "moe_tokens_per_expert")]
     if not counts:
@@ -564,6 +588,11 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     loads = [n.max() / jnp.maximum(n.mean(), 1.0) for n in counts]
     sums = {name: [jnp.sum(v) for v in _sown(intermediates, name)]
             for name in ("moe_dropped", "moe_rows_held", "moe_rows_absent")}
-    return {"moe_load_max_over_mean": jnp.max(jnp.stack(loads)),
-            **{name: jnp.sum(jnp.stack(v)) for name, v in sums.items()
-               if v}}
+    stats = {"moe_load_max_over_mean": jnp.max(jnp.stack(loads)),
+             **{name: jnp.sum(jnp.stack(v)) for name, v in sums.items()
+                if v}}
+    tiles = [v.reshape(-1, 2) for v in _sown(intermediates, "moe_gmm_tiles")]
+    if tiles:
+        walked, of = jnp.concatenate(tiles).astype(jnp.float32).sum(0)
+        stats["moe_gmm_tiles_share"] = walked / of
+    return stats

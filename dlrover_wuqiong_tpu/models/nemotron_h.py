@@ -112,7 +112,7 @@ class NemotronHConfig:
             selection_bias=True, routed_scaling=self.routed_scaling,
             expert_act="relu2", shared_width=self.shared_width,
             experts_held=self.experts_held, first_expert=self.first_expert,
-            bias_update_rate=self.bias_update_rate)
+            bias_update_rate=self.bias_update_rate, mesh=self.mesh)
 
     def num_params(self) -> int:
         h = self.hidden_size

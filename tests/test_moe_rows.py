@@ -3,7 +3,9 @@
 pass, both gathers through the sort and its inverse.  Held here, on the
 CPU in float32, against the forms plain autodiff gives (`tokens[idx]` and
 its scatter-add, `segment_sum` of a weighted copy), for every expert held
-and for a chip's share of them."""
+and for a chip's share of them.  A share's grouped products through the
+kernel route of `ops/grouped_matmul.py` (interpret mode) are the plain
+route's."""
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +13,7 @@ import numpy as np
 import pytest
 
 from dlrover_wuqiong_tpu.models.moe import combine, dispatch, grouped_experts
+from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
 
 T, K, D, F = 48, 6, 16, 12
 
@@ -213,3 +216,50 @@ def test_the_expert_pass_keeps_its_value_and_every_gradient(holding,
     assert sizes.shape == (held,)
     if routing == "none_held":
         assert int(sizes.sum()) == 0 and not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("holding,routing", [
+    c for c in CASES if c.values[0] != "all_8"])
+def test_a_shares_pass_through_the_kernels_is_the_plain_routes(
+        monkeypatch, holding, routing):
+    """`grouped_experts` on the route a share takes on one TPU device —
+    `dwt_gmm` / `dwt_gmm_t` / `dwt_tgmm`, here in interpret mode at a row
+    tile of 32 (T*k = 288 rows: nine tiles, of which the held rows fill
+    one or none) — against the route every CPU run takes: the output,
+    `group_sizes` and every gradient.  The kernels leave the rows of no
+    group unwritten; the interpreter hands them back as it finds them,
+    and the masks that keep them out are `grouped_experts`' own."""
+    held, first, num_experts = HOLDINGS[holding]
+    experts = _routing(routing, held, first, num_experts)
+    tokens, gates, w_in, w_down = _draw(
+        (T, D), (T, K), (held, D, F), (held, F, D), seed=5)
+    w_in, w_down = 0.2 * w_in, 0.2 * w_down
+
+    def run(*args):
+        out, sizes = grouped_experts(args[0], args[1], experts, None,
+                                     args[2], args[3], first_expert=first,
+                                     num_experts=num_experts)
+        return jnp.sum(jnp.sin(out)), (out, sizes)
+
+    args = (tokens, gates, w_in, w_down)
+    both = jax.value_and_grad(run, argnums=(0, 1, 2, 3), has_aux=True)
+    assert gm.gmm_route((T * K, D), w_in.shape, num_experts) == "plain"
+    (_, (want, want_sizes)), want_g = both(*args)
+    calls, grouped_kernels = [], gm._grouped_kernels
+
+    def kernels(lhs, rhs, sizes):
+        calls.append(lhs.shape)
+        return grouped_kernels(lhs, rhs, sizes, interpret=True)
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(gm, "_ROW_TILE", 32)
+    monkeypatch.setattr(gm, "_grouped_kernels", kernels)
+    assert gm.gmm_route((T * K, D), w_in.shape, num_experts) == "kernel"
+    (_, (got, got_sizes)), got_g = both(*args)
+    assert calls == [(T * K, D), (T * K, F)]
+    _close(got, want)
+    np.testing.assert_array_equal(np.asarray(got_sizes),
+                                  np.asarray(want_sizes))
+    for g, w in zip(got_g, want_g):
+        assert np.isfinite(np.asarray(g)).all()
+        _close(g, w)

@@ -19,8 +19,10 @@ from benchmark import reference_nemotron_h as ref
 from benchmark.reference import loss_and_grad_norm
 from dlrover_wuqiong_tpu.models.llama import Llama, LlamaConfig
 from dlrover_wuqiong_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer
-from dlrover_wuqiong_tpu.models.moe import MoEConfig, MoEMLP
+from dlrover_wuqiong_tpu.models.moe import (
+    MoEConfig, MoEMLP, collect_moe_stats)
 from dlrover_wuqiong_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
 from dlrover_wuqiong_tpu.ops.ssd import ssd_scan
 from dlrover_wuqiong_tpu.trainer.train_step import make_lm_loss
 
@@ -315,12 +317,34 @@ def test_the_bias_rule_steps_each_expert_toward_the_even_load():
             ruled, selection_bias=False)).init(jax.random.PRNGKey(0), x)
 
 
-def test_the_shares_parts_add_up_to_the_uncut_layer():
+def _on_the_kernel_route(monkeypatch, tile):
+    """What a share's grouped products take on one TPU device, here: the
+    route's own decision with the backend said to be the TPU and a row
+    tile that divides the nano buffer, the kernels in interpret mode."""
+    kernels = gm._grouped_kernels
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(gm, "_ROW_TILE", tile)
+    monkeypatch.setattr(gm, "_grouped_kernels", functools.partial(
+        kernels, interpret=True))
+
+
+@pytest.mark.parametrize("route", ["plain", "kernel"])
+def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
     """Four chips with two of the eight experts each: their parts of the
-    result, the shared expert counted once, are the whole layer's."""
+    result, the shared expert counted once, are the whole layer's — on
+    the route every CPU run takes and on the `dwt_gmm` kernels a share
+    runs on one TPU device (interpret mode, 192 rows in six tiles of
+    32).  Each share counts the row tiles its products walk: the whole
+    buffer on the plain route, on the kernels' the groups' visits."""
     whole = _moe()
     layer, params, x = _expert_layer(whole)
     want = _reference_layer(params, x, whole)
+    _, upd = layer.apply({"params": params}, x, mutable=["intermediates"])
+    assert "moe_gmm_tiles" not in upd["intermediates"]
+    assert "moe_gmm_tiles_share" not in collect_moe_stats(
+        upd["intermediates"])
+    if route == "kernel":
+        _on_the_kernel_route(monkeypatch, 32)
     shared = jnp.square(jax.nn.relu(
         x @ params["shared_up_proj"]["kernel"])) \
         @ params["shared_down_proj"]["kernel"]
@@ -330,6 +354,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer():
         part = {**params, **{
             k: params[k][first:first + 2]
             for k in ("experts_w_in", "experts_w_down")}}
+        assert gm.gmm_route((192, 32), (2, 32, 24), 8) == route
         y, upd = MoEMLP(hidden=32, ffn=24, moe=moe).apply(
             {"params": part}, x, mutable=["intermediates"])
         inter = upd["intermediates"]
@@ -339,9 +364,42 @@ def test_the_shares_parts_add_up_to_the_uncut_layer():
         assert int(inter["moe_dropped"][0]) == 0
         assert inter["moe_tokens_per_expert"][0].shape == (2,)
         rows += held
+        sizes = np.asarray(inter["moe_tokens_per_expert"][0])
+        ends = np.cumsum(sizes)
+        visits = sum(int((e - 1) // 32 - (e - n) // 32 + 1)
+                     for e, n in zip(ends, sizes) if n)
+        assert 0 < visits < 6
+        walked, of = (int(v) for v in inter["moe_gmm_tiles"][0])
+        # (the plain route counts in the kernels' own tile of 256 rows)
+        assert (walked, of) == ((visits, 6) if route == "kernel" else (1, 1))
+        assert float(collect_moe_stats(inter)["moe_gmm_tiles_share"]) \
+            == pytest.approx(walked / of)
     assert rows == 2 * 48 * 2  # every assignment is held by one chip
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=0,
                                atol=2e-5 * float(jnp.abs(want).max()))
+
+
+def test_a_shares_gradients_through_the_kernels_are_the_plain_routes(
+        monkeypatch):
+    """Every leaf's gradient of a share's layer (2 of 8 experts): the
+    kernel route (interpret mode, tiles of 32 rows) against the plain."""
+    moe = _moe(experts_held=2, first_expert=4)
+    layer, params, x = _expert_layer(moe)
+
+    def run(p):
+        return jnp.sum(jnp.sin(layer.apply({"params": p}, x)))
+
+    want, want_g = jax.value_and_grad(run)(params)
+    _on_the_kernel_route(monkeypatch, 32)
+    assert "pallas_call" in str(jax.make_jaxpr(jax.grad(run))(params))
+    got, got_g = jax.value_and_grad(run)(params)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    for (path, g), w in zip(
+            jax.tree_util.tree_flatten_with_path(got_g)[0],
+            jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=1e-5,
+            atol=1e-6 * max(1.0, float(jnp.abs(w).max())), err_msg=str(path))
 
 
 def test_a_share_runs_the_one_grouped_path_over_the_held_experts():
@@ -677,6 +735,8 @@ def test_a_few_trainer_steps_count_the_share_and_leave_the_bias_alone(
     for a in events:
         assert a["moe_rows_held"] + a["moe_rows_absent"] == 8 * SEQ * 2 * 2
         assert a["moe_rows_held"] > 0 and a["moe_dropped"] == 0.0
+        # off the TPU the grouped products walk the whole buffer
+        assert a["moe_gmm_tiles_share"] == 1.0
     after = jax.tree.map(np.asarray, tr.state.params)
     for path, old in jax.tree_util.tree_flatten_with_path(before)[0]:
         new = functools.reduce(lambda t, k: t[k.key], path, after)

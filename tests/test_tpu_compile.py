@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from dlrover_wuqiong_tpu.analysis.hlo_budget import iter_collectives
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
+from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
 from dlrover_wuqiong_tpu.ops import quantization as qz
 from dlrover_wuqiong_tpu.ops import ssd
 
@@ -287,6 +288,7 @@ def _one_chip_step(topo, name, model_file):
         mp.setattr("dlrover_wuqiong_tpu.models.attention._on_tpu",
                    lambda: True)
         mp.setattr(ssd, "_on_tpu", lambda: True)
+        mp.setattr(gm, "_on_tpu", lambda: True)
         res = auto_accelerate(
             model, strategy=[("fsdp", {})], devices=topo.devices[:1],
             optimizer=optax.chain(optax.clip_by_global_norm(1.0),
@@ -440,12 +442,31 @@ def test_nemotron_step_runs_the_kernels_at_d128_t8192(nemotron_step):
     assert f"bf16[{b * 32},8192,128]" not in text
 
 
+def _grouped_kernel_calls(text):
+    """{custom call: (scope, the bf16 shapes of its result and operands)}
+    of the `dwt_gmm*` / `dwt_tgmm*` kernels in a compiled step's text."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+
+    table = scope_table(text)
+    calls = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%((?:dwt_gmm|dwt_tgmm)[\w.]*) = ", line)
+        if m and "custom-call(" in line:
+            calls[m.group(1)] = (table[m.group(1)],
+                                 re.findall(r"bf16\[([\d,]+)\]", line))
+    return calls
+
+
 def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
         nemotron_step):
     """Every scope the per-layer metrics read is in the compiled step.
-    The expert layers run the whole layer's path (the compiler's
-    grouped-matmul kernels over the sorted T*k = 98,304-row buffer) on 8
-    groups: every weight operand holds the 8 held experts, none the
+    The expert layers run the whole layer's path over the sorted T*k =
+    98,304-row buffer on 8 groups, and since a share's buffer is mostly
+    empty its grouped products are the `dwt_gmm` / `dwt_gmm_t` /
+    `dwt_tgmm` kernels of `ops/grouped_matmul.py` (eight a layer: two
+    forward, two recomputed, four backward), every one under
+    `feed_forward/moe/experts`, none of the compiler's `ragged-dot`
+    kernels: every weight operand holds the 8 held experts, none the
     published 128, and the group sizes are 8 numbers — an assignment to
     an absent expert has no group.  Nothing in the step holds other ops
     (a `while`, a `conditional`), which a device trace would count
@@ -464,9 +485,14 @@ def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
         assert any(part in s for s in scopes), part
     assert not any("moe/aux" in s for s in scopes)  # no auxiliary loss
     rows = cell["global_batch"] * 8192 * 6
-    kernels = re.findall(r"%ragged-dot-none\.\d+ = bf16\[([\d,]+)\]", text)
-    assert kernels and set(kernels) == {
-        f"{rows},1856", f"{rows},2688", "8,2688,1856", "8,1856,2688"}
+    assert gm.gmm_route((rows, 2688), (8, 2688, 1856), 128) == "plain"  # CPU
+    calls = _grouped_kernel_calls(text)
+    assert len(calls) == 32 and "ragged-dot" not in text
+    assert all("feed_forward/moe/experts/dwt_" in scope
+               for scope, _ in calls.values()), calls
+    shapes = {s for _, operands in calls.values() for s in operands}
+    assert shapes == {f"{rows},1856", f"{rows},2688", "8,2688,1856",
+                      "8,1856,2688"}
     assert "[128,2688,1856]" not in text and "[128,1856,2688]" not in text
     # 128 numbers appear where the bias's rule counts every expert's
     # load (dispatch) and steps the bias (out_of_band), nowhere else
@@ -666,18 +692,28 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
         [by_assignment] * layers + [in_order] * layers)
     assert len(row_gathers("")) == layers * (passes + 3)
 
-    # the compiler's grouped-matmul kernels, by the buffer each writes:
-    # 9 a step at OLMoE, 32 at the hybrid (44 with their metadata ops)
+    # the grouped matmuls, by the buffer each writes: the compiler's
+    # kernels where the layer holds every expert (9 a step at OLMoE, 11
+    # ops with their metadata), `ops/grouped_matmul.py`'s where it holds
+    # a share (32 a step at the hybrid, and no `ragged-dot` at all)
+    rows = tokens * k
     kernels = collections.Counter(re.findall(
         r"%ragged-dot-none[.\d]* = bf16\[([\d,]+)\]", text))
-    rows = tokens * k
-    assert kernels == {
-        "olmoe_step": {f"{rows},1024": 3, f"{rows},2048": 3,
-                       "64,2048,1024": 2, "64,1024,2048": 1},
-        "nemotron_step": {f"{rows},1856": 12, f"{rows},2688": 12,
-                          "8,2688,1856": 4, "8,1856,2688": 4}}[fixture]
     ragged = [n for n in scope_table(text) if n.startswith("ragged-dot")]
-    assert len(ragged) == {"olmoe_step": 11, "nemotron_step": 44}[fixture]
+    ours = collections.Counter(
+        (re.sub(r"[.\d]+$", "", name), shapes[0])  # the result's comes first
+        for name, (_, shapes) in _grouped_kernel_calls(text).items())
+    if fixture == "olmoe_step":
+        assert kernels == {f"{rows},1024": 3, f"{rows},2048": 3,
+                           "64,2048,1024": 2, "64,1024,2048": 1}
+        assert len(ragged) == 11 and not ours
+    else:
+        assert not kernels and not ragged
+        assert ours == {
+            ("dwt_gmm", f"{rows},1856"): 8, ("dwt_gmm", f"{rows},2688"): 8,
+            ("dwt_gmm_t", f"{rows},1856"): 4,
+            ("dwt_gmm_t", f"{rows},2688"): 4,
+            ("dwt_tgmm", "8,2688,1856"): 4, ("dwt_tgmm", "8,1856,2688"): 4}
     assert " while(" not in text and " conditional(" not in text
 
 
@@ -798,3 +834,30 @@ def test_scan_kernels_compile_at_the_cells_shapes(topo, b, t, h, p, g, n,
                              argnums=tuple(range(6))), *shapes)
     assert "dwt_ssd_fwd" in text and "dwt_ssd_bwd" in text
     assert _square_tiles(text, "", chunk) == []
+
+
+# ----------------------- the grouped products of a share of the experts
+
+@pytest.mark.parametrize("c,n", [(2688, 1856), (1856, 2688)])
+def test_grouped_kernels_compile_at_the_cells_shapes(topo, c, n):
+    """`dwt_gmm`, `dwt_gmm_t` and `dwt_tgmm` alone at the hybrid cell's
+    four shapes — 98,304 rows x (8, 2688, 1856) and x (8, 1856, 2688),
+    their transposed-weight forms and the two weight gradients — a few
+    seconds a shape: what the interpret-mode tests
+    (tests/test_grouped_matmul.py) cannot see.  1856 columns are taken
+    whole, 2688 in tiles of 896; the blocks fit the kernels' VMEM."""
+    one = SingleDeviceSharding(topo.devices[0])
+    rows = 2 * 8192 * 6
+    lhs, rhs, sizes = (jax.ShapeDtypeStruct(s, d, sharding=one)
+                       for s, d in (((rows, c), jnp.bfloat16),
+                                    ((8, c, n), jnp.bfloat16),
+                                    ((8,), jnp.int32)))
+    assert gm._column_tile(1856) == 1856 and gm._column_tile(2688) == 896
+    assert gm._vmem_bytes(c, n) < gm._VMEM_LIMIT
+    text = _compile(jax.value_and_grad(
+        lambda l, r, s: gm._grouped_kernels(l, r, s).astype(
+            jnp.float32).sum(), argnums=(0, 1)), lhs, rhs, sizes)
+    for kernel in ("dwt_gmm.", "dwt_gmm_t", "dwt_tgmm"):
+        assert kernel in text, kernel
+    assert "ragged-dot" not in text
+    assert " while(" not in text and " conditional(" not in text

@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_sweep|attn_direct|head|model|opt|
-step|lib|dispatch|rpc] ...  (no args = step/attn/head/model/opt).  One JSON line
+step|lib|dispatch|rpc|gmm] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -15,6 +15,11 @@ per probe as it finishes, then ONE summary line
 the K-step driver (trainer/train_step.py) in THIS environment;
 `rpc` streams per-round control-plane RPCs/s per verb class against a
 per-frame-fsync and a group-commit master, rounds interleaved.
+`gmm` reads, from a profiler trace, the device time of each grouped
+product of a chip's share of an expert layer (98,304 rows of which
+6,800 are held in 8 groups) as `ops/grouped_matmul.py`'s kernels and as
+`lax.ragged_dot` run it: a stand-alone jit puts layout copies of its
+own around either, so a host clock around the call measures those.
 """
 
 from __future__ import annotations
@@ -556,13 +561,70 @@ def probe_rpc(rounds=2, clients=48, procs=4, duration_s=1.5,
                    round(gc_mean / base_mean, 2) if base_mean else 0.0})
 
 
+def _device_ops_ms(fn, *args, iters=5, top=4):
+    """{device op: ms a call} of `fn(*args)`, the `top` largest, from a
+    profiler trace of `iters` calls (`utils/xplane.py` reads it: ops of
+    one name, `copy.4` and `copy.5`, are summed)."""
+    import tempfile
+
+    from dlrover_wuqiong_tpu.utils.xplane import parse_trace_dir
+
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory(prefix="perf_probe_") as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        profile = parse_trace_dir(trace_dir)
+    return {o.name: round(o.total_s * 1e3 / iters, 4)
+            for o in (profile.top(k=top) if profile else [])}
+
+
+def probe_gmm(rows=98304, sizes=(1530, 400, 950, 700, 1100, 520, 900, 700)):
+    """The hybrid cell's grouped products, forward and both backward
+    forms at both shapes: `dwt_gmm` / `dwt_gmm_t` / `dwt_tgmm` against
+    the compiler's `ragged-dot` kernels (PERF.md section 6, PR 36)."""
+    from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
+
+    sizes = jnp.array(sizes, jnp.int32)
+    plan = dict(tile=gm._ROW_TILE, columns=gm._COLUMN_TILE, interpret=False)
+
+    def plain(l, r):
+        return jax.lax.ragged_dot(l, r, sizes)
+
+    def plain_bwd(which):
+        return jax.jit(lambda l, r, d: jax.vjp(plain, l, r)[1](d)[which])
+
+    for c, n in ((2688, 1856), (1856, 2688)):
+        ks = jax.random.split(jax.random.PRNGKey(c), 3)
+        lhs = jax.random.normal(ks[0], (rows, c), jnp.bfloat16)
+        rhs = (0.02 * jax.random.normal(ks[1], (len(sizes), c, n))
+               ).astype(jnp.bfloat16)
+        d_out = jax.random.normal(ks[2], (rows, n), jnp.bfloat16)
+        for name, fn, args in (
+                ("ragged_dot", jax.jit(plain), (lhs, rhs)),
+                ("ragged_dot_d_lhs", plain_bwd(0), (lhs, rhs, d_out)),
+                ("ragged_dot_d_rhs", plain_bwd(1), (lhs, rhs, d_out)),
+                ("dwt_gmm", lambda l, r: gm._gmm(
+                    l, r, sizes, transposed=False, **plan), (lhs, rhs)),
+                ("dwt_gmm_t", lambda d, r: gm._gmm(
+                    d, r, sizes, transposed=True, **plan), (d_out, rhs)),
+                ("dwt_tgmm", lambda l, d: gm._tgmm(
+                    l, d, sizes, dtype=jnp.dtype(jnp.bfloat16), **plan),
+                 (lhs, d_out))):
+            _emit_raw({"probe": "gmm", "what": name, "shape": [c, n],
+                       "held_rows": int(sizes.sum()), "rows": rows,
+                       "device_ops_ms": _device_ops_ms(fn, *args)})
+
+
 ALL = {"attn": probe_attn, "attn_sweep": probe_attn_sweep,
        "attn_direct": probe_attn_direct, "lib": probe_lib,
        "remat": probe_remat,
        "splash": probe_splash, "dots": probe_dots,
        "head": probe_head, "model": probe_model, "opt": probe_opt,
        "step": probe_step, "dispatch": probe_dispatch,
-       "rpc": probe_rpc}
+       "rpc": probe_rpc, "gmm": probe_gmm}
 
 
 def main(argv=None) -> int:
