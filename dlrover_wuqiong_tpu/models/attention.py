@@ -6,18 +6,23 @@ REPLACEMENT_PAIRS) and its distributed attention dispatch
 (`modules/distributed_modules/transformer.py:1`).  TPU redesign: instead
 of swapping nn.Module classes post-hoc, the model config carries
 `attn_impl` ("flash" | "ring" | "ulysses") and, for the SP impls, the
-`mesh` whose `sp` axis shards the sequence.  The `sequence_parallel`
-strategy (auto/accelerate.py:424) rewrites these fields so the same
-model definition runs single-chip, GSPMD-sharded, or context-parallel
-(parallel/long_context.py) without code changes.
+`mesh` whose `sp` axis shards the sequence.  Two more fields reach the
+kernels the same way, read here and nowhere else: `attn_scale` (the
+softmax's scale) and `attn_window` (a sliding window: ring attention
+refuses one, Ulysses and the shard_map of a mesh pass it through).  The
+`sequence_parallel` strategy (auto/accelerate.py:424) rewrites the
+first two so the same model definition runs single-chip, GSPMD-sharded,
+or context-parallel (parallel/long_context.py) without code changes.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..ops.flash_attention import (
     _on_tpu,
+    causal_tile_count,
     flash_attention_projected,
     mha,
     projected_ok,
@@ -41,6 +46,45 @@ def softmax_scale(cfg):
     has the field and sets it (Granite's `attention_multiplier`), else
     None, which every entry reads as 1/sqrt(head size)."""
     return getattr(cfg, "attn_scale", 0.0) or None
+
+
+def attention_window(cfg):
+    """The window the kernels are handed: `cfg.attn_window` where a
+    config has the field and sets it (a sliding-window layer: a query
+    sees that many keys, its own the last), else None: every key at or
+    before the query."""
+    return getattr(cfg, "attn_window", 0) or None
+
+
+def window_tiles(cfg, batch: int, n_head: int, seq: int):
+    """(score tiles a windowed layer's kernels compute in one pass over
+    `batch` sequences, what a causal call of the same shape would), or
+    None for a layer without a window: `causal_tile_count` at the
+    kernels' own blocks — the count the kernels' grids are PLANNED from,
+    static numbers, not a count of what ran: whether the kernels skip
+    what the plan skips is what `kernel.attn_window_ms` and the compiled
+    grid (tests/test_program_from_arguments.py) show.  What
+    `LlamaAttention` sows as `attn_tiles` and `collect_attention_stats`
+    sums."""
+    window = attention_window(cfg)
+    if window is None:
+        return None
+    return tuple(batch * n_head * causal_tile_count(seq, seq, window=w)[0]
+                 for w in (window, None))
+
+
+def collect_attention_stats(intermediates) -> dict:
+    """What the windowed attention layers of one forward pass counted,
+    summed over the layers — {} for a model with none: `attn_tiles_window`
+    and `attn_tiles_causal` (`window_tiles`)."""
+    from .moe import _sown
+
+    tiles = [v.reshape(-1, 2) for v in _sown(intermediates, "attn_tiles")]
+    if not tiles:
+        return {}
+    with jax.named_scope("attn_tiles"):  # the sum's copies get an owner
+        window, causal = jnp.concatenate(tiles).sum(0)
+    return {"attn_tiles_window": window, "attn_tiles_causal": causal}
 
 
 def attend_projected(proj, n_head: int, cfg, causal: bool = True):
@@ -69,7 +113,8 @@ def attend_projected(proj, n_head: int, cfg, causal: bool = True):
     d = lanes // n_head
     if goes_direct(cfg, n_head, d, t):
         return flash_attention_projected(proj, n_head, causal,
-                                         softmax_scale(cfg))
+                                         softmax_scale(cfg),
+                                         attention_window(cfg))
     if len(proj) == 1:
         proj = jnp.split(proj[0], 3, axis=-1)
     q, k, v = (x.reshape(b, t, n_head, d) for x in proj)
@@ -84,14 +129,23 @@ def attend(q, k, v, cfg, causal: bool = True):
     the jnp reference off it)."""
     impl = getattr(cfg, "attn_impl", "flash")
     mesh = getattr(cfg, "mesh", None)
-    scale = softmax_scale(cfg)
+    scale, window = softmax_scale(cfg), attention_window(cfg)
     if impl in ("ring", "ulysses") and mesh is not None:
         from ..parallel.long_context import ring_attention, ulysses_attention
 
-        fn = ring_attention if impl == "ring" else ulysses_attention
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        return fn(qt, kt, vt, mesh, causal=causal,
-                  sm_scale=scale).transpose(0, 2, 1, 3)
+        if impl == "ring":
+            if window is not None:
+                # its chunks meet as whole blocks, gated by the ONE
+                # diagonal; a window would cut some of them short
+                raise ValueError("ring attention knows no window: a "
+                                 "windowed layer takes ulysses or flash")
+            out = ring_attention(qt, kt, vt, mesh, causal=causal,
+                                 sm_scale=scale)
+        else:
+            out = ulysses_attention(qt, kt, vt, mesh, causal=causal,
+                                    sm_scale=scale, window=window)
+        return out.transpose(0, 2, 1, 3)
     if mesh is not None and mesh.size > 1 and _on_tpu():
         # the Pallas kernels need a shard_map on a multi-device mesh; the
         # jnp reference off-TPU is partitioned by GSPMD like any other op
@@ -99,6 +153,6 @@ def attend(q, k, v, cfg, causal: bool = True):
 
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         return sharded_flash_attention(
-            qt, kt, vt, mesh, causal=causal,
-            sm_scale=scale).transpose(0, 2, 1, 3)
-    return mha(q, k, v, causal=causal, sm_scale=scale)
+            qt, kt, vt, mesh, causal=causal, sm_scale=scale,
+            window=window).transpose(0, 2, 1, 3)
+    return mha(q, k, v, causal=causal, sm_scale=scale, window=window)

@@ -4,7 +4,9 @@ Two fields of `LlamaConfig` reach the Llama-shaped MoE families through
 the same block (OLMoE: both): `moe` puts `models/moe.py`'s expert layer
 in every block's feed-forward slot (expert width `intermediate_size`),
 `qk_norm` an RMSNorm over the whole q and k projections before the split
-into heads and before RoPE.
+into heads and before RoPE.  `attn_window` makes the attention a sliding
+window (models/smallthinker.py's local layers), as `attn_scale` and
+`rope` make it Granite's.
 
 Parity: the reference's flagship workloads are GLM/Llama-class LMs via atorch
 (`BASELINE.json` configs: Llama-3 8B auto_accelerate, Llama-3 70B Megatron
@@ -63,6 +65,10 @@ class LlamaConfig:
     # the softmax's scale where it is not 1/sqrt(head size) (Granite's
     # `attention_multiplier`); 0 = 1/sqrt(head size)
     attn_scale: float = 0.0
+    # a sliding window: a query sees this many keys, its own the last
+    # (models/attention.py hands it to the kernels); 0 = every key at or
+    # before it
+    attn_window: int = 0
 
     @classmethod
     def nano(cls):
@@ -91,13 +97,14 @@ class LlamaConfig:
 
     def ffn_params(self) -> int:
         """The feed-forward slot: a SwiGLU, or `moe`'s expert layer — the
-        router over all experts, the experts HELD here, a selection bias,
-        a shared expert — each expert `intermediate_size` wide."""
+        router over all experts, the experts HELD here (three matrices
+        each, relu2 two), a selection bias, a shared expert — each expert
+        `intermediate_size` wide."""
         h, i = self.hidden_size, self.intermediate_size
         if self.moe is None:
             return 3 * h * i
         m = self.moe
-        mats = 3 if m.expert_act == "swiglu" else 2
+        mats = 2 if m.expert_act == "relu2" else 3
         return (m.num_experts * h + m.held * mats * h * i
                 + (m.num_experts if m.selection_bias else 0)
                 + 2 * h * m.shared_width)
@@ -157,7 +164,13 @@ class LlamaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, cos, sin):
-        from .attention import attend, attend_projected, goes_direct
+        from .attention import (
+            attend,
+            attend_projected,
+            goes_direct,
+            window_tiles,
+        )
+        from ..ops.flash_attention import _kept_mask
         from .fp8 import dense
 
         cfg = self.config
@@ -188,6 +201,10 @@ class LlamaAttention(nn.Module):
         if rep > 1:  # GQA: repeat kv heads
             k, v = (jnp.repeat(t.reshape(B, T, cfg.num_kv_heads, hd), rep,
                                axis=2).reshape(q.shape) for t in (k, v))
+        tiles = window_tiles(cfg, B, cfg.num_heads, T)
+        if tiles is not None:  # counted, not timed (static numbers)
+            self.sow("intermediates", "attn_tiles",
+                     jnp.asarray(tiles, jnp.float32))
         if direct:
             y = attend_projected((q, k, v), cfg.num_heads, cfg, causal=True)
         elif cfg.use_flash_attention:
@@ -196,8 +213,8 @@ class LlamaAttention(nn.Module):
             att = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
             att = att * cfg.attn_scale if cfg.attn_scale else \
                 att / jnp.sqrt(jnp.float32(hd))
-            mask = jnp.tril(jnp.ones((T, T), bool))
-            att = jnp.where(mask, att, -jnp.inf)
+            att = jnp.where(_kept_mask(T, T, cfg.attn_window or None), att,
+                            -jnp.inf)
             att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
             y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
         y = y.reshape(B, T, cfg.num_heads * hd)

@@ -37,6 +37,15 @@ chips that hold them or for the exchange with them.  Such a layer also
 sows `moe_rows_held` and `moe_rows_absent` (assignments to experts held
 / not held here); an assignment to an absent expert is not a drop.
 
+An expert is three matrices under a gate — SwiGLU `(silu(x Wg) * (x Wi))
+Wd` or ReGLU `(relu(x Wg) * (x Wi)) Wd` — or relu^2's two
+(`MoEConfig.expert_act`; the gated forms share every line of the grouped
+path, `grouped_experts(gate_act=...)`, and the capacity path knows
+SwiGLU alone).  The router reads the experts' own input unless the block
+hands it another (`MoEMLP(x, router_input=h)`: a router placed before
+the block's attention reads the block's normalised input; its product
+stays under the scope `moe/router`).
+
 Which kernels run the grouped products is `ops/grouped_matmul.gmm_route`'s
 to say, from the call's shapes, mesh and backend: a whole layer fills
 its T*k-row buffer and keeps `lax.ragged_dot` (the TPU compiler's own
@@ -111,8 +120,9 @@ class MoEConfig:
     bias_update_rate: float = 0.0
     # the k gates are multiplied by this after normalisation
     routed_scaling: float = 1.0
-    # an expert's form: "swiglu" (silu(x Wg) * (x Wi)) Wd | "relu2"
-    # relu(x Wi)^2 Wd, no gate matrix
+    # an expert's form: "swiglu" (silu(x Wg) * (x Wi)) Wd | "reglu"
+    # (relu(x Wg) * (x Wi)) Wd, the same three matrices under another
+    # gate | "relu2" relu(x Wi)^2 Wd, no gate matrix
     expert_act: str = "swiglu"
     # width of one always-on relu2 expert beside the routed ones, on
     # every token (scope `shared`; with expert_act="relu2"); 0 = none
@@ -138,6 +148,10 @@ class MoEConfig:
         if self.aux_loss == "none":
             off["aux_loss"] = "none"
         return off
+
+
+# the gate of a three-matrix expert, by MoEConfig.expert_act
+_GATE_ACTS = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
 
 
 def top_k_gating(logits: jax.Array, k: int, capacity: int,
@@ -289,14 +303,15 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
                     w_gate: Optional[jax.Array], w_in: jax.Array,
                     w_down: jax.Array, first_expert: int = 0,
-                    num_experts: Optional[int] = None, mesh=None
-                    ) -> Tuple[jax.Array, jax.Array]:
+                    num_experts: Optional[int] = None, mesh=None,
+                    gate_act=jax.nn.silu) -> Tuple[jax.Array, jax.Array]:
     """The dropless expert pass for a routing already made: returns
     (out (T, d), group_sizes (held,)).  Scopes: `dispatch` (sort, its
     inverse, gather), `experts` (grouped matmuls, gating product),
     `combine` (gather through the inverse, weighting, sum over k), and
     their backward passes under the same two (`dispatch`, `combine`
-    above).  `w_gate=None`: relu^2 experts.
+    above).  `w_gate=None`: relu^2 experts; else `gate_act` of the gate
+    matrix's product times the other's (silu: SwiGLU, relu: ReGLU).
 
     The weights hold `held = w_in.shape[0]` experts, numbers
     `first_expert ..` of the `num_experts` that `experts` (T, k) names.
@@ -341,7 +356,7 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
         if w_gate is None:  # relu^2: no gate matrix
             h = jnp.square(jax.nn.relu(grouped(xs, w_in)))
         else:
-            h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_in)
+            h = gate_act(grouped(xs, w_gate)) * grouped(xs, w_in)
         # (T*k, d); no mask here: `combine` reads the held rows alone
         ys = grouped_matmul(h, w_down, group_sizes, num_experts, mesh)
     with jax.named_scope("combine"):
@@ -400,9 +415,11 @@ def _aux_loss(cfg: MoEConfig, logits, probs, experts):
 
 
 class MoEMLP(nn.Module):
-    """Drop-in MLP replacement: router + stacked experts (SwiGLU, or
-    relu^2 without a gate matrix), and where `shared_width` is set one
-    always-on relu^2 expert beside them.
+    """Drop-in MLP replacement: router + stacked experts (SwiGLU or
+    ReGLU, or relu^2 without a gate matrix), and where `shared_width` is
+    set one always-on relu^2 expert beside them.  The router reads the
+    experts' own input unless it is handed another (`router_input`: a
+    block whose router sits before its attention).
 
     Expert weights are (held, d, h)/(held, h, d) so the `ep` mesh axis
     shards the leading dim (MOE_RULES in parallel/sharding.py);
@@ -421,7 +438,7 @@ class MoEMLP(nn.Module):
     # `<module>._helper`, in the middle of the scopes above
     @nn.compact
     @jax.named_scope("moe")
-    def __call__(self, x):  # x: (B, T, d)
+    def __call__(self, x, router_input=None):  # both (B, T, d)
         cfg = self.moe
         if cfg.impl != "grouped" and cfg.grouped_only_fields():
             raise ValueError(
@@ -443,13 +460,15 @@ class MoEMLP(nn.Module):
 
         router = nn.Dense(cfg.num_experts, use_bias=False,
                           dtype=jnp.float32, name="router")
-        logits = router(tokens.astype(jnp.float32))
+        routed_on = tokens if router_input is None \
+            else router_input.reshape(B * T, d)
+        logits = router(routed_on.astype(jnp.float32))
 
         w_in = self.param(
             "experts_w_in", nn.initializers.normal(0.02),
             (cfg.held, d, self.ffn)).astype(cfg.dtype)
         w_gate = None
-        if cfg.expert_act == "swiglu":
+        if cfg.expert_act in _GATE_ACTS:
             w_gate = self.param(
                 "experts_w_gate", nn.initializers.normal(0.02),
                 (cfg.held, d, self.ffn)).astype(cfg.dtype)
@@ -499,7 +518,8 @@ class MoEMLP(nn.Module):
             out, counts = grouped_experts(
                 tokens, gates, experts, w_gate, w_in, w_out,
                 first_expert=cfg.first_expert, num_experts=cfg.num_experts,
-                mesh=cfg.mesh)
+                mesh=cfg.mesh,
+                gate_act=_GATE_ACTS.get(cfg.expert_act, jax.nn.silu))
         else:
             combine, dispatch = top_k_gating(logits, cfg.top_k, capacity)
             # dispatch: (T, E, C) x (T, d) -> (E, C, d)

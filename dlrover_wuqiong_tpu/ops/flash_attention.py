@@ -1,4 +1,5 @@
-"""Flash attention for TPU: Pallas kernels (fwd + bwd) with online softmax.
+"""Flash attention for TPU: Pallas kernels (fwd + bwd) with online softmax,
+causal or not, under a sliding window or not.
 
 Parity: reference flash-attn integrations — atorch
 `modules/transformer/layers.py:1167` (`flash_attn_with_mask_bias`,
@@ -28,6 +29,26 @@ Design (FA2 scheme, canonical Mosaic structure):
   all sit at offset 0); block_q != block_k or a ragged offset keeps the
   whole-block mask by grid position.  A non-causal call is the whole-block
   program, untouched.
+- a WINDOW is a second diagonal (`window=` on every entry; a query at i
+  sees key j iff 0 <= i + (sk - sq) - j < window): what is kept is a
+  band, not a triangle.  Where the grid places the diagonals (square
+  blocks, sq - sk a multiple of them) the swept grid axis is NARROWED to
+  the blocks a query block (for dk/dv: a key block) can see
+  (`_window_plan`, `_sweep_place`, `_swept`): a block wholly below the
+  window is no grid step, is not fetched and costs nothing; the block on
+  the causal diagonal and the one or two the window's diagonal crosses
+  run the tiles it leaves, masked only where crossed (`_window_tiles`,
+  the windowed twin of `_causal_bands`); the blocks between run whole.
+  At T = 16,384, window 4,096, that is 4.5 blocks' work in 5 grid steps
+  a query block where a causal call runs 8.5 on average.  Blocks the
+  grid cannot place keep the full grid and a whole-block mask under both
+  diagonals.  A windowed call's kernels are named `dwt_fa_win_*` (the
+  prefix `dwt_fa_` keeps them in every reader of the kernels' time, the
+  rest lets one take them alone); `causal_tile_count(window=...)` stays
+  the counter of what they compute.  A call without a window — or with
+  one no shorter than the keys — traces the program it did before any
+  of this existed (pinned: tests/test_flash_attention_tiles.py).  The
+  jnp paths off the TPU take the same window (`_kept_mask`).
 - backward: two kernels — dq (grid: q outer, kv inner) and dk/dv (grid: kv
   outer, q inner) — each recomputing p = exp(s - lse) per tile IN
   TRANSPOSED SPACE (queries in lanes) so the (sq, sk) attention matrix
@@ -228,17 +249,22 @@ def _causal_bands_t(block_q: int, block_k: int, off: int,
 
 
 def causal_tile_count(sq: int, sk: int, block_q: int = 1024,
-                      block_k: int = 1024, tile: Optional[int] = None):
+                      block_k: int = 1024, tile: Optional[int] = None,
+                      window: Optional[int] = None):
     """(score tiles a causal call computes, tiles in its sq x sk square),
     in tiles of `_causal_tile`'s side (whole blocks where it gives none).
 
     Static, like the decision itself: grid blocks above the diagonal
     never run, blocks below it run whole, and a block the diagonal
-    crosses runs the tiles of `_causal_bands`."""
+    crosses runs the tiles of `_causal_bands`.  With a `window` (a query
+    sees the `window` keys that end at its own) the blocks wholly below
+    the window never run either, and a block either diagonal crosses
+    runs the tiles of `_window_tiles`."""
     block_q = _fit_block(sq, block_q) or sq
     block_k = _fit_block(sk, block_k) or sk
     tile = _causal_tile(block_q, block_k, tile)
     num_q, num_kv, kv_offset = sq // block_q, sk // block_k, sk - sq
+    window = _effective_window(window, True, sk)
     off = _diag_offset(num_q, num_kv, block_q, block_k, kv_offset)
     if not tile or off is None:
         tq, tk, off = block_q, block_k, None  # count whole blocks
@@ -251,7 +277,12 @@ def causal_tile_count(sq: int, sk: int, block_q: int = 1024,
             lo = qi * block_q + kv_offset - ki * block_k  # r - c at (0, 0)
             if lo + block_q <= 0:        # run is False: above the diagonal
                 continue
-            if lo >= block_k - 1 or off is None:  # below it, or kept whole
+            if window is not None:
+                if lo - (block_k - 1) >= window:    # below the window
+                    continue
+                done += per_block if off is None else len(_window_tiles(
+                    block_q, block_k, lo, window, tile)[0])
+            elif lo >= block_k - 1 or off is None:  # below it, or kept whole
                 done += per_block
             else:
                 done += sum((q1 - q0) // tq * (k_end // tk) for
@@ -260,9 +291,92 @@ def causal_tile_count(sq: int, sk: int, block_q: int = 1024,
     return done, num_q * num_kv * per_block
 
 
+def _effective_window(window: Optional[int], causal: bool,
+                      sk: int) -> Optional[int]:
+    """The window the kernels are handed: None where the call has none
+    or it leaves no key out that the causal mask keeps (a query's
+    farthest key lies sk - 1 behind it), so that such a call is the
+    causal program, kernel names and all."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"a window ({window}) is at least one key wide "
+                         f"and belongs to a causal call")
+    return None if window >= sk else window
+
+
+def _window_tiles(block_q: int, block_k: int, rel: int, window: int,
+                  tile: Optional[int]):
+    """({(q0, k0): (crossed by the causal diagonal, crossed by the
+    window's)}, tile rows, tile columns): the score tiles of one block
+    that hold a kept entry, when local (r, c) is kept iff
+    0 <= r - c + rel < window (`rel` is the block's query position less
+    its key position, bottom-right aligned).  A tile neither diagonal
+    crosses is computed unmasked, a tile of no kept entry is not in the
+    dict and never computed.  Without a tile the block is one.
+
+    THE source of what the windowed kernels compute, as `_causal_bands`
+    is of the causal ones: `_window_work` builds their loops from it and
+    `causal_tile_count` sums it."""
+    tq, tk = (tile, tile) if tile else (block_q, block_k)
+    tiles = {}
+    for q0 in range(0, block_q, tq):
+        for k0 in range(0, block_k, tk):
+            lo = q0 - (k0 + tk - 1) + rel       # least r - c + rel
+            hi = q0 + tq - 1 - k0 + rel         # largest
+            if hi >= 0 and lo < window:
+                tiles[q0, k0] = (lo < 0, hi >= window)
+    return tiles, tq, tk
+
+
+def _window_work(by_keys: bool, transposed: bool, block_q: int,
+                 block_k: int, rel: int, window: int,
+                 tile: Optional[int]):
+    """`_block_work`'s bands for a block a window's diagonal (or both
+    diagonals) crosses, from `_window_tiles`: neighbouring tiles of one
+    kind are one piece, masked by the diagonals that cross them and by
+    no other."""
+    tiles, tq, tk = _window_tiles(block_q, block_k, rel, window, tile)
+    masks = {}
+
+    def mask(q_lo, q_hi, k_lo, k_hi, upper, lower):
+        if not (upper or lower):
+            return None
+        key = (q_hi - q_lo, k_hi - k_lo, rel + q_lo - k_lo, upper, lower)
+        if key not in masks:
+            nq, nk, delta = key[:3]
+            kept = _rel_mask(nq, nk, delta, transposed) if upper else None
+            if lower:  # r - c + delta < window
+                inside = jnp.logical_not(
+                    _rel_mask(nq, nk, delta - window, transposed))
+                kept = inside if kept is None else kept & inside
+            masks[key] = kept
+        return masks[key]
+
+    n_band, t_band, n_piece, t_piece = (
+        (block_k, tk, block_q, tq) if by_keys else (block_q, tq, block_k, tk))
+    work = []
+    for b0 in range(0, n_band, t_band):
+        runs = []  # [lo, hi, kind] of neighbouring tiles of one kind
+        for p0 in range(0, n_piece, t_piece):
+            kind = tiles.get((p0, b0) if by_keys else (b0, p0))
+            if kind is None:
+                continue
+            if runs and runs[-1][1] == p0 and runs[-1][2] == kind:
+                runs[-1][1] = p0 + t_piece
+            else:
+                runs.append([p0, p0 + t_piece, kind])
+        work.append((b0, b0 + t_band, [
+            (lo, hi, mask(lo, hi, b0, b0 + t_band, *kind) if by_keys
+             else mask(b0, b0 + t_band, lo, hi, *kind))
+            for lo, hi, kind in runs]))
+    return work
+
+
 def _block_work(mask_block: bool, by_keys: bool, transposed: bool,
                 block_q: int, block_k: int, diag_off: Optional[int],
-                tile: Optional[int], qi, ki, kv_offset: int):
+                tile: Optional[int], qi, ki, kv_offset: int,
+                window: Optional[int] = None, rel: Optional[int] = None):
     """What a kernel computes of its resident block, as
     [(lo, hi, [(piece_lo, piece_hi, mask | None), ...]), ...]: bands of
     queries whose pieces are key ranges (forward, dq), or with `by_keys`
@@ -272,10 +386,22 @@ def _block_work(mask_block: bool, by_keys: bool, transposed: bool,
     unmasked piece: the program it always was.  A block on it is the
     tiles of `_causal_bands`, only the pieces the diagonal crosses
     masked — or, where no static offset exists (`diag_off` None), one
-    whole-block piece masked by its grid position (qi, ki)."""
+    whole-block piece masked by its grid position (qi, ki).  With a
+    `window` a masked block is one either diagonal of the window
+    crosses: `_window_work`'s tiles at its static place `rel`, or
+    without one (`rel` None) the whole block under both diagonals by its
+    grid position."""
     n_band, n_piece = (block_k, block_q) if by_keys else (block_q, block_k)
     if not mask_block:
         return [(0, n_band, [(0, n_piece, None)])]
+    if window is not None and rel is not None:
+        return _window_work(by_keys, transposed, block_q, block_k, rel,
+                            window, tile)
+    if window is not None:
+        pos = qi * block_q + kv_offset - ki * block_k
+        return [(0, n_band, [(0, n_piece, _rel_mask(
+            block_q, block_k, pos, transposed) & jnp.logical_not(_rel_mask(
+                block_q, block_k, pos - window, transposed)))])]
     if diag_off is None:
         return [(0, n_band, [(0, n_piece, _rel_mask(
             block_q, block_k, qi * block_q + kv_offset - ki * block_k,
@@ -319,6 +445,90 @@ def _rows(lo: int, hi: int, n: int):
 def _lanes(lo: int, hi: int, n: int):
     """The same for the (1, n) lse / delta rows: a lane slice."""
     return () if (lo, hi) == (0, n) else (slice(None), slice(lo, hi))
+
+
+# ------------------------------------------- a window: a second diagonal
+#
+# A windowed call (query i sees key j iff 0 <= i + (sk - sq) - j <
+# window) keeps a band of blocks, not a triangle.  Where the grid alone
+# places the diagonals (`_diag_offset`: square blocks, sq - sk a
+# multiple of them) a block's class is its distance d = qi + koff - ki
+# from the causal diagonal, so the grid's swept axis is NARROWED to the
+# d_max + 1 blocks a query block (a key block) can see and walks them by
+# d: a block wholly below the window is no grid step at all, is not
+# fetched and costs nothing; d = 0 and the one or two d the window's
+# diagonal crosses run `_window_work`'s tiles at their static place
+# d * block, every d between runs whole and unmasked.
+
+
+def _window_plan(window: Optional[int], num_q: int, num_kv: int,
+                 block_q: int, block_k: int, kv_offset: int) -> dict:
+    """The kernels' static `window` and where its diagonals lie (the
+    other keywords of `_sweep_place`); nothing for a call without one,
+    whose kernels are the causal program."""
+    if window is None:
+        return {}
+    if num_q == 1 and num_kv == 1:  # one block, wherever its diagonals
+        return {"window": window, "crossed": ((0, kv_offset),)}
+    if _diag_offset(num_q, num_kv, block_q, block_k, kv_offset) is None:
+        return {"window": window}  # whole blocks, masked by grid position
+    d_max = (window + block_q - 2) // block_q
+    inside = [d for d in range(1, d_max + 1) if (d + 1) * block_q <= window]
+    return {"window": window, "steps": d_max + 1,
+            "koff": kv_offset // block_q,
+            "crossed": tuple((d, d * block_q) for d in range(d_max + 1)
+                             if d not in inside),
+            "whole": (inside[0], inside[-1]) if inside else None}
+
+
+def _sweep_place(outer, step, by_keys: bool, block_q: int, block_k: int,
+                 kv_offset: int, window: int, steps: Optional[int] = None,
+                 koff: int = 0, limit: int = 0, crossed=None, whole=None):
+    """(the swept axis' block, whether it runs, `_window_blocks`' place)
+    of a windowed kernel's grid step: `outer` is the query block and the
+    sweep walks its key blocks from the farthest to its own, or with
+    `by_keys` the key block and its query blocks from its own to the
+    farthest.  Without `steps` the grid is not narrowed and `step` is
+    the block itself."""
+    d, pos = None, step
+    if steps is not None:
+        d = step if by_keys else steps - 1 - step
+        pos = outer - koff + d if by_keys else outer + koff - d
+    qi, ki = (pos, outer) if by_keys else (outer, pos)
+    rel = qi * block_q + kv_offset - ki * block_k
+    run = (rel + block_q > 0) & (rel - (block_k - 1) < window)
+    if steps is not None:
+        run = run & (pos >= 0) & (pos < limit)
+    return pos, run, (d, rel, block_q, block_k, window, crossed, whole)
+
+
+def _window_blocks(inner, run, d, rel, block_q: int, block_k: int,
+                   window: int, crossed, whole) -> None:
+    """`inner(masked, static place)` for the resident block of a
+    windowed call, by its class (`_window_plan`)."""
+    if crossed is None:  # no static place: by grid position
+        inside = (rel - (block_k - 1) >= 0) & (rel + block_q - 1 < window)
+        pl.when(run & inside)(functools.partial(inner, False))
+        pl.when(run & jnp.logical_not(inside))(functools.partial(inner, True))
+        return
+    for at, place in crossed:
+        pl.when(run if d is None else run & (d == at))(
+            functools.partial(inner, True, place))
+    if whole:
+        pl.when(run & (d >= whole[0]) & (d <= whole[1]))(
+            functools.partial(inner, False))
+
+
+def _swept(steps: int, koff: int, limit: int, by_keys: bool):
+    """The block of the swept array a narrowed grid step reads, as a
+    function of the grid indices after the first: `_sweep_place`'s,
+    held inside the array (a step outside it does not run, and a block
+    index that repeats is not fetched again)."""
+    def at(ij):
+        pos = ij[0] - koff + ij[1] if by_keys \
+            else ij[0] + koff - (steps - 1 - ij[1])
+        return jnp.clip(pos, 0, limit - 1)
+    return at
 
 
 # heads per iteration of the loop over a tiled block's packed heads: pairs
@@ -429,7 +639,9 @@ def _fold(op, xs):
     whole one (PERF.md section 6, PR 27)."""
     if len(xs) == 1:
         return xs[0]
-    w = min(x.shape[1] for x in xs)
+    # the widest slice every piece is a multiple of (the narrowest piece,
+    # wherever the pieces are one tile and a run of tiles)
+    w = math.gcd(*[x.shape[1] for x in xs])
     return functools.reduce(op, [x[:, j:j + w] for x in xs
                                  for j in range(0, x.shape[1], w)])
 
@@ -438,9 +650,11 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                    num_kv: int, causal: bool, sm_scale: float,
                    block_q: int, block_k: int, kv_offset: int, pack: int,
                    diag_off: Optional[int] = None,
-                   tile: Optional[int] = None, slab_heads: int = 1):
+                   tile: Optional[int] = None, slab_heads: int = 1,
+                   window: Optional[int] = None, **sweep):
     """Packed forward: refs carry `pack` units in the leading dim, each a
-    head or a slab of `slab_heads` heads (above).
+    head or a slab of `slab_heads` heads (above).  `window`, `sweep`: a
+    windowed call's place in its narrowed grid (`_sweep_place`).
 
     Leading-dim indexing (ref[hh]) is a free address offset (unlike lane
     slicing), so packing amortizes per-grid-step fixed costs and generates
@@ -460,7 +674,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     scratch) and its o and lse are written where they are computed.
     """
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)
     single = num_kv == 1  # whole KV sweep in one step: no online state
     stateless = not scratch
     m_scr, l_scr, acc_scr = scratch or (
@@ -468,7 +682,10 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     width = o_ref.shape[-1]
     heads = range(slab_heads)
 
-    if causal:
+    if window is not None:
+        ki, run, place = _sweep_place(qi, step, False, block_q, block_k,
+                                      kv_offset, window, **sweep)
+    elif causal:
         # block fully masked when its first key exceeds the last query's reach
         run = (qi + 1) * block_q + kv_offset > ki * block_k
     else:
@@ -502,7 +719,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             lse_ref[(head,) + lanes] = lse.T
 
     if not stateless and not single:
-        @pl.when(ki == 0)
+        @pl.when(step == 0)
         def _init():
             m_scr[...] = jnp.full_like(m_scr, NEG_INF)
             l_scr[...] = jnp.zeros_like(l_scr)
@@ -516,9 +733,9 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 _empty(hh, (), block_q)
                 _finish(hh, (), ())
 
-    def _inner(mask_block: bool):
+    def _inner(mask_block: bool, rel: Optional[int] = None):
         work = _block_work(mask_block, False, False, block_q, block_k,
-                           diag_off, tile, qi, ki, kv_offset)
+                           diag_off, tile, qi, ki, kv_offset, window, rel)
 
         def _unit(hh):
             for q0, q1, pieces in work:
@@ -586,7 +803,9 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             acc_scr[acc] = acc_scr[acc] * _join(alphas, width) + _join(
                 [pv() for pv in pvs], width)
 
-    if causal:
+    if window is not None:
+        _window_blocks(_inner, run, *place)
+    elif causal:
         # only blocks straddling the diagonal pay for mask generation
         diag = (qi * block_q + kv_offset < (ki + 1) * block_k) & run
 
@@ -604,7 +823,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             _inner(False)
 
     if not stateless:
-        @pl.when(ki == num_kv - 1)
+        @pl.when(step == num_kv - 1)
         def _finalize():
             for hh in range(pack):
                 _finish(hh, (), ())
@@ -653,6 +872,8 @@ def _block_specs(slabs: Optional[_Slabs], pack: int, d: int):
     batch row of (b, s, lanes) arrays; lse and delta are (bh, 1, s) in
     both."""
     def at(ij, axis):
+        if callable(axis):  # a windowed call's narrowed sweep (`_swept`)
+            return axis(ij)
         return 0 if axis is None else ij[axis]
 
     def operand(block, axis, first_slab=0):
@@ -687,13 +908,15 @@ def _geometry(q, slabs: Optional[_Slabs]):
 def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
                        block_q: int, block_k: int, interpret: bool,
                        tile: Optional[int] = None,
-                       slabs: Optional[_Slabs] = None):
+                       slabs: Optional[_Slabs] = None,
+                       window: Optional[int] = None):
     """q: (bh, sq, d), k/v: (bh, sk, d) → (o, lse (bh, 1, sq) f32); with
     `slabs`, q/k/v: (b, s, lanes) as `_Slabs` says → (o (b, sq, h*d),
     lse (b*h, 1, sq)).
 
     `tile` overrides `_causal_tile` (tests and sweeps: a tile the size of
     the block is the whole-block mask); no caller of the package sets it.
+    `window`: `_effective_window`'s (None: the causal program).
     """
     sq, sk = q.shape[1], k.shape[1]
     bh, d, pack, groups, lanes = _geometry(q, slabs)
@@ -701,20 +924,25 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
     qo, ko, vo = slabs.offsets if slabs else (0, 0, 0)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    num_kv = sk // block_k
+    num_kv, keys = sk // block_k, 1  # keys: the grid axis that walks them
+    win = _window_plan(window, sq // block_q, num_kv, block_q, block_k,
+                       sk - sq)
+    if "steps" in win:  # the key blocks a query block sees, not all
+        win["limit"], num_kv = num_kv, win["steps"]
+        keys = _swept(num_kv, win["koff"], win["limit"], False)
     grid = (groups, sq // block_q, num_kv)
     operand, row = _block_specs(slabs, pack, d)
 
     kernel = functools.partial(
         _fa_fwd_kernel, num_kv=num_kv, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, kv_offset=sk - sq, pack=pack,
-        **_causal_plan(causal, sq // block_q, num_kv, block_q, block_k,
-                       sk - sq, tile), **_slab_heads(slabs))
+        **_causal_plan(causal, sq // block_q, sk // block_k, block_q,
+                       block_k, sk - sq, tile), **_slab_heads(slabs), **win)
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[operand(block_q, 0, qo), operand(block_k, 1, ko),
-                  operand(block_k, 1, vo)],
+        in_specs=[operand(block_q, 0, qo), operand(block_k, keys, ko),
+                  operand(block_k, keys, vo)],
         out_specs=(operand(block_q, 0), row(block_q, 0)),
         out_shape=(
             _out_struct(q.shape[:2] + (lanes,), q.dtype, q),
@@ -730,9 +958,16 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          vmem_limit=100 * 1024 * 1024),
         interpret=interpret,
-        name="dwt_fa_fwd",
+        name=_kernel_name("fwd", window),
     )(q, k, v)
     return o, lse
+
+
+def _kernel_name(which: str, window: Optional[int]) -> str:
+    """`dwt_fa_<which>`, or `dwt_fa_win_<which>` for a windowed call: the
+    prefix every reader of the kernels' time takes, and one by which a
+    reader takes the windowed calls alone."""
+    return f"dwt_fa_{'win_' if window is not None else ''}{which}"
 
 
 def _slab_heads(slabs: Optional[_Slabs]) -> dict:
@@ -782,25 +1017,29 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       kv_offset: int, pack: int,
                       diag_off: Optional[int] = None,
                       tile: Optional[int] = None, slab_heads: int = 1,
-                      delta_from_o: bool = False):
+                      delta_from_o: bool = False,
+                      window: Optional[int] = None, **sweep):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    ki = step = pl.program_id(2)
     heads = range(slab_heads)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    if causal:
+    if window is not None:
+        ki, run, place = _sweep_place(qi, step, False, block_q, block_k,
+                                      kv_offset, window, **sweep)
+    elif causal:
         run = (qi + 1) * block_q + kv_offset > ki * block_k
     else:
         run = True
 
-    def _inner(mask_block: bool):
+    def _inner(mask_block: bool, rel: Optional[int] = None):
         # by query tile, like the forward: each dq tile is added to once
         # per piece, its keys the prefix the tile sees
         work = _block_work(mask_block, False, True, block_q, block_k,
-                           diag_off, tile, qi, ki, kv_offset)
+                           diag_off, tile, qi, ki, kv_offset, window, rel)
         def _unit(hh):
             for q0, q1, pieces in work:
                 rows = (hh,) + _rows(q0, q1, block_q)
@@ -826,7 +1065,9 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         _each_head(pack, work, _unit)
 
-    if causal:
+    if window is not None:
+        _window_blocks(_inner, run, *place)
+    elif causal:
         diag = (qi * block_q + kv_offset < (ki + 1) * block_k) & run
 
         @pl.when(diag)
@@ -842,7 +1083,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         def _compute():
             _inner(False)
 
-    @pl.when(ki == num_kv - 1)
+    @pl.when(step == num_kv - 1)
     def _finalize():
         for hh in range(pack):
             dq_ref[hh] = dq_scr[hh].astype(dq_ref.dtype)
@@ -854,25 +1095,30 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        block_k: int, kv_offset: int, pack: int,
                        diag_off: Optional[int] = None,
                        tile: Optional[int] = None, slab_heads: int = 1,
-                       delta_from_o: bool = False):
+                       delta_from_o: bool = False,
+                       window: Optional[int] = None, **sweep):
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi = step = pl.program_id(2)
     heads = range(slab_heads)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    if causal:
+    if window is not None:
+        qi, run, place = _sweep_place(ki, step, True, block_q, block_k,
+                                      kv_offset, window, **sweep)
+    elif causal:
         run = (qi + 1) * block_q + kv_offset > ki * block_k
     else:
         run = True
 
-    def _inner(mask_block: bool):
-        # by key tile: its queries are the suffix that sees it
+    def _inner(mask_block: bool, rel: Optional[int] = None):
+        # by key tile: its queries are the suffix that sees it (under a
+        # window: the run of queries that do)
         work = _block_work(mask_block, True, True, block_q, block_k,
-                           diag_off, tile, qi, ki, kv_offset)
+                           diag_off, tile, qi, ki, kv_offset, window, rel)
         def _unit(hh):
             for k0, k1, pieces in work:
                 keys = (hh,) + _rows(k0, k1, block_k)
@@ -904,7 +1150,9 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         _each_head(pack, work, _unit)
 
-    if causal:
+    if window is not None:
+        _window_blocks(_inner, run, *place)
+    elif causal:
         diag = (qi * block_q + kv_offset < (ki + 1) * block_k) & run
 
         @pl.when(diag)
@@ -920,7 +1168,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         def _compute():
             _inner(False)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(step == num_q - 1)
     def _finalize():
         for hh in range(pack):
             dk_ref[hh] = dk_scr[hh].astype(dk_ref.dtype)
@@ -933,7 +1181,8 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          kv_offset: int, pack: int,
                          diag_off: Optional[int] = None,
                          tile: Optional[int] = None, slab_heads: int = 1,
-                         delta_from_o: bool = False):
+                         delta_from_o: bool = False,
+                         window: Optional[int] = None):
     """Single-block fused backward: dq, dk AND dv in one pass.
 
     Only legal when the whole sequence fits one block each way (num_q ==
@@ -951,14 +1200,19 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dots a head, each on the tiles at or below the diagonal.
     """
     work = _block_work(causal, True, True, block_q, block_k, diag_off, tile,
-                       0, 0, kv_offset)
+                       0, 0, kv_offset, window,
+                       None if window is None else kv_offset)
     # the first key tile is seen by every query that sees any: it sets
-    # dq's rows, the later tiles add to them
-    seen_from = work[0][2][0][0] if work[0][2] else block_q
+    # dq's rows, the later tiles add to them.  Not under a window, whose
+    # late queries see no early key: dq's rows are zeroed first there
+    seen_from = 0 if window is not None else \
+        work[0][2][0][0] if work[0][2] else block_q
     heads = range(slab_heads)
     width = dq_ref.shape[-1]
 
     def _unit(hh):
+        if dq_scr and window is not None:
+            dq_scr[0][...] = jnp.zeros_like(dq_scr[0])
         for k0, k1, pieces in work:
             keys = (hh,) + _rows(k0, k1, block_k)
             k = dk = dv = None
@@ -995,7 +1249,7 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                             width)                           # (rows, d)
                 if not dq_scr:
                     dq_ref[rows] = dqp.astype(dq_ref.dtype)
-                elif k0 == 0:
+                elif k0 == 0 and window is None:
                     dq_scr[0][q0:q1] = dqp
                 else:
                     dq_scr[0][q0:q1] += dqp
@@ -1016,7 +1270,8 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                         block_q: int, block_k: int, interpret: bool,
                         glse=None, tile: Optional[int] = None,
-                        slabs: Optional[_Slabs] = None):
+                        slabs: Optional[_Slabs] = None,
+                        window: Optional[int] = None):
     """All operands flat (bh, s, d), or with `slabs` (b, s, lanes) as
     `_fa_forward_pallas` takes and gives them; lse (bh, 1, sq) f32.
     Returns dq, dk, dv in o's layout.
@@ -1035,6 +1290,7 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     num_kv = sk // block_k
     plan = dict(_causal_plan(causal, num_q, num_kv, block_q, block_k,
                              kv_offset, tile), **_slab_heads(slabs))
+    win = _window_plan(window, num_q, num_kv, block_q, block_k, kv_offset)
 
     operand, row = _block_specs(slabs, pack, d)
     # delta = rowsum(dO ∘ O) a head — cheap fused reduce; (bh, 1, sq)
@@ -1072,7 +1328,7 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             functools.partial(
                 _fa_bwd_fused_kernel, causal=causal, sm_scale=sm_scale,
                 block_q=block_q, block_k=block_k, kv_offset=kv_offset,
-                pack=pack, **plan),
+                pack=pack, window=window, **plan),
             grid=(groups,),
             in_specs=[operand(block_q, None, qo), operand(block_k, None, ko),
                       operand(block_k, None, vo), operand(block_q, None),
@@ -1086,17 +1342,28 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             compiler_params=_compiler_params(
                 "parallel", vmem_limit=100 * 1024 * 1024),
             interpret=interpret,
-            name="dwt_fa_bwd_fused",
+            name=_kernel_name("bwd_fused", window),
         )(*ops)
 
+    # a windowed call on blocks the grid places sweeps the blocks that
+    # see each other, not all (`_window_plan`): the keys of a query block
+    # for dq, the queries of a key block for dk and dv
+    keys = queries = 1  # the grid axis that walks them
+    dq_steps, dkv_steps, dq_win, dkv_win = num_kv, num_q, win, win
+    if "steps" in win:
+        dq_steps = dkv_steps = win["steps"]
+        dq_win, dkv_win = dict(win, limit=num_kv), dict(win, limit=num_q)
+        keys = _swept(dq_steps, win["koff"], num_kv, False)
+        queries = _swept(dkv_steps, win["koff"], num_q, True)
+
     dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, num_kv=num_kv, causal=causal,
+        functools.partial(_fa_bwd_dq_kernel, num_kv=dq_steps, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, kv_offset=kv_offset, pack=pack,
-                          **plan),
-        grid=(groups, num_q, num_kv),
-        in_specs=[operand(block_q, 0, qo), operand(block_k, 1, ko),
-                  operand(block_k, 1, vo), operand(block_q, 0),
+                          **plan, **dq_win),
+        grid=(groups, num_q, dq_steps),
+        in_specs=[operand(block_q, 0, qo), operand(block_k, keys, ko),
+                  operand(block_k, keys, vo), operand(block_q, 0),
                   row(block_q, 0), delta_spec(block_q, 0)],
         out_specs=operand(block_q, 0),
         out_shape=dq_shape,
@@ -1104,19 +1371,19 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          vmem_limit=100 * 1024 * 1024),
         interpret=interpret,
-        name="dwt_fa_bwd_dq",
+        name=_kernel_name("bwd_dq", window),
     )(*ops)
 
     # dkv grid: kv outer, q inner — same operands, transposed index maps
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, num_q=num_q, causal=causal,
+        functools.partial(_fa_bwd_dkv_kernel, num_q=dkv_steps, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, kv_offset=kv_offset, pack=pack,
-                          **plan),
-        grid=(groups, num_kv, num_q),
-        in_specs=[operand(block_q, 1, qo), operand(block_k, 0, ko),
-                  operand(block_k, 0, vo), operand(block_q, 1),
-                  row(block_q, 1), delta_spec(block_q, 1)],
+                          **plan, **dkv_win),
+        grid=(groups, num_kv, dkv_steps),
+        in_specs=[operand(block_q, queries, qo), operand(block_k, 0, ko),
+                  operand(block_k, 0, vo), operand(block_q, queries),
+                  row(block_q, queries), delta_spec(block_q, queries)],
         out_specs=(operand(block_k, 0), operand(block_k, 0)),
         out_shape=(dk_shape, dv_shape),
         scratch_shapes=[
@@ -1126,7 +1393,7 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          vmem_limit=100 * 1024 * 1024),
         interpret=interpret,
-        name="dwt_fa_bwd_dkv",
+        name=_kernel_name("bwd_dkv", window),
     )(*ops)
     return dq, dk, dv
 
@@ -1134,15 +1401,32 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
 # ----------------------------------------------------------------- reference
 
 
-def _attention_reference(q, k, v, causal: bool, sm_scale: float):
+def _kept_mask(sq: int, sk: int, window: Optional[int] = None):
+    """(sq, sk) bool: query i sees key j iff 0 <= i + (sk - sq) - j, and
+    with a window iff that distance is also < window.  The jnp paths'
+    one mask."""
+    mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
+    return mask
+
+
+def _kept_at(rows, cols, window: Optional[int]):
+    """`_kept_mask` for one block of the streamed paths: `rows` and
+    `cols` are the queries' and keys' absolute key positions."""
+    dist = rows[:, None] - cols[None, :]
+    return dist >= 0 if window is None else (dist >= 0) & (dist < window)
+
+
+def _attention_reference(q, k, v, causal: bool, sm_scale: float,
+                         window: Optional[int] = None):
     """Plain jnp attention — numerics oracle + non-TPU fallback.
 
     q: (b, h, sq, d); k/v: (b, h, sk, d)
     """
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if causal:
-        sq, sk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        mask = _kept_mask(s.shape[-2], s.shape[-1], window)
         s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
@@ -1151,11 +1435,12 @@ def _attention_reference(q, k, v, causal: bool, sm_scale: float):
 # ---------------------------------------------------------------- public API
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024,
-                    bwd_block_q: int = 0, bwd_block_k: int = 0):
+                    bwd_block_q: int = 0, bwd_block_k: int = 0,
+                    window: Optional[int] = None):
     """Multi-head attention, FA2-style.
 
     Args: q (b, h, sq, d); k, v (b, h, sk, d).  Returns (b, h, sq, d).
@@ -1167,9 +1452,12 @@ def flash_attention(q, k, v, causal: bool = True,
     docstring).  `bwd_block_q`/`bwd_block_k` block the dq/dkv backward
     kernels independently (0 = inherit block_q/block_k; no chip run of
     this repository has measured another choice — PERF.md section 6).
+    `window`: a causal call's query sees the `window` keys that end at
+    its own and none before (None, or a window no shorter than the keys:
+    the causal call itself).
     """
     out, _ = _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                     bwd_block_q, bwd_block_k)
+                     bwd_block_q, bwd_block_k, window)
     return out
 
 
@@ -1240,32 +1528,33 @@ def _flat_padded(q, k, v, d_pad):
     return qf, kf, vf
 
 
-def _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k):
+def _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k, window=None):
     """Shared forward: returns ((out, lse_bhs), residuals)."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     scale = _resolve_scale(sm_scale, d)
+    window = _effective_window(window, causal, sk)
     if _use_pallas(sq, sk, d, block_q, block_k):
         bq = _fit_block(sq, block_q)
         bk = _fit_block(sk, block_k)
         d_pad = _kernel_head_dim(d)
         qf, kf, vf = _flat_padded(q, k, v, d_pad)
         o, lse = _fa_forward_pallas(qf, kf, vf, causal, scale, bq, bk,
-                                    interpret=False)
+                                    interpret=False, window=window)
         out = o[:, :, :d].reshape(b, h, sq, d)
         return (out, lse.reshape(b, h, sq)), (q, k, v, o, lse)
     if _use_streamed(sq, sk):
-        out, lse = _streamed_with_lse(q, k, v, causal, scale, block_k)
+        out, lse = _streamed_with_lse(q, k, v, causal, scale, block_k,
+                                      window)
         return (out, lse), (q, k, v, out, lse)
-    out, lse = _reference_with_lse(q, k, v, causal, scale)
+    out, lse = _reference_with_lse(q, k, v, causal, scale, window)
     return (out, lse), (q, k, v, out, None)
 
 
-def _reference_with_lse(q, k, v, causal, scale):
+def _reference_with_lse(q, k, v, causal, scale, window=None):
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
-        sq, sk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        mask = _kept_mask(s.shape[-2], s.shape[-1], window)
         s = jnp.where(mask, s, -jnp.inf)
     m = jnp.max(s, axis=-1, keepdims=True)
     m = jnp.where(jnp.isfinite(m), m, 0.0)
@@ -1277,7 +1566,7 @@ def _reference_with_lse(q, k, v, causal, scale):
     return o, lse
 
 
-def _streamed_with_lse(q, k, v, causal, scale, block_k):
+def _streamed_with_lse(q, k, v, causal, scale, block_k, window=None):
     """Online-softmax forward as a `lax.scan` over key blocks.
 
     Same math as the Pallas kernel, in plain jnp: peak temps are
@@ -1301,7 +1590,7 @@ def _streamed_with_lse(q, k, v, causal, scale, block_k):
         mask = None
         if causal:
             cols = j * bk + jnp.arange(bk)
-            mask = rows[:, None] >= cols[None, :]
+            mask = _kept_at(rows, cols, window)
             s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(-1))
         alpha = jnp.exp(m - m_new)
@@ -1327,7 +1616,8 @@ def _streamed_with_lse(q, k, v, causal, scale, block_k):
     return out, lse
 
 
-def _streamed_bwd(q, k, v, out, lse, g, causal, scale, block_q, glse):
+def _streamed_bwd(q, k, v, out, lse, g, causal, scale, block_q, glse,
+                  window=None):
     """Flash-style recompute backward as one `lax.scan` over query blocks.
 
     Each step re-derives p for its q block from the stored lse, emits the
@@ -1356,7 +1646,7 @@ def _streamed_bwd(q, k, v, out, lse, g, causal, scale, block_q, glse):
         p = jnp.exp(s - lseblk[..., None])
         if causal:
             rows = i * bq + jnp.arange(bq) + off
-            p = jnp.where(rows[:, None] >= cols[None, :], p, 0.0)
+            p = jnp.where(_kept_at(rows, cols, window), p, 0.0)
         dp = jnp.einsum("bhqd,bhkd->bhqk", gblk, v32)
         ds = p * (dp - dblk[..., None])
         dqblk = jnp.einsum("bhqk,bhkd->bhqd", ds, k32) * scale
@@ -1372,19 +1662,21 @@ def _streamed_bwd(q, k, v, out, lse, g, causal, scale, block_q, glse):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _fa_bwd_impl(causal, sm_scale, block_q, block_k, res, g, glse):
+def _fa_bwd_impl(causal, sm_scale, block_q, block_k, res, g, glse,
+                 window=None):
     """Shared backward; glse (b, h, sq) f32 or None folds the lse cotangent
     into delta (d lse / d s = p, so ds = p * (dp - delta + glse))."""
     q, k, v, out, lse = res
     b, h, sq, d = q.shape
     sk = k.shape[2]
     scale = _resolve_scale(sm_scale, d)
+    window = _effective_window(window, causal, sk)
     if lse is not None and not _use_pallas(sq, sk, d, block_q, block_k):
         # streamed forward ran (lse present, kernels unavailable): its
         # recompute backward — NOT the dense path, which would undo the
         # memory bound the streamed path exists for
         return _streamed_bwd(q, k, v, out, lse, g, causal, scale,
-                             block_q, glse)
+                             block_q, glse, window)
     if lse is not None:  # pallas forward ran: pallas backward
         bq = _fit_block(sq, block_q)
         bk = _fit_block(sk, block_k)
@@ -1394,15 +1686,15 @@ def _fa_bwd_impl(causal, sm_scale, block_q, block_k, res, g, glse):
         glse_f = None if glse is None else glse.reshape(b * h, 1, sq)
         dq, dk, dv = _fa_backward_pallas(qf, kf, vf, out, lse,
                                          gf, causal, scale, bq, bk,
-                                         interpret=False, glse=glse_f)
+                                         interpret=False, glse=glse_f,
+                                         window=window)
         return (dq[:, :, :d].reshape(b, h, sq, d).astype(q.dtype),
                 dk[:, :, :d].reshape(b, h, sk, d).astype(k.dtype),
                 dv[:, :, :d].reshape(b, h, sk, d).astype(v.dtype))
     # jnp recompute fallback (matches _attention_reference numerics)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
-        mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
-        s = jnp.where(mask, s, -jnp.inf)
+        s = jnp.where(_kept_mask(sq, sk, window), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     g32 = g.astype(jnp.float32)
     v32 = v.astype(jnp.float32)
@@ -1418,44 +1710,47 @@ def _fa_bwd_impl(causal, sm_scale, block_q, block_k, res, g, glse):
 
 
 def _fa_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-            bwd_block_q=0, bwd_block_k=0):
-    (out, _), res = _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k)
+            bwd_block_q=0, bwd_block_k=0, window=None):
+    (out, _), res = _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k,
+                                window)
     return out, res
 
 
 def _fa_bwd(causal, sm_scale, block_q, block_k, bwd_block_q, bwd_block_k,
-            res, g):
+            window, res, g):
     return _fa_bwd_impl(causal, sm_scale, bwd_block_q or block_q,
-                        bwd_block_k or block_k, res, g, None)
+                        bwd_block_k or block_k, res, g, None, window)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention_with_lse(q, k, v, causal: bool = True,
                              sm_scale: Optional[float] = None,
                              block_q: int = 1024, block_k: int = 1024,
-                             bwd_block_q: int = 0, bwd_block_k: int = 0):
+                             bwd_block_q: int = 0, bwd_block_k: int = 0,
+                             window: Optional[int] = None):
     """Like `flash_attention` but also returns lse (b, h, sq) f32 — the
     building block for ring/blockwise attention where partial results over
     disjoint key sets merge by logsumexp weights.  Differentiable in both
     outputs (the lse cotangent folds into the delta term)."""
-    (out, lse), _ = _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k)
+    (out, lse), _ = _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k,
+                                window)
     return out, lse
 
 
 def _fa_lse_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                bwd_block_q=0, bwd_block_k=0):
-    return _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k)
+                bwd_block_q=0, bwd_block_k=0, window=None):
+    return _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k, window)
 
 
 def _fa_lse_bwd(causal, sm_scale, block_q, block_k, bwd_block_q,
-                bwd_block_k, res, gs):
+                bwd_block_k, window, res, gs):
     g, glse = gs
     return _fa_bwd_impl(causal, sm_scale, bwd_block_q or block_q,
                         bwd_block_k or block_k, res, g,
-                        glse.astype(jnp.float32))
+                        glse.astype(jnp.float32), window)
 
 
 flash_attention_with_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)
@@ -1513,20 +1808,21 @@ def _projected_slabs(proj, n_head: int) -> Tuple[_Slabs, int]:
     return _Slabs(per_row, heads, lanes // per_row, offsets), d
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
 def flash_attention_projected(proj, n_head: int, causal: bool = True,
-                              sm_scale: Optional[float] = None):
+                              sm_scale: Optional[float] = None,
+                              window: Optional[int] = None):
     """Attention on the projections' own layout: `proj` = (qkv,), one
     (b, s, 3*h*d) array (GPT-2's `c_attn` output, never split), or
     (q, k, v), (b, s, h*d) each → (b, s, h*d), which the output
     projection takes as it is.  The cotangent comes back in the same
     form.  No array is split, reshaped to heads or transposed on the
     way: the kernels' BlockSpecs index the slabs where they lie
-    (`_Slabs`).
+    (`_Slabs`).  `window` as `flash_attention` takes it.
 
     For calls `projected_ok` takes; any other raises ValueError (the
     caller asks first: `models/attention.attend_projected`)."""
-    return _fa_projected_fwd(proj, n_head, causal, sm_scale)[0]
+    return _fa_projected_fwd(proj, n_head, causal, sm_scale, window)[0]
 
 
 def _projected_operands(proj):
@@ -1538,12 +1834,13 @@ def _projected_operands(proj):
 # lowered to Mosaic ONCE a step program, not once a layer (each is a
 # few hundred equations walked in Python).  XLA inlines the calls, so
 # the compiled step is the one it would have been.
-_STATIC = ("causal", "sm_scale", "block_q", "block_k", "interpret", "slabs")
+_STATIC = ("causal", "sm_scale", "block_q", "block_k", "interpret", "slabs",
+           "window")
 _projected_forward = jax.jit(_fa_forward_pallas, static_argnames=_STATIC)
 _projected_backward = jax.jit(_fa_backward_pallas, static_argnames=_STATIC)
 
 
-def _projected_plan(proj, n_head, causal, sm_scale) -> dict:
+def _projected_plan(proj, n_head, causal, sm_scale, window) -> dict:
     slabs, d = _projected_slabs(proj, n_head)
     seq = proj[0].shape[1]
     if not projected_ok(n_head, d, seq):
@@ -1551,22 +1848,24 @@ def _projected_plan(proj, n_head, causal, sm_scale) -> dict:
             f"no direct kernel off the TPU or at a sequence of {seq}: "
             f"take flash_attention on (b, h, s, d)")
     block = _fit_block(seq, _PROJECTED_BLOCK)
-    return dict(causal=causal, sm_scale=_resolve_scale(sm_scale, d),
+    plan = dict(causal=causal, sm_scale=_resolve_scale(sm_scale, d),
                 block_q=block, block_k=block, interpret=False, slabs=slabs)
+    window = _effective_window(window, causal, seq)
+    return plan if window is None else dict(plan, window=window)
 
 
-def _fa_projected_fwd(proj, n_head, causal, sm_scale):
+def _fa_projected_fwd(proj, n_head, causal, sm_scale, window=None):
     o, lse = _projected_forward(
         *_projected_operands(proj),
-        **_projected_plan(proj, n_head, causal, sm_scale))
+        **_projected_plan(proj, n_head, causal, sm_scale, window))
     return o, (proj, o, lse)
 
 
-def _fa_projected_bwd(n_head, causal, sm_scale, res, g):
+def _fa_projected_bwd(n_head, causal, sm_scale, window, res, g):
     proj, o, lse = res
     grads = _projected_backward(
         *_projected_operands(proj), o, lse, g,
-        **_projected_plan(proj, n_head, causal, sm_scale))
+        **_projected_plan(proj, n_head, causal, sm_scale, window))
     if len(proj) == 1:  # c_attn's cotangent: dq, dk, dv side by side
         return ((jnp.concatenate(grads, axis=-1),),)
     return (tuple(grads),)
@@ -1575,7 +1874,8 @@ def _fa_projected_bwd(n_head, causal, sm_scale, res, g):
 flash_attention_projected.defvjp(_fa_projected_fwd, _fa_projected_bwd)
 
 
-def mha(q, k, v, causal: bool = True, sm_scale: Optional[float] = None):
+def mha(q, k, v, causal: bool = True, sm_scale: Optional[float] = None,
+        window: Optional[int] = None):
     """`flash_attention` on the (b, s, h, d) layout (flax convention): the
     transposed route, for callers whose heads do not fall on slab
     boundaries (`attention_route`) or that hold q, k and v by head
@@ -1587,5 +1887,5 @@ def mha(q, k, v, causal: bool = True, sm_scale: Optional[float] = None):
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    out = flash_attention(qt, kt, vt, causal, sm_scale)
+    out = flash_attention(qt, kt, vt, causal, sm_scale, window=window)
     return out.transpose(0, 2, 1, 3)

@@ -70,7 +70,8 @@ def _qkv_spec(mesh: Mesh, seq_axis: Optional[str], batch_size: int) -> P:
 
 
 def sharded_flash_attention(q, k, v, mesh: Mesh, causal: bool = True,
-                            sm_scale: Optional[float] = None):
+                            sm_scale: Optional[float] = None,
+                            window: Optional[int] = None):
     """`flash_attention` for (b, h, S, d) operands that live on a mesh of
     more than one device.
 
@@ -80,14 +81,16 @@ def sharded_flash_attention(q, k, v, mesh: Mesh, causal: bool = True,
     data axes, heads over `tp` when they divide, the sequence whole —
     each device runs the kernel on its own (b_local, h_local) slab, no
     collective.  A sequence sharded over `sp` by GSPMD is gathered at
-    the boundary (ring/ulysses are the paths that keep it sharded)."""
+    the boundary (ring/ulysses are the paths that keep it sharded).  A
+    `window` goes to the kernel as it is: every device holds whole
+    sequences."""
     spec = _qkv_spec(mesh, None, q.shape[0])
     tp = mesh.shape.get("tp", 1)
     if tp > 1 and q.shape[1] % tp == 0:
         spec = P(spec[0], "tp", None, None)
     fn = shard_map(
         functools.partial(flash_attention, causal=causal,
-                          sm_scale=sm_scale),
+                          sm_scale=sm_scale, window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)
 
@@ -188,7 +191,7 @@ def ring_attention(q, k, v, mesh: Mesh, causal: bool = True,
 
 
 def _ulysses_local(q, k, v, *, axis_name: str, causal: bool,
-                   sm_scale: Optional[float]):
+                   sm_scale: Optional[float], window: Optional[int] = None):
     """Per-device body: q/k/v (b, h, s_local, d) → all-to-all to
     (b, h/sp, S, d), full-seq attention, inverse all-to-all."""
     # scatter heads (axis 1), gather sequence (axis 2)
@@ -198,7 +201,7 @@ def _ulysses_local(q, k, v, *, axis_name: str, causal: bool,
                             tiled=True)
     vh = jax.lax.all_to_all(v, axis_name, split_axis=1, concat_axis=2,
                             tiled=True)
-    o = flash_attention(qh, kh, vh, causal, sm_scale)
+    o = flash_attention(qh, kh, vh, causal, sm_scale, window=window)
     # scatter sequence back, gather heads
     return jax.lax.all_to_all(o, axis_name, split_axis=2, concat_axis=1,
                               tiled=True)
@@ -206,15 +209,16 @@ def _ulysses_local(q, k, v, *, axis_name: str, causal: bool,
 
 def ulysses_attention(q, k, v, mesh: Mesh, causal: bool = True,
                       sm_scale: Optional[float] = None,
-                      axis: str = "sp"):
+                      axis: str = "sp", window: Optional[int] = None):
     """Ulysses-style SP attention (parity `_SeqAllToAll` distributed.py:474).
 
     q/k/v (b, h, S, d) seq-sharded over `axis`; heads must divide the axis
-    size.  Each device computes full-sequence attention for h/sp heads.
+    size.  Each device computes full-sequence attention for h/sp heads,
+    so a `window` goes to the kernel as it is.
     """
     sp = mesh.shape.get(axis, 1)
     if sp == 1:
-        return flash_attention(q, k, v, causal, sm_scale)
+        return flash_attention(q, k, v, causal, sm_scale, window=window)
     if q.shape[1] % sp:
         raise ValueError(
             f"ulysses needs heads ({q.shape[1]}) divisible by {axis}={sp}")
@@ -222,6 +226,6 @@ def ulysses_attention(q, k, v, mesh: Mesh, causal: bool = True,
     spec = _qkv_spec(mesh, axis, q.shape[0])
     fn = shard_map(
         functools.partial(_ulysses_local, axis_name=axis, causal=causal,
-                          sm_scale=sm_scale),
+                          sm_scale=sm_scale, window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)

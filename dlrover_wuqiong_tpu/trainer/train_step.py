@@ -322,9 +322,11 @@ def make_lm_loss(model_apply: Callable) -> Callable:
 
     Collects sown auxiliary losses (MoE load-balancing, router z-loss)
     when present.  `loss_fn.with_stats(params, batch) -> (loss, stats)` is
-    the same loss with what the MoE layers counted (`collect_moe_stats`;
-    {} for a dense model): `make_train_step` differentiates that one and
-    returns the counters in the step's metrics."""
+    the same loss with what the MoE layers and the windowed attention
+    layers counted (`collect_moe_stats`, `collect_attention_stats`; {}
+    for a dense model without a window): `make_train_step`
+    differentiates that one and returns the counters in the step's
+    metrics."""
     from ..models.gpt import cross_entropy_loss
 
     def with_stats(params, batch):
@@ -335,6 +337,7 @@ def make_lm_loss(model_apply: Callable) -> Callable:
         inter = updates.get("intermediates", {})
         stats = {}
         if inter:
+            from ..models.attention import collect_attention_stats
             from ..models.moe import (
                 collect_moe_aux_loss,
                 collect_moe_stats,
@@ -342,7 +345,8 @@ def make_lm_loss(model_apply: Callable) -> Callable:
             )
 
             loss = loss + collect_moe_aux_loss(inter)
-            stats = collect_moe_stats(inter)
+            stats = {**collect_moe_stats(inter),
+                     **collect_attention_stats(inter)}
             steps = collect_param_steps(inter)
             if steps:
                 stats["param_steps"] = steps

@@ -4,8 +4,14 @@ against a brute-force mask, its count at the benchmark cells' shapes,
 and interpret-mode parity of the tiled forward, fused backward and split
 backward with the plain reference and its `jax.grad` — on the transposed
 (bh, s, d) layout and on the projections' own (b, s, h*d), one head or
-two a lane slab.
+two a lane slab.  A no-window call's traced program is pinned to what
+it was before the kernels knew a window; the WINDOWED cases, which reuse
+this file's inputs, kernels and reference, are their own file so that
+another worker runs them (tests/test_flash_attention_window.py).
 """
+
+import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -205,13 +211,13 @@ def _kernels(form, heads, q, k, v):
     return forward, backward
 
 
-def _reference(q, k, v, g, gl, causal, scale):
+def _reference(q, k, v, g, gl, causal, scale, window=None):
     """o, lse and the gradients of sum(o * g) + sum(lse * gl) by
     `jax.grad` of the plain reference (the lse variant: a row that sees
     no key gives o = 0 there, not NaN)."""
     def loss(q, k, v):
         o, lse = fa._reference_with_lse(q[None], k[None], v[None], causal,
-                                        scale)
+                                        scale, window)
         extra = 0.0 if gl is None else (lse[0] * gl[:, 0]).sum()
         return (o[0] * g).sum() + extra, (o[0], lse[0])
 
@@ -327,3 +333,58 @@ def test_dots_traced_at_gpt2_shape(causal, tile, fwd, bwd):
         q, k, v, o, l, do, causal, 0.125, 1024, 1024, False, tile=tile))(
             x, x, x, x, row, x)
     assert _dots(b.jaxpr) == bwd
+
+
+# ---------------------------------- a call without a window is unchanged
+
+# sha256 of the traced program (kernel bodies included; addresses and
+# source positions taken out) of a no-window attention call, forward and
+# gradient, at the attention shapes of the benchmark's five cells that
+# had none — read on the commit BEFORE the kernels knew a window and
+# unchanged since: their lowering cannot have moved
+NO_WINDOW = {
+    "gpt2_124m.steady": ("d244927abd3b6e78", "1326a3ad4f1fea22"),
+    "olmoe_1b_7b.steady": ("89c61244ab8db745", "284030d2600fe654"),
+    "nemotron3_nano_30b_a3b.steady": ("8a7845390dc1b315",
+                                      "468e31377f294c45"),
+    "granite4_h_micro.steady": ("c2e4852ee8c4fb1f", "c22d4204310af046"),
+    "gpt2_xl.fsdp4_steady": ("e7adcde6deea4954", "3ad9d7005f42f756"),
+}
+
+
+def _digest(jaxpr) -> str:
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    text = re.sub(r" at /[^ ]*flash_attention.py:\d+", "", text)
+    text = re.sub(r"flash_attention.py:\d+", "", text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("cell", sorted(NO_WINDOW))
+def test_a_call_without_a_window_traces_the_program_it_always_did(
+        monkeypatch, cell):
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    bf = jnp.bfloat16
+    proj, heads = {
+        "gpt2_124m.steady": (((24, 1024, 3 * 768),), 12),
+        "olmoe_1b_7b.steady": (((5, 4096, 2048),) * 3, 16),
+        "nemotron3_nano_30b_a3b.steady": (((2, 8192, 4096),) * 3, 32),
+        "granite4_h_micro.steady": (((1, 8192, 2048),) * 3, 32),
+        "gpt2_xl.fsdp4_steady": (((4, 25, 1024, 64),) * 3, 0),
+    }[cell]
+    args = [jax.ShapeDtypeStruct(s, bf) for s in proj]
+    if heads:
+        def call(*p):
+            return fa.flash_attention_projected(tuple(p), heads, True, None)
+    else:  # 25 heads of 64: the transposed route
+        def call(q, k, v):
+            return fa.flash_attention(q, k, v, True, None)
+
+    def grads(*p):
+        return jax.grad(lambda *pp: call(*pp).astype(jnp.float32).sum(),
+                        argnums=tuple(range(len(p))))(*p)
+
+    assert (_digest(jax.make_jaxpr(call)(*args)),
+            _digest(jax.make_jaxpr(grads)(*args))) == NO_WINDOW[cell]
+    t = proj[0][-2]
+    assert fa.causal_tile_count(t, t) == {
+        1024: (3, 4), 4096: (36, 64), 8192: (136, 256)}[t]

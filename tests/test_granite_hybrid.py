@@ -392,17 +392,21 @@ def test_the_kernel_entries_are_handed_none_at_the_default(monkeypatch):
 
     seen = []
 
+    windows = []
+
     def spy(name, result):
         def fn(*args, sm_scale="absent", **kw):
             seen.append((name, sm_scale))
+            windows.append(kw.get("window"))
             return result(*args)
         return fn
 
     monkeypatch.setattr(dispatch, "mha", spy("mha", lambda q, k, v: q))
     monkeypatch.setattr(
         dispatch, "flash_attention_projected",
-        lambda proj, n_head, causal, sm_scale="absent":
-        seen.append(("projected", sm_scale)) or proj[0])
+        lambda proj, n_head, causal, sm_scale="absent", window=None:
+        seen.append(("projected", sm_scale)) or windows.append(window)
+        or proj[0])
     monkeypatch.setattr(long_context, "sharded_flash_attention",
                         spy("sharded", lambda q, k, v, mesh: q))
     monkeypatch.setattr(long_context, "ring_attention",
@@ -427,3 +431,5 @@ def test_the_kernel_entries_are_handed_none_at_the_default(monkeypatch):
                 cfg, mesh=FourChips(), attn_impl="ring"))
         assert seen == [("mha", want), ("projected", want),
                         ("sharded", want), ("ring", want)]
+    # and no window where `attn_window` is 0 (PR 37's field, read beside)
+    assert windows == [None] * 8

@@ -183,6 +183,45 @@ def test_the_cells_attention_plans(monkeypatch, b, h, t, d, form, route,
         assert "concatenate" not in _primitives(jaxpr)
 
 
+@pytest.mark.parametrize("window,names,sweep,tiles", [
+    # the GLOBAL layer: the causal kernels over all 16 x 16 blocks
+    (0, ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"), 16, (528, 1024)),
+    # a WINDOWED layer: kernels of another name on a grid narrowed to the
+    # five key blocks a query block sees (the five query blocks a key
+    # block is seen by): a block below the window is no grid step
+    (4096, ("dwt_fa_win_fwd", "dwt_fa_win_bwd_dq", "dwt_fa_win_bwd_dkv"),
+     5, (252, 1024)),
+    # a window no shorter than the sequence is the causal call itself
+    (16384, ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"), 16,
+     (528, 1024)),
+])
+def test_the_windowed_cells_attention_plans(monkeypatch, window, names,
+                                            sweep, tiles):
+    """`smallthinker_21b_a3b.steady`'s two kinds of attention layer, from
+    their shape and `LlamaConfig.attn_window` alone: 28 heads of 128 are
+    28 lane slabs, so both go DIRECT (after the 7-fold repeat of k and
+    v) on 16 blocks of 1,024 a side."""
+    from dlrover_wuqiong_tpu.models.attention import (
+        attend_projected,
+        window_tiles,
+    )
+    from dlrover_wuqiong_tpu.models.llama import LlamaConfig
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    cfg = LlamaConfig(hidden_size=2560, num_heads=28, num_kv_heads=4,
+                      attn_head_dim=128, attn_window=window)
+    assert fa.attention_route(28, 128) == ("direct", 1)
+    x = jax.ShapeDtypeStruct((2, 16384, 28 * 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda proj: attend_projected(proj, 28, cfg).astype(
+            jnp.float32).sum()))((x,) * 3).jaxpr
+    assert sorted(_pallas_calls(jaxpr)) == sorted(
+        (name, (2 * 28, 16, sweep)) for name in names)
+    assert fa.causal_tile_count(16384, 16384, window=window or None) == tiles
+    assert window_tiles(cfg, 2, 28, 16384) == (
+        None if not window else (56 * tiles[0], 56 * 528))
+
+
 @pytest.mark.parametrize("h,d,form", [
     (3, 64, "qkv"),      # an odd number of heads of 64
     (3, 64, "q,k,v"),

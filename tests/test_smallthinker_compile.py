@@ -1,0 +1,153 @@
+"""`smallthinker_21b_a3b.steady`'s step and its windowed attention kernels,
+compiled by the TPU's own compiler for a DESCRIBED v5e (no chip attached),
+as tests/test_tpu_compile.py does for the other cells — whose helpers these
+tests use.  A file of its own so that another xdist worker compiles this
+step (about 80 s) while that file compiles the other four.
+"""
+
+import collections
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are fixtures
+    _compile,
+    _every_device_op_has_an_owner,
+    _grouped_kernel_calls,
+    _no_fusion_falls_to_the_root,
+    _no_persistent_cache,
+    _one_chip_step,
+    topo,
+)
+
+from dlrover_wuqiong_tpu.ops import flash_attention as fa
+
+
+# ------------------------------- SmallThinker-21B-A3B's step on one chip
+
+@pytest.fixture(scope="module")
+def smallthinker_step(topo):
+    """`smallthinker_21b_a3b.steady`'s step — published widths, one
+    period (a global no-position layer and three windowed RoPE layers),
+    16 of 64 ReGLU experts held, an eighth of the vocabulary, the cell's
+    batch of 16,384-token sequences, full recomputation (about 50 s)."""
+    return _one_chip_step(topo, "smallthinker_21b_a3b.steady",
+                          "smallthinker")
+
+
+def test_smallthinker_step_fits_one_chip_by_the_rule_and_fills_it(
+        smallthinker_step):
+    """State + temporaries under 90% of the chip's 16 GB at the shipped
+    batch (PR 26's rule; described compiles read 11.09 / 14.37 GB live at
+    1 / 2 sequences), of which 6.71 GB is donated state; far over the
+    25% a cell has to fill."""
+    cell, model, step = smallthinker_step
+    assert model.config.num_params() == 559_290_880
+    assert cell["seq_len"] == 16384
+    m = step.memory_analysis()
+    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    want = {1: 11.09, 2: 14.37}[cell["global_batch"]]
+    assert live / 1e9 == pytest.approx(want, abs=0.05)
+    assert 0.25 * 16 * 2 ** 30 < 0.65 * 16e9 < live < 0.90 * 16e9, live / 1e9
+    assert m.alias_size_in_bytes >= 12 * model.config.num_params()
+
+
+def test_smallthinker_step_runs_two_kinds_of_attention_kernel(
+        smallthinker_step):
+    """The global layer runs the causal kernels and the three windowed
+    layers kernels of their own name (`dwt_fa_win_*`: inside
+    `kernel.attn_ms` by prefix, alone in `kernel.attn_window_ms`), each
+    forward, recomputed and backward: 4 and 12 custom calls.  28 heads
+    of 128 are lane slabs: the kernels index the projections' own
+    (batch, 16384, 28 x 128) after GQA's 7-fold repeat, nothing is laid
+    out by head."""
+    cell, _, step = smallthinker_step
+    text = step.as_text()
+    calls = collections.Counter(re.findall(
+        r"%(dwt_fa_\w+?)(?:\.\d+)? = ", text))
+    assert calls == {"dwt_fa_fwd": 2, "dwt_fa_bwd_dq": 1,
+                     "dwt_fa_bwd_dkv": 1, "dwt_fa_win_fwd": 6,
+                     "dwt_fa_win_bwd_dq": 3, "dwt_fa_win_bwd_dkv": 3}
+    b = cell["global_batch"]
+    assert fa.attention_route(28, 128) == ("direct", 1)
+    assert f"operand_layout_constraints={{bf16[{b},16384,3584]" in text
+    assert f"bf16[{b * 28},16384,128]" not in text
+    assert fa.causal_tile_count(16384, 16384, window=4096) == (252, 1024)
+
+
+def test_smallthinker_step_holds_its_scopes_and_a_share_of_reglu_experts(
+        smallthinker_step):
+    """Every scope the cell's scopes file names is in the compiled step,
+    the router's product (which reads the block's input, from before the
+    attention) under `moe/router`.  A share's three grouped products a
+    layer run `ops/grouped_matmul.py`'s kernels — twelve a layer: three
+    forward, three recomputed, six backward — every one under
+    `feed_forward/moe/experts`, every weight operand the 16 held
+    experts, none the published 64; no `ragged-dot`.  Nothing in the
+    step holds other ops (a `while`, a `conditional`)."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+
+    cell, _, step = smallthinker_step
+    text = step.as_text()
+    scopes = set(scope_table(text).values())
+    for part in ("feed_forward/moe/router", "feed_forward/moe/dispatch",
+                 "feed_forward/moe/experts", "feed_forward/moe/combine",
+                 "attention/q_proj", "attention/k_proj", "attention/v_proj",
+                 "attention/o_proj", "input_norm", "post_attn_norm",
+                 "SmallThinker/head", "loss", "optimizer"):
+        assert any(part in s for s in scopes), part
+    # the file's assumed load-balancing term is sown, no shared expert is
+    assert cell["config"]["assumed"]["router_aux_loss_coef"] == 0.01
+    assert any("moe/aux" in s for s in scopes)
+    assert not any("moe/shared" in s for s in scopes)
+    rows = cell["global_batch"] * 16384 * 6
+    calls = _grouped_kernel_calls(text)
+    assert len(calls) == 48 and "ragged-dot" not in text
+    assert all("feed_forward/moe/experts/dwt_" in scope
+               for scope, _ in calls.values()), calls
+    ours = collections.Counter(
+        (re.sub(r"[.\d]+$", "", name), shapes[0])
+        for name, (_, shapes) in calls.items())
+    assert ours == {
+        ("dwt_gmm", f"{rows},768"): 16, ("dwt_gmm", f"{rows},2560"): 8,
+        ("dwt_gmm_t", f"{rows},768"): 4, ("dwt_gmm_t", f"{rows},2560"): 8,
+        ("dwt_tgmm", "16,2560,768"): 8, ("dwt_tgmm", "16,768,2560"): 4}
+    assert "[64,2560,768]" not in text and "[64,768,2560]" not in text
+    assert " while(" not in text and " conditional(" not in text
+
+
+@pytest.mark.parametrize("window", [4096, 4000])
+def test_windowed_kernels_compile_at_the_cells_shape(topo, window):
+    """One sequence of 16,384 tokens, 28 heads of 128 on their lane
+    slabs, blocks of 1,024: the forward and the split backward of a
+    windowed call on its narrowed grid (index maps that clamp, a class
+    a grid step), at the published window and at one off the block and
+    the tile."""
+    one = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((1, 16384, 3584), jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((28, 1, 16384), jnp.float32, sharding=one)
+    slabs, _ = fa._projected_slabs((x,) * 3, 28)
+    kw = dict(slabs=slabs, window=window)
+    fwd = _compile(lambda q, k, v: fa._fa_forward_pallas(
+        q, k, v, True, 128 ** -0.5, 1024, 1024, False, **kw), x, x, x)
+    assert "dwt_fa_win_fwd" in fwd and "tpu_custom_call" in fwd
+    bwd = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
+        q, k, v, o, l, do, True, 128 ** -0.5, 1024, 1024, False, **kw),
+        x, x, x, x, lse, x)
+    assert "dwt_fa_win_bwd_dq" in bwd and "dwt_fa_win_bwd_dkv" in bwd
+    assert "dwt_fa_fwd" not in fwd + bwd and "dwt_fa_bwd" not in bwd
+
+
+def test_every_device_op_of_the_step_has_an_owner(smallthinker_step):
+    """As the other four steps (tests/test_tpu_compile.py): what has no
+    owner is a copy only the step's outputs read, the step counter and
+    `moe_dropped`; the two tile counts' copies are the scope
+    `attn_tiles`'s (`models/attention.collect_attention_stats`)."""
+    _every_device_op_has_an_owner(smallthinker_step[2])
+
+
+def test_no_fusion_of_the_step_falls_to_the_models_root(smallthinker_step):
+    _no_fusion_falls_to_the_root(smallthinker_step[2], "SmallThinker")

@@ -590,6 +590,9 @@ def test_granite_step_runs_the_kernels_direct_at_32_heads_of_64(
 
 
 # --------------------------- who owns the device ops of the four steps
+#
+# (and of the windowed MoE's, the fifth: tests/test_smallthinker_compile.py,
+# which calls `_every_device_op_has_an_owner` and `_no_fusion_falls_to_the_root`)
 
 _STEPS = [("gpt2_124m_step", "GPT"), ("olmoe_step", "Llama"),
           ("nemotron_step", "NemotronH"), ("granite_step", "GraniteHybrid")]
@@ -611,7 +614,11 @@ def test_every_device_op_of_the_step_has_an_owner(request, fixture, root):
     op (`copy*`, `slice-*`, fusions, `reduce-window`, custom calls) with
     `via: none` — but the copies of what nothing names and only the
     step's outputs read: the step counter, a constant it returns."""
-    table, ins = _owned(request.getfixturevalue(fixture)[2])
+    _every_device_op_has_an_owner(request.getfixturevalue(fixture)[2])
+
+
+def _every_device_op_has_an_owner(step):
+    table, ins = _owned(step)
     unowned = [n for n, e in table.items() if e["via"] == "none"
                and (ins[n]["opcode"] in ("fusion", "reduce-window",
                                          "custom-call")
@@ -632,7 +639,11 @@ def test_no_fusion_of_the_step_falls_to_the_models_root(request, fixture,
     root instruction's scope or its members'; recomputed and backward
     instructions of one module agree on the module (no scope holds the
     root twice)."""
-    table, ins = _owned(request.getfixturevalue(fixture)[2])
+    _no_fusion_falls_to_the_root(request.getfixturevalue(fixture)[2], root)
+
+
+def _no_fusion_falls_to_the_root(step, root):
+    table, ins = _owned(step)
     fusions = {n: e for n, e in table.items()
                if ins[n]["opcode"] == "fusion"}
     assert len(fusions) > 100
