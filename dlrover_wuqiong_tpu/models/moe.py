@@ -46,15 +46,24 @@ hands it another (`MoEMLP(x, router_input=h)`: a router placed before
 the block's attention reads the block's normalised input; its product
 stays under the scope `moe/router`).
 
-Which kernels run the grouped products is `ops/grouped_matmul.gmm_route`'s
-to say, from the call's shapes, mesh and backend: a whole layer fills
-its T*k-row buffer and keeps `lax.ragged_dot` (the TPU compiler's own
-grouped kernels, 57% of peak on OLMoE's full buffer); a SHARE on one
-TPU device runs `dwt_gmm` / `dwt_tgmm`, whose grid walks only the row
-tiles that hold a held row (the compiler's kernels walk the whole
-buffer: at 8 of 128 experts 93% of it is empty).  A share also sows
-`moe_gmm_tiles` (row tiles its grouped products walk, row tiles of the
-buffer), which `collect_moe_stats` reduces to `moe_gmm_tiles_share`.
+Which kernels run the grouped products AND the elementwise passes
+between them is `ops/grouped_matmul.experts_route`'s to say, once a
+layer call (`layer_route`), from the call's shapes, mesh and backend: a
+whole layer fills its T*k-row buffer and keeps `lax.ragged_dot` (the TPU
+compiler's own grouped kernels, 57% of peak on OLMoE's full buffer) and
+the `jax.numpy` lines below, which stay the one definition of the
+mathematics; a SHARE on one TPU device runs `dwt_gmm` / `dwt_tgmm`,
+whose grid walks only the row tiles that hold a held row (the
+compiler's kernels walk the whole buffer: at 8 of 128 experts 93% of it
+is empty), and `dwt_rows_map_*` over the same tiles for the activation
+(and gating product) and its backward, the sum of the two first
+products' row gradients, and the combine's backward pair (the weighted
+cotangent and <row, cotangent>).  What still walks a share's whole
+buffer is what MOVES or COUNTS rows: the four row gathers a layer, the
+two sorts, the `bincount`s.  A share also sows `moe_gmm_tiles` and
+`moe_map_tiles` (row tiles its grouped products / its elementwise
+passes walk, row tiles of the buffer), which `collect_moe_stats` reduces
+to `moe_gmm_tiles_share` and `moe_map_tiles_share`.
 """
 
 from __future__ import annotations
@@ -67,7 +76,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.grouped_matmul import gmm_route, grouped_matmul, row_tiles
+from ..ops.grouped_matmul import (
+    experts_route,
+    grouped_matmul,
+    map_tiles,
+    row_tiles,
+    rows_map,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -264,29 +279,47 @@ def _dispatch_bwd(res, d_rows):
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def combine(rows: jax.Array, gates: Optional[jax.Array], order: jax.Array,
-            inv: jax.Array, held_rows: jax.Array) -> jax.Array:
+            inv: jax.Array, held_rows: jax.Array,
+            route: str = "plain") -> jax.Array:
     """rows (T*k, d) in expert order, gates (T, k) or None (all ones) ->
     (T, d): the sum of each token's k rows, weighted in the same pass,
     accumulated in float32 (`dispatch` says what `order`, `inv` and
-    `held_rows` are)."""
+    `held_rows` are).  `route` is the layer's (`grouped_experts`): on
+    "kernel" the backward pass's two passes over the row buffer are one
+    `rows_map` over the held tiles."""
     picked = _by_assignment(rows, inv, held_rows).astype(jnp.float32)
     if gates is not None:
         picked = picked * gates.T[..., None]
     return picked.sum(0).astype(rows.dtype)
 
 
-def _combine_fwd(rows, gates, order, inv, held_rows):
-    return (combine(rows, gates, order, inv, held_rows),
+def _combine_fwd(rows, gates, order, inv, held_rows, route):
+    return (combine(rows, gates, order, inv, held_rows, route),
             (rows, gates, order, inv, held_rows))
 
 
-def _combine_bwd(res, d_out):
+def _weigh(rows, d_rows, gates):
+    """`_combine_bwd`'s two passes on blocks: the cotangent weighted (a
+    float32 product, rounded once) and <row, cotangent> a row."""
+    dots = (rows.astype(jnp.float32) * d_rows).sum(-1, keepdims=True)
+    return (d_rows * gates).astype(rows.dtype), dots
+
+
+def _combine_bwd(route, res, d_out):
     rows, gates, order, inv, held_rows = res
     d_rows = dispatch(d_out, order, inv, held_rows)
     if gates is None:
         return d_rows, None, None, None, None
+    if route == "kernel":
+        # both passes in one kernel over the tiles that hold a held row,
+        # the weighted cotangent written over the gathered one
+        flat_gates = gates.reshape(-1)[order]
+        d_rows, dots = rows_map(_weigh, held_rows, rows, d_rows,
+                                flat_gates[:, None], alias=(1, 0))
+        d_gates = jnp.where(inv < held_rows, dots[:, 0][inv], 0.0).T
+        return d_rows, d_gates.astype(gates.dtype), None, None, None
     # <row, its token's cotangent> in expert order, where both lie (a
     # pass over the buffer, no second gather of rows), then back to
     # (T, k) through `inv` as T*k numbers
@@ -298,6 +331,34 @@ def _combine_bwd(res, d_out):
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def layer_route(rows: int, w_gate: Optional[jax.Array], w_in: jax.Array,
+                w_down: jax.Array, num_experts: Optional[int],
+                mesh=None) -> str:
+    """`ops/grouped_matmul.experts_route` of a layer's weights: the one
+    route of its grouped products AND of the elementwise passes between
+    them, from what the call can observe."""
+    return experts_route(
+        rows, [w.shape for w in (w_gate, w_in, w_down) if w is not None],
+        num_experts, mesh)
+
+
+@functools.lru_cache(maxsize=None)
+def _activation(gate_act):
+    """An expert's activation as a function of the first products'
+    blocks (`rows_map` wants one object a form, whatever the layer):
+    relu^2 of the one product where there is no gate, else `gate_act` of
+    the gate matrix's times the other's."""
+    if gate_act is None:
+        def act(a):
+            return (jnp.square(jax.nn.relu(a)),)
+        act.__name__ = "relu2"
+    else:
+        def act(g, u):
+            return (gate_act(g) * u,)
+        act.__name__ = f"gated_{gate_act.__name__}"
+    return act
 
 
 def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
@@ -319,19 +380,24 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
     expert is taken out BEFORE the sort.  It gets no group (`group_sizes`
     has one entry a held expert) and its place in the static (T*k)-row
     buffer lies behind every held row.  No group of a grouped product
-    writes those places (`ops/grouped_matmul.grouped_matmul`: on one TPU
-    device, `mesh` None or of size 1, a share's products run kernels
-    that never visit a row tile behind the held rows; everything else is
-    `lax.ragged_dot`), and what the TPU's grouped kernels leave there is
-    not zero (a NaN by step 20, PERF.md section 6, PR 31).  Two things keep
-    it out of every result and gradient: the first product's places
-    behind the held rows are set to zero (so the activation's are, and
-    by the mask's transpose those of its cotangent), and the sums over a
-    token's k rows read no place behind them (`_by_assignment`), be it of
-    the last product or of the rows' own gradient."""
+    writes those places, and what the TPU's grouped kernels leave there
+    is not zero (a NaN by step 20, PERF.md section 6, PR 31).  Two
+    things keep it out of every result and gradient.  On the "plain"
+    route (`lax.ragged_dot`) the first product's places behind the held
+    rows are set to zero (so the activation's are, and by the mask's
+    transpose those of its cotangent); on the "kernel" route (`route`,
+    decided ONCE here for the products and the passes between them: a
+    share on one TPU device, `mesh` None or of size 1) nothing reads or
+    writes a row tile behind the held rows at all — the products'
+    kernels and the maps' visit the tiles that hold a held row, and a
+    map zeroes the rest of the last one.  And on either route the sums
+    over a token's k rows read no place behind the held rows
+    (`_by_assignment`), be it of the last product or of the rows' own
+    gradient."""
     T, top_k = experts.shape
     E = w_in.shape[0]
     share = num_experts is not None and E < num_experts
+    route = layer_route(T * top_k, w_gate, w_in, w_down, num_experts, mesh)
     with jax.named_scope("dispatch"):
         flat_expert = experts.reshape(-1)              # (T*k,)
         if share:
@@ -349,18 +415,26 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
         xs = xs.astype(w_in.dtype)                     # (T*k, d) sorted
 
     def grouped(lhs, rhs):
-        out = grouped_matmul(lhs, rhs, group_sizes, num_experts, mesh)
+        out = grouped_matmul(lhs, rhs, group_sizes)
         return jnp.where(held_row[:, None], out, 0) if share else out
 
     with jax.named_scope("experts"):
-        if w_gate is None:  # relu^2: no gate matrix
+        if route == "kernel":
+            # no mask over the buffer: the activation's kernel zeroes
+            # what the last held tile holds behind the held rows and
+            # visits no tile behind it, forward and backward
+            firsts = (w_in,) if w_gate is None else (w_gate, w_in)
+            h, = rows_map(_activation(None if w_gate is None else gate_act),
+                          held_rows,
+                          *grouped_matmul(xs, firsts, group_sizes, route))
+        elif w_gate is None:  # relu^2: no gate matrix
             h = jnp.square(jax.nn.relu(grouped(xs, w_in)))
         else:
             h = gate_act(grouped(xs, w_gate)) * grouped(xs, w_in)
         # (T*k, d); no mask here: `combine` reads the held rows alone
-        ys = grouped_matmul(h, w_down, group_sizes, num_experts, mesh)
+        ys = grouped_matmul(h, w_down, group_sizes, route)
     with jax.named_scope("combine"):
-        out = combine(ys, gates, order, inv, held_rows)
+        out = combine(ys, gates, order, inv, held_rows, route)
     return out.astype(tokens.dtype), group_sizes
 
 
@@ -541,12 +615,16 @@ class MoEMLP(nn.Module):
             self.sow("intermediates", "moe_rows_held", counts.sum())
             self.sow("intermediates", "moe_rows_absent", dropped)
             dropped = jnp.zeros((), dropped.dtype)
-            # how far the grouped products follow the held rows: row
-            # tiles they walk, row tiles of the buffer (no sync)
+            # how far the grouped products, and the elementwise passes
+            # between them, follow the held rows: row tiles they walk,
+            # row tiles of the buffer (no sync)
             rows = n_tok * cfg.top_k
-            self.sow("intermediates", "moe_gmm_tiles", jnp.stack(row_tiles(
-                counts, rows, gmm_route((rows, d), w_in.shape,
-                                        cfg.num_experts, cfg.mesh))))
+            route = layer_route(rows, w_gate, w_in, w_out, cfg.num_experts,
+                                cfg.mesh)
+            self.sow("intermediates", "moe_gmm_tiles",
+                     jnp.stack(row_tiles(counts, rows, route)))
+            self.sow("intermediates", "moe_map_tiles",
+                     jnp.stack(map_tiles(counts, rows, route)))
         self.sow("intermediates", "moe_dropped", dropped)
         if cfg.shared_width:
             with jax.named_scope("shared"):
@@ -611,8 +689,9 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     stats = {"moe_load_max_over_mean": jnp.max(jnp.stack(loads)),
              **{name: jnp.sum(jnp.stack(v)) for name, v in sums.items()
                 if v}}
-    tiles = [v.reshape(-1, 2) for v in _sown(intermediates, "moe_gmm_tiles")]
-    if tiles:
-        walked, of = jnp.concatenate(tiles).astype(jnp.float32).sum(0)
-        stats["moe_gmm_tiles_share"] = walked / of
+    for name in ("moe_gmm_tiles", "moe_map_tiles"):
+        tiles = [v.reshape(-1, 2) for v in _sown(intermediates, name)]
+        if tiles:
+            walked, of = jnp.concatenate(tiles).astype(jnp.float32).sum(0)
+            stats[f"{name}_share"] = walked / of
     return stats

@@ -9,8 +9,12 @@ M: a chip that holds `E` of a layer's experts receives its rows into a
 buffer sized for the worst case (`models/moe.py::grouped_experts`), and
 the rows behind the last group belong to nobody.
 
-Two routes compute it, chosen by `gmm_route` from what a call can
-observe (its shapes, the mesh, the backend), never by a knob:
+Two routes compute it, chosen from what a call can observe (its shapes,
+the mesh, the backend), never by a knob — `gmm_route` says it of one
+product, `experts_route` ONCE of a layer: all its products and the
+elementwise passes between them take the same route, because a pass
+that leaves the tiles behind the held rows unwritten may never feed a
+`lax.ragged_dot`, which reads them:
 
 - "kernel": Pallas (Mosaic) kernels behind one `jax.custom_vjp`.
   `dwt_gmm` is the product above, `dwt_gmm_t` the same kernel reading
@@ -32,6 +36,22 @@ observe (its shapes, the mesh, the backend), never by a knob:
   kernels and these leave NaNs behind the held rows) reaches a sum.
   Rows of no group are left unwritten, as the compiler's grouped
   kernels leave them: the caller masks what it reads.
+  What lies BETWEEN the products is `rows_map` (`dwt_rows_map_<name>`,
+  one `pallas_call`): an elementwise `jax.numpy` function of one or
+  more (M, c) buffers' row blocks — an activation, a gating product,
+  the sum of two row gradients, a weighting by per-row operands (M, 1),
+  per-row sums (M, 1) — over ceil(held rows / `_ROW_TILE`) row tiles,
+  that dynamic number again the grid, the count of held rows by scalar
+  prefetch.  Inside the last visited tile the rows behind the held ones
+  are written as ZERO (a select on a row iota: a NaN goes no further),
+  the tiles behind it are not visited.  Its backward pass is the same
+  kernel over the function's own VJP (`jax.vjp` on the blocks, inside
+  the body), so cotangents obey the same contract and no mask over the
+  whole buffer is needed in either direction.  The blocks are widened
+  to float32 in the kernel and each result is rounded once (Mosaic
+  refuses a bfloat16 compare on a v5e; the compiler's fusion of the
+  same lines also keeps float32 between its ops).  A pass costs the
+  held share of the buffer's bytes plus a launch.
 - "plain": `jax.lax.ragged_dot`, for which the TPU compiler has
   grouped-matmul kernels of its own that walk every row tile of the
   buffer.  Wherever the groups fill the buffer (a whole layer: `E ==
@@ -53,8 +73,9 @@ gradient (a float32 transpose a step); a stand-alone jit of either adds
 layout copies around the call that are as long as the product, so time
 these from a trace (`tools/perf_probe.py gmm`), not with a host clock.
 
-Scopes: the caller's (`moe/experts`); the metadata's few integer ops
-and the custom calls, forward, recomputed and backward, carry it.
+Scopes: the caller's (`moe/experts`, for the combine's backward pair
+`moe/combine`); the metadata's few integer ops and the custom calls,
+forward, recomputed and backward, carry it.
 
 Parity: reference `atorch/atorch/modules/moe/grouped_gemm_moe.py` (a
 CUDA grouped GEMM); the design is megablox's (`jax.experimental.pallas.
@@ -65,7 +86,7 @@ contraction axis and its group offsets.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +99,7 @@ _LANES = 128
 _ROW_TILE = 256
 _COLUMN_TILE = 1024  # result columns a grid step takes, where they tile
 _VMEM_LIMIT = 96 * 1024 * 1024
+_MAP_VMEM_FLOOR = 16 * 1024 * 1024  # the compiler's own default
 
 
 # ------------------------------------------------------------ the route
@@ -125,16 +147,49 @@ def gmm_route(lhs_shape: Tuple[int, int], rhs_shape: Tuple[int, int, int],
     return "kernel"
 
 
-def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
-                   num_experts: Optional[int] = None,
-                   mesh=None) -> jax.Array:
+def _map_vmem_bytes(blocks: Sequence[Tuple[int, int]], tile: int) -> int:
+    """What `dwt_rows_map` holds at `blocks`, the (columns, bytes an
+    entry) of its buffers and results: each double-buffered (a per-row
+    one fills 128 lanes), and a float32 temporary a block and two
+    besides."""
+    lanes = [max(c, _LANES) for c, _ in blocks]
+    return 2 * tile * sum(n * size for n, (_, size) in zip(lanes, blocks)) \
+        + (len(blocks) + 2) * tile * max(lanes) * 4
+
+
+def experts_route(rows: int, weight_shapes: Sequence[Tuple[int, int, int]],
+                  num_experts: Optional[int], mesh=None) -> str:
+    """The ONE route of an expert layer's call, for its grouped products
+    (`weight_shapes`, each (E, C, N)) and the elementwise passes between
+    them: "kernel" where `gmm_route` says so of EVERY product and the
+    maps' blocks fit VMEM, else "plain".  A map leaves the tiles behind
+    the held rows unwritten and `lax.ragged_dot` reads them, so the two
+    may never disagree: `models/moe.py::grouped_experts` asks once and
+    hands the answer to `grouped_matmul` and to `rows_map`."""
+    routes = {gmm_route((rows, c), (e, c, n), num_experts, mesh)
+              for e, c, n in weight_shapes}
+    widest = max(max(c, n) for _, c, n in weight_shapes)
+    # the widest map there is: three buffers in, two out, of four bytes
+    if routes != {"kernel"} or \
+            _map_vmem_bytes([(widest, 4)] * 5, _ROW_TILE) > _VMEM_LIMIT:
+        return "plain"
+    return "kernel"
+
+
+def grouped_matmul(lhs: jax.Array, rhs, group_sizes: jax.Array,
+                   route: str = "plain"):
     """lhs (M, C) x rhs (E, C, N) by group -> (M, N) in the operands'
-    dtype, float32 accumulation; `num_experts` is how many groups the
-    rows were routed over (None: the E of `rhs`), `mesh` where the call
-    runs."""
-    if gmm_route(lhs.shape, rhs.shape, num_experts, mesh) == "plain":
-        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
-    return _grouped_kernels(lhs, rhs, group_sizes)
+    dtype, float32 accumulation, on `route` (`experts_route`'s answer for
+    the layer; "plain" is `lax.ragged_dot` word for word).  `rhs` may be
+    a tuple of weights: a tuple of products of the one `lhs`, whose
+    backward pass sums their row gradients over the held tiles alone."""
+    if route == "kernel":
+        return _grouped_kernels(lhs, rhs, group_sizes)
+    if route != "plain":
+        raise ValueError(f"no route {route!r}")
+    if isinstance(rhs, tuple):
+        return tuple(jax.lax.ragged_dot(lhs, w, group_sizes) for w in rhs)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
 # --------------------------------------------------------- the metadata
@@ -178,6 +233,18 @@ def row_tiles(group_sizes: jax.Array, m: int,
     if route == "plain":
         return tiles, tiles
     return group_visits(group_sizes, m, _ROW_TILE)[3], tiles
+
+
+def map_tiles(group_sizes: jax.Array, m: int,
+              route: str) -> Tuple[jax.Array, jax.Array]:
+    """(row tiles an elementwise pass of `route` walks, row tiles of the
+    `m`-row buffer): the tiles that hold a held row (`dwt_rows_map`'s
+    grid), or every tile where the pass is the compiler's fusion."""
+    tiles = jnp.asarray(-(-m // _ROW_TILE), jnp.int32)
+    if route == "plain":
+        return tiles, tiles
+    held = group_sizes.astype(jnp.int32).sum()
+    return -(-held // _ROW_TILE), tiles
 
 
 # ---------------------------------------------------------- the kernels
@@ -279,29 +346,119 @@ def _tgmm_pallas(lhs, rhs, group_sizes, *, dtype, tile, columns, interpret):
     )(*plan[:3], lhs, rhs)
 
 
+def _rows_map_kernel(held_ref, *refs, fn, tile, n_in):
+    """One visited row tile: `fn` of the blocks, the rows at or behind
+    the held rows written as zero whatever `fn` made of what lay there
+    (a select: a NaN of an unwritten place goes no further)."""
+    rows = pl.program_id(0) * tile + jax.lax.broadcasted_iota(
+        jnp.int32, (tile, 1), 0)
+    held = rows < held_ref[0]
+    # in float32 whatever the buffers hold, each result rounded once (a
+    # v5e's vector unit compares no bfloat16, Mosaic refuses one): what
+    # the compiler's fusion of the same lines computes
+    values = fn(*(ref[...].astype(jnp.float32) for ref in refs[:n_in]))
+    for ref, value in zip(refs[n_in:], values):
+        ref[...] = jnp.where(held, value, jnp.zeros_like(value)).astype(
+            ref.dtype)
+
+
+def _rows_map_pallas(held_rows, *buffers, fn, tile, interpret, alias=None):
+    """`fn`, elementwise in the rows, over the row tiles that hold a held
+    row.  `buffers`: arrays (M, c_i) in expert order, c_i = 1 a per-row
+    operand; `fn` takes their (tile, c_i) blocks and returns a tuple of
+    blocks (tile, n_j), n_j = 1 a per-row result (a sum over the
+    columns); -> the tuple of (M, n_j) arrays.  Grid: ceil(held_rows /
+    tile) steps, DYNAMIC, `held_rows` by scalar prefetch; behind it
+    nothing is visited or written.  `alias` (i, j): result j is written
+    over buffer i (same shape and dtype, dead after the call)."""
+    m = buffers[0].shape[0]
+    outs = jax.eval_shape(fn, *(jax.ShapeDtypeStruct((tile, b.shape[1]),
+                                                     b.dtype)
+                                for b in buffers))
+
+    def block(like):
+        return pl.BlockSpec((tile, like.shape[1]), lambda t, held: (t, 0))
+
+    # What the kernel holds, and no more, and what a call costs where
+    # every row is held (the compiler knows no dynamic grid).  With the
+    # products' 96 MB, or with no estimate (a custom call then costs
+    # nothing to the scheduler), the compiler stops staging a
+    # neighbouring gather's 88 MB source in VMEM under this call: 4.2 ms
+    # a gather where it takes 0.8 (PERF.md section 6, PR 38).
+    blocks = [(x.shape[1], jnp.dtype(x.dtype).itemsize)
+              for x in (*buffers, *outs)]
+    vmem = _map_vmem_bytes(blocks, tile)
+    return pl.pallas_call(
+        functools.partial(_rows_map_kernel, fn=fn, tile=tile,
+                          n_in=len(buffers)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(-(-held_rows // tile),),
+            in_specs=[block(b) for b in buffers],
+            out_specs=[block(o) for o in outs]),
+        out_shape=[_out_struct((m, o.shape[1]), o.dtype, buffers[0])
+                   for o in outs],
+        # operand 0 is the prefetched scalar
+        input_output_aliases={} if alias is None else {1 + alias[0]: alias[1]},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(vmem * 5 // 4, _MAP_VMEM_FLOOR)),
+        cost_estimate=pl.CostEstimate(
+            flops=m * sum(c for c, _ in blocks), transcendentals=0,
+            bytes_accessed=m * sum(c * size for c, size in blocks)),
+        interpret=interpret,
+        name=f"dwt_rows_map_{fn.__name__.lstrip('_')}",
+    )(held_rows.astype(jnp.int32).reshape(1), *buffers)
+
+
 # static plan: behind `jax.jit` a kernel body is traced and lowered to
 # Mosaic once a shape, not once a call (four layers, one trace)
 _STATIC = ("tile", "columns", "interpret")
 _gmm = jax.jit(_gmm_pallas, static_argnames=_STATIC + ("transposed",))
 _tgmm = jax.jit(_tgmm_pallas, static_argnames=_STATIC + ("dtype",))
+_rows_map = jax.jit(_rows_map_pallas,
+                    static_argnames=("fn", "tile", "interpret", "alias"))
+
+
+def _row_tile(rows: int, tile: Optional[int]) -> int:
+    """The row tile of a kernel-route call (`tile` or `_ROW_TILE`), which
+    has to divide the buffer's rows."""
+    tile = tile or _ROW_TILE
+    if rows % tile:
+        raise ValueError(f"{rows} rows are no multiple of the row tile "
+                         f"{tile}")
+    return tile
+
+
+def _add(a, b):
+    return (a + b,)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _products(lhs, rhs, group_sizes, plan):
-    return _gmm(lhs, rhs, group_sizes, transposed=False, **dict(plan))
+def _products(lhs, weights, group_sizes, plan):
+    """lhs x each of the tuple `weights` by group."""
+    return tuple(_gmm(lhs, w, group_sizes, transposed=False, **dict(plan))
+                 for w in weights)
 
 
-def _products_fwd(lhs, rhs, group_sizes, plan):
-    return _products(lhs, rhs, group_sizes, plan), (lhs, rhs, group_sizes)
+def _products_fwd(lhs, weights, group_sizes, plan):
+    return (_products(lhs, weights, group_sizes, plan),
+            (lhs, weights, group_sizes))
 
 
-def _products_bwd(plan, res, d_out):
-    lhs, rhs, group_sizes = res
-    d_out = d_out.astype(lhs.dtype)
-    d_lhs = _gmm(d_out, rhs, group_sizes, transposed=True, **dict(plan))
-    d_rhs = _tgmm(lhs, d_out, group_sizes, dtype=jnp.dtype(rhs.dtype),
-                  **dict(plan))
-    return d_lhs, d_rhs, None
+def _products_bwd(plan, res, d_outs):
+    lhs, weights, group_sizes = res
+    d_outs = [d.astype(lhs.dtype) for d in d_outs]
+    d_lhs = [_gmm(d, w, group_sizes, transposed=True, **dict(plan))
+             for d, w in zip(d_outs, weights)]
+    while len(d_lhs) > 1:
+        # the sum autodiff would write over the whole buffer (`add_any`),
+        # over the held tiles and in place
+        d_lhs[:2] = _rows_map(
+            group_sizes.sum(), *d_lhs[:2], fn=_add, alias=(0, 0),
+            **{k: v for k, v in plan if k != "columns"})
+    d_weights = tuple(_tgmm(lhs, d, group_sizes, dtype=jnp.dtype(w.dtype),
+                            **dict(plan)) for d, w in zip(d_outs, weights))
+    return d_lhs[0], d_weights, None
 
 
 _products.defvjp(_products_fwd, _products_bwd)
@@ -309,12 +466,75 @@ _products.defvjp(_products_fwd, _products_bwd)
 
 def _grouped_kernels(lhs, rhs, group_sizes, tile=None, columns=_COLUMN_TILE,
                      interpret=False):
-    """The kernel route whatever `gmm_route` says (the tests reach it in
-    interpret mode off the chip, at any row tile that divides M)."""
-    tile = tile or _ROW_TILE
-    if lhs.shape[0] % tile:
-        raise ValueError(f"{lhs.shape[0]} rows are no multiple of the row "
-                         f"tile {tile}")
-    plan = (("tile", tile), ("columns", columns), ("interpret", interpret))
-    return _products(lhs, rhs.astype(lhs.dtype),
+    """The kernel route whatever the layer's route says (the tests reach
+    it in interpret mode off the chip, at any row tile that divides M);
+    `rhs` one weight or a tuple of them, as `grouped_matmul` takes it."""
+    plan = (("tile", _row_tile(lhs.shape[0], tile)), ("columns", columns),
+            ("interpret", interpret))
+    weights = rhs if isinstance(rhs, tuple) else (rhs,)
+    outs = _products(lhs, tuple(w.astype(lhs.dtype) for w in weights),
                      group_sizes.astype(jnp.int32), plan)
+    return outs if isinstance(rhs, tuple) else outs[0]
+
+
+# -------------------------------------------- the passes between products
+
+@functools.lru_cache(maxsize=None)
+def _vjp_of(fn, n):
+    """`fn`'s own VJP as a function of blocks: (n primals, the results'
+    cotangents) -> the primals' cotangents.  Elementwise in the rows as
+    `fn` is, so it runs in the same kernel; cached, so that a program's
+    layers hand the kernel ONE function and trace it once."""
+    def backward(*blocks):
+        return jax.vjp(fn, *blocks[:n])[1](tuple(blocks[n:]))
+    backward.__name__ = f"{fn.__name__}_bwd"
+    return backward
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _mapped(fn, plan, alias, held_rows, *buffers):
+    return tuple(_rows_map(held_rows, *buffers, fn=fn, alias=alias,
+                           **dict(plan)))
+
+
+def _mapped_fwd(fn, plan, alias, held_rows, *buffers):
+    return (_mapped(fn, plan, alias, held_rows, *buffers),
+            (held_rows, buffers))
+
+
+def _mapped_bwd(fn, plan, alias, res, d_outs):
+    held_rows, buffers = res
+    n = len(buffers)
+    # the first cotangent dies here: where it has the first buffer's
+    # form, that buffer's cotangent is written over it
+    d, b = d_outs[0], buffers[0]
+    over = (n, 0) if (d.shape, d.dtype) == (b.shape, b.dtype) else None
+    return (None, *_rows_map(held_rows, *buffers, *d_outs,
+                             fn=_vjp_of(fn, n), alias=over, **dict(plan)))
+
+
+_mapped.defvjp(_mapped_fwd, _mapped_bwd)
+
+
+def _rows_map_kernels(fn, held_rows, *buffers, alias=None, tile=None,
+                      interpret=False):
+    """`rows_map` whatever the layer's route says, as `_grouped_kernels`
+    is `grouped_matmul`'s."""
+    plan = (("tile", _row_tile(buffers[0].shape[0], tile)),
+            ("interpret", interpret))
+    return _mapped(fn, plan, alias, held_rows, *buffers)
+
+
+def rows_map(fn, held_rows: jax.Array, *buffers: jax.Array,
+             alias: Optional[Tuple[int, int]] = None) -> Tuple[jax.Array, ...]:
+    """The kernel route's elementwise pass over a share's row buffers
+    (`dwt_rows_map_<fn's name>`): `fn(*blocks) -> (block, ...)` applied to
+    the row tiles that hold one of the first `held_rows` rows and to no
+    other.  `buffers` are (M, c_i) arrays in expert order, (M, 1) a
+    per-row operand; a result block (tile, 1) is a per-row result (a sum
+    over the columns).  Rows behind the held ones are written as zero
+    inside the last visited tile and left unwritten behind it — in every
+    result and, `fn`'s own VJP running through the same kernel, in every
+    cotangent.  `alias` (i, j): result j is written over buffer i.  `fn`
+    is a static argument: hand every layer the same object."""
+    return _rows_map_kernels(fn, held_rows, *buffers, alias=alias)

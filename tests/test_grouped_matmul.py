@@ -4,7 +4,11 @@ interpret mode on the CPU, against `jax.lax.ragged_dot` and its
 differentiation: groups that are empty, groups that share a row tile,
 group sizes that sum to less than the buffer with the rows behind them
 poisoned (NaN) on the way in, and the grid's row axis — the visits —
-against a count by hand.  What the described-`v5e` compiles cannot see
+against a count by hand.  And the elementwise passes between a share's
+products (`dwt_rows_map_*`: an activation, a sum of two row gradients,
+the combine's backward pair) against the `jax.numpy` function each one
+applies, values and gradients, at the edges of the held rows.  What the
+described-`v5e` compiles cannot see
 (results), as they see what this cannot (tiling, VMEM):
 tests/test_tpu_compile.py.
 """
@@ -16,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dlrover_wuqiong_tpu.models import moe
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
 
 TILE = 32
@@ -182,7 +187,7 @@ def test_off_the_kernel_route_the_product_is_ragged_dot_word_for_word():
     (lhs, _), _, rhs, sizes = _operands(CASES["a_share_of_the_buffer"],
                                         jnp.float32)
     ours = jax.make_jaxpr(
-        lambda l, r, s: gm.grouped_matmul(l, r, s, 128))(lhs, rhs, sizes)
+        lambda l, r, s: gm.grouped_matmul(l, r, s, "plain"))(lhs, rhs, sizes)
     theirs = jax.make_jaxpr(
         lambda l, r, s: jax.lax.ragged_dot(l, r, s))(lhs, rhs, sizes)
     assert str(ours) == str(theirs)
@@ -221,3 +226,200 @@ def test_the_kernel_route_refuses_rows_the_tile_does_not_divide():
                                         jnp.float32)
     with pytest.raises(ValueError, match="row tile"):
         gm._grouped_kernels(lhs[:250], rhs, sizes, tile=TILE, interpret=True)
+
+
+# ------------------------------------------ the passes between products
+
+# how many of the 256 rows are held: none, a whole number of 32-row
+# tiles, one short of it, one over, a share that ends inside a tile, all
+HELD = {"none": 0, "two_tiles": 64, "one_short": 63, "one_over": 65,
+        "a_share": 100, "one_row": 1, "whole_buffer": ROWS}
+
+# (the function of blocks, widths of its buffers; 1 = a per-row operand)
+FORMS = {
+    "relu2": (moe._activation(None), (N,)),
+    "reglu": (moe._activation(jax.nn.relu), (N, N)),
+    "swiglu": (moe._activation(jax.nn.silu), (N, N)),
+    "sum_of_two": (gm._add, (C, C)),
+    "combine_bwd_pair": (moe._weigh, (C, C, 1)),
+}
+
+
+def _buffers(widths, held, dtype, seed=0):
+    """(clean, poisoned): zeros / NaNs behind the held rows; a per-row
+    operand (the gates) is float32 whatever the buffers are."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(widths))
+    behind = (jnp.arange(ROWS) >= held)[:, None]
+    drawn = [jax.random.normal(k, (ROWS, w), jnp.float32)
+             for k, w in zip(keys, widths)]
+    return tuple([jnp.where(behind, fill, a).astype(
+        jnp.float32 if a.shape[1] == 1 else dtype) for a in drawn]
+        for fill in (0, jnp.nan))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 1e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("held", HELD)
+@pytest.mark.parametrize("form", FORMS)
+def test_a_map_is_its_function_on_the_held_rows(form, held, dtype, tol):
+    """`rows_map`: every result and every buffer's gradient are the
+    plain `jax.numpy` function's on the held rows, ZERO on the rows of
+    the last visited tile that lie behind them — though the buffers and
+    the cotangents are handed in with NaNs there — and whatever the
+    interpreter left in the tiles no grid step visits."""
+    fn, widths = FORMS[form]
+    n_held = HELD[held]
+    visited = -(-n_held // TILE) * TILE
+    clean, dirty = _buffers(widths, n_held, dtype)
+    mask = (jnp.arange(ROWS) < n_held)[:, None]
+
+    def plain(*buffers):
+        return tuple(jnp.where(mask, o, 0) for o in fn(*buffers))
+
+    def ours(*buffers):
+        return gm._rows_map_kernels(fn, jnp.int32(n_held), *buffers,
+                                    tile=TILE, interpret=True)
+
+    want, want_vjp = jax.vjp(plain, *clean)
+    got, got_vjp = jax.vjp(ours, *dirty)
+    d_clean, d_dirty = _buffers([w.shape[1] for w in want], n_held,
+                                jnp.float32, seed=1)
+    cots = [[d.astype(w.dtype) for d, w in zip(ds, want)]
+            for ds in (d_clean, d_dirty)]
+    pairs = list(zip(got, want)) + list(
+        zip(got_vjp(tuple(cots[1])), want_vjp(tuple(cots[0]))))
+    assert len(pairs) == len(want) + len(widths)
+    for g, w in pairs:
+        assert g.shape == w.shape and g.dtype == w.dtype
+        g, w = (np.asarray(a, np.float32)[:visited] for a in (g, w))
+        assert np.isfinite(g).all()
+        assert not g[n_held:].any()
+        scale = max(1.0, float(np.abs(w).max(initial=0.0)))
+        assert np.abs(g - w).max(initial=0.0) <= tol * scale
+
+
+def test_a_map_writes_its_result_over_the_buffer_it_is_told_to():
+    """`alias`: the combine's backward pair writes the weighted cotangent
+    over the gathered one; the results are the un-aliased call's."""
+    fn, widths = FORMS["combine_bwd_pair"]
+    clean, _ = _buffers(widths, 100, jnp.float32)
+    run = [gm._rows_map_kernels(fn, jnp.int32(100), *clean, alias=alias,
+                                tile=TILE, interpret=True)
+           for alias in (None, (1, 0))]
+    for a, b in zip(*run):
+        np.testing.assert_array_equal(np.asarray(a)[:128], np.asarray(b)[:128])
+    # operand 0 is the prefetched count of held rows
+    assert "input_output_aliases=((2, 0),)" in str(jax.make_jaxpr(
+        lambda *b: gm._rows_map_pallas(jnp.int32(100), *b, fn=fn, tile=TILE,
+                                       interpret=True, alias=(1, 0)))(*clean))
+
+
+@pytest.mark.parametrize("held", HELD)
+def test_the_maps_grid_is_the_tiles_that_hold_a_held_row(held):
+    """`map_tiles`: ceil(held / 256) of the buffer's tiles on the kernel
+    route, all of them on the plain."""
+    sizes = jnp.array([HELD[held] * 20, 0, HELD[held] * 12])
+    rows = ROWS * 32
+    walked, of = gm.map_tiles(sizes, rows, "kernel")
+    assert (int(walked), int(of)) == (-(-HELD[held] * 32 // 256), 32)
+    walked, of = gm.map_tiles(sizes, rows, "plain")
+    assert (int(walked), int(of)) == (32, 32)
+
+
+@pytest.mark.parametrize("d,f,route", [
+    (2688, 1856, "kernel"),     # the hybrid cell's share
+    (2560, 768, "kernel"),      # SmallThinker's
+    (32768, 1856, "plain"),     # the first product's blocks pass VMEM
+    (1856, 32768, "plain"),     # the last product's do
+    (8192, 1024, "plain"),      # no product's, the maps' do
+])
+def test_a_layer_has_one_route(monkeypatch, d, f, route):
+    """`experts_route`: "kernel" only where every product's `gmm_route`
+    says so and the maps' blocks fit; one product over the VMEM bound
+    makes the whole layer plain (its other product alone would not
+    be)."""
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    shapes = [(8, d, f), (8, d, f), (8, f, d)]
+    assert gm.experts_route(98304, shapes, 128) == route
+    assert gm.experts_route(98304, shapes[1:], 128) == route
+    singly = {gm.gmm_route((98304, c), (e, c, n), 128) for e, c, n in shapes}
+    assert singly == {"kernel"} if route == "kernel" or d == 8192 \
+        else singly == {"kernel", "plain"}
+    # and nothing but a share on one TPU device
+    assert gm.experts_route(98304, shapes, 8) == "plain"
+    assert gm.experts_route(98304, shapes, 128, _mesh(4)) == "plain"
+    assert gm.experts_route(98304 + 8, shapes, 128) == "plain"
+    monkeypatch.setattr(gm, "_on_tpu", lambda: False)
+    assert gm.experts_route(98304, shapes, 128) == "plain"
+
+
+def test_a_route_nobody_knows_is_refused():
+    (lhs, _), _, rhs, sizes = _operands(CASES["a_share_of_the_buffer"],
+                                        jnp.float32)
+    with pytest.raises(ValueError, match="no route"):
+        gm.grouped_matmul(lhs, rhs, sizes, 128)
+
+
+def test_a_pair_of_products_sums_its_row_gradients_in_a_map():
+    """`grouped_matmul(lhs, (a, b), ...)`: both products and every
+    gradient are two single calls'; the backward pass holds ONE
+    `dwt_rows_map_add` and no `add_any` over the buffer."""
+    (lhs, d_out), (lhs_nan, d_out_nan), rhs, sizes = _operands(
+        CASES["a_share_of_the_buffer"], jnp.float32)
+    held = sum(CASES["a_share_of_the_buffer"])
+    pair = (rhs, rhs[::-1] * 0.5)
+
+    def plain(l, a, b):
+        return gm.grouped_matmul(l, (a, b), sizes, "plain")
+
+    def ours(l, a, b):
+        return gm._grouped_kernels(l, (a, b), sizes, tile=TILE,
+                                   interpret=True)
+
+    want, want_vjp = jax.vjp(plain, lhs, *pair)
+    got, got_vjp = jax.vjp(ours, lhs_nan, *pair)
+    want_g = want_vjp((d_out, -d_out))
+    got_g = got_vjp((d_out_nan, -d_out_nan))
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g[:held], w[:held], atol=2e-5)
+    np.testing.assert_allclose(got_g[0][:held], want_g[0][:held], atol=1e-4)
+    assert not np.asarray(got_g[0][held:-(-held // TILE) * TILE]).any()
+    for g, w in zip(got_g[1:], want_g[1:]):
+        np.testing.assert_allclose(g, w, atol=1e-4)
+    text = str(jax.make_jaxpr(
+        lambda l, a, b, d: jax.vjp(ours, l, a, b)[1]((d, d)))(
+            lhs, *pair, d_out))
+    assert text.count("dwt_rows_map_add") == 1 and "add_any" not in text
+
+
+def test_a_program_traces_each_map_once(monkeypatch):
+    """Four layers, one trace of the map's body a function and shape
+    (forward, and its VJP), as the products' kernels."""
+    fn, widths = FORMS["reglu"]
+    clean, _ = _buffers(widths, 100, jnp.float32)
+    traced = []
+    body = gm._rows_map_kernel
+
+    def counting(*refs, fn, **kw):
+        traced.append(fn.__name__)
+        return body(*refs, fn=fn, **kw)
+
+    monkeypatch.setattr(gm, "_rows_map_kernel", counting)
+
+    def four_layers(g, u):
+        for _ in range(4):
+            g, = gm._rows_map_kernels(fn, jnp.int32(100), g, u, tile=16,
+                                      interpret=True)
+        return g.sum()
+
+    jax.jit(jax.grad(four_layers, argnums=(0, 1))).lower(*clean)
+    assert sorted(traced) == ["gated_relu", "gated_relu_bwd"]
+
+
+def test_a_map_refuses_rows_the_tile_does_not_divide():
+    fn, widths = FORMS["relu2"]
+    clean, _ = _buffers(widths, 100, jnp.float32)
+    with pytest.raises(ValueError, match="row tile"):
+        gm._rows_map_kernels(fn, jnp.int32(100), clean[0][:250], tile=TILE,
+                             interpret=True)

@@ -7,11 +7,14 @@ and for a chip's share of them.  A share's grouped products through the
 kernel route of `ops/grouped_matmul.py` (interpret mode) are the plain
 route's."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dlrover_wuqiong_tpu.models import moe
 from dlrover_wuqiong_tpu.models.moe import combine, dispatch, grouped_experts
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
 
@@ -218,48 +221,129 @@ def test_the_expert_pass_keeps_its_value_and_every_gradient(holding,
         assert int(sizes.sum()) == 0 and not np.asarray(got).any()
 
 
-@pytest.mark.parametrize("holding,routing", [
-    c for c in CASES if c.values[0] != "all_8"])
-def test_a_shares_pass_through_the_kernels_is_the_plain_routes(
-        monkeypatch, holding, routing):
-    """`grouped_experts` on the route a share takes on one TPU device —
-    `dwt_gmm` / `dwt_gmm_t` / `dwt_tgmm`, here in interpret mode at a row
-    tile of 32 (T*k = 288 rows: nine tiles, of which the held rows fill
-    one or none) — against the route every CPU run takes: the output,
-    `group_sizes` and every gradient.  The kernels leave the rows of no
-    group unwritten; the interpreter hands them back as it finds them,
-    and the masks that keep them out are `grouped_experts`' own."""
-    held, first, num_experts = HOLDINGS[holding]
-    experts = _routing(routing, held, first, num_experts)
-    tokens, gates, w_in, w_down = _draw(
-        (T, D), (T, K), (held, D, F), (held, F, D), seed=5)
-    w_in, w_down = 0.2 * w_in, 0.2 * w_down
+# an expert's form -> the gate's activation (None: relu^2, no gate matrix)
+FORMS = {"relu2": None, "reglu": jax.nn.relu, "swiglu": jax.nn.silu}
 
-    def run(*args):
-        out, sizes = grouped_experts(args[0], args[1], experts, None,
-                                     args[2], args[3], first_expert=first,
-                                     num_experts=num_experts)
-        return jnp.sum(jnp.sin(out)), (out, sizes)
 
-    args = (tokens, gates, w_in, w_down)
-    both = jax.value_and_grad(run, argnums=(0, 1, 2, 3), has_aux=True)
-    assert gm.gmm_route((T * K, D), w_in.shape, num_experts) == "plain"
-    (_, (want, want_sizes)), want_g = both(*args)
-    calls, grouped_kernels = [], gm._grouped_kernels
+def _on_the_kernel_route(monkeypatch, poison):
+    """What a share takes on one TPU device, here: the layer's own route
+    decision with the backend said to be the TPU and a row tile of 32
+    (T*k = 288 rows: nine tiles, of which the held rows fill one or
+    none), every kernel in interpret mode.  `poison`: every place a
+    kernel may leave unwritten is handed on as NaN — the rows of no
+    group in a product, the tiles no grid step visits in a map — forward
+    and backward.  Returns the list the kernels' calls are noted in."""
+    calls = []
+    kernels, maps, gmm, rows_map = (gm._grouped_kernels, gm._rows_map_kernels,
+                                    gm._gmm, gm._rows_map)
 
-    def kernels(lhs, rhs, sizes):
+    def poisoned_gmm(lhs, rhs, sizes, **kw):
+        out = gmm(lhs, rhs, sizes, **kw)
+        behind = jnp.arange(out.shape[0]) >= sizes.sum()
+        return jnp.where(behind[:, None], jnp.nan, out)
+
+    def poisoned_map(held_rows, *buffers, fn, tile, **kw):
+        calls.append(fn.__name__.lstrip("_"))
+        outs = rows_map(held_rows, *buffers, fn=fn, tile=tile, **kw)
+        unvisited = jnp.arange(outs[0].shape[0]) >= -(-held_rows // tile) * tile
+        return [jnp.where(unvisited[:, None], jnp.nan, o) for o in outs]
+
+    def noting_kernels(lhs, rhs, sizes):
         calls.append(lhs.shape)
-        return grouped_kernels(lhs, rhs, sizes, interpret=True)
+        return kernels(lhs, rhs, sizes, interpret=True)
 
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
     monkeypatch.setattr(gm, "_ROW_TILE", 32)
-    monkeypatch.setattr(gm, "_grouped_kernels", kernels)
-    assert gm.gmm_route((T * K, D), w_in.shape, num_experts) == "kernel"
-    (_, (got, got_sizes)), got_g = both(*args)
-    assert calls == [(T * K, D), (T * K, F)]
+    monkeypatch.setattr(gm, "_grouped_kernels", noting_kernels)
+    monkeypatch.setattr(gm, "_rows_map_kernels",
+                        functools.partial(maps, interpret=True))
+    if poison:
+        monkeypatch.setattr(gm, "_gmm", poisoned_gmm)
+        monkeypatch.setattr(gm, "_rows_map", poisoned_map)
+    return calls
+
+
+def _expert_pass(form, holding, routing, seed=5):
+    """(a function of (tokens, gates, w_gate or None, w_in, w_down) ->
+    loss, (out, sizes); its arguments; the layer's weights' shapes)."""
+    held, first, num_experts = HOLDINGS[holding]
+    experts = _routing(routing, held, first, num_experts)
+    tokens, gates, w_gate, w_in, w_down = _draw(
+        (T, D), (T, K), (held, D, F), (held, D, F), (held, F, D), seed=seed)
+    gated = FORMS[form] is not None
+
+    def run(tokens, gates, w_gate, w_in, w_down):
+        out, sizes = grouped_experts(
+            tokens, gates, experts, w_gate if gated else None, w_in, w_down,
+            first_expert=first, num_experts=num_experts,
+            gate_act=FORMS[form] or jax.nn.silu)
+        return jnp.sum(jnp.sin(out)), (out, sizes)
+
+    return run, (tokens, gates, 0.2 * w_gate, 0.2 * w_in, 0.2 * w_down)
+
+
+@pytest.mark.parametrize("poison", [False, True], ids=["", "poisoned"])
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("holding,routing", [
+    c for c in CASES if c.values[0] != "all_8"])
+def test_a_shares_pass_through_the_kernels_is_the_plain_routes(
+        monkeypatch, holding, routing, form, poison):
+    """`grouped_experts` on the route a share takes on one TPU device —
+    the grouped products in `dwt_gmm` / `dwt_gmm_t` / `dwt_tgmm`, the
+    activation, the sum of two first products' row gradients and the
+    combine's backward pair in `dwt_rows_map_*`, here in interpret mode —
+    against the route every CPU run takes: the output, `group_sizes` and
+    every gradient, for relu^2, ReGLU and SwiGLU experts.  Poisoned, a
+    NaN stands wherever a kernel may leave a place unwritten, in every
+    buffer the maps and the products read: the loss and every gradient
+    are finite and the plain route's all the same."""
+    run, args = _expert_pass(form, holding, routing)
+    gated = FORMS[form] is not None
+    both = jax.value_and_grad(run, argnums=(0, 1, 2, 3, 4), has_aux=True)
+    weights = [a.shape for a in args[2 if gated else 3:]]
+    num_experts = HOLDINGS[holding][2]
+    assert gm.experts_route(T * K, weights, num_experts) == "plain"
+    (want_loss, (want, want_sizes)), want_g = both(*args)
+    calls = _on_the_kernel_route(monkeypatch, poison)
+    assert gm.experts_route(T * K, weights, num_experts) == "kernel"
+    (loss, (got, got_sizes)), got_g = both(*args)
+    assert [c for c in calls if isinstance(c, tuple)] \
+        == [(T * K, D), (T * K, F)]
+    if poison:
+        act = {"relu2": "relu2", "reglu": "gated_relu",
+               "swiglu": "gated_silu"}[form]
+        assert sorted(c for c in calls if isinstance(c, str)) == sorted(
+            [act, f"{act}_bwd", "weigh"] + ["add"] * gated)
+    assert np.isfinite(float(loss))
+    # (a sum of T*D sines of either sign)
+    assert float(loss) == pytest.approx(float(want_loss), abs=1e-6 * T * D)
     _close(got, want)
     np.testing.assert_array_equal(np.asarray(got_sizes),
                                   np.asarray(want_sizes))
     for g, w in zip(got_g, want_g):
         assert np.isfinite(np.asarray(g)).all()
         _close(g, w)
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_one_product_off_the_kernels_takes_the_whole_layer_off_them(
+        monkeypatch, form):
+    """ONE route a layer call: where the first products' blocks pass the
+    VMEM bound and the last product's do not, `gmm_route` alone would
+    send the last one to the kernels; the layer's route is "plain" and
+    its program holds no Pallas call — no map leaves a tile unwritten
+    that `lax.ragged_dot` then reads."""
+    run, args = _expert_pass(form, "share_8_of_128_at_40", "even")
+    plain = str(jax.make_jaxpr(jax.grad(lambda *a: run(*a)[0],
+                                        argnums=(0, 1, 2, 3, 4)))(*args))
+    calls = _on_the_kernel_route(monkeypatch, poison=True)
+    monkeypatch.setattr(
+        gm, "_vmem_bytes",
+        lambda c, n: gm._VMEM_LIMIT + 1 if c == D else 0)
+    assert gm.gmm_route((T * K, D), (8, D, F), 128) == "plain"
+    assert gm.gmm_route((T * K, F), (8, F, D), 128) == "kernel"
+    assert moe.layer_route(T * K, *args[2:], 128) == "plain"
+    traced = str(jax.make_jaxpr(jax.grad(lambda *a: run(*a)[0],
+                                         argnums=(0, 1, 2, 3, 4)))(*args))
+    assert not calls and "pallas_call" not in traced
+    assert traced == plain

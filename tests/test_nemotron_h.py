@@ -326,6 +326,8 @@ def _on_the_kernel_route(monkeypatch, tile):
     monkeypatch.setattr(gm, "_ROW_TILE", tile)
     monkeypatch.setattr(gm, "_grouped_kernels", functools.partial(
         kernels, interpret=True))
+    monkeypatch.setattr(gm, "_rows_map_kernels", functools.partial(
+        gm._rows_map_kernels, interpret=True))
 
 
 @pytest.mark.parametrize("route", ["plain", "kernel"])
@@ -340,9 +342,10 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
     layer, params, x = _expert_layer(whole)
     want = _reference_layer(params, x, whole)
     _, upd = layer.apply({"params": params}, x, mutable=["intermediates"])
-    assert "moe_gmm_tiles" not in upd["intermediates"]
-    assert "moe_gmm_tiles_share" not in collect_moe_stats(
-        upd["intermediates"])
+    for counter in ("moe_gmm_tiles", "moe_map_tiles"):
+        assert counter not in upd["intermediates"]
+        assert f"{counter}_share" not in collect_moe_stats(
+            upd["intermediates"])
     if route == "kernel":
         _on_the_kernel_route(monkeypatch, 32)
     shared = jnp.square(jax.nn.relu(
@@ -373,6 +376,13 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
         # (the plain route counts in the kernels' own tile of 256 rows)
         assert (walked, of) == ((visits, 6) if route == "kernel" else (1, 1))
         assert float(collect_moe_stats(inter)["moe_gmm_tiles_share"]) \
+            == pytest.approx(walked / of)
+        # the passes between the products walk the tiles that hold a
+        # held row: within one tile of the held rows' share
+        walked, of = (int(v) for v in inter["moe_map_tiles"][0])
+        assert (walked, of) == ((-(-held // 32), 6) if route == "kernel"
+                                else (1, 1))
+        assert float(collect_moe_stats(inter)["moe_map_tiles_share"]) \
             == pytest.approx(walked / of)
     assert rows == 2 * 48 * 2  # every assignment is held by one chip
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=0,
@@ -737,6 +747,8 @@ def test_a_few_trainer_steps_count_the_share_and_leave_the_bias_alone(
         assert a["moe_rows_held"] > 0 and a["moe_dropped"] == 0.0
         # off the TPU the grouped products walk the whole buffer
         assert a["moe_gmm_tiles_share"] == 1.0
+        # and so do the elementwise passes between them
+        assert a["moe_map_tiles_share"] == 1.0
     after = jax.tree.map(np.asarray, tr.state.params)
     for path, old in jax.tree_util.tree_flatten_with_path(before)[0]:
         new = functools.reduce(lambda t, k: t[k.key], path, after)

@@ -145,6 +145,8 @@ def _on_the_kernel_route(monkeypatch, tile):
     monkeypatch.setattr(gm, "_ROW_TILE", tile)
     monkeypatch.setattr(gm, "_grouped_kernels", functools.partial(
         kernels, interpret=True))
+    monkeypatch.setattr(gm, "_rows_map_kernels", functools.partial(
+        gm._rows_map_kernels, interpret=True))
 
 
 @pytest.mark.parametrize("route", ["plain", "kernel"])

@@ -19,6 +19,8 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
     _no_fusion_falls_to_the_root,
     _no_persistent_cache,
     _one_chip_step,
+    _row_buffer_walkers,
+    _rows_map_calls,
     topo,
 )
 
@@ -41,15 +43,16 @@ def test_smallthinker_step_fits_one_chip_by_the_rule_and_fills_it(
         smallthinker_step):
     """State + temporaries under 90% of the chip's 16 GB at the shipped
     batch (PR 26's rule; described compiles read 11.09 / 14.37 GB live at
-    1 / 2 sequences), of which 6.71 GB is donated state; far over the
-    25% a cell has to fill."""
+    1 / 2 sequences; 14.26 since PR 38: the masks over the row buffer
+    and the sum of two row gradients' temporaries went), of which 6.71
+    GB is donated state; far over the 25% a cell has to fill."""
     cell, model, step = smallthinker_step
     assert model.config.num_params() == 559_290_880
     assert cell["seq_len"] == 16384
     m = step.memory_analysis()
     live = m.argument_size_in_bytes + m.temp_size_in_bytes \
         + m.output_size_in_bytes - m.alias_size_in_bytes
-    want = {1: 11.09, 2: 14.37}[cell["global_batch"]]
+    want = {1: 11.09, 2: 14.26}[cell["global_batch"]]
     assert live / 1e9 == pytest.approx(want, abs=0.05)
     assert 0.25 * 16 * 2 ** 30 < 0.65 * 16e9 < live < 0.90 * 16e9, live / 1e9
     assert m.alias_size_in_bytes >= 12 * model.config.num_params()
@@ -116,6 +119,27 @@ def test_smallthinker_step_holds_its_scopes_and_a_share_of_reglu_experts(
         ("dwt_gmm_t", f"{rows},768"): 4, ("dwt_gmm_t", f"{rows},2560"): 8,
         ("dwt_tgmm", "16,2560,768"): 8, ("dwt_tgmm", "16,768,2560"): 4}
     assert "[64,2560,768]" not in text and "[64,768,2560]" not in text
+    assert " while(" not in text and " conditional(" not in text
+
+
+def test_smallthinker_step_walks_its_row_buffer_in_gathers_alone(
+        smallthinker_step):
+    """The elementwise passes of a share's four expert layers are
+    `dwt_rows_map_*` kernels over the tiles that hold a held row: ReGLU
+    forward and recomputed (8) and its backward (4) over (T*k, 768), the
+    sum of the two first products' row gradients (4) over (T*k, 2560),
+    all under `moe/experts`, and the combine's backward pair (4) under
+    `moe/combine` — and no fusion under either scope still has a (T*k,
+    width) operand but the gathers."""
+    cell, _, step = smallthinker_step
+    text = step.as_text()
+    rows = cell["global_batch"] * 16384 * 6
+    assert _rows_map_calls(text) == {
+        ("dwt_rows_map_gated_relu", f"{rows},768"): 8,
+        ("dwt_rows_map_gated_relu_bwd", f"{rows},768"): 4,
+        ("dwt_rows_map_add", f"{rows},2560"): 4,
+        ("dwt_rows_map_weigh", f"{rows},2560"): 4}
+    assert _row_buffer_walkers(text, rows) == []
     assert " while(" not in text and " conditional(" not in text
 
 

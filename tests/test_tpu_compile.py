@@ -442,19 +442,64 @@ def test_nemotron_step_runs_the_kernels_at_d128_t8192(nemotron_step):
     assert f"bf16[{b * 32},8192,128]" not in text
 
 
-def _grouped_kernel_calls(text):
+def _grouped_kernel_calls(text, kernels="dwt_gmm|dwt_tgmm"):
     """{custom call: (scope, the bf16 shapes of its result and operands)}
-    of the `dwt_gmm*` / `dwt_tgmm*` kernels in a compiled step's text."""
+    of the `dwt_gmm*` / `dwt_tgmm*` kernels (or of `kernels`) in a
+    compiled step's text."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
 
     table = scope_table(text)
     calls = {}
     for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%((?:dwt_gmm|dwt_tgmm)[\w.]*) = ", line)
+        m = re.match(rf"\s*(?:ROOT )?%((?:{kernels})[\w.]*) = ", line)
         if m and "custom-call(" in line:
             calls[m.group(1)] = (table[m.group(1)],
                                  re.findall(r"bf16\[([\d,]+)\]", line))
     return calls
+
+
+def _rows_map_calls(text):
+    """{(kernel, its first result's shape): custom calls} of the
+    `dwt_rows_map_*` kernels in a compiled step's text, every one under
+    `moe/experts` or `moe/combine`."""
+    calls = _grouped_kernel_calls(text, "dwt_rows_map")
+    assert all("/moe/experts/" in scope or "/moe/combine/" in scope
+               for scope, _ in calls.values()), calls
+    return collections.Counter(
+        (re.sub(r"\.\d+$", "", name), shapes[0])
+        for name, (_, shapes) in calls.items())
+
+
+def _row_buffer_walkers(text, rows):
+    """The device ops a compiled step still runs over a whole (rows,
+    width) buffer under `moe/experts` or `moe/combine`, the row gathers
+    left out (a fusion that holds a `gather`; per-row arrays (rows, 1)
+    are no buffer) and the kernels too (their grids follow the held
+    rows): what an elementwise pass of `jax.numpy` over the T*k-row
+    buffer compiles to."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
+        owners, parse_computations)
+
+    comps = parse_computations(text)
+    ins = {i["name"]: i for body in comps.values() for i in body}
+    buffer = re.compile(rf"\[{rows},(?!1\])\d+\]")
+    found = []
+    for name, entry in owners(text).items():
+        i = ins.get(name)
+        if i is None or i["opcode"] in (
+                "custom-call", "parameter", "bitcast", "get-tuple-element",
+                "tuple") or not any(
+                    s in entry["scope"]
+                    for s in ("moe/experts", "moe/combine")):
+            continue
+        shapes = [i["shape"]] + [ins[o]["shape"] for o in i["operands"]
+                                 if o in ins]
+        if not any(buffer.search(s) for s in shapes):
+            continue
+        if any(m["opcode"] == "gather" for m in comps.get(i["calls"], [])):
+            continue
+        found.append((name, i["opcode"], entry["scope"]))
+    return found
 
 
 def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
@@ -502,6 +547,38 @@ def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
                         for ln in wide if "op_name=" in ln)
     assert any("out_of_band" in s for s in scopes)
     assert " while(" not in text and " conditional(" not in text
+
+
+def test_nemotron_step_walks_its_row_buffer_in_gathers_alone(nemotron_step):
+    """The elementwise passes of a share's four expert layers are
+    `dwt_rows_map_*` kernels over the tiles that hold a held row: relu^2
+    forward and recomputed (8) and its backward (4) over (T*k, 1856)
+    under `moe/experts`, the combine's backward pair (4) over (T*k,
+    2688) under `moe/combine` — and no fusion under either scope still
+    has a (T*k, width) operand but the gathers."""
+    cell, _, step = nemotron_step
+    text = step.as_text()
+    rows = cell["global_batch"] * 8192 * 6
+    assert _rows_map_calls(text) == {
+        ("dwt_rows_map_relu2", f"{rows},1856"): 8,
+        ("dwt_rows_map_relu2_bwd", f"{rows},1856"): 4,
+        ("dwt_rows_map_weigh", f"{rows},2688"): 4}
+    assert _row_buffer_walkers(text, rows) == []
+    # a map reserves the VMEM it holds and says what it costs at most,
+    # so the compiler still stages the 88 MB source of the cotangent's
+    # gather into expert order in VMEM under the calls before it (`S(1)`
+    # in the operand's layout), as it does the forward gathers': from
+    # HBM such a gather takes 4.2 ms for 0.8 (PERF.md section 6, PR 38)
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import parse_computations
+
+    ins = {i["name"]: i for body in parse_computations(text).values()
+           for i in body}
+    sources = [ins[i["operands"][0]]["shape"] for i in ins.values()
+               if i["opcode"] == "fusion"
+               and i["op_name"].endswith("moe/combine/gather")
+               and "transpose(jvp" in i["op_name"]
+               and i["shape"].startswith(f"bf16[{rows},2688]")]
+    assert len(sources) == 4 and all("S(1)" in s for s in sources), sources
 
 
 # --------------------------------- granite-4.0-h-micro's step on one chip
@@ -718,6 +795,8 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
         assert kernels == {f"{rows},1024": 3, f"{rows},2048": 3,
                            "64,2048,1024": 2, "64,1024,2048": 1}
         assert len(ragged) == 11 and not ours
+        # a whole layer fills its buffer: no map, the compiler's fusions
+        assert "dwt_rows_map" not in text
     else:
         assert not kernels and not ragged
         assert ours == {

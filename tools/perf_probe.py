@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_sweep|attn_direct|head|model|opt|
-step|lib|dispatch|rpc|gmm] ...  (no args = step/attn/head/model/opt).  One JSON line
+step|lib|dispatch|rpc|gmm|rows_map] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -20,6 +20,10 @@ product of a chip's share of an expert layer (98,304 rows of which
 6,800 are held in 8 groups) as `ops/grouped_matmul.py`'s kernels and as
 `lax.ragged_dot` run it: a stand-alone jit puts layout copies of its
 own around either, so a host clock around the call measures those.
+`rows_map` reads the same way what the elementwise passes between those
+products cost, as the compiler's fusions over the whole T*k-row buffer
+and as `dwt_rows_map_*` over the tiles that hold a held row, at both
+share cells' shapes and held shares.
 """
 
 from __future__ import annotations
@@ -618,13 +622,67 @@ def probe_gmm(rows=98304, sizes=(1530, 400, 950, 700, 1100, 520, 900, 700)):
                        "device_ops_ms": _device_ops_ms(fn, *args)})
 
 
+def probe_rows_map():
+    """The elementwise passes of a share's expert layer at both cells'
+    shapes and held shares (the hybrid's relu^2 over 98,304 x 1856 at
+    7.2% held, SmallThinker's ReGLU over 196,608 x 768 at 29.7%; the sum
+    of two row gradients and the combine's backward pair over the model
+    width): `models/moe.py`'s `jax.numpy` lines under their masks, jitted
+    alone, against `dwt_rows_map_*` (PERF.md section 6, PR 38)."""
+    from dlrover_wuqiong_tpu.models import moe
+    from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
+
+    def draw(seed, *shapes, dtype=jnp.bfloat16):
+        keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+        return [jax.random.normal(k, s, dtype) for k, s in zip(keys, shapes)]
+
+    for rows, d, f, held, gate_act in ((98304, 2688, 1856, 7050, None),
+                                       (196608, 2560, 768, 58400,
+                                        jax.nn.relu)):
+        act = moe._activation(gate_act)
+        n = 1 if gate_act is None else 2
+        held_rows = jnp.int32(held)
+        mask = (jnp.arange(rows) < held)[:, None]
+        products = draw(0, *[(rows, f)] * n)
+        d_h, = draw(1, (rows, f))
+        ys, d_rows = draw(2, (rows, d), (rows, d))
+        gates, = draw(3, (rows, 1), dtype=jnp.float32)
+
+        def plain_act(*a):
+            return act(*(jnp.where(mask, x, 0) for x in a))[0]
+
+        def plain_weigh(ys, d_rows, gates):
+            dots = (ys.astype(jnp.float32) * d_rows).sum(-1)
+            return (d_rows * gates).astype(ys.dtype), dots
+
+        def mapped(fn, alias=None):
+            return lambda *a: gm.rows_map(fn, held_rows, *a, alias=alias)
+
+        def grad_of(fn):
+            return lambda *a: jax.vjp(fn, *a[:-1])[1](a[-1])
+
+        for name, fn, args in (
+                ("plain_act", plain_act, products),
+                ("plain_act_bwd", grad_of(plain_act), (*products, d_h)),
+                ("plain_sum_of_two", jnp.add, (ys, d_rows)),
+                ("plain_weigh", plain_weigh, (ys, d_rows, gates)),
+                ("map_act", mapped(act), products),
+                ("map_act_bwd", grad_of(lambda *a: mapped(act)(*a)[0]),
+                 (*products, d_h)),
+                ("map_sum_of_two", mapped(gm._add), (ys, d_rows)),
+                ("map_weigh", mapped(moe._weigh), (ys, d_rows, gates))):
+            _emit_raw({"probe": "rows_map", "what": name,
+                       "shape": [rows, d, f], "held_rows": held,
+                       "device_ops_ms": _device_ops_ms(jax.jit(fn), *args)})
+
+
 ALL = {"attn": probe_attn, "attn_sweep": probe_attn_sweep,
        "attn_direct": probe_attn_direct, "lib": probe_lib,
        "remat": probe_remat,
        "splash": probe_splash, "dots": probe_dots,
        "head": probe_head, "model": probe_model, "opt": probe_opt,
        "step": probe_step, "dispatch": probe_dispatch,
-       "rpc": probe_rpc, "gmm": probe_gmm}
+       "rpc": probe_rpc, "gmm": probe_gmm, "rows_map": probe_rows_map}
 
 
 def main(argv=None) -> int:
