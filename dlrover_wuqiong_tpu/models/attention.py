@@ -74,17 +74,24 @@ def window_tiles(cfg, batch: int, n_head: int, seq: int):
 
 
 def collect_attention_stats(intermediates) -> dict:
-    """What the windowed attention layers of one forward pass counted,
-    summed over the layers — {} for a model with none: `attn_tiles_window`
-    and `attn_tiles_causal` (`window_tiles`)."""
+    """What the attention layers of one forward pass counted, summed over
+    the layers — {} for a model that sows neither: `attn_tiles_window`
+    and `attn_tiles_causal` of the windowed layers (`window_tiles`),
+    `attn_lanes_run` and `attn_lanes_model` of the latent ones
+    (`models/latent_attention.py`: the lanes a score entry's two
+    products run as the kernels block them, and the lanes the model's
+    widths ask)."""
     from .moe import _sown
 
-    tiles = [v.reshape(-1, 2) for v in _sown(intermediates, "attn_tiles")]
-    if not tiles:
-        return {}
-    with jax.named_scope("attn_tiles"):  # the sum's copies get an owner
-        window, causal = jnp.concatenate(tiles).sum(0)
-    return {"attn_tiles_window": window, "attn_tiles_causal": causal}
+    stats = {}
+    for sown, names in (
+            ("attn_tiles", ("attn_tiles_window", "attn_tiles_causal")),
+            ("attn_lanes", ("attn_lanes_run", "attn_lanes_model"))):
+        pairs = [v.reshape(-1, 2) for v in _sown(intermediates, sown)]
+        if pairs:
+            with jax.named_scope(sown):  # the sum's copies get an owner
+                stats.update(zip(names, jnp.concatenate(pairs).sum(0)))
+    return stats
 
 
 def attend_projected(proj, n_head: int, cfg, causal: bool = True):
@@ -122,7 +129,8 @@ def attend_projected(proj, n_head: int, cfg, causal: bool = True):
 
 
 def attend(q, k, v, cfg, causal: bool = True):
-    """q/k/v in flax layout (b, T, h, d); returns (b, T, h, d): the
+    """q/k/v in flax layout (b, T, h, d) — v, and what comes back, may
+    have a width of their own on one device — returns (b, T, h, d): the
     TRANSPOSED route of every impl — ring and Ulysses over the mesh's
     `sp` axis, the kernels inside a shard_map on any other multi-device
     mesh, `mha` on one device (the kernels on (b, h, T, d) on the TPU,
@@ -130,6 +138,11 @@ def attend(q, k, v, cfg, causal: bool = True):
     impl = getattr(cfg, "attn_impl", "flash")
     mesh = getattr(cfg, "mesh", None)
     scale, window = softmax_scale(cfg), attention_window(cfg)
+    if q.shape[-1] != v.shape[-1] and mesh is not None and mesh.size > 1:
+        raise ValueError(
+            f"q and k {q.shape[-1]} wide beside v {v.shape[-1]} (latent "
+            f"attention) runs on one device: ring, Ulysses and the "
+            f"shard_map of a mesh are handed one width")
     if impl in ("ring", "ulysses") and mesh is not None:
         from ..parallel.long_context import ring_attention, ulysses_attention
 

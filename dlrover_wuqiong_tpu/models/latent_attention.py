@@ -1,0 +1,126 @@
+"""Latent attention (multi-head latent attention, DeepSeek-V2's MLA,
+arXiv:2405.04434): keys and values are up-projections of ONE normed
+low-rank latent a token, and the part of a key that carries the position
+is one rotated vector shared by every head.
+
+    q        = h Wq                    -> heads x (nope | rope)
+    c        = h Wkv_a                 -> (latent of kv_lora_rank | rope key)
+    kv       = RMSNorm(c[:rank]) Wkv_b -> heads x (k_nope | v)
+    q_rope, k_rope rotated; k_h = (k_nope_h | k_rope), the same k_rope
+    o_h      = softmax_causal(q_h k_h^T / sqrt(nope + rope)) v_h
+    out      = concat_h(o_h) Wo
+
+So a head's q and k are `qk_nope_head_dim + qk_rope_head_dim` wide (192)
+and its v `v_head_dim` (128): `ops/flash_attention.py`'s kernels take the
+two widths as they are, QK^T over the one and PV over the other, on the
+transposed (b, h, T, d) route (`attention_route(h, 192, 128)`: a head of
+a slab and a half lies on no slab boundary).  What is NOT here: a latent
+for q (`q_lora_rank`: refused, not guessed), a kernel that takes
+`k_rope` once instead of broadcast to the heads and joined to `k_nope`
+in HBM (ROADMAP, Speed), the cache of latents a server would keep
+(`serving/`), and a mesh (ring, Ulysses and the shard_map of
+`parallel/long_context.py` are handed one width).
+
+Scopes in the compiled step, all under the module's own name:
+`q_proj`, `kv_a_proj`, `kv_a_norm`, `kv_b_proj`, `o_proj` (the flax
+modules' names), `rope` (the two rotations) and `assemble` (the cut of
+the projections into their parts, the broadcast of `k_rope` and the
+joins into the 192-wide q and k).  The module sows `attn_lanes`: the
+lanes of q/k and v a score entry's two products run as the kernels block
+them, and the lanes the model's widths ask
+(`models/attention.collect_attention_stats`).
+
+Parity: none — the reference trains Llama/GLM-class stacks only.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from .llama import RMSNorm, apply_rope
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionConfig:
+    hidden_size: int = 2048
+    num_heads: int = 16
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    # a latent for q as well (q = RMSNorm(h Wq_a) Wq_b): not built
+    q_lora_rank: Optional[int] = None
+    rms_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    use_flash_attention: bool = True
+    mesh: Any = None
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def attention_params(self) -> int:
+        """q, kv_a, the latent's norm, kv_b, o; no block norm."""
+        h, n, r = self.hidden_size, self.num_heads, self.kv_lora_rank
+        return (h * n * self.qk_head_dim + h * (r + self.qk_rope_head_dim)
+                + r + r * n * (self.qk_nope_head_dim + self.v_head_dim)
+                + n * self.v_head_dim * h)
+
+
+class LatentAttention(nn.Module):
+    config: LatentAttentionConfig
+
+    @nn.compact
+    def __call__(self, x, cos, sin):
+        """x (B, T, hidden); cos, sin: `rope_freqs(qk_rope_head_dim, ...)`."""
+        from ..ops.flash_attention import _kept_mask, kernel_lanes
+        from .attention import attend
+        from .fp8 import dense
+
+        cfg = self.config
+        if cfg.q_lora_rank:
+            raise ValueError(
+                f"q_lora_rank={cfg.q_lora_rank}: a latent for q is not "
+                f"built here (q is one projection of the hidden state)")
+        B, T, C = x.shape
+        H, rank = cfg.num_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        q = dense(cfg, H * (dn + dr), "q_proj", use_bias=False)(x)
+        c = dense(cfg, rank + dr, "kv_a_proj", use_bias=False)(x)
+        latent = RMSNorm(cfg.rms_eps, cfg.dtype, name="kv_a_norm")(
+            c[..., :rank])
+        kv = dense(cfg, H * (dn + dv), "kv_b_proj", use_bias=False)(latent)
+        with jax.named_scope("assemble"):
+            q = q.reshape(B, T, H, dn + dr)
+            kv = kv.reshape(B, T, H, dn + dv)
+            q_nope, q_rope = q[..., :dn], q[..., dn:]
+            k_nope, v = kv[..., :dn], kv[..., dn:]
+            k_rope = c[..., rank:].reshape(B, T, 1, dr)
+        with jax.named_scope("rope"):
+            q_rope = apply_rope(q_rope, cos, sin)
+            k_rope = apply_rope(k_rope, cos, sin)  # ONE for all heads
+        with jax.named_scope("assemble"):
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (B, T, H, dr))], axis=-1)
+        # counted, not timed (static numbers): a score entry's lanes
+        flash = cfg.use_flash_attention
+        self.sow("intermediates", "attn_lanes", jnp.asarray(
+            [kernel_lanes(dn + dr, dv) if flash else dn + dr + dv,
+             dn + dr + dv], jnp.float32))
+        if flash:
+            y = attend(q, k, v, cfg, causal=True)
+        else:
+            att = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+            att = jnp.where(_kept_mask(T, T),
+                            att / jnp.sqrt(jnp.float32(dn + dr)), -jnp.inf)
+            att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
+            y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
+        return dense(cfg, C, "o_proj", use_bias=False)(
+            y.reshape(B, T, H * dv))

@@ -98,8 +98,8 @@ class LlamaConfig:
     def ffn_params(self) -> int:
         """The feed-forward slot: a SwiGLU, or `moe`'s expert layer — the
         router over all experts, the experts HELD here (three matrices
-        each, relu2 two), a selection bias, a shared expert — each expert
-        `intermediate_size` wide."""
+        each, relu2 two), a selection bias, a shared expert of the same
+        form — each routed expert `intermediate_size` wide."""
         h, i = self.hidden_size, self.intermediate_size
         if self.moe is None:
             return 3 * h * i
@@ -107,7 +107,7 @@ class LlamaConfig:
         mats = 2 if m.expert_act == "relu2" else 3
         return (m.num_experts * h + m.held * mats * h * i
                 + (m.num_experts if m.selection_bias else 0)
-                + 2 * h * m.shared_width)
+                + mats * h * m.shared_width)
 
     def num_params(self) -> int:
         h = self.hidden_size

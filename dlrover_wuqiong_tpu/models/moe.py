@@ -139,8 +139,9 @@ class MoEConfig:
     # (relu(x Wg) * (x Wi)) Wd, the same three matrices under another
     # gate | "relu2" relu(x Wi)^2 Wd, no gate matrix
     expert_act: str = "swiglu"
-    # width of one always-on relu2 expert beside the routed ones, on
-    # every token (scope `shared`; with expert_act="relu2"); 0 = none
+    # width of one always-on expert beside the routed ones, on every
+    # token, in the routed experts' form (`expert_act`: relu2's two
+    # matrices, or a gated form's three; scope `shared`); 0 = none
     shared_width: int = 0
     # how many of the num_experts this layer holds, experts first_expert
     # .. first_expert + experts_held - 1; 0 = all of them
@@ -491,7 +492,7 @@ def _aux_loss(cfg: MoEConfig, logits, probs, experts):
 class MoEMLP(nn.Module):
     """Drop-in MLP replacement: router + stacked experts (SwiGLU or
     ReGLU, or relu^2 without a gate matrix), and where `shared_width` is
-    set one always-on relu^2 expert beside them.  The router reads the
+    set one always-on expert of the same form beside them.  The router reads the
     experts' own input unless it is handed another (`router_input`: a
     block whose router sits before its attention).
 
@@ -520,9 +521,6 @@ class MoEMLP(nn.Module):
                 f"impl='grouped': top_k_gating is a softmax router with "
                 f"renormalised gates over SwiGLU experts that are all "
                 f"held, and nothing else")
-        if cfg.shared_width and cfg.expert_act != "relu2":
-            raise ValueError("a shared expert exists in the relu2 form "
-                             "only: no model here has another")
         if cfg.bias_update_rate and not cfg.selection_bias:
             raise ValueError("bias_update_rate sets the selection bias: "
                              "there is none")
@@ -630,8 +628,12 @@ class MoEMLP(nn.Module):
             with jax.named_scope("shared"):
                 dense = functools.partial(nn.Dense, use_bias=False,
                                           dtype=cfg.dtype)
-                h = jnp.square(jax.nn.relu(
-                    dense(cfg.shared_width, name="shared_up_proj")(tokens)))
+                h = dense(cfg.shared_width, name="shared_up_proj")(tokens)
+                if w_gate is None:
+                    h = jnp.square(jax.nn.relu(h))
+                else:  # the routed experts' form: a third matrix, gated
+                    h = _GATE_ACTS[cfg.expert_act](dense(
+                        cfg.shared_width, name="shared_gate_proj")(tokens)) * h
                 out = out + dense(d, name="shared_down_proj")(h)
         return out.reshape(B, T, d)
 

@@ -876,9 +876,9 @@ def _block_specs(slabs: Optional[_Slabs], pack: int, d: int):
             return axis(ij)
         return 0 if axis is None else ij[axis]
 
-    def operand(block, axis, first_slab=0):
-        if slabs is None:
-            return pl.BlockSpec((pack, block, d),
+    def operand(block, axis, first_slab=0, width=d):
+        if slabs is None:  # `width`: v's, o's and dO's where it is not q's
+            return pl.BlockSpec((pack, block, width),
                                 lambda g, *ij: (g, at(ij, axis), 0))
         n = slabs.per_row
         return pl.BlockSpec(
@@ -893,16 +893,20 @@ def _block_specs(slabs: Optional[_Slabs], pack: int, d: int):
     return operand, row
 
 
-def _geometry(q, slabs: Optional[_Slabs]):
-    """(heads in all, lanes a unit, units a grid step, grid steps along
-    the first axis, lanes of o's rows) of a call on `q`'s layout."""
+def _geometry(q, v, slabs: Optional[_Slabs]):
+    """(heads in all, lanes a unit of q and k, lanes a unit of v and o,
+    units a grid step, grid steps along the first axis, lanes of q's
+    rows, lanes of v's rows) of a call on `q`'s layout.  The transposed
+    layout's two widths are the arrays' own (latent attention: q and k
+    192 wide, v and o 128); a slab has one."""
     if slabs is None:
-        bh, _, d = q.shape
+        (bh, _, d), dv = q.shape, v.shape[-1]
         pack = _fit_pack(bh)
-        return bh, d, pack, bh // pack, d
+        return bh, d, dv, pack, bh // pack, d, dv
     groups = q.shape[0] * slabs.per_row
-    return (groups * slabs.heads, slabs.width, 1, groups,
-            slabs.per_row * slabs.width)
+    lanes = slabs.per_row * slabs.width
+    return (groups * slabs.heads, slabs.width, slabs.width, 1, groups,
+            lanes, lanes)
 
 
 def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
@@ -910,16 +914,18 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
                        tile: Optional[int] = None,
                        slabs: Optional[_Slabs] = None,
                        window: Optional[int] = None):
-    """q: (bh, sq, d), k/v: (bh, sk, d) → (o, lse (bh, 1, sq) f32); with
-    `slabs`, q/k/v: (b, s, lanes) as `_Slabs` says → (o (b, sq, h*d),
-    lse (b*h, 1, sq)).
+    """q: (bh, sq, d), k: (bh, sk, d), v: (bh, sk, dv) → (o (bh, sq, dv),
+    lse (bh, 1, sq) f32): dv is d, or v's own width (each a block's whole
+    last dimension: no operand is padded to the other's); with `slabs`,
+    q/k/v: (b, s, lanes) as `_Slabs` says → (o (b, sq, h*d), lse (b*h, 1,
+    sq)).
 
     `tile` overrides `_causal_tile` (tests and sweeps: a tile the size of
     the block is the whole-block mask); no caller of the package sets it.
     `window`: `_effective_window`'s (None: the causal program).
     """
     sq, sk = q.shape[1], k.shape[1]
-    bh, d, pack, groups, lanes = _geometry(q, slabs)
+    bh, d, dv, pack, groups, _, lanes = _geometry(q, v, slabs)
     heads = slabs.heads if slabs else 1
     qo, ko, vo = slabs.offsets if slabs else (0, 0, 0)
     block_q = min(block_q, sq)
@@ -942,8 +948,8 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
         kernel,
         grid=grid,
         in_specs=[operand(block_q, 0, qo), operand(block_k, keys, ko),
-                  operand(block_k, keys, vo)],
-        out_specs=(operand(block_q, 0), row(block_q, 0)),
+                  operand(block_k, keys, vo, dv)],
+        out_specs=(operand(block_q, 0, width=dv), row(block_q, 0)),
         out_shape=(
             _out_struct(q.shape[:2] + (lanes,), q.dtype, q),
             _out_struct((bh, 1, sq), jnp.float32, q),
@@ -953,7 +959,7 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
         scratch_shapes=[] if causal and num_kv == 1 else [
             pltpu.VMEM((pack * heads, block_q, 1), jnp.float32),
             pltpu.VMEM((pack * heads, block_q, 1), jnp.float32),
-            pltpu.VMEM((pack, block_q, d), jnp.float32),
+            pltpu.VMEM((pack, block_q, dv), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          vmem_limit=100 * 1024 * 1024),
@@ -1272,16 +1278,17 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                         glse=None, tile: Optional[int] = None,
                         slabs: Optional[_Slabs] = None,
                         window: Optional[int] = None):
-    """All operands flat (bh, s, d), or with `slabs` (b, s, lanes) as
+    """All operands flat (bh, s, d) — v, o and dO (bh, s, dv) where v has
+    a width of its own — or with `slabs` (b, s, lanes) as
     `_fa_forward_pallas` takes and gives them; lse (bh, 1, sq) f32.
-    Returns dq, dk, dv in o's layout.
+    Returns dq, dk, dv, each in its operand's layout.
 
     The kernels recompute p in TRANSPOSED space (queries in lanes) so the
     per-row lse/delta broadcast natively — see `_p_transposed`.  delta and
     the optional lse cotangent `glse` (bh, 1, sq) fold together outside
     (d lse / d s = p, so ds = p * (dp - delta + glse))."""
     sq, sk = q.shape[1], k.shape[1]
-    bh, d, pack, groups, lanes = _geometry(q, slabs)
+    bh, d, dv, pack, groups, lanes, v_lanes = _geometry(q, v, slabs)
     qo, ko, vo = slabs.offsets if slabs else (0, 0, 0)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
@@ -1318,7 +1325,7 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
 
     dq_shape = _out_struct(q.shape[:2] + (lanes,), q.dtype, q)
     dk_shape = _out_struct(k.shape[:2] + (lanes,), k.dtype, q)
-    dv_shape = _out_struct(v.shape[:2] + (lanes,), v.dtype, q)
+    dv_shape = _out_struct(v.shape[:2] + (v_lanes,), v.dtype, q)
     ops = [q, k, v, do, lse, delta]
 
     if num_q == 1 and num_kv == 1:
@@ -1331,10 +1338,11 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                 pack=pack, window=window, **plan),
             grid=(groups,),
             in_specs=[operand(block_q, None, qo), operand(block_k, None, ko),
-                      operand(block_k, None, vo), operand(block_q, None),
+                      operand(block_k, None, vo, dv),
+                      operand(block_q, None, width=dv),
                       row(block_q, None), delta_spec(block_q, None)],
             out_specs=(operand(block_q, None), operand(block_k, None),
-                       operand(block_k, None)),
+                       operand(block_k, None, width=dv)),
             out_shape=(dq_shape, dk_shape, dv_shape),
             # dq across key tiles, one unit at a time
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
@@ -1363,7 +1371,8 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                           **plan, **dq_win),
         grid=(groups, num_q, dq_steps),
         in_specs=[operand(block_q, 0, qo), operand(block_k, keys, ko),
-                  operand(block_k, keys, vo), operand(block_q, 0),
+                  operand(block_k, keys, vo, dv),
+                  operand(block_q, 0, width=dv),
                   row(block_q, 0), delta_spec(block_q, 0)],
         out_specs=operand(block_q, 0),
         out_shape=dq_shape,
@@ -1382,13 +1391,14 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                           **plan, **dkv_win),
         grid=(groups, num_kv, dkv_steps),
         in_specs=[operand(block_q, queries, qo), operand(block_k, 0, ko),
-                  operand(block_k, 0, vo), operand(block_q, queries),
+                  operand(block_k, 0, vo, dv),
+                  operand(block_q, queries, width=dv),
                   row(block_q, queries), delta_spec(block_q, queries)],
-        out_specs=(operand(block_k, 0), operand(block_k, 0)),
+        out_specs=(operand(block_k, 0), operand(block_k, 0, width=dv)),
         out_shape=(dk_shape, dv_shape),
         scratch_shapes=[
             pltpu.VMEM((pack, block_k, d), jnp.float32),
-            pltpu.VMEM((pack, block_k, d), jnp.float32),
+            pltpu.VMEM((pack, block_k, dv), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
                                          vmem_limit=100 * 1024 * 1024),
@@ -1443,7 +1453,10 @@ def flash_attention(q, k, v, causal: bool = True,
                     window: Optional[int] = None):
     """Multi-head attention, FA2-style.
 
-    Args: q (b, h, sq, d); k, v (b, h, sk, d).  Returns (b, h, sq, d).
+    Args: q (b, h, sq, d); k (b, h, sk, d); v (b, h, sk, dv), dv = d or
+    a width of v's own (latent attention: QK^T over 192, PV over 128; the
+    kernels block each operand at its own width, none is padded to the
+    other's).  Returns (b, h, sq, dv).  `sm_scale` None: 1/sqrt(d), q's.
     `block_q`/`block_k` are the GRID's blocks, capped at the sequence: at
     T <= 1024 the grid is one block each way, which is what lets the
     backward be the one fused kernel.  What a causal call skips below
@@ -1513,6 +1526,15 @@ def _kernel_head_dim(d: int) -> int:
     return d if d % 8 == 0 else max(128, -(-d // 128) * 128)
 
 
+def kernel_lanes(d_qk: int, d_v: int) -> int:
+    """Lanes a score entry's two products run in the kernels, QK^T over
+    q's and k's width and PV over v's, each as the kernels block it
+    (`_kernel_head_dim`): the counter of what a call's widths cost
+    beside what its model asks (d_qk + d_v), as `causal_tile_count` is of
+    its tiles."""
+    return _kernel_head_dim(d_qk) + _kernel_head_dim(d_v)
+
+
 def _pad_head_dim(x, d_pad):
     d = x.shape[-1]
     if d == d_pad:
@@ -1520,16 +1542,18 @@ def _pad_head_dim(x, d_pad):
     return jnp.pad(x, ((0, 0), (0, 0), (0, d_pad - d)))
 
 
-def _flat_padded(q, k, v, d_pad):
-    b, h, sq, d = q.shape
-    qf = _pad_head_dim(q.reshape(b * h, sq, d), d_pad)
-    kf = _pad_head_dim(k.reshape(b * h, k.shape[2], d), d_pad)
-    vf = _pad_head_dim(v.reshape(b * h, v.shape[2], d), d_pad)
-    return qf, kf, vf
+def _flat_padded(q, k, v):
+    """(b, h, s, d) -> (b*h, s, d as the kernels see it), each of q, k
+    and v at its own width."""
+    return tuple(_pad_head_dim(x.reshape(-1, *x.shape[2:]),
+                               _kernel_head_dim(x.shape[-1]))
+                 for x in (q, k, v))
 
 
 def _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k, window=None):
-    """Shared forward: returns ((out, lse_bhs), residuals)."""
+    """Shared forward: returns ((out, lse_bhs), residuals).  q and k
+    share a width and v may have its own, which is the output's; the
+    default scale is 1/sqrt(q's)."""
     b, h, sq, d = q.shape
     sk = k.shape[2]
     scale = _resolve_scale(sm_scale, d)
@@ -1537,11 +1561,11 @@ def _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k, window=None):
     if _use_pallas(sq, sk, d, block_q, block_k):
         bq = _fit_block(sq, block_q)
         bk = _fit_block(sk, block_k)
-        d_pad = _kernel_head_dim(d)
-        qf, kf, vf = _flat_padded(q, k, v, d_pad)
+        qf, kf, vf = _flat_padded(q, k, v)
         o, lse = _fa_forward_pallas(qf, kf, vf, causal, scale, bq, bk,
                                     interpret=False, window=window)
-        out = o[:, :, :d].reshape(b, h, sq, d)
+        dv = v.shape[-1]
+        out = o[:, :, :dv].reshape(b, h, sq, dv)
         return (out, lse.reshape(b, h, sq)), (q, k, v, o, lse)
     if _use_streamed(sq, sk):
         out, lse = _streamed_with_lse(q, k, v, causal, scale, block_k,
@@ -1574,12 +1598,12 @@ def _streamed_with_lse(q, k, v, causal, scale, block_k, window=None):
     memory-faithful any-backend stand-in for the kernel (used by the 8B
     AOT fit proof on the virtual CPU mesh)."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[-1]
     bk = _fit_block(sk, min(block_k, 512)) or sk
     nb = sk // bk
     q32 = q.astype(jnp.float32)
     kb = jnp.moveaxis(k.reshape(b, h, nb, bk, d), 2, 0)
-    vb = jnp.moveaxis(v.reshape(b, h, nb, bk, d), 2, 0)
+    vb = jnp.moveaxis(v.reshape(b, h, nb, bk, dv), 2, 0)
     rows = jnp.arange(sq) + (sk - sq)  # absolute key index each row sees
 
     def body(carry, inp):
@@ -1606,7 +1630,7 @@ def _streamed_with_lse(q, k, v, causal, scale, block_k, window=None):
             "bhqk,bhkd->bhqd", p, vblk.astype(jnp.float32))
         return (acc, m_new, l), None
 
-    init = (jnp.zeros((b, h, sq, d), jnp.float32),
+    init = (jnp.zeros((b, h, sq, dv), jnp.float32),
             jnp.full((b, h, sq), NEG_INF, jnp.float32),
             jnp.zeros((b, h, sq), jnp.float32))
     (acc, m, l), _ = jax.lax.scan(body, init, (jnp.arange(nb), kb, vb))
@@ -1623,7 +1647,7 @@ def _streamed_bwd(q, k, v, out, lse, g, causal, scale, block_q, glse,
     Each step re-derives p for its q block from the stored lse, emits the
     block's dq, and accumulates dk/dv — peak temps O(h * block_q * sk)."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[-1]
     bq = _fit_block(sq, min(block_q, 512)) or sq
     nb = sq // bq
     q32, k32, v32 = (t.astype(jnp.float32) for t in (q, k, v))
@@ -1633,7 +1657,7 @@ def _streamed_bwd(q, k, v, out, lse, g, causal, scale, block_q, glse,
         delta = delta - glse
     lse_safe = jnp.where(jnp.isfinite(lse), lse, 0.0)
     qb = jnp.moveaxis(q32.reshape(b, h, nb, bq, d), 2, 0)
-    gb = jnp.moveaxis(g32.reshape(b, h, nb, bq, d), 2, 0)
+    gb = jnp.moveaxis(g32.reshape(b, h, nb, bq, dv), 2, 0)
     lb = jnp.moveaxis(lse_safe.reshape(b, h, nb, bq), 2, 0)
     db = jnp.moveaxis(delta.reshape(b, h, nb, bq), 2, 0)
     cols = jnp.arange(sk)
@@ -1655,7 +1679,7 @@ def _streamed_bwd(q, k, v, out, lse, g, causal, scale, block_q, glse,
         return (dk, dv), dqblk
 
     init = (jnp.zeros((b, h, sk, d), jnp.float32),
-            jnp.zeros((b, h, sk, d), jnp.float32))
+            jnp.zeros((b, h, sk, dv), jnp.float32))
     (dk, dv), dqb = jax.lax.scan(
         body, init, (jnp.arange(nb), qb, gb, lb, db))
     dq = jnp.moveaxis(dqb, 0, 2).reshape(b, h, sq, d)
@@ -1680,17 +1704,15 @@ def _fa_bwd_impl(causal, sm_scale, block_q, block_k, res, g, glse,
     if lse is not None:  # pallas forward ran: pallas backward
         bq = _fit_block(sq, block_q)
         bk = _fit_block(sk, block_k)
-        d_pad = _kernel_head_dim(d)
-        qf, kf, vf = _flat_padded(q, k, v, d_pad)
-        gf = _pad_head_dim(g.reshape(b * h, sq, d), d_pad)
+        qf, kf, vf = _flat_padded(q, k, v)
+        gf = _pad_head_dim(g.reshape(b * h, sq, -1), vf.shape[-1])
         glse_f = None if glse is None else glse.reshape(b * h, 1, sq)
         dq, dk, dv = _fa_backward_pallas(qf, kf, vf, out, lse,
                                          gf, causal, scale, bq, bk,
                                          interpret=False, glse=glse_f,
                                          window=window)
-        return (dq[:, :, :d].reshape(b, h, sq, d).astype(q.dtype),
-                dk[:, :, :d].reshape(b, h, sk, d).astype(k.dtype),
-                dv[:, :, :d].reshape(b, h, sk, d).astype(v.dtype))
+        return tuple(dx[:, :, :x.shape[-1]].reshape(x.shape).astype(x.dtype)
+                     for dx, x in ((dq, q), (dk, k), (dv, v)))
     # jnp recompute fallback (matches _attention_reference numerics)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
@@ -1759,18 +1781,24 @@ flash_attention_with_lse.defvjp(_fa_lse_fwd, _fa_lse_bwd)
 # ------------------------------------- the projections' own (b, s, h*d) layout
 
 
-def attention_route(n_head: int, head_dim: int) -> Tuple[str, int]:
+def attention_route(n_head: int, head_dim: int,
+                    v_head_dim: Optional[int] = None) -> Tuple[str, int]:
     """Which layout a model's attention hands the kernels, from its
     shape alone: ("direct", heads a slab) when the heads fall on 128-lane
     slab boundaries of the projections' (b, s, h*d) output — a head is a
     slab or more (d % 128 == 0), or two heads of 64 share one and there
     is an even number of them; ("transposed", 0) otherwise: the
-    (b, h, s, d) arrays of `flash_attention`.
+    (b, h, s, d) arrays of `flash_attention`.  `head_dim` is q's and
+    k's; a `v_head_dim` of its own (latent attention's 192 beside 128:
+    a head of a slab and a half beside one of a slab) is transposed
+    whatever the two are, a slab having one width.
 
     The counter of this decision, as `causal_tile_count` is of the
     tiles: `projected_ok` asks it for the dispatcher, and
     tests/test_program_from_arguments.py pins it for the benchmark's
     cells."""
+    if v_head_dim not in (None, head_dim):
+        return "transposed", 0
     if head_dim % 128 == 0:
         return "direct", 1
     if head_dim == 64 and n_head % 2 == 0:
@@ -1781,13 +1809,14 @@ def attention_route(n_head: int, head_dim: int) -> Tuple[str, int]:
 _PROJECTED_BLOCK = 1024  # the direct calls' preferred block, q and keys
 
 
-def projected_ok(n_head: int, head_dim: int, seq: int) -> bool:
+def projected_ok(n_head: int, head_dim: int, seq: int,
+                 v_head_dim: Optional[int] = None) -> bool:
     """Whether `flash_attention_projected` takes a self-attention of this
     shape: the heads on slab boundaries (`attention_route`), on the TPU,
     at a sequence a block fits (`_use_pallas`).  The one predicate of the
     direct route: `models/attention.attend_projected` adds only where the
     call runs (the mesh), and the entry itself refuses what this does."""
-    return (attention_route(n_head, head_dim)[0] == "direct"
+    return (attention_route(n_head, head_dim, v_head_dim)[0] == "direct"
             and _use_pallas(seq, seq, head_dim, _PROJECTED_BLOCK,
                             _PROJECTED_BLOCK))
 

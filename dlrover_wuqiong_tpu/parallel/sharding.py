@@ -58,6 +58,14 @@ TRANSFORMER_RULES: List[Rule] = [
     # attention qkv: column-parallel (heads split over tp)
     (r".*(attn|attention).*(q_proj|k_proj|v_proj|qkv|c_attn|query|key|value)"
      r"/kernel$", P("fsdp", "tp")),
+    # latent attention (models/latent_attention.py): the down-projection
+    # to the latent and the ONE rotated key part is small and feeds every
+    # head, so its columns stay whole; the up-projection from the normed
+    # latent is column-parallel (heads split over tp) as q_proj is; the
+    # latent's norm is a norm
+    (r".*(attn|attention).*kv_a_proj/kernel$", P("fsdp", None)),
+    (r".*(attn|attention).*kv_b_proj/kernel$", P("fsdp", "tp")),
+    (r".*(attn|attention).*kv_a_norm/scale$", P()),
     # attention out: row-parallel (parity RowParallelLinear :239)
     (r".*(attn|attention).*(o_proj|out_proj|c_proj|dense|out)/kernel$",
      P("tp", "fsdp")),

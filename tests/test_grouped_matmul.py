@@ -175,6 +175,22 @@ def test_the_route_is_the_calls_shapes_mesh_and_backend(
                         _mesh(mesh)) == route
 
 
+@pytest.mark.parametrize("cell,rows,held,d,f,named,first", [
+    # relu2 experts: no gate matrix
+    ("nemotron3_nano_30b_a3b.steady", 98304, 8, 2688, 1856, 128, False),
+    ("smallthinker_21b_a3b.steady", 196608, 16, 2560, 768, 64, True),
+    ("kimi_vl_a3b.steady", 196608, 8, 2048, 1408, 64, True),
+])
+def test_the_share_cells_layers_take_the_kernels(monkeypatch, cell, rows,
+                                                 held, d, f, named, first):
+    """`experts_route` at the three share cells' own T*k rows and weight
+    shapes, on one TPU device: "kernel", whole layer."""
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    shapes = [(held, d, f)] * (1 + first) + [(held, f, d)]
+    assert gm.experts_route(rows, shapes, named) == "kernel"
+    assert gm.experts_route(rows, shapes, held) == "plain"  # all held
+
+
 def test_blocks_that_do_not_fit_vmem_keep_the_plain_route(monkeypatch):
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
     assert gm.gmm_route((4096, 32768), (8, 32768, 1856), 128) == "plain"
@@ -330,6 +346,7 @@ def test_the_maps_grid_is_the_tiles_that_hold_a_held_row(held):
 @pytest.mark.parametrize("d,f,route", [
     (2688, 1856, "kernel"),     # the hybrid cell's share
     (2560, 768, "kernel"),      # SmallThinker's
+    (2048, 1408, "kernel"),     # the latent-attention MoE's SwiGLU experts
     (32768, 1856, "plain"),     # the first product's blocks pass VMEM
     (1856, 32768, "plain"),     # the last product's do
     (8192, 1024, "plain"),      # no product's, the maps' do

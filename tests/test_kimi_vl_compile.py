@@ -1,0 +1,181 @@
+"""`kimi_vl_a3b.steady`'s step and its two-width attention kernels,
+compiled by the TPU's own compiler for a DESCRIBED v5e (no chip
+attached), as tests/test_tpu_compile.py does for the other cells — whose
+helpers these tests use.  A file of its own so that another xdist worker
+compiles this step (about 70 s) while those files compile the other
+five.
+"""
+
+import collections
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are fixtures
+    _compile,
+    _every_device_op_has_an_owner,
+    _grouped_kernel_calls,
+    _no_fusion_falls_to_the_root,
+    _no_persistent_cache,
+    _one_chip_step,
+    _row_buffer_walkers,
+    _rows_map_calls,
+    topo,
+)
+
+from dlrover_wuqiong_tpu.ops import flash_attention as fa
+from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
+
+
+@pytest.fixture(scope="module")
+def kimi_vl_step(topo):
+    """`kimi_vl_a3b.steady`'s step — published widths, the leading dense
+    layer and four expert layers, 8 of 64 SwiGLU experts held beside the
+    shared one, an eighth of the vocabulary, the cell's batch of
+    16,384-token sequences, full recomputation (about 70 s)."""
+    return _one_chip_step(topo, "kimi_vl_a3b.steady", "kimi_vl")
+
+
+def test_kimi_vl_step_fits_one_chip_by_the_rule_and_fills_it(kimi_vl_step):
+    """State + temporaries under 90% of the chip's 16 GB at the shipped
+    batch (PR 26's rule; described compiles read 11.08 / 14.27 GB live at
+    1 / 2 sequences at depth 5, 12.50 at the fallback's 1 at depth 6), of
+    which 6.82 GB is donated state; far over the 25% a cell has to fill."""
+    cell, model, step = kimi_vl_step
+    assert model.config.num_params() == 568_484_608
+    assert (cell["seq_len"], cell["global_batch"]) == (16384, 2)
+    m = step.memory_analysis()
+    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    rung = cell["config"]["train"]["memory_rung"]
+    assert live / 1e9 == pytest.approx(
+        rung["live_GB"]["2 x 16384 at depth 5"], abs=0.05)
+    assert 0.25 * 16 * 2 ** 30 < 0.65 * 16e9 < live < 0.90 * 16e9, live / 1e9
+    assert m.alias_size_in_bytes >= 12 * model.config.num_params()
+
+
+def test_kimi_vl_step_runs_the_kernels_at_192_and_128_unpadded(kimi_vl_step):
+    """Five latent layers run the causal kernels forward, recomputed and
+    backward: 10, 5 and 5 custom calls under their `dwt_fa_*` names.  16
+    heads of 192 | 128 lie on no slab boundary: the TRANSPOSED route on
+    (batch x 16, 16384, d), q and k handed in 192 wide and v, o and dO
+    128 wide; nothing is padded to 256 lanes or to the other's width."""
+    cell, _, step = kimi_vl_step
+    text = step.as_text()
+    calls = collections.Counter(re.findall(
+        r"%(dwt_fa_\w+?)(?:\.\d+)? = ", text))
+    assert calls == {"dwt_fa_fwd": 10, "dwt_fa_bwd_dq": 5,
+                     "dwt_fa_bwd_dkv": 5}
+    assert fa.attention_route(16, 192, 128) == ("transposed", 0)
+    bh = cell["global_batch"] * 16
+    shapes = collections.Counter()
+    for line in text.splitlines():
+        m = re.match(r"\s*%(dwt_fa_\w+?)(?:\.\d+)? = ", line)
+        if m and "custom-call(" in line:
+            operands = line.split("operand_layout_constraints={", 1)[1]
+            shapes[m.group(1), tuple(re.findall(
+                r"(?:bf16|f32)\[[\d,]+\]",
+                operands.split("}, frontend", 1)[0]))] += 1
+    wide, narrow = f"bf16[{bh},16384,192]", f"bf16[{bh},16384,128]"
+    row = f"f32[{bh},1,16384]"
+    ins = (wide, wide, narrow, narrow, row, row)
+    assert shapes == {("dwt_fa_fwd", (wide, wide, narrow)): 10,
+                      ("dwt_fa_bwd_dq", ins): 5,
+                      ("dwt_fa_bwd_dkv", ins): 5}
+    assert f"bf16[{bh},16384,256]" not in text
+    assert " while(" not in text and " conditional(" not in text
+
+
+def test_kimi_vl_step_holds_its_scopes_and_a_share_of_swiglu_experts(
+        kimi_vl_step):
+    """Every scope the cell's scopes file names is in the compiled step.
+    A share's three grouped products a layer run `ops/grouped_matmul.py`'s
+    kernels — twelve a layer: three forward, three recomputed, six
+    backward — every one under `feed_forward/moe/experts`, every weight
+    operand the 8 held experts, none the published 64; no `ragged-dot`;
+    the SwiGLU shared expert under `moe/shared`; layer 0's dense SwiGLU
+    under `feed_forward` itself.  No auxiliary term is sown."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+
+    cell, _, step = kimi_vl_step
+    text = step.as_text()
+    scopes = set(scope_table(text).values())
+    for part in ("attention/q_proj", "attention/kv_a_proj",
+                 "attention/kv_a_norm", "attention/kv_b_proj",
+                 "attention/rope", "attention/assemble", "attention/o_proj",
+                 "feed_forward/moe/router", "feed_forward/moe/dispatch",
+                 "feed_forward/moe/experts", "feed_forward/moe/combine",
+                 "feed_forward/moe/shared/shared_gate_proj",
+                 "layers/feed_forward/gate_proj",
+                 "layers/feed_forward/down_proj", "input_norm",
+                 "post_attn_norm", "LatentMoE/head", "loss", "optimizer",
+                 "attn_lanes"):
+        assert any(part in s for s in scopes), part
+    assert not any("moe/aux" in s for s in scopes)
+    rows = cell["global_batch"] * 16384 * 6
+    assert gm.experts_route(rows, [(8, 2048, 1408), (8, 2048, 1408),
+                                   (8, 1408, 2048)], 64) == "plain"  # off TPU
+    calls = _grouped_kernel_calls(text)
+    assert len(calls) == 48 and "ragged-dot" not in text
+    assert all("feed_forward/moe/experts/dwt_" in scope
+               for scope, _ in calls.values()), calls
+    ours = collections.Counter(
+        (re.sub(r"[.\d]+$", "", name), shapes[0])
+        for name, (_, shapes) in calls.items())
+    assert ours == {
+        ("dwt_gmm", f"{rows},1408"): 16, ("dwt_gmm", f"{rows},2048"): 8,
+        ("dwt_gmm_t", f"{rows},1408"): 4, ("dwt_gmm_t", f"{rows},2048"): 8,
+        ("dwt_tgmm", "8,2048,1408"): 8, ("dwt_tgmm", "8,1408,2048"): 4}
+    assert "[64,2048,1408]" not in text and "[64,1408,2048]" not in text
+    assert " while(" not in text and " conditional(" not in text
+
+
+def test_kimi_vl_step_walks_its_row_buffer_in_gathers_alone(kimi_vl_step):
+    """The elementwise passes of a share's four expert layers are
+    `dwt_rows_map_*` kernels over the tiles that hold a held row: SwiGLU
+    forward and recomputed (8) and its backward (4) over (T*k, 1408), the
+    sum of the two first products' row gradients (4) over (T*k, 2048),
+    the combine's backward pair (4) — and no fusion under either scope
+    still has a (T*k, width) operand but the gathers."""
+    cell, _, step = kimi_vl_step
+    text = step.as_text()
+    rows = cell["global_batch"] * 16384 * 6
+    assert _rows_map_calls(text) == {
+        ("dwt_rows_map_gated_silu", f"{rows},1408"): 8,
+        ("dwt_rows_map_gated_silu_bwd", f"{rows},1408"): 4,
+        ("dwt_rows_map_add", f"{rows},2048"): 4,
+        ("dwt_rows_map_weigh", f"{rows},2048"): 4}
+    assert _row_buffer_walkers(text, rows) == []
+
+
+@pytest.mark.parametrize("seq", [16384, 1024])
+def test_two_width_kernels_compile_at_the_cells_shape(topo, seq):
+    """Two sequences' 32 heads, q and k 192 wide beside v 128, blocks of
+    1,024: the forward and the split backward at the cell's 16,384, and
+    the fused backward at one block each way."""
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((32, seq, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((32, seq, 128), jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((32, 1, seq), jnp.float32, sharding=one)
+    fwd = _compile(lambda q, k, v: fa._fa_forward_pallas(
+        q, k, v, True, 192 ** -0.5, 1024, 1024, False), q, q, v)
+    assert "dwt_fa_fwd" in fwd and "tpu_custom_call" in fwd
+    bwd = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
+        q, k, v, o, l, do, True, 192 ** -0.5, 1024, 1024, False),
+        q, q, v, v, lse, v)
+    names = ("dwt_fa_bwd_dq", "dwt_fa_bwd_dkv") if seq > 1024 \
+        else ("dwt_fa_bwd_fused",)
+    assert all(name in bwd for name in names)
+
+
+def test_every_device_op_of_the_step_has_an_owner(kimi_vl_step):
+    """As the other five steps (tests/test_tpu_compile.py); the lane
+    counts' copies are the scope `attn_lanes`'s
+    (`models/attention.collect_attention_stats`)."""
+    _every_device_op_has_an_owner(kimi_vl_step[2])
+
+
+def test_no_fusion_of_the_step_falls_to_the_models_root(kimi_vl_step):
+    _no_fusion_falls_to_the_root(kimi_vl_step[2], "LatentMoE")

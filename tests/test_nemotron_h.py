@@ -529,12 +529,21 @@ def test_new_fields_at_their_defaults_leave_olmoes_layer_as_it_was():
                                np.asarray(want), atol=1e-6)
 
 
-def test_a_shared_expert_is_refused_beside_swiglu_experts():
+def test_a_shared_expert_beside_swiglu_experts_is_a_swiglu():
+    """Once refused (relu2 was the one form a shared expert had): it takes
+    the routed experts' form, three matrices, and the count follows."""
     cfg = MoEConfig(num_experts=4, top_k=2, impl="grouped", shared_width=16,
                     dtype=jnp.float32)
-    with pytest.raises(ValueError, match="relu2"):
-        MoEMLP(hidden=32, ffn=24, moe=cfg).init(
-            jax.random.PRNGKey(1), jnp.zeros((1, 8, 32)))
+    params = MoEMLP(hidden=32, ffn=24, moe=cfg).init(
+        jax.random.PRNGKey(1), jnp.zeros((1, 8, 32)))["params"]
+    assert {k: v["kernel"].shape for k, v in params.items()
+            if k.startswith("shared")} == {
+        "shared_gate_proj": (32, 16), "shared_up_proj": (32, 16),
+        "shared_down_proj": (16, 32)}
+    llama = dataclasses.replace(LlamaConfig.nano(), hidden_size=32,
+                                intermediate_size=24, moe=cfg)
+    assert llama.ffn_params() == sum(
+        a.size for a in jax.tree.leaves(params))
 
 
 # ------------------------------------------------- attention's two fields

@@ -183,6 +183,39 @@ def test_the_cells_attention_plans(monkeypatch, b, h, t, d, form, route,
         assert "concatenate" not in _primitives(jaxpr)
 
 
+def test_the_latent_cells_attention_plan(monkeypatch):
+    """`kimi_vl_a3b.steady`'s attention from its shape alone: 16 heads
+    whose q and k are 192 wide and whose v is 128 lie on no slab
+    boundary, so `attend` hands the kernels the transposed (b*h, T, d)
+    arrays, 8 heads a grid step on 16 blocks of 1,024 a side, each
+    operand at its own width and none padded."""
+    from dlrover_wuqiong_tpu.models.attention import attend, goes_direct
+    from dlrover_wuqiong_tpu.models.latent_attention import (
+        LatentAttentionConfig,
+    )
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    cfg = LatentAttentionConfig()
+    assert (cfg.num_heads, cfg.qk_head_dim, cfg.v_head_dim) == (16, 192, 128)
+    assert fa.attention_route(16, 192, 128) == ("transposed", 0)
+    assert not fa.projected_ok(16, 192, 16384, 128)
+    assert not goes_direct(cfg, 16, 192, 16384)  # a slab and a half
+    q = jax.ShapeDtypeStruct((2, 16384, 16, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, 16384, 16, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: attend(q, k, v, cfg).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(q, q, v)
+    assert [a.aval.shape for a in jaxpr.jaxpr.outvars] == [
+        q.shape, q.shape, v.shape]
+    groups = 2 * 16 // fa._fit_pack(2 * 16)
+    assert sorted(_pallas_calls(jaxpr.jaxpr)) == sorted(
+        (name, (groups, 16, 16)) for name in
+        ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"))
+    assert "pad" not in _primitives(jaxpr.jaxpr)
+    assert fa.kernel_lanes(192, 128) == 192 + 128
+    assert fa.causal_tile_count(16384, 16384) == (528, 1024)
+
+
 @pytest.mark.parametrize("window,names,sweep,tiles", [
     # the GLOBAL layer: the causal kernels over all 16 x 16 blocks
     (0, ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"), 16, (528, 1024)),
@@ -569,18 +602,17 @@ def test_a_retired_variable_changes_no_program_and_no_key(monkeypatch, name,
 # -------------------------- (C) fused, split and reference backward agree
 
 
-@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("d,dv", [(64, 64), (128, 128), (192, 128)])
 @pytest.mark.parametrize("sq,sk", [
     (128, 128), (256, 256), (128, 256), (256, 128)])
-def test_fused_and_split_backward_agree_with_the_reference(sq, sk, d):
+def test_fused_and_split_backward_agree_with_the_reference(sq, sk, d, dv):
     """The same arrays through both forms of the backward — one block
     each way, and the blocks halved — and through `jax.grad` of the plain
-    reference (interpret mode)."""
+    reference (interpret mode); q and k `d` wide, v and dO `dv`."""
     keys = jax.random.split(jax.random.PRNGKey(sq + sk + d), 4)
-    q, g = (jax.random.normal(kx, (2, sq, d), jnp.float32)
-            for kx in keys[:2])
-    k, v = (jax.random.normal(kx, (2, sk, d), jnp.float32)
-            for kx in keys[2:])
+    widths = {0: (sq, dv), 1: (sq, d), 2: (sk, d), 3: (sk, dv)}
+    g, q, k, v = (jax.random.normal(kx, (2, *widths[i]), jnp.float32)
+                  for i, kx in enumerate(keys))
     scale = d ** -0.5
 
     def loss(q, k, v):
