@@ -49,12 +49,25 @@ Design (FA2 scheme, canonical Mosaic structure):
   one no shorter than the keys — traces the program it did before any
   of this existed (pinned: tests/test_flash_attention_tiles.py).  The
   jnp paths off the TPU take the same window (`_kept_mask`).
-- backward: two kernels — dq (grid: q outer, kv inner) and dk/dv (grid: kv
-  outer, q inner) — each recomputing p = exp(s - lse) per tile IN
-  TRANSPOSED SPACE (queries in lanes) so the (sq, sk) attention matrix
-  never hits HBM and the per-row lse/delta broadcast without relayouts.
-  One block each way takes ONE fused kernel instead (dq, dk and dv from
-  a single recompute of p).
+- backward: ONE kernel wherever it can be — dq, dk and dv from a single
+  recompute of p = exp(s - lse) per tile, IN TRANSPOSED SPACE (queries
+  in lanes) so the (sq, sk) attention matrix never hits HBM and the
+  per-row lse/delta broadcast without relayouts: five products and one
+  elementwise pass a kept tile.  One block each way is a kernel of its
+  own on a grid of heads (`_fa_bwd_fused_kernel`).  Several blocks run
+  the dk/dv sweep (grid: kv outer, q inner; dk and dv summed in float32
+  scratch over a key block's queries) with dq summed in a float32
+  scratch that spans the unit's WHOLE query length and written to an
+  output block of the same span: that block's index is constant over
+  both inner grid axes, so it stays in VMEM from the group's first grid
+  step to its last (16,384 x 128 lanes: 8 MiB of scratch and 4 of
+  bfloat16 block, double-buffered; the packed heads a grid step are as
+  many as fit).  Where a unit's dq does not fit the VMEM the call states
+  (131,072 x 128 lanes) it is two kernels — dq (grid: q outer, kv
+  inner) and dk/dv — each with a recompute of its own, seven products
+  and two passes.  `backward_route` says which, from shapes alone; the
+  kernels' names say it in a trace (`dwt_fa_bwd_fused`, or
+  `dwt_fa_bwd_dq` + `dwt_fa_bwd_dkv`).
 - TWO LAYOUTS, one set of kernels.  TRANSPOSED: `flash_attention` /
   `flash_attention_with_lse` take (b, h, s, d) and index the flat
   (b*h, s, d) arrays a group of `_fit_pack` heads a grid step; head_dim
@@ -122,6 +135,11 @@ def _on_tpu() -> bool:
     # a backend that fails to initialise raises from here: training on
     # the jnp reference because the chip did not come up is not a mode
     return jax.default_backend() == "tpu"
+
+
+# what every kernel here asks of the v5e's 128 MiB of VMEM, and what
+# `backward_route` reckons a fused backward's resident set against
+_VMEM_LIMIT = 100 * 1024 * 1024
 
 
 def _compiler_params(*semantics, vmem_limit: Optional[int] = None):
@@ -962,7 +980,7 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
             pltpu.VMEM((pack, block_q, dv), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
-                                         vmem_limit=100 * 1024 * 1024),
+                                         vmem_limit=_VMEM_LIMIT),
         interpret=interpret,
         name=_kernel_name("fwd", window),
     )(q, k, v)
@@ -994,7 +1012,7 @@ def _dot_c0(a, b):
 def _p_transposed(q, k, lse, mask, sm_scale):
     """Recompute p^T = exp(s^T - lse) as (block_k, block_q).
 
-    Both backward kernels work in transposed space — scores with queries in
+    The backward kernels work in transposed space — scores with queries in
     LANES — so the per-row lse/delta arrive as native (1, block_q) row
     vectors and broadcast straight across sublanes.  The row-major layout
     (bh, 1, sq) costs no 128x lane padding in HBM and no per-grid-step
@@ -1096,21 +1114,57 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_scr, dv_scr, *, num_q: int,
-                       causal: bool, sm_scale: float, block_q: int,
-                       block_k: int, kv_offset: int, pack: int,
+                       *outs, num_q: int, causal: bool, sm_scale: float,
+                       block_q: int, block_k: int, kv_offset: int, pack: int,
                        diag_off: Optional[int] = None,
                        tile: Optional[int] = None, slab_heads: int = 1,
                        delta_from_o: bool = False,
                        window: Optional[int] = None, **sweep):
+    """dk and dv of a key block, summed in float32 scratch over the query
+    blocks that see it (grid: key blocks outer, query blocks inner):
+    `outs` = dk, dv and their scratch.
+
+    Handed dq's too (`outs` = dq, dk, dv, then the three scratches) it is
+    the several-block FUSED backward: the same sweep, the same recompute
+    of p, and one more product a tile, `_dot_c0(dsT, k)`, added into a
+    float32 dq that spans the unit's WHOLE query length (`dq_scr`:
+    (pack * query blocks, block_q, lanes), a query block found by its
+    leading index).  dq's output block is the whole sequence of the
+    group as well: its index is constant over both inner grid axes, so
+    it stays in VMEM from the group's first grid step to its last, is
+    written once, at the last, and leaves for HBM while the next group
+    runs.  A query block's sum runs over its key blocks in ascending
+    order, as the dq kernel's does."""
     ki = pl.program_id(1)
     qi = step = pl.program_id(2)
     heads = range(slab_heads)
+    if len(outs) == 6:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = outs
+        q_blocks = dq_scr.shape[0] // pack
+    else:
+        (dk_ref, dv_ref, dk_scr, dv_scr), dq_ref, dq_scr = outs, None, None
+
+    def _each_dq_block(body):  # a loop, not q_blocks copies of the code
+        def _block(i, carry):
+            for hh in range(pack):
+                body(hh, i)
+            return carry
+
+        jax.lax.fori_loop(0, q_blocks, _block, 0)
 
     @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    if dq_scr is not None:
+        @pl.when((step == 0) & (ki == 0))
+        def _init_dq():
+            def _zero(hh, i):
+                dq_scr[hh * q_blocks + i] = jnp.zeros(
+                    dq_scr.shape[1:], jnp.float32)
+
+            _each_dq_block(_zero)
 
     if window is not None:
         qi, run, place = _sweep_place(ki, step, True, block_q, block_k,
@@ -1153,6 +1207,13 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                              * sm_scale).astype(q.dtype) for a in heads]
                     dk_scr[keys] += functools.reduce(jnp.add, [
                         _dot(dsTs[a], qs[a]) for a in heads])  # (keys, d)
+                    if dq_scr is not None:
+                        # against the slab's k, right in the head's own
+                        # lanes
+                        dq_scr[(hh * q_blocks + qi,)
+                               + _rows(q0, q1, block_q)] += _join(
+                            [_dot_c0(dsTs[a], k) for a in heads],
+                            dq_scr.shape[-1])                # (rows, d)
 
         _each_head(pack, work, _unit)
 
@@ -1180,6 +1241,16 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dk_ref[hh] = dk_scr[hh].astype(dk_ref.dtype)
             dv_ref[hh] = dv_scr[hh].astype(dv_ref.dtype)
 
+    if dq_scr is not None:
+        @pl.when((step == num_q - 1) & (ki == pl.num_programs(1) - 1))
+        def _finalize_dq():
+            def _write(hh, i):
+                rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+                dq_ref[hh, rows] = dq_scr[hh * q_blocks + i].astype(
+                    dq_ref.dtype)
+
+            _each_dq_block(_write)
+
 
 def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dk_ref, dv_ref, *dq_scr, causal: bool,
@@ -1189,15 +1260,16 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          tile: Optional[int] = None, slab_heads: int = 1,
                          delta_from_o: bool = False,
                          window: Optional[int] = None):
-    """Single-block fused backward: dq, dk AND dv in one pass.
-
-    Only legal when the whole sequence fits one block each way (num_q ==
-    num_kv == 1) — the general case cannot fuse because dq accumulates
+    """Single-block fused backward: dq, dk AND dv in one pass, for a call
+    whose whole sequence is one block each way (num_q == num_kv == 1): a
+    grid of heads alone, no sum across grid steps, dk and dv written
+    where they are computed.  Against the split pair's 7 dots it saves
+    the second S and dP recomputes and one full exp pass over the score
+    matrix.  Several blocks fuse too, by another means: dq accumulates
     over the kv grid axis while dk/dv accumulate over the q axis, and a
     Pallas TPU output block only stays resident across CONSECUTIVE grid
-    steps (the reason the split kernels exist).  At the 1k-context bench
-    shape this saves 2 of the split path's 7 dots (the second S and dP
-    recomputes) and one full exp pass over the score matrix.
+    steps — so `_fa_bwd_dkv_kernel` keeps a dq block of the WHOLE
+    sequence, whose index never changes inside a group.
 
     Causal, the block is computed by key tile (`_block_work`): a tile's
     dk and dv come out whole from the queries that see it, and only dq
@@ -1273,11 +1345,68 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _each_head(pack, work, _unit)
 
 
+def _fused_bwd_vmem(pack: int, sq: int, block_q: int, block_k: int,
+                    d_qk: int, d_v: int, slab_heads: int,
+                    itemsize: int) -> int:
+    """Bytes of VMEM a fused several-block backward holds at `pack` units
+    a grid step, reckoned from shapes: every block at the 128-lane tiles
+    it is stored in (192 lanes lie on 256), operands and results
+    double-buffered, and two float32 score tiles a head of the slab for
+    the values of the body (what Mosaic holds of them, compiled for a
+    described v5e at the cells' shapes, is half of that: 43, 81, 24 and
+    22 MiB used where this says 46, 84, 28 and 29)."""
+    wq, wv = (-(-w // 128) * 128 for w in (d_qk, d_v))
+    dq = pack * sq * wq * (4 + 2 * itemsize)     # scratch, output block
+    dkv = pack * block_k * (wq + wv) * (4 + 2 * itemsize)
+    # q, k, v, dO, and o where the kernel sums delta itself
+    ins = 2 * pack * itemsize * (block_q * (wq + wv) + block_k * (wq + wv)
+                                 + block_q * wv * (slab_heads > 1))
+    rows = 2 * 2 * pack * slab_heads * 8 * block_q * 4   # lse, delta
+    live = 2 * 4 * block_q * block_k * slab_heads
+    return dq + dkv + ins + rows + live
+
+
+def backward_route(sq: int, sk: int, d_qk: int, d_v: Optional[int] = None,
+                   slabs: int = 0, bh: int = 8, block_q: int = 1024,
+                   block_k: int = 1024, itemsize: int = 2) -> Tuple[str, int]:
+    """Which kernels a backward call runs, from its shapes alone:
+    ("fused", units a grid step) — ONE kernel gives dq, dk and dv from a
+    single recompute of p — or ("split", units a grid step): the dq and
+    dk/dv pair, each with a recompute of its own.
+
+    One block each way is fused as it always was.  Several blocks are
+    fused where the WHOLE query length of a unit's dq — float32 scratch
+    and a double-buffered output block — fits `_VMEM_LIMIT` beside the
+    sweep's blocks (`_fused_bwd_vmem`): 16,384 x 128 lanes is 16 MiB,
+    131,072 x 128 does not fit and takes the pair.  `slabs`: the heads a
+    slab of the direct layout (0: the transposed layout, whose `bh`
+    heads are packed by the largest of 8/4/2/1 that divides them and
+    fits).
+
+    The counter of this decision, as `attention_route` is of the layout
+    and `causal_tile_count` of the tiles; in a trace its witness is the
+    kernels' names (`dwt_fa_bwd_fused` / `dwt_fa_bwd_dq` + `_dkv`)."""
+    d_v = d_qk if d_v is None else d_v
+    d_qk, d_v = _kernel_head_dim(d_qk), _kernel_head_dim(d_v)
+    block_q = _fit_block(sq, block_q) or sq
+    block_k = _fit_block(sk, block_k) or sk
+    most = 1 if slabs else _fit_pack(bh)  # what the pair packs
+    if sq == block_q and sk == block_k:
+        return "fused", most
+    for pack in (8, 4, 2, 1):
+        if pack <= most and _fused_bwd_vmem(
+                pack, sq, block_q, block_k, d_qk, d_v, slabs or 1,
+                itemsize) <= _VMEM_LIMIT:
+            return "fused", pack
+    return "split", most
+
+
 def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                         block_q: int, block_k: int, interpret: bool,
                         glse=None, tile: Optional[int] = None,
                         slabs: Optional[_Slabs] = None,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None,
+                        route: Optional[Tuple[str, int]] = None):
     """All operands flat (bh, s, d) — v, o and dO (bh, s, dv) where v has
     a width of its own — or with `slabs` (b, s, lanes) as
     `_fa_forward_pallas` takes and gives them; lse (bh, 1, sq) f32.
@@ -1286,12 +1415,22 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     The kernels recompute p in TRANSPOSED space (queries in lanes) so the
     per-row lse/delta broadcast natively — see `_p_transposed`.  delta and
     the optional lse cotangent `glse` (bh, 1, sq) fold together outside
-    (d lse / d s = p, so ds = p * (dp - delta + glse))."""
+    (d lse / d s = p, so ds = p * (dp - delta + glse)).
+
+    `route` overrides `backward_route` for a several-block call (tests
+    and sweeps: the pair at a shape that fuses, another pack); no caller
+    of the package sets it."""
     sq, sk = q.shape[1], k.shape[1]
     bh, d, dv, pack, groups, lanes, v_lanes = _geometry(q, v, slabs)
     qo, ko, vo = slabs.offsets if slabs else (0, 0, 0)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
+    how, pack = route or backward_route(
+        sq, sk, d, dv, slabs.heads if slabs else 0, bh, block_q, block_k,
+        q.dtype.itemsize)
+    fused = how == "fused"
+    if slabs is None:
+        groups = bh // pack
     kv_offset = sk - sq
     num_q = sq // block_q
     num_kv = sk // block_k
@@ -1348,7 +1487,7 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
             if tiled else [],
             compiler_params=_compiler_params(
-                "parallel", vmem_limit=100 * 1024 * 1024),
+                "parallel", vmem_limit=_VMEM_LIMIT),
             interpret=interpret,
             name=_kernel_name("bwd_fused", window),
         )(*ops)
@@ -1364,6 +1503,37 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         keys = _swept(dq_steps, win["koff"], num_kv, False)
         queries = _swept(dkv_steps, win["koff"], num_q, True)
 
+    # grid: kv outer, q inner.  Fused (`backward_route`) the sweep gives
+    # dq too, from a float32 scratch and an output block of the group's
+    # whole query length; else dk and dv alone, and dq has its own kernel
+    sweep = pl.pallas_call(
+        functools.partial(_fa_bwd_dkv_kernel, num_q=dkv_steps, causal=causal,
+                          sm_scale=sm_scale, block_q=block_q,
+                          block_k=block_k, kv_offset=kv_offset, pack=pack,
+                          **plan, **dkv_win),
+        grid=(groups, num_kv, dkv_steps),
+        in_specs=[operand(block_q, queries, qo), operand(block_k, 0, ko),
+                  operand(block_k, 0, vo, dv),
+                  operand(block_q, queries, width=dv),
+                  row(block_q, queries), delta_spec(block_q, queries)],
+        out_specs=(operand(sq, None),) * fused + (
+            operand(block_k, 0), operand(block_k, 0, width=dv)),
+        out_shape=(dq_shape,) * fused + (dk_shape, dv_shape),
+        scratch_shapes=[
+            pltpu.VMEM((pack * num_q, block_q, d), jnp.float32)] * fused + [
+            pltpu.VMEM((pack, block_k, d), jnp.float32),
+            pltpu.VMEM((pack, block_k, dv), jnp.float32),
+        ],
+        # dq's block outlives a key block: the key axis is a sequence too
+        compiler_params=_compiler_params(
+            "parallel", "arbitrary" if fused else "parallel", "arbitrary",
+            vmem_limit=_VMEM_LIMIT),
+        interpret=interpret,
+        name=_kernel_name("bwd_fused" if fused else "bwd_dkv", window),
+    )
+    if fused:
+        return sweep(*ops)
+
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, num_kv=dq_steps, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
@@ -1378,33 +1548,11 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((pack, block_q, d), jnp.float32)],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
-                                         vmem_limit=100 * 1024 * 1024),
+                                         vmem_limit=_VMEM_LIMIT),
         interpret=interpret,
         name=_kernel_name("bwd_dq", window),
     )(*ops)
-
-    # dkv grid: kv outer, q inner — same operands, transposed index maps
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, num_q=dkv_steps, causal=causal,
-                          sm_scale=sm_scale, block_q=block_q,
-                          block_k=block_k, kv_offset=kv_offset, pack=pack,
-                          **plan, **dkv_win),
-        grid=(groups, num_kv, dkv_steps),
-        in_specs=[operand(block_q, queries, qo), operand(block_k, 0, ko),
-                  operand(block_k, 0, vo, dv),
-                  operand(block_q, queries, width=dv),
-                  row(block_q, queries), delta_spec(block_q, queries)],
-        out_specs=(operand(block_k, 0), operand(block_k, 0, width=dv)),
-        out_shape=(dk_shape, dv_shape),
-        scratch_shapes=[
-            pltpu.VMEM((pack, block_k, d), jnp.float32),
-            pltpu.VMEM((pack, block_k, dv), jnp.float32),
-        ],
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
-                                         vmem_limit=100 * 1024 * 1024),
-        interpret=interpret,
-        name=_kernel_name("bwd_dkv", window),
-    )(*ops)
+    dk, dv = sweep(*ops)
     return dq, dk, dv
 
 
@@ -1458,12 +1606,11 @@ def flash_attention(q, k, v, causal: bool = True,
     kernels block each operand at its own width, none is padded to the
     other's).  Returns (b, h, sq, dv).  `sm_scale` None: 1/sqrt(d), q's.
     `block_q`/`block_k` are the GRID's blocks, capped at the sequence: at
-    T <= 1024 the grid is one block each way, which is what lets the
-    backward be the one fused kernel.  What a causal call skips below
-    that grain is not the caller's to set: the kernels cut a block the
-    diagonal crosses into `_CAUSAL_TILE` tiles themselves (module
-    docstring).  `bwd_block_q`/`bwd_block_k` block the dq/dkv backward
-    kernels independently (0 = inherit block_q/block_k; no chip run of
+    T <= 1024 the grid is one block each way.  What a causal call skips
+    below that grain is not the caller's to set: the kernels cut a block
+    the diagonal crosses into `_CAUSAL_TILE` tiles themselves (module
+    docstring).  `bwd_block_q`/`bwd_block_k` block the backward kernels
+    independently (0 = inherit block_q/block_k; no chip run of
     this repository has measured another choice — PERF.md section 6).
     `window`: a causal call's query sees the `window` keys that end at
     its own and none before (None, or a window no shorter than the keys:

@@ -1,11 +1,12 @@
 """The causal kernels compute only the score tiles at or below the
 diagonal of a block (`ops/flash_attention._causal_bands`): the tile set
 against a brute-force mask, its count at the benchmark cells' shapes,
-and interpret-mode parity of the tiled forward, fused backward and split
-backward with the plain reference and its `jax.grad` — on the transposed
-(bh, s, d) layout and on the projections' own (b, s, h*d), one head or
-two a lane slab.  A no-window call's traced program is pinned to what
-it was before the kernels knew a window; the WINDOWED cases, which reuse
+and interpret-mode parity of the tiled forward, the one-block, the fused
+several-block and the split backward with the plain reference and its
+`jax.grad` — on the transposed (bh, s, d) layout and on the projections'
+own (b, s, h*d), one head or two a lane slab — and which of them a
+backward call runs, from its shapes (`backward_route`).  A no-window
+call's traced program is pinned; the WINDOWED cases, which reuse
 this file's inputs, kernels and reference, are their own file so that
 another worker runs them (tests/test_flash_attention_window.py).
 """
@@ -240,9 +241,12 @@ def test_tiled_forward_matches_reference(sq, sk, block_q, block_k, tile,
     np.testing.assert_allclose(lse[:, 0], rlse, atol=2e-5)
 
 
-# one block each way takes the fused kernel; several blocks take the dq
-# and dk/dv kernels.  Every one-block case runs both ways: as it is, and
-# with its blocks halved (a 2 x 2 grid over the same arrays)
+# one block each way takes the one-block fused kernel; several blocks
+# take ONE sweep that gives dq, dk and dv where a unit's whole dq fits
+# VMEM (`fa.backward_route`: every shape here) and the dq and dk/dv pair
+# where it does not.  Every one-block case runs both ways: as it is, and
+# with its blocks halved (a 2 x 2 grid over the same arrays); every
+# several-block case runs on both routes, the pair by `route=`
 def _halved(case):
     sq, sk, block_q, block_k, tile, *rest = case
     return (sq, sk, block_q // 2, block_k // 2,
@@ -250,21 +254,43 @@ def _halved(case):
 
 
 _ONE_BLOCK = [c for c in CASES if c[:2] == c[2:4]]
-BWD_CASES = [c + (False,) for c in _ONE_BLOCK] \
-    + [(_halved(c) if c in _ONE_BLOCK else c) + (True,) for c in CASES]
+BWD_CASES = [c + ("one",) for c in _ONE_BLOCK] + [
+    (_halved(c) if c in _ONE_BLOCK else c) + (route,)
+    for c in CASES for route in ("fused", "split")]
 # "qkv" holds q and k in one array: sq == sk
 assert all(c[0] == c[1] for c in CASES if c[8] == "qkv")
 
 
-def _is_split(sq, sk, block_q, block_k) -> bool:
+def _several(sq, sk, block_q, block_k) -> bool:
     return sq // block_q > 1 or sk // block_k > 1
 
 
+def _route(route, sq, sk, block_q, block_k, bh, d, dv, form, heads) -> dict:
+    """`_fa_backward_pallas`'s `route=` for a case's route: nothing
+    where the rule itself gives it ("one" block; "fused", which every
+    shape of these files is), the pair by hand."""
+    assert (route != "one") == _several(sq, sk, block_q, block_k)
+    slab_heads = fa.attention_route(heads, d)[1] if form else 0
+    rule = fa.backward_route(sq, sk, d, dv, slab_heads, bh, block_q, block_k,
+                             itemsize=4)
+    assert rule == ("fused", 1 if form else fa._fit_pack(bh))
+    return {"route": ("split", rule[1])} if route == "split" else {}
+
+
+def _kernel_names(jaxpr):
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _kernel_names(sub)
+    return sorted(names)
+
+
 @pytest.mark.parametrize(
-    "sq,sk,block_q,block_k,tile,causal,bh,d,form,heads,split", BWD_CASES)
+    "sq,sk,block_q,block_k,tile,causal,bh,d,form,heads,route", BWD_CASES)
 def test_tiled_backward_matches_reference(sq, sk, block_q, block_k, tile,
-                                          causal, bh, d, form, heads, split):
-    assert split == _is_split(sq, sk, block_q, block_k)
+                                          causal, bh, d, form, heads, route):
     q, k, v, g, _ = _inputs(sq, sk, bh, d, seed=1)
     scale = d ** -0.5
     forward, backward = _kernels(form, heads, q, k, v)
@@ -272,36 +298,96 @@ def test_tiled_backward_matches_reference(sq, sk, block_q, block_k, tile,
                      interpret=True, tile=tile)
     dq, dk, dv = backward(
         q, k, v, o, lse, g, causal, scale, block_q, block_k, interpret=True,
-        tile=tile)
+        tile=tile, **_route(route, sq, sk, block_q, block_k, bh, d, d, form,
+                            heads))
     _, _, (rq, rk, rv) = _reference(q, k, v, g, None, causal, scale)
     np.testing.assert_allclose(dq, rq, atol=5e-4)
     np.testing.assert_allclose(dk, rk, atol=5e-4)
     np.testing.assert_allclose(dv, rv, atol=5e-4)
 
 
-@pytest.mark.parametrize("sq,sk,block_q,block_k,tile,split", [
-    (128, 128, 128, 128, 32, False),
-    (128, 128, 64, 64, 16, True),
-    (256, 256, 64, 64, 16, True),
-    (64, 128, 64, 128, 32, False),
-    (128, 256, 64, 64, 16, True),
+@pytest.mark.parametrize("sq,sk,block_q,block_k,tile,route", [
+    (128, 128, 128, 128, 32, "one"),
+    (128, 128, 64, 64, 16, "split"),
+    (128, 128, 64, 64, 16, "fused"),
+    (256, 256, 64, 64, 16, "split"),
+    (256, 256, 64, 64, 16, "fused"),
+    (64, 128, 64, 128, 32, "one"),
+    (128, 256, 64, 64, 16, "split"),
+    (128, 256, 64, 64, 16, "fused"),
 ])
 def test_tiled_backward_takes_the_lse_cotangent(sq, sk, block_q, block_k,
-                                                tile, split):
+                                                tile, route):
     """`flash_attention_with_lse`'s second cotangent, folded into delta,
     reaches every tile's ds."""
-    assert split == _is_split(sq, sk, block_q, block_k)
     q, k, v, g, gl = _inputs(sq, sk, 2, 64, seed=2)
     o, lse = fa._fa_forward_pallas(q, k, v, True, 0.125, block_q, block_k,
                                    interpret=True, tile=tile)
     dq, dk, dv = fa._fa_backward_pallas(
         q, k, v, o, lse, g, True, 0.125, block_q, block_k, interpret=True,
-        glse=gl, tile=tile)
+        glse=gl, tile=tile, **_route(route, sq, sk, block_q, block_k, 2, 64,
+                                     64, None, 0))
     _, _, (rq, rk, rv) = _reference(q, k, v, g, gl, True, 0.125)
     assert float(jnp.abs(gl).max()) > 1.0
     np.testing.assert_allclose(dq, rq, atol=5e-4)
     np.testing.assert_allclose(dk, rk, atol=5e-4)
     np.testing.assert_allclose(dv, rv, atol=5e-4)
+
+
+# ------------------------------------------ which kernels a backward runs
+
+@pytest.mark.parametrize("cell,shape,want", [
+    # sq, sk, d_qk, d_v, heads a slab (0: transposed), heads in the arrays
+    ("gpt2_124m.steady", (1024, 1024, 64, 64, 2, 24 * 12), ("fused", 1)),
+    ("gpt2_xl.fsdp4_steady", (1024, 1024, 64, 64, 0, 4 * 25), ("fused", 4)),
+    ("olmoe_1b_7b.steady", (4096, 4096, 128, 128, 1, 5 * 16), ("fused", 1)),
+    ("nemotron3_nano_30b_a3b.steady", (8192, 8192, 128, 128, 1, 2 * 32),
+     ("fused", 1)),
+    ("granite4_h_micro.steady", (8192, 8192, 64, 64, 2, 32), ("fused", 1)),
+    ("smallthinker_21b_a3b.steady", (16384, 16384, 128, 128, 1, 2 * 28),
+     ("fused", 1)),
+    # 16 MiB of float32 dq a head and as much of output block: two fit
+    ("kimi_vl_a3b.steady", (16384, 16384, 192, 128, 0, 2 * 16),
+     ("fused", 2)),
+    # a head's dq alone is 64 MiB of scratch and 64 of output block
+    ("128k at 128 lanes", (131072, 131072, 128, 128, 1, 16), ("split", 1)),
+    ("128k, transposed", (131072, 131072, 128, 128, 0, 16), ("split", 8)),
+    ("64k still fits", (65536, 65536, 128, 128, 1, 16), ("fused", 1)),
+    # a ring step's block of keys: sq != sk, by the same rule
+    ("ring block", (4096, 16384, 128, 128, 0, 16), ("fused", 8)),
+])
+def test_backward_route_at_the_cells_shapes(cell, shape, want):
+    """`backward_route`: a static function of the call's shapes, fused
+    wherever a unit's whole dq fits the VMEM the call states."""
+    assert fa.backward_route(*shape) == want
+    sq, sk, d, dv, slabs, bh = shape
+    if want[0] == "fused" and sq > 1024:
+        assert fa._fused_bwd_vmem(want[1], sq, 1024, 1024, d, dv, slabs or 1,
+                                  2) <= fa._VMEM_LIMIT
+
+
+@pytest.mark.parametrize("window", [None, 64])
+@pytest.mark.parametrize("route,names", [
+    (None, ["bwd_fused"]), (("fused", 1), ["bwd_fused"]),
+    (("split", 2), ["bwd_dkv", "bwd_dq"])])
+def test_the_route_names_the_kernels(route, names, window):
+    """Several blocks: ONE `pallas_call` on the fused route, whose grid
+    is the dk/dv kernel's and whose dq block is the group's whole query
+    length; the pair on the other."""
+    x = jax.ShapeDtypeStruct((2, 256, 64), jnp.float32)
+    row = jax.ShapeDtypeStruct((2, 1, 256), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
+        q, k, v, o, l, do, True, 0.125, 64, 64, False, tile=16,
+        window=window, route=route))(x, x, x, x, row, x).jaxpr
+    assert _kernel_names(jaxpr) == [fa._kernel_name(n, window) for n in names]
+    if len(names) == 1:
+        call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        gm = call.params["grid_mapping"]
+        pack = route[1] if route else 2
+        assert gm.grid == (2 // pack, 4, 4 if window is None else 2)
+        blocks = [tuple(int(getattr(b, "block_size", b))
+                        for b in bm.block_shape) for bm in gm.block_mappings]
+        assert blocks[6:] == [(pack, 256, 64), (pack, 64, 64), (pack, 64, 64)]
 
 
 def _dots(jaxpr) -> int:
@@ -340,14 +426,18 @@ def test_dots_traced_at_gpt2_shape(causal, tile, fwd, bwd):
 # sha256 of the traced program (kernel bodies included; addresses and
 # source positions taken out) of a no-window attention call, forward and
 # gradient, at the attention shapes of the benchmark's five cells that
-# had none — read on the commit BEFORE the kernels knew a window and
-# unchanged since: their lowering cannot have moved
+# had none.  The forwards, and the gradients of the two ONE-BLOCK cells,
+# were read on the commit BEFORE the kernels knew a window and are
+# unchanged since: their lowering cannot have moved.  The gradients of
+# the three several-block cells were re-taken when their backward
+# became one kernel (PR 40: `backward_route`; before it they read
+# 284030d2600fe654, 468e31377f294c45, c22d4204310af046)
 NO_WINDOW = {
     "gpt2_124m.steady": ("d244927abd3b6e78", "1326a3ad4f1fea22"),
-    "olmoe_1b_7b.steady": ("89c61244ab8db745", "284030d2600fe654"),
+    "olmoe_1b_7b.steady": ("89c61244ab8db745", "87fa5be05e083b57"),
     "nemotron3_nano_30b_a3b.steady": ("8a7845390dc1b315",
-                                      "468e31377f294c45"),
-    "granite4_h_micro.steady": ("c2e4852ee8c4fb1f", "c22d4204310af046"),
+                                      "42e2beca4fe42827"),
+    "granite4_h_micro.steady": ("c2e4852ee8c4fb1f", "4f689be584f47c4b"),
     "gpt2_xl.fsdp4_steady": ("e7adcde6deea4954", "3ad9d7005f42f756"),
 }
 

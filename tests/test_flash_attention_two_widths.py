@@ -1,8 +1,9 @@
 """Attention whose q and k are wider than its v (latent attention: QK^T
 over 192 lanes, PV over 128): `ops/flash_attention.py`'s four kernels in
 interpret mode at d_qk != d_v against the plain reference — forward, the
-fused and the split backward, tiled and whole, with a shifted diagonal,
-under a window, with an lse cotangent — the public entries' jnp paths
+one-block, the fused several-block and the split backward, tiled and
+whole, with a shifted diagonal, under a window, with an lse cotangent —
+the public entries' jnp paths
 (the dense reference and the streamed scan), the default scale, and the
 route's answer.  The cases sit beside tests/test_flash_attention_tiles.
 py's and use its reference; equal widths stay that file's.
@@ -12,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from test_flash_attention_tiles import _reference
+from test_flash_attention_tiles import _reference, _route, _several
 
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
 
@@ -52,10 +53,15 @@ CASES = [
 ]
 
 
+# several blocks: the ONE sweep that gives dq too, and the pair
+ROUTED = [c + (route,) for c in CASES for route in (
+    ("fused", "split") if _several(*c[:4]) else ("one",))]
+
+
 @pytest.mark.parametrize(
-    "sq,sk,block_q,block_k,tile,causal,window,bh,d,dv", CASES)
+    "sq,sk,block_q,block_k,tile,causal,window,bh,d,dv,route", ROUTED)
 def test_forward_and_backward_match_the_reference(
-        sq, sk, block_q, block_k, tile, causal, window, bh, d, dv):
+        sq, sk, block_q, block_k, tile, causal, window, bh, d, dv, route):
     """o has v's width, dq and dk q's, dv v's; every operand is handed
     to the kernels at its own width."""
     q, k, v, g, _ = _inputs(sq, sk, bh, d, dv)
@@ -69,20 +75,25 @@ def test_forward_and_backward_match_the_reference(
     np.testing.assert_allclose(lse[:, 0], rlse, atol=2e-5)
     got = fa._fa_backward_pallas(q, k, v, o, lse, g, causal, scale, block_q,
                                  block_k, interpret=True, tile=tile,
-                                 window=w)
+                                 window=w, **_route(
+                                     route, sq, sk, block_q, block_k, bh, d,
+                                     dv, None, 0))
     assert [x.shape for x in got] == [q.shape, k.shape, v.shape]
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=5e-5)
 
 
-@pytest.mark.parametrize("block", [128, 64])  # fused, split
-def test_backward_takes_the_lse_cotangent(block):
+@pytest.mark.parametrize("block,route", [
+    (128, "one"), (64, "fused"), (64, "split")])
+def test_backward_takes_the_lse_cotangent(block, route):
     q, k, v, g, gl = _inputs(128, 128, 2, 48, 32)
     scale = 48 ** -0.5
     o, lse = fa._fa_forward_pallas(q, k, v, True, scale, block, block,
                                    interpret=True, tile=16)
-    got = fa._fa_backward_pallas(q, k, v, o, lse, g, True, scale, block,
-                                 block, interpret=True, glse=gl, tile=16)
+    got = fa._fa_backward_pallas(
+        q, k, v, o, lse, g, True, scale, block, block, interpret=True,
+        glse=gl, tile=16, **_route(route, 128, 128, block, block, 2, 48, 32,
+                                   None, 0))
     _, _, want = _reference(q, k, v, g, gl, True, scale)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=5e-5)
@@ -118,8 +129,15 @@ def test_the_kernels_block_each_operand_at_its_own_width():
             q, k, v, o, l, do, True, 192 ** -0.5, 1024, 1024, False))(
                 q, q, v, v, lse, v).jaxpr)
     ins = [wide, wide, narrow, narrow, row, row]
-    assert bwd == {"dwt_fa_bwd_dq": ins + [wide],
-                   "dwt_fa_bwd_dkv": ins + [wide, narrow]}
+    # fused: dq's block is the eight heads' whole query length
+    assert fa.backward_route(2048, 2048, 192, 128, 0, 8) == ("fused", 8)
+    assert bwd == {"dwt_fa_bwd_fused": ins + [(8, 2048, 192), wide, narrow]}
+    pair = blocks(jax.make_jaxpr(
+        lambda q, k, v, o, l, do: fa._fa_backward_pallas(
+            q, k, v, o, l, do, True, 192 ** -0.5, 1024, 1024, False,
+            route=("split", 8)))(q, q, v, v, lse, v).jaxpr)
+    assert pair == {"dwt_fa_bwd_dq": ins + [wide],
+                    "dwt_fa_bwd_dkv": ins + [wide, narrow]}
 
 
 @pytest.mark.parametrize("sq,sk,path", [

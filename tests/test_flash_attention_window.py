@@ -13,7 +13,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from test_flash_attention_tiles import _inputs, _kernels, _reference
+from test_flash_attention_tiles import (
+    _inputs,
+    _kernels,
+    _reference,
+    _route,
+    _several,
+)
 
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
 
@@ -55,13 +61,20 @@ WINDOWED = [
 ]
 
 
+# a several-block case runs its backward on both routes: the ONE sweep
+# that gives dq too (the rule's answer at these shapes), and the pair
+ROUTED = [c + (route,) for c in WINDOWED for route in (
+    ("fused", "split") if _several(*c[:4]) else ("one",))]
+
+
 @pytest.mark.parametrize(
-    "sq,sk,block_q,block_k,tile,window,bh,d,form,heads", WINDOWED)
+    "sq,sk,block_q,block_k,tile,window,bh,d,form,heads,route", ROUTED)
 def test_windowed_forward_and_all_three_gradients_match_the_mask(
-        sq, sk, block_q, block_k, tile, window, bh, d, form, heads):
-    """Forward, lse, dq, dk and dv of a windowed call — split kernels on
-    a narrowed grid, or the fused one — against `jax.grad` of the plain
-    reference under the mask written out."""
+        sq, sk, block_q, block_k, tile, window, bh, d, form, heads, route):
+    """Forward, lse, dq, dk and dv of a windowed call — on a narrowed
+    grid the fused sweep or the split kernels, or the one-block fused
+    one — against `jax.grad` of the plain reference under the mask
+    written out."""
     q, k, v, g, _ = _inputs(sq, sk, bh, d, seed=3)
     scale = d ** -0.5
     assert fa._effective_window(window, True, sk) == window
@@ -69,7 +82,9 @@ def test_windowed_forward_and_all_three_gradients_match_the_mask(
     o, lse = forward(q, k, v, True, scale, block_q, block_k,
                      interpret=True, tile=tile, window=window)
     dq, dk, dv = backward(q, k, v, o, lse, g, True, scale, block_q, block_k,
-                          interpret=True, tile=tile, window=window)
+                          interpret=True, tile=tile, window=window,
+                          **_route(route, sq, sk, block_q, block_k, bh, d, d,
+                                   form, heads))
     ro, rlse, (rq, rk, rv) = _reference(q, k, v, g, None, True, scale,
                                         window)
     kept = np.asarray(fa._kept_mask(sq, sk, window))

@@ -58,7 +58,8 @@ def test_kimi_vl_step_fits_one_chip_by_the_rule_and_fills_it(kimi_vl_step):
 
 def test_kimi_vl_step_runs_the_kernels_at_192_and_128_unpadded(kimi_vl_step):
     """Five latent layers run the causal kernels forward, recomputed and
-    backward: 10, 5 and 5 custom calls under their `dwt_fa_*` names.  16
+    backward: 10 forwards and 5 fused backwards (one sweep gives dq, dk
+    and dv: `fa.backward_route`) under their `dwt_fa_*` names.  16
     heads of 192 | 128 lie on no slab boundary: the TRANSPOSED route on
     (batch x 16, 16384, d), q and k handed in 192 wide and v, o and dO
     128 wide; nothing is padded to 256 lanes or to the other's width."""
@@ -66,10 +67,10 @@ def test_kimi_vl_step_runs_the_kernels_at_192_and_128_unpadded(kimi_vl_step):
     text = step.as_text()
     calls = collections.Counter(re.findall(
         r"%(dwt_fa_\w+?)(?:\.\d+)? = ", text))
-    assert calls == {"dwt_fa_fwd": 10, "dwt_fa_bwd_dq": 5,
-                     "dwt_fa_bwd_dkv": 5}
+    assert calls == {"dwt_fa_fwd": 10, "dwt_fa_bwd_fused": 5}
     assert fa.attention_route(16, 192, 128) == ("transposed", 0)
     bh = cell["global_batch"] * 16
+    assert fa.backward_route(16384, 16384, 192, 128, 0, bh) == ("fused", 2)
     shapes = collections.Counter()
     for line in text.splitlines():
         m = re.match(r"\s*%(dwt_fa_\w+?)(?:\.\d+)? = ", line)
@@ -82,8 +83,7 @@ def test_kimi_vl_step_runs_the_kernels_at_192_and_128_unpadded(kimi_vl_step):
     row = f"f32[{bh},1,16384]"
     ins = (wide, wide, narrow, narrow, row, row)
     assert shapes == {("dwt_fa_fwd", (wide, wide, narrow)): 10,
-                      ("dwt_fa_bwd_dq", ins): 5,
-                      ("dwt_fa_bwd_dkv", ins): 5}
+                      ("dwt_fa_bwd_fused", ins): 5}
     assert f"bf16[{bh},16384,256]" not in text
     assert " while(" not in text and " conditional(" not in text
 
@@ -150,11 +150,17 @@ def test_kimi_vl_step_walks_its_row_buffer_in_gathers_alone(kimi_vl_step):
     assert _row_buffer_walkers(text, rows) == []
 
 
-@pytest.mark.parametrize("seq", [16384, 1024])
-def test_two_width_kernels_compile_at_the_cells_shape(topo, seq):
+@pytest.mark.parametrize("seq,route,names", [
+    (16384, None, ("dwt_fa_bwd_fused",)),
+    (16384, ("split", 8), ("dwt_fa_bwd_dq", "dwt_fa_bwd_dkv")),
+    (1024, None, ("dwt_fa_bwd_fused",))])
+def test_two_width_kernels_compile_at_the_cells_shape(topo, seq, route,
+                                                      names):
     """Two sequences' 32 heads, q and k 192 wide beside v 128, blocks of
-    1,024: the forward and the split backward at the cell's 16,384, and
-    the fused backward at one block each way."""
+    1,024: the forward, and the backward at the cell's 16,384 — fused,
+    two heads' whole dq resident (64 MiB of the 100 the call states),
+    and as the pair a longer sequence would take — and at one block each
+    way."""
     one = SingleDeviceSharding(topo.devices[0])
     q = jax.ShapeDtypeStruct((32, seq, 192), jnp.bfloat16, sharding=one)
     v = jax.ShapeDtypeStruct((32, seq, 128), jnp.bfloat16, sharding=one)
@@ -163,11 +169,9 @@ def test_two_width_kernels_compile_at_the_cells_shape(topo, seq):
         q, k, v, True, 192 ** -0.5, 1024, 1024, False), q, q, v)
     assert "dwt_fa_fwd" in fwd and "tpu_custom_call" in fwd
     bwd = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
-        q, k, v, o, l, do, True, 192 ** -0.5, 1024, 1024, False),
-        q, q, v, v, lse, v)
-    names = ("dwt_fa_bwd_dq", "dwt_fa_bwd_dkv") if seq > 1024 \
-        else ("dwt_fa_bwd_fused",)
-    assert all(name in bwd for name in names)
+        q, k, v, o, l, do, True, 192 ** -0.5, 1024, 1024, False,
+        route=route), q, q, v, v, lse, v)
+    assert sorted(set(re.findall(r"dwt_fa_bwd_[a-z]+", bwd))) == sorted(names)
 
 
 def test_every_device_op_of_the_step_has_an_owner(kimi_vl_step):

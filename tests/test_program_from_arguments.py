@@ -46,28 +46,40 @@ def test_pack_is_the_largest_of_8_4_2_1_dividing_the_heads(bh, pack):
 
 
 @pytest.mark.parametrize("causal", [True, False])  # ring steps are not
-@pytest.mark.parametrize("sq,sk,block,fused", [
-    (1024, 1024, 1024, True),    # GPT-2's context: one block each way
-    (512, 512, 1024, True),      # a block is capped at the sequence
-    (256, 256, 256, True),
-    (256, 512, 512, True),       # one block each way, sq != sk
-    (1024, 1024, 512, False),    # 2 x 2
-    (2048, 2048, 1024, False),
-    (1024, 2048, 1024, False),   # one query block, two key blocks
-    (2048, 1024, 1024, False),
+@pytest.mark.parametrize("sq,sk,block,grid", [
+    (1024, 1024, 1024, ()),      # GPT-2's context: one block each way
+    (512, 512, 1024, ()),        # a block is capped at the sequence
+    (256, 256, 256, ()),
+    (256, 512, 512, ()),         # one block each way, sq != sk
+    (1024, 1024, 512, (2, 2)),   # key blocks, then query blocks
+    (2048, 2048, 1024, (2, 2)),
+    (1024, 2048, 1024, (2, 1)),  # one query block, two key blocks
+    (2048, 1024, 1024, (1, 2)),
 ])
-def test_backward_is_fused_iff_one_block_each_way(sq, sk, block, fused,
-                                                  causal):
+def test_backward_is_one_kernel_wherever_dq_fits(sq, sk, block, grid,
+                                                 causal):
+    """One block each way: the one-block kernel on a grid of heads alone.
+    Several: the dk/dv sweep's grid with the packed heads' whole dq in
+    VMEM (`backward_route`), or by `route=` the dq and dk/dv pair."""
     q = jax.ShapeDtypeStruct((2, sq, 64), jnp.float32)
     k = jax.ShapeDtypeStruct((2, sk, 64), jnp.float32)
     lse = jax.ShapeDtypeStruct((2, 1, sq), jnp.float32)
-    jaxpr = jax.make_jaxpr(
-        lambda q, k, v, o, l, g: fa._fa_backward_pallas(
-            q, k, v, o, l, g, causal, 0.125, block, block, True))(
-        q, k, k, q, lse, q)
-    names = sorted(name for name, _ in _pallas_calls(jaxpr.jaxpr))
-    assert names == (["dwt_fa_bwd_fused"] if fused
-                     else ["dwt_fa_bwd_dkv", "dwt_fa_bwd_dq"])
+
+    def calls(**kw):
+        return sorted(_pallas_calls(jax.make_jaxpr(
+            lambda q, k, v, o, l, g: fa._fa_backward_pallas(
+                q, k, v, o, l, g, causal, 0.125, block, block, True, **kw))(
+            q, k, k, q, lse, q).jaxpr))
+
+    assert fa.backward_route(sq, sk, 64, 64, 0, 2, block, block, 4) == (
+        "fused", 2)
+    assert calls() == [("dwt_fa_bwd_fused", (1,) + grid)]
+    if grid:
+        assert calls(route=("split", 2)) == [
+            ("dwt_fa_bwd_dkv", (1,) + grid),
+            ("dwt_fa_bwd_dq", (1,) + grid[::-1])]
+        assert calls(route=("fused", 1)) == [
+            ("dwt_fa_bwd_fused", (2,) + grid)]
 
 
 @pytest.mark.parametrize("sq,sk,streamed", [
@@ -144,16 +156,20 @@ def _primitives(jaxpr) -> set:
 # transposed array (124M: 24 x 6 slabs of two heads, was 288 / 8 groups;
 # OLMoE: 5 x 16 slabs of one head, was 80 / 8), because a slab is what a
 # BlockSpec can address in the projections' layout without a lane slice
-@pytest.mark.parametrize("b,h,t,d,form,route,groups,grid,fused,tiles", [
-    (24, 12, 1024, 64, "qkv", "direct", 24 * 6, (1, 1), True,
+@pytest.mark.parametrize("b,h,t,d,form,route,groups,grid,tiles", [
+    (24, 12, 1024, 64, "qkv", "direct", 24 * 6, (1, 1),
      (3, 4)),                                          # gpt2_124m.steady
-    (4, 25, 1024, 64, "qkv", "transposed", 100 // 4, (1, 1), True,
+    (4, 25, 1024, 64, "qkv", "transposed", 100 // 4, (1, 1),
      (3, 4)),                                          # gpt2_xl, per chip
-    (5, 16, 4096, 128, "q,k,v", "direct", 5 * 16, (4, 4), False,
+    (5, 16, 4096, 128, "q,k,v", "direct", 5 * 16, (4, 4),
      (36, 64)),                                        # olmoe_1b_7b.steady
+    (2, 32, 8192, 128, "q,k,v", "direct", 2 * 32, (8, 8),
+     (136, 256)),                           # nemotron3_nano_30b_a3b.steady
+    (1, 32, 8192, 64, "q,k,v", "direct", 16, (8, 8),
+     (136, 256)),                                 # granite4_h_micro.steady
 ])
 def test_the_cells_attention_plans(monkeypatch, b, h, t, d, form, route,
-                                   groups, grid, fused, tiles):
+                                   groups, grid, tiles):
     """PERF.md section 5's prose, pinned: what each benchmark cell's
     attention traces to on the chip, from its shape alone — the route,
     the kernels and their grids, and whether anything is split, cut to
@@ -161,21 +177,24 @@ def test_the_cells_attention_plans(monkeypatch, b, h, t, d, form, route,
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     jaxpr = _model_attention_jaxpr(b, h, t, d, form).jaxpr
     assert fa.attention_route(h, d)[0] == route
-    want = [("dwt_fa_fwd", (groups,) + grid)]
-    want += [("dwt_fa_bwd_fused", (groups,))] if fused else \
-        [("dwt_fa_bwd_dq", (groups,) + grid),
-         ("dwt_fa_bwd_dkv", (groups,) + grid)]
-    assert sorted(_pallas_calls(jaxpr)) == sorted(want)
+    # the backward is ONE kernel in every cell: on heads alone where the
+    # sequence is one block, else the dk/dv sweep with dq resident
+    assert fa.backward_route(t, t, d, d, fa.attention_route(h, d)[1],
+                             b * h)[0] == "fused"
+    assert sorted(_pallas_calls(jaxpr)) == sorted([
+        ("dwt_fa_fwd", (groups,) + grid),
+        ("dwt_fa_bwd_fused", (groups,) + (grid if t > 1024 else ()))])
     assert fa.causal_tile_count(t, t) == tiles
     relaid = _primitives(jaxpr) & {"transpose", "split", "reshape",
                                    "slice"}
     if route == "transposed":
         assert fa._fit_pack(b * h) == b * h // groups
         assert relaid == {"transpose", "split", "reshape"}
-    elif form == "qkv":
+    elif d == 64:
         # two heads a slab: delta is the kernel's too; all that is left
         # is the join of dq, dk and dv into c_attn's cotangent
-        assert relaid == set() and "concatenate" in _primitives(jaxpr)
+        assert relaid == set()
+        assert ("concatenate" in _primitives(jaxpr)) == (form == "qkv")
     else:
         # a head a slab: delta is one reduce of (b, t, h, d) over d,
         # turned to the kernels' (b*h, 1, t) as a (b, t, h) array
@@ -187,8 +206,10 @@ def test_the_latent_cells_attention_plan(monkeypatch):
     """`kimi_vl_a3b.steady`'s attention from its shape alone: 16 heads
     whose q and k are 192 wide and whose v is 128 lie on no slab
     boundary, so `attend` hands the kernels the transposed (b*h, T, d)
-    arrays, 8 heads a grid step on 16 blocks of 1,024 a side, each
-    operand at its own width and none padded."""
+    arrays on 16 blocks of 1,024 a side, 8 heads a grid step forward and
+    2 backward (two heads' whole dq, 64 MiB, is what fits the fused
+    kernel's VMEM: `backward_route`), each operand at its own width and
+    none padded."""
     from dlrover_wuqiong_tpu.models.attention import attend, goes_direct
     from dlrover_wuqiong_tpu.models.latent_attention import (
         LatentAttentionConfig,
@@ -207,10 +228,11 @@ def test_the_latent_cells_attention_plan(monkeypatch):
         argnums=(0, 1, 2)))(q, q, v)
     assert [a.aval.shape for a in jaxpr.jaxpr.outvars] == [
         q.shape, q.shape, v.shape]
-    groups = 2 * 16 // fa._fit_pack(2 * 16)
-    assert sorted(_pallas_calls(jaxpr.jaxpr)) == sorted(
-        (name, (groups, 16, 16)) for name in
-        ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"))
+    assert fa._fit_pack(2 * 16) == 8
+    assert fa.backward_route(16384, 16384, 192, 128, 0, 2 * 16) == (
+        "fused", 2)
+    assert sorted(_pallas_calls(jaxpr.jaxpr)) == [
+        ("dwt_fa_bwd_fused", (16, 16, 16)), ("dwt_fa_fwd", (4, 16, 16))]
     assert "pad" not in _primitives(jaxpr.jaxpr)
     assert fa.kernel_lanes(192, 128) == 192 + 128
     assert fa.causal_tile_count(16384, 16384) == (528, 1024)
@@ -218,15 +240,15 @@ def test_the_latent_cells_attention_plan(monkeypatch):
 
 @pytest.mark.parametrize("window,names,sweep,tiles", [
     # the GLOBAL layer: the causal kernels over all 16 x 16 blocks
-    (0, ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"), 16, (528, 1024)),
+    (0, ("dwt_fa_fwd", "dwt_fa_bwd_fused"), 16, (528, 1024)),
     # a WINDOWED layer: kernels of another name on a grid narrowed to the
-    # five key blocks a query block sees (the five query blocks a key
-    # block is seen by): a block below the window is no grid step
-    (4096, ("dwt_fa_win_fwd", "dwt_fa_win_bwd_dq", "dwt_fa_win_bwd_dkv"),
-     5, (252, 1024)),
+    # five key blocks a query block sees (backward: the five query
+    # blocks a key block is seen by): a block below the window is no
+    # grid step.  The backward is one kernel a layer, a slab's whole dq
+    # resident
+    (4096, ("dwt_fa_win_fwd", "dwt_fa_win_bwd_fused"), 5, (252, 1024)),
     # a window no shorter than the sequence is the causal call itself
-    (16384, ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"), 16,
-     (528, 1024)),
+    (16384, ("dwt_fa_fwd", "dwt_fa_bwd_fused"), 16, (528, 1024)),
 ])
 def test_the_windowed_cells_attention_plans(monkeypatch, window, names,
                                             sweep, tiles):

@@ -63,7 +63,8 @@ def test_smallthinker_step_runs_two_kinds_of_attention_kernel(
     """The global layer runs the causal kernels and the three windowed
     layers kernels of their own name (`dwt_fa_win_*`: inside
     `kernel.attn_ms` by prefix, alone in `kernel.attn_window_ms`), each
-    forward, recomputed and backward: 4 and 12 custom calls.  28 heads
+    forward, recomputed and backward — the backward ONE fused kernel a
+    layer (`fa.backward_route`): 3 and 9 custom calls.  28 heads
     of 128 are lane slabs: the kernels index the projections' own
     (batch, 16384, 28 x 128) after GQA's 7-fold repeat, nothing is laid
     out by head."""
@@ -71,10 +72,11 @@ def test_smallthinker_step_runs_two_kinds_of_attention_kernel(
     text = step.as_text()
     calls = collections.Counter(re.findall(
         r"%(dwt_fa_\w+?)(?:\.\d+)? = ", text))
-    assert calls == {"dwt_fa_fwd": 2, "dwt_fa_bwd_dq": 1,
-                     "dwt_fa_bwd_dkv": 1, "dwt_fa_win_fwd": 6,
-                     "dwt_fa_win_bwd_dq": 3, "dwt_fa_win_bwd_dkv": 3}
+    assert calls == {"dwt_fa_fwd": 2, "dwt_fa_bwd_fused": 1,
+                     "dwt_fa_win_fwd": 6, "dwt_fa_win_bwd_fused": 3}
     b = cell["global_batch"]
+    assert fa.backward_route(16384, 16384, 128, 128, 1, b * 28) == (
+        "fused", 1)
     assert fa.attention_route(28, 128) == ("direct", 1)
     assert f"operand_layout_constraints={{bf16[{b},16384,3584]" in text
     assert f"bf16[{b * 28},16384,128]" not in text
@@ -143,13 +145,18 @@ def test_smallthinker_step_walks_its_row_buffer_in_gathers_alone(
     assert " while(" not in text and " conditional(" not in text
 
 
-@pytest.mark.parametrize("window", [4096, 4000])
-def test_windowed_kernels_compile_at_the_cells_shape(topo, window):
+@pytest.mark.parametrize("window,route,names", [
+    (4096, None, ("dwt_fa_win_bwd_fused",)),
+    (4000, None, ("dwt_fa_win_bwd_fused",)),
+    (4096, ("split", 1), ("dwt_fa_win_bwd_dq", "dwt_fa_win_bwd_dkv"))])
+def test_windowed_kernels_compile_at_the_cells_shape(topo, window, route,
+                                                     names):
     """One sequence of 16,384 tokens, 28 heads of 128 on their lane
-    slabs, blocks of 1,024: the forward and the split backward of a
-    windowed call on its narrowed grid (index maps that clamp, a class
-    a grid step), at the published window and at one off the block and
-    the tile."""
+    slabs, blocks of 1,024: the forward and the backward of a windowed
+    call on its narrowed grid (index maps that clamp, a class a grid
+    step), at the published window and at one off the block and the
+    tile — the fused sweep with a slab's whole dq resident, and the pair
+    a sequence that does not fit would take."""
     one = SingleDeviceSharding(topo.devices[0])
     x = jax.ShapeDtypeStruct((1, 16384, 3584), jnp.bfloat16, sharding=one)
     lse = jax.ShapeDtypeStruct((28, 1, 16384), jnp.float32, sharding=one)
@@ -159,9 +166,10 @@ def test_windowed_kernels_compile_at_the_cells_shape(topo, window):
         q, k, v, True, 128 ** -0.5, 1024, 1024, False, **kw), x, x, x)
     assert "dwt_fa_win_fwd" in fwd and "tpu_custom_call" in fwd
     bwd = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
-        q, k, v, o, l, do, True, 128 ** -0.5, 1024, 1024, False, **kw),
-        x, x, x, x, lse, x)
-    assert "dwt_fa_win_bwd_dq" in bwd and "dwt_fa_win_bwd_dkv" in bwd
+        q, k, v, o, l, do, True, 128 ** -0.5, 1024, 1024, False,
+        route=route, **kw), x, x, x, x, lse, x)
+    assert sorted(set(re.findall(r"dwt_fa_win_bwd_[a-z]+", bwd))) == sorted(
+        names)
     assert "dwt_fa_fwd" not in fwd + bwd and "dwt_fa_bwd" not in bwd
 
 

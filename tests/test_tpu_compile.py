@@ -111,22 +111,33 @@ def test_attention_forward_and_backward_compile(topo, bh, t, d, causal,
     if heads:  # nothing is cut to heads or turned on the way in or out
         assert f"bf16[{bh},{t},{d}]" not in fwd + bwd
         assert f"bf16[{b},{t},{lanes}]" in bwd
-    if t == blk:  # one block each way: the fused dq+dk+dv kernel
-        assert "dwt_fa_bwd_fused" in bwd
-    else:
-        assert "dwt_fa_bwd_dq" in bwd and "dwt_fa_bwd_dkv" in bwd
+    # one block each way, or several with a unit's whole dq resident:
+    # ONE kernel gives dq, dk and dv (`fa.backward_route`)
+    assert "dwt_fa_bwd_fused" in bwd
+    assert "dwt_fa_bwd_dq" not in bwd and "dwt_fa_bwd_dkv" not in bwd
+    if t > blk:  # the pair a sequence that does not fit would take
+        pair = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
+            q, k, v, o, l, do, causal, sc, blk, blk, False,
+            route=("split", 1 if heads else fa._fit_pack(bh)), **kw),
+            q, q, q, x, lse, x)
+        assert "dwt_fa_bwd_dq" in pair and "dwt_fa_bwd_dkv" in pair
 
 
-def test_attention_split_backward_compiles_at_gpt2_shape(topo):
-    """Blocks of 512 at T = 1024 are a 2 x 2 grid: the backward takes the
-    dq and dk/dv kernels where one block each way takes the fused one."""
+@pytest.mark.parametrize("route,names", [
+    (None, ["dwt_fa_bwd_fused"]),
+    (("split", 4), ["dwt_fa_bwd_dkv", "dwt_fa_bwd_dq"])])
+def test_attention_several_block_backward_compiles_at_gpt2_shape(
+        topo, route, names):
+    """Blocks of 512 at T = 1024 are a 2 x 2 grid: the backward is the
+    sweep that keeps four heads' whole dq in VMEM, or by `route=` the dq
+    and dk/dv kernels."""
     one = SingleDeviceSharding(topo.devices[0])
     x = jax.ShapeDtypeStruct((4, 1024, 64), jnp.bfloat16, sharding=one)
     lse = jax.ShapeDtypeStruct((4, 1, 1024), jnp.float32, sharding=one)
     bwd = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
-        q, k, v, o, l, do, True, 0.125, 512, 512, False),
+        q, k, v, o, l, do, True, 0.125, 512, 512, False, route=route),
         x, x, x, x, lse, x)
-    assert "dwt_fa_bwd_dq" in bwd and "dwt_fa_bwd_dkv" in bwd
+    assert sorted(set(re.findall(r"dwt_fa_bwd_[a-z]+", bwd))) == names
 
 
 def test_int8_quantise_kernels_compile(topo, monkeypatch):
@@ -357,8 +368,9 @@ def test_olmoe_step_fits_one_chip_and_fills_it(olmoe_step):
 def test_olmoe_step_runs_the_kernels_at_d128_t4096(olmoe_step):
     cell, _, step = olmoe_step
     text = step.as_text()
-    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"):
+    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_fused"):
         assert kernel in text, kernel
+    assert "dwt_fa_bwd_dq" not in text and "dwt_fa_bwd_dkv" not in text
     # a head is a lane slab: the kernels index the projections' own
     # (batch, 4096, 16 x 128), nothing is laid out by head
     b = cell["global_batch"]
@@ -432,8 +444,9 @@ def test_nemotron_step_fits_one_chip_and_fills_it(nemotron_step):
 def test_nemotron_step_runs_the_kernels_at_d128_t8192(nemotron_step):
     cell, _, step = nemotron_step
     text = step.as_text()
-    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"):
+    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_fused"):
         assert kernel in text, kernel
+    assert "dwt_fa_bwd_dq" not in text and "dwt_fa_bwd_dkv" not in text
     # 32 heads of 128 on a hidden size of 2688: a head is a lane slab, the
     # kernels index the projections' own (batch, 8192, 32 x 128)
     b = cell["global_batch"]
@@ -645,7 +658,8 @@ def test_granite_step_runs_the_kernels_direct_at_32_heads_of_64(
     """The first grouped-query call at d = 64 through a model: 32 query
     heads are 16 lane slabs of two, so after the four-fold repeat of k
     and v the kernels index the projections' own (1, 8192, 32 x 64) —
-    the DIRECT route, over 8 x 8 blocks (split backward), nothing laid
+    the DIRECT route, over 8 x 8 blocks (ONE backward kernel, a slab's
+    whole dq resident: `fa.backward_route`), nothing laid
     out by head.  Recorded, not required (either route computes the
     same): what moves data under `attention` outside the projections is
     six (1, 8192, 2048) copies — the repeats of k and v in the forward
@@ -656,9 +670,10 @@ def test_granite_step_runs_the_kernels_direct_at_32_heads_of_64(
 
     text = granite_step[2].as_text()
     assert fa.attention_route(32, 64) == ("direct", 2)
-    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_dq", "dwt_fa_bwd_dkv"):
+    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_fused"):
         assert kernel in text, kernel
-    assert "dwt_fa_bwd_fused" not in text
+    assert "dwt_fa_bwd_dq" not in text and "dwt_fa_bwd_dkv" not in text
+    assert fa.backward_route(8192, 8192, 64, 64, 2, 32) == ("fused", 1)
     assert "operand_layout_constraints={bf16[1,8192,2048]" in text
     assert "bf16[32,8192,64]" not in text
     moved = relayouts(text, "attention", outside=(
@@ -899,7 +914,7 @@ def test_hybrid_step_scans_in_its_kernels_and_holds_no_decay_tensor(
 
     attention = [n for n in table if n.startswith("dwt_fa_")]
     assert sorted(n.split(".")[0] for n in attention) == [
-        "dwt_fa_bwd_dkv", "dwt_fa_bwd_dq", "dwt_fa_fwd", "dwt_fa_fwd"]
+        "dwt_fa_bwd_fused", "dwt_fa_fwd", "dwt_fa_fwd"]
     assert " while(" not in text and " conditional(" not in text
 
 

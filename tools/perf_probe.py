@@ -6,8 +6,8 @@ host readback; iterations chain on carried values.
 A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
-Usage: python tools/perf_probe.py [attn|attn_sweep|attn_direct|head|model|opt|
-step|lib|dispatch|rpc|gmm|rows_map] ...  (no args = step/attn/head/model/opt).  One JSON line
+Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
+model|opt|step|lib|dispatch|rpc|gmm|rows_map] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -15,6 +15,9 @@ per probe as it finishes, then ONE summary line
 the K-step driver (trainer/train_step.py) in THIS environment;
 `rpc` streams per-round control-plane RPCs/s per verb class against a
 per-frame-fsync and a group-commit master, rounds interleaved.
+`attn` is GPT-2's attention forward and backward by the host's clock,
+then `attn_bwd`: the backward alone at the five several-block cells'
+shapes, fused against the dq + dk/dv pair, by device time.
 `gmm` reads, from a profiler trace, the device time of each grouped
 product of a chip's share of an expert layer (98,304 rows of which
 6,800 are held in 8 groups) as `ops/grouped_matmul.py`'s kernels and as
@@ -122,6 +125,69 @@ def probe_attn(block_q=1024, block_k=1024, tag="attn"):
           blocks=[block_q, block_k],
           ideal_fwd_ms=round(2 * mm / 155e12 * 1e3, 2),
           ideal_fwdbwd_ms=round(7 * mm / 155e12 * 1e3, 2))
+
+
+# the five several-block cells' attention: (cell, T, d_qk, d_v, heads,
+# batch, window).  The route and layout follow from the shapes
+# (`attention_route`, `backward_route`)
+BWD_CELLS = [
+    ("olmoe_1b_7b", 4096, 128, 128, 16, 5, None),
+    ("nemotron3_nano_30b_a3b", 8192, 128, 128, 32, 2, None),
+    ("granite4_h_micro", 8192, 64, 64, 32, 1, None),
+    ("smallthinker_21b_a3b", 16384, 128, 128, 28, 2, None),
+    ("smallthinker_21b_a3b", 16384, 128, 128, 28, 2, 4096),
+    ("kimi_vl_a3b", 16384, 192, 128, 16, 2, None),
+]
+
+
+def probe_attn_bwd():
+    """The backward ALONE at the five several-block cells' attention
+    shapes, as ONE fused kernel (`backward_route`'s answer, and on the
+    transposed layout every smaller pack too) and as the dq + dk/dv
+    pair: the device time of each kernel from a profiler trace, so that
+    the route's rule rests on kernel times and not on a whole cell's."""
+    from dlrover_wuqiong_tpu.ops import flash_attention as fa
+
+    for cell, t, d, dv, heads, batch, window in BWD_CELLS:
+        layout, slab_heads = fa.attention_route(heads, d, dv)
+        bh = batch * heads
+        ks = jax.random.split(jax.random.PRNGKey(t + d), 4)
+        if layout == "direct":
+            shapes = [(batch, t, heads * d)] * 4
+            slabs = fa._projected_slabs(
+                [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes[:3]],
+                heads)[0]
+        else:
+            shapes = [(bh, t, d), (bh, t, d), (bh, t, dv), (bh, t, dv)]
+            slabs = None
+        q, k, v, do = (jax.random.normal(key, s, jnp.bfloat16)
+                       for key, s in zip(ks, shapes))
+        plan = dict(causal=True, sm_scale=d ** -0.5, block_q=1024,
+                    block_k=1024, interpret=False, slabs=slabs,
+                    window=window)
+        o, lse = jax.jit(functools.partial(fa._fa_forward_pallas, **plan))(
+            q, k, v)
+        rule = fa.backward_route(t, t, d, dv, slab_heads, bh)
+        routes = [rule] + [("fused", p) for p in (4, 2, 1)
+                           if slabs is None and p < rule[1]]
+        routes.append(("split", 1 if slabs else fa._fit_pack(bh)))
+        for route in routes:
+            fn = jax.jit(functools.partial(fa._fa_backward_pallas,
+                                           route=route, **plan))
+            ops = _device_ops_ms(fn, q, k, v, o, lse, do, top=6)
+            kernels = {n: ms for n, ms in ops.items()
+                       if n.startswith("dwt_fa_")}
+            _emit_raw({"probe": "attn_bwd", "cell": cell,
+                       "shape": [bh, t, d, dv], "layout": layout,
+                       "window": window, "route": list(route),
+                       "the_rule": route == rule,
+                       "kernels_ms": round(sum(kernels.values()), 4),
+                       "device_ops_ms": ops})
+
+
+def probe_attn_cells():
+    probe_attn()
+    probe_attn_bwd()
 
 
 def probe_attn_direct():
@@ -676,7 +742,8 @@ def probe_rows_map():
                        "device_ops_ms": _device_ops_ms(jax.jit(fn), *args)})
 
 
-ALL = {"attn": probe_attn, "attn_sweep": probe_attn_sweep,
+ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
+       "attn_sweep": probe_attn_sweep,
        "attn_direct": probe_attn_direct, "lib": probe_lib,
        "remat": probe_remat,
        "splash": probe_splash, "dots": probe_dots,
