@@ -58,12 +58,18 @@ compiler's kernels walk the whole buffer: at 8 of 128 experts 93% of it
 is empty), and `dwt_rows_map_*` over the same tiles for the activation
 (and gating product) and its backward, the sum of the two first
 products' row gradients, and the combine's backward pair (the weighted
-cotangent and <row, cotangent>).  What still walks a share's whole
-buffer is what MOVES or COUNTS rows: the four row gathers a layer, the
-two sorts, the `bincount`s.  A share also sows `moe_gmm_tiles` and
-`moe_map_tiles` (row tiles its grouped products / its elementwise
-passes walk, row tiles of the buffer), which `collect_moe_stats` reduces
-to `moe_gmm_tiles_share` and `moe_map_tiles_share`.
+cotangent and <row, cotangent>).  The gathers INTO expert order follow
+the held rows too on that route (`dispatch`: a loop over chunks of the
+held rows, forward, recomputed and for the combine's cotangent).  What
+still walks a share's whole buffer is the two gathers a layer BY
+ASSIGNMENT (`_by_assignment`: `combine`, and `dispatch`'s backward —
+their held entries lie scattered over the (k, T) index list, so no
+prefix cuts it) and what COUNTS rows: the two sorts, the `bincount`s.
+A share also sows `moe_gmm_tiles`, `moe_map_tiles` (row tiles its
+grouped products / its elementwise passes walk, row tiles of the
+buffer) and `moe_gather_rows` (rows `dispatch` fetches, rows of the
+buffer), which `collect_moe_stats` reduces to `moe_gmm_tiles_share`,
+`moe_map_tiles_share` and `moe_gather_rows_share`.
 """
 
 from __future__ import annotations
@@ -82,6 +88,7 @@ from ..ops.grouped_matmul import (
     map_tiles,
     row_tiles,
     rows_map,
+    unwritten_rows,
 )
 
 
@@ -252,28 +259,80 @@ def _by_assignment(rows: jax.Array, inv: jax.Array,
                      jnp.zeros((), rows.dtype))
 
 
-@jax.custom_vjp
+# rows a turn of `dispatch`'s loop gathers on the "kernel" route: a
+# multiple of the kernels' row tile, so the last turn fills the last held
+# tile.  More turns cost their overhead, fewer waste half a longer chunk:
+# on the chip 4,096 / 8,192 / 16,384 read 39.4 / 38.8 / 39.3 ms a layer
+# at 12% of 196,608 rows held, 47.0 / 45.9 / 46.0 at 25% (PERF.md
+# section 7, PR 42)
+_GATHER_CHUNK = 8192
+
+
+def _gather_chunk(rows: int) -> int:
+    return min(_GATHER_CHUNK, rows)
+
+
+def gathered_rows(group_sizes: jax.Array, rows: int,
+                  route: str) -> Tuple[jax.Array, jax.Array]:
+    """(rows `dispatch` fetches on `route`, rows of the buffer), from
+    `group_sizes` alone as `row_tiles` and `map_tiles` are: the held
+    rows rounded up to a turn of the loop, or every row where the
+    gather is the whole index list's."""
+    of = jnp.asarray(rows, jnp.int32)
+    if route == "plain":
+        return of, of
+    chunk = _gather_chunk(rows)
+    held = group_sizes.astype(jnp.int32).sum()
+    return jnp.minimum(-(-held // chunk) * chunk, of), of
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def dispatch(tokens: jax.Array, order: jax.Array, inv: jax.Array,
-             held_rows: jax.Array) -> jax.Array:
+             held_rows: jax.Array, route: str = "plain") -> jax.Array:
     """tokens (T, d) -> rows (T*k, d) in expert order: row r is the
     token of assignment `order[r]`, token `order[r] // k`.  `inv` (k, T)
     is the inverse of `order`: `inv[j, t]` is the row of assignment
     t*k + j (k leads, so that a sum over a token's k rows adds k whole
     (T, d) slabs).  `held_rows`: how many rows belong to a group.
 
+    `route` is the layer's (`grouped_experts`).  On "plain" every row is
+    gathered, `tokens[order // k]`: the one definition of the
+    mathematics.  On "kernel" nothing reads a row tile behind the held
+    rows, and a row gather from HBM costs the length of its index list
+    whatever a row weighs, so the buffer is filled by a loop of
+    ceil(held_rows / chunk) turns — a dynamic trip count, as the
+    kernels' grids are — each turn the same gather over `_GATHER_CHUNK`
+    entries of `order`, written in place.  The rows behind the last turn
+    are never written: they hold whatever `unwritten_rows` left there.
+
     `dispatch` and `combine` are each other's transposes and each one's
     backward pass is the other, so rows move by gathers in both
     directions: a token's k rows are found through `inv` and summed,
     none is scattered.  A backward pass is traced under the named scopes
     of the forward call, so its instructions keep the caller's scope."""
-    return tokens[order // inv.shape[0]]
+    k = inv.shape[0]
+    if route != "kernel":
+        return tokens[order // k]
+    rows, chunk = order.shape[0], _gather_chunk(order.shape[0])
+
+    def turn(c, buffer):
+        # a last turn that would pass the buffer's end is moved back onto
+        # it by the slice and by the update alike (both clamp their
+        # start): what it gathers twice is the same
+        idx = jax.lax.dynamic_slice(order, (c * chunk,), (chunk,)) // k
+        return jax.lax.dynamic_update_slice(buffer, tokens[idx],
+                                            (c * chunk, 0))
+
+    return jax.lax.fori_loop(0, -(-held_rows // chunk), turn,
+                             unwritten_rows(rows, tokens))
 
 
-def _dispatch_fwd(tokens, order, inv, held_rows):
-    return dispatch(tokens, order, inv, held_rows), (order, inv, held_rows)
+def _dispatch_fwd(tokens, order, inv, held_rows, route):
+    return (dispatch(tokens, order, inv, held_rows, route),
+            (order, inv, held_rows))
 
 
-def _dispatch_bwd(res, d_rows):
+def _dispatch_bwd(route, res, d_rows):
     return combine(d_rows, None, *res), None, None, None
 
 
@@ -310,7 +369,7 @@ def _weigh(rows, d_rows, gates):
 
 def _combine_bwd(route, res, d_out):
     rows, gates, order, inv, held_rows = res
-    d_rows = dispatch(d_out, order, inv, held_rows)
+    d_rows = dispatch(d_out, order, inv, held_rows, route)
     if gates is None:
         return d_rows, None, None, None, None
     if route == "kernel":
@@ -389,9 +448,11 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
     transpose those of its cotangent); on the "kernel" route (`route`,
     decided ONCE here for the products and the passes between them: a
     share on one TPU device, `mesh` None or of size 1) nothing reads or
-    writes a row tile behind the held rows at all — the products'
-    kernels and the maps' visit the tiles that hold a held row, and a
-    map zeroes the rest of the last one.  And on either route the sums
+    writes a row tile behind the held rows at all — `dispatch` gathers
+    the held rows' chunks alone and leaves the buffer behind them as it
+    found it, the products' kernels and the maps' visit the tiles that
+    hold a held row, and a map zeroes the rest of the last one.  And on
+    either route the sums
     over a token's k rows read no place behind the held rows
     (`_by_assignment`), be it of the last product or of the rows' own
     gradient."""
@@ -412,7 +473,7 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
         group_sizes = jnp.bincount(flat_expert, length=E)
         held_rows = group_sizes.sum()
         held_row = jnp.arange(T * top_k) < held_rows
-        xs = dispatch(tokens, order, inv, held_rows)
+        xs = dispatch(tokens, order, inv, held_rows, route)
         xs = xs.astype(w_in.dtype)                     # (T*k, d) sorted
 
     def grouped(lhs, rhs):
@@ -613,9 +674,10 @@ class MoEMLP(nn.Module):
             self.sow("intermediates", "moe_rows_held", counts.sum())
             self.sow("intermediates", "moe_rows_absent", dropped)
             dropped = jnp.zeros((), dropped.dtype)
-            # how far the grouped products, and the elementwise passes
-            # between them, follow the held rows: row tiles they walk,
-            # row tiles of the buffer (no sync)
+            # how far the grouped products, the elementwise passes
+            # between them and the gathers into expert order follow the
+            # held rows: row tiles (rows) they walk, of the buffer's
+            # (no sync)
             rows = n_tok * cfg.top_k
             route = layer_route(rows, w_gate, w_in, w_out, cfg.num_experts,
                                 cfg.mesh)
@@ -623,6 +685,8 @@ class MoEMLP(nn.Module):
                      jnp.stack(row_tiles(counts, rows, route)))
             self.sow("intermediates", "moe_map_tiles",
                      jnp.stack(map_tiles(counts, rows, route)))
+            self.sow("intermediates", "moe_gather_rows",
+                     jnp.stack(gathered_rows(counts, rows, route)))
         self.sow("intermediates", "moe_dropped", dropped)
         if cfg.shared_width:
             with jax.named_scope("shared"):
@@ -680,7 +744,8 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     `max_i(tokens_i) / mean_i(tokens_i)`, the dropped assignments of all
     layers, and where the layers hold a share of their experts the
     assignments to experts held / not held here, all layers, and the
-    share of the row buffers' tiles their grouped products walk."""
+    shares of the row buffers' tiles their grouped products and their
+    elementwise passes walk and of their rows `dispatch` fetches."""
     counts = [n.astype(jnp.float32)
               for n in _sown(intermediates, "moe_tokens_per_expert")]
     if not counts:
@@ -691,7 +756,7 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     stats = {"moe_load_max_over_mean": jnp.max(jnp.stack(loads)),
              **{name: jnp.sum(jnp.stack(v)) for name, v in sums.items()
                 if v}}
-    for name in ("moe_gmm_tiles", "moe_map_tiles"):
+    for name in ("moe_gmm_tiles", "moe_map_tiles", "moe_gather_rows"):
         tiles = [v.reshape(-1, 2) for v in _sown(intermediates, name)]
         if tiles:
             walked, of = jnp.concatenate(tiles).astype(jnp.float32).sum(0)

@@ -52,6 +52,9 @@ that leaves the tiles behind the held rows unwritten may never feed a
   refuses a bfloat16 compare on a v5e; the compiler's fusion of the
   same lines also keeps float32 between its ops).  A pass costs the
   held share of the buffer's bytes plus a launch.
+  The buffer such a layer fills from its head (`models/moe.dispatch`'s
+  loop over the held rows) starts as `unwritten_rows`
+  (`dwt_rows_unwritten`): (M, c) of HBM that no one has written.
 - "plain": `jax.lax.ragged_dot`, for which the TPU compiler has
   grouped-matmul kernels of its own that walk every row tile of the
   buffer.  Wherever the groups fill the buffer (a whole layer: `E ==
@@ -538,3 +541,31 @@ def rows_map(fn, held_rows: jax.Array, *buffers: jax.Array,
     cotangent.  `alias` (i, j): result j is written over buffer i.  `fn`
     is a static argument: hand every layer the same object."""
     return _rows_map_kernels(fn, held_rows, *buffers, alias=alias)
+
+
+def _unwritten_kernel(rows, like, interpret=False):
+    """`unwritten_rows`, as `_rows_map_kernels` is `rows_map` (interpret
+    mode hands back NaN where nothing was written)."""
+    return pl.pallas_call(
+        lambda like_ref, out_ref: None,
+        out_shape=_out_struct((rows, like.shape[1]), like.dtype, like),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        cost_estimate=pl.CostEstimate(flops=0, transcendentals=0,
+                                      bytes_accessed=0),
+        interpret=interpret,
+        name="dwt_rows_unwritten",
+    )(like)
+
+
+def unwritten_rows(rows: int, like: jax.Array) -> jax.Array:
+    """(rows, like's width) of like's dtype in HBM that nobody has
+    written (`dwt_rows_unwritten`, a kernel with no body): what a loop
+    that fills the held rows' chunks in place starts from.  The buffer
+    exists from where `like` does: the TPU compiler places one that
+    waits for nothing (`jax.lax.empty`, a broadcast of zeros) a whole
+    recomputed layer before its first use, alive all the while (1.5 GB
+    of a step's live bytes at SmallThinker's shapes), and zeros cost a
+    write of the buffer besides (1.35 ms for 1.0 GB: PERF.md section 7,
+    PR 42)."""
+    return _unwritten_kernel(rows, like)

@@ -5,7 +5,8 @@ CPU in float32, against the forms plain autodiff gives (`tokens[idx]` and
 its scatter-add, `segment_sum` of a weighted copy), for every expert held
 and for a chip's share of them.  A share's grouped products through the
 kernel route of `ops/grouped_matmul.py` (interpret mode) are the plain
-route's."""
+route's, and so is the gather into expert order that walks the held
+rows' chunks alone there."""
 
 import functools
 
@@ -15,7 +16,15 @@ import numpy as np
 import pytest
 
 from dlrover_wuqiong_tpu.models import moe
-from dlrover_wuqiong_tpu.models.moe import combine, dispatch, grouped_experts
+from dlrover_wuqiong_tpu.models.moe import (
+    MoEConfig,
+    MoEMLP,
+    collect_moe_stats,
+    combine,
+    dispatch,
+    gathered_rows,
+    grouped_experts,
+)
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
 
 T, K, D, F = 48, 6, 16, 12
@@ -221,6 +230,8 @@ def test_the_expert_pass_keeps_its_value_and_every_gradient(holding,
         assert int(sizes.sum()) == 0 and not np.asarray(got).any()
 
 
+CHUNK = 64  # `moe._GATHER_CHUNK` on the kernel route here: two row tiles
+
 # an expert's form -> the gate's activation (None: relu^2, no gate matrix)
 FORMS = {"relu2": None, "reglu": jax.nn.relu, "swiglu": jax.nn.silu}
 
@@ -229,10 +240,14 @@ def _on_the_kernel_route(monkeypatch, poison):
     """What a share takes on one TPU device, here: the layer's own route
     decision with the backend said to be the TPU and a row tile of 32
     (T*k = 288 rows: nine tiles, of which the held rows fill one or
-    none), every kernel in interpret mode.  `poison`: every place a
-    kernel may leave unwritten is handed on as NaN — the rows of no
-    group in a product, the tiles no grid step visits in a map — forward
-    and backward.  Returns the list the kernels' calls are noted in."""
+    none) and a gather chunk of two tiles (four and a half of them: the
+    last turn of an all-held buffer is moved back onto its end), every
+    kernel in interpret mode.  `poison`: every place a kernel may leave
+    unwritten is handed on as NaN — the rows of no group in a product,
+    the tiles no grid step visits in a map, the row buffers behind the
+    chunks `dispatch` gathers (of the tokens and of the cotangent) —
+    forward and backward.  Returns the list the kernels' calls are noted
+    in."""
     calls = []
     kernels, maps, gmm, rows_map = (gm._grouped_kernels, gm._rows_map_kernels,
                                     gm._gmm, gm._rows_map)
@@ -257,10 +272,81 @@ def _on_the_kernel_route(monkeypatch, poison):
     monkeypatch.setattr(gm, "_grouped_kernels", noting_kernels)
     monkeypatch.setattr(gm, "_rows_map_kernels",
                         functools.partial(maps, interpret=True))
+    monkeypatch.setattr(gm, "_unwritten_kernel", functools.partial(
+        gm._unwritten_kernel, interpret=True))
+    monkeypatch.setattr(moe, "_GATHER_CHUNK", CHUNK)
     if poison:
         monkeypatch.setattr(gm, "_gmm", poisoned_gmm)
         monkeypatch.setattr(gm, "_rows_map", poisoned_map)
+        monkeypatch.setattr(gm, "_unwritten_kernel", lambda rows, like: (
+            jnp.full((rows, like.shape[1]), jnp.nan, like.dtype)))
     return calls
+
+
+@pytest.mark.parametrize("held", [0, 1, CHUNK, CHUNK + 1, T * K])
+def test_the_chunked_dispatch_is_the_plain_gather_on_the_held_rows(
+        monkeypatch, held):
+    """`dispatch` on the kernel route against `tokens[order // k]`: the
+    same rows, to the bit, wherever a row is held; behind the last turn
+    of the loop the buffer is what it was (NaN here), and what
+    `gathered_rows` counts is what was fetched; the backward pass reads
+    the held rows alone on either route."""
+    _on_the_kernel_route(monkeypatch, poison=True)
+    order = jax.random.permutation(jax.random.PRNGKey(held), T * K)
+    inv = jnp.argsort(order).reshape(T, K).T
+    tokens, d_rows = _draw((T, D), (T * K, D), seed=6)
+    held_rows = jnp.asarray(held, jnp.int32)
+
+    def both(route):
+        return jax.vjp(lambda x: dispatch(x, order, inv, held_rows, route),
+                       tokens)
+
+    (got, vjp), (want, plain_vjp) = both("kernel"), both("plain")
+    fetched, of = (int(n) for n in gathered_rows(held_rows[None], T * K,
+                                                 "kernel"))
+    assert (fetched, of) == (min(-(-held // CHUNK) * CHUNK, T * K), T * K)
+    got = np.asarray(got)
+    np.testing.assert_array_equal(got[:fetched], np.asarray(want)[:fetched])
+    assert held <= fetched and np.isnan(got[fetched:]).all()
+    np.testing.assert_array_equal(np.asarray(vjp(d_rows)[0]),
+                                  np.asarray(plain_vjp(d_rows)[0]))
+    assert [int(n) for n in gathered_rows(held_rows[None], T * K, "plain")] \
+        == [T * K, T * K]
+
+
+@pytest.mark.parametrize("route", ["plain", "kernel"])
+def test_a_share_counts_the_rows_its_dispatch_fetches(monkeypatch, route):
+    """A layer that holds 2 of 8 experts sows `moe_gather_rows` beside
+    its tile counts — the held rows rounded up to a turn of the loop on
+    the kernel route, every row on the plain — and `collect_moe_stats`
+    reduces it to `moe_gather_rows_share`; a whole layer sows none."""
+    cfg = dict(num_experts=8, top_k=3, impl="grouped", aux_loss="none",
+               expert_act="relu2", dtype=jnp.float32)
+    x = _draw((2, 48, D), seed=7)[0]
+    rows = 2 * 48 * 3
+    if route == "kernel":
+        _on_the_kernel_route(monkeypatch, poison=False)
+    for held in (8, 2):
+        layer = MoEMLP(hidden=D, ffn=F, moe=MoEConfig(
+            **cfg, experts_held=held % 8))
+        params = layer.init(jax.random.PRNGKey(0), x)["params"]
+        out, upd = layer.apply({"params": params}, x,
+                               mutable=["intermediates"])
+        assert np.isfinite(np.asarray(out)).all()
+        inter, stats = upd["intermediates"], collect_moe_stats(
+            upd["intermediates"])
+        if held == 8:
+            assert "moe_gather_rows" not in inter
+            assert "moe_gather_rows_share" not in stats
+            continue
+        held_rows = int(inter["moe_rows_held"][0])
+        assert 0 < held_rows < rows - CHUNK
+        fetched = -(-held_rows // CHUNK) * CHUNK if route == "kernel" \
+            else rows
+        assert [int(n) for n in inter["moe_gather_rows"][0]] \
+            == [fetched, rows]
+        assert float(stats["moe_gather_rows_share"]) == pytest.approx(
+            fetched / rows)
 
 
 def _expert_pass(form, holding, routing, seed=5):
@@ -291,12 +377,13 @@ def test_a_shares_pass_through_the_kernels_is_the_plain_routes(
     """`grouped_experts` on the route a share takes on one TPU device —
     the grouped products in `dwt_gmm` / `dwt_gmm_t` / `dwt_tgmm`, the
     activation, the sum of two first products' row gradients and the
-    combine's backward pair in `dwt_rows_map_*`, here in interpret mode —
-    against the route every CPU run takes: the output, `group_sizes` and
-    every gradient, for relu^2, ReGLU and SwiGLU experts.  Poisoned, a
-    NaN stands wherever a kernel may leave a place unwritten, in every
-    buffer the maps and the products read: the loss and every gradient
-    are finite and the plain route's all the same."""
+    combine's backward pair in `dwt_rows_map_*`, the gathers into expert
+    order over the held rows' chunks, here in interpret mode — against
+    the route every CPU run takes: the output, `group_sizes` and every
+    gradient, for relu^2, ReGLU and SwiGLU experts.  Poisoned, a NaN
+    stands wherever a kernel or `dispatch`'s loop may leave a place
+    unwritten, in every buffer the maps and the products read: the loss
+    and every gradient are finite and the plain route's all the same."""
     run, args = _expert_pass(form, holding, routing)
     gated = FORMS[form] is not None
     both = jax.value_and_grad(run, argnums=(0, 1, 2, 3, 4), has_aux=True)

@@ -328,6 +328,8 @@ def _on_the_kernel_route(monkeypatch, tile):
         kernels, interpret=True))
     monkeypatch.setattr(gm, "_rows_map_kernels", functools.partial(
         gm._rows_map_kernels, interpret=True))
+    monkeypatch.setattr(gm, "_unwritten_kernel", functools.partial(
+        gm._unwritten_kernel, interpret=True))
 
 
 @pytest.mark.parametrize("route", ["plain", "kernel"])
@@ -342,7 +344,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
     layer, params, x = _expert_layer(whole)
     want = _reference_layer(params, x, whole)
     _, upd = layer.apply({"params": params}, x, mutable=["intermediates"])
-    for counter in ("moe_gmm_tiles", "moe_map_tiles"):
+    for counter in ("moe_gmm_tiles", "moe_map_tiles", "moe_gather_rows"):
         assert counter not in upd["intermediates"]
         assert f"{counter}_share" not in collect_moe_stats(
             upd["intermediates"])
@@ -384,6 +386,8 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
                                 else (1, 1))
         assert float(collect_moe_stats(inter)["moe_map_tiles_share"]) \
             == pytest.approx(walked / of)
+        # (192 rows are one turn of `dispatch`'s loop: every row)
+        assert [int(v) for v in inter["moe_gather_rows"][0]] == [192, 192]
     assert rows == 2 * 48 * 2  # every assignment is held by one chip
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=0,
                                atol=2e-5 * float(jnp.abs(want).max()))
@@ -756,8 +760,10 @@ def test_a_few_trainer_steps_count_the_share_and_leave_the_bias_alone(
         assert a["moe_rows_held"] > 0 and a["moe_dropped"] == 0.0
         # off the TPU the grouped products walk the whole buffer
         assert a["moe_gmm_tiles_share"] == 1.0
-        # and so do the elementwise passes between them
+        # and so do the elementwise passes between them, and the
+        # gathers into expert order
         assert a["moe_map_tiles_share"] == 1.0
+        assert a["moe_gather_rows_share"] == 1.0
     after = jax.tree.map(np.asarray, tr.state.params)
     for path, old in jax.tree_util.tree_flatten_with_path(before)[0]:
         new = functools.reduce(lambda t, k: t[k.key], path, after)

@@ -147,6 +147,8 @@ def _on_the_kernel_route(monkeypatch, tile):
         kernels, interpret=True))
     monkeypatch.setattr(gm, "_rows_map_kernels", functools.partial(
         gm._rows_map_kernels, interpret=True))
+    monkeypatch.setattr(gm, "_unwritten_kernel", functools.partial(
+        gm._unwritten_kernel, interpret=True))
 
 
 @pytest.mark.parametrize("route", ["plain", "kernel"])

@@ -487,8 +487,11 @@ def _row_buffer_walkers(text, rows):
     """The device ops a compiled step still runs over a whole (rows,
     width) buffer under `moe/experts` or `moe/combine`, the row gathers
     left out (a fusion that holds a `gather`; per-row arrays (rows, 1)
-    are no buffer) and the kernels too (their grids follow the held
-    rows): what an elementwise pass of `jax.numpy` over the T*k-row
+    are no buffer), the kernels too (their grids follow the held rows)
+    and the loop that fills the cotangent's buffer by chunks of the held
+    rows (the `while` holds its body's ops, and the body's
+    `dynamic-update-slice`, alone or in a fusion, writes a chunk in
+    place): what an elementwise pass of `jax.numpy` over the T*k-row
     buffer compiles to."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
         owners, parse_computations)
@@ -501,7 +504,7 @@ def _row_buffer_walkers(text, rows):
         i = ins.get(name)
         if i is None or i["opcode"] in (
                 "custom-call", "parameter", "bitcast", "get-tuple-element",
-                "tuple") or not any(
+                "tuple", "while", "dynamic-update-slice") or not any(
                     s in entry["scope"]
                     for s in ("moe/experts", "moe/combine")):
             continue
@@ -509,10 +512,63 @@ def _row_buffer_walkers(text, rows):
                                  if o in ins]
         if not any(buffer.search(s) for s in shapes):
             continue
-        if any(m["opcode"] == "gather" for m in comps.get(i["calls"], [])):
+        if any(m["opcode"] in ("gather", "dynamic-update-slice")
+               for m in comps.get(i["calls"], [])):
             continue
         found.append((name, i["opcode"], entry["scope"]))
     return found
+
+
+def _held_row_loops(text, rows, width, layers, chunk=8192):
+    """The ops of a compiled step that hold other ops: none but the
+    `while`s of `models/moe.dispatch` on the kernel route, three an
+    expert layer — forward and recomputed under `moe/dispatch`, the
+    cotangent's under `moe/combine` — each over a buffer that starts
+    unwritten (`dwt_rows_unwritten`) and is carried, (rows, width), with
+    no copy of it at the loop's entry or exit.  A turn gathers (chunk,
+    width) and writes it in place: no gather of the step has a (rows,
+    width) result.  Returns the row gathers' result shapes, counted."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
+        instructions_of, parse_computations, scope_of)
+
+    comps = parse_computations(text)
+    every = [i for body in comps.values() for i in body]
+    assert " conditional(" not in text
+    loops = [i for i in every if i["opcode"] == "while"]
+    assert collections.Counter(
+        scope_of(i["op_name"]).split("/")[0] + "/"
+        + scope_of(i["op_name"]).rsplit("moe/", 1)[1]
+        for i in loops) == {"fwd/dispatch": layers,
+                            "recompute/dispatch": layers,
+                            "bwd/combine": layers}
+    buffer, turn = f"bf16[{rows},{width}]", f"bf16[{chunk},{width}]"
+    assert all(buffer in i["shape"] for i in loops)
+    assert len([i for i in every if i["opcode"] == "custom-call"
+                and i["name"].startswith("dwt_rows_unwritten")
+                and i["shape"].startswith(buffer)]) == 3 * layers
+    assert not [i["name"] for i in every if i["shape"].startswith(buffer)
+                and i["opcode"] in ("copy", "copy-start")]
+    bodies = {name for line in text.splitlines() if " while(" in line
+              for name in re.findall(r"body=%?([\w.\-]+)", line)}
+    assert len(bodies) == 3 * layers
+    in_place = [i for name in bodies for i in comps[name]
+                if i["shape"].startswith(buffer)
+                and (i["opcode"] == "dynamic-update-slice" or any(
+                    m["opcode"] == "dynamic-update-slice"
+                    for m in comps.get(i["calls"], [])))]
+    assert len(in_place) == 3 * layers
+    found = instructions_of(text, "gather", "moe")
+    gathers = collections.Counter(
+        shape.split("{")[0] for shape in found.values()
+        if shape.startswith("bf16["))
+    assert buffer not in gathers and gathers[turn] == 3 * layers
+    # the chunks' gathers stand in the loops' bodies and nowhere else
+    home = {i["name"]: name for name, body in comps.items() for i in body}
+    called = {i["calls"]: home[i["name"]] for i in every if i["calls"]}
+    for name, shape in found.items():
+        if shape.startswith(turn):
+            assert called.get(home[name], home[name]) in bodies, name
+    return gathers
 
 
 def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
@@ -526,9 +582,9 @@ def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
     `feed_forward/moe/experts`, none of the compiler's `ragged-dot`
     kernels: every weight operand holds the 8 held experts, none the
     published 128, and the group sizes are 8 numbers — an assignment to
-    an absent expert has no group.  Nothing in the step holds other ops
-    (a `while`, a `conditional`), which a device trace would count
-    beside the ops they ran."""
+    an absent expert has no group.  What holds other ops in the step
+    (a `while`, which a device trace counts beside the ops it ran) is
+    the twelve loops over the held rows' chunks, no `conditional`."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
 
     cell, _, step = nemotron_step
@@ -559,7 +615,7 @@ def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
     assert wide and all("moe/dispatch" in ln or "out_of_band" in ln
                         for ln in wide if "op_name=" in ln)
     assert any("out_of_band" in s for s in scopes)
-    assert " while(" not in text and " conditional(" not in text
+    _held_row_loops(text, rows, 2688, layers=4)
 
 
 def test_nemotron_step_walks_its_row_buffer_in_gathers_alone(nemotron_step):
@@ -568,7 +624,12 @@ def test_nemotron_step_walks_its_row_buffer_in_gathers_alone(nemotron_step):
     forward and recomputed (8) and its backward (4) over (T*k, 1856)
     under `moe/experts`, the combine's backward pair (4) over (T*k,
     2688) under `moe/combine` — and no fusion under either scope still
-    has a (T*k, width) operand but the gathers."""
+    has a (T*k, width) operand but the gathers by assignment.  The
+    gathers INTO expert order walk the held rows' chunks: twelve loops
+    whose turn gathers (8192, 2688) — where the parent ran twelve
+    gathers of (T*k, 2688), four of them (the cotangent's) from an 88 MB
+    source staged in VMEM (`S(1)`, PERF.md section 6, PR 38) — and a
+    turn's source or its result is still staged there."""
     cell, _, step = nemotron_step
     text = step.as_text()
     rows = cell["global_batch"] * 8192 * 6
@@ -577,21 +638,27 @@ def test_nemotron_step_walks_its_row_buffer_in_gathers_alone(nemotron_step):
         ("dwt_rows_map_relu2_bwd", f"{rows},1856"): 4,
         ("dwt_rows_map_weigh", f"{rows},2688"): 4}
     assert _row_buffer_walkers(text, rows) == []
-    # a map reserves the VMEM it holds and says what it costs at most,
-    # so the compiler still stages the 88 MB source of the cotangent's
-    # gather into expert order in VMEM under the calls before it (`S(1)`
-    # in the operand's layout), as it does the forward gathers': from
-    # HBM such a gather takes 4.2 ms for 0.8 (PERF.md section 6, PR 38)
+    gathers = _held_row_loops(text, rows, 2688, layers=4)
+    assert gathers == {"bf16[8192,2688]": 12,
+                       f"bf16[6,{rows // 6},2688]": 8}
+    # a map reserves the VMEM it holds and says what it costs at most
+    # (PR 38), so the compiler still stages in VMEM (`S(1)`) what a turn
+    # reads or what it writes: the (T, 2688) source in the loop's carry,
+    # or the gathered chunk
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import parse_computations
 
-    ins = {i["name"]: i for body in parse_computations(text).values()
-           for i in body}
-    sources = [ins[i["operands"][0]]["shape"] for i in ins.values()
-               if i["opcode"] == "fusion"
-               and i["op_name"].endswith("moe/combine/gather")
-               and "transpose(jvp" in i["op_name"]
-               and i["shape"].startswith(f"bf16[{rows},2688]")]
-    assert len(sources) == 4 and all("S(1)" in s for s in sources), sources
+    comps = parse_computations(text)
+    staged = 0
+    for line in text.splitlines():
+        if " while(" not in line:
+            continue
+        body = comps[re.search(r"body=%?([\w.\-]+)", line).group(1)]
+        source = re.search(rf"bf16\[{rows // 6},2688\]\{{[^}}]*\}}", line)
+        chunks = [i["shape"] for i in body
+                  if i["shape"].startswith("bf16[8192,2688]")]
+        staged += "S(1)" in source.group(0) or all(
+            "S(1)" in shape for shape in chunks)
+    assert staged == 12
 
 
 # --------------------------------- granite-4.0-h-micro's step on one chip
@@ -716,9 +783,17 @@ def _every_device_op_has_an_owner(step):
                                          "custom-call")
                     or ins[n]["opcode"].startswith(("copy", "slice-")))]
     assert len(unowned) <= 2, unowned
+
+    def origin(name):
+        # (a share's step copies its zero once for the loops' counters
+        # too, and returns a copy of that copy)
+        while ins[name]["opcode"] == "copy":
+            name = ins[name]["operands"][0]
+        return ins[name]["opcode"]
+
     for name in unowned:
         assert ins[name]["opcode"] == "copy", name
-        assert {ins[o]["opcode"] for o in ins[name]["operands"]} <= {
+        assert {origin(o) for o in ins[name]["operands"]} <= {
             "parameter", "constant"}, name
     assert sum(e["via"] in ("consumer", "producer")
                for e in table.values()) > 500
@@ -762,8 +837,12 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
     into expert order (T*k, d) and back by assignment (k, T, d), forward
     and backward — and the two only the backward passes emit carry the
     scope of the call they are the backward of, as every such gather in
-    the step does: none is left without a scope.  The grouped matmuls
-    see the same buffers as before, and nothing holds other ops."""
+    the step does: none is left without a scope.  Where a layer holds a
+    share (the kernel route) a gather into expert order is a loop over
+    the held rows' chunks (8192, d), its body's gather under the same
+    scope; a whole layer keeps the one gather of (T*k, d) and holds no
+    op that holds others.  The grouped matmuls see the same buffers as
+    before."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
         instructions_of, scope_table)
 
@@ -782,6 +861,9 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
     for scope in ("moe/dispatch", "moe/combine"):
         assert {s.split("[")[0] for s in shapes("scatter", scope)} \
             <= {"s32"}, scope
+
+    if fixture == "nemotron_step":
+        in_order = f"bf16[8192,{width}]"  # a turn of `dispatch`'s loop
 
     def row_gathers(under):
         return [s for s in shapes("gather", under)
@@ -810,8 +892,10 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
         assert kernels == {f"{rows},1024": 3, f"{rows},2048": 3,
                            "64,2048,1024": 2, "64,1024,2048": 1}
         assert len(ragged) == 11 and not ours
-        # a whole layer fills its buffer: no map, the compiler's fusions
-        assert "dwt_rows_map" not in text
+        # a whole layer fills its buffer: no map, the compiler's fusions,
+        # one gather of every row
+        assert "dwt_rows_map" not in text and "dwt_rows_unwritten" not in text
+        assert " while(" not in text and " conditional(" not in text
     else:
         assert not kernels and not ragged
         assert ours == {
@@ -819,7 +903,7 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
             ("dwt_gmm_t", f"{rows},1856"): 4,
             ("dwt_gmm_t", f"{rows},2688"): 4,
             ("dwt_tgmm", "8,2688,1856"): 4, ("dwt_tgmm", "8,1856,2688"): 4}
-    assert " while(" not in text and " conditional(" not in text
+        _held_row_loops(text, rows, width, layers)
 
 
 # ------------------------------ the scan's kernels in both hybrids' steps
@@ -870,7 +954,8 @@ def test_hybrid_step_scans_in_its_kernels_and_holds_no_decay_tensor(
     (the plain form, compiled alone for the same chip, names them).
     None of the kernels is named `dwt_fa_*` (`kernel.attn_ms` sums
     those): the attention layer's own four are all there are.  Nothing
-    holds other ops."""
+    of the scan holds other ops: its carry between chunks is a grid
+    axis of the kernels, no loop of the step."""
     import json
 
     from benchmark import cells, program
@@ -915,7 +1000,11 @@ def test_hybrid_step_scans_in_its_kernels_and_holds_no_decay_tensor(
     attention = [n for n in table if n.startswith("dwt_fa_")]
     assert sorted(n.split(".")[0] for n in attention) == [
         "dwt_fa_bwd_fused", "dwt_fa_fwd", "dwt_fa_fwd"]
-    assert " while(" not in text and " conditional(" not in text
+    # (what the other hybrid's step holds of loops is its expert
+    # layers': `_held_row_loops`)
+    assert " conditional(" not in text and not [
+        line for line in text.splitlines()
+        if " while(" in line and "/moe/" not in line]
 
 
 @pytest.mark.parametrize("b,t,h,p,g,n,chunk", [
