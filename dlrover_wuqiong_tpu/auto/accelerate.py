@@ -74,10 +74,10 @@ class StrategyContext:
     def model_overrides(self, model) -> Dict[str, Any]:
         """Map the flags onto the model config's field names (only fields the
         config actually has — foreign models pass through untouched)."""
-        cfg = getattr(model, "config", None)
-        if cfg is None or not dataclasses.is_dataclass(cfg):
+        fields = _config_fields(model)
+        if not fields:
             return {}
-        fields = {f.name for f in dataclasses.fields(cfg)}
+        cfg = model.config
         out: Dict[str, Any] = {}
         if self.amp is not None and "dtype" in fields:
             out["dtype"] = jnp.bfloat16 if self.amp else jnp.float32
@@ -431,6 +431,13 @@ def _warn_slow_offload_link(ctx, devices, num_params) -> None:
                     if est is not None else "")
 
 
+def _config_fields(model) -> set:
+    """The names of the model config's fields; none for a foreign model."""
+    cfg = getattr(model, "config", None)
+    return {f.name for f in dataclasses.fields(cfg)} \
+        if dataclasses.is_dataclass(cfg) else set()
+
+
 def _with_config(model, **changes):
     """The model rebuilt on its config with `changes` applied."""
     new_cfg = dataclasses.replace(model.config, **changes)
@@ -520,16 +527,17 @@ def auto_accelerate(
         if ctx.plan.ep > 1:
             planner.with_moe()
         sp_impl = ctx.extra.get("sp_impl", "ulysses")
-        has_attn_cfg = hasattr(model, "config") and \
-            dataclasses.is_dataclass(model.config) and \
-            any(f.name == "attn_impl"
-                for f in dataclasses.fields(model.config))
-        if mesh.size > 1 and has_attn_cfg:
-            # every multi-device plan hands the attention dispatch its mesh:
-            # the Pallas kernels cannot be partitioned by GSPMD and run under
-            # a shard_map over it (models/attention.py)
+        # as `model_overrides`: only fields the config actually has
+        cfg_fields = _config_fields(model)
+        if mesh.size > 1 and "mesh" in cfg_fields:
+            # every multi-device plan hands the model its mesh: the Pallas
+            # kernels (attention, the Mamba-2 scan, the grouped products)
+            # cannot be partitioned by GSPMD, and each dispatch reads the
+            # mesh to run under a shard_map over it or take its plain
+            # form (models/attention.py, mamba2.py, ops/grouped_matmul.py)
             model = _with_config(model, mesh=mesh)
-        if ctx.plan.sp > 1 and sp_impl != "gspmd" and has_attn_cfg:
+        if ctx.plan.sp > 1 and sp_impl != "gspmd" and \
+                "attn_impl" in cfg_fields:
             # context-parallel attention: ring (ppermute) or Ulysses
             # (all-to-all)
             heads = getattr(model.config, "n_head",

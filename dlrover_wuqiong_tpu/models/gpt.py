@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.sharding import pin_activation
+from . import stack
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,39 +180,18 @@ class GPT(nn.Module):
                        dtype=cfg.dtype, name="wte")(idx)
         pos = nn.Embed(cfg.block_size, cfg.n_embd,
                        dtype=cfg.dtype, name="wpe")(jnp.arange(T)[None, :])
-        x = tok + pos
-        block = Block
-        if cfg.remat:
-            from ..ops.remat import resolve_remat_policy
-
-            # prevent_cse=True: the layers run in a python loop (not
-            # scan), and without the CSE barrier XLA merges the
-            # rematerialized forward back into the saved one — measured on
-            # v5e as remat silently becoming a no-op (identical step time
-            # AND activation temps with remat on/off)
-            from ..ops.remat import MODEL_CHECKPOINT_NAMES
-
-            block = nn.remat(
-                Block, prevent_cse=True,
-                policy=resolve_remat_policy(
-                    cfg.remat_policy,
-                    cfg.remat_names or MODEL_CHECKPOINT_NAMES))
-        for i in range(cfg.n_layer):
-            x = block(cfg, name=f"h_{i}")(x, deterministic)
+        x = stack.layers(Block, cfg, [()] * cfg.n_layer, tok + pos,
+                         deterministic, prefix="h",
+                         remat_names=cfg.remat_names, static_argnums=(2,))
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
-        # weight-tied lm head (einsum against wte); it sits in no flax
-        # module, so the scope is what names its ops in the compiled step
-        # (analysis/hlo_scopes.py)
-        wte = self.variables["params"]["wte"]["embedding"]
-        with jax.named_scope("head"):
-            logits = jnp.einsum("bte,ve->btv", x, wte.astype(cfg.dtype))
+        logits = stack.tied_head(
+            x, self.variables["params"]["wte"]["embedding"], cfg.dtype)
         if return_hidden:  # e.g. a value head on the trunk (rl/ppo.py)
             return logits, x
         return logits
 
     def init_params(self, rng, batch: int = 1, seq: int = 8):
-        idx = jnp.zeros((batch, seq), jnp.int32)
-        return self.init(rng, idx)["params"]
+        return stack.init_params(self, rng, batch, seq)
 
 
 def cross_entropy_loss(logits, targets, ignore_index: int = -1):

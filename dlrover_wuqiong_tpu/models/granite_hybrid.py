@@ -15,9 +15,7 @@ The head is the embedding table itself (no `lm_head` leaf): the table
 takes the lookup's gradient and the head's.  Parameter names are
 `layers_<i>/{input_norm,post_mixer_norm}`, `layers_<i>/{mamba|attention}`
 and `layers_<i>/feed_forward`, matched by `parallel/sharding.py`; the
-head's product sits under the `head` scope as `models/gpt.py`'s tied one
-does.  Each block is recomputed through `ops/remat.py` as the Llama
-stack does it.
+stack and the tied head are `models/stack.py`'s.
 
 Parity: none — the reference trains Llama/GLM-class stacks only
 (models/llama.py); this stack exists for the dense hybrid's benchmark
@@ -30,10 +28,10 @@ import dataclasses
 from typing import Any, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from ..parallel.sharding import pin_activation
+from . import stack
 from .llama import LlamaAttention, LlamaConfig, LlamaMLP, RMSNorm
 from .mamba2 import Mamba2Config, Mamba2Mixer
 
@@ -165,29 +163,13 @@ class GraniteHybrid(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                          name="embed_tokens")
         x = _scaled(embed(idx), cfg.embedding_multiplier)
-        block = GraniteHybridBlock
-        if cfg.remat:
-            from ..ops.remat import (
-                MODEL_CHECKPOINT_NAMES,
-                resolve_remat_policy,
-            )
-
-            # prevent_cse=True, as models/llama.py
-            block = nn.remat(
-                GraniteHybridBlock, prevent_cse=True, static_argnums=(),
-                policy=resolve_remat_policy(cfg.remat_policy,
-                                            MODEL_CHECKPOINT_NAMES))
-        for i, kind in enumerate(cfg.layer_types):
-            x = block(cfg, kind, name=f"layers_{i}")(x)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
-        # the tied head sits in no flax module of its own: the scope is
-        # what names its ops in the compiled step (as models/gpt.py)
-        with jax.named_scope("head"):
-            logits = jnp.einsum("bte,ve->btv", x,
-                                embed.embedding.astype(cfg.dtype))
-            logits = _scaled(logits, 1.0 / cfg.logits_scaling)
-        return logits
+        x = stack.layers(GraniteHybridBlock, cfg,
+                         [(kind,) for kind in cfg.layer_types], x)
+        return stack.tied_head(
+            RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x),
+            embed.embedding, cfg.dtype,
+            scaled=lambda logits: _scaled(logits, 1.0 / cfg.logits_scaling))
 
     def init_params(self, rng, batch: int = 1, seq: int = 0):
-        idx = jnp.zeros((batch, seq or self.config.chunk_size), jnp.int32)
-        return self.init(rng, idx)["params"]
+        return stack.init_params(self, rng, batch,
+                                 seq or self.config.chunk_size)

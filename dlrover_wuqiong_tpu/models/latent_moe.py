@@ -40,10 +40,10 @@ import dataclasses
 from typing import Any, Optional
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from ..parallel.sharding import pin_activation
+from . import stack
 from .latent_attention import LatentAttention, LatentAttentionConfig
 from .llama import LlamaConfig, LlamaMLP, RMSNorm, rope_freqs
 from .moe import MoEConfig, MoEMLP
@@ -176,26 +176,11 @@ class LatentMoE(nn.Module):
         # the rotated part alone carries the positions
         cos, sin = rope_freqs(cfg.qk_rope_head_dim, cfg.max_seq_len,
                               cfg.rope_theta)
-        block = LatentMoEBlock
-        if cfg.remat:
-            from ..ops.remat import (
-                MODEL_CHECKPOINT_NAMES,
-                resolve_remat_policy,
-            )
-
-            # prevent_cse=True, as models/llama.py
-            block = nn.remat(
-                LatentMoEBlock, prevent_cse=True, static_argnums=(),
-                policy=resolve_remat_policy(cfg.remat_policy,
-                                            MODEL_CHECKPOINT_NAMES))
-        for i in range(cfg.num_layers):
-            x = block(cfg, i, name=f"layers_{i}")(x, cos, sin)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
-        with jax.named_scope("head"):  # as models/llama.py names its head
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=cfg.dtype, name="lm_head")(x)
-        return logits
+        x = stack.layers(LatentMoEBlock, cfg,
+                         [(i,) for i in range(cfg.num_layers)], x, cos, sin)
+        return stack.untied_head(
+            RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x),
+            cfg.vocab_size, cfg.dtype)
 
     def init_params(self, rng, batch: int = 1, seq: int = 8):
-        idx = jnp.zeros((batch, seq), jnp.int32)
-        return self.init(rng, idx)["params"]
+        return stack.init_params(self, rng, batch, seq)

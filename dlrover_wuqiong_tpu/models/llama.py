@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..parallel.sharding import pin_activation
+from . import stack
 
 
 @dataclasses.dataclass(frozen=True)
@@ -277,27 +278,11 @@ class Llama(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      name="embed_tokens")(idx)
         cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-        block = LlamaBlock
-        if cfg.remat:
-            from ..ops.remat import resolve_remat_policy
-
-            # prevent_cse=True — see models/gpt.py: python-loop layers
-            # need the CSE barrier or XLA undoes the remat
-            from ..ops.remat import MODEL_CHECKPOINT_NAMES
-
-            block = nn.remat(
-                LlamaBlock, prevent_cse=True, static_argnums=(),
-                policy=resolve_remat_policy(
-                    cfg.remat_policy,
-                    cfg.remat_names or MODEL_CHECKPOINT_NAMES))
-        for i in range(cfg.num_layers):
-            x = block(cfg, name=f"layers_{i}")(x, cos, sin)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
-        with jax.named_scope("head"):  # as models/gpt.py names its head
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=cfg.dtype, name="lm_head")(x)
-        return logits
+        x = stack.layers(LlamaBlock, cfg, [()] * cfg.num_layers, x, cos, sin,
+                         remat_names=cfg.remat_names)
+        return stack.untied_head(
+            RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x),
+            cfg.vocab_size, cfg.dtype)
 
     def init_params(self, rng, batch: int = 1, seq: int = 8):
-        idx = jnp.zeros((batch, seq), jnp.int32)
-        return self.init(rng, idx)["params"]
+        return stack.init_params(self, rng, batch, seq)

@@ -12,9 +12,8 @@ grouped-query attention without rotary embedding (models/llama.py's
 ONE mixer a block behind one RMSNorm, so the layers of a stack cost
 unequal amounts.  Parameter names are `layers_<i>/norm` and
 `layers_<i>/{mamba|feed_forward|attention}`, matched by
-`parallel/sharding.py`; the embedding, the head and its `head` scope are
-`models/llama.py`'s.  Each block is recomputed through `ops/remat.py`
-as the Llama stack does it.
+`parallel/sharding.py`; the embedding's and the head's are
+`models/llama.py`'s, the stack `models/stack.py`'s.
 
 Parity: none — the reference trains Llama/GLM-class stacks only
 (models/llama.py); this stack exists for the hybrid's benchmark cell.
@@ -26,10 +25,10 @@ import dataclasses
 from typing import Any
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from ..parallel.sharding import pin_activation
+from . import stack
 from .llama import LlamaAttention, LlamaConfig, RMSNorm
 from .mamba2 import Mamba2Config, Mamba2Mixer
 from .moe import MoEConfig, MoEMLP
@@ -166,26 +165,12 @@ class NemotronH(nn.Module):
                              f"{sorted(KINDS)}")
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      name="embed_tokens")(idx)
-        block = NemotronHBlock
-        if cfg.remat:
-            from ..ops.remat import (
-                MODEL_CHECKPOINT_NAMES,
-                resolve_remat_policy,
-            )
-
-            # prevent_cse=True, as models/llama.py
-            block = nn.remat(
-                NemotronHBlock, prevent_cse=True, static_argnums=(),
-                policy=resolve_remat_policy(cfg.remat_policy,
-                                            MODEL_CHECKPOINT_NAMES))
-        for i, kind in enumerate(cfg.pattern):
-            x = block(cfg, kind, name=f"layers_{i}")(x)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
-        with jax.named_scope("head"):  # as models/llama.py names its head
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=cfg.dtype, name="lm_head")(x)
-        return logits
+        x = stack.layers(NemotronHBlock, cfg,
+                         [(kind,) for kind in cfg.pattern], x)
+        return stack.untied_head(
+            RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x),
+            cfg.vocab_size, cfg.dtype)
 
     def init_params(self, rng, batch: int = 1, seq: int = 0):
-        idx = jnp.zeros((batch, seq or self.config.chunk_size), jnp.int32)
-        return self.init(rng, idx)["params"]
+        return stack.init_params(self, rng, batch,
+                                 seq or self.config.chunk_size)

@@ -38,10 +38,10 @@ import dataclasses
 from typing import Any, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from ..parallel.sharding import pin_activation
+from . import stack
 from .llama import LlamaAttention, LlamaConfig, RMSNorm, rope_freqs
 from .moe import MoEConfig, MoEMLP
 
@@ -159,26 +159,11 @@ class SmallThinker(nn.Module):
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      name="embed_tokens")(idx)
         cos, sin = rope_freqs(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-        block = SmallThinkerBlock
-        if cfg.remat:
-            from ..ops.remat import (
-                MODEL_CHECKPOINT_NAMES,
-                resolve_remat_policy,
-            )
-
-            # prevent_cse=True, as models/llama.py
-            block = nn.remat(
-                SmallThinkerBlock, prevent_cse=True, static_argnums=(),
-                policy=resolve_remat_policy(cfg.remat_policy,
-                                            MODEL_CHECKPOINT_NAMES))
-        for i in range(cfg.num_layers):
-            x = block(cfg, i, name=f"layers_{i}")(x, cos, sin)
-        x = RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x)
-        with jax.named_scope("head"):  # as models/llama.py names its head
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=cfg.dtype, name="lm_head")(x)
-        return logits
+        x = stack.layers(SmallThinkerBlock, cfg,
+                         [(i,) for i in range(cfg.num_layers)], x, cos, sin)
+        return stack.untied_head(
+            RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x),
+            cfg.vocab_size, cfg.dtype)
 
     def init_params(self, rng, batch: int = 1, seq: int = 8):
-        idx = jnp.zeros((batch, seq), jnp.int32)
-        return self.init(rng, idx)["params"]
+        return stack.init_params(self, rng, batch, seq)

@@ -748,6 +748,44 @@ def test_granite_step_runs_the_kernels_direct_at_32_heads_of_64(
     assert sorted(moved.values()) == ["copy"] * 6, moved
 
 
+def test_granite_on_four_chips_is_handed_its_mesh_and_compiles(
+        topo, monkeypatch):
+    """ROADMAP D19: `GraniteHybridConfig` declares `mesh` and no
+    `attn_impl`, so the parent's `auto_accelerate` left it `None` on
+    four chips and the model traced its Mosaic kernels outside any
+    shard_map.  Handed the plan's mesh, a mamba and an attention layer
+    at the published widths compile under `fsdp` for the described 2x2:
+    the attention's kernels sit in a shard_map, a chip's row of 32 heads
+    each (the transposed route; off one device nothing goes direct), and
+    the scan is the plain one (no `dwt_ssd` kernel, which GSPMD could
+    not partition)."""
+    import optax
+
+    from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
+    from dlrover_wuqiong_tpu.models.granite_hybrid import (
+        GraniteHybrid, GraniteHybridConfig)
+
+    monkeypatch.setenv("DWT_COMPILE_CACHE", "0")
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    monkeypatch.setattr("dlrover_wuqiong_tpu.models.attention._on_tpu",
+                        lambda: True)
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
+    cfg = GraniteHybridConfig(layer_types=("mamba", "attention"))
+    res = auto_accelerate(
+        GraniteHybrid(cfg), strategy=[("fsdp", {})], devices=topo.devices,
+        optimizer=optax.adamw(3e-4), materialize=False, seq_len=512)
+    assert res.mesh.size == 4 and res.model.config.mesh is res.mesh
+    ids = jax.ShapeDtypeStruct((4, 512), jnp.int32,
+                               sharding=res.batch_sharding_fn(2))
+    text = res.train_step.lower(
+        res.state, {"input_ids": ids, "labels": ids}).compile().as_text()
+    kernels = re.findall(r'custom_call_target="tpu_custom_call"[^\n]*?'
+                         r'op_name="([^"]*)/pallas_call"', text)
+    assert len(kernels) == 3 and all(
+        "/layers_1/attention/shard_map/dwt_fa_" in k for k in kernels), kernels
+    assert "bf16[32,512,64]" in text and "dwt_ssd" not in text
+
+
 # --------------------------- who owns the device ops of the four steps
 #
 # (and of the windowed MoE's, the fifth: tests/test_smallthinker_compile.py,
