@@ -103,8 +103,12 @@ class LatentAttention(nn.Module):
             k_nope, v = kv[..., :dn], kv[..., dn:]
             k_rope = c[..., rank:].reshape(B, T, 1, dr)
         with jax.named_scope("rope"):
-            q_rope = apply_rope(q_rope, cos, sin)
-            k_rope = apply_rope(k_rope, cos, sin)  # ONE for all heads
+            # on one TPU device both take the kernel route: the q
+            # heads' parts side by side are rows of H x 64 lanes, two
+            # heads a slab, and the ONE key part for all heads half a
+            # slab, padded to one
+            q_rope = apply_rope(q_rope, cos, sin, mesh=cfg.mesh)
+            k_rope = apply_rope(k_rope, cos, sin, mesh=cfg.mesh)
         with jax.named_scope("assemble"):
             q = jnp.concatenate([q_nope, q_rope], axis=-1)
             k = jnp.concatenate(

@@ -18,6 +18,7 @@ embed_tokens, lm_head) so TP/FSDP/SP specs bind without per-model glue.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -137,16 +138,33 @@ def rope_freqs(head_dim: int, max_seq: int, theta: float):
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
-def apply_rope(x, cos, sin):
+def apply_rope(x, cos, sin, mesh=None):
     """Rotate each head's pairs (even, odd interleaved by halves).  x is
     (b, s, h, d), or the projections' own (b, s, h*d) with the heads side
     by side and never cut apart — the one rotation of both layouts.
+
+    Two routes, chosen by what the call can observe
+    (`ops/rope.rope_route`, never a knob): on the TPU, d of 64 or 128,
+    rows whose h*d is a whole number of 128-lane slabs or one lone head
+    of 64, on one device (`mesh` is the model config's) or inside a
+    `shard_map`, take `ops/rope.py`'s kernel `dwt_rope`, one read and
+    one write of the rows, differentiated by the same kernel —
+    SmallThinker's and OLMoE's q and k, latent attention's q heads and
+    its one shared key part.  Every other call — the CPU, a head size or
+    an odd number of heads of 64 off the slab, a mesh of several devices
+    — takes the formula below: the plain route, and the tests' oracle.
 
     A head's two halves trade places by two rolls of the last axis and a
     select (a roll never wraps into a lane that is kept; over one head's
     d the two rolls are the same swap), and the sign rides on the sine:
     two products and one sum an element."""
+    from ..ops.rope import rope_route, rotate_rows
+
     s, lanes, half = x.shape[1], x.shape[-1], cos.shape[-1]
+    row = math.prod(x.shape[2:])  # a position's heads side by side
+    if rope_route(row, 2 * half, mesh) == "kernel":
+        return rotate_rows(x.reshape(*x.shape[:2], row), cos,
+                           sin).reshape(x.shape)
     heads = lanes // (2 * half)
     c = jnp.tile(jnp.concatenate([cos[:s], cos[:s]], axis=-1), (1, heads))
     si = jnp.tile(jnp.concatenate([-sin[:s], sin[:s]], axis=-1),
@@ -196,8 +214,8 @@ class LlamaAttention(nn.Module):
             k = k.reshape(B, T, cfg.num_kv_heads, hd)
             v = v.reshape(B, T, cfg.num_kv_heads, hd)
         if cfg.rope:
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            q = apply_rope(q, cos, sin, mesh=cfg.mesh)
+            k = apply_rope(k, cos, sin, mesh=cfg.mesh)
         rep = cfg.num_heads // cfg.num_kv_heads
         if rep > 1:  # GQA: repeat kv heads
             k, v = (jnp.repeat(t.reshape(B, T, cfg.num_kv_heads, hd), rep,

@@ -1,0 +1,184 @@
+"""Rotary position embedding over the projections' rows in ONE pass.
+
+    y[.., t, head, :] = (x1 cos_t - x2 sin_t | x2 cos_t + x1 sin_t)
+
+for each head's halves (x1 | x2), on rows laid out as the projections
+leave them: (B, T, heads * d), the heads side by side.
+
+`dwt_rope` (one `pallas_call`) reads a block of rows once and writes it
+once.  A grid step is (a tile of `_ROW_TILE` positions, a batch row); its
+block is the rows' whole width, worked on a 128-lane slab at a time.  The
+block's positions pick their rows of ONE float32 table over one slab,
+`[cos | sin]` a head (`rope_table`: (T, 128), a head's d at d = 128, two
+heads' at d = 64; 8 MB at T = 16,384), the same for every head and every
+batch row — the batch is the inner grid axis, so a tile of the table is
+fetched once for all of it — and nothing of the rows' size is ever tiled
+out in HBM.  A grid step turns its tile of the table into `[cos | cos]`
+and `[-sin | sin]` (a roll by d/2 and two selects, once for all the
+slabs).  Inside a slab the partner of lane i is lane i +- d/2 of the
+SAME head: at d = 128 a head is a slab and both directions are the one
+cyclic `pltpu.roll` by 64; at d = 64 two heads share a slab, and the
+partner is the roll by 32 in a head's upper half and by 96 (= -32) in
+its lower — neither wraps into a lane that is kept.  The sign rides on
+the sine: two products and one sum an element, in float32 whatever the
+rows hold, rounded once.
+
+The cotangent of a rotation by theta is the rotation by -theta: the
+backward pass of the `jax.custom_vjp` is the SAME kernel with the sine
+negated (in VMEM, a tile a grid step), and keeps nothing but the table.
+
+Which calls take it is what a call can observe, never a knob
+(`rope_route`): on the TPU, d of 64 or 128, rows whose width is a whole
+number of slabs — or ONE head of 64, half a slab, which is padded to one
+(latent attention's shared key part: it then reads the table its q
+heads read, and the formula's own tables are not built at all) — on one
+device or inside a `shard_map` (a Mosaic kernel cannot be partitioned by
+GSPMD).  Every other call keeps the formula of `models/llama.apply_rope`,
+which is the plain route and the tests' oracle.
+
+What a v5e trace showed: PERF.md section 6, PR 44
+(`tools/perf_probe.py rope`).
+
+Parity: none — the reference rotates with torch ops a head at a time.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _on_tpu, _out_struct
+
+_LANES = 128
+_ROW_TILE = 512  # positions a grid step: a bf16 block of 3,584 lanes is 3.5 MB
+_VMEM_FLOOR = 16 * 1024 * 1024  # the compiler's own default
+
+
+def _inside_shard_map() -> bool:
+    """Whether the trace runs inside a `shard_map` over every axis of
+    its mesh: the one place a kernel runs on a mesh of several devices."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return bool(mesh.axis_names) and set(mesh.manual_axes) == set(
+        mesh.axis_names)
+
+
+def rope_route(lanes: int, d: int, mesh=None) -> str:
+    """Which route a rotation of rows `lanes` wide, heads of `d`, takes:
+    "kernel" (`dwt_rope`) on the TPU when a head is a slab or half of
+    one, the rows are a whole number of 128-lane slabs or one lone head
+    (half a slab: padded), and the call runs on one device (`mesh` is
+    the model config's, None or of size 1) or inside a `shard_map`; else
+    "plain", `models/llama.apply_rope`'s own lines.  The static counter
+    of the decision, with the compiled step's count of `dwt_rope` custom
+    calls, as `ops/ssd.scan_route` is of the scan's."""
+    slabs = lanes % _LANES == 0 or lanes == d  # whole, or a lone head
+    if not _on_tpu() or d not in (64, 128) or not slabs:
+        return "plain"
+    if mesh is not None and mesh.size > 1 and not _inside_shard_map():
+        return "plain"
+    return "kernel"
+
+
+def rope_table(cos, sin):
+    """`rope_freqs`' (T, d/2) cos and sin -> the kernel's float32 table
+    over one 128-lane slab, (T, 128): `[cos | sin]` a head, two heads
+    side by side at d = 64."""
+    heads = _LANES // (2 * cos.shape[-1])
+    return jnp.tile(jnp.concatenate([cos, sin], axis=-1).astype(jnp.float32),
+                    (1, heads))
+
+
+def _rope_kernel(x_ref, table_ref, o_ref, *, half, inverse):
+    """One (position tile, batch row): every slab of the block rotated
+    by the tile's rows of the table — by their negative where `inverse`."""
+    cos_sin = table_ref[...]
+    sin_cos = pltpu.roll(cos_sin, half, 1)
+    # which half of its head a lane lies in
+    upper = (jax.lax.broadcasted_iota(jnp.int32, cos_sin.shape, 1)
+             // half) % 2 == 1
+    c = jnp.where(upper, sin_cos, cos_sin)   # [cos | cos]
+    s = jnp.where(upper, cos_sin, -sin_cos)  # [-sin | sin]
+    if inverse:
+        s = -s
+    for slab in range(x_ref.shape[-1] // _LANES):
+        lanes = slice(slab * _LANES, (slab + 1) * _LANES)
+        x = x_ref[0, :, lanes].astype(jnp.float32)
+        partner = pltpu.roll(x, half, 1)
+        if 2 * half < _LANES:  # two heads a slab
+            partner = jnp.where(upper, partner,
+                                pltpu.roll(x, _LANES - half, 1))
+        o_ref[0, :, lanes] = (x * c + partner * s).astype(o_ref.dtype)
+
+
+def _rope_pallas(x, table, *, half, inverse, tile, interpret):
+    """x (B, T, lanes) rotated by the table (T, 128).  Grid: (ceil(T /
+    tile), B); what a last tile reads behind T is never written."""
+    b, t, lanes = x.shape
+    size = jnp.dtype(x.dtype).itemsize
+    rows = pl.BlockSpec((1, tile, lanes), lambda i, j: (j, i, 0))
+    # blocks in and out and the table's tile, double-buffered, and the
+    # float32 temporaries of the table's two forms and of a slab
+    vmem = 2 * (2 * tile * lanes * size + tile * _LANES * 4) \
+        + 10 * tile * _LANES * 4
+    return pl.pallas_call(
+        functools.partial(_rope_kernel, half=half, inverse=inverse),
+        grid=(pl.cdiv(t, tile), b),
+        in_specs=[rows, pl.BlockSpec((tile, _LANES), lambda i, j: (i, 0))],
+        out_specs=rows,
+        out_shape=_out_struct(x.shape, x.dtype, x),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(vmem * 5 // 4, _VMEM_FLOOR)),
+        cost_estimate=pl.CostEstimate(
+            flops=3 * x.size, transcendentals=0,
+            bytes_accessed=2 * x.size * size + t * _LANES * 4),
+        interpret=interpret,
+        name="dwt_rope",
+    )(x, table)
+
+
+# behind `jax.jit` the body is traced and lowered to Mosaic once a shape,
+# not once a call (three layers x q and k, forward and backward)
+_rope = jax.jit(_rope_pallas,
+                static_argnames=("half", "inverse", "tile", "interpret"))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rotated(x, table, plan):
+    return _rope(x, table, inverse=False, **dict(plan))
+
+
+def _rotated_fwd(x, table, plan):
+    return _rotated(x, table, plan), table
+
+
+def _rotated_bwd(plan, table, d_out):
+    return _rope(d_out, table, inverse=True, **dict(plan)), None
+
+
+_rotated.defvjp(_rotated_fwd, _rotated_bwd)
+
+
+def _rope_kernels(x, cos, sin, tile=None, interpret=False):
+    """`rotate_rows` whatever the route says (tests reach the kernel in
+    interpret mode through here, the probe its tile)."""
+    t, lanes = x.shape[1:]
+    # all of a shorter sequence: a block's rows are a multiple of a
+    # packed bfloat16 tile, 16, or the array's own
+    plan = (("half", cos.shape[-1]), ("tile", min(t, tile or _ROW_TILE)),
+            ("interpret", interpret))
+    if lanes < _LANES:  # a lone head of 64: an empty head beside it
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, _LANES - lanes)))
+    return _rotated(x, rope_table(cos[:t], sin[:t]), plan)[..., :lanes]
+
+
+def rotate_rows(x, cos, sin):
+    """x (B, T, heads * d) rotated head by head, position t by row t of
+    `rope_freqs`' cos and sin ((T', d/2), T' >= T): the kernel route of
+    `models/llama.apply_rope`, for calls of which `rope_route` says
+    "kernel".  Differentiable in x; the tables are constants."""
+    return _rope_kernels(x, cos, sin)

@@ -142,6 +142,27 @@ def test_kimi_vl_step_holds_its_scopes_and_a_share_of_swiglu_experts(
     _held_row_loops(text, rows, 2048, layers=4)
 
 
+def test_kimi_vl_step_rotates_in_one_pass_under_its_scope(kimi_vl_step):
+    """The five latent layers rotate the q heads' 64-wide parts side by
+    side, (batch, 16384, 16 x 64), two heads a lane slab, and the ONE
+    key part a token, 64 lanes padded to a slab: two `dwt_rope` calls
+    forward, recomputed and backward a layer — 30, each under the scope
+    `attention/rope`, which `step.attn_latent_ms` reads."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import owners
+
+    cell, _, step = kimi_vl_step
+    text = step.as_text()
+    b = cell["global_batch"]
+    calls = collections.Counter(re.findall(
+        r"%dwt_rope[.\d]* = (\w+\[[\d,]+\])", text))
+    assert calls == {f"bf16[{b},16384,1024]": 15, f"bf16[{b},16384,128]": 15}
+    own = owners(text)
+    assert collections.Counter(
+        re.sub(r"^\w+/", "", own[name]["scope"])
+        for name in re.findall(r"%(dwt_rope[.\d]*) = ", text)) == {
+            "LatentMoE/layers/attention/rope/dwt_rope": 30}
+
+
 def test_kimi_vl_step_walks_its_row_buffer_in_gathers_alone(kimi_vl_step):
     """The elementwise passes of a share's four expert layers are
     `dwt_rows_map_*` kernels over the tiles that hold a held row: SwiGLU

@@ -404,14 +404,14 @@ def test_llama_off_the_direct_route_rotates_a_head_at_a_time(monkeypatch):
             ).as_text()
         return sorted(op for op, _, _ in iter_collectives(text))
 
-    def split_and_join(x, cos, sin):  # apply_rope as it was before PR 29
+    def split_and_join(x, cos, sin, mesh=None):  # as it was before PR 29
         c = cos[:x.shape[1]][None, :, None, :]
         si = sin[:x.shape[1]][None, :, None, :]
         x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
         return jnp.concatenate([x1 * c - x2 * si, x2 * c + x1 * si],
                                axis=-1).astype(x.dtype)
 
-    def whole_row_then_cut(x, cos, sin, rope=llama.apply_rope):
+    def whole_row_then_cut(x, cos, sin, mesh=None, rope=llama.apply_rope):
         b, s, h, d = x.shape
         return rope(x.reshape(b, s, h * d), cos, sin).reshape(x.shape)
 

@@ -1,0 +1,142 @@
+"""`ops/rope.py`'s kernel `dwt_rope` in interpret mode (no chip): against
+the written-out rotation, its VJP against the plain formula's, a
+rotation and its inverse, and which calls `rope_route` hands to it.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+from test_program_from_arguments import _rope_written_out
+
+from dlrover_wuqiong_tpu.models.llama import apply_rope, rope_freqs
+from dlrover_wuqiong_tpu.ops import rope
+
+T, TILE = 40, 16  # two whole row tiles and half of one
+
+
+@pytest.fixture
+def on_the_kernel_route(monkeypatch):
+    """What one TPU device runs, here: the route's own decision with the
+    backend said to be the TPU, a row tile T is no multiple of, the
+    kernel in interpret mode; -> the calls the kernel took."""
+    calls = []
+
+    def kernels(x, cos, sin):
+        calls.append(x.shape)
+        return rope._rope_kernels(x, cos, sin, interpret=True)
+
+    monkeypatch.setattr(rope, "_on_tpu", lambda: True)
+    monkeypatch.setattr(rope, "_ROW_TILE", TILE)
+    monkeypatch.setattr(rope, "rotate_rows", kernels)
+    return calls
+
+
+def _close(got, want, dtype):
+    """Equal to a rounding of the last sum (the interpreter's CPU may
+    contract a product and the sum where the formula's fusion does not)."""
+    ulp = 2.0 ** -7 if dtype == jnp.bfloat16 else 2.0 ** -22
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=ulp,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("layout", ["heads", "flat"])
+@pytest.mark.parametrize("h", [1, 4, 7])
+@pytest.mark.parametrize("d", [64, 128])
+def test_rotation_is_the_written_out_formula_on_either_route(
+        on_the_kernel_route, d, h, layout, dtype):
+    """`apply_rope` as one TPU device runs it, on (b, s, h, d) and on the
+    projections' own (b, s, h*d): rows of a whole number of slabs go
+    through `dwt_rope` (a head a slab at 128, two a slab at 64), a
+    lone head of 64 too (padded to a slab), seven heads of 64 keep the
+    formula — and both are the per-head rotation."""
+    b = 2
+    cos, sin = rope_freqs(d, T + 8, 10000.0)
+    x = jax.random.normal(jax.random.PRNGKey(h + d), (b, T, h, d), dtype)
+    got = apply_rope(x if layout == "heads" else x.reshape(b, T, h * d),
+                     cos, sin)
+    route = rope.rope_route(h * d, d)
+    assert route == ("plain" if (h, d) == (7, 64) else "kernel")
+    assert on_the_kernel_route == ([(b, T, h * d)] if route == "kernel"
+                                   else [])
+    assert got.dtype == dtype
+    assert got.shape == (x.shape if layout == "heads" else (b, T, h * d))
+    _close(got.reshape(x.shape), _rope_written_out(x, cos, sin), dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("d", [64, 128])
+def test_backward_is_the_plain_formulas_gradient(on_the_kernel_route, d,
+                                                 dtype):
+    """The `custom_vjp` (the kernel again, the sine negated) against
+    JAX's own differentiation of the formula, off the TPU."""
+    cos, sin = rope_freqs(d, T, 500000.0)
+    keys = jax.random.split(jax.random.PRNGKey(d), 2)
+    x, d_out = (jax.random.normal(k, (2, T, 4 * d), dtype) for k in keys)
+    got, = jax.vjp(lambda x: apply_rope(x, cos, sin), x)[1](d_out)
+    assert len(on_the_kernel_route) == 1 and got.dtype == dtype
+    # a mesh of several devices outside a shard_map: the plain route
+    want, = jax.vjp(lambda x: apply_rope(x, cos, sin, mesh=_mesh(2)), x)[1](
+        d_out)
+    assert len(on_the_kernel_route) == 1
+    _close(got, want, dtype)
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_a_rotation_and_its_inverse_are_the_identity(d):
+    cos, sin = rope_freqs(d, T, 10000.0)
+    x = jax.random.normal(jax.random.PRNGKey(d), (2, T, 2 * d), jnp.float32)
+    turn = functools.partial(rope._rope, half=d // 2, tile=TILE,
+                             interpret=True)
+    table = rope.rope_table(cos, sin)
+    assert table.shape == (T, 128) and table.dtype == jnp.float32
+    there = turn(x, table, inverse=False)
+    assert float(jnp.abs(there[:, 1:] - x[:, 1:]).max()) > 0.5
+    np.testing.assert_allclose(turn(there, table, inverse=True), x,
+                               atol=2e-6)
+
+
+def _mesh(n):
+    return Mesh(np.array(jax.devices()[:n]), ("fsdp",))
+
+
+@pytest.mark.parametrize("on_tpu,lanes,d,devices,inside,want", [
+    # the cells: SmallThinker's q and k, OLMoE's, latent attention's q part
+    (True, 3584, 128, 0, False, "kernel"),
+    (True, 512, 128, 1, False, "kernel"),
+    (True, 2048, 128, 1, False, "kernel"),
+    (True, 1024, 64, 1, False, "kernel"),
+    # latent attention's ONE rotated key part: half a slab, padded
+    (True, 64, 64, 1, False, "kernel"),
+    # seven heads of 64, half a head of 128, a head size off the slab
+    (True, 448, 64, 0, False, "plain"),
+    (True, 64, 128, 0, False, "plain"),
+    (True, 1024, 32, 0, False, "plain"),
+    (True, 1024, 256, 0, False, "plain"),
+    # off the TPU; on a mesh of several devices, but inside a shard_map
+    (False, 3584, 128, 0, False, "plain"),
+    (True, 3584, 128, 4, False, "plain"),
+    (True, 3584, 128, 4, True, "kernel"),
+])
+def test_which_calls_take_the_kernel(monkeypatch, on_tpu, lanes, d, devices,
+                                     inside, want):
+    """`rope_route`: the static counter of the decision."""
+    monkeypatch.setattr(rope, "_on_tpu", lambda: on_tpu)
+    mesh = _mesh(devices) if devices else None
+    if not inside:
+        assert rope.rope_route(lanes, d, mesh) == want
+        return
+    seen = []
+
+    def shard(x):
+        seen.append(rope.rope_route(lanes, d, mesh))
+        return x
+
+    jax.eval_shape(jax.shard_map(shard, mesh=mesh, in_specs=P("fsdp"),
+                                 out_specs=P("fsdp")), jnp.zeros((8, 4)))
+    assert seen == [want]

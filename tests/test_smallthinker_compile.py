@@ -52,7 +52,9 @@ def test_smallthinker_step_fits_one_chip_by_the_rule_and_fills_it(
     XLA ops alone: +1.007 GB for a (196608, 2560) bf16 carry, where the
     program's heap holds it once and the chip reserves the parent's
     bytes to 16 KB: PERF.md section 6, PR 42): the one row buffer is
-    taken off the reading before it is held to the pin."""
+    taken off the reading before it is held to the pin.  13.85 since
+    PR 44: the rotation's float32 copy of q and its tables tiled out to
+    3,584 lanes went with the formula's fusions (`dwt_rope`)."""
     cell, model, step = smallthinker_step
     assert model.config.num_params() == 559_290_880
     assert cell["seq_len"] == 16384
@@ -60,7 +62,7 @@ def test_smallthinker_step_fits_one_chip_by_the_rule_and_fills_it(
     live = m.argument_size_in_bytes + m.temp_size_in_bytes \
         + m.output_size_in_bytes - m.alias_size_in_bytes
     live -= cell["global_batch"] * 16384 * 6 * 2560 * 2  # counted twice
-    want = {1: 11.09, 2: 14.26}[cell["global_batch"]]
+    want = {1: 11.09, 2: 13.85}[cell["global_batch"]]
     assert live / 1e9 == pytest.approx(want, abs=0.05)
     assert 0.25 * 16 * 2 ** 30 < 0.65 * 16e9 < live < 0.90 * 16e9, live / 1e9
     assert m.alias_size_in_bytes >= 12 * model.config.num_params()
@@ -155,6 +157,39 @@ def test_smallthinker_step_walks_its_row_buffer_in_gathers_alone(
     assert _row_buffer_walkers(text, rows) == []
     assert _held_row_loops(text, rows, 2560, layers=4) == {
         "bf16[8192,2560]": 12, f"bf16[6,{rows // 6},2560]": 8}
+
+
+def test_smallthinker_step_rotates_q_and_k_in_one_pass_each(
+        smallthinker_step):
+    """The three windowed layers rotate q and k, each forward,
+    recomputed and backward (the backward the same kernel): 18
+    `dwt_rope` calls on the projections' own (batch, 16384, 28 x 128)
+    and (batch, 16384, 4 x 128), all under `attention`; the global layer
+    carries no position.  And the formula's fusions are gone: no fusion
+    of the step under `attention` that is neither a projection's nor a
+    kernel's has a q- or k-shaped result (the parent's step had nine of
+    each: the rolls' slices padded back under a select)."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import owners
+
+    cell, _, step = smallthinker_step
+    text = step.as_text()
+    b = cell["global_batch"]
+    calls = collections.Counter(re.findall(
+        r"%dwt_rope[.\d]* = bf16\[(\d+),16384,(\d+)\]", text))
+    assert calls == {(str(b), "3584"): 9, (str(b), "512"): 9}
+    own = owners(text)
+    scopes = collections.Counter(
+        re.sub(r"^\w+/", "", own[name]["scope"])
+        for name in re.findall(r"%(dwt_rope[.\d]*) = ", text))
+    assert scopes == {"SmallThinker/layers/attention/dwt_rope": 18}
+    rotated = [
+        name for name, shape, op in re.findall(
+            r"^\s*%([\w.\-]+) = (\w+\[[\d,]+\])\S* ([\w\-]+)\(", text,
+            re.M)
+        if op == "fusion" and re.search(rf"\[{b},16384,(3584|512)\]", shape)
+        and name in own and "attention" in own[name]["scope"]
+        and not re.search(r"[qkvo]_proj|dwt_", own[name]["scope"])]
+    assert rotated == []
 
 
 @pytest.mark.parametrize("window,route,names", [

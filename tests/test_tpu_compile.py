@@ -27,6 +27,7 @@ from dlrover_wuqiong_tpu.analysis.hlo_budget import iter_collectives
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
 from dlrover_wuqiong_tpu.ops import quantization as qz
+from dlrover_wuqiong_tpu.ops import rope
 from dlrover_wuqiong_tpu.ops import ssd
 
 
@@ -300,6 +301,7 @@ def _one_chip_step(topo, name, model_file):
                    lambda: True)
         mp.setattr(ssd, "_on_tpu", lambda: True)
         mp.setattr(gm, "_on_tpu", lambda: True)
+        mp.setattr(rope, "_on_tpu", lambda: True)
         res = auto_accelerate(
             model, strategy=[("fsdp", {})], devices=topo.devices[:1],
             optimizer=optax.chain(optax.clip_by_global_norm(1.0),
@@ -381,18 +383,24 @@ def test_olmoe_step_runs_the_kernels_at_d128_t4096(olmoe_step):
 
 def test_olmoe_step_moves_little_around_its_attention_kernels(olmoe_step):
     """`hlo_scopes.relayouts` under `attention` outside the projections
-    and QK-norm.  What stays: RoPE's half-swap on the (b, t, h*d) rows
-    (the two lane rolls of q and of k, four slicing fusions and two
-    copies forward, six slices backward), delta's turn to
-    (b*h, 1, t) (a copy and a reshape of a (b, t, h) array) and, nameless
-    between two bitcasts of the backward pass and so its reader's since
-    PR 35, a copy of a (b*t/8, 8, h, d) float32 array."""
+    and QK-norm.  What stays: delta's turn to (b*h, 1, t) (a copy and a
+    reshape of a (b, t, h) array) and, nameless between two bitcasts of
+    the backward pass and so its reader's since PR 35, a copy of a
+    (b*t/8, 8, h, d) float32 array.  RoPE's half-swap on the (b, t, h*d)
+    rows moved twelve more until PR 44 (the two lane rolls of q and of
+    k: four slicing fusions and two copies forward, six slices
+    backward); it is `ops/rope.py`'s kernel now, one call for q and one
+    for k, forward and backward."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import relayouts
 
-    moved = relayouts(olmoe_step[2].as_text(), "attention", outside=(
+    text = olmoe_step[2].as_text()
+    moved = relayouts(text, "attention", outside=(
         "q_proj", "k_proj", "v_proj", "o_proj", "qk_norm"))
-    assert sorted(moved.values()) == \
-        ["copy"] * 2 + ["fusion"] * 6 + ["reshape"] + ["slice"] * 6, moved
+    assert sorted(moved.values()) == ["copy"] * 2 + ["reshape"], moved
+    b = olmoe_step[0]["global_batch"]
+    assert collections.Counter(re.findall(
+        r"%dwt_rope[.\d]* = (\w+\[[\d,]+\])", text)) == {
+            f"bf16[{b},4096,2048]": 4}
 
 
 def test_olmoe_step_keeps_its_scopes_and_names_the_grouped_matmuls(

@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
-model|opt|step|lib|dispatch|rpc|gmm|rows_map] ...  (no args = step/attn/head/model/opt).  One JSON line
+model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -27,6 +27,10 @@ own around either, so a host clock around the call measures those.
 products cost, as the compiler's fusions over the whole T*k-row buffer
 and as `dwt_rows_map_*` over the tiles that hold a held row, at both
 share cells' shapes and held shares.
+`rope` reads the same way one rotation of the projections' rows, forward
+and backward, at the three rotating cells' shapes: `models/llama.
+apply_rope`'s formula as the compiler fuses it against `dwt_rope`
+(`ops/rope.py`) at three row tiles.
 """
 
 from __future__ import annotations
@@ -742,6 +746,50 @@ def probe_rows_map():
                        "device_ops_ms": _device_ops_ms(jax.jit(fn), *args)})
 
 
+def probe_rope():
+    """One rotation, forward and backward, at SmallThinker's q and k
+    (2 x 16,384 x 3,584 and x 512, heads of 128), latent attention's q
+    part (2 x 16,384 x 16 heads of 64) and OLMoE's q (5 x 4,096 x
+    2,048): the formula of `models/llama.apply_rope` jitted alone
+    against `dwt_rope` (PERF.md section 6, PR 44)."""
+    from unittest import mock
+
+    from dlrover_wuqiong_tpu.models.llama import apply_rope, rope_freqs
+    from dlrover_wuqiong_tpu.ops import rope
+
+    def grad_of(fn):
+        return lambda x, d_out: jax.vjp(fn, x)[1](d_out)[0]
+
+    for b, t, lanes, d, tiles in ((2, 16384, 3584, 128, (256, 512, 1024)),
+                                  (2, 16384, 512, 128, (512,)),
+                                  (2, 16384, 1024, 64, (512,)),
+                                  (5, 4096, 2048, 128, (512,))):
+        cos, sin = rope_freqs(d, t, 10000.0)
+        keys = jax.random.split(jax.random.PRNGKey(lanes), 2)
+        x, d_out = (jax.random.normal(k, (b, t, lanes), jnp.bfloat16)
+                    for k in keys)
+
+        def plain(x):
+            return apply_rope(x, cos, sin)
+
+        def kernel(tile):
+            return lambda x: rope._rope_kernels(x, cos, sin, tile=tile)
+
+        cases = [("plain", plain, None)] + [
+            ("dwt_rope", kernel(tile), tile) for tile in tiles]
+        for name, fn, tile in cases:
+            # the formula is what a call off the TPU traces
+            with mock.patch.object(rope, "_on_tpu",
+                                   lambda: name != "plain"):
+                for what, f, args in ((name, jax.jit(fn), (x,)),
+                                      (name + "_bwd", jax.jit(grad_of(fn)),
+                                       (x, d_out))):
+                    _emit_raw({"probe": "rope", "what": what,
+                               "shape": [b, t, lanes], "head": d,
+                               "tile": tile,
+                               "device_ops_ms": _device_ops_ms(f, *args)})
+
+
 ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "attn_sweep": probe_attn_sweep,
        "attn_direct": probe_attn_direct, "lib": probe_lib,
@@ -749,7 +797,8 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "splash": probe_splash, "dots": probe_dots,
        "head": probe_head, "model": probe_model, "opt": probe_opt,
        "step": probe_step, "dispatch": probe_dispatch,
-       "rpc": probe_rpc, "gmm": probe_gmm, "rows_map": probe_rows_map}
+       "rpc": probe_rpc, "gmm": probe_gmm, "rows_map": probe_rows_map,
+       "rope": probe_rope}
 
 
 def main(argv=None) -> int:
