@@ -64,7 +64,22 @@ held rows, forward, recomputed and for the combine's cotangent).  What
 still walks a share's whole buffer is the two gathers a layer BY
 ASSIGNMENT (`_by_assignment`: `combine`, and `dispatch`'s backward —
 their held entries lie scattered over the (k, T) index list, so no
-prefix cuts it) and what COUNTS rows: the two sorts, the `bincount`s.
+prefix cuts it).
+
+The bookkeeping around the rows — on every route, a whole layer's too —
+holds no scatter and no gather of single numbers: on the TPU either
+costs the length of its index list whatever an entry weighs (a count of
+196,608 assignments into sixteen numbers as long as three sorts of
+them).  The assignments to ALL the experts are counted once a layer
+call by a compare against `arange(E)` and a sum (`expert_counts`: the
+auxiliary term, the bias's rule and the groups' sizes read that one
+count); the gates are the scores under a select on the chosen experts
+(`route_top_k`: its transpose is a select too); they reach expert order
+as a third operand of the sort that makes `order` (`_expert_order`),
+and the backward pass's <row, cotangent> numbers return to (T, k) by a
+sort ON `order` (`_numbers_by_assignment`), which the compiler folds
+into the sort that inverts it where both stand in one pass.  What is
+left per T*k entry is those two sorts a pass.
 A share also sows `moe_gmm_tiles`, `moe_map_tiles` (row tiles its
 grouped products / its elementwise passes walk, row tiles of the
 buffer) and `moe_gather_rows` (rows `dispatch` fetches, rows of the
@@ -231,12 +246,19 @@ def route_top_k(probs: jax.Array, top_k: int, norm_topk_prob: bool = True,
     `probs` at the chosen experts, WITHOUT the bias.  Normalised gates
     are divided by max(sum, 1e-9) (`floor`), or by sum + 1e-20 (the
     sigmoid router's published form)."""
-    if bias is None:
-        gates, experts = jax.lax.top_k(probs, top_k)
-    else:
-        _, experts = jax.lax.top_k(
-            probs + jax.lax.stop_gradient(bias), top_k)
-        gates = jnp.take_along_axis(probs, experts, axis=-1)
+    scores = probs if bias is None else probs + jax.lax.stop_gradient(bias)
+    _, experts = jax.lax.top_k(scores, top_k)
+    # `probs` at the chosen experts by a select and a sum over E: a
+    # token's k experts are distinct, so it is the number `top_k` or
+    # `take_along_axis` hands back, exactly, and its transpose is a dense
+    # select where theirs is a scatter-add of T*k numbers into (T, E)
+    chosen = experts[..., None] == jnp.arange(probs.shape[-1])
+    gates = jnp.where(chosen, probs[..., None, :], 0).sum(-1)
+    # the barrier keeps that sum (exact: one term is not zero) a reduce
+    # of its own: merged with the sum over k below, as the compiler does
+    # under `jit`, the k scores add up in another order and the total's
+    # last bit is not `top_k`'s gates' any more (PERF.md section 6, PR 45)
+    gates = jax.lax.optimization_barrier(gates)
     if norm_topk_prob:
         total = gates.sum(-1, keepdims=True)
         gates = gates / (jnp.maximum(total, 1e-9) if floor
@@ -244,6 +266,42 @@ def route_top_k(probs: jax.Array, top_k: int, norm_topk_prob: bool = True,
     if scaling != 1.0:
         gates = gates * scaling
     return gates, experts
+
+
+def expert_counts(experts: jax.Array, num_experts: int) -> jax.Array:
+    """(num_experts,) int32: how many of the (T, k) assignments name each
+    expert.  A compare against `arange(num_experts)` and a sum, which the
+    compiler fuses (nothing of size T*k*E is written): `jnp.bincount` is
+    an integer scatter-add, and on the TPU that costs the length of its
+    index list whatever it adds up (PERF.md section 6, PR 45)."""
+    hit = experts[..., None] == jnp.arange(num_experts, dtype=experts.dtype)
+    return hit.sum(tuple(range(experts.ndim)), dtype=jnp.int32)
+
+
+def _expert_order(flat_expert: jax.Array, gates: jax.Array
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """(order, the gates in expert order) of the T*k assignments: ONE
+    stable sort on the expert's number that carries each assignment's
+    own number and its gate along — `argsort(flat_expert)` and
+    `gates.reshape(-1)[order]` without the gather of T*k numbers.  The
+    gates come out as constants: only a backward pass reads them in that
+    order (`_combine_bwd`)."""
+    assignment = jnp.arange(flat_expert.shape[0], dtype=jnp.int32)
+    _, order, flat_gates = jax.lax.sort(
+        (flat_expert, assignment,
+         jax.lax.stop_gradient(gates.reshape(-1))), num_keys=1)
+    return order, flat_gates
+
+
+def _numbers_by_assignment(numbers: jax.Array, order: jax.Array,
+                           held_rows: jax.Array, k: int) -> jax.Array:
+    """numbers (T*k,) in expert order -> (T, k), each assignment's own
+    and zero where its row lies behind the held rows: `order` is a
+    permutation, so a sort ON it puts row r's number at assignment
+    `order[r]` — what `numbers[inv]` gathers one index at a time."""
+    held = jnp.arange(order.shape[0]) < held_rows
+    _, own = jax.lax.sort((order, jnp.where(held, numbers, 0)), num_keys=1)
+    return own.reshape(-1, k)
 
 
 def _by_assignment(rows: jax.Array, inv: jax.Array,
@@ -333,31 +391,35 @@ def _dispatch_fwd(tokens, order, inv, held_rows, route):
 
 
 def _dispatch_bwd(route, res, d_rows):
-    return combine(d_rows, None, *res), None, None, None
+    return combine(d_rows, None, None, *res), None, None, None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def combine(rows: jax.Array, gates: Optional[jax.Array], order: jax.Array,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def combine(rows: jax.Array, gates: Optional[jax.Array],
+            flat_gates: Optional[jax.Array], order: jax.Array,
             inv: jax.Array, held_rows: jax.Array,
             route: str = "plain") -> jax.Array:
     """rows (T*k, d) in expert order, gates (T, k) or None (all ones) ->
     (T, d): the sum of each token's k rows, weighted in the same pass,
     accumulated in float32 (`dispatch` says what `order`, `inv` and
-    `held_rows` are).  `route` is the layer's (`grouped_experts`): on
-    "kernel" the backward pass's two passes over the row buffer are one
-    `rows_map` over the held tiles."""
+    `held_rows` are).  `flat_gates` (T*k,) is the gates once more, in
+    expert order (`_expert_order`; None with `gates`): the backward pass
+    weighs the rows' cotangent with them where the rows lie, and they
+    get no gradient of their own.  `route` is the layer's
+    (`grouped_experts`): on "kernel" the backward pass's two passes over
+    the row buffer are one `rows_map` over the held tiles."""
     picked = _by_assignment(rows, inv, held_rows).astype(jnp.float32)
     if gates is not None:
         picked = picked * gates.T[..., None]
     return picked.sum(0).astype(rows.dtype)
 
 
-def _combine_fwd(rows, gates, order, inv, held_rows, route):
-    return (combine(rows, gates, order, inv, held_rows, route),
-            (rows, gates, order, inv, held_rows))
+def _combine_fwd(rows, gates, flat_gates, order, inv, held_rows, route):
+    return (combine(rows, gates, flat_gates, order, inv, held_rows, route),
+            (rows, gates, flat_gates, order, inv, held_rows))
 
 
 def _weigh(rows, d_rows, gates):
@@ -368,26 +430,24 @@ def _weigh(rows, d_rows, gates):
 
 
 def _combine_bwd(route, res, d_out):
-    rows, gates, order, inv, held_rows = res
+    rows, gates, flat_gates, order, inv, held_rows = res
     d_rows = dispatch(d_out, order, inv, held_rows, route)
     if gates is None:
-        return d_rows, None, None, None, None
+        return d_rows, None, None, None, None, None
     if route == "kernel":
         # both passes in one kernel over the tiles that hold a held row,
         # the weighted cotangent written over the gathered one
-        flat_gates = gates.reshape(-1)[order]
         d_rows, dots = rows_map(_weigh, held_rows, rows, d_rows,
                                 flat_gates[:, None], alias=(1, 0))
-        d_gates = jnp.where(inv < held_rows, dots[:, 0][inv], 0.0).T
-        return d_rows, d_gates.astype(gates.dtype), None, None, None
-    # <row, its token's cotangent> in expert order, where both lie (a
-    # pass over the buffer, no second gather of rows), then back to
-    # (T, k) through `inv` as T*k numbers
-    dots = (rows.astype(jnp.float32) * d_rows).sum(-1)
-    d_gates = jnp.where(inv < held_rows, dots[inv], 0.0).T
-    flat_gates = gates.reshape(-1)[order]
-    d_rows = (d_rows * flat_gates[:, None]).astype(rows.dtype)
-    return d_rows, d_gates.astype(gates.dtype), None, None, None
+        dots = dots[:, 0]
+    else:
+        # <row, its token's cotangent> in expert order, where both lie
+        # (a pass over the buffer, no second gather of rows)
+        dots = (rows.astype(jnp.float32) * d_rows).sum(-1)
+        d_rows = (d_rows * flat_gates[:, None]).astype(rows.dtype)
+    # back to (T, k) as T*k numbers
+    d_gates = _numbers_by_assignment(dots, order, held_rows, inv.shape[0])
+    return d_rows, d_gates.astype(gates.dtype), None, None, None, None
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
@@ -425,7 +485,8 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
                     w_gate: Optional[jax.Array], w_in: jax.Array,
                     w_down: jax.Array, first_expert: int = 0,
                     num_experts: Optional[int] = None, mesh=None,
-                    gate_act=jax.nn.silu) -> Tuple[jax.Array, jax.Array]:
+                    gate_act=jax.nn.silu, load: Optional[jax.Array] = None
+                    ) -> Tuple[jax.Array, jax.Array]:
     """The dropless expert pass for a routing already made: returns
     (out (T, d), group_sizes (held,)).  Scopes: `dispatch` (sort, its
     inverse, gather), `experts` (grouped matmuls, gating product),
@@ -433,6 +494,9 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
     their backward passes under the same two (`dispatch`, `combine`
     above).  `w_gate=None`: relu^2 experts; else `gate_act` of the gate
     matrix's product times the other's (silu: SwiGLU, relu: ReGLU).
+    `load`: `expert_counts(experts, num_experts)` where the caller has
+    counted already (`MoEMLP`: once a layer call), else counted here;
+    the groups' sizes are the held experts' part of it.
 
     The weights hold `held = w_in.shape[0]` experts, numbers
     `first_expert ..` of the `num_experts` that `experts` (T, k) names.
@@ -466,11 +530,13 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
             flat_expert = flat_expert - first_expert
             flat_expert = jnp.where(
                 (flat_expert >= 0) & (flat_expert < E), flat_expert, E)
-        order = jnp.argsort(flat_expert)               # stable per expert
+        order, flat_gates = _expert_order(flat_expert, gates)
         # the inverse of a permutation is its argsort: T*k integers (on
         # the chip a third of what scattering them costs, PERF.md PR 32)
         inv = jnp.argsort(order).reshape(T, top_k).T
-        group_sizes = jnp.bincount(flat_expert, length=E)
+        if load is None:
+            load = expert_counts(experts, num_experts or E)
+        group_sizes = load[first_expert:first_expert + E]
         held_rows = group_sizes.sum()
         held_row = jnp.arange(T * top_k) < held_rows
         xs = dispatch(tokens, order, inv, held_rows, route)
@@ -496,7 +562,7 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
         # (T*k, d); no mask here: `combine` reads the held rows alone
         ys = grouped_matmul(h, w_down, group_sizes, route)
     with jax.named_scope("combine"):
-        out = combine(ys, gates, order, inv, held_rows, route)
+        out = combine(ys, gates, flat_gates, order, inv, held_rows, route)
     return out.astype(tokens.dtype), group_sizes
 
 
@@ -521,7 +587,12 @@ def grouped_moe(tokens: jax.Array, probs: jax.Array, w_gate: jax.Array,
     gather in the backward pass) 12 ms each, as long as two of the
     grouped matmuls; as the gathers through the sort's inverse that
     `dispatch` and `combine` are now, 5.6 ms each (a row gather from
-    HBM runs at 34 ns a 4 KB row) and 1.0 ms for the sum over k.
+    HBM runs at 34 ns a 4 KB row) and 1.0 ms for the sum over k.  An
+    index costs a few nanoseconds whatever it moves, so the single
+    numbers around the rows are not indexed at all (PR 45): the group
+    sizes are a compare-and-sum (`expert_counts`; as a `bincount` 1.4
+    ms), the gates in expert order and their gradient's dots ride the
+    sorts (as gathers of T*k numbers 1.2 and 1.4 ms).
 
     tokens (T, d); probs (T, E) router softmax; w_gate/w_in (E, d, f);
     w_down (E, f, d).  Returns (T, d).
@@ -530,13 +601,13 @@ def grouped_moe(tokens: jax.Array, probs: jax.Array, w_gate: jax.Array,
     return grouped_experts(tokens, gates, experts, w_gate, w_in, w_down)[0]
 
 
-def _aux_loss(cfg: MoEConfig, logits, probs, experts):
-    """The layer's auxiliary loss terms, weighted, as one scalar."""
+def _aux_loss(cfg: MoEConfig, logits, probs, load):
+    """The layer's auxiliary loss terms, weighted, as one scalar; `load`
+    is `expert_counts` of the step's routing (the "topk" term reads it)."""
     E = cfg.num_experts
     if cfg.aux_loss == "topk":
         # f_i: share of tokens with expert i among their k (sums to k)
-        f = jnp.bincount(experts.reshape(-1), length=E).astype(
-            jnp.float32) / probs.shape[0]
+        f = load.astype(jnp.float32) / probs.shape[0]
         aux = (f * probs.mean(0)).sum() * E
     elif cfg.aux_loss == "switch":
         top1 = jax.nn.one_hot(jnp.argmax(probs, -1), E, dtype=jnp.float32)
@@ -627,21 +698,23 @@ class MoEMLP(nn.Module):
             else:
                 raise ValueError(f"unknown MoEConfig.score_func "
                                  f"{cfg.score_func!r}")
-        gates = experts = None
+        gates = experts = load = None
         if cfg.impl == "grouped" or cfg.aux_loss == "topk":
             with jax.named_scope("dispatch"):
                 gates, experts = route_top_k(
                     probs, cfg.top_k, cfg.norm_topk_prob, bias=bias,
                     floor=cfg.score_func == "softmax",
                     scaling=cfg.routed_scaling)
+                # the step's assignments to each of ALL the experts,
+                # counted once: the auxiliary term, the bias's rule and
+                # the groups' sizes read it
+                load = expert_counts(experts, cfg.num_experts)
         if cfg.aux_loss != "none":
             with jax.named_scope("aux"):
                 self.sow("intermediates", "moe_aux_loss",
-                         _aux_loss(cfg, logits, probs, experts))
+                         _aux_loss(cfg, logits, probs, load))
         if cfg.bias_update_rate:
             with jax.named_scope("dispatch"):
-                load = jnp.bincount(experts.reshape(-1),
-                                    length=cfg.num_experts)
                 even = n_tok * cfg.top_k / cfg.num_experts
                 self.sow("intermediates", "moe_selection_bias_step",
                          cfg.bias_update_rate * jnp.clip(
@@ -652,7 +725,8 @@ class MoEMLP(nn.Module):
                 tokens, gates, experts, w_gate, w_in, w_out,
                 first_expert=cfg.first_expert, num_experts=cfg.num_experts,
                 mesh=cfg.mesh,
-                gate_act=_GATE_ACTS.get(cfg.expert_act, jax.nn.silu))
+                gate_act=_GATE_ACTS.get(cfg.expert_act, jax.nn.silu),
+                load=load)
         else:
             combine, dispatch = top_k_gating(logits, cfg.top_k, capacity)
             # dispatch: (T, E, C) x (T, d) -> (E, C, d)

@@ -18,6 +18,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
     _every_device_op_has_an_owner,
     _grouped_kernel_calls,
     _held_row_loops,
+    _index_ops_of_numbers,
     _no_fusion_falls_to_the_root,
     _no_persistent_cache,
     _one_chip_step,
@@ -183,6 +184,22 @@ def test_kimi_vl_step_walks_its_row_buffer_in_gathers_alone(kimi_vl_step):
     assert _row_buffer_walkers(text, rows) == []
     assert _held_row_loops(text, rows, 2048, layers=4) == {
         "bf16[8192,2048]": 12, f"bf16[6,{rows // 6},2048]": 8}
+
+
+def test_kimi_vl_step_indexes_no_single_numbers(kimi_vl_step):
+    """The expert layers' bookkeeping holds no scatter and no gather of
+    single numbers (`_index_ops_of_numbers`, PR 45): the count of all 64
+    experts the bias's rule reads and the groups' sizes are ONE
+    compare-and-sum a layer call, the scores at the experts the biased
+    choice names are a select (its transpose too: the parent's was a
+    nameless scatter into (T, 64) two fusions down), the gates reach
+    expert order and the dots their assignments as further operands of
+    the sorts.  The row gathers are pinned above."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import instructions_of
+
+    text = kimi_vl_step[2].as_text()
+    assert not instructions_of(text, "scatter", "moe")
+    assert _index_ops_of_numbers(text, 2048) == []
 
 
 @pytest.mark.parametrize("seq,route,names", [

@@ -116,7 +116,8 @@ def test_combine_is_segment_sum_of_the_weighted_rows(holding, routing):
                                    token_idx, num_segments=T)
 
     got, vjp = jax.vjp(
-        lambda ys, g: combine(ys, g, order, inv, held_rows), rows, gates)
+        lambda ys, g: combine(ys, g, g.reshape(-1)[order], order, inv,
+                              held_rows), rows, gates)
     want, plain_vjp = jax.vjp(plain, rows, gates)
     _close(got, want)
     (d_rows, d_gates), (want_rows, want_gates) = vjp(d_out), plain_vjp(d_out)
@@ -135,18 +136,18 @@ def test_each_ones_backward_pass_is_the_other(holding, routing):
     flat_gates = gates.reshape(-1)[order][:, None]
 
     _, vjp = jax.vjp(lambda x: dispatch(x, order, inv, held_rows), tokens)
-    _close(vjp(rows)[0], combine(rows, None, order, inv, held_rows))
-    _, vjp = jax.vjp(lambda ys: combine(ys, None, order, inv, held_rows),
+    _close(vjp(rows)[0], combine(rows, None, None, order, inv, held_rows))
+    _, vjp = jax.vjp(lambda ys: combine(ys, None, None, order, inv, held_rows),
                      rows)
     _close(vjp(tokens)[0], dispatch(tokens, order, inv, held_rows))
-    _, vjp = jax.vjp(lambda ys: combine(ys, gates, order, inv, held_rows),
-                     rows)
+    _, vjp = jax.vjp(lambda ys: combine(ys, gates, flat_gates[:, 0], order,
+                                        inv, held_rows), rows)
     _close(vjp(tokens)[0],
            dispatch(tokens, order, inv, held_rows) * flat_gates)
     # transposes: <dispatch(x), r> = <x, combine(r)> over the held rows
     held = (jnp.arange(T * K) < held_rows)[:, None]
     lhs = jnp.vdot(dispatch(tokens, order, inv, held_rows) * held, rows)
-    rhs = jnp.vdot(tokens, combine(rows, None, order, inv, held_rows))
+    rhs = jnp.vdot(tokens, combine(rows, None, None, order, inv, held_rows))
     np.testing.assert_allclose(float(lhs), float(rhs), rtol=1e-5, atol=1e-5)
 
 
@@ -164,7 +165,8 @@ def test_what_lies_behind_the_held_rows_is_never_read(holding, routing):
 
     def run(ys):
         out, vjp = jax.vjp(
-            lambda g: combine(ys, g, order, inv, held_rows), gates)
+            lambda g: combine(ys, g, g.reshape(-1)[order], order, inv,
+                              held_rows), gates)
         return out, vjp(d_out)[0]
 
     for got, want in zip(run(dirty), run(clean)):
@@ -434,3 +436,284 @@ def test_one_product_off_the_kernels_takes_the_whole_layer_off_them(
                                          argnums=(0, 1, 2, 3, 4)))(*args))
     assert not calls and "pallas_call" not in traced
     assert traced == plain
+
+
+# ------- the bookkeeping (PR 45), against the `jax.numpy` lines it replaces
+
+def _assignments(kind, k, num_experts, first=0, held=None, tokens=T):
+    """experts (tokens, k), a token's k all different: drawn from all of
+    them ("random"), from all but experts `first .. first + held - 1`
+    ("absent": every assignment misses the share that holds those), or
+    from all but expert `first` ("one_empty")."""
+    held = held or num_experts
+    names = np.arange(num_experts)
+    if kind == "absent":
+        names = np.setdiff1d(names, np.arange(first, first + held))
+    elif kind == "one_empty":
+        names = np.setdiff1d(names, [first])
+    elif kind != "random":
+        raise ValueError(kind)
+    rng = np.random.default_rng(k * 1000 + num_experts)
+    return jnp.asarray(np.stack([rng.choice(names, k, replace=False)
+                                 for _ in range(tokens)]), jnp.int32)
+
+
+def _held_number(experts, first, held):
+    """The (T*k,) assignments as `grouped_experts` sorts them: a held
+    expert's number among the held, `held` for an absent one."""
+    flat = experts.reshape(-1) - first
+    return jnp.where((flat >= 0) & (flat < held), flat, held)
+
+
+def _parent_order(flat_expert, gates):
+    order = jnp.argsort(flat_expert)
+    return order, jax.lax.stop_gradient(gates).reshape(-1)[order]
+
+
+def _parent_numbers(numbers, order, held_rows, k):
+    inv = jnp.argsort(order).reshape(-1, k).T
+    return jnp.where(inv < held_rows, numbers[inv], 0.0).T
+
+
+def _parent_counts(experts, num_experts):
+    return jnp.bincount(experts.reshape(-1), length=num_experts)
+
+
+def _parent_route_top_k(probs, top_k, norm_topk_prob=True, bias=None,
+                        floor=True, scaling=1.0):
+    if bias is None:
+        gates, experts = jax.lax.top_k(probs, top_k)
+    else:
+        _, experts = jax.lax.top_k(
+            probs + jax.lax.stop_gradient(bias), top_k)
+        gates = jnp.take_along_axis(probs, experts, axis=-1)
+    if norm_topk_prob:
+        total = gates.sum(-1, keepdims=True)
+        gates = gates / (jnp.maximum(total, 1e-9) if floor
+                         else total + 1e-20)
+    if scaling != 1.0:
+        gates = gates * scaling
+    return gates, experts
+
+
+def _the_parents_lines(monkeypatch):
+    """`models/moe.py` with the bookkeeping as the `jax.numpy` lines it
+    was: `bincount`, `argsort` and a gather of the gates, a gather of
+    the dots through `inv`, `take_along_axis`."""
+    monkeypatch.setattr(moe, "expert_counts", _parent_counts)
+    monkeypatch.setattr(moe, "_expert_order", _parent_order)
+    monkeypatch.setattr(moe, "_numbers_by_assignment", _parent_numbers)
+    monkeypatch.setattr(moe, "route_top_k", _parent_route_top_k)
+
+
+def _same_bits(got, want):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("kind", ["random", "absent", "one_empty"])
+@pytest.mark.parametrize("k", [1, 6, 8])
+@pytest.mark.parametrize("num_experts", [16, 64, 128])
+def test_the_counts_are_bincounts(num_experts, k, kind):
+    """`expert_counts` is `jnp.bincount` over all the experts, and the
+    groups' sizes of a share are its held part — also where no
+    assignment falls on a held expert, and where an expert has no row."""
+    first, held = num_experts // 2, num_experts // 4
+    experts = _assignments(kind, k, num_experts, first, held)
+    got = moe.expert_counts(experts, num_experts)
+    _same_bits(got, _parent_counts(experts, num_experts))
+    assert int(got.sum()) == T * k
+    flat = _held_number(experts, first, held)
+    _same_bits(got[first:first + held], jnp.bincount(flat, length=held))
+    if kind == "absent":
+        assert not int(got[first:first + held].sum())
+    if kind == "one_empty":
+        assert not int(got[first])
+    # and of a batch of sequences, as `MoEMLP` never hands it but may
+    _same_bits(moe.expert_counts(experts.reshape(4, T // 4, k),
+                                 num_experts), got)
+
+
+# (experts held, first of them, experts the router names), assignments
+SORTED = [pytest.param(h, kind, id=f"{h[0]}_of_{h[2]}_at_{h[1]}-{kind}")
+          for h in [(16, 0, 16), (8, 0, 128), (8, 40, 128)]
+          for kind in ("random", "absent", "one_empty")
+          if not (kind == "absent" and h[0] == h[2])]
+
+
+@pytest.mark.parametrize("k", [1, 6, 8])
+@pytest.mark.parametrize("holding,kind", SORTED)
+def test_one_sort_orders_the_assignments_and_their_gates(holding, kind, k):
+    """`_expert_order` is `argsort` (stable) and the gather of the gates
+    through it; the gates in expert order carry no gradient."""
+    held, first, num_experts = holding
+    experts = _assignments(kind, k, num_experts, first, held)
+    flat = _held_number(experts, first, held)
+    gates, = _draw((T, k), seed=8)
+    _same_bits(moe._expert_order(flat, gates), _parent_order(flat, gates))
+    d_gates = jax.grad(lambda g: moe._expert_order(flat, g)[1].sum())(gates)
+    assert not np.asarray(d_gates).any()
+
+
+@pytest.mark.parametrize("held", [0, 1, 100, T * K])
+@pytest.mark.parametrize("k", [1, 6, 8])
+def test_a_sort_on_the_order_takes_numbers_back_by_assignment(k, held):
+    """`_numbers_by_assignment` is the gather through `inv` under its
+    mask: what lies behind the held rows (NaN here) reaches nothing."""
+    held = min(held, T * k)
+    order = jax.random.permutation(jax.random.PRNGKey(k + held), T * k)
+    numbers, = _draw((T * k,), seed=9)
+    numbers = jnp.where(jnp.arange(T * k) < held, numbers, jnp.nan)
+    held_rows = jnp.asarray(held, jnp.int32)
+    got = moe._numbers_by_assignment(numbers, order, held_rows, k)
+    _same_bits(got, _parent_numbers(numbers, order, held_rows, k))
+    assert np.isfinite(np.asarray(got)).all()
+    assert int((np.asarray(got) != 0).sum()) == held
+
+
+@pytest.mark.parametrize("norm,floor,scaling", [
+    (True, True, 1.0), (True, False, 2.5), (False, True, 1.0)])
+@pytest.mark.parametrize("k", [1, 6, 8])
+@pytest.mark.parametrize("biased", [False, True], ids=["", "biased"])
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+def test_the_gates_are_a_select_of_the_scores(jitted, biased, k, norm, floor,
+                                              scaling):
+    """`route_top_k` reads the scores at the chosen experts by a select
+    and a sum: the gates `top_k` hands back — under a selection bias
+    `take_along_axis`'s — the same experts, and the same gradient to the
+    scores, bit for bit; the bias gets none.  Under `jit` too: without
+    its barrier the compiler merges the select's sum over E with the
+    normaliser's over k, the k scores add up in another order and a
+    third of the gates lose their last bit."""
+    logits, bias, d_gates = _draw((T, 64), (64,), (T, k), seed=10)
+    probs = jax.nn.sigmoid(logits)
+
+    def run(fn):
+        def both(probs, bias, d_gates):
+            def routed(p, b):
+                return fn(p, k, norm, b if biased else None, floor, scaling)
+
+            gates, vjp, experts = jax.vjp(routed, probs, bias, has_aux=True)
+            return gates, experts, vjp(d_gates)
+
+        return (jax.jit(both) if jitted else both)(probs, bias, d_gates)
+
+    got, want = run(moe.route_top_k), run(_parent_route_top_k)
+    _same_bits(got, want)
+    assert not np.asarray(got[2][1]).any()
+    # the choice follows the biased scores, the gates do not
+    assert biased == (np.asarray(got[1]) != np.asarray(
+        jax.lax.top_k(probs, k)[1])).any()
+
+
+ROUTED = [pytest.param(route, *case.values, id=f"{route}-{case.id}")
+          for route in ("plain", "kernel") for case in SORTED
+          if not (route == "kernel" and case.values[0][0] == 16)]
+
+
+@pytest.mark.parametrize("k", [1, 6, 8])
+@pytest.mark.parametrize("route,holding,kind", ROUTED)
+def test_the_expert_pass_is_the_parents_bit_for_bit(monkeypatch, route,
+                                                    holding, kind, k):
+    """`grouped_experts` with the bookkeeping as dense vector ops against
+    the same pass with the `jax.numpy` lines it had (`bincount`,
+    `argsort` and two gathers of T*k numbers): the output, the groups'
+    sizes and every gradient to the bit, on the route every CPU run
+    takes and on a share's kernels (interpret mode) — also where no
+    assignment reaches the share, and where an expert has no row."""
+    held, first, num_experts = holding
+    tokens = 64  # T*k a multiple of the kernels' row tile here, k = 1 too
+    experts = _assignments(kind, k, num_experts, first, held, tokens)
+    x, gates, w_gate, w_in, w_down = _draw(
+        (tokens, D), (tokens, k), (held, D, F), (held, D, F), (held, F, D),
+        seed=11)
+    args = (x, gates, 0.2 * w_gate, 0.2 * w_in, 0.2 * w_down)
+
+    def run(x, gates, w_gate, w_in, w_down):
+        out, sizes = grouped_experts(x, gates, experts, w_gate, w_in, w_down,
+                                     first_expert=first,
+                                     num_experts=num_experts)
+        return jnp.sum(jnp.sin(out)), (out, sizes)
+
+    both = jax.value_and_grad(run, argnums=(0, 1, 2, 3, 4), has_aux=True)
+    if route == "kernel":
+        _on_the_kernel_route(monkeypatch, poison=False)
+    assert moe.layer_route(tokens * k, *args[2:], num_experts) == route
+    got = both(*args)
+    _the_parents_lines(monkeypatch)
+    want = both(*args)
+    _same_bits(got, want)
+    flat = _held_number(experts, first, held)
+    _same_bits(got[0][1][1], jnp.bincount(flat, length=held))
+    if kind == "absent":
+        assert not np.asarray(got[0][1][0]).any()
+
+
+# (experts held, first of them) of 16, a selection bias with its rule,
+# the auxiliary term, k, the layer's route
+LAYERS = [pytest.param(held, first, biased, aux, k, route,
+                       id=f"{held}_at_{first}-{'biased-' * biased}{aux}"
+                          f"-k{k}-{route}")
+          for held, first in ((16, 0), (4, 8)) for biased in (False, True)
+          for aux in ("topk", "none") for k in (1, 6, 8)
+          for route in ("plain", "kernel")
+          if not (route == "kernel" and held == 16)]
+
+
+@pytest.mark.parametrize("held,first,biased,aux,k,route", LAYERS)
+def test_the_layer_is_the_parents_bit_for_bit(monkeypatch, held, first,
+                                              biased, aux, k, route):
+    """`MoEMLP` counts its assignments ONCE (the auxiliary term, the
+    bias's rule and the groups' sizes read that count) and routes by a
+    select: against the layer with the parent's lines (three
+    `bincount`s, `take_along_axis`, `argsort` and the gathers of
+    numbers) the output, everything it sows and every gradient agree to
+    the bit, a whole layer and a share, on both routes."""
+    cfg = MoEConfig(
+        num_experts=16, top_k=k, impl="grouped", aux_loss=aux,
+        aux_loss_weight=0.01, dtype=jnp.float32, selection_bias=biased,
+        bias_update_rate=0.05 * biased,
+        score_func="sigmoid" if biased else "softmax",
+        routed_scaling=2.5 if biased else 1.0, experts_held=held % 16,
+        first_expert=first)
+    layer = MoEMLP(hidden=D, ffn=F, moe=cfg)
+    x, = _draw((2, 32, D), seed=12)
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+
+    def run(params, x):
+        out, upd = layer.apply({"params": params}, x,
+                               mutable=["intermediates"])
+        inter = upd["intermediates"]
+        return (jnp.sum(jnp.sin(out)) + moe.collect_moe_aux_loss(inter),
+                (out, inter))
+
+    def both(params, x):
+        # the plain route under `jit`, as a step runs it (what the
+        # compiler fuses it adds up in its own order: `route_top_k`'s
+        # barrier); the kernels in interpret mode stay eager, and so does
+        # a sigmoid router under the "topk" term, which no model has:
+        # there the CPU compiler contracts the term's gradient, load * c,
+        # and its sum with the select's into one fused multiply-add, and
+        # a few of the router's gradients keep the bit the parent's
+        # product rounds away
+        fn = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)
+        jitted = route == "plain" and not (biased and aux == "topk")
+        return (jax.jit(fn) if jitted else fn)(params, x)
+
+    if route == "kernel":
+        _on_the_kernel_route(monkeypatch, poison=False)
+    got = both(params, x)
+    inter = got[0][1][1]
+    assert ("moe_aux_loss" in inter) == (aux == "topk")
+    assert ("moe_selection_bias_step" in inter) == biased
+    assert ("moe_rows_held" in inter) == (held < 16)
+    assert moe.layer_route(64 * k, *(params[f"experts_w_{w}"] for w in (
+        "gate", "in", "down")), 16) == route
+    if held < 16:
+        assert int(inter["moe_rows_held"][0]) + int(
+            inter["moe_rows_absent"][0]) == 64 * k
+    _the_parents_lines(monkeypatch)
+    _same_bits(got, both(params, x))

@@ -432,16 +432,22 @@ def test_a_share_runs_the_one_grouped_path_over_the_held_experts():
             _expert_layer(_moe())[1], x))
     for traced in (text, whole):
         assert traced.count("ragged_dot_general[") == 2  # relu2: no gate
-        # the assignments' sort and the inverse of its permutation
-        assert traced.count("jit[name=argsort") == 2
+        # the assignments' sort (their numbers and their gates ride it)
+        # and the inverse of its permutation
+        assert len(re.findall(r"\bsort\[", traced)) == 2
+        assert len(re.findall(r":i32\[192\] \w+:i32\[192\] \w+:f32\[192\] "
+                              r"= sort\[", traced)) == 1
+        assert traced.count("name=argsort") == 1
         assert traced.count("custom_vjp_call[") == 2
         assert traced.count("name=dispatch") == 1
         assert traced.count("name=combine") == 1
-        # no row is scattered; the one scatter counts the groups' sizes
-        assert len(re.findall(r" = scatter[-\w]*\[", traced)) == 1
+        # no row is scattered, and nothing is counted by a scatter: the
+        # groups' sizes are a compare-and-sum (`expert_counts`)
+        assert not re.findall(r" = scatter[-\w]*\[", traced)
         assert traced.count("mode=GatherScatterMode.PROMISE_IN_BOUNDS") == 2
     assert "f32[2,32,24]" in text and "f32[8,32,24]" not in text
-    assert "i32[2]" in text and "i32[8]" not in text      # group_sizes
+    # group_sizes: the held experts' part of the ONE count of all 8
+    assert re.search(r":i32\[2\] = slice\[limit_indices=\(6,\)", text)
     assert "f32[8,32,24]" in whole and "i32[8]" in whole
 
 

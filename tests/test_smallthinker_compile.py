@@ -17,6 +17,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
     _every_device_op_has_an_owner,
     _grouped_kernel_calls,
     _held_row_loops,
+    _index_ops_of_numbers,
     _no_fusion_falls_to_the_root,
     _no_persistent_cache,
     _one_chip_step,
@@ -157,6 +158,21 @@ def test_smallthinker_step_walks_its_row_buffer_in_gathers_alone(
     assert _row_buffer_walkers(text, rows) == []
     assert _held_row_loops(text, rows, 2560, layers=4) == {
         "bf16[8192,2560]": 12, f"bf16[6,{rows // 6},2560]": 8}
+
+
+def test_smallthinker_step_indexes_no_single_numbers(smallthinker_step):
+    """The expert layers' bookkeeping holds no scatter and no gather of
+    single numbers (`_index_ops_of_numbers`, PR 45): the count of all 64
+    experts the auxiliary term reads and the groups' sizes are ONE
+    compare-and-sum a layer call, the gates are a select of the scores
+    (the transpose of `top_k`'s own was a nameless scatter into (T, 64)),
+    and they reach expert order, as the dots do their assignments, as
+    further operands of the sorts.  The row gathers are pinned above."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import instructions_of
+
+    text = smallthinker_step[2].as_text()
+    assert not instructions_of(text, "scatter", "moe")
+    assert _index_ops_of_numbers(text, 2560) == []
 
 
 def test_smallthinker_step_rotates_q_and_k_in_one_pass_each(

@@ -579,6 +579,40 @@ def _held_row_loops(text, rows, width, layers, chunk=8192):
     return gathers
 
 
+def _index_ops_of_numbers(text, width):
+    """The gathers and scatters a compiled step runs under `moe` that
+    move single NUMBERS — (opcode, result shape, scope) of each, a
+    device op of its own or a member of a fusion however deep (the
+    compiler nests them and drops their names there: the transpose of
+    `take_along_axis` was a nameless scatter two fusions down, which
+    `instructions_of` cannot see), every one but the row gathers, whose
+    result's last dimension is the model's `width`.  On the TPU such an
+    op costs the length of its index list whatever an entry weighs; the
+    expert layer's bookkeeping holds none (PERF.md section 6, PR 45):
+    it counts by compare-and-sum, carries the gates and the dots in its
+    sorts and reads the scores by a select."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
+        owners, parse_computations)
+
+    comps = parse_computations(text)
+    ins = {i["name"]: i for body in comps.values() for i in body}
+    found = []
+
+    def walk(i, scope):
+        shape = i["shape"].split("{")[0]
+        if i["opcode"] == "scatter" or (
+                i["opcode"] == "gather"
+                and not shape.endswith(f",{width}]")):
+            found.append((i["opcode"], shape, scope))
+        for member in comps.get(i["calls"], []):
+            walk(member, scope)
+
+    for name, entry in owners(text).items():
+        if "/moe/" in f"/{entry['scope']}/" and name in ins:
+            walk(ins[name], entry["scope"])
+    return found
+
+
 def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
         nemotron_step):
     """Every scope the per-layer metrics read is in the compiled step.
@@ -874,12 +908,15 @@ def _no_fusion_falls_to_the_root(step, root):
 ])
 def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
                                                  passes):
-    """The static counter of `models/moe.dispatch` / `combine`: in the
-    compiled step no `scatter` under `moe/dispatch` or `moe/combine` has
-    a row buffer for its operand (rank 2, the model's width: the parent
-    had two a layer, the combine's `segment_sum` and the transpose of
-    the gather into expert order; what is left counts group sizes and
-    expert loads, integers).  The rows move by four gathers a layer —
+    """The static counter of `models/moe.dispatch` / `combine` and of
+    the layer's bookkeeping: the compiled step holds no `scatter` under
+    `moe` at all (PR 32 took the two a layer that moved rows, the
+    combine's `segment_sum` and the transpose of the gather into expert
+    order; PR 45 the integer scatter-adds that counted group sizes and
+    expert loads, and the nameless one behind the gates' choice) and no
+    gather of single numbers (`_index_ops_of_numbers`: the gates into
+    expert order, the dots back by assignment, the scores at the chosen
+    experts).  The rows move by four gathers a layer —
     into expert order (T*k, d) and back by assignment (k, T, d), forward
     and backward — and the two only the backward passes emit carry the
     scope of the call they are the backward of, as every such gather in
@@ -904,9 +941,8 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
         return sorted(s.split("{")[0] for s in
                       instructions_of(text, opcode, under).values())
 
-    for scope in ("moe/dispatch", "moe/combine"):
-        assert {s.split("[")[0] for s in shapes("scatter", scope)} \
-            <= {"s32"}, scope
+    assert not instructions_of(text, "scatter", "moe")
+    assert _index_ops_of_numbers(text, width) == []
 
     if fixture == "nemotron_step":
         in_order = f"bf16[8192,{width}]"  # a turn of `dispatch`'s loop

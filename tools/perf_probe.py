@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
-model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope] ...  (no args = step/attn/head/model/opt).  One JSON line
+model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -31,6 +31,11 @@ share cells' shapes and held shares.
 and backward, at the three rotating cells' shapes: `models/llama.
 apply_rope`'s formula as the compiler fuses it against `dwt_rope`
 (`ops/rope.py`) at three row tiles.
+`moe_numbers` reads the same way the expert layer's bookkeeping at the
+share cells' shapes: each line that indexed T*k single numbers (a
+`bincount`, a gather through a sort or its inverse, `take_along_axis`
+and its transpose) against the compare-and-sum, the sort operand or the
+select `models/moe.py` runs in its place.
 """
 
 from __future__ import annotations
@@ -746,6 +751,67 @@ def probe_rows_map():
                        "device_ops_ms": _device_ops_ms(jax.jit(fn), *args)})
 
 
+def probe_moe_numbers():
+    """The expert layer's bookkeeping at the share cells' shapes
+    (32,768 tokens x 6 of 64 experts, 16 held; 16,384 x 6 of 128, 8
+    held): each `jax.numpy` line that indexed T*k single numbers,
+    jitted alone, against the dense form `models/moe.py` runs now —
+    the counts, the gates into expert order, the dots back by
+    assignment, the scores at the chosen experts and its transpose
+    (PERF.md section 6, PR 45)."""
+    from dlrover_wuqiong_tpu.models import moe
+
+    for tokens, k, num_experts, held in ((32768, 6, 64, 16),
+                                         (16384, 6, 128, 8)):
+        keys = jax.random.split(jax.random.PRNGKey(tokens), 4)
+        probs = jax.nn.sigmoid(jax.random.normal(keys[0],
+                                                 (tokens, num_experts)))
+        gates, experts = jax.lax.top_k(probs, k)
+        flat = experts.reshape(-1)
+        flat = jnp.where(flat < held, flat, held)
+        order = jnp.argsort(flat)
+        inv = jnp.argsort(order).reshape(tokens, k).T
+        dots = jax.random.normal(keys[1], (tokens * k,))
+        d_gates = jax.random.normal(keys[2], (tokens, k))
+        held_rows = (flat < held).sum()
+
+        def gathered_gates(flat, gates):
+            order = jnp.argsort(flat)
+            return order, gates.reshape(-1)[order]
+
+        def chosen(fn):
+            return lambda probs, d: jax.vjp(fn, probs)[1](d)[0]
+
+        def taken(probs):
+            return jnp.take_along_axis(probs, experts, axis=-1)
+
+        def selected(probs):
+            return moe.route_top_k(probs, k, False)[0]
+
+        for name, fn, args in (
+                ("bincount_held", lambda f: jnp.bincount(f, length=held),
+                 (flat,)),
+                ("bincount_all", lambda e: jnp.bincount(
+                    e.reshape(-1), length=num_experts), (experts,)),
+                ("expert_counts", lambda e: moe.expert_counts(
+                    e, num_experts), (experts,)),
+                ("argsort_and_gather_of_gates", gathered_gates,
+                 (flat, gates)),
+                ("expert_order", moe._expert_order, (flat, gates)),
+                ("dots_through_inv", lambda d, inv: jnp.where(
+                    inv < held_rows, d[inv], 0.0).T, (dots, inv)),
+                ("numbers_by_assignment", lambda d, o:
+                 moe._numbers_by_assignment(d, o, held_rows, k),
+                 (dots, order)),
+                ("take_along_axis", taken, (probs,)),
+                ("take_along_axis_bwd", chosen(taken), (probs, d_gates)),
+                ("top_k_and_select", selected, (probs,)),
+                ("select_bwd", chosen(selected), (probs, d_gates))):
+            _emit_raw({"probe": "moe_numbers", "what": name,
+                       "shape": [tokens, k, num_experts, held],
+                       "device_ops_ms": _device_ops_ms(jax.jit(fn), *args)})
+
+
 def probe_rope():
     """One rotation, forward and backward, at SmallThinker's q and k
     (2 x 16,384 x 3,584 and x 512, heads of 128), latent attention's q
@@ -798,7 +864,7 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "head": probe_head, "model": probe_model, "opt": probe_opt,
        "step": probe_step, "dispatch": probe_dispatch,
        "rpc": probe_rpc, "gmm": probe_gmm, "rows_map": probe_rows_map,
-       "rope": probe_rope}
+       "rope": probe_rope, "moe_numbers": probe_moe_numbers}
 
 
 def main(argv=None) -> int:
