@@ -97,8 +97,12 @@ def collect_attention_stats(intermediates) -> dict:
 def attend_projected(proj, n_head: int, cfg, causal: bool = True):
     """Self-attention on the projections' own layout: `proj` is (qkv,),
     one (b, T, 3*h*d) array of q, k and v side by side (`c_attn`'s
-    output), or (q, k, v), (b, T, h*d) each; returns (b, T, h*d) for the
-    output projection.
+    output), or (q, k, v), (b, T, h*d) each — k and v their own
+    (b, T, n_kv*d) where the call goes direct and
+    `ops/flash_attention.kv_route` says "indexed" (grouped heads, a head
+    a slab: the kernels hand a group's query heads one kv slab and
+    nothing is repeated; the caller asks both, `models/llama.
+    LlamaAttention`); returns (b, T, h*d) for the output projection.
 
     Which route a call takes is its shape and where it runs
     (`goes_direct`), nothing else:
@@ -107,8 +111,9 @@ def attend_projected(proj, n_head: int, cfg, causal: bool = True):
       of heads of 64), one device, on the TPU: DIRECT.  The kernels index
       the arrays as they are (`flash_attention_projected`); nothing is
       split, reshaped to heads or transposed, forward or backward.
-      GPT-2 124M (12 x 64: two heads a slab) and OLMoE (16 x 128: a head
-      a slab) go this way.
+      GPT-2 124M (12 x 64: two heads a slab), OLMoE (16 x 128: a head a
+      slab) and SmallThinker (28 x 128 over 4 kv heads, k and v 512
+      lanes wide) go this way.
     - every other call: q, k and v are cut to (b, T, h, d) and go through
       `attend`, as they always did — an odd number of heads of 64 (GPT-2
       XL's 25), a head size off the slab, ring and Ulysses attention, the

@@ -189,7 +189,7 @@ class LlamaAttention(nn.Module):
             goes_direct,
             window_tiles,
         )
-        from ..ops.flash_attention import _kept_mask
+        from ..ops.flash_attention import _kept_mask, kv_route
         from .fp8 import dense
 
         cfg = self.config
@@ -204,9 +204,11 @@ class LlamaAttention(nn.Module):
                 k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
         v = dense(cfg, cfg.num_kv_heads * hd, "v_proj", use_bias=False)(x)
         # where a head is a lane slab the kernels index q, k and v in the
-        # projections' (B, T, heads*hd), and nothing here cuts them to
-        # heads (models/attention.py); every other call does, as it
-        # always did, before the rotation
+        # projections' own (B, T, heads*hd) — k and v at their kv heads,
+        # a group's query heads reading one slab (`kv_route`) — and
+        # nothing here cuts them to heads or repeats them
+        # (models/attention.py); every other call cuts, before the
+        # rotation, and repeats the kv heads, as it always did
         direct = cfg.use_flash_attention and goes_direct(
             cfg, cfg.num_heads, hd, T)
         if not direct:
@@ -216,8 +218,8 @@ class LlamaAttention(nn.Module):
         if cfg.rope:
             q = apply_rope(q, cos, sin, mesh=cfg.mesh)
             k = apply_rope(k, cos, sin, mesh=cfg.mesh)
-        rep = cfg.num_heads // cfg.num_kv_heads
-        if rep > 1:  # GQA: repeat kv heads
+        how, rep = kv_route(cfg.num_heads, cfg.num_kv_heads, hd)
+        if rep > 1 and not (direct and how == "indexed"):  # GQA: repeat
             k, v = (jnp.repeat(t.reshape(B, T, cfg.num_kv_heads, hd), rep,
                                axis=2).reshape(q.shape) for t in (k, v))
         tiles = window_tiles(cfg, B, cfg.num_heads, T)

@@ -82,7 +82,11 @@ Design (FA2 scheme, canonical Mosaic structure):
   by a column offset.  o, dq, dk and dv are written the same way, so the
   output projection and the backward of the input projection take them
   as they are and the compiled step holds no split, cut to heads or
-  transpose around the kernels.  `attention_route(h, d)` says which
+  transpose around the kernels.  GROUPED HEADS are indexed too, not
+  repeated: where a head is a slab k and v keep their projections' own
+  (b, s, n_kv*d) and query slab s reads slab s // rep of them (`kv_route`,
+  `_Slabs.kv_rep`); dk and dv leave the kernels a query head and the
+  entry sums a group's.  `attention_route(h, d)` says which
   layout a shape takes: direct when the heads fall on slab boundaries —
   a head is a slab (d % 128 == 0), or two heads of 64 share one and
   their number is even — transposed otherwise (GPT-2 XL's 25 heads,
@@ -874,21 +878,33 @@ class _Slabs(NamedTuple):
     (b, s, lanes) arrays: a batch row's heads lie side by side in
     `per_row` slabs of `width` lanes, `heads` heads each; q's, k's and
     v's first slab in its array is `offsets` (one array handed in three
-    times has all three: GPT-2's `c_attn` output)."""
+    times has all three: GPT-2's `c_attn` output).  Under grouped heads
+    k's and v's own arrays hold `per_row // kv_rep` slabs a row and
+    query slab s reads slab s // kv_rep of them (`kv_route`)."""
     per_row: int
     heads: int
     width: int
     offsets: Tuple[int, int, int] = (0, 0, 0)
+    kv_rep: int = 1
 
 
 def _block_specs(slabs: Optional[_Slabs], pack: int, d: int):
-    """(operand, row): the BlockSpec of a (block, d) piece of q/k/v/o/dO
-    and of a (1, block_q) piece of lse/delta, both as functions of the
-    grid axis (after the first) that walks the sequence, None = the one
-    block.  The first grid axis walks groups of `pack` heads of the
-    transposed (bh, s, d) arrays, or with `slabs` the slabs of every
-    batch row of (b, s, lanes) arrays; lse and delta are (bh, 1, s) in
-    both."""
+    """(operand, keyed, grouped, row): the BlockSpec of a (block, d)
+    piece of q/o/dO/dq, of k or v, of dk or dv, and of a (1, block_q)
+    piece of lse/delta, each as a function of the grid axis (after the
+    first) that walks the sequence, None = the one block.  The first
+    grid axis walks groups of `pack` heads of the transposed (bh, s, d)
+    arrays, or with `slabs` the QUERY slabs of every batch row of
+    (b, s, lanes) arrays; lse and delta are (bh, 1, s) in both.
+
+    Grouped heads (`slabs.kv_rep` > 1; without them `keyed` and
+    `grouped` are `operand` itself): k's and v's arrays hold `kv_rep`
+    times fewer slabs a row and `keyed` hands query slab s slab
+    s // kv_rep of them; dk and dv are written a QUERY head, and
+    `grouped` puts a group's heads on an axis of their own,
+    (b, kv_rep, s, kv lanes): their sum is then over a major axis, a
+    pass that relays nothing (summed over lane slabs of a (b, s, lanes)
+    array the compiler first transposes the whole of it)."""
     def at(ij, axis):
         if callable(axis):  # a windowed call's narrowed sweep (`_swept`)
             return axis(ij)
@@ -908,7 +924,21 @@ def _block_specs(slabs: Optional[_Slabs], pack: int, d: int):
         return pl.BlockSpec((heads, 1, block_q),
                             lambda g, *ij: (g, 0, at(ij, axis)))
 
-    return operand, row
+    if slabs is None or slabs.kv_rep == 1:
+        return operand, operand, operand, row
+    n, rep = slabs.per_row, slabs.kv_rep
+
+    def keyed(block, axis, first_slab=0, width=d):
+        return pl.BlockSpec(
+            (1, block, slabs.width),
+            lambda g, *ij: (g // n, at(ij, axis), first_slab + g % n // rep))
+
+    def grouped(block, axis, width=d):
+        return pl.BlockSpec(
+            (1, None, block, slabs.width),
+            lambda g, *ij: (g // n, g % n % rep, at(ij, axis), g % n // rep))
+
+    return operand, keyed, grouped, row
 
 
 def _geometry(q, v, slabs: Optional[_Slabs]):
@@ -955,7 +985,7 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
         win["limit"], num_kv = num_kv, win["steps"]
         keys = _swept(num_kv, win["koff"], win["limit"], False)
     grid = (groups, sq // block_q, num_kv)
-    operand, row = _block_specs(slabs, pack, d)
+    operand, keyed, _, row = _block_specs(slabs, pack, d)
 
     kernel = functools.partial(
         _fa_fwd_kernel, num_kv=num_kv, causal=causal, sm_scale=sm_scale,
@@ -965,8 +995,8 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[operand(block_q, 0, qo), operand(block_k, keys, ko),
-                  operand(block_k, keys, vo, dv)],
+        in_specs=[operand(block_q, 0, qo), keyed(block_k, keys, ko),
+                  keyed(block_k, keys, vo, dv)],
         out_specs=(operand(block_q, 0, width=dv), row(block_q, 0)),
         out_shape=(
             _out_struct(q.shape[:2] + (lanes,), q.dtype, q),
@@ -1410,7 +1440,10 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     """All operands flat (bh, s, d) — v, o and dO (bh, s, dv) where v has
     a width of its own — or with `slabs` (b, s, lanes) as
     `_fa_forward_pallas` takes and gives them; lse (bh, 1, sq) f32.
-    Returns dq, dk, dv, each in its operand's layout.
+    Returns dq, dk, dv, each in its operand's layout — under grouped
+    heads (`slabs.kv_rep` > 1) dk and dv a QUERY head, a group's heads
+    on an axis of their own, (b, kv_rep, s, kv lanes): the caller sums
+    over it (`_fa_projected_bwd`).
 
     The kernels recompute p in TRANSPOSED space (queries in lanes) so the
     per-row lse/delta broadcast natively — see `_p_transposed`.  delta and
@@ -1438,7 +1471,7 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                              kv_offset, tile), **_slab_heads(slabs))
     win = _window_plan(window, num_q, num_kv, block_q, block_k, kv_offset)
 
-    operand, row = _block_specs(slabs, pack, d)
+    operand, keyed, grouped, row = _block_specs(slabs, pack, d)
     # delta = rowsum(dO ∘ O) a head — cheap fused reduce; (bh, 1, sq)
     # row-major layout avoids the 128x lane padding a (bh, sq, 1) array
     # would pay.  Two heads a slab: the kernels take o and sum each
@@ -1463,8 +1496,13 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             delta = delta - glse
 
     dq_shape = _out_struct(q.shape[:2] + (lanes,), q.dtype, q)
-    dk_shape = _out_struct(k.shape[:2] + (lanes,), k.dtype, q)
-    dv_shape = _out_struct(v.shape[:2] + (v_lanes,), v.dtype, q)
+    if slabs is not None and slabs.kv_rep > 1:  # (b, kv_rep, s, kv lanes)
+        dk_shape, dv_shape = (_out_struct(
+            x.shape[:1] + (slabs.kv_rep,) + x.shape[1:], x.dtype, q)
+            for x in (k, v))
+    else:
+        dk_shape = _out_struct(k.shape[:2] + (lanes,), k.dtype, q)
+        dv_shape = _out_struct(v.shape[:2] + (v_lanes,), v.dtype, q)
     ops = [q, k, v, do, lse, delta]
 
     if num_q == 1 and num_kv == 1:
@@ -1476,12 +1514,12 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                 block_q=block_q, block_k=block_k, kv_offset=kv_offset,
                 pack=pack, window=window, **plan),
             grid=(groups,),
-            in_specs=[operand(block_q, None, qo), operand(block_k, None, ko),
-                      operand(block_k, None, vo, dv),
+            in_specs=[operand(block_q, None, qo), keyed(block_k, None, ko),
+                      keyed(block_k, None, vo, dv),
                       operand(block_q, None, width=dv),
                       row(block_q, None), delta_spec(block_q, None)],
-            out_specs=(operand(block_q, None), operand(block_k, None),
-                       operand(block_k, None, width=dv)),
+            out_specs=(operand(block_q, None), grouped(block_k, None),
+                       grouped(block_k, None, width=dv)),
             out_shape=(dq_shape, dk_shape, dv_shape),
             # dq across key tiles, one unit at a time
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
@@ -1512,12 +1550,12 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                           block_k=block_k, kv_offset=kv_offset, pack=pack,
                           **plan, **dkv_win),
         grid=(groups, num_kv, dkv_steps),
-        in_specs=[operand(block_q, queries, qo), operand(block_k, 0, ko),
-                  operand(block_k, 0, vo, dv),
+        in_specs=[operand(block_q, queries, qo), keyed(block_k, 0, ko),
+                  keyed(block_k, 0, vo, dv),
                   operand(block_q, queries, width=dv),
                   row(block_q, queries), delta_spec(block_q, queries)],
         out_specs=(operand(sq, None),) * fused + (
-            operand(block_k, 0), operand(block_k, 0, width=dv)),
+            grouped(block_k, 0), grouped(block_k, 0, width=dv)),
         out_shape=(dq_shape,) * fused + (dk_shape, dv_shape),
         scratch_shapes=[
             pltpu.VMEM((pack * num_q, block_q, d), jnp.float32)] * fused + [
@@ -1540,8 +1578,8 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                           block_k=block_k, kv_offset=kv_offset, pack=pack,
                           **plan, **dq_win),
         grid=(groups, num_q, dq_steps),
-        in_specs=[operand(block_q, 0, qo), operand(block_k, keys, ko),
-                  operand(block_k, keys, vo, dv),
+        in_specs=[operand(block_q, 0, qo), keyed(block_k, keys, ko),
+                  keyed(block_k, keys, vo, dv),
                   operand(block_q, 0, width=dv),
                   row(block_q, 0), delta_spec(block_q, 0)],
         out_specs=operand(block_q, 0),
@@ -1953,6 +1991,31 @@ def attention_route(n_head: int, head_dim: int,
     return "transposed", 0
 
 
+def kv_route(n_head: int, n_kv: int, head_dim: int) -> Tuple[str, int]:
+    """How a direct call gets at the k and v of grouped heads, from its
+    shape alone: ("indexed", rep) — `flash_attention_projected` takes k
+    and v as their projections leave them, (b, s, n_kv*d), and the
+    kernels' BlockSpecs hand query slab s slab s // rep of them — where
+    a head is a slab (d % 128 == 0) or there is nothing to repeat
+    (rep = n_head // n_kv = 1); ("repeated", rep) where two heads of 64
+    share a slab: one kv head is HALF a slab there and both query heads
+    of a slab want the same half, so the caller repeats k and v to
+    (b, s, n_head*d) first, as every call off the direct route does.
+
+    The counter of this decision, as `attention_route` is of the layout:
+    `models/llama.LlamaAttention` asks it, the entry reads `rep` off its
+    operands' shapes and refuses what this calls repeated, and
+    tests/test_program_from_arguments.py pins it for the benchmark's
+    cells; in a compiled step its witness is that no broadcast or copy
+    of a (b, s, n_head*d) k or v stands before the kernels."""
+    rep, rest = divmod(n_head, n_kv)
+    if rest:
+        raise ValueError(f"{n_kv} kv heads do not divide {n_head} heads")
+    if rep == 1 or attention_route(n_head, head_dim) == ("direct", 1):
+        return "indexed", rep
+    return "repeated", rep
+
+
 _PROJECTED_BLOCK = 1024  # the direct calls' preferred block, q and keys
 
 
@@ -1970,8 +2033,9 @@ def projected_ok(n_head: int, head_dim: int, seq: int,
 
 def _projected_slabs(proj, n_head: int) -> Tuple[_Slabs, int]:
     """(`_Slabs` of a direct call, its head size).  `proj` is (qkv,),
-    one (b, s, 3*h*d) array of q, k and v side by side, or (q, k, v),
-    (b, s, h*d) each."""
+    one (b, s, 3*h*d) array of q, k and v side by side, or (q, k, v):
+    q (b, s, h*d), k and v that or, under grouped heads, their own
+    (b, s, n_kv*d) where `kv_route` says "indexed"."""
     lanes = proj[0].shape[-1] // (3 if len(proj) == 1 else 1)
     d = lanes // n_head
     route, heads = attention_route(n_head, d)
@@ -1979,9 +2043,19 @@ def _projected_slabs(proj, n_head: int) -> Tuple[_Slabs, int]:
         raise ValueError(
             f"{n_head} heads of {d} do not fall on slab boundaries: "
             f"take flash_attention on (b, h, s, d)")
+    k_lanes, v_lanes = (x.shape[-1] for x in proj[1:]) \
+        if len(proj) == 3 else (lanes, lanes)
+    if k_lanes != v_lanes or k_lanes % d:
+        raise ValueError(f"k {k_lanes} and v {v_lanes} lanes wide are no "
+                         f"kv heads of {d}, as q's are")
+    how, kv_rep = kv_route(n_head, k_lanes // d, d)
+    if how != "indexed":
+        raise ValueError(
+            f"a kv head of {d} is no lane slab: repeat k and v to "
+            f"{n_head} heads first")
     per_row = n_head // heads
     offsets = (0, per_row, 2 * per_row) if len(proj) == 1 else (0, 0, 0)
-    return _Slabs(per_row, heads, lanes // per_row, offsets), d
+    return _Slabs(per_row, heads, lanes // per_row, offsets, kv_rep), d
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
@@ -1991,10 +2065,16 @@ def flash_attention_projected(proj, n_head: int, causal: bool = True,
     """Attention on the projections' own layout: `proj` = (qkv,), one
     (b, s, 3*h*d) array (GPT-2's `c_attn` output, never split), or
     (q, k, v), (b, s, h*d) each → (b, s, h*d), which the output
-    projection takes as it is.  The cotangent comes back in the same
-    form.  No array is split, reshaped to heads or transposed on the
-    way: the kernels' BlockSpecs index the slabs where they lie
-    (`_Slabs`).  `window` as `flash_attention` takes it.
+    projection takes as it is.  Under grouped heads k and v stay their
+    projections' own (b, s, n_kv*d) where `kv_route` says "indexed" (a
+    head a slab): `rep` = h // n_kv is read off the operands' shapes and
+    query slab s reads kv slab s // rep, nothing is repeated.  The
+    cotangent comes back in the same form: dk and dv leave the kernels
+    a query head and a group's `rep` are summed here, the sum the
+    transpose of a repeat would run (in float32, rounded once).  No
+    array is split, reshaped to heads or transposed on the way: the
+    kernels' BlockSpecs index the slabs where they lie (`_Slabs`).
+    `window` as `flash_attention` takes it.
 
     For calls `projected_ok` takes; any other raises ValueError (the
     caller asks first: `models/attention.attend_projected`)."""
@@ -2044,6 +2124,8 @@ def _fa_projected_bwd(n_head, causal, sm_scale, window, res, g):
         **_projected_plan(proj, n_head, causal, sm_scale, window))
     if len(proj) == 1:  # c_attn's cotangent: dq, dk, dv side by side
         return ((jnp.concatenate(grads, axis=-1),),)
+    if grads[1].ndim == 4:  # grouped heads: a kv head's is its group's sum
+        grads = (grads[0],) + tuple(dx.sum(axis=1) for dx in grads[1:])
     return (tuple(grads),)
 
 

@@ -478,3 +478,44 @@ def test_a_call_without_a_window_traces_the_program_it_always_did(
     t = proj[0][-2]
     assert fa.causal_tile_count(t, t) == {
         1024: (3, 4), 4096: (36, 64), 8192: (136, 256)}[t]
+
+
+# ------------------------------- grouped heads: the calls that did move
+
+# sha256, as above, of the direct calls whose k and v are the kv heads'
+# own (b, T, kv*d) arrays (PR 46: `fa.kv_route` "indexed", rep > 1): the
+# two cells whose program changed when the repeat left the step, the
+# windowed cell's two kinds of layer each.  Read when the k / v
+# BlockSpecs learned `s // rep`; the same calls on repeated k and v are
+# rep 1 and trace what they did (the hybrid's pin above is that form).
+GROUPED = {
+    "smallthinker_21b_a3b.steady global": (
+        (2, 16384, 3584), (2, 16384, 512), 28, None,
+        ("b50a6c1a4870f6a0", "102eb781f1fc6cf5")),
+    "smallthinker_21b_a3b.steady windowed": (
+        (2, 16384, 3584), (2, 16384, 512), 28, 4096,
+        ("78cdb769cdb5f931", "5065cc0c791113b8")),
+    "nemotron3_nano_30b_a3b.steady": (
+        (2, 8192, 4096), (2, 8192, 256), 32, None,
+        ("c0081390c134d497", "e939ecb8af6d99fb")),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(GROUPED))
+def test_a_grouped_call_traces_the_program_pinned_for_it(monkeypatch, cell):
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    q, kv, heads, window, want = GROUPED[cell]
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (q, kv, kv)]
+
+    def call(*p):
+        return fa.flash_attention_projected(tuple(p), heads, True, None,
+                                            window)
+
+    def grads(*p):
+        return jax.grad(lambda *pp: call(*pp).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(*p)
+
+    assert (_digest(jax.make_jaxpr(call)(*args)),
+            _digest(jax.make_jaxpr(grads)(*args))) == want
+    assert fa.kv_route(heads, kv[-1] // 128, 128) == (
+        "indexed", q[-1] // kv[-1])

@@ -102,6 +102,26 @@ def test_the_route_is_the_shape_of_the_heads(heads, d, route):
     assert fa.attention_route(heads, d) == route
 
 
+@pytest.mark.parametrize("cell,heads,kv,d,want", [
+    # a head a slab: k and v stay (b, T, kv*d), query slab s reads kv
+    # slab s // rep and nothing is repeated
+    ("smallthinker_21b_a3b.steady", 28, 4, 128, ("indexed", 7)),
+    ("nemotron3_nano_30b_a3b.steady", 32, 2, 128, ("indexed", 16)),
+    # two heads of 64 a slab: a kv head is HALF a slab, the caller repeats
+    ("granite4_h_micro.steady", 32, 8, 64, ("repeated", 4)),
+    # every head its own k and v: nothing to repeat on any route
+    ("olmoe_1b_7b.steady", 16, 16, 128, ("indexed", 1)),
+    ("gpt2_124m.steady", 12, 12, 64, ("indexed", 1)),
+    ("gpt2_xl.fsdp4_steady", 25, 25, 64, ("indexed", 1)),
+    ("kimi_vl_a3b.steady", 16, 16, 192, ("indexed", 1)),
+])
+def test_grouped_heads_are_indexed_where_a_head_is_a_slab(cell, heads, kv,
+                                                          d, want):
+    """`kv_route` at the seven cells' shapes — static, from shapes alone,
+    the counter of whether a direct call's k and v are repeated."""
+    assert fa.kv_route(heads, kv, d) == want
+
+
 @pytest.mark.parametrize("on_tpu,h,d,t,why", [
     (False, 12, 64, 1024, "off the TPU"),
     (True, 12, 64, 4099, "a sequence of 4099"),   # no block tiles it
@@ -254,8 +274,9 @@ def test_the_windowed_cells_attention_plans(monkeypatch, window, names,
                                             sweep, tiles):
     """`smallthinker_21b_a3b.steady`'s two kinds of attention layer, from
     their shape and `LlamaConfig.attn_window` alone: 28 heads of 128 are
-    28 lane slabs, so both go DIRECT (after the 7-fold repeat of k and
-    v) on 16 blocks of 1,024 a side."""
+    28 lane slabs, so both go DIRECT on 16 blocks of 1,024 a side, k
+    and v the 4 kv heads' own 512 lanes (`kv_route`: query slab s reads
+    kv slab s // 7; nothing is repeated)."""
     from dlrover_wuqiong_tpu.models.attention import (
         attend_projected,
         window_tiles,
@@ -266,12 +287,19 @@ def test_the_windowed_cells_attention_plans(monkeypatch, window, names,
     cfg = LlamaConfig(hidden_size=2560, num_heads=28, num_kv_heads=4,
                       attn_head_dim=128, attn_window=window)
     assert fa.attention_route(28, 128) == ("direct", 1)
+    assert fa.kv_route(28, 4, 128) == ("indexed", 7)
     x = jax.ShapeDtypeStruct((2, 16384, 28 * 128), jnp.bfloat16)
-    jaxpr = jax.make_jaxpr(jax.grad(
-        lambda proj: attend_projected(proj, 28, cfg).astype(
-            jnp.float32).sum()))((x,) * 3).jaxpr
+    kv = jax.ShapeDtypeStruct((2, 16384, 4 * 128), jnp.bfloat16)
+    grads = jax.grad(lambda proj: attend_projected(proj, 28, cfg).astype(
+        jnp.float32).sum())
+    jaxpr = jax.make_jaxpr(grads)((x, kv, kv)).jaxpr
     assert sorted(_pallas_calls(jaxpr)) == sorted(
         (name, (2 * 28, 16, sweep)) for name in names)
+    assert [g.shape for g in jax.eval_shape(grads, (x, kv, kv))] == [
+        x.shape, kv.shape, kv.shape]
+    # the repeated form (another caller's) runs the same kernels
+    assert sorted(_pallas_calls(jax.make_jaxpr(grads)((x,) * 3).jaxpr)) == \
+        sorted((name, (2 * 28, 16, sweep)) for name in names)
     assert fa.causal_tile_count(16384, 16384, window=window or None) == tiles
     assert window_tiles(cfg, 2, 28, 16384) == (
         None if not window else (56 * tiles[0], 56 * 528))

@@ -21,6 +21,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
     _no_fusion_falls_to_the_root,
     _no_persistent_cache,
     _one_chip_step,
+    _repeated_kv_ops,
     _row_buffer_walkers,
     _rows_map_calls,
     topo,
@@ -55,7 +56,10 @@ def test_smallthinker_step_fits_one_chip_by_the_rule_and_fills_it(
     bytes to 16 KB: PERF.md section 6, PR 42): the one row buffer is
     taken off the reading before it is held to the pin.  13.85 since
     PR 44: the rotation's float32 copy of q and its tables tiled out to
-    3,584 lanes went with the formula's fusions (`dwt_rope`)."""
+    3,584 lanes went with the formula's fusions (`dwt_rope`).  13.46
+    since PR 46: k and v repeated to 28 heads, (2, 16384, 3584) each,
+    and their copies in the kernels' operand layout are no array of the
+    step (`fa.kv_route`): 0.94 GB under the rule's 14.4."""
     cell, model, step = smallthinker_step
     assert model.config.num_params() == 559_290_880
     assert cell["seq_len"] == 16384
@@ -63,7 +67,7 @@ def test_smallthinker_step_fits_one_chip_by_the_rule_and_fills_it(
     live = m.argument_size_in_bytes + m.temp_size_in_bytes \
         + m.output_size_in_bytes - m.alias_size_in_bytes
     live -= cell["global_batch"] * 16384 * 6 * 2560 * 2  # counted twice
-    want = {1: 11.09, 2: 13.85}[cell["global_batch"]]
+    want = {1: 11.09, 2: 13.46}[cell["global_batch"]]
     assert live / 1e9 == pytest.approx(want, abs=0.05)
     assert 0.25 * 16 * 2 ** 30 < 0.65 * 16e9 < live < 0.90 * 16e9, live / 1e9
     assert m.alias_size_in_bytes >= 12 * model.config.num_params()
@@ -77,8 +81,16 @@ def test_smallthinker_step_runs_two_kinds_of_attention_kernel(
     forward, recomputed and backward — the backward ONE fused kernel a
     layer (`fa.backward_route`): 3 and 9 custom calls.  28 heads
     of 128 are lane slabs: the kernels index the projections' own
-    (batch, 16384, 28 x 128) after GQA's 7-fold repeat, nothing is laid
-    out by head."""
+    (batch, 16384, 28 x 128) and, for k and v, the 4 kv heads' own
+    (batch, 16384, 512) — query slab s reads kv slab s // 7
+    (`fa.kv_route`) — nothing is laid out by head and nothing repeats k
+    or v: no broadcast, no copy and no array of a repeated k's or v's
+    size stands under `attention` outside the projections and the
+    kernels (the parent's step held 40: 16 broadcasts, 16 copies into
+    the kernels' operand layout, 8 re-layouts of dk and dv before their
+    group sums); dk and dv leave the backward kernels a query head as
+    (batch, 7, 16384, 512) and eight `reduce`s a step sum them to k's
+    and v's own width."""
     cell, _, step = smallthinker_step
     text = step.as_text()
     calls = collections.Counter(re.findall(
@@ -89,8 +101,21 @@ def test_smallthinker_step_runs_two_kinds_of_attention_kernel(
     assert fa.backward_route(16384, 16384, 128, 128, 1, b * 28) == (
         "fused", 1)
     assert fa.attention_route(28, 128) == ("direct", 1)
-    assert f"operand_layout_constraints={{bf16[{b},16384,3584]" in text
+    assert fa.kv_route(28, 4, 128) == ("indexed", 7)
+    kernels = re.findall(
+        r"%dwt_fa_\w+?(?:\.\d+)? = .*operand_layout_constraints=\{"
+        r"(bf16\[[\d,]+\])\{2,1,0\}, (bf16\[[\d,]+\])\{2,1,0\}, "
+        r"(bf16\[[\d,]+\])\{2,1,0\}", text)
+    assert kernels == [(f"bf16[{b},16384,3584]",) + (
+        f"bf16[{b},16384,512]",) * 2] * 12
     assert f"bf16[{b * 28},16384,128]" not in text
+    assert _repeated_kv_ops(text, b, 16384, 28, 128) == []
+    sums = re.findall(
+        rf"= bf16\[{b},16384,512\]\S* reduce\(.*attention/reduce_sum", text)
+    assert len(sums) == 8
+    assert len(re.findall(rf"bf16\[{b},7,16384,512\]\S*, "
+                          rf"bf16\[{b},7,16384,512\]\S*\) custom-call",
+                          text)) == 4
     assert fa.causal_tile_count(16384, 16384, window=4096) == (252, 1024)
 
 
@@ -215,22 +240,27 @@ def test_smallthinker_step_rotates_q_and_k_in_one_pass_each(
 def test_windowed_kernels_compile_at_the_cells_shape(topo, window, route,
                                                      names):
     """One sequence of 16,384 tokens, 28 heads of 128 on their lane
-    slabs, blocks of 1,024: the forward and the backward of a windowed
+    slabs over the 4 kv heads' own 512 lanes (query slab s reads kv slab
+    s // 7), blocks of 1,024: the forward and the backward of a windowed
     call on its narrowed grid (index maps that clamp, a class a grid
     step), at the published window and at one off the block and the
     tile — the fused sweep with a slab's whole dq resident, and the pair
-    a sequence that does not fit would take."""
+    a sequence that does not fit would take; dk and dv a query head,
+    a group's heads on an axis of their own."""
     one = SingleDeviceSharding(topo.devices[0])
     x = jax.ShapeDtypeStruct((1, 16384, 3584), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 512), jnp.bfloat16, sharding=one)
     lse = jax.ShapeDtypeStruct((28, 1, 16384), jnp.float32, sharding=one)
-    slabs, _ = fa._projected_slabs((x,) * 3, 28)
+    slabs, _ = fa._projected_slabs((x, kv, kv), 28)
+    assert slabs == (28, 1, 128, (0, 0, 0), 7)
     kw = dict(slabs=slabs, window=window)
     fwd = _compile(lambda q, k, v: fa._fa_forward_pallas(
-        q, k, v, True, 128 ** -0.5, 1024, 1024, False, **kw), x, x, x)
+        q, k, v, True, 128 ** -0.5, 1024, 1024, False, **kw), x, kv, kv)
     assert "dwt_fa_win_fwd" in fwd and "tpu_custom_call" in fwd
     bwd = _compile(lambda q, k, v, o, l, do: fa._fa_backward_pallas(
         q, k, v, o, l, do, True, 128 ** -0.5, 1024, 1024, False,
-        route=route, **kw), x, x, x, x, lse, x)
+        route=route, **kw), x, kv, kv, x, lse, x)
+    assert "bf16[1,7,16384,512]" in bwd
     assert sorted(set(re.findall(r"dwt_fa_win_bwd_[a-z]+", bwd))) == sorted(
         names)
     assert "dwt_fa_fwd" not in fwd + bwd and "dwt_fa_bwd" not in bwd

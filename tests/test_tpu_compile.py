@@ -99,8 +99,8 @@ def test_attention_forward_and_backward_compile(topo, bh, t, d, causal,
         qkv = jax.ShapeDtypeStruct((b, t, 3 * lanes), jnp.bfloat16,
                                    sharding=one)
         slabs, _ = fa._projected_slabs((qkv,) if fused else (x,) * 3, heads)
-        assert slabs == ((6, 2, 128, (0, 6, 12)) if fused
-                         else (16, 1, 128, (0, 0, 0)))
+        assert slabs == ((6, 2, 128, (0, 6, 12), 1) if fused
+                         else (16, 1, 128, (0, 0, 0), 1))
         kw = {"slabs": slabs}
     q = qkv if heads and fused else x
     fwd = _compile(lambda q, k, v: fa._fa_forward_pallas(
@@ -456,11 +456,44 @@ def test_nemotron_step_runs_the_kernels_at_d128_t8192(nemotron_step):
         assert kernel in text, kernel
     assert "dwt_fa_bwd_dq" not in text and "dwt_fa_bwd_dkv" not in text
     # 32 heads of 128 on a hidden size of 2688: a head is a lane slab, the
-    # kernels index the projections' own (batch, 8192, 32 x 128)
+    # kernels index the projections' own (batch, 8192, 32 x 128) and, for
+    # k and v, the 2 kv heads' own (batch, 8192, 256): query slab s reads
+    # kv slab s // 16 and nothing repeats them (`fa.kv_route`)
     b = cell["global_batch"]
     assert fa.attention_route(32, 128) == ("direct", 1)
-    assert f"operand_layout_constraints={{bf16[{b},8192,4096]" in text
+    assert fa.kv_route(32, 2, 128) == ("indexed", 16)
+    assert (f"operand_layout_constraints={{bf16[{b},8192,4096]{{2,1,0}}, "
+            f"bf16[{b},8192,256]{{2,1,0}}, bf16[{b},8192,256]{{2,1,0}}"
+            in text)
     assert f"bf16[{b * 32},8192,128]" not in text
+    assert _repeated_kv_ops(text, b, 8192, 32, 128) == []
+
+
+def _repeated_kv_ops(text, b, t, heads, d):
+    """Device ops under `attention`, outside its projections and its
+    kernels, with a bfloat16 result the size of a k or v repeated to
+    the query heads, whatever axes it is cut into ((b, t, heads*d),
+    (b, t, kv, rep, d), ...): what GQA's repeat leaves in a step — the
+    broadcasts, the copies of the repeated arrays into the kernels'
+    operand layout and the re-layouts of dk and dv before their group
+    sums.  Empty where `fa.kv_route` says "indexed": the kernels read
+    the kv heads' own arrays, and dk and dv a query head are the
+    kernels' own results, summed by a `reduce` to the kv heads' width."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import owners
+
+    own = owners(text)
+    found = []
+    for name, dims, op in re.findall(
+            r"^\s*%([\w.\-]+) = bf16\[([\d,]+)\]\S* ([\w\-]+)\(", text,
+            re.M):
+        scope = own.get(name, {}).get("scope", "")
+        if op in ("bitcast", "get-tuple-element", "parameter") \
+                or "attention" not in scope \
+                or re.search(r"[qkvo]_proj|dwt_", scope):
+            continue
+        if math.prod(map(int, dims.split(","))) == b * t * heads * d:
+            found.append((op, dims))
+    return sorted(found)
 
 
 def _grouped_kernel_calls(text, kernels="dwt_gmm|dwt_tgmm"):
@@ -779,6 +812,8 @@ def test_granite_step_runs_the_kernels_direct_at_32_heads_of_64(
 
     text = granite_step[2].as_text()
     assert fa.attention_route(32, 64) == ("direct", 2)
+    # a kv head of 64 is HALF a slab: not indexed, the repeat stays
+    assert fa.kv_route(32, 8, 64) == ("repeated", 4)
     for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_fused"):
         assert kernel in text, kernel
     assert "dwt_fa_bwd_dq" not in text and "dwt_fa_bwd_dkv" not in text
