@@ -1,0 +1,170 @@
+"""Gated delta-rule mixer (linear attention) over `ops/delta_rule.py`.
+
+With H heads held here, keys of dk and values of dv:
+
+    q~ = x Wq (H*dk)   k~ = x Wk (H*dk)   v~ = x Wv (H*dv)   z = x Wg (H*dv)
+    a  = x Wa (H)      b  = x Wb (H)
+    q, k, v = silu(causal_depthwise_conv1d(q~ | k~ | v~))     no bias
+    q^ = q / ||q||_2 / sqrt(dk)     k^ = k / ||k||_2          per head
+    beta = 2 * sigmoid(b)           g = -exp(A_log) * softplus(a + dt_bias)
+    o   = gated_delta_rule(q^, k^, v, g, beta)                alpha = exp(g)
+    y   = RMSNorm_dv(o) * silu(z)   per head, one (dv,) scale for all
+    out = concat_heads(y) Wo
+
+The factor 2 on the write gate lets a state's eigenvalue along a key go
+negative (beta in (0, 2): the published models' `allow_neg_eigval`).
+The mixer is told how many heads it holds and nothing else: the state,
+both norms, both gates and the output norm are per head and the
+convolution per channel, so a share of the heads IS a share of the
+mixer, and `Wo`'s partial sums over the shares add up to the whole
+(tests/test_olmo_hybrid.py).
+
+Scopes, under the module's own name: `q_proj`, `k_proj`, `v_proj`,
+`g_proj`, `gates` (`a_proj`, `b_proj` and the two gates), `conv`,
+`delta` (the L2 norms and all of the recurrence), `gate_norm`, `o_proj`.
+Parameter names are matched by `parallel/sharding.py`.  The module sows
+`delta_stats`: the lanes the products that meet a head's state run and
+the lanes dk | dv ask (`ops/delta_rule.product_lanes`; static numbers),
+and the sums and count of the decay alpha and the write gate beta over
+heads and tokens — what says a gate has saturated.  They ride the step's
+metrics (`collect_delta_stats`, through `make_lm_loss.with_stats`).
+
+The short convolution and the draw of `dt_bias` are `models/mamba2.py`'s.
+
+Parity: none — the reference's model zoo (atorch) is attention-only; the
+equations are arXiv:2412.06464's in the form of its public
+implementations, as benchmark/reference_olmo_hybrid.py writes them out.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops.delta_rule import gated_delta_rule, product_lanes
+from .mamba2 import _conv_init, _dt_bias_init, causal_conv_silu
+
+_NORM_EPS = 1e-6  # inside the root of both L2 norms
+
+
+@dataclasses.dataclass(frozen=True)
+class GatedDeltaConfig:
+    hidden_size: int = 256
+    num_heads: int = 4          # the heads HELD here
+    key_dim: int = 16
+    value_dim: int = 32
+    conv_kernel: int = 4
+    chunk_size: int = 64
+    eps: float = 1e-6           # the output norm's
+    dtype: Any = jnp.bfloat16
+    # initialiser settings of dt_bias (`mamba2._dt_bias_init`)
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+    @property
+    def conv_dim(self) -> int:
+        return self.num_heads * (2 * self.key_dim + self.value_dim)
+
+    def num_params(self) -> int:
+        h, heads = self.hidden_size, self.num_heads
+        qk, v = heads * self.key_dim, heads * self.value_dim
+        return (h * (2 * qk + 2 * v) + v * h      # q k v g, o
+                + 2 * h * heads                   # a, b
+                + self.conv_kernel * self.conv_dim
+                + 2 * heads + self.value_dim)     # A_log dt_bias, the norm
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """log(uniform(0, 16)): a decay rate up to 16, as slow as float32
+    resolves at the low end."""
+    return jnp.log(jax.random.uniform(
+        key, shape, jnp.float32, jnp.finfo(jnp.float32).tiny, 16.0)
+    ).astype(dtype)
+
+
+def _l2_normalised(x, scale: float = 1.0):
+    x = x.astype(jnp.float32)
+    return x * (jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + _NORM_EPS)
+                * scale)
+
+
+class GatedDeltaMixer(nn.Module):
+    config: GatedDeltaConfig
+
+    @nn.compact
+    def __call__(self, x):  # (B, T, hidden)
+        cfg = self.config
+        bsz, t, _ = x.shape
+        heads, dk, dv = cfg.num_heads, cfg.key_dim, cfg.value_dim
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            name=name)
+
+        q = dense(heads * dk, "q_proj")(x)
+        k = dense(heads * dk, "k_proj")(x)
+        v = dense(heads * dv, "v_proj")(x)
+        z = dense(heads * dv, "g_proj")(x)
+
+        dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (heads,))
+        a_log = self.param("A_log", _a_log_init, (heads,))
+        with jax.named_scope("gates"):
+            # float32 from the projections' outputs on
+            a = dense(heads, "a_proj")(x).astype(jnp.float32)
+            b = dense(heads, "b_proj")(x).astype(jnp.float32)
+            beta = 2.0 * jax.nn.sigmoid(b)
+            g = -jnp.exp(a_log.astype(jnp.float32)) \
+                * jax.nn.softplus(a + dt_bias)
+            # counted, not timed: static lanes, two sums of T x H numbers
+            self.sow("intermediates", "delta_stats", jnp.stack([
+                *jnp.asarray(product_lanes(dk, dv), jnp.float32),
+                jnp.sum(jnp.exp(g)), jnp.sum(beta), jnp.float32(g.size)]))
+
+        # one filter a channel over q | k | v: three slices of one leaf, so
+        # the three projections are never laid side by side
+        kernel = self.param("conv_kernel", _conv_init(cfg.conv_kernel),
+                            (cfg.conv_kernel, cfg.conv_dim))
+        bounds = (0, heads * dk, 2 * heads * dk, cfg.conv_dim)
+        with jax.named_scope("conv"):
+            filters = [kernel[:, lo:hi] for lo, hi in zip(bounds, bounds[1:])]
+        q, k, v = (causal_conv_silu(a_, f, None, cfg.dtype)
+                   for a_, f in zip((q, k, v), filters))
+
+        with jax.named_scope("delta"):
+            q = _l2_normalised(q.reshape(bsz, t, heads, dk),
+                               1.0 / math.sqrt(dk))
+            k = _l2_normalised(k.reshape(bsz, t, heads, dk))
+            v = v.reshape(bsz, t, heads, dv)
+        o = gated_delta_rule(q, k, v, g, beta, chunk=cfg.chunk_size,
+                             dtype=cfg.dtype)
+
+        scale = self.param("gate_norm_scale", nn.initializers.ones, (dv,))
+        with jax.named_scope("gate_norm"):
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                                  + cfg.eps) * scale
+            y = o * jax.nn.silu(z.astype(jnp.float32)
+                                ).reshape(bsz, t, heads, dv)
+            y = y.reshape(bsz, t, heads * dv).astype(cfg.dtype)
+        return dense(cfg.hidden_size, "o_proj")(y)
+
+
+def collect_delta_stats(intermediates) -> dict:
+    """What the gated delta-rule mixers of one forward pass counted — {}
+    for a model without one: `delta_lanes_run` and `delta_lanes_model`
+    summed over the layers, `delta_alpha_mean` and `delta_beta_mean` over
+    heads, tokens and layers."""
+    from .moe import _sown
+
+    rows = [v.reshape(-1, 5) for v in _sown(intermediates, "delta_stats")]
+    if not rows:
+        return {}
+    with jax.named_scope("delta_stats"):  # the sum's copies get an owner
+        run, model, alpha, beta, n = jnp.concatenate(rows).sum(0)
+    return {"delta_lanes_run": run, "delta_lanes_model": model,
+            "delta_alpha_mean": alpha / n, "delta_beta_mean": beta / n}

@@ -102,6 +102,20 @@ def _conv_init(width: int):
     return init
 
 
+@jax.named_scope("conv")
+def causal_conv_silu(x, kernel, bias, dtype):
+    """silu(causal depthwise convolution of x (b, T, channels) along T):
+    `kernel` (taps, channels), one filter a channel, its LAST tap on the
+    current step; `bias` (channels,) or None.  The taps are shifted
+    products, which XLA fuses into one pass.  The short convolution of
+    this mixer and of `models/gated_delta.py`'s."""
+    k, t = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    conv = sum(padded[:, j:j + t] * kernel[j].astype(dtype)
+               for j in range(k))
+    return jax.nn.silu(conv if bias is None else conv + bias.astype(dtype))
+
+
 class Mamba2Mixer(nn.Module):
     config: Mamba2Config
 
@@ -118,14 +132,7 @@ class Mamba2Mixer(nn.Module):
                             (cfg.conv_kernel, cfg.conv_dim))
         bias = self.param("conv_bias", _conv_init(cfg.conv_kernel),
                           (cfg.conv_dim,))
-        with jax.named_scope("conv"):
-            # the filter's LAST tap multiplies the current step; k shifted
-            # products, which XLA fuses into one pass
-            k = cfg.conv_kernel
-            padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
-            conv = sum(padded[:, j:j + t] * kernel[j].astype(cfg.dtype)
-                       for j in range(k))
-            xbc = jax.nn.silu(conv + bias.astype(cfg.dtype))
+        xbc = causal_conv_silu(xbc, kernel, bias, cfg.dtype)
         x, b_mat, c_mat = jnp.split(xbc, [di, di + gn], axis=-1)
 
         dt_bias = self.param("dt_bias", _dt_bias_init(cfg),

@@ -322,9 +322,10 @@ def make_lm_loss(model_apply: Callable) -> Callable:
 
     Collects sown auxiliary losses (MoE load-balancing, router z-loss)
     when present.  `loss_fn.with_stats(params, batch) -> (loss, stats)` is
-    the same loss with what the MoE layers and the windowed attention
-    layers counted (`collect_moe_stats`, `collect_attention_stats`; {}
-    for a dense model without a window): `make_train_step`
+    the same loss with what the MoE layers, the windowed or latent
+    attention layers and the gated delta-rule mixers counted
+    (`collect_moe_stats`, `collect_attention_stats`, `collect_delta_stats`;
+    {} for a dense model without any of them): `make_train_step`
     differentiates that one and returns the counters in the step's
     metrics."""
     from ..models.gpt import cross_entropy_loss
@@ -338,6 +339,7 @@ def make_lm_loss(model_apply: Callable) -> Callable:
         stats = {}
         if inter:
             from ..models.attention import collect_attention_stats
+            from ..models.gated_delta import collect_delta_stats
             from ..models.moe import (
                 collect_moe_aux_loss,
                 collect_moe_stats,
@@ -346,7 +348,8 @@ def make_lm_loss(model_apply: Callable) -> Callable:
 
             loss = loss + collect_moe_aux_loss(inter)
             stats = {**collect_moe_stats(inter),
-                     **collect_attention_stats(inter)}
+                     **collect_attention_stats(inter),
+                     **collect_delta_stats(inter)}
             steps = collect_param_steps(inter)
             if steps:
                 stats["param_steps"] = steps

@@ -1,0 +1,125 @@
+"""`olmo_hybrid_7b.steady`'s step, compiled by the TPU's own compiler for
+a DESCRIBED v5e (no chip attached), as tests/test_tpu_compile.py does for
+the other cells — whose helpers these tests use.  A file of its own, as
+tests/test_smallthinker_compile.py is, so that another xdist worker
+compiles this step (about 50 s) while that file compiles the other four.
+"""
+
+import json
+import os
+
+import pytest
+from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are fixtures
+    _every_device_op_has_an_owner,
+    _no_fusion_falls_to_the_root,
+    _no_persistent_cache,
+    _one_chip_step,
+    topo,
+)
+
+from dlrover_wuqiong_tpu.ops import flash_attention as fa
+
+SUBSCOPES = ("q_proj", "k_proj", "v_proj", "g_proj", "gates", "conv",
+             "delta", "gate_norm", "o_proj")
+
+
+@pytest.fixture(scope="module")
+def olmo_step(topo):
+    """`olmo_hybrid_7b.steady`'s step — published widths, one period
+    (three gated delta-rule mixers at fifteen of thirty heads, chunk 64,
+    one attention layer of thirty heads of 128, a SwiGLU of 11,008 behind
+    each), the untied head on an eighth of the vocabulary, one
+    8192-token sequence, full recomputation (about 50 s)."""
+    return _one_chip_step(topo, "olmo_hybrid_7b.steady", "olmo_hybrid")
+
+
+def test_olmo_step_fits_one_chip_by_the_rule_and_fills_it(olmo_step):
+    """State + temporaries under 90% of the chip's 16 GB (PR 26's rule)
+    at the shipped sizes: 14.16 GB, of which 9.55 GB is donated state and
+    4.61 GB temporaries — rung (a) of the configuration file, 0.24 GB
+    under the limit.  With all thirty heads of the linear mixers held the
+    state and its gradients alone would be 14.86 GB."""
+    cell, model, step = olmo_step
+    assert model.config.num_params() == 795_736_986
+    assert (cell["global_batch"], cell["seq_len"], model.config.chunk_size,
+            model.config.linear_heads) == (1, 8192, 64, 15)
+    m = step.memory_analysis()
+    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    rung = cell["config"]["train"]["memory_rung"]
+    assert rung["taken"] == "a"
+    assert live / 1e9 == pytest.approx(
+        rung["live_GB"]["a: 1 x 8192, chunk 64"], abs=0.05)
+    assert 0.25 * 16 * 2 ** 30 < 0.75 * 16e9 < live < \
+        rung["limit_GB"] * 1e9 == 0.90 * 16e9, live / 1e9
+    assert m.alias_size_in_bytes >= 12 * model.config.num_params()
+
+
+def test_olmo_step_holds_its_scopes_and_no_op_that_holds_others(olmo_step):
+    """Every scope the cell's scopes file names is in the compiled step,
+    the untied head's product under `head`; every op under
+    `linear_attention` belongs to the file's `linattn` part, the
+    convolution's and the delta rule's to `linattn_scan`; and nothing in
+    the step holds other ops (a `while`, a `conditional`), which a device
+    trace would count beside the ops they ran — at three mixers of 128
+    chunks each, forward, recomputed and backward."""
+    from benchmark import cells, program
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+
+    cell, _, step = olmo_step
+    text = step.as_text()
+    assert " while(" not in text and " conditional(" not in text
+    table = scope_table(text)
+    scopes = set(table.values())
+    for part in (*(f"linear_attention/{s}" for s in SUBSCOPES),
+                 "feed_forward/gate_proj", "feed_forward/up_proj",
+                 "feed_forward/down_proj", "attention/q_proj",
+                 "attention/k_proj", "attention/v_proj", "attention/o_proj",
+                 "attention/qk_norm", "post_mixer_norm",
+                 "post_feedforward_norm", "OlmoHybrid/head", "loss",
+                 "optimizer"):
+        assert any(part in s for s in scopes), part
+    assert not any("mamba" in s or "moe" in s for s in scopes)
+    with open(os.path.join(cells.HERE, "models", cell["config"][
+            "model_class"] + ".scopes.json")) as f:
+        rules = json.load(f)
+    under = {s for s in scopes if "linear_attention" in s.split("/")}
+    assert len(under) > 30
+    for scope in under:
+        assert program.part_of(scope, rules["parts"]) == "linattn", scope
+        path = scope.split("/")
+        sub = path[path.index("linear_attention") + 1:][:1]
+        # (a fusion whose members span two of the nine is named by the
+        # mixer alone)
+        assert not sub or sub[0] in SUBSCOPES, scope
+        want = "linattn_scan" if sub and sub[0] in ("conv", "delta") \
+            else program.UNSCOPED
+        assert program.part_of(scope, rules["linattn_parts"]) == want
+    for phase in ("fwd", "recompute", "bwd"):
+        assert any(s.startswith(phase) and "linear_attention/delta" in s
+                   for s in scopes), phase
+    # no kernel of the mixer's yet: the attention layer's are all
+    kernels = sorted(n.split(".")[0] for n in table if n.startswith("dwt_"))
+    assert kernels == ["dwt_fa_bwd_fused", "dwt_fa_fwd", "dwt_fa_fwd"]
+
+
+def test_olmo_step_runs_the_kernels_direct_at_thirty_heads_of_128(
+        olmo_step):
+    """The first un-grouped call at thirty heads through a model: a head
+    is a lane slab, so the kernels index the projections' own
+    (1, 8192, 3840) — the DIRECT route, ONE backward kernel — and nothing
+    is laid out by head."""
+    text = olmo_step[2].as_text()
+    assert fa.attention_route(30, 128)[0] == "direct"
+    assert fa.kv_route(30, 30, 128)[1] == 1
+    assert fa.backward_route(8192, 8192, 128, 128, 1, 30)[0] == "fused"
+    assert "dwt_fa_bwd_dq" not in text and "dwt_fa_bwd_dkv" not in text
+    assert "bf16[30,8192,128]" not in text
+
+
+def test_every_device_op_of_the_olmo_step_has_an_owner(olmo_step):
+    _every_device_op_has_an_owner(olmo_step[2])
+
+
+def test_no_fusion_of_the_olmo_step_falls_to_the_models_root(olmo_step):
+    _no_fusion_falls_to_the_root(olmo_step[2], "OlmoHybrid")
