@@ -29,6 +29,13 @@ and the sums and count of the decay alpha and the write gate beta over
 heads and tokens — what says a gate has saturated.  They ride the step's
 metrics (`collect_delta_stats`, through `make_lm_loss.with_stats`).
 
+Which route the recurrence takes is the call's shapes and where it runs,
+nothing else: `ops/delta_rule.delta_route` reads the shapes, the backend
+and `GatedDeltaConfig.mesh` — the model config's own, as
+`models/mamba2.scans_on_one_device` reads `Mamba2Config.mesh` (a Mosaic
+kernel cannot be partitioned by GSPMD, so a mixer on a mesh of several
+devices keeps the chunked `jax.numpy` form).
+
 The short convolution and the draw of `dt_bias` are `models/mamba2.py`'s.
 
 Parity: none — the reference's model zoo (atorch) is attention-only; the
@@ -66,6 +73,9 @@ class GatedDeltaConfig:
     dt_min: float = 0.001
     dt_max: float = 0.1
     dt_floor: float = 1e-4
+    # where the mixer runs: the model config's mesh (`auto_accelerate`
+    # hands it over); `ops/delta_rule.delta_route` reads it
+    mesh: Any = None
 
     @property
     def conv_dim(self) -> int:
@@ -142,7 +152,7 @@ class GatedDeltaMixer(nn.Module):
             k = _l2_normalised(k.reshape(bsz, t, heads, dk))
             v = v.reshape(bsz, t, heads, dv)
         o = gated_delta_rule(q, k, v, g, beta, chunk=cfg.chunk_size,
-                             dtype=cfg.dtype)
+                             dtype=cfg.dtype, mesh=cfg.mesh)
 
         scale = self.param("gate_norm_scale", nn.initializers.ones, (dv,))
         with jax.named_scope("gate_norm"):

@@ -93,7 +93,8 @@ class OlmoHybridConfig:
             hidden_size=self.hidden_size, num_heads=self.linear_heads,
             key_dim=self.linear_key_dim, value_dim=self.linear_value_dim,
             conv_kernel=self.conv_kernel,
-            chunk_size=self.chunk_size, eps=self.rms_eps, dtype=self.dtype)
+            chunk_size=self.chunk_size, eps=self.rms_eps, dtype=self.dtype,
+            mesh=self.mesh)
 
     def num_params(self) -> int:
         h, llama = self.hidden_size, self.attention_config()

@@ -53,22 +53,58 @@ other products' operands are rounded to (accumulation is float32); the
 mask is applied BEFORE every exp.
 
 Routes, chosen by `delta_route` from what a call can observe (its
-shapes), never by a knob:
+shapes, the backend, the mesh it runs on), never by a knob:
 
+- "kernel": a pair of Pallas (Mosaic) kernels, `dwt_gdr_fwd` and
+  `dwt_gdr_bwd`, behind one `jax.custom_vjp` (`ops/ssd.py`'s design).  A
+  grid step is one (batch row, block of heads, chunk — or the few chunks
+  that fill the MXU's 128 rows side by side, their tiles block
+  diagonal), the chunk axis last and sequential: the forward kernel
+  walks it in order, the backward in reverse.  In VMEM and nowhere
+  else, a head and a chunk: the decay tile, K K^T, L, T by the forward
+  substitution in blocks above (float32 products at full precision; the
+  first round, from D_1 = I, needs none), U0, W, U, P = Q K^T o decay
+  and o; the carried state,
+  (dk x dv) float32 a head, is a scratch the chunk axis walks (zeroed at
+  chunk 0) and the carry is APPLIED, S <- e^{b_C} S + Kd^T U: no (dk x
+  dk) transition, no associative scan, no shift and no pad is on this
+  route (a sequential axis needs no associative form).  The forward
+  kernel writes o and the state ENTERING each chunk ((b, H, chunks, dk,
+  dv) float32); the backward kernel reads those, REBUILDS the chunk's
+  tiles, carries dS (dk x dv) float32 in reverse and emits dq, dk, dv,
+  dbeta and the cotangents of the running sums by column and by row; the
+  solve's cotangent is -T^T dT T^T in the kernel.  What stays `jax.numpy`
+  around the kernels, differentiated by JAX: the T x H numbers (the
+  running sums of g, their layouts by column and by row, beta's by
+  column) and ONE head-major re-layout of q, k, v and o a pass.  The
+  operands `_chunked` rounds to `dtype` are rounded in the kernel too,
+  the same ones at the same places; what differs is the order of float32
+  sums and that U, rounded, meets Kd^T where `_chunked` composes (A, B).
+  On the TPU, on one device (a Mosaic kernel cannot be partitioned by
+  GSPMD).
 - "chunked": the form above in `jax.numpy`, the backward pass its
   differentiation but for the two `custom_vjp`s.  GSPMD partitions it, so
-  a mixer on a mesh of several devices runs it too.
+  a mixer on a mesh of several devices runs it, as does every CPU run and
+  a shape the kernels do not take — and it is the kernels' oracle.
 - "sequential": `lax.scan` over time, for a sequence that is no whole
   number of chunks (a parameter draw on a few tokens) — and the tests'
   oracle.
 
-There is no kernel route yet: a Pallas pair with the state in a VMEM
-scratch along a sequential grid axis (`ops/ssd.py`'s design) would take
-the within-chunk products, the solve and the carry into one kernel
-(ROADMAP M6).  `benchmark/`'s `kernel.delta_roofline` counts the
-RECURRENCE's work from shapes, whatever computes it.
+Keys of 96, values of 192 (ROADMAP M6(b2)): NOTHING is padded in HBM.
+The kernels' operands are head-major, (b, H, T, dk) and (b, H, T, dv),
+and a block is (heads, C, dk) or (heads, C, dv): its last dimension is
+the array's own, which Mosaic takes at any width.  In VMEM a row of 96
+lies on a 128-lane tile as every narrow row does, and a 96-wide
+contraction costs the MXU what a 128-wide one does, so padding keys to a
+slab in HBM would buy no speed and cost a third more key bytes each way:
+`product_lanes` reads dk + dv run for dk + dv asked.  The carried state's
+scratch is (dk x dv): it has no padding lanes a stray value could sit in.
 
-Scopes (under the caller's): `delta` around all of it.
+`benchmark/`'s `kernel.delta_roofline` counts the RECURRENCE's work from
+shapes, whatever computes it.
+
+Scopes (under the caller's): `delta` around all of it; the kernels'
+custom calls, forward, recomputed and backward, carry it.
 
 Parity: none — the reference (atorch's modules and kernels) has no
 linear-attention layer; this is the paper's algorithm.
@@ -76,9 +112,16 @@ linear-attention layer; this is the paper's algorithm.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _dot, _dot_c0, _dot_t, _on_tpu, _out_struct
+from .ssd import _iota, _put
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -93,29 +136,91 @@ def _einsum32(spec, *operands):
                       preferred_element_type=jnp.float32)
 
 
-def delta_route(t: int, chunk: int) -> str:
-    """Which route `gated_delta_rule` takes at these shapes: "chunked"
-    where the sequence is a whole number of chunks, else "sequential"."""
-    return "chunked" if t >= chunk and t % chunk == 0 else "sequential"
+# ------------------------------------------------------------ the route
+
+_SUBLANES = 16           # a bfloat16 tile's; a float32 tile's 8 divides it
+_WIDEST = 256            # lanes of a key or a value the kernels were built at
+_HEADS_A_STEP = 5        # see `_heads_block`
+_ROWS = 128              # the MXU's: a grid step's chunks fill them
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _heads_block(h: int) -> int:
+    """Heads a grid step takes: the largest divisor of `h` up to
+    `_HEADS_A_STEP`.  The heads of a step are independent chains of
+    products (the solve's ten dependent float32 products a tile above
+    all), which the scheduler interleaves.  Measured at the cell's shape,
+    fifteen heads, two chunks a step (PERF.md section 6, PR 48): 1 / 3 /
+    5 / 15 heads a step run a layer's forward kernel in 2.77 / 2.69 /
+    2.68 / 2.65 ms and its backward in 3.89 / 3.70 / 3.61 / 4.37 (at
+    fifteen the unrolled backward is 3 times the code for nothing)."""
+    return max(d for d in range(1, _HEADS_A_STEP + 1) if h % d == 0)
+
+
+def _vmem_bytes(dk: int, dv: int, rows: int, hb: int) -> int:
+    """What the backward kernel (the larger) holds at `rows` rows a grid
+    step: its double-buffered blocks (q, k, dq, dk float32; v, dv; dO
+    float32; the entering states), the carried cotangent, and a head's
+    tiles and temporaries."""
+    def lanes(d):
+        return -(-d // 128) * 128
+
+    blocks = rows * 4 * (4 * lanes(dk) + 3 * lanes(dv))
+    state = dk * lanes(dv) * 4
+    states = -(-rows // 64) * state
+    tiles = 4 * rows * (16 * lanes(rows) + 8 * (lanes(dk) + lanes(dv)))
+    return 2 * hb * (blocks + states) + hb * state + 3 * tiles + 3 * state
+
+
+def delta_route(t: int, chunk: int, heads: int, dk: int, dv: int,
+                mesh=None):
+    """Which route `gated_delta_rule` takes, from what the call can
+    observe: ("kernel", heads a grid step) on the TPU, on one device
+    (`mesh` is the mixer config's: a Mosaic kernel cannot be partitioned
+    by GSPMD), when the sequence is a whole number of chunks, the chunk a
+    multiple of the sublane tile, dk and dv multiples of 32 up to 256
+    lanes, and the blocks plus the state of a block of heads fit the VMEM
+    the call states; else "chunked" where the sequence is a whole number
+    of chunks, else "sequential".  The static counter of the decision
+    (with the compiled step's count of `dwt_gdr_*` custom calls); pinned
+    by tests/test_program_from_arguments.py for the benchmark's cell."""
+    if t < chunk or t % chunk:
+        return "sequential"
+    if not _on_tpu() or not (mesh is None or mesh.size == 1):
+        return "chunked"
+    if chunk % _SUBLANES or dk % 32 or dv % 32 or max(dk, dv) > _WIDEST:
+        return "chunked"
+    hb = _heads_block(heads)
+    rows = chunk * _chunks_a_step(chunk, t // chunk)
+    if _vmem_bytes(dk, dv, rows, hb) > _VMEM_LIMIT:
+        return "chunked"
+    return "kernel", hb
 
 
 def product_lanes(dk: int, dv: int) -> tuple:
     """(lanes run, lanes the model asks) of the products that meet a head's
-    state, key side | value side.  No route pads a head today (XLA tiles
-    dk and dv as they are), so both read dk + dv; a kernel route that lays
-    keys of 96 on a 128-lane slab would run 128 + dv."""
+    state, key side | value side, by the blocks' declared last dimensions
+    (as `ops/flash_attention.kernel_lanes` counts the attention's).  No
+    route pads a head in HBM: XLA tiles dk and dv as they are on the
+    `jax.numpy` routes, and the kernels' head-major blocks are (C, dk) and
+    (C, dv), their last dimension the array's own, so both read dk + dv.
+    (Keys of 96 laid on a 128-lane slab in HBM would run 128 + dv.)"""
     return dk + dv, dk + dv
 
 
 @jax.named_scope("delta")
 def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
-                     dtype=jnp.float32):
+                     dtype=jnp.float32, mesh=None):
     """q, k (b, T, H, dk), normalised and q scaled; v (b, T, H, dv);
     g (b, T, H), the log of the decay (<= 0); beta (b, T, H), the write
-    gate.  Returns o (b, T, H, dv) in float32."""
-    if delta_route(q.shape[1], chunk) == "chunked":
+    gate; `mesh`, where the call runs (the mixer config's).  Returns
+    o (b, T, H, dv) in float32."""
+    route = delta_route(q.shape[1], chunk, *k.shape[2:], v.shape[-1], mesh)
+    if route == "chunked":
         return _chunked(q, k, v, g, beta, chunk, dtype)
-    return gated_delta_rule_sequential(q, k, v, g, beta)
+    if route == "sequential":
+        return gated_delta_rule_sequential(q, k, v, g, beta)
+    return _chunk_kernels(q, k, v, g, beta, chunk, dtype, route[1])
 
 
 def gated_delta_rule_sequential(q, k, v, g, beta):
@@ -259,3 +364,357 @@ def _chunked(q, k, v, g, beta, chunk, dtype):
     o = o + _einsum("bcrhd,bchdv->bcrhv", qs, s_in, dtype=dtype) \
         * by_step(jnp.exp(cum))
     return o.reshape(bsz, t, h, dv)
+
+
+# ------------------------------------------------------------ the kernels
+#
+# Layouts.  q, k (b, H, T, dk), v, o, dO (b, H, T, dv): head-major, a
+# block (hb, R, d) of R = n x C rows, n chunks side by side, whose last
+# dimension is the array's own (nothing is padded in HBM; in VMEM a row
+# of 96 lies on a 128-lane tile as any narrow row does).  The T x H
+# numbers come BY COLUMN, (b, H/hb, steps, R, hb), time on sublanes — the
+# running sums of g inside a chunk and beta — and the running sums also
+# BY ROW, (b, H/hb, steps, hb, R), time on lanes, so that no kernel
+# transposes a vector.  The entering states, (b, H, chunks, dk, dv)
+# float32, a block (hb, n, dk, dv).  The grid is (batch row, block of
+# heads, step), the step axis sequential: the carried state (backward:
+# its cotangent) of the block's heads is a VMEM scratch.
+#
+# A step's n chunks share every (R x R) tile: the tiles are block
+# diagonal (a mask keeps a chunk to itself), so the solve's rounds, T's
+# products and the masked products run once for n chunks at the MXU's
+# full 128 rows — a (64 x 64 x 64) product occupies a pass as a (128 x
+# 128 x 128) one does, and the solve's float32 products are most of a
+# tile's passes.  Only what meets the carried state is a chunk's own,
+# in order (backward: in reverse) inside the step.
+
+def _dot32(a, b, dims=((1,), (0,))):
+    """A float32 product at full precision (the solve's, its cotangent's)."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _row_sum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _col_sum(x):
+    return jnp.sum(x, axis=0, keepdims=True)
+
+
+def _stack(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
+def _masks(chunk, n):
+    """Of an (R x R) tile of n chunks: (s <= r, s < r, each inside its
+    chunk; s == r; the sub-diagonal blocks that join two s-blocks into one
+    of 2s for s = 1, 2, 4, ... below the chunk)."""
+    size = n * chunk
+    rows, cols = _iota((size, size), 0), _iota((size, size), 1)
+    same = rows // chunk == cols // chunk
+    joins, s = [], 1
+    while s < chunk:
+        joins.append((rows // (2 * s) == cols // (2 * s))
+                     & (rows // s != cols // s))
+        s *= 2
+    return (rows >= cols) & same, (rows > cols) & same, rows == cols, joins
+
+
+def _solve(low, eye, joins):
+    """`_unit_lower_inverse` on one tile: D_2s = D_s - D_s E D_s from
+    D_1 = I, whose first round needs no product (I E I = E)."""
+    inv = jnp.where(eye, 1.0, 0.0) - jnp.where(joins[0], low, 0.0)
+    for join in joins[1:]:
+        inv = inv - _dot32(_dot32(inv, jnp.where(join, low, 0.0)), inv)
+    return inv
+
+
+class _Tiles:
+    """A head's tiles of one step's chunks that do not depend on the
+    entering state — what the forward kernel builds and the backward
+    REBUILDS — named as the module's docstring names them; `*b` is the
+    operand rounded to the products' dtype, as `_chunked` rounds it."""
+
+    def __init__(self, refs, h, masks, chunk, dtype):
+        q_ref, k_ref, v_ref, bc_ref, br_ref, bt_ref = refs
+        tril, strict, eye, joins = masks
+        f32 = jnp.float32
+        b_col, self.beta = bc_ref[:, h:h + 1], bt_ref[:, h:h + 1]
+        size = b_col.shape[0]
+        row = _iota((size, 1), 0)
+        # a chunk's total, b_C, over its rows; e^{b_C} a chunk
+        total, self.ets = None, []
+        for c in range(size // chunk):
+            last = _col_sum(jnp.where(row == (c + 1) * chunk - 1, b_col,
+                                      0.0))                      # (1, 1)
+            total = last if c == 0 else jnp.where(
+                row >= c * chunk, last, total)
+            self.ets.append(jnp.exp(last))
+        self.decay = jnp.exp(jnp.where(
+            tril, b_col - br_ref[h:h + 1, :], -jnp.inf))
+        self.eb, self.te = jnp.exp(b_col), jnp.exp(total - b_col)
+        self.kf, self.vf = k_ref[h].astype(f32), v_ref[h].astype(f32)
+        self.kb, self.qb = self.kf.astype(dtype), q_ref[h].astype(dtype)
+        self.kk = _dot_t(self.kb, self.kb)
+        self.tm = _solve(
+            jnp.where(strict, self.beta * self.decay * self.kk, 0.0),
+            eye, joins)
+        self.tb = self.tm.astype(dtype)
+        self.vbb = (self.vf * self.beta).astype(dtype)
+        self.keb = (self.kf * (self.beta * self.eb)).astype(dtype)
+        self.kdb = (self.kf * self.te).astype(dtype)
+        self.u0 = _dot(self.tb, self.vbb)
+        self.wb = _dot(self.tb, self.keb).astype(dtype)
+        self.qk = _dot_t(self.qb, self.kb)
+        self.pb = (self.qk * self.decay).astype(dtype)
+
+
+def _gdr_fwd_kernel(q_ref, k_ref, v_ref, bc_ref, br_ref, bt_ref, o_ref,
+                    *rest, chunk, dtype, save):
+    st_ref = rest[0] if save else None
+    s_scr = rest[-1]
+    hb, size, _ = q_ref.shape
+    n = size // chunk
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_chunk():
+        s_scr[...] = jnp.zeros(s_scr.shape, jnp.float32)
+
+    masks = _masks(chunk, n)
+    for h in range(hb):
+        t = _Tiles((q_ref, k_ref, v_ref, bc_ref, br_ref, bt_ref), h, masks,
+                   chunk, dtype)
+        state, ubs, qss = s_scr[h], [], []
+        for c in range(n):
+            rows = slice(c * chunk, (c + 1) * chunk)
+            if save:
+                st_ref[h, c] = state
+            sb = state.astype(dtype)
+            ubs.append((t.u0[rows] - _dot(t.wb[rows], sb)).astype(dtype))
+            qss.append(_dot(t.qb[rows], sb))
+            state = t.ets[c] * state + _dot_c0(t.kdb[rows], ubs[c])
+        s_scr[h] = state
+        o_ref[h] = _dot(t.pb, _stack(ubs)) + _stack(qss) * t.eb
+
+
+def _gdr_bwd_kernel(q_ref, k_ref, v_ref, bc_ref, br_ref, bt_ref, st_ref,
+                    do_ref, dq_ref, dk_ref, dv_ref, dbc_ref, dbr_ref,
+                    dbt_ref, ds_scr, *, chunk, dtype):
+    hb, size, _ = q_ref.shape
+    n = size // chunk
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _last_chunk():
+        ds_scr[...] = jnp.zeros(ds_scr.shape, f32)
+
+    masks = _masks(chunk, n)
+    strict = masks[1]
+    row = _iota((size, 1), 0)
+    col_head, row_head = _iota((1, hb), 1), _iota((hb, 1), 0)
+    dbc = jnp.zeros((size, hb), f32)
+    dbt = jnp.zeros((size, hb), f32)
+    dbr = jnp.zeros((hb, size), f32)
+    for h in range(hb):
+        t = _Tiles((q_ref, k_ref, v_ref, bc_ref, br_ref, bt_ref), h, masks,
+                   chunk, dtype)
+        chunks = [slice(c * chunk, (c + 1) * chunk) for c in range(n)]
+        sbs = [st_ref[h, c].astype(dtype) for c in range(n)]
+        ubs = [(t.u0[rows] - _dot(t.wb[rows], sb)).astype(dtype)
+               for rows, sb in zip(chunks, sbs)]
+        qs = _stack([_dot(t.qb[rows], sb) for rows, sb in zip(chunks, sbs)])
+        do = do_ref[h].astype(f32)
+        dob = do.astype(dtype)
+        # o = P U + e^b (Q S_in);  S_out = e^{b_C} S_in + Kd^T U
+        dp = _dot_t(dob, _stack(ubs))                          # (R, R)
+        dqs = (do * t.eb).astype(dtype)
+        du = _dot_c0(t.pb, dob)                                # (R, dv)
+        # what meets the carried state, a chunk at a time from the last:
+        # U = U0 - W S_in
+        ds_out = ds_scr[h]     # cotangent of the state LEAVING a chunk
+        dubs, dwbs, dkds, dq_s, d_et = ([None] * n for _ in range(5))
+        for c in reversed(range(n)):
+            rows, sb, gb = chunks[c], sbs[c], ds_out.astype(dtype)
+            dubs[c] = (du[rows] + _dot(t.kdb[rows], gb)).astype(dtype)
+            dkds[c] = _dot_t(ubs[c], gb)                       # (C, dk)
+            dwbs[c] = (-_dot_t(dubs[c], sb)).astype(dtype)     # (C, dk)
+            dq_s[c] = _dot_t(dqs[rows], sb)
+            d_et[c] = t.ets[c] * _col_sum(_row_sum(ds_out * st_ref[h, c]))
+            ds_out = t.ets[c] * ds_out + _dot_c0(t.qb[rows], dqs[rows]) \
+                - _dot_c0(t.wb[rows], dubs[c])
+        ds_scr[h] = ds_out
+        dub, dwb, dkd = _stack(dubs), _stack(dwbs), _stack(dkds)
+        # U0 = T (beta V);  W = T (beta e^b K)
+        dvb = _dot_c0(t.tb, dub)                               # (R, dv)
+        dke = _dot_c0(t.tb, dwb)                               # (R, dk)
+        # T = (I + L)^-1: dL = -T^T dT T^T on the strict lower triangle
+        d_tm = _dot_t(dub, t.vbb) + _dot_t(dwb, t.keb)
+        dlow = jnp.where(strict, -_dot32(
+            _dot32(t.tm, d_tm, ((0,), (0,))), t.tm, ((1,), (1,))), 0.0)
+        dkkb = (dlow * (t.beta * t.decay)).astype(dtype)
+        dqkb = (dp * t.decay).astype(dtype)
+        m = (dp * t.qk + dlow * (t.beta * t.kk)) * t.decay  # d(b_r - b_s)
+        dq_ref[h] = (_stack(dq_s) + _dot(dqkb, t.kb)).astype(dq_ref.dtype)
+        dk_ref[h] = (dkd * t.te + dke * (t.beta * t.eb)
+                     + _dot_c0(dqkb, t.qb) + _dot(dkkb, t.kb)
+                     + _dot_c0(dkkb, t.kb)).astype(dk_ref.dtype)
+        dv_ref[h] = (dvb * t.beta).astype(dv_ref.dtype)
+        through_ke = _row_sum(dke * t.kf) * t.eb               # (R, 1)
+        through_te = _row_sum(dkd * t.kf) * t.te
+        d_col = _row_sum(m) + _row_sum(do * qs) * t.eb \
+            + through_ke * t.beta - through_te
+        for c, rows in enumerate(chunks):    # d(b_C), at a chunk's last row
+            d_total = d_et[c] + _col_sum(jnp.where(
+                (row >= rows.start) & (row < rows.stop), through_te, 0.0))
+            d_col = d_col + jnp.where(row == rows.stop - 1, d_total, 0.0)
+        dbc = _put(dbc, col_head, h, d_col)
+        dbt = _put(dbt, col_head, h, _row_sum(dlow * (t.decay * t.kk))
+                   + _row_sum(dvb * t.vf) + through_ke)
+        dbr = _put(dbr, row_head, h, -_col_sum(m))
+    dbc_ref[...], dbr_ref[...], dbt_ref[...] = dbc, dbr, dbt
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _specs(size, n, hb, dk, dv, at):
+    """BlockSpecs by operand kind; `at(k)` is the step (n chunks, `size`
+    rows) a grid step works on (the backward kernel walks them in
+    reverse)."""
+    def rows(d):
+        return pl.BlockSpec((None, hb, size, d),
+                            lambda b, j, k: (b, j, at(k), 0))
+    return dict(
+        key=rows(dk), value=rows(dv),
+        col=pl.BlockSpec((None, None, None, size, hb),
+                         lambda b, j, k: (b, j, at(k), 0, 0)),
+        row=pl.BlockSpec((None, None, None, hb, size),
+                         lambda b, j, k: (b, j, at(k), 0, 0)),
+        state=pl.BlockSpec((None, hb, n, dk, dv),
+                           lambda b, j, k: (b, j, at(k), 0, 0)))
+
+
+def _gdr_forward_pallas(q, k, v, bc, br, bt, *, chunk, hb, dtype, save,
+                        interpret):
+    """o (b, H, T, dv) float32 and, with `save`, the state ENTERING every
+    chunk, (b, H, chunks, dk, dv) float32, for the backward kernel."""
+    bsz, h, t, dk = k.shape
+    dv, size = v.shape[-1], bc.shape[-2]
+    n, steps = size // chunk, t // size
+    sp = _specs(size, n, hb, dk, dv, lambda k: k)
+    out_shape = [_out_struct((bsz, h, t, dv), jnp.float32, q)]
+    out_specs = [sp["value"]]
+    if save:
+        out_shape.append(_out_struct((bsz, h, t // chunk, dk, dv),
+                                     jnp.float32, q))
+        out_specs.append(sp["state"])
+    out = pl.pallas_call(
+        functools.partial(_gdr_fwd_kernel, chunk=chunk, dtype=dtype,
+                          save=save),
+        grid=(bsz, h // hb, steps),
+        in_specs=[sp["key"], sp["key"], sp["value"], sp["col"], sp["row"],
+                  sp["col"]],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
+        compiler_params=_params(), interpret=interpret,
+        name="dwt_gdr_fwd",
+    )(q, k, v, bc, br, bt)
+    return tuple(out) if save else (out[0], None)
+
+
+def _gdr_backward_pallas(q, k, v, bc, br, bt, states, do, *, chunk, hb,
+                         dtype, interpret):
+    bsz, h, t, dk = k.shape
+    dv, size = v.shape[-1], bc.shape[-2]
+    n, steps = size // chunk, t // size
+    sp = _specs(size, n, hb, dk, dv, lambda k: steps - 1 - k)
+    f32 = jnp.float32
+    return pl.pallas_call(
+        functools.partial(_gdr_bwd_kernel, chunk=chunk, dtype=dtype),
+        grid=(bsz, h // hb, steps),
+        in_specs=[sp["key"], sp["key"], sp["value"], sp["col"], sp["row"],
+                  sp["col"], sp["state"], sp["value"]],
+        out_specs=[sp["key"], sp["key"], sp["value"], sp["col"], sp["row"],
+                   sp["col"]],
+        out_shape=[_out_struct(q.shape, q.dtype, q),
+                   _out_struct(k.shape, k.dtype, q),
+                   _out_struct(v.shape, v.dtype, q),
+                   _out_struct(bc.shape, f32, q),
+                   _out_struct(br.shape, f32, q),
+                   _out_struct(bt.shape, f32, q)],
+        scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
+        compiler_params=_params(), interpret=interpret,
+        name="dwt_gdr_bwd",
+    )(q, k, v, bc, br, bt, states, do)
+
+
+# A model's layers call the kernels with the same shapes and the same
+# static plan: behind `jax.jit` a kernel body is traced and lowered to
+# Mosaic once a step program, not once a layer (`ops/ssd.py`'s way).
+_STATIC = ("chunk", "hb", "dtype", "interpret")
+_forward = jax.jit(_gdr_forward_pallas, static_argnames=_STATIC + ("save",))
+_backward = jax.jit(_gdr_backward_pallas, static_argnames=_STATIC)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _chunks(q, k, v, bc, br, bt, plan):
+    """The kernels' pair: (q, k, v head-major, the running sums by column
+    and by row, beta by column) -> o (b, H, T, dv) float32.  `plan`: the
+    static arguments."""
+    return _forward(q, k, v, bc, br, bt, save=False, **dict(plan))[0]
+
+
+def _chunks_fwd(q, k, v, bc, br, bt, plan):
+    o, states = _forward(q, k, v, bc, br, bt, save=True, **dict(plan))
+    return o, (q, k, v, bc, br, bt, states)
+
+
+def _chunks_bwd(plan, res, do):
+    return tuple(_backward(*res, do, **dict(plan)))
+
+
+_chunks.defvjp(_chunks_fwd, _chunks_bwd)
+
+
+def _chunks_a_step(chunk: int, chunks: int) -> int:
+    """Chunks side by side a grid step: as many as fill the MXU's rows
+    (`_ROWS`) and divide the sequence's chunks.  Measured at the cell's
+    shape, five heads a step (PERF.md section 6, PR 48): one chunk of 64
+    a step runs a layer's forward kernel in 3.62 ms and its backward in
+    4.97, two in 2.68 and 3.61, the same numbers bit for bit."""
+    n = max(1, _ROWS // chunk)
+    while chunks % n:
+        n -= 1
+    return n
+
+
+def _kernel_operands(q, k, v, g, beta, chunk, hb, n):
+    """What the kernels are handed, from `gated_delta_rule`'s arguments:
+    q, k, v head-major (ONE re-layout each a pass); the running sums of g
+    inside a chunk by column and by row, beta by column, n chunks a step.
+    `jax.numpy`, differentiated by JAX."""
+    bsz, t, h = g.shape
+    c = t // chunk
+
+    def steps(x):  # (b, chunks, C, H) -> (b, H/hb, steps, R, hb)
+        return x.reshape(bsz, c // n, n * chunk, h // hb, hb).transpose(
+            0, 3, 1, 2, 4)
+
+    cum = steps(jnp.cumsum(
+        g.astype(jnp.float32).reshape(bsz, c, chunk, h), axis=2))
+    return (*(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
+            cum, cum.swapaxes(-1, -2), steps(beta.astype(jnp.float32)))
+
+
+def _chunk_kernels(q, k, v, g, beta, chunk, dtype, hb, interpret=False,
+                   chunks_a_step=None):
+    """The kernel route: `_chunks` on `_kernel_operands`."""
+    n = chunks_a_step or _chunks_a_step(chunk, q.shape[1] // chunk)
+    plan = (("chunk", chunk), ("hb", hb), ("dtype", jnp.dtype(dtype)),
+            ("interpret", interpret))
+    o = _chunks(*_kernel_operands(q, k, v, g, beta, chunk, hb, n), plan)
+    return o.transpose(0, 2, 1, 3)

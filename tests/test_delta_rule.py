@@ -53,7 +53,7 @@ def test_the_chunked_form_is_the_recurrence(oracle, chunk, what):
     """ONE test over chunk sizes {4, 16, 64} (T = 64: sixteen chunks, four,
     one) and over the output and each operand's gradient."""
     ops, w, out, grads = oracle
-    assert dr.delta_route(T, chunk) == "chunked"
+    assert dr.delta_route(T, chunk, H, DK, DV) == "chunked"
     with jax.default_matmul_precision("highest"):
         if what == "value":
             got, want = dr.gated_delta_rule(*ops, chunk=chunk), out
@@ -124,10 +124,10 @@ def test_the_carry_is_the_loop_over_the_chunks_and_so_is_its_gradient():
 
 
 def test_the_route_is_the_shapes_and_a_ragged_sequence_still_runs():
-    assert dr.delta_route(8192, 64) == "chunked"
-    assert dr.delta_route(64, 64) == "chunked"
-    assert dr.delta_route(8192 + 32, 64) == "sequential"
-    assert dr.delta_route(16, 64) == "sequential"
+    assert dr.delta_route(8192, 64, 15, 96, 192) == "chunked"  # the CPU
+    assert dr.delta_route(64, 64, H, DK, DV) == "chunked"
+    assert dr.delta_route(8192 + 32, 64, 15, 96, 192) == "sequential"
+    assert dr.delta_route(16, 64, H, DK, DV) == "sequential"
     assert dr.product_lanes(96, 192) == (288, 288)
     ops = draw(5, t=24)  # no multiple of 16: one step at a time
     with jax.default_matmul_precision("highest"):

@@ -1,0 +1,205 @@
+"""The gated delta rule's Pallas kernels (`ops/delta_rule.py`:
+`dwt_gdr_fwd`, `dwt_gdr_bwd`) in interpret mode on the CPU, against the
+chunked `jax.numpy` form of the same equations AND against the recurrence
+one step at a time: the output and the gradient of every operand at the
+cell's widths (keys of 96, values of 192) and at the nano model's (8 and
+24), chunks of 64 and of 16, one chunk a grid step and several side by
+side, more than two steps (the carried state, forward and in reverse),
+one block of heads and several, two batch rows.
+What the described-`v5e` compile cannot see (results), as it sees what
+this cannot (tiling, VMEM): tests/test_olmo_hybrid_compile.py.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_wuqiong_tpu.ops import delta_rule as dr
+
+NAMES = ["o", "dq", "dk", "dv", "dg", "dbeta"]
+
+# (T, H, dk, dv, chunk, heads a grid step, chunks a grid step)
+CASES = {
+    "cell_widths_C64_three_steps_of_one": (192, 2, 96, 192, 64, 2, 1),
+    "cell_widths_C64_two_steps_of_two": (256, 2, 96, 192, 64, 2, 2),
+    "cell_widths_C16_three_blocks": (64, 3, 96, 192, 16, 1, 2),
+    "nano_widths_C16_one_step_of_four": (64, 3, 8, 24, 16, 3, 4),
+    "nano_widths_C64_two_blocks": (128, 4, 8, 24, 64, 2, 2),
+}
+
+
+def draw(seed, t, h, dk, dv, b=2, agree=0.0, beta_top=2.0):
+    """Operands as the mixer hands them over (tests/test_delta_rule.py's
+    draw, which that file's oracle pins to one shape, at any shape)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(ks[0], (b, t, h, dk))
+    k = jax.random.normal(ks[1], (b, t, h, dk)) \
+        + agree * jax.random.normal(ks[5], (b, 1, h, dk))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(dk)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, t, h, dv))
+    g = -0.5 * jax.nn.softplus(jax.random.normal(ks[3], (b, t, h)))
+    beta = beta_top * jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    return q, k, v, g, beta
+
+
+def _value_and_grads(fn, args):
+    @jax.jit
+    def both(*a):
+        return (fn(*a),) + jax.grad(
+            lambda *x: jnp.sum(jnp.sin(fn(*x))), argnums=tuple(range(5)))(*a)
+    return both(*args)
+
+
+def _kernels(chunk, dtype, hb, n=None):
+    return lambda *a: dr._chunk_kernels(*a, chunk, dtype, hb,
+                                        interpret=True, chunks_a_step=n)
+
+
+@functools.lru_cache(maxsize=None)
+def _three_ways(case):
+    t, h, dk, dv, chunk, hb, n = CASES[case]
+    args = draw(0, t, h, dk, dv)
+    with jax.default_matmul_precision("highest"):
+        return {
+            "kernel": _value_and_grads(_kernels(chunk, jnp.float32, hb, n),
+                                       args),
+            "chunked": _value_and_grads(
+                lambda *a: dr._chunked(*a, chunk, jnp.float32), args),
+            "sequential": _value_and_grads(dr.gated_delta_rule_sequential,
+                                           args),
+        }
+
+
+def _off(got, want):
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert float(jnp.abs(want).max()) > 0
+    return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("what", NAMES)
+@pytest.mark.parametrize("against", ("chunked", "sequential"))
+@pytest.mark.parametrize("case", CASES)
+def test_the_kernels_are_the_chunked_form_and_the_recurrence(case, against,
+                                                             what):
+    """ONE test over shapes, the two oracles, the output and each
+    operand's gradient: float32 throughout, so what differs is the order
+    of the sums."""
+    ways = _three_ways(case)
+    i = NAMES.index(what)
+    assert _off(ways["kernel"][i], ways[against][i]) < 2e-5
+
+
+@pytest.mark.parametrize("agree,beta_top", [(0.0, 1.0), (3.0, 2.0),
+                                            (30.0, 2.0)])
+def test_keys_that_agree_under_a_write_gate_near_two_stay_exact(agree,
+                                                                beta_top):
+    """tests/test_delta_rule.py's case through the kernel: the solve in
+    VMEM is the module's forward substitution in blocks, not the
+    nilpotent product, at one chunk of 64 and at the second of two."""
+    ops = draw(3, 128, 3, 6, 10, agree=agree, beta_top=beta_top)
+    ops = ops[:3] + (ops[3] * 0.01, ops[4])  # next to no decay
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(_kernels(64, jnp.float32, 3))(*ops)
+        want = dr.gated_delta_rule_sequential(*ops)
+    np.testing.assert_allclose(got, want, rtol=1e-3,
+                               atol=1e-4 * float(jnp.abs(want).max()))
+
+
+def _dots(jaxpr):
+    """Every `dot_general` of a jaxpr and of the jaxprs its equations
+    hold (a `pallas_call`'s kernel, a `pjit`'s body)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn)
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                found += _dots(inner)
+    return found
+
+
+@pytest.mark.parametrize("phase", ("forward", "backward"))
+def test_the_statistics_stay_float32_under_a_bfloat16_dtype(phase):
+    """`dtype` rounds the operands `_chunked` rounds — eight products of
+    the forward kernel — and nothing else: every product accumulates in
+    float32, the solve's (two a round from the second on) and its
+    cotangent's take float32 operands at the highest precision, the
+    carried state's scratch, the saved states and the output are
+    float32."""
+    chunk, rounds = 16, 4
+    ops = draw(7, 32, 2, 8, 24)
+
+    def fn(*a):
+        return dr._chunk_kernels(*a, chunk, jnp.bfloat16, 1, interpret=True,
+                                 chunks_a_step=1)
+
+    if phase == "forward":
+        jaxpr = jax.make_jaxpr(fn)(*ops)
+        assert jaxpr.out_avals[0].dtype == jnp.float32
+        want_rounded, want_exact = 8, 2 * (rounds - 1)
+    else:
+        jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(fn, *a)[1](
+            jnp.ones(ops[2].shape, jnp.float32)))(*ops)
+        # the forward kernel again (it saves the states), then the
+        # backward: the rebuilt tiles' 8 less P U and Kd^T U, 16 of the
+        # cotangents'; the solve again and the two of -T^T dT T^T
+        want_rounded = 8 + 6 + 16
+        want_exact = 2 * (rounds - 1) * 2 + 2
+    dots = _dots(jaxpr.jaxpr)
+    rounded = [d for d in dots
+               if all(v.aval.dtype == jnp.bfloat16 for v in d.invars)]
+    exact = [d for d in dots if d not in rounded]
+    assert (len(rounded), len(exact)) == (want_rounded, want_exact)
+    for d in dots:
+        assert d.outvars[0].aval.dtype == jnp.float32
+    for d in exact:
+        assert all(v.aval.dtype == jnp.float32 for v in d.invars)
+        assert d.params["precision"] == (jax.lax.Precision.HIGHEST,) * 2
+
+
+def test_the_rounded_operands_are_the_chunked_forms():
+    """Under a bfloat16 `dtype` the kernel route is the chunked form to
+    bfloat16's rounding over several chunks (the carry is applied, where
+    `_chunked` composes (dk x dk) transitions), and at ONE chunk — the
+    same operands rounded at the same places, no entering state, the sums
+    in the same order — bit for bit.  (One batch row of three heads: a
+    shape whose bfloat16 products the CPU runs.)"""
+    ops = draw(8, 64, 3, 8, 24, b=1)
+    for chunk, tol in ((16, 2.0 ** -7), (64, 0.0)):
+        got = jax.jit(_kernels(chunk, jnp.bfloat16, 3))(*ops)
+        want = jax.jit(lambda *a: dr._chunked(
+            *a, chunk, jnp.bfloat16))(*ops)  # noqa: B023
+        assert _off(got, want) <= tol, chunk
+
+
+@pytest.mark.parametrize("heads,hb", [(15, 5), (30, 5), (3, 3), (4, 4),
+                                      (7, 1), (16, 4)])
+def test_a_block_of_heads_divides_the_heads_evenly(heads, hb):
+    """The plan is the kernel's own, from shapes: a grid step takes the
+    largest divisor of the heads up to five, so no block is ragged — a
+    block that does not divide the heads is never asked of the kernels,
+    and `_kernel_operands` could not lay one out."""
+    assert dr._heads_block(heads) == hb and heads % hb == 0
+    ops = draw(1, 32, 3, 8, 24, b=1)
+    with pytest.raises(TypeError, match="reshape"):
+        dr._chunk_kernels(*ops, 16, jnp.float32, 2, interpret=True)
+
+
+def test_the_kernels_block_keys_and_values_at_their_own_widths():
+    """Keys of 96 and values of 192 are not padded in HBM: the kernels'
+    operands are the head-major arrays at dk and dv, the saved states
+    (dk x dv), and `product_lanes` says so."""
+    ops = draw(2, 128, 2, 96, 192, b=1)
+    jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(_kernels(
+        64, jnp.bfloat16, 2), *a))(*ops)
+    shapes = {tuple(v.aval.shape) for eqn in jaxpr.jaxpr.eqns
+              for v in (*eqn.invars, *eqn.outvars) if hasattr(v, "aval")}
+    assert (1, 2, 128, 96) in shapes and (1, 2, 128, 192) in shapes
+    assert (1, 2, 2, 96, 192) in shapes                  # entering states
+    assert {s[-1] for s in shapes if s[:-1] == (1, 2, 128)} == {96, 192}
+    assert dr.product_lanes(96, 192) == (288, 288)

@@ -556,6 +556,71 @@ def test_a_mixer_on_a_mesh_of_several_devices_keeps_the_plain_scan(
     assert len(_pallas_calls(_mixer_grad_jaxpr(one).jaxpr)) == 2
 
 
+def _two_devices():
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:2]), ("fsdp",))
+
+
+@pytest.mark.parametrize("on_tpu,shape,mesh,route", [
+    # olmo_hybrid_7b.steady: one sequence of 8,192 = 128 chunks of 64,
+    # fifteen heads held, keys of 96, values of 192, one device
+    (True, (8192, 64, 15, 96, 192), None, ("kernel", 5)),
+    (True, (8192, 64, 15, 96, 192), _two_devices, "chunked"),
+    (False, (8192, 64, 15, 96, 192), None, "chunked"),   # every CPU run
+    (True, (8192 + 32, 64, 15, 96, 192), None, "sequential"),   # ragged
+    (True, (16, 64, 15, 96, 192), None, "sequential"),   # a parameter draw
+    (True, (8192, 64, 30, 96, 192), None, ("kernel", 5)),  # all the heads
+    (True, (8192, 64, 7, 96, 192), None, ("kernel", 1)),
+    (True, (64, 16, 3, 8, 24), None, "chunked"),         # the nano widths
+    (True, (8192, 8, 15, 96, 192), None, "chunked"),     # chunk off a tile
+    (True, (8192, 64, 15, 320, 192), None, "chunked"),   # keys too wide
+    (True, (8192, 2048, 15, 256, 256), None, "chunked"),  # tiles over VMEM
+])
+def test_the_delta_rules_route_is_its_shapes_the_backend_and_the_mesh(
+        monkeypatch, on_tpu, shape, mesh, route):
+    """`ops/delta_rule.delta_route(t, chunk, heads, dk, dv, mesh)`: the
+    static counter of which calls run `dwt_gdr_fwd` / `dwt_gdr_bwd`, and
+    with how many heads a grid step."""
+    from dlrover_wuqiong_tpu.ops import delta_rule as dr
+
+    monkeypatch.setattr(dr, "_on_tpu", lambda: on_tpu)
+    assert dr.delta_route(*shape, mesh and mesh()) == route
+
+
+def _delta_mixer_grad_jaxpr(mesh):
+    from dlrover_wuqiong_tpu.models.gated_delta import (
+        GatedDeltaConfig, GatedDeltaMixer)
+
+    cfg = GatedDeltaConfig(hidden_size=64, num_heads=6, key_dim=32,
+                           value_dim=64, chunk_size=16, mesh=mesh)
+    mixer = GatedDeltaMixer(cfg)
+    u = jax.ShapeDtypeStruct((2, 256, 64), jnp.bfloat16)
+    params = jax.eval_shape(lambda: mixer.init(
+        jax.random.PRNGKey(0), jnp.zeros(u.shape, u.dtype))["params"])
+    return jax.make_jaxpr(jax.grad(lambda p, u: mixer.apply(
+        {"params": p}, u).astype(jnp.float32).sum()))(params, u)
+
+
+def test_a_delta_mixer_takes_the_kernels_on_one_tpu_device_only(
+        monkeypatch):
+    """One forward and one backward kernel a mixer, over (batch rows,
+    blocks of heads, steps): 2 x 2 x 2 at six heads, three a step, and
+    sixteen chunks of 16, eight side by side a step; on a
+    mesh of two devices (`GatedDeltaConfig.mesh`, handed on by
+    `OlmoHybridConfig.linear_config`) and off the TPU none."""
+    from dlrover_wuqiong_tpu.models.olmo_hybrid import OlmoHybridConfig
+    from dlrover_wuqiong_tpu.ops import delta_rule as dr
+
+    two = _two_devices()
+    assert OlmoHybridConfig.nano(mesh=two).linear_config().mesh is two
+    assert _pallas_calls(_delta_mixer_grad_jaxpr(None).jaxpr) == []
+    monkeypatch.setattr(dr, "_on_tpu", lambda: True)
+    assert sorted(_pallas_calls(_delta_mixer_grad_jaxpr(None).jaxpr)) == [
+        ("dwt_gdr_bwd", (2, 2, 2)), ("dwt_gdr_fwd", (2, 2, 2))]
+    assert _pallas_calls(_delta_mixer_grad_jaxpr(two).jaxpr) == []
+
+
 @pytest.mark.parametrize("dtype,sha", [
     (jnp.float32,
      "8a984bb94ddcdc4f436c9c961286e605a9f18602ff27cf5058797de82fab862f"),

@@ -302,6 +302,8 @@ def _one_chip_step(topo, name, model_file):
         mp.setattr(ssd, "_on_tpu", lambda: True)
         mp.setattr(gm, "_on_tpu", lambda: True)
         mp.setattr(rope, "_on_tpu", lambda: True)
+        mp.setattr("dlrover_wuqiong_tpu.ops.delta_rule._on_tpu",
+                   lambda: True)
         res = auto_accelerate(
             model, strategy=[("fsdp", {})], devices=topo.devices[:1],
             optimizer=optax.chain(optax.clip_by_global_norm(1.0),

@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
-model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers] ...  (no args = step/attn/head/model/opt).  One JSON line
+model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -36,6 +36,11 @@ share cells' shapes: each line that indexed T*k single numbers (a
 `bincount`, a gather through a sort or its inverse, `take_along_axis`
 and its transpose) against the compare-and-sum, the sort operand or the
 select `models/moe.py` runs in its place.
+`delta` reads the same way the gated delta rule at the Olmo hybrid
+cell's shape, forward and forward + backward: the chunked `jax.numpy`
+form against `dwt_gdr_fwd` / `dwt_gdr_bwd` (`ops/delta_rule.py`) at 1 /
+3 / 5 / 15 heads and one or two chunks a grid step, with each one's
+distance from the chunked form.
 """
 
 from __future__ import annotations
@@ -856,6 +861,51 @@ def probe_rope():
                                "device_ops_ms": _device_ops_ms(f, *args)})
 
 
+def probe_delta(plans=((5, 1), (1, 2), (3, 2), (5, 2), (15, 2)),
+                shape=(1, 8192, 15, 96, 192, 64), interpret=False):
+    """The gated delta rule at `olmo_hybrid_7b.steady`'s shape (1 x 8192,
+    fifteen heads, keys of 96, values of 192, chunk 64, bfloat16
+    products), forward and forward + backward: the chunked `jax.numpy`
+    form against `dwt_gdr_fwd` / `dwt_gdr_bwd` at several (heads, chunks)
+    a grid step, each with the kernel route's largest relative distance
+    from the chunked form (PERF.md section 6, PR 48)."""
+    from dlrover_wuqiong_tpu.ops import delta_rule as dr
+
+    b, t, h, dk, dv, chunk = shape
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    q = jax.random.normal(ks[0], (b, t, h, dk))
+    k = jax.random.normal(ks[1], (b, t, h, dk))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / dk ** 0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, t, h, dv), jnp.bfloat16)
+    g = -0.2 * jax.nn.softplus(jax.random.normal(ks[3], (b, t, h)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    d_out = jax.random.normal(ks[5], (b, t, h, dv))
+    args = (q, k, v, g, beta)
+
+    def both(fn):
+        return jax.jit(lambda *a: (fn(*a), *jax.vjp(fn, *a)[1](d_out)))
+
+    def chunked(*a):
+        return dr._chunked(*a, chunk, jnp.bfloat16)
+
+    want = both(chunked)(*args)
+    cases = [("chunked", chunked, None)] + [
+        ("dwt_gdr", functools.partial(
+            lambda plan, *a: dr._chunk_kernels(
+                *a, chunk, jnp.bfloat16, plan[0], interpret, plan[1]),
+            plan), plan) for plan in plans]
+    for name, fn, plan in cases:
+        off = [float(jnp.abs(x.astype(jnp.float32) - y.astype(jnp.float32)
+                             ).max() / jnp.abs(y.astype(jnp.float32)).max())
+               for x, y in zip(both(fn)(*args), want)]
+        for what, f in ((name, jax.jit(fn)), (name + "_fwd_bwd", both(fn))):
+            _emit_raw({"probe": "delta", "what": what,
+                       "heads_and_chunks_a_step": plan,
+                       "off_o_dq_dk_dv_dg_dbeta": [round(x, 6) for x in off],
+                       "device_ops_ms": _device_ops_ms(f, *args, top=6)})
+
+
 ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "attn_sweep": probe_attn_sweep,
        "attn_direct": probe_attn_direct, "lib": probe_lib,
@@ -864,7 +914,8 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "head": probe_head, "model": probe_model, "opt": probe_opt,
        "step": probe_step, "dispatch": probe_dispatch,
        "rpc": probe_rpc, "gmm": probe_gmm, "rows_map": probe_rows_map,
-       "rope": probe_rope, "moe_numbers": probe_moe_numbers}
+       "rope": probe_rope, "moe_numbers": probe_moe_numbers,
+       "delta": probe_delta}
 
 
 def main(argv=None) -> int:
