@@ -250,12 +250,6 @@ class ElasticAgent:
             NodeEnv.LOCAL_DEVICE_COUNT: str(outcome.local_world_size),
             NodeEnv.RESTART_COUNT: str(self._restart_count),
         })
-        # trace context crosses the process boundary via env: the worker's
-        # spans (restore tiers, rpc verbs) parent under this agent's trace
-        from ..telemetry import spans as tspans
-
-        with tspans.env_context() as span_env:
-            env.update(span_env)
         env.setdefault("DWT_PROC_ROLE", "trainer")
         # one compile-cache dir across worker generations and warm
         # children: the restarted worker must read what the pool wrote
@@ -287,9 +281,19 @@ class ElasticAgent:
             log_path = os.path.join(
                 log_dir, f"{prune_prefix}r{self._restart_count}.stderr")
             stderr = open(log_path, "ab")
-        proc = subprocess.Popen(
-            self.entrypoint, env=env, stdout=stdout, stderr=stderr,
-            start_new_session=True)
+        # trace context crosses the process boundary via env: the worker's
+        # spans (its `proc:boot`, restore tiers, rpc verbs) parent under
+        # this span of the agent's, so a restart is one tree
+        from ..telemetry import spans as tspans
+
+        with tspans.span("agent:launch_worker",
+                         {"restart_count": self._restart_count}) as rec, \
+                tspans.env_context() as span_env:
+            env.update(span_env)
+            proc = subprocess.Popen(
+                self.entrypoint, env=env, stdout=stdout, stderr=stderr,
+                start_new_session=True)
+            rec["attrs"]["worker_pid"] = proc.pid
         # the child holds its own dups — close the parent copies, or the
         # agent leaks one fd per restart over a long elastic job
         for fh in (stdout, stderr):
@@ -346,7 +350,14 @@ class ElasticAgent:
         devices the old one still holds."""
         if self._worker is None:
             return
-        proc = self._worker.proc
+        from ..telemetry import spans as tspans
+
+        with tspans.span("agent:stop_worker"):
+            self._stop_worker_group(self._worker.proc, timeout)
+        self._worker = None
+
+    @staticmethod
+    def _stop_worker_group(proc: subprocess.Popen, timeout: float):
         pgid = proc.pid
 
         def _signal_group(sig) -> bool:
@@ -368,7 +379,6 @@ class ElasticAgent:
             while _signal_group(0) and \
                     time.monotonic() < deadline + 10:
                 time.sleep(0.05)
-        self._worker = None
 
     def _start_heartbeat(self):
         def _loop():
@@ -441,71 +451,94 @@ class ElasticAgent:
         self._config_tuner.start()
         self.mc.register_node(self.node_rank,
                               accelerator_num=self.config.nproc_per_node)
-        while not self._stopped.is_set():
-            outcome = self.rendezvous()
-            if self._saver is not None:
-                # commit must wait for EVERY rank's done-file — tell the saver
-                # the current world size (reference ckpt_saver.py:863).  Ranks
-                # are re-assigned each rendezvous (compacted on scale-down),
-                # so the saver's committer/global-rank identity must follow.
-                # Routed through the event queue: applies on the saver thread,
-                # never racing an in-flight save.
-                from ..checkpoint.ckpt_saver import CheckpointEvent
+        try:
+            while not self._stopped.is_set():
+                # one span a turn of the loop: a worker generation, from
+                # its rendezvous to what its exit set off
+                with tspans.span(
+                        "agent:generation",
+                        {"restart_count": self._restart_count}) as rec:
+                    code = self._run_generation(rec["attrs"])
+                if code is not None:
+                    return code
+            return 1
+        finally:
+            if self._restart_count:
+                # the restarts as whole trees (the fault's own dump was
+                # written before the agent had dealt with it)
+                self._flush_flight("agent-exit")
 
-                self._saver._event_queue.put(CheckpointEvent.update_world(
-                    outcome.num_processes, outcome.process_id))
-            try:
+    def _run_generation(self, attrs: Dict) -> Optional[int]:
+        """One turn of the supervisor loop.  The code `run` returns, or
+        None to go round again."""
+        from ..telemetry import spans as tspans
+
+        outcome = self.rendezvous()
+        if self._saver is not None:
+            # commit must wait for EVERY rank's done-file — tell the saver
+            # the current world size (reference ckpt_saver.py:863).  Ranks
+            # are re-assigned each rendezvous (compacted on scale-down),
+            # so the saver's committer/global-rank identity must follow.
+            # Routed through the event queue: applies on the saver thread,
+            # never racing an in-flight save.
+            from ..checkpoint.ckpt_saver import CheckpointEvent
+
+            self._saver._event_queue.put(CheckpointEvent.update_world(
+                outcome.num_processes, outcome.process_id))
+        try:
+            with tspans.span("agent:replication_setup"):
                 self._setup_replication(outcome)
-                if self._replica_manager is not None:
-                    self._saver.post_save_hook = \
-                        lambda step: self._replica_manager.backup()
-            except Exception:  # noqa: BLE001 — replication is best-effort
-                logger.exception("checkpoint replication setup failed")
-            self._worker = self._launch_worker(outcome)
-            self._kick_warm_pool(outcome)
-            exit_code = self._monitor_worker()
-            if exit_code == 0:
-                logger.info("worker succeeded")
-                return 0
-            if exit_code is None:
-                # membership change → restart workers into a new world
-                logger.info("membership change — restarting worker")
-                self._stop_worker()
-                continue
-            # failure path
-            logger.warning("worker failed with exit code %s", exit_code)
-            self._flush_flight("worker-fault")
-            if self._saver is not None:
-                try:
-                    self._saver.save_shm_to_storage()
-                except Exception:  # noqa: BLE001
-                    logger.exception("failure-save failed")
-            # normalize Python's negative signal codes to shell style
-            # (-9 → 137) so the master's error catalogue can classify
-            # signal deaths (SIGKILL=OOM-kill, SIGTERM=preemption)
-            report_code = 128 - exit_code if exit_code < 0 else exit_code
-            error_data = f"exit_code={report_code}"
-            tail = self._worker_log_tail()
-            if tail:
-                error_data += "\n" + tail
-                # stderr is captured to a file now — echo the tail so local
-                # runs still show the traceback on the console
-                logger.error("worker stderr tail:\n%s", tail[-1500:])
-            resp = self.mc.report_failure(error_data,
-                                          restart_count=self._restart_count)
-            if resp is not None and not getattr(resp, "success", True):
-                # master's error catalogue says restarts can't fix this
-                # class (e.g. user-code error) — stop burning restarts
-                logger.error("master: %s — not restarting",
-                             getattr(resp, "reason", ""))
-                return exit_code
-            self._restart_count += 1
-            if self._restart_count > self.config.max_restarts:
-                logger.error("max restarts (%d) exhausted",
-                             self.config.max_restarts)
-                return exit_code
+            if self._replica_manager is not None:
+                self._saver.post_save_hook = \
+                    lambda step: self._replica_manager.backup()
+        except Exception:  # noqa: BLE001 — replication is best-effort
+            logger.exception("checkpoint replication setup failed")
+        self._worker = self._launch_worker(outcome)
+        self._kick_warm_pool(outcome)
+        exit_code = attrs["exit_code"] = self._monitor_worker()
+        if exit_code == 0:
+            logger.info("worker succeeded")
+            return 0
+        if exit_code is None:
+            # membership change → restart workers into a new world
+            logger.info("membership change — restarting worker")
             self._stop_worker()
-        return 1
+            return None
+        # failure path
+        logger.warning("worker failed with exit code %s", exit_code)
+        self._flush_flight("worker-fault")
+        if self._saver is not None:
+            try:
+                with tspans.span("agent:failure_save"):
+                    self._saver.save_shm_to_storage()
+            except Exception:  # noqa: BLE001
+                logger.exception("failure-save failed")
+        # normalize Python's negative signal codes to shell style
+        # (-9 → 137) so the master's error catalogue can classify
+        # signal deaths (SIGKILL=OOM-kill, SIGTERM=preemption)
+        report_code = 128 - exit_code if exit_code < 0 else exit_code
+        error_data = f"exit_code={report_code}"
+        tail = self._worker_log_tail()
+        if tail:
+            error_data += "\n" + tail
+            # stderr is captured to a file now — echo the tail so local
+            # runs still show the traceback on the console
+            logger.error("worker stderr tail:\n%s", tail[-1500:])
+        resp = self.mc.report_failure(error_data,
+                                      restart_count=self._restart_count)
+        if resp is not None and not getattr(resp, "success", True):
+            # master's error catalogue says restarts can't fix this
+            # class (e.g. user-code error) — stop burning restarts
+            logger.error("master: %s — not restarting",
+                         getattr(resp, "reason", ""))
+            return exit_code
+        self._restart_count += 1
+        if self._restart_count > self.config.max_restarts:
+            logger.error("max restarts (%d) exhausted",
+                         self.config.max_restarts)
+            return exit_code
+        self._stop_worker()
+        return None
 
     def _kick_warm_pool(self, outcome: RendezvousOutcome,
                         spec_wait_s: float = 120.0):
@@ -561,10 +594,17 @@ class ElasticAgent:
 
         Returns exit code, or None when a re-rendezvous is needed.
         """
+        from ..telemetry import spans as tspans
+
         proc = self._worker.proc
         while not self._stopped.is_set():
             code = proc.poll()
             if code is not None:
+                # the poll SAW the exit here; the exit itself lies up to
+                # one interval back (bounded by it, not measured)
+                tspans.span_event("agent:worker_exit", {
+                    "exit_code": code,
+                    "poll_interval_s": self.config.monitor_interval})
                 return code
             if self._membership_changed():
                 return None

@@ -107,13 +107,21 @@ def run_check_child(timeout: float = 300.0) -> Tuple[bool, float]:
     prints its verdict as one JSON line and exits — the devices are free
     again before this returns.  A child that dies or hangs is an
     unhealthy node."""
+    from ..telemetry import spans as tspans
+
     try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "dlrover_wuqiong_tpu.agent.node_check"],
-            capture_output=True, text=True, timeout=timeout)
-        out = json.loads(proc.stdout.strip().splitlines()[-1])
-        logger.info("node check child: healthy=%s elapsed=%.3fs",
-                    out["healthy"], out["elapsed"])
+        # the child's life under one span of the agent's; of what lies
+        # in it the child reports its backend's start (`attach_s`)
+        with tspans.span("agent:node_check") as rec:
+            proc = subprocess.run(
+                [sys.executable, "-m",
+                 "dlrover_wuqiong_tpu.agent.node_check"],
+                capture_output=True, text=True, timeout=timeout)
+            out = json.loads(proc.stdout.strip().splitlines()[-1])
+            rec["attrs"].update(out)
+        logger.info("node check child: healthy=%s elapsed=%.3fs "
+                    "attach=%.3fs", out["healthy"], out["elapsed"],
+                    out.get("attach_s", 0.0))
         return bool(out["healthy"]), float(out["elapsed"])
     except (subprocess.TimeoutExpired, ValueError, IndexError, KeyError):
         logger.exception("node check child failed")
@@ -148,6 +156,14 @@ def run_network_check(agent, rounds: int = 2,
 
 
 if __name__ == "__main__":
+    import jax
+
+    from ..telemetry import spans as tspans
+
+    # the child's first touch of the devices, apart from the probes
+    with tspans.backend_attach("devices") as _attach:
+        jax.devices()
     _healthy, _elapsed = run_check_workload()
-    print(json.dumps({"healthy": _healthy, "elapsed": _elapsed}),
+    print(json.dumps({"healthy": _healthy, "elapsed": _elapsed,
+                      "attach_s": _attach["dur_s"] if _attach else 0.0}),
           flush=True)

@@ -71,8 +71,11 @@ counters = CacheCounters()
 # cache, whichever served it) and, inside the latter, `jax:cache_load`
 # (the retrieval alone).  The first dispatch of the step is made of
 # these; a set-up reader sums the ones with the step's `fun_name`.  A
-# ring of its own (drop-oldest): eager helpers emit hundreds of them and
-# must not push a control-plane span out of telemetry/spans.py's buffer.
+# ring of its own (drop-oldest): eager helpers emit thousands of them
+# (a set-up of the 124M model writes 8,400, Kimi's 15,300, XL's more
+# than the 16,384 this ring held before PR 49, whose readers of set-up
+# then read low in silence) and must not push a control-plane span out
+# of telemetry/spans.py's buffer.
 _DURATION_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "jax:trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax:lower",
@@ -80,10 +83,35 @@ _DURATION_EVENTS = {
     "/jax/compilation_cache/cache_retrieval_time_sec": "jax:cache_load",
 }
 durations: "collections.deque[Dict[str, Any]]" = collections.deque(
-    maxlen=16384)
+    maxlen=65536)
 _unnamed = threading.local()
 _listeners_installed = False
 _enabled_dir: Optional[str] = None
+
+
+def seconds_between(t0: float, t1: float) -> Dict[str, float]:
+    """Seconds the `durations` records (every function's) cover inside
+    `t0` .. `t1` on `time.monotonic()`, by kind: `trace_s`, `lower_s`,
+    `backend_compile_s`, `cache_load_s` — what a first dispatch is made
+    of.  Covered, not summed: a function traced inside another's trace
+    has a record of its own, which lies within the outer one."""
+    spans: Dict[str, list] = {short: [] for short in
+                              _DURATION_EVENTS.values()}
+    for rec in reversed(list(durations)):
+        end = rec["t_mono"] + rec["dur_s"]
+        if end < t0:
+            break  # appended as they end: the rest ended earlier still
+        if rec["t_mono"] >= t0 and end <= t1:
+            spans[rec["name"]].append((rec["t_mono"], end))
+    out = {}
+    for short, ivals in spans.items():
+        covered, reach = 0.0, t0
+        for lo, hi in sorted(ivals):
+            covered += max(0.0, hi - max(lo, reach))
+            reach = max(reach, hi)
+        out[short.removeprefix("jax:") + "_s"] = covered
+    return out
+
 
 CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT_CACHE_DIR = os.path.join(

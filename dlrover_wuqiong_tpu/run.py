@@ -74,8 +74,12 @@ def run(args: argparse.Namespace) -> int:
     local_master = None
     use_standalone = args.standalone or not master_addr
     if use_standalone:
-        local_master = _launch_local_master(min_nodes, max_nodes,
-                                            args.node_unit)
+        from .telemetry import spans as tspans
+
+        # the local master's start and the wait until it listens
+        with tspans.span("cli:master"):
+            local_master = _launch_local_master(min_nodes, max_nodes,
+                                                args.node_unit)
         master_addr = local_master.addr
         os.environ[NodeEnv.MASTER_ADDR] = master_addr
         logger.info("standalone: local master at %s", master_addr)

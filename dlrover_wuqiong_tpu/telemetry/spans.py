@@ -40,6 +40,14 @@ under a hot one names it as its parent.
 Child processes spawned mid-span inherit the active context through
 ``DWT_TRACE_ID`` / ``DWT_TRACE_PARENT`` (see `env_context`); the spawned
 side picks them up lazily on its first span.
+
+The start of the process: `process_start()` is when the kernel started
+this process, brought onto both clocks, and `past_span()` writes a full
+record for a stretch that is already over.  Together they let the first
+span of a process (`proc:boot`, written by `boot_span()`) begin where
+the process did — interpreter, imports and the backend's start included
+— so a restart reads as one chain from the agent's `Popen` to the
+worker's first step.
 """
 
 from __future__ import annotations
@@ -52,7 +60,7 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 from .recorder import get_recorder
 
@@ -73,6 +81,12 @@ _HOT_IDS = itertools.count(1)
 _TLS = threading.local()
 
 _ROLE = os.getenv("DWT_PROC_ROLE", "")
+
+#: this module's import, the stand-in for the process's start where the
+#: kernel's own stamp cannot be read
+_IMPORTED = (time.monotonic(), time.time())
+_PROCESS_START: Optional[Tuple[float, float]] = None
+_BOOT_WRITTEN = False
 
 
 def _new_id() -> str:
@@ -163,12 +177,9 @@ def _record(rec: Dict):
     get_recorder().record("span", rec["name"], rec)
 
 
-@contextlib.contextmanager
-def span(name: str, attrs: Optional[Dict] = None):
-    """Open a span; nests under the active one, propagates via frames."""
-    stack = _stack()
-    parent = stack[-1] if stack else None
-    rec = {
+def _new_record(name: str, attrs: Optional[Dict], parent: Optional[Dict],
+                t_wall: float, t_mono: float) -> Dict:
+    return {
         "schema": SPAN_SCHEMA_VERSION,
         "name": name,
         "trace_id": parent["trace_id"] if parent else _new_id(),
@@ -176,12 +187,20 @@ def span(name: str, attrs: Optional[Dict] = None):
         "parent_span": parent.get("span_id", "") if parent else "",
         "role": process_role(),
         "pid": os.getpid(),
-        "t_wall": time.time(),
-        "t_mono": time.monotonic(),
+        "t_wall": t_wall,
+        "t_mono": t_mono,
         "dur_s": 0.0,
         "attrs": dict(attrs or {}),
         "status": "ok",
     }
+
+
+@contextlib.contextmanager
+def span(name: str, attrs: Optional[Dict] = None):
+    """Open a span; nests under the active one, propagates via frames."""
+    stack = _stack()
+    rec = _new_record(name, attrs, stack[-1] if stack else None,
+                      time.time(), time.monotonic())
     stack.append({"trace_id": rec["trace_id"], "span_id": rec["span_id"]})
     ann = _annotation(name)
     try:
@@ -195,6 +214,92 @@ def span(name: str, attrs: Optional[Dict] = None):
             ann.__exit__(None, None, None)
         stack.pop()
         _record(rec)
+
+
+def past_span(name: str, t0: float, t1: float,
+              attrs: Optional[Dict] = None,
+              beside: Optional[Dict] = None) -> Dict:
+    """Write a span for a stretch that is over: `t0` .. `t1` on
+    `time.monotonic()`.  The same record as `span()`'s, into the same
+    buffer and the flight recorder; no profiler annotation, since there
+    is nothing left to annotate.  It hangs under the thread's active
+    span — or, given `beside`, under that record's parent and in its
+    trace: the stretch that ended where `beside` began."""
+    if beside is not None:
+        parent = {"trace_id": beside["trace_id"],
+                  "span_id": beside["parent_span"]}
+    else:
+        stack = _stack()
+        parent = stack[-1] if stack else None
+    to_wall = time.time() - time.monotonic()  # graftlint: disable=wall-clock-duration -- the wall/monotonic anchor of a past start, not elapsed-time math
+    rec = _new_record(name, attrs, parent, t0 + to_wall, t0)
+    rec["dur_s"] = t1 - t0
+    _record(rec)
+    return rec
+
+
+def process_start() -> Tuple[float, float]:
+    """(t_mono, t_wall) of this process's start as the kernel has it:
+    `/proc/self/stat`'s start time (field 22, clock ticks since boot)
+    against `CLOCK_BOOTTIME`, brought onto `time.monotonic()` and the
+    wall.  Where that cannot be read (no `/proc`), this module's import.
+    Read once."""
+    global _PROCESS_START
+    if _PROCESS_START is None:
+        start = _IMPORTED
+        try:
+            with open("/proc/self/stat") as f:
+                # the command (field 2) may hold spaces and brackets
+                fields = f.read().rpartition(")")[2].split()
+            age = time.clock_gettime(time.CLOCK_BOOTTIME) \
+                - int(fields[19]) / os.sysconf("SC_CLK_TCK")
+            now_mono, now_wall = time.monotonic(), time.time()
+            # the tick is 10 ms: a start "after" the import is rounding
+            start = (min(now_mono - age, _IMPORTED[0]),
+                     min(now_wall - age, _IMPORTED[1]))
+        except (OSError, ValueError, IndexError, AttributeError):
+            pass
+        _PROCESS_START = start
+    return _PROCESS_START
+
+
+def backend_attached() -> bool:
+    """Whether a JAX backend stands in this process.  Asked only where
+    JAX is imported already (the agent and the master never load it);
+    `xla_bridge` is private, as `compile_cache`'s listeners are."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
+def boot_span(beside: Dict) -> Optional[Dict]:
+    """`proc:boot`, once a process: from `process_start()` to where
+    `beside` (the first span of the program proper, `trainer:build`)
+    began — the interpreter, the imports, the caller's own preparation
+    and the backend's start by whoever made it, which
+    `backend_attached_by` names: the `caller` if a backend stands
+    already, else the `program` (its own `backend:attach` follows)."""
+    global _BOOT_WRITTEN
+    if _BOOT_WRITTEN:
+        return None
+    _BOOT_WRITTEN = True
+    by = "caller" if backend_attached() else "program"
+    return past_span("proc:boot", process_start()[0], beside["t_mono"],
+                     {"backend_attached_by": by}, beside=beside)
+
+
+@contextlib.contextmanager
+def backend_attach(via: str) -> Iterator[Optional[Dict]]:
+    """`backend:attach` around the program's own first touch of the
+    devices (`via` says which call); no span, and None, where a backend
+    stands."""
+    if backend_attached():
+        yield None
+        return
+    with span("backend:attach", {"via": via}) as rec:
+        yield rec
 
 
 class hot_span:

@@ -192,10 +192,13 @@ def init_elastic(connect_master: bool = True) -> ElasticContext:
         logger.info("jax.distributed.initialize(coord=%s, n=%d, id=%d)",
                     world.coordinator_addr, world.num_processes,
                     world.process_id)
-        jax.distributed.initialize(
-            coordinator_address=world.coordinator_addr,
-            num_processes=world.num_processes,
-            process_id=world.process_id)
+        from ..telemetry import spans as tspans
+
+        with tspans.backend_attach("distributed_initialize"):
+            jax.distributed.initialize(
+                coordinator_address=world.coordinator_addr,
+                num_processes=world.num_processes,
+                process_id=world.process_id)
     mc = None
     master_addr = os.getenv(NodeEnv.MASTER_ADDR, "")
     if connect_master and master_addr:

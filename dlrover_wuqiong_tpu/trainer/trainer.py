@@ -23,6 +23,7 @@ exactly where the unfused loop would have fired them.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -258,8 +259,11 @@ class Trainer:
                  optimizer=None, loss_fn: Optional[Callable] = None,
                  callbacks: Optional[list] = None):
         # construction is a large share of a restart (the plan, a sharded
-        # state init, the checkpoint engine): its own span and children
-        with tspans.span("trainer:build"):
+        # state init, the checkpoint engine): its own span and children.
+        # What the process did before it got here is `proc:boot`, which
+        # ends on this span's start.
+        with tspans.span("trainer:build") as rec:
+            tspans.boot_span(beside=rec)
             self._build(model, args, train_data, eval_data, optimizer,
                         loss_fn, callbacks)
 
@@ -744,10 +748,20 @@ class Trainer:
     # ---------------------------------------------------------------- train
 
     def train(self) -> Dict[str, float]:
+        # every `trainer:iteration` and `ckpt:restore:*` hangs under
+        # this span.  A fault ends it where it is caught (`end_span`),
+        # so the flight dump written there holds the chain to its end.
+        with contextlib.ExitStack() as scope:
+            rec = scope.enter_context(tspans.span("trainer:train"))
+            return self._train(rec, scope.close)
+
+    def _train(self, span_rec: Dict[str, Any],
+               end_span: Callable[[], None]) -> Dict[str, float]:
         import signal as _signal
 
         import jax
 
+        from ..auto.compile_cache import seconds_between
         from ..telemetry.ledger import get_ledger
         from ..telemetry.perf import keep_step_executable
         from ..telemetry.recorder import get_recorder
@@ -756,6 +770,7 @@ class Trainer:
         led = get_ledger()
         led.start()
         start_step = 0
+        restored_tier = ""
         # rollback rework ceiling: steps below this were trained before a
         # loss-spike rollback and are re-executed ("rework", not goodput)
         self._rework_until = -1
@@ -778,6 +793,7 @@ class Trainer:
                 if rb >= 0:
                     self._rework_until = rb
                 rep = self.ckpt.last_restore_report
+                restored_tier = str(rep.get("tier", ""))
                 logger.info("resumed from step %d (tier=%s%s)", start_step,
                             rep.get("tier", "?"),
                             ", degraded" if rep.get("fallbacks") else "")
@@ -792,6 +808,8 @@ class Trainer:
                         f"fallbacks={rep.get('fallbacks')}",
                         level="warning")
 
+        span_rec["attrs"].update(start_step=start_step,
+                                 restored_tier=restored_tier)
         last_loss = float("nan")
         metrics = None
         self._preempted = False
@@ -924,6 +942,20 @@ class Trainer:
                         self._compiled_modes.add(k_eff)
                         led.account("compile", blk_s)
                         credited_blk = blk_s
+                        # once a width: the dispatch call, and what JAX
+                        # spent inside it getting programs ready
+                        tspans.past_span(
+                            "trainer:first_step", t_blk0, t_blk0 + blk_s,
+                            {"k": k_eff, "blk_s": blk_s,
+                             **seconds_between(t_blk0, t_blk0 + blk_s)})
+                        if self.ctx.world.restart_count and \
+                                len(self._compiled_modes) == 1:
+                            # a restarted generation leaves its start-up
+                            # chain beside the checkpoints: the worker's
+                            # half of the restart's tree
+                            # (tools/incident_report.py --restart-table)
+                            get_recorder().flush(
+                                self.ckpt.checkpoint_dir, "resumed")
                         # for a reader of the step's text, afterwards
                         # (telemetry/perf.py): shapes and shardings only,
                         # no array is kept; ~2 ms at 1,740 leaves, once
@@ -1003,7 +1035,11 @@ class Trainer:
                             "exiting", step)
         except BaseException:
             # fault flight dump: ring buffer + ledger snapshot land next
-            # to the checkpoints so post-mortem tooling finds them
+            # to the checkpoints so post-mortem tooling finds them —
+            # `trainer:train` among them, ended here
+            span_rec["status"] = "error"
+            span_rec["attrs"]["stopped_at"] = step
+            end_span()
             get_recorder().flush(self.ckpt.checkpoint_dir, "fault")
             raise
         finally:
@@ -1040,6 +1076,7 @@ class Trainer:
             self.profiler.close()
         if last_loss != last_loss and metrics is not None:
             last_loss = float(metrics["loss"])  # only short runs never log
+        span_rec["attrs"]["stopped_at"] = step
         return {"final_step": a.max_steps, "final_loss": last_loss,
                 "stopped_at": step}
 

@@ -28,6 +28,23 @@ summary): ``--events-out FILE`` writes the canonical incident JSON;
 ``--perfetto FILE`` writes a chrome://tracing / Perfetto trace of the
 whole incident (spans from every process + journal instants).
 
+The operator's reader of ONE restart, as text and not as a JSON line:
+
+    python tools/incident_report.py --restart-table CKPT_DIR
+
+prints, for every worker generation in the flight dumps under
+``CKPT_DIR/flight/`` whose exit the agent saw with a code other than 0,
+the restart as one indented table — span, process role, start relative
+to ``agent:worker_exit``, seconds — from the agent's handling of the
+exit (``agent:failure_save``, the ``rpc:report`` of the failure,
+``agent:stop_worker``) through the next generation's rendezvous and
+``agent:launch_worker`` down to the worker's ``proc:boot``,
+``trainer:build``, ``ckpt:restore:*`` and ``trainer:first_step`` (with
+the seconds JAX spent inside it by kind).  The agent writes its half at
+exit (``agent-exit``), a restarted worker its own once its first step is
+dispatched (``resumed``).  rc=1 and a message on stderr where the dumps
+hold no such generation.
+
 Summary fields: source bookkeeping, event/span/trace/epoch/process
 counts, incidents with per-incident lost seconds, goodput_fraction,
 and timeline_sha256.  Exit/error contract matches the other report
@@ -134,8 +151,132 @@ def _from_master(addr: str, vals: dict) -> dict:
                                      "ckpt_dir": ckpt, **gauges})
 
 
+# ------------------------------------------------- one restart as a table
+
+
+def _flight_spans(ckpt_dir: str) -> dict:
+    """{span_id: record} over every dump, each with `start` on the
+    shared wall (its own monotonic start through the dump's anchor)."""
+    from dlrover_wuqiong_tpu.telemetry import load_flight_dumps
+    from dlrover_wuqiong_tpu.telemetry.timeline import anchored_wall
+
+    spans = {}
+    for dump in load_flight_dumps(ckpt_dir):
+        for evt in dump.get("events") or []:
+            rec = evt.get("data") or {}
+            if evt.get("kind") != "span" or rec.get("span_id") in spans:
+                continue
+            # the record's own clocks: the span's START (the event's are
+            # when it was written)
+            spans[rec["span_id"]] = {**rec, "children": [],
+                                     "start": anchored_wall(dump, rec)}
+    return spans
+
+
+def _link(spans: dict) -> None:
+    """Hang every span under its parent (`children`).  A span
+    whose parent is in no dump (a `trainer:train` still open when its
+    worker flushed, a per-step span of the hot ring) goes to the
+    narrowest span of its process that holds its start, else to the
+    `agent:launch_worker` that started its process."""
+    for rec in sorted(spans.values(), key=lambda r: r["start"]):
+        parent = spans.get(rec.get("parent_span") or "")
+        if parent is None and rec.get("parent_span"):
+            around = [s for s in spans.values() if s is not rec
+                      and s.get("pid") == rec.get("pid")
+                      and s["start"] <= rec["start"]
+                      <= s["start"] + s.get("dur_s", 0.0)]
+            parent = min(around, key=lambda s: s.get("dur_s", 0.0),
+                         default=None) or next(
+                (s for s in spans.values()
+                 if s["name"] == "agent:launch_worker"
+                 and s.get("attrs", {}).get("worker_pid")
+                 == rec.get("pid")), None)
+        if parent is not None:
+            parent["children"].append(rec)
+
+
+def _rows(rec: dict, depth: int, lo: float, hi: float) -> list:
+    """(depth, record) of `rec` and of what lies under it and starts in
+    `lo` .. `hi`.  A call made over and over under one parent (the
+    monitor's poll of the master) keeps its first row; the rest are
+    counted on it (`repeats`)."""
+    out = []
+    if depth == 0 or lo <= rec["start"] <= hi:
+        out.append((depth, rec))
+    seen = {}
+    for child in rec["children"]:
+        kind = (child["name"], child.get("attrs", {}).get("msg"))
+        if kind[1] is not None and kind in seen:
+            if lo <= child["start"] <= hi:
+                seen[kind]["repeats"] = seen[kind].get("repeats", 0) + 1
+            continue
+        rows = _rows(child, depth + 1, lo, hi)
+        if rows and kind[1] is not None:
+            seen[kind] = rows[0][1]
+        out += rows
+    return out
+
+
+def restart_table(ckpt_dir: str) -> str:
+    spans = _flight_spans(ckpt_dir)
+    _link(spans)
+    gens = sorted((s for s in spans.values()
+                   if s["name"] == "agent:generation"),
+                  key=lambda s: s["start"])
+    blocks = []
+    for i, gen in enumerate(gens):
+        exits = [c for c in gen["children"]
+                 if c["name"] == "agent:worker_exit"
+                 and c.get("attrs", {}).get("exit_code")]
+        if not exits:
+            continue
+        t0 = exits[0]["start"]
+        rows = _rows(gen, 0, t0, float("inf"))
+        if i + 1 < len(gens):
+            nxt = gens[i + 1]
+            firsts = [s["start"] + s["dur_s"] for s in spans.values()
+                      if s["name"] == "trainer:first_step"
+                      and s["start"] >= nxt["start"]]
+            rows += _rows(nxt, 0, t0, min(firsts, default=float("inf")))
+        lines = [f"restart {len(blocks) + 1}: generation "
+                 f"{gen['attrs'].get('restart_count')} left with exit code "
+                 f"{exits[0]['attrs']['exit_code']} (seen by a poll every "
+                 f"{exits[0]['attrs'].get('poll_interval_s')} s); starts "
+                 f"are seconds from agent:worker_exit",
+                 f"{'span':<44} {'role':<8} {'start_s':>9} {'seconds':>9}"
+                 f"  attrs"]
+        for depth, rec in rows:
+            attrs = " ".join(
+                f"{k}={v:.3f}" if isinstance(v, float) else f"{k}={v}"
+                for k, v in rec.get("attrs", {}).items())
+            if rec.get("status", "ok") != "ok":
+                attrs = f"status={rec['status']} {attrs}"
+            if rec.get("repeats"):
+                attrs += f" (+{rec['repeats']} more)"
+            name = "  " * depth + rec["name"]
+            lines.append(f"{name:<44} {rec.get('role', ''):<8} "
+                         f"{rec['start'] - t0:>9.3f} "
+                         f"{rec.get('dur_s', 0.0):>9.3f}  {attrs}".rstrip())
+        blocks.append("\n".join(lines))
+    if not blocks:
+        raise LookupError(
+            f"no agent:generation with a failed worker in the flight "
+            f"dumps under {ckpt_dir!r}")
+    return "\n\n".join(blocks)
+
+
 def main(argv=None) -> int:
     from dlrover_wuqiong_tpu.common.report_cli import run_report
+
+    args = list(sys.argv[1:] if argv is None else argv)
+    if "--restart-table" in args:
+        try:
+            print(restart_table(args[args.index("--restart-table") + 1]))
+        except (IndexError, LookupError, OSError) as e:
+            print(f"incident_report: {e!r}", file=sys.stderr)
+            return 1
+        return 0
 
     return run_report(
         argv, __doc__,
