@@ -60,11 +60,19 @@ is empty), and `dwt_rows_map_*` over the same tiles for the activation
 products' row gradients, and the combine's backward pair (the weighted
 cotangent and <row, cotangent>).  The gathers INTO expert order follow
 the held rows too on that route (`dispatch`: a loop over chunks of the
-held rows, forward, recomputed and for the combine's cotangent).  What
-still walks a share's whole buffer is the two gathers a layer BY
-ASSIGNMENT (`_by_assignment`: `combine`, and `dispatch`'s backward —
-their held entries lie scattered over the (k, T) index list, so no
-prefix cuts it).
+held rows, forward, recomputed and for the combine's cotangent), and so
+do the two sums a layer BY ASSIGNMENT (`combine`, and `dispatch`'s
+backward): their held entries lie scattered over the (k, T) index list,
+so ONE sort of the rows' numbers makes a prefix of them
+(`_held_by_token`: the held rows in assignment order, in the place of
+the sort that inverts `order`), a loop over that prefix's chunks adds a
+token's rows where they lie side by side, and one gather of T entries
+reads each token's sum (`_sum_held`): held rows + T index entries where
+the gather through the inverse has T*k.  What still walks a share's
+whole buffer is the sorts, and nothing else.  The plain route
+keeps the gather through the inverse (`_by_assignment`): it is the one
+definition of the mathematics, and where every row is held, held rows +
+T is more than T*k.
 
 The bookkeeping around the rows — on every route, a whole layer's too —
 holds no scatter and no gather of single numbers: on the TPU either
@@ -78,20 +86,24 @@ count); the gates are the scores under a select on the chosen experts
 as a third operand of the sort that makes `order` (`_expert_order`),
 and the backward pass's <row, cotangent> numbers return to (T, k) by a
 sort ON `order` (`_numbers_by_assignment`), which the compiler folds
-into the sort that inverts it where both stand in one pass.  What is
-left per T*k entry is those two sorts a pass.
+into the sort that inverts it where both stand in one pass (the plain
+route: two sorts a pass; on the kernel route, where the held rows' sort
+stands in the inverse's place, a backward pass runs it as a third).
+What is left per T*k entry is those sorts.
 A share also sows `moe_gmm_tiles`, `moe_map_tiles` (row tiles its
 grouped products / its elementwise passes walk, row tiles of the
-buffer) and `moe_gather_rows` (rows `dispatch` fetches, rows of the
-buffer), which `collect_moe_stats` reduces to `moe_gmm_tiles_share`,
-`moe_map_tiles_share` and `moe_gather_rows_share`.
+buffer), `moe_gather_rows` (rows `dispatch` fetches, rows of the
+buffer) and `moe_combine_rows` (index entries a sum by assignment
+fetches, T*k), which `collect_moe_stats` reduces to
+`moe_gmm_tiles_share`, `moe_map_tiles_share`, `moe_gather_rows_share`
+and `moe_combine_rows_share`.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -307,7 +319,10 @@ def _numbers_by_assignment(numbers: jax.Array, order: jax.Array,
 def _by_assignment(rows: jax.Array, inv: jax.Array,
                    held_rows: jax.Array) -> jax.Array:
     """rows (T*k, d) in expert order -> (k, T, d), each assignment's own
-    row: a gather through the sort's inverse.  An assignment whose row
+    row: a gather through the sort's inverse, T*k index entries — the
+    plain route's, every CPU run's and the parity tests' reference; a
+    share on the kernel route sums the same rows from the held ones
+    alone (`_sum_held`).  An assignment whose row
     lies behind the held rows (its expert is on another chip) reads
     zeros, whatever that place holds.  The mask comes BEFORE any cast:
     the TPU compiler then fuses cast, weighting and sum into one pass
@@ -330,6 +345,30 @@ def _gather_chunk(rows: int) -> int:
     return min(_GATHER_CHUNK, rows)
 
 
+# rows a turn of a sum by assignment's loop gathers (`_sum_held`): half
+# `dispatch`'s, because a turn here holds its chunk three times over —
+# the gathered rows, their float32 copy that the k - 1 shifted adds
+# read, the rounded sums — and at 8,192 rows of 2,560 numbers the
+# gathered rows are no longer staged in VMEM.  On the chip a call at
+# 2,048 / 4,096 / 8,192 reads 3.97 / 3.85 / 4.40 ms at SmallThinker's
+# shape (49,033 of 196,608 rows held), 2.65 / 2.68 / 2.81 at Kimi's,
+# 1.08 / 1.05 / 1.14 at the Nemotron hybrid's (PERF.md section 6, PR 50)
+_SUM_CHUNK = 4096
+
+
+def _sum_chunk(rows: int) -> int:
+    return min(_SUM_CHUNK, rows)
+
+
+def _halo(k: int) -> int:
+    """Rows a turn of the sums by assignment reads past its chunk: a
+    token's k rows lie side by side in assignment order, so the first of
+    them finds the others at most k - 1 places on, in the next turn's
+    chunk where the token straddles the edge (rounded up to 16 rows, a
+    bfloat16 tile)."""
+    return -(-(k - 1) // 16) * 16
+
+
 def gathered_rows(group_sizes: jax.Array, rows: int,
                   route: str) -> Tuple[jax.Array, jax.Array]:
     """(rows `dispatch` fetches on `route`, rows of the buffer), from
@@ -344,14 +383,109 @@ def gathered_rows(group_sizes: jax.Array, rows: int,
     return jnp.minimum(-(-held // chunk) * chunk, of), of
 
 
+def combined_rows(group_sizes: jax.Array, rows: int, k: int,
+                  route: str) -> Tuple[jax.Array, jax.Array]:
+    """(index entries ONE sum by assignment fetches on `route`, the T*k
+    of the whole list), from `group_sizes` alone as `gathered_rows` is:
+    a chunk and its halo a turn of the loop over the held rows and one
+    entry a token, or every assignment where the gather is through the
+    sort's inverse."""
+    of = jnp.asarray(rows, jnp.int32)
+    if route == "plain":
+        return of, of
+    chunk = _sum_chunk(rows)
+    held = group_sizes.astype(jnp.int32).sum()
+    return -(-held // chunk) * (chunk + _halo(k)) + rows // k, of
+
+
+class HeldByToken(NamedTuple):
+    """The held rows in ASSIGNMENT order (`_held_by_token`): entry i is
+    the i-th held assignment, token-major and slot-minor, so a token's
+    rows lie side by side.  `token`, `row`, `gate` are (T*k + halo,):
+    the assignment's token (T behind the held entries), its row of the
+    buffer and its gate; `first` (T,) is where each token's entries
+    start, `count` (T,) how many it has."""
+    token: jax.Array
+    row: jax.Array
+    gate: jax.Array
+    first: jax.Array
+    count: jax.Array
+
+
+def _held_by_token(order: jax.Array, flat_gates: jax.Array,
+                   held: jax.Array) -> HeldByToken:
+    """The kernel route's way back from rows to tokens.  `held` (T, k):
+    which assignments name a held expert (their rows come first in
+    expert order).  ONE sort of the rows' numbers on `order` where a row
+    is held and T*k where it is not puts the held rows first, ascending
+    by assignment — the inverse's place in the pass, and no gather of
+    T*k numbers; a token's entries start at the running sum of the
+    tokens' counts before it."""
+    t, k = held.shape
+    count = held.sum(-1, dtype=jnp.int32)
+    row = jnp.arange(t * k, dtype=jnp.int32)
+    key = jnp.where(row < count.sum(), order, t * k)
+    key, row, gate = jax.lax.sort((key, row, flat_gates), num_keys=1)
+    token, row, gate = (
+        jnp.pad(x, (0, _halo(k)), constant_values=fill)
+        for x, fill in ((key // k, t), (row, 0), (gate, 0)))
+    return HeldByToken(token, row, gate, jnp.cumsum(count) - count, count)
+
+
+def _sum_held(rows: jax.Array, by_token: HeldByToken, held_rows: jax.Array,
+              weighted: bool) -> jax.Array:
+    """`_by_assignment`'s sum over a token's k rows, from the held rows
+    alone: rows (T*k, d) in expert order -> (T, d).  A loop of
+    ceil(held_rows / chunk) turns, as `dispatch`'s, of `_SUM_CHUNK` rows:
+    a turn gathers a chunk (and its halo) of the buffer's rows in
+    assignment order, weighs them in float32 and adds to each entry the
+    up to k - 1 that follow it while they are the same token's — at a
+    token's FIRST entry that is its rows' sum in ascending slot order,
+    the additions `picked.sum(0)` makes over the k slabs in the same
+    order (an absent assignment adds an exact zero there) — rounds it
+    once and writes the chunk in place.  One gather of T entries then
+    reads each token's sum at its first entry; a token with none reads
+    zero."""
+    t = by_token.first.shape[0]
+    k = rows.shape[0] // t
+    chunk, halo = _sum_chunk(rows.shape[0]), _halo(k)
+
+    def turn(c, buffer):
+        # a last turn that would pass the buffer's end is moved back onto
+        # it by the slices and by the update alike, as `dispatch`'s is
+        # (the lists are a halo longer than the buffer and so is a
+        # slice: both clamp their start to rows - chunk); what it sums
+        # twice is the same
+        at = c * chunk
+        token, row, gate = (
+            jax.lax.dynamic_slice(x, (at,), (chunk + halo,))
+            for x in (by_token.token, by_token.row, by_token.gate))
+        picked = rows[row].astype(jnp.float32)
+        if weighted:
+            picked = picked * gate[:, None]
+        total = picked[:chunk]
+        for s in range(1, k):
+            same = token[s:s + chunk] == token[:chunk]
+            total = total + jnp.where(same[:, None], picked[s:s + chunk], 0)
+        return jax.lax.dynamic_update_slice(
+            buffer, total.astype(rows.dtype), (at, 0))
+
+    sums = jax.lax.fori_loop(0, -(-held_rows // chunk), turn,
+                             unwritten_rows(rows.shape[0], rows))
+    return jnp.where((by_token.count > 0)[:, None], sums[by_token.first],
+                     jnp.zeros((), rows.dtype))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def dispatch(tokens: jax.Array, order: jax.Array, inv: jax.Array,
+def dispatch(tokens: jax.Array, order: jax.Array, by_token,
              held_rows: jax.Array, route: str = "plain") -> jax.Array:
     """tokens (T, d) -> rows (T*k, d) in expert order: row r is the
-    token of assignment `order[r]`, token `order[r] // k`.  `inv` (k, T)
-    is the inverse of `order`: `inv[j, t]` is the row of assignment
-    t*k + j (k leads, so that a sum over a token's k rows adds k whole
-    (T, d) slabs).  `held_rows`: how many rows belong to a group.
+    token of assignment `order[r]`, token `order[r] // k`.  `held_rows`:
+    how many rows belong to a group.  `by_token` is the way back, which
+    the backward pass takes: on "plain" `inv` (k, T), the inverse of
+    `order` — `inv[j, t]` is the row of assignment t*k + j (k leads, so
+    that a sum over a token's k rows adds k whole (T, d) slabs); on
+    "kernel" a `HeldByToken`.
 
     `route` is the layer's (`grouped_experts`).  On "plain" every row is
     gathered, `tokens[order // k]`: the one definition of the
@@ -365,10 +499,11 @@ def dispatch(tokens: jax.Array, order: jax.Array, inv: jax.Array,
 
     `dispatch` and `combine` are each other's transposes and each one's
     backward pass is the other, so rows move by gathers in both
-    directions: a token's k rows are found through `inv` and summed,
-    none is scattered.  A backward pass is traced under the named scopes
-    of the forward call, so its instructions keep the caller's scope."""
-    k = inv.shape[0]
+    directions: a token's k rows are found (through `inv`, or side by
+    side among the held rows in assignment order) and summed, none is
+    scattered.  A backward pass is traced under the named scopes of the
+    forward call, so its instructions keep the caller's scope."""
+    k = order.shape[0] // tokens.shape[0]
     if route != "kernel":
         return tokens[order // k]
     rows, chunk = order.shape[0], _gather_chunk(order.shape[0])
@@ -385,13 +520,13 @@ def dispatch(tokens: jax.Array, order: jax.Array, inv: jax.Array,
                              unwritten_rows(rows, tokens))
 
 
-def _dispatch_fwd(tokens, order, inv, held_rows, route):
-    return (dispatch(tokens, order, inv, held_rows, route),
-            (order, inv, held_rows))
+def _dispatch_fwd(tokens, order, by_token, held_rows, route):
+    return (dispatch(tokens, order, by_token, held_rows, route),
+            (order, by_token, held_rows))
 
 
 def _dispatch_bwd(route, res, d_rows):
-    return combine(d_rows, None, None, *res), None, None, None
+    return combine(d_rows, None, None, *res, route), None, None, None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -400,26 +535,35 @@ dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def combine(rows: jax.Array, gates: Optional[jax.Array],
             flat_gates: Optional[jax.Array], order: jax.Array,
-            inv: jax.Array, held_rows: jax.Array,
+            by_token, held_rows: jax.Array,
             route: str = "plain") -> jax.Array:
     """rows (T*k, d) in expert order, gates (T, k) or None (all ones) ->
     (T, d): the sum of each token's k rows, weighted in the same pass,
-    accumulated in float32 (`dispatch` says what `order`, `inv` and
+    accumulated in float32 (`dispatch` says what `order`, `by_token` and
     `held_rows` are).  `flat_gates` (T*k,) is the gates once more, in
     expert order (`_expert_order`; None with `gates`): the backward pass
     weighs the rows' cotangent with them where the rows lie, and they
-    get no gradient of their own.  `route` is the layer's
-    (`grouped_experts`): on "kernel" the backward pass's two passes over
-    the row buffer are one `rows_map` over the held tiles."""
-    picked = _by_assignment(rows, inv, held_rows).astype(jnp.float32)
+    get no gradient of their own.
+
+    `route` is the layer's (`grouped_experts`).  On "plain" the rows are
+    gathered through `inv`, all T*k of them (`_by_assignment`): the one
+    definition of the mathematics, and where every row is held the
+    shortest index list there is.  On "kernel" the sum reads the held
+    rows alone (`_sum_held`: held rows + T index entries, the same
+    additions in the same order), and the backward pass's two passes
+    over the row buffer are one `rows_map` over the held tiles."""
+    if route == "kernel":
+        return _sum_held(rows, by_token, held_rows, gates is not None)
+    picked = _by_assignment(rows, by_token, held_rows).astype(jnp.float32)
     if gates is not None:
         picked = picked * gates.T[..., None]
     return picked.sum(0).astype(rows.dtype)
 
 
-def _combine_fwd(rows, gates, flat_gates, order, inv, held_rows, route):
-    return (combine(rows, gates, flat_gates, order, inv, held_rows, route),
-            (rows, gates, flat_gates, order, inv, held_rows))
+def _combine_fwd(rows, gates, flat_gates, order, by_token, held_rows, route):
+    return (combine(rows, gates, flat_gates, order, by_token, held_rows,
+                    route),
+            (rows, gates, flat_gates, order, by_token, held_rows))
 
 
 def _weigh(rows, d_rows, gates):
@@ -430,8 +574,8 @@ def _weigh(rows, d_rows, gates):
 
 
 def _combine_bwd(route, res, d_out):
-    rows, gates, flat_gates, order, inv, held_rows = res
-    d_rows = dispatch(d_out, order, inv, held_rows, route)
+    rows, gates, flat_gates, order, by_token, held_rows = res
+    d_rows = dispatch(d_out, order, by_token, held_rows, route)
     if gates is None:
         return d_rows, None, None, None, None, None
     if route == "kernel":
@@ -446,7 +590,7 @@ def _combine_bwd(route, res, d_out):
         dots = (rows.astype(jnp.float32) * d_rows).sum(-1)
         d_rows = (d_rows * flat_gates[:, None]).astype(rows.dtype)
     # back to (T, k) as T*k numbers
-    d_gates = _numbers_by_assignment(dots, order, held_rows, inv.shape[0])
+    d_gates = _numbers_by_assignment(dots, order, held_rows, gates.shape[1])
     return d_rows, d_gates.astype(gates.dtype), None, None, None, None
 
 
@@ -489,11 +633,12 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
                     ) -> Tuple[jax.Array, jax.Array]:
     """The dropless expert pass for a routing already made: returns
     (out (T, d), group_sizes (held,)).  Scopes: `dispatch` (sort, its
-    inverse, gather), `experts` (grouped matmuls, gating product),
-    `combine` (gather through the inverse, weighting, sum over k), and
-    their backward passes under the same two (`dispatch`, `combine`
-    above).  `w_gate=None`: relu^2 experts; else `gate_act` of the gate
-    matrix's product times the other's (silu: SwiGLU, relu: ReGLU).
+    inverse — on the kernel route the held rows in assignment order —
+    gather), `experts` (grouped matmuls, gating product), `combine`
+    (the rows back by assignment, weighting, sum over k), and their
+    backward passes under the same two (`dispatch`, `combine` above).
+    `w_gate=None`: relu^2 experts; else `gate_act` of the gate matrix's
+    product times the other's (silu: SwiGLU, relu: ReGLU).
     `load`: `expert_counts(experts, num_experts)` where the caller has
     counted already (`MoEMLP`: once a layer call), else counted here;
     the groups' sizes are the held experts' part of it.
@@ -516,10 +661,13 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
     the held rows' chunks alone and leaves the buffer behind them as it
     found it, the products' kernels and the maps' visit the tiles that
     hold a held row, and a map zeroes the rest of the last one.  And on
-    either route the sums
-    over a token's k rows read no place behind the held rows
-    (`_by_assignment`), be it of the last product or of the rows' own
-    gradient."""
+    either route the sums over a token's k rows take in no place behind
+    the held rows, be it of the last product or of the rows' own
+    gradient: on "plain" a mask over the gather of all T*k assignments
+    (`_by_assignment`), on "kernel" a sum over the held rows in
+    assignment order, which indexes no other (`_sum_held`; a last
+    turn's chunk may pass them, and a select keeps what it fetched
+    there out of every token's sum)."""
     T, top_k = experts.shape
     E = w_in.shape[0]
     share = num_experts is not None and E < num_experts
@@ -531,15 +679,20 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
             flat_expert = jnp.where(
                 (flat_expert >= 0) & (flat_expert < E), flat_expert, E)
         order, flat_gates = _expert_order(flat_expert, gates)
-        # the inverse of a permutation is its argsort: T*k integers (on
-        # the chip a third of what scattering them costs, PERF.md PR 32)
-        inv = jnp.argsort(order).reshape(T, top_k).T
+        if route == "kernel":
+            by_token = _held_by_token(order, flat_gates,
+                                      flat_expert.reshape(T, top_k) < E)
+        else:
+            # the inverse of a permutation is its argsort: T*k integers
+            # (on the chip a third of what scattering them costs, PERF.md
+            # PR 32)
+            by_token = jnp.argsort(order).reshape(T, top_k).T
         if load is None:
             load = expert_counts(experts, num_experts or E)
         group_sizes = load[first_expert:first_expert + E]
         held_rows = group_sizes.sum()
         held_row = jnp.arange(T * top_k) < held_rows
-        xs = dispatch(tokens, order, inv, held_rows, route)
+        xs = dispatch(tokens, order, by_token, held_rows, route)
         xs = xs.astype(w_in.dtype)                     # (T*k, d) sorted
 
     def grouped(lhs, rhs):
@@ -562,7 +715,8 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
         # (T*k, d); no mask here: `combine` reads the held rows alone
         ys = grouped_matmul(h, w_down, group_sizes, route)
     with jax.named_scope("combine"):
-        out = combine(ys, gates, flat_gates, order, inv, held_rows, route)
+        out = combine(ys, gates, flat_gates, order, by_token, held_rows,
+                      route)
     return out.astype(tokens.dtype), group_sizes
 
 
@@ -749,9 +903,9 @@ class MoEMLP(nn.Module):
             self.sow("intermediates", "moe_rows_absent", dropped)
             dropped = jnp.zeros((), dropped.dtype)
             # how far the grouped products, the elementwise passes
-            # between them and the gathers into expert order follow the
-            # held rows: row tiles (rows) they walk, of the buffer's
-            # (no sync)
+            # between them, the gathers into expert order and the sums
+            # by assignment follow the held rows: row tiles (rows, index
+            # entries) they walk, of the buffer's (no sync)
             rows = n_tok * cfg.top_k
             route = layer_route(rows, w_gate, w_in, w_out, cfg.num_experts,
                                 cfg.mesh)
@@ -761,6 +915,9 @@ class MoEMLP(nn.Module):
                      jnp.stack(map_tiles(counts, rows, route)))
             self.sow("intermediates", "moe_gather_rows",
                      jnp.stack(gathered_rows(counts, rows, route)))
+            self.sow("intermediates", "moe_combine_rows",
+                     jnp.stack(combined_rows(counts, rows, cfg.top_k,
+                                             route)))
         self.sow("intermediates", "moe_dropped", dropped)
         if cfg.shared_width:
             with jax.named_scope("shared"):
@@ -819,7 +976,8 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     layers, and where the layers hold a share of their experts the
     assignments to experts held / not held here, all layers, and the
     shares of the row buffers' tiles their grouped products and their
-    elementwise passes walk and of their rows `dispatch` fetches."""
+    elementwise passes walk, of their rows `dispatch` fetches and of
+    their assignments the sums by assignment index."""
     counts = [n.astype(jnp.float32)
               for n in _sown(intermediates, "moe_tokens_per_expert")]
     if not counts:
@@ -830,7 +988,8 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     stats = {"moe_load_max_over_mean": jnp.max(jnp.stack(loads)),
              **{name: jnp.sum(jnp.stack(v)) for name, v in sums.items()
                 if v}}
-    for name in ("moe_gmm_tiles", "moe_map_tiles", "moe_gather_rows"):
+    for name in ("moe_gmm_tiles", "moe_map_tiles", "moe_gather_rows",
+                 "moe_combine_rows"):
         tiles = [v.reshape(-1, 2) for v in _sown(intermediates, name)]
         if tiles:
             walked, of = jnp.concatenate(tiles).astype(jnp.float32).sum(0)

@@ -46,12 +46,15 @@ def test_kimi_vl_step_fits_one_chip_by_the_rule_and_fills_it(kimi_vl_step):
     1 / 2 sequences at depth 5, 12.50 at the fallback's 1 at depth 6), of
     which 6.82 GB is donated state; far over the 25% a cell has to fill.
     The file's 14.27 is the reading of a step without loops.  Since
-    PR 42 the step holds twelve, and the compiler's statistics count a
-    loop-carried buffer that outlives its loop twice (tests/
-    test_smallthinker_compile.py says how that was found): 15.17 as
-    read, 14.37 with the one (196608, 2048) bf16 buffer taken off, and
-    on the chip the peak is the parent's (`device.peak_hbm_gib` 13.920
-    for 13.921: PERF.md section 6, PR 42)."""
+    PR 42 the step holds twelve (twenty since PR 50), and the
+    compiler's statistics count a loop-carried buffer that outlives its
+    loop twice (tests/test_smallthinker_compile.py says how that was
+    found): 15.17 as read (15.16 since PR 50: the sums by assignment
+    carry a (196608, 2048) buffer where the parent wrote the gathered
+    (6, 32768, 2048)), 14.37 with the one (196608, 2048) bf16 buffer
+    taken off, and on the chip the peak is the parent's
+    (`device.peak_hbm_gib` 13.920 for 13.921: PERF.md section 6,
+    PR 42)."""
     cell, model, step = kimi_vl_step
     assert model.config.num_params() == 568_484_608
     assert (cell["seq_len"], cell["global_batch"]) == (16384, 2)
@@ -106,8 +109,9 @@ def test_kimi_vl_step_holds_its_scopes_and_a_share_of_swiglu_experts(
     operand the 8 held experts, none the published 64; no `ragged-dot`;
     the SwiGLU shared expert under `moe/shared`; layer 0's dense SwiGLU
     under `feed_forward` itself.  No auxiliary term is sown.  What holds
-    other ops in the step is the twelve loops that gather the held rows'
-    chunks into expert order (`_held_row_loops`), no `conditional`."""
+    other ops in the step is the twenty loops over the held rows' chunks
+    — twelve gather them into expert order, eight sum them by assignment
+    (`_held_row_loops`) — no `conditional`."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
 
     cell, _, step = kimi_vl_step
@@ -170,9 +174,12 @@ def test_kimi_vl_step_walks_its_row_buffer_in_gathers_alone(kimi_vl_step):
     forward and recomputed (8) and its backward (4) over (T*k, 1408), the
     sum of the two first products' row gradients (4) over (T*k, 2048),
     the combine's backward pair (4) — and no fusion under either scope
-    still has a (T*k, width) operand but the gathers by assignment
-    (eight, (k, T, 2048)); the twelve INTO expert order are loops whose
-    turn gathers (8192, 2048), none has a (T*k, 2048) result."""
+    still has a (T*k, width) operand.  The twelve gathers INTO expert
+    order are loops whose turn gathers (8192, 2048); the eight sums BY
+    ASSIGNMENT are loops over the held rows too, a turn gathers (4096 +
+    16, 2048), and one gather of (T, 2048) behind each reads the tokens'
+    sums (PR 50: were eight gathers of (k, T, 2048), T*k index entries
+    each); no gather has a (T*k, 2048) or a (k, T, 2048) result."""
     cell, _, step = kimi_vl_step
     text = step.as_text()
     rows = cell["global_batch"] * 16384 * 6
@@ -183,7 +190,8 @@ def test_kimi_vl_step_walks_its_row_buffer_in_gathers_alone(kimi_vl_step):
         ("dwt_rows_map_weigh", f"{rows},2048"): 4}
     assert _row_buffer_walkers(text, rows) == []
     assert _held_row_loops(text, rows, 2048, layers=4) == {
-        "bf16[8192,2048]": 12, f"bf16[6,{rows // 6},2048]": 8}
+        "bf16[8192,2048]": 12, "bf16[4112,2048]": 8,
+        f"bf16[{rows // 6},2048]": 8}
 
 
 def test_kimi_vl_step_indexes_no_single_numbers(kimi_vl_step):

@@ -232,7 +232,9 @@ def test_the_expert_pass_keeps_its_value_and_every_gradient(holding,
         assert int(sizes.sum()) == 0 and not np.asarray(got).any()
 
 
-CHUNK = 64  # `moe._GATHER_CHUNK` on the kernel route here: two row tiles
+# `moe._GATHER_CHUNK` and `moe._SUM_CHUNK` on the kernel route here: two
+# row tiles
+CHUNK = 64
 
 # an expert's form -> the gate's activation (None: relu^2, no gate matrix)
 FORMS = {"relu2": None, "reglu": jax.nn.relu, "swiglu": jax.nn.silu}
@@ -277,6 +279,7 @@ def _on_the_kernel_route(monkeypatch, poison):
     monkeypatch.setattr(gm, "_unwritten_kernel", functools.partial(
         gm._unwritten_kernel, interpret=True))
     monkeypatch.setattr(moe, "_GATHER_CHUNK", CHUNK)
+    monkeypatch.setattr(moe, "_SUM_CHUNK", CHUNK)
     if poison:
         monkeypatch.setattr(gm, "_gmm", poisoned_gmm)
         monkeypatch.setattr(gm, "_rows_map", poisoned_map)
@@ -300,7 +303,9 @@ def test_the_chunked_dispatch_is_the_plain_gather_on_the_held_rows(
     held_rows = jnp.asarray(held, jnp.int32)
 
     def both(route):
-        return jax.vjp(lambda x: dispatch(x, order, inv, held_rows, route),
+        back = inv if route == "plain" else moe._held_by_token(
+            order, jnp.ones(T * K), (inv < held_rows).T)
+        return jax.vjp(lambda x: dispatch(x, order, back, held_rows, route),
                        tokens)
 
     (got, vjp), (want, plain_vjp) = both("kernel"), both("plain")
@@ -320,8 +325,10 @@ def test_the_chunked_dispatch_is_the_plain_gather_on_the_held_rows(
 def test_a_share_counts_the_rows_its_dispatch_fetches(monkeypatch, route):
     """A layer that holds 2 of 8 experts sows `moe_gather_rows` beside
     its tile counts — the held rows rounded up to a turn of the loop on
-    the kernel route, every row on the plain — and `collect_moe_stats`
-    reduces it to `moe_gather_rows_share`; a whole layer sows none."""
+    the kernel route, every row on the plain — and `moe_combine_rows`,
+    the index entries of a sum by assignment; `collect_moe_stats`
+    reduces them to `moe_gather_rows_share` and
+    `moe_combine_rows_share`; a whole layer sows neither."""
     cfg = dict(num_experts=8, top_k=3, impl="grouped", aux_loss="none",
                expert_act="relu2", dtype=jnp.float32)
     x = _draw((2, 48, D), seed=7)[0]
@@ -338,8 +345,9 @@ def test_a_share_counts_the_rows_its_dispatch_fetches(monkeypatch, route):
         inter, stats = upd["intermediates"], collect_moe_stats(
             upd["intermediates"])
         if held == 8:
-            assert "moe_gather_rows" not in inter
-            assert "moe_gather_rows_share" not in stats
+            for counter in ("moe_gather_rows", "moe_combine_rows"):
+                assert counter not in inter
+                assert f"{counter}_share" not in stats
             continue
         held_rows = int(inter["moe_rows_held"][0])
         assert 0 < held_rows < rows - CHUNK
@@ -349,6 +357,16 @@ def test_a_share_counts_the_rows_its_dispatch_fetches(monkeypatch, route):
             == [fetched, rows]
         assert float(stats["moe_gather_rows_share"]) == pytest.approx(
             fetched / rows)
+        # and the index entries a sum by assignment fetches: a chunk and
+        # its halo a turn over the held rows and one a token on the
+        # kernel route, every assignment on the plain
+        indexed = -(-held_rows // CHUNK) * (CHUNK + moe._halo(3)) + 2 * 48 \
+            if route == "kernel" else rows
+        assert [int(n) for n in inter["moe_combine_rows"][0]] \
+            == [indexed, rows]
+        assert float(stats["moe_combine_rows_share"]) == pytest.approx(
+            indexed / rows)
+        assert (indexed < rows) == (route == "kernel")
 
 
 def _expert_pass(form, holding, routing, seed=5):
@@ -717,3 +735,87 @@ def test_the_layer_is_the_parents_bit_for_bit(monkeypatch, held, first,
             inter["moe_rows_absent"][0]) == 64 * k
     _the_parents_lines(monkeypatch)
     _same_bits(got, both(params, x))
+
+
+# ------- the sums by assignment over the held rows alone (PR 50), against
+# ------- the gather through the sort's inverse
+
+def _ways_back(experts, first, held, gates):
+    """What `grouped_experts` hands `dispatch` and `combine` on either
+    route: (order, the gates in expert order, held_rows, {route: the way
+    back from rows to tokens})."""
+    tokens, k = experts.shape
+    flat = _held_number(experts, first, held)
+    order, flat_gates = moe._expert_order(flat, gates)
+    held_rows = (flat < held).sum().astype(jnp.int32)
+    return order, flat_gates, held_rows, {
+        "plain": jnp.argsort(order).reshape(tokens, k).T,
+        "kernel": moe._held_by_token(order, flat_gates,
+                                     flat.reshape(tokens, k) < held)}
+
+
+# (experts held of 64, the share of the rows that is): none, a chip's 4
+# and 18 of 64, every one
+SHARES = {"none": 0, "6pct": 4, "28pct": 18, "all": 64}
+SUMS = [pytest.param(share, k, id=f"{share}-k{k}")
+        for share in SHARES for k in (1, 6, 8)]
+
+
+@pytest.mark.parametrize("share,k", SUMS)
+def test_the_sums_over_the_held_rows_are_the_gathers_by_assignment(
+        monkeypatch, share, k):
+    """`combine` (with gates) and `dispatch`'s backward pass (without)
+    on the kernel route — a loop over the held rows' chunks in
+    assignment order, a token's rows added where they lie side by side,
+    one gather of T entries — against the plain route's gather of all
+    T*k rows through the inverse (`_by_assignment`): the sums, the rows'
+    gradient over the held rows and the gates' gradient bit for bit,
+    bfloat16 rows weighted and added in float32 and rounded once.  No
+    row held, a chip's share (under one turn of the loop; several and a
+    part of one), every row held; k = 1 (nothing to add), 6 and 8;
+    tokens whose rows lie across a turn's edge; and NaN in every row
+    behind the held ones, which nothing may read."""
+    held, tokens = SHARES[share], 96
+    rows = tokens * k
+    _on_the_kernel_route(monkeypatch, poison=True)
+    experts = _assignments("absent" if not held else "random", k, 64, 0,
+                           held or 8, tokens)
+    gates, ys, d_ys, d_out = _draw((tokens, k), (rows, D), (rows, D),
+                                   (tokens, D), seed=13)
+    # gates that are powers of two, of either sign: a turn of the loop is
+    # compiled as one program, and the CPU's compiler contracts its
+    # product and sum into one fused multiply-add, which keeps the bits a
+    # product of its own rounds away (the TPU has no such instruction)
+    gates = jnp.sign(gates) * 2.0 ** jnp.round(jnp.abs(gates) * 2 - 2)
+    order, flat_gates, held_rows, back = _ways_back(experts, 0, held, gates)
+    n = int(held_rows)
+    assert n == {"none": 0, "all": rows}.get(share, n)
+    if share in ("6pct", "28pct"):
+        assert 0.5 * held / 64 < n / rows < 1.5 * held / 64
+    if (share, k) in (("28pct", 6), ("28pct", 8), ("all", 1)):
+        assert n > CHUNK and n % CHUNK  # several turns and a part of one
+    if (share, k) in (("28pct", 6), ("28pct", 8), ("all", 6)):
+        # some token's rows lie on both sides of a turn's edge
+        token = np.asarray(back["kernel"].token)
+        assert any(token[e - 1] == token[e] for e in range(CHUNK, n, CHUNK))
+    behind = (jnp.arange(rows) >= held_rows)[:, None]
+    ys, d_ys = (jnp.where(behind, jnp.nan, x).astype(jnp.bfloat16)
+                for x in (ys, d_ys))
+    d_out = d_out.astype(jnp.bfloat16)
+    x = jnp.zeros((tokens, D), jnp.bfloat16)
+
+    def run(route):
+        out, vjp = jax.vjp(
+            lambda r, g: combine(r, g, flat_gates, order, back[route],
+                                 held_rows, route), ys, gates)
+        d_rows, d_gates = vjp(d_out)
+        d_x, = jax.vjp(lambda t: dispatch(t, order, back[route], held_rows,
+                                          route), x)[1](d_ys)
+        return out, d_rows[:n], d_gates, d_x
+
+    got, want = run("kernel"), run("plain")
+    for leaf in got:
+        assert np.isfinite(np.asarray(leaf, np.float32)).all()
+    _same_bits(got, want)
+    if not held:
+        assert not any(np.asarray(leaf, np.float32).any() for leaf in got)

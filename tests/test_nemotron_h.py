@@ -344,7 +344,8 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
     layer, params, x = _expert_layer(whole)
     want = _reference_layer(params, x, whole)
     _, upd = layer.apply({"params": params}, x, mutable=["intermediates"])
-    for counter in ("moe_gmm_tiles", "moe_map_tiles", "moe_gather_rows"):
+    for counter in ("moe_gmm_tiles", "moe_map_tiles", "moe_gather_rows",
+                    "moe_combine_rows"):
         assert counter not in upd["intermediates"]
         assert f"{counter}_share" not in collect_moe_stats(
             upd["intermediates"])
@@ -770,6 +771,8 @@ def test_a_few_trainer_steps_count_the_share_and_leave_the_bias_alone(
         # gathers into expert order
         assert a["moe_map_tiles_share"] == 1.0
         assert a["moe_gather_rows_share"] == 1.0
+        # and the sums by assignment index every assignment
+        assert a["moe_combine_rows_share"] == 1.0
     after = jax.tree.map(np.asarray, tr.state.params)
     for path, old in jax.tree_util.tree_flatten_with_path(before)[0]:
         new = functools.reduce(lambda t, k: t[k.key], path, after)

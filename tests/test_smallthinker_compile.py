@@ -128,8 +128,9 @@ def test_smallthinker_step_holds_its_scopes_and_a_share_of_reglu_experts(
     forward, three recomputed, six backward — every one under
     `feed_forward/moe/experts`, every weight operand the 16 held
     experts, none the published 64; no `ragged-dot`.  What holds other
-    ops in the step is the twelve loops that gather the held rows'
-    chunks into expert order (`_held_row_loops`), no `conditional`."""
+    ops in the step is the twenty loops over the held rows' chunks —
+    twelve gather them into expert order, eight sum them by assignment
+    (`_held_row_loops`) — no `conditional`."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
 
     cell, _, step = smallthinker_step
@@ -169,9 +170,12 @@ def test_smallthinker_step_walks_its_row_buffer_in_gathers_alone(
     sum of the two first products' row gradients (4) over (T*k, 2560),
     all under `moe/experts`, and the combine's backward pair (4) under
     `moe/combine` — and no fusion under either scope still has a (T*k,
-    width) operand but the gathers by assignment (eight, (k, T, 2560));
-    the twelve INTO expert order are loops whose turn gathers (8192,
-    2560), none has a (T*k, 2560) result."""
+    width) operand.  The twelve gathers INTO expert order are loops
+    whose turn gathers (8192, 2560); the eight sums BY ASSIGNMENT are
+    loops over the held rows too, a turn gathers (4096 + 16, 2560), and
+    one gather of (T, 2560) behind each reads the tokens' sums (PR 50:
+    were eight gathers of (k, T, 2560), T*k index entries each); no
+    gather has a (T*k, 2560) or a (k, T, 2560) result."""
     cell, _, step = smallthinker_step
     text = step.as_text()
     rows = cell["global_batch"] * 16384 * 6
@@ -182,7 +186,8 @@ def test_smallthinker_step_walks_its_row_buffer_in_gathers_alone(
         ("dwt_rows_map_weigh", f"{rows},2560"): 4}
     assert _row_buffer_walkers(text, rows) == []
     assert _held_row_loops(text, rows, 2560, layers=4) == {
-        "bf16[8192,2560]": 12, f"bf16[6,{rows // 6},2560]": 8}
+        "bf16[8192,2560]": 12, "bf16[4112,2560]": 8,
+        f"bf16[{rows // 6},2560]": 8}
 
 
 def test_smallthinker_step_indexes_no_single_numbers(smallthinker_step):

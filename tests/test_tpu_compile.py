@@ -562,17 +562,31 @@ def _row_buffer_walkers(text, rows):
     return found
 
 
-def _held_row_loops(text, rows, width, layers, chunk=8192):
+def _held_row_loops(text, rows, width, layers, chunk=8192, sum_chunk=4096,
+                    k=6):
     """The ops of a compiled step that hold other ops: none but the
-    `while`s of `models/moe.dispatch` on the kernel route, three an
-    expert layer — forward and recomputed under `moe/dispatch`, the
-    cotangent's under `moe/combine` — each over a buffer that starts
-    unwritten (`dwt_rows_unwritten`) and is carried, (rows, width), with
-    no copy of it at the loop's entry or exit.  A turn gathers (chunk,
-    width) and writes it in place: no gather of the step has a (rows,
-    width) result.  Returns the row gathers' result shapes, counted."""
+    `while`s of `models/moe.py` on the kernel route, five an expert
+    layer.  Three are `dispatch`'s — forward and recomputed under
+    `moe/dispatch`, the cotangent's under `moe/combine` — and a turn of
+    theirs gathers (chunk, width); two are the sums by assignment over
+    the held rows (`_sum_held`) — `combine`'s under `moe/combine` in
+    the forward pass, `dispatch`'s backward under `moe/dispatch` in the
+    backward pass (a recomputed forward pass stops at the rows) — and a
+    turn of theirs gathers a chunk of their own size and its halo.
+    Each is over a buffer that starts unwritten (`dwt_rows_unwritten`)
+    and is carried, (rows, width), with no copy of it at the loop's
+    entry or exit, and a turn writes its chunk in place.  Behind a sum's loop ONE gather of
+    (rows / k, width) reads each token's sum.  No gather of the step
+    has a (rows, width) or a (k, rows / k, width) result: none has an
+    index list of T*k entries.  What is left per T*k entry is the
+    sorts: two a forward pass, recomputed too (the assignments into
+    expert order, the held rows into assignment order), and one more in
+    the backward pass (the dots back by assignment: its key is `order`
+    itself, where the parent folded it into the sort that inverted
+    `order`).  Returns the row gathers' result shapes, counted."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
         instructions_of, parse_computations, scope_of)
+    from dlrover_wuqiong_tpu.models.moe import _halo
 
     comps = parse_computations(text)
     every = [i for body in comps.values() for i in body]
@@ -583,34 +597,47 @@ def _held_row_loops(text, rows, width, layers, chunk=8192):
         + scope_of(i["op_name"]).rsplit("moe/", 1)[1]
         for i in loops) == {"fwd/dispatch": layers,
                             "recompute/dispatch": layers,
-                            "bwd/combine": layers}
+                            "bwd/combine": layers,
+                            "fwd/combine": layers,
+                            "bwd/dispatch": layers}
+    assert collections.Counter(
+        scope_of(i["op_name"]).split("/")[0] for i in every
+        if i["opcode"] == "sort" and f"s32[{rows}]" in i["shape"]) == {
+            "fwd": 2 * layers, "recompute": 2 * layers, "bwd": layers}
     buffer, turn = f"bf16[{rows},{width}]", f"bf16[{chunk},{width}]"
+    summed = f"bf16[{sum_chunk + _halo(k)},{width}]"
+    placed = f"bf16[{rows // k},{width}]"
     assert all(buffer in i["shape"] for i in loops)
     assert len([i for i in every if i["opcode"] == "custom-call"
                 and i["name"].startswith("dwt_rows_unwritten")
-                and i["shape"].startswith(buffer)]) == 3 * layers
+                and i["shape"].startswith(buffer)]) == 5 * layers
     assert not [i["name"] for i in every if i["shape"].startswith(buffer)
                 and i["opcode"] in ("copy", "copy-start")]
     bodies = {name for line in text.splitlines() if " while(" in line
               for name in re.findall(r"body=%?([\w.\-]+)", line)}
-    assert len(bodies) == 3 * layers
+    assert len(bodies) == 5 * layers
     in_place = [i for name in bodies for i in comps[name]
                 if i["shape"].startswith(buffer)
                 and (i["opcode"] == "dynamic-update-slice" or any(
                     m["opcode"] == "dynamic-update-slice"
                     for m in comps.get(i["calls"], [])))]
-    assert len(in_place) == 3 * layers
+    assert len(in_place) == 5 * layers
     found = instructions_of(text, "gather", "moe")
     gathers = collections.Counter(
         shape.split("{")[0] for shape in found.values()
         if shape.startswith("bf16["))
-    assert buffer not in gathers and gathers[turn] == 3 * layers
-    # the chunks' gathers stand in the loops' bodies and nowhere else
+    assert buffer not in gathers
+    assert f"bf16[{k},{rows // k},{width}]" not in gathers
+    assert gathers == {turn: 3 * layers, summed: 2 * layers,
+                       placed: 2 * layers}
+    # the chunks' gathers stand in the loops' bodies and nowhere else,
+    # the tokens' behind them
     home = {i["name"]: name for name, body in comps.items() for i in body}
     called = {i["calls"]: home[i["name"]] for i in every if i["calls"]}
     for name, shape in found.items():
-        if shape.startswith(turn):
-            assert called.get(home[name], home[name]) in bodies, name
+        if shape.startswith("bf16["):
+            inside = called.get(home[name], home[name]) in bodies
+            assert inside == (not shape.startswith(placed)), name
     return gathers
 
 
@@ -661,7 +688,7 @@ def test_nemotron_step_keeps_its_scopes_and_holds_eight_experts(
     published 128, and the group sizes are 8 numbers — an assignment to
     an absent expert has no group.  What holds other ops in the step
     (a `while`, which a device trace counts beside the ops it ran) is
-    the twelve loops over the held rows' chunks, no `conditional`."""
+    the twenty loops over the held rows' chunks, no `conditional`."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
 
     cell, _, step = nemotron_step
@@ -701,12 +728,16 @@ def test_nemotron_step_walks_its_row_buffer_in_gathers_alone(nemotron_step):
     forward and recomputed (8) and its backward (4) over (T*k, 1856)
     under `moe/experts`, the combine's backward pair (4) over (T*k,
     2688) under `moe/combine` — and no fusion under either scope still
-    has a (T*k, width) operand but the gathers by assignment.  The
-    gathers INTO expert order walk the held rows' chunks: twelve loops
-    whose turn gathers (8192, 2688) — where the parent ran twelve
-    gathers of (T*k, 2688), four of them (the cotangent's) from an 88 MB
-    source staged in VMEM (`S(1)`, PERF.md section 6, PR 38) — and a
-    turn's source or its result is still staged there."""
+    has a (T*k, width) operand.  The gathers INTO expert order walk the
+    held rows' chunks: twelve loops whose turn gathers (8192, 2688) —
+    where the parent ran twelve gathers of (T*k, 2688), four of them
+    (the cotangent's) from an 88 MB source staged in VMEM (`S(1)`,
+    PERF.md section 6, PR 38) — and a turn's source or its result is
+    still staged there.  The sums BY ASSIGNMENT walk them too (PR 50):
+    eight loops whose turn gathers (4096 + 16, 2688) and one gather of
+    (T, 2688) behind each, where eight gathers of (k, T, 2688) stood;
+    a turn's rows in float32, the chunk its k - 1 shifted adds read,
+    are staged in VMEM."""
     cell, _, step = nemotron_step
     text = step.as_text()
     rows = cell["global_batch"] * 8192 * 6
@@ -716,8 +747,8 @@ def test_nemotron_step_walks_its_row_buffer_in_gathers_alone(nemotron_step):
         ("dwt_rows_map_weigh", f"{rows},2688"): 4}
     assert _row_buffer_walkers(text, rows) == []
     gathers = _held_row_loops(text, rows, 2688, layers=4)
-    assert gathers == {"bf16[8192,2688]": 12,
-                       f"bf16[6,{rows // 6},2688]": 8}
+    assert gathers == {"bf16[8192,2688]": 12, "bf16[4112,2688]": 8,
+                       f"bf16[{rows // 6},2688]": 8}
     # a map reserves the VMEM it holds and says what it costs at most
     # (PR 38), so the compiler still stages in VMEM (`S(1)`) what a turn
     # reads or what it writes: the (T, 2688) source in the loop's carry,
@@ -725,17 +756,22 @@ def test_nemotron_step_walks_its_row_buffer_in_gathers_alone(nemotron_step):
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import parse_computations
 
     comps = parse_computations(text)
-    staged = 0
+    staged = summed = 0
     for line in text.splitlines():
         if " while(" not in line:
             continue
         body = comps[re.search(r"body=%?([\w.\-]+)", line).group(1)]
         source = re.search(rf"bf16\[{rows // 6},2688\]\{{[^}}]*\}}", line)
+        if source is None:  # a sum by assignment carries no tokens
+            weighed = [i["shape"] for i in body
+                       if i["shape"].startswith("f32[4112,2688]")]
+            summed += bool(weighed) and all("S(1)" in s for s in weighed)
+            continue
         chunks = [i["shape"] for i in body
                   if i["shape"].startswith("bf16[8192,2688]")]
         staged += "S(1)" in source.group(0) or all(
             "S(1)" in shape for shape in chunks)
-    assert staged == 12
+    assert (staged, summed) == (12, 8)
 
 
 # --------------------------------- granite-4.0-h-micro's step on one chip
@@ -960,9 +996,12 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
     the step does: none is left without a scope.  Where a layer holds a
     share (the kernel route) a gather into expert order is a loop over
     the held rows' chunks (8192, d), its body's gather under the same
-    scope; a whole layer keeps the one gather of (T*k, d) and holds no
-    op that holds others.  The grouped matmuls see the same buffers as
-    before."""
+    scope, and a sum by assignment is one too (PR 50: a turn gathers a
+    chunk and its halo, (4096 + 16, d), and one gather of (T, d) behind
+    the loop reads the tokens' sums — no index list of T*k entries is
+    left); a whole layer keeps the one gather of (T*k, d) and the one
+    of (k, T, d) and holds no op that holds others.  The grouped
+    matmuls see the same buffers as before."""
     from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
         instructions_of, scope_table)
 
@@ -981,20 +1020,28 @@ def test_moe_step_moves_its_rows_by_gathers_only(request, fixture, layers,
     assert not instructions_of(text, "scatter", "moe")
     assert _index_ops_of_numbers(text, width) == []
 
+    back = [by_assignment]
     if fixture == "nemotron_step":
         in_order = f"bf16[8192,{width}]"  # a turn of `dispatch`'s loop
+        # and of a sum by assignment's, with its halo, and the one
+        # gather of the tokens' sums behind that loop
+        back = [f"bf16[{4096 + 16},{width}]", f"bf16[{tokens},{width}]"]
 
     def row_gathers(under):
         return [s for s in shapes("gather", under)
-                if s in (in_order, by_assignment)]
+                if s in (in_order, by_assignment, *back)]
 
     assert row_gathers("moe/dispatch") == sorted(
-        [in_order] * layers * passes + [by_assignment] * layers)
+        [in_order] * layers * passes + back * layers)
     # (a recomputed forward pass stops at the rows: nothing in the
     # backward pass reads the sum it would make of them)
     assert row_gathers("moe/combine") == sorted(
-        [by_assignment] * layers + [in_order] * layers)
-    assert len(row_gathers("")) == layers * (passes + 3)
+        back * layers + [in_order] * layers)
+    assert len(row_gathers("moe")) == layers * (passes + 1 + 2 * len(back))
+    # (the one other gather of (T, d) in the hybrid's step is the
+    # embedding's lookup)
+    assert len(row_gathers("")) == len(row_gathers("moe")) + (
+        fixture == "nemotron_step")
 
     # the grouped matmuls, by the buffer each writes: the compiler's
     # kernels where the layer holds every expert (9 a step at OLMoE, 11

@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
-model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta] ...  (no args = step/attn/head/model/opt).  One JSON line
+model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|sums] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -36,6 +36,10 @@ share cells' shapes: each line that indexed T*k single numbers (a
 `bincount`, a gather through a sort or its inverse, `take_along_axis`
 and its transpose) against the compare-and-sum, the sort operand or the
 select `models/moe.py` runs in its place.
+`sums` reads the same way the sum of a token's k expert rows at the
+three share cells' shapes: the gather of all T*k rows through the sort's
+inverse against the kernel route's loop over the held rows in assignment
+order at three chunks a turn, and counts the entries that differ.
 `delta` reads the same way the gated delta rule at the Olmo hybrid
 cell's shape, forward and forward + backward: the chunked `jax.numpy`
 form against `dwt_gdr_fwd` / `dwt_gdr_bwd` (`ops/delta_rule.py`) at 1 /
@@ -817,6 +821,61 @@ def probe_moe_numbers():
                        "device_ops_ms": _device_ops_ms(jax.jit(fn), *args)})
 
 
+def probe_sums(chunks=(2048, 4096, 8192)):
+    """The sum of a token's k expert rows at the three share cells'
+    shapes and even routing (32,768 tokens x 6 of 64 experts, 16 or 8
+    held; 16,384 x 6 of 128, 8 held), with gates and without: the gather
+    of all T*k rows through the sort's inverse (`models/moe.py`'s plain
+    route) against the loop over the held rows in assignment order and
+    its one gather of T entries (the kernel route's `_sum_held`) at
+    three chunks a turn, with how many entries of the result differ
+    from the plain route's (PERF.md section 6, PR 50)."""
+    from dlrover_wuqiong_tpu.models import moe
+
+    shipped = moe._SUM_CHUNK
+    try:
+        for tokens, k, num_experts, held, width in (
+                (32768, 6, 64, 16, 2560), (32768, 6, 64, 8, 2048),
+                (16384, 6, 128, 8, 2688)):
+            keys = jax.random.split(jax.random.PRNGKey(tokens + width), 2)
+            probs = jax.nn.softmax(jax.random.normal(keys[0],
+                                                     (tokens, num_experts)))
+            gates, experts = jax.lax.top_k(probs, k)
+            flat = jnp.where(experts.reshape(-1) < held, experts.reshape(-1),
+                             held)
+            order, flat_gates = moe._expert_order(flat, gates)
+            held_rows = (flat < held).sum().astype(jnp.int32)
+            rows = jax.random.normal(keys[1], (tokens * k, width),
+                                     jnp.bfloat16)
+            ways = {"plain": jnp.argsort(order).reshape(tokens, k).T}
+
+            def summed(route, way, weighted):
+                return jax.jit(lambda r, g: moe.combine(
+                    r, g if weighted else None,
+                    flat_gates if weighted else None,
+                    order, way, held_rows, route))
+
+            want = {w: summed("plain", ways["plain"], w)(rows, gates)
+                    for w in (True, False)}
+            for chunk in (None, *chunks):
+                moe._SUM_CHUNK = chunk or shipped
+                route = "kernel" if chunk else "plain"
+                way = moe._held_by_token(order, flat_gates, flat.reshape(
+                    tokens, k) < held) if chunk else ways["plain"]
+                for weighted in (True, False):
+                    fn = summed(route, way, weighted)
+                    differ = int((fn(rows, gates).astype(jnp.float32)
+                                  != want[weighted].astype(jnp.float32)).sum())
+                    _emit_raw({"probe": "sums", "route": route, "chunk": chunk,
+                               "gates": weighted, "held_rows": int(held_rows),
+                               "shape": [tokens, k, num_experts, held, width],
+                               "differs_from_plain": differ,
+                               "device_ops_ms": _device_ops_ms(
+                                   fn, rows, gates, top=8)})
+    finally:
+        moe._SUM_CHUNK = shipped
+
+
 def probe_rope():
     """One rotation, forward and backward, at SmallThinker's q and k
     (2 x 16,384 x 3,584 and x 512, heads of 128), latent attention's q
@@ -915,7 +974,7 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "step": probe_step, "dispatch": probe_dispatch,
        "rpc": probe_rpc, "gmm": probe_gmm, "rows_map": probe_rows_map,
        "rope": probe_rope, "moe_numbers": probe_moe_numbers,
-       "delta": probe_delta}
+       "delta": probe_delta, "sums": probe_sums}
 
 
 def main(argv=None) -> int:
