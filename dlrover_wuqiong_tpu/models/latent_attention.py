@@ -3,27 +3,32 @@ arXiv:2405.04434): keys and values are up-projections of ONE normed
 low-rank latent a token, and the part of a key that carries the position
 is one rotated vector shared by every head.
 
-    q        = h Wq                    -> heads x (nope | rope)
+    q        = h Wq                    -> heads x (nope | rope), or with
+               a latent for q (`q_lora_rank`): RMSNorm(h Wq_a) Wq_b
     c        = h Wkv_a                 -> (latent of kv_lora_rank | rope key)
     kv       = RMSNorm(c[:rank]) Wkv_b -> heads x (k_nope | v)
     q_rope, k_rope rotated; k_h = (k_nope_h | k_rope), the same k_rope
-    o_h      = softmax_causal(q_h k_h^T / sqrt(nope + rope)) v_h
+    o_h      = softmax_causal(q_h k_h^T * scale) v_h, scale 1 / sqrt(nope
+               + rope) unless `attn_scale` sets it (YaRN's m^2 factor)
     out      = concat_h(o_h) Wo
 
 So a head's q and k are `qk_nope_head_dim + qk_rope_head_dim` wide (192)
 and its v `v_head_dim` (128): `ops/flash_attention.py`'s kernels take the
 two widths as they are, QK^T over the one and PV over the other, on the
 transposed (b, h, T, d) route (`attention_route(h, 192, 128)`: a head of
-a slab and a half lies on no slab boundary).  What is NOT here: a latent
-for q (`q_lora_rank`: refused, not guessed), a kernel that takes
-`k_rope` once instead of broadcast to the heads and joined to `k_nope`
-in HBM (ROADMAP, Speed), the cache of latents a server would keep
-(`serving/`), and a mesh (ring, Ulysses and the shard_map of
-`parallel/long_context.py` are handed one width).
+a slab and a half lies on no slab boundary).  The tables it is handed
+carry the positions' scaling (`models/llama.py::rope_freqs`, YaRN's
+through `RopeScaling`).  What is NOT here: a kernel that takes `k_rope`
+once instead of broadcast to the heads and joined to `k_nope` in HBM
+(ROADMAP, Speed), the cache of latents a server would keep (`serving/`:
+no latent cache, no absorbed up-projections), and a mesh (ring, Ulysses
+and the shard_map of `parallel/long_context.py` are handed one width: two
+widths on a mesh are not built).
 
 Scopes in the compiled step, all under the module's own name:
-`q_proj`, `kv_a_proj`, `kv_a_norm`, `kv_b_proj`, `o_proj` (the flax
-modules' names), `rope` (the two rotations) and `assemble` (the cut of
+`q_proj` (or `q_a_proj`, `q_a_norm`, `q_b_proj` with a q latent),
+`kv_a_proj`, `kv_a_norm`, `kv_b_proj`, `o_proj` (the flax modules'
+names), `rope` (the two rotations) and `assemble` (the cut of
 the projections into their parts, the broadcast of `k_rope` and the
 joins into the 192-wide q and k).  The module sows `attn_lanes`: the
 lanes of q/k and v a score entry's two products run as the kernels block
@@ -53,8 +58,11 @@ class LatentAttentionConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     kv_lora_rank: int = 512
-    # a latent for q as well (q = RMSNorm(h Wq_a) Wq_b): not built
+    # a latent for q as well: q = RMSNorm(h Wq_a) Wq_b (None = h Wq)
     q_lora_rank: Optional[int] = None
+    # the softmax's scale (`models/attention.softmax_scale`); 0 = 1 /
+    # sqrt(nope + rope)
+    attn_scale: float = 0.0
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     use_flash_attention: bool = True
@@ -65,9 +73,13 @@ class LatentAttentionConfig:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     def attention_params(self) -> int:
-        """q, kv_a, the latent's norm, kv_b, o; no block norm."""
+        """q (one product, or the latent's two and its norm), kv_a, the
+        latent's norm, kv_b, o; no block norm."""
         h, n, r = self.hidden_size, self.num_heads, self.kv_lora_rank
-        return (h * n * self.qk_head_dim + h * (r + self.qk_rope_head_dim)
+        qr = self.q_lora_rank
+        q = h * qr + qr + qr * n * self.qk_head_dim if qr \
+            else h * n * self.qk_head_dim
+        return (q + h * (r + self.qk_rope_head_dim)
                 + r + r * n * (self.qk_nope_head_dim + self.v_head_dim)
                 + n * self.v_head_dim * h)
 
@@ -83,15 +95,16 @@ class LatentAttention(nn.Module):
         from .fp8 import dense
 
         cfg = self.config
-        if cfg.q_lora_rank:
-            raise ValueError(
-                f"q_lora_rank={cfg.q_lora_rank}: a latent for q is not "
-                f"built here (q is one projection of the hidden state)")
         B, T, C = x.shape
         H, rank = cfg.num_heads, cfg.kv_lora_rank
         dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
-        q = dense(cfg, H * (dn + dr), "q_proj", use_bias=False)(x)
+        if cfg.q_lora_rank:
+            q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_a_norm")(
+                dense(cfg, cfg.q_lora_rank, "q_a_proj", use_bias=False)(x))
+            q = dense(cfg, H * (dn + dr), "q_b_proj", use_bias=False)(q)
+        else:
+            q = dense(cfg, H * (dn + dr), "q_proj", use_bias=False)(x)
         c = dense(cfg, rank + dr, "kv_a_proj", use_bias=False)(x)
         latent = RMSNorm(cfg.rms_eps, cfg.dtype, name="kv_a_norm")(
             c[..., :rank])
@@ -122,8 +135,9 @@ class LatentAttention(nn.Module):
             y = attend(q, k, v, cfg, causal=True)
         else:
             att = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-            att = jnp.where(_kept_mask(T, T),
-                            att / jnp.sqrt(jnp.float32(dn + dr)), -jnp.inf)
+            att = att * cfg.attn_scale if cfg.attn_scale \
+                else att / jnp.sqrt(jnp.float32(dn + dr))
+            att = jnp.where(_kept_mask(T, T), att, -jnp.inf)
             att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
             y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
         return dense(cfg, C, "o_proj", use_bias=False)(

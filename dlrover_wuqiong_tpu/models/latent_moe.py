@@ -13,6 +13,29 @@ experts and a SwiGLU shared expert on every token.
                  else sum_{e chosen, held} g_e swiglu_e(u) + swiglu_shared(u))
     logits = RMSNorm(x) @ W_head                              (untied)
 
+With `residual_lanes` n > 1 (Xing4.0's `hc_mult`) the stream between the
+blocks is n lanes of the hidden size, carried (b, n, T, d), and each of
+a block's two sublayers sits in a manifold-constrained hyper-connection
+(`models/hyper_connection.py`: its own `attention_hc` / `feed_forward_hc`
+leaves): X_0 is the embedding in every lane, a sublayer reads u = sum_i
+h_pre[i] X[i] and writes X'[i] = sum_j H_res[i, j] X[j] + h_post[i] F(u),
+and the lanes' SUM goes to the last norm.  `q_lora_rank` gives q a latent
+of its own, `rope_scaling` YaRN's tables and the softmax its m^2
+(`models/llama.py::RopeScaling`).  `mtp_layers` 1 puts DeepSeek-V3's
+multi-token-prediction module (arXiv:2412.19437, section 2.2) behind the
+trunk, `mtp_0/{hnorm, enorm, eh_proj, block_0, norm}`:
+
+    g      = [RMSNorm(x_t) ; RMSNorm(embed[id_{t+1}])] W_eh    (2 d -> d)
+    logits2 = RMSNorm(block(g)) @ W_head       the SAME table and head
+
+with x_t the trunk's summed, un-normed output; the model sows
+`mtp_logits` and `mtp_loss_weight`, and `collect_mtp_loss` adds
+lambda x the cross-entropy of logits2[t] against id_{t+2} (`labels[:, t
++ 1]`; the last position has none) to the loss
+(`trainer/train_step.py::make_lm_loss`).  id_{t+1} is read off the ids
+the model is given, shifted by one (`labels[:, t]` for every t the term
+counts).
+
 Nothing here is a copy: the attention is `LatentAttention`, the norms
 `models/llama.py`'s `RMSNorm`, the dense feed-forward its `LlamaMLP`, the
 expert layer `models/moe.py`'s `MoEMLP` on its grouped path
@@ -28,7 +51,9 @@ attention,post_attn_norm,feed_forward}`, `embed_tokens`, `norm`,
 Not built: a vision or audio tower in front of the embedding (text ids
 go in), a limit on the groups of experts a token may choose from
 (`n_group` = `topk_group` = 1 is the only form), a sequence-wise
-auxiliary loss.
+auxiliary loss, several lanes on a mesh (the (b, n, T, d) carry has no
+pins: refused), two widths of attention on a mesh, a server's cache of
+latents.
 
 Parity: none — the reference trains Llama/GLM-class stacks only; this
 stack exists for the latent-attention MoE's benchmark cell.
@@ -40,12 +65,14 @@ import dataclasses
 from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from ..parallel.sharding import pin_activation
+from . import hyper_connection as hc
 from . import stack
 from .latent_attention import LatentAttention, LatentAttentionConfig
-from .llama import LlamaConfig, LlamaMLP, RMSNorm, rope_freqs
+from .llama import LlamaConfig, LlamaMLP, RMSNorm, RopeScaling, rope_freqs
 from .moe import MoEConfig, MoEMLP
 
 
@@ -63,10 +90,21 @@ class LatentMoEConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     kv_lora_rank: int = 512
-    q_lora_rank: Optional[int] = None  # LatentAttention refuses one
+    q_lora_rank: Optional[int] = None  # a latent for q as well
     max_seq_len: int = 131072
     rope_theta: float = 800000.0
+    rope_scaling: Optional[RopeScaling] = None  # YaRN
     rms_eps: float = 1e-5
+    # the residual stream: lanes (1 = x + branch), mixed by manifold-
+    # constrained hyper-connections (models/hyper_connection.py)
+    residual_lanes: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: tuple = (-30.0, 30.0)
+    # multi-token prediction: modules behind the trunk (0 or 1) and the
+    # weight of their cross-entropy in the loss
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.3
     # the expert layer: SwiGLU experts of `expert_width`, the router over
     # all `num_experts`, of which this chip holds `experts_held` from
     # `first_expert` on (0 = all); a shared SwiGLU of shared_experts x
@@ -96,8 +134,17 @@ class LatentMoEConfig:
             v_head_dim=16, kv_lora_rank=24, max_seq_len=64, num_experts=8,
             top_k=3, expert_width=32), **over})
 
+    def hyper_config(self) -> hc.HyperConnectionConfig:
+        return hc.HyperConnectionConfig(
+            hidden_size=self.hidden_size, lanes=self.residual_lanes,
+            sinkhorn_iters=self.hc_sinkhorn_iters, eps=self.hc_eps,
+            norm_eps=self.rms_eps, clamp=self.hc_res_clamp)
+
     def attention_config(self) -> LatentAttentionConfig:
+        scaling = self.rope_scaling
         return LatentAttentionConfig(
+            attn_scale=(self.qk_nope_head_dim + self.qk_rope_head_dim)
+            ** -0.5 * scaling.softmax_mscale ** 2 if scaling else 0.0,
             hidden_size=self.hidden_size, num_heads=self.num_heads,
             qk_nope_head_dim=self.qk_nope_head_dim,
             qk_rope_head_dim=self.qk_rope_head_dim,
@@ -132,9 +179,15 @@ class LatentMoEConfig:
             dense, moe=self.moe_config(), intermediate_size=self.expert_width)
         n_dense = min(self.first_dense_layers, self.num_layers)
         per_block = self.attention_config().attention_params() + 2 * h
+        if self.residual_lanes > 1:
+            per_block += 2 * self.hyper_config().num_params()
+        # an MTP module: two norms, the joining product, an expert block,
+        # its last norm
+        mtp = self.mtp_layers * (3 * h + 2 * h * h + per_block
+                                 + experts.ffn_params())
         return (2 * self.vocab_size * h + h + self.num_layers * per_block
                 + n_dense * dense.ffn_params()
-                + (self.num_layers - n_dense) * experts.ffn_params())
+                + (self.num_layers - n_dense) * experts.ffn_params() + mtp)
 
 
 class LatentMoEBlock(nn.Module):
@@ -146,6 +199,8 @@ class LatentMoEBlock(nn.Module):
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
+        if cfg.residual_lanes > 1:
+            return self._lanes(x, cos, sin)
         x = pin_activation(x, cfg.mesh)
         h = RMSNorm(cfg.rms_eps, cfg.dtype, name="input_norm")(x)
         attn = LatentAttention(cfg.attention_config(), name="attention")(
@@ -153,12 +208,70 @@ class LatentMoEBlock(nn.Module):
         # the save/offload anchors of the *_names remat policies
         x = x + checkpoint_name(attn, "attn_out")
         u = RMSNorm(cfg.rms_eps, cfg.dtype, name="post_attn_norm")(x)
+        return x + checkpoint_name(self._feed_forward(u), "mlp_out")
+
+    @nn.nowrap
+    def _feed_forward(self, u):
+        cfg = self.config
         if self.layer < cfg.first_dense_layers:
-            out = LlamaMLP(cfg.dense_config(), name="feed_forward")(u)
-        else:
-            out = MoEMLP(cfg.hidden_size, cfg.expert_width, cfg.moe_config(),
-                         name="feed_forward")(u)
-        return x + checkpoint_name(out, "mlp_out")
+            return LlamaMLP(cfg.dense_config(), name="feed_forward")(u)
+        return MoEMLP(cfg.hidden_size, cfg.expert_width, cfg.moe_config(),
+                      name="feed_forward")(u)
+
+    @nn.nowrap
+    def _lanes(self, x, cos, sin):
+        """x (b, n, T, d): each sublayer between a hyper-connection's
+        read and its write."""
+        from jax.ad_checkpoint import checkpoint_name
+
+        cfg, hyper = self.config, self.config.hyper_config()
+
+        def around(x, name, norm, branch, anchor):
+            leaves = hc.HyperConnection(hyper, name=f"{name}_hc")()
+            h_pre, h_post, h_res = hc.coefficients(leaves, x, hyper)
+            self.sow("intermediates", "hc_sinkhorn_err",
+                     jax.lax.stop_gradient(hc.sinkhorn_err(h_res)))
+            u = RMSNorm(cfg.rms_eps, cfg.dtype, name=norm)(
+                hc.read(h_pre, x))
+            return hc.write(h_res, h_post, x,
+                            checkpoint_name(branch(u), anchor))
+
+        x = around(x, "attention", "input_norm",
+                   lambda u: LatentAttention(
+                       cfg.attention_config(), name="attention")(u, cos, sin),
+                   "attn_out")
+        return around(x, "feed_forward", "post_attn_norm",
+                      self._feed_forward, "mlp_out")
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction module: the trunk's un-normed output
+    and the next token's embedding, each normed, joined by one product,
+    through one more expert block, to its own last norm."""
+    config: LatentMoEConfig
+    layer: int  # the block's index in the depth (an expert layer)
+
+    @nn.compact
+    def __call__(self, x, next_embedding, cos, sin):
+        cfg = self.config
+        g = jnp.concatenate(
+            [RMSNorm(cfg.rms_eps, cfg.dtype, name="hnorm")(x),
+             RMSNorm(cfg.rms_eps, cfg.dtype, name="enorm")(next_embedding)],
+            axis=-1)
+        g = nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
+                     name="eh_proj")(g)
+        g = _through(cfg, [(self.layer,)], g, cos, sin, prefix="block")
+        return RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(g)
+
+
+def _through(cfg, per_layer, x, cos, sin, prefix: str = "layers"):
+    """x (b, T, d) through the blocks: as it is with one lane; with
+    several, every lane starts as x and their sum comes back."""
+    if cfg.residual_lanes > 1:
+        x = hc.expand(x, cfg.residual_lanes)
+    x = stack.layers(LatentMoEBlock, cfg, per_layer, x, cos, sin,
+                     prefix=prefix)
+    return hc.read_out(x) if cfg.residual_lanes > 1 else x
 
 
 class LatentMoE(nn.Module):
@@ -166,21 +279,60 @@ class LatentMoE(nn.Module):
 
     # leaves the optimizer leaves alone, as models/nemotron_h.py's: the
     # selection bias has no gradient, its rule runs out of band
-    untrained_params = (r"layers_\d+/feed_forward/selection_bias",)
+    untrained_params = (
+        r"(layers|mtp_\d+/block)_\d+/feed_forward/selection_bias",)
 
     @nn.compact
     def __call__(self, idx):
         cfg = self.config
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
-                     name="embed_tokens")(idx)
+        if cfg.residual_lanes > 1 and cfg.mesh is not None \
+                and cfg.mesh.size > 1:
+            raise ValueError(
+                f"residual_lanes={cfg.residual_lanes}: the (b, n, T, d) "
+                f"stream runs on one device (no pins on a mesh)")
+        embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                         name="embed_tokens")
+        x = embed(idx)
         # the rotated part alone carries the positions
         cos, sin = rope_freqs(cfg.qk_rope_head_dim, cfg.max_seq_len,
-                              cfg.rope_theta)
-        x = stack.layers(LatentMoEBlock, cfg,
-                         [(i,) for i in range(cfg.num_layers)], x, cos, sin)
-        return stack.untied_head(
-            RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x),
+                              cfg.rope_theta, cfg.rope_scaling)
+        x = _through(cfg, [(i,) for i in range(cfg.num_layers)], x, cos, sin)
+        if not cfg.mtp_layers:
+            return stack.untied_head(
+                RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x),
+                cfg.vocab_size, cfg.dtype)
+        if cfg.mtp_layers != 1:
+            raise ValueError("one multi-token-prediction module or none")
+        # id_{t+1} off the ids, shifted; the last position's is masked out
+        # of the term, so what stands there is never read
+        following = jnp.concatenate([idx[:, 1:], idx[:, -1:]], axis=1)
+        second = MTPModule(cfg, cfg.num_layers, name="mtp_0")(
+            x, embed(following), cos, sin)
+        logits, mtp_logits = stack.untied_heads(
+            [RMSNorm(cfg.rms_eps, cfg.dtype, name="norm")(x), second],
             cfg.vocab_size, cfg.dtype)
+        self.sow("intermediates", "mtp_logits", mtp_logits)
+        self.sow("intermediates", "mtp_loss_weight",
+                 jnp.float32(cfg.mtp_loss_weight))
+        return logits
 
     def init_params(self, rng, batch: int = 1, seq: int = 8):
         return stack.init_params(self, rng, batch, seq)
+
+
+def collect_mtp_loss(intermediates, labels):
+    """(lambda x the second prediction's cross-entropy, that
+    cross-entropy) of a forward pass that sowed `mtp_logits`, or None:
+    position t's second logits against `labels[:, t + 1]`, the last
+    position left out."""
+    from .gpt import cross_entropy_loss
+    from .moe import _sown
+
+    logits = list(_sown(intermediates, "mtp_logits"))
+    if not logits:
+        return None
+    weight, = _sown(intermediates, "mtp_loss_weight")
+    targets = jnp.concatenate(
+        [labels[:, 1:], jnp.full_like(labels[:, :1], -1)], axis=1)
+    ce = cross_entropy_loss(logits[0], targets)
+    return weight.reshape(()) * ce, ce

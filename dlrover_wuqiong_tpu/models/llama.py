@@ -130,11 +130,61 @@ class RMSNorm(nn.Module):
         return (norm * scale).astype(self.dtype)
 
 
-def rope_freqs(head_dim: int, max_seq: int, theta: float):
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN (Peng et al., arXiv:2309.00071) as the DeepSeek-V2/V3 family's
+    published code applies it: the slow pairs' frequencies are divided by
+    `factor`, the fast ones kept, a linear ramp between the pairs that
+    turn `beta_fast` and `beta_slow` times over the original positions;
+    the tables carry `mscale / mscale_all_dim`'s ratio and the SOFTMAX's
+    scale the square of `softmax_mscale`."""
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _mscale(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    @property
+    def table_mscale(self) -> float:
+        return self._mscale(self.factor, self.mscale) \
+            / self._mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_mscale(self) -> float:
+        """m: the softmax's 1 / sqrt(d) is multiplied by m * m."""
+        return self._mscale(self.factor, self.mscale_all_dim)
+
+    def ramp_ends(self, head_dim: int, theta: float) -> tuple:
+        """(lo, hi): the pairs between which the ramp runs."""
+        def pair(turns):
+            return head_dim * math.log(
+                self.original_max_position_embeddings
+                / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+        return (max(math.floor(pair(self.beta_fast)), 0),
+                min(math.ceil(pair(self.beta_slow)), head_dim - 1))
+
+
+def rope_freqs(head_dim: int, max_seq: int, theta: float,
+               scaling: Optional[RopeScaling] = None):
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
                                       dtype=jnp.float32) / head_dim))
+    if scaling is not None:
+        lo, hi = scaling.ramp_ends(head_dim, theta)
+        ramp = (jnp.arange(head_dim // 2, dtype=jnp.float32) - lo) \
+            / max(hi - lo, 0.001)
+        kept = 1.0 - jnp.clip(ramp, 0.0, 1.0)  # 1 = the unscaled pair
+        inv = inv / scaling.factor * (1.0 - kept) + inv * kept
     t = jnp.arange(max_seq, dtype=jnp.float32)
     freqs = jnp.outer(t, inv)  # (seq, head_dim/2)
+    if scaling is not None and scaling.table_mscale != 1.0:
+        return (jnp.cos(freqs) * scaling.table_mscale,
+                jnp.sin(freqs) * scaling.table_mscale)
     return jnp.cos(freqs), jnp.sin(freqs)
 
 

@@ -56,9 +56,15 @@ def layers(block, cfg, per_layer, x, *shared, prefix: str = "layers",
 
 def untied_head(x, vocab_size: int, dtype):
     """Logits of the normed stream x through the head's own matrix."""
+    return untied_heads([x], vocab_size, dtype)[0]
+
+
+def untied_heads(xs, vocab_size: int, dtype) -> list:
+    """Logits of several normed streams through ONE head matrix (a
+    multi-token-prediction module shares the trunk's)."""
+    head = nn.Dense(vocab_size, use_bias=False, dtype=dtype, name="lm_head")
     with jax.named_scope("head"):
-        return nn.Dense(vocab_size, use_bias=False, dtype=dtype,
-                        name="lm_head")(x)
+        return [head(x) for x in xs]
 
 
 def tied_head(x, table, dtype, scaled=None):

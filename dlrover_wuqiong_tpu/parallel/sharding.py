@@ -66,6 +66,18 @@ TRANSFORMER_RULES: List[Rule] = [
     (r".*(attn|attention).*kv_a_proj/kernel$", P("fsdp", None)),
     (r".*(attn|attention).*kv_b_proj/kernel$", P("fsdp", "tp")),
     (r".*(attn|attention).*kv_a_norm/scale$", P()),
+    # a latent for q goes as the one for keys and values: whole columns
+    # down, heads over tp up, a norm between
+    (r".*(attn|attention).*q_a_proj/kernel$", P("fsdp", None)),
+    (r".*(attn|attention).*q_b_proj/kernel$", P("fsdp", "tp")),
+    (r".*(attn|attention).*q_a_norm/scale$", P()),
+    # a hyper-connection (models/hyper_connection.py): Phi's rows are the
+    # lanes' hidden features, its 24 columns stay whole; gains and biases
+    # are a few numbers
+    (r".*_hc/phi$", P(None, "fsdp", None)),
+    (r".*_hc/(alpha|b_pre|b_post|b_res)$", P()),
+    # a multi-token-prediction module's joining product: column-parallel
+    (r".*mtp_\d+/eh_proj/kernel$", P("fsdp", "tp")),
     # attention out: row-parallel (parity RowParallelLinear :239)
     (r".*(attn|attention).*(o_proj|out_proj|c_proj|dense|out)/kernel$",
      P("tp", "fsdp")),

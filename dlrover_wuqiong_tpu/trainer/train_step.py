@@ -321,13 +321,16 @@ def make_lm_loss(model_apply: Callable) -> Callable:
     """Standard causal-LM loss over a batch dict {input_ids, labels}.
 
     Collects sown auxiliary losses (MoE load-balancing, router z-loss)
-    when present.  `loss_fn.with_stats(params, batch) -> (loss, stats)` is
-    the same loss with what the MoE layers, the windowed or latent
-    attention layers and the gated delta-rule mixers counted
-    (`collect_moe_stats`, `collect_attention_stats`, `collect_delta_stats`;
-    {} for a dense model without any of them): `make_train_step`
-    differentiates that one and returns the counters in the step's
-    metrics."""
+    and a multi-token-prediction module's weighted cross-entropy
+    (`models/latent_moe.collect_mtp_loss`: its target is `labels` one
+    further on) when present.  `loss_fn.with_stats(params, batch) ->
+    (loss, stats)` is the same loss with what the MoE layers, the
+    windowed or latent attention layers, the gated delta-rule mixers and
+    the hyper-connections counted (`collect_moe_stats`,
+    `collect_attention_stats`, `collect_delta_stats`,
+    `collect_residual_stats`; {} for a dense model without any of them),
+    and `mtp_ce` beside them: `make_train_step` differentiates that one
+    and returns the counters in the step's metrics."""
     from ..models.gpt import cross_entropy_loss
 
     def with_stats(params, batch):
@@ -340,6 +343,8 @@ def make_lm_loss(model_apply: Callable) -> Callable:
         if inter:
             from ..models.attention import collect_attention_stats
             from ..models.gated_delta import collect_delta_stats
+            from ..models.hyper_connection import collect_residual_stats
+            from ..models.latent_moe import collect_mtp_loss
             from ..models.moe import (
                 collect_moe_aux_loss,
                 collect_moe_stats,
@@ -349,7 +354,11 @@ def make_lm_loss(model_apply: Callable) -> Callable:
             loss = loss + collect_moe_aux_loss(inter)
             stats = {**collect_moe_stats(inter),
                      **collect_attention_stats(inter),
-                     **collect_delta_stats(inter)}
+                     **collect_delta_stats(inter),
+                     **collect_residual_stats(inter)}
+            mtp = collect_mtp_loss(inter, batch["labels"])
+            if mtp is not None:
+                loss, stats["mtp_ce"] = loss + mtp[0], mtp[1]
             steps = collect_param_steps(inter)
             if steps:
                 stats["param_steps"] = steps

@@ -28,10 +28,58 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+import collections  # noqa: E402
 import json  # noqa: E402
 import threading  # noqa: E402
 
 import pytest  # noqa: E402
+
+# Under xdist's `--dist load` (the driver's command) a worker's first
+# chunk is `tests // workers // 4` CONSECUTIVE tests and later chunks
+# shrink with what is pending, so the last files of the alphabet reach
+# the workers two tests at a time: a module fixture of theirs is then
+# wanted by every worker at once.  The files whose module fixtures
+# compile a whole step for a described chip (25 to 120 s each; built
+# once a run behind a file lock, tests/test_tpu_compile.py::_once_a_run,
+# so the others would WAIT) each open one worker's first chunk, and
+# files of many small independent cases close the run, where the chunks
+# are smallest.  The files that read a CPU profile go before the first
+# of them: a process that has loaded the TPU's compiler traces no CPU op
+# (`OpProfile(categories={})`), and every worker loads it in its first
+# chunk now.  Every worker collects and reorders alike.
+_BEFORE_THE_TPU_COMPILER = ("test_xplane.py", "test_trainer.py",
+                            "test_perf.py", "test_metrics.py")
+_OPEN_A_CHUNK = ("test_xing4_0_compile.py", "test_tpu_compile.py",
+                 "test_kimi_vl_compile.py", "test_smallthinker_compile.py",
+                 "test_olmo_hybrid_compile.py", "test_scale_8b.py")
+_CLOSE_THE_RUN = ("test_program_from_arguments.py", "test_ssd_kernel.py",
+                  "test_moe_rows.py", "test_flash_attention_tiles.py")
+
+
+def pytest_collection_modifyitems(config, items):
+    workers = int(os.environ.get("PYTEST_XDIST_WORKER_COUNT") or 0)
+    if workers < 2:
+        return
+    by_file = collections.defaultdict(list)
+    for item in items:
+        by_file[os.path.basename(str(item.fspath))].append(item)
+    moved = {*_BEFORE_THE_TPU_COMPILER, *_OPEN_A_CHUNK, *_CLOSE_THE_RUN}
+    rest = iter([item for item in items
+                 if os.path.basename(str(item.fspath)) not in moved])
+    chunk = max(len(items) // workers // 4, 2)
+    openers = [by_file.get(name, []) for name in _OPEN_A_CHUNK]
+    openers[0] = [item for name in _BEFORE_THE_TPU_COMPILER
+                  for item in by_file.get(name, [])] + openers[0]
+    ordered = []
+    for opener in openers:  # each starts on a chunk's boundary
+        ordered += opener
+        ordered += [item for _, item in zip(range(-len(opener) % chunk),
+                                            rest)]
+    ordered += list(rest)
+    for name in _CLOSE_THE_RUN:
+        ordered += by_file.get(name, [])
+    assert len(ordered) == len(items)
+    items[:] = ordered
 
 
 @pytest.fixture(scope="session")

@@ -95,6 +95,10 @@ def test_can_run_in_process_does_not_initialize_backends(monkeypatch):
     assert not graft._can_run_in_process(2)
 
 
+# slow: the dry run's six compiles in a child process are what
+# `test_dryrun_subprocess_path_from_noncpu_backend` holds (at 8 devices
+# for this one's 4); what this adds is the child's environment
+@pytest.mark.slow
 def test_dryrun_subprocess_env_is_clean():
     """The re-exec must force JAX_PLATFORMS=cpu and the device-count flag
     even when the caller env carries conflicting values."""

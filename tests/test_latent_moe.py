@@ -145,10 +145,16 @@ def test_latent_attention_with_one_term_wrong_is_told_apart(wrong):
     assert float(jnp.abs(want - other).max()) > 1e-3
 
 
-def test_a_q_latent_is_refused_not_guessed():
+def test_a_q_latent_is_built_not_refused():
+    """`q_lora_rank` was refused until PR 51; it is the down-projection,
+    its norm and the up-projection now (tests/test_xing4_0.py holds them
+    to the reference), and `q_proj` is gone from such a layer."""
     _, layer, x, cos, sin = _attention(q_lora_rank=32)
-    with pytest.raises(ValueError, match="q_lora_rank"):
-        layer.init(jax.random.PRNGKey(1), x, cos, sin)
+    params = layer.init(jax.random.PRNGKey(1), x, cos, sin)["params"]
+    assert "q_proj" not in params
+    assert params["q_a_proj"]["kernel"].shape == (64, 32)
+    assert params["q_a_norm"]["scale"].shape == (32,)
+    assert params["q_b_proj"]["kernel"].shape == (32, 4 * 24)
 
 
 def test_latent_attention_on_a_mesh_is_refused():
@@ -361,7 +367,7 @@ def test_no_leaf_of_the_stack_falls_to_an_unnamed_default():
 
 def test_the_selection_bias_is_left_to_its_rule():
     assert LatentMoE.untrained_params == (
-        r"layers_\d+/feed_forward/selection_bias",)
+        r"(layers|mtp_\d+/block)_\d+/feed_forward/selection_bias",)
     cfg = _nano(bias_update_rate=0.01)
     _, stats = make_lm_loss(LatentMoE(cfg).apply).with_stats(
         _params(cfg), _batch())

@@ -24,13 +24,13 @@ SUBSCOPES = ("q_proj", "k_proj", "v_proj", "g_proj", "gates", "conv",
 
 
 @pytest.fixture(scope="module")
-def olmo_step(topo):
+def olmo_step(request):
     """`olmo_hybrid_7b.steady`'s step — published widths, one period
     (three gated delta-rule mixers at fifteen of thirty heads, chunk 64,
     one attention layer of thirty heads of 128, a SwiGLU of 11,008 behind
     each), the untied head on an eighth of the vocabulary, one
     8192-token sequence, full recomputation (about 50 s)."""
-    return _one_chip_step(topo, "olmo_hybrid_7b.steady", "olmo_hybrid")
+    return _one_chip_step(request, "olmo_hybrid_7b.steady", "olmo_hybrid")
 
 
 def test_olmo_step_fits_one_chip_by_the_rule_and_fills_it(olmo_step):

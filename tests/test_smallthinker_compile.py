@@ -33,12 +33,12 @@ from dlrover_wuqiong_tpu.ops import flash_attention as fa
 # ------------------------------- SmallThinker-21B-A3B's step on one chip
 
 @pytest.fixture(scope="module")
-def smallthinker_step(topo):
+def smallthinker_step(request):
     """`smallthinker_21b_a3b.steady`'s step — published widths, one
     period (a global no-position layer and three windowed RoPE layers),
     16 of 64 ReGLU experts held, an eighth of the vocabulary, the cell's
     batch of 16,384-token sequences, full recomputation (about 50 s)."""
-    return _one_chip_step(topo, "smallthinker_21b_a3b.steady",
+    return _one_chip_step(request, "smallthinker_21b_a3b.steady",
                           "smallthinker")
 
 

@@ -12,9 +12,11 @@ runs, so nothing here is a statement about results or speed.
 
 import collections
 import contextlib
+import json
 import math
 import os
 import re
+import types
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -64,6 +66,56 @@ def _no_persistent_cache():
 
 def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
+
+
+class _Step:
+    """What the tests read of a compiled step, as plain data: its text
+    and the memory analysis' numbers."""
+
+    _FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
+               "temp_size_in_bytes", "alias_size_in_bytes",
+               "generated_code_size_in_bytes")
+
+    def __init__(self, text: str, memory: dict):
+        self._text, self._memory = text, memory
+
+    @classmethod
+    def of(cls, compiled):
+        m = compiled.memory_analysis()
+        return cls(compiled.as_text(), {k: getattr(m, k) for k in cls._FIELDS})
+
+    def plain(self) -> list:
+        return [self._text, self._memory]
+
+    def as_text(self) -> str:
+        return self._text
+
+    def memory_analysis(self):
+        return types.SimpleNamespace(**self._memory)
+
+
+def _once_a_run(request, key: str, build):
+    """`build(topo)`'s {name: _Step}, compiled ONCE A RUN however many
+    xdist workers are handed tests of the file (`--dist load` sends them
+    to whichever is free, and a module fixture is built a worker):
+    pytest-xdist's recipe for a session's shared data — the first worker
+    to take the lock beside the run's base directory builds and writes,
+    the others read.  A whole step compiles for 25 to 180 s and its text
+    reads back in well under one."""
+    from filelock import FileLock
+
+    base = request.getfixturevalue("tmp_path_factory").getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        base = base.parent  # the workers' own directories lie in the run's
+    path = base / f"compiled_{key}.json"
+    with FileLock(str(path) + ".lock"):
+        if path.is_file():
+            kept = json.loads(path.read_text())
+        else:
+            steps = build(request.getfixturevalue("topo"))
+            kept = {name: step.plain() for name, step in steps.items()}
+            path.write_text(json.dumps(kept))
+    return {name: _Step(*data) for name, data in kept.items()}
 
 
 # (bh, T, d, causal): GPT-2's heads at the default 8-head pack; GPT-2 XL's
@@ -196,10 +248,15 @@ XL_BATCH, XL_SEQ, XL_WIDTH = 16, 1024, 1600
 
 
 @pytest.fixture(scope="module")
-def xl_fsdp4_steps(topo):
-    """{depth: Compiled} of `gpt2_xl.fsdp4_steady`'s step at 2 and 3
+def xl_fsdp4_steps(request):
+    """{depth: step} of `gpt2_xl.fsdp4_steady`'s step at 2 and 3
     layers: XL widths, batch 16 x 1024, full remat, Trainer's optimizer,
-    fsdp over the described 2x2 (about 25 s a compile)."""
+    fsdp over the described 2x2 (about 25 s a compile, once a run)."""
+    steps = _once_a_run(request, "gpt2_xl.fsdp4", _xl_fsdp4_steps)
+    return {int(depth): step for depth, step in steps.items()}
+
+
+def _xl_fsdp4_steps(topo):
     import optax
 
     from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
@@ -222,8 +279,8 @@ def xl_fsdp4_steps(topo):
             assert res.model.config.mesh is res.mesh
             ids = jax.ShapeDtypeStruct((XL_BATCH, XL_SEQ), jnp.int32,
                                        sharding=res.batch_sharding_fn(2))
-            steps[depth] = res.train_step.lower(
-                res.state, {"input_ids": ids, "labels": ids}).compile()
+            steps[str(depth)] = _Step.of(res.train_step.lower(
+                res.state, {"input_ids": ids, "labels": ids}).compile())
     return steps
 
 
@@ -283,17 +340,28 @@ def test_fsdp4_step_temporaries_fit(xl_fsdp4_steps):
 
 # ------------------------------------- the control's step on one chip
 
-def _one_chip_step(topo, name, model_file):
-    """Cell `name`'s step as its configuration file builds it, the
-    cell's batch, Trainer's optimizer, compiled for ONE described v5e
-    chip: (cell, model, Compiled)."""
-    import optax
-
+def _one_chip_step(request, name, model_file, **config):
+    """Cell `name`'s step as its configuration file builds it (`config`
+    replaces top-level keys of the file), the cell's batch, Trainer's
+    optimizer, compiled for ONE described v5e chip, once a run
+    (`_once_a_run`): (cell, model, step) — the step as the tests read
+    it, its text and its memory analysis."""
     from benchmark import cells
-    from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
 
     cell = cells.load_cell(name)
+    cell["config"].update(config)
     model = cells.load_module("models", model_file).build(cell["config"])
+    key = "_".join([name, *(f"{k}={v}" for k, v in sorted(config.items()))])
+    step = _once_a_run(request, key, lambda topo: {
+        "step": _compile_one_chip_step(topo, cell, model)})["step"]
+    return cell, model, step
+
+
+def _compile_one_chip_step(topo, cell, model):
+    import optax
+
+    from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
+
     with pytest.MonkeyPatch.context() as mp, _cache_off():
         mp.setenv("DWT_COMPILE_CACHE", "0")
         mp.setattr(fa, "_on_tpu", lambda: True)
@@ -312,15 +380,14 @@ def _one_chip_step(topo, name, model_file):
         ids = jax.ShapeDtypeStruct(
             (cell["global_batch"], cell["seq_len"]), jnp.int32,
             sharding=res.batch_sharding_fn(2))
-        step = res.train_step.lower(
-            res.state, {"input_ids": ids, "labels": ids}).compile()
-    return cell, model, step
+        return _Step.of(res.train_step.lower(
+            res.state, {"input_ids": ids, "labels": ids}).compile())
 
 
 @pytest.fixture(scope="module")
-def gpt2_124m_step(topo):
+def gpt2_124m_step(request):
     """`gpt2_124m.steady`'s step (about 30 s)."""
-    return _one_chip_step(topo, "gpt2_124m.steady", "gpt")
+    return _one_chip_step(request, "gpt2_124m.steady", "gpt")
 
 
 def test_124m_step_moves_nothing_around_its_attention_kernels(
@@ -349,10 +416,10 @@ def test_124m_step_moves_nothing_around_its_attention_kernels(
 # ------------------------------------------------- OLMoE's step on one chip
 
 @pytest.fixture(scope="module")
-def olmoe_step(topo):
+def olmoe_step(request):
     """`olmoe_1b_7b.steady`'s step — published widths, depth 1, all 64
     experts, the cell's batch of 4096-token sequences (about 50 s)."""
-    return _one_chip_step(topo, "olmoe_1b_7b.steady", "olmoe")
+    return _one_chip_step(request, "olmoe_1b_7b.steady", "olmoe")
 
 
 def test_olmoe_step_fits_one_chip_and_fills_it(olmoe_step):
@@ -428,12 +495,12 @@ def test_olmoe_step_keeps_its_scopes_and_names_the_grouped_matmuls(
 # ------------------------------------- Nemotron-3-Nano's step on one chip
 
 @pytest.fixture(scope="module")
-def nemotron_step(topo):
+def nemotron_step(request):
     """`nemotron3_nano_30b_a3b.steady`'s step — published widths, nine
     layers (4 Mamba-2, 4 expert, 1 attention), 8 of 128 experts held, the
     cell's batch of 8192-token sequences, full recomputation (about
     50 s)."""
-    return _one_chip_step(topo, "nemotron3_nano_30b_a3b.steady",
+    return _one_chip_step(request, "nemotron3_nano_30b_a3b.steady",
                           "nemotron_h")
 
 
@@ -777,13 +844,13 @@ def test_nemotron_step_walks_its_row_buffer_in_gathers_alone(nemotron_step):
 # --------------------------------- granite-4.0-h-micro's step on one chip
 
 @pytest.fixture(scope="module")
-def granite_step(topo):
+def granite_step(request):
     """`granite4_h_micro.steady`'s step — published widths, one period
     (nine Mamba-2 layers at ONE state group and chunk 256, one attention
     layer, a SwiGLU of 8192 behind each), the tied head on an eighth of
     the table, one 8192-token sequence, full recomputation (about
     60 s)."""
-    return _one_chip_step(topo, "granite4_h_micro.steady", "granite_hybrid")
+    return _one_chip_step(request, "granite4_h_micro.steady", "granite_hybrid")
 
 
 def test_granite_step_fits_one_chip_by_the_rule_and_fills_it(granite_step):
