@@ -61,11 +61,12 @@ Design (FA2 scheme, canonical Mosaic structure):
   output block of the same span: that block's index is constant over
   both inner grid axes, so it stays in VMEM from the group's first grid
   step to its last (16,384 x 128 lanes: 8 MiB of scratch and 4 of
-  bfloat16 block, double-buffered; the packed heads a grid step are as
-  many as fit).  Where a unit's dq does not fit the VMEM the call states
-  (131,072 x 128 lanes) it is two kernels — dq (grid: q outer, kv
-  inner) and dk/dv — each with a recompute of its own, seven products
-  and two passes.  `backward_route` says which, from shapes alone; the
+  bfloat16 block, double-buffered; the packed heads a grid step are
+  two where two fit, since PR 52 never more).  Where a unit's dq does
+  not fit the VMEM the call states (131,072 x 128 lanes) it is two
+  kernels — dq (grid: q outer, kv inner) and dk/dv — each with a
+  recompute of its own, seven products and two passes.
+  `backward_route` says which, from shapes alone; the
   kernels' names say it in a trace (`dwt_fa_bwd_fused`, or
   `dwt_fa_bwd_dq` + `dwt_fa_bwd_dkv`).
 - TWO LAYOUTS, one set of kernels.  TRANSPOSED: `flash_attention` /
@@ -555,7 +556,10 @@ def _swept(steps: int, koff: int, limit: int, by_keys: bool):
 
 # heads per iteration of the loop over a tiled block's packed heads: pairs
 # ran as fast as or faster than 1, 4 and all of them (unrolled) at all
-# three swept shapes (PERF.md section 6, PR 27)
+# three swept shapes (PERF.md section 6, PR 27).  Also the most units a
+# grid step `backward_route` gives a several-block fused sweep, whose
+# whole blocks `_each_head` unrolls: held four or eight times beside the
+# looped tiles, the body ran at 0.57-0.75 of its speed at two (PR 52)
 _HEAD_GROUP = 2
 
 
@@ -565,7 +569,11 @@ def _each_head(pack: int, work, body):
     are traced, lowered and held as instructions once or twice, not
     `pack` times (unrolled, they added a quarter to a warm `setup_s`, and
     a several-block kernel ran at half the speed).  A block computed
-    whole keeps the Python loop it always had."""
+    whole keeps the Python loop it always had: the forward and the
+    one-block backward run it at any pack; the several-block fused
+    sweep, which holds a whole block's body BESIDE the diagonal block's
+    tiles, is handed at most `_HEAD_GROUP` units (`backward_route`), so
+    nothing of it is unrolled further than the loop's own body is."""
     if len(work) == 1 or pack <= _HEAD_GROUP:
         for hh in range(pack):
             body(hh)
@@ -1384,7 +1392,11 @@ def _fused_bwd_vmem(pack: int, sq: int, block_q: int, block_k: int,
     double-buffered, and two float32 score tiles a head of the slab for
     the values of the body (what Mosaic holds of them, compiled for a
     described v5e at the cells' shapes, is half of that: 43, 81, 24 and
-    22 MiB used where this says 46, 84, 28 and 29)."""
+    22 MiB used where this says 46, 84, 28 and 29).  It is the test of
+    whether a unit's dq fits AT ALL, and whether two units' do; above
+    two it picks nothing any more: the bytes were never what a larger
+    pack cost (PR 52: four units ran as slow at 4,096 rows, 64.5 MiB
+    reckoned, as at 8,192 rows and 96.5)."""
     wq, wv = (-(-w // 128) * 128 for w in (d_qk, d_v))
     dq = pack * sq * wq * (4 + 2 * itemsize)     # scratch, output block
     dkv = pack * block_k * (wq + wv) * (4 + 2 * itemsize)
@@ -1410,12 +1422,23 @@ def backward_route(sq: int, sk: int, d_qk: int, d_v: Optional[int] = None,
     sweep's blocks (`_fused_bwd_vmem`): 16,384 x 128 lanes is 16 MiB,
     131,072 x 128 does not fit and takes the pair.  `slabs`: the heads a
     slab of the direct layout (0: the transposed layout, whose `bh`
-    heads are packed by the largest of 8/4/2/1 that divides them and
-    fits).
+    heads the one-block kernel and the pair pack by the largest of
+    8/4/2/1 that divides them).
+
+    A several-block fused sweep takes `_HEAD_GROUP` units a grid step
+    where two divide the heads and fit, else one: the most `_each_head`
+    runs without unrolling a whole block's body further than its loop
+    over a tiled block does.  Until PR 52 it took the largest of 8/4/2/1
+    that fit, by the byte count alone; timed on the chip (32 heads, 192
+    | 128 wide, ms a call at 8 / 4 / 2 / 1 units): 8,192 rows - / 23.44
+    / 13.46 / 13.66, 4,096 rows - / 6.03 / 3.62 / 3.68, 2,048 rows 1.35
+    / 1.61 / 1.00 / 1.02, Kimi's 16,384 rows - / - / 51.90 / 52.58
+    (PERF.md section 6, PR 52).
 
     The counter of this decision, as `attention_route` is of the layout
     and `causal_tile_count` of the tiles; in a trace its witness is the
-    kernels' names (`dwt_fa_bwd_fused` / `dwt_fa_bwd_dq` + `_dkv`)."""
+    kernels' names (`dwt_fa_bwd_fused` / `dwt_fa_bwd_dq` + `_dkv`) and
+    the sweep's grid (heads / units, key blocks, query blocks)."""
     d_v = d_qk if d_v is None else d_v
     d_qk, d_v = _kernel_head_dim(d_qk), _kernel_head_dim(d_v)
     block_q = _fit_block(sq, block_q) or sq
@@ -1423,7 +1446,7 @@ def backward_route(sq: int, sk: int, d_qk: int, d_v: Optional[int] = None,
     most = 1 if slabs else _fit_pack(bh)  # what the pair packs
     if sq == block_q and sk == block_k:
         return "fused", most
-    for pack in (8, 4, 2, 1):
+    for pack in (_HEAD_GROUP, 1):
         if pack <= most and _fused_bwd_vmem(
                 pack, sq, block_q, block_k, d_qk, d_v, slabs or 1,
                 itemsize) <= _VMEM_LIMIT:

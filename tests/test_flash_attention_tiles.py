@@ -273,8 +273,12 @@ def _route(route, sq, sk, block_q, block_k, bh, d, dv, form, heads) -> dict:
     slab_heads = fa.attention_route(heads, d)[1] if form else 0
     rule = fa.backward_route(sq, sk, d, dv, slab_heads, bh, block_q, block_k,
                              itemsize=4)
-    assert rule == ("fused", 1 if form else fa._fit_pack(bh))
-    return {"route": ("split", rule[1])} if route == "split" else {}
+    # one block and the pair pack all they can, a several-block sweep at
+    # most `_HEAD_GROUP` (PR 52)
+    most = 1 if form else fa._fit_pack(bh)
+    assert rule == ("fused", most if route == "one"
+                    else min(most, fa._HEAD_GROUP))
+    return {"route": ("split", most)} if route == "split" else {}
 
 
 def _kernel_names(jaxpr):
@@ -349,19 +353,27 @@ def test_tiled_backward_takes_the_lse_cotangent(sq, sk, block_q, block_k,
     # 16 MiB of float32 dq a head and as much of output block: two fit
     ("kimi_vl_a3b.steady", (16384, 16384, 192, 128, 0, 2 * 16),
      ("fused", 2)),
+    # four would fit (96.5 MiB reckoned) and ran at 0.57 of two's speed
+    ("xing4_0_29b_a4b.steady", (8192, 8192, 192, 128, 0, 32), ("fused", 2)),
+    # four at 64.5 MiB were as slow: never more than `_HEAD_GROUP`
+    ("32 heads at 4,096, transposed", (4096, 4096, 192, 128, 0, 32),
+     ("fused", 2)),
     # a head's dq alone is 64 MiB of scratch and 64 of output block
     ("128k at 128 lanes", (131072, 131072, 128, 128, 1, 16), ("split", 1)),
     ("128k, transposed", (131072, 131072, 128, 128, 0, 16), ("split", 8)),
     ("64k still fits", (65536, 65536, 128, 128, 1, 16), ("fused", 1)),
     # a ring step's block of keys: sq != sk, by the same rule
-    ("ring block", (4096, 16384, 128, 128, 0, 16), ("fused", 8)),
+    ("ring block", (4096, 16384, 128, 128, 0, 16), ("fused", 2)),
 ])
 def test_backward_route_at_the_cells_shapes(cell, shape, want):
     """`backward_route`: a static function of the call's shapes, fused
-    wherever a unit's whole dq fits the VMEM the call states."""
+    wherever a unit's whole dq fits the VMEM the call states, several
+    blocks at no more units a grid step than `_each_head` runs without
+    a loop."""
     assert fa.backward_route(*shape) == want
     sq, sk, d, dv, slabs, bh = shape
     if want[0] == "fused" and sq > 1024:
+        assert want[1] <= fa._HEAD_GROUP
         assert fa._fused_bwd_vmem(want[1], sq, 1024, 1024, d, dv, slabs or 1,
                                   2) <= fa._VMEM_LIMIT
 

@@ -9,6 +9,8 @@ route's answer.  The cases sit beside tests/test_flash_attention_tiles.
 py's and use its reference; equal widths stay that file's.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -99,6 +101,39 @@ def test_backward_takes_the_lse_cotangent(block, route):
         np.testing.assert_allclose(a, b, atol=5e-5)
 
 
+@functools.lru_cache(maxsize=None)
+def _sweep_at(blocks, d, dv, pack):
+    """(inputs, the fused sweep's dq, dk, dv) of eight heads on a
+    `blocks` x `blocks` grid of 64-row blocks at `pack` units a step."""
+    sq = blocks * 64
+    q, k, v, g, _ = _inputs(sq, sq, 8, d, dv)
+    scale = d ** -0.5
+    o, lse = fa._fa_forward_pallas(q, k, v, True, scale, 64, 64,
+                                   interpret=True, tile=16)
+    return (q, k, v, g), fa._fa_backward_pallas(
+        q, k, v, o, lse, g, True, scale, 64, 64, interpret=True, tile=16,
+        route=("fused", pack))
+
+
+@pytest.mark.parametrize("pack", [1, 2, 4])
+@pytest.mark.parametrize("d,dv", [(192, 128), (64, 64)])
+@pytest.mark.parametrize("blocks", [2, 4])
+def test_the_fused_sweep_is_the_same_numbers_at_every_pack(blocks, d, dv,
+                                                           pack):
+    """Whatever `backward_route` hands a several-block sweep — 1 or 2
+    units a grid step, and 4, which it handed Xing's shape before PR 52
+    and `route=` still reaches — a head's dq, dk and dv are the
+    reference's, and do not depend on which heads share its grid step:
+    4 units give 1 unit's numbers bit for bit."""
+    (q, k, v, g), got = _sweep_at(blocks, d, dv, pack)
+    _, _, want = _reference(q, k, v, g, None, True, d ** -0.5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+    if pack > 1:
+        for a, b in zip(got, _sweep_at(blocks, d, dv, 1)[1]):
+            np.testing.assert_array_equal(a, b)
+
+
 def test_the_kernels_block_each_operand_at_its_own_width():
     """No operand of a two-width call is padded to the other's width:
     the blocks of q, k, dq and dk are 192 lanes and those of v, o, dO and
@@ -129,9 +164,11 @@ def test_the_kernels_block_each_operand_at_its_own_width():
             q, k, v, o, l, do, True, 192 ** -0.5, 1024, 1024, False))(
                 q, q, v, v, lse, v).jaxpr)
     ins = [wide, wide, narrow, narrow, row, row]
-    # fused: dq's block is the eight heads' whole query length
-    assert fa.backward_route(2048, 2048, 192, 128, 0, 8) == ("fused", 8)
-    assert bwd == {"dwt_fa_bwd_fused": ins + [(8, 2048, 192), wide, narrow]}
+    # fused: two heads a grid step (all eight fit, and ran slower: PR
+    # 52), dq's block their whole query length
+    assert fa.backward_route(2048, 2048, 192, 128, 0, 8) == ("fused", 2)
+    assert bwd == {"dwt_fa_bwd_fused": [
+        (2,) + b[1:] for b in ins + [(8, 2048, 192), wide, narrow]]}
     pair = blocks(jax.make_jaxpr(
         lambda q, k, v, o, l, do: fa._fa_backward_pallas(
             q, k, v, o, l, do, True, 192 ** -0.5, 1024, 1024, False,

@@ -9,9 +9,13 @@ leaves out, compiles here at published widths behind the dense layer.
 """
 
 import collections
+import functools
 import re
 
+import jax
+import jax.numpy as jnp
 import pytest
+from test_program_from_arguments import _pallas_calls
 from test_tpu_compile import (  # noqa: F401 — `topo`, the cache switch: fixtures
     _every_device_op_has_an_owner,
     _grouped_kernel_calls,
@@ -91,6 +95,19 @@ def test_xing_step_runs_the_kernels_at_192_and_128_and_rotates_scaled(
     assert calls == {"dwt_fa_fwd": 10, "dwt_fa_bwd_fused": 5}
     assert fa.attention_route(32, 192, 128) == ("transposed", 0)
     assert "bf16[32,8192,192]" in text and "bf16[32,8192,256]" not in text
+    # the backward sweeps two heads a grid step, not the four that fit
+    # (23.4 ms a call on the chip at four, 13.5 at two: PR 52)
+    bh = cell["global_batch"] * 32
+    assert fa.backward_route(8192, 8192, 192, 128, 0, bh) == ("fused", 2)
+    wide, narrow = ((bh, 8192, w) for w in (192, 128))
+    grids = _pallas_calls(jax.make_jaxpr(functools.partial(
+        fa._fa_backward_pallas, causal=True, sm_scale=192 ** -0.5,
+        block_q=1024, block_k=1024, interpret=False))(*(
+            jax.ShapeDtypeStruct(s, jnp.bfloat16)
+            for s in (wide, wide, narrow, narrow)),
+        jax.ShapeDtypeStruct((bh, 1, 8192), jnp.float32),
+        jax.ShapeDtypeStruct(narrow, jnp.bfloat16)).jaxpr)
+    assert grids == [("dwt_fa_bwd_fused", (16, 8, 8))]
     assert collections.Counter(re.findall(
         r"%dwt_rope[.\d]* = (\w+\[[\d,]+\])", text)) == {
             "bf16[1,8192,2048]": 15, "bf16[1,8192,128]": 15}

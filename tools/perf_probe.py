@@ -16,8 +16,9 @@ the K-step driver (trainer/train_step.py) in THIS environment;
 `rpc` streams per-round control-plane RPCs/s per verb class against a
 per-frame-fsync and a group-commit master, rounds interleaved.
 `attn` is GPT-2's attention forward and backward by the host's clock,
-then `attn_bwd`: the backward alone at the five several-block cells'
-shapes, fused against the dq + dk/dv pair, by device time.
+then `attn_bwd`: the backward alone at the six several-block cells'
+shapes, fused at every pack that fits against the dq + dk/dv pair, by
+device time.
 `gmm` reads, from a profiler trace, the device time of each grouped
 product of a chip's share of an expert layer (98,304 rows of which
 6,800 are held in 8 groups) as `ops/grouped_matmul.py`'s kernels and as
@@ -145,9 +146,11 @@ def probe_attn(block_q=1024, block_k=1024, tag="attn"):
           ideal_fwdbwd_ms=round(7 * mm / 155e12 * 1e3, 2))
 
 
-# the five several-block cells' attention: (cell, T, d_qk, d_v, heads,
+# the six several-block cells' attention: (cell, T, d_qk, d_v, heads,
 # batch, window).  The route and layout follow from the shapes
-# (`attention_route`, `backward_route`)
+# (`attention_route`, `backward_route`).  The last row is no cell's:
+# Xing's heads at half its rows, where four units a grid step are two
+# thirds of the VMEM they are at 8,192 (PR 52: code size or bytes)
 BWD_CELLS = [
     ("olmoe_1b_7b", 4096, 128, 128, 16, 5, None),
     ("nemotron3_nano_30b_a3b", 8192, 128, 128, 32, 2, None),
@@ -155,18 +158,23 @@ BWD_CELLS = [
     ("smallthinker_21b_a3b", 16384, 128, 128, 28, 2, None),
     ("smallthinker_21b_a3b", 16384, 128, 128, 28, 2, 4096),
     ("kimi_vl_a3b", 16384, 192, 128, 16, 2, None),
+    ("xing4_0_29b_a4b", 8192, 192, 128, 32, 1, None),
+    ("32_heads_4096_transposed", 4096, 192, 128, 32, 1, None),
 ]
 
 
-def probe_attn_bwd():
-    """The backward ALONE at the five several-block cells' attention
-    shapes, as ONE fused kernel (`backward_route`'s answer, and on the
-    transposed layout every smaller pack too) and as the dq + dk/dv
-    pair: the device time of each kernel from a profiler trace, so that
-    the route's rule rests on kernel times and not on a whole cell's."""
+def probe_attn_bwd(cells=BWD_CELLS):
+    """The backward ALONE at the several-block cells' attention shapes,
+    as ONE fused kernel (`backward_route`'s answer, and on the
+    transposed layout every pack of 8/4/2/1 whose resident set fits,
+    the rule's or not) and as the dq + dk/dv pair: the device time of
+    each kernel from a profiler trace, so that the route's rule rests on
+    kernel times and not on a whole cell's.  A line names what was
+    timed: the sweep's grid and `_fused_bwd_vmem`'s bytes at that
+    pack."""
     from dlrover_wuqiong_tpu.ops import flash_attention as fa
 
-    for cell, t, d, dv, heads, batch, window in BWD_CELLS:
+    for cell, t, d, dv, heads, batch, window in cells:
         layout, slab_heads = fa.attention_route(heads, d, dv)
         bh = batch * heads
         ks = jax.random.split(jax.random.PRNGKey(t + d), 4)
@@ -186,19 +194,31 @@ def probe_attn_bwd():
         o, lse = jax.jit(functools.partial(fa._fa_forward_pallas, **plan))(
             q, k, v)
         rule = fa.backward_route(t, t, d, dv, slab_heads, bh)
-        routes = [rule] + [("fused", p) for p in (4, 2, 1)
-                           if slabs is None and p < rule[1]]
-        routes.append(("split", 1 if slabs else fa._fit_pack(bh)))
+        most = 1 if slabs else fa._fit_pack(bh)
+        blocks = t // 1024
+        vmem = {p: fa._fused_bwd_vmem(
+            p, t, 1024, 1024, fa._kernel_head_dim(d),
+            fa._kernel_head_dim(dv), slab_heads or 1, 2)
+            for p in (8, 4, 2, 1) if p <= most}
+        routes = [("fused", p) for p, held in vmem.items()
+                  if held <= fa._VMEM_LIMIT] + [("split", most)]
+        swept = fa._window_plan(
+            fa._effective_window(window, True, t), blocks, blocks, 1024,
+            1024, 0).get("steps", blocks)
         for route in routes:
             fn = jax.jit(functools.partial(fa._fa_backward_pallas,
                                            route=route, **plan))
             ops = _device_ops_ms(fn, q, k, v, o, lse, do, top=6)
             kernels = {n: ms for n, ms in ops.items()
                        if n.startswith("dwt_fa_")}
+            groups = batch * slabs.per_row if slabs else bh // route[1]
             _emit_raw({"probe": "attn_bwd", "cell": cell,
                        "shape": [bh, t, d, dv], "layout": layout,
                        "window": window, "route": list(route),
                        "the_rule": route == rule,
+                       "grid": [groups, blocks, swept],
+                       "fused_vmem_mib": round(vmem[route[1]] / 2 ** 20, 1)
+                       if route[0] == "fused" else None,
                        "kernels_ms": round(sum(kernels.values()), 4),
                        "device_ops_ms": ops})
 
