@@ -73,24 +73,48 @@ def window_tiles(cfg, batch: int, n_head: int, seq: int):
                  for w in (window, None))
 
 
+def window_pairs(cfg, batch: int, n_head: int, seq: int):
+    """((query, key) pairs a windowed layer KEEPS in one pass over
+    `batch` sequences, pairs in the score tiles its kernels compute), or
+    None for a layer without a window: the band's window * seq - window
+    * (window - 1) / 2 a head, beside `window_tiles`' count at the side
+    of the tile it is counted in.  What a tile's grain costs: a window of
+    half a tile keeps about half of what the tiles on its band hold.
+    Static numbers, as `window_tiles`'; sown as `attn_pairs`."""
+    window = attention_window(cfg)
+    if window is None:
+        return None
+    w = min(window, seq)
+    done, square = causal_tile_count(seq, seq, window=window)
+    return (batch * n_head * (w * seq - w * (w - 1) // 2),
+            batch * n_head * done * seq * seq // square)
+
+
 def collect_attention_stats(intermediates) -> dict:
     """What the attention layers of one forward pass counted, summed over
-    the layers — {} for a model that sows neither: `attn_tiles_window`
+    the layers — {} for a model that sows none of it: `attn_tiles_window`
     and `attn_tiles_causal` of the windowed layers (`window_tiles`),
-    `attn_lanes_run` and `attn_lanes_model` of the latent ones
-    (`models/latent_attention.py`: the lanes a score entry's two
-    products run as the kernels block them, and the lanes the model's
-    widths ask)."""
+    `attn_pairs_kept` and `attn_pairs_computed` of the same layers where
+    they sow them (`window_pairs`), `attn_lanes_run` and
+    `attn_lanes_model` of the latent ones (`models/latent_attention.py`:
+    the lanes a score entry's two products run as the kernels block
+    them, and the lanes the model's widths ask), and `attn_gate_mean`,
+    the gated layers' mean output gate (`LlamaConfig.attn_gate`)."""
     from .moe import _sown
 
     stats = {}
     for sown, names in (
             ("attn_tiles", ("attn_tiles_window", "attn_tiles_causal")),
+            ("attn_pairs", ("attn_pairs_kept", "attn_pairs_computed")),
             ("attn_lanes", ("attn_lanes_run", "attn_lanes_model"))):
         pairs = [v.reshape(-1, 2) for v in _sown(intermediates, sown)]
         if pairs:
             with jax.named_scope(sown):  # the sum's copies get an owner
                 stats.update(zip(names, jnp.concatenate(pairs).sum(0)))
+    gates = [v.reshape(()) for v in _sown(intermediates, "attn_gate_mean")]
+    if gates:
+        with jax.named_scope("attn_gate_mean"):
+            stats["attn_gate_mean"] = jnp.stack(gates).mean()
     return stats
 
 

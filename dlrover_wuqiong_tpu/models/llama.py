@@ -6,7 +6,9 @@ in every block's feed-forward slot (expert width `intermediate_size`),
 `qk_norm` an RMSNorm over the whole q and k projections before the split
 into heads and before RoPE.  `attn_window` makes the attention a sliding
 window (models/smallthinker.py's local layers), as `attn_scale` and
-`rope` make it Granite's.
+`rope` make it Granite's.  `attn_gate` puts one sigmoid gate a head and
+token on the attention's output; tables narrower than half a head rotate
+the head's first features only (models/laguna.py's layers).
 
 Parity: the reference's flagship workloads are GLM/Llama-class LMs via atorch
 (`BASELINE.json` configs: Llama-3 8B auto_accelerate, Llama-3 70B Megatron
@@ -17,6 +19,7 @@ embed_tokens, lm_head) so TP/FSDP/SP specs bind without per-model glue.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Any, Optional
@@ -71,6 +74,10 @@ class LlamaConfig:
     # (models/attention.py hands it to the kernels); 0 = every key at or
     # before it
     attn_window: int = 0
+    # one gate a head and token on the attention's output, before
+    # `o_proj`: sigmoid(x @ g_proj), x the block's normalised input
+    # (arXiv:2505.06708's head-wise form); False = none
+    attn_gate: bool = False
 
     @classmethod
     def nano(cls):
@@ -92,10 +99,12 @@ class LlamaConfig:
         return self.attn_head_dim or self.hidden_size // self.num_heads
 
     def attention_params(self) -> int:
-        """q, k, v, o and the QK-norm's scales; no block norm."""
+        """q, k, v, o, the QK-norm's scales and the output gate's
+        product; no block norm."""
         h, q = self.hidden_size, self.num_heads * self.head_dim
         kv = self.num_kv_heads * self.head_dim
-        return 2 * h * q + 2 * h * kv + (q + kv if self.qk_norm else 0)
+        return 2 * h * q + 2 * h * kv + (q + kv if self.qk_norm else 0) \
+            + (h * self.num_heads if self.attn_gate else 0)
 
     def ffn_params(self) -> int:
         """The feed-forward slot: a SwiGLU, or `moe`'s expert layer — the
@@ -188,10 +197,12 @@ def rope_freqs(head_dim: int, max_seq: int, theta: float,
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
-def apply_rope(x, cos, sin, mesh=None):
+def apply_rope(x, cos, sin, mesh=None, head_dim: int = 0):
     """Rotate each head's pairs (even, odd interleaved by halves).  x is
     (b, s, h, d), or the projections' own (b, s, h*d) with the heads side
     by side and never cut apart — the one rotation of both layouts.
+    `head_dim` d wider than the tables' 2 * half (0 = as wide) rotates a
+    head's FIRST 2 * half features and passes the rest as they are.
 
     Two routes, chosen by what the call can observe
     (`ops/rope.rope_route`, never a knob): on the TPU, d of 64 or 128,
@@ -212,17 +223,27 @@ def apply_rope(x, cos, sin, mesh=None):
 
     s, lanes, half = x.shape[1], x.shape[-1], cos.shape[-1]
     row = math.prod(x.shape[2:])  # a position's heads side by side
-    if rope_route(row, 2 * half, mesh) == "kernel":
+    d = head_dim or 2 * half
+    if d == 2 * half and rope_route(row, d, mesh) == "kernel":
         return rotate_rows(x.reshape(*x.shape[:2], row), cos,
                            sin).reshape(x.shape)
-    heads = lanes // (2 * half)
-    c = jnp.tile(jnp.concatenate([cos[:s], cos[:s]], axis=-1), (1, heads))
-    si = jnp.tile(jnp.concatenate([-sin[:s], sin[:s]], axis=-1),
-                  (1, heads))
+    heads = lanes // d
+    # a head's lanes are [cos | cos | 1 ..] and [-sin | sin | 0 ..]:
+    # those behind the rotated ones pass, whatever their partner
+    passed = (s, d - 2 * half)
+    c = jnp.tile(jnp.concatenate(
+        [cos[:s], cos[:s]] + [jnp.ones(passed, cos.dtype)] * (d > 2 * half),
+        axis=-1), (1, heads))
+    si = jnp.tile(jnp.concatenate(
+        [-sin[:s], sin[:s]] + [jnp.zeros(passed, sin.dtype)]
+        * (d > 2 * half), axis=-1), (1, heads))
     if x.ndim == 4:
         c, si = c[:, None], si[:, None]
     x32 = x.astype(jnp.float32)
-    second = (jnp.arange(lanes) // half) % 2 == 1  # a head's upper half
+    lane = jnp.arange(lanes)
+    if d > 2 * half:
+        lane = lane % d  # a lane's place in its head
+    second = (lane // half) % 2 == 1  # a rotated pair's upper half
     partner = jnp.where(second, jnp.roll(x32, half, axis=-1),
                         jnp.roll(x32, -half, axis=-1))
     return (x32 * c + partner * si).astype(x.dtype)
@@ -266,8 +287,14 @@ class LlamaAttention(nn.Module):
             k = k.reshape(B, T, cfg.num_kv_heads, hd)
             v = v.reshape(B, T, cfg.num_kv_heads, hd)
         if cfg.rope:
-            q = apply_rope(q, cos, sin, mesh=cfg.mesh)
-            k = apply_rope(k, cos, sin, mesh=cfg.mesh)
+            # tables narrower than half the head rotate its first
+            # features only: the formula's ops, under a scope of their own
+            partial = 2 * cos.shape[-1] < hd
+            width = {"head_dim": hd} if partial else {}
+            with jax.named_scope("rope_partial") if partial \
+                    else contextlib.nullcontext():
+                q = apply_rope(q, cos, sin, mesh=cfg.mesh, **width)
+                k = apply_rope(k, cos, sin, mesh=cfg.mesh, **width)
         how, rep = kv_route(cfg.num_heads, cfg.num_kv_heads, hd)
         if rep > 1 and not (direct and how == "indexed"):  # GQA: repeat
             k, v = (jnp.repeat(t.reshape(B, T, cfg.num_kv_heads, hd), rep,
@@ -289,6 +316,17 @@ class LlamaAttention(nn.Module):
             att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
             y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
         y = y.reshape(B, T, cfg.num_heads * hd)
+        if cfg.attn_gate:
+            # the flax Dense names the product's ops `g_proj`; the
+            # sigmoid and the 128-lane slabs' multiply sit under `gate`
+            g = dense(cfg, cfg.num_heads, "g_proj", use_bias=False)(x)
+            with jax.named_scope("gate"):
+                g = jax.nn.sigmoid(g.astype(jnp.float32))
+                self.sow("intermediates", "attn_gate_mean",
+                         jax.lax.stop_gradient(g.mean()))
+                # y keeps the layout the kernels wrote: a head's gate
+                # is spread over its lanes, not y cut to heads
+                y = (y * jnp.repeat(g, hd, axis=-1)).astype(cfg.dtype)
         return dense(cfg, C, "o_proj", use_bias=False)(y)
 
 

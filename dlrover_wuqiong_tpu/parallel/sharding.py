@@ -78,6 +78,9 @@ TRANSFORMER_RULES: List[Rule] = [
     (r".*_hc/(alpha|b_pre|b_post|b_res)$", P()),
     # a multi-token-prediction module's joining product: column-parallel
     (r".*mtp_\d+/eh_proj/kernel$", P("fsdp", "tp")),
+    # a per-head gate on the attention's output (LlamaConfig.attn_gate):
+    # one column a head, split over tp as q_proj's heads are
+    (r".*(attn|attention).*g_proj/kernel$", P("fsdp", "tp")),
     # attention out: row-parallel (parity RowParallelLinear :239)
     (r".*(attn|attention).*(o_proj|out_proj|c_proj|dense|out)/kernel$",
      P("tp", "fsdp")),
