@@ -347,6 +347,80 @@ def scope_table(hlo_text: str) -> Dict[str, str]:
             for name, entry in owners(hlo_text).items()}
 
 
+_ARRAY = re.compile(r"\b([a-z][a-z0-9]*)\[([\d,]*)\]")
+_PARAMETER = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*\sparameter\((\d+)\)")
+_SLICES = frozenset({"slice", "dynamic-slice"})
+_HOLDS_OPS = frozenset({"while", "conditional", "call"})
+
+
+def shape_bytes(shape: str) -> int:
+    """Bytes of an HLO shape as `read_instruction` gives it — an array's
+    (`bf16[1,4,8192,3584]{3,2,1,0:T(8,128)(2,1)}`) or a tuple's, summed;
+    an element's width is the number in its type's name (`pred`: a
+    byte), a `token` or an opaque handle is nothing."""
+    total = 0
+    for dtype, dims in _ARRAY.findall(shape):
+        bits = 8 if dtype == "pred" else int(
+            (re.search(r"\d+", dtype) or [0])[0])
+        count = 1
+        for dim in filter(None, dims.split(",")):
+            count *= int(dim)
+        total += count * bits // 8
+    return total
+
+
+def moved_bytes(hlo_text: str, under: str) -> Dict[str, dict]:
+    """{instruction name: {"scope", "read", "written"}}: the bytes of the
+    operands and of the result of every device op whose owner's scope
+    (`owners`) holds the path `under` (`"hc"`, `"hc/coeff"`) — the
+    compiled program's own byte ledger of a layer, what its ops move
+    through HBM if nothing stays in fast memory between them.  A
+    fusion's operand that the fusion only SLICES counts at its slices
+    (a fusion that reads one lane of the stream is handed all four);
+    the two halves of an async pair count once, at what the `-done`
+    half delivers, read and written; an op that only holds other ops
+    (`while`, `conditional`) counts nothing and its body's ops count
+    ONCE, whatever the trip count."""
+    comps = parse_computations(hlo_text)
+    top = {ins["name"]: ins for body in comps.values() for ins in body}
+    index = {m.group(1): int(m.group(2)) for m in map(
+        _PARAMETER.match, hlo_text.splitlines()) if m}
+
+    def sliced(cname: str) -> Dict[int, int]:
+        """{operand index: bytes} of the parameters a called
+        computation reads only through slices."""
+        body = comps.get(cname, [])
+        users: Dict[str, List[dict]] = {}
+        for ins in body:
+            for operand in ins["operands"]:
+                users.setdefault(operand, []).append(ins)
+        return {index[ins["name"]]: sum(
+                    shape_bytes(u["shape"]) for u in users[ins["name"]])
+                for ins in body
+                if ins["opcode"] == "parameter" and users.get(ins["name"])
+                and all(u["opcode"] in _SLICES for u in users[ins["name"]])}
+
+    found: Dict[str, dict] = {}
+    for name, entry in _resolve(comps).items():
+        ins = top[name]
+        opcode = ins["opcode"]
+        if f"/{under}/" not in f"/{entry['scope']}/" or opcode in _FREE \
+                or opcode in _HOLDS_OPS or opcode.endswith("-start"):
+            continue
+        written = shape_bytes(ins["shape"])
+        if opcode.endswith("-done"):
+            read = written
+        else:
+            at_slices = sliced(ins["calls"]) if ins["calls"] else {}
+            read = sum(at_slices[i] if i in at_slices
+                       else shape_bytes(top[o]["shape"]) if o in top else 0
+                       for i, o in enumerate(ins["operands"]))
+        found[name] = {"scope": entry["scope"], "read": read,
+                       "written": written}
+    return found
+
+
 def relayouts(hlo_text: str, under: str, outside=()) -> Dict[str, str]:
     """{instruction name: opcode} of the device ops whose scope holds the
     component `under`, holds none of `outside`, and that only MOVE data

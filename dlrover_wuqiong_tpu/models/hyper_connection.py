@@ -16,32 +16,52 @@ writes back through a learned 1 x n and a doubly-stochastic n x n.
 
 The stream is carried as (b, n, T, d): a lane is a LEADING axis, so the
 bf16 tiles of (T, d) hold no padding (with (.., n, d) as the last two
-axes a (16, 128) tile would pad 4 lanes to 16), a lane is a slice along
-a major axis, and the mixes' transposes are sums and stacks of such
-slices (`read`, `write`: the backward rules are written out, so no pad
-of a cotangent into a zero stream is ever added up — with plain autodiff
-of the same forward the described-`v5e` compile of Xing4.0's step at
-1 x 8192 holds 5.14 GB of temporaries where it holds 4.75 with the
-rules, 14.25 GB live against the 14.4 a cell may take, and the chip
-runs the step in 551.4 ms for 548.2).  The
-coefficients are float32 with the tokens on the LANE axis — (b, n, T)
-and (b, n, n, T) — so Sinkhorn's twenty rounds (one `lax.scan`) and what
-their backward keeps are whole tiles of tokens, not one padded tile a
-token.  `z Phi` is computed as `(X Phi) * rsqrt(mean(X^2) +
-eps)`: the norm's factor is one number a token, so z itself is never
-written.
+axes a (16, 128) tile would pad 4 lanes to 16) and a lane is a slice
+along a major axis.  The coefficients are float32 with the tokens on
+the LANE axis — (b, n, T) and (b, n, n, T) — so Sinkhorn's twenty
+rounds (one `lax.scan`) and what their backward keeps are whole tiles
+of tokens, not one padded tile a token.  `z Phi` is computed as `(X
+Phi) * rsqrt(mean(X^2) + eps)`: the norm's factor is one number a
+token, so z itself is never written.
 
-Scopes in the compiled step: `hc/coeff` (the norm's statistic, the one
-(n d) x (n^2 + 2n) product, the gains and biases), `hc/sinkhorn`,
-`hc/pre` (u) and `hc/post_res` (X'), forward and backward alike (the
-custom rules open them themselves), and `hc/expand` / `hc/read_out` at
-the stack's two ends.  The leaves sit in the module
-`<sublayer>_hc` (`HyperConnection`): `phi` (n, d, n^2 + 2n), `alpha`
-(3: pre, post, res), `b_pre`, `b_post` (n), `b_res` (n, n).
+Two routes, chosen by what a call can observe (`ops/hc_mix.hc_route`:
+the backend, the devices, the hidden size, the tokens), never by a
+knob; a block enters through `mix_in` and leaves through `mix_out`:
+
+- "kernel" (the TPU, one device, d a whole number of 128-lane slabs):
+  `ops/hc_mix.py`'s four Pallas kernels.  `dwt_hc_pre` reads the
+  stream once and makes the norm's statistic, the product, h_pre and u
+  from it; `dwt_hc_post` writes all n lanes of X' from one read of the
+  n + 1 inputs; backward `dwt_hc_post_bwd` makes dy, H_res^T dX' and
+  the n^2 + n dot products from one read, and `dwt_hc_pre_bwd` adds
+  every cotangent of the stream — through `write`, through `read`,
+  through the product and through the statistic — in float32 into ONE
+  rounding and accumulates Phi's.  55 hidden vectors a token a sublayer
+  where the plain route's fusions moved 130 (ROADMAP S16; PERF.md
+  section 6, PR 54).  Sinkhorn, the gains, the biases and h_post's
+  sigmoid stay `jax.numpy` on (b, k, T) float32 arrays.
+- "plain" (the CPU, a mesh, any other width): the formulas below, the
+  tests' oracle.  `read` and `write` are sums and stacks of lane
+  slices with written-out backward rules (plain autodiff of the same
+  forward pads each lane's cotangent into a zero stream and adds them
+  up: the described-`v5e` compile of Xing4.0's step at 1 x 8192 held
+  5.14 GB of temporaries so, 4.75 with the rules); `coefficients` is
+  plain autodiff, so on this route the stream's three cotangents (of
+  `write`, of `read`, of the product and the statistic) are written a
+  lane at a time, joined and added by the compiler's fusions.
+
+Scopes in the compiled step, the same on both routes: `hc/coeff` (the
+gains, biases and sigmoids; on the plain route also the statistic and
+the product), `hc/sinkhorn`, `hc/pre` (u; `dwt_hc_pre`, `dwt_hc_pre_bwd`)
+and `hc/post_res` (X'; `dwt_hc_post`, `dwt_hc_post_bwd`), forward and
+backward alike (the custom rules open them themselves), and
+`hc/expand` / `hc/read_out` at the stack's two ends.  The leaves sit in
+the module `<sublayer>_hc` (`HyperConnection`): `phi` (n, d, n^2 + 2n),
+`alpha` (3: pre, post, res), `b_pre`, `b_post` (n), `b_res` (n, n).
 
 Not built: a mesh (the (b, n, T, d) carry has no pins under `fsdp` or
-`tp`), a fused kernel for the two mixes (ROADMAP, Speed), a learned
-read-out of the lanes (the stack sums them).
+`tp`), a learned read-out of the lanes (the stack sums them), Sinkhorn's
+rounds inside a kernel (3 ms of a 420 ms step: launches, not bytes).
 
 Parity: none — the reference trains Llama/GLM-class stacks only.
 """
@@ -54,6 +74,8 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..ops import hc_mix
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,6 +144,21 @@ def sinkhorn_err(h_res):
                            jnp.abs(h_res.sum(1) - 1).max())
 
 
+def _post_res(leaves: dict, raw, cfg: HyperConnectionConfig):
+    """The raw coefficients of the normed stream (b, >= n^2 + 2n, T),
+    z Phi's rows -> h_post (b, n, T) and H_res (b, n, n, T)."""
+    n = cfg.lanes
+    with jax.named_scope("hc"), jax.named_scope("coeff"):
+        alpha = leaves["alpha"]
+        a_post = alpha[1] * raw[:, n:2 * n] + leaves["b_post"][:, None]
+        a_res = alpha[2] * raw[:, 2 * n:n * (n + 2)].reshape(
+            -1, n, n, raw.shape[-1]) + leaves["b_res"][:, :, None]
+        h_post = 2 * jax.nn.sigmoid(a_post)
+    with jax.named_scope("hc"), jax.named_scope("sinkhorn"):
+        h_res = sinkhorn(a_res, cfg.sinkhorn_iters, cfg.eps, cfg.clamp)
+    return h_post, h_res
+
+
 def coefficients(leaves: dict, x, cfg: HyperConnectionConfig):
     """x (b, n, T, d) -> h_pre (b, n, T), h_post (b, n, T), H_res
     (b, n, n, T), float32."""
@@ -135,15 +172,33 @@ def coefficients(leaves: dict, x, cfg: HyperConnectionConfig):
                   for i in range(n))
         # tokens to the lane axis: (b, n^2 + 2n, T)
         raw = (raw * rms[..., None]).transpose(0, 2, 1)
-        alpha = leaves["alpha"]
-        a_pre = alpha[0] * raw[:, :n] + leaves["b_pre"][:, None]
-        a_post = alpha[1] * raw[:, n:2 * n] + leaves["b_post"][:, None]
-        a_res = alpha[2] * raw[:, 2 * n:].reshape(-1, n, n, raw.shape[-1]) \
-            + leaves["b_res"][:, :, None]
-        h_pre, h_post = jax.nn.sigmoid(a_pre), 2 * jax.nn.sigmoid(a_post)
-    with jax.named_scope("hc"), jax.named_scope("sinkhorn"):
-        h_res = sinkhorn(a_res, cfg.sinkhorn_iters, cfg.eps, cfg.clamp)
-    return h_pre, h_post, h_res
+        h_pre = jax.nn.sigmoid(leaves["alpha"][0] * raw[:, :n]
+                               + leaves["b_pre"][:, None])
+    return (h_pre, *_post_res(leaves, raw, cfg))
+
+
+def mix_in(leaves: dict, x, cfg: HyperConnectionConfig, mesh=None):
+    """A sublayer's way in: x (b, n, T, d) -> u (b, T, d), the stream
+    for `mix_out`, h_post and H_res.  On the kernel route
+    (`ops/hc_mix.hc_route`) ONE pass over the stream makes u and the raw
+    coefficients, and the stream it returns carries the cotangent of
+    `mix_out` back into that pass's backward; on the plain route it is x
+    itself, `coefficients` and `read`."""
+    _, n, t, d = x.shape
+    if hc_mix.hc_route(n, t, d, mesh) == "plain":
+        h_pre, h_post, h_res = coefficients(leaves, x, cfg)
+        return read(h_pre, x), x, h_post, h_res
+    u, coef, x = hc_mix.mix_in(x, leaves["phi"], leaves["alpha"][0],
+                               leaves["b_pre"], cfg.norm_eps, hc_mix.plan(t))
+    return (u, x, *_post_res(leaves, coef, cfg))
+
+
+def mix_out(h_res, h_post, x, y, mesh=None):
+    """A sublayer's way out, X' of `write`, on `mix_in`'s route."""
+    _, n, t, d = x.shape
+    if hc_mix.hc_route(n, t, d, mesh) == "plain":
+        return write(h_res, h_post, x, y)
+    return hc_mix.mix_out(h_res, h_post, x, y, hc_mix.plan(t))
 
 
 def _weighted(weights, parts, dtype):

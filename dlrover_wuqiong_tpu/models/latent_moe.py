@@ -228,13 +228,12 @@ class LatentMoEBlock(nn.Module):
 
         def around(x, name, norm, branch, anchor):
             leaves = hc.HyperConnection(hyper, name=f"{name}_hc")()
-            h_pre, h_post, h_res = hc.coefficients(leaves, x, hyper)
+            u, x, h_post, h_res = hc.mix_in(leaves, x, hyper, cfg.mesh)
             self.sow("intermediates", "hc_sinkhorn_err",
                      jax.lax.stop_gradient(hc.sinkhorn_err(h_res)))
-            u = RMSNorm(cfg.rms_eps, cfg.dtype, name=norm)(
-                hc.read(h_pre, x))
-            return hc.write(h_res, h_post, x,
-                            checkpoint_name(branch(u), anchor))
+            u = RMSNorm(cfg.rms_eps, cfg.dtype, name=norm)(u)
+            return hc.mix_out(h_res, h_post, x,
+                              checkpoint_name(branch(u), anchor), cfg.mesh)
 
         x = around(x, "attention", "input_norm",
                    lambda u: LatentAttention(

@@ -12,11 +12,13 @@ import pytest
 from dlrover_wuqiong_tpu.analysis.hlo_budget import iter_collectives
 from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
     instructions_of,
+    moved_bytes,
     owners,
     parse_computations,
     relayouts,
     scope_of,
     scope_table,
+    shape_bytes,
 )
 
 
@@ -469,3 +471,76 @@ def test_the_dropless_layer_scatters_no_row_forward_or_backward(
     everywhere = [s for s in instructions_of(text, "gather", "").values()
                   if rows.match(s)]
     assert len(everywhere) == 4
+
+
+@pytest.mark.parametrize("shape,nbytes", [
+    ("bf16[1,4,8192,3584]{3,2,1,0:T(8,128)(2,1)}", 4 * 8192 * 3584 * 2),
+    ("f32[1,32,8192]{2,1,0:T(8,128)S(1)}", 32 * 8192 * 4),
+    ("(bf16[8,64]{1,0}, f32[4,32,16]{2,1,0:T(8,128)}, s32[])",
+     8 * 64 * 2 + 4 * 32 * 16 * 4 + 4),
+    ("pred[16,128]{1,0}", 16 * 128), ("f8e4m3fn[32,128]", 32 * 128),
+    ("token[]", 0), ("", 0),
+])
+def test_shape_bytes_reads_arrays_and_tuples_past_their_layouts(shape,
+                                                                nbytes):
+    assert shape_bytes(shape) == nbytes
+
+
+_M = "jit(train_step)/jvp(X)/layers_0/hc"
+_LEDGER_HLO = f"""\
+HloModule ledger
+
+%one_lane (p0: bf16[4,64,128], p1: f32[64]) -> bf16[64,128] {{
+  %p0 = bf16[4,64,128]{{2,1,0}} parameter(0)
+  %p1 = f32[64]{{0}} parameter(1)
+  %lane = bf16[1,64,128]{{2,1,0}} slice(%p0), slice={{[2:3], [0:64], [0:128]}}
+  ROOT %scaled = bf16[64,128]{{1,0}} multiply(%lane, %p1), metadata={{op_name="{_M}/pre/mul"}}
+}}
+
+%all_lanes (q0: bf16[4,64,128]) -> bf16[64,128] {{
+  %q0 = bf16[4,64,128]{{2,1,0}} parameter(0)
+  %first = bf16[1,64,128]{{2,1,0}} slice(%q0), slice={{[0:1], [0:64], [0:128]}}
+  ROOT %summed = bf16[64,128]{{1,0}} reduce(%q0, %first), metadata={{op_name="{_M}/read_out/reduce_sum"}}
+}}
+
+%round (c: (f32[4,64])) -> (f32[4,64]) {{
+  %c = (f32[4,64]{{1,0}}) parameter(0)
+  %m = f32[4,64]{{1,0}} get-tuple-element(%c), index=0
+  %n = f32[4,64]{{1,0}} divide(%m, %m), metadata={{op_name="{_M}/sinkhorn/while/body/div"}}
+  ROOT %t = (f32[4,64]{{1,0}}) tuple(%n)
+}}
+
+ENTRY %main (x: bf16[4,64,128], w: f32[64], a: f32[4,64]) -> bf16[64,128] {{
+  %x = bf16[4,64,128]{{2,1,0}} parameter(0)
+  %w = f32[64]{{0}} parameter(1)
+  %a = f32[4,64]{{1,0}} parameter(2)
+  %fusion.1 = bf16[64,128]{{1,0}} fusion(%x, %w), kind=kLoop, calls=%one_lane, metadata={{op_name="{_M}/pre/mul"}}
+  %fusion.2 = bf16[64,128]{{1,0}} fusion(%x), kind=kLoop, calls=%all_lanes, metadata={{op_name="{_M}/read_out/reduce_sum"}}
+  %copy-start.1 = (bf16[64,128]{{1,0}}, bf16[64,128]{{1,0}}, u32[]) copy-start(%fusion.1), metadata={{op_name="{_M}/pre/mul"}}
+  %copy-done.1 = bf16[64,128]{{1,0:S(1)}} copy-done(%copy-start.1), metadata={{op_name="{_M}/pre/mul"}}
+  %init = (f32[4,64]{{1,0}}) tuple(%a)
+  %while.1 = (f32[4,64]{{1,0}}) while(%init), condition=%cond, body=%round, metadata={{op_name="{_M}/sinkhorn/while"}}
+  %dwt_hc_post.1 = bf16[4,64,128]{{2,1,0}} custom-call(%a, %x, %copy-done.1), custom_call_target="tpu_custom_call", metadata={{op_name="{_M}/post_res/dwt_hc_post/pallas_call"}}
+  %elsewhere = bf16[64,128]{{1,0}} add(%fusion.2, %fusion.2), metadata={{op_name="jit(train_step)/jvp(X)/layers_0/norm/add"}}
+  ROOT %out = bf16[64,128]{{1,0}} add(%elsewhere, %copy-done.1), metadata={{op_name="jit(train_step)/jvp(X)/head/add"}}
+}}
+"""
+
+
+def test_moved_bytes_is_a_scopes_byte_ledger():
+    """Operands and result of every device op under a scope: a fusion's
+    operand it only slices at the slice, one it reads whole (beside a
+    slice) whole; an async pair once, at what arrives; a loop's body
+    once and the `while` itself nothing; a kernel its operands and its
+    result; nothing of another scope, no parameter, no tuple."""
+    lane, stream = 64 * 128 * 2, 4 * 64 * 128 * 2
+    moved = moved_bytes(_LEDGER_HLO, "hc")
+    assert {name: (e["read"], e["written"]) for name, e in moved.items()} \
+        == {"fusion.1": (lane + 64 * 4, lane), "fusion.2": (stream, lane),
+            "copy-done.1": (lane, lane), "n": (2 * 4 * 64 * 4, 4 * 64 * 4),
+            "dwt_hc_post.1": (4 * 64 * 4 + stream + lane, stream)}
+    assert moved["dwt_hc_post.1"]["scope"] \
+        == "fwd/X/layers/hc/post_res/dwt_hc_post"
+    assert set(moved_bytes(_LEDGER_HLO, "hc/pre")) == {"fusion.1",
+                                                      "copy-done.1"}
+    assert set(moved_bytes(_LEDGER_HLO, "norm")) == {"elsewhere"}

@@ -372,6 +372,7 @@ def _compile_one_chip_step(topo, cell, model):
         mp.setattr(rope, "_on_tpu", lambda: True)
         mp.setattr("dlrover_wuqiong_tpu.ops.delta_rule._on_tpu",
                    lambda: True)
+        mp.setattr("dlrover_wuqiong_tpu.ops.hc_mix._on_tpu", lambda: True)
         res = auto_accelerate(
             model, strategy=[("fsdp", {})], devices=topo.devices[:1],
             optimizer=optax.chain(optax.clip_by_global_norm(1.0),

@@ -49,9 +49,11 @@ def xing_mtp_step(request):
 
 def test_xing_step_fits_one_chip_by_the_rule_and_fills_it(xing_step):
     """State + temporaries under 90% of the chip's 16 GB at 1 x 8192
-    (PR 26's rule; described compiles read 13.86 GB live there and 12.25
-    at 1 x 4096), of which 9.11 GB is donated state; far over the 25% a
-    cell has to fill."""
+    (PR 26's rule; the described compile read 13.86 GB live there and
+    12.25 at 1 x 4096 when the rung was taken, and reads 13.74 since the
+    mixes are kernels: three whole-stream cotangents a sublayer are no
+    longer alive together), of which 9.11 GB is donated state; far over
+    the 25% a cell has to fill."""
     cell, model, step = xing_step
     assert model.config.num_params() == 759_346_446
     assert (cell["seq_len"], cell["global_batch"]) == (8192, 1)
@@ -61,7 +63,7 @@ def test_xing_step_fits_one_chip_by_the_rule_and_fills_it(xing_step):
     rung = cell["config"]["train"]["memory_rung"]
     assert rung["live_GB"]["1 x 8192"] == 13.86 < rung["limit_GB"] == 14.4
     assert rung["taken"] == "1 x 8192"
-    assert live / 1e9 == pytest.approx(13.86, abs=0.05)
+    assert 13.6 < live / 1e9 <= 13.86 + 0.05 < rung["limit_GB"], live / 1e9
     assert 0.25 * 16 * 2 ** 30 < 0.75 * 16e9 < live < 0.90 * 16e9, live / 1e9
     assert m.alias_size_in_bytes >= 12 * model.config.num_params()
 
@@ -167,6 +169,60 @@ def test_xing_step_holds_its_scopes_and_a_share_of_the_experts(xing_step):
     loops = [line for line in text.splitlines() if " while(" in line]
     assert sum("hc/sinkhorn" in line for line in loops) == 30
     assert all("hc/sinkhorn" in line or "/moe/" in line for line in loops)
+
+
+def test_xing_step_mixes_its_lanes_in_four_kernels_a_sublayer(
+        xing_step, monkeypatch):
+    """The route and its static counter: ten sublayers run `dwt_hc_pre`
+    and `dwt_hc_post` forward, again recomputed (the last sublayer of a
+    block is recomputed for nobody: five, not ten) and `dwt_hc_post_bwd`
+    and `dwt_hc_pre_bwd` backward, each under the scope of its mix, the
+    stream's cotangent through H_res written into `dwt_hc_pre_bwd`'s
+    output buffer.  The byte ledger (`hlo_scopes.moved_bytes`): nothing
+    else between the stack's two ends reads or writes a whole (1, 4,
+    8192, 3584) stream, and all of `hc/*` moves under 600 hidden vectors
+    a token (the least is 560, `resmix_bytes_per_step`; the plain route
+    moved 1,350)."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import moved_bytes
+    from dlrover_wuqiong_tpu.ops import hc_mix
+
+    cell, _, step = xing_step
+    text = step.as_text()
+    monkeypatch.setattr(hc_mix, "_on_tpu", lambda: True)
+    assert hc_mix.hc_route(4, cell["seq_len"], 3584) == "kernel"
+    assert dict(hc_mix.plan(cell["seq_len"])) == {
+        "tile": 128, "pre_tile": 512, "interpret": False}
+    moved = moved_bytes(text, "hc")
+    shapes = dict(re.findall(r"%(dwt_hc_[\w.]+) = (.*?) custom-call\(", text))
+    calls = collections.Counter()
+    for name, entry in moved.items():
+        kernel = re.match(r"(dwt_hc_\w+?)(?:\.\d+)?$", name)
+        if kernel:
+            phase, *path = entry["scope"].split("/")
+            calls[kernel.group(1), phase,
+                  "/".join(path[path.index("hc"):][:2]),
+                  " ".join(re.findall(r"\w+\[[\d,]+\]", shapes[name]))] += 1
+    stream, vector, coef = ("bf16[1,4,8192,3584]", "bf16[1,8192,3584]",
+                            "f32[1,32,8192]")
+    assert calls == {
+        ("dwt_hc_pre", "fwd", "hc/pre", f"{vector} {coef}"): 10,
+        ("dwt_hc_pre", "recompute", "hc/pre", f"{vector} {coef}"): 10,
+        ("dwt_hc_post", "fwd", "hc/post_res", stream): 10,
+        ("dwt_hc_post", "recompute", "hc/post_res", stream): 5,
+        ("dwt_hc_post_bwd", "bwd", "hc/post_res",
+         f"{vector} {stream} f32[1,24,8192]"): 10,
+        ("dwt_hc_pre_bwd", "bwd", "hc/pre",
+         f"{stream} f32[4,32,3584] {coef}"): 10}
+    assert text.count("output_to_operand_aliasing={{0}: (5, {})}") == 10
+    one = cell["global_batch"] * cell["seq_len"] * 3584 * 2
+    whole = {name for name, e in moved.items()
+             if max(e["read"], e["written"]) >= 4 * one}
+    ends = {name for name in whole if not name.startswith("dwt_hc_")}
+    assert len(whole - ends) == 55
+    assert sorted(moved[name]["scope"] for name in ends) == [
+        "bwd/LatentMoE/hc/expand", "fwd/LatentMoE/hc/read_out"]
+    total = sum(e["read"] + e["written"] for e in moved.values()) / one
+    assert 550 < total < 600, total
 
 
 def test_every_device_op_of_the_step_has_an_owner(xing_step):

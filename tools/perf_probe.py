@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
-model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|sums] ...  (no args = step/attn/head/model/opt).  One JSON line
+model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|sums|hc] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -46,6 +46,10 @@ cell's shape, forward and forward + backward: the chunked `jax.numpy`
 form against `dwt_gdr_fwd` / `dwt_gdr_bwd` (`ops/delta_rule.py`) at 1 /
 3 / 5 / 15 heads and one or two chunks a grid step, with each one's
 distance from the chunked form.
+`hc` reads the same way one sublayer's hyper-connection at Xing's
+stream (four lanes of 8,192 x 3,584): the plain route's fusions against
+`dwt_hc_pre` / `_post` / `_post_bwd` / `_pre_bwd` (`ops/hc_mix.py`) at
+three token tiles, with each kernel's GB/s over the bytes its pass moves.
 """
 
 from __future__ import annotations
@@ -985,6 +989,67 @@ def probe_delta(plans=((5, 1), (1, 2), (3, 2), (5, 2), (15, 2)),
                        "device_ops_ms": _device_ops_ms(f, *args, top=6)})
 
 
+def probe_hc(shape=(1, 4, 8192, 3584), tiles=(128, 256, 512)):
+    """One sublayer's hyper-connection at `xing4_0_29b_a4b.steady`'s
+    stream (1 x 4 lanes x 8,192 x 3,584, bfloat16), the branch a
+    doubling: `models/hyper_connection.py`'s plain route (`coefficients`,
+    `read`, `write`) against `ops/hc_mix.py`'s four kernels at several
+    token tiles, forward alone and forward + backward, every device op's
+    ms a call and each kernel's GB/s over the bytes its pass moves
+    (PERF.md section 6, PR 54)."""
+    from dlrover_wuqiong_tpu.models import hyper_connection as hc
+    from dlrover_wuqiong_tpu.ops import hc_mix
+
+    b, n, t, d = shape
+    cfg = hc.HyperConnectionConfig(hidden_size=d, lanes=n, norm_eps=1e-5)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    leaves = hc.HyperConnection(cfg).init(keys[0])["params"]
+    leaves = {**leaves, "phi": 0.02 * jax.random.normal(
+        keys[1], leaves["phi"].shape)}
+    x = jax.random.normal(keys[2], shape, jnp.bfloat16)
+    vector = b * t * d * 2  # one hidden vector a token, bytes
+    passes = {"dwt_hc_pre": n + 1, "dwt_hc_post": 2 * n + 1,
+              "dwt_hc_post_bwd": 3 * n + 2, "dwt_hc_pre_bwd": 3 * n + 1}
+
+    def plain(leaves, x):
+        h_pre, h_post, h_res = hc.coefficients(leaves, x, cfg)
+        return hc.write(h_res, h_post, x, 2 * hc.read(h_pre, x))
+
+    def kernels(tile):
+        plan = hc_mix.plan(t, tile)
+
+        def fn(leaves, x):
+            u, coef, x = hc_mix.mix_in(
+                x, leaves["phi"], leaves["alpha"][0], leaves["b_pre"],
+                cfg.norm_eps, plan)
+            h_post, h_res = hc._post_res(leaves, coef, cfg)
+            return hc_mix.mix_out(h_res, h_post, x, 2 * u, plan)
+        return fn
+
+    def both(fn):
+        return lambda leaves, x, d_out: jax.vjp(fn, leaves, x)[1](d_out)
+
+    want = jax.jit(both(plain))(leaves, x, x)
+    for name, fn, tile in [("plain", plain, None)] + [
+            ("dwt_hc", kernels(tile), tile) for tile in tiles]:
+        got = jax.jit(both(fn))(leaves, x, x)
+        off = [float(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)
+                             ).max() / jnp.abs(w.astype(jnp.float32)).max())
+               for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
+        for what, f, args in ((name, jax.jit(fn), (leaves, x)),
+                              (name + "_fwd_bwd", jax.jit(both(fn)),
+                               (leaves, x, x))):
+            ops = _device_ops_ms(f, *args, top=256)
+            _emit_raw({
+                "probe": "hc", "what": what, "shape": list(shape),
+                "tile": tile, "all_ops_ms": round(sum(ops.values()), 4),
+                "off_alpha_b_post_b_pre_b_res_phi_x": [
+                    round(o, 6) for o in off],
+                "gb_per_s": {k: round(passes[k] * vector / ms / 1e6, 1)
+                             for k, ms in ops.items() if k in passes},
+                "device_ops_ms": dict(list(ops.items())[:8])})
+
+
 ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "attn_sweep": probe_attn_sweep,
        "attn_direct": probe_attn_direct, "lib": probe_lib,
@@ -994,7 +1059,7 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "step": probe_step, "dispatch": probe_dispatch,
        "rpc": probe_rpc, "gmm": probe_gmm, "rows_map": probe_rows_map,
        "rope": probe_rope, "moe_numbers": probe_moe_numbers,
-       "delta": probe_delta, "sums": probe_sums}
+       "delta": probe_delta, "sums": probe_sums, "hc": probe_hc}
 
 
 def main(argv=None) -> int:
