@@ -419,6 +419,11 @@ while s < total_steps:
         with led.window("productive"):
             time.sleep(dt * (k_eff - n_rework))
     step = s + k_eff - 1
+    # counted BEFORE staged: a kill between the two re-executes a block
+    # (counted twice), never leaves a staged step in no count
+    for i in range(k_eff):
+        log.write(f"{time.time()} {s + i} {restart}\n")
+    log.flush()
     sd = {"w": np.full((8, 8), float(step), np.float32),
           "step": np.int64(step)}
     if flash:
@@ -429,9 +434,6 @@ while s < total_steps:
     if any((s + i) % interval == 0 for i in range(k_eff)) or \
         step == total_steps - 1:
         ckpt.save_checkpoint(step, sd, storage_type=StorageType.DISK)
-    for i in range(k_eff):
-        log.write(f"{time.time()} {s + i} {restart}\n")
-    log.flush()
     ctx.report_step(step)
     dump_ledger()  # boundary-cadence: the kill sees the latest split
     s += k_eff

@@ -20,8 +20,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..ops import mosaic
 from ..ops.flash_attention import (
-    _on_tpu,
     causal_tile_count,
     flash_attention_projected,
     mha,
@@ -32,13 +32,12 @@ from ..ops.flash_attention import (
 def goes_direct(cfg, n_head: int, head_dim: int, seq: int) -> bool:
     """Whether a self-attention of this shape, under this config, takes
     the DIRECT route: the kernels take it (`ops/flash_attention.
-    projected_ok`: heads on 128-lane slabs, on the TPU, a sequence a
-    block fits), and it runs where `attend` would have ended in `mha`'s
-    kernels — on one device, not over ring, Ulysses or a shard_map."""
+    projected_ok`: heads on 128-lane slabs, one TPU device, a sequence a
+    block fits), and `attend` would have ended in `mha`'s kernels — not
+    over ring or Ulysses, which a config with a mesh may name."""
     mesh = getattr(cfg, "mesh", None)
-    on_one_device = mesh is None or (
-        mesh.size == 1 and getattr(cfg, "attn_impl", "flash") == "flash")
-    return on_one_device and projected_ok(n_head, head_dim, seq)
+    flash = mesh is None or getattr(cfg, "attn_impl", "flash") == "flash"
+    return flash and projected_ok(n_head, head_dim, seq, mesh=mesh)
 
 
 def softmax_scale(cfg):
@@ -188,7 +187,7 @@ def attend(q, k, v, cfg, causal: bool = True):
             out = ulysses_attention(qt, kt, vt, mesh, causal=causal,
                                     sm_scale=scale, window=window)
         return out.transpose(0, 2, 1, 3)
-    if mesh is not None and mesh.size > 1 and _on_tpu():
+    if mosaic.kernel_site(mesh) in ("mesh", "manual"):
         # the Pallas kernels need a shard_map on a multi-device mesh; the
         # jnp reference off-TPU is partitioned by GSPMD like any other op
         from ..parallel.long_context import sharded_flash_attention

@@ -29,12 +29,9 @@ and the sums and count of the decay alpha and the write gate beta over
 heads and tokens — what says a gate has saturated.  They ride the step's
 metrics (`collect_delta_stats`, through `make_lm_loss.with_stats`).
 
-Which route the recurrence takes is the call's shapes and where it runs,
-nothing else: `ops/delta_rule.delta_route` reads the shapes, the backend
-and `GatedDeltaConfig.mesh` — the model config's own, as
-`models/mamba2.scans_on_one_device` reads `Mamba2Config.mesh` (a Mosaic
-kernel cannot be partitioned by GSPMD, so a mixer on a mesh of several
-devices keeps the chunked `jax.numpy` form).
+Which route the recurrence takes is `ops/delta_rule.delta_route`'s to
+say, from the call's shapes and where it runs (the backend and
+`GatedDeltaConfig.mesh`, the model config's own).
 
 The short convolution and the draw of `dt_bias` are `models/mamba2.py`'s.
 

@@ -90,7 +90,7 @@ class LatentAttention(nn.Module):
     @nn.compact
     def __call__(self, x, cos, sin):
         """x (B, T, hidden); cos, sin: `rope_freqs(qk_rope_head_dim, ...)`."""
-        from ..ops.flash_attention import _kept_mask, kernel_lanes
+        from ..ops.flash_attention import kept_mask, kernel_lanes
         from .attention import attend
         from .fp8 import dense
 
@@ -137,7 +137,7 @@ class LatentAttention(nn.Module):
             att = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
             att = att * cfg.attn_scale if cfg.attn_scale \
                 else att / jnp.sqrt(jnp.float32(dn + dr))
-            att = jnp.where(_kept_mask(T, T), att, -jnp.inf)
+            att = jnp.where(kept_mask(T, T), att, -jnp.inf)
             att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
             y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
         return dense(cfg, C, "o_proj", use_bias=False)(

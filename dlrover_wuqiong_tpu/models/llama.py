@@ -204,16 +204,13 @@ def apply_rope(x, cos, sin, mesh=None, head_dim: int = 0):
     `head_dim` d wider than the tables' 2 * half (0 = as wide) rotates a
     head's FIRST 2 * half features and passes the rest as they are.
 
-    Two routes, chosen by what the call can observe
-    (`ops/rope.rope_route`, never a knob): on the TPU, d of 64 or 128,
-    rows whose h*d is a whole number of 128-lane slabs or one lone head
-    of 64, on one device (`mesh` is the model config's) or inside a
-    `shard_map`, take `ops/rope.py`'s kernel `dwt_rope`, one read and
-    one write of the rows, differentiated by the same kernel —
-    SmallThinker's and OLMoE's q and k, latent attention's q heads and
-    its one shared key part.  Every other call — the CPU, a head size or
-    an odd number of heads of 64 off the slab, a mesh of several devices
-    — takes the formula below: the plain route, and the tests' oracle.
+    Two routes, chosen by what the call can observe (`mesh` is the model
+    config's), never a knob: where `ops/rope.rope_route` says "kernel",
+    `ops/rope.py`'s `dwt_rope`, one read and one write of the rows,
+    differentiated by the same kernel — SmallThinker's and OLMoE's q and
+    k, latent attention's q heads and its one shared key part.  Every
+    other call takes the formula below: the plain route, and the tests'
+    oracle.
 
     A head's two halves trade places by two rolls of the last axis and a
     select (a roll never wraps into a lane that is kept; over one head's
@@ -260,7 +257,7 @@ class LlamaAttention(nn.Module):
             goes_direct,
             window_tiles,
         )
-        from ..ops.flash_attention import _kept_mask, kv_route
+        from ..ops.flash_attention import kept_mask, kv_route
         from .fp8 import dense
 
         cfg = self.config
@@ -311,7 +308,7 @@ class LlamaAttention(nn.Module):
             att = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
             att = att * cfg.attn_scale if cfg.attn_scale else \
                 att / jnp.sqrt(jnp.float32(hd))
-            att = jnp.where(_kept_mask(T, T, cfg.attn_window or None), att,
+            att = jnp.where(kept_mask(T, T, cfg.attn_window or None), att,
                             -jnp.inf)
             att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
             y = jnp.einsum("bhqk,bkhd->bqhd", att, v)

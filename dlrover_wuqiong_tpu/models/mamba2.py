@@ -16,10 +16,8 @@ recomputed and backward, carry it), `gate_norm`, `out_proj`.  Parameter
 names are matched by `parallel/sharding.py` (the two projections as
 dense kernels, everything else replicated).
 
-Which route the scan takes is the call's shapes and where it runs,
-nothing else: `ops/ssd.scan_route` reads the shapes and the backend,
-`scans_on_one_device` the mesh (a Mosaic kernel cannot be partitioned by
-GSPMD, so a mixer on a mesh of several devices keeps the plain form).
+Which route the scan takes is `ops/ssd.scan_route`'s to say, from the
+call's shapes and where it runs (the backend and `Mamba2Config.mesh`).
 
 Parity: none — the reference's model zoo (atorch) is attention-only; the
 equations are `nemotron_h`'s, as benchmark/reference_nemotron_h.py
@@ -36,7 +34,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.ssd import ssd_scan, ssd_scan_plain
+from ..ops.ssd import ssd_scan
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,12 +69,6 @@ class Mamba2Config:
                 + (self.conv_kernel + 1) * self.conv_dim   # conv + bias
                 + 3 * self.num_heads                       # dt_bias A_log D
                 + di + di * h)                             # gate_norm out
-
-
-def scans_on_one_device(cfg: Mamba2Config) -> bool:
-    """Whether the mixer's scan runs where `ops/ssd.ssd_scan` may take
-    its kernels (as `models/attention.goes_direct` reads the mesh)."""
-    return cfg.mesh is None or cfg.mesh.size == 1
 
 
 def _dt_bias_init(cfg: Mamba2Config):
@@ -144,12 +136,11 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssd"):
             dlt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
             a = -jnp.exp(a_log.astype(jnp.float32))
-        scan = ssd_scan if scans_on_one_device(cfg) else ssd_scan_plain
-        y = scan(
+        y = ssd_scan(
             x.reshape(bsz, t, cfg.num_heads, cfg.head_dim), dlt, a,
             b_mat.reshape(bsz, t, cfg.n_groups, cfg.state_size),
             c_mat.reshape(bsz, t, cfg.n_groups, cfg.state_size),
-            d_skip, chunk=cfg.chunk_size, dtype=cfg.dtype)
+            d_skip, chunk=cfg.chunk_size, dtype=cfg.dtype, mesh=cfg.mesh)
 
         scale = self.param("gate_norm_scale", nn.initializers.ones, (di,))
         with jax.named_scope("gate_norm"):

@@ -48,8 +48,8 @@ stays under the scope `moe/router`).
 
 Which kernels run the grouped products AND the elementwise passes
 between them is `ops/grouped_matmul.experts_route`'s to say, once a
-layer call (`layer_route`), from the call's shapes, mesh and backend: a
-whole layer fills its T*k-row buffer and keeps `lax.ragged_dot` (the TPU
+layer call, from the call's shapes, its mesh and the backend: a whole
+layer fills its T*k-row buffer and keeps `lax.ragged_dot` (the TPU
 compiler's own grouped kernels, 57% of peak on OLMoE's full buffer) and
 the `jax.numpy` lines below, which stay the one definition of the
 mathematics; a SHARE on one TPU device runs `dwt_gmm` / `dwt_tgmm`,
@@ -597,17 +597,6 @@ def _combine_bwd(route, res, d_out):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def layer_route(rows: int, w_gate: Optional[jax.Array], w_in: jax.Array,
-                w_down: jax.Array, num_experts: Optional[int],
-                mesh=None) -> str:
-    """`ops/grouped_matmul.experts_route` of a layer's weights: the one
-    route of its grouped products AND of the elementwise passes between
-    them, from what the call can observe."""
-    return experts_route(
-        rows, [w.shape for w in (w_gate, w_in, w_down) if w is not None],
-        num_experts, mesh)
-
-
 @functools.lru_cache(maxsize=None)
 def _activation(gate_act):
     """An expert's activation as a function of the first products'
@@ -671,7 +660,8 @@ def grouped_experts(tokens: jax.Array, gates: jax.Array, experts: jax.Array,
     T, top_k = experts.shape
     E = w_in.shape[0]
     share = num_experts is not None and E < num_experts
-    route = layer_route(T * top_k, w_gate, w_in, w_down, num_experts, mesh)
+    route = experts_route(T * top_k, (w_gate, w_in, w_down), num_experts,
+                          mesh)
     with jax.named_scope("dispatch"):
         flat_expert = experts.reshape(-1)              # (T*k,)
         if share:
@@ -907,8 +897,8 @@ class MoEMLP(nn.Module):
             # by assignment follow the held rows: row tiles (rows, index
             # entries) they walk, of the buffer's (no sync)
             rows = n_tok * cfg.top_k
-            route = layer_route(rows, w_gate, w_in, w_out, cfg.num_experts,
-                                cfg.mesh)
+            route = experts_route(rows, (w_gate, w_in, w_out),
+                                  cfg.num_experts, cfg.mesh)
             self.sow("intermediates", "moe_gmm_tiles",
                      jnp.stack(row_tiles(counts, rows, route)))
             self.sow("intermediates", "moe_map_tiles",

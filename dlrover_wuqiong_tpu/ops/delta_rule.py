@@ -80,8 +80,7 @@ shapes, the backend, the mesh it runs on), never by a knob:
   operands `_chunked` rounds to `dtype` are rounded in the kernel too,
   the same ones at the same places; what differs is the order of float32
   sums and that U, rounded, meets Kd^T where `_chunked` composes (A, B).
-  On the TPU, on one device (a Mosaic kernel cannot be partitioned by
-  GSPMD).
+  Where `delta_route` says so (`_SITES`).
 - "chunked": the form above in `jax.numpy`, the backward pass its
   differentiation but for the two `custom_vjp`s.  GSPMD partitions it, so
   a mixer on a mesh of several devices runs it, as does every CPU run and
@@ -120,15 +119,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _dot, _dot_c0, _dot_t, _on_tpu, _out_struct
-from .ssd import _iota, _put
+from . import mosaic
+from .mosaic import (
+    _compiler_params, _dot, _dot_c0, _dot_t, _einsum, _iota, _out_struct,
+    _put, _round_up)
 
 _HIGHEST = jax.lax.Precision.HIGHEST
-
-
-def _einsum(spec, *operands, dtype):
-    return jnp.einsum(spec, *(o.astype(dtype) for o in operands),
-                      preferred_element_type=jnp.float32)
 
 
 def _einsum32(spec, *operands):
@@ -138,11 +134,11 @@ def _einsum32(spec, *operands):
 
 # ------------------------------------------------------------ the route
 
-_SUBLANES = 16           # a bfloat16 tile's; a float32 tile's 8 divides it
 _WIDEST = 256            # lanes of a key or a value the kernels were built at
 _HEADS_A_STEP = 5        # see `_heads_block`
 _ROWS = 128              # the MXU's: a grid step's chunks fill them
-_VMEM_LIMIT = 64 * 1024 * 1024
+_VMEM_LIMIT = 64 * 1024 * 1024  # this kernel's own request of the compiler
+_SITES = frozenset({"device"})  # S9 (ROADMAP) adds "manual", and the record
 
 
 def _heads_block(h: int) -> int:
@@ -162,9 +158,7 @@ def _vmem_bytes(dk: int, dv: int, rows: int, hb: int) -> int:
     step: its double-buffered blocks (q, k, dq, dk float32; v, dv; dO
     float32; the entering states), the carried cotangent, and a head's
     tiles and temporaries."""
-    def lanes(d):
-        return -(-d // 128) * 128
-
+    lanes = functools.partial(_round_up, m=mosaic.LANES)
     blocks = rows * 4 * (4 * lanes(dk) + 3 * lanes(dv))
     state = dk * lanes(dv) * 4
     states = -(-rows // 64) * state
@@ -175,20 +169,21 @@ def _vmem_bytes(dk: int, dv: int, rows: int, hb: int) -> int:
 def delta_route(t: int, chunk: int, heads: int, dk: int, dv: int,
                 mesh=None):
     """Which route `gated_delta_rule` takes, from what the call can
-    observe: ("kernel", heads a grid step) on the TPU, on one device
-    (`mesh` is the mixer config's: a Mosaic kernel cannot be partitioned
-    by GSPMD), when the sequence is a whole number of chunks, the chunk a
-    multiple of the sublane tile, dk and dv multiples of 32 up to 256
-    lanes, and the blocks plus the state of a block of heads fit the VMEM
-    the call states; else "chunked" where the sequence is a whole number
-    of chunks, else "sequential".  The static counter of the decision
-    (with the compiled step's count of `dwt_gdr_*` custom calls); pinned
-    by tests/test_program_from_arguments.py for the benchmark's cell."""
+    observe: ("kernel", heads a grid step) where the call runs on one of
+    `_SITES` (`mesh` is the mixer config's: a Mosaic kernel cannot be
+    partitioned by GSPMD), when the sequence is a whole number of chunks,
+    the chunk a multiple of the sublane tile, dk and dv multiples of 32
+    up to 256 lanes, and the blocks plus the state of a block of heads
+    fit the VMEM the call states; else "chunked" where the sequence is a
+    whole number of chunks, else "sequential".  The static counter of
+    the decision (with the compiled step's count of `dwt_gdr_*` custom
+    calls); pinned by tests/test_program_from_arguments.py for the
+    benchmark's cell."""
     if t < chunk or t % chunk:
         return "sequential"
-    if not _on_tpu() or not (mesh is None or mesh.size == 1):
+    if mosaic.kernel_site(mesh) not in _SITES:
         return "chunked"
-    if chunk % _SUBLANES or dk % 32 or dv % 32 or max(dk, dv) > _WIDEST:
+    if chunk % mosaic.SUBLANES or dk % 32 or dv % 32 or max(dk, dv) > _WIDEST:
         return "chunked"
     hb = _heads_block(heads)
     rows = chunk * _chunks_a_step(chunk, t // chunk)
@@ -575,10 +570,8 @@ def _gdr_bwd_kernel(q_ref, k_ref, v_ref, bc_ref, br_ref, bt_ref, st_ref,
     dbc_ref[...], dbr_ref[...], dbt_ref[...] = dbc, dbr, dbt
 
 
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=_VMEM_LIMIT)
+_PARAMS = _compiler_params("parallel", "parallel", "arbitrary",
+                           vmem_limit=_VMEM_LIMIT)
 
 
 def _specs(size, n, hb, dk, dv, at):
@@ -620,7 +613,7 @@ def _gdr_forward_pallas(q, k, v, bc, br, bt, *, chunk, hb, dtype, save,
                   sp["col"]],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
-        compiler_params=_params(), interpret=interpret,
+        compiler_params=_PARAMS, interpret=interpret,
         name="dwt_gdr_fwd",
     )(q, k, v, bc, br, bt)
     return tuple(out) if save else (out[0], None)
@@ -647,7 +640,7 @@ def _gdr_backward_pallas(q, k, v, bc, br, bt, states, do, *, chunk, hb,
                    _out_struct(br.shape, f32, q),
                    _out_struct(bt.shape, f32, q)],
         scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
-        compiler_params=_params(), interpret=interpret,
+        compiler_params=_PARAMS, interpret=interpret,
         name="dwt_gdr_bwd",
     )(q, k, v, bc, br, bt, states, do)
 

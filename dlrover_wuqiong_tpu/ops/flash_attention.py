@@ -48,7 +48,7 @@ Design (FA2 scheme, canonical Mosaic structure):
   the counter of what they compute.  A call without a window — or with
   one no shorter than the keys — traces the program it did before any
   of this existed (pinned: tests/test_flash_attention_tiles.py).  The
-  jnp paths off the TPU take the same window (`_kept_mask`).
+  jnp paths off the TPU take the same window (`kept_mask`).
 - backward: ONE kernel wherever it can be — dq, dk and dv from a single
   recompute of p = exp(s - lse) per tile, IN TRANSPOSED SPACE (queries
   in lanes) so the (sq, sk) attention matrix never hits HBM and the
@@ -122,12 +122,10 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common.log import get_logger
-
-logger = get_logger("flash_attention")
+from . import mosaic
+from .mosaic import _compiler_params, _dot, _dot_c0, _dot_t, _out_struct
 
 NEG_INF = -1e30  # avoids inf-inf NaNs while dominating any real score
 LOG2E = 1.4426950408889634  # exp(x) == exp2(x * LOG2E); folding LOG2E
@@ -136,43 +134,10 @@ LOG2E = 1.4426950408889634  # exp(x) == exp2(x * LOG2E); folding LOG2E
 # (the hardware exponent unit is base-2; jnp.exp emits the mul per call)
 
 
-def _on_tpu() -> bool:
-    # a backend that fails to initialise raises from here: training on
-    # the jnp reference because the chip did not come up is not a mode
-    return jax.default_backend() == "tpu"
-
-
 # what every kernel here asks of the v5e's 128 MiB of VMEM, and what
 # `backward_route` reckons a fused backward's resident set against
 _VMEM_LIMIT = 100 * 1024 * 1024
-
-
-def _compiler_params(*semantics, vmem_limit: Optional[int] = None):
-    kw = {}
-    if vmem_limit is not None:
-        # the fused multi-head kernels hold q/k/v/o blocks for ALL heads
-        # plus per-head f32 scratch: past the 16MB default scoped limit,
-        # well inside v5e's 128MB physical VMEM
-        kw["vmem_limit_bytes"] = vmem_limit
-    return pltpu.CompilerParams(dimension_semantics=semantics, **kw)
-
-
-def _out_struct(shape, dtype, like):
-    """Kernel output struct.  Inside a shard_map (the only way a Mosaic
-    kernel runs on a multi-device mesh) the outputs vary over the same
-    manual axes as the operands; outside one the set is empty."""
-    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
-
-
-def _dot(a, b):
-    """a @ b with native-dtype (bf16) MXU multiply, f32 accumulation."""
-    return jax.lax.dot(a, b, preferred_element_type=jnp.float32)
-
-
-def _dot_t(a, b):
-    """a @ b.T with native-dtype MXU multiply, f32 accumulation."""
-    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
+_DIRECT_SITES = frozenset({"device"})  # `attend` places the (b, h, s, d) entry
 
 
 def _rel_mask(nq, nk, delta, transposed=False):
@@ -1041,12 +1006,6 @@ def _slab_heads(slabs: Optional[_Slabs]) -> dict:
 # ------------------------------------------------------------ backward kernels
 
 
-def _dot_c0(a, b):
-    """Contract dim 0 of both: (K, M) x (K, N) -> (M, N), f32 accumulate."""
-    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
 def _p_transposed(q, k, lse, mask, sm_scale):
     """Recompute p^T = exp(s^T - lse) as (block_k, block_q).
 
@@ -1620,7 +1579,7 @@ def _fa_backward_pallas(q, k, v, o, lse, do, causal: bool, sm_scale: float,
 # ----------------------------------------------------------------- reference
 
 
-def _kept_mask(sq: int, sk: int, window: Optional[int] = None):
+def kept_mask(sq: int, sk: int, window: Optional[int] = None):
     """(sq, sk) bool: query i sees key j iff 0 <= i + (sk - sq) - j, and
     with a window iff that distance is also < window.  The jnp paths'
     one mask."""
@@ -1631,7 +1590,7 @@ def _kept_mask(sq: int, sk: int, window: Optional[int] = None):
 
 
 def _kept_at(rows, cols, window: Optional[int]):
-    """`_kept_mask` for one block of the streamed paths: `rows` and
+    """`kept_mask` for one block of the streamed paths: `rows` and
     `cols` are the queries' and keys' absolute key positions."""
     dist = rows[:, None] - cols[None, :]
     return dist >= 0 if window is None else (dist >= 0) & (dist < window)
@@ -1645,7 +1604,7 @@ def _attention_reference(q, k, v, causal: bool, sm_scale: float,
     """
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if causal:
-        mask = _kept_mask(s.shape[-2], s.shape[-1], window)
+        mask = kept_mask(s.shape[-2], s.shape[-1], window)
         s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
@@ -1701,11 +1660,9 @@ def _fit_block(seq: int, pref: int) -> Optional[int]:
 
 
 def _use_pallas(sq, sk, d, block_q, block_k) -> bool:
-    if not _on_tpu():
-        return False
     # head_dim runs natively (lane-aligned) or zero-padded, so any d
     # qualifies; sequences need a workable tile size
-    return (_fit_block(sq, block_q) is not None
+    return (mosaic.on_tpu() and _fit_block(sq, block_q) is not None
             and _fit_block(sk, block_k) is not None)
 
 
@@ -1786,7 +1743,7 @@ def _fa_fwd_lse(q, k, v, causal, sm_scale, block_q, block_k, window=None):
 def _reference_with_lse(q, k, v, causal, scale, window=None):
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
-        mask = _kept_mask(s.shape[-2], s.shape[-1], window)
+        mask = kept_mask(s.shape[-2], s.shape[-1], window)
         s = jnp.where(mask, s, -jnp.inf)
     m = jnp.max(s, axis=-1, keepdims=True)
     m = jnp.where(jnp.isfinite(m), m, 0.0)
@@ -1924,7 +1881,7 @@ def _fa_bwd_impl(causal, sm_scale, block_q, block_k, res, g, glse,
     # jnp recompute fallback (matches _attention_reference numerics)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
-        s = jnp.where(_kept_mask(sq, sk, window), s, -jnp.inf)
+        s = jnp.where(kept_mask(sq, sk, window), s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     g32 = g.astype(jnp.float32)
     v32 = v.astype(jnp.float32)
@@ -2007,10 +1964,10 @@ def attention_route(n_head: int, head_dim: int,
     cells."""
     if v_head_dim not in (None, head_dim):
         return "transposed", 0
-    if head_dim % 128 == 0:
-        return "direct", 1
-    if head_dim == 64 and n_head % 2 == 0:
-        return "direct", 2
+    heads = mosaic.slab_heads(head_dim)
+    # what the kernels are written for: a head a slab, or a PAIR on one
+    if heads == 1 or (heads == 2 and n_head % 2 == 0):
+        return "direct", heads
     return "transposed", 0
 
 
@@ -2034,7 +1991,7 @@ def kv_route(n_head: int, n_kv: int, head_dim: int) -> Tuple[str, int]:
     rep, rest = divmod(n_head, n_kv)
     if rest:
         raise ValueError(f"{n_kv} kv heads do not divide {n_head} heads")
-    if rep == 1 or attention_route(n_head, head_dim) == ("direct", 1):
+    if rep == 1 or mosaic.slab_heads(head_dim) == 1:
         return "indexed", rep
     return "repeated", rep
 
@@ -2043,13 +2000,15 @@ _PROJECTED_BLOCK = 1024  # the direct calls' preferred block, q and keys
 
 
 def projected_ok(n_head: int, head_dim: int, seq: int,
-                 v_head_dim: Optional[int] = None) -> bool:
+                 v_head_dim: Optional[int] = None, mesh=None) -> bool:
     """Whether `flash_attention_projected` takes a self-attention of this
-    shape: the heads on slab boundaries (`attention_route`), on the TPU,
-    at a sequence a block fits (`_use_pallas`).  The one predicate of the
-    direct route: `models/attention.attend_projected` adds only where the
-    call runs (the mesh), and the entry itself refuses what this does."""
+    shape: the heads on slab boundaries (`attention_route`), the call on
+    one of `_DIRECT_SITES` (`mesh` is the model config's), at a sequence
+    a block fits (`_use_pallas`).  The one predicate of the direct
+    route: `models/attention.goes_direct` adds only the config's
+    `attn_impl`, and the entry itself refuses what this does."""
     return (attention_route(n_head, head_dim, v_head_dim)[0] == "direct"
+            and mosaic.kernel_site(mesh) in _DIRECT_SITES
             and _use_pallas(seq, seq, head_dim, _PROJECTED_BLOCK,
                             _PROJECTED_BLOCK))
 

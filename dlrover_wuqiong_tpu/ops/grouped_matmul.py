@@ -96,13 +96,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _dot, _dot_c0, _dot_t, _on_tpu, _out_struct
+from . import mosaic
+from .mosaic import (
+    LANES, _compiler_params, _dot, _dot_c0, _dot_t, _out_struct,
+    _reckoned_vmem)
 
-_LANES = 128
 _ROW_TILE = 256
 _COLUMN_TILE = 1024  # result columns a grid step takes, where they tile
-_VMEM_LIMIT = 96 * 1024 * 1024
-_MAP_VMEM_FLOOR = 16 * 1024 * 1024  # the compiler's own default
+_VMEM_LIMIT = 96 * 1024 * 1024  # these kernels' own request of the compiler
+_SITES = frozenset({"device"})  # M1 (ROADMAP) adds "manual", and the record
 
 
 # ------------------------------------------------------------ the route
@@ -111,7 +113,7 @@ def _column_tile(n: int, most: int = _COLUMN_TILE) -> int:
     """The largest multiple of 128 that divides `n` and is at most
     `most`; `n` whole where there is none (a block's lane axis is a
     multiple of 128 or the array's own)."""
-    for t in range(most - most % _LANES, 0, -_LANES):
+    for t in range(most - most % LANES, 0, -LANES):
         if n % t == 0:
             return t
     return n
@@ -134,16 +136,15 @@ def gmm_route(lhs_shape: Tuple[int, int], rhs_shape: Tuple[int, int, int],
     """Which route `grouped_matmul` takes: "kernel" where the weights
     hold FEWER groups than the router names (`rhs_shape[0] <
     num_experts`: the only case in which the group sizes can sum to less
-    than the buffer — a static fact of the call), on the TPU, on one
-    device (`mesh` is the model config's, None or of size 1), at a row
-    count `_ROW_TILE` divides and blocks that fit VMEM; else "plain".
+    than the buffer — a static fact of the call), where the call runs
+    on one of `_SITES` (`mesh` is the model config's), at a row count
+    `_ROW_TILE` divides and blocks that fit VMEM; else "plain".
     The static counter of the decision, with the compiled step's count
     of `dwt_gmm*` / `dwt_tgmm` custom calls (tests/test_tpu_compile.py),
     as `ops/ssd.scan_route` is of the scan's."""
     (m, c), (e, _, n) = lhs_shape, rhs_shape
-    if num_experts is None or e >= num_experts or not _on_tpu():
-        return "plain"
-    if mesh is not None and mesh.size > 1:
+    if num_experts is None or e >= num_experts \
+            or mosaic.kernel_site(mesh) not in _SITES:
         return "plain"
     if m % _ROW_TILE or _vmem_bytes(c, n) > _VMEM_LIMIT:
         return "plain"
@@ -155,23 +156,25 @@ def _map_vmem_bytes(blocks: Sequence[Tuple[int, int]], tile: int) -> int:
     entry) of its buffers and results: each double-buffered (a per-row
     one fills 128 lanes), and a float32 temporary a block and two
     besides."""
-    lanes = [max(c, _LANES) for c, _ in blocks]
+    lanes = [max(c, LANES) for c, _ in blocks]
     return 2 * tile * sum(n * size for n, (_, size) in zip(lanes, blocks)) \
         + (len(blocks) + 2) * tile * max(lanes) * 4
 
 
-def experts_route(rows: int, weight_shapes: Sequence[Tuple[int, int, int]],
-                  num_experts: Optional[int], mesh=None) -> str:
+def experts_route(rows: int, weights: Sequence, num_experts: Optional[int],
+                  mesh=None) -> str:
     """The ONE route of an expert layer's call, for its grouped products
-    (`weight_shapes`, each (E, C, N)) and the elementwise passes between
+    (`weights`: each an (E, C, N) array or its shape; a layer without a
+    gate matrix may hand None for it) and the elementwise passes between
     them: "kernel" where `gmm_route` says so of EVERY product and the
     maps' blocks fit VMEM, else "plain".  A map leaves the tiles behind
     the held rows unwritten and `lax.ragged_dot` reads them, so the two
     may never disagree: `models/moe.py::grouped_experts` asks once and
     hands the answer to `grouped_matmul` and to `rows_map`."""
+    shapes = [getattr(w, "shape", w) for w in weights if w is not None]
     routes = {gmm_route((rows, c), (e, c, n), num_experts, mesh)
-              for e, c, n in weight_shapes}
-    widest = max(max(c, n) for _, c, n in weight_shapes)
+              for e, c, n in shapes}
+    widest = max(max(c, n) for _, c, n in shapes)
     # the widest map there is: three buffers in, two out, of four bytes
     if routes != {"kernel"} or \
             _map_vmem_bytes([(widest, 4)] * 5, _ROW_TILE) > _VMEM_LIMIT:
@@ -291,9 +294,7 @@ def _tgmm_kernel(offsets_ref, group_ref, tile_ref, lhs_ref, rhs_ref, out_ref,
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
-def _params(*semantics):
-    return pltpu.CompilerParams(dimension_semantics=semantics,
-                                vmem_limit_bytes=_VMEM_LIMIT)
+_params = functools.partial(_compiler_params, vmem_limit=_VMEM_LIMIT)
 
 
 def _gmm_pallas(lhs, rhs, group_sizes, *, transposed, tile, columns,
@@ -402,9 +403,8 @@ def _rows_map_pallas(held_rows, *buffers, fn, tile, interpret, alias=None):
                    for o in outs],
         # operand 0 is the prefetched scalar
         input_output_aliases={} if alias is None else {1 + alias[0]: alias[1]},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=max(vmem * 5 // 4, _MAP_VMEM_FLOOR)),
+        compiler_params=_compiler_params(
+            "arbitrary", vmem_limit=_reckoned_vmem(vmem)),
         cost_estimate=pl.CostEstimate(
             flops=m * sum(c for c, _ in blocks), transcendentals=0,
             bytes_accessed=m * sum(c * size for c, size in blocks)),

@@ -43,11 +43,10 @@ output, `mix_out` takes it from there, so the stream's cotangent
 arrives at `mix_in`'s rule as one array and leaves it as one — no join,
 no add of stream-sized arrays in XLA.
 
-Which calls take the kernels is what a call can observe (`hc_route`):
-the TPU, one device or inside a `shard_map`, the hidden size a whole
-number of 128-lane slabs, the tokens a whole number of packed bfloat16
-tiles (16); a last tile of tokens may be ragged.  Everything else keeps
-`models/hyper_connection.py`'s formulas.
+Which calls take the kernels is what a call can observe (`hc_route`): on
+one of `_SITES`, the hidden size a whole number of 128-lane slabs, the
+tokens a whole number of packed bfloat16 tiles (16); a last tile may be
+ragged.  Everything else keeps `models/hyper_connection.py`'s formulas.
 
 What a v5e trace showed: PERF.md section 6, PR 54 (`tools/perf_probe.py
 hc`).
@@ -65,35 +64,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _dot, _dot_t, _on_tpu, _out_struct
-from .rope import _inside_shard_map
+from . import mosaic
+from .mosaic import (
+    LANES, _compiler_params, _dot, _dot_t, _out_struct, _reckoned_vmem,
+    _round_up)
 
-_LANES = 128
 _ROWS = 16  # tokens a chunk: one packed bfloat16 tile of sublanes
 _GROUP = 4  # 128-lane slabs of the hidden size worked on at a time
 _TOKEN_TILE = 128  # tokens a grid step: a bf16 lane's block is 0.9 MB
 _PRE_TILE = 512  # `dwt_hc_pre`'s: five blocks a step, not nine to fourteen
-_VMEM_FLOOR = 16 * 1024 * 1024  # the compiler's own default
+_SITES = frozenset({"device", "manual"})  # a token at a time: a shard is one
 _F32 = jnp.float32
 
 
 def hc_route(lanes: int, tokens: int, d: int, mesh=None) -> str:
     """Which route a hyper-connection over `lanes` lanes of `tokens` x
-    `d` takes: "kernel" (`dwt_hc_*`) on the TPU when the hidden size is
-    a whole number of 128-lane slabs, the tokens a whole number of 16,
-    and the call runs on one device (`mesh` is the model config's, None
-    or of size 1) or inside a `shard_map`; else "plain", the formulas of
+    `d` takes: "kernel" (`dwt_hc_*`) when the hidden size is a whole
+    number of 128-lane slabs, the tokens a whole number of 16, and the
+    call runs on one of `_SITES` (`mesh` is the model config's); else
+    "plain", the formulas of
     `models/hyper_connection.py`.  The static counter of the decision,
     with the compiled step's count of `dwt_hc_*` custom calls."""
-    if not _on_tpu() or lanes < 2 or d % _LANES or tokens % _ROWS:
-        return "plain"
-    if mesh is not None and mesh.size > 1 and not _inside_shard_map():
+    if lanes < 2 or d % LANES or tokens % _ROWS \
+            or mosaic.kernel_site(mesh) not in _SITES:
         return "plain"
     return "kernel"
-
-
-def _round_up(x: int, m: int) -> int:
-    return -(-x // m) * m
 
 
 def coef_rows(n: int) -> int:
@@ -115,8 +110,8 @@ def _each_group(d: int, body, carry=0, unroll=False):
     (PERF.md section 6, PR 54).  `unroll`: the loop's copies are made
     when the kernel is lowered, for a body of so few operations that the
     loop's own cost shows (`dwt_hc_pre` ran 0.65 ms rolled, 0.44 so)."""
-    slabs = d // _LANES
-    width = max(g for g in range(1, _GROUP + 1) if slabs % g == 0) * _LANES
+    slabs = d // LANES
+    width = max(g for g in range(1, _GROUP + 1) if slabs % g == 0) * LANES
 
     def step(g, carry):
         return body(pl.ds(pl.multiple_of(g * width, width), width), carry)
@@ -135,7 +130,7 @@ def _each_chunk(tile: int, body):
 
 def _slab_sum(v):
     """(rows, lanes) -> (rows, 128): the 128-lane slabs added up."""
-    return sum(v[:, s:s + _LANES] for s in range(0, v.shape[1], _LANES))
+    return sum(v[:, s:s + LANES] for s in range(0, v.shape[1], LANES))
 
 
 def _columns(parts, width: int):
@@ -149,7 +144,7 @@ def _columns(parts, width: int):
 
 
 def _zeros(count: int):
-    return (jnp.zeros((_ROWS, _LANES), _F32),) * count
+    return (jnp.zeros((_ROWS, LANES), _F32),) * count
 
 
 # --------------------------------------------------------------- forward
@@ -346,9 +341,8 @@ def _params(block_bytes: int, scratch_bytes: int):
     # every block double-buffered, the scratch, and room for a chunk's
     # temporaries and the small arrays' two layouts
     vmem = 2 * block_bytes + scratch_bytes + 4 * 1024 * 1024
-    return pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"),
-        vmem_limit_bytes=max(vmem * 5 // 4, _VMEM_FLOOR))
+    return _compiler_params("arbitrary", "arbitrary",
+                            vmem_limit=_reckoned_vmem(vmem))
 
 
 def _pre_pallas(x, phi_t, gb, *, eps, tile, interpret):
@@ -362,10 +356,10 @@ def _pre_pallas(x, phi_t, gb, *, eps, tile, interpret):
         out_specs=(_branch(tile, d), _small(kp, tile)),
         out_shape=(_out_struct((b, t, d), x.dtype, x),
                    _out_struct((b, kp, t), _F32, x)),
-        scratch_shapes=[pltpu.VMEM((tile, _LANES), _F32),
+        scratch_shapes=[pltpu.VMEM((tile, LANES), _F32),
                         pltpu.VMEM((tile, kp), _F32)],
         compiler_params=_params((n + 1) * lane + phi_t.size * size,
-                                2 * tile * _LANES * 4),
+                                2 * tile * LANES * 4),
         cost_estimate=pl.CostEstimate(
             flops=(2 * kp + 4) * x.size, transcendentals=b * t * kp,
             bytes_accessed=(n + 1) * b * t * d * size + phi_t.size * size
@@ -386,7 +380,7 @@ def _post_pallas(c, x, y, *, tile, interpret):
         out_shape=_out_struct(x.shape, x.dtype, x),
         scratch_shapes=[pltpu.VMEM((tile, cp), _F32)],
         compiler_params=_params((2 * n + 1) * tile * d * size,
-                                tile * _LANES * 4),
+                                tile * LANES * 4),
         cost_estimate=pl.CostEstimate(
             flops=2 * (n + 1) * x.size, transcendentals=0,
             bytes_accessed=(2 * n + 1) * b * t * d * size + c.size * 4),
@@ -410,7 +404,7 @@ def _post_bwd_pallas(c, g, x, y, *, tile, interpret):
         scratch_shapes=[pltpu.VMEM((tile, cp), _F32),
                         pltpu.VMEM((tile, cp), _F32)],
         compiler_params=_params((3 * n + 2) * tile * d * size,
-                                2 * tile * _LANES * 4),
+                                2 * tile * LANES * 4),
         cost_estimate=pl.CostEstimate(
             flops=4 * (n + 1) * x.size, transcendentals=0,
             bytes_accessed=(3 * n + 2) * b * t * d * size + 2 * c.size * 4),
@@ -440,7 +434,7 @@ def _pre_bwd_pallas(coef, d_coef, gb, du, x, px, phi_t, *, tile, interpret):
         input_output_aliases={5: 0},
         compiler_params=_params(
             (3 * n + 1) * lane + phi_t.size * (size + 4),
-            2 * tile * _LANES * 4 + tile * d * 4),
+            2 * tile * LANES * 4 + tile * d * 4),
         cost_estimate=pl.CostEstimate(
             flops=(4 * kp + 6) * x.size, transcendentals=b * t * kp,
             bytes_accessed=(3 * n + 1) * b * t * d * size
@@ -466,7 +460,7 @@ def plan(tokens: int, tile=None, interpret: bool = False) -> tuple:
     54: `dwt_hc_pre` 0.349 ms a call at 512 tokens, 0.443 at 128; the
     three others fastest at 128)."""
     tiles = (tile or _TOKEN_TILE, tile or _PRE_TILE)
-    assert tokens % _ROWS == 0 and not any(t % _LANES for t in tiles), (
+    assert tokens % _ROWS == 0 and not any(t % LANES for t in tiles), (
         tokens, tiles)
     return (("tile", min(tokens, tiles[0])),
             ("pre_tile", min(tokens, tiles[1])), ("interpret", interpret))

@@ -26,13 +26,9 @@ import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
 
+from . import mosaic
+
 BLOCK = 256
-
-
-def _on_tpu() -> bool:
-    # a backend that fails to initialise raises from here (it is not
-    # "not a TPU")
-    return jax.default_backend() == "tpu"
 
 
 # ------------------------------------------------------------- int8 blockwise
@@ -64,7 +60,7 @@ def quantize_int8_blockwise(x: jax.Array, block: int = BLOCK
         flat = jnp.pad(flat, (0, pad))
     rows = flat.size // block
     tiled = flat.reshape(rows, block)
-    if _on_tpu() and rows % 8 == 0:
+    if mosaic.on_tpu() and rows % 8 == 0:
         grid = (rows // 8,)
         q, s = pl.pallas_call(
             _quant_kernel,
@@ -89,7 +85,7 @@ def dequantize_int8_blockwise(q: jax.Array, scale: jax.Array,
                               dtype=jnp.float32) -> jax.Array:
     """Inverse of quantize_int8_blockwise."""
     rows, block = q.shape
-    if _on_tpu() and rows % 8 == 0:
+    if mosaic.on_tpu() and rows % 8 == 0:
         x = pl.pallas_call(
             _dequant_kernel,
             grid=(rows // 8,),

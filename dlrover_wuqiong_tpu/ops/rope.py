@@ -28,13 +28,12 @@ backward pass of the `jax.custom_vjp` is the SAME kernel with the sine
 negated (in VMEM, a tile a grid step), and keeps nothing but the table.
 
 Which calls take it is what a call can observe, never a knob
-(`rope_route`): on the TPU, d of 64 or 128, rows whose width is a whole
-number of slabs — or ONE head of 64, half a slab, which is padded to one
-(latent attention's shared key part: it then reads the table its q
-heads read, and the formula's own tables are not built at all) — on one
-device or inside a `shard_map` (a Mosaic kernel cannot be partitioned by
-GSPMD).  Every other call keeps the formula of `models/llama.apply_rope`,
-which is the plain route and the tests' oracle.
+(`rope_route`): d of 64 or 128, rows whose width is a whole number of
+slabs — or ONE head of 64, half a slab, which is padded to one (latent
+attention's shared key part: it then reads the table its q heads read,
+and the formula's own tables are not built at all) — on one of `_SITES`.
+Every other call keeps the formula of `models/llama.apply_rope`, which
+is the plain route and the tests' oracle.
 
 What a v5e trace showed: PERF.md section 6, PR 44
 (`tools/perf_probe.py rope`).
@@ -51,34 +50,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _on_tpu, _out_struct
+from . import mosaic
+from .mosaic import LANES, _compiler_params, _out_struct, _reckoned_vmem
 
-_LANES = 128
 _ROW_TILE = 512  # positions a grid step: a bf16 block of 3,584 lanes is 3.5 MB
-_VMEM_FLOOR = 16 * 1024 * 1024  # the compiler's own default
-
-
-def _inside_shard_map() -> bool:
-    """Whether the trace runs inside a `shard_map` over every axis of
-    its mesh: the one place a kernel runs on a mesh of several devices."""
-    mesh = jax.sharding.get_abstract_mesh()
-    return bool(mesh.axis_names) and set(mesh.manual_axes) == set(
-        mesh.axis_names)
+_SITES = frozenset({"device", "manual"})  # a row at a time: a shard is one
 
 
 def rope_route(lanes: int, d: int, mesh=None) -> str:
     """Which route a rotation of rows `lanes` wide, heads of `d`, takes:
-    "kernel" (`dwt_rope`) on the TPU when a head is a slab or half of
-    one, the rows are a whole number of 128-lane slabs or one lone head
-    (half a slab: padded), and the call runs on one device (`mesh` is
-    the model config's, None or of size 1) or inside a `shard_map`; else
+    "kernel" (`dwt_rope`) when a head is a slab or half of one
+    (`mosaic.slab_heads` 1 or 2 of one slab), the rows are a whole number
+    of 128-lane slabs or one lone head (half a slab: padded), and the
+    call runs on one of `_SITES` (`mesh` is the model config's); else
     "plain", `models/llama.apply_rope`'s own lines.  The static counter
     of the decision, with the compiled step's count of `dwt_rope` custom
     calls, as `ops/ssd.scan_route` is of the scan's."""
-    slabs = lanes % _LANES == 0 or lanes == d  # whole, or a lone head
-    if not _on_tpu() or d not in (64, 128) or not slabs:
-        return "plain"
-    if mesh is not None and mesh.size > 1 and not _inside_shard_map():
+    heads = mosaic.slab_heads(d)
+    a_slab = heads in (1, 2) and heads * d == LANES  # d of 128, or 64
+    slabs = lanes % LANES == 0 or lanes == d  # whole, or a lone head
+    if not a_slab or not slabs or mosaic.kernel_site(mesh) not in _SITES:
         return "plain"
     return "kernel"
 
@@ -87,7 +78,7 @@ def rope_table(cos, sin):
     """`rope_freqs`' (T, d/2) cos and sin -> the kernel's float32 table
     over one 128-lane slab, (T, 128): `[cos | sin]` a head, two heads
     side by side at d = 64."""
-    heads = _LANES // (2 * cos.shape[-1])
+    heads = LANES // (2 * cos.shape[-1])
     return jnp.tile(jnp.concatenate([cos, sin], axis=-1).astype(jnp.float32),
                     (1, heads))
 
@@ -104,13 +95,13 @@ def _rope_kernel(x_ref, table_ref, o_ref, *, half, inverse):
     s = jnp.where(upper, cos_sin, -sin_cos)  # [-sin | sin]
     if inverse:
         s = -s
-    for slab in range(x_ref.shape[-1] // _LANES):
-        lanes = slice(slab * _LANES, (slab + 1) * _LANES)
+    for slab in range(x_ref.shape[-1] // LANES):
+        lanes = slice(slab * LANES, (slab + 1) * LANES)
         x = x_ref[0, :, lanes].astype(jnp.float32)
         partner = pltpu.roll(x, half, 1)
-        if 2 * half < _LANES:  # two heads a slab
+        if 2 * half < LANES:  # two heads a slab
             partner = jnp.where(upper, partner,
-                                pltpu.roll(x, _LANES - half, 1))
+                                pltpu.roll(x, LANES - half, 1))
         o_ref[0, :, lanes] = (x * c + partner * s).astype(o_ref.dtype)
 
 
@@ -122,20 +113,19 @@ def _rope_pallas(x, table, *, half, inverse, tile, interpret):
     rows = pl.BlockSpec((1, tile, lanes), lambda i, j: (j, i, 0))
     # blocks in and out and the table's tile, double-buffered, and the
     # float32 temporaries of the table's two forms and of a slab
-    vmem = 2 * (2 * tile * lanes * size + tile * _LANES * 4) \
-        + 10 * tile * _LANES * 4
+    vmem = 2 * (2 * tile * lanes * size + tile * LANES * 4) \
+        + 10 * tile * LANES * 4
     return pl.pallas_call(
         functools.partial(_rope_kernel, half=half, inverse=inverse),
         grid=(pl.cdiv(t, tile), b),
-        in_specs=[rows, pl.BlockSpec((tile, _LANES), lambda i, j: (i, 0))],
+        in_specs=[rows, pl.BlockSpec((tile, LANES), lambda i, j: (i, 0))],
         out_specs=rows,
         out_shape=_out_struct(x.shape, x.dtype, x),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=max(vmem * 5 // 4, _VMEM_FLOOR)),
+        compiler_params=_compiler_params(
+            "parallel", "arbitrary", vmem_limit=_reckoned_vmem(vmem)),
         cost_estimate=pl.CostEstimate(
             flops=3 * x.size, transcendentals=0,
-            bytes_accessed=2 * x.size * size + t * _LANES * 4),
+            bytes_accessed=2 * x.size * size + t * LANES * 4),
         interpret=interpret,
         name="dwt_rope",
     )(x, table)
@@ -171,8 +161,8 @@ def _rope_kernels(x, cos, sin, tile=None, interpret=False):
     # packed bfloat16 tile, 16, or the array's own
     plan = (("half", cos.shape[-1]), ("tile", min(t, tile or _ROW_TILE)),
             ("interpret", interpret))
-    if lanes < _LANES:  # a lone head of 64: an empty head beside it
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, _LANES - lanes)))
+    if lanes < LANES:  # a lone head of 64: an empty head beside it
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, LANES - lanes)))
     return _rotated(x, rope_table(cos[:t], sin[:t]), plan)[..., :lanes]
 
 

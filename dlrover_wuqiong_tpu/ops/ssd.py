@@ -21,7 +21,7 @@ rounded to (accumulation is float32); C B^T o decay is formed in float32
 and rounded once; the mask is applied BEFORE the exp.
 
 Two routes compute it, chosen by `scan_route` from what a call can
-observe (its shapes, the backend), never by a knob:
+observe (its shapes, where it runs), never by a knob:
 
 - "kernel": a pair of Pallas (Mosaic) kernels, `dwt_ssd_fwd` and
   `dwt_ssd_bwd`, behind one `jax.custom_vjp`.  A grid step is one
@@ -41,8 +41,8 @@ observe (its shapes, the backend), never by a knob:
 - "plain": `jax.numpy` einsums, the backward pass their differentiation,
   the state entering a chunk as one (chunks x chunks) product.  Off the
   TPU, on a mesh of several devices (a Mosaic kernel cannot be
-  partitioned by GSPMD; `models/mamba2.py` reads the mesh), at shapes the
-  kernels do not take — and the tests' oracle.
+  partitioned by GSPMD; `_SITES`), at shapes the kernels do not take —
+  and the tests' oracle.
 
 `benchmark/`'s `kernel.ssd_roofline` counts the RECURRENCE's work from
 shapes, whatever computes it.
@@ -64,27 +64,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _dot, _dot_c0, _dot_t, _on_tpu, _out_struct
-
-
-def _einsum(spec, *operands, dtype):
-    return jnp.einsum(spec, *(o.astype(dtype) for o in operands),
-                      preferred_element_type=jnp.float32)
+from . import mosaic
+from .mosaic import (
+    LANES, _compiler_params, _dot, _dot_c0, _dot_t, _einsum, _iota,
+    _out_struct, _put)
 
 
 # ------------------------------------------------------------ the route
 
-_LANES = 128
 _BLOCK_LANES = 1024  # lanes of x a grid step takes: 16 heads of 64
-_VMEM_LIMIT = 64 * 1024 * 1024
-
-
-def _slab_heads(p: int) -> int:
-    """Heads on one 128-lane slab of x's (b, T, H*P) layout; 0 where a
-    head falls on no slab boundary."""
-    if p % _LANES == 0:
-        return 1
-    return _LANES // p if _LANES % p == 0 and p >= 32 else 0
+_VMEM_LIMIT = 64 * 1024 * 1024  # this kernel's own request of the compiler
+_SITES = frozenset({"device"})  # S9 (ROADMAP) adds "manual", and the record
 
 
 def _heads_block(r: int, p: int) -> int:
@@ -93,7 +83,7 @@ def _heads_block(r: int, p: int) -> int:
     at granite's shape (PERF.md section 6, PR 34): 4 / 8 / 16 heads a
     step run a layer's two forwards and backward in 4.7 / 4.0 / 3.5 ms,
     32 and 64 within 4% of 16 at two and four times the unrolled code."""
-    s = _slab_heads(p)
+    s = mosaic.slab_heads(p)
     if not s or r % s:
         return 0
     hb = s
@@ -112,21 +102,21 @@ def _vmem_bytes(h: int, p: int, n: int, chunk: int, hb: int) -> int:
             + 2 * 6 * chunk * n * 4)         # B, C, their transposes, dB, dC
 
 
-def scan_route(h: int, p: int, g: int, n: int, chunk: int,
-               t: int) -> Tuple[str, int]:
+def scan_route(h: int, p: int, g: int, n: int, chunk: int, t: int,
+               mesh=None) -> Tuple[str, int]:
     """Which route `ssd_scan` takes at these shapes: ("kernel", heads a
-    grid step) on the TPU when the chunk is a multiple of 128 (a chunk
-    is the lane axis of the decay tile), the state size too (the lane
-    axis of B and C), the heads fall on 128-lane slabs of x and a block
-    fits VMEM; else ("plain", 0).  The static counter of the decision
-    (with the compiled step's count of `dwt_ssd_*` custom calls), as
+    grid step) where the call runs on one of `_SITES` (`mesh` is the
+    mixer config's) when the chunk is a multiple of 128 (a chunk is the
+    lane axis of the decay tile), the state size too (the lane axis of B
+    and C), the heads fall on 128-lane slabs of x and a block fits VMEM;
+    else ("plain", 0).  The static counter of the decision (with the
+    compiled step's count of `dwt_ssd_*` custom calls), as
     `ops/flash_attention.attention_route` is of the attention's; pinned
-    by tests/test_program_from_arguments.py for the benchmark's cells.
-    Where the call runs (the mesh) is `models/mamba2.py`'s to add."""
-    if not _on_tpu() or t % chunk or h % g:
+    by tests/test_program_from_arguments.py for the benchmark's cells."""
+    if mosaic.kernel_site(mesh) not in _SITES or t % chunk or h % g:
         return "plain", 0
     hb = _heads_block(h // g, p)
-    if not hb or chunk % _LANES or n % _LANES:
+    if not hb or chunk % LANES or n % LANES:
         return "plain", 0
     if _vmem_bytes(h, p, n, chunk, hb) > _VMEM_LIMIT:
         return "plain", 0
@@ -137,16 +127,17 @@ def scan_route(h: int, p: int, g: int, n: int, chunk: int,
 
 @jax.named_scope("ssd")
 def ssd_scan(x, dlt, a, b_mat, c_mat, d_skip, chunk: int = 128,
-             dtype=jnp.float32):
+             dtype=jnp.float32, mesh=None):
     """x (b, T, H, P); dlt (b, T, H), the step sizes AFTER softplus;
     a (H,), negative; b_mat, c_mat (b, T, G, N), head h using group
-    h // (H/G); d_skip (H,).  Returns y (b, T, H, P) in float32.
+    h // (H/G); d_skip (H,); `mesh` the mixer config's.  Returns y
+    (b, T, H, P) in float32.
 
     T must be a multiple of `chunk`: a ragged last chunk would need a
     padded copy of every operand, and no caller has one."""
     _check(x, b_mat, chunk)
     route, hb = scan_route(x.shape[2], x.shape[3], *b_mat.shape[2:], chunk,
-                           x.shape[1])
+                           x.shape[1], mesh)
     if route == "kernel":
         return _scan_kernels(x, dlt, a, b_mat, c_mat, d_skip, chunk, dtype,
                              hb)
@@ -156,8 +147,8 @@ def ssd_scan(x, dlt, a, b_mat, c_mat, d_skip, chunk: int = 128,
 @jax.named_scope("ssd")
 def ssd_scan_plain(x, dlt, a, b_mat, c_mat, d_skip, chunk: int = 128,
                    dtype=jnp.float32):
-    """`ssd_scan` on the plain route whatever the shapes: for a caller
-    on a mesh of several devices, and the tests' oracle."""
+    """`ssd_scan` on the plain route whatever the shapes and the site:
+    the tests' oracle."""
     _check(x, b_mat, chunk)
     return _scan_plain(x, dlt, a, b_mat, c_mat, d_skip, chunk, dtype)
 
@@ -233,10 +224,6 @@ def _scan_plain(x, dlt, a, b_mat, c_mat, d_skip, chunk, dtype):
 # (N, L) @ (dlt x o to_end) (L, hb*P) fills it, C (L, N) @ it is the
 # entering state's part of y, every head of the block in one product.
 
-def _iota(shape, axis):
-    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
-
-
 def _spread(cols, lane_head):
     """s columns (L, 1), one a head of a slab -> (L, W): each over its
     own head's lanes (a head a slab: the column itself)."""
@@ -258,11 +245,6 @@ def _last_row(v):
 def _own(v, lane_head, i, s):
     """v (.., W) with every lane of another head of the slab zeroed."""
     return v if s == 1 else jnp.where(lane_head == i, v, 0.0)
-
-
-def _put(acc, index, i, v):
-    """acc with column (or row) i set to the broadcast of v."""
-    return jnp.where(index == i, v, acc)
 
 
 def _slab(refs, u, w, s, lane_head):
@@ -426,10 +408,8 @@ def _ssd_bwd_kernel(x_ref, dl_ref, cc_ref, cr_ref, b_ref, c_ref, ct_ref,
         db_ref[...] += _dot_c0(dcbb, cm)
 
 
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        vmem_limit_bytes=_VMEM_LIMIT)
+_PARAMS = _compiler_params("parallel", "arbitrary", "arbitrary",
+                           vmem_limit=_VMEM_LIMIT)
 
 
 def _specs(chunk, hb, p, n, nb, nbg, at):
@@ -468,7 +448,7 @@ def _ssd_forward_pallas(x, dl_col, cc, cr, bm, bt, cm, d_vec, *, chunk, p,
         out_shape.append(_out_struct((bsz, c, n, hp), jnp.float32, x))
         out_specs.append(sp["state"])
     out = pl.pallas_call(
-        functools.partial(_ssd_fwd_kernel, p=p, s=_slab_heads(p) or 1,
+        functools.partial(_ssd_fwd_kernel, p=p, s=mosaic.slab_heads(p) or 1,
                           nbg=nbg, dtype=dtype, save=save),
         grid=(bsz, c, nb),
         in_specs=[sp["x"], sp["col"], sp["col"], sp["row"], sp["bc"],
@@ -477,7 +457,7 @@ def _ssd_forward_pallas(x, dl_col, cc, cr, bm, bt, cm, d_vec, *, chunk, p,
         scratch_shapes=[pltpu.VMEM((nb, n, hb * p), jnp.float32),
                         pltpu.VMEM((chunk, chunk), jnp.float32),
                         pltpu.VMEM((chunk, hb * p), dtype)],
-        compiler_params=_params(), interpret=interpret,
+        compiler_params=_PARAMS, interpret=interpret,
         name="dwt_ssd_fwd",
     )(x, dl_col, cc, cr, bm, bt, cm, d_vec)
     return tuple(out) if save else (out[0], None)
@@ -492,7 +472,7 @@ def _ssd_backward_pallas(x, dl_col, cc, cr, bm, cm, ct, d_vec, states, dy,
     sp = _specs(chunk, hb, p, n, nb, nbg, lambda k: c - 1 - k)
     f32 = jnp.float32
     return pl.pallas_call(
-        functools.partial(_ssd_bwd_kernel, p=p, s=_slab_heads(p) or 1,
+        functools.partial(_ssd_bwd_kernel, p=p, s=mosaic.slab_heads(p) or 1,
                           nbg=nbg, dtype=dtype),
         grid=(bsz, c, nb),
         in_specs=[sp["x"], sp["col"], sp["col"], sp["row"], sp["bc"],
@@ -510,7 +490,7 @@ def _ssd_backward_pallas(x, dl_col, cc, cr, bm, cm, ct, d_vec, states, dy,
                         pltpu.VMEM((chunk, chunk), f32),
                         pltpu.VMEM((chunk, hb * p), dtype),
                         pltpu.VMEM((chunk, hb * p), dtype)],
-        compiler_params=_params(), interpret=interpret,
+        compiler_params=_PARAMS, interpret=interpret,
         name="dwt_ssd_bwd",
     )(x, dl_col, cc, cr, bm, cm, ct, d_vec, states, dy)
 

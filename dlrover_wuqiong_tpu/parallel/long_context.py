@@ -98,18 +98,6 @@ def sharded_flash_attention(q, k, v, mesh: Mesh, causal: bool = True,
 # ------------------------------------------------------------- lse utilities
 
 
-def _attention_with_lse(q, k, v, causal: bool, sm_scale: Optional[float]):
-    """(b, h, sq, d) attention returning (o, lse (b, h, sq) f32) — jnp path
-    usable on any backend (shared with ops.flash_attention's fallback)."""
-    import math
-
-    from ..ops.flash_attention import _reference_with_lse
-
-    d = q.shape[-1]
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    return _reference_with_lse(q, k, v, causal, scale)
-
-
 def _merge_partials(o1, lse1, o2, lse2):
     """Combine two blockwise attention partials over disjoint key sets."""
     m = jnp.maximum(lse1, lse2)
@@ -130,11 +118,13 @@ def _merge_partials(o1, lse1, o2, lse2):
 def _chunk_attention(q, k, v, causal: bool, sm_scale: Optional[float]):
     """(o, lse) for one KV chunk — the Pallas kernel on TPU (O(s_local) VMEM
     working set, no score matrix in HBM), jnp reference elsewhere."""
-    from ..ops.flash_attention import _on_tpu, flash_attention_with_lse
+    from ..ops import flash_attention as fa
+    from ..ops import mosaic
 
-    if _on_tpu():
-        return flash_attention_with_lse(q, k, v, causal, sm_scale)
-    return _attention_with_lse(q, k, v, causal, sm_scale)
+    if mosaic.kernel_site() != "off":  # a ring step is inside a shard_map
+        return fa.flash_attention_with_lse(q, k, v, causal, sm_scale)
+    return fa._reference_with_lse(
+        q, k, v, causal, fa._resolve_scale(sm_scale, q.shape[-1]))
 
 
 def _ring_attention_local(q, k, v, *, axis_name: str, n: int, causal: bool,

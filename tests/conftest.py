@@ -29,6 +29,7 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 import collections  # noqa: E402
+import functools  # noqa: E402
 import json  # noqa: E402
 import threading  # noqa: E402
 
@@ -80,6 +81,41 @@ def pytest_collection_modifyitems(config, items):
         ordered += by_file.get(name, [])
     assert len(ordered) == len(items)
     items[:] = ordered
+
+
+@pytest.fixture
+def on_tpu(request, monkeypatch):
+    """The backend said to be the TPU for the whole test — to EVERY
+    caller at once: `ops/mosaic.on_tpu` is the one reading of the backend
+    (tests/test_program_from_arguments.py guards that no module keeps a
+    copy), so a kernel module added later is covered without being named
+    here.  Parametrise it indirectly with False for a route's "off the
+    TPU" cases.  What the test then traces is what one TPU device
+    traces: run it only behind the kernels' interpret mode
+    (`held_rows_interpreted`, a file's own fixture), or compile it for a
+    described chip.  A test that wants the patch for one block only
+    (`monkeypatch.context()`) sets the same one name."""
+    from dlrover_wuqiong_tpu.ops import mosaic
+
+    said = getattr(request, "param", True)
+    monkeypatch.setattr(mosaic, "on_tpu", lambda: said)
+    return said
+
+
+@pytest.fixture
+def held_rows_interpreted(on_tpu, monkeypatch):
+    """What a share of an expert layer takes on one TPU device, here:
+    `experts_route`'s own decision with the backend said to be the TPU
+    and a row tile (32) that divides the nano buffers, the grouped
+    products, the maps between them and the unwritten buffer in
+    interpret mode."""
+    from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
+
+    monkeypatch.setattr(gm, "_ROW_TILE", 32)
+    for name in ("_grouped_kernels", "_rows_map_kernels",
+                 "_unwritten_kernel"):
+        monkeypatch.setattr(gm, name, functools.partial(
+            getattr(gm, name), interpret=True))
 
 
 @pytest.fixture(scope="session")

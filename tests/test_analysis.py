@@ -226,7 +226,7 @@ class TestDonationAndHostKinds:
         tree = {"x": _FakeSharding("unpinned_host", platform="cpu")}
         assert check_host_out_shardings(tree) == []
 
-    def test_unpinned_host_on_tpu_flagged(self):
+    def test_unpinned_host_on_a_tpu_flagged(self):
         tree = {"x": _FakeSharding("unpinned_host", platform="tpu")}
         assert len(check_host_out_shardings(tree)) == 1
 

@@ -15,9 +15,8 @@ from dlrover_wuqiong_tpu.ops import flash_attention as fa
 
 
 @pytest.fixture
-def direct(monkeypatch):
+def direct(on_tpu, monkeypatch):
     """The direct entry as the chip runs it, its kernels interpreted."""
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     for name in ("_projected_forward", "_projected_backward"):
         monkeypatch.setattr(fa, name, functools.partial(
             lambda kernel, *a, **kw: kernel(*a, **{**kw, "interpret": True}),
@@ -237,7 +236,7 @@ def test_the_backward_puts_a_groups_heads_on_an_axis_of_their_own():
     (4, 2, 64, True, 256),     # two heads a slab, direct: repeated
     (4, 4, 128, True, 512),    # nothing to repeat
     (4, 2, 128, False, None),  # off the TPU: cut to heads and repeated
-])
+], indirect=["on_tpu"])
 def test_llama_attention_repeats_where_the_kernels_cannot_index(
         monkeypatch, heads, kv, d, on_tpu, lanes):
     """`LlamaAttention` hands the direct entry k and v of their own
@@ -262,7 +261,6 @@ def test_llama_attention_repeats_where_the_kernels_cannot_index(
         seen["mha"] = [x.shape for x in (q, k, v)]
         return jnp.zeros_like(q)
 
-    monkeypatch.setattr(fa, "_on_tpu", lambda: on_tpu)
     monkeypatch.setattr(dispatch, "flash_attention_projected", projected)
     monkeypatch.setattr(dispatch, "mha", transposed)
     cfg = LlamaConfig(hidden_size=256, num_heads=heads, num_kv_heads=kv,

@@ -456,15 +456,14 @@ NO_WINDOW = {
 
 def _digest(jaxpr) -> str:
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
-    text = re.sub(r" at /[^ ]*flash_attention.py:\d+", "", text)
-    text = re.sub(r"flash_attention.py:\d+", "", text)
+    text = re.sub(r" at /[^ ]*(flash_attention|mosaic).py:\d+", "", text)
+    text = re.sub(r"(flash_attention|mosaic).py:\d+", "", text)
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
 @pytest.mark.parametrize("cell", sorted(NO_WINDOW))
 def test_a_call_without_a_window_traces_the_program_it_always_did(
-        monkeypatch, cell):
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+        on_tpu, cell):
     bf = jnp.bfloat16
     proj, heads = {
         "gpt2_124m.steady": (((24, 1024, 3 * 768),), 12),
@@ -514,8 +513,7 @@ GROUPED = {
 
 
 @pytest.mark.parametrize("cell", sorted(GROUPED))
-def test_a_grouped_call_traces_the_program_pinned_for_it(monkeypatch, cell):
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+def test_a_grouped_call_traces_the_program_pinned_for_it(on_tpu, cell):
     q, kv, heads, window, want = GROUPED[cell]
     args = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (q, kv, kv)]
 

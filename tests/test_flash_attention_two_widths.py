@@ -222,10 +222,9 @@ def test_the_public_entries_take_two_widths_off_the_tpu(sq, sk, path):
     (12, 64, 64, ("direct", 2)),
     (25, 64, 64, ("transposed", 0)),
 ])
-def test_the_route_answers_for_two_widths(monkeypatch, heads, d, dv, route):
+def test_the_route_answers_for_two_widths(on_tpu, heads, d, dv, route):
     """`attention_route(heads, d_qk, d_v)`: a v of its own width is the
     transposed (b*h, T, d) layout whatever the widths, and the direct
     route refuses it by the same predicate."""
     assert fa.attention_route(heads, d, dv) == route
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     assert fa.projected_ok(heads, d, 16384, dv) == (route[0] == "direct")

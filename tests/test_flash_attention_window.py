@@ -87,7 +87,7 @@ def test_windowed_forward_and_all_three_gradients_match_the_mask(
                                    form, heads))
     ro, rlse, (rq, rk, rv) = _reference(q, k, v, g, None, True, scale,
                                         window)
-    kept = np.asarray(fa._kept_mask(sq, sk, window))
+    kept = np.asarray(fa.kept_mask(sq, sk, window))
     i, j = np.arange(sq)[:, None] + sk - sq, np.arange(sk)[None, :]
     np.testing.assert_array_equal(kept, (j <= i) & (i - j < window))
     np.testing.assert_allclose(o, ro, atol=2e-5)

@@ -24,6 +24,7 @@ from dlrover_wuqiong_tpu.models.granite_hybrid import (
     GraniteHybridConfig,
 )
 from dlrover_wuqiong_tpu.models.llama import LlamaAttention, LlamaConfig
+from dlrover_wuqiong_tpu.ops import mosaic
 from dlrover_wuqiong_tpu.ops.ssd import ssd_scan
 from dlrover_wuqiong_tpu.trainer.train_step import make_lm_loss
 
@@ -424,7 +425,7 @@ def test_the_kernel_entries_are_handed_none_at_the_default(monkeypatch):
         with monkeypatch.context() as m:
             m.setattr(dispatch, "goes_direct", lambda *a: True)
             dispatch.attend_projected(flat, 2, cfg)
-            m.setattr(dispatch, "_on_tpu", lambda: True)
+            m.setattr(mosaic, "on_tpu", lambda: True)
             dispatch.attend(q, q, q, dataclasses.replace(
                 cfg, mesh=FourChips()))
             dispatch.attend(q, q, q, dataclasses.replace(

@@ -22,6 +22,7 @@ import pytest
 
 from dlrover_wuqiong_tpu.models import moe
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
+from dlrover_wuqiong_tpu.ops import mosaic
 
 TILE = 32
 ROWS, C, N = 256, 64, 128
@@ -164,11 +165,10 @@ def _mesh(size):
         (True, 98304, 8, None, None, "plain"),   # nobody says how many
         (True, 98304 + 8, 8, 128, None, "plain"),  # the tile divides it not
         (True, 192, 4, 8, None, "plain"),        # nano sizes
-    ])
+    ], indirect=["on_tpu"])
 def test_the_route_is_the_calls_shapes_mesh_and_backend(
-        monkeypatch, on_tpu, rows, held, named, mesh, route):
+        on_tpu, rows, held, named, mesh, route):
     """`gmm_route`: no knob, no environment variable, no model's name."""
-    monkeypatch.setattr(gm, "_on_tpu", lambda: on_tpu)
     assert gm.gmm_route((rows, 2688), (held, 2688, 1856), named,
                         _mesh(mesh)) == route
     assert gm.gmm_route((rows, 1856), (held, 1856, 2688), named,
@@ -181,18 +181,16 @@ def test_the_route_is_the_calls_shapes_mesh_and_backend(
     ("smallthinker_21b_a3b.steady", 196608, 16, 2560, 768, 64, True),
     ("kimi_vl_a3b.steady", 196608, 8, 2048, 1408, 64, True),
 ])
-def test_the_share_cells_layers_take_the_kernels(monkeypatch, cell, rows,
-                                                 held, d, f, named, first):
+def test_the_share_cells_layers_take_the_kernels(on_tpu, cell, rows, held,
+                                                 d, f, named, first):
     """`experts_route` at the three share cells' own T*k rows and weight
     shapes, on one TPU device: "kernel", whole layer."""
-    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
     shapes = [(held, d, f)] * (1 + first) + [(held, f, d)]
     assert gm.experts_route(rows, shapes, named) == "kernel"
     assert gm.experts_route(rows, shapes, held) == "plain"  # all held
 
 
-def test_blocks_that_do_not_fit_vmem_keep_the_plain_route(monkeypatch):
-    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+def test_blocks_that_do_not_fit_vmem_keep_the_plain_route(on_tpu):
     assert gm.gmm_route((4096, 32768), (8, 32768, 1856), 128) == "plain"
 
 
@@ -351,12 +349,11 @@ def test_the_maps_grid_is_the_tiles_that_hold_a_held_row(held):
     (1856, 32768, "plain"),     # the last product's do
     (8192, 1024, "plain"),      # no product's, the maps' do
 ])
-def test_a_layer_has_one_route(monkeypatch, d, f, route):
+def test_a_layer_has_one_route(on_tpu, monkeypatch, d, f, route):
     """`experts_route`: "kernel" only where every product's `gmm_route`
     says so and the maps' blocks fit; one product over the VMEM bound
     makes the whole layer plain (its other product alone would not
     be)."""
-    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
     shapes = [(8, d, f), (8, d, f), (8, f, d)]
     assert gm.experts_route(98304, shapes, 128) == route
     assert gm.experts_route(98304, shapes[1:], 128) == route
@@ -367,7 +364,7 @@ def test_a_layer_has_one_route(monkeypatch, d, f, route):
     assert gm.experts_route(98304, shapes, 8) == "plain"
     assert gm.experts_route(98304, shapes, 128, _mesh(4)) == "plain"
     assert gm.experts_route(98304 + 8, shapes, 128) == "plain"
-    monkeypatch.setattr(gm, "_on_tpu", lambda: False)
+    monkeypatch.setattr(mosaic, "on_tpu", lambda: False)
     assert gm.experts_route(98304, shapes, 128) == "plain"
 
 

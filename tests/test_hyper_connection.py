@@ -18,6 +18,7 @@ import pytest
 
 from benchmark import reference_xing4_0 as ref
 from dlrover_wuqiong_tpu.models import hyper_connection as hc
+from dlrover_wuqiong_tpu.ops import flash_attention as fa
 from dlrover_wuqiong_tpu.ops import hc_mix
 
 N, D, T = 4, 32, 24
@@ -212,13 +213,19 @@ def test_expand_and_read_out_are_replicate_and_sum():
 # ------------------------------------------------------- the kernel route
 
 @pytest.fixture
-def kernel_route(monkeypatch):
+def kernel_route(request, monkeypatch):
     """`mix_in` / `mix_out` take the kernels, in interpret mode, at the
-    token tile the test names (`kernel_route(tile)`)."""
+    token tile the test names (`kernel_route(tile)`) — from the call on:
+    the backend is said to be the TPU to everyone, so the attention of a
+    whole stack takes its kernels too, interpreted as well."""
     def switch(tile=None):
-        monkeypatch.setattr(hc_mix, "_on_tpu", lambda: True)
+        request.getfixturevalue("on_tpu")
         monkeypatch.setattr(hc_mix, "plan", functools.partial(
             hc_mix.plan, tile=tile, interpret=True))
+        for name in ("_fa_forward_pallas", "_fa_backward_pallas"):
+            monkeypatch.setattr(fa, name, functools.partial(
+                lambda kernel, *a, **kw: kernel(
+                    *a, **{**kw, "interpret": True}), getattr(fa, name)))
     return switch
 
 
@@ -346,12 +353,10 @@ def test_the_stream_goes_through_mix_in_and_its_cotangent_comes_back(
     (True, 4, 8192, 200, 1, "plain"),       # no whole lane slabs
     (True, 4, 8200, 3584, 1, "plain"),      # no whole packed tiles
     (True, 1, 8192, 3584, 1, "plain"),      # one lane mixes nothing
-])
-def test_which_calls_take_the_kernels(monkeypatch, on_tpu, n, t, d, devices,
-                                      route):
+], indirect=["on_tpu"])
+def test_which_calls_take_the_kernels(on_tpu, n, t, d, devices, route):
     from jax.sharding import Mesh
 
-    monkeypatch.setattr(hc_mix, "_on_tpu", lambda: on_tpu)
     mesh = None if devices is None else Mesh(
         np.array(jax.devices()[:devices]), ("fsdp",))
     assert hc_mix.hc_route(n, t, d, mesh) == route

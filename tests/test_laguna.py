@@ -41,6 +41,7 @@ from dlrover_wuqiong_tpu.models.llama import (
 from dlrover_wuqiong_tpu.models.moe import MoEConfig, MoEMLP
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
+from dlrover_wuqiong_tpu.ops import rope
 from dlrover_wuqiong_tpu.trainer.train_step import make_lm_loss
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -297,10 +298,12 @@ def test_a_gated_attention_layer_is_the_references(heads, window, rotary,
 
 
 @pytest.fixture
-def direct(monkeypatch):
+def direct(on_tpu, monkeypatch):
     """tests/test_flash_attention_grouped.py's: the direct entry as the
-    chip runs it, its kernels interpreted, at blocks of 64."""
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    chip runs it, its kernels interpreted, at blocks of 64 — and the
+    rotation's, which one TPU device takes beside them."""
+    monkeypatch.setattr(rope, "_rope_kernels", functools.partial(
+        rope._rope_kernels, interpret=True))
     for name in ("_projected_forward", "_projected_backward"):
         monkeypatch.setattr(fa, name, functools.partial(
             lambda kernel, *a, **kw: kernel(*a, **{**kw, "interpret": True}),
@@ -361,19 +364,8 @@ def test_the_direct_kernels_run_both_kinds_of_layer(direct, heads, window,
             err_msg=str(path))
 
 
-def _on_the_kernel_route(monkeypatch, tile):
-    """tests/test_nemotron_h.py's: the route a share takes on one TPU
-    device, its kernels in interpret mode."""
-    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
-    monkeypatch.setattr(gm, "_ROW_TILE", tile)
-    for name in ("_grouped_kernels", "_rows_map_kernels",
-                 "_unwritten_kernel"):
-        monkeypatch.setattr(gm, name, functools.partial(
-            getattr(gm, name), interpret=True))
-
-
 @pytest.mark.parametrize("route", ["plain", "kernel"])
-def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
+def test_the_shares_parts_add_up_to_the_uncut_layer(request, route):
     """Eight chips with two of the sixteen SwiGLU experts each: the
     routed parts all eight give, and the shared expert — which every
     chip computes alike — counted ONCE, are the uncut reference layer's,
@@ -396,7 +388,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
                                          shared=False, **sizes)[0]
     assert float(jnp.abs(shared).max()) > 1e-2
     if route == "kernel":
-        _on_the_kernel_route(monkeypatch, 32)
+        request.getfixturevalue("held_rows_interpreted")
     routed, rows = 0.0, 0
     for first in range(0, 16, 2):
         moe = dataclasses.replace(whole, experts_held=2, first_expert=first)

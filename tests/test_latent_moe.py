@@ -217,27 +217,13 @@ def test_the_reference_with_one_term_wrong_is_told_apart(wrong):
     assert abs(float(got) - float(other)) > 1e-4 * abs(float(got))
 
 
-def _on_the_kernel_route(monkeypatch, tile):
-    """tests/test_nemotron_h.py's: the route a share takes on one TPU
-    device, its kernels in interpret mode."""
-    kernels = gm._grouped_kernels
-    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
-    monkeypatch.setattr(gm, "_ROW_TILE", tile)
-    monkeypatch.setattr(gm, "_grouped_kernels", functools.partial(
-        kernels, interpret=True))
-    monkeypatch.setattr(gm, "_rows_map_kernels", functools.partial(
-        gm._rows_map_kernels, interpret=True))
-    monkeypatch.setattr(gm, "_unwritten_kernel", functools.partial(
-        gm._unwritten_kernel, interpret=True))
-
-
 _LAYER = dict(num_experts=8, top_k=3, impl="grouped", expert_act="swiglu",
               aux_loss="none", score_func="sigmoid", selection_bias=True,
               routed_scaling=2.446, shared_width=48, dtype=jnp.float32)
 
 
 @pytest.mark.parametrize("route", ["plain", "kernel"])
-def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
+def test_the_shares_parts_add_up_to_the_uncut_layer(request, route):
     """Four chips with two of the eight SwiGLU experts each: their
     routed parts, and the SwiGLU shared expert that every chip computes
     alike counted ONCE, are the uncut reference layer's — on the route
@@ -259,7 +245,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
                                      for n in ("gate", "up", "down")))
     assert float(jnp.abs(shared).max()) > 1e-2
     if route == "kernel":
-        _on_the_kernel_route(monkeypatch, 32)
+        request.getfixturevalue("held_rows_interpreted")
     routed, rows = 0.0, 0
     for first in (0, 2, 4, 6):
         moe = dataclasses.replace(whole, experts_held=2, first_expert=first)

@@ -12,7 +12,7 @@ import pytest
 
 from dlrover_wuqiong_tpu.ops.flash_attention import _attention_reference
 from dlrover_wuqiong_tpu.parallel.long_context import (
-    _attention_with_lse,
+    _chunk_attention,
     _merge_partials,
     ring_attention,
     ulysses_attention,
@@ -35,17 +35,17 @@ def _qkv(key, b=2, h=4, s=128, d=16):
 class TestMergePartials:
     def test_merge_two_halves_equals_full(self):
         q, k, v = _qkv(jax.random.PRNGKey(0), s=64)
-        o_full, _ = _attention_with_lse(q, k, v, False, None)
-        o1, l1 = _attention_with_lse(q, k[:, :, :32], v[:, :, :32], False,
+        o_full, _ = _chunk_attention(q, k, v, False, None)
+        o1, l1 = _chunk_attention(q, k[:, :, :32], v[:, :, :32], False,
                                      None)
-        o2, l2 = _attention_with_lse(q, k[:, :, 32:], v[:, :, 32:], False,
+        o2, l2 = _chunk_attention(q, k[:, :, 32:], v[:, :, 32:], False,
                                      None)
         o, _ = _merge_partials(o1, l1, o2, l2)
         np.testing.assert_allclose(o, o_full, atol=1e-5)
 
     def test_merge_with_empty_partial(self):
         q, k, v = _qkv(jax.random.PRNGKey(1), s=32)
-        o1, l1 = _attention_with_lse(q, k, v, False, None)
+        o1, l1 = _chunk_attention(q, k, v, False, None)
         o0 = jnp.zeros_like(o1)
         l0 = jnp.full(l1.shape, -jnp.inf)
         o, lse = _merge_partials(o1, l1, o0, l0)

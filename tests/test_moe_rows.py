@@ -8,7 +8,6 @@ kernel route of `ops/grouped_matmul.py` (interpret mode) are the plain
 route's, and so is the gather into expert order that walks the held
 rows' chunks alone there."""
 
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -240,21 +239,23 @@ CHUNK = 64
 FORMS = {"relu2": None, "reglu": jax.nn.relu, "swiglu": jax.nn.silu}
 
 
-def _on_the_kernel_route(monkeypatch, poison):
-    """What a share takes on one TPU device, here: the layer's own route
-    decision with the backend said to be the TPU and a row tile of 32
-    (T*k = 288 rows: nine tiles, of which the held rows fill one or
-    none) and a gather chunk of two tiles (four and a half of them: the
-    last turn of an all-held buffer is moved back onto its end), every
-    kernel in interpret mode.  `poison`: every place a kernel may leave
+def _on_the_kernel_route(request, poison):
+    """What a share takes on one TPU device, here (`held_rows_interpreted`
+    of tests/conftest.py): the layer's own route decision with the
+    backend said to be the TPU and a row tile of 32 (T*k = 288 rows:
+    nine tiles, of which the held rows fill one or none), every kernel
+    in interpret mode; and a gather chunk of two tiles (four and a half
+    of them: the last turn of an all-held buffer is moved back onto its
+    end).  `poison`: every place a kernel may leave
     unwritten is handed on as NaN — the rows of no group in a product,
     the tiles no grid step visits in a map, the row buffers behind the
     chunks `dispatch` gathers (of the tokens and of the cotangent) —
     forward and backward.  Returns the list the kernels' calls are noted
     in."""
+    request.getfixturevalue("held_rows_interpreted")
+    monkeypatch = request.getfixturevalue("monkeypatch")
     calls = []
-    kernels, maps, gmm, rows_map = (gm._grouped_kernels, gm._rows_map_kernels,
-                                    gm._gmm, gm._rows_map)
+    kernels, gmm, rows_map = gm._grouped_kernels, gm._gmm, gm._rows_map
 
     def poisoned_gmm(lhs, rhs, sizes, **kw):
         out = gmm(lhs, rhs, sizes, **kw)
@@ -269,15 +270,9 @@ def _on_the_kernel_route(monkeypatch, poison):
 
     def noting_kernels(lhs, rhs, sizes):
         calls.append(lhs.shape)
-        return kernels(lhs, rhs, sizes, interpret=True)
+        return kernels(lhs, rhs, sizes)
 
-    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
-    monkeypatch.setattr(gm, "_ROW_TILE", 32)
     monkeypatch.setattr(gm, "_grouped_kernels", noting_kernels)
-    monkeypatch.setattr(gm, "_rows_map_kernels",
-                        functools.partial(maps, interpret=True))
-    monkeypatch.setattr(gm, "_unwritten_kernel", functools.partial(
-        gm._unwritten_kernel, interpret=True))
     monkeypatch.setattr(moe, "_GATHER_CHUNK", CHUNK)
     monkeypatch.setattr(moe, "_SUM_CHUNK", CHUNK)
     if poison:
@@ -290,13 +285,13 @@ def _on_the_kernel_route(monkeypatch, poison):
 
 @pytest.mark.parametrize("held", [0, 1, CHUNK, CHUNK + 1, T * K])
 def test_the_chunked_dispatch_is_the_plain_gather_on_the_held_rows(
-        monkeypatch, held):
+        request, held):
     """`dispatch` on the kernel route against `tokens[order // k]`: the
     same rows, to the bit, wherever a row is held; behind the last turn
     of the loop the buffer is what it was (NaN here), and what
     `gathered_rows` counts is what was fetched; the backward pass reads
     the held rows alone on either route."""
-    _on_the_kernel_route(monkeypatch, poison=True)
+    _on_the_kernel_route(request, poison=True)
     order = jax.random.permutation(jax.random.PRNGKey(held), T * K)
     inv = jnp.argsort(order).reshape(T, K).T
     tokens, d_rows = _draw((T, D), (T * K, D), seed=6)
@@ -322,7 +317,7 @@ def test_the_chunked_dispatch_is_the_plain_gather_on_the_held_rows(
 
 
 @pytest.mark.parametrize("route", ["plain", "kernel"])
-def test_a_share_counts_the_rows_its_dispatch_fetches(monkeypatch, route):
+def test_a_share_counts_the_rows_its_dispatch_fetches(request, route):
     """A layer that holds 2 of 8 experts sows `moe_gather_rows` beside
     its tile counts — the held rows rounded up to a turn of the loop on
     the kernel route, every row on the plain — and `moe_combine_rows`,
@@ -334,7 +329,7 @@ def test_a_share_counts_the_rows_its_dispatch_fetches(monkeypatch, route):
     x = _draw((2, 48, D), seed=7)[0]
     rows = 2 * 48 * 3
     if route == "kernel":
-        _on_the_kernel_route(monkeypatch, poison=False)
+        _on_the_kernel_route(request, poison=False)
     for held in (8, 2):
         layer = MoEMLP(hidden=D, ffn=F, moe=MoEConfig(
             **cfg, experts_held=held % 8))
@@ -393,7 +388,7 @@ def _expert_pass(form, holding, routing, seed=5):
 @pytest.mark.parametrize("holding,routing", [
     c for c in CASES if c.values[0] != "all_8"])
 def test_a_shares_pass_through_the_kernels_is_the_plain_routes(
-        monkeypatch, holding, routing, form, poison):
+        request, holding, routing, form, poison):
     """`grouped_experts` on the route a share takes on one TPU device —
     the grouped products in `dwt_gmm` / `dwt_gmm_t` / `dwt_tgmm`, the
     activation, the sum of two first products' row gradients and the
@@ -411,7 +406,7 @@ def test_a_shares_pass_through_the_kernels_is_the_plain_routes(
     num_experts = HOLDINGS[holding][2]
     assert gm.experts_route(T * K, weights, num_experts) == "plain"
     (want_loss, (want, want_sizes)), want_g = both(*args)
-    calls = _on_the_kernel_route(monkeypatch, poison)
+    calls = _on_the_kernel_route(request, poison)
     assert gm.experts_route(T * K, weights, num_experts) == "kernel"
     (loss, (got, got_sizes)), got_g = both(*args)
     assert [c for c in calls if isinstance(c, tuple)] \
@@ -434,7 +429,7 @@ def test_a_shares_pass_through_the_kernels_is_the_plain_routes(
 
 @pytest.mark.parametrize("form", FORMS)
 def test_one_product_off_the_kernels_takes_the_whole_layer_off_them(
-        monkeypatch, form):
+        request, monkeypatch, form):
     """ONE route a layer call: where the first products' blocks pass the
     VMEM bound and the last product's do not, `gmm_route` alone would
     send the last one to the kernels; the layer's route is "plain" and
@@ -443,13 +438,13 @@ def test_one_product_off_the_kernels_takes_the_whole_layer_off_them(
     run, args = _expert_pass(form, "share_8_of_128_at_40", "even")
     plain = str(jax.make_jaxpr(jax.grad(lambda *a: run(*a)[0],
                                         argnums=(0, 1, 2, 3, 4)))(*args))
-    calls = _on_the_kernel_route(monkeypatch, poison=True)
+    calls = _on_the_kernel_route(request, poison=True)
     monkeypatch.setattr(
         gm, "_vmem_bytes",
         lambda c, n: gm._VMEM_LIMIT + 1 if c == D else 0)
     assert gm.gmm_route((T * K, D), (8, D, F), 128) == "plain"
     assert gm.gmm_route((T * K, F), (8, F, D), 128) == "kernel"
-    assert moe.layer_route(T * K, *args[2:], 128) == "plain"
+    assert gm.experts_route(T * K, args[2:], 128) == "plain"
     traced = str(jax.make_jaxpr(jax.grad(lambda *a: run(*a)[0],
                                          argnums=(0, 1, 2, 3, 4)))(*args))
     assert not calls and "pallas_call" not in traced
@@ -634,8 +629,8 @@ ROUTED = [pytest.param(route, *case.values, id=f"{route}-{case.id}")
 
 @pytest.mark.parametrize("k", [1, 6, 8])
 @pytest.mark.parametrize("route,holding,kind", ROUTED)
-def test_the_expert_pass_is_the_parents_bit_for_bit(monkeypatch, route,
-                                                    holding, kind, k):
+def test_the_expert_pass_is_the_parents_bit_for_bit(request, monkeypatch,
+                                                    route, holding, kind, k):
     """`grouped_experts` with the bookkeeping as dense vector ops against
     the same pass with the `jax.numpy` lines it had (`bincount`,
     `argsort` and two gathers of T*k numbers): the output, the groups'
@@ -658,8 +653,8 @@ def test_the_expert_pass_is_the_parents_bit_for_bit(monkeypatch, route,
 
     both = jax.value_and_grad(run, argnums=(0, 1, 2, 3, 4), has_aux=True)
     if route == "kernel":
-        _on_the_kernel_route(monkeypatch, poison=False)
-    assert moe.layer_route(tokens * k, *args[2:], num_experts) == route
+        _on_the_kernel_route(request, poison=False)
+    assert gm.experts_route(tokens * k, args[2:], num_experts) == route
     got = both(*args)
     _the_parents_lines(monkeypatch)
     want = both(*args)
@@ -682,8 +677,8 @@ LAYERS = [pytest.param(held, first, biased, aux, k, route,
 
 
 @pytest.mark.parametrize("held,first,biased,aux,k,route", LAYERS)
-def test_the_layer_is_the_parents_bit_for_bit(monkeypatch, held, first,
-                                              biased, aux, k, route):
+def test_the_layer_is_the_parents_bit_for_bit(request, monkeypatch, held,
+                                              first, biased, aux, k, route):
     """`MoEMLP` counts its assignments ONCE (the auxiliary term, the
     bias's rule and the groups' sizes read that count) and routes by a
     select: against the layer with the parent's lines (three
@@ -722,14 +717,14 @@ def test_the_layer_is_the_parents_bit_for_bit(monkeypatch, held, first,
         return (jax.jit(fn) if jitted else fn)(params, x)
 
     if route == "kernel":
-        _on_the_kernel_route(monkeypatch, poison=False)
+        _on_the_kernel_route(request, poison=False)
     got = both(params, x)
     inter = got[0][1][1]
     assert ("moe_aux_loss" in inter) == (aux == "topk")
     assert ("moe_selection_bias_step" in inter) == biased
     assert ("moe_rows_held" in inter) == (held < 16)
-    assert moe.layer_route(64 * k, *(params[f"experts_w_{w}"] for w in (
-        "gate", "in", "down")), 16) == route
+    assert gm.experts_route(64 * k, [params[f"experts_w_{w}"] for w in (
+        "gate", "in", "down")], 16) == route
     if held < 16:
         assert int(inter["moe_rows_held"][0]) + int(
             inter["moe_rows_absent"][0]) == 64 * k
@@ -763,7 +758,7 @@ SUMS = [pytest.param(share, k, id=f"{share}-k{k}")
 
 @pytest.mark.parametrize("share,k", SUMS)
 def test_the_sums_over_the_held_rows_are_the_gathers_by_assignment(
-        monkeypatch, share, k):
+        request, share, k):
     """`combine` (with gates) and `dispatch`'s backward pass (without)
     on the kernel route — a loop over the held rows' chunks in
     assignment order, a token's rows added where they lie side by side,
@@ -777,7 +772,7 @@ def test_the_sums_over_the_held_rows_are_the_gathers_by_assignment(
     behind the held ones, which nothing may read."""
     held, tokens = SHARES[share], 96
     rows = tokens * k
-    _on_the_kernel_route(monkeypatch, poison=True)
+    _on_the_kernel_route(request, poison=True)
     experts = _assignments("absent" if not held else "random", k, 64, 0,
                            held or 8, tokens)
     gates, ys, d_ys, d_out = _draw((tokens, k), (rows, D), (rows, D),

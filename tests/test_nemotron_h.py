@@ -317,23 +317,8 @@ def test_the_bias_rule_steps_each_expert_toward_the_even_load():
             ruled, selection_bias=False)).init(jax.random.PRNGKey(0), x)
 
 
-def _on_the_kernel_route(monkeypatch, tile):
-    """What a share's grouped products take on one TPU device, here: the
-    route's own decision with the backend said to be the TPU and a row
-    tile that divides the nano buffer, the kernels in interpret mode."""
-    kernels = gm._grouped_kernels
-    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
-    monkeypatch.setattr(gm, "_ROW_TILE", tile)
-    monkeypatch.setattr(gm, "_grouped_kernels", functools.partial(
-        kernels, interpret=True))
-    monkeypatch.setattr(gm, "_rows_map_kernels", functools.partial(
-        gm._rows_map_kernels, interpret=True))
-    monkeypatch.setattr(gm, "_unwritten_kernel", functools.partial(
-        gm._unwritten_kernel, interpret=True))
-
-
 @pytest.mark.parametrize("route", ["plain", "kernel"])
-def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
+def test_the_shares_parts_add_up_to_the_uncut_layer(request, route):
     """Four chips with two of the eight experts each: their parts of the
     result, the shared expert counted once, are the whole layer's — on
     the route every CPU run takes and on the `dwt_gmm` kernels a share
@@ -350,7 +335,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
         assert f"{counter}_share" not in collect_moe_stats(
             upd["intermediates"])
     if route == "kernel":
-        _on_the_kernel_route(monkeypatch, 32)
+        request.getfixturevalue("held_rows_interpreted")
     shared = jnp.square(jax.nn.relu(
         x @ params["shared_up_proj"]["kernel"])) \
         @ params["shared_down_proj"]["kernel"]
@@ -395,7 +380,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
 
 
 def test_a_shares_gradients_through_the_kernels_are_the_plain_routes(
-        monkeypatch):
+        request):
     """Every leaf's gradient of a share's layer (2 of 8 experts): the
     kernel route (interpret mode, tiles of 32 rows) against the plain."""
     moe = _moe(experts_held=2, first_expert=4)
@@ -405,7 +390,7 @@ def test_a_shares_gradients_through_the_kernels_are_the_plain_routes(
         return jnp.sum(jnp.sin(layer.apply({"params": p}, x)))
 
     want, want_g = jax.value_and_grad(run)(params)
-    _on_the_kernel_route(monkeypatch, 32)
+    request.getfixturevalue("held_rows_interpreted")
     assert "pallas_call" in str(jax.make_jaxpr(jax.grad(run))(params))
     got, got_g = jax.value_and_grad(run)(params)
     assert float(got) == pytest.approx(float(want), rel=1e-6)

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
+from dlrover_wuqiong_tpu.ops import mosaic
 
 
 def _pallas_calls(jaxpr):
@@ -126,20 +127,19 @@ def test_grouped_heads_are_indexed_where_a_head_is_a_slab(cell, heads, kv,
     (False, 12, 64, 1024, "off the TPU"),
     (True, 12, 64, 4099, "a sequence of 4099"),   # no block tiles it
     (True, 25, 64, 1024, "slab boundaries"),
-])
+], indirect=["on_tpu"])
 def test_the_direct_entry_refuses_what_projected_ok_does(monkeypatch,
                                                          on_tpu, h, d, t,
                                                          why):
     """`flash_attention_projected` has the dispatcher's own guard: a
     call `projected_ok` does not take raises, and never reaches a kernel
     with no block or a TPU lowering off the TPU."""
-    monkeypatch.setattr(fa, "_on_tpu", lambda: on_tpu)
     assert not fa.projected_ok(h, d, t)
     qkv = jax.ShapeDtypeStruct((2, t, 3 * h * d), jnp.bfloat16)
     with pytest.raises(ValueError, match=why):
         jax.eval_shape(lambda x: fa.flash_attention_projected((x,), h),
                        qkv)
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(mosaic, "on_tpu", lambda: True)
     assert fa.projected_ok(12, 64, 1024) and fa.projected_ok(16, 128, 4096)
 
 
@@ -188,13 +188,12 @@ def _primitives(jaxpr) -> set:
     (1, 32, 8192, 64, "q,k,v", "direct", 16, (8, 8),
      (136, 256)),                                 # granite4_h_micro.steady
 ])
-def test_the_cells_attention_plans(monkeypatch, b, h, t, d, form, route,
-                                   groups, grid, tiles):
+def test_the_cells_attention_plans(on_tpu, b, h, t, d, form, route, groups,
+                                   grid, tiles):
     """PERF.md section 5's prose, pinned: what each benchmark cell's
     attention traces to on the chip, from its shape alone — the route,
     the kernels and their grids, and whether anything is split, cut to
     heads or transposed around them."""
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     jaxpr = _model_attention_jaxpr(b, h, t, d, form).jaxpr
     assert fa.attention_route(h, d)[0] == route
     # the backward is ONE kernel in every cell: on heads alone where the
@@ -222,7 +221,7 @@ def test_the_cells_attention_plans(monkeypatch, b, h, t, d, form, route,
         assert "concatenate" not in _primitives(jaxpr)
 
 
-def test_the_latent_cells_attention_plan(monkeypatch):
+def test_the_latent_cells_attention_plan(on_tpu):
     """`kimi_vl_a3b.steady`'s attention from its shape alone: 16 heads
     whose q and k are 192 wide and whose v is 128 lie on no slab
     boundary, so `attend` hands the kernels the transposed (b*h, T, d)
@@ -235,7 +234,6 @@ def test_the_latent_cells_attention_plan(monkeypatch):
         LatentAttentionConfig,
     )
 
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     cfg = LatentAttentionConfig()
     assert (cfg.num_heads, cfg.qk_head_dim, cfg.v_head_dim) == (16, 192, 128)
     assert fa.attention_route(16, 192, 128) == ("transposed", 0)
@@ -270,8 +268,8 @@ def test_the_latent_cells_attention_plan(monkeypatch):
     # a window no shorter than the sequence is the causal call itself
     (16384, ("dwt_fa_fwd", "dwt_fa_bwd_fused"), 16, (528, 1024)),
 ])
-def test_the_windowed_cells_attention_plans(monkeypatch, window, names,
-                                            sweep, tiles):
+def test_the_windowed_cells_attention_plans(on_tpu, window, names, sweep,
+                                            tiles):
     """`smallthinker_21b_a3b.steady`'s two kinds of attention layer, from
     their shape and `LlamaConfig.attn_window` alone: 28 heads of 128 are
     28 lane slabs, so both go DIRECT on 16 blocks of 1,024 a side, k
@@ -283,7 +281,6 @@ def test_the_windowed_cells_attention_plans(monkeypatch, window, names,
     )
     from dlrover_wuqiong_tpu.models.llama import LlamaConfig
 
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     cfg = LlamaConfig(hidden_size=2560, num_heads=28, num_kv_heads=4,
                       attn_head_dim=128, attn_window=window)
     assert fa.attention_route(28, 128) == ("direct", 1)
@@ -311,8 +308,8 @@ def test_the_windowed_cells_attention_plans(monkeypatch, window, names,
     (2, 80, "qkv"),      # h*d % 128 != 0
     (4, 32, "q,k,v"),    # h*d % 128 == 0, four heads a slab
 ])
-def test_shapes_off_the_slab_fall_back_and_still_match(monkeypatch, h, d,
-                                                       form):
+def test_shapes_off_the_slab_fall_back_and_still_match(on_tpu, monkeypatch,
+                                                       h, d, form):
     """`attend_projected` on a shape `attention_route` calls transposed:
     the kernels (interpret mode) on (b*h, t, d) arrays behind the split,
     the cut to heads and the transposes, against the plain reference."""
@@ -322,7 +319,6 @@ def test_shapes_off_the_slab_fall_back_and_still_match(monkeypatch, h, d,
     from dlrover_wuqiong_tpu.models.gpt import GPTConfig
 
     assert fa.attention_route(h, d)[0] == "transposed"
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     for name in ("_fa_forward_pallas", "_fa_backward_pallas"):
         kernel = getattr(fa, name)
         monkeypatch.setattr(fa, name, functools.partial(
@@ -498,15 +494,13 @@ NEMOTRON = (64, 64, 8, 128, 128, 8192)   # nemotron3_nano_30b_a3b.steady
     (True, (64, 64, 1, 64, 256, 8192), ("plain", 0)),     # state < a lane
     (True, (64, 64, 1, 128, 256, 8000), ("plain", 0)),    # ragged: refused
     (True, (4096, 64, 1, 128, 256, 8192), ("plain", 0)),  # states over VMEM
-])
-def test_the_scans_route_is_its_shapes_and_the_backend(monkeypatch, on_tpu,
-                                                       shape, route):
+], indirect=["on_tpu"])
+def test_the_scans_route_is_its_shapes_and_the_backend(on_tpu, shape, route):
     """`ops/ssd.scan_route(h, p, g, n, chunk, t)`: the static counter of
     which scan calls run `dwt_ssd_fwd` / `dwt_ssd_bwd`, and with how
     many heads a grid step."""
     from dlrover_wuqiong_tpu.ops import ssd
 
-    monkeypatch.setattr(ssd, "_on_tpu", lambda: on_tpu)
     assert ssd.scan_route(*shape) == route
 
 
@@ -523,35 +517,31 @@ def _mixer_grad_jaxpr(mesh):
         {"params": p}, u).astype(jnp.float32).sum()))(params, u)
 
 
-def test_a_mixer_on_one_tpu_device_scans_in_the_kernels(monkeypatch):
+def test_a_mixer_on_one_tpu_device_scans_in_the_kernels(on_tpu):
     """One forward and one backward kernel a mixer, over (batch rows,
     chunks, blocks of heads): 2 x 4 x 2 at two groups of four heads."""
     from dlrover_wuqiong_tpu.ops import ssd
 
-    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
     assert ssd.scan_route(8, 64, 2, 128, 128, 512) == ("kernel", 4)
     assert sorted(_pallas_calls(_mixer_grad_jaxpr(None).jaxpr)) == [
         ("dwt_ssd_bwd", (2, 4, 2)), ("dwt_ssd_fwd", (2, 4, 2))]
 
 
-def test_a_mixer_on_a_mesh_of_several_devices_keeps_the_plain_scan(
-        monkeypatch):
+def test_a_mixer_on_a_mesh_of_several_devices_keeps_the_plain_scan(on_tpu):
     """A Mosaic kernel cannot be partitioned by GSPMD (PR 22): where the
-    model config carries a mesh of more than one device the mixer takes
-    `ssd_scan_plain`, whatever `scan_route` says of the shapes; a mesh
+    model config carries a mesh of more than one device `scan_route`,
+    which the mixer hands it, says "plain" whatever the shapes; a mesh
     of one device is one device."""
     from jax.sharding import Mesh
 
-    from dlrover_wuqiong_tpu.models.mamba2 import (
-        Mamba2Config, scans_on_one_device)
     from dlrover_wuqiong_tpu.ops import ssd
 
-    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
     two = Mesh(np.array(jax.devices()[:2]), ("fsdp",))
     one = Mesh(np.array(jax.devices()[:1]), ("fsdp",))
-    assert not scans_on_one_device(Mamba2Config(mesh=two))
-    assert scans_on_one_device(Mamba2Config(mesh=one))
-    assert scans_on_one_device(Mamba2Config())
+    shape = (8, 64, 2, 128, 128, 512)
+    assert ssd.scan_route(*shape, two) == ("plain", 0)
+    assert ssd.scan_route(*shape, one) == ("kernel", 4)
+    assert ssd.scan_route(*shape) == ("kernel", 4)
     assert _pallas_calls(_mixer_grad_jaxpr(two).jaxpr) == []
     assert len(_pallas_calls(_mixer_grad_jaxpr(one).jaxpr)) == 2
 
@@ -576,15 +566,14 @@ def _two_devices():
     (True, (8192, 8, 15, 96, 192), None, "chunked"),     # chunk off a tile
     (True, (8192, 64, 15, 320, 192), None, "chunked"),   # keys too wide
     (True, (8192, 2048, 15, 256, 256), None, "chunked"),  # tiles over VMEM
-])
+], indirect=["on_tpu"])
 def test_the_delta_rules_route_is_its_shapes_the_backend_and_the_mesh(
-        monkeypatch, on_tpu, shape, mesh, route):
+        on_tpu, shape, mesh, route):
     """`ops/delta_rule.delta_route(t, chunk, heads, dk, dv, mesh)`: the
     static counter of which calls run `dwt_gdr_fwd` / `dwt_gdr_bwd`, and
     with how many heads a grid step."""
     from dlrover_wuqiong_tpu.ops import delta_rule as dr
 
-    monkeypatch.setattr(dr, "_on_tpu", lambda: on_tpu)
     assert dr.delta_route(*shape, mesh and mesh()) == route
 
 
@@ -610,12 +599,11 @@ def test_a_delta_mixer_takes_the_kernels_on_one_tpu_device_only(
     mesh of two devices (`GatedDeltaConfig.mesh`, handed on by
     `OlmoHybridConfig.linear_config`) and off the TPU none."""
     from dlrover_wuqiong_tpu.models.olmo_hybrid import OlmoHybridConfig
-    from dlrover_wuqiong_tpu.ops import delta_rule as dr
 
     two = _two_devices()
     assert OlmoHybridConfig.nano(mesh=two).linear_config().mesh is two
     assert _pallas_calls(_delta_mixer_grad_jaxpr(None).jaxpr) == []
-    monkeypatch.setattr(dr, "_on_tpu", lambda: True)
+    monkeypatch.setattr(mosaic, "on_tpu", lambda: True)
     assert sorted(_pallas_calls(_delta_mixer_grad_jaxpr(None).jaxpr)) == [
         ("dwt_gdr_bwd", (2, 2, 2)), ("dwt_gdr_fwd", (2, 2, 2))]
     assert _pallas_calls(_delta_mixer_grad_jaxpr(two).jaxpr) == []
@@ -700,7 +688,7 @@ def _programs_and_keys(monkeypatch):
             batch_shape=[8, 32]).spec_key(),
     }
     with monkeypatch.context() as m:
-        m.setattr(fa, "_on_tpu", lambda: True)
+        m.setattr(mosaic, "on_tpu", lambda: True)
         out["attention_tpu"] = str(_attention_grad_jaxpr(1, 8, 256, 64))
     return out
 
@@ -712,6 +700,57 @@ def test_a_retired_variable_changes_no_program_and_no_key(monkeypatch, name,
     unset = _programs_and_keys(monkeypatch)
     monkeypatch.setenv(name, value)
     assert _programs_and_keys(monkeypatch) == unset
+
+
+def _modules(package):
+    import ast
+    import pathlib
+
+    import dlrover_wuqiong_tpu
+
+    root = pathlib.Path(dlrover_wuqiong_tpu.__file__).parent / package
+    return [(path, ast.parse(path.read_text()))
+            for path in sorted(root.rglob("*.py"))]
+
+
+@pytest.mark.parametrize("package", ["ops", "models", "parallel"])
+def test_the_backend_is_read_in_one_place(package):
+    """`ops/mosaic.py` holds the ONE reading of the backend and the one
+    test for a `shard_map`, and everyone calls them through the module:
+    no other module of `ops/`, `models/` or `parallel/` calls
+    `jax.default_backend()`, defines a copy, or binds `on_tpu` by name
+    at import — a patch of `mosaic.on_tpu` (tests/conftest.py) would
+    not reach it.  And no kernel module stands on another's private
+    names: what they share is the toolkit's."""
+    import ast
+
+    public = {"on_tpu", "inside_shard_map", "kernel_site"}
+    copies = public | {"_" + name for name in public}
+    found = []
+    for path, tree in _modules(package):
+        toolkit = (package, path.name) == ("ops", "mosaic.py")
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom):
+                names = {alias.name for alias in node.names}
+                if "on_tpu" in names:
+                    found.append((path.name, "imports on_tpu by name"))
+                if package == "ops" and node.level == 1 and node.module \
+                        not in (None, "mosaic") and any(
+                            n.startswith("_") for n in names):
+                    found.append((path.name, f"{node.module}'s privates"))
+            if toolkit:
+                continue
+            if isinstance(node, ast.Attribute) \
+                    and node.attr == "default_backend":
+                found.append((path.name, "reads the backend"))
+            if isinstance(node, ast.FunctionDef) and node.name in copies:
+                found.append((path.name, f"defines {node.name}"))
+    assert found == []
+    if package == "ops":
+        defined = {node.name for path, tree in _modules("ops")
+                   if path.name == "mosaic.py" for node in tree.body
+                   if isinstance(node, ast.FunctionDef)}
+        assert public <= defined
 
 
 # -------------------------- (C) fused, split and reference backward agree

@@ -19,7 +19,7 @@ T, TILE = 40, 16  # two whole row tiles and half of one
 
 
 @pytest.fixture
-def on_the_kernel_route(monkeypatch):
+def on_the_kernel_route(on_tpu, monkeypatch):
     """What one TPU device runs, here: the route's own decision with the
     backend said to be the TPU, a row tile T is no multiple of, the
     kernel in interpret mode; -> the calls the kernel took."""
@@ -29,7 +29,6 @@ def on_the_kernel_route(monkeypatch):
         calls.append(x.shape)
         return rope._rope_kernels(x, cos, sin, interpret=True)
 
-    monkeypatch.setattr(rope, "_on_tpu", lambda: True)
     monkeypatch.setattr(rope, "_ROW_TILE", TILE)
     monkeypatch.setattr(rope, "rotate_rows", kernels)
     return calls
@@ -122,11 +121,9 @@ def _mesh(n):
     (False, 3584, 128, 0, False, "plain"),
     (True, 3584, 128, 4, False, "plain"),
     (True, 3584, 128, 4, True, "kernel"),
-])
-def test_which_calls_take_the_kernel(monkeypatch, on_tpu, lanes, d, devices,
-                                     inside, want):
+], indirect=["on_tpu"])
+def test_which_calls_take_the_kernel(on_tpu, lanes, d, devices, inside, want):
     """`rope_route`: the static counter of the decision."""
-    monkeypatch.setattr(rope, "_on_tpu", lambda: on_tpu)
     mesh = _mesh(devices) if devices else None
     if not inside:
         assert rope.rope_route(lanes, d, mesh) == want

@@ -137,22 +137,8 @@ def test_the_router_reads_the_blocks_input_not_the_experts():
         atol=2e-5)
 
 
-def _on_the_kernel_route(monkeypatch, tile):
-    """tests/test_nemotron_h.py's: the route a share takes on one TPU
-    device, its kernels in interpret mode."""
-    kernels = gm._grouped_kernels
-    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
-    monkeypatch.setattr(gm, "_ROW_TILE", tile)
-    monkeypatch.setattr(gm, "_grouped_kernels", functools.partial(
-        kernels, interpret=True))
-    monkeypatch.setattr(gm, "_rows_map_kernels", functools.partial(
-        gm._rows_map_kernels, interpret=True))
-    monkeypatch.setattr(gm, "_unwritten_kernel", functools.partial(
-        gm._unwritten_kernel, interpret=True))
-
-
 @pytest.mark.parametrize("route", ["plain", "kernel"])
-def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
+def test_the_shares_parts_add_up_to_the_uncut_layer(request, route):
     """Four chips with two of the eight ReGLU experts each: their parts
     of the result are the uncut reference layer's (no shared expert to
     count once), on the route every CPU run takes and on the `dwt_gmm`
@@ -170,7 +156,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
         want, _ = ref.expert_layer(u.reshape(64, 32), h.reshape(64, 32),
                                    params, top_k=3, first_expert=0)
     if route == "kernel":
-        _on_the_kernel_route(monkeypatch, 32)
+        request.getfixturevalue("held_rows_interpreted")
     total, rows = 0.0, 0
     for first in (0, 2, 4, 6):
         moe = dataclasses.replace(whole, experts_held=2, first_expert=first)
@@ -192,7 +178,7 @@ def test_the_shares_parts_add_up_to_the_uncut_layer(monkeypatch, route):
 
 
 def test_a_reglu_shares_gradients_through_the_kernels_are_the_plain_routes(
-        monkeypatch):
+        request):
     """Three matrices a group on the share's kernels: every leaf's
     gradient against the plain route's."""
     moe = MoEConfig(num_experts=8, top_k=3, impl="grouped",
@@ -208,7 +194,7 @@ def test_a_reglu_shares_gradients_through_the_kernels_are_the_plain_routes(
         return jnp.sum(jnp.sin(30.0 * layer.apply({"params": p}, u)))
 
     want, want_g = jax.value_and_grad(run)(params)
-    _on_the_kernel_route(monkeypatch, 32)
+    request.getfixturevalue("held_rows_interpreted")
     text = str(jax.make_jaxpr(jax.grad(run))(params))
     assert "pallas_call" in text and "ragged_dot" not in text
     got, got_g = jax.value_and_grad(run)(params)

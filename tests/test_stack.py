@@ -16,7 +16,6 @@ import numpy as np
 import optax
 import pytest
 
-from test_program_from_arguments import _pallas_calls
 
 from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
 from dlrover_wuqiong_tpu.models.gpt import cross_entropy_loss
@@ -117,13 +116,26 @@ def test_a_rematerialised_block_branches_on_its_static_arguments():
 # ------------------------------------------- the mesh hand-off (ROADMAP D19)
 
 
+def _kernels(jaxpr, sharded=False):
+    """{(name of a pallas_call under `jaxpr`, whether it sits inside a
+    shard_map)}: one outside is GSPMD's to partition, which it cannot."""
+    found = set()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.add((eqn.params["name"], sharded))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found |= _kernels(sub, sharded
+                              or eqn.primitive.name == "shard_map")
+    return found
+
+
 def _kernel_names(model, seq):
     """The kernels the model's loss and gradient trace at 2 x seq."""
     params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     ids = jax.ShapeDtypeStruct((2, seq), jnp.int32)
-    return {name for name, _grid in _pallas_calls(jax.make_jaxpr(jax.grad(
+    return _kernels(jax.make_jaxpr(jax.grad(
         lambda p, ids: cross_entropy_loss(
-            model.apply({"params": p}, ids), ids)))(params, ids).jaxpr)}
+            model.apply({"params": p}, ids), ids)))(params, ids).jaxpr)
 
 
 # widths at which the scan (`ops/ssd.scan_route`) and a share of an expert
@@ -148,12 +160,12 @@ def test_a_config_that_declares_a_mesh_is_handed_the_plans(monkeypatch, cls):
     model config that DECLARES the field, whatever else it declares (the
     parent asked for `attn_impl`, which these three do not have): with
     the backend patched to read "TPU" the model as given traces its scan
-    and grouped kernels, the model that comes back traces none (a Mosaic
-    kernel outside a shard_map is a program GSPMD cannot partition), and
-    an optimizer step runs on the two devices."""
+    and grouped kernels, the model that comes back traces none outside a
+    shard_map (a Mosaic kernel there is a program GSPMD cannot partition;
+    its attention's sit in `attend`'s own), and an optimizer step runs
+    on the two devices."""
     from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
-    from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
-    from dlrover_wuqiong_tpu.ops import ssd
+    from dlrover_wuqiong_tpu.ops import mosaic
 
     module, config, sizes, kernels = MESHED[cls]
     model = _model(module, cls, config, **sizes)
@@ -162,10 +174,11 @@ def test_a_config_that_declares_a_mesh_is_handed_the_plans(monkeypatch, cls):
                           optimizer=optax.adamw(1e-3), seq_len=256)
     assert res.mesh.size == 2 and res.model.config.mesh is res.mesh
     with monkeypatch.context() as mp:
-        mp.setattr(ssd, "_on_tpu", lambda: True)
-        mp.setattr(gm, "_on_tpu", lambda: True)
-        assert kernels <= _kernel_names(model, 256)
-        assert _kernel_names(res.model, 256) == set()
+        mp.setattr(mosaic, "on_tpu", lambda: True)
+        assert {(name, False) for name in kernels} <= _kernel_names(
+            model, 256)
+        assert all(name.startswith("dwt_fa_") and sharded
+                   for name, sharded in _kernel_names(res.model, 256))
     ids = np.random.default_rng(0).integers(0, 256, (2, 256), dtype=np.int32)
     batch = res.place_batch({"input_ids": ids, "labels": ids})
     state, metrics = res.train_step(res.state, batch)

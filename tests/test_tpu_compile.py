@@ -28,8 +28,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from dlrover_wuqiong_tpu.analysis.hlo_budget import iter_collectives
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
+from dlrover_wuqiong_tpu.ops import mosaic
 from dlrover_wuqiong_tpu.ops import quantization as qz
-from dlrover_wuqiong_tpu.ops import rope
 from dlrover_wuqiong_tpu.ops import ssd
 
 
@@ -193,8 +193,7 @@ def test_attention_several_block_backward_compiles_at_gpt2_shape(
     assert sorted(set(re.findall(r"dwt_fa_bwd_[a-z]+", bwd))) == names
 
 
-def test_int8_quantise_kernels_compile(topo, monkeypatch):
-    monkeypatch.setattr(qz, "_on_tpu", lambda: True)
+def test_int8_quantise_kernels_compile(topo, on_tpu):
     one = SingleDeviceSharding(topo.devices[0])
     shape = (768, 3072)
     rows = shape[0] * shape[1] // qz.BLOCK
@@ -209,7 +208,7 @@ def test_int8_quantise_kernels_compile(topo, monkeypatch):
     assert "dwt_int8_dequant" in dq
 
 
-def test_attention_on_a_four_chip_mesh_is_shard_mapped(topo, monkeypatch):
+def test_attention_on_a_four_chip_mesh_is_shard_mapped(topo, on_tpu):
     """GSPMD refuses to partition a Mosaic kernel; on a multi-device mesh
     the model's attention must reach it through a shard_map, each chip
     running the kernel on its quarter of the batch."""
@@ -220,10 +219,6 @@ def test_attention_on_a_four_chip_mesh_is_shard_mapped(topo, monkeypatch):
     from dlrover_wuqiong_tpu.models.gpt import GPTConfig
     from dlrover_wuqiong_tpu.parallel.mesh import AXIS_ORDER
 
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    # models/attention.py bound the name at import
-    monkeypatch.setattr("dlrover_wuqiong_tpu.models.attention._on_tpu",
-                        lambda: True)
     mesh = Mesh(np.array(topo.devices).reshape(1, 1, 4, 1, 1, 1), AXIS_ORDER)
     cfg = GPTConfig(n_head=2, n_embd=128, mesh=mesh)
     x = jax.ShapeDtypeStruct(
@@ -265,9 +260,7 @@ def _xl_fsdp4_steps(topo):
     steps = {}
     with pytest.MonkeyPatch.context() as mp, _cache_off():
         mp.setenv("DWT_COMPILE_CACHE", "0")
-        mp.setattr(fa, "_on_tpu", lambda: True)
-        mp.setattr("dlrover_wuqiong_tpu.models.attention._on_tpu",
-                   lambda: True)
+        mp.setattr(mosaic, "on_tpu", lambda: True)
         for depth in (2, 3):
             cfg = GPTConfig(n_layer=depth, n_head=25, n_embd=XL_WIDTH,
                             remat=True, remat_policy="full")
@@ -364,15 +357,9 @@ def _compile_one_chip_step(topo, cell, model):
 
     with pytest.MonkeyPatch.context() as mp, _cache_off():
         mp.setenv("DWT_COMPILE_CACHE", "0")
-        mp.setattr(fa, "_on_tpu", lambda: True)
-        mp.setattr("dlrover_wuqiong_tpu.models.attention._on_tpu",
-                   lambda: True)
-        mp.setattr(ssd, "_on_tpu", lambda: True)
-        mp.setattr(gm, "_on_tpu", lambda: True)
-        mp.setattr(rope, "_on_tpu", lambda: True)
-        mp.setattr("dlrover_wuqiong_tpu.ops.delta_rule._on_tpu",
-                   lambda: True)
-        mp.setattr("dlrover_wuqiong_tpu.ops.hc_mix._on_tpu", lambda: True)
+        # every kernel module at once, one added later too: the backend
+        # is read in one place (`ops/mosaic.on_tpu`)
+        mp.setattr(mosaic, "on_tpu", lambda: True)
         res = auto_accelerate(
             model, strategy=[("fsdp", {})], devices=topo.devices[:1],
             optimizer=optax.chain(optax.clip_by_global_norm(1.0),
@@ -932,7 +919,7 @@ def test_granite_step_runs_the_kernels_direct_at_32_heads_of_64(
 
 
 def test_granite_on_four_chips_is_handed_its_mesh_and_compiles(
-        topo, monkeypatch):
+        topo, on_tpu, monkeypatch):
     """ROADMAP D19: `GraniteHybridConfig` declares `mesh` and no
     `attn_impl`, so the parent's `auto_accelerate` left it `None` on
     four chips and the model traced its Mosaic kernels outside any
@@ -949,10 +936,6 @@ def test_granite_on_four_chips_is_handed_its_mesh_and_compiles(
         GraniteHybrid, GraniteHybridConfig)
 
     monkeypatch.setenv("DWT_COMPILE_CACHE", "0")
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    monkeypatch.setattr("dlrover_wuqiong_tpu.models.attention._on_tpu",
-                        lambda: True)
-    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
     cfg = GraniteHybridConfig(layer_types=("mamba", "attention"))
     res = auto_accelerate(
         GraniteHybrid(cfg), strategy=[("fsdp", {})], devices=topo.devices,
@@ -1173,7 +1156,7 @@ def _square_tiles(text, under, side):
     ("nemotron_step", 4, 8),    # 8 groups of 8, chunk 128
 ])
 def test_hybrid_step_scans_in_its_kernels_and_holds_no_decay_tensor(
-        request, topo, monkeypatch, fixture, layers, heads_block):
+        request, topo, on_tpu, fixture, layers, heads_block):
     """The static counters of the scan's route.  Every scan of the step
     runs the kernels: three custom calls a layer — `dwt_ssd_fwd` in the
     forward pass, again in its recomputation, `dwt_ssd_bwd` in the
@@ -1198,7 +1181,6 @@ def test_hybrid_step_scans_in_its_kernels_and_holds_no_decay_tensor(
     cell, model, step = request.getfixturevalue(fixture)
     text = step.as_text()
     cfg = model.config.mamba_config()
-    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
     assert ssd.scan_route(cfg.num_heads, cfg.head_dim, cfg.n_groups,
                           cfg.state_size, cfg.chunk_size,
                           cell["seq_len"]) == ("kernel", heads_block)

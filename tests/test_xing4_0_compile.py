@@ -172,7 +172,7 @@ def test_xing_step_holds_its_scopes_and_a_share_of_the_experts(xing_step):
 
 
 def test_xing_step_mixes_its_lanes_in_four_kernels_a_sublayer(
-        xing_step, monkeypatch):
+        xing_step, on_tpu):
     """The route and its static counter: ten sublayers run `dwt_hc_pre`
     and `dwt_hc_post` forward, again recomputed (the last sublayer of a
     block is recomputed for nobody: five, not ten) and `dwt_hc_post_bwd`
@@ -188,7 +188,6 @@ def test_xing_step_mixes_its_lanes_in_four_kernels_a_sublayer(
 
     cell, _, step = xing_step
     text = step.as_text()
-    monkeypatch.setattr(hc_mix, "_on_tpu", lambda: True)
     assert hc_mix.hc_route(4, cell["seq_len"], 3584) == "kernel"
     assert dict(hc_mix.plan(cell["seq_len"])) == {
         "tile": 128, "pre_tile": 512, "interpret": False}

@@ -909,7 +909,7 @@ def probe_rope():
     from unittest import mock
 
     from dlrover_wuqiong_tpu.models.llama import apply_rope, rope_freqs
-    from dlrover_wuqiong_tpu.ops import rope
+    from dlrover_wuqiong_tpu.ops import mosaic, rope
 
     def grad_of(fn):
         return lambda x, d_out: jax.vjp(fn, x)[1](d_out)[0]
@@ -933,7 +933,7 @@ def probe_rope():
             ("dwt_rope", kernel(tile), tile) for tile in tiles]
         for name, fn, tile in cases:
             # the formula is what a call off the TPU traces
-            with mock.patch.object(rope, "_on_tpu",
+            with mock.patch.object(mosaic, "on_tpu",
                                    lambda: name != "plain"):
                 for what, f, args in ((name, jax.jit(fn), (x,)),
                                       (name + "_bwd", jax.jit(grad_of(fn)),
