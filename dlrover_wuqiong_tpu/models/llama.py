@@ -205,12 +205,14 @@ def apply_rope(x, cos, sin, mesh=None, head_dim: int = 0):
     head's FIRST 2 * half features and passes the rest as they are.
 
     Two routes, chosen by what the call can observe (`mesh` is the model
-    config's), never a knob: where `ops/rope.rope_route` says "kernel",
-    `ops/rope.py`'s `dwt_rope`, one read and one write of the rows,
-    differentiated by the same kernel — SmallThinker's and OLMoE's q and
-    k, latent attention's q heads and its one shared key part.  Every
-    other call takes the formula below: the plain route, and the tests'
-    oracle.
+    config's), never a knob: where `ops/rope.rope_route` says "kernel"
+    of the rows' width, the head's and the tables', `ops/rope.py`'s
+    `dwt_rope`, one read and one write of the rows, differentiated by
+    the same kernel — SmallThinker's and OLMoE's q and k, latent
+    attention's q heads and its one shared key part, Laguna's sliding
+    layers and its full layers' half-rotated heads (the passed lanes
+    pass inside the kernel).  Every other call takes the formula below:
+    the plain route, and the tests' oracle.
 
     A head's two halves trade places by two rolls of the last axis and a
     select (a roll never wraps into a lane that is kept; over one head's
@@ -221,9 +223,9 @@ def apply_rope(x, cos, sin, mesh=None, head_dim: int = 0):
     s, lanes, half = x.shape[1], x.shape[-1], cos.shape[-1]
     row = math.prod(x.shape[2:])  # a position's heads side by side
     d = head_dim or 2 * half
-    if d == 2 * half and rope_route(row, d, mesh) == "kernel":
-        return rotate_rows(x.reshape(*x.shape[:2], row), cos,
-                           sin).reshape(x.shape)
+    if rope_route(row, d, mesh, 2 * half) == "kernel":
+        return rotate_rows(x.reshape(*x.shape[:2], row), cos, sin,
+                           d).reshape(x.shape)
     heads = lanes // d
     # a head's lanes are [cos | cos | 1 ..] and [-sin | sin | 0 ..]:
     # those behind the rotated ones pass, whatever their partner
@@ -285,7 +287,7 @@ class LlamaAttention(nn.Module):
             v = v.reshape(B, T, cfg.num_kv_heads, hd)
         if cfg.rope:
             # tables narrower than half the head rotate its first
-            # features only: the formula's ops, under a scope of their own
+            # features only: under a scope of their own
             partial = 2 * cos.shape[-1] < hd
             width = {"head_dim": hd} if partial else {}
             with jax.named_scope("rope_partial") if partial \

@@ -18,6 +18,8 @@ written here as literals.  PR 55 put the question of the site in one
 place (`ops/mosaic.kernel_site`) and each kernel module's answer in one
 constant (`_SITES`): a predicate whose answer moves fails here, and S9 /
 M1 (ROADMAP), which flip a constant, edit the column they mean to.
+PR 56 gave `rope_route` the rotated width (Laguna's full layers turn 64
+of a head's 128 lanes): the rows of the calls the program now makes.
 """
 
 import types
@@ -130,7 +132,8 @@ TABLE = {
         ("nemotron", (64, 64, 8, 128, 128, 8192), _scan(8)),
         ("nano", (8, 16, 2, 8, 16, 64), (("plain", 0),) * 5),
     ],
-    # (lanes of a row, head size): q's rows and k's
+    # (lanes of a row, head size[, its first lanes that turn: the tables'
+    # width, where it is not the head's]): q's rows and k's
     "rope_route": [
         ("olmoe_q_and_k", (2048, 128), _OR_A_SHARD_MAP),
         ("smallthinker_q", (3584, 128), _OR_A_SHARD_MAP),
@@ -139,7 +142,13 @@ TABLE = {
         ("kimi_vl_k_and_xing4_0_k", (64, 64), _OR_A_SHARD_MAP),
         ("xing4_0_q", (2048, 64), _OR_A_SHARD_MAP),
         ("olmo_hybrid_q_and_k", (3840, 128), _OR_A_SHARD_MAP),
-        ("laguna_half_a_head", (3072, 64), _OR_A_SHARD_MAP),
+        ("laguna_sliding_q", (8192, 128), _OR_A_SHARD_MAP),
+        ("laguna_sliding_k", (1024, 128), _OR_A_SHARD_MAP),
+        ("laguna_full_q_half_a_head", (6144, 128, 64), _OR_A_SHARD_MAP),
+        ("laguna_full_k_half_a_head", (1024, 128, 64), _OR_A_SHARD_MAP),
+        ("a_quarter_of_a_head", (2048, 128, 32), _OR_A_SHARD_MAP),
+        ("an_eighth_of_a_head", (2048, 128, 16), _NOWHERE),
+        ("three_quarters_of_a_head", (2048, 128, 96), _NOWHERE),
         ("seven_heads_of_64", (448, 64), _NOWHERE),
         ("heads_of_32", (1024, 32), _NOWHERE),
         ("heads_of_256", (1024, 256), _NOWHERE),
@@ -194,6 +203,8 @@ def _ask(predicate, args, mesh):
     module = {"scan_route": ssd, "rope_route": rope, "hc_route": hc_mix,
               "delta_route": delta_rule, "gmm_route": gm,
               "experts_route": gm}[predicate]
+    if predicate == "rope_route":  # the mesh sits before the rotated part
+        return rope.rope_route(*args[:2], mesh, *args[2:])
     return getattr(module, predicate)(*args, mesh)
 
 

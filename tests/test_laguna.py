@@ -314,6 +314,7 @@ def direct(on_tpu, monkeypatch):
 
 @pytest.mark.parametrize("heads,window,rotary", [
     (6, 0, 64),      # a full layer: groups of 3, half the head rotated
+                     # (`dwt_rope` passes the rest inside the kernel)
     (8, 32, 128),    # a sliding layer: groups of 4, a window of HALF a block
     (8, 20, 128),    # and one under a tile that is no multiple of anything
 ])
@@ -342,6 +343,9 @@ def test_the_direct_kernels_run_both_kinds_of_layer(direct, heads, window,
 
     text = str(jax.make_jaxpr(run)(params, x))
     assert ("dwt_fa_win_fwd" in text) == bool(window)
+    # q's rotation and k's are the kernel's, half a head as the whole
+    assert rope.rope_route(heads * d, d, None, rotary) == "kernel"
+    assert text.count("name=dwt_rope") == 2
     inv = 1.0 / 10000.0 ** (jnp.arange(0, rotary, 2) / rotary)
 
     def plain(params, x):

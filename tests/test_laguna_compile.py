@@ -146,22 +146,31 @@ def test_laguna_step_holds_its_scopes_a_gate_and_a_share_of_experts(
     assert "conditional(" not in text
 
 
-def test_laguna_step_rotates_whole_heads_by_the_kernel_and_half_heads_plain(
+def test_laguna_step_rotates_whole_heads_and_half_heads_by_the_kernel(
         laguna_step):
-    """The three sliding layers rotate q and k whole, each forward,
-    recomputed and backward: 18 `dwt_rope` calls on the projections' own
-    (1, 16384, 64 x 128) and (1, 16384, 8 x 128).  The two full layers
-    rotate HALF a head: `apply_rope`'s plain route (the kernel's one
-    table a slab has no lanes that pass), fusions under
-    `attention/rope_partial` and no kernel call at 48 heads' width."""
-    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+    """Every rotation of the step is `dwt_rope` on the projections' own
+    rows, each forward, recomputed and backward: the three sliding
+    layers' q and k whole (9 calls at 64 x 128 lanes, 9 at 8 x 128), the
+    two full layers' q and k HALF a head (6 at 48 x 128, 6 more at
+    8 x 128; the lanes behind the first 64 pass inside the kernel) — 30
+    calls, and under `attention/rope_partial` nothing of the formula: no
+    fusion, copy or broadcast of a row's size, only the kernels and the
+    (16384, 128) float32 table they read."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import moved_bytes
 
     text = laguna_step[2].as_text()
     calls = collections.Counter(re.findall(
         r"%dwt_rope[.\d]* = bf16\[(\d+),16384,(\d+)\]", text))
-    assert calls == {("1", "8192"): 9, ("1", "1024"): 9}
-    partial = [s for s in scope_table(text).values() if "rope_partial" in s]
-    assert partial and not any("dwt_rope" in s for s in partial)
+    assert calls == {("1", "8192"): 9, ("1", "1024"): 15, ("1", "6144"): 6}
+    partial = moved_bytes(text, "rope_partial")
+    kernels = [op for op in partial if op.startswith("dwt_rope")]
+    assert len(kernels) == 12, sorted(partial)
+    # what else the scope holds makes and stages the table: no op of it
+    # writes more than the table's 8 MB, where a row of q is 201
+    table = 16384 * 128 * 4
+    assert partial[kernels[0]]["written"] >= 16384 * 1024 * 2 > table
+    assert {op: m["written"] for op, m in partial.items()
+            if op not in kernels and m["written"] > table} == {}
 
 
 @pytest.mark.parametrize("heads,route,names", [

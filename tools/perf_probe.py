@@ -29,9 +29,9 @@ products cost, as the compiler's fusions over the whole T*k-row buffer
 and as `dwt_rows_map_*` over the tiles that hold a held row, at both
 share cells' shapes and held shares.
 `rope` reads the same way one rotation of the projections' rows, forward
-and backward, at the three rotating cells' shapes: `models/llama.
-apply_rope`'s formula as the compiler fuses it against `dwt_rope`
-(`ops/rope.py`) at three row tiles.
+and backward, at the rotating cells' shapes (Laguna's half-rotated
+heads among them): `models/llama.apply_rope`'s formula as the compiler
+fuses it against `dwt_rope` (`ops/rope.py`) at three row tiles.
 `moe_numbers` reads the same way the expert layer's bookkeeping at the
 share cells' shapes: each line that indexed T*k single numbers (a
 `bincount`, a gather through a sort or its inverse, `take_along_axis`
@@ -903,9 +903,11 @@ def probe_sums(chunks=(2048, 4096, 8192)):
 def probe_rope():
     """One rotation, forward and backward, at SmallThinker's q and k
     (2 x 16,384 x 3,584 and x 512, heads of 128), latent attention's q
-    part (2 x 16,384 x 16 heads of 64) and OLMoE's q (5 x 4,096 x
-    2,048): the formula of `models/llama.apply_rope` jitted alone
-    against `dwt_rope` (PERF.md section 6, PR 44)."""
+    part (2 x 16,384 x 16 heads of 64), OLMoE's q (5 x 4,096 x 2,048)
+    and Laguna's full layers' q and k (1 x 16,384 x 48 and x 8 heads of
+    128 whose first 64 features turn): the formula of
+    `models/llama.apply_rope` jitted alone against `dwt_rope` (PERF.md
+    section 6, PR 44 and PR 56)."""
     from unittest import mock
 
     from dlrover_wuqiong_tpu.models.llama import apply_rope, rope_freqs
@@ -914,20 +916,24 @@ def probe_rope():
     def grad_of(fn):
         return lambda x, d_out: jax.vjp(fn, x)[1](d_out)[0]
 
-    for b, t, lanes, d, tiles in ((2, 16384, 3584, 128, (256, 512, 1024)),
-                                  (2, 16384, 512, 128, (512,)),
-                                  (2, 16384, 1024, 64, (512,)),
-                                  (5, 4096, 2048, 128, (512,))):
-        cos, sin = rope_freqs(d, t, 10000.0)
+    several = (256, 512, 1024)
+    for b, t, lanes, d, rotated, tiles in (
+            (2, 16384, 3584, 128, 128, several),
+            (2, 16384, 512, 128, 128, (512,)),
+            (2, 16384, 1024, 64, 64, (512,)),
+            (5, 4096, 2048, 128, 128, (512,)),
+            (1, 16384, 6144, 128, 64, several),
+            (1, 16384, 1024, 128, 64, (512,))):
+        cos, sin = rope_freqs(rotated, t, 10000.0)
         keys = jax.random.split(jax.random.PRNGKey(lanes), 2)
         x, d_out = (jax.random.normal(k, (b, t, lanes), jnp.bfloat16)
                     for k in keys)
 
         def plain(x):
-            return apply_rope(x, cos, sin)
+            return apply_rope(x, cos, sin, head_dim=d)
 
         def kernel(tile):
-            return lambda x: rope._rope_kernels(x, cos, sin, tile=tile)
+            return lambda x: rope._rope_kernels(x, cos, sin, d, tile=tile)
 
         cases = [("plain", plain, None)] + [
             ("dwt_rope", kernel(tile), tile) for tile in tiles]
@@ -940,7 +946,7 @@ def probe_rope():
                                        (x, d_out))):
                     _emit_raw({"probe": "rope", "what": what,
                                "shape": [b, t, lanes], "head": d,
-                               "tile": tile,
+                               "rotated": rotated, "tile": tile,
                                "device_ops_ms": _device_ops_ms(f, *args)})
 
 
