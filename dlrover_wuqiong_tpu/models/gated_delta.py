@@ -31,7 +31,11 @@ metrics (`collect_delta_stats`, through `make_lm_loss.with_stats`).
 
 Which route the recurrence takes is `ops/delta_rule.delta_route`'s to
 say, from the call's shapes and where it runs (the backend and
-`GatedDeltaConfig.mesh`, the model config's own).
+`GatedDeltaConfig.mesh`, the model config's own).  This mixer's decay is
+ONE number a head, g (b, T, H): every route takes that form — the
+kernels, the chunked `_chunked`, the sequential scan; a decay a key
+CHANNEL, g (b, T, H, dk), is `models/kda.py`'s mixer and runs
+`_chunked_channel` or the same sequential scan, never the kernels.
 
 The short convolution and the draw of `dt_bias` are `models/mamba2.py`'s.
 

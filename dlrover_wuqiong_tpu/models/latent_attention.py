@@ -10,7 +10,16 @@ is one rotated vector shared by every head.
     q_rope, k_rope rotated; k_h = (k_nope_h | k_rope), the same k_rope
     o_h      = softmax_causal(q_h k_h^T * scale) v_h, scale 1 / sqrt(nope
                + rope) unless `attn_scale` sets it (YaRN's m^2 factor)
+    o_h     *= sigmoid(h Wgate)[h]     with `attn_gate`: one number a head
+                                       and token (`LlamaConfig.attn_gate`'s
+                                       form), before Wo
     out      = concat_h(o_h) Wo
+
+`num_heads` is the heads HELD here: every head has its own columns of
+Wq, Wkv_b and Wgate and its own rows of Wo, while Wkv_a, the latent's
+norm and the one rotated key part are computed alike wherever a head
+lives, so a share of the heads is a share of the layer and Wo's partial
+sums over the shares add up to the whole (tests/test_bailing_hybrid.py).
 
 So a head's q and k are `qk_nope_head_dim + qk_rope_head_dim` wide (192)
 and its v `v_head_dim` (128): `ops/flash_attention.py`'s kernels take the
@@ -27,8 +36,10 @@ widths on a mesh are not built).
 
 Scopes in the compiled step, all under the module's own name:
 `q_proj` (or `q_a_proj`, `q_a_norm`, `q_b_proj` with a q latent),
-`kv_a_proj`, `kv_a_norm`, `kv_b_proj`, `o_proj` (the flax modules'
-names), `rope` (the two rotations) and `assemble` (the cut of
+`kv_a_proj`, `kv_a_norm`, `kv_b_proj`, `o_proj`, `g_proj` (the flax
+modules' names), `gate` (the output gate's sigmoid and multiply; the
+layer then sows `attn_gate_mean` as a gated `LlamaAttention` does),
+`rope` (the two rotations) and `assemble` (the cut of
 the projections into their parts, the broadcast of `k_rope` and the
 joins into the 192-wide q and k).  The module sows `attn_lanes`: the
 lanes of q/k and v a score entry's two products run as the kernels block
@@ -53,7 +64,7 @@ from .llama import RMSNorm, apply_rope
 @dataclasses.dataclass(frozen=True)
 class LatentAttentionConfig:
     hidden_size: int = 2048
-    num_heads: int = 16
+    num_heads: int = 16         # the heads HELD here
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
@@ -63,6 +74,9 @@ class LatentAttentionConfig:
     # the softmax's scale (`models/attention.softmax_scale`); 0 = 1 /
     # sqrt(nope + rope)
     attn_scale: float = 0.0
+    # one sigmoid gate a head and token on the heads' output, before
+    # `o_proj`: sigmoid(x @ g_proj), x the block's normalised input
+    attn_gate: bool = False
     rms_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     use_flash_attention: bool = True
@@ -74,14 +88,15 @@ class LatentAttentionConfig:
 
     def attention_params(self) -> int:
         """q (one product, or the latent's two and its norm), kv_a, the
-        latent's norm, kv_b, o; no block norm."""
+        latent's norm, kv_b, o, the output gate's; no block norm."""
         h, n, r = self.hidden_size, self.num_heads, self.kv_lora_rank
         qr = self.q_lora_rank
         q = h * qr + qr + qr * n * self.qk_head_dim if qr \
             else h * n * self.qk_head_dim
         return (q + h * (r + self.qk_rope_head_dim)
                 + r + r * n * (self.qk_nope_head_dim + self.v_head_dim)
-                + n * self.v_head_dim * h)
+                + n * self.v_head_dim * h
+                + (h * n if self.attn_gate else 0))
 
 
 class LatentAttention(nn.Module):
@@ -140,5 +155,12 @@ class LatentAttention(nn.Module):
             att = jnp.where(kept_mask(T, T), att, -jnp.inf)
             att = jax.nn.softmax(att, axis=-1).astype(cfg.dtype)
             y = jnp.einsum("bhqk,bkhd->bqhd", att, v)
+        if cfg.attn_gate:
+            g = dense(cfg, H, "g_proj", use_bias=False)(x)
+            with jax.named_scope("gate"):
+                g = jax.nn.sigmoid(g.astype(jnp.float32))
+                self.sow("intermediates", "attn_gate_mean",
+                         jax.lax.stop_gradient(g.mean()))
+                y = (y * g[..., None]).astype(cfg.dtype)
         return dense(cfg, C, "o_proj", use_bias=False)(
             y.reshape(B, T, H * dv))

@@ -49,9 +49,9 @@ attention,post_attn_norm,feed_forward}`, `embed_tokens`, `norm`,
 `lm_head`), so `parallel/sharding.py`'s rules bind.
 
 Not built: a vision or audio tower in front of the embedding (text ids
-go in), a limit on the groups of experts a token may choose from
-(`n_group` = `topk_group` = 1 is the only form), a sequence-wise
-auxiliary loss, several lanes on a mesh (the (b, n, T, d) carry has no
+go in), a sequence-wise auxiliary loss (a limit on the groups of experts
+a token may choose from is `MoEConfig.n_group` / `topk_group` since
+PR 57; this stack's configurations have none and pass 1 / 1), several lanes on a mesh (the (b, n, T, d) carry has no
 pins: refused), two widths of attention on a mesh, a server's cache of
 latents.
 

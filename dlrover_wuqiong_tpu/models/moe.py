@@ -169,6 +169,13 @@ class MoEConfig:
     bias_update_rate: float = 0.0
     # the k gates are multiplied by this after normalisation
     routed_scaling: float = 1.0
+    # a limit on the groups a token may choose from (DeepSeek-V3's
+    # `noaux_tc`): the experts lie in n_group groups of num_experts /
+    # n_group, a group's score is the sum of its two largest `probs +
+    # bias`, and the k experts come from the topk_group best groups
+    # alone (`limit_to_groups`).  1 / 1 = no limit
+    n_group: int = 1
+    topk_group: int = 1
     # an expert's form: "swiglu" (silu(x Wg) * (x Wi)) Wd | "reglu"
     # (relu(x Wg) * (x Wi)) Wd, the same three matrices under another
     # gate | "relu2" relu(x Wi)^2 Wd, no gate matrix
@@ -191,7 +198,7 @@ class MoEConfig:
         """The fields the capacity path cannot honour, off their defaults."""
         off = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)
                if f.name in ("score_func", "selection_bias", "routed_scaling",
-                             "bias_update_rate",
+                             "bias_update_rate", "n_group", "topk_group",
                              "expert_act", "shared_width", "experts_held",
                              "first_expert", "norm_topk_prob")
                and getattr(self, f.name) != f.default}
@@ -250,15 +257,68 @@ def top_k_gating(logits: jax.Array, k: int, capacity: int,
     return combine, dispatch
 
 
+def _choice_scores(probs, bias):
+    return probs if bias is None else probs + jax.lax.stop_gradient(bias)
+
+
+def _kept_groups(scores: jax.Array, n_group: int, topk_group: int):
+    """(T, n_group) bool: a token's `topk_group` best groups, a group's
+    score the sum of its two largest entries.  Two reductions a group
+    (the maximum, then the maximum with its first place left out): no
+    sort of the group's entries."""
+    tokens, experts = scores.shape
+    if experts % n_group or not 0 < topk_group <= n_group \
+            or experts // n_group < 2:
+        raise ValueError(f"{experts} experts in n_group={n_group} groups, "
+                         f"topk_group={topk_group} kept")
+    grouped = scores.reshape(tokens, n_group, experts // n_group)
+    first = jnp.argmax(grouped, axis=-1)[..., None]
+    rest = jnp.where(first == jnp.arange(grouped.shape[-1]), -jnp.inf,
+                     grouped)
+    group_scores = grouped.max(-1) + rest.max(-1)
+    _, best = jax.lax.top_k(group_scores, topk_group)
+    return (best[..., None] == jnp.arange(n_group)).any(-2)
+
+
+def limit_to_groups(scores: jax.Array, n_group: int, topk_group: int
+                    ) -> jax.Array:
+    """`scores` (T, E) with every expert outside the token's `topk_group`
+    best groups at -inf: one more `where` before the top-k."""
+    kept = _kept_groups(scores, n_group, topk_group)
+    return jnp.where(jnp.repeat(kept, scores.shape[-1] // n_group, axis=-1),
+                     scores, -jnp.inf)
+
+
+def group_limit_binds(probs: jax.Array, bias: Optional[jax.Array],
+                      experts: jax.Array, n_group: int, topk_group: int
+                      ) -> jax.Array:
+    """How many tokens' k experts under the group limit differ from the k
+    an unlimited choice would take: an expert OUTSIDE the kept groups
+    scores over the least of the chosen.  Two fused reductions over (T,
+    E), no second top-k."""
+    scores = _choice_scores(probs, bias)
+    kept = _kept_groups(scores, n_group, topk_group)
+    outside = jnp.where(jnp.repeat(kept, scores.shape[-1] // n_group, -1),
+                        -jnp.inf, scores).max(-1)
+    chosen = experts[..., None] == jnp.arange(probs.shape[-1])
+    least = jnp.where(chosen, scores[..., None, :], jnp.inf).min((-2, -1))
+    return (outside > least).sum(dtype=jnp.int32)
+
+
 def route_top_k(probs: jax.Array, top_k: int, norm_topk_prob: bool = True,
                 bias: Optional[jax.Array] = None, floor: bool = True,
-                scaling: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+                scaling: float = 1.0, n_group: int = 1, topk_group: int = 1
+                ) -> Tuple[jax.Array, jax.Array]:
     """(gates (T, k), experts (T, k)) of the k largest router scores —
-    of `probs + bias` where a selection bias is given; the gates are
-    `probs` at the chosen experts, WITHOUT the bias.  Normalised gates
+    of `probs + bias` where a selection bias is given, and inside the
+    token's `topk_group` best of `n_group` groups where a group limit is
+    (`limit_to_groups`); the gates are `probs` at the chosen experts,
+    WITHOUT the bias.  Normalised gates
     are divided by max(sum, 1e-9) (`floor`), or by sum + 1e-20 (the
     sigmoid router's published form)."""
-    scores = probs if bias is None else probs + jax.lax.stop_gradient(bias)
+    scores = _choice_scores(probs, bias)
+    if n_group > 1:
+        scores = limit_to_groups(scores, n_group, topk_group)
     _, experts = jax.lax.top_k(scores, top_k)
     # `probs` at the chosen experts by a select and a sum over E: a
     # token's k experts are distinct, so it is the number `top_k` or
@@ -845,10 +905,20 @@ class MoEMLP(nn.Module):
         gates = experts = load = None
         if cfg.impl == "grouped" or cfg.aux_loss == "topk":
             with jax.named_scope("dispatch"):
+                # a router without a group limit is called as it always
+                # was (tests stand the parent's `route_top_k` in its place)
+                limit = dict(n_group=cfg.n_group, topk_group=cfg.topk_group
+                             ) if cfg.n_group > 1 else {}
                 gates, experts = route_top_k(
                     probs, cfg.top_k, cfg.norm_topk_prob, bias=bias,
                     floor=cfg.score_func == "softmax",
-                    scaling=cfg.routed_scaling)
+                    scaling=cfg.routed_scaling, **limit)
+                if limit:
+                    # counted, not timed: tokens the limit moved, tokens
+                    self.sow("intermediates", "moe_group_limit", jnp.stack([
+                        group_limit_binds(probs, bias, experts, cfg.n_group,
+                                          cfg.topk_group),
+                        jnp.int32(n_tok)]))
                 # the step's assignments to each of ALL the experts,
                 # counted once: the auxiliary term, the bias's rule and
                 # the groups' sizes read it
@@ -967,7 +1037,9 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     assignments to experts held / not held here, all layers, and the
     shares of the row buffers' tiles their grouped products and their
     elementwise passes walk, of their rows `dispatch` fetches and of
-    their assignments the sums by assignment index."""
+    their assignments the sums by assignment index; under a group limit
+    `moe_group_limit_binds`, the share of tokens (all layers) whose k
+    experts differ from the k an unlimited choice would take."""
     counts = [n.astype(jnp.float32)
               for n in _sown(intermediates, "moe_tokens_per_expert")]
     if not counts:
@@ -978,10 +1050,13 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     stats = {"moe_load_max_over_mean": jnp.max(jnp.stack(loads)),
              **{name: jnp.sum(jnp.stack(v)) for name, v in sums.items()
                 if v}}
-    for name in ("moe_gmm_tiles", "moe_map_tiles", "moe_gather_rows",
-                 "moe_combine_rows"):
+    for name, stat in (("moe_gmm_tiles", "moe_gmm_tiles_share"),
+                       ("moe_map_tiles", "moe_map_tiles_share"),
+                       ("moe_gather_rows", "moe_gather_rows_share"),
+                       ("moe_combine_rows", "moe_combine_rows_share"),
+                       ("moe_group_limit", "moe_group_limit_binds")):
         tiles = [v.reshape(-1, 2) for v in _sown(intermediates, name)]
         if tiles:
             walked, of = jnp.concatenate(tiles).astype(jnp.float32).sum(0)
-            stats[f"{name}_share"] = walked / of
+            stats[stat] = walked / of
     return stats

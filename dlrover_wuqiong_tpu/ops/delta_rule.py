@@ -55,8 +55,9 @@ mask is applied BEFORE every exp.
 Routes, chosen by `delta_route` from what a call can observe (its
 shapes, the backend, the mesh it runs on), never by a knob:
 
-- "kernel": a pair of Pallas (Mosaic) kernels, `dwt_gdr_fwd` and
-  `dwt_gdr_bwd`, behind one `jax.custom_vjp` (`ops/ssd.py`'s design).  A
+- "kernel" (a decay a HEAD only): a pair of Pallas (Mosaic) kernels,
+  `dwt_gdr_fwd` and `dwt_gdr_bwd`, behind one `jax.custom_vjp`
+  (`ops/ssd.py`'s design).  A
   grid step is one (batch row, block of heads, chunk — or the few chunks
   that fill the MXU's 128 rows side by side, their tiles block
   diagonal), the chunk axis last and sequential: the forward kernel
@@ -81,13 +82,44 @@ shapes, the backend, the mesh it runs on), never by a knob:
   the same ones at the same places; what differs is the order of float32
   sums and that U, rounded, meets Kd^T where `_chunked` composes (A, B).
   Where `delta_route` says so (`_SITES`).
-- "chunked": the form above in `jax.numpy`, the backward pass its
+- "chunked": the form above in `jax.numpy` (`_chunked` for a decay a
+  head, `_chunked_channel` for a decay a channel), the backward pass its
   differentiation but for the two `custom_vjp`s.  GSPMD partitions it, so
   a mixer on a mesh of several devices runs it, as does every CPU run and
   a shape the kernels do not take — and it is the kernels' oracle.
 - "sequential": `lax.scan` over time, for a sequence that is no whole
   number of chunks (a parameter draw on a few tokens) — and the tests'
-  oracle.
+  oracle; either form of the decay.
+
+A decay a CHANNEL (Kimi Delta Attention, arXiv:2510.26692: g (b, T, H,
+dk), alpha_t in R^dk): S_t = Diag(alpha_t) S_{t-1} + k_t u_t^T with u_t =
+beta_t (v_t - (Diag(alpha_t) S_{t-1})^T k_t) — the recurrence above where
+a head's channels decay alike.  In chunks the decay then sits INSIDE the
+contraction over a key's channels,
+
+    L_rs = beta_r sum_d k_rd e^{b_rd - b_sd} k_sd      (s < r)
+    P_rs =        sum_d q_rd e^{b_rd - b_sd} k_sd      (s <= r)
+
+W = T diag(beta) (K o e^b), Kd_s = k_s o e^{b_C - b_s}, A = Diag(e^{b_C})
+- Kd^T W, and the entering state meets q o e^b.  Every exponent there is
+<= 0 but L's and P's: (k_r o e^{b_r}) . (k_s o e^{-b_s}) would form
+e^{-b_s}, and a chunk of 64 steps that each decay by e^-5 overflows
+float32 (5 x 64 = 320 > 88).  So L and P are formed in sub-blocks of
+`_SUB` = 16 steps, the row's operand scaled e^{b_r - b_ref} and the
+column's e^{b_ref - b_s} with b_ref the running sum at the MIDDLE step of
+the ROW's block: a column of an earlier block has b_ref - b_s <= 0, one
+of the row's own block and the row itself lie at most 8 steps from the
+reference (the caller keeps a step's g at or over `CHANNEL_DECAY_FLOOR`:
+8 x 8 = 64 < 88, and e^-64 times a key's small entry is still no
+denormal, which the TPU and the CPU both flush to zero: a reference at
+the block's FIRST step spans 15 steps one way, e^-75 at the published
+bound of -5, and loses the small entries of the rows furthest from it),
+and a later block's is masked BEFORE the exp.  No (C x C x dk) array exists: the scaled column
+operand is (chunk / 16) copies of K, one a row block.  The solve, its
+cotangent, the carry's associative scan and its reverse are the scalar
+form's functions; the sequential route scales the state a channel.  This
+form has no kernel yet: `delta_route(..., channel_decay=True)` says
+"chunked" or "sequential" and never "kernel".
 
 Keys of 96, values of 192 (ROADMAP M6(b2)): NOTHING is padded in HBM.
 The kernels' operands are head-major, (b, H, T, dk) and (b, H, T, dv),
@@ -103,7 +135,9 @@ scratch is (dk x dv): it has no padding lanes a stray value could sit in.
 shapes, whatever computes it.
 
 Scopes (under the caller's): `delta` around all of it; the kernels'
-custom calls, forward, recomputed and backward, carry it.
+custom calls, forward, recomputed and backward, carry it; the channel
+form's stages under it, `sums`, `tiles`, `solve`, `operands`, `carry`,
+`output` (what a probe splits its time by).
 
 Parity: none — the reference (atorch's modules and kernels) has no
 linear-attention layer; this is the paper's algorithm.
@@ -139,6 +173,11 @@ _HEADS_A_STEP = 5        # see `_heads_block`
 _ROWS = 128              # the MXU's: a grid step's chunks fill them
 _VMEM_LIMIT = 64 * 1024 * 1024  # this kernel's own request of the compiler
 _SITES = frozenset({"device"})  # S9 (ROADMAP) adds "manual", and the record
+_SUB = 16                # steps of a sub-block where the decay is a channel's
+# the least log-decay a step of the channel form may carry: the 8 steps
+# either side of a sub-block's reference stay inside e^+-64, where a key's
+# small entry times the scale is no denormal yet
+CHANNEL_DECAY_FLOOR = -8.0
 
 
 def _heads_block(h: int) -> int:
@@ -167,7 +206,7 @@ def _vmem_bytes(dk: int, dv: int, rows: int, hb: int) -> int:
 
 
 def delta_route(t: int, chunk: int, heads: int, dk: int, dv: int,
-                mesh=None):
+                mesh=None, channel_decay: bool = False):
     """Which route `gated_delta_rule` takes, from what the call can
     observe: ("kernel", heads a grid step) where the call runs on one of
     `_SITES` (`mesh` is the mixer config's: a Mosaic kernel cannot be
@@ -175,12 +214,16 @@ def delta_route(t: int, chunk: int, heads: int, dk: int, dv: int,
     the chunk a multiple of the sublane tile, dk and dv multiples of 32
     up to 256 lanes, and the blocks plus the state of a block of heads
     fit the VMEM the call states; else "chunked" where the sequence is a
-    whole number of chunks, else "sequential".  The static counter of
+    whole number of chunks, else "sequential".  With `channel_decay` (g a
+    number a key channel) there is no kernel: "chunked" where the sequence
+    is whole chunks of whole sub-blocks.  The static counter of
     the decision (with the compiled step's count of `dwt_gdr_*` custom
     calls); pinned by tests/test_program_from_arguments.py for the
     benchmark's cell."""
     if t < chunk or t % chunk:
         return "sequential"
+    if channel_decay:
+        return "sequential" if chunk % _SUB else "chunked"
     if mosaic.kernel_site(mesh) not in _SITES:
         return "chunked"
     if chunk % mosaic.SUBLANES or dk % 32 or dv % 32 or max(dk, dv) > _WIDEST:
@@ -207,25 +250,32 @@ def product_lanes(dk: int, dv: int) -> tuple:
 def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
                      dtype=jnp.float32, mesh=None):
     """q, k (b, T, H, dk), normalised and q scaled; v (b, T, H, dv);
-    g (b, T, H), the log of the decay (<= 0); beta (b, T, H), the write
-    gate; `mesh`, where the call runs (the mixer config's).  Returns
-    o (b, T, H, dv) in float32."""
-    route = delta_route(q.shape[1], chunk, *k.shape[2:], v.shape[-1], mesh)
+    g, the log of the decay (<= 0): (b, T, H), one a head, or (b, T, H,
+    dk), one a key channel (then >= `CHANNEL_DECAY_FLOOR` a step); beta
+    (b, T, H), the write gate; `mesh`, where the call runs (the mixer
+    config's).  Returns o (b, T, H, dv) in float32."""
+    channel = g.ndim == 4
+    route = delta_route(q.shape[1], chunk, *k.shape[2:], v.shape[-1], mesh,
+                        channel_decay=channel)
     if route == "chunked":
-        return _chunked(q, k, v, g, beta, chunk, dtype)
+        return (_chunked_channel if channel else _chunked)(
+            q, k, v, g, beta, chunk, dtype)
     if route == "sequential":
         return gated_delta_rule_sequential(q, k, v, g, beta)
     return _chunk_kernels(q, k, v, g, beta, chunk, dtype, route[1])
 
 
 def gated_delta_rule_sequential(q, k, v, g, beta):
-    """The recurrence as it is written, one step at a time, float32."""
+    """The recurrence as it is written, one step at a time, float32; g a
+    head's number or a key channel's."""
     q, k, v, g, beta = (a.astype(jnp.float32) for a in (q, k, v, g, beta))
     bsz, _, h, dk = k.shape
+    if g.ndim == 3:
+        g = g[..., None]
 
     def step(state, qkvgb):
         q_t, k_t, v_t, g_t, b_t = qkvgb
-        state = state * jnp.exp(g_t)[..., None, None]
+        state = state * jnp.exp(g_t)[..., None]
         u = b_t[..., None] * (v_t - _einsum32("bhkv,bhk->bhv", state, k_t))
         state = state + k_t[..., :, None] * u[..., None, :]
         return state, _einsum32("bhkv,bhk->bhv", state, q_t)
@@ -358,6 +408,82 @@ def _chunked(q, k, v, g, beta, chunk, dtype):
     o = _einsum("bchrs,bcshv->bcrhv", qk * decay, u, dtype=dtype)
     o = o + _einsum("bcrhd,bchdv->bcrhv", qs, s_in, dtype=dtype) \
         * by_step(jnp.exp(cum))
+    return o.reshape(bsz, t, h, dv)
+
+
+def _chunked_channel(q, k, v, g, beta, chunk, dtype):
+    """`_chunked` where g is (b, T, H, dk): the module docstring's vector
+    form.  What differs from `_chunked` is how L and P are formed (the
+    sub-blocks) and where a decay multiplies (an operand's channels, not
+    a product's entries)."""
+    bsz, t, h, dk = k.shape
+    dv = v.shape[-1]
+    c, n = t // chunk, chunk // _SUB
+    f32 = jnp.float32
+
+    def cut(x):  # (b, T, H, ...) -> (b, chunks, C, H, ...)
+        return x.reshape(bsz, c, chunk, *x.shape[2:])
+
+    def blocks(x):  # (b, chunks, C, H, d) -> (b, chunks, n, _SUB, H, d)
+        return x.reshape(bsz, c, n, _SUB, *x.shape[3:])
+
+    def by_step(x):  # (b, chunks, H, C) -> (b, chunks, C, H, 1)
+        return jnp.moveaxis(x, -1, 2)[..., None]
+
+    qs, ks, vs = (cut(x).astype(f32) for x in (q, k, v))
+    bt = jnp.moveaxis(cut(beta.astype(f32)), 2, -1)           # (b, c, H, C)
+    with jax.named_scope("sums"):
+        cum = jnp.cumsum(cut(g.astype(f32)), axis=2)          # (b,c,C,H,dk)
+        total = cum[:, :, -1]                                 # (b, c, H, dk)
+        e_cum = jnp.exp(cum)
+
+    # L and P by sub-blocks: rows (I, i) scaled e^{b_r - b_ref(I)},
+    # columns (J, j) e^{b_ref(I) - b_s} for J <= I (mask BEFORE exp),
+    # b_ref(I) the running sum at block I's middle step.
+    # Recomputed in the backward pass (`jax.checkpoint`): the scaled
+    # column operand is n copies of K, kept it would outweigh the rest
+    earlier = (np.arange(n)[:, None] >= np.arange(n)[None, :]
+               ).reshape(n, n, 1, 1, 1)
+
+    @jax.checkpoint
+    @jax.named_scope("tiles")
+    def tiles(qs, ks, cum):  # sum_d rows[I,i,d] kc[I,J,j,d], (b,c,H,C,C)
+        cum_b = blocks(cum)
+        ref = cum_b[:, :, :, _SUB // 2]                       # (b,c,n,H,dk)
+        row_scale = jnp.exp(cum_b - ref[:, :, :, None])
+        col_scale = jnp.exp(jnp.where(
+            earlier, ref[:, :, :, None, None] - cum_b[:, :, None], -jnp.inf))
+        kc = blocks(ks)[:, :, None] * col_scale           # (b,c,I,J,j,H,dk)
+        return tuple(
+            _einsum("bcIihd,bcIJjhd->bchIiJj", blocks(rows) * row_scale, kc,
+                    dtype=dtype).reshape(bsz, c, h, chunk, chunk)
+            for rows in (ks, qs))
+
+    kk, qk = tiles(qs, ks, cum)
+    tril = np.tril(np.ones((chunk, chunk), bool))
+    with jax.named_scope("solve"):
+        # the mask FIRST: over the diagonal an entry's two scales multiply
+        # to e^{b_r - b_s} > 1, past float32 at the floor (inf - inf)
+        low = bt[..., :, None] * jnp.where(np.tril(tril, -1), kk, 0.0)
+        solve = _unit_lower_inverse(low)                      # T, float32
+        p_mat = jnp.where(tril, qk, 0.0)
+
+    with jax.named_scope("operands"):
+        u0 = _einsum("bchrs,bcshv->bcrhv", solve, vs * by_step(bt),
+                     dtype=dtype)
+        w = _einsum("bchrs,bcshd->bcrhd", solve, ks * e_cum * by_step(bt),
+                    dtype=dtype)
+        kd = ks * jnp.exp(total[:, :, None] - cum)            # (b,c,C,H,dk)
+        a_mat = jnp.exp(total)[..., :, None] * jnp.eye(dk, dtype=f32) \
+            - _einsum("bcshi,bcshj->bchij", kd, w, dtype=dtype)
+        b_mat = _einsum("bcshi,bcshv->bchiv", kd, u0, dtype=dtype)
+    with jax.named_scope("carry"):
+        s_in = _entering(a_mat, b_mat)                        # (b,c,H,dk,dv)
+
+    with jax.named_scope("output"):
+        u = u0 - _einsum("bcrhd,bchdv->bcrhv", w, s_in, dtype=dtype)
+        o = _einsum("bchrs,bcshv->bcrhv", p_mat, u, dtype=dtype) \
+            + _einsum("bcrhd,bchdv->bcrhv", qs * e_cum, s_in, dtype=dtype)
     return o.reshape(bsz, t, h, dv)
 
 

@@ -96,12 +96,15 @@ TRANSFORMER_RULES: List[Rule] = [
     (r".*mamba.*in_proj/kernel$", P("fsdp", "tp")),
     (r".*mamba.*out_proj/kernel$", P("tp", "fsdp")),
     (r".*mamba.*/(conv_kernel|conv_bias|A_log|D|dt_bias)$", P()),
-    # a gated delta-rule mixer (models/gated_delta.py): q, k, v and o go
-    # by the attention rules above; the output gate's projection is
-    # column-parallel as v's is, the two per-head gates' projections are a
-    # few columns and stay whole, the filter and the per-head leaves are
-    # small and replicated
-    (r".*linear_attention.*g_proj/kernel$", P("fsdp", "tp")),
+    # a gated delta-rule mixer (models/gated_delta.py, models/kda.py): q,
+    # k, v and o go by the attention rules above, and so does a KDA
+    # mixer's head-wise output gate (g_proj, a column a head: the
+    # attention gate's rule above binds first); the element-wise output
+    # gate's projection and the channel decay's (f_proj) are column-
+    # parallel as v's is, the per-head gates' projections are a few
+    # columns and stay whole, the filter and the per-head or per-channel
+    # leaves are small and replicated
+    (r".*linear_attention.*(g_proj|f_proj)/kernel$", P("fsdp", "tp")),
     (r".*linear_attention.*(a_proj|b_proj)/kernel$", P("fsdp", None)),
     (r".*linear_attention.*/(conv_kernel|A_log|dt_bias)$", P()),
     (r".*selection_bias$", P()),
