@@ -17,9 +17,13 @@ With H heads held here, keys of dk and values of dv:
                                                    token, one (dv,) scale
     out = concat_heads(y) Wo
 
-The lower bound is what lets the chunked form scale its operands in
-sub-blocks (`ops/delta_rule.CHANNEL_DECAY_FLOOR`): a config below it is
-refused.  The mixer is told how many heads it holds and nothing else:
+The lower bound is what lets the chunked form and the kernels scale
+their operands in sub-blocks (`ops/delta_rule.CHANNEL_DECAY_FLOOR`): a
+config below it is refused.  The recurrence runs where
+`ops/delta_rule.delta_route(..., channel_decay=True)` says: on one TPU
+device the Pallas pair `dwt_kda_fwd` / `dwt_kda_bwd` (the cell's shape:
+four heads a grid step), everywhere else the chunked `jax.numpy` form,
+the pair's oracle.  The mixer is told how many heads it holds and nothing else:
 the state, both norms, the three gates and the output norm are per head
 and the convolution and the decay per channel, so a share of the heads
 IS a share of the mixer, and `Wo`'s partial sums over the shares add up
@@ -27,8 +31,9 @@ to the whole (tests/test_bailing_hybrid.py).
 
 Scopes, under the module's own name: `q_proj`, `k_proj`, `v_proj`,
 `f_proj`, `decay` (the decay's activation), `gates` (`b_proj`, beta),
-`conv`, `delta` (the L2 norms and all of the recurrence, the decay's
-running sums with it), `g_proj` and `gate` (the head-wise output gate),
+`conv`, `delta` (the L2 norms and all of the recurrence — the kernels'
+custom calls, the decay's running sums and the head-major re-layouts
+with it), `g_proj` and `gate` (the head-wise output gate),
 `gate_norm`, `o_proj`.  Parameter names are matched by
 `parallel/sharding.py`.  The module sows `delta_stats` as
 `models/gated_delta.py`'s mixer does (the decay averaged over a head's

@@ -55,9 +55,11 @@ mask is applied BEFORE every exp.
 Routes, chosen by `delta_route` from what a call can observe (its
 shapes, the backend, the mesh it runs on), never by a knob:
 
-- "kernel" (a decay a HEAD only): a pair of Pallas (Mosaic) kernels,
-  `dwt_gdr_fwd` and `dwt_gdr_bwd`, behind one `jax.custom_vjp`
-  (`ops/ssd.py`'s design).  A
+- "kernel": a pair of Pallas (Mosaic) kernels behind one
+  `jax.custom_vjp` (`ops/ssd.py`'s design), `dwt_gdr_fwd` and
+  `dwt_gdr_bwd` for a decay a HEAD — described here — and `dwt_kda_fwd`
+  and `dwt_kda_bwd` for a decay a CHANNEL (below: the same grid, carry
+  and wrappers; what differs is said there).  A
   grid step is one (batch row, block of heads, chunk — or the few chunks
   that fill the MXU's 128 rows side by side, their tiles block
   diagonal), the chunk axis last and sequential: the forward kernel
@@ -117,9 +119,27 @@ bound of -5, and loses the small entries of the rows furthest from it),
 and a later block's is masked BEFORE the exp.  No (C x C x dk) array exists: the scaled column
 operand is (chunk / 16) copies of K, one a row block.  The solve, its
 cotangent, the carry's associative scan and its reverse are the scalar
-form's functions; the sequential route scales the state a channel.  This
-form has no kernel yet: `delta_route(..., channel_decay=True)` says
-"chunked" or "sequential" and never "kernel".
+form's functions; the sequential route scales the state a channel.
+
+Its kernel pair, `dwt_kda_fwd` / `dwt_kda_bwd` (PR 58), is the scalar
+pair's design — grid (batch row, block of heads, step), the step's
+chunks side by side at the MXU's 128 rows, the solve in VMEM, the carried
+state a float32 scratch the sequential step axis walks, the entering
+states saved for a backward kernel that rebuilds the tiles and carries dS
+in reverse — and differs in this only: L and P are built in VMEM a row
+block of 16 steps at a time, exactly the scaling above (the block's k
+rows over its q rows, (32 x dk), against ONE scaled column operand: one
+product a row block, no `decay` tile); a decay multiplies an operand's
+channels (W, Kd, Q o e^b), and the carry scales the state's rows by
+e^{b_C}, a vector over dk — so the state's scratch is held TRANSPOSED,
+(dv, dk), the decay broadcasts along its sublanes and the products that
+meet it are `_dot_t` / `_dot_c0`: no kernel transposes a vector; the
+running sums and their cotangent are (R, dk) float32 a head, head-major
+like k (beta keeps its layout by column).  The sub-blocks' reference
+carries no cotangent (L and P do not depend on it).  The cumulative sum
+of g stays `jax.numpy` in front of the kernels, differentiated by JAX.
+The operands `_chunked_channel` rounds to `dtype` are rounded in the
+kernels at the same places.
 
 Keys of 96, values of 192 (ROADMAP M6(b2)): NOTHING is padded in HBM.
 The kernels' operands are head-major, (b, H, T, dk) and (b, H, T, dv),
@@ -135,9 +155,9 @@ scratch is (dk x dv): it has no padding lanes a stray value could sit in.
 shapes, whatever computes it.
 
 Scopes (under the caller's): `delta` around all of it; the kernels'
-custom calls, forward, recomputed and backward, carry it; the channel
-form's stages under it, `sums`, `tiles`, `solve`, `operands`, `carry`,
-`output` (what a probe splits its time by).
+custom calls of either pair, forward, recomputed and backward, carry it;
+the chunked channel form's stages under it, `sums`, `tiles`, `solve`,
+`operands`, `carry`, `output` (what a probe splits its time by).
 
 Parity: none — the reference (atorch's modules and kernels) has no
 linear-attention layer; this is the paper's algorithm.
@@ -188,20 +208,29 @@ def _heads_block(h: int) -> int:
     fifteen heads, two chunks a step (PERF.md section 6, PR 48): 1 / 3 /
     5 / 15 heads a step run a layer's forward kernel in 2.77 / 2.69 /
     2.68 / 2.65 ms and its backward in 3.89 / 3.70 / 3.61 / 4.37 (at
-    fifteen the unrolled backward is 3 times the code for nothing)."""
+    fifteen the unrolled backward is 3 times the code for nothing).  The
+    channel pair at its cell's shape, sixteen heads of 128 | 128, two
+    chunks a step (PERF.md section 6, PR 58): 1 / 2 / 4 heads a step run
+    a layer's forward kernel in 3.09 / 3.04 / 2.96 ms and its backward in
+    4.30 / 4.22 / 4.11."""
     return max(d for d in range(1, _HEADS_A_STEP + 1) if h % d == 0)
 
 
-def _vmem_bytes(dk: int, dv: int, rows: int, hb: int) -> int:
+def _vmem_bytes(dk: int, dv: int, rows: int, hb: int,
+                channel: bool = False) -> int:
     """What the backward kernel (the larger) holds at `rows` rows a grid
     step: its double-buffered blocks (q, k, dq, dk float32; v, dv; dO
-    float32; the entering states), the carried cotangent, and a head's
-    tiles and temporaries."""
+    float32; the entering states; with a decay a `channel`, the running
+    sums and their cotangent, two more key-sized blocks), the carried
+    cotangent, and a head's tiles and temporaries (the channel form's
+    scaled column operands, one a sub-block, beside them)."""
     lanes = functools.partial(_round_up, m=mosaic.LANES)
-    blocks = rows * 4 * (4 * lanes(dk) + 3 * lanes(dv))
+    blocks = rows * 4 * ((6 if channel else 4) * lanes(dk) + 3 * lanes(dv))
     state = dk * lanes(dv) * 4
     states = -(-rows // 64) * state
     tiles = 4 * rows * (16 * lanes(rows) + 8 * (lanes(dk) + lanes(dv)))
+    if channel:  # a sub-block's column scale (float32) and operand (bf16)
+        tiles += rows // _SUB * rows * (4 + 2) * lanes(dk)
     return 2 * hb * (blocks + states) + hb * state + 3 * tiles + 3 * state
 
 
@@ -215,22 +244,20 @@ def delta_route(t: int, chunk: int, heads: int, dk: int, dv: int,
     up to 256 lanes, and the blocks plus the state of a block of heads
     fit the VMEM the call states; else "chunked" where the sequence is a
     whole number of chunks, else "sequential".  With `channel_decay` (g a
-    number a key channel) there is no kernel: "chunked" where the sequence
-    is whole chunks of whole sub-blocks.  The static counter of
-    the decision (with the compiled step's count of `dwt_gdr_*` custom
-    calls); pinned by tests/test_program_from_arguments.py for the
-    benchmark's cell."""
-    if t < chunk or t % chunk:
+    number a key channel) a chunk is whole sub-blocks too, on either
+    route, and the kernels are the channel pair.  The static counter of
+    the decision (with the compiled step's count of `dwt_gdr_*` or
+    `dwt_kda_*` custom calls); pinned by
+    tests/test_program_from_arguments.py for the benchmark's cells."""
+    if t < chunk or t % chunk or (channel_decay and chunk % _SUB):
         return "sequential"
-    if channel_decay:
-        return "sequential" if chunk % _SUB else "chunked"
     if mosaic.kernel_site(mesh) not in _SITES:
         return "chunked"
     if chunk % mosaic.SUBLANES or dk % 32 or dv % 32 or max(dk, dv) > _WIDEST:
         return "chunked"
     hb = _heads_block(heads)
     rows = chunk * _chunks_a_step(chunk, t // chunk)
-    if _vmem_bytes(dk, dv, rows, hb) > _VMEM_LIMIT:
+    if _vmem_bytes(dk, dv, rows, hb, channel_decay) > _VMEM_LIMIT:
         return "chunked"
     return "kernel", hb
 
@@ -696,14 +723,205 @@ def _gdr_bwd_kernel(q_ref, k_ref, v_ref, bc_ref, br_ref, bt_ref, st_ref,
     dbc_ref[...], dbr_ref[...], dbt_ref[...] = dbc, dbr, dbt
 
 
+# ------------------------------------------- the kernels, a decay a CHANNEL
+#
+# What differs from the pair above is the module docstring's ("Its kernel
+# pair").  Layouts: the running sums of g inside a chunk and their
+# cotangent are ONE operand each, (b, H, T, dk) float32, head-major like
+# k, a block (hb, R, dk); the entering states and the carried state's
+# scratch are TRANSPOSED, (dv, dk) a head; the rest as above.
+
+class _SubBlock:
+    """Row block `i` of a step's tiles: `rs`, the rows' scale (_SUB, dk);
+    `cs`, the columns' (R, dk), zero outside the row's chunk and past the
+    row's block; the two operands as the product takes them."""
+
+    def __init__(self, b_ref, h, i, b, kf, qf, row, chunk, dtype):
+        lo, mid = i * _SUB, i * _SUB + _SUB // 2
+        ref = b_ref[h, mid:mid + 1, :]                          # (1, dk)
+        seen = (row >= lo // chunk * chunk) & (row < lo + _SUB)
+        self.rs = jnp.exp(b[lo:lo + _SUB] - ref)
+        self.cs = jnp.exp(jnp.where(seen, ref - b, -jnp.inf))
+        self.rows = jnp.concatenate(
+            [kf[lo:lo + _SUB] * self.rs, qf[lo:lo + _SUB] * self.rs],
+            axis=0).astype(dtype)                               # (32, dk)
+        self.cols = (kf * self.cs).astype(dtype)                # (R, dk)
+
+
+class _ChannelTiles:
+    """`_Tiles` where the decay is a channel's: what the forward kernel
+    builds of a head and a step that does not depend on the entering
+    state, and the backward REBUILDS; `*b` is an operand rounded to the
+    products' dtype, as `_chunked_channel` rounds it."""
+
+    def __init__(self, refs, h, masks, chunk, dtype):
+        q_ref, k_ref, v_ref, b_ref, bt_ref = refs
+        tril, strict, eye, joins = masks
+        f32 = jnp.float32
+        b, self.beta = b_ref[h], bt_ref[:, h:h + 1]       # (R, dk), (R, 1)
+        size = b.shape[0]
+        row = _iota((size, 1), 0)
+        # a chunk's total, b_C (1, dk), over its rows; e^{b_C} a chunk
+        total, self.ets = None, []
+        for c in range(size // chunk):
+            last = b_ref[h, (c + 1) * chunk - 1:(c + 1) * chunk, :]
+            total = last if c == 0 else jnp.where(
+                row >= c * chunk, last, total)
+            self.ets.append(jnp.exp(last))
+        self.eb, self.te = jnp.exp(b), jnp.exp(total - b)
+        self.kf, self.qf = k_ref[h].astype(f32), q_ref[h].astype(f32)
+        self.vf = v_ref[h].astype(f32)
+        self.subs = [_SubBlock(b_ref, h, i, b, self.kf, self.qf, row, chunk,
+                               dtype) for i in range(size // _SUB)]
+        both = [_dot_t(s.rows, s.cols) for s in self.subs]      # (32, R)
+        # the mask FIRST: over the diagonal an entry's two scales multiply
+        # to e^{b_r - b_s} > 1, past float32 at the floor
+        self.kk = jnp.where(strict, _stack([x[:_SUB] for x in both]), 0.0)
+        self.pb = jnp.where(tril, _stack([x[_SUB:] for x in both]),
+                            0.0).astype(dtype)
+        self.tm = _solve(self.beta * self.kk, eye, joins)
+        self.tb = self.tm.astype(dtype)
+        self.vbb = (self.vf * self.beta).astype(dtype)
+        self.keb = (self.kf * self.eb * self.beta).astype(dtype)
+        self.kdb = (self.kf * self.te).astype(dtype)
+        self.qeb = (self.qf * self.eb).astype(dtype)
+        self.u0 = _dot(self.tb, self.vbb)
+        self.wb = _dot(self.tb, self.keb).astype(dtype)
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, b_ref, bt_ref, o_ref, *rest,
+                    chunk, dtype, save):
+    st_ref = rest[0] if save else None
+    s_scr = rest[-1]                            # (hb, dv, dk): transposed
+    hb, size, _ = q_ref.shape
+    n = size // chunk
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_chunk():
+        s_scr[...] = jnp.zeros(s_scr.shape, jnp.float32)
+
+    masks = _masks(chunk, n)
+    for h in range(hb):
+        t = _ChannelTiles((q_ref, k_ref, v_ref, b_ref, bt_ref), h, masks,
+                          chunk, dtype)
+        state, ubs, qss = s_scr[h], [], []
+        for c in range(n):
+            rows = slice(c * chunk, (c + 1) * chunk)
+            if save:
+                st_ref[h, c] = state
+            sb = state.astype(dtype)
+            ubs.append((t.u0[rows] - _dot_t(t.wb[rows], sb)).astype(dtype))
+            qss.append(_dot_t(t.qeb[rows], sb))
+            state = t.ets[c] * state + _dot_c0(ubs[c], t.kdb[rows])
+        s_scr[h] = state
+        o_ref[h] = _dot(t.pb, _stack(ubs)) + _stack(qss)
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, b_ref, bt_ref, st_ref, do_ref,
+                    dq_ref, dk_ref, dv_ref, db_ref, dbt_ref, ds_scr, *,
+                    chunk, dtype):
+    hb, size, _ = q_ref.shape
+    n = size // chunk
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _last_chunk():
+        ds_scr[...] = jnp.zeros(ds_scr.shape, f32)
+
+    masks = _masks(chunk, n)
+    tril, strict = masks[:2]
+    row = _iota((size, 1), 0)
+    col_head = _iota((1, hb), 1)
+    dbt = jnp.zeros((size, hb), f32)
+    for h in range(hb):
+        t = _ChannelTiles((q_ref, k_ref, v_ref, b_ref, bt_ref), h, masks,
+                          chunk, dtype)
+        chunks = [slice(c * chunk, (c + 1) * chunk) for c in range(n)]
+        sbs = [st_ref[h, c].astype(dtype) for c in range(n)]
+        ubs = [(t.u0[rows] - _dot_t(t.wb[rows], sb)).astype(dtype)
+               for rows, sb in zip(chunks, sbs)]
+        dob = do_ref[h].astype(dtype)
+        # o = P U + (Q o e^b) S_in;  S_out = e^{b_C} o S_in + Kd^T U
+        dp = _dot_t(dob, _stack(ubs))                          # (R, R)
+        du = _dot_c0(t.pb, dob)                                # (R, dv)
+        # what meets the carried state, a chunk at a time from the last:
+        # U = U0 - W S_in
+        ds_out = ds_scr[h]     # cotangent of the state LEAVING, (dv, dk)
+        dubs, dwbs, dkds, dqes, d_tot = ([None] * n for _ in range(5))
+        for c in reversed(range(n)):
+            rows, sb, gb = chunks[c], sbs[c], ds_out.astype(dtype)
+            dubs[c] = (du[rows] + _dot_t(t.kdb[rows], gb)).astype(dtype)
+            dkds[c] = _dot(ubs[c], gb)                         # (C, dk)
+            dwbs[c] = (-_dot(dubs[c], sb)).astype(dtype)       # (C, dk)
+            dqes[c] = _dot(dob[rows], sb)
+            d_tot[c] = t.ets[c] * _col_sum(ds_out * st_ref[h, c])  # (1, dk)
+            ds_out = t.ets[c] * ds_out + _dot_c0(dob[rows], t.qeb[rows]) \
+                - _dot_c0(dubs[c], t.wb[rows])
+        ds_scr[h] = ds_out
+        dub, dwb, dkd = _stack(dubs), _stack(dwbs), _stack(dkds)
+        # U0 = T (beta V);  W = T (beta e^b o K)
+        dvb = _dot_c0(t.tb, dub)                               # (R, dv)
+        dke = _dot_c0(t.tb, dwb)                               # (R, dk)
+        # T = (I + L)^-1: dL = -T^T dT T^T on the strict lower triangle
+        d_tm = _dot_t(dub, t.vbb) + _dot_t(dwb, t.keb)
+        dlow = jnp.where(strict, -_dot32(
+            _dot32(t.tm, d_tm, ((0,), (0,))), t.tm, ((1,), (1,))), 0.0)
+        dkk, dqk = dlow * t.beta, jnp.where(tril, dp, 0.0)
+        # the sub-blocks' products, a row block at a time: through the
+        # rows (k over q) and through the one column operand.  b_ref's
+        # own cotangent is zero: L and P do not depend on the reference
+        through_cols = jnp.zeros(t.kf.shape, f32)
+        k_rows, q_rows = [], []
+        for i, sub in enumerate(t.subs):
+            block = slice(i * _SUB, (i + 1) * _SUB)
+            d_both = jnp.concatenate([dkk[block], dqk[block]],
+                                     axis=0).astype(dtype)     # (32, R)
+            d_rows = _dot(d_both, sub.cols)                    # (32, dk)
+            k_rows.append(d_rows[:_SUB] * sub.rs)
+            q_rows.append(d_rows[_SUB:] * sub.rs)
+            through_cols = through_cols + _dot_c0(d_both, sub.rows) * sub.cs
+        dq = _stack(dqes) * t.eb + _stack(q_rows)
+        # dk's terms whose scale rises with b, then those whose falls
+        dk_up = dke * t.beta * t.eb + _stack(k_rows)
+        dk_down = dkd * t.te + through_cols
+        dq_ref[h] = dq.astype(dq_ref.dtype)
+        dk_ref[h] = (dk_up + dk_down).astype(dk_ref.dtype)
+        dv_ref[h] = (dvb * t.beta).astype(dv_ref.dtype)
+        db = dq * t.qf + (dk_up - dk_down) * t.kf
+        through_te = dkd * t.kf * t.te
+        for c, rows in enumerate(chunks):    # d(b_C), at a chunk's last row
+            d_total = d_tot[c] + _col_sum(jnp.where(
+                (row >= rows.start) & (row < rows.stop), through_te, 0.0))
+            db = db + jnp.where(row == rows.stop - 1, d_total, 0.0)
+        db_ref[h] = db
+        dbt = _put(dbt, col_head, h, _row_sum(dlow * t.kk)
+                   + _row_sum(dvb * t.vf) + _row_sum(dke * t.kf * t.eb))
+    dbt_ref[...] = dbt
+
+
+# ------------------------------------------------------ the kernels' calls
+
 _PARAMS = _compiler_params("parallel", "parallel", "arbitrary",
                            vmem_limit=_VMEM_LIMIT)
+# by the decay's form (a CHANNEL's or not): the kernels, their names, and
+# the operands' kinds in order (`_specs`); then come the entering states
+# and dO, and the backward kernel's outputs are the operands' cotangents
+_PAIRS = {
+    False: (_gdr_fwd_kernel, _gdr_bwd_kernel, "dwt_gdr",
+            ("key", "key", "value", "col", "row", "col")),
+    True: (_kda_fwd_kernel, _kda_bwd_kernel, "dwt_kda",
+           ("key", "key", "value", "key", "col")),
+}
 
 
-def _specs(size, n, hb, dk, dv, at):
+def _state_shape(dk, dv, channel):
+    return (dv, dk) if channel else (dk, dv)
+
+
+def _specs(size, n, hb, dk, dv, at, channel=False):
     """BlockSpecs by operand kind; `at(k)` is the step (n chunks, `size`
     rows) a grid step works on (the backward kernel walks them in
-    reverse)."""
+    reverse).  The channel pair's states are held transposed."""
     def rows(d):
         return pl.BlockSpec((None, hb, size, d),
                             lambda b, j, k: (b, j, at(k), 0))
@@ -713,87 +931,88 @@ def _specs(size, n, hb, dk, dv, at):
                          lambda b, j, k: (b, j, at(k), 0, 0)),
         row=pl.BlockSpec((None, None, None, hb, size),
                          lambda b, j, k: (b, j, at(k), 0, 0)),
-        state=pl.BlockSpec((None, hb, n, dk, dv),
+        state=pl.BlockSpec((None, hb, n, *_state_shape(dk, dv, channel)),
                            lambda b, j, k: (b, j, at(k), 0, 0)))
 
 
-def _gdr_forward_pallas(q, k, v, bc, br, bt, *, chunk, hb, dtype, save,
-                        interpret):
+def _gdr_forward_pallas(*operands, chunk, hb, dtype, save, interpret,
+                        channel=False):
     """o (b, H, T, dv) float32 and, with `save`, the state ENTERING every
     chunk, (b, H, chunks, dk, dv) float32, for the backward kernel."""
+    q, k, v = operands[:3]
     bsz, h, t, dk = k.shape
-    dv, size = v.shape[-1], bc.shape[-2]
+    dv, size = v.shape[-1], operands[-1].shape[-2]
     n, steps = size // chunk, t // size
-    sp = _specs(size, n, hb, dk, dv, lambda k: k)
+    kernel, _, name, kinds = _PAIRS[channel]
+    state = _state_shape(dk, dv, channel)
+    sp = _specs(size, n, hb, dk, dv, lambda k: k, channel)
     out_shape = [_out_struct((bsz, h, t, dv), jnp.float32, q)]
     out_specs = [sp["value"]]
     if save:
-        out_shape.append(_out_struct((bsz, h, t // chunk, dk, dv),
+        out_shape.append(_out_struct((bsz, h, t // chunk, *state),
                                      jnp.float32, q))
         out_specs.append(sp["state"])
     out = pl.pallas_call(
-        functools.partial(_gdr_fwd_kernel, chunk=chunk, dtype=dtype,
-                          save=save),
+        functools.partial(kernel, chunk=chunk, dtype=dtype, save=save),
         grid=(bsz, h // hb, steps),
-        in_specs=[sp["key"], sp["key"], sp["value"], sp["col"], sp["row"],
-                  sp["col"]],
+        in_specs=[sp[kind] for kind in kinds],
         out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((hb, dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, *state), jnp.float32)],
         compiler_params=_PARAMS, interpret=interpret,
-        name="dwt_gdr_fwd",
-    )(q, k, v, bc, br, bt)
+        name=name + "_fwd",
+    )(*operands)
     return tuple(out) if save else (out[0], None)
 
 
-def _gdr_backward_pallas(q, k, v, bc, br, bt, states, do, *, chunk, hb,
-                         dtype, interpret):
+def _gdr_backward_pallas(*operands_states_do, chunk, hb, dtype, interpret,
+                         channel=False):
+    operands = operands_states_do[:-2]
+    q, k, v = operands[:3]
     bsz, h, t, dk = k.shape
-    dv, size = v.shape[-1], bc.shape[-2]
+    dv, size = v.shape[-1], operands[-1].shape[-2]
     n, steps = size // chunk, t // size
-    sp = _specs(size, n, hb, dk, dv, lambda k: steps - 1 - k)
-    f32 = jnp.float32
+    _, kernel, name, kinds = _PAIRS[channel]
+    sp = _specs(size, n, hb, dk, dv, lambda k: steps - 1 - k, channel)
+    specs = [sp[kind] for kind in kinds]
     return pl.pallas_call(
-        functools.partial(_gdr_bwd_kernel, chunk=chunk, dtype=dtype),
+        functools.partial(kernel, chunk=chunk, dtype=dtype),
         grid=(bsz, h // hb, steps),
-        in_specs=[sp["key"], sp["key"], sp["value"], sp["col"], sp["row"],
-                  sp["col"], sp["state"], sp["value"]],
-        out_specs=[sp["key"], sp["key"], sp["value"], sp["col"], sp["row"],
-                   sp["col"]],
-        out_shape=[_out_struct(q.shape, q.dtype, q),
-                   _out_struct(k.shape, k.dtype, q),
-                   _out_struct(v.shape, v.dtype, q),
-                   _out_struct(bc.shape, f32, q),
-                   _out_struct(br.shape, f32, q),
-                   _out_struct(bt.shape, f32, q)],
-        scratch_shapes=[pltpu.VMEM((hb, dk, dv), f32)],
+        in_specs=[*specs, sp["state"], sp["value"]],
+        out_specs=specs,
+        # q's, k's and v's cotangents in their dtypes, the rest float32
+        out_shape=[_out_struct(x.shape, x.dtype if i < 3 else jnp.float32, q)
+                   for i, x in enumerate(operands)],
+        scratch_shapes=[pltpu.VMEM((hb, *_state_shape(dk, dv, channel)),
+                                   jnp.float32)],
         compiler_params=_PARAMS, interpret=interpret,
-        name="dwt_gdr_bwd",
-    )(q, k, v, bc, br, bt, states, do)
+        name=name + "_bwd",
+    )(*operands_states_do)
 
 
 # A model's layers call the kernels with the same shapes and the same
 # static plan: behind `jax.jit` a kernel body is traced and lowered to
 # Mosaic once a step program, not once a layer (`ops/ssd.py`'s way).
-_STATIC = ("chunk", "hb", "dtype", "interpret")
+_STATIC = ("chunk", "hb", "dtype", "interpret", "channel")
 _forward = jax.jit(_gdr_forward_pallas, static_argnames=_STATIC + ("save",))
 _backward = jax.jit(_gdr_backward_pallas, static_argnames=_STATIC)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _chunks(q, k, v, bc, br, bt, plan):
-    """The kernels' pair: (q, k, v head-major, the running sums by column
-    and by row, beta by column) -> o (b, H, T, dv) float32.  `plan`: the
-    static arguments."""
-    return _forward(q, k, v, bc, br, bt, save=False, **dict(plan))[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _chunks(operands, plan):
+    """A kernels' pair on its operands — q, k, v head-major, then the
+    running sums by column and by row (a decay a head) or head-major as k
+    is (a decay a channel), beta by column -> o (b, H, T, dv) float32.
+    `plan`: the static arguments."""
+    return _forward(*operands, save=False, **dict(plan))[0]
 
 
-def _chunks_fwd(q, k, v, bc, br, bt, plan):
-    o, states = _forward(q, k, v, bc, br, bt, save=True, **dict(plan))
-    return o, (q, k, v, bc, br, bt, states)
+def _chunks_fwd(operands, plan):
+    o, states = _forward(*operands, save=True, **dict(plan))
+    return o, (*operands, states)
 
 
 def _chunks_bwd(plan, res, do):
-    return tuple(_backward(*res, do, **dict(plan)))
+    return (tuple(_backward(*res, do, **dict(plan))),)
 
 
 _chunks.defvjp(_chunks_fwd, _chunks_bwd)
@@ -804,7 +1023,9 @@ def _chunks_a_step(chunk: int, chunks: int) -> int:
     (`_ROWS`) and divide the sequence's chunks.  Measured at the cell's
     shape, five heads a step (PERF.md section 6, PR 48): one chunk of 64
     a step runs a layer's forward kernel in 3.62 ms and its backward in
-    4.97, two in 2.68 and 3.61, the same numbers bit for bit."""
+    4.97, two in 2.68 and 3.61, the same numbers bit for bit; the channel
+    pair, four heads a step (PR 58): one in 3.84 and 5.51, two in 2.96
+    and 4.11."""
     n = max(1, _ROWS // chunk)
     while chunks % n:
         n -= 1
@@ -814,26 +1035,33 @@ def _chunks_a_step(chunk: int, chunks: int) -> int:
 def _kernel_operands(q, k, v, g, beta, chunk, hb, n):
     """What the kernels are handed, from `gated_delta_rule`'s arguments:
     q, k, v head-major (ONE re-layout each a pass); the running sums of g
-    inside a chunk by column and by row, beta by column, n chunks a step.
-    `jax.numpy`, differentiated by JAX."""
-    bsz, t, h = g.shape
+    inside a chunk — a head's by column and by row, a channel's head-major
+    as k is — and beta by column, n chunks a step.  `jax.numpy`,
+    differentiated by JAX."""
+    bsz, t, h = beta.shape
     c = t // chunk
 
     def steps(x):  # (b, chunks, C, H) -> (b, H/hb, steps, R, hb)
         return x.reshape(bsz, c // n, n * chunk, h // hb, hb).transpose(
             0, 3, 1, 2, 4)
 
-    cum = steps(jnp.cumsum(
-        g.astype(jnp.float32).reshape(bsz, c, chunk, h), axis=2))
+    cum = jnp.cumsum(g.astype(jnp.float32).reshape(
+        bsz, c, chunk, *g.shape[2:]), axis=2)
+    if g.ndim == 4:
+        return (*(x.transpose(0, 2, 1, 3)
+                  for x in (q, k, v, cum.reshape(g.shape))),
+                steps(beta.astype(jnp.float32)))
+    cum = steps(cum)
     return (*(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
             cum, cum.swapaxes(-1, -2), steps(beta.astype(jnp.float32)))
 
 
 def _chunk_kernels(q, k, v, g, beta, chunk, dtype, hb, interpret=False,
                    chunks_a_step=None):
-    """The kernel route: `_chunks` on `_kernel_operands`."""
+    """The kernel route: `_chunks` on `_kernel_operands`, the pair g's
+    rank names."""
     n = chunks_a_step or _chunks_a_step(chunk, q.shape[1] // chunk)
     plan = (("chunk", chunk), ("hb", hb), ("dtype", jnp.dtype(dtype)),
-            ("interpret", interpret))
-    o = _chunks(*_kernel_operands(q, k, v, g, beta, chunk, hb, n), plan)
+            ("interpret", interpret), ("channel", g.ndim == 4))
+    o = _chunks(_kernel_operands(q, k, v, g, beta, chunk, hb, n), plan)
     return o.transpose(0, 2, 1, 3)

@@ -211,18 +211,18 @@ def test_the_sub_blocks_hold_at_the_lower_bound_on_every_step(floor):
 
 
 @pytest.mark.parametrize("t,chunk,on_tpu,want", [
-    (8192, 64, True, "chunked"),      # the cell's: no kernel for this form
+    (8192, 64, True, ("kernel", 4)),  # the cell's: the channel pair
     (8192, 64, False, "chunked"), (16, 64, True, "sequential"),
     (100, 64, False, "sequential"), (96, 24, True, "sequential")],
     indirect=["on_tpu"])
 def test_the_route_of_a_decay_a_channel(t, chunk, on_tpu, want):
     """`delta_route` answers for the channel form from the call's shapes:
-    whole chunks of whole sub-blocks are chunked, anything else
-    sequential, never the decay-a-head kernels — whose answer for the
-    same shapes is unchanged."""
+    whole chunks of whole sub-blocks are chunked — on one TPU device the
+    channel pair's, by the plan the decay-a-head pair has at the same
+    shapes — anything else sequential."""
     assert dr.delta_route(t, chunk, 16, 128, 128, channel_decay=True) \
         == want
-    if want == "chunked" and on_tpu:
+    if want != "sequential" and on_tpu:
         assert dr.delta_route(t, chunk, 16, 128, 128) == ("kernel", 4)
 
 

@@ -159,6 +159,11 @@ TABLE = {
          ("chunked", ("kernel", 5), ("kernel", 5), "chunked", "chunked")),
         ("ragged", (8200, 64, 15, 96, 192), ("sequential",) * 5),
         ("nano", (64, 16, 3, 8, 24), ("chunked",) * 5),
+        # a sixth entry: the decay is a key CHANNEL's
+        ("ling3_0_flash", (8192, 64, 16, 128, 128, True),
+         ("chunked", ("kernel", 4), ("kernel", 4), "chunked", "chunked")),
+        ("no_whole_sub_blocks", (8184, 24, 16, 128, 128, True),
+         ("sequential",) * 5),
     ],
     # (lanes of the stream, tokens, hidden size)
     "hc_route": [
@@ -203,9 +208,9 @@ def _ask(predicate, args, mesh):
     module = {"scan_route": ssd, "rope_route": rope, "hc_route": hc_mix,
               "delta_route": delta_rule, "gmm_route": gm,
               "experts_route": gm}[predicate]
-    if predicate == "rope_route":  # the mesh sits before the rotated part
-        return rope.rope_route(*args[:2], mesh, *args[2:])
-    return getattr(module, predicate)(*args, mesh)
+    # the mesh sits before the rotated part, and before the decay's form
+    at = {"rope_route": 2, "delta_route": 5}.get(predicate, len(args))
+    return getattr(module, predicate)(*args[:at], mesh, *args[at:])
 
 
 def _inside_a_shard_map(mesh, ask, over, **kw):

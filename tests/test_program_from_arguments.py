@@ -504,17 +504,22 @@ def test_the_scans_route_is_its_shapes_and_the_backend(on_tpu, shape, route):
     assert ssd.scan_route(*shape) == route
 
 
+def _grad_jaxpr_of(mixer, tokens):
+    """The jaxpr of a mixer's loss gradient on two rows of `tokens`
+    tokens, 64 wide, bfloat16."""
+    u = jax.ShapeDtypeStruct((2, tokens, 64), jnp.bfloat16)
+    params = jax.eval_shape(lambda: mixer.init(
+        jax.random.PRNGKey(0), jnp.zeros(u.shape, u.dtype))["params"])
+    return jax.make_jaxpr(jax.grad(lambda p, u: mixer.apply(
+        {"params": p}, u).astype(jnp.float32).sum()))(params, u)
+
+
 def _mixer_grad_jaxpr(mesh):
     from dlrover_wuqiong_tpu.models.mamba2 import Mamba2Config, Mamba2Mixer
 
     cfg = Mamba2Config(hidden_size=64, num_heads=8, head_dim=64, n_groups=2,
                        state_size=128, chunk_size=128, mesh=mesh)
-    mixer = Mamba2Mixer(cfg)
-    u = jax.ShapeDtypeStruct((2, 512, 64), jnp.bfloat16)
-    params = jax.eval_shape(lambda: mixer.init(
-        jax.random.PRNGKey(0), jnp.zeros(u.shape, u.dtype))["params"])
-    return jax.make_jaxpr(jax.grad(lambda p, u: mixer.apply(
-        {"params": p}, u).astype(jnp.float32).sum()))(params, u)
+    return _grad_jaxpr_of(Mamba2Mixer(cfg), 512)
 
 
 def test_a_mixer_on_one_tpu_device_scans_in_the_kernels(on_tpu):
@@ -566,15 +571,28 @@ def _two_devices():
     (True, (8192, 8, 15, 96, 192), None, "chunked"),     # chunk off a tile
     (True, (8192, 64, 15, 320, 192), None, "chunked"),   # keys too wide
     (True, (8192, 2048, 15, 256, 256), None, "chunked"),  # tiles over VMEM
+    # ling3_0_flash.steady: a decay a key CHANNEL (the shape's sixth
+    # entry), sixteen heads held, keys and values of 128, one device
+    (True, (8192, 64, 16, 128, 128, True), None, ("kernel", 4)),
+    (True, (8192, 64, 16, 128, 128, True), _two_devices, "chunked"),
+    (False, (8192, 64, 16, 128, 128, True), None, "chunked"),  # every CPU run
+    (True, (8184, 24, 16, 128, 128, True), None, "sequential"),  # no whole
+    (False, (8184, 24, 16, 128, 128, True), None, "sequential"),  # sub-blocks
+    (True, (8184, 24, 16, 128, 128), None, "chunked"),   # (a head's: a tile)
+    (True, (8192, 64, 32, 128, 128, True), None, ("kernel", 4)),  # all heads
+    (True, (64, 16, 4, 16, 16, True), None, "chunked"),  # the nano widths
+    (True, (8192, 256, 16, 256, 256, True), None, "chunked"),  # over VMEM
+    (True, (8192, 256, 16, 256, 256), None, ("kernel", 4)),  # (a head's fit)
 ], indirect=["on_tpu"])
 def test_the_delta_rules_route_is_its_shapes_the_backend_and_the_mesh(
         on_tpu, shape, mesh, route):
-    """`ops/delta_rule.delta_route(t, chunk, heads, dk, dv, mesh)`: the
-    static counter of which calls run `dwt_gdr_fwd` / `dwt_gdr_bwd`, and
-    with how many heads a grid step."""
+    """`ops/delta_rule.delta_route(t, chunk, heads, dk, dv, mesh,
+    channel_decay)`: the static counter of which calls run `dwt_gdr_fwd` /
+    `dwt_gdr_bwd` — a decay a channel's `dwt_kda_fwd` / `dwt_kda_bwd` —
+    and with how many heads a grid step."""
     from dlrover_wuqiong_tpu.ops import delta_rule as dr
 
-    assert dr.delta_route(*shape, mesh and mesh()) == route
+    assert dr.delta_route(*shape[:5], mesh and mesh(), *shape[5:]) == route
 
 
 def _delta_mixer_grad_jaxpr(mesh):
@@ -583,12 +601,7 @@ def _delta_mixer_grad_jaxpr(mesh):
 
     cfg = GatedDeltaConfig(hidden_size=64, num_heads=6, key_dim=32,
                            value_dim=64, chunk_size=16, mesh=mesh)
-    mixer = GatedDeltaMixer(cfg)
-    u = jax.ShapeDtypeStruct((2, 256, 64), jnp.bfloat16)
-    params = jax.eval_shape(lambda: mixer.init(
-        jax.random.PRNGKey(0), jnp.zeros(u.shape, u.dtype))["params"])
-    return jax.make_jaxpr(jax.grad(lambda p, u: mixer.apply(
-        {"params": p}, u).astype(jnp.float32).sum()))(params, u)
+    return _grad_jaxpr_of(GatedDeltaMixer(cfg), 256)
 
 
 def test_a_delta_mixer_takes_the_kernels_on_one_tpu_device_only(
@@ -607,6 +620,33 @@ def test_a_delta_mixer_takes_the_kernels_on_one_tpu_device_only(
     assert sorted(_pallas_calls(_delta_mixer_grad_jaxpr(None).jaxpr)) == [
         ("dwt_gdr_bwd", (2, 2, 2)), ("dwt_gdr_fwd", (2, 2, 2))]
     assert _pallas_calls(_delta_mixer_grad_jaxpr(two).jaxpr) == []
+
+
+def _kda_mixer_grad_jaxpr(mesh):
+    from dlrover_wuqiong_tpu.models.kda import KDAConfig, KDAMixer
+
+    cfg = KDAConfig(hidden_size=64, num_heads=8, key_dim=32, value_dim=32,
+                    chunk_size=16, mesh=mesh)
+    return _grad_jaxpr_of(KDAMixer(cfg), 256)
+
+
+def test_a_kda_mixer_takes_the_channel_pair_on_one_tpu_device_only(
+        monkeypatch):
+    """As the gated-delta mixer above, the decay a key channel: one
+    `dwt_kda_fwd` and one `dwt_kda_bwd` a mixer over (2 batch rows, 2
+    blocks of four of eight heads, 2 steps of eight chunks of 16), none
+    of the scalar pair; on a mesh of two devices (`KDAConfig.mesh`,
+    handed on by `BailingHybridConfig.linear_config`) and off the TPU
+    none."""
+    from dlrover_wuqiong_tpu.models.bailing_hybrid import BailingHybridConfig
+
+    two = _two_devices()
+    assert BailingHybridConfig.nano(mesh=two).linear_config().mesh is two
+    assert _pallas_calls(_kda_mixer_grad_jaxpr(None).jaxpr) == []
+    monkeypatch.setattr(mosaic, "on_tpu", lambda: True)
+    assert sorted(_pallas_calls(_kda_mixer_grad_jaxpr(None).jaxpr)) == [
+        ("dwt_kda_bwd", (2, 2, 2)), ("dwt_kda_fwd", (2, 2, 2))]
+    assert _pallas_calls(_kda_mixer_grad_jaxpr(two).jaxpr) == []
 
 
 @pytest.mark.parametrize("dtype,sha", [

@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
-model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|sums|hc] ...  (no args = step/attn/head/model/opt).  One JSON line
+model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|delta_kda|sums|hc] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -42,10 +42,11 @@ three share cells' shapes: the gather of all T*k rows through the sort's
 inverse against the kernel route's loop over the held rows in assignment
 order at three chunks a turn, and counts the entries that differ.
 `delta` reads the same way the gated delta rule at the Olmo hybrid
-cell's shape, forward and forward + backward: the chunked `jax.numpy`
-form against `dwt_gdr_fwd` / `dwt_gdr_bwd` (`ops/delta_rule.py`) at 1 /
-3 / 5 / 15 heads and one or two chunks a grid step, with each one's
-distance from the chunked form.
+cell's shape (a decay a head) and at Ling's (a decay a key channel),
+forward and forward + backward: the chunked `jax.numpy` form against the
+form's pair, `dwt_gdr_*` or `dwt_kda_*` (`ops/delta_rule.py`), at several
+heads and one or two chunks a grid step, with each one's distance from
+the chunked form; `delta_kda` is Ling's half alone.
 `hc` reads the same way one sublayer's hyper-connection at Xing's
 stream (four lanes of 8,192 x 3,584): the plain route's fusions against
 `dwt_hc_pre` / `_post` / `_post_bwd` / `_pre_bwd` (`ops/hc_mix.py`) at
@@ -950,25 +951,49 @@ def probe_rope():
                                "device_ops_ms": _device_ops_ms(f, *args)})
 
 
-def probe_delta(plans=((5, 1), (1, 2), (3, 2), (5, 2), (15, 2)),
-                shape=(1, 8192, 15, 96, 192, 64), interpret=False):
-    """The gated delta rule at `olmo_hybrid_7b.steady`'s shape (1 x 8192,
-    fifteen heads, keys of 96, values of 192, chunk 64, bfloat16
-    products), forward and forward + backward: the chunked `jax.numpy`
-    form against `dwt_gdr_fwd` / `dwt_gdr_bwd` at several (heads, chunks)
-    a grid step, each with the kernel route's largest relative distance
-    from the chunked form (PERF.md section 6, PR 48)."""
+# the delta rule's two forms by the decay's rank: ((heads, chunks) a grid
+# step to try, (b, T, H, dk, dv, chunk)) at the cell's shape
+DELTA_FORMS = {
+    "head": (((5, 1), (1, 2), (3, 2), (5, 2), (15, 2)),
+             (1, 8192, 15, 96, 192, 64)),          # olmo_hybrid_7b.steady
+    "channel": (((4, 1), (1, 2), (2, 2), (4, 2)),
+                (1, 8192, 16, 128, 128, 64)),      # ling3_0_flash.steady
+}
+
+
+def probe_delta(forms=tuple(DELTA_FORMS), interpret=False, shapes=None):
+    """The gated delta rule at its two cells' shapes (1 x 8192, chunk 64,
+    bfloat16 products; `olmo_hybrid_7b.steady`: a decay a HEAD, fifteen
+    heads, keys of 96, values of 192; `ling3_0_flash.steady`: a decay a
+    key CHANNEL in (-5, 0), sixteen heads of 128 | 128), forward and
+    forward + backward: the chunked `jax.numpy` form against the form's
+    kernel pair (`dwt_gdr_*` / `dwt_kda_*`) at several (heads, chunks) a
+    grid step, each with the kernel route's largest relative distance
+    from the chunked form (PERF.md section 6, PR 48 and PR 58)."""
+    for form in forms:
+        plans, shape = DELTA_FORMS[form]
+        _probe_delta_form(form, plans, (shapes or {}).get(form, shape),
+                          interpret)
+
+
+def _probe_delta_form(form, plans, shape, interpret):
     from dlrover_wuqiong_tpu.ops import delta_rule as dr
 
     b, t, h, dk, dv, chunk = shape
+    channel = form == "channel"
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     q = jax.random.normal(ks[0], (b, t, h, dk))
     k = jax.random.normal(ks[1], (b, t, h, dk))
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / dk ** 0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     v = jax.random.normal(ks[2], (b, t, h, dv), jnp.bfloat16)
-    g = -0.2 * jax.nn.softplus(jax.random.normal(ks[3], (b, t, h)))
-    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
+    if channel:  # the safe gate's range, most channels far from the bound
+        g = -5.0 * jax.nn.sigmoid(
+            jax.random.normal(ks[3], (b, t, h, dk)) - 2.0)
+    else:
+        g = -0.2 * jax.nn.softplus(jax.random.normal(ks[3], (b, t, h)))
+    beta = (1.0 if channel else 2.0) * jax.nn.sigmoid(
+        jax.random.normal(ks[4], (b, t, h)))
     d_out = jax.random.normal(ks[5], (b, t, h, dv))
     args = (q, k, v, g, beta)
 
@@ -976,20 +1001,22 @@ def probe_delta(plans=((5, 1), (1, 2), (3, 2), (5, 2), (15, 2)),
         return jax.jit(lambda *a: (fn(*a), *jax.vjp(fn, *a)[1](d_out)))
 
     def chunked(*a):
-        return dr._chunked(*a, chunk, jnp.bfloat16)
+        return (dr._chunked_channel if channel else dr._chunked)(
+            *a, chunk, jnp.bfloat16)
 
     want = both(chunked)(*args)
+    name = "dwt_kda" if channel else "dwt_gdr"
     cases = [("chunked", chunked, None)] + [
-        ("dwt_gdr", functools.partial(
+        (name, functools.partial(
             lambda plan, *a: dr._chunk_kernels(
                 *a, chunk, jnp.bfloat16, plan[0], interpret, plan[1]),
             plan), plan) for plan in plans]
-    for name, fn, plan in cases:
+    for what, fn, plan in cases:
         off = [float(jnp.abs(x.astype(jnp.float32) - y.astype(jnp.float32)
                              ).max() / jnp.abs(y.astype(jnp.float32)).max())
                for x, y in zip(both(fn)(*args), want)]
-        for what, f in ((name, jax.jit(fn)), (name + "_fwd_bwd", both(fn))):
-            _emit_raw({"probe": "delta", "what": what,
+        for label, f in ((what, jax.jit(fn)), (what + "_fwd_bwd", both(fn))):
+            _emit_raw({"probe": "delta", "form": form, "what": label,
                        "heads_and_chunks_a_step": plan,
                        "off_o_dq_dk_dv_dg_dbeta": [round(x, 6) for x in off],
                        "device_ops_ms": _device_ops_ms(f, *args, top=6)})
@@ -1065,7 +1092,9 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "step": probe_step, "dispatch": probe_dispatch,
        "rpc": probe_rpc, "gmm": probe_gmm, "rows_map": probe_rows_map,
        "rope": probe_rope, "moe_numbers": probe_moe_numbers,
-       "delta": probe_delta, "sums": probe_sums, "hc": probe_hc}
+       "delta": probe_delta,
+       "delta_kda": functools.partial(probe_delta, forms=("channel",)),
+       "sums": probe_sums, "hc": probe_hc}
 
 
 def main(argv=None) -> int:
