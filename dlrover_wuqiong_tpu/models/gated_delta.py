@@ -37,7 +37,10 @@ kernels, the chunked `_chunked`, the sequential scan; a decay a key
 CHANNEL, g (b, T, H, dk), is `models/kda.py`'s mixer and runs
 `_chunked_channel` or the same sequential scan, never the kernels.
 
-The short convolution and the draw of `dt_bias` are `models/mamba2.py`'s.
+The short convolution and the draw of `dt_bias` are `models/mamba2.py`'s:
+`causal_conv_silu` runs `ops/short_conv.py`'s Pallas pair where
+`conv_route` lets a call's shape in — whole 128-lane tiles of channels,
+so not the Olmo hybrid's 1,440 and 2,880 — and its plain lines elsewhere.
 
 Parity: none — the reference's model zoo (atorch) is attention-only; the
 equations are arXiv:2412.06464's in the form of its public
@@ -144,7 +147,7 @@ class GatedDeltaMixer(nn.Module):
         bounds = (0, heads * dk, 2 * heads * dk, cfg.conv_dim)
         with jax.named_scope("conv"):
             filters = [kernel[:, lo:hi] for lo, hi in zip(bounds, bounds[1:])]
-        q, k, v = (causal_conv_silu(a_, f, None, cfg.dtype)
+        q, k, v = (causal_conv_silu(a_, f, None, cfg.dtype, cfg.mesh)
                    for a_, f in zip((q, k, v), filters))
 
         with jax.named_scope("delta"):

@@ -43,8 +43,11 @@ gate's sum and count.  They ride the step's metrics
 (`collect_delta_stats`, `collect_kda_stats`, through
 `make_lm_loss.with_stats`).
 
-The short convolution and the draw of `dt_bias` are `models/mamba2.py`'s,
-the L2 norms and the draw of `A_log` `models/gated_delta.py`'s.
+The short convolution and the draw of `dt_bias` are `models/mamba2.py`'s
+(`causal_conv_silu`: on one TPU device at whole lane tiles of channels —
+Ling's three calls of 2,048 a layer — `ops/short_conv.py`'s Pallas pair
+`dwt_conv_fwd` / `dwt_conv_bwd`, its plain lines elsewhere), the L2 norms
+and the draw of `A_log` `models/gated_delta.py`'s.
 
 Parity: none — the reference's model zoo (atorch) is attention-only; the
 equations are benchmark/reference_bailing_hybrid.py's.
@@ -150,7 +153,7 @@ class KDAMixer(nn.Module):
         bounds = (0, heads * dk, 2 * heads * dk, cfg.conv_dim)
         with jax.named_scope("conv"):
             filters = [kernel[:, lo:hi] for lo, hi in zip(bounds, bounds[1:])]
-        q, k, v = (causal_conv_silu(a_, f_, None, cfg.dtype)
+        q, k, v = (causal_conv_silu(a_, f_, None, cfg.dtype, cfg.mesh)
                    for a_, f_ in zip((q, k, v), filters))
 
         with jax.named_scope("delta"):

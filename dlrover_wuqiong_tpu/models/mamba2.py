@@ -10,11 +10,12 @@
 
 d_inner = heads x head_dim: the projections' widths are given, not
 derived from an expansion factor.  Scopes, under the module's own name:
-`in_proj`, `conv`, `ssd` (the step sizes, the decay rates and all of the
-scan: on one TPU device the `dwt_ssd_*` kernels' custom calls, forward,
-recomputed and backward, carry it), `gate_norm`, `out_proj`.  Parameter
-names are matched by `parallel/sharding.py` (the two projections as
-dense kernels, everything else replicated).
+`in_proj`, `conv` (on one TPU device the `dwt_conv_*` kernels' custom
+calls, `ops/short_conv.py`), `ssd` (the step sizes, the decay rates and
+all of the scan: on one TPU device the `dwt_ssd_*` kernels' custom
+calls, forward, recomputed and backward, carry it), `gate_norm`,
+`out_proj`.  Parameter names are matched by `parallel/sharding.py` (the
+two projections as dense kernels, everything else replicated).
 
 Which route the scan takes is `ops/ssd.scan_route`'s to say, from the
 call's shapes and where it runs (the backend and `Mamba2Config.mesh`).
@@ -34,6 +35,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops import short_conv
 from ..ops.ssd import ssd_scan
 
 
@@ -95,13 +97,26 @@ def _conv_init(width: int):
 
 
 @jax.named_scope("conv")
-def causal_conv_silu(x, kernel, bias, dtype):
+def causal_conv_silu(x, kernel, bias, dtype, mesh=None, source=None):
     """silu(causal depthwise convolution of x (b, T, channels) along T):
     `kernel` (taps, channels), one filter a channel, its LAST tap on the
-    current step; `bias` (channels,) or None.  The taps are shifted
-    products, which XLA fuses into one pass.  The short convolution of
-    this mixer and of `models/gated_delta.py`'s."""
+    current step; `bias` (channels,) or None.  The short convolution of
+    this mixer, of `models/gated_delta.py`'s and of `models/kda.py`'s.
+
+    Which route it takes is `ops/short_conv.conv_route`'s to say, from
+    the call's shapes and where it runs (the backend and `mesh`, the
+    model config's): the Pallas pair `dwt_conv_fwd` / `dwt_conv_bwd`
+    (one read and one write of the rows, float32 inside, rounded once),
+    or these lines — shifted products in `dtype`, which the compiler
+    runs as four fusions and 20 to 25 passes over the rows a layer
+    (PERF.md section 6, PR 59) — on every CPU run, a mesh of several
+    devices, channels that are no whole lane tiles, and as the kernels'
+    oracle.  `source` (rows, lane), for an x that is the slice
+    `rows[..., lane:lane + channels]`, lets the kernels read it where it
+    lies; the plain lines never look at it."""
     k, t = kernel.shape[0], x.shape[1]
+    if short_conv.conv_route(t, x.shape[2], k, dtype, mesh) == "kernel":
+        return short_conv.conv_silu_rows(x, kernel, bias, dtype, source)
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     conv = sum(padded[:, j:j + t] * kernel[j].astype(dtype)
                for j in range(k))
@@ -124,7 +139,8 @@ class Mamba2Mixer(nn.Module):
                             (cfg.conv_kernel, cfg.conv_dim))
         bias = self.param("conv_bias", _conv_init(cfg.conv_kernel),
                           (cfg.conv_dim,))
-        xbc = causal_conv_silu(xbc, kernel, bias, cfg.dtype)
+        xbc = causal_conv_silu(xbc, kernel, bias, cfg.dtype, cfg.mesh,
+                               source=(proj, di))
         x, b_mat, c_mat = jnp.split(xbc, [di, di + gn], axis=-1)
 
         dt_bias = self.param("dt_bias", _dt_bias_init(cfg),

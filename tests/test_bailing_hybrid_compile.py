@@ -93,14 +93,16 @@ def _live_gb(step) -> float:
             + m.output_size_in_bytes - m.alias_size_in_bytes) / 1e9
 
 
-LIVE_GB = 11.93  # the step's described reading with the channel pair
+# the step's described reading with the channel pair, and since PR 59
+# the convolutions' (11.93 before it)
+LIVE_GB = 11.82
 
 
 @pytest.mark.slow
 def test_ling_step_fits_one_chip_by_the_rule_and_fills_it(ling_step):
     """State + temporaries under 90% of the chip's 16 GB at rung (b), one
     sequence of 8,192 tokens (PR 26's rule), of which 7.79 GB is donated
-    state: 11.93 GB live with the channel pair, held here; the cell's file
+    state: 11.82 GB live with the two pairs, held here; the cell's file
     keeps the chunked form's readings (12.92 at (b), and (a), one sequence
     of 16,384, over at 15.71), an upper bound on this one.  Far over the
     25% a cell has to fill."""
@@ -165,6 +167,39 @@ def test_ling_step_runs_the_channel_decay_by_the_kernel_pair(ling_step):
             if dims[-2:] == [dk, dk]:          # the states, by chunk
                 assert dims == [1, heads, tokens // chunk, dk, dk], line
     assert seen >= len(under) - 5
+
+
+@pytest.mark.slow
+def test_ling_step_convolves_in_its_kernels(ling_step, on_tpu):
+    """Every KDA mixer's three short convolutions (q, k and v, 2,048
+    channels each at sixteen heads of 128) run `ops/short_conv.py`'s
+    pair: eighteen `dwt_conv_fwd` in the forward pass, eighteen in its
+    recomputation, eighteen `dwt_conv_bwd` in the backward pass, each
+    under `linear_attention/conv`, which the cell's scopes file puts in
+    `step.linattn_scan_ms`."""
+    import json
+    import os
+
+    from benchmark import cells, program
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+    from dlrover_wuqiong_tpu.ops import short_conv
+
+    cell, _, step = ling_step
+    assert short_conv.conv_route(cell["seq_len"], 16 * 128, 4,
+                                 jnp.bfloat16) == "kernel"
+    table = scope_table(step.as_text())
+    calls = {n: s for n, s in table.items() if n.startswith("dwt_conv_")}
+    assert collections.Counter(
+        (n.split(".")[0], s.split("/")[0]) for n, s in calls.items()) == {
+            ("dwt_conv_fwd", "fwd"): 18, ("dwt_conv_fwd", "recompute"): 18,
+            ("dwt_conv_bwd", "bwd"): 18}
+    with open(os.path.join(cells.HERE, "models", cell["config"][
+            "model_class"] + ".scopes.json")) as f:
+        rules = json.load(f)
+    for name, scope in calls.items():
+        assert "/linear_attention/conv/" in f"/{scope}/", (name, scope)
+        assert program.part_of(scope, rules["linattn_parts"]) \
+            == "linattn_scan"
 
 
 @pytest.mark.slow

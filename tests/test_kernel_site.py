@@ -20,6 +20,8 @@ constant (`_SITES`): a predicate whose answer moves fails here, and S9 /
 M1 (ROADMAP), which flip a constant, edit the column they mean to.
 PR 56 gave `rope_route` the rotated width (Laguna's full layers turn 64
 of a head's 128 lanes): the rows of the calls the program now makes.
+PR 59 added `conv_route` (`ops/short_conv.py`), a predicate the parent
+did not have: its rows are what the mixers' convolutions are handed.
 """
 
 import types
@@ -34,7 +36,7 @@ from jax.sharding import PartitionSpec as P
 from dlrover_wuqiong_tpu.models import attention
 from dlrover_wuqiong_tpu.ops import delta_rule, flash_attention as fa
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
-from dlrover_wuqiong_tpu.ops import hc_mix, mosaic, rope, ssd
+from dlrover_wuqiong_tpu.ops import hc_mix, mosaic, rope, short_conv, ssd
 
 SITES = ("off", "device", "one", "mesh", "manual")
 
@@ -170,6 +172,17 @@ TABLE = {
         ("xing4_0", (4, 8192, 3584), _OR_A_SHARD_MAP),
         ("no_whole_slabs", (4, 8192, 200), _NOWHERE),
     ],
+    # (sequence, channels, taps, the rows' dtype)
+    "conv_route": [
+        ("nemotron", (8192, 6144, 4, jnp.bfloat16), _ONE_DEVICE),
+        ("granite", (8192, 4352, 4, jnp.bfloat16), _ONE_DEVICE),
+        ("ling3_0_flash_q_k_and_v", (8192, 2048, 4, jnp.bfloat16),
+         _ONE_DEVICE),
+        ("olmo_hybrid_q_and_k", (8192, 1440, 4, jnp.bfloat16), _NOWHERE),
+        ("olmo_hybrid_v", (8192, 2880, 4, jnp.bfloat16), _NOWHERE),
+        ("no_whole_row_block", (8200, 6144, 4, jnp.bfloat16), _NOWHERE),
+        ("nano", (64, 320, 4, jnp.float32), _NOWHERE),
+    ],
     # (lhs (T*k, c), rhs (held, c, n), experts the router names)
     "gmm_route": [
         ("nemotron_in", ((98304, 2688), (8, 2688, 1856), 128), _ONE_DEVICE),
@@ -207,7 +220,7 @@ def _ask(predicate, args, mesh):
             types.SimpleNamespace(mesh=mesh, attn_impl=impl), *shape)
     module = {"scan_route": ssd, "rope_route": rope, "hc_route": hc_mix,
               "delta_route": delta_rule, "gmm_route": gm,
-              "experts_route": gm}[predicate]
+              "experts_route": gm, "conv_route": short_conv}[predicate]
     # the mesh sits before the rotated part, and before the decay's form
     at = {"rope_route": 2, "delta_route": 5}.get(predicate, len(args))
     return getattr(module, predicate)(*args[:at], mesh, *args[at:])
@@ -275,9 +288,10 @@ def test_every_kernel_module_states_its_sites_once():
     """`_SITES` beside the VMEM request: a subset of `kernel_site`'s
     answers, "off" in none."""
     stated = {m.__name__.rsplit(".", 1)[1]: set(m._SITES)
-              for m in (ssd, rope, hc_mix, delta_rule, gm)}
+              for m in (ssd, rope, hc_mix, delta_rule, gm, short_conv)}
     stated["flash_attention (direct)"] = set(fa._DIRECT_SITES)
     assert stated == {
         "ssd": {"device"}, "grouped_matmul": {"device"},
         "delta_rule": {"device"}, "flash_attention (direct)": {"device"},
+        "short_conv": {"device"},
         "rope": {"device", "manual"}, "hc_mix": {"device", "manual"}}

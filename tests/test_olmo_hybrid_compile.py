@@ -108,6 +108,32 @@ def test_olmo_step_holds_its_scopes_and_no_op_that_holds_others(olmo_step):
         + ["dwt_gdr_bwd"] * 3 + ["dwt_gdr_fwd"] * 6
 
 
+def test_olmo_step_keeps_the_plain_convolution(olmo_step, on_tpu):
+    """The mixers' q, k and v are 1,440, 1,440 and 2,880 channels wide at
+    fifteen heads: 11.25 and 22.5 lane tiles, so `conv_route` says
+    "plain" on the chip too, the step holds no `dwt_conv_*` custom call,
+    and `linear_attention/conv` is the compiler's fusions in all three
+    phases (3.9 of `step.linattn_scan_ms`' 40.65 ms: ROADMAP S15)."""
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import scope_table
+    from dlrover_wuqiong_tpu.ops import short_conv
+
+    cell, model, step = olmo_step
+    cfg = model.config.linear_config()
+    widths = (cfg.num_heads * cfg.key_dim, cfg.num_heads * cfg.value_dim)
+    assert widths == (1440, 2880)
+    for channels in widths:
+        assert short_conv.conv_route(cell["seq_len"], channels,
+                                     cfg.conv_kernel, cfg.dtype) == "plain"
+    assert short_conv.conv_route(cell["seq_len"], 1536, cfg.conv_kernel,
+                                 cfg.dtype) == "kernel"
+    text = step.as_text()
+    assert "dwt_conv" not in text
+    scopes = set(scope_table(text).values())
+    for phase in ("fwd", "recompute", "bwd"):
+        assert any(s.startswith(phase) and "linear_attention/conv" in s
+                   for s in scopes), phase
+
+
 def test_olmo_step_runs_the_delta_rule_in_its_kernels(olmo_step):
     """Every `dwt_gdr_*` custom call is owned by `linear_attention/delta`
     — two forward kernels and one backward a layer, in the forward, the

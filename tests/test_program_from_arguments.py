@@ -524,22 +524,28 @@ def _mixer_grad_jaxpr(mesh):
 
 def test_a_mixer_on_one_tpu_device_scans_in_the_kernels(on_tpu):
     """One forward and one backward kernel a mixer, over (batch rows,
-    chunks, blocks of heads): 2 x 4 x 2 at two groups of four heads."""
-    from dlrover_wuqiong_tpu.ops import ssd
+    chunks, blocks of heads): 2 x 4 x 2 at two groups of four heads;
+    and since PR 59 the short convolution's pair in front of them, over
+    (blocks of 256 of the 1,024 channels, batch rows, blocks of rows):
+    4 x 2 x 1 at one row block of 512."""
+    from dlrover_wuqiong_tpu.ops import short_conv, ssd
 
     assert ssd.scan_route(8, 64, 2, 128, 128, 512) == ("kernel", 4)
+    assert short_conv.conv_route(512, 1024, 4, jnp.bfloat16) == "kernel"
     assert sorted(_pallas_calls(_mixer_grad_jaxpr(None).jaxpr)) == [
+        ("dwt_conv_bwd", (4, 2, 1)), ("dwt_conv_fwd", (4, 2, 1)),
         ("dwt_ssd_bwd", (2, 4, 2)), ("dwt_ssd_fwd", (2, 4, 2))]
 
 
 def test_a_mixer_on_a_mesh_of_several_devices_keeps_the_plain_scan(on_tpu):
     """A Mosaic kernel cannot be partitioned by GSPMD (PR 22): where the
     model config carries a mesh of more than one device `scan_route`,
-    which the mixer hands it, says "plain" whatever the shapes; a mesh
-    of one device is one device."""
+    which the mixer hands it, says "plain" whatever the shapes — and so
+    does the convolution's `conv_route`; a mesh of one device is one
+    device (the scan's pair and the convolution's)."""
     from jax.sharding import Mesh
 
-    from dlrover_wuqiong_tpu.ops import ssd
+    from dlrover_wuqiong_tpu.ops import short_conv, ssd
 
     two = Mesh(np.array(jax.devices()[:2]), ("fsdp",))
     one = Mesh(np.array(jax.devices()[:1]), ("fsdp",))
@@ -547,8 +553,10 @@ def test_a_mixer_on_a_mesh_of_several_devices_keeps_the_plain_scan(on_tpu):
     assert ssd.scan_route(*shape, two) == ("plain", 0)
     assert ssd.scan_route(*shape, one) == ("kernel", 4)
     assert ssd.scan_route(*shape) == ("kernel", 4)
+    assert short_conv.conv_route(512, 1024, 4, jnp.bfloat16, two) == "plain"
+    assert short_conv.conv_route(512, 1024, 4, jnp.bfloat16, one) == "kernel"
     assert _pallas_calls(_mixer_grad_jaxpr(two).jaxpr) == []
-    assert len(_pallas_calls(_mixer_grad_jaxpr(one).jaxpr)) == 2
+    assert len(_pallas_calls(_mixer_grad_jaxpr(one).jaxpr)) == 4
 
 
 def _two_devices():

@@ -30,7 +30,7 @@ from dlrover_wuqiong_tpu.ops import flash_attention as fa
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
 from dlrover_wuqiong_tpu.ops import mosaic
 from dlrover_wuqiong_tpu.ops import quantization as qz
-from dlrover_wuqiong_tpu.ops import ssd
+from dlrover_wuqiong_tpu.ops import short_conv, ssd
 
 
 @pytest.fixture(scope="module")
@@ -843,12 +843,14 @@ def granite_step(request):
 
 def test_granite_step_fits_one_chip_by_the_rule_and_fills_it(granite_step):
     """State + temporaries under 90% of the chip's 16 GB (PR 26's rule)
-    at the shipped sizes: 12.11 GB, of which 9.27 GB is donated state —
-    0.36 GB under the reading the configuration file records for its
+    at the shipped sizes: 11.92 GB, of which 9.27 GB is donated state —
+    0.55 GB under the reading the configuration file records for its
     memory rung (a), 12.47 GB, taken on the plain scan (PR 33): the
     kernels keep a layer's 537 MB decay tensor out of HBM and save 67 MB
-    of entering states.  A second sequence doubles the 2.8 GB of
-    temporaries: over."""
+    of entering states, and since PR 59 the convolution's pair reads
+    xBC where it lies in the projection's output and holds no padded
+    copy of it (12.11 GB before it).  A second sequence doubles the
+    2.7 GB of temporaries: over."""
     cell, model, step = granite_step
     assert model.config.num_params() == 772_160_448
     assert (cell["global_batch"], cell["seq_len"],
@@ -858,9 +860,9 @@ def test_granite_step_fits_one_chip_by_the_rule_and_fills_it(granite_step):
     live = m.argument_size_in_bytes + m.temp_size_in_bytes \
         + m.output_size_in_bytes - m.alias_size_in_bytes
     rung = cell["config"]["train"]["memory_rung"]
-    assert live / 1e9 == pytest.approx(12.11, abs=0.05)
+    assert live / 1e9 == pytest.approx(11.92, abs=0.05)
     assert live / 1e9 < rung["live_GB"]["a: 1 x 8192, chunk 256"] - 0.3
-    assert 0.25 * 16 * 2 ** 30 < 0.75 * 16e9 < live < \
+    assert 0.25 * 16 * 2 ** 30 < 0.70 * 16e9 < live < \
         rung["limit_GB"] * 1e9 == 0.90 * 16e9, live / 1e9
     assert live + m.temp_size_in_bytes > rung["limit_GB"] * 1e9
     assert m.alias_size_in_bytes >= 12 * model.config.num_params()
@@ -1244,6 +1246,90 @@ def test_scan_kernels_compile_at_the_cells_shapes(topo, b, t, h, p, g, n,
                              argnums=tuple(range(6))), *shapes)
     assert "dwt_ssd_fwd" in text and "dwt_ssd_bwd" in text
     assert _square_tiles(text, "", chunk) == []
+
+
+# ------------------- the short convolution's pair in the hybrids' steps
+
+@pytest.mark.parametrize("fixture,layers", [
+    ("granite_step", 9),     # 4,352 channels: 17 pairs of lane tiles
+    ("nemotron_step", 4),    # 6,144 channels, two sequences
+])
+def test_hybrid_step_convolves_in_its_kernels(request, on_tpu, fixture,
+                                              layers):
+    """The static counters of the convolution's route.  Every Mamba-2
+    layer's short convolution runs the pair: three custom calls a layer
+    — `dwt_conv_fwd` in the forward pass, again in its recomputation,
+    `dwt_conv_bwd` in the backward pass — 27 at granite's nine layers,
+    12 at the other hybrid's four, each under `mamba/conv` in all three
+    phases, so `step.ssm_scan_ms` reads the same work through the
+    untouched scopes files.  Nothing else under that scope is as large
+    as the rows: the shifted products' fusions and the padded copy are
+    gone, what stays is the (8, channels) coefficients."""
+    import json
+
+    from benchmark import cells, program
+    from dlrover_wuqiong_tpu.analysis.hlo_scopes import (
+        read_instruction, scope_table)
+
+    cell, model, step = request.getfixturevalue(fixture)
+    text = step.as_text()
+    cfg = model.config.mamba_config()
+    rows = cell["global_batch"] * cell["seq_len"] * cfg.conv_dim
+    assert short_conv.conv_route(cell["seq_len"], cfg.conv_dim,
+                                 cfg.conv_kernel, cfg.dtype) == "kernel"
+    table = scope_table(text)
+    calls = {n: s for n, s in table.items() if n.startswith("dwt_conv_")}
+    by_kernel = collections.Counter(
+        (n.split(".")[0], s.split("/")[0]) for n, s in calls.items())
+    assert by_kernel == {("dwt_conv_fwd", "fwd"): layers,
+                         ("dwt_conv_fwd", "recompute"): layers,
+                         ("dwt_conv_bwd", "bwd"): layers}
+    assert len(calls) == 3 * layers == {9: 27, 4: 12}[layers]
+    assert len(re.findall(r"custom-call\([^\n]*dwt_conv_", text)) \
+        == len(calls)
+    with open(os.path.join(cells.HERE, "models", cell["config"][
+            "model_class"] + ".scopes.json")) as f:
+        rules = json.load(f)
+    for name, scope in calls.items():
+        assert "/mamba/conv/" in f"/{scope}/", (name, scope)
+        assert program.part_of(scope, rules["ssm_parts"]) == "ssm_scan"
+        assert program.part_of(scope, rules["parts"]) == "ssm"
+    under = {n for n, s in table.items()
+             if "mamba/conv" in s and n not in calls}
+    for line in text.splitlines():
+        inst = read_instruction(line)
+        if inst is None or inst["name"] not in under \
+                or "get-tuple-element(" in line:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]+)\]", inst["shape"]):
+            assert math.prod(int(d) for d in dims.split(",")) \
+                < rows // 100, line.strip()[:200]
+
+
+@pytest.mark.parametrize("b,t,channels,bias,dtype", [
+    (2, 8192, 6144, True, jnp.bfloat16),    # nemotron3_nano_30b_a3b.steady
+    (1, 8192, 4352, True, jnp.bfloat16),    # granite4_h_micro.steady
+    (1, 8192, 2048, False, jnp.bfloat16),   # ling3_0_flash.steady: q, k, v
+    (1, 1536, 384, True, jnp.float32),      # one lane tile a step, float32
+])
+def test_conv_kernels_compile_at_the_cells_shapes(topo, b, t, channels,
+                                                  bias, dtype):
+    """`dwt_conv_fwd` and `dwt_conv_bwd` alone, a second or two a shape:
+    what the interpret-mode tests (tests/test_short_conv_kernel.py)
+    cannot see — the sublane rolls, the halo views at a packed tile's
+    grain, the blocks inside VMEM."""
+    one = SingleDeviceSharding(topo.devices[0])
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one) for s, d in (
+        ((b, t, channels), dtype), ((4, channels), jnp.float32),
+        ((channels,), jnp.float32))]
+
+    def conv(x, kernel, bias_):
+        return short_conv._conv_kernels(x, kernel, bias_ if bias else None,
+                                        dtype)
+
+    assert "dwt_conv_fwd" in _compile(conv, *shapes)
+    text = _compile(lambda x, *a: jax.vjp(conv, x, *a)[1](x), *shapes)
+    assert "dwt_conv_bwd" in text and "dwt_conv_fwd" not in text
 
 
 # ----------------------- the grouped products of a share of the experts

@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
-model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|delta_kda|sums|hc] ...  (no args = step/attn/head/model/opt).  One JSON line
+model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|delta_kda|sums|hc|conv] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -51,6 +51,14 @@ the chunked form; `delta_kda` is Ling's half alone.
 stream (four lanes of 8,192 x 3,584): the plain route's fusions against
 `dwt_hc_pre` / `_post` / `_post_bwd` / `_pre_bwd` (`ops/hc_mix.py`) at
 three token tiles, with each kernel's GB/s over the bytes its pass moves.
+`conv` reads the same way one layer's short convolution + silu at the
+four cells' shapes that call it (the two Mamba-2 hybrids', one of Ling's
+three calls, Olmo's two widths), forward and forward + gradient:
+`models/mamba2.causal_conv_silu`'s plain lines as the compiler fuses
+them against `dwt_conv_fwd` / `dwt_conv_bwd` (`ops/short_conv.py`) at
+several rows a grid step, in ms and in GB/s over the passes the least
+implementation moves (2 forward, 5 with the gradient: 7 a layer under
+full recomputation); lines kept under `chiprun_out/`.
 """
 
 from __future__ import annotations
@@ -1083,6 +1091,91 @@ def probe_hc(shape=(1, 4, 8192, 3584), tiles=(128, 256, 512)):
                 "device_ops_ms": dict(list(ops.items())[:8])})
 
 
+# (cell, (b, T, channels), a bias): what `causal_conv_silu` is handed
+CONV_CALLS = (
+    ("nemotron3_nano_30b_a3b", (2, 8192, 6144), True),
+    ("granite4_h_micro", (1, 8192, 4352), True),
+    ("ling3_0_flash_q_k_or_v", (1, 8192, 2048), False),
+    ("olmo_hybrid_7b_q_or_k", (1, 8192, 1440), False),
+    ("olmo_hybrid_7b_v", (1, 8192, 2880), False),
+)
+
+
+def probe_conv(calls=CONV_CALLS, rows=(1024, 2048, 4096), out=None):
+    """One layer's short convolution + silu, forward alone and forward
+    + gradient (x, filter, bias), at the cells' shapes in bfloat16: the
+    plain lines of `models/mamba2.causal_conv_silu` against
+    `ops/short_conv.py`'s pair wherever `conv_route` lets a shape in, at
+    several rows a grid step; every device op's ms a call, their sum,
+    and that sum as GB/s over the passes the least implementation moves
+    (PERF.md section 6, PR 59).  The lines are kept in `out`
+    (`chiprun_out/pr59/conv_probe.jsonl`)."""
+    from unittest import mock
+
+    from dlrover_wuqiong_tpu.models.mamba2 import causal_conv_silu
+    from dlrover_wuqiong_tpu.ops import mosaic, short_conv
+
+    out = out or os.path.join("chiprun_out", "pr59", "conv_probe.jsonl")
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    for cell, shape, has_bias in calls:
+        t, channels = shape[1:]
+        keys = jax.random.split(jax.random.PRNGKey(channels), 4)
+        x, d_out = (jax.random.normal(k, shape, jnp.bfloat16)
+                    for k in keys[:2])
+        leaves = [jax.random.uniform(keys[2], (4, channels), jnp.float32,
+                                     -0.5, 0.5)]
+        if has_bias:
+            leaves.append(jax.random.uniform(
+                keys[3], (channels,), jnp.float32, -0.5, 0.5))
+
+        def plain(x, *leaves):
+            return causal_conv_silu(x, leaves[0], (leaves + (None,))[1],
+                                    jnp.bfloat16)
+
+        def kernel(rows):
+            return lambda x, *leaves: short_conv._conv_kernels(
+                x, leaves[0], (leaves + (None,))[1], jnp.bfloat16,
+                rows=rows)
+
+        def both(fn):  # y too, or the compiler drops the forward
+            def run(d_out, x, *leaves):
+                y, vjp = jax.vjp(fn, x, *leaves)
+                return (y, *vjp(d_out))
+            return run
+
+        takes = short_conv.conv_route(t, channels, 4, jnp.bfloat16)
+        cases = [("plain", plain, None)] + [
+            ("dwt_conv", kernel(r), r) for r in rows
+            if takes == "kernel" and t % r == 0]
+        want = None
+        for name, fn, r in cases:
+            # the plain lines are what a call off the TPU traces
+            with mock.patch.object(mosaic, "on_tpu", lambda: False):
+                got = jax.jit(both(fn))(d_out, x, *leaves)[1:]
+                want = want or got
+                off = [float(jnp.abs(g.astype(jnp.float32)
+                                     - w.astype(jnp.float32)).max()
+                             / jnp.abs(w.astype(jnp.float32)).max())
+                       for g, w in zip(got, want)]
+                for what, f, args, passes in (
+                        (name, jax.jit(fn), (x, *leaves), 2),
+                        (name + "_fwd_bwd", jax.jit(both(fn)),
+                         (d_out, x, *leaves), 5)):
+                    ops = _device_ops_ms(f, *args, top=256)
+                    ms = sum(ops.values())
+                    line = {
+                        "probe": "conv", "cell": cell, "what": what,
+                        "shape": list(shape), "route": takes,
+                        "rows_a_step": r, "all_ops_ms": round(ms, 4),
+                        "passes": passes, "gb_per_s": ms and round(
+                            passes * x.size * 2 / ms / 1e6, 1),
+                        "off_dx_dfilter_dbias": [round(o, 6) for o in off],
+                        "device_ops_ms": dict(list(ops.items())[:8])}
+                    _emit_raw(line)
+                    with open(out, "a") as f_out:
+                        f_out.write(json.dumps(line) + "\n")
+
+
 ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "attn_sweep": probe_attn_sweep,
        "attn_direct": probe_attn_direct, "lib": probe_lib,
@@ -1094,7 +1187,7 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "rope": probe_rope, "moe_numbers": probe_moe_numbers,
        "delta": probe_delta,
        "delta_kda": functools.partial(probe_delta, forms=("channel",)),
-       "sums": probe_sums, "hc": probe_hc}
+       "sums": probe_sums, "hc": probe_hc, "conv": probe_conv}
 
 
 def main(argv=None) -> int:
