@@ -4,8 +4,10 @@ Two fields of `LlamaConfig` reach the Llama-shaped MoE families through
 the same block (OLMoE: both): `moe` puts `models/moe.py`'s expert layer
 in every block's feed-forward slot (expert width `intermediate_size`),
 `qk_norm` an RMSNorm over the whole q and k projections before the split
-into heads and before RoPE.  `attn_window` makes the attention a sliding
-window (models/smallthinker.py's local layers), as `attn_scale` and
+into heads and before RoPE; `qk_head_norm` is the per-head form, an
+RMSNorm over each head's lanes (models/lfm2.py's layers).
+`attn_window` makes the attention a sliding window
+(models/smallthinker.py's local layers), as `attn_scale` and
 `rope` make it Granite's.  `attn_gate` puts one sigmoid gate a head and
 token on the attention's output; tables narrower than half a head rotate
 the head's first features only (models/laguna.py's layers).
@@ -61,6 +63,10 @@ class LlamaConfig:
     # RMSNorm (own scale, `rms_eps`) over all of q's and all of k's
     # features, before the heads are split and before RoPE (OLMoE)
     qk_norm: bool = False
+    # the per-head form: RMSNorm (`rms_eps`) over each head's lanes of q
+    # and of k, ONE (head size,) scale each shared by the heads, before
+    # RoPE (models/lfm2.py); not both
+    qk_head_norm: bool = False
     # an explicit head size where heads x size is not the hidden size
     # (q and o are then hidden x heads*size); 0 = hidden_size // num_heads
     attn_head_dim: int = 0
@@ -104,6 +110,7 @@ class LlamaConfig:
         h, q = self.hidden_size, self.num_heads * self.head_dim
         kv = self.num_kv_heads * self.head_dim
         return 2 * h * q + 2 * h * kv + (q + kv if self.qk_norm else 0) \
+            + (2 * self.head_dim if self.qk_head_norm else 0) \
             + (h * self.num_heads if self.attn_gate else 0)
 
     def ffn_params(self) -> int:
@@ -272,6 +279,17 @@ class LlamaAttention(nn.Module):
             with jax.named_scope("qk_norm"):
                 q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(q)
                 k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(k)
+        if cfg.qk_head_norm:
+            if cfg.qk_norm:
+                raise ValueError("qk_norm and qk_head_norm: one norm of q "
+                                 "and k, over the projection or over a head")
+            with jax.named_scope("qk_norm"):
+                # a head's lanes last for the statistic, then the
+                # projections' own layout again
+                q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(
+                    q.reshape(B, T, cfg.num_heads, hd)).reshape(q.shape)
+                k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(
+                    k.reshape(B, T, cfg.num_kv_heads, hd)).reshape(k.shape)
         v = dense(cfg, cfg.num_kv_heads * hd, "v_proj", use_bias=False)(x)
         # where a head is a lane slab the kernels index q, k and v in the
         # projections' own (B, T, heads*hd) — k and v at their kv heads,

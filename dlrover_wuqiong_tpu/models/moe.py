@@ -167,6 +167,9 @@ class MoEConfig:
     # (`collect_param_steps`), the optimizer still leaves it alone.
     # 0 = the rule is not run
     bias_update_rate: float = 0.0
+    # what a sigmoid router's chosen gates' sum is increased by before it
+    # divides them (DeepSeek-V3's published 1e-20; LFM2's 1e-6)
+    gate_norm_eps: float = 1e-20
     # the k gates are multiplied by this after normalisation
     routed_scaling: float = 1.0
     # a limit on the groups a token may choose from (DeepSeek-V3's
@@ -198,6 +201,7 @@ class MoEConfig:
         """The fields the capacity path cannot honour, off their defaults."""
         off = {f.name: getattr(self, f.name) for f in dataclasses.fields(self)
                if f.name in ("score_func", "selection_bias", "routed_scaling",
+                             "gate_norm_eps",
                              "bias_update_rate", "n_group", "topk_group",
                              "expert_act", "shared_width", "experts_held",
                              "first_expert", "norm_topk_prob")
@@ -307,15 +311,15 @@ def group_limit_binds(probs: jax.Array, bias: Optional[jax.Array],
 
 def route_top_k(probs: jax.Array, top_k: int, norm_topk_prob: bool = True,
                 bias: Optional[jax.Array] = None, floor: bool = True,
-                scaling: float = 1.0, n_group: int = 1, topk_group: int = 1
-                ) -> Tuple[jax.Array, jax.Array]:
+                scaling: float = 1.0, n_group: int = 1, topk_group: int = 1,
+                eps: float = 1e-20) -> Tuple[jax.Array, jax.Array]:
     """(gates (T, k), experts (T, k)) of the k largest router scores —
     of `probs + bias` where a selection bias is given, and inside the
     token's `topk_group` best of `n_group` groups where a group limit is
     (`limit_to_groups`); the gates are `probs` at the chosen experts,
     WITHOUT the bias.  Normalised gates
-    are divided by max(sum, 1e-9) (`floor`), or by sum + 1e-20 (the
-    sigmoid router's published form)."""
+    are divided by max(sum, 1e-9) (`floor`), or by sum + `eps` (the
+    sigmoid router's published form, `MoEConfig.gate_norm_eps`)."""
     scores = _choice_scores(probs, bias)
     if n_group > 1:
         scores = limit_to_groups(scores, n_group, topk_group)
@@ -334,7 +338,7 @@ def route_top_k(probs: jax.Array, top_k: int, norm_topk_prob: bool = True,
     if norm_topk_prob:
         total = gates.sum(-1, keepdims=True)
         gates = gates / (jnp.maximum(total, 1e-9) if floor
-                         else total + 1e-20)
+                         else total + eps)
     if scaling != 1.0:
         gates = gates * scaling
     return gates, experts
@@ -909,10 +913,12 @@ class MoEMLP(nn.Module):
                 # was (tests stand the parent's `route_top_k` in its place)
                 limit = dict(n_group=cfg.n_group, topk_group=cfg.topk_group
                              ) if cfg.n_group > 1 else {}
+                eps = {} if cfg.gate_norm_eps == MoEConfig.gate_norm_eps \
+                    else dict(eps=cfg.gate_norm_eps)
                 gates, experts = route_top_k(
                     probs, cfg.top_k, cfg.norm_topk_prob, bias=bias,
                     floor=cfg.score_func == "softmax",
-                    scaling=cfg.routed_scaling, **limit)
+                    scaling=cfg.routed_scaling, **limit, **eps)
                 if limit:
                     # counted, not timed: tokens the limit moved, tokens
                     self.sow("intermediates", "moe_group_limit", jnp.stack([

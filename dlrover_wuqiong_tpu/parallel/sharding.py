@@ -107,6 +107,12 @@ TRANSFORMER_RULES: List[Rule] = [
     (r".*linear_attention.*(g_proj|f_proj)/kernel$", P("fsdp", "tp")),
     (r".*linear_attention.*(a_proj|b_proj)/kernel$", P("fsdp", None)),
     (r".*linear_attention.*/(conv_kernel|A_log|dt_bias)$", P()),
+    # a gated short-convolution mixer (models/lfm2.py): its two
+    # projections column- then row-parallel, as a state-space mixer's; its
+    # filter is a few numbers a channel and replicated
+    (r".*short_conv.*in_proj/kernel$", P("fsdp", "tp")),
+    (r".*short_conv.*out_proj/kernel$", P("tp", "fsdp")),
+    (r".*short_conv.*/conv_kernel$", P()),
     (r".*selection_bias$", P()),
     # lm head: vocab-parallel
     (r".*(lm_head|output_proj)/kernel$", P("fsdp", "tp")),

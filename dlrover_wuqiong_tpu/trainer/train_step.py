@@ -325,9 +325,10 @@ def make_lm_loss(model_apply: Callable) -> Callable:
     (`models/latent_moe.collect_mtp_loss`: its target is `labels` one
     further on) when present.  `loss_fn.with_stats(params, batch) ->
     (loss, stats)` is the same loss with what the MoE layers, the
-    windowed or latent attention layers, the delta-rule mixers and
-    the hyper-connections counted (`collect_moe_stats`,
-    `collect_attention_stats`, `collect_delta_stats`, `collect_kda_stats`,
+    windowed or latent attention layers, the delta-rule mixers, the
+    gated short convolutions and the hyper-connections counted
+    (`collect_moe_stats`, `collect_attention_stats`,
+    `collect_delta_stats`, `collect_kda_stats`, `collect_shortconv_stats`,
     `collect_residual_stats`; {} for a dense model without any of them),
     and `mtp_ce` beside them: `make_train_step` differentiates that one
     and returns the counters in the step's metrics."""
@@ -346,6 +347,7 @@ def make_lm_loss(model_apply: Callable) -> Callable:
             from ..models.hyper_connection import collect_residual_stats
             from ..models.kda import collect_kda_stats
             from ..models.latent_moe import collect_mtp_loss
+            from ..models.lfm2 import collect_shortconv_stats
             from ..models.moe import (
                 collect_moe_aux_loss,
                 collect_moe_stats,
@@ -357,6 +359,7 @@ def make_lm_loss(model_apply: Callable) -> Callable:
                      **collect_attention_stats(inter),
                      **collect_delta_stats(inter),
                      **collect_kda_stats(inter),
+                     **collect_shortconv_stats(inter),
                      **collect_residual_stats(inter)}
             mtp = collect_mtp_loss(inter, batch["labels"])
             if mtp is not None:
