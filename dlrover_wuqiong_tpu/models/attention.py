@@ -98,7 +98,10 @@ def collect_attention_stats(intermediates) -> dict:
     `attn_lanes_model` of the latent ones (`models/latent_attention.py`:
     the lanes a score entry's two products run as the kernels block
     them, and the lanes the model's widths ask), and `attn_gate_mean`,
-    the gated layers' mean output gate (`LlamaConfig.attn_gate`)."""
+    the gated layers' mean output gate (`LlamaConfig.attn_gate`), with
+    `attn_gate_kernel_share`, the share of those layers whose multiply
+    took `ops/head_gate.py`'s kernels (`gate_route`: counted, not
+    timed)."""
     from .moe import _sown
 
     stats = {}
@@ -111,9 +114,20 @@ def collect_attention_stats(intermediates) -> dict:
             with jax.named_scope(sown):  # the sum's copies get an owner
                 stats.update(zip(names, jnp.concatenate(pairs).sum(0)))
     gates = [v.reshape(()) for v in _sown(intermediates, "attn_gate_mean")]
+    # `LlamaAttention`'s gates say which route they took; latent
+    # attention's, lines of its own on another layout, have no other
+    took = [v.reshape(()) for v in _sown(intermediates, "attn_gate_kernel")]
     if gates:
         with jax.named_scope("attn_gate_mean"):
-            stats["attn_gate_mean"] = jnp.stack(gates).mean()
+            if len(took) == len(gates):
+                # ONE mean over the layers' (gate, took) pairs: a static
+                # share on its own folds to a constant the step returns
+                # through a copy that nothing names
+                pairs = jnp.stack([jnp.stack(gates), jnp.stack(took)], 1)
+                stats["attn_gate_mean"], stats["attn_gate_kernel_share"] \
+                    = pairs.mean(0)
+            else:
+                stats["attn_gate_mean"] = jnp.stack(gates).mean()
     return stats
 
 

@@ -267,6 +267,7 @@ class LlamaAttention(nn.Module):
             window_tiles,
         )
         from ..ops.flash_attention import kept_mask, kv_route
+        from ..ops.head_gate import gate_route, gate_rows
         from .fp8 import dense
 
         cfg = self.config
@@ -341,9 +342,17 @@ class LlamaAttention(nn.Module):
                 g = jax.nn.sigmoid(g.astype(jnp.float32))
                 self.sow("intermediates", "attn_gate_mean",
                          jax.lax.stop_gradient(g.mean()))
-                # y keeps the layout the kernels wrote: a head's gate
-                # is spread over its lanes, not y cut to heads
-                y = (y * jnp.repeat(g, hd, axis=-1)).astype(cfg.dtype)
+                # y keeps the layout the kernels wrote, not cut to
+                # heads: where a head is whole lane slabs a kernel
+                # reads each head's g once (ops/head_gate.py), else the
+                # gate is spread over the head's lanes
+                took = gate_route(y.shape[-1], hd, cfg.mesh) == "kernel"
+                self.sow("intermediates", "attn_gate_kernel",
+                         jnp.float32(took))
+                if took:
+                    y = gate_rows(y, g).astype(cfg.dtype)
+                else:
+                    y = (y * jnp.repeat(g, hd, axis=-1)).astype(cfg.dtype)
         return dense(cfg, C, "o_proj", use_bias=False)(y)
 
 

@@ -113,6 +113,15 @@ def _dot_c0(a, b):
                                preferred_element_type=jnp.float32)
 
 
+def _row_dot(a, b):
+    """(rows, lanes) . (rows, lanes) -> (rows, 1): the product's sum over
+    the lanes, in float32 whatever the two hold.  A head's cotangent of a
+    gate on its lanes (`ops/head_gate.py`); attention's delta = rowsum(dO
+    . O) of a head's slabs is the same sum."""
+    return (a.astype(jnp.float32) * b.astype(jnp.float32)).sum(
+        axis=-1, keepdims=True)
+
+
 def _iota(shape, axis):
     return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 

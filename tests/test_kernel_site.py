@@ -22,6 +22,8 @@ PR 56 gave `rope_route` the rotated width (Laguna's full layers turn 64
 of a head's 128 lanes): the rows of the calls the program now makes.
 PR 59 added `conv_route` (`ops/short_conv.py`), a predicate the parent
 did not have: its rows are what the mixers' convolutions are handed.
+PR 61 added `gate_route` (`ops/head_gate.py`), new too: its rows are the
+rows of y a gated `LlamaAttention` multiplies.
 """
 
 import types
@@ -36,7 +38,14 @@ from jax.sharding import PartitionSpec as P
 from dlrover_wuqiong_tpu.models import attention
 from dlrover_wuqiong_tpu.ops import delta_rule, flash_attention as fa
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
-from dlrover_wuqiong_tpu.ops import hc_mix, mosaic, rope, short_conv, ssd
+from dlrover_wuqiong_tpu.ops import (
+    hc_mix,
+    head_gate,
+    mosaic,
+    rope,
+    short_conv,
+    ssd,
+)
 
 SITES = ("off", "device", "one", "mesh", "manual")
 
@@ -183,6 +192,16 @@ TABLE = {
         ("no_whole_row_block", (8200, 6144, 4, jnp.bfloat16), _NOWHERE),
         ("nano", (64, 320, 4, jnp.float32), _NOWHERE),
     ],
+    # (lanes of a row of y, head size)
+    "gate_route": [
+        ("laguna_sliding", (8192, 128), _OR_A_SHARD_MAP),
+        ("laguna_full", (6144, 128), _OR_A_SHARD_MAP),
+        ("heads_of_two_slabs", (512, 256), _OR_A_SHARD_MAP),
+        ("heads_of_64", (4096, 64), _NOWHERE),
+        ("heads_of_96", (6144, 96), _NOWHERE),
+        ("a_row_that_cuts_a_head", (6144 + 64, 128), _NOWHERE),
+        ("a_row_that_cuts_a_head_of_two_slabs", (640, 256), _NOWHERE),
+    ],
     # (lhs (T*k, c), rhs (held, c, n), experts the router names)
     "gmm_route": [
         ("nemotron_in", ((98304, 2688), (8, 2688, 1856), 128), _ONE_DEVICE),
@@ -220,7 +239,8 @@ def _ask(predicate, args, mesh):
             types.SimpleNamespace(mesh=mesh, attn_impl=impl), *shape)
     module = {"scan_route": ssd, "rope_route": rope, "hc_route": hc_mix,
               "delta_route": delta_rule, "gmm_route": gm,
-              "experts_route": gm, "conv_route": short_conv}[predicate]
+              "experts_route": gm, "conv_route": short_conv,
+              "gate_route": head_gate}[predicate]
     # the mesh sits before the rotated part, and before the decay's form
     at = {"rope_route": 2, "delta_route": 5}.get(predicate, len(args))
     return getattr(module, predicate)(*args[:at], mesh, *args[at:])

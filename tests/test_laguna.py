@@ -41,6 +41,7 @@ from dlrover_wuqiong_tpu.models.llama import (
 from dlrover_wuqiong_tpu.models.moe import MoEConfig, MoEMLP
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
+from dlrover_wuqiong_tpu.ops import head_gate
 from dlrover_wuqiong_tpu.ops import rope
 from dlrover_wuqiong_tpu.trainer.train_step import make_lm_loss
 
@@ -301,9 +302,11 @@ def test_a_gated_attention_layer_is_the_references(heads, window, rotary,
 def direct(on_tpu, monkeypatch):
     """tests/test_flash_attention_grouped.py's: the direct entry as the
     chip runs it, its kernels interpreted, at blocks of 64 — and the
-    rotation's, which one TPU device takes beside them."""
+    rotation's and the gate's, which one TPU device takes beside them."""
     monkeypatch.setattr(rope, "_rope_kernels", functools.partial(
         rope._rope_kernels, interpret=True))
+    monkeypatch.setattr(head_gate, "_gate_kernels", functools.partial(
+        head_gate._gate_kernels, interpret=True))
     for name in ("_projected_forward", "_projected_backward"):
         monkeypatch.setattr(fa, name, functools.partial(
             lambda kernel, *a, **kw: kernel(*a, **{**kw, "interpret": True}),
@@ -346,6 +349,9 @@ def test_the_direct_kernels_run_both_kinds_of_layer(direct, heads, window,
     # q's rotation and k's are the kernel's, half a head as the whole
     assert rope.rope_route(heads * d, d, None, rotary) == "kernel"
     assert text.count("name=dwt_rope") == 2
+    # the gate multiplies on the slabs the attention kernels wrote
+    assert head_gate.gate_route(heads * d, d) == "kernel"
+    assert len(re.findall(r"name=dwt_gate\b", text)) == 1
     inv = 1.0 / 10000.0 ** (jnp.arange(0, rotary, 2) / rotary)
 
     def plain(params, x):

@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
-model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|delta_kda|sums|hc|conv] ...  (no args = step/attn/head/model/opt).  One JSON line
+model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|delta_kda|sums|hc|conv|gate] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -59,6 +59,13 @@ them against `dwt_conv_fwd` / `dwt_conv_bwd` (`ops/short_conv.py`) at
 several rows a grid step, in ms and in GB/s over the passes the least
 implementation moves (2 forward, 5 with the gradient: 7 a layer under
 full recomputation); lines kept under `chiprun_out/`.
+`gate` reads the same way one layer's head-wise output gate at Laguna's
+two head counts (1 x 16,384 x 64 and x 48 heads of 128), forward and
+forward + gradient: `models/llama.LlamaAttention`'s plain line, and the
+same product with g spread by a one-hot matrix product, as the compiler
+fuses them against `dwt_gate` / `dwt_gate_bwd` (`ops/head_gate.py`) at
+several row tiles, in ms and in GB/s over the passes the least
+implementation moves (2 forward, 5 with the gradient).
 """
 
 from __future__ import annotations
@@ -1176,6 +1183,78 @@ def probe_conv(calls=CONV_CALLS, rows=(1024, 2048, 4096), out=None):
                         f_out.write(json.dumps(line) + "\n")
 
 
+def _gate_plain(y, g):
+    d = y.shape[-1] // g.shape[-1]
+    return (y * jnp.repeat(g, d, axis=-1)).astype(y.dtype)
+
+
+def _gate_one_hot(y, g):
+    """g spread over its head's lanes by the matrix unit: exact at the
+    highest precision (a one-hot column picks one g), six passes."""
+    lanes, heads = y.shape[-1], g.shape[-1]
+    spread = (jnp.arange(lanes)[None] // (lanes // heads)
+              == jnp.arange(heads)[:, None]).astype(jnp.float32)
+    return (y * jnp.einsum("bth,hl->btl", g, spread,
+                           precision=jax.lax.Precision.HIGHEST)
+            ).astype(y.dtype)
+
+
+def probe_gate(heads=(64, 48), tiles=(64, 128, 256, 512), t=16384,
+               interpret=False, out=None):
+    """One layer's output gate at Laguna's sliding (64 heads) and full
+    (48) layers, y (1, 16384, heads x 128) bfloat16 and g float32,
+    forward alone and forward + gradient (y and g): the plain line and
+    its one-hot form as the compiler fuses them, against
+    `ops/head_gate.py`'s pair at several row tiles; every device op's ms
+    a call, their sum, and that sum as GB/s over the passes the least
+    implementation moves (PERF.md section 6, PR 61).  The lines are kept
+    in `out` (`chiprun_out/pr61/gate_probe.jsonl`)."""
+    from dlrover_wuqiong_tpu.ops import head_gate
+
+    out = out or os.path.join("chiprun_out", "pr61", "gate_probe.jsonl")
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+
+    def both(fn):  # y' too, or the compiler drops the forward
+        def run(d_out, y, g):
+            gated, vjp = jax.vjp(fn, y, g)
+            return (gated, *vjp(d_out))
+        return run
+
+    for h in heads:
+        shape = (1, t, h * 128)
+        keys = jax.random.split(jax.random.PRNGKey(h), 3)
+        y, d_out = (jax.random.normal(k, shape, jnp.bfloat16)
+                    for k in keys[:2])
+        g = jax.nn.sigmoid(jax.random.normal(keys[2], shape[:2] + (h,)))
+        cases = [("plain", _gate_plain, None),
+                 ("one_hot", _gate_one_hot, None)] + [
+            ("dwt_gate", functools.partial(head_gate._gate_kernels, tile=tile,
+                                           interpret=interpret), tile)
+            for tile in tiles]
+        want = jax.jit(both(_gate_plain))(d_out, y, g)
+        for name, fn, tile in cases:
+            got = jax.jit(both(fn))(d_out, y, g)
+            off = [float(jnp.abs(a.astype(jnp.float32)
+                                 - w.astype(jnp.float32)).max())
+                   for a, w in zip(got, want)]
+            for what, f, args, passes in (
+                    (name, jax.jit(fn), (y, g), 2),
+                    (name + "_fwd_bwd", jax.jit(both(fn)), (d_out, y, g),
+                     5)):
+                ops = _device_ops_ms(f, *args, top=256)
+                ms = sum(ops.values())
+                line = {"probe": "gate", "what": what, "shape": list(shape),
+                        "heads": h, "tile": tile,
+                        "all_ops_ms": round(ms, 4), "passes": passes,
+                        "gb_per_s": ms and round(
+                            passes * y.size * 2 / ms / 1e6, 1),
+                        "off_y_dy_dg": [round(o, 8) for o in off],
+                        "device_ops_ms": dict(list(ops.items())[:8])}
+                _emit_raw(line)
+                with open(out, "a") as f_out:
+                    f_out.write(json.dumps(line) + "\n")
+
+
 ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "attn_sweep": probe_attn_sweep,
        "attn_direct": probe_attn_direct, "lib": probe_lib,
@@ -1187,7 +1266,8 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "rope": probe_rope, "moe_numbers": probe_moe_numbers,
        "delta": probe_delta,
        "delta_kda": functools.partial(probe_delta, forms=("channel",)),
-       "sums": probe_sums, "hc": probe_hc, "conv": probe_conv}
+       "sums": probe_sums, "hc": probe_hc, "conv": probe_conv,
+       "gate": probe_gate}
 
 
 def main(argv=None) -> int:
