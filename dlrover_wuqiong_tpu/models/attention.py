@@ -101,7 +101,15 @@ def collect_attention_stats(intermediates) -> dict:
     the gated layers' mean output gate (`LlamaConfig.attn_gate`), with
     `attn_gate_kernel_share`, the share of those layers whose multiply
     took `ops/head_gate.py`'s kernels (`gate_route`: counted, not
-    timed)."""
+    timed).  Of the layers under a learned choice of keys
+    (`LlamaConfig.attn_index_topk`): `attn_sparse_kept` and
+    `attn_sparse_causal`, the (query, key) pairs a sequence-layer keeps
+    and its causal pairs; `attn_sparse_live_tiles` and
+    `attn_sparse_tiles_causal`, the score tiles of the kernels' block
+    that hold a kept pair — data, counted from the step's own choice —
+    beside the causal ones; `attn_sparse_tiles_run`, the tiles the
+    implementation computes; and `index_kl`, the layers' mean KL term
+    (`collect_attention_aux_loss` is what joins the loss)."""
     from .moe import _sown
 
     stats = {}
@@ -113,6 +121,16 @@ def collect_attention_stats(intermediates) -> dict:
         if pairs:
             with jax.named_scope(sown):  # the sum's copies get an owner
                 stats.update(zip(names, jnp.concatenate(pairs).sum(0)))
+    sparse = [v.reshape(-1, 5) for v in _sown(intermediates, "attn_sparse")]
+    if sparse:
+        with jax.named_scope("attn_sparse"):
+            stats.update(zip(
+                ("attn_sparse_kept", "attn_sparse_causal",
+                 "attn_sparse_live_tiles", "attn_sparse_tiles_causal",
+                 "attn_sparse_tiles_run"), jnp.concatenate(sparse).sum(0)))
+            stats["index_kl"] = jnp.stack([
+                v.reshape(()) for v in _sown(intermediates,
+                                             "attn_index_kl")]).mean()
     gates = [v.reshape(()) for v in _sown(intermediates, "attn_gate_mean")]
     # `LlamaAttention`'s gates say which route they took; latent
     # attention's, lines of its own on another layout, have no other
@@ -129,6 +147,17 @@ def collect_attention_stats(intermediates) -> dict:
             else:
                 stats["attn_gate_mean"] = jnp.stack(gates).mean()
     return stats
+
+
+def collect_attention_aux_loss(intermediates):
+    """The sum of the sown `attn_index_loss` leaves — the sparse
+    layers' weighted KL terms, which reach the indexers' leaves alone —
+    and nothing else an attention layer sows (0.0 without one)."""
+    from .moe import _sown
+
+    return sum((jnp.sum(v) for v in _sown(intermediates,
+                                          "attn_index_loss")),
+               jnp.zeros((), jnp.float32))
 
 
 def attend_projected(proj, n_head: int, cfg, causal: bool = True):

@@ -11,6 +11,13 @@ RMSNorm over each head's lanes (models/lfm2.py's layers).
 `rope` make it Granite's.  `attn_gate` puts one sigmoid gate a head and
 token on the attention's output; tables narrower than half a head rotate
 the head's first features only (models/laguna.py's layers).
+`attn_index_topk` makes the attention SPARSE by a learned choice: a
+`models/sparse_indexer.SparseIndexer` scores the causal keys, each query
+keeps the top `attn_index_topk`, and `ops/sparse_attention.py` runs the
+softmax over that set and the indexer's KL term (models/keye.py's
+layers); tables with a batch axis (`mrope_tables`: M-RoPE under explicit
+positions, the sections the MODEL's to name) rotate each sequence by its
+own angles.
 
 Parity: the reference's flagship workloads are GLM/Llama-class LMs via atorch
 (`BASELINE.json` configs: Llama-3 8B auto_accelerate, Llama-3 70B Megatron
@@ -84,6 +91,15 @@ class LlamaConfig:
     # `o_proj`: sigmoid(x @ g_proj), x the block's normalised input
     # (arXiv:2505.06708's head-wise form); False = none
     attn_gate: bool = False
+    # a learned choice of keys (ops/sparse_attention.py): each query
+    # keeps the `attn_index_topk` causal keys its indexer scores highest
+    # (`attn_index_heads` heads of `attn_index_dim` over ONE shared key);
+    # the layer sows the indexer's KL term times `attn_index_loss_weight`
+    # as a loss; 0 = every causal key, no indexer
+    attn_index_topk: int = 0
+    attn_index_heads: int = 0
+    attn_index_dim: int = 0
+    attn_index_loss_weight: float = 1.0
 
     @classmethod
     def nano(cls):
@@ -105,13 +121,17 @@ class LlamaConfig:
         return self.attn_head_dim or self.hidden_size // self.num_heads
 
     def attention_params(self) -> int:
-        """q, k, v, o, the QK-norm's scales and the output gate's
-        product; no block norm."""
+        """q, k, v, o, the QK-norm's scales, the output gate's product
+        and the sparse indexer's leaves (its q heads, its one key with
+        the key's LayerNorm, a weight a head); no block norm."""
         h, q = self.hidden_size, self.num_heads * self.head_dim
         kv = self.num_kv_heads * self.head_dim
+        idx = self.attn_index_dim
         return 2 * h * q + 2 * h * kv + (q + kv if self.qk_norm else 0) \
             + (2 * self.head_dim if self.qk_head_norm else 0) \
-            + (h * self.num_heads if self.attn_gate else 0)
+            + (h * self.num_heads if self.attn_gate else 0) \
+            + (h * (self.attn_index_heads * (idx + 1) + idx) + 2 * idx
+               if self.attn_index_topk else 0)
 
     def ffn_params(self) -> int:
         """The feed-forward slot: a SwiGLU, or `moe`'s expert layer — the
@@ -204,6 +224,27 @@ def rope_freqs(head_dim: int, max_seq: int, theta: float,
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
+def mrope_tables(head_dim: int, theta: float, sections, positions):
+    """(cos, sin), each (b, T, head_dim / 2), of M-RoPE: `positions` is
+    (3, b, T) — the temporal, height and width stream — and pair i of a
+    head takes its angle from the stream its consecutive section names
+    (`sections` pairs each, summing to head_dim / 2; a head of another
+    width than the sections were published for takes them in proportion).
+    Where the three streams coincide these are `rope_freqs`' rows."""
+    import numpy as np
+
+    half, total = head_dim // 2, sum(sections)
+    if len(sections) != 3 or any(n * half % total for n in sections):
+        raise ValueError(f"mrope sections {sections!r}: three counts of "
+                         f"pairs in proportion to {half}")
+    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                      dtype=jnp.float32) / head_dim))
+    stream = np.repeat(np.arange(3), [n * half // total for n in sections])
+    # (half, b, T) -> (b, T, half): each pair's own stream
+    angle = jnp.moveaxis(positions.astype(jnp.float32)[stream], 0, -1) * inv
+    return jnp.cos(angle), jnp.sin(angle)
+
+
 def apply_rope(x, cos, sin, mesh=None, head_dim: int = 0):
     """Rotate each head's pairs (even, odd interleaved by halves).  x is
     (b, s, h, d), or the projections' own (b, s, h*d) with the heads side
@@ -227,6 +268,13 @@ def apply_rope(x, cos, sin, mesh=None, head_dim: int = 0):
     two products and one sum an element."""
     from ..ops.rope import rope_route, rotate_rows
 
+    if cos.ndim == 3:
+        # a sequence's own angles (`mrope_tables`): whole heads, cut to
+        # (b, s, h, d), by the formula
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        c, si = cos[:, :, None], sin[:, :, None]
+        return jnp.concatenate([x1 * c - x2 * si, x2 * c + x1 * si],
+                               axis=-1).astype(x.dtype)
     s, lanes, half = x.shape[1], x.shape[-1], cos.shape[-1]
     row = math.prod(x.shape[2:])  # a position's heads side by side
     d = head_dim or 2 * half
@@ -259,7 +307,7 @@ class LlamaAttention(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, x, cos, sin):
+    def __call__(self, x, cos, sin, index_tables=None):
         from .attention import (
             attend,
             attend_projected,
@@ -298,8 +346,8 @@ class LlamaAttention(nn.Module):
         # nothing here cuts them to heads or repeats them
         # (models/attention.py); every other call cuts, before the
         # rotation, and repeats the kv heads, as it always did
-        direct = cfg.use_flash_attention and goes_direct(
-            cfg, cfg.num_heads, hd, T)
+        direct = cfg.use_flash_attention and not cfg.attn_index_topk \
+            and goes_direct(cfg, cfg.num_heads, hd, T)
         if not direct:
             q = q.reshape(B, T, cfg.num_heads, hd)
             k = k.reshape(B, T, cfg.num_kv_heads, hd)
@@ -313,6 +361,40 @@ class LlamaAttention(nn.Module):
                     else contextlib.nullcontext():
                 q = apply_rope(q, cos, sin, mesh=cfg.mesh, **width)
                 k = apply_rope(k, cos, sin, mesh=cfg.mesh, **width)
+        if cfg.attn_index_topk:
+            # the attention over the indexer's choice of keys: q (B, T,
+            # H, d), k and v (B, T, KV, d) rotated; the kv heads are
+            # indexed by their group, never repeated
+            from ..ops.sparse_attention import sparse_attention
+            from .attention import softmax_scale
+            from .sparse_indexer import SparseIndexer
+
+            if cfg.mesh is not None and cfg.mesh.size > 1:
+                raise ValueError(
+                    "a learned choice of keys (attn_index_topk) runs on "
+                    "one device: the choice over a sharded sequence has "
+                    "no route")
+            if cfg.attn_window or cfg.attn_gate:
+                raise ValueError("attn_index_topk beside a window or a "
+                                 "gate: no layer has asked for both")
+            if index_tables is None:  # one position stream: RoPE's rows
+                index_tables = rope_freqs(cfg.attn_index_dim, T,
+                                          cfg.rope_theta)
+            operands = SparseIndexer(
+                cfg.attn_index_heads, cfg.attn_index_dim, cfg.rms_eps,
+                cfg.dtype, cfg.mesh, name="indexer")(x, *index_tables)
+            y, kl, counted = sparse_attention(
+                q, k, v, *operands, cfg.attn_index_topk, softmax_scale(cfg),
+                cfg.mesh)
+            # the term joins the loss (`collect_attention_aux_loss`); the
+            # bare KL and the counters are read, never summed into it
+            self.sow("intermediates", "attn_index_loss",
+                     cfg.attn_index_loss_weight * kl)
+            self.sow("intermediates", "attn_index_kl",
+                     jax.lax.stop_gradient(kl))
+            self.sow("intermediates", "attn_sparse", counted)
+            return dense(cfg, C, "o_proj", use_bias=False)(
+                y.reshape(B, T, cfg.num_heads * hd))
         how, rep = kv_route(cfg.num_heads, cfg.num_kv_heads, hd)
         if rep > 1 and not (direct and how == "indexed"):  # GQA: repeat
             k, v = (jnp.repeat(t.reshape(B, T, cfg.num_kv_heads, hd), rep,

@@ -1,0 +1,834 @@
+"""Attention over a LEARNED choice of keys (DeepSeek Sparse Attention's
+form, arXiv 2512.02556 / the V3.2-Exp report): a light indexer scores
+every causal (query, key) pair, each query keeps the `topk` keys of
+largest score, the main attention's softmax runs over exactly that set,
+and the indexer learns from a KL term to the main attention's own
+distribution over the set.
+
+    I[t, s]  = sum_j w[t, j] relu(qI[t, j] . kI[s])        s <= t, float32
+    S_t      = the min(topk, t + 1) keys s <= t of largest I[t, s],
+               a tie to the lower s
+    p_a[t, .] = softmax over s in S_t of q_a[t] . k[s] * scale
+    o_a[t]   = sum_{s in S_t} p_a[t, s] v[s]
+    pbar     = mean_a p_a                                   (a constant)
+    L_I      = mean_t sum_{s in S_t} pbar (log pbar - log softmax_S(I))
+
+Four parts, each under its own scope (`sparse_attn/index`, `/select`,
+`/attend`, `/index_loss`) and each with two routes chosen by what the
+call can observe (`sparse_route`: shapes and `mosaic.kernel_site(mesh)`,
+never a knob):
+
+- "kernel", on one TPU device at whole blocks of `_BLOCK` positions,
+  heads of whole 128-lane slabs: `dwt_idx_scores` forms I a (block x
+  block) tile at a time — no per-head (T x T) array exists anywhere —
+  `dwt_idx_select` finds each row's EXACT threshold by a bitwise search
+  over the scores' ordered integer form in VMEM (32 counts of a row
+  block, then the tie's cut by position) and writes the choice as an
+  int8 (b, T, T) mask with the row's log-sum over the kept scores;
+  `dwt_fa_sp_fwd` / `dwt_fa_sp_bwd_dq` / `dwt_fa_sp_bwd_dkv` are a flash
+  attention a kv head's GROUP of query heads a grid step, every causal
+  tile computed and masked by the choice (dense work, the mathematics of
+  the kept set: the counters say so — `tiles_run` = `tiles_causal`; a
+  grid that skips tiles without a kept pair is a later change);
+  `dwt_idx_kl` recomputes the heads' probabilities from the saved
+  log-sums a tile at a time, sums them over the heads in VMEM (no
+  (heads x T x T) array), and leaves the tile of `softmax_S(I) - pbar`
+  where I's tile was; `dwt_idx_bwd` takes that to the indexer's three
+  operands.
+- "plain": every CPU run, a mesh GSPMD partitions, any other shape —
+  dense `jax.numpy` lines, the kernels' oracle.  `lax.top_k` a row makes
+  the choice there (stable: the lower index first among equals).
+
+The selection passes no gradient, the indexer's operands are detached by
+the caller (`models/sparse_indexer.py`), and q and k reach the index
+term as constants: the cross-entropy reaches the main leaves alone, the
+index term the indexer's alone.
+
+Parity: none — the reference has no sparse attention.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import mosaic
+from .mosaic import (
+    LANES,
+    _compiler_params,
+    _dot,
+    _dot_c0,
+    _dot_t,
+    _iota,
+    _out_struct,
+)
+
+_BLOCK = 512      # positions a tile's side: q rows, keys, the mask's tile
+_SELECT_ROWS = 128  # rows whose whole score row sits in VMEM for the search
+_SITES = frozenset({"device"})  # a whole sequence's keys: no shard is one
+_VMEM = 96 * 1024 * 1024
+_NEG = -1e30  # a masked score: finite, so an empty tile's row stays finite
+_INT_MIN = -2 ** 31
+
+
+def sparse_route(t: int, d: int, idx_d: int, mesh=None) -> str:
+    """Which route a sparse attention over `t` positions, main heads of
+    `d` and indexer heads of `idx_d` lanes, takes: "kernel" on one TPU
+    device (`mesh` is the model config's) where the sequence is whole
+    blocks, a main head is whole slabs and an indexer head's lanes are
+    whole sublane tiles; else "plain"."""
+    if t % _BLOCK or d % LANES or idx_d % 8 \
+            or mosaic.kernel_site(mesh) not in _SITES:
+        return "plain"
+    return "kernel"
+
+
+def kept_pairs(t: int, topk: int) -> int:
+    """(query, key) pairs a sequence of `t` keeps: min(topk, row + 1) a
+    row."""
+    k = min(topk, t)
+    return k * (k + 1) // 2 + (t - k) * k
+
+
+# ------------------------------------------------------------ plain route
+
+def _plain_scores(q_idx, k_idx, w):
+    """I (b, T, T) float32 of q_idx (b, T, H, di), k_idx (b, T, di) and
+    the weights w (b, T, H) with every constant factor in them."""
+    r = jnp.einsum("bthd,bsd->bhts", q_idx, k_idx,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("bhts,bth->bts", jnp.maximum(r, 0.0),
+                      w.astype(jnp.float32))
+
+
+def _plain_select(scores, topk: int):
+    """The kept set as a (b, T, T) bool mask: each row's min(topk, t + 1)
+    largest scores among s <= t, a tie to the lower s (`lax.top_k` is
+    stable)."""
+    b, t, _ = scores.shape
+    valid = jnp.tril(jnp.ones((t, t), bool))
+    masked = jnp.where(valid, scores, -jnp.inf)
+    _, idx = jax.lax.top_k(masked, min(topk, t))
+    rows = jnp.arange(t)[None, :, None]
+    chosen = jnp.zeros((b, t, t), bool).at[
+        jnp.arange(b)[:, None, None], rows, idx].set(True)
+    return chosen & valid
+
+
+def _plain_attend(q, k, v, mask, scale):
+    """(o (b, T, H, d), p (b, H, T, T) float32) over the kept set; q
+    (b, T, H, d), k and v (b, T, KV, d)."""
+    rep = q.shape[2] // k.shape[2]
+    kr, vr = (jnp.repeat(x, rep, axis=2) for x in (k, v))
+    s = jnp.einsum("bthd,bshd->bhts", q, kr,
+                   preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(mask[:, None], s, -jnp.inf), axis=-1)
+    o = jnp.einsum("bhts,bshd->bthd", p.astype(v.dtype), vr,
+                   preferred_element_type=jnp.float32)
+    return o.astype(q.dtype), p
+
+
+def _plain_kl(scores, mask, pbar):
+    """sum_t sum_{S_t} pbar (log pbar - log softmax_S(I)); pbar a
+    constant."""
+    logq = jax.nn.log_softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    live = mask & (pbar > 0)
+    safe = jnp.where(live, pbar, 1.0)
+    return jnp.where(live, safe * (jnp.log(safe)
+                                   - jnp.where(live, logq, 0.0)), 0.0).sum()
+
+
+# ----------------------------------------------------------- kernel bodies
+
+def _ordered(x):
+    """float32 -> int32 in the floats' TOTAL order, `lax.top_k`'s: -0.0
+    below 0.0."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _fold(x):
+    """(rows, n * 128) -> (rows, 128): the slabs' sum, no lane crossed."""
+    n = x.shape[-1]
+    if n <= LANES or n % LANES:
+        return x
+    out = x[:, :LANES]
+    for i in range(1, n // LANES):
+        out = out + x[:, i * LANES:(i + 1) * LANES]
+    return out
+
+
+def _scores_kernel(q_ref, k_ref, w_ref, o_ref):
+    """One (q block, key block) tile of I: the heads' relu'd products
+    against the ONE key, weighted and summed."""
+    i, j = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j <= i)
+    def _():
+        k = k_ref[0]
+        w = w_ref[0]
+        acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
+        for h in range(q_ref.shape[1]):
+            acc = acc + w[:, h:h + 1] * jnp.maximum(_dot_t(q_ref[0, h], k),
+                                                    0.0)
+        o_ref[0] = acc
+
+
+def _select_kernel(s_ref, mask_ref, logz_ref, count_ref, keys_ref, *, topk,
+                   chunk):
+    """One block of rows: every row's exact threshold among its causal
+    keys by a bitwise search over the ordered integers (the largest x
+    with count(key >= x) >= the row's k), the tie's cut by position the
+    same way, the kept set as int8 and its log-sum."""
+    rows = s_ref.shape[1]
+    row0 = pl.program_id(1) * rows
+    chunks = (row0 + rows + chunk - 1) // chunk  # to the diagonal's end
+    t_of = row0 + _iota((rows, 1), 0)
+    want = jnp.minimum(topk, t_of + 1)
+
+    width = LANES if chunk % LANES == 0 else chunk  # of a folded count
+
+    def cols(c):
+        return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+
+    def keys_at(c):
+        return c * chunk + _iota((rows, chunk), 1)
+
+    def valid(c):
+        return keys_at(c) <= t_of
+
+    def prepare(c, top):
+        x = s_ref[0, :, cols(c)]
+        keys_ref[:, cols(c)] = jnp.where(valid(c), _ordered(x), _INT_MIN)
+        return jnp.maximum(top, jnp.where(valid(c), x, _NEG).max(
+            -1, keepdims=True))
+
+    top = jax.lax.fori_loop(0, chunks, prepare,
+                            jnp.full((rows, 1), _NEG, jnp.float32))
+
+    def count(pred):
+        def body(c, acc):
+            return acc + _fold(pred(c, keys_ref[:, cols(c)]).astype(
+                jnp.int32))
+
+        return jax.lax.fori_loop(
+            0, chunks, body, jnp.zeros((rows, width), jnp.int32)).sum(
+                -1, keepdims=True)
+
+    def bit_of_threshold(n, found):
+        cand = found | jnp.left_shift(jnp.int32(1), 31 - n)
+        signed = cand ^ _INT_MIN
+        enough = count(lambda c, keys: keys >= signed) >= want
+        return jnp.where(enough, cand, found)
+
+    # the bits are the key's with the sign flipped: unsigned order
+    thr = jax.lax.fori_loop(0, 32, bit_of_threshold,
+                            jnp.zeros((rows, 1), jnp.int32)) ^ _INT_MIN
+    need = want - count(lambda c, keys: keys > thr)  # ties to keep: >= 1
+    bits = max(1, math.ceil(math.log2(s_ref.shape[2])))
+
+    def bit_of_cut(n, found):
+        cand = found | jnp.left_shift(jnp.int32(1), bits - 1 - n)
+        fits = count(lambda c, keys: (keys == thr)
+                     & (keys_at(c) <= cand)) <= need
+        return jnp.where(fits, cand, found)
+
+    cut = jax.lax.fori_loop(0, bits, bit_of_cut,
+                            jnp.zeros((rows, 1), jnp.int32))
+
+    def write(c, total):
+        keys = keys_ref[:, cols(c)]
+        kept = ((keys > thr) | ((keys == thr) & (keys_at(c) <= cut))) \
+            & valid(c)
+        mask_ref[0, :, cols(c)] = kept.astype(jnp.int8)
+        # the chunk's kept pairs, for the counters: a row of the count
+        count_ref[0, 0, pl.ds(c, 1), :] = jnp.broadcast_to(
+            kept.astype(jnp.int32).sum(-1, keepdims=True).sum(
+                0, keepdims=True), (1, count_ref.shape[-1]))
+        return total + _fold(jnp.where(
+            kept, jnp.exp(s_ref[0, :, cols(c)] - top), 0.0))
+
+    total = jax.lax.fori_loop(0, chunks, write,
+                              jnp.zeros((rows, width), jnp.float32))
+    logz_ref[0] = top + jnp.log(total.sum(-1, keepdims=True))
+
+
+def _kept(mask_ref):
+    return mask_ref[0].astype(jnp.float32) > 0.0
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, *, scale, rep, d):
+    """One (batch row, kv head, q block, key block): the group's `rep`
+    query heads against the one kv head, online softmax over the kept
+    entries of the tile."""
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        kept = _kept(mask_ref)
+        k, v = k_ref[0], v_ref[0]
+        for h in range(rep):
+            s = _dot_t(q_ref[0, :, h * d:(h + 1) * d], k) * scale
+            s = jnp.where(kept, s, _NEG)
+            m_old = m_scr[h]
+            m_new = jnp.maximum(m_old, s.max(-1, keepdims=True))
+            p = jnp.where(kept, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_old - m_new)
+            l_scr[h] = alpha * l_scr[h] + p.sum(-1, keepdims=True)
+            acc_scr[h] = alpha * acc_scr[h] + _dot(p.astype(v.dtype), v)
+            m_scr[h] = m_new
+
+    @pl.when(j == i)
+    def _():
+        for h in range(rep):
+            o_ref[0, :, h * d:(h + 1) * d] = (
+                acc_scr[h] / l_scr[h]).astype(o_ref.dtype)
+            lse_ref[0, 0, :, h:h + 1] = m_scr[h] + jnp.log(l_scr[h])
+
+
+def _probs(q, k, kept, lse, scale):
+    """A head's probabilities on the tile from its saved log-sum."""
+    return jnp.where(kept, jnp.exp(_dot_t(q, k) * scale - lse), 0.0)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+               dq_ref, acc_scr, *, scale, rep, d):
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        kept = _kept(mask_ref)
+        k, v = k_ref[0], v_ref[0]
+        for h in range(rep):
+            lanes = slice(h * d, (h + 1) * d)
+            p = _probs(q_ref[0, :, lanes], k, kept,
+                       lse_ref[0, 0, :, h:h + 1], scale)
+            dp = _dot_t(do_ref[0, :, lanes], v)
+            ds = p * (dp - delta_ref[0, 0, :, h:h + 1]) * scale
+            acc_scr[:, lanes] += _dot(ds.astype(k.dtype), k)
+
+    @pl.when(j == i)
+    def _():
+        dq_ref[0] = acc_scr[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, rep, d):
+    """One (batch row, kv head, key block, q block): dk and dv of the kv
+    head summed over the q blocks at or below it and the group's heads."""
+    j, i = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(i == 0)
+    def _():
+        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    @pl.when(i >= j)
+    def _():
+        kept = _kept(mask_ref)
+        k, v = k_ref[0], v_ref[0]
+        for h in range(rep):
+            lanes = slice(h * d, (h + 1) * d)
+            q, do = q_ref[0, :, lanes], do_ref[0, :, lanes]
+            p = _probs(q, k, kept, lse_ref[0, 0, :, h:h + 1], scale)
+            dv_scr[...] += _dot_c0(p.astype(do.dtype), do)
+            ds = p * (_dot_t(do, v) - delta_ref[0, 0, :, h:h + 1]) * scale
+            dk_scr[...] += _dot_c0(ds.astype(q.dtype), q)
+
+    @pl.when(i == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _kl_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, logz_ref,
+               part_ref, ds_ref, pbar_scr, *, scale, rep, d, heads):
+    """One (batch row, q block, key block, kv head): the group's heads'
+    probabilities summed into the tile's pbar; at the last group the
+    tile's part of the KL sum and softmax_S(I) - pbar."""
+    i, j, g = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(g == 0)
+    def _():
+        pbar_scr[...] = jnp.zeros(pbar_scr.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        kept = _kept(mask_ref)
+        k = k_ref[0]
+        total = pbar_scr[...]
+        for h in range(rep):
+            total = total + _probs(q_ref[0, :, h * d:(h + 1) * d], k, kept,
+                                   lse_ref[0, 0, :, h:h + 1], scale)
+        pbar_scr[...] = total
+
+    @pl.when((j <= i) & (g == pl.num_programs(3) - 1))
+    def _():
+        kept = _kept(mask_ref)
+        pbar = pbar_scr[...] * (1.0 / heads)
+        logq = s_ref[0] - logz_ref[0]
+        live = kept & (pbar > 0.0)
+        safe = jnp.where(live, pbar, 1.0)
+        part = jnp.where(live, safe * (jnp.log(safe) - logq), 0.0)
+        part_ref[0, 0, 0] = jnp.broadcast_to(
+            part.sum(-1, keepdims=True).sum(0, keepdims=True),
+            part_ref.shape[3:])
+        ds_ref[0] = jnp.where(kept, jnp.exp(logq), 0.0) - pbar
+
+
+def _idx_bwd_kernel(ds_ref, q_ref, k_ref, w_ref, dq_ref, dk_ref, dw_ref):
+    """One (batch row, q block, key block) of the indexer's backward:
+    the tile of dI to the heads' queries and weights (summed over the
+    key blocks in their resident blocks) and to the ONE key (summed over
+    every tile of the sequence in its resident block)."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    block = k_ref.shape[1]
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
+
+    @pl.when(j == 0)
+    def _():
+        dq_ref[...] = jnp.zeros(dq_ref.shape, jnp.float32)
+        dw_ref[...] = jnp.zeros(dw_ref.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        ds, k, w = ds_ref[0], k_ref[0], w_ref[0]
+        rows = pl.ds(pl.multiple_of(j * block, block), block)
+        dk = jnp.zeros(k.shape, jnp.float32)
+        for h in range(q_ref.shape[1]):
+            q = q_ref[0, h]
+            r = _dot_t(q, k)
+            g = jnp.where(r > 0.0, ds * w[:, h:h + 1], 0.0).astype(k.dtype)
+            dq_ref[0, h] += _dot(g, k)
+            dk = dk + _dot_c0(g, q)
+            dw_ref[0, :, h:h + 1] += (ds * jnp.maximum(r, 0.0)).sum(
+                -1, keepdims=True)
+        dk_ref[0, rows, :] += dk
+
+
+# ------------------------------------------------------------ pallas calls
+
+def _causal(j, i):
+    return jnp.minimum(j, i)
+
+
+def _params(*semantics):
+    return _compiler_params(*semantics, vmem_limit=_VMEM)
+
+
+def _scores_pallas(q_t, k_idx, w, *, block, interpret):
+    """I (b, T, T) float32 from q_t (b, H, T, di), k_idx (b, T, di) and
+    w (b, T, H): the tiles at or below the diagonal are written, the
+    rest is never read."""
+    b, heads, t, di = q_t.shape
+    n = t // block
+    return pl.pallas_call(
+        _scores_kernel,
+        grid=(b, n, n),
+        in_specs=[
+            pl.BlockSpec((1, heads, block, di), lambda b_, i, j: (b_, 0, i, 0)),
+            pl.BlockSpec((1, block, di),
+                         lambda b_, i, j: (b_, _causal(j, i), 0)),
+            pl.BlockSpec((1, block, heads), lambda b_, i, j: (b_, i, 0))],
+        out_specs=pl.BlockSpec((1, block, block),
+                               lambda b_, i, j: (b_, i, _causal(j, i))),
+        out_shape=_out_struct((b, t, t), jnp.float32, q_t),
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
+        cost_estimate=pl.CostEstimate(
+            flops=b * heads * di * t * t, transcendentals=0,
+            bytes_accessed=2 * b * t * t + q_t.size * 2),
+        interpret=interpret,
+        name="dwt_idx_scores",
+    )(q_t, k_idx, w)
+
+
+def _select_pallas(scores, *, topk, rows, chunk, interpret):
+    """(mask int8 (b, T, T), logz (b, T, 1), the kept pairs of each
+    (row block, chunk) up to the diagonal's (b, T / rows, T / chunk,
+    128), the rest unwritten) of the scores' rows."""
+    b, t, _ = scores.shape
+    row_block = pl.BlockSpec((1, rows, t), lambda b_, r: (b_, r, 0))
+    return pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk, chunk=chunk),
+        grid=(b, t // rows),
+        in_specs=[row_block],
+        out_specs=[row_block,
+                   pl.BlockSpec((1, rows, 1), lambda b_, r: (b_, r, 0)),
+                   pl.BlockSpec((1, 1, t // chunk, LANES),
+                                lambda b_, r: (b_, r, 0, 0))],
+        out_shape=[_out_struct((b, t, t), jnp.int8, scores),
+                   _out_struct((b, t, 1), jnp.float32, scores),
+                   _out_struct((b, t // rows, t // chunk, LANES), jnp.int32,
+                               scores)],
+        scratch_shapes=[pltpu.VMEM((rows, t), jnp.int32)],
+        compiler_params=_params("parallel", "parallel"),
+        cost_estimate=pl.CostEstimate(
+            flops=80 * b * t * t, transcendentals=b * t * t // 2,
+            bytes_accessed=5 * b * t * t // 2),
+        interpret=interpret,
+        name="dwt_idx_select",
+    )(scores)
+
+
+def _dims(q, k, n_kv, block):
+    """(b, t, q's lanes, head size, query heads a kv head, blocks)."""
+    b, t, lanes = q.shape
+    d = k.shape[-1] // n_kv
+    return b, t, lanes, d, lanes // d // n_kv, t // block
+
+
+def _group_specs(block, rep, d):
+    """The blocks of a grid (b, kv head g, q block i, key block j): the
+    group's q rows, the kv head's rows, the rows' per-head numbers and
+    the mask's tile."""
+    rows = pl.BlockSpec((1, block, rep * d),
+                        lambda b_, g, i, j: (b_, i, g))
+    keys = pl.BlockSpec((1, block, d),
+                        lambda b_, g, i, j: (b_, _causal(j, i), g))
+    nums = pl.BlockSpec((1, 1, block, rep),
+                        lambda b_, g, i, j: (b_, g, i, 0))
+    tile = pl.BlockSpec((1, block, block),
+                        lambda b_, g, i, j: (b_, i, _causal(j, i)))
+    return rows, keys, nums, tile
+
+
+def _fwd_pallas(q, k, v, mask, *, scale, n_kv, block, interpret):
+    """(o (b, T, H*d), lse (b, KV, T, rep)) over the mask's kept set."""
+    b, t, lanes, d, rep, n = _dims(q, k, n_kv, block)
+    rows, keys, nums, tile = _group_specs(block, rep, d)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, rep=rep, d=d),
+        grid=(b, n_kv, n, n),
+        in_specs=[rows, keys, keys, tile],
+        out_specs=[rows, nums],
+        out_shape=[_out_struct(q.shape, q.dtype, q),
+                   _out_struct((b, n_kv, t, rep), jnp.float32, q)],
+        scratch_shapes=[pltpu.VMEM((rep, block, 1), jnp.float32),
+                        pltpu.VMEM((rep, block, 1), jnp.float32),
+                        pltpu.VMEM((rep, block, d), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * b * lanes * t * t, transcendentals=b * lanes // d
+            * t * t // 2, bytes_accessed=4 * q.size + n_kv * b * t * t // 2),
+        interpret=interpret,
+        name="dwt_fa_sp_fwd",
+    )(q, k, v, mask)
+
+
+def _dq_pallas(q, k, v, do, lse, delta, mask, *, scale, n_kv, block,
+               interpret):
+    b, t, lanes, d, rep, n = _dims(q, k, n_kv, block)
+    rows, keys, nums, tile = _group_specs(block, rep, d)
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, rep=rep, d=d),
+        grid=(b, n_kv, n, n),
+        in_specs=[rows, keys, keys, rows, nums, nums, tile],
+        out_specs=rows,
+        out_shape=_out_struct(q.shape, q.dtype, q),
+        scratch_shapes=[pltpu.VMEM((block, rep * d), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        cost_estimate=pl.CostEstimate(
+            flops=3 * b * lanes * t * t, transcendentals=b * lanes // d
+            * t * t // 2, bytes_accessed=6 * q.size + n_kv * b * t * t // 2),
+        interpret=interpret,
+        name="dwt_fa_sp_bwd_dq",
+    )(q, k, v, do, lse, delta, mask)
+
+
+def _dkv_pallas(q, k, v, do, lse, delta, mask, *, scale, n_kv, block,
+                interpret):
+    b, t, lanes, d, rep, n = _dims(q, k, n_kv, block)
+
+    def below(i, j):
+        return jnp.maximum(i, j)
+
+    rows = pl.BlockSpec((1, block, rep * d),
+                        lambda b_, g, j, i: (b_, below(i, j), g))
+    keys = pl.BlockSpec((1, block, d), lambda b_, g, j, i: (b_, j, g))
+    nums = pl.BlockSpec((1, 1, block, rep),
+                        lambda b_, g, j, i: (b_, g, below(i, j), 0))
+    tile = pl.BlockSpec((1, block, block),
+                        lambda b_, g, j, i: (b_, below(i, j), j))
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, rep=rep, d=d),
+        grid=(b, n_kv, n, n),
+        in_specs=[rows, keys, keys, rows, nums, nums, tile],
+        out_specs=[keys, keys],
+        out_shape=[_out_struct(k.shape, k.dtype, k),
+                   _out_struct(v.shape, v.dtype, v)],
+        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                        pltpu.VMEM((block, d), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "parallel",
+                                "arbitrary"),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * b * lanes * t * t, transcendentals=b * lanes // d
+            * t * t // 2, bytes_accessed=6 * q.size + n_kv * b * t * t // 2),
+        interpret=interpret,
+        name="dwt_fa_sp_bwd_dkv",
+    )(q, k, v, do, lse, delta, mask)
+
+
+def _kl_pallas(q, k, lse, mask, scores, logz, *, scale, n_kv, block,
+               interpret):
+    """(the tiles' parts of the KL sum (b, n, n, 8, 128), softmax_S(I) -
+    pbar (b, T, T) in the scores' own buffer): tiles above the diagonal
+    are written by neither."""
+    b, t, lanes, d, rep, n = _dims(q, k, n_kv, block)
+    tile = pl.BlockSpec((1, block, block),
+                        lambda b_, i, j, g: (b_, i, _causal(j, i)))
+    return pl.pallas_call(
+        functools.partial(_kl_kernel, scale=scale, rep=rep, d=d,
+                          heads=lanes // d),
+        grid=(b, n, n, n_kv),
+        in_specs=[
+            pl.BlockSpec((1, block, rep * d),
+                         lambda b_, i, j, g: (b_, i, g)),
+            pl.BlockSpec((1, block, d),
+                         lambda b_, i, j, g: (b_, _causal(j, i), g)),
+            pl.BlockSpec((1, 1, block, rep),
+                         lambda b_, i, j, g: (b_, g, i, 0)),
+            tile, tile,
+            pl.BlockSpec((1, block, 1), lambda b_, i, j, g: (b_, i, 0))],
+        out_specs=[
+            pl.BlockSpec((1, 1, 1, 8, LANES),
+                         lambda b_, i, j, g: (b_, i, _causal(j, i), 0, 0)),
+            tile],
+        out_shape=[_out_struct((b, n, n, 8, LANES), jnp.float32, q),
+                   _out_struct((b, t, t), jnp.float32, q)],
+        scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)],
+        input_output_aliases={4: 1},
+        compiler_params=_params("parallel", "parallel", "arbitrary",
+                                "arbitrary"),
+        cost_estimate=pl.CostEstimate(
+            flops=b * lanes * t * t, transcendentals=b * lanes // d
+            * t * t // 2, bytes_accessed=2 * q.size * n + 5 * b * t * t),
+        interpret=interpret,
+        name="dwt_idx_kl",
+    )(q, k, lse, mask, scores, logz)
+
+
+def _idx_bwd_pallas(ds, q_t, k_idx, w, *, block, interpret):
+    """(dq_t, dk, dw) float32 of `_scores_pallas` from dI's tiles."""
+    b, heads, t, di = q_t.shape
+    n = t // block
+    q_block = pl.BlockSpec((1, heads, block, di),
+                           lambda b_, i, j: (b_, 0, i, 0))
+    w_block = pl.BlockSpec((1, block, heads), lambda b_, i, j: (b_, i, 0))
+    return pl.pallas_call(
+        _idx_bwd_kernel,
+        grid=(b, n, n),
+        in_specs=[
+            pl.BlockSpec((1, block, block),
+                         lambda b_, i, j: (b_, i, _causal(j, i))),
+            q_block,
+            pl.BlockSpec((1, block, di),
+                         lambda b_, i, j: (b_, _causal(j, i), 0)),
+            w_block],
+        out_specs=[q_block,
+                   pl.BlockSpec((1, t, di), lambda b_, i, j: (b_, 0, 0)),
+                   w_block],
+        out_shape=[_out_struct(q_t.shape, jnp.float32, q_t),
+                   _out_struct(k_idx.shape, jnp.float32, q_t),
+                   _out_struct(w.shape, jnp.float32, q_t)],
+        compiler_params=_params("parallel", "arbitrary", "arbitrary"),
+        cost_estimate=pl.CostEstimate(
+            flops=3 * b * heads * di * t * t, transcendentals=0,
+            bytes_accessed=2 * b * t * t + 3 * q_t.size * 4),
+        interpret=interpret,
+        name="dwt_idx_bwd",
+    )(ds, q_t, k_idx, w)
+
+
+_scores = jax.jit(_scores_pallas, static_argnames=("block", "interpret"))
+_select = jax.jit(_select_pallas,
+                  static_argnames=("topk", "rows", "chunk", "interpret"))
+_STATIC = ("scale", "n_kv", "block", "interpret")
+_fwd = jax.jit(_fwd_pallas, static_argnames=_STATIC)
+_dq = jax.jit(_dq_pallas, static_argnames=_STATIC)
+_dkv = jax.jit(_dkv_pallas, static_argnames=_STATIC)
+_kl = jax.jit(_kl_pallas, static_argnames=_STATIC)
+_idx_bwd = jax.jit(_idx_bwd_pallas, static_argnames=("block", "interpret"))
+
+
+# ----------------------------------------------------- the kernel route
+
+def _float0(x):
+    return np.zeros(x.shape, jax.dtypes.float0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _attend_kernels(q, k, v, mask, plan):
+    return tuple(_fwd(q, k, v, mask, **dict(plan)))
+
+
+def _attend_fwd(q, k, v, mask, plan):
+    o, lse = _fwd(q, k, v, mask, **dict(plan))
+    return (o, lse), (q, k, v, mask, o, lse)
+
+
+def _attend_bwd(plan, kept, cotangents):
+    q, k, v, mask, o, lse = kept
+    do = cotangents[0]  # the log-sums feed constants only
+    b, t, _ = q.shape
+    n_kv, rep = lse.shape[1], lse.shape[3]
+    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
+        b, t, n_kv, rep, -1).sum(-1).transpose(0, 2, 1, 3)
+    dq = _dq(q, k, v, do, lse, delta, mask, **dict(plan))
+    dk, dv = _dkv(q, k, v, do, lse, delta, mask, **dict(plan))
+    return dq, dk, dv, _float0(mask)
+
+
+_attend_kernels.defvjp(_attend_fwd, _attend_bwd)
+
+
+def _tile_sum(parts):
+    """The parts of the tiles at or below the diagonal, summed."""
+    n = parts.shape[1]
+    return jnp.where(jnp.tril(jnp.ones((n, n), bool)),
+                     parts[..., 0, 0], 0.0).sum()
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+def _kl_kernels(q_t, k_idx, w, scores, mask, logz, q, k, lse, plan):
+    return _tile_sum(_kl(q, k, lse, mask, scores, logz, **dict(plan))[0])
+
+
+def _kl_fwd(q_t, k_idx, w, scores, mask, logz, q, k, lse, plan):
+    parts, ds = _kl(q, k, lse, mask, scores, logz, **dict(plan))
+    # logz, q, k and lse live on as the attention's own residuals
+    return _tile_sum(parts), (ds, q_t, k_idx, w, mask, logz, q, k, lse)
+
+
+def _kl_bwd(plan, kept, g):
+    ds, q_t, k_idx, w, mask, *constants = kept
+    plan = dict(plan)
+    with jax.named_scope("scores"):  # the scores' own backward
+        dq, dk, dw = _idx_bwd(ds, q_t, k_idx, w, block=plan["block"],
+                              interpret=plan["interpret"])
+    # the constants' cotangents meet a stop_gradient: never computed
+    return ((g * dq).astype(q_t.dtype), (g * dk).astype(k_idx.dtype),
+            (g * dw).astype(w.dtype), jnp.zeros_like(ds), _float0(mask),
+            *(jnp.zeros_like(x) for x in constants))
+
+
+_kl_kernels.defvjp(_kl_fwd, _kl_bwd)
+
+
+def _sparse_kernels(q, k, v, q_idx, k_idx, w, topk, scale, block=None,
+                    rows=None, interpret=False):
+    """`sparse_attention` on the kernel route whatever the route says
+    (tests reach the kernels in interpret mode through here)."""
+    b, t, n_kv = *q.shape[:2], k.shape[2]
+    block = block or _BLOCK
+    rows = min(rows or _SELECT_ROWS, block)
+    heads, d = q.shape[2:]
+    stop = jax.lax.stop_gradient
+    plan = (("scale", scale), ("n_kv", n_kv), ("block", block),
+            ("interpret", interpret))
+    q_t = q_idx.transpose(0, 2, 1, 3)  # a head's rows together
+    w = w.astype(jnp.float32)
+    with jax.named_scope("index/scores"):
+        scores = _scores(stop(q_t), stop(k_idx), stop(w), block=block,
+                         interpret=interpret)
+    with jax.named_scope("select"):
+        mask, logz, counts = _select(scores, topk=topk, rows=rows,
+                                     chunk=block, interpret=interpret)
+        # a tile's kept pairs: its row blocks' counts
+        tiles = counts[..., 0].reshape(b, t // block, block // rows,
+                                       t // block).sum(2)
+    with jax.named_scope("attend"):
+        rows_of = [x.reshape(b, t, -1) for x in (q, k, v)]
+        o, lse = _attend_kernels(*rows_of, mask, plan)
+    with jax.named_scope("index_loss"):
+        kl = _kl_kernels(q_t, k_idx, w, scores, mask, logz,
+                         stop(rows_of[0]), stop(rows_of[1]), stop(lse), plan)
+    return o.reshape(b, t, heads, d), kl / (b * t), mask, tiles
+
+
+# ------------------------------------------------------------- the entry
+
+def _sparse_plain(q, k, v, q_idx, k_idx, w, topk, scale):
+    stop = jax.lax.stop_gradient
+    with jax.named_scope("index"):
+        scores = _plain_scores(q_idx, k_idx, w)
+    with jax.named_scope("select"):
+        mask = _plain_select(stop(scores), topk)
+    with jax.named_scope("attend"):
+        o, p = _plain_attend(q, k, v, mask, scale)
+    with jax.named_scope("index_loss"):
+        kl = _plain_kl(scores, mask, stop(p.mean(1)))
+    return o, kl / math.prod(q.shape[:2]), mask, tiles_of(mask, _BLOCK)
+
+
+def tiles_of(mask, block: int):
+    """The kept pairs of each score tile of side `block` (the whole
+    sequence's where it is shorter), (b, n, n) int32, of a (b, T, T)
+    choice."""
+    b, t, _ = mask.shape
+    block = min(block, t)
+    n = t // block
+    return mask[:, :n * block, :n * block].astype(jnp.int32).reshape(
+        b, n, block, n, block).sum((2, 4))
+
+
+def tile_counts(tiles):
+    """(kept pairs, score tiles that hold a kept pair, causal tiles) of
+    a (b, n, n) count of kept pairs a tile, float32: data, counted from
+    the step's own choice; what lies above the diagonal's tiles is not
+    read."""
+    b, n, _ = tiles.shape
+    tiles = jnp.where(jnp.tril(jnp.ones((n, n), bool)), tiles, 0)
+    return (tiles.sum().astype(jnp.float32),
+            (tiles > 0).sum().astype(jnp.float32),
+            jnp.float32(b * n * (n + 1) // 2))
+
+
+def sparse_attention(q, k, v, q_idx, k_idx, w, topk: int, scale=None,
+                     mesh=None):
+    """(o, index KL, stats) of the module docstring's equations.
+
+    q (b, T, H, d), k and v (b, T, KV, d), rotated and normed by the
+    caller; q_idx (b, T, Hi, di), k_idx (b, T, di) and w (b, T, Hi) the
+    indexer's, w carrying every constant factor.  The cotangent of o
+    reaches q, k and v alone and the KL's the indexer's three alone (the
+    choice is a constant, q and k are constants to the KL): the caller
+    detaches what FEEDS the indexer.  `stats` is (kept pairs, causal
+    pairs, live tiles, causal tiles, tiles the implementation computes)
+    of this call, float32 scalars."""
+    b, t, heads, d = q.shape
+    scale = scale or 1.0 / math.sqrt(d)
+    with jax.named_scope("sparse_attn"):
+        if sparse_route(t, d, q_idx.shape[-1], mesh) == "kernel":
+            o, kl, _, tiles = _sparse_kernels(q, k, v, q_idx, k_idx, w,
+                                              topk, scale)
+        else:
+            o, kl, _, tiles = _sparse_plain(q, k, v, q_idx, k_idx, w, topk,
+                                            scale)
+        with jax.named_scope("counters"):
+            kept, live, causal_tiles = tile_counts(
+                jax.lax.stop_gradient(tiles))
+    stats = jnp.stack([kept, jnp.float32(b * t * (t + 1) // 2), live,
+                       causal_tiles, causal_tiles])
+    return o, kl, stats

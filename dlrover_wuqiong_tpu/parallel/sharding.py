@@ -113,6 +113,12 @@ TRANSFORMER_RULES: List[Rule] = [
     (r".*short_conv.*in_proj/kernel$", P("fsdp", "tp")),
     (r".*short_conv.*out_proj/kernel$", P("tp", "fsdp")),
     (r".*short_conv.*/conv_kernel$", P()),
+    # a sparse attention's indexer (models/sparse_indexer.py): its query
+    # heads column-parallel as q's are; the ONE key's projection and the
+    # weight-a-head's are a few columns and stay whole; its LayerNorm
+    # goes by the norms' rule below
+    (r".*indexer.*wq_idx/kernel$", P("fsdp", "tp")),
+    (r".*indexer.*(wk_idx|w_proj)/kernel$", P("fsdp", None)),
     (r".*selection_bias$", P()),
     # lm head: vocab-parallel
     (r".*(lm_head|output_proj)/kernel$", P("fsdp", "tp")),

@@ -320,8 +320,10 @@ def train_state_shardings(state_like: TrainState, planner: ShardingPlanner,
 def make_lm_loss(model_apply: Callable) -> Callable:
     """Standard causal-LM loss over a batch dict {input_ids, labels}.
 
-    Collects sown auxiliary losses (MoE load-balancing, router z-loss)
-    and a multi-token-prediction module's weighted cross-entropy
+    Collects sown auxiliary losses (MoE load-balancing, router z-loss;
+    the sparse-attention indexers' KL terms, `models/attention.
+    collect_attention_aux_loss`, with the bare cross-entropy beside them
+    in the stats as `ce`) and a multi-token-prediction module's weighted cross-entropy
     (`models/latent_moe.collect_mtp_loss`: its target is `labels` one
     further on) when present.  `loss_fn.with_stats(params, batch) ->
     (loss, stats)` is the same loss with what the MoE layers, the
@@ -338,11 +340,14 @@ def make_lm_loss(model_apply: Callable) -> Callable:
         logits, updates = model_apply(
             {"params": params}, batch["input_ids"],
             mutable=["intermediates"])
-        loss = cross_entropy_loss(logits, batch["labels"])
+        loss = ce = cross_entropy_loss(logits, batch["labels"])
         inter = updates.get("intermediates", {})
         stats = {}
         if inter:
-            from ..models.attention import collect_attention_stats
+            from ..models.attention import (
+                collect_attention_aux_loss,
+                collect_attention_stats,
+            )
             from ..models.gated_delta import collect_delta_stats
             from ..models.hyper_connection import collect_residual_stats
             from ..models.kda import collect_kda_stats
@@ -361,6 +366,9 @@ def make_lm_loss(model_apply: Callable) -> Callable:
                      **collect_kda_stats(inter),
                      **collect_shortconv_stats(inter),
                      **collect_residual_stats(inter)}
+            if "index_kl" in stats:  # the two terms, seen apart
+                stats["ce"] = ce
+                loss = loss + collect_attention_aux_loss(inter)
             mtp = collect_mtp_loss(inter, batch["labels"])
             if mtp is not None:
                 loss, stats["mtp_ce"] = loss + mtp[0], mtp[1]

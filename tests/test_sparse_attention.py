@@ -1,0 +1,211 @@
+"""`ops/sparse_attention.py`: the kernel route (interpret mode) against
+the plain route and both against a gather-and-softmax written here — the
+chosen set with ties, the forward, the KL term, the VJP of every operand;
+the route's truth table; the counters; and that a model WITHOUT the new
+`LlamaConfig` fields lowers to the text it lowered to before them.
+"""
+
+import dataclasses
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_wuqiong_tpu.ops import sparse_attention as sa
+
+B, T, H, KV, D, HI, DI, TOPK = 2, 64, 4, 2, 128, 2, 16, 16
+SCALE = 0.1
+
+
+@pytest.fixture(scope="module")
+def operands():
+    ks = jax.random.split(jax.random.PRNGKey(0), 7)
+    f = jnp.float32
+    return dict(
+        q=jax.random.normal(ks[0], (B, T, H, D), f) * 0.3,
+        k=jax.random.normal(ks[1], (B, T, KV, D), f) * 0.3,
+        v=jax.random.normal(ks[2], (B, T, KV, D), f),
+        q_idx=jax.random.normal(ks[3], (B, T, HI, DI), f),
+        k_idx=jax.random.normal(ks[4], (B, T, DI), f),
+        w=jax.random.normal(ks[5], (B, T, HI), f),
+        ct=jax.random.normal(ks[6], (B, T, H, D), f))
+
+
+NAMES = ("q", "k", "v", "q_idx", "k_idx", "w")
+
+
+def _sorted_sets(q_idx, k_idx, w):
+    """Each query's top-k causal keys by a stable sort of its own scores:
+    (indices (B, T, TOPK) padded with 0, which of them are real)."""
+    scores = np.asarray((jnp.maximum(jnp.einsum(
+        "bthd,bsd->bhts", q_idx, k_idx), 0.0)
+        * w.transpose(0, 2, 1)[..., None]).sum(1))
+    idx = np.zeros((B, T, TOPK), np.int32)
+    real = np.zeros((B, T, TOPK), bool)
+    for b in range(B):
+        for t in range(T):
+            kept = np.sort(np.argsort(-scores[b, t, :t + 1],
+                                      kind="stable")[:TOPK])
+            idx[b, t, :len(kept)], real[b, t, :len(kept)] = kept, True
+    return idx, real
+
+
+def _gathered(q, k, v, q_idx, k_idx, w, ct, sets):
+    """The equations over a GATHER of each query's kept keys: a softmax
+    over TOPK gathered keys, never a (T x T) mask."""
+    idx, real = sets
+    rows = jnp.arange(B)[:, None, None]
+    rep = H // KV
+    kk, vv = (jnp.repeat(a[rows, idx], rep, axis=3) for a in (k, v))
+    att = jnp.einsum("bthd,btshd->bths", q, kk) * SCALE
+    p = jax.nn.softmax(jnp.where(real[:, :, None], att, -jnp.inf), -1)
+    out = jnp.einsum("bths,btshd->bthd", p, vv)
+    scores = (jnp.maximum(jnp.einsum("bthd,btsd->bths", q_idx,
+                                     k_idx[rows, idx]), 0.0)
+              * w[..., None]).sum(2)
+    logq = jax.nn.log_softmax(jnp.where(real, scores, -jnp.inf), -1)
+    pbar = jax.lax.stop_gradient(p.mean(2))
+    kl = jnp.where(real, pbar * (jnp.log(jnp.where(real, pbar, 1.0))
+                                 - jnp.where(real, logq, 0.0)), 0.0).sum()
+    return (out * ct).sum() + 3.0 * kl / (B * T), (out, kl / (B * T), sets)
+
+
+def _route(which):
+    def run(q, k, v, q_idx, k_idx, w, ct):
+        if which == "plain":
+            o, kl, mask, _ = sa._sparse_plain(q, k, v, q_idx, k_idx, w, TOPK,
+                                           SCALE)
+        else:
+            o, kl, mask, _ = sa._sparse_kernels(
+                q, k, v, q_idx, k_idx, w, TOPK, SCALE, block=16, rows=8,
+                interpret=True)
+        return (o * ct).sum() + 3.0 * kl, (o, kl, mask)
+    return run
+
+
+@pytest.fixture(scope="module")
+def results(operands):
+    import functools
+
+    with jax.default_matmul_precision("highest"):
+        sets = _sorted_sets(*(operands[n] for n in NAMES[3:]))
+        return {name: jax.value_and_grad(
+            fn, argnums=tuple(range(6)), has_aux=True)(*operands.values())
+            for name, fn in (
+                ("gathered", functools.partial(_gathered, sets=sets)),
+                ("plain", _route("plain")), ("kernel", _route("kernel")))}
+
+
+@pytest.mark.parametrize("route", ("plain", "kernel"))
+def test_the_chosen_set_is_the_sorted_one(results, route):
+    idx, real = results["gathered"][0][1][2]
+    mask = np.asarray(results[route][0][1][2]) != 0
+    for b in range(B):
+        for t in range(T):
+            assert np.array_equal(np.flatnonzero(mask[b, t, :t + 1]),
+                                  idx[b, t][real[b, t]]), (b, t)
+
+
+@pytest.mark.parametrize("route", ("plain", "kernel"))
+def test_forward_and_kl_are_the_gathered_softmaxs(results, route):
+    (_, (want_o, want_kl, _)), _ = results["gathered"]
+    (_, (o, kl, _)), _ = results[route]
+    np.testing.assert_allclose(o, want_o, rtol=1e-4, atol=1e-5)
+    assert abs(float(kl) - float(want_kl)) < 1e-5 * float(want_kl)
+
+
+@pytest.mark.parametrize("route", ("plain", "kernel"))
+def test_the_vjp_is_the_gathered_softmaxs(results, route):
+    """Every operand's cotangent, of the output and of the KL."""
+    for operand, name in enumerate(NAMES):
+        want = results["gathered"][1][operand]
+        np.testing.assert_allclose(
+            results[route][1][operand], want, rtol=1e-3,
+            atol=1e-5 * float(jnp.abs(want).max()) + 1e-7, err_msg=name)
+
+
+def test_the_terms_reach_their_own_operands_alone(operands):
+    """The output's cotangent reaches q, k, v and no indexer operand;
+    the KL's the indexer's three and none of q, k, v."""
+    args = [operands[n] for n in NAMES]
+    for pick, reached in ((0, (0, 1, 2)), (1, (3, 4, 5))):
+        def fn(*a):
+            out = sa._sparse_kernels(*a, TOPK, SCALE, block=16, rows=8,
+                                     interpret=True)
+            return out[pick].sum()
+
+        grads = jax.grad(fn, argnums=tuple(range(6)))(*args)
+        for i, g in enumerate(grads):
+            assert bool(np.any(np.asarray(g))) == (i in reached), NAMES[i]
+
+
+@pytest.mark.parametrize("steps", (2.0, 1.0))
+def test_the_search_cuts_a_run_of_equals_at_the_lowest_keys(steps):
+    """Scores on a coarse grid (runs of equal values, -0.0 among them):
+    the kernel's threshold search and its cut by position keep what
+    `lax.top_k` keeps."""
+    scores = jnp.round(jax.random.normal(
+        jax.random.PRNGKey(3), (B, T, T)) * steps) / steps
+    mask, logz, counts = sa._select(scores, topk=TOPK, rows=8, chunk=16,
+                            interpret=True)
+    want = np.asarray(sa._plain_select(scores, TOPK))
+    tri = np.tril(np.ones((T, T), bool))
+    assert np.array_equal((np.asarray(mask) != 0)[:, tri], want[:, tri])
+    kept = np.where(want, np.asarray(scores), -np.inf)
+    np.testing.assert_allclose(
+        logz[..., 0], jax.scipy.special.logsumexp(kept, axis=-1), rtol=1e-5)
+    # a run was cut somewhere: the test tests what it says
+    last = np.asarray(scores[0, T - 1])
+    thr = last[want[0, T - 1]].min()
+    assert (last == thr).sum() > want[0, T - 1][last == thr].sum() > 0
+
+
+def test_the_entry_counts_from_the_choice_itself(operands):
+    args = [operands[n] for n in NAMES]
+    _, _, stats = sa.sparse_attention(*args, TOPK, SCALE)
+    kept, causal, live, tiles, run = (float(x) for x in stats)
+    assert kept == B * sa.kept_pairs(T, TOPK)
+    assert causal == B * T * (T + 1) // 2
+    assert live == tiles == run == B  # T under a block: one tile
+    mask = sa._plain_select(sa._plain_scores(*args[3:]), TOPK)
+    kept16, live16, tiles16 = (float(x) for x in sa.tile_counts(
+        sa.tiles_of(mask, 16)))
+    # the kernel route's own count of the same tiles
+    *_, tiles = sa._sparse_kernels(*args, TOPK, SCALE, block=16, rows=8,
+                                   interpret=True)
+    tri = np.tril(np.ones((4, 4), bool))
+    assert np.array_equal(np.asarray(tiles)[:, tri],
+                          np.asarray(sa.tiles_of(mask, 16))[:, tri])
+    assert kept16 == kept and tiles16 == B * 4 * 5 // 2
+    assert 0 < live16 <= tiles16
+
+
+def test_the_route_is_the_shapes_and_the_site(on_tpu):
+    assert sa.sparse_route(16384, 128, 64) == "kernel"
+    assert sa.sparse_route(16384 + 8, 128, 64) == "plain"  # no whole block
+    assert sa.sparse_route(16384, 64, 64) == "plain"  # half a slab a head
+    assert sa.sparse_route(8, 128, 64) == "plain"  # a parameter draw
+
+
+def test_off_the_chip_the_route_is_plain():
+    assert sa.sparse_route(16384, 128, 64) == "plain"
+
+
+def test_a_model_without_the_fields_lowers_to_the_text_it_did():
+    """`LlamaConfig`'s new fields default off: a Llama nano's loss and
+    gradient (per-head QK norm on, the rest default) lower to the text of
+    the commit before them (sha256 taken there)."""
+    from dlrover_wuqiong_tpu.models.llama import Llama, LlamaConfig
+    from dlrover_wuqiong_tpu.trainer.train_step import make_lm_loss
+
+    cfg = dataclasses.replace(LlamaConfig.nano(), qk_head_norm=True)
+    assert not cfg.attn_index_topk
+    model = Llama(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    ids = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    text = jax.jit(jax.value_and_grad(make_lm_loss(model.apply))).lower(
+        params, {"input_ids": ids, "labels": ids}).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        "4b7e13c9b71a33015882e6eb9419fbba28302a326b7559711c183af459bb08d0"
