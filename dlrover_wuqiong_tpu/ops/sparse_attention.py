@@ -29,7 +29,33 @@ never a knob):
   attention a kv head's GROUP of query heads a grid step, every causal
   tile computed and masked by the choice (dense work, the mathematics of
   the kept set: the counters say so — `tiles_run` = `tiles_causal`; a
-  grid that skips tiles without a kept pair is a later change);
+  grid that skips tiles without a kept pair is a later change).  What a
+  masked tile costs the forward: the int8 tile becomes ONE float32 bias
+  a grid step (0 kept, `_NEG` not), added to each of the group's heads'
+  scores — no select a head, and none after the exponent, because the
+  running maximum starts at a floor ABOVE a masked score (`_NEG / 2`):
+  a masked entry's exponent is exactly 0 whatever the row has seen, a
+  row whose leading key blocks hold no kept key included.  The scores
+  live in base 2 (float32 `s * (scale * log2 e)`, one multiply on the
+  tile, `exp2` bare; q stays the bfloat16 it was) and the log-sum goes
+  back to the natural one, `(m + log2 l) ln 2`, where it is written.
+  A forward step takes `_fwd_blocks`' (512 q rows x 1,024 keys), two
+  tiles of keys: a head's row state (m, l, alpha: (512, 1) columns, a
+  quarter of a tile pass each) and the rescale of its (512 x 128)
+  accumulator are paid once for 1,024 keys; where the diagonal crosses
+  such a block only the tiles at or below it run (`_fwd_bands`), and a
+  sequence of an odd number of tiles keeps (512 x 512).  Inside a
+  step the group's eight first products come first, then its eight
+  softmaxes, then its eight products with v: the MXU's and the vector
+  units' work in long runs.  Measured by op at the cell's shape
+  (PERF.md section 6, PR 63): 30.3 ms a call as (512 x 512) with two
+  selects, 18.1 as (512 x 1,024) under the bias in base 2 head after
+  head, 15.2 as this; (1024 x 512) 26.6 and (1024 x 1024) 42.3 head
+  after head.  `_BLOCK` stays the tile of the mask's COUNTERS
+  (`tiles_of`, `tile_counts`, the choice's `chunk`): a live tile is a
+  (512 x 512) one, whatever a forward step takes.  The other kernels
+  keep (512 x 512) a step; their `_probs` takes the same bias and the
+  same folded constant against `lse * log2 e`, a column.
   `dwt_idx_kl` recomputes the heads' probabilities from the saved
   log-sums a tile at a time, sums them over the heads in VMEM (no
   (heads x T x T) array), and leaves the tile of `softmax_S(I) - pbar`
@@ -70,10 +96,13 @@ from .mosaic import (
 )
 
 _BLOCK = 512      # positions a tile's side: q rows, keys, the mask's tile
+_FWD_TILES = (1, 2)  # tiles a forward step's (q rows, keys) take: swept
 _SELECT_ROWS = 128  # rows whose whole score row sits in VMEM for the search
 _SITES = frozenset({"device"})  # a whole sequence's keys: no shard is one
 _VMEM = 96 * 1024 * 1024
 _NEG = -1e30  # a masked score: finite, so an empty tile's row stays finite
+_LN2 = math.log(2.0)
+_LOG2E = 1.0 / _LN2  # exp(x) = exp2(x * _LOG2E): the exponent unit's base
 _INT_MIN = -2 ** 31
 
 
@@ -263,45 +292,101 @@ def _kept(mask_ref):
     return mask_ref[0].astype(jnp.float32) > 0.0
 
 
+def _bias_of(mask):
+    """The choice as what is ADDED to a score, float32: 0 on a kept
+    entry, `_NEG` elsewhere.  Formed once a grid step for the group's
+    heads; a score under it leaves every exponent as exactly 0."""
+    return jnp.where(mask.astype(jnp.float32) > 0.0, 0.0, _NEG)
+
+
+def _fwd_blocks(t: int, block: int):
+    """(q rows, keys) a grid step of the forward takes, in whole tiles of
+    `block`: `_FWD_TILES` of them each way where the sequence is whole
+    such blocks, else one."""
+    return tuple(n * block if t % (n * block) == 0 else block
+                 for n in _FWD_TILES)
+
+
+def _fwd_bands(bq: int, bk: int, rel: int, tile: int):
+    """[(q0, q1, k_end)] of a (bq x bk) block whose first query lies
+    `rel` positions after its first key: the rows [q0, q1) run the keys
+    [0, k_end), whole tiles at or below the diagonal (the choice masks
+    inside them), rows of one reach joined; a row tile that sees no key
+    of the block is in no band."""
+    bands = []
+    for q0 in range(0, bq, tile):
+        k_end = min(bk, max(0, (q0 + rel) // tile + 1) * tile)
+        if bands and bands[-1][2] == k_end:
+            bands[-1] = (bands[-1][0], q0 + tile, k_end)
+        elif k_end:
+            bands.append((q0, q0 + tile, k_end))
+    return bands
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, rep, d):
+                m_scr, l_scr, acc_scr, *, scale, rep, d, tile):
     """One (batch row, kv head, q block, key block): the group's `rep`
-    query heads against the one kv head, online softmax over the kept
-    entries of the tile."""
+    query heads against the one kv head, online softmax in base 2 over
+    the tile's scores under the choice's bias."""
+    bq, bk = mask_ref.shape[1:]
     i, j = pl.program_id(2), pl.program_id(3)
+    rel = i * bq - j * bk  # the block's first query less its first key
+    to_log2 = scale * _LOG2E
 
     @pl.when(j == 0)
     def _():
-        m_scr[...] = jnp.full(m_scr.shape, _NEG, jnp.float32)
+        # a floor ABOVE a masked score: exp2(masked - m) is exactly 0
+        # whatever the row has seen, a row of no kept key yet included
+        m_scr[...] = jnp.full(m_scr.shape, _NEG / 2, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(j <= i)
-    def _():
-        kept = _kept(mask_ref)
-        k, v = k_ref[0], v_ref[0]
-        for h in range(rep):
-            s = _dot_t(q_ref[0, :, h * d:(h + 1) * d], k) * scale
-            s = jnp.where(kept, s, _NEG)
-            m_old = m_scr[h]
-            m_new = jnp.maximum(m_old, s.max(-1, keepdims=True))
-            p = jnp.where(kept, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_old - m_new)
-            l_scr[h] = alpha * l_scr[h] + p.sum(-1, keepdims=True)
-            acc_scr[h] = alpha * acc_scr[h] + _dot(p.astype(v.dtype), v)
-            m_scr[h] = m_new
+    def run(bands):
+        for q0, q1, k_end in bands:
+            rows = slice(q0, q1)
+            bias = _bias_of(mask_ref[0, rows, :k_end])
+            k, v = k_ref[0, :k_end], v_ref[0, :k_end]
+            # the group's first products, THEN its softmaxes, THEN its
+            # products with v: head after head (product, softmax,
+            # product) the same work took 18.2 ms a call where this
+            # order takes 15.2 (PERF.md section 6, PR 63)
+            scores = [_dot_t(q_ref[0, rows, h * d:(h + 1) * d], k)
+                      for h in range(rep)]
+            probs = []
+            for h, s in enumerate(scores):
+                s = s * to_log2 + bias
+                m_old = m_scr[h, rows]
+                m_new = jnp.maximum(m_old, s.max(-1, keepdims=True))
+                p = jnp.exp2(s - m_new)
+                alpha = jnp.exp2(m_old - m_new)
+                l_scr[h, rows] = alpha * l_scr[h, rows] + p.sum(
+                    -1, keepdims=True)
+                m_scr[h, rows] = m_new
+                probs.append((alpha, p.astype(v.dtype)))
+            for h, (alpha, p) in enumerate(probs):
+                acc_scr[h, rows] = alpha * acc_scr[h, rows] + _dot(p, v)
 
-    @pl.when(j == i)
+    # below the diagonal every tile of the block runs; a block it
+    # crosses runs the tiles at or below it, by its static place
+    pl.when(rel >= bk - tile)(lambda: run([(0, bq, bk)]))
+    for at in range(tile - bq, bk - tile, tile):
+        if at % math.gcd(bq, bk) == 0:
+            pl.when(rel == at)(functools.partial(
+                run, _fwd_bands(bq, bk, at, tile)))
+
+    @pl.when(j == ((i + 1) * bq - 1) // bk)
     def _():
         for h in range(rep):
             o_ref[0, :, h * d:(h + 1) * d] = (
                 acc_scr[h] / l_scr[h]).astype(o_ref.dtype)
-            lse_ref[0, 0, :, h:h + 1] = m_scr[h] + jnp.log(l_scr[h])
+            lse_ref[0, 0, :, h:h + 1] = (
+                m_scr[h] + jnp.log2(l_scr[h])) * _LN2
 
 
-def _probs(q, k, kept, lse, scale):
-    """A head's probabilities on the tile from its saved log-sum."""
-    return jnp.where(kept, jnp.exp(_dot_t(q, k) * scale - lse), 0.0)
+def _probs(q, k, bias, lse, scale):
+    """A head's probabilities on the tile from its saved (natural)
+    log-sum: base-2 scores under the choice's bias."""
+    return jnp.exp2(_dot_t(q, k) * (scale * _LOG2E) + bias - lse * _LOG2E)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
@@ -314,11 +399,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
 
     @pl.when(j <= i)
     def _():
-        kept = _kept(mask_ref)
+        bias = _bias_of(mask_ref[0])
         k, v = k_ref[0], v_ref[0]
         for h in range(rep):
             lanes = slice(h * d, (h + 1) * d)
-            p = _probs(q_ref[0, :, lanes], k, kept,
+            p = _probs(q_ref[0, :, lanes], k, bias,
                        lse_ref[0, 0, :, h:h + 1], scale)
             dp = _dot_t(do_ref[0, :, lanes], v)
             ds = p * (dp - delta_ref[0, 0, :, h:h + 1]) * scale
@@ -342,12 +427,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
 
     @pl.when(i >= j)
     def _():
-        kept = _kept(mask_ref)
+        bias = _bias_of(mask_ref[0])
         k, v = k_ref[0], v_ref[0]
         for h in range(rep):
             lanes = slice(h * d, (h + 1) * d)
             q, do = q_ref[0, :, lanes], do_ref[0, :, lanes]
-            p = _probs(q, k, kept, lse_ref[0, 0, :, h:h + 1], scale)
+            p = _probs(q, k, bias, lse_ref[0, 0, :, h:h + 1], scale)
             dv_scr[...] += _dot_c0(p.astype(do.dtype), do)
             ds = p * (_dot_t(do, v) - delta_ref[0, 0, :, h:h + 1]) * scale
             dk_scr[...] += _dot_c0(ds.astype(q.dtype), q)
@@ -371,11 +456,11 @@ def _kl_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, logz_ref,
 
     @pl.when(j <= i)
     def _():
-        kept = _kept(mask_ref)
+        bias = _bias_of(mask_ref[0])
         k = k_ref[0]
         total = pbar_scr[...]
         for h in range(rep):
-            total = total + _probs(q_ref[0, :, h * d:(h + 1) * d], k, kept,
+            total = total + _probs(q_ref[0, :, h * d:(h + 1) * d], k, bias,
                                    lse_ref[0, 0, :, h:h + 1], scale)
         pbar_scr[...] = total
 
@@ -497,35 +582,35 @@ def _dims(q, k, n_kv, block):
     return b, t, lanes, d, lanes // d // n_kv, t // block
 
 
-def _group_specs(block, rep, d):
-    """The blocks of a grid (b, kv head g, q block i, key block j): the
-    group's q rows, the kv head's rows, the rows' per-head numbers and
-    the mask's tile."""
-    rows = pl.BlockSpec((1, block, rep * d),
-                        lambda b_, g, i, j: (b_, i, g))
-    keys = pl.BlockSpec((1, block, d),
-                        lambda b_, g, i, j: (b_, _causal(j, i), g))
-    nums = pl.BlockSpec((1, 1, block, rep),
-                        lambda b_, g, i, j: (b_, g, i, 0))
-    tile = pl.BlockSpec((1, block, block),
-                        lambda b_, g, i, j: (b_, i, _causal(j, i)))
-    return rows, keys, nums, tile
+def _fwd_pallas(q, k, v, mask, *, scale, n_kv, block, interpret,
+                blocks=None):
+    """(o (b, T, H*d), lse (b, KV, T, rep)) over the mask's kept set, a
+    grid step `_fwd_blocks`' (q rows x keys) in tiles of `block`
+    (`blocks` overrides them: sweeps and tests, no caller of the package
+    sets it)."""
+    b, t, lanes, d, rep, _ = _dims(q, k, n_kv, block)
+    bq, bk = blocks or _fwd_blocks(t, block)
 
+    def keys_of(i, j):  # the last key block at or below the q block's end
+        return jnp.minimum(j, ((i + 1) * bq - 1) // bk)
 
-def _fwd_pallas(q, k, v, mask, *, scale, n_kv, block, interpret):
-    """(o (b, T, H*d), lse (b, KV, T, rep)) over the mask's kept set."""
-    b, t, lanes, d, rep, n = _dims(q, k, n_kv, block)
-    rows, keys, nums, tile = _group_specs(block, rep, d)
+    rows = pl.BlockSpec((1, bq, rep * d), lambda b_, g, i, j: (b_, i, g))
+    keys = pl.BlockSpec((1, bk, d),
+                        lambda b_, g, i, j: (b_, keys_of(i, j), g))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, rep=rep, d=d),
-        grid=(b, n_kv, n, n),
-        in_specs=[rows, keys, keys, tile],
-        out_specs=[rows, nums],
+        functools.partial(_fwd_kernel, scale=scale, rep=rep, d=d,
+                          tile=block),
+        grid=(b, n_kv, t // bq, t // bk),
+        in_specs=[rows, keys, keys,
+                  pl.BlockSpec((1, bq, bk),
+                               lambda b_, g, i, j: (b_, i, keys_of(i, j)))],
+        out_specs=[rows, pl.BlockSpec((1, 1, bq, rep),
+                                      lambda b_, g, i, j: (b_, g, i, 0))],
         out_shape=[_out_struct(q.shape, q.dtype, q),
                    _out_struct((b, n_kv, t, rep), jnp.float32, q)],
-        scratch_shapes=[pltpu.VMEM((rep, block, 1), jnp.float32),
-                        pltpu.VMEM((rep, block, 1), jnp.float32),
-                        pltpu.VMEM((rep, block, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((rep, bq, 1), jnp.float32),
+                        pltpu.VMEM((rep, bq, 1), jnp.float32),
+                        pltpu.VMEM((rep, bq, d), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "parallel",
                                 "arbitrary"),
         cost_estimate=pl.CostEstimate(
@@ -539,7 +624,16 @@ def _fwd_pallas(q, k, v, mask, *, scale, n_kv, block, interpret):
 def _dq_pallas(q, k, v, do, lse, delta, mask, *, scale, n_kv, block,
                interpret):
     b, t, lanes, d, rep, n = _dims(q, k, n_kv, block)
-    rows, keys, nums, tile = _group_specs(block, rep, d)
+    # a grid (b, kv head g, q block i, key block j): the group's q rows,
+    # the kv head's rows, the rows' per-head numbers and the mask's tile
+    rows = pl.BlockSpec((1, block, rep * d),
+                        lambda b_, g, i, j: (b_, i, g))
+    keys = pl.BlockSpec((1, block, d),
+                        lambda b_, g, i, j: (b_, _causal(j, i), g))
+    nums = pl.BlockSpec((1, 1, block, rep),
+                        lambda b_, g, i, j: (b_, g, i, 0))
+    tile = pl.BlockSpec((1, block, block),
+                        lambda b_, g, i, j: (b_, i, _causal(j, i)))
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, rep=rep, d=d),
         grid=(b, n_kv, n, n),

@@ -6,6 +6,7 @@ the route's truth table; the counters; and that a model WITHOUT the new
 """
 
 import dataclasses
+import functools
 import hashlib
 
 import jax
@@ -36,18 +37,19 @@ def operands():
 NAMES = ("q", "k", "v", "q_idx", "k_idx", "w")
 
 
-def _sorted_sets(q_idx, k_idx, w):
+def _sorted_sets(q_idx, k_idx, w, topk=TOPK):
     """Each query's top-k causal keys by a stable sort of its own scores:
-    (indices (B, T, TOPK) padded with 0, which of them are real)."""
+    (indices (b, t, topk) padded with 0, which of them are real)."""
     scores = np.asarray((jnp.maximum(jnp.einsum(
         "bthd,bsd->bhts", q_idx, k_idx), 0.0)
         * w.transpose(0, 2, 1)[..., None]).sum(1))
-    idx = np.zeros((B, T, TOPK), np.int32)
-    real = np.zeros((B, T, TOPK), bool)
-    for b in range(B):
-        for t in range(T):
+    n_b, n_t = scores.shape[:2]
+    idx = np.zeros((n_b, n_t, topk), np.int32)
+    real = np.zeros((n_b, n_t, topk), bool)
+    for b in range(n_b):
+        for t in range(n_t):
             kept = np.sort(np.argsort(-scores[b, t, :t + 1],
-                                      kind="stable")[:TOPK])
+                                      kind="stable")[:topk])
             idx[b, t, :len(kept)], real[b, t, :len(kept)] = kept, True
     return idx, real
 
@@ -56,8 +58,9 @@ def _gathered(q, k, v, q_idx, k_idx, w, ct, sets):
     """The equations over a GATHER of each query's kept keys: a softmax
     over TOPK gathered keys, never a (T x T) mask."""
     idx, real = sets
-    rows = jnp.arange(B)[:, None, None]
-    rep = H // KV
+    n_b, n_t = q.shape[:2]
+    rows = jnp.arange(n_b)[:, None, None]
+    rep = q.shape[2] // k.shape[2]
     kk, vv = (jnp.repeat(a[rows, idx], rep, axis=3) for a in (k, v))
     att = jnp.einsum("bthd,btshd->bths", q, kk) * SCALE
     p = jax.nn.softmax(jnp.where(real[:, :, None], att, -jnp.inf), -1)
@@ -69,43 +72,79 @@ def _gathered(q, k, v, q_idx, k_idx, w, ct, sets):
     pbar = jax.lax.stop_gradient(p.mean(2))
     kl = jnp.where(real, pbar * (jnp.log(jnp.where(real, pbar, 1.0))
                                  - jnp.where(real, logq, 0.0)), 0.0).sum()
-    return (out * ct).sum() + 3.0 * kl / (B * T), (out, kl / (B * T), sets)
+    return (out * ct).sum() + 3.0 * kl / (n_b * n_t), (
+        out, kl / (n_b * n_t), None)
 
 
-def _route(which):
+def _route(which, topk=TOPK):
     def run(q, k, v, q_idx, k_idx, w, ct):
         if which == "plain":
-            o, kl, mask, _ = sa._sparse_plain(q, k, v, q_idx, k_idx, w, TOPK,
+            o, kl, mask, _ = sa._sparse_plain(q, k, v, q_idx, k_idx, w, topk,
                                            SCALE)
         else:
             o, kl, mask, _ = sa._sparse_kernels(
-                q, k, v, q_idx, k_idx, w, TOPK, SCALE, block=16, rows=8,
+                q, k, v, q_idx, k_idx, w, topk, SCALE, block=16, rows=8,
                 interpret=True)
         return (o * ct).sum() + 3.0 * kl, (o, kl, mask)
     return run
 
 
-@pytest.fixture(scope="module")
-def results(operands):
-    import functools
-
+def _all_routes(operands, topk=TOPK):
+    """{gathered | plain | kernel: ((value, (o, kl, mask)), the six
+    operands' gradients), sets: `_sorted_sets`'}, each route one jitted
+    program (op by op they take four times as long)."""
     with jax.default_matmul_precision("highest"):
-        sets = _sorted_sets(*(operands[n] for n in NAMES[3:]))
-        return {name: jax.value_and_grad(
-            fn, argnums=tuple(range(6)), has_aux=True)(*operands.values())
+        sets = _sorted_sets(*(operands[n] for n in NAMES[3:]), topk)
+        return {"sets": sets, **{name: jax.jit(jax.value_and_grad(
+            fn, argnums=tuple(range(6)), has_aux=True))(*operands.values())
             for name, fn in (
                 ("gathered", functools.partial(_gathered, sets=sets)),
-                ("plain", _route("plain")), ("kernel", _route("kernel")))}
+                ("plain", _route("plain", topk)),
+                ("kernel", _route("kernel", topk)))}}
+
+
+# an indexer that prefers the RECENT keys: under it a late row keeps
+# nothing in its leading key blocks, which is what the floor of the
+# forward's running maximum is for
+RECENT_TOPK = 8  # under one block of 16
+RH, RKV = 2, 1   # one group of two heads: half the kernels to lower
+LONG = 80  # five tiles of 16 and no whole number of 32 keys: the
+#            forward's step is (16 x 16) there, (16 x 32) at T = 64
+
+
+def _recent_operands(t):
+    """One sequence of `t` whose indexer scores GROW with the key's
+    position (numpy draws: nothing to compile)."""
+    draw = np.random.default_rng(t).standard_normal
+    f = np.float32
+    ramp = (np.arange(t, dtype=f)[:, None] + 1.0) / t
+    return {name: jnp.asarray(x, f) for name, x in dict(
+        q=draw((1, t, RH, D)) * 0.3, k=draw((1, t, RKV, D)) * 0.3,
+        v=draw((1, t, RKV, D)), q_idx=np.abs(draw((1, t, HI, DI))),
+        k_idx=ramp[None] + 0.01 * draw((1, t, DI)),
+        w=np.abs(draw((1, t, HI))) + 0.1, ct=draw((1, t, RH, D))).items()}
+
+
+@pytest.fixture(scope="module", params=("scattered", "recent"))
+def results(request, operands):
+    """Every route's results: on the module's random operands, and on one
+    sequence of `LONG` under the indexer that prefers recent keys."""
+    if request.param == "scattered":
+        return _all_routes(operands)
+    return _all_routes(_recent_operands(LONG), RECENT_TOPK)
 
 
 @pytest.mark.parametrize("route", ("plain", "kernel"))
 def test_the_chosen_set_is_the_sorted_one(results, route):
-    idx, real = results["gathered"][0][1][2]
+    idx, real = results["sets"]
     mask = np.asarray(results[route][0][1][2]) != 0
-    for b in range(B):
-        for t in range(T):
+    for b in range(mask.shape[0]):
+        for t in range(mask.shape[1]):
             assert np.array_equal(np.flatnonzero(mask[b, t, :t + 1]),
                                   idx[b, t][real[b, t]]), (b, t)
+    if mask.shape[1] == LONG:  # late rows keep nothing in the leading tiles
+        assert not mask[0, 32:, :16].any()
+        assert not mask[0, LONG - 1, :LONG - 16].any()
 
 
 @pytest.mark.parametrize("route", ("plain", "kernel"))
@@ -126,6 +165,62 @@ def test_the_vjp_is_the_gathered_softmaxs(results, route):
             atol=1e-5 * float(jnp.abs(want).max()) + 1e-7, err_msg=name)
 
 
+def test_the_forwards_step_is_whole_blocks_of_the_sequence():
+    assert sa._fwd_blocks(T, 16) == (16, 32)
+    assert sa._fwd_blocks(LONG, 16) == (16, 16)
+    assert sa._fwd_blocks(16384, sa._BLOCK) == tuple(
+        n * sa._BLOCK for n in sa._FWD_TILES)
+    assert sa._fwd_blocks(16384 + sa._BLOCK, sa._BLOCK) == (
+        sa._BLOCK, sa._BLOCK)
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_forward():
+    """(q, k, v as rows, the choice, o, the kept scores' log-sums
+    (b, KV, T, rep)) of recent keys at T by dense lines."""
+    operands = _recent_operands(T)
+    q, k, v = (operands[n] for n in NAMES[:3])
+    with jax.default_matmul_precision("highest"):
+        mask = sa._plain_select(sa._plain_scores(
+            *(operands[n] for n in NAMES[3:])), RECENT_TOPK)
+        o, _ = sa._plain_attend(q, k, v, mask, SCALE)
+        s = jnp.einsum("bthd,bshd->bhts", q, jnp.repeat(k, RH // RKV, 2))
+    assert not mask[0, 32:, :16].any()
+    lse = jax.scipy.special.logsumexp(
+        jnp.where(mask[:, None], s * SCALE, -jnp.inf), axis=-1)
+    return ([x.reshape(1, T, -1) for x in (q, k, v)],
+            mask.astype(jnp.int8), o.reshape(1, T, -1),
+            lse.reshape(1, RKV, RH // RKV, T).transpose(0, 1, 3, 2))
+
+
+@pytest.mark.parametrize("blocks", (None, (32, 16), (32, 32), (64, 32),
+                                    (16, 64)))
+def test_every_block_geometry_of_the_forward_is_the_plain_attention(blocks):
+    """The forward's (q rows x keys) a grid step (None: its own), whole
+    tiles at or below the diagonal only, late rows' leading key blocks
+    empty: o and the natural log-sum of the kept scores."""
+    rows, mask, want_o, want_lse = _plain_forward()
+    with jax.default_matmul_precision("highest"):
+        o, lse = jax.jit(functools.partial(
+            sa._fwd_pallas, scale=SCALE, n_kv=RKV, block=16, interpret=True,
+            blocks=blocks))(*rows, mask)
+    np.testing.assert_allclose(o, want_o, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-5)
+
+
+@pytest.mark.parametrize("bq,bk,rel,bands", (
+    (1024, 512, -512, [(512, 1024, 512)]),
+    (1024, 512, 0, [(0, 1024, 512)]),
+    (512, 1024, 0, [(0, 512, 512)]),
+    (512, 1024, 512, [(0, 512, 1024)]),
+    (1024, 1024, 0, [(0, 512, 512), (512, 1024, 1024)]),
+    (1024, 1024, 1024, [(0, 1024, 1024)]),
+    (512, 512, 0, [(0, 512, 512)])))
+def test_a_block_the_diagonal_crosses_runs_the_tiles_at_or_below_it(
+        bq, bk, rel, bands):
+    assert sa._fwd_bands(bq, bk, rel, 512) == bands
+
+
 def test_the_terms_reach_their_own_operands_alone(operands):
     """The output's cotangent reaches q, k, v and no indexer operand;
     the KL's the indexer's three and none of q, k, v."""
@@ -136,7 +231,7 @@ def test_the_terms_reach_their_own_operands_alone(operands):
                                      interpret=True)
             return out[pick].sum()
 
-        grads = jax.grad(fn, argnums=tuple(range(6)))(*args)
+        grads = jax.jit(jax.grad(fn, argnums=tuple(range(6))))(*args)
         for i, g in enumerate(grads):
             assert bool(np.any(np.asarray(g))) == (i in reached), NAMES[i]
 
