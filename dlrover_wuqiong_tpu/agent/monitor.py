@@ -60,24 +60,19 @@ def get_process_resource() -> Dict[str, float]:
 
 
 def get_accelerator_stats() -> Dict[str, float]:
-    """TPU-side stats via jax (device memory where the backend exposes it)."""
-    stats: Dict[str, float] = {}
-    try:
-        import jax
+    """Device memory of the FULLEST local device (the one that dies
+    first), where this process holds devices that report any
+    (`telemetry/memory.py`: JAX is never imported from here — the agent
+    stays clear of it, and then reports none)."""
+    from ..telemetry import memory as tmemory
 
-        devs = jax.local_devices()
-        stats["num_devices"] = float(len(devs))
-        for d in devs[:1]:
-            mem = getattr(d, "memory_stats", None)
-            if callable(mem):
-                m = mem() or {}
-                stats["hbm_bytes_in_use"] = float(
-                    m.get("bytes_in_use", 0))
-                stats["hbm_bytes_limit"] = float(
-                    m.get("bytes_limit", 0))
-    except Exception:  # noqa: BLE001
-        pass
-    return stats
+    full = tmemory.reading()
+    if not full:
+        return {}
+    return {"num_devices": float(full["devices"]),
+            "hbm_bytes_in_use": float(full["bytes_in_use"]),
+            "hbm_bytes_reserved": float(full["bytes_reserved"]),
+            "hbm_bytes_limit": float(full["bytes_limit"])}
 
 
 class ResourceMonitor:

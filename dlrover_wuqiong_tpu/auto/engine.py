@@ -146,6 +146,7 @@ def score_candidate(cand: Candidate, model, optimizer, sample_batch: Dict,
     """
     import jax
 
+    from ..telemetry.memory import compiled_memory
     from .accelerate import auto_accelerate
 
     try:
@@ -164,13 +165,7 @@ def score_candidate(cand: Candidate, model, optimizer, sample_batch: Dict,
             costs = costs[0] if costs else {}
     except Exception:  # noqa: BLE001
         costs = {}
-    mem = compiled.memory_analysis()
-    peak = 0
-    if mem is not None:
-        peak = int(getattr(mem, "temp_size_in_bytes", 0)
-                   + getattr(mem, "argument_size_in_bytes", 0)
-                   + getattr(mem, "output_size_in_bytes", 0)
-                   - getattr(mem, "alias_size_in_bytes", 0))
+    peak = compiled_memory(compiled).get("live_bytes", 0)
     cand.peak_bytes = peak
     limit = hbm_per_device
     if limit and peak > limit:

@@ -33,6 +33,7 @@ from ..common.constants import CheckpointConstant
 from ..common.log import get_logger
 from ..common.multi_process import SharedLock, SharedQueue
 from ..common.storage import CheckpointStorage, get_checkpoint_storage
+from ..telemetry import memory as tmemory
 from ..telemetry import spans as tspans
 from ..telemetry.ledger import get_ledger
 from .ckpt_saver import (
@@ -283,8 +284,12 @@ class CheckpointEngine:
             # restore a stale segment left over from an unrelated job run
             extra.setdefault("_ckpt_dir", path or self.checkpoint_dir)
             try:
-                with tspans.span("ckpt:snapshot"):
+                # a save's device-side copy is where a job that trains
+                # fits and a job that saves does not
+                with tspans.span("ckpt:snapshot") as snap_rec:
+                    tmemory.note(snap_rec, "hbm_before")
                     snapshot = self._device_snapshot(state)
+                    tmemory.note(snap_rec, "hbm_after")
             except Exception as e:  # noqa: BLE001
                 # state too big to double-buffer in HBM (e.g. GPT-2 xl +
                 # AdamW on a 16GB chip): fall back to synchronous staging

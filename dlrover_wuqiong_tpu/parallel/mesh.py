@@ -136,14 +136,14 @@ def auto_plan(num_devices: int, num_params: Optional[int] = None,
 
 def detect_hbm_per_device(devices: Optional[Sequence] = None) -> int:
     """Per-device accelerator memory, from the runtime when available."""
+    from ..telemetry.memory import device_memory
+
     try:
         import jax
 
-        devices = devices or jax.devices()
-        stats = devices[0].memory_stats()
-        limit = int(stats.get("bytes_limit", 0)) if stats else 0
-        if limit > 0:
-            return limit
+        first = device_memory((devices or jax.devices())[:1])
+        if first and first[0]["bytes_limit"] > 0:
+            return first[0]["bytes_limit"]
     except Exception:  # noqa: BLE001 — CPU/older runtimes have no stats
         pass
     return 16 << 30

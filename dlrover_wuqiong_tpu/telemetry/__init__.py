@@ -61,6 +61,7 @@ from .perf import (  # noqa: F401
     reset_observatory,
     set_observatory,
     step_executables,
+    step_memory,
 )
 from .recorder import (  # noqa: F401
     FLIGHT_SCHEMA_VERSION,

@@ -595,3 +595,16 @@ def keep_step_executable(mode: Any, jitted: Any, state: Any,
 def step_executables() -> Dict[Any, Any]:
     """{mode: jax.stages.Compiled} of every step program kept so far."""
     return {mode: find() for mode, find in list(_step_executables.items())}
+
+
+def step_memory() -> Dict[Any, Dict[str, int]]:
+    """{mode: the compiled budget in bytes (`telemetry.memory.
+    compiled_memory`: argument, output, alias, temp, generated code,
+    live)} of every step program kept so far — what the step the loop
+    dispatched was compiled to hold, per device.  Asked for off the
+    loop, like `step_executables()`, whose finds it reuses."""
+    from .memory import compiled_memory
+
+    budgets = {mode: compiled_memory(compiled)
+               for mode, compiled in step_executables().items()}
+    return {mode: b for mode, b in budgets.items() if b}

@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, Iterable, Optional
 import numpy as np
 
 from ..common.log import get_logger
+from ..telemetry import memory as tmemory
 from ..telemetry import spans as tspans
 
 logger = get_logger("trainer")
@@ -39,6 +40,22 @@ logger = get_logger("trainer")
 # the step's metrics the loop already handles by name; whatever else a
 # step returns is a counted scalar the metrics pump passes on as it is
 _STEP_SERIES = ("loss", "grad_norm", "losses", "grad_norms")
+
+
+def _step_budget(mode: int) -> Dict[str, int]:
+    """The compiled budget of the step program just dispatched at
+    fusion width `mode` (`telemetry.perf.step_memory`), for
+    `trainer:first_step`'s attrs: one `lower` walk over the leaves,
+    answered by JAX's caches, once a width.  Telemetry never kills the
+    run: a program the way back does not find again has no budget."""
+    from ..telemetry.perf import step_memory
+
+    try:
+        return step_memory().get(mode, {})
+    except Exception:  # noqa: BLE001 — see docstring
+        logger.warning("no memory budget of the step at K=%d", mode,
+                       exc_info=True)
+        return {}
 
 
 @dataclasses.dataclass
@@ -266,6 +283,9 @@ class Trainer:
             tspans.boot_span(beside=rec)
             self._build(model, args, train_data, eval_data, optimizer,
                         loss_fn, callbacks)
+            # the state resident after `accelerate:init_state` and
+            # `ckpt:open` (absent where the backend reads no memory)
+            tmemory.note(rec, "hbm")
 
     def _build(self, model, args, train_data, eval_data, optimizer,
                loss_fn, callbacks):
@@ -698,6 +718,12 @@ class Trainer:
                 # executable as the loss: there once the loss is
                 counted = {k: float(v) for k, v in job["metrics"].items()
                            if k not in _STEP_SERIES}
+            # the device has finished `step` and later steps are queued:
+            # the devices' memory while the step runs, with no sync of
+            # its own and nothing on the main thread
+            hbm = tmemory.reading()
+            if hbm:
+                tspans.span_event("trainer:memory", {"step": step, **hbm})
             with tspans.hot_span("pump:report"):
                 self._report_boundary(job, step, loss, t_read, counted)
         return loss
@@ -810,6 +836,9 @@ class Trainer:
 
         span_rec["attrs"].update(start_step=start_step,
                                  restored_tier=restored_tier)
+        # what the caller and a restore left, peaks included: whether a
+        # process-wide peak predates the loop is read off this
+        tmemory.note(span_rec, "hbm_at_entry")
         last_loss = float("nan")
         metrics = None
         self._preempted = False
@@ -942,12 +971,22 @@ class Trainer:
                         self._compiled_modes.add(k_eff)
                         led.account("compile", blk_s)
                         credited_blk = blk_s
-                        # once a width: the dispatch call, and what JAX
-                        # spent inside it getting programs ready
+                        # for a reader of the step's text or budget,
+                        # afterwards (telemetry/perf.py): shapes and
+                        # shardings only, no array is kept; ~2 ms at
+                        # 1,740 leaves, once
+                        keep_step_executable(
+                            k_eff, self.res.fused_train_step(k_eff),
+                            self.state, batch)
+                        # once a width: the dispatch call, what JAX
+                        # spent inside it getting programs ready, and
+                        # what the program it dispatched was compiled
+                        # to hold (a flight dump then has the budget)
                         tspans.past_span(
                             "trainer:first_step", t_blk0, t_blk0 + blk_s,
                             {"k": k_eff, "blk_s": blk_s,
-                             **seconds_between(t_blk0, t_blk0 + blk_s)})
+                             **seconds_between(t_blk0, t_blk0 + blk_s),
+                             **_step_budget(k_eff)})
                         if self.ctx.world.restart_count and \
                                 len(self._compiled_modes) == 1:
                             # a restarted generation leaves its start-up
@@ -956,12 +995,6 @@ class Trainer:
                             # (tools/incident_report.py --restart-table)
                             get_recorder().flush(
                                 self.ckpt.checkpoint_dir, "resumed")
-                        # for a reader of the step's text, afterwards
-                        # (telemetry/perf.py): shapes and shardings only,
-                        # no array is kept; ~2 ms at 1,740 leaves, once
-                        keep_step_executable(
-                            k_eff, self.res.fused_train_step(k_eff),
-                            self.state, batch)
                     else:
                         credited_blk = min(blk_s, self._dispatch_overhead_s())
                         led.account("dispatch_overhead", credited_blk)

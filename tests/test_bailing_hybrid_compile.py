@@ -34,6 +34,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
 
 from dlrover_wuqiong_tpu.ops import delta_rule as dr
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
+from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 
 @pytest.fixture(scope="module")
@@ -88,9 +89,7 @@ def test_the_channel_decay_gradient_compiles_at_the_cells_shape(topo, on_tpu):
 
 
 def _live_gb(step) -> float:
-    m = step.memory_analysis()
-    return (m.argument_size_in_bytes + m.temp_size_in_bytes
-            + m.output_size_in_bytes - m.alias_size_in_bytes) / 1e9
+    return compiled_memory(step)["live_bytes"] / 1e9
 
 
 # the step's described reading with the channel pair, and since PR 59

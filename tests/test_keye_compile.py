@@ -30,6 +30,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
 )
 
 from dlrover_wuqiong_tpu.ops import sparse_attention as sa
+from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 B, T, H, KV, D, HI, DI, TOPK = 1, 16384, 32, 4, 128, 16, 64, 2048
 KERNELS = ("dwt_idx_scores", "dwt_idx_select", "dwt_fa_sp_fwd",
@@ -79,9 +80,7 @@ def test_one_layers_sparse_attention_compiles_at_the_cells_shape(
 
 
 def _live_gb(step) -> float:
-    m = step.memory_analysis()
-    return (m.argument_size_in_bytes + m.temp_size_in_bytes
-            + m.output_size_in_bytes - m.alias_size_in_bytes) / 1e9
+    return compiled_memory(step)["live_bytes"] / 1e9
 
 
 LIVE_GB = 13.74  # the step's described reading at rung (c)

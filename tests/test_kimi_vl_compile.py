@@ -29,6 +29,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
 
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
 from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
+from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 
 @pytest.fixture(scope="module")
@@ -59,8 +60,7 @@ def test_kimi_vl_step_fits_one_chip_by_the_rule_and_fills_it(kimi_vl_step):
     assert model.config.num_params() == 568_484_608
     assert (cell["seq_len"], cell["global_batch"]) == (16384, 2)
     m = step.memory_analysis()
-    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
-        + m.output_size_in_bytes - m.alias_size_in_bytes
+    live = compiled_memory(step)["live_bytes"]
     live -= cell["global_batch"] * 16384 * 6 * 2048 * 2  # counted twice
     rung = cell["config"]["train"]["memory_rung"]
     assert rung["live_GB"]["2 x 16384 at depth 5"] == 14.27

@@ -23,6 +23,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
 )
 
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
+from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 
 @pytest.fixture(scope="module")
@@ -36,9 +37,7 @@ def laguna_step(request):
 
 
 def _live_gb(step) -> float:
-    m = step.memory_analysis()
-    return (m.argument_size_in_bytes + m.temp_size_in_bytes
-            + m.output_size_in_bytes - m.alias_size_in_bytes) / 1e9
+    return compiled_memory(step)["live_bytes"] / 1e9
 
 
 def test_laguna_step_fits_one_chip_by_the_rule_and_fills_it(laguna_step):

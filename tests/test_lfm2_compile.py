@@ -32,6 +32,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
 
 from dlrover_wuqiong_tpu.models.lfm2 import ShortConvMixer
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
+from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 TOKENS, HIDDEN = (4, 8192), 2048
 
@@ -88,9 +89,7 @@ def test_one_conv_mixers_gradient_compiles_at_the_cells_shape(topo):
 
 
 def _live_gb(step) -> float:
-    m = step.memory_analysis()
-    return (m.argument_size_in_bytes + m.temp_size_in_bytes
-            + m.output_size_in_bytes - m.alias_size_in_bytes) / 1e9
+    return compiled_memory(step)["live_bytes"] / 1e9
 
 
 LIVE_GB = 13.17  # the step's described reading at rung (a)

@@ -18,6 +18,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
 )
 
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
+from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 SUBSCOPES = ("q_proj", "k_proj", "v_proj", "g_proj", "gates", "conv",
              "delta", "gate_norm", "o_proj")
@@ -46,8 +47,7 @@ def test_olmo_step_fits_one_chip_by_the_rule_and_fills_it(olmo_step):
     assert (cell["global_batch"], cell["seq_len"], model.config.chunk_size,
             model.config.linear_heads) == (1, 8192, 64, 15)
     m = step.memory_analysis()
-    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
-        + m.output_size_in_bytes - m.alias_size_in_bytes
+    live = compiled_memory(step)["live_bytes"]
     rung = cell["config"]["train"]["memory_rung"]
     assert rung["taken"] == "a"
     assert live / 1e9 == pytest.approx(12.96, abs=0.05)

@@ -28,6 +28,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo` and the cache switch are
 )
 
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
+from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 
 # ------------------------------- SmallThinker-21B-A3B's step on one chip
@@ -64,8 +65,7 @@ def test_smallthinker_step_fits_one_chip_by_the_rule_and_fills_it(
     assert model.config.num_params() == 559_290_880
     assert cell["seq_len"] == 16384
     m = step.memory_analysis()
-    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
-        + m.output_size_in_bytes - m.alias_size_in_bytes
+    live = compiled_memory(step)["live_bytes"]
     live -= cell["global_batch"] * 16384 * 6 * 2560 * 2  # counted twice
     want = {1: 11.09, 2: 13.46}[cell["global_batch"]]
     assert live / 1e9 == pytest.approx(want, abs=0.05)

@@ -290,8 +290,13 @@ def test_one_first_step_a_fusion_width(program_first):
     dispatches = _named(program_first["hot"], "trainer:dispatch")
     for f in firsts:
         assert f["parent_span"] in iterations
+        # and, since PR 64, the compiled budget of the program it
+        # dispatched (tests/test_step_memory.py)
         assert set(f["attrs"]) == {"k", "blk_s", "trace_s", "lower_s",
-                                   "backend_compile_s", "cache_load_s"}
+                                   "backend_compile_s", "cache_load_s",
+                                   "argument_bytes", "output_bytes",
+                                   "alias_bytes", "temp_bytes",
+                                   "generated_code_bytes", "live_bytes"}
         assert f["dur_s"] == pytest.approx(f["attrs"]["blk_s"])
         # the stretch is the dispatch call's: it holds that span
         held = [d for d in dispatches if f["t_mono"] <= d["t_mono"]

@@ -31,6 +31,7 @@ from dlrover_wuqiong_tpu.ops import grouped_matmul as gm
 from dlrover_wuqiong_tpu.ops import mosaic
 from dlrover_wuqiong_tpu.ops import quantization as qz
 from dlrover_wuqiong_tpu.ops import short_conv, ssd
+from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 
 @pytest.fixture(scope="module")
@@ -417,8 +418,7 @@ def test_olmoe_step_fits_one_chip_and_fills_it(olmoe_step):
     cell, model, step = olmoe_step
     assert model.config.num_params() == 625_616_896
     m = step.memory_analysis()
-    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
-        + m.output_size_in_bytes - m.alias_size_in_bytes
+    live = compiled_memory(step)["live_bytes"]
     assert 0.75 * 16e9 < live < 0.90 * 16e9, live / 1e9
     # the state is donated: 12 B a parameter in, the same out
     assert m.alias_size_in_bytes >= 12 * model.config.num_params()
@@ -500,8 +500,7 @@ def test_nemotron_step_fits_one_chip_and_fills_it(nemotron_step):
     assert model.config.num_params() == 666_963_456
     assert cell["global_batch"] == 2 and cell["seq_len"] == 8192
     m = step.memory_analysis()
-    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
-        + m.output_size_in_bytes - m.alias_size_in_bytes
+    live = compiled_memory(step)["live_bytes"]
     assert 0.25 * 16 * 2 ** 30 < 0.80 * 16e9 < live < 0.90 * 16e9, live / 1e9
     assert m.alias_size_in_bytes >= 12 * model.config.num_params()
 
@@ -857,8 +856,7 @@ def test_granite_step_fits_one_chip_by_the_rule_and_fills_it(granite_step):
             model.config.chunk_size, model.config.n_groups) == \
         (1, 8192, 256, 1)
     m = step.memory_analysis()
-    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
-        + m.output_size_in_bytes - m.alias_size_in_bytes
+    live = compiled_memory(step)["live_bytes"]
     rung = cell["config"]["train"]["memory_rung"]
     assert live / 1e9 == pytest.approx(11.92, abs=0.05)
     assert live / 1e9 < rung["live_GB"]["a: 1 x 8192, chunk 256"] - 0.3

@@ -27,6 +27,7 @@ from test_tpu_compile import (  # noqa: F401 — `topo`, the cache switch: fixtu
 )
 
 from dlrover_wuqiong_tpu.ops import flash_attention as fa
+from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 
 @pytest.fixture(scope="module")
@@ -58,8 +59,7 @@ def test_xing_step_fits_one_chip_by_the_rule_and_fills_it(xing_step):
     assert model.config.num_params() == 759_346_446
     assert (cell["seq_len"], cell["global_batch"]) == (8192, 1)
     m = step.memory_analysis()
-    live = m.argument_size_in_bytes + m.temp_size_in_bytes \
-        + m.output_size_in_bytes - m.alias_size_in_bytes
+    live = compiled_memory(step)["live_bytes"]
     rung = cell["config"]["train"]["memory_rung"]
     assert rung["live_GB"]["1 x 8192"] == 13.86 < rung["limit_GB"] == 14.4
     assert rung["taken"] == "1 x 8192"

@@ -45,6 +45,22 @@ exit (``agent-exit``), a restarted worker its own once its first step is
 dispatched (``resumed``).  rc=1 and a message on stderr where the dumps
 hold no such generation.
 
+The operator's reader of a job's device memory, as text too:
+
+    python tools/incident_report.py --memory CKPT_DIR
+
+prints, for every process with a memory record in the flight dumps under
+``CKPT_DIR/flight/`` (a trainer's ``fault``, ``sigterm`` and ``resumed``
+dumps), the step's compiled budget by fusion width
+(``trainer:first_step``: arguments, outputs, what they alias, the
+temporaries, the code, and what is live at once) and the timeline of the
+device's readings up to the dump — ``trainer:build``'s ``hbm``,
+``trainer:train``'s ``hbm_at_entry``, one ``trainer:memory`` a logging
+boundary, ``ckpt:snapshot``'s ``hbm_before`` / ``hbm_after`` — of the
+fullest device, in GiB, with the headroom (``bytes_limit`` less in use
+and reserved) and the largest free block beside each.  rc=1 and a
+message on stderr where the dumps hold no such record.
+
 Summary fields: source bookkeeping, event/span/trace/epoch/process
 counts, incidents with per-incident lost seconds, goodput_fraction,
 and timeline_sha256.  Exit/error contract matches the other report
@@ -154,14 +170,14 @@ def _from_master(addr: str, vals: dict) -> dict:
 # ------------------------------------------------- one restart as a table
 
 
-def _flight_spans(ckpt_dir: str) -> dict:
-    """{span_id: record} over every dump, each with `start` on the
-    shared wall (its own monotonic start through the dump's anchor)."""
-    from dlrover_wuqiong_tpu.telemetry import load_flight_dumps
+def _flight_spans(dumps: list) -> dict:
+    """{span_id: record} over every dump (`load_flight_dumps`), each
+    with `start` on the shared wall (its own monotonic start through
+    the dump's anchor)."""
     from dlrover_wuqiong_tpu.telemetry.timeline import anchored_wall
 
     spans = {}
-    for dump in load_flight_dumps(ckpt_dir):
+    for dump in dumps:
         for evt in dump.get("events") or []:
             rec = evt.get("data") or {}
             if evt.get("kind") != "span" or rec.get("span_id") in spans:
@@ -219,7 +235,9 @@ def _rows(rec: dict, depth: int, lo: float, hi: float) -> list:
 
 
 def restart_table(ckpt_dir: str) -> str:
-    spans = _flight_spans(ckpt_dir)
+    from dlrover_wuqiong_tpu.telemetry import load_flight_dumps
+
+    spans = _flight_spans(load_flight_dumps(ckpt_dir))
     _link(spans)
     gens = sorted((s for s in spans.values()
                    if s["name"] == "agent:generation"),
@@ -247,9 +265,11 @@ def restart_table(ckpt_dir: str) -> str:
                  f"{'span':<44} {'role':<8} {'start_s':>9} {'seconds':>9}"
                  f"  attrs"]
         for depth, rec in rows:
+            # a memory reading (a dict) has `--memory`'s table
             attrs = " ".join(
                 f"{k}={v:.3f}" if isinstance(v, float) else f"{k}={v}"
-                for k, v in rec.get("attrs", {}).items())
+                for k, v in rec.get("attrs", {}).items()
+                if not isinstance(v, dict))
             if rec.get("status", "ok") != "ok":
                 attrs = f"status={rec['status']} {attrs}"
             if rec.get("repeats"):
@@ -266,13 +286,107 @@ def restart_table(ckpt_dir: str) -> str:
     return "\n\n".join(blocks)
 
 
+# ---------------------------------------------- a job's device memory
+
+_GIB = 2 ** 30
+_BUDGET = ("argument", "output", "alias", "temp", "generated_code", "live")
+#: (span, attr holding the reading or None for the span's own attrs,
+#: whether the reading was taken at the span's end)
+_READINGS = (("trainer:build", "hbm", True),
+             ("trainer:train", "hbm_at_entry", False),
+             ("trainer:memory", None, False),
+             ("ckpt:snapshot", "hbm_before", False),
+             ("ckpt:snapshot", "hbm_after", True))
+
+
+def _memory_rows(spans: list) -> list:
+    """(wall instant, label, step, reading) of one process's spans."""
+    rows = []
+    for rec in spans:
+        attrs = rec.get("attrs", {})
+        for name, key, at_end in _READINGS:
+            hbm = attrs if key is None else attrs.get(key)
+            if rec["name"] != name or not hbm or "bytes_in_use" not in hbm:
+                continue
+            rows.append((rec["start"] + (rec.get("dur_s", 0.0) if at_end
+                                         else 0.0),
+                         name if key is None else f"{name} {key}",
+                         attrs.get("step", ""), hbm))
+    return sorted(rows, key=lambda r: r[0])
+
+
+def memory_table(ckpt_dir: str) -> str:
+    from dlrover_wuqiong_tpu.telemetry import load_flight_dumps
+    from dlrover_wuqiong_tpu.telemetry.memory import (
+        headroom_bytes,
+        held_bytes,
+    )
+
+    dumps = load_flight_dumps(ckpt_dir)  # oldest first
+    by_pid = {}
+    for rec in _flight_spans(dumps).values():
+        by_pid.setdefault(rec.get("pid"), []).append(rec)
+    blocks = []
+    for pid in dict.fromkeys(d.get("pid") for d in dumps):
+        mine = [d for d in dumps if d.get("pid") == pid]
+        role, t_end = mine[-1].get("role", ""), mine[-1]["flushed_at"]
+        reasons = [d.get("reason") for d in mine]
+        spans = by_pid.get(pid, [])
+        budgets = sorted((s["attrs"] for s in spans
+                          if s["name"] == "trainer:first_step"
+                          and "live_bytes" in s.get("attrs", {})),
+                         key=lambda a: a["k"])
+        rows = _memory_rows(spans)
+        if not budgets and not rows:
+            continue
+        lines = [f"{role} pid {pid} (dumps: {', '.join(reasons)}): GiB of "
+                 f"the fullest device; at_s is seconds before the last dump"]
+        if budgets:
+            lines.append("the step's compiled budget by fusion width "
+                         "(live = argument + temp + output - alias)")
+            lines.append(f"{'K':>4} " + " ".join(f"{c:>14}"
+                                                 for c in _BUDGET))
+            lines += [f"{a['k']:>4} " + " ".join(
+                f"{a[c + '_bytes'] / _GIB:>14.3f}" for c in _BUDGET)
+                for a in budgets]
+        if rows:
+            cols = ("in_use", "reserved", "sum", "peak_in_use",
+                    "peak_reserved", "headroom", "largest_free",
+                    "least_in_use")
+            lines.append(f"{'at_s':>10} {'record':<26} {'step':>7} "
+                         + " ".join(f"{c:>13}" for c in cols) + "  device")
+            for at, label, step, hbm in rows:
+                vals = (hbm["bytes_in_use"], hbm["bytes_reserved"],
+                        held_bytes(hbm),
+                        hbm["peak_bytes_in_use"],
+                        hbm["peak_bytes_reserved"], headroom_bytes(hbm),
+                        hbm["largest_free_block_bytes"],
+                        hbm["least_bytes_in_use"])
+                lines.append(
+                    f"{at - t_end:>10.3f} {label:<26} {step!s:>7} "
+                    + " ".join(f"{v / _GIB:>13.3f}" for v in vals)
+                    + f"  {hbm.get('device', '')} of "
+                      f"{hbm.get('devices', '')}")
+        blocks.append("\n".join(lines))
+    if not blocks:
+        raise LookupError(
+            f"no memory record (trainer:first_step's budget, hbm, "
+            f"trainer:memory) in the flight dumps under {ckpt_dir!r}")
+    return "\n\n".join(blocks)
+
+
+_TABLES = {"--restart-table": restart_table, "--memory": memory_table}
+
+
 def main(argv=None) -> int:
     from dlrover_wuqiong_tpu.common.report_cli import run_report
 
     args = list(sys.argv[1:] if argv is None else argv)
-    if "--restart-table" in args:
+    for flag, table in _TABLES.items():
+        if flag not in args:
+            continue
         try:
-            print(restart_table(args[args.index("--restart-table") + 1]))
+            print(table(args[args.index(flag) + 1]))
         except (IndexError, LookupError, OSError) as e:
             print(f"incident_report: {e!r}", file=sys.stderr)
             return 1

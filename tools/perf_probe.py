@@ -605,6 +605,7 @@ def probe_remat():
 
     from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
     from dlrover_wuqiong_tpu.models.gpt import GPT, GPTConfig
+    from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
     base = GPTConfig.gpt2()
     data = jax.random.randint(jax.random.PRNGKey(0), (B, T + 1), 0,
@@ -620,13 +621,8 @@ def probe_remat():
                                   devices=jax.devices()[:1], strategy=strat)
             b = res.place_batch({"input_ids": data[:, :-1],
                                  "labels": data[:, 1:]})
-            lowered = jax.jit(
-                res.train_step._fun if hasattr(res.train_step, "_fun")
-                else res.train_step.__wrapped__,
-                donate_argnums=(0,)).lower(res.state, b)                 if False else res.train_step.lower(res.state, b)
-            compiled = lowered.compile()
-            mem = compiled.memory_analysis()
-            temp_gb = getattr(mem, "temp_size_in_bytes", 0) / 2**30
+            budget = compiled_memory(
+                res.train_step.lower(res.state, b).compile())
 
             def stepper(state):
                 state, _ = res.train_step(state, b)
@@ -634,7 +630,9 @@ def probe_remat():
 
             t = _time(stepper, jax.tree.map(jnp.copy, res.state),
                       iters=10, warmup=2)
-            _emit(f"remat_{policy}", t, temp_gb=round(temp_gb, 3))
+            _emit(f"remat_{policy}", t,
+                  temp_gb=round(budget.get("temp_bytes", 0) / 2**30, 3),
+                  live_gb=round(budget.get("live_bytes", 0) / 2**30, 3))
             del res
         except Exception as e:  # noqa: BLE001
             _emit_raw({"probe": f"remat_{policy}",

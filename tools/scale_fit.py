@@ -52,6 +52,7 @@ def main():
         os.path.abspath(__file__))))
     from dlrover_wuqiong_tpu.auto.accelerate import auto_accelerate
     from dlrover_wuqiong_tpu.models.llama import Llama, LlamaConfig
+    from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
     cfg = {"8b": LlamaConfig.llama3_8b,
            "70b": LlamaConfig.llama3_70b}[cfg_in.get("model", "8b")]()
@@ -69,17 +70,20 @@ def main():
                                          sharding=bsh)}
     compiled = res.train_step.lower(res.state, ab).compile()
     ma = compiled.memory_analysis()
+    budget = compiled_memory(compiled)
     out = {
         "ok": True,
         "mesh": res.strategy.plan.describe(),
         "params": cfg.num_params(),
         "seq": seq, "batch": batch, "n_devices": n_dev,
         "compile_s": round(time.monotonic() - t0, 1),
-        "arg_gib": round(ma.argument_size_in_bytes / 2**30, 3),
-        "out_gib": round(ma.output_size_in_bytes / 2**30, 3),
-        "alias_gib": round(ma.alias_size_in_bytes / 2**30, 3),
+        "arg_gib": round(budget["argument_bytes"] / 2**30, 3),
+        "out_gib": round(budget["output_bytes"] / 2**30, 3),
+        "alias_gib": round(budget["alias_bytes"] / 2**30, 3),
         "temp_gib_cpu_upper_bound": round(
-            ma.temp_size_in_bytes / 2**30, 3),
+            budget["temp_bytes"] / 2**30, 3),
+        "live_gib_cpu_upper_bound": round(
+            budget["live_bytes"] / 2**30, 3),
         "host_arg_gib": round(
             ma.host_argument_size_in_bytes / 2**30, 3),
         "host_out_gib": round(ma.host_output_size_in_bytes / 2**30, 3),
