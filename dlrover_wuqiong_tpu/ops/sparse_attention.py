@@ -25,11 +25,12 @@ never a knob):
   over the scores' ordered integer form in VMEM (32 counts of a row
   block, then the tie's cut by position) and writes the choice as an
   int8 (b, T, T) mask with the row's log-sum over the kept scores;
-  `dwt_fa_sp_fwd` / `dwt_fa_sp_bwd_dq` / `dwt_fa_sp_bwd_dkv` are a flash
-  attention a kv head's GROUP of query heads a grid step, every causal
-  tile computed and masked by the choice (dense work, the mathematics of
-  the kept set: the counters say so — `tiles_run` = `tiles_causal`; a
-  grid that skips tiles without a kept pair is a later change).  What a
+  `dwt_fa_sp_fwd` / `dwt_fa_sp_bwd_fused` are a flash attention, every
+  causal tile computed and masked by the choice (dense work, the
+  mathematics of the kept set: the counters say so — `tiles_run` =
+  `tiles_causal`; a grid that skips tiles without a kept pair is a later
+  change) — the forward a kv head's GROUP of query heads a grid step,
+  the backward a UNIT of one or two of them (below).  What a
   masked tile costs the forward: the int8 tile becomes ONE float32 bias
   a grid step (0 kept, `_NEG` not), added to each of the group's heads'
   scores — no select a head, and none after the exponent, because the
@@ -53,10 +54,35 @@ never a knob):
   head, 15.2 as this; (1024 x 512) 26.6 and (1024 x 1024) 42.3 head
   after head.  `_BLOCK` stays the tile of the mask's COUNTERS
   (`tiles_of`, `tile_counts`, the choice's `chunk`): a live tile is a
-  (512 x 512) one, whatever a forward step takes.  The other kernels
-  keep (512 x 512) a step; their `_probs` takes the same bias and the
-  same folded constant against `lse * log2 e`, a column.
-  `dwt_idx_kl` recomputes the heads' probabilities from the saved
+  (512 x 512) one, whatever a step of either kernel takes.
+  The backward is ONE sweep (PR 65; until then a dq and a dk/dv kernel,
+  seven products and two recomputations of p a head and tile, each at
+  89% of the MXU's time for its own products): grid (batch, kv head,
+  unit of the group's heads, key block, q block at or below it), the
+  two inner axes sequential; a step recomputes p ONCE a head under the
+  same bias and folded constant (the saved natural log-sum to base 2, a
+  column), and from it dv += pT dO, ds = p (dO vT - delta), dk += dsT q
+  and dq += ds k — five products.  dq cannot leave a step (its sum runs
+  over key blocks, the OUTER axis), so it is summed in a float32 scratch
+  that spans the unit's whole query length and leaves once, at the
+  unit's last step, `ops/flash_attention.py`'s form since PR 40; a
+  whole group's (16,384 x 1,024 lanes, 64 MiB) does not fit, one or two
+  heads' do.  dk and dv are sums over every head of the group, and a
+  unit is not the group: they are resident for the kv head's WHOLE key
+  length the same way (2 x 16,384 x 128 float32; their block's index is
+  constant over the unit, key and q axes), summed in float32 across all
+  the group's heads and rounded once — no temporary leaves the kernel.
+  `scale` meets the sums of dq and dk where they are written.  What a
+  step takes is `bwd_step`'s, from shapes alone: the first of
+  `_BWD_STEPS` that divides the group and the sequence and fits VMEM —
+  one head at (1,024 q rows x 2,048 keys) at the cell's shape, 33.1 ms
+  a call where the pair took 45.1 (PERF.md section 6, PR 65: the
+  per-step cost, 0.67 us, is what a (512 x 512) step of one head loses,
+  46.3 ms, and two heads there halve, 40.6; 4,096 keys a step lose
+  everything, 112-249 ms); the diagonal's blocks run only the tiles at
+  or below it, as the forward's do (`_run_bands`).
+  `dwt_idx_kl` (still (512 x 512) a step; `_probs` takes the same bias
+  and folded constant) recomputes the heads' probabilities from the saved
   log-sums a tile at a time, sums them over the heads in VMEM (no
   (heads x T x T) array), and leaves the tile of `softmax_S(I) - pbar`
   where I's tile was; `dwt_idx_bwd` takes that to the indexer's three
@@ -97,6 +123,10 @@ from .mosaic import (
 
 _BLOCK = 512      # positions a tile's side: q rows, keys, the mask's tile
 _FWD_TILES = (1, 2)  # tiles a forward step's (q rows, keys) take: swept
+# (heads of a group, tiles of q rows, tiles of keys) a backward step takes,
+# the fastest first (ms a call at the cell's shape, PERF.md section 6, PR
+# 65: 33.1, 35.0, 35.5, 40.6, 46.3); `bwd_step` takes the first that fits
+_BWD_STEPS = ((1, 2, 4), (2, 2, 2), (1, 2, 2), (2, 1, 1), (1, 1, 1))
 _SELECT_ROWS = 128  # rows whose whole score row sits in VMEM for the search
 _SITES = frozenset({"device"})  # a whole sequence's keys: no shard is one
 _VMEM = 96 * 1024 * 1024
@@ -106,14 +136,19 @@ _LOG2E = 1.0 / _LN2  # exp(x) = exp2(x * _LOG2E): the exponent unit's base
 _INT_MIN = -2 ** 31
 
 
-def sparse_route(t: int, d: int, idx_d: int, mesh=None) -> str:
+def sparse_route(t: int, d: int, idx_d: int, mesh=None,
+                 itemsize: int = 2) -> str:
     """Which route a sparse attention over `t` positions, main heads of
     `d` and indexer heads of `idx_d` lanes, takes: "kernel" on one TPU
     device (`mesh` is the model config's) where the sequence is whole
-    blocks, a main head is whole slabs and an indexer head's lanes are
-    whole sublane tiles; else "plain"."""
+    blocks, a main head is whole slabs, an indexer head's lanes are
+    whole sublane tiles and ONE head's whole-length dq fits VMEM beside
+    the kv head's dk and dv (`bwd_step`; operands of `itemsize` bytes:
+    30,720 positions at two, far past what the (T x T) float32 scores
+    leave of one chip's memory at any model); else "plain"."""
     if t % _BLOCK or d % LANES or idx_d % 8 \
-            or mosaic.kernel_site(mesh) not in _SITES:
+            or mosaic.kernel_site(mesh) not in _SITES \
+            or not bwd_step(t, d, 1, itemsize):
         return "plain"
     return "kernel"
 
@@ -323,6 +358,18 @@ def _fwd_bands(bq: int, bk: int, rel: int, tile: int):
     return bands
 
 
+def _run_bands(run, bq: int, bk: int, rel, tile: int):
+    """run(bands) of the (bq x bk) block whose first query lies `rel`
+    (traced) positions after its first key: below the diagonal every
+    tile of the block runs; a block the diagonal crosses runs the tiles
+    at or below it, by its static place; a block above it runs nothing."""
+    pl.when(rel >= bk - tile)(lambda: run([(0, bq, bk)]))
+    for at in range(tile - bq, bk - tile, tile):
+        if at % math.gcd(bq, bk) == 0:
+            pl.when(rel == at)(functools.partial(
+                run, _fwd_bands(bq, bk, at, tile)))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, rep, d, tile):
     """One (batch row, kv head, q block, key block): the group's `rep`
@@ -366,13 +413,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
             for h, (alpha, p) in enumerate(probs):
                 acc_scr[h, rows] = alpha * acc_scr[h, rows] + _dot(p, v)
 
-    # below the diagonal every tile of the block runs; a block it
-    # crosses runs the tiles at or below it, by its static place
-    pl.when(rel >= bk - tile)(lambda: run([(0, bq, bk)]))
-    for at in range(tile - bq, bk - tile, tile):
-        if at % math.gcd(bq, bk) == 0:
-            pl.when(rel == at)(functools.partial(
-                run, _fwd_bands(bq, bk, at, tile)))
+    _run_bands(run, bq, bk, rel, tile)
 
     @pl.when(j == ((i + 1) * bq - 1) // bk)
     def _():
@@ -389,58 +430,108 @@ def _probs(q, k, bias, lse, scale):
     return jnp.exp2(_dot_t(q, k) * (scale * _LOG2E) + bias - lse * _LOG2E)
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-               dq_ref, acc_scr, *, scale, rep, d):
-    i, j = pl.program_id(2), pl.program_id(3)
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, lse_scr,
+                delta_scr, *, scale, units, d, tile):
+    """One (batch row, kv head, unit of the group's heads, key block, q
+    block at or below it): dq, dk and dv from ONE recomputation of p a
+    head under the choice's bias — five products a head and tile.
 
-    @pl.when(j == 0)
+    dq of the unit's `units` heads is summed in a float32 scratch that
+    spans the WHOLE query length, at the q block's rows; its output
+    block is the unit's whole sequence (an index constant over both
+    inner axes), written once, at the unit's last step.  dk and dv are
+    sums over every head of the GROUP, and a unit is not the group: they
+    are resident for the kv head's whole key length the same way — their
+    block's index is constant over the unit, key and q axes — summed in
+    float32 across all the group's heads and rounded once, at the
+    group's last step.  `scale` meets the sums of dq and dk where they
+    are written, not every ds tile."""
+    bq, bk = mask_ref.shape[1:]
+    c, j, i = (pl.program_id(a) for a in (2, 3, 4))
+    group_ends, *unit_ends = (
+        pl.program_id(a) == pl.num_programs(a) - 1 for a in (2, 3, 4))
+    unit_ends = unit_ends[0] & unit_ends[1]  # its last key and q block
+    rel = i * bq - j * bk  # the block's first query less its first key
+    to_log2 = scale * _LOG2E
+
+    def each_block(body, rows):  # a loop, not T / rows copies of the code
+        def _block(r, carry):
+            body(pl.ds(pl.multiple_of(r * rows, rows), rows))
+            return carry
+
+        jax.lax.fori_loop(0, dq_scr.shape[0] // rows, _block, 0)
+
+    @pl.when((j == 0) & (i == 0))
     def _():
-        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+        def zero(rows):
+            dq_scr[rows] = jnp.zeros((bq,) + dq_scr.shape[1:], jnp.float32)
 
-    @pl.when(j <= i)
+        each_block(zero, bq)
+
+    @pl.when((c == 0) & (j == 0) & (i == 0))
     def _():
-        bias = _bias_of(mask_ref[0])
-        k, v = k_ref[0], v_ref[0]
-        for h in range(rep):
-            lanes = slice(h * d, (h + 1) * d)
-            p = _probs(q_ref[0, :, lanes], k, bias,
-                       lse_ref[0, 0, :, h:h + 1], scale)
-            dp = _dot_t(do_ref[0, :, lanes], v)
-            ds = p * (dp - delta_ref[0, 0, :, h:h + 1]) * scale
-            acc_scr[:, lanes] += _dot(ds.astype(k.dtype), k)
+        def zero(rows):
+            dk_scr[rows] = jnp.zeros((bk, d), jnp.float32)
+            dv_scr[rows] = jnp.zeros((bk, d), jnp.float32)
 
-    @pl.when(j == i)
+        each_block(zero, bk)
+
+    # the unit's columns of the group's log-sums (to base 2) and deltas,
+    # where a tile of the block runs: which they are is the grid's, a
+    # static slice a case
+    for unit in range(lse_ref.shape[-1] // units):
+        @pl.when((rel > -bq) & (c == unit))
+        def _():
+            for a in range(units):
+                h = unit * units + a
+                lse_scr[a] = lse_ref[0, 0, :, h:h + 1] * _LOG2E
+                delta_scr[a] = delta_ref[0, 0, :, h:h + 1]
+
+    def run(bands):
+        for q0, q1, k_end in bands:
+            rows = slice(q0, q1)
+            at = pl.ds(pl.multiple_of(i * bq + q0, tile), q1 - q0)
+            keys = pl.ds(pl.multiple_of(j * bk, tile), k_end)
+            bias = _bias_of(mask_ref[0, rows, :k_end])
+            k, v = k_ref[0, :k_end], v_ref[0, :k_end]
+            heads = [slice(a * d, (a + 1) * d) for a in range(units)]
+            qs = [q_ref[0, rows, lanes] for lanes in heads]
+            dos = [do_ref[0, rows, lanes] for lanes in heads]
+            # the unit's first products, THEN its elementwise passes,
+            # THEN its products with them: the forward's order (head
+            # after head took the same time here, to 0.4 ms a call)
+            scores = [_dot_t(q, k) for q in qs]
+            dps = [_dot_t(do, v) for do in dos]
+            ps, dss = [], []
+            for a in range(units):
+                p = jnp.exp2(scores[a] * to_log2 + bias - lse_scr[a, rows])
+                ds = p * (dps[a] - delta_scr[a, rows])
+                ps.append(p.astype(v.dtype))
+                dss.append(ds.astype(k.dtype))
+            dv_scr[keys] += functools.reduce(jnp.add, [
+                _dot_c0(p, do) for p, do in zip(ps, dos)])
+            dk_scr[keys] += functools.reduce(jnp.add, [
+                _dot_c0(ds, q) for ds, q in zip(dss, qs)])
+            for ds, lanes in zip(dss, heads):
+                dq_scr[at, lanes] += _dot(ds, k)
+
+    _run_bands(run, bq, bk, rel, tile)
+
+    @pl.when(unit_ends)
     def _():
-        dq_ref[0] = acc_scr[...].astype(dq_ref.dtype)
+        def write(rows):
+            dq_ref[0, rows] = (dq_scr[rows] * scale).astype(dq_ref.dtype)
 
+        each_block(write, bq)
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, rep, d):
-    """One (batch row, kv head, key block, q block): dk and dv of the kv
-    head summed over the q blocks at or below it and the group's heads."""
-    j, i = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(i == 0)
+    @pl.when(group_ends & unit_ends)
     def _():
-        dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
-        dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+        def write(rows):
+            dk_ref[0, rows] = (dk_scr[rows] * scale).astype(dk_ref.dtype)
+            dv_ref[0, rows] = dv_scr[rows].astype(dv_ref.dtype)
 
-    @pl.when(i >= j)
-    def _():
-        bias = _bias_of(mask_ref[0])
-        k, v = k_ref[0], v_ref[0]
-        for h in range(rep):
-            lanes = slice(h * d, (h + 1) * d)
-            q, do = q_ref[0, :, lanes], do_ref[0, :, lanes]
-            p = _probs(q, k, bias, lse_ref[0, 0, :, h:h + 1], scale)
-            dv_scr[...] += _dot_c0(p.astype(do.dtype), do)
-            ds = p * (_dot_t(do, v) - delta_ref[0, 0, :, h:h + 1]) * scale
-            dk_scr[...] += _dot_c0(ds.astype(q.dtype), q)
-
-    @pl.when(i == pl.num_programs(3) - 1)
-    def _():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        each_block(write, bk)
 
 
 def _kl_kernel(q_ref, k_ref, lse_ref, mask_ref, s_ref, logz_ref,
@@ -621,66 +712,93 @@ def _fwd_pallas(q, k, v, mask, *, scale, n_kv, block, interpret,
     )(q, k, v, mask)
 
 
-def _dq_pallas(q, k, v, do, lse, delta, mask, *, scale, n_kv, block,
-               interpret):
-    b, t, lanes, d, rep, n = _dims(q, k, n_kv, block)
-    # a grid (b, kv head g, q block i, key block j): the group's q rows,
-    # the kv head's rows, the rows' per-head numbers and the mask's tile
-    rows = pl.BlockSpec((1, block, rep * d),
-                        lambda b_, g, i, j: (b_, i, g))
-    keys = pl.BlockSpec((1, block, d),
-                        lambda b_, g, i, j: (b_, _causal(j, i), g))
-    nums = pl.BlockSpec((1, 1, block, rep),
-                        lambda b_, g, i, j: (b_, g, i, 0))
-    tile = pl.BlockSpec((1, block, block),
-                        lambda b_, g, i, j: (b_, i, _causal(j, i)))
+def _bwd_vmem(units: int, t: int, d: int, itemsize: int, bq: int,
+              bk: int) -> int:
+    """Bytes of VMEM the fused backward holds at `units` heads and
+    (bq x bk) a grid step, reckoned from shapes: the whole-length
+    float32 sums with their double-buffered output blocks (dq a unit; dk
+    and dv the kv head), the step's operands double-buffered (the
+    log-sums' and deltas' columns lie on 128 lanes), and three float32
+    score tiles a head for the values of the body.  Against the least
+    limit Mosaic compiles each under for a described v5e at the cell's
+    shape: 81 / 95 / 66 / 73 / 54 MiB reckoned for `_BWD_STEPS`' five
+    where 73 / 91 / 61 / 73 / 52 are held."""
+    whole = t * d * (units + 2) * (4 + 2 * itemsize)
+    step = 2 * (2 * itemsize * d * (bq * units + bk) + bq * bk
+                + 2 * 4 * bq * LANES)
+    return whole + step + 3 * 4 * bq * bk * units
+
+
+def bwd_step(t: int, d: int, rep: int, itemsize: int = 2,
+             block: int = _BLOCK) -> tuple | None:
+    """(heads of a group, q rows, keys) a grid step of the fused
+    backward takes, from the shapes alone — the first of `_BWD_STEPS`
+    whose heads divide the group's `rep`, whose blocks divide the
+    sequence and whose VMEM (`_bwd_vmem`: a unit's whole-length dq
+    beside the kv head's dk and dv and the step's blocks) fits `_VMEM`;
+    None where not one head's at one tile does (`sparse_route` then says
+    "plain").  At the cell's 16,384 x 128: one head at (1,024 x 2,048) —
+    two heads there do not fit, and one head at that step is faster than
+    two at any that does.  The counter of this decision; in a trace its
+    witness is the sweep's grid, (batch, kv heads, rep / heads, key
+    blocks, q blocks)."""
+    for units, *tiles in _BWD_STEPS:
+        bq, bk = (n * block for n in tiles)
+        if rep % units == 0 and t % bq == 0 and t % bk == 0 and _bwd_vmem(
+                units, t, d, itemsize, bq, bk) <= _VMEM:
+            return units, bq, bk
+    return None
+
+
+def _bwd_pallas(q, k, v, do, lse, delta, mask, *, scale, n_kv, block,
+                interpret, step=None):
+    """(dq, dk, dv) of `_fwd_pallas` over the mask's kept set in ONE
+    sweep, `bwd_step`'s (heads of a group, q rows, keys) a grid step
+    (`step` overrides it: sweeps and tests, no caller of the package
+    sets it)."""
+    b, t, lanes, d, rep, _ = _dims(q, k, n_kv, block)
+    units, bq, bk = step or bwd_step(t, d, rep, q.dtype.itemsize, block)
+    n_units = rep // units
+
+    def below(j, i):  # the first q block that sees the key block, or i
+        return jnp.maximum(i, j * bk // bq)
+
+    # a grid (b, kv head g, unit c, key block j, q block i): the unit's
+    # q rows, the kv head's rows, the group's per-head numbers of the
+    # rows and the mask's tile; dq the unit's and dk, dv the kv head's
+    # WHOLE length
+    rows = pl.BlockSpec((1, bq, units * d), lambda b_, g, c, j, i: (
+        b_, below(j, i), g * n_units + c))
+    keys = pl.BlockSpec((1, bk, d), lambda b_, g, c, j, i: (b_, j, g))
+    nums = pl.BlockSpec((1, 1, bq, rep), lambda b_, g, c, j, i: (
+        b_, g, below(j, i), 0))
+    tile = pl.BlockSpec((1, bq, bk), lambda b_, g, c, j, i: (
+        b_, below(j, i), j))
+    whole_q = pl.BlockSpec((1, t, units * d), lambda b_, g, c, j, i: (
+        b_, 0, g * n_units + c))
+    whole_k = pl.BlockSpec((1, t, d), lambda b_, g, c, j, i: (b_, 0, g))
     return pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, rep=rep, d=d),
-        grid=(b, n_kv, n, n),
+        functools.partial(_bwd_kernel, scale=scale, units=units, d=d,
+                          tile=block),
+        grid=(b, n_kv, n_units, t // bk, t // bq),
         in_specs=[rows, keys, keys, rows, nums, nums, tile],
-        out_specs=rows,
-        out_shape=_out_struct(q.shape, q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((block, rep * d), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "parallel",
-                                "arbitrary"),
-        cost_estimate=pl.CostEstimate(
-            flops=3 * b * lanes * t * t, transcendentals=b * lanes // d
-            * t * t // 2, bytes_accessed=6 * q.size + n_kv * b * t * t // 2),
-        interpret=interpret,
-        name="dwt_fa_sp_bwd_dq",
-    )(q, k, v, do, lse, delta, mask)
-
-
-def _dkv_pallas(q, k, v, do, lse, delta, mask, *, scale, n_kv, block,
-                interpret):
-    b, t, lanes, d, rep, n = _dims(q, k, n_kv, block)
-
-    def below(i, j):
-        return jnp.maximum(i, j)
-
-    rows = pl.BlockSpec((1, block, rep * d),
-                        lambda b_, g, j, i: (b_, below(i, j), g))
-    keys = pl.BlockSpec((1, block, d), lambda b_, g, j, i: (b_, j, g))
-    nums = pl.BlockSpec((1, 1, block, rep),
-                        lambda b_, g, j, i: (b_, g, below(i, j), 0))
-    tile = pl.BlockSpec((1, block, block),
-                        lambda b_, g, j, i: (b_, below(i, j), j))
-    return pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, rep=rep, d=d),
-        grid=(b, n_kv, n, n),
-        in_specs=[rows, keys, keys, rows, nums, nums, tile],
-        out_specs=[keys, keys],
-        out_shape=[_out_struct(k.shape, k.dtype, k),
+        out_specs=[whole_q, whole_k, whole_k],
+        out_shape=[_out_struct(q.shape, q.dtype, q),
+                   _out_struct(k.shape, k.dtype, k),
                    _out_struct(v.shape, v.dtype, v)],
-        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
-                        pltpu.VMEM((block, d), jnp.float32)],
-        compiler_params=_params("parallel", "parallel", "parallel",
-                                "arbitrary"),
+        scratch_shapes=[pltpu.VMEM((t, units * d), jnp.float32),
+                        pltpu.VMEM((t, d), jnp.float32),
+                        pltpu.VMEM((t, d), jnp.float32),
+                        pltpu.VMEM((units, bq, 1), jnp.float32),
+                        pltpu.VMEM((units, bq, 1), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary",
+                                "arbitrary", "arbitrary"),
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * lanes * t * t, transcendentals=b * lanes // d
-            * t * t // 2, bytes_accessed=6 * q.size + n_kv * b * t * t // 2),
+            flops=5 * b * lanes * t * t, transcendentals=b * lanes // d
+            * t * t // 2, bytes_accessed=b * lanes // d * t * t // 2 * (
+                2 * q.dtype.itemsize * d * units + bk) // (bk * units)),
         interpret=interpret,
-        name="dwt_fa_sp_bwd_dkv",
+        name="dwt_fa_sp_bwd_fused",
     )(q, k, v, do, lse, delta, mask)
 
 
@@ -760,8 +878,7 @@ _select = jax.jit(_select_pallas,
                   static_argnames=("topk", "rows", "chunk", "interpret"))
 _STATIC = ("scale", "n_kv", "block", "interpret")
 _fwd = jax.jit(_fwd_pallas, static_argnames=_STATIC)
-_dq = jax.jit(_dq_pallas, static_argnames=_STATIC)
-_dkv = jax.jit(_dkv_pallas, static_argnames=_STATIC)
+_bwd = jax.jit(_bwd_pallas, static_argnames=_STATIC + ("step",))
 _kl = jax.jit(_kl_pallas, static_argnames=_STATIC)
 _idx_bwd = jax.jit(_idx_bwd_pallas, static_argnames=("block", "interpret"))
 
@@ -772,26 +889,25 @@ def _float0(x):
     return np.zeros(x.shape, jax.dtypes.float0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _attend_kernels(q, k, v, mask, plan):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _attend_kernels(q, k, v, mask, plan, step):
     return tuple(_fwd(q, k, v, mask, **dict(plan)))
 
 
-def _attend_fwd(q, k, v, mask, plan):
+def _attend_fwd(q, k, v, mask, plan, step):
     o, lse = _fwd(q, k, v, mask, **dict(plan))
     return (o, lse), (q, k, v, mask, o, lse)
 
 
-def _attend_bwd(plan, kept, cotangents):
+def _attend_bwd(plan, step, kept, cotangents):
     q, k, v, mask, o, lse = kept
     do = cotangents[0]  # the log-sums feed constants only
     b, t, _ = q.shape
     n_kv, rep = lse.shape[1], lse.shape[3]
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
         b, t, n_kv, rep, -1).sum(-1).transpose(0, 2, 1, 3)
-    dq = _dq(q, k, v, do, lse, delta, mask, **dict(plan))
-    dk, dv = _dkv(q, k, v, do, lse, delta, mask, **dict(plan))
-    return dq, dk, dv, _float0(mask)
+    return *_bwd(q, k, v, do, lse, delta, mask, step=step,
+                 **dict(plan)), _float0(mask)
 
 
 _attend_kernels.defvjp(_attend_fwd, _attend_bwd)
@@ -831,9 +947,11 @@ _kl_kernels.defvjp(_kl_fwd, _kl_bwd)
 
 
 def _sparse_kernels(q, k, v, q_idx, k_idx, w, topk, scale, block=None,
-                    rows=None, interpret=False):
+                    rows=None, interpret=False, bwd=None):
     """`sparse_attention` on the kernel route whatever the route says
-    (tests reach the kernels in interpret mode through here)."""
+    (tests reach the kernels in interpret mode through here, and the
+    attention's backward at the step `bwd` names, `_bwd_pallas`'s
+    override)."""
     b, t, n_kv = *q.shape[:2], k.shape[2]
     block = block or _BLOCK
     rows = min(rows or _SELECT_ROWS, block)
@@ -854,7 +972,7 @@ def _sparse_kernels(q, k, v, q_idx, k_idx, w, topk, scale, block=None,
                                        t // block).sum(2)
     with jax.named_scope("attend"):
         rows_of = [x.reshape(b, t, -1) for x in (q, k, v)]
-        o, lse = _attend_kernels(*rows_of, mask, plan)
+        o, lse = _attend_kernels(*rows_of, mask, plan, bwd)
     with jax.named_scope("index_loss"):
         kl = _kl_kernels(q_t, k_idx, w, scores, mask, logz,
                          stop(rows_of[0]), stop(rows_of[1]), stop(lse), plan)
@@ -914,7 +1032,8 @@ def sparse_attention(q, k, v, q_idx, k_idx, w, topk: int, scale=None,
     b, t, heads, d = q.shape
     scale = scale or 1.0 / math.sqrt(d)
     with jax.named_scope("sparse_attn"):
-        if sparse_route(t, d, q_idx.shape[-1], mesh) == "kernel":
+        if sparse_route(t, d, q_idx.shape[-1], mesh,
+                        q.dtype.itemsize) == "kernel":
             o, kl, _, tiles = _sparse_kernels(q, k, v, q_idx, k_idx, w,
                                               topk, scale)
         else:

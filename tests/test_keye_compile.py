@@ -3,7 +3,7 @@ the TPU's own compiler for a DESCRIBED v5e (no chip attached), as
 tests/test_tpu_compile.py does for the other cells — whose helpers these
 tests use.
 
-Tier-1 compiles ONE layer's sparse attention — the seven kernels of
+Tier-1 compiles ONE layer's sparse attention — the six kernels of
 `ops/sparse_attention.py`, forward and backward — at the cell's shape
 (about ten seconds).  The WHOLE step is `slow` (tier-2, `-m slow`): ONE
 module-scoped fixture compiles it, once a run, and that takes the TPU
@@ -34,8 +34,7 @@ from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 B, T, H, KV, D, HI, DI, TOPK = 1, 16384, 32, 4, 128, 16, 64, 2048
 KERNELS = ("dwt_idx_scores", "dwt_idx_select", "dwt_fa_sp_fwd",
-           "dwt_idx_kl", "dwt_fa_sp_bwd_dq", "dwt_fa_sp_bwd_dkv",
-           "dwt_idx_bwd")
+           "dwt_idx_kl", "dwt_fa_sp_bwd_fused", "dwt_idx_bwd")
 
 
 @pytest.fixture(scope="module")
@@ -51,8 +50,9 @@ def test_one_layers_sparse_attention_compiles_at_the_cells_shape(
     """Scores, choice, attention over the choice and the index term of
     ONE layer, forward and backward, at (1, 16384) x 32/4 heads of 128
     and 16 indexer heads of 64 in bfloat16 on one TPU device: each of the
-    seven kernels once, the (T x T) float32 scores' buffer reused for the
-    index term's cotangent (no second one), nothing that holds other ops,
+    six kernels once (the attention's backward ONE fused sweep), the
+    (T x T) float32 scores' buffer reused for the index term's cotangent
+    (no second one), nothing that holds other ops,
     and temporaries under 2.3 GB (2.10 as compiled: 1.07 of scores, 0.27 of mask, the operands' gradients)."""
     one = SingleDeviceSharding(topo.devices[0])
     assert sa.sparse_route(T, D, DI) == "kernel"
@@ -113,7 +113,8 @@ def test_keye_step_holds_its_scopes_kernels_and_a_share_of_experts(
     """Every scope the cell's scopes file names is in the compiled step;
     each of the six layers runs the scores', the choice's and the
     attention's forward kernels twice (forward, recomputed), the KL
-    term's twice, the three backward kernels once, and `dwt_rope` on q,
+    term's twice, the two backward kernels once (the attention's ONE
+    fused sweep, the scores'), and `dwt_rope` on q,
     k and the indexer's two; no kernel of `ops/flash_attention.py` is in
     the step; a share's grouped products run `ops/grouped_matmul.py`'s
     kernels on the 16 held experts of 768, none on the published 128."""
@@ -144,8 +145,7 @@ def test_keye_step_holds_its_scopes_kernels_and_a_share_of_experts(
     assert calls == {
         "dwt_idx_scores": 2 * layers, "dwt_idx_select": 2 * layers,
         "dwt_fa_sp_fwd": 2 * layers, "dwt_idx_kl": 2 * layers,
-        "dwt_fa_sp_bwd_dq": layers, "dwt_fa_sp_bwd_dkv": layers,
-        "dwt_idx_bwd": layers}
+        "dwt_fa_sp_bwd_fused": layers, "dwt_idx_bwd": layers}
     grouped = _grouped_kernel_calls(text)
     assert grouped and "ragged-dot" not in text
     assert all("feed_forward/moe/experts/dwt_" in scope

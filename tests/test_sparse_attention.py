@@ -76,7 +76,10 @@ def _gathered(q, k, v, q_idx, k_idx, w, ct, sets):
         out, kl / (n_b * n_t), None)
 
 
-def _route(which, topk=TOPK):
+def _route(which, topk=TOPK, bwd=None):
+    """`which`: "plain", "kernel" (the backward at `bwd_step`'s own
+    step) or "kernel_other" (at the step `bwd`, by the wrapper's
+    override)."""
     def run(q, k, v, q_idx, k_idx, w, ct):
         if which == "plain":
             o, kl, mask, _ = sa._sparse_plain(q, k, v, q_idx, k_idx, w, topk,
@@ -84,23 +87,29 @@ def _route(which, topk=TOPK):
         else:
             o, kl, mask, _ = sa._sparse_kernels(
                 q, k, v, q_idx, k_idx, w, topk, SCALE, block=16, rows=8,
-                interpret=True)
+                interpret=True, bwd=bwd if which == "kernel_other" else None)
         return (o * ct).sum() + 3.0 * kl, (o, kl, mask)
     return run
 
 
-def _all_routes(operands, topk=TOPK):
-    """{gathered | plain | kernel: ((value, (o, kl, mask)), the six
-    operands' gradients), sets: `_sorted_sets`'}, each route one jitted
-    program (op by op they take four times as long)."""
+ROUTES = ("plain", "kernel", "kernel_other")
+
+
+def _all_routes(operands, topk, bwd):
+    """{gathered | plain | kernel | kernel_other: ((value, (o, kl,
+    mask)), the six operands' gradients), sets: `_sorted_sets`'}, each
+    route one jitted program (op by op they take four times as long);
+    `bwd` the OTHER step of the fused backward, (heads a unit, q rows,
+    keys), than the one `bwd_step` gives the operands' shape."""
+    rep = operands["q"].shape[2] // operands["k"].shape[2]
+    assert bwd != sa.bwd_step(operands["q"].shape[1], D, rep, 4, 16)
     with jax.default_matmul_precision("highest"):
         sets = _sorted_sets(*(operands[n] for n in NAMES[3:]), topk)
         return {"sets": sets, **{name: jax.jit(jax.value_and_grad(
             fn, argnums=tuple(range(6)), has_aux=True))(*operands.values())
             for name, fn in (
                 ("gathered", functools.partial(_gathered, sets=sets)),
-                ("plain", _route("plain", topk)),
-                ("kernel", _route("kernel", topk)))}}
+                *((name, _route(name, topk, bwd)) for name in ROUTES))}}
 
 
 # an indexer that prefers the RECENT keys: under it a late row keeps
@@ -109,32 +118,41 @@ def _all_routes(operands, topk=TOPK):
 RECENT_TOPK = 8  # under one block of 16
 RH, RKV = 2, 1   # one group of two heads: half the kernels to lower
 LONG = 80  # five tiles of 16 and no whole number of 32 keys: the
-#            forward's step is (16 x 16) there, (16 x 32) at T = 64
+#            forward's step is (16 x 16) there, (16 x 32) at T = 64; five
+#            query blocks of the backward: dq carried across key blocks
+ODD = 3  # heads on the one kv head of a group two do not divide
 
 
-def _recent_operands(t):
+def _recent_operands(t, heads=RH):
     """One sequence of `t` whose indexer scores GROW with the key's
     position (numpy draws: nothing to compile)."""
     draw = np.random.default_rng(t).standard_normal
     f = np.float32
     ramp = (np.arange(t, dtype=f)[:, None] + 1.0) / t
     return {name: jnp.asarray(x, f) for name, x in dict(
-        q=draw((1, t, RH, D)) * 0.3, k=draw((1, t, RKV, D)) * 0.3,
+        q=draw((1, t, heads, D)) * 0.3, k=draw((1, t, RKV, D)) * 0.3,
         v=draw((1, t, RKV, D)), q_idx=np.abs(draw((1, t, HI, DI))),
         k_idx=ramp[None] + 0.01 * draw((1, t, DI)),
-        w=np.abs(draw((1, t, HI))) + 0.1, ct=draw((1, t, RH, D))).items()}
+        w=np.abs(draw((1, t, HI))) + 0.1, ct=draw((1, t, heads, D))).items()}
 
 
-@pytest.fixture(scope="module", params=("scattered", "recent"))
+@pytest.fixture(scope="module", params=("scattered", "recent", "odd_group"))
 def results(request, operands):
-    """Every route's results: on the module's random operands, and on one
-    sequence of `LONG` under the indexer that prefers recent keys."""
+    """Every route's results: on the module's random operands (groups of
+    two heads; the backward's own step there is one head at (32 x 64),
+    the other the pair at single tiles), on one sequence of `LONG` under
+    the indexer that prefers recent keys (its own: the pair at single
+    tiles, five q blocks — dq carried across key blocks; the other: one
+    head), and on one of T whose one group is `ODD` heads (no pair
+    divides it: one head, at (32 x 64) or at single tiles)."""
     if request.param == "scattered":
-        return _all_routes(operands)
-    return _all_routes(_recent_operands(LONG), RECENT_TOPK)
+        return _all_routes(operands, TOPK, (2, 16, 16))
+    if request.param == "recent":
+        return _all_routes(_recent_operands(LONG), RECENT_TOPK, (1, 16, 16))
+    return _all_routes(_recent_operands(T, ODD), RECENT_TOPK, (1, 16, 16))
 
 
-@pytest.mark.parametrize("route", ("plain", "kernel"))
+@pytest.mark.parametrize("route", ROUTES[:2])
 def test_the_chosen_set_is_the_sorted_one(results, route):
     idx, real = results["sets"]
     mask = np.asarray(results[route][0][1][2]) != 0
@@ -147,7 +165,7 @@ def test_the_chosen_set_is_the_sorted_one(results, route):
         assert not mask[0, LONG - 1, :LONG - 16].any()
 
 
-@pytest.mark.parametrize("route", ("plain", "kernel"))
+@pytest.mark.parametrize("route", ROUTES[:2])
 def test_forward_and_kl_are_the_gathered_softmaxs(results, route):
     (_, (want_o, want_kl, _)), _ = results["gathered"]
     (_, (o, kl, _)), _ = results[route]
@@ -155,11 +173,13 @@ def test_forward_and_kl_are_the_gathered_softmaxs(results, route):
     assert abs(float(kl) - float(want_kl)) < 1e-5 * float(want_kl)
 
 
-@pytest.mark.parametrize("route", ("plain", "kernel"))
+@pytest.mark.parametrize("route", ROUTES)
 def test_the_vjp_is_the_gathered_softmaxs(results, route):
-    """Every operand's cotangent, of the output and of the KL."""
+    """Every operand's cotangent, of the output and of the KL; finite on
+    a row that keeps no key in its leading blocks."""
     for operand, name in enumerate(NAMES):
         want = results["gathered"][1][operand]
+        assert np.isfinite(results[route][1][operand]).all(), name
         np.testing.assert_allclose(
             results[route][1][operand], want, rtol=1e-3,
             atol=1e-5 * float(jnp.abs(want).max()) + 1e-7, err_msg=name)
@@ -206,6 +226,38 @@ def test_every_block_geometry_of_the_forward_is_the_plain_attention(blocks):
             blocks=blocks))(*rows, mask)
     np.testing.assert_allclose(o, want_o, rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(lse, want_lse, rtol=1e-5)
+
+
+@pytest.mark.parametrize("units", (1, 2))
+@pytest.mark.parametrize("blocks", ((16, 16), (16, 32), (32, 16), (32, 64)))
+def test_every_step_of_the_backward_is_the_plain_attentions_vjp(
+        blocks, units):
+    """The fused backward at one and at two heads a unit and every shape
+    of (q rows x keys) a grid step, whole tiles at or below the diagonal
+    only, late rows' leading key blocks empty: dq, dk and dv of the dense
+    lines."""
+    (q, k, v), mask, _, _ = _plain_forward()
+    do = jnp.asarray(np.random.default_rng(5).standard_normal(q.shape),
+                     jnp.float32)
+
+    def plain(q, k, v):
+        o, _ = sa._plain_attend(*(x.reshape(1, T, -1, D) for x in (q, k, v)),
+                                mask != 0, SCALE)
+        return o.reshape(q.shape)
+
+    plan = dict(scale=SCALE, n_kv=RKV, block=16, interpret=True)
+    with jax.default_matmul_precision("highest"):
+        o, vjp = jax.vjp(plain, q, k, v)
+        _, lse = sa._fwd_pallas(q, k, v, mask, **plan)
+        delta = (do * o).reshape(1, T, RKV, RH // RKV, D).sum(-1).transpose(
+            0, 2, 1, 3)
+        got = jax.jit(functools.partial(
+            sa._bwd_pallas, step=(units, *blocks), **plan))(
+                q, k, v, do, lse, delta, mask)
+    for name, x, want in zip("qkv", got, vjp(do)):
+        np.testing.assert_allclose(
+            x, want, rtol=1e-4, atol=1e-5 * float(jnp.abs(want).max()),
+            err_msg="d" + name)
 
 
 @pytest.mark.parametrize("bq,bk,rel,bands", (
@@ -282,6 +334,33 @@ def test_the_route_is_the_shapes_and_the_site(on_tpu):
     assert sa.sparse_route(16384 + 8, 128, 64) == "plain"  # no whole block
     assert sa.sparse_route(16384, 64, 64) == "plain"  # half a slab a head
     assert sa.sparse_route(8, 128, 64) == "plain"  # a parameter draw
+
+
+def test_the_backwards_step_is_the_shapes(on_tpu):
+    """(heads of a group, q rows, keys) a grid step of the fused
+    backward: the fastest measured step that divides the group and the
+    sequence and whose whole-length sums fit VMEM; where one head's at
+    one tile does not there is no kernel route at all."""
+    assert sa.bwd_step(16384, 128, 8) == (1, 1024, 2048)  # the cell's shape
+    assert sa._bwd_vmem(2, 16384, 128, 2, 1024, 2048) > sa._VMEM  # no pair
+    assert sa.bwd_step(16384, 128, ODD) == (1, 1024, 2048)
+    # an odd number of tiles: single tiles, and there the pair is faster
+    assert sa.bwd_step(16384 + 512, 128, 8) == (2, 512, 512)
+    assert sa.bwd_step(16384 + 512, 128, ODD) == (1, 512, 512)
+    assert sa.bwd_step(16384 + 1024, 128, 8) == (1, 1024, 1024)
+    assert sa.bwd_step(16384, 128, 8, itemsize=4) == (1, 1024, 1024)
+    # the whole-length sums outgrow the wide steps, then every step
+    assert sa.bwd_step(24576, 128, 8) == (1, 1024, 1024)
+    assert sa.bwd_step(30720, 128, 8) == (1, 512, 512)
+    assert sa.bwd_step(30720 + 512, 128, 8) is None
+    assert sa._bwd_vmem(1, 30720 + 512, 128, 2, 512, 512) > sa._VMEM
+    assert sa.sparse_route(30720, 128, 64) == "kernel"
+    assert sa.sparse_route(30720 + 512, 128, 64) == "plain"
+    assert sa.sparse_route(16384, 128, 64, itemsize=4) == "kernel"
+    # the tests' own shapes
+    assert sa.bwd_step(T, D, H // KV, 4, 16) == (1, 32, 64)
+    assert sa.bwd_step(LONG, D, RH // RKV, 4, 16) == (2, 16, 16)
+    assert sa.bwd_step(T, D, ODD, 4, 16) == (1, 32, 64)
 
 
 def test_off_the_chip_the_route_is_plain():
