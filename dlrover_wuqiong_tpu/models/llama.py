@@ -11,6 +11,11 @@ RMSNorm over each head's lanes (models/lfm2.py's layers).
 `rope` make it Granite's.  `attn_gate` puts one sigmoid gate a head and
 token on the attention's output; tables narrower than half a head rotate
 the head's first features only (models/laguna.py's layers).
+`attn_out_gate` is the ELEMENTWISE output gate that comes out of
+`q_proj` itself (a head's 2 x d lanes are `[query | gate]`, and the
+attention's output is multiplied lane by lane by sigmoid(gate):
+models/qwen3_next.py's layers), and `norm_zero_centred` the `(1 + w)`
+form of `RMSNorm`, w drawn at 0, for the per-head QK norms.
 `attn_index_topk` makes the attention SPARSE by a learned choice: a
 `models/sparse_indexer.SparseIndexer` scores the causal keys, each query
 keeps the top `attn_index_topk`, and `ops/sparse_attention.py` runs the
@@ -91,6 +96,14 @@ class LlamaConfig:
     # `o_proj`: sigmoid(x @ g_proj), x the block's normalised input
     # (arXiv:2505.06708's head-wise form); False = none
     attn_gate: bool = False
+    # an ELEMENTWISE gate on the attention's output that `q_proj` itself
+    # carries: q_proj is hidden x heads * 2 * size, a head's lanes
+    # `[query | gate]`, and the output is multiplied lane by lane by
+    # sigmoid(gate) before `o_proj` (models/qwen3_next.py); not both gates
+    attn_out_gate: bool = False
+    # the QK norms' scales in the zero-centred form: `RMSNorm(...,
+    # zero_centred=True)`, x * rsqrt(...) * (1 + w), w drawn at 0
+    norm_zero_centred: bool = False
     # a learned choice of keys (ops/sparse_attention.py): each query
     # keeps the `attn_index_topk` causal keys its indexer scores highest
     # (`attn_index_heads` heads of `attn_index_dim` over ONE shared key);
@@ -121,13 +134,15 @@ class LlamaConfig:
         return self.attn_head_dim or self.hidden_size // self.num_heads
 
     def attention_params(self) -> int:
-        """q, k, v, o, the QK-norm's scales, the output gate's product
-        and the sparse indexer's leaves (its q heads, its one key with
-        the key's LayerNorm, a weight a head); no block norm."""
+        """q (twice as wide under `attn_out_gate`), k, v, o, the
+        QK-norm's scales, the head-wise output gate's product and the
+        sparse indexer's leaves (its q heads, its one key with the key's
+        LayerNorm, a weight a head); no block norm."""
         h, q = self.hidden_size, self.num_heads * self.head_dim
         kv = self.num_kv_heads * self.head_dim
         idx = self.attn_index_dim
         return 2 * h * q + 2 * h * kv + (q + kv if self.qk_norm else 0) \
+            + (h * q if self.attn_out_gate else 0) \
             + (2 * self.head_dim if self.qk_head_norm else 0) \
             + (h * self.num_heads if self.attn_gate else 0) \
             + (h * (self.attn_index_heads * (idx + 1) + idx) + 2 * idx
@@ -137,7 +152,8 @@ class LlamaConfig:
         """The feed-forward slot: a SwiGLU, or `moe`'s expert layer — the
         router over all experts, the experts HELD here (three matrices
         each, relu2 two), a selection bias, a shared expert of the same
-        form — each routed expert `intermediate_size` wide."""
+        form and its gate's vector — each routed expert
+        `intermediate_size` wide."""
         h, i = self.hidden_size, self.intermediate_size
         if self.moe is None:
             return 3 * h * i
@@ -145,7 +161,8 @@ class LlamaConfig:
         mats = 2 if m.expert_act == "relu2" else 3
         return (m.num_experts * h + m.held * mats * h * i
                 + (m.num_experts if m.selection_bias else 0)
-                + mats * h * m.shared_width)
+                + mats * h * m.shared_width
+                + (h if m.shared_gate else 0))
 
     def num_params(self) -> int:
         h = self.hidden_size
@@ -154,15 +171,23 @@ class LlamaConfig:
 
 
 class RMSNorm(nn.Module):
+    """x * rsqrt(mean(x^2) + eps) * w, w drawn at 1 — or, `zero_centred`,
+    * (1 + w) with w drawn at 0 (the form whose weight decay pulls the
+    scale to 1, not to 0); the statistics in float32 either way."""
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.zero_centred
+            else nn.initializers.ones, (x.shape[-1],))
         x32 = x.astype(jnp.float32)
         norm = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        if self.zero_centred:
+            scale = 1.0 + scale
         return (norm * scale).astype(self.dtype)
 
 
@@ -321,8 +346,18 @@ class LlamaAttention(nn.Module):
         cfg = self.config
         B, T, C = x.shape
         hd = cfg.head_dim
-        q = dense(cfg, cfg.num_heads * hd, "q_proj", use_bias=False)(
-            x)
+        q = dense(cfg, cfg.num_heads * hd * (2 if cfg.attn_out_gate else 1),
+                  "q_proj", use_bias=False)(x)
+        if cfg.attn_out_gate:
+            if cfg.attn_gate or cfg.attn_index_topk:
+                raise ValueError("attn_out_gate beside a head-wise gate or "
+                                 "a learned choice of keys: no layer has "
+                                 "asked for both")
+            # a head's 2 * hd lanes are [query | gate]: two slices of the
+            # head-major view, each laid out again as the projections' own
+            q, out_gate = (
+                part.reshape(B, T, cfg.num_heads * hd) for part in jnp.split(
+                    q.reshape(B, T, cfg.num_heads, 2 * hd), 2, axis=-1))
         k = dense(cfg, cfg.num_kv_heads * hd, "k_proj", use_bias=False)(x)
         if cfg.qk_norm:
             with jax.named_scope("qk_norm"):
@@ -335,9 +370,10 @@ class LlamaAttention(nn.Module):
             with jax.named_scope("qk_norm"):
                 # a head's lanes last for the statistic, then the
                 # projections' own layout again
-                q = RMSNorm(cfg.rms_eps, cfg.dtype, name="q_norm")(
+                zc = cfg.norm_zero_centred
+                q = RMSNorm(cfg.rms_eps, cfg.dtype, zc, name="q_norm")(
                     q.reshape(B, T, cfg.num_heads, hd)).reshape(q.shape)
-                k = RMSNorm(cfg.rms_eps, cfg.dtype, name="k_norm")(
+                k = RMSNorm(cfg.rms_eps, cfg.dtype, zc, name="k_norm")(
                     k.reshape(B, T, cfg.num_kv_heads, hd)).reshape(k.shape)
         v = dense(cfg, cfg.num_kv_heads * hd, "v_proj", use_bias=False)(x)
         # where a head is a lane slab the kernels index q, k and v in the
@@ -435,6 +471,20 @@ class LlamaAttention(nn.Module):
                     y = gate_rows(y, g).astype(cfg.dtype)
                 else:
                     y = (y * jnp.repeat(g, hd, axis=-1)).astype(cfg.dtype)
+        if cfg.attn_out_gate:
+            from ..ops.flash_attention import kernel_lanes
+
+            with jax.named_scope("gate"):
+                # lane by lane on the layout the kernels wrote: one fused
+                # pass, nothing to spread over a head
+                g = jax.nn.sigmoid(out_gate.astype(jnp.float32))
+                self.sow("intermediates", "attn_gate_mean",
+                         jax.lax.stop_gradient(g.mean()))
+                y = (y * g).astype(cfg.dtype)
+            # counted, not timed (static numbers): the lanes a score
+            # entry's two products run at this head size, the model's
+            self.sow("intermediates", "attn_lanes", jnp.asarray(
+                (kernel_lanes(hd, hd), 2 * hd), jnp.float32))
         return dense(cfg, C, "o_proj", use_bias=False)(y)
 
 

@@ -41,7 +41,12 @@ An expert is three matrices under a gate — SwiGLU `(silu(x Wg) * (x Wi))
 Wd` or ReGLU `(relu(x Wg) * (x Wi)) Wd` — or relu^2's two
 (`MoEConfig.expert_act`; the gated forms share every line of the grouped
 path, `grouped_experts(gate_act=...)`, and the capacity path knows
-SwiGLU alone).  The router reads the experts' own input unless the block
+SwiGLU alone).  One always-on expert of the routed experts' form may
+stand beside them (`shared_width`), and where `shared_gate` is set its
+output is multiplied by ONE sigmoid gate a token, sigmoid(x w_s) from a
+(hidden, 1) leaf `shared_expert_gate` — product and sigmoid in float32,
+under the scope `shared` with the expert it gates; the layer sows the
+gate's mean (`moe_shared_gate_mean`).  The router reads the experts' own input unless the block
 hands it another (`MoEMLP(x, router_input=h)`: a router placed before
 the block's attention reads the block's normalised input; its product
 stays under the scope `moe/router`).
@@ -187,6 +192,11 @@ class MoEConfig:
     # token, in the routed experts' form (`expert_act`: relu2's two
     # matrices, or a gated form's three; scope `shared`); 0 = none
     shared_width: int = 0
+    # ONE sigmoid gate a token on the shared expert's output, from a
+    # (hidden, 1) leaf `shared_expert_gate`: out += sigmoid(x w_s) *
+    # shared(x) (models/qwen3_next.py); False = the shared expert is
+    # added as it is.  The layer sows the gate's mean
+    shared_gate: bool = False
     # how many of the num_experts this layer holds, experts first_expert
     # .. first_expert + experts_held - 1; 0 = all of them
     experts_held: int = 0
@@ -203,7 +213,8 @@ class MoEConfig:
                if f.name in ("score_func", "selection_bias", "routed_scaling",
                              "gate_norm_eps",
                              "bias_update_rate", "n_group", "topk_group",
-                             "expert_act", "shared_width", "experts_held",
+                             "expert_act", "shared_width", "shared_gate",
+                             "experts_held",
                              "first_expert", "norm_topk_prob")
                and getattr(self, f.name) != f.default}
         if self.aux_loss == "none":
@@ -832,7 +843,8 @@ def _aux_loss(cfg: MoEConfig, logits, probs, load):
 class MoEMLP(nn.Module):
     """Drop-in MLP replacement: router + stacked experts (SwiGLU or
     ReGLU, or relu^2 without a gate matrix), and where `shared_width` is
-    set one always-on expert of the same form beside them.  The router reads the
+    set one always-on expert of the same form beside them — under ONE
+    sigmoid gate a token where `shared_gate` is set.  The router reads the
     experts' own input unless it is handed another (`router_input`: a
     block whose router sits before its attention).
 
@@ -861,6 +873,9 @@ class MoEMLP(nn.Module):
                 f"impl='grouped': top_k_gating is a softmax router with "
                 f"renormalised gates over SwiGLU experts that are all "
                 f"held, and nothing else")
+        if cfg.shared_gate and not cfg.shared_width:
+            raise ValueError("shared_gate gates the shared expert: there "
+                             "is none")
         if cfg.bias_update_rate and not cfg.selection_bias:
             raise ValueError("bias_update_rate sets the selection bias: "
                              "there is none")
@@ -995,7 +1010,17 @@ class MoEMLP(nn.Module):
                 else:  # the routed experts' form: a third matrix, gated
                     h = _GATE_ACTS[cfg.expert_act](dense(
                         cfg.shared_width, name="shared_gate_proj")(tokens)) * h
-                out = out + dense(d, name="shared_down_proj")(h)
+                shared = dense(d, name="shared_down_proj")(h)
+                if cfg.shared_gate:
+                    # one number a token, float32 from the product on
+                    gate = jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=jnp.float32,
+                        name="shared_expert_gate")(
+                            tokens.astype(jnp.float32)))
+                    self.sow("intermediates", "moe_shared_gate_mean",
+                             jax.lax.stop_gradient(gate.mean()))
+                    shared = (shared * gate).astype(shared.dtype)
+                out = out + shared
         return out.reshape(B, T, d)
 
 
@@ -1045,7 +1070,9 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     elementwise passes walk, of their rows `dispatch` fetches and of
     their assignments the sums by assignment index; under a group limit
     `moe_group_limit_binds`, the share of tokens (all layers) whose k
-    experts differ from the k an unlimited choice would take."""
+    experts differ from the k an unlimited choice would take; where the
+    shared experts are gated (`MoEConfig.shared_gate`)
+    `moe_shared_gate_mean`, the gates' mean over tokens and layers."""
     counts = [n.astype(jnp.float32)
               for n in _sown(intermediates, "moe_tokens_per_expert")]
     if not counts:
@@ -1065,4 +1092,8 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
         if tiles:
             walked, of = jnp.concatenate(tiles).astype(jnp.float32).sum(0)
             stats[stat] = walked / of
+    gates = [v.reshape(()) for v in _sown(intermediates,
+                                          "moe_shared_gate_mean")]
+    if gates:
+        stats["moe_shared_gate_mean"] = jnp.stack(gates).mean()
     return stats

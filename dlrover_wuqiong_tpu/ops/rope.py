@@ -88,7 +88,12 @@ def rope_route(lanes: int, d: int, mesh=None, rotated: int = 0) -> str:
     head may (`mosaic.slab_heads`: 128, 64 or 32 lanes), the rows are a
     whole number of 128-lane slabs or one lone head (half a slab:
     padded), and the call runs on one of `_SITES` (`mesh` is the model
-    config's); else "plain", `models/llama.apply_rope`'s own lines.  The
+    config's); else "plain", `models/llama.apply_rope`'s own lines.  A
+    head of TWO slabs is "plain" whatever turns: d = 256 with its first
+    64 lanes rotated (`rope_route(4096, 256, mesh, 64)`: qwen3_next's
+    attention) takes the formula — the kernel walks a row a slab at a
+    time and a head's place in it is an iota % d within ONE slab
+    (tests/test_kernel_site.py pins the two calls).  The
     static counter of the decision, with the compiled step's count of
     `dwt_rope` custom calls, as `ops/ssd.scan_route` is of the scan's."""
     heads = mosaic.slab_heads(d)

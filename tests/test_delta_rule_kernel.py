@@ -3,7 +3,7 @@ a head `dwt_gdr_fwd`, `dwt_gdr_bwd`; a decay a key CHANNEL `dwt_kda_fwd`,
 `dwt_kda_bwd`) in interpret mode on the CPU, against the chunked
 `jax.numpy` form of the same equations AND against the recurrence one
 step at a time: the output and the gradient of every operand at the
-cells' widths (keys of 96, values of 192; the channel form's 128 | 128)
+cells' widths (keys of 96, values of 192; 128 | 128 for either form)
 and at the nano model's (8 and 24), chunks of 64 and of 16, one chunk a
 grid step and several side by side, more than two steps (the carried
 state, forward and in reverse), one block of heads and several, two
@@ -32,6 +32,10 @@ CASES = {
     "cell_widths_C16_three_blocks": (64, 3, 96, 192, 16, 1, 2),
     "nano_widths_C16_one_step_of_four": (64, 3, 8, 24, 16, 3, 4),
     "nano_widths_C64_two_blocks": (128, 4, 8, 24, 64, 2, 2),
+    # 128 | 128 under one decay a head, four heads a grid step: what a
+    # mixer of 32 value heads hands the scalar pair (PR 66)
+    "grouped_cell_widths_C64_two_steps_of_two": (256, 4, 128, 128, 64, 4,
+                                                 2),
     # a decay a key channel (the name says so: `_channel`)
     "channel_cell_widths_C64_three_steps_of_one": (192, 2, 128, 128, 64, 2,
                                                    1),

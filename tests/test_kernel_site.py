@@ -24,6 +24,9 @@ PR 59 added `conv_route` (`ops/short_conv.py`), a predicate the parent
 did not have: its rows are what the mixers' convolutions are handed.
 PR 61 added `gate_route` (`ops/head_gate.py`), new too: its rows are the
 rows of y a gated `LlamaAttention` multiplies.
+PR 66 added the rows of `qwen3_next`'s calls (heads of 256, 64 of them
+rotated; 32 value heads of 128 | 128 under one decay a head; the
+convolution's 2,048 and 4,096 channels): no predicate changed.
 """
 
 import types
@@ -60,6 +63,9 @@ def _scan(hb):
             ("plain", 0))
 
 
+HELD = 16  # qwen3_next's experts held (its memory rung's)
+
+
 def _swiglu(held, d, f):
     return [(held, d, f)] * 2 + [(held, f, d)]
 
@@ -79,6 +85,7 @@ TABLE = {
         ("xing4_0", (32, 192, 128), (("transposed", 0),) * 5),
         ("laguna_full", (48, 128), (("direct", 1),) * 5),
         ("laguna_sliding", (64, 128), (("direct", 1),) * 5),
+        ("qwen3_next", (16, 256), (("direct", 1),) * 5),
     ],
     # (heads, kv heads, width)
     "kv_route": [
@@ -93,6 +100,7 @@ TABLE = {
         ("xing4_0", (32, 32, 192), (("indexed", 1),) * 5),
         ("laguna_full", (48, 8, 128), (("indexed", 6),) * 5),
         ("laguna_sliding", (64, 8, 128), (("indexed", 8),) * 5),
+        ("qwen3_next", (16, 2, 256), (("indexed", 8),) * 5),
     ],
     # (heads, width, sequence[, v's width]) and NO mesh: the entry's own
     # guard, which the backend alone moves
@@ -109,6 +117,7 @@ TABLE = {
         ("laguna_full", (48, 128, 16384), (False, True, True, True, True)),
         ("laguna_sliding", (64, 128, 16384),
          (False, True, True, True, True)),
+        ("qwen3_next", (16, 256, 16384), (False, True, True, True, True)),
         ("a_sequence_no_block_tiles", (12, 64, 4099), (False,) * 5),
     ],
     # (the config's attn_impl, heads, width, sequence): the config's mesh
@@ -130,6 +139,8 @@ TABLE = {
         ("laguna_full", ("flash", 48, 128, 16384),
          (False, True, True, False, False)),
         ("laguna_sliding", ("flash", 64, 128, 16384),
+         (False, True, True, False, False)),
+        ("qwen3_next", ("flash", 16, 256, 16384),
          (False, True, True, False, False)),
         # ring and Ulysses are read only where there is a mesh to run on
         ("olmoe_over_ring", ("ring", 16, 128, 4096),
@@ -163,6 +174,9 @@ TABLE = {
         ("seven_heads_of_64", (448, 64), _NOWHERE),
         ("heads_of_32", (1024, 32), _NOWHERE),
         ("heads_of_256", (1024, 256), _NOWHERE),
+        # a head of two slabs turning its first 64 lanes: the formula
+        ("qwen3_next_q_64_of_256", (4096, 256, 64), _NOWHERE),
+        ("qwen3_next_k_64_of_256", (512, 256, 64), _NOWHERE),
     ],
     # (sequence, chunk, heads, key width, value width)
     "delta_route": [
@@ -175,6 +189,9 @@ TABLE = {
          ("chunked", ("kernel", 4), ("kernel", 4), "chunked", "chunked")),
         ("no_whole_sub_blocks", (8184, 24, 16, 128, 128, True),
          ("sequential",) * 5),
+        # 32 value heads (their 16 key heads' q and k repeated to them)
+        ("qwen3_next", (16384, 64, 32, 128, 128),
+         ("chunked", ("kernel", 4), ("kernel", 4), "chunked", "chunked")),
     ],
     # (lanes of the stream, tokens, hidden size)
     "hc_route": [
@@ -189,6 +206,8 @@ TABLE = {
          _ONE_DEVICE),
         ("olmo_hybrid_q_and_k", (8192, 1440, 4, jnp.bfloat16), _NOWHERE),
         ("olmo_hybrid_v", (8192, 2880, 4, jnp.bfloat16), _NOWHERE),
+        ("qwen3_next_q_and_k", (16384, 2048, 4, jnp.bfloat16), _ONE_DEVICE),
+        ("qwen3_next_v", (16384, 4096, 4, jnp.bfloat16), _ONE_DEVICE),
         ("no_whole_row_block", (8200, 6144, 4, jnp.bfloat16), _NOWHERE),
         ("nano", (64, 320, 4, jnp.float32), _NOWHERE),
     ],
@@ -212,6 +231,8 @@ TABLE = {
         ("kimi_vl_in", ((196608, 2048), (8, 2048, 1408), 64), _ONE_DEVICE),
         ("xing4_0_in", ((32768, 3584), (8, 3584, 1024), 64), _ONE_DEVICE),
         ("laguna_in", ((131072, 2048), (32, 2048, 512), 256), _ONE_DEVICE),
+        ("qwen3_next_in", ((163840, 2048), (HELD, 2048, 512), 512),
+         _ONE_DEVICE),
         ("olmoe_whole_layer", ((163840, 2048), (64, 2048, 1024), 64),
          _NOWHERE),
     ],
@@ -223,6 +244,8 @@ TABLE = {
         ("kimi_vl", (196608, _swiglu(8, 2048, 1408), 64), _ONE_DEVICE),
         ("xing4_0", (32768, _swiglu(8, 3584, 1024), 64), _ONE_DEVICE),
         ("laguna", (131072, _swiglu(32, 2048, 512), 256), _ONE_DEVICE),
+        ("qwen3_next", (163840, _swiglu(HELD, 2048, 512), 512),
+         _ONE_DEVICE),
         ("olmoe_whole_layer", (163840, _swiglu(64, 2048, 1024), 64),
          _NOWHERE),
         ("maps_over_vmem", (98304, _swiglu(8, 8192, 1024), 128), _NOWHERE),

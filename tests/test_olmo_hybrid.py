@@ -348,3 +348,68 @@ def test_the_lifted_convolution_left_the_hybrids_parameter_trees_alone():
         {"params": mixer.init(jax.random.PRNGKey(0), u)["params"]},
         u).as_text(debug_info=True)
     assert "Mamba2Mixer/conv/" in text
+
+
+# ------------------- what PR 66's extensions left as it was (sha256, PR 65)
+
+def _mixer_text(dtype):
+    cfg = GatedDeltaConfig(hidden_size=48, num_heads=3, key_dim=8,
+                           value_dim=24, chunk_size=16, dtype=dtype)
+    mixer = GatedDeltaMixer(cfg)
+    u = jax.ShapeDtypeStruct((2, 64, 48), jnp.float32)
+    params = jax.eval_shape(lambda: mixer.init(
+        jax.random.PRNGKey(1), jnp.zeros(u.shape))["params"])
+
+    def loss(p, u):
+        out, sown = mixer.apply({"params": p}, u, mutable=["intermediates"])
+        return out.astype(jnp.float32).sum() \
+            + sown["intermediates"]["delta_stats"][0].sum()
+
+    return jax.jit(jax.value_and_grad(loss)).lower(params, u).as_text()
+
+
+def _shared_expert_text():
+    from dlrover_wuqiong_tpu.models import moe
+
+    layer = moe.MoEMLP(24, 16, moe.MoEConfig(
+        num_experts=8, top_k=2, impl="grouped", dtype=jnp.float32,
+        aux_loss="none", shared_width=16))
+    x = jax.ShapeDtypeStruct((2, 16, 24), jnp.float32)
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(1), jnp.zeros(x.shape))["params"])
+    return jax.jit(jax.value_and_grad(
+        lambda p, x: layer.apply({"params": p}, x).sum())).lower(
+            params, x).as_text()
+
+
+def _norm_text():
+    from dlrover_wuqiong_tpu.models.llama import RMSNorm
+
+    norm = RMSNorm(1e-6, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((3, 16), jnp.float32)
+    params = jax.eval_shape(lambda: norm.init(jax.random.PRNGKey(1),
+                                              jnp.zeros(x.shape)))
+    return jax.jit(norm.apply).lower(params, x).as_text()
+
+
+@pytest.mark.parametrize("text,sha", [
+    (functools.partial(_mixer_text, jnp.float32),
+     "405ce1e4fc078b09663d2e4b89c5d1d878c1ea6745761c7af8580c5f2c3d5804"),
+    (functools.partial(_mixer_text, jnp.bfloat16),
+     "0fff0fdb20933d197685fbb65efe481d06d87a4e31db0d2c1d3165aa684ed65a"),
+    (_shared_expert_text,
+     "90e1d46db83060a8156014e47d8cffe33f966b320c5d3baa77b935325ad1f3a6"),
+    (_norm_text,
+     "0817bbb873205170c72ffdd97176cd411d06937ea69ad0de4e7863214138d833"),
+], ids=["mixer_f32", "mixer_bf16", "ungated_shared_expert", "plain_norm"])
+def test_what_the_grouped_heads_and_gates_extend_lowers_to_the_parents_text(
+        text, sha):
+    """`GatedDeltaMixer` at equal heads (value and gradient, its five
+    counters), an expert layer whose shared expert is ungated, and the
+    plain `RMSNorm` lower on the CPU to the text they lowered to before
+    `num_key_heads`, `neg_eigval`, `shared_gate` and `zero_centred`
+    were there (the sha256 of that text, taken from the parent commit's
+    checkout, PR 65's tree): bit for bit what they were."""
+    import hashlib
+
+    assert hashlib.sha256(text().encode()).hexdigest() == sha

@@ -657,6 +657,71 @@ def test_a_kda_mixer_takes_the_channel_pair_on_one_tpu_device_only(
     assert _pallas_calls(_kda_mixer_grad_jaxpr(two).jaxpr) == []
 
 
+def _qwen3_next_routes(mesh):
+    """Every route `qwen3_next_80b_a3b.steady` asks, at the cell's
+    shapes: one sequence of 16,384, 16 query heads over 2 kv heads of
+    256 with 64 lanes rotated, 32 value heads over 16 key heads of 128 |
+    128, a convolution over 2,048 | 2,048 | 4,096 channels, 16 of 512
+    experts of 512 held, 10 a token."""
+    from dlrover_wuqiong_tpu.models.attention import goes_direct
+    from dlrover_wuqiong_tpu.models.qwen3_next import Qwen3NextConfig
+    from dlrover_wuqiong_tpu.ops import delta_rule, grouped_matmul as gm
+    from dlrover_wuqiong_tpu.ops import rope, short_conv
+
+    cfg = Qwen3NextConfig(vocab_size=18992, num_layers=4, experts_held=16,
+                          mesh=mesh)
+    t, lin = 16384, cfg.linear_config()
+    assert (lin.num_heads, lin.key_heads, lin.neg_eigval, lin.mesh) == \
+        (32, 16, False, mesh)
+    experts = [(16, 2048, 512)] * 2 + [(16, 512, 2048)]
+    return {
+        "attention_route": fa.attention_route(16, 256),
+        "kv_route": fa.kv_route(16, 2, 256),
+        "goes_direct": goes_direct(cfg.attention_config(), 16, 256, t),
+        "backward_route": fa.backward_route(t, t, 256, 256, 1, 16)[0],
+        "rope_route_q": rope.rope_route(16 * 256, 256, mesh, 64),
+        "rope_route_k": rope.rope_route(2 * 256, 256, mesh, 64),
+        "delta_route": delta_rule.delta_route(t, 64, 32, 128, 128, mesh),
+        "conv_route_q_and_k": short_conv.conv_route(
+            t, 2048, 4, jnp.bfloat16, mesh),
+        "conv_route_v": short_conv.conv_route(t, 4096, 4, jnp.bfloat16,
+                                              mesh),
+        "experts_route": gm.experts_route(t * 10, experts, 512, mesh),
+    }
+
+
+@pytest.mark.parametrize("on_tpu,mesh,routes", [
+    (True, None, {
+        "attention_route": ("direct", 1), "kv_route": ("indexed", 8),
+        "goes_direct": True, "backward_route": "fused",
+        "rope_route_q": "plain", "rope_route_k": "plain",
+        "delta_route": ("kernel", 4), "conv_route_q_and_k": "kernel",
+        "conv_route_v": "kernel", "experts_route": "kernel"}),
+    (True, _two_devices, {
+        "attention_route": ("direct", 1), "kv_route": ("indexed", 8),
+        "goes_direct": False, "backward_route": "fused",
+        "rope_route_q": "plain", "rope_route_k": "plain",
+        "delta_route": "chunked", "conv_route_q_and_k": "plain",
+        "conv_route_v": "plain", "experts_route": "plain"}),
+    (False, None, {   # every CPU run
+        "attention_route": ("direct", 1), "kv_route": ("indexed", 8),
+        "goes_direct": False, "backward_route": "fused",
+        "rope_route_q": "plain", "rope_route_k": "plain",
+        "delta_route": "chunked", "conv_route_q_and_k": "plain",
+        "conv_route_v": "plain", "experts_route": "plain"}),
+], indirect=["on_tpu"])
+def test_the_qwen3_next_cells_routes_are_its_shapes_and_its_site(
+        on_tpu, mesh, routes):
+    """PR 66's cell from its shapes alone: heads of 256 go DIRECT (two
+    slabs a head, eight query heads reading one kv head's), the rotation
+    of 64 of 256 lanes keeps the formula, the recurrence runs the scalar
+    pair at 128 | 128 four value heads a grid step, the convolution the
+    Pallas pair (whole lane tiles, which the Olmo hybrid's 1,440 are
+    not), the share of the experts the held-rows kernels — on one TPU
+    device and nowhere else."""
+    assert _qwen3_next_routes(mesh and mesh()) == routes
+
+
 @pytest.mark.parametrize("dtype,sha", [
     (jnp.float32,
      "8a984bb94ddcdc4f436c9c961286e605a9f18602ff27cf5058797de82fab862f"),
