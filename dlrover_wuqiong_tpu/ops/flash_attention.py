@@ -87,7 +87,17 @@ Design (FA2 scheme, canonical Mosaic structure):
   repeated: where a head is a slab k and v keep their projections' own
   (b, s, n_kv*d) and query slab s reads slab s // rep of them (`kv_route`,
   `_Slabs.kv_rep`); dk and dv leave the kernels a query head and the
-  entry sums a group's.  `attention_route(h, d)` says which
+  entry sums a group's.  A grouped CAUSAL forward over several key
+  blocks (no window, sq == sk) takes another step: the GROUP
+  (`forward_route`, `_fa_grp_fwd_kernel`, `dwt_fa_grp_fwd`) — the group's
+  query slabs lie side by side, so its q and o block is ONE lane range
+  of (block q rows) x (`_group_heads` heads x d lanes) against the kv
+  head's one (block keys, d) slab, fetched once for the step's heads
+  and not at all above the diagonal (the key block's index is clamped
+  there); inside the step the group's first products, then its
+  softmaxes, then its products with v, in three runs.  o and lse leave
+  as the slab step leaves them, the backward reads them unchanged.
+  `attention_route(h, d)` says which
   layout a shape takes: direct when the heads fall on slab boundaries —
   a head is a slab (d % 128 == 0), or two heads of 64 share one and
   their number is even — transposed otherwise (GPT-2 XL's 25 heads,
@@ -934,7 +944,9 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
                        block_q: int, block_k: int, interpret: bool,
                        tile: Optional[int] = None,
                        slabs: Optional[_Slabs] = None,
-                       window: Optional[int] = None):
+                       window: Optional[int] = None,
+                       route: Optional[Tuple[str, int]] = None,
+                       blocks: Optional[Tuple[int, int]] = None):
     """q: (bh, sq, d), k: (bh, sk, d), v: (bh, sk, dv) → (o (bh, sq, dv),
     lse (bh, 1, sq) f32): dv is d, or v's own width (each a block's whole
     last dimension: no operand is padded to the other's); with `slabs`,
@@ -944,6 +956,8 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
     `tile` overrides `_causal_tile` (tests and sweeps: a tile the size of
     the block is the whole-block mask); no caller of the package sets it.
     `window`: `_effective_window`'s (None: the causal program).
+    `route` overrides `forward_route`, and `blocks` = (q rows, keys) the
+    group step's, which are the call's own (tests and sweeps again).
     """
     sq, sk = q.shape[1], k.shape[1]
     bh, d, dv, pack, groups, _, lanes = _geometry(q, v, slabs)
@@ -951,6 +965,12 @@ def _fa_forward_pallas(q, k, v, causal: bool, sm_scale: float,
     qo, ko, vo = slabs.offsets if slabs else (0, 0, 0)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
+    how, group = route or forward_route(
+        sq, sk, d, slabs.kv_rep if slabs else 1, causal, window, block_k)
+    if how == "group":
+        return _fa_group_forward(q, k, v, sm_scale, slabs, group,
+                                 *(blocks or (block_q, block_k)), tile,
+                                 interpret)
     num_kv, keys = sk // block_k, 1  # keys: the grid axis that walks them
     win = _window_plan(window, sq // block_q, num_kv, block_q, block_k,
                        sk - sq)
@@ -1001,6 +1021,198 @@ def _slab_heads(slabs: Optional[_Slabs]) -> dict:
     """The kernels' static `slab_heads`; nothing for the transposed
     layout, whose unit is a head."""
     return {} if slabs is None else {"slab_heads": slabs.heads}
+
+
+# ------------------------------------- a group of query heads a grid step
+#
+# Under grouped heads on the direct route the `rep` query heads of a kv
+# head lie side by side in q's (b, s, h*d) rows and read ONE k and v
+# slab: a grid step is the GROUP over that slab.
+
+_GROUP_HEADS = 7     # the most query heads a group step takes, and
+_GROUP_LANES = 1024  # the most lanes of q they span: see `_group_heads`
+
+
+def _group_heads(rep: int, width: int) -> int:
+    """Query heads of a group of `rep`, `width` lanes each, a grid step
+    takes: the largest divisor of `rep` up to `_GROUP_HEADS` whose heads
+    span at most `_GROUP_LANES` — the whole group where that is one (6,
+    7), else a part of it (16 heads of 128 in four steps of 4, 8 heads
+    of 256 in two of 4).
+
+    Measured on the chip at the four grouped cells' causal calls, the
+    forward alone by device time, ms a call (PERF.md section 6, PR 67;
+    `tools/perf_probe.py attn_direct` runs the table again).  The step
+    is the slab step's own (1,024 q rows x 1,024 keys): there o and lse
+    are the slab step's bit for bit, and of the six geometries swept it
+    was the fastest at every d = 128 shape —
+      Laguna 1 x 16,384 x 48/8 (slab step 28.09; 6 heads a step):
+        (1024 x 1024) 19.84, (512 x 1024) 20.85, (256 x 1024) 22.26,
+        (1024 x 512) 33.16, (512 x 512) 34.72, (512 x 2048) 87.52;
+        3 heads a step 20.78, 2 heads 21.96;
+      SmallThinker 2 x 16,384 x 28/4 (32.33; 7 heads): 23.10, 24.06,
+        25.32, 38.20, 39.16, 104.74;
+      Nemotron 2 x 8,192 x 32/2 (9.77): 4 heads a step 7.45 at (1024 x
+        1024) and 8.16 at (512 x 1024); 8 heads 17.66 and 7.66; 16 heads
+        refused (VMEM) and 20.09;
+      Qwen3-Next 1 x 16,384 x 16/2 x 256 (16.44): 4 heads 13.53 at (1024
+        x 1024), 13.84 at (512 x 1024), 12.86 at (1024 x 512); 8 heads
+        27.98, 35.39 and 39.99, 12.99 at (512 x 512); 2 heads 13.57.
+    A step's time falls off a cliff, 2.4-4 times, at 8 heads of 128 on
+    (1024 x 1024) (32 MiB of float32 scores a step; 7 heads, 28 MiB, run)
+    and at 2,048 lanes of q on (512 x 1024) (16 heads of 128, 8 of 256;
+    1,024 lanes run); (2048 x 1024) fell at 3 heads of 128 (64.66) and
+    2 of 256 (34.81) and ran at 2 of 128 (20.77).  What Mosaic holds of
+    a step is no byte count read off these shapes, so the two bounds are
+    the largest that RAN under each, not a model of it.  The q pre-scale
+    hoisted to a q block's first key step moved nothing (19.89 for
+    19.84)."""
+    return max((g for g in range(1, _GROUP_HEADS + 1)
+                if rep % g == 0 and g * width <= _GROUP_LANES), default=1)
+
+
+def forward_route(sq: int, sk: int, d: int, rep: int = 1,
+                  causal: bool = True, window: Optional[int] = None,
+                  block_k: int = 1024) -> Tuple[str, int]:
+    """Which step a forward call's grid takes, from its shapes alone:
+    ("group", query heads a grid step) — `_fa_grp_fwd_kernel`, a kv
+    head's group of query heads (or a part of it: `_group_heads`) over
+    their one k and v slab — or ("slab", 0): `_fa_fwd_kernel`, a slab
+    (or `_fit_pack` heads of the transposed layout) a step.
+
+    The group step is for what it was measured at: grouped heads indexed
+    on the direct route (`rep` = `_Slabs.kv_rep` > 1: a head is one or
+    more whole slabs, `d` lanes), causal, no window, sq == sk over
+    several key blocks of `block_k`.  One key block, a window,
+    `sq != sk`, a head of its own k and v, two heads a slab and the
+    transposed layout keep the slab step.
+
+    The counter of this decision, as `backward_route` is of the
+    backward's; in a trace its witness is the kernel's name
+    (`dwt_fa_grp_fwd` / `dwt_fa_fwd`) and grid."""
+    block_k = _fit_block(sk, block_k) or sk
+    heads = _group_heads(rep, d)
+    if heads > 1 and causal and window is None and sq == sk > block_k:
+        return "group", heads
+    return "slab", 0
+
+
+def _fa_grp_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                       acc_scr, *, sm_scale: float, heads: int, width: int,
+                       tile: Optional[int]):
+    """One (batch row x kv head x part of its group, q block, key block)
+    of a causal call with sq == sk: `heads` query heads, `width` lanes
+    each of the q block's one lane range, against the one k and v block.
+
+    The mathematics is `_fa_fwd_kernel`'s, operand for operand (q
+    pre-scaled by sm_scale * LOG2E in its own dtype, float32 scores,
+    statistics and accumulators, the operands' own dtype into the MXU,
+    only the tile the diagonal crosses masked, by `_rel_mask`); what
+    differs is the step and the order of its work.  A row band
+    (`_block_work`) runs the group's first products, THEN its softmaxes,
+    THEN its products with v: the MXU's and the vector units' work in
+    long runs, nothing of one head waiting on its own exponentials
+    (`sparse_attention._fwd_kernel`'s order, PERF.md section 6, PR 63).
+    The key block's index is clamped at the diagonal by the BlockSpec, so
+    a step above it fetches nothing and, here, runs nothing."""
+    bq, bk = q_ref.shape[1], k_ref.shape[1]
+    i, j = pl.program_id(1), pl.program_id(2)
+    rel = i * bq - j * bk  # the block's first query less its first key
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def _inner(mask_block: bool, off: Optional[int] = None):
+        for q0, q1, pieces in _block_work(mask_block, False, False, bq, bk,
+                                          off, tile, 0, 0, 0):
+            if pieces:  # else bq > bk: no row of the tile sees a key here
+                _band(slice(q0, q1), pieces)
+
+    def _band(rows, pieces):
+        kv = [(k_ref[0, k0:k1], v_ref[0, k0:k1]) for k0, k1, _ in pieces]
+        scores = []
+        for a in range(heads):
+            q = (q_ref[0, rows, a * width:(a + 1) * width].astype(
+                jnp.float32) * (sm_scale * LOG2E)).astype(q_ref.dtype)
+            scores.append([
+                s if mask is None else jnp.where(mask, s, NEG_INF)
+                for s, (_, _, mask) in zip(
+                    [_dot_t(q, k) for k, _ in kv], pieces)])
+        probs = []
+        for a, ss in enumerate(scores):
+            m_prev = m_scr[a, rows]
+            m_new = jnp.maximum(m_prev, _fold(jnp.maximum, ss).max(
+                axis=-1, keepdims=True))
+            ps = [jnp.exp2(s - m_new) for s in ss]
+            alpha = jnp.exp2(m_prev - m_new)
+            m_scr[a, rows] = m_new
+            l_scr[a, rows] = l_scr[a, rows] * alpha + _fold(
+                jnp.add, ps).sum(axis=-1, keepdims=True)
+            probs.append((alpha, [p.astype(v_ref.dtype) for p in ps]))
+        for a, (alpha, ps) in enumerate(probs):
+            acc_scr[a, rows] = acc_scr[a, rows] * alpha + functools.reduce(
+                jnp.add, [_dot(p, v) for p, (_, v) in zip(ps, kv)])
+
+    # below the diagonal a block runs whole; one it crosses runs the
+    # tiles at or below it, by its static place; one above it nothing
+    pl.when(rel >= bk)(functools.partial(_inner, False))
+    places = math.gcd(bq, bk)  # `rel` is a multiple of it
+    for off in range(places - bq, bk, places):
+        pl.when(rel == off)(functools.partial(_inner, True, off))
+
+    @pl.when(j == ((i + 1) * bq - 1) // bk)  # the diagonal's last block
+    def _finalize():
+        for a in range(heads):
+            l = l_scr[a]
+            o_ref[0, :, a * width:(a + 1) * width] = (
+                acc_scr[a] / l).astype(o_ref.dtype)
+            # as `_fa_fwd_kernel._finish` leaves it: natural log, (1, bq)
+            lse_ref[a] = (m_scr[a] * (1.0 / LOG2E) + jnp.log(l)).T
+
+
+def _fa_group_forward(q, k, v, sm_scale: float, slabs: _Slabs, heads: int,
+                      bq: int, bk: int, tile: Optional[int],
+                      interpret: bool):
+    """`_fa_forward_pallas` where `forward_route` says ("group", heads):
+    q (b, s, h*d), k and v (b, s, n_kv*d) -> (o (b, s, h*d), lse (b*h, 1,
+    s) float32), what the slab step gives and the backward reads; a
+    grid step (bq q rows x bk keys), a block the diagonal crosses cut
+    into `tile`s (None: `_CAUSAL_TILE`, or the block's shorter side
+    where a sweep's is shorter)."""
+    b, s, _ = q.shape
+    width = slabs.width
+    parts = slabs.kv_rep // heads
+    n = slabs.per_row // heads  # groups a batch row: kv heads x parts
+
+    def keys_of(i, j):  # the last key block at or below the q block's end
+        return jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+
+    rows = pl.BlockSpec((1, bq, heads * width),
+                        lambda u, i, j: (u // n, i, u % n))
+    keys = pl.BlockSpec(
+        (1, bk, width),
+        lambda u, i, j: (u // n, keys_of(i, j), u % n // parts))
+    return pl.pallas_call(
+        functools.partial(
+            _fa_grp_fwd_kernel, sm_scale=sm_scale, heads=heads, width=width,
+            tile=_causal_tile(bq, bk, tile or min(_CAUSAL_TILE, bq, bk))),
+        grid=(b * n, s // bq, s // bk),
+        in_specs=[rows, keys, keys],
+        out_specs=(rows, pl.BlockSpec((heads, 1, bq),
+                                      lambda u, i, j: (u, 0, i))),
+        out_shape=(_out_struct(q.shape, q.dtype, q),
+                   _out_struct((b * slabs.per_row, 1, s), jnp.float32, q)),
+        scratch_shapes=[pltpu.VMEM((heads, bq, 1), jnp.float32),
+                        pltpu.VMEM((heads, bq, 1), jnp.float32),
+                        pltpu.VMEM((heads, bq, width), jnp.float32)],
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary",
+                                         vmem_limit=_VMEM_LIMIT),
+        interpret=interpret,
+        name="dwt_fa_grp_fwd",
+    )(q, k, v)
 
 
 # ------------------------------------------------------------ backward kernels

@@ -1,7 +1,10 @@
 """Grouped heads on the direct route: `flash_attention_projected` on k
 and v of their own (b, s, n_kv*d) width — the kernels' BlockSpecs hand
 query slab s kv slab s // rep (`fa.kv_route`, `_Slabs.kv_rep`) — against
-the same call on `jnp.repeat`ed k and v.  Interpret mode, on the CPU.
+the same call on `jnp.repeat`ed k and v; and the causal forward whose
+grid step is a kv head's GROUP of query heads (`fa.forward_route`,
+`dwt_fa_grp_fwd`) against the slab step and the plain reference.
+Interpret mode, on the CPU.
 """
 
 import functools
@@ -226,6 +229,169 @@ def test_the_backward_puts_a_groups_heads_on_an_axis_of_their_own():
         by_head = got.reshape(b, rep, t, n_kv, d).transpose(
             0, 2, 3, 1, 4).reshape(b, t, n_kv * rep * d)
         np.testing.assert_array_equal(by_head, heads)
+
+
+# --------------------------------------- a group of heads a grid step
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """The cells' geometry at a sixteenth: tiles of 32, blocks of 64."""
+    monkeypatch.setattr(fa, "_CAUSAL_TILE", 32)
+    return 64
+
+
+def _both_steps(q, k, v, n_head, block, heads, blocks):
+    slabs, d = fa._projected_slabs((q, k, v), n_head)
+    args = (q, k, v, True, d ** -0.5, block, block, True)
+    return (fa._fa_forward_pallas(*args, slabs=slabs, route=("slab", 0)),
+            fa._fa_forward_pallas(*args, slabs=slabs,
+                                  route=("group", heads), blocks=blocks))
+
+
+def _plain_o(q, k, v, n_kv, rep):
+    """`_attention_reference` on the operands' values, in float32."""
+    b, t, _ = k.shape
+    d = k.shape[-1] // n_kv
+
+    def heads(x, n):
+        return x.astype(jnp.float32).reshape(b, t, n, d).transpose(
+            0, 2, 1, 3)
+
+    kk, vv = (jnp.repeat(heads(x, n_kv), rep, axis=1) for x in (k, v))
+    return fa._attention_reference(
+        heads(q, n_kv * rep), kk, vv, True, d ** -0.5).transpose(
+            0, 2, 1, 3).reshape(q.shape)
+
+
+# (kv heads, query heads a kv head, head size, heads a grid step):
+# Laguna's 48 over 8, SmallThinker's 28 over 4, Nemotron's 32 over 2 (a
+# group in four parts) and Qwen3-Next's 16 over 2 at heads of 256 (two
+# parts); then what the rule gives the other head size
+GROUP_STEPS = [(2, 6, 128, 6), (1, 7, 128, 7), (1, 16, 128, 4),
+               (2, 8, 256, 4), (1, 8, 128, 4), (1, 6, 256, 3),
+               (1, 16, 256, 4)]
+
+
+@pytest.mark.parametrize("key_blocks", [2, 3])
+@pytest.mark.parametrize("n_kv,rep,d,heads", GROUP_STEPS, ids=[
+    "rep6", "rep7", "rep16_in_parts", "rep8_d256_in_parts", "rep8_in_parts",
+    "rep6_d256_in_parts", "rep16_d256_in_parts"])
+def test_the_group_step_is_the_slab_step_bit_for_bit(small_tiles, n_kv, rep,
+                                                     d, heads, key_blocks):
+    """At the shipped geometry (the slab step's own blocks) a row sees
+    the same sequence of products, maxima and sums under either step: o
+    and lse are equal, bfloat16 operands as every cell's; and o is the
+    plain reference's to what bfloat16 probabilities cost."""
+    block, t = small_tiles, small_tiles * key_blocks
+    assert fa.forward_route(t, t, d, rep, block_k=block) == ("group", heads)
+    q, k, v, _ = _operands(1, t, n_kv, rep, d, jnp.bfloat16, seed=rep)
+    (want_o, want_lse), (o, lse) = _both_steps(
+        q, k, v, n_kv * rep, block, heads, None)
+    assert o.shape == q.shape and o.dtype == q.dtype
+    assert lse.shape == (n_kv * rep, 1, t) and lse.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(o, np.float32),
+                                  np.asarray(want_o, np.float32))
+    np.testing.assert_array_equal(lse, want_lse)
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               _plain_o(q, k, v, n_kv, rep), atol=2e-2)
+
+
+def test_a_group_no_step_takes_keeps_the_slab_step_and_still_runs(
+        small_tiles):
+    """Seven heads of 256 are 1,792 lanes and seven has no smaller part:
+    `forward_route` leaves the call on the slab step; `route=` (sweeps)
+    still reaches the group step there, all seven a step."""
+    block, t, n_kv, rep, d = small_tiles, 128, 1, 7, 256
+    assert fa._group_heads(rep, d) == 1
+    assert fa.forward_route(t, t, d, rep, block_k=block) == ("slab", 0)
+    q, k, v, _ = _operands(1, t, n_kv, rep, d, jnp.bfloat16, seed=5)
+    (want_o, want_lse), (o, lse) = _both_steps(q, k, v, rep, block, rep, None)
+    np.testing.assert_array_equal(np.asarray(o, np.float32),
+                                  np.asarray(want_o, np.float32))
+    np.testing.assert_array_equal(lse, want_lse)
+
+
+# every (q rows, keys) the chip sweep timed (tools/perf_probe.py's
+# `GROUP_BLOCKS`; the first is the rule's), at a sixteenth; `exact`: the
+# key blocks and the tiles are the slab step's, so the sums are too
+@pytest.mark.parametrize("blocks,exact", [
+    ((64, 64), True), ((32, 64), True), ((64, 32), False),
+    ((16, 64), False), ((32, 128), False), ((32, 32), False)],
+    ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else None)
+@pytest.mark.parametrize("n_kv,rep,heads,d", [(1, 6, 6, 128), (1, 16, 8, 128),
+                                              (1, 4, 2, 256)],
+                         ids=["rep6", "rep16_in_twos", "rep4_d256_in_twos"])
+def test_every_swept_geometry_is_the_slab_step(small_tiles, n_kv, rep, heads,
+                                               d, blocks, exact):
+    """`blocks=` (sweeps and tests) against the slab step and the plain
+    reference: another key block is another order of the same sums, to
+    float32's and bfloat16's rounding."""
+    block, t = small_tiles, 128
+    q, k, v, _ = _operands(1, t, n_kv, rep, d, jnp.bfloat16, seed=3)
+    (want_o, want_lse), (o, lse) = _both_steps(
+        q, k, v, n_kv * rep, block, heads, blocks)
+    o, want_o = (np.asarray(x, np.float32) for x in (o, want_o))
+    if exact:
+        np.testing.assert_array_equal(o, want_o)
+        np.testing.assert_array_equal(lse, want_lse)
+    np.testing.assert_allclose(o, want_o, rtol=2 ** -7, atol=2 ** -8)
+    np.testing.assert_allclose(lse, want_lse, atol=1e-5)
+    np.testing.assert_allclose(o, _plain_o(q, k, v, n_kv, rep), atol=2e-2)
+
+
+def test_the_entry_differentiates_through_the_group_step(direct, small_tiles,
+                                                         monkeypatch):
+    """`flash_attention_projected` at a grouped causal shape of several
+    key blocks runs `dwt_fa_grp_fwd` and hands the UNTOUCHED backward its
+    o and its (b*h, 1, s) lse: float32 operands against grouped
+    attention written out, value and gradients."""
+    direct(small_tiles)
+    b, t, n_kv, rep, d = 2, 192, 2, 3, 128
+    q, k, v, g = _operands(b, t, n_kv, rep, d, jnp.float32, seed=4)
+    names = []
+    monkeypatch.setattr(fa.pl, "pallas_call", functools.partial(
+        lambda call, *a, **kw: names.append(kw["name"]) or call(*a, **kw),
+        fa.pl.pallas_call))
+
+    def plain(q, k, v):
+        o = _plain_o(q, k, v, n_kv, rep)
+        return (o * g).sum(), o
+
+    want, want_o = jax.grad(plain, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    o, got = _call_and_grads((q, k, v), n_kv * rep, g, None)
+    assert sorted(set(names)) == ["dwt_fa_bwd_fused", "dwt_fa_grp_fwd"]
+    np.testing.assert_allclose(o, want_o, atol=2e-5)
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(a, w, atol=5e-4)
+
+
+@pytest.mark.parametrize("why,sq,sk,d,rep,causal,window,want", [
+    ("laguna_xs_2_33b_a3b.steady, a full layer", 16384, 16384, 128, 6,
+     True, None, ("group", 6)),
+    ("smallthinker_21b_a3b.steady, the global layer", 16384, 16384, 128, 7,
+     True, None, ("group", 7)),
+    ("nemotron3_nano_30b_a3b.steady", 8192, 8192, 128, 16, True, None,
+     ("group", 4)),
+    ("qwen3_next_80b_a3b.steady", 16384, 16384, 256, 8, True, None,
+     ("group", 4)),
+    ("two key blocks", 2048, 2048, 128, 4, True, None, ("group", 4)),
+    ("a windowed layer", 16384, 16384, 128, 7, True, 4096, ("slab", 0)),
+    ("a head of its own k and v", 4096, 4096, 128, 1, True, None,
+     ("slab", 0)),
+    ("one key block", 1024, 1024, 128, 7, True, None, ("slab", 0)),
+    ("sq != sk", 1024, 4096, 128, 7, True, None, ("slab", 0)),
+    ("not causal", 4096, 4096, 128, 7, False, None, ("slab", 0)),
+    ("three key blocks of 512", 1536, 1536, 128, 7, True, None,
+     ("group", 7)),
+    ("a group of a prime over the most a step takes", 4096, 4096, 128, 11,
+     True, None, ("slab", 0)),
+    ("seven heads of 256: 1,792 lanes and no smaller part", 4096, 4096,
+     256, 7, True, None, ("slab", 0)),
+])
+def test_forward_route_is_the_shape_of_the_call(why, sq, sk, d, rep, causal,
+                                                window, want):
+    assert fa.forward_route(sq, sk, d, rep, causal, window) == want
 
 
 # ------------------------------------------ who repeats and who does not

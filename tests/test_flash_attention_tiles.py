@@ -499,16 +499,29 @@ def test_a_call_without_a_window_traces_the_program_it_always_did(
 # windowed cell's two kinds of layer each.  Read when the k / v
 # BlockSpecs learned `s // rep`; the same calls on repeated k and v are
 # rep 1 and trace what they did (the hybrid's pin above is that form).
+# The causal ones were re-taken on purpose when their forward's grid
+# step became a kv head's group of query heads (PR 67:
+# `fa.forward_route`, `dwt_fa_grp_fwd`; before it SmallThinker's global
+# layer read b50a6c1a4870f6a0, 102eb781f1fc6cf5 and the hybrid
+# c0081390c134d497, e939ecb8af6d99fb), Laguna's full layer and
+# Qwen3-Next's heads of 256 pinned beside them; the windowed call keeps
+# the slab step and its digests.
 GROUPED = {
     "smallthinker_21b_a3b.steady global": (
         (2, 16384, 3584), (2, 16384, 512), 28, None,
-        ("b50a6c1a4870f6a0", "102eb781f1fc6cf5")),
+        ("21c8358582343f40", "5ec039d9f2f75019")),
     "smallthinker_21b_a3b.steady windowed": (
         (2, 16384, 3584), (2, 16384, 512), 28, 4096,
         ("78cdb769cdb5f931", "5065cc0c791113b8")),
     "nemotron3_nano_30b_a3b.steady": (
         (2, 8192, 4096), (2, 8192, 256), 32, None,
-        ("c0081390c134d497", "e939ecb8af6d99fb")),
+        ("c1901c642daeae0d", "492c48e183fd6bc8")),
+    "laguna_xs_2_33b_a3b.steady full": (
+        (1, 16384, 6144), (1, 16384, 1024), 48, None,
+        ("23ff548cd557af3a", "2029d15dfcc7bcb3")),
+    "qwen3_next_80b_a3b.steady": (
+        (1, 16384, 4096), (1, 16384, 512), 16, None,
+        ("1b5b1994eda18f2c", "b9d9732e6fcbc0c1")),
 }
 
 
@@ -527,5 +540,47 @@ def test_a_grouped_call_traces_the_program_pinned_for_it(on_tpu, cell):
 
     assert (_digest(jax.make_jaxpr(call)(*args)),
             _digest(jax.make_jaxpr(grads)(*args))) == want
-    assert fa.kv_route(heads, kv[-1] // 128, 128) == (
-        "indexed", q[-1] // kv[-1])
+    d = q[-1] // heads
+    assert fa.kv_route(heads, kv[-1] // d, d) == ("indexed", q[-1] // kv[-1])
+
+
+# ------------------- the calls the group step (PR 67) leaves where they were
+
+# sha256, as above, of the gradient's trace at five more cells' attention
+# shapes, read on PR 67's PARENT (6e6b2e1) and on its tree, equal: the
+# transposed route at two widths, a head of its own k and v, a windowed
+# grouped call, and two heads a slab on repeated k and v all keep
+# `_fa_fwd_kernel` and trace the program they did.
+SLAB_STEP = {
+    "kimi_vl_a3b.steady": (
+        "transposed", ((2, 16, 16384, 192),) * 2 + ((2, 16, 16384, 128),),
+        0, None, "ace93aac02f23a48"),
+    "xing4_0_29b_a4b.steady": (
+        "transposed", ((1, 32, 8192, 192),) * 2 + ((1, 32, 8192, 128),),
+        0, None, "3765a8e75f6a77ff"),
+    "olmo_hybrid_7b.steady": (
+        "direct", ((1, 8192, 30 * 128),) * 3, 30, None, "d82aafea696592a5"),
+    "laguna_xs_2_33b_a3b.steady sliding": (
+        "direct", ((1, 16384, 64 * 128),) + ((1, 16384, 8 * 128),) * 2, 64,
+        512, "6fd858ccfa985876"),
+    "lfm2_24b_a2b.steady": (
+        "direct", ((1, 16384, 32 * 64),) * 3, 32, None, "990d7f9a32fc0ac4"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SLAB_STEP))
+def test_a_call_the_group_step_does_not_take_traces_the_parents_program(
+        on_tpu, cell):
+    layout, shapes, heads, window, want = SLAB_STEP[cell]
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes]
+
+    def call(*p):
+        if layout == "transposed":
+            return fa.flash_attention(*p, True, None)
+        return fa.flash_attention_projected(tuple(p), heads, True, None,
+                                            window)
+
+    grads = jax.grad(lambda *p: call(*p).astype(jnp.float32).sum(),
+                     argnums=(0, 1, 2))
+    assert _digest(jax.make_jaxpr(grads)(*args)) == want
+    assert "dwt_fa_grp_fwd" not in str(jax.make_jaxpr(grads)(*args))

@@ -82,8 +82,12 @@ def test_laguna_step_runs_two_kinds_of_attention_kernel_at_two_head_counts(
     text = step.as_text()
     calls = collections.Counter(re.findall(
         r"%(dwt_fa_\w+?)(?:\.\d+)? = ", text))
-    assert calls == {"dwt_fa_fwd": 4, "dwt_fa_bwd_fused": 2,
+    assert calls == {"dwt_fa_grp_fwd": 4, "dwt_fa_bwd_fused": 2,
                      "dwt_fa_win_fwd": 6, "dwt_fa_win_bwd_fused": 3}
+    # a full layer's forward takes a kv head's six query heads a grid
+    # step (PR 67); a windowed layer's keeps the slab step
+    assert fa.forward_route(16384, 16384, 128, 6) == ("group", 6)
+    assert fa.forward_route(16384, 16384, 128, 8, window=512) == ("slab", 0)
     for heads, rep in ((48, 6), (64, 8)):
         assert fa.attention_route(heads, 128) == ("direct", 1)
         assert fa.kv_route(heads, 8, 128) == ("indexed", rep)

@@ -123,6 +123,50 @@ def test_grouped_heads_are_indexed_where_a_head_is_a_slab(cell, heads, kv,
     assert fa.kv_route(heads, kv, d) == want
 
 
+@pytest.mark.parametrize("cell,heads,kv,d,dv,t,window,want", [
+    # grouped heads indexed on the direct route, causal over several key
+    # blocks: a kv head's group of query heads a grid step, or a part
+    ("laguna_xs_2_33b_a3b.steady full", 48, 8, 128, 128, 16384, None,
+     ("group", 6)),
+    ("smallthinker_21b_a3b.steady global", 28, 4, 128, 128, 16384, None,
+     ("group", 7)),
+    ("nemotron3_nano_30b_a3b.steady", 32, 2, 128, 128, 8192, None,
+     ("group", 4)),
+    ("qwen3_next_80b_a3b.steady", 16, 2, 256, 256, 16384, None,
+     ("group", 4)),
+    # every windowed call
+    ("laguna_xs_2_33b_a3b.steady sliding", 64, 8, 128, 128, 16384, 512,
+     ("slab", 0)),
+    ("smallthinker_21b_a3b.steady windowed", 28, 4, 128, 128, 16384, 4096,
+     ("slab", 0)),
+    # a head of its own k and v
+    ("olmoe_1b_7b.steady", 16, 16, 128, 128, 4096, None, ("slab", 0)),
+    ("olmo_hybrid_7b.steady", 30, 30, 128, 128, 8192, None, ("slab", 0)),
+    # two heads a slab: k and v repeated, `kv_rep` 1 at the kernel
+    ("gpt2_124m.steady", 12, 12, 64, 64, 1024, None, ("slab", 0)),
+    ("granite4_h_micro.steady", 32, 8, 64, 64, 8192, None, ("slab", 0)),
+    ("lfm2_24b_a2b.steady", 32, 8, 64, 64, 16384, None, ("slab", 0)),
+    # the transposed route
+    ("gpt2_xl.fsdp4_steady", 25, 25, 64, 64, 1024, None, ("slab", 0)),
+    ("kimi_vl_a3b.steady", 16, 16, 192, 128, 16384, None, ("slab", 0)),
+    ("xing4_0_29b_a4b.steady", 32, 32, 192, 128, 8192, None, ("slab", 0)),
+    # one key block
+    ("grouped at T = 1,024", 28, 4, 128, 128, 1024, None, ("slab", 0)),
+])
+def test_the_forward_step_is_the_shape_of_the_call(cell, heads, kv, d, dv, t,
+                                                   window, want):
+    """`forward_route` at the cells' shapes, handed what
+    `_fa_forward_pallas` hands it: `_Slabs.kv_rep` on the direct route
+    where k and v are indexed, 1 everywhere else."""
+    layout, slab_heads = fa.attention_route(heads, d, dv)
+    how, rep = fa.kv_route(heads, kv, d)
+    at_kernel = rep if layout == "direct" and how == "indexed" else 1
+    window = fa._effective_window(window, True, t)
+    assert fa.forward_route(t, t, d, at_kernel, True, window) == want
+    assert fa.forward_route(t // 2, t, d, at_kernel, True, window) == (
+        "slab", 0)  # sq != sk
+
+
 @pytest.mark.parametrize("on_tpu,h,d,t,why", [
     (False, 12, 64, 1024, "off the TPU"),
     (True, 12, 64, 4099, "a sequence of 4099"),   # no block tiles it
@@ -143,11 +187,12 @@ def test_the_direct_entry_refuses_what_projected_ok_does(monkeypatch,
     assert fa.projected_ok(12, 64, 1024) and fa.projected_ok(16, 128, 4096)
 
 
-def _model_attention_jaxpr(b, h, t, d, form):
+def _model_attention_jaxpr(b, h, t, d, form, kv=None):
     """The attention a model of this shape traces on one device: through
     `models/attention.attend_projected`, from the projections' own
     layout — `form` "qkv" as models/gpt.py hands it, "q,k,v" as
-    models/llama.py does."""
+    models/llama.py does, k and v at `kv` heads' width where
+    `kv_route` says they are indexed."""
     from dlrover_wuqiong_tpu.models.attention import attend_projected
     from dlrover_wuqiong_tpu.models.gpt import GPTConfig
 
@@ -155,6 +200,8 @@ def _model_attention_jaxpr(b, h, t, d, form):
     n = 3 if form == "qkv" else 1
     x = jax.ShapeDtypeStruct((b, t, n * h * d), jnp.bfloat16)
     proj = (x,) * (4 - n)
+    if kv:
+        proj = (x,) + (jax.ShapeDtypeStruct((b, t, kv * d), jnp.bfloat16),) * 2
     return jax.make_jaxpr(jax.grad(
         lambda proj: attend_projected(proj, h, cfg).astype(
             jnp.float32).sum()))(proj)
@@ -176,32 +223,37 @@ def _primitives(jaxpr) -> set:
 # transposed array (124M: 24 x 6 slabs of two heads, was 288 / 8 groups;
 # OLMoE: 5 x 16 slabs of one head, was 80 / 8), because a slab is what a
 # BlockSpec can address in the projections' layout without a lane slice
-@pytest.mark.parametrize("b,h,t,d,form,route,groups,grid,tiles", [
-    (24, 12, 1024, 64, "qkv", "direct", 24 * 6, (1, 1),
-     (3, 4)),                                          # gpt2_124m.steady
-    (4, 25, 1024, 64, "qkv", "transposed", 100 // 4, (1, 1),
-     (3, 4)),                                          # gpt2_xl, per chip
-    (5, 16, 4096, 128, "q,k,v", "direct", 5 * 16, (4, 4),
-     (36, 64)),                                        # olmoe_1b_7b.steady
-    (2, 32, 8192, 128, "q,k,v", "direct", 2 * 32, (8, 8),
-     (136, 256)),                           # nemotron3_nano_30b_a3b.steady
-    (1, 32, 8192, 64, "q,k,v", "direct", 16, (8, 8),
-     (136, 256)),                                 # granite4_h_micro.steady
+# Nemotron's row hands k and v at their 2 kv heads' own width since PR
+# 67, as its model does: the forward is the GROUP step, 4 of a kv head's
+# 16 query heads a grid step (2 rows x 2 kv heads x 4 parts), the
+# backward the slab sweep it was
+@pytest.mark.parametrize("b,h,kv,t,d,form,route,groups,grid,tiles,forward", [
+    (24, 12, None, 1024, 64, "qkv", "direct", 24 * 6, (1, 1),
+     (3, 4), None),                                    # gpt2_124m.steady
+    (4, 25, None, 1024, 64, "qkv", "transposed", 100 // 4, (1, 1),
+     (3, 4), None),                                    # gpt2_xl, per chip
+    (5, 16, None, 4096, 128, "q,k,v", "direct", 5 * 16, (4, 4),
+     (36, 64), None),                                  # olmoe_1b_7b.steady
+    (2, 32, 2, 8192, 128, "q,k,v", "direct", 2 * 32, (8, 8),
+     (136, 256), ("dwt_fa_grp_fwd", (2 * 2 * 4, 8, 8))),
+    #                                         nemotron3_nano_30b_a3b.steady
+    (1, 32, None, 8192, 64, "q,k,v", "direct", 16, (8, 8),
+     (136, 256), None),   # granite4_h_micro.steady: k and v repeated to 32
 ])
-def test_the_cells_attention_plans(on_tpu, b, h, t, d, form, route, groups,
-                                   grid, tiles):
+def test_the_cells_attention_plans(on_tpu, b, h, kv, t, d, form, route,
+                                   groups, grid, tiles, forward):
     """PERF.md section 5's prose, pinned: what each benchmark cell's
     attention traces to on the chip, from its shape alone — the route,
     the kernels and their grids, and whether anything is split, cut to
     heads or transposed around them."""
-    jaxpr = _model_attention_jaxpr(b, h, t, d, form).jaxpr
+    jaxpr = _model_attention_jaxpr(b, h, t, d, form, kv).jaxpr
     assert fa.attention_route(h, d)[0] == route
     # the backward is ONE kernel in every cell: on heads alone where the
     # sequence is one block, else the dk/dv sweep with dq resident
     assert fa.backward_route(t, t, d, d, fa.attention_route(h, d)[1],
                              b * h)[0] == "fused"
     assert sorted(_pallas_calls(jaxpr)) == sorted([
-        ("dwt_fa_fwd", (groups,) + grid),
+        forward or ("dwt_fa_fwd", (groups,) + grid),
         ("dwt_fa_bwd_fused", (groups,) + (grid if t > 1024 else ()))])
     assert fa.causal_tile_count(t, t) == tiles
     relaid = _primitives(jaxpr) & {"transpose", "split", "reshape",
@@ -257,8 +309,9 @@ def test_the_latent_cells_attention_plan(on_tpu):
 
 
 @pytest.mark.parametrize("window,names,sweep,tiles", [
-    # the GLOBAL layer: the causal kernels over all 16 x 16 blocks
-    (0, ("dwt_fa_fwd", "dwt_fa_bwd_fused"), 16, (528, 1024)),
+    # the GLOBAL layer: the causal kernels over all 16 x 16 blocks, the
+    # forward a kv head's seven query heads a grid step (PR 67)
+    (0, ("dwt_fa_grp_fwd", "dwt_fa_bwd_fused"), 16, (528, 1024)),
     # a WINDOWED layer: kernels of another name on a grid narrowed to the
     # five key blocks a query block sees (backward: the five query
     # blocks a key block is seen by): a block below the window is no
@@ -266,7 +319,7 @@ def test_the_latent_cells_attention_plan(on_tpu):
     # resident
     (4096, ("dwt_fa_win_fwd", "dwt_fa_win_bwd_fused"), 5, (252, 1024)),
     # a window no shorter than the sequence is the causal call itself
-    (16384, ("dwt_fa_fwd", "dwt_fa_bwd_fused"), 16, (528, 1024)),
+    (16384, ("dwt_fa_grp_fwd", "dwt_fa_bwd_fused"), 16, (528, 1024)),
 ])
 def test_the_windowed_cells_attention_plans(on_tpu, window, names, sweep,
                                             tiles):
@@ -290,13 +343,17 @@ def test_the_windowed_cells_attention_plans(on_tpu, window, names, sweep,
     grads = jax.grad(lambda proj: attend_projected(proj, 28, cfg).astype(
         jnp.float32).sum())
     jaxpr = jax.make_jaxpr(grads)((x, kv, kv)).jaxpr
+    # a slab a grid step, but for the group forward: 2 rows x 4 kv heads
     assert sorted(_pallas_calls(jaxpr)) == sorted(
-        (name, (2 * 28, 16, sweep)) for name in names)
+        (name, (2 * 4 if "grp" in name else 2 * 28, 16, sweep))
+        for name in names)
     assert [g.shape for g in jax.eval_shape(grads, (x, kv, kv))] == [
         x.shape, kv.shape, kv.shape]
-    # the repeated form (another caller's) runs the same kernels
+    # the repeated form (another caller's) has no group to step over:
+    # the slab kernels, as before
     assert sorted(_pallas_calls(jax.make_jaxpr(grads)((x,) * 3).jaxpr)) == \
-        sorted((name, (2 * 28, 16, sweep)) for name in names)
+        sorted((name.replace("grp_", ""), (2 * 28, 16, sweep))
+               for name in names)
     assert fa.causal_tile_count(16384, 16384, window=window or None) == tiles
     assert window_tiles(cfg, 2, 28, 16384) == (
         None if not window else (56 * tiles[0], 56 * 528))

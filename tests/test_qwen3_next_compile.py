@@ -49,12 +49,14 @@ def qwen_step(request):
 def test_heads_of_256_compile_on_the_direct_kernels_at_the_cells_shape(
         topo, on_tpu, _no_persistent_cache):
     """q (1, 16384, 16 x 256) over k and v (1, 16384, 2 x 256) in
-    bfloat16 on one TPU device: `dwt_fa_fwd` and ONE fused backward
-    kernel on the projections' own layout, nothing repeated to sixteen
-    heads, nothing laid out by head."""
+    bfloat16 on one TPU device: `dwt_fa_grp_fwd` — four of a kv head's
+    eight query heads a grid step, 1,024 lanes (`fa.forward_route`, PR
+    67) — and ONE fused backward kernel on the projections' own layout,
+    nothing repeated to sixteen heads, nothing laid out by head."""
     one = SingleDeviceSharding(topo.devices[0])
     assert fa.projected_ok(H, D, T)
     assert fa.kv_route(H, KV, D) == ("indexed", 8)
+    assert fa.forward_route(T, T, D, 8) == ("group", 4)
 
     def shape(heads):
         return jax.ShapeDtypeStruct((B, T, heads * D), jnp.bfloat16,
@@ -69,7 +71,7 @@ def test_heads_of_256_compile_on_the_direct_kernels_at_the_cells_shape(
     text = compiled.as_text()
     calls = collections.Counter(re.findall(
         r"%(dwt_\w*?)(?:\.\d+)? = ", text))
-    assert calls == {"dwt_fa_fwd": 1, "dwt_fa_bwd_fused": 1}
+    assert calls == {"dwt_fa_grp_fwd": 1, "dwt_fa_bwd_fused": 1}
     assert "bf16[1,16384,4096]" in text  # q as its projection left it
     assert "bf16[16,16384,256]" not in text
     assert " while(" not in text and " conditional(" not in text
@@ -132,7 +134,7 @@ def test_qwen_step_holds_its_scopes_kernels_and_a_share_of_experts(
     assert calls == {
         "dwt_gdr_fwd": 2 * 3, "dwt_gdr_bwd": 3,
         "dwt_conv_fwd": 2 * 3 * 3, "dwt_conv_bwd": 3 * 3,
-        "dwt_fa_fwd": 2, "dwt_fa_bwd_fused": 1}
+        "dwt_fa_grp_fwd": 2, "dwt_fa_bwd_fused": 1}
     grouped = _grouped_kernel_calls(text)
     assert grouped and "ragged-dot" not in text
     assert all("feed_forward/moe/experts/dwt_" in scope

@@ -138,19 +138,23 @@ class TestRunReportContract:
         assert rc == 0 and out == [] and DOC in err
 
 
+def _perf_probe_tool():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "perf_probe_tool", os.path.join(REPO, "tools", "perf_probe.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 class TestMigratedProbeTool:
     def test_perf_probe_streams_lines_then_summary(self, capsys,
                                                    monkeypatch):
         """tools/perf_probe.py after the run_report migration: the
         historical per-probe JSON lines still stream, and the FINAL line
         is the contract summary folding every emitted record."""
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "perf_probe_tool", os.path.join(REPO, "tools",
-                                            "perf_probe.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
+        mod = _perf_probe_tool()
         monkeypatch.setitem(
             mod.ALL, "fake",
             lambda: mod._emit("fake", 0.001, note="x"))
@@ -163,3 +167,28 @@ class TestMigratedProbeTool:
         summary = json.loads(out[1])
         assert summary["emitted"] == 1
         assert summary["probes"] == [json.loads(out[0])]
+
+    @pytest.mark.parametrize("argv,want", [
+        (["attn_direct", "--shape", "1,48,8,16384,128", "--blocks",
+          "1024x1024,512x1024", "--heads", "6,3"],
+         {"shape": (1, 48, 8, 16384, 128),
+          "blocks": ((1024, 1024), (512, 1024)), "heads": (6, 3)}),
+        (["attn_direct", "--shape", "2,32,2,8192,128"],
+         {"shape": (2, 32, 2, 8192, 128), "blocks": None, "heads": None}),
+        (["attn_direct"], {"shape": None, "blocks": None, "heads": None}),
+    ])
+    def test_attn_direct_takes_a_grouped_shape_and_blocks(self, capsys,
+                                                          monkeypatch, argv,
+                                                          want):
+        """`attn_direct --shape b,heads,kv,T,d --blocks .. --heads ..`:
+        the group forward's sweep at one shape (PR 67); the flags' values
+        are no probe names, and no other probe is handed them."""
+        mod = _perf_probe_tool()
+        seen = []
+        monkeypatch.setitem(mod.ALL, "attn_direct",
+                            lambda **kw: seen.append(kw))
+        monkeypatch.setitem(mod.ALL, "fake", lambda: seen.append("fake"))
+        rc = mod.main(argv + ["fake"])
+        assert rc == 0 and seen == [want, "fake"]
+        assert json.loads(capsys.readouterr().out.splitlines()[-1]) == {
+            "probes": [], "emitted": 0}

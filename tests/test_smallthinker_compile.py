@@ -95,8 +95,12 @@ def test_smallthinker_step_runs_two_kinds_of_attention_kernel(
     text = step.as_text()
     calls = collections.Counter(re.findall(
         r"%(dwt_fa_\w+?)(?:\.\d+)? = ", text))
-    assert calls == {"dwt_fa_fwd": 2, "dwt_fa_bwd_fused": 1,
+    assert calls == {"dwt_fa_grp_fwd": 2, "dwt_fa_bwd_fused": 1,
                      "dwt_fa_win_fwd": 6, "dwt_fa_win_bwd_fused": 3}
+    # the global layer's forward takes a kv head's seven query heads a
+    # grid step (PR 67); the windowed layers' keep the slab step
+    assert fa.forward_route(16384, 16384, 128, 7) == ("group", 7)
+    assert fa.forward_route(16384, 16384, 128, 7, window=4096) == ("slab", 0)
     b = cell["global_batch"]
     assert fa.backward_route(16384, 16384, 128, 128, 1, b * 28) == (
         "fused", 1)

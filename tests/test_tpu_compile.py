@@ -508,8 +508,12 @@ def test_nemotron_step_fits_one_chip_and_fills_it(nemotron_step):
 def test_nemotron_step_runs_the_kernels_at_d128_t8192(nemotron_step):
     cell, _, step = nemotron_step
     text = step.as_text()
-    for kernel in ("dwt_fa_fwd", "dwt_fa_bwd_fused"):
+    # the forward a kv head's sixteen query heads in four grid steps of
+    # four (`fa.forward_route`, PR 67), the backward the slab sweep
+    assert fa.forward_route(8192, 8192, 128, 16) == ("group", 4)
+    for kernel in ("dwt_fa_grp_fwd", "dwt_fa_bwd_fused"):
         assert kernel in text, kernel
+    assert "dwt_fa_fwd" not in text
     assert "dwt_fa_bwd_dq" not in text and "dwt_fa_bwd_dkv" not in text
     # 32 heads of 128 on a hidden size of 2688: a head is a lane slab, the
     # kernels index the projections' own (batch, 8192, 32 x 128) and, for
@@ -1214,8 +1218,11 @@ def test_hybrid_step_scans_in_its_kernels_and_holds_no_decay_tensor(
     assert _square_tiles(plain, "ssd", cfg.chunk_size)
 
     attention = [n for n in table if n.startswith("dwt_fa_")]
-    assert sorted(n.split(".")[0] for n in attention) == [
-        "dwt_fa_bwd_fused", "dwt_fa_fwd", "dwt_fa_fwd"]
+    # granite repeats k and v (d = 64): the slab step; the other hybrid
+    # indexes them: a group of query heads a grid step
+    forward = "dwt_fa_fwd" if fixture == "granite_step" else "dwt_fa_grp_fwd"
+    assert sorted(n.split(".")[0] for n in attention) == sorted(
+        ["dwt_fa_bwd_fused", forward, forward])
     # (what the other hybrid's step holds of loops is its expert
     # layers': `_held_row_loops`)
     assert " conditional(" not in text and not [

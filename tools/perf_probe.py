@@ -19,6 +19,14 @@ per-frame-fsync and a group-commit master, rounds interleaved.
 then `attn_bwd`: the backward alone at the six several-block cells'
 shapes, fused at every pack that fits against the dq + dk/dv pair, by
 device time.
+`attn_direct` is GPT-2's layer on the two routes of `attention_route`
+by the host's clock, then the causal forward ALONE under grouped heads
+at the four grouped cells' shapes by device time: the slab step
+(`dwt_fa_fwd`) against the group step (`dwt_fa_grp_fwd`) at six (q rows
+x keys), with each one's distance from the slab step's o and lse;
+`--shape b,heads,kv_heads,T,d [--blocks 1024x1024,512x1024] [--heads
+6,3]` runs that sweep alone at one shape (PERF.md section 6, PR 67, has
+the table).
 `gmm` reads, from a profiler trace, the device time of each grouped
 product of a chip's share of an expert layer (98,304 rows of which
 6,800 are held in 8 groups) as `ops/grouped_matmul.py`'s kernels and as
@@ -248,12 +256,18 @@ def probe_attn_cells():
     probe_attn_bwd()
 
 
-def probe_attn_direct():
+def probe_attn_direct(shape=None, blocks=None, heads=None):
     """The same heads on the two routes of `attention_route`, a layer's
     attention as a model runs it, forward + backward: c_attn's
     (B, T, 3*H*D) output through `flash_attention_projected` (the
     kernels index it, two heads a lane slab), and through the split,
-    the cut to heads and `mha`'s transposes (the transposed route)."""
+    the cut to heads and `mha`'s transposes (the transposed route).
+    Then `probe_attn_grouped` at the four grouped cells' shapes — or,
+    with `--shape b,heads,kv,T,d` (and `--blocks 512x1024,...`,
+    `--heads 8,16`), that sweep alone at that shape."""
+    if shape or blocks or heads:
+        return probe_attn_grouped({"shape": shape} if shape else None,
+                                  blocks, heads)
     from dlrover_wuqiong_tpu.ops.flash_attention import (
         flash_attention_projected,
         mha,
@@ -279,6 +293,70 @@ def probe_attn_direct():
             return qkv
 
         _emit(tag, _time(fwdbwd, qkv, iters=5) / INNER, heads=[H, D])
+    probe_attn_grouped()
+
+
+# (b, heads, kv heads, T, d) of the causal calls that take the group
+# step (`fa.forward_route`): Laguna's two full layers, SmallThinker's
+# global layer, Nemotron's attention layers, Qwen3-Next's
+GROUPED_CELLS = {
+    "laguna_xs_2_33b_a3b": (1, 48, 8, 16384, 128),
+    "smallthinker_21b_a3b": (2, 28, 4, 16384, 128),
+    "nemotron3_nano_30b_a3b": (2, 32, 2, 8192, 128),
+    "qwen3_next_80b_a3b": (1, 16, 2, 16384, 256),
+}
+GROUP_BLOCKS = ((1024, 1024), (512, 1024), (1024, 512), (256, 1024),
+                (512, 2048), (512, 512))
+
+
+def probe_attn_grouped(shapes=None, blocks=None, heads=None):
+    """The causal forward ALONE under grouped heads on the direct route,
+    by device time from a profiler trace: the slab step (`dwt_fa_fwd`,
+    one query head a grid step) against the group step
+    (`dwt_fa_grp_fwd`) at every (q rows, keys) of `blocks` (default
+    `GROUP_BLOCKS`, the rule's first) and every heads-a-step of `heads`
+    (default: the rule's), with each one's
+    distance from the slab step's o and lse.  `shapes`: {name: (b,
+    heads, kv heads, T, d)}, default the four grouped cells'."""
+    from dlrover_wuqiong_tpu.ops import flash_attention as fa
+
+    for cell, (b, h, kv, t, d) in (shapes or GROUPED_CELLS).items():
+        ks = jax.random.split(jax.random.PRNGKey(t + h), 3)
+        q, k, v = (jax.random.normal(key, (b, t, n * d), jnp.bfloat16)
+                   for key, n in zip(ks, (h, kv, kv)))
+        slabs = fa._projected_slabs((q, k, v), h)[0]
+        plan = dict(causal=True, sm_scale=d ** -0.5, block_q=1024,
+                    block_k=1024, interpret=False, slabs=slabs)
+        rep = h // kv
+        rule = fa.forward_route(t, t, d, rep)
+        tiles = b * h * fa.causal_tile_count(t, t)[0]
+        routes = [(("slab", 0), None)] + [
+            (("group", g), bb) for bb in blocks or GROUP_BLOCKS
+            for g in (heads or [fa._group_heads(rep, d)])
+            if t % bb[0] == 0 and t % bb[1] == 0 and rep % g == 0]
+        want = None
+        for route, bb in routes:
+            fn = jax.jit(functools.partial(fa._fa_forward_pallas, route=route,
+                                           blocks=bb, **plan))
+            line = {"probe": "attn_grouped", "cell": cell,
+                    "shape": [b, h, kv, t, d], "route": list(route),
+                    "blocks": bb, "the_rule": route == rule and (
+                        bb is None or tuple(bb) == GROUP_BLOCKS[0])}
+            try:
+                ops = _device_ops_ms(fn, q, k, v, top=3)
+                got = fn(q, k, v)
+            except Exception as e:  # noqa: BLE001 — a step Mosaic refuses
+                _emit_raw(dict(line, error=repr(e)[:300]))
+                continue
+            want = want or got
+            ms = sum(x for n, x in ops.items() if n.startswith("dwt_fa_"))
+            _emit_raw(dict(
+                line, kernel_ms=round(ms, 4),
+                us_a_head_tile=round(ms * 1e3 / tiles, 4),
+                o_max_diff=float(jnp.abs(got[0].astype(jnp.float32)
+                                         - want[0].astype(jnp.float32)).max()),
+                lse_max_diff=float(jnp.abs(got[1] - want[1]).max()),
+                device_ops_ms=ops))
 
 
 def probe_attn_sweep():
@@ -1277,16 +1355,28 @@ def main(argv=None) -> int:
     argv = list(argv) if argv is not None else sys.argv[1:]
     from dlrover_wuqiong_tpu.common.report_cli import run_report
 
+    def _ints(text, sep=","):
+        return tuple(int(x) for x in text.split(sep))
+
+    flags = {  # `attn_direct`'s sweep
+        "--shape": _ints, "--heads": _ints,
+        "--blocks": lambda text: tuple(_ints(b, "x")
+                                       for b in text.split(","))}
+
     def _offline(vals):
-        names = [a for a in argv if not a.startswith("-")] \
+        given = {vals[f] for f in flags if f in vals}
+        names = [a for a in argv if not a.startswith("-")
+                 and a not in given] \
             or ["step", "attn", "head", "model", "opt"]
+        sweep = {f[2:]: parse(vals[f]) if f in vals else None
+                 for f, parse in flags.items()}
         unknown = [n for n in names if n not in ALL]
         if unknown:
             raise ValueError(
                 f"unknown probe(s) {unknown}; have {sorted(ALL)}")
         del _EMITTED[:]
         for n in names:
-            ALL[n]()
+            ALL[n](**(sweep if n == "attn_direct" else {}))
         return {"probes": list(_EMITTED), "emitted": len(_EMITTED)}
 
     def _no_live(addr, vals):
@@ -1298,7 +1388,8 @@ def main(argv=None) -> int:
         offline=_offline,
         live=_no_live,
         no_addr_error="perf_probe runs on-device probes, not a master "
-                      "RPC")
+                      "RPC",
+        value_flags=tuple(flags))
 
 
 if __name__ == "__main__":
