@@ -27,6 +27,7 @@ from ..ops.flash_attention import (
     mha,
     projected_ok,
 )
+from .sown import counters, sown, term
 
 
 def goes_direct(cfg, n_head: int, head_dim: int, seq: int) -> bool:
@@ -89,6 +90,7 @@ def window_pairs(cfg, batch: int, n_head: int, seq: int):
             batch * n_head * done * seq * seq // square)
 
 
+@counters
 def collect_attention_stats(intermediates) -> dict:
     """What the attention layers of one forward pass counted, summed over
     the layers — {} for a model that sows none of it: `attn_tiles_window`
@@ -110,18 +112,16 @@ def collect_attention_stats(intermediates) -> dict:
     beside the causal ones; `attn_sparse_tiles_run`, the tiles the
     implementation computes; and `index_kl`, the layers' mean KL term
     (`collect_attention_aux_loss` is what joins the loss)."""
-    from .moe import _sown
-
     stats = {}
-    for sown, names in (
+    for under, names in (
             ("attn_tiles", ("attn_tiles_window", "attn_tiles_causal")),
             ("attn_pairs", ("attn_pairs_kept", "attn_pairs_computed")),
             ("attn_lanes", ("attn_lanes_run", "attn_lanes_model"))):
-        pairs = [v.reshape(-1, 2) for v in _sown(intermediates, sown)]
+        pairs = [v.reshape(-1, 2) for v in sown(intermediates, under)]
         if pairs:
-            with jax.named_scope(sown):  # the sum's copies get an owner
+            with jax.named_scope(under):  # the sum's copies get an owner
                 stats.update(zip(names, jnp.concatenate(pairs).sum(0)))
-    sparse = [v.reshape(-1, 5) for v in _sown(intermediates, "attn_sparse")]
+    sparse = [v.reshape(-1, 5) for v in sown(intermediates, "attn_sparse")]
     if sparse:
         with jax.named_scope("attn_sparse"):
             stats.update(zip(
@@ -129,12 +129,12 @@ def collect_attention_stats(intermediates) -> dict:
                  "attn_sparse_live_tiles", "attn_sparse_tiles_causal",
                  "attn_sparse_tiles_run"), jnp.concatenate(sparse).sum(0)))
             stats["index_kl"] = jnp.stack([
-                v.reshape(()) for v in _sown(intermediates,
-                                             "attn_index_kl")]).mean()
-    gates = [v.reshape(()) for v in _sown(intermediates, "attn_gate_mean")]
+                v.reshape(()) for v in sown(intermediates,
+                                            "attn_index_kl")]).mean()
+    gates = [v.reshape(()) for v in sown(intermediates, "attn_gate_mean")]
     # `LlamaAttention`'s gates say which route they took; latent
     # attention's, lines of its own on another layout, have no other
-    took = [v.reshape(()) for v in _sown(intermediates, "attn_gate_kernel")]
+    took = [v.reshape(()) for v in sown(intermediates, "attn_gate_kernel")]
     if gates:
         with jax.named_scope("attn_gate_mean"):
             if len(took) == len(gates):
@@ -149,15 +149,17 @@ def collect_attention_stats(intermediates) -> dict:
     return stats
 
 
-def collect_attention_aux_loss(intermediates):
-    """The sum of the sown `attn_index_loss` leaves — the sparse
-    layers' weighted KL terms, which reach the indexers' leaves alone —
-    and nothing else an attention layer sows (0.0 without one)."""
-    from .moe import _sown
-
-    return sum((jnp.sum(v) for v in _sown(intermediates,
-                                          "attn_index_loss")),
-               jnp.zeros((), jnp.float32))
+@term
+def collect_attention_aux_loss(intermediates, batch, ce):
+    """The loss's term of the sparse layers, or None for a model without
+    one: the sum of the sown `attn_index_loss` leaves — the layers'
+    weighted KL terms, which reach the indexers' leaves alone — and
+    nothing else an attention layer sows; beside it `ce`, the bare
+    cross-entropy: the two terms, seen apart."""
+    terms = [jnp.sum(v) for v in sown(intermediates, "attn_index_loss")]
+    if not terms:
+        return None
+    return sum(terms, jnp.zeros((), jnp.float32)), {"ce": ce}
 
 
 def attend_projected(proj, n_head: int, cfg, causal: bool = True):

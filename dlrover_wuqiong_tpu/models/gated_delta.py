@@ -74,6 +74,7 @@ import jax.numpy as jnp
 
 from ..ops.delta_rule import gated_delta_rule, product_lanes
 from .mamba2 import _conv_init, _dt_bias_init, causal_conv_silu
+from .sown import counters, sown
 
 _NORM_EPS = 1e-6  # inside the root of both L2 norms
 
@@ -208,6 +209,7 @@ class GatedDeltaMixer(nn.Module):
         return dense(cfg.hidden_size, "o_proj")(y)
 
 
+@counters
 def collect_delta_stats(intermediates) -> dict:
     """What the gated delta-rule mixers of one forward pass counted — {}
     for a model without one: `delta_lanes_run` and `delta_lanes_model`
@@ -216,10 +218,8 @@ def collect_delta_stats(intermediates) -> dict:
     their key heads, `delta_qk_rows_run` and `delta_qk_rows_model` (the q
     and k head-rows the recurrence's route reads, those the model has),
     summed over those layers."""
-    from .moe import _sown
-
     rows = [v.reshape(-1, v.shape[-1])
-            for v in _sown(intermediates, "delta_stats")]
+            for v in sown(intermediates, "delta_stats")]
     if not rows:
         return {}
     with jax.named_scope("delta_stats"):  # the sum's copies get an owner

@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import hc_mix
+from .sown import counters, sown
 
 
 @dataclasses.dataclass(frozen=True)
@@ -287,14 +288,13 @@ def read_out(x):
         return x.sum(1).astype(x.dtype)
 
 
+@counters
 def collect_residual_stats(intermediates) -> dict:
     """What the hyper-connections of one forward pass counted — {} for a
     model without one: `resmix_sinkhorn_err`, the largest deviation of a
     row or column sum of any sublayer's H_res from 1 over the step's
     tokens."""
-    from .moe import _sown
-
-    errs = [v.reshape(-1) for v in _sown(intermediates, "hc_sinkhorn_err")]
+    errs = [v.reshape(-1) for v in sown(intermediates, "hc_sinkhorn_err")]
     if not errs:
         return {}
     with jax.named_scope("hc_stats"):  # the max's copies get an owner

@@ -71,6 +71,7 @@ from ..ops.delta_rule import (
 from .gated_delta import _a_log_init, _l2_normalised
 from .llama import RMSNorm
 from .mamba2 import _conv_init, _dt_bias_init, causal_conv_silu
+from .sown import counters, sown
 
 _FLOOR_BAND = 0.99  # a channel "at the floor": g within 1% of the bound
 
@@ -175,14 +176,13 @@ class KDAMixer(nn.Module):
         return dense(cfg.hidden_size, "o_proj")(y.astype(cfg.dtype))
 
 
+@counters
 def collect_kda_stats(intermediates) -> dict:
     """What the KDA mixers of one forward pass counted — {} for a model
     without one: `kda_decay_floor_share`, the share of decay channels
     (heads, tokens, layers) within 1% of the lower bound, and
     `kda_gate_mean`, the head-wise output gate's mean."""
-    from .moe import _sown
-
-    rows = [v.reshape(-1, 4) for v in _sown(intermediates, "kda_stats")]
+    rows = [v.reshape(-1, 4) for v in sown(intermediates, "kda_stats")]
     if not rows:
         return {}
     with jax.named_scope("kda_stats"):  # the sum's copies get an owner

@@ -29,10 +29,10 @@ trunk, `mtp_0/{hnorm, enorm, eh_proj, block_0, norm}`:
     logits2 = RMSNorm(block(g)) @ W_head       the SAME table and head
 
 with x_t the trunk's summed, un-normed output; the model sows
-`mtp_logits` and `mtp_loss_weight`, and `collect_mtp_loss` adds
-lambda x the cross-entropy of logits2[t] against id_{t+2} (`labels[:, t
-+ 1]`; the last position has none) to the loss
-(`trainer/train_step.py::make_lm_loss`).  id_{t+1} is read off the ids
+`mtp_logits` and `mtp_loss_weight`, and `collect_mtp_loss`, the term
+this file registers with `models/sown.py`, adds lambda x the
+cross-entropy of logits2[t] against id_{t+2} (`labels[:, t + 1]`; the
+last position has none) to the loss.  id_{t+1} is read off the ids
 the model is given, shifted by one (`labels[:, t]` for every t the term
 counts).
 
@@ -74,6 +74,7 @@ from . import stack
 from .latent_attention import LatentAttention, LatentAttentionConfig
 from .llama import LlamaConfig, LlamaMLP, RMSNorm, RopeScaling, rope_freqs
 from .moe import MoEConfig, MoEMLP
+from .sown import sown, term
 
 
 @dataclasses.dataclass(frozen=True)
@@ -319,19 +320,20 @@ class LatentMoE(nn.Module):
         return stack.init_params(self, rng, batch, seq)
 
 
-def collect_mtp_loss(intermediates, labels):
-    """(lambda x the second prediction's cross-entropy, that
-    cross-entropy) of a forward pass that sowed `mtp_logits`, or None:
-    position t's second logits against `labels[:, t + 1]`, the last
-    position left out."""
+@term
+def collect_mtp_loss(intermediates, batch, ce):
+    """The loss's term of a forward pass that sowed `mtp_logits`, or
+    None: lambda x the second prediction's cross-entropy, and that
+    cross-entropy as `mtp_ce` — position t's second logits against
+    `labels[:, t + 1]`, the last position left out."""
     from .gpt import cross_entropy_loss
-    from .moe import _sown
 
-    logits = list(_sown(intermediates, "mtp_logits"))
+    logits = list(sown(intermediates, "mtp_logits"))
     if not logits:
         return None
-    weight, = _sown(intermediates, "mtp_loss_weight")
+    weight, = sown(intermediates, "mtp_loss_weight")
+    labels = batch["labels"]
     targets = jnp.concatenate(
         [labels[:, 1:], jnp.full_like(labels[:, :1], -1)], axis=1)
-    ce = cross_entropy_loss(logits[0], targets)
-    return weight.reshape(()) * ce, ce
+    mtp_ce = cross_entropy_loss(logits[0], targets)
+    return weight.reshape(()) * mtp_ce, {"mtp_ce": mtp_ce}

@@ -65,6 +65,7 @@ from . import stack
 from .llama import LlamaAttention, LlamaConfig, LlamaMLP, RMSNorm, rope_freqs
 from .mamba2 import _conv_init
 from .moe import MoEConfig, MoEMLP
+from .sown import counters, sown
 
 KINDS = ("conv", "full_attention")
 
@@ -202,14 +203,13 @@ class ShortConvMixer(nn.Module):
                         name="out_proj")(y)
 
 
+@counters
 def collect_shortconv_stats(intermediates) -> dict:
     """What the gated short convolutions of one forward pass counted — {}
     for a model without one: `shortconv_plain_calls`, the mixers that ran
     the `jax.numpy` lines, and `shortconv_calls`, the mixers."""
-    from .moe import _sown
-
-    rows = [v.reshape(-1, 2) for v in _sown(intermediates,
-                                            "shortconv_calls")]
+    rows = [v.reshape(-1, 2) for v in sown(intermediates,
+                                           "shortconv_calls")]
     if not rows:
         return {}
     with jax.named_scope("shortconv_calls"):  # the sum's copies get an owner

@@ -18,15 +18,16 @@ top-k form OLMoE/Mixtral train with (HF `load_balancing_loss_func`):
 E * sum_i f_i P_i, f_i the share of tokens that have expert i among their
 k (so the f_i sum to k).  `z_loss_weight` adds the router z-loss
 mean(logsumexp(logits)^2) (ST-MoE; OLMoE trains with 0.001).  Both are
-sown as `moe_aux_loss`, which `make_lm_loss` adds to the cross-entropy.
+sown as `moe_aux_loss`, which `moe_aux_term`, registered below with
+`models/sown.py`, adds to the cross-entropy.
 
 What the layer counts (no token is timed, nothing syncs): each call sows
 `moe_tokens_per_expert` (E,) and `moe_dropped` (assignments that reached
 no expert: 0 by construction in the grouped path).  The step program
-returns them reduced (`collect_moe_stats`) beside `loss`, and the
-Trainer's metrics pump reads them where it already reads the loss and
-passes them on as it does any scalar a step counts (log line, callbacks,
-a `trainer:step_metrics` span event).
+returns them reduced (`collect_moe_stats`, registered below) beside
+`loss`, and the Trainer's metrics pump reads them where it already reads
+the loss and passes them on as it does any scalar a step counts (log
+line, callbacks, a `trainer:step_metrics` span event).
 
 A chip's share of a wider deployment (`experts_held` < `num_experts`):
 the layer is told which experts it holds, routes over all of them and
@@ -122,6 +123,7 @@ from ..ops.grouped_matmul import (
     rows_map,
     unwritten_rows,
 )
+from .sown import counters, param_steps, sown, term
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1024,24 +1026,27 @@ class MoEMLP(nn.Module):
         return out.reshape(B, T, d)
 
 
-def _sown(intermediates, name: str):
-    """The leaves sown under `name`, whatever module path they sit on."""
-    for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
-        if name in [getattr(p, "key", getattr(p, "name", None))
-                    for p in path]:
-            yield leaf
-
-
 def collect_moe_aux_loss(intermediates) -> jax.Array:
     """Sum only the sown `moe_aux_loss` leaves of an intermediates
     collection — any other sown diagnostic (attention stats, logging
     metrics) must not silently become a loss term."""
     total = jnp.zeros((), jnp.float32)
-    for leaf in _sown(intermediates, "moe_aux_loss"):
+    for leaf in sown(intermediates, "moe_aux_loss"):
         total = total + jnp.sum(leaf)
     return total
 
 
+@term
+def moe_aux_term(intermediates, batch, ce):
+    """`collect_moe_aux_loss` as the loss's first term, or None where no
+    layer sowed one: the step's program is then the same whether or not
+    this module was ever imported."""
+    if not list(sown(intermediates, "moe_aux_loss")):
+        return None
+    return collect_moe_aux_loss(intermediates), {}
+
+
+@param_steps
 def collect_param_steps(intermediates) -> Dict[str, Any]:
     """The steps the layers of one forward pass ask for on variables the
     optimizer leaves alone, as a tree shaped like the part of `params`
@@ -1060,6 +1065,7 @@ def collect_param_steps(intermediates) -> Dict[str, Any]:
     return steps
 
 
+@counters
 def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     """What the MoE layers of one forward pass counted, reduced to
     scalars — or {} for a model with no such layer: the worst layer's
@@ -1074,11 +1080,11 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
     shared experts are gated (`MoEConfig.shared_gate`)
     `moe_shared_gate_mean`, the gates' mean over tokens and layers."""
     counts = [n.astype(jnp.float32)
-              for n in _sown(intermediates, "moe_tokens_per_expert")]
+              for n in sown(intermediates, "moe_tokens_per_expert")]
     if not counts:
         return {}
     loads = [n.max() / jnp.maximum(n.mean(), 1.0) for n in counts]
-    sums = {name: [jnp.sum(v) for v in _sown(intermediates, name)]
+    sums = {name: [jnp.sum(v) for v in sown(intermediates, name)]
             for name in ("moe_dropped", "moe_rows_held", "moe_rows_absent")}
     stats = {"moe_load_max_over_mean": jnp.max(jnp.stack(loads)),
              **{name: jnp.sum(jnp.stack(v)) for name, v in sums.items()
@@ -1088,12 +1094,12 @@ def collect_moe_stats(intermediates) -> Dict[str, jax.Array]:
                        ("moe_gather_rows", "moe_gather_rows_share"),
                        ("moe_combine_rows", "moe_combine_rows_share"),
                        ("moe_group_limit", "moe_group_limit_binds")):
-        tiles = [v.reshape(-1, 2) for v in _sown(intermediates, name)]
+        tiles = [v.reshape(-1, 2) for v in sown(intermediates, name)]
         if tiles:
             walked, of = jnp.concatenate(tiles).astype(jnp.float32).sum(0)
             stats[stat] = walked / of
-    gates = [v.reshape(()) for v in _sown(intermediates,
-                                          "moe_shared_gate_mean")]
+    gates = [v.reshape(()) for v in sown(intermediates,
+                                         "moe_shared_gate_mean")]
     if gates:
         stats["moe_shared_gate_mean"] = jnp.stack(gates).mean()
     return stats

@@ -595,8 +595,8 @@ class PipelinedLM:
             x = res
         logits = self._head(params, x)
         if mutable:
-            # surface the MoE aux loss the way flax sow would, so
-            # make_lm_loss's collect_moe_aux_loss finds it
+            # surface what the blocks add to the loss the way flax sow
+            # would, under the MoE layers' name: make_lm_loss collects it
             inter = ({"intermediates": {"moe_aux_loss": (aux,)}}
                      if want_aux else {})
             return logits, inter
@@ -681,17 +681,18 @@ class PipelinedLM:
             return self.block_builder(params, idx, deterministic)
         cfg = self.config
         if getattr(cfg, "moe_experts", 0) and "wte" in params:
-            # MoE blocks: capture the sown load-balance aux loss and carry
-            # it through the pipeline as an explicit scalar
+            # MoE blocks: capture what the block sowed for the loss (the
+            # load-balance aux term) and carry it through the pipeline as
+            # an explicit scalar
             from ..models.gpt import Block
-            from ..models.moe import collect_moe_aux_loss
+            from ..models.sown import collect
 
             def fn(pl, h):
                 h2, upd = Block(cfg).apply(
                     {"params": pl}, h, deterministic,
                     mutable=["intermediates"])
-                return h2, collect_moe_aux_loss(
-                    upd.get("intermediates", {}))
+                return h2, collect(upd.get("intermediates", {}), None,
+                                   jnp.zeros((), jnp.float32))[0]
         elif "wte" in params:
             from ..models.gpt import Block
 

@@ -143,8 +143,8 @@ def make_train_step(
 
     def _grads(params, batch):
         """(loss, grads, stats): a loss that also counts (make_lm_loss:
-        the MoE layers' load) hands its counters out beside the loss,
-        and they ride in the step's metrics.  `stats["param_steps"]`,
+        what the model's layers sowed) hands its counters out beside the
+        loss, and they ride in the step's metrics.  `stats["param_steps"]`,
         where the loss gives one, is no counter: a tree shaped like part
         of `params`, added to them after the optimizer's update (a rule
         run out of band on variables the optimizer leaves alone)."""
@@ -320,62 +320,23 @@ def train_state_shardings(state_like: TrainState, planner: ShardingPlanner,
 def make_lm_loss(model_apply: Callable) -> Callable:
     """Standard causal-LM loss over a batch dict {input_ids, labels}.
 
-    Collects sown auxiliary losses (MoE load-balancing, router z-loss;
-    the sparse-attention indexers' KL terms, `models/attention.
-    collect_attention_aux_loss`, with the bare cross-entropy beside them
-    in the stats as `ce`) and a multi-token-prediction module's weighted cross-entropy
-    (`models/latent_moe.collect_mtp_loss`: its target is `labels` one
-    further on) when present.  `loss_fn.with_stats(params, batch) ->
-    (loss, stats)` is the same loss with what the MoE layers, the
-    windowed or latent attention layers, the delta-rule mixers, the
-    gated short convolutions and the hyper-connections counted
-    (`collect_moe_stats`, `collect_attention_stats`,
-    `collect_delta_stats`, `collect_kda_stats`, `collect_shortconv_stats`,
-    `collect_residual_stats`; {} for a dense model without any of them),
-    and `mtp_ce` beside them: `make_train_step` differentiates that one
-    and returns the counters in the step's metrics."""
+    To the cross-entropy it adds every term the model's layers sowed for
+    the loss, and `loss_fn.with_stats(params, batch) -> (loss, stats)` is
+    the same loss with what the layers counted beside it ({} for a model
+    that sows nothing): `models/sown.collect` is the one place that is
+    asked, and the file that sows a value says there what it is.
+    `make_train_step` differentiates `with_stats` and returns the
+    counters in the step's metrics."""
     from ..models.gpt import cross_entropy_loss
+    from ..models.sown import collect
 
     def with_stats(params, batch):
         logits, updates = model_apply(
             {"params": params}, batch["input_ids"],
             mutable=["intermediates"])
-        loss = ce = cross_entropy_loss(logits, batch["labels"])
+        ce = cross_entropy_loss(logits, batch["labels"])
         inter = updates.get("intermediates", {})
-        stats = {}
-        if inter:
-            from ..models.attention import (
-                collect_attention_aux_loss,
-                collect_attention_stats,
-            )
-            from ..models.gated_delta import collect_delta_stats
-            from ..models.hyper_connection import collect_residual_stats
-            from ..models.kda import collect_kda_stats
-            from ..models.latent_moe import collect_mtp_loss
-            from ..models.lfm2 import collect_shortconv_stats
-            from ..models.moe import (
-                collect_moe_aux_loss,
-                collect_moe_stats,
-                collect_param_steps,
-            )
-
-            loss = loss + collect_moe_aux_loss(inter)
-            stats = {**collect_moe_stats(inter),
-                     **collect_attention_stats(inter),
-                     **collect_delta_stats(inter),
-                     **collect_kda_stats(inter),
-                     **collect_shortconv_stats(inter),
-                     **collect_residual_stats(inter)}
-            if "index_kl" in stats:  # the two terms, seen apart
-                stats["ce"] = ce
-                loss = loss + collect_attention_aux_loss(inter)
-            mtp = collect_mtp_loss(inter, batch["labels"])
-            if mtp is not None:
-                loss, stats["mtp_ce"] = loss + mtp[0], mtp[1]
-            steps = collect_param_steps(inter)
-            if steps:
-                stats["param_steps"] = steps
-        return loss, stats
+        return collect(inter, batch, ce) if inter else (ce, {})
 
     def loss_fn(params, batch):
         return with_stats(params, batch)[0]

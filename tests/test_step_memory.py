@@ -74,11 +74,17 @@ def _named(name):
     return [s for s in tspans.spans_snapshot() if s["name"] == name]
 
 
-def _compiles():
+def _compiles(since=0.0):
+    """(lowerings and compiles this process began at or after `since` on
+    `time.monotonic`'s clock, its questions to the persistent cache).
+    `durations` is a ring that drops its oldest record: a sum over the
+    whole of it goes DOWN when a worker's 65,536th record falls out, so
+    what a test may compare is the records newer than its own mark."""
     from dlrover_wuqiong_tpu.auto import compile_cache
 
     return (sum(d["name"] in ("jax:backend_compile", "jax:lower")
-                for d in compile_cache.durations),
+                and d["t_mono"] >= since
+                for d in list(compile_cache.durations)),
             compile_cache.counters.hits + compile_cache.counters.misses)
 
 
@@ -127,9 +133,9 @@ def test_asking_for_the_budget_compiles_nothing(trained):
     # by JAX's caches: nothing is traced, lowered or compiled
     for find in perf._step_executables.values():
         find.cache_clear()
-    before = _compiles()
+    mark, (_, asked) = time.monotonic(), _compiles()
     assert perf.step_memory()[1]["live_bytes"] > 0
-    assert _compiles() == before
+    assert _compiles(since=mark) == (0, asked)
 
 
 def test_first_step_carries_the_budget_once_a_width(tmp_path):
