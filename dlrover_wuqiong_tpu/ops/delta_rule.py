@@ -37,7 +37,10 @@ range is (0, 2)) L^32's entries pass 1e27 while the inverse's stay
 under 2 — float32 cancels to nothing.  The block form only ever
 multiplies inverses of diagonal blocks, which are bounded as the result
 is.  Its cotangent is -T^T dT T^T (a `custom_vjp`: two products, not
-the differentiation of the rounds).
+the differentiation of the rounds).  (`_unit_lower_inverse` is these
+rounds as written, the `jax.numpy` routes' and the kernels' oracle; the
+kernels' `_solve` runs the first three as one substitution, "kernel"
+below.)
 
 The chunk-to-chunk step holds no loop: the entering states are one
 `jax.lax.associative_scan` over the (A, B) pairs ((A2, B2) o (A1, B1) =
@@ -65,9 +68,8 @@ shapes, the backend, the mesh it runs on), never by a knob:
   diagonal), the chunk axis last and sequential: the forward kernel
   walks it in order, the backward in reverse.  In VMEM and nowhere
   else, a head and a chunk: the decay tile, K K^T, L, T by the forward
-  substitution in blocks above (float32 products at full precision; the
-  first round, from D_1 = I, needs none), U0, W, U, P = Q K^T o decay
-  and o; the carried state,
+  substitution in blocks above (`_solve`, below), U0, W, U, P = Q K^T o
+  decay and o; the carried state,
   (dk x dv) float32 a head, is a scratch the chunk axis walks (zeroed at
   chunk 0) and the carry is APPLIED, S <- e^{b_C} S + Kd^T U: no (dk x
   dk) transition, no associative scan, no shift and no pad is on this
@@ -84,6 +86,29 @@ shapes, the backend, the mesh it runs on), never by a knob:
   the same ones at the same places; what differs is the order of float32
   sums and that U, rounded, meets Kd^T where `_chunked` composes (A, B).
   Where `delta_route` says so (`_SITES`).
+  The solve in the kernels (`_solve`; `solve_rounds(chunk)` is its
+  static record, PR 69): the rounds that join blocks SMALLER than a
+  float32 sublane tile (s = 1, 2, 4: the diagonal blocks of 8 steps) are
+  no products at all.  The tile's sixteen (8 x 8) diagonal blocks are
+  laid side by side in one (8 x 128) register (16 selects, 15 adds), read
+  out by sub-diagonal as (1 x 128) rows, and inverted by the forward
+  substitution T = I - T L run down the sub-diagonals: 21 lane rolls,
+  multiplies and adds on single rows, plain float32 on the vector units
+  (an exact multiply-add where a six-pass bfloat16 product is float32 to
+  its last bit or two; a term is an entry of a diagonal block's inverse
+  times one of L, bounded as the result is: still NOT the nilpotent
+  product).  The rounds that join blocks of 8, 16, ... stay D_2s = D_s -
+  D_s E D_s in two float32 products at `Precision.HIGHEST` each, but
+  over the LOWER-half rows of each block of 2s alone — D_s E D_s is
+  B E21 A, zero everywhere else, and from s = 8 on those rows are whole
+  sublane tiles — so a product pushes 64 rows and not 128, and the mask
+  sits on the result (B's rows are zero outside B: L needs none).  At a
+  chunk of 64: 36 MXU passes of 64 rows a tile where there were 60 of
+  128; the solve alone 1.80 -> 0.93 us a tile, a forward call 2.67 ->
+  2.12 us a head-tile and a backward one (it rebuilds the tiles) 3.56 ->
+  3.03 at Qwen3-Next's shape (PERF.md section 6, PR 69, with what was
+  tried and left: blocks of 16 on the vector units are slower than none).
+  No product of the solve or of its cotangent runs below float32.
 - "chunked": the form above in `jax.numpy` (`_chunked` for a decay a
   head, `_chunked_channel` for a decay a channel), the backward pass its
   differentiation but for the two `custom_vjp`s.  GSPMD partitions it, so
@@ -194,6 +219,8 @@ _ROWS = 128              # the MXU's: a grid step's chunks fill them
 _VMEM_LIMIT = 64 * 1024 * 1024  # this kernel's own request of the compiler
 _SITES = frozenset({"device"})  # S9 (ROADMAP) adds "manual", and the record
 _SUB = 16                # steps of a sub-block where the decay is a channel's
+_BLOCK = 8               # steps of a block the solve inverts without the MXU:
+#                          a float32 sublane tile; see `solve_rounds`
 # the least log-decay a step of the channel form may carry: the 8 steps
 # either side of a sub-block's reference stay inside e^+-64, where a key's
 # small entry times the scale is no denormal yet
@@ -203,8 +230,10 @@ CHANNEL_DECAY_FLOOR = -8.0
 def _heads_block(h: int) -> int:
     """Heads a grid step takes: the largest divisor of `h` up to
     `_HEADS_A_STEP`.  The heads of a step are independent chains of
-    products (the solve's ten dependent float32 products a tile above
-    all), which the scheduler interleaves.  Measured at the cell's shape,
+    products (the solve's dependent float32 products a tile above all:
+    ten when these were measured, six since PR 69, whose probe of the
+    solve alone still reads five heads a step 3% under four), which the
+    scheduler interleaves.  Measured at the cell's shape,
     fifteen heads, two chunks a step (PERF.md section 6, PR 48): 1 / 3 /
     5 / 15 heads a step run a layer's forward kernel in 2.77 / 2.69 /
     2.68 / 2.65 ms and its backward in 3.89 / 3.70 / 3.61 / 4.37 (at
@@ -240,7 +269,10 @@ def delta_route(t: int, chunk: int, heads: int, dk: int, dv: int,
     observe: ("kernel", heads a grid step) where the call runs on one of
     `_SITES` (`mesh` is the mixer config's: a Mosaic kernel cannot be
     partitioned by GSPMD), when the sequence is a whole number of chunks,
-    the chunk a multiple of the sublane tile, dk and dv multiples of 32
+    the chunk a multiple of the sublane tile and a power of two (the
+    solve's rounds double the blocks: at a chunk of 48, two a grid step,
+    the kernels' answer was wrong by 0.16 until PR 69 sent it to the
+    chunked form), dk and dv multiples of 32
     up to 256 lanes, and the blocks plus the state of a block of heads
     fit the VMEM the call states; else "chunked" where the sequence is a
     whole number of chunks, else "sequential".  With `channel_decay` (g a
@@ -254,6 +286,8 @@ def delta_route(t: int, chunk: int, heads: int, dk: int, dv: int,
     if mosaic.kernel_site(mesh) not in _SITES:
         return "chunked"
     if chunk % mosaic.SUBLANES or dk % 32 or dv % 32 or max(dk, dv) > _WIDEST:
+        return "chunked"
+    if chunk & (chunk - 1):  # the solve's rounds double the blocks
         return "chunked"
     hb = _heads_block(heads)
     rows = chunk * _chunks_a_step(chunk, t // chunk)
@@ -532,9 +566,10 @@ def _chunked_channel(q, k, v, g, beta, chunk, dtype):
 # diagonal (a mask keeps a chunk to itself), so the solve's rounds, T's
 # products and the masked products run once for n chunks at the MXU's
 # full 128 rows — a (64 x 64 x 64) product occupies a pass as a (128 x
-# 128 x 128) one does, and the solve's float32 products are most of a
-# tile's passes.  Only what meets the carried state is a chunk's own,
-# in order (backward: in reverse) inside the step.
+# 128 x 128) one does, and the solve's float32 products were most of a
+# tile's passes (60 of some 79; 36 half passes of some 55 since PR 69).
+# Only what meets the carried state is a chunk's own, in order (backward:
+# in reverse) inside the step.
 
 def _dot32(a, b, dims=((1,), (0,))):
     """A float32 product at full precision (the solve's, its cotangent's)."""
@@ -555,27 +590,56 @@ def _stack(parts):
 
 
 def _masks(chunk, n):
-    """Of an (R x R) tile of n chunks: (s <= r, s < r, each inside its
-    chunk; s == r; the sub-diagonal blocks that join two s-blocks into one
-    of 2s for s = 1, 2, 4, ... below the chunk)."""
+    """Of an (R x R) tile of n chunks: s <= r and s < r, each inside its
+    chunk; and what `_solve` selects by — the diagonal blocks of `_BLOCK`
+    steps, (R x R); each sub-diagonal d of those blocks where they lie
+    side by side, (`_BLOCK` x R); and for each round s that stays on the
+    MXU, of the LOWER-half rows of the blocks of 2s it makes, (R / 2 x R),
+    their upper-half columns."""
     size = n * chunk
     rows, cols = _iota((size, size), 0), _iota((size, size), 1)
     same = rows // chunk == cols // chunk
-    joins, s = [], 1
-    while s < chunk:
-        joins.append((rows // (2 * s) == cols // (2 * s))
-                     & (rows // s != cols // s))
-        s *= 2
-    return (rows >= cols) & same, (rows > cols) & same, rows == cols, joins
+    band = _iota((_BLOCK, size), 0) - _iota((_BLOCK, size), 1) % _BLOCK
+    half = (size // 2, size)
+    joins = {s: _iota(half, 1) // s == 2 * (_iota(half, 0) // s)
+             for s in solve_rounds(chunk)["mxu"]}
+    return (rows >= cols) & same, (rows > cols) & same, (
+        rows // _BLOCK == cols // _BLOCK,
+        [band == d for d in range(_BLOCK)], joins)
 
 
-def _solve(low, eye, joins):
-    """`_unit_lower_inverse` on one tile: D_2s = D_s - D_s E D_s from
-    D_1 = I, whose first round needs no product (I E I = E)."""
-    inv = jnp.where(eye, 1.0, 0.0) - jnp.where(joins[0], low, 0.0)
-    for join in joins[1:]:
-        inv = inv - _dot32(_dot32(inv, jnp.where(join, low, 0.0)), inv)
-    return inv
+def _solve(low, masks):
+    """`_unit_lower_inverse` on one (R x R) tile, `low` strictly lower
+    inside each chunk (a power of two, `_BLOCK` steps at least), as the
+    module's docstring says ("The solve in the kernels").  `side` is the
+    diagonal blocks side by side, (`_BLOCK` x R), a block's column on its
+    lane; `sub[d]` and `inv[d]`, (1 x R), are L's and T's sub-diagonal d
+    by column, T[c + d, c] = -(L[c + d, c] + sum_{0 < e < d} T[c + d,
+    c + e] L[c + e, c]); a round on the MXU takes B E21 A from the
+    lower-half rows of D_s times L times D_s and masks the RESULT (B's
+    rows are zero outside B, so L needs no mask)."""
+    blocks, bands, joins = masks
+    size = low.shape[0]
+    tiled = (size // _BLOCK, _BLOCK, size)
+    side = jnp.sum(jnp.where(blocks, low, 0.0).reshape(tiled), axis=0)
+    sub = [None] + [jnp.sum(jnp.where(band, side, 0.0), axis=0, keepdims=True)
+                    for band in bands[1:]]
+    inv = [jnp.ones((1, size), jnp.float32)]
+    for d in range(1, _BLOCK):
+        inv.append(-sum((pltpu.roll(inv[d - e], size - e, 1) * sub[e]
+                         for e in range(1, d)), sub[d]))
+    side = jnp.zeros_like(side)
+    for band, row in zip(bands, inv):
+        side = jnp.where(band, row, side)
+    tm = jnp.where(blocks, jnp.broadcast_to(side[None], tiled).reshape(
+        size, size), 0.0)
+    for s, join in joins.items():
+        starts = range(0, size, 2 * s)
+        lower = [tm[i + s:i + 2 * s] for i in starts]
+        upd = jnp.where(join, _dot32(_dot32(_stack(lower), low), tm), 0.0)
+        tm = _stack([x for j, i in enumerate(starts) for x in (
+            tm[i:i + s], lower[j] - upd[j * s:(j + 1) * s])])
+    return tm
 
 
 class _Tiles:
@@ -586,7 +650,7 @@ class _Tiles:
 
     def __init__(self, refs, h, masks, chunk, dtype):
         q_ref, k_ref, v_ref, bc_ref, br_ref, bt_ref = refs
-        tril, strict, eye, joins = masks
+        tril, strict, solve = masks
         f32 = jnp.float32
         b_col, self.beta = bc_ref[:, h:h + 1], bt_ref[:, h:h + 1]
         size = b_col.shape[0]
@@ -606,8 +670,7 @@ class _Tiles:
         self.kb, self.qb = self.kf.astype(dtype), q_ref[h].astype(dtype)
         self.kk = _dot_t(self.kb, self.kb)
         self.tm = _solve(
-            jnp.where(strict, self.beta * self.decay * self.kk, 0.0),
-            eye, joins)
+            jnp.where(strict, self.beta * self.decay * self.kk, 0.0), solve)
         self.tb = self.tm.astype(dtype)
         self.vbb = (self.vf * self.beta).astype(dtype)
         self.keb = (self.kf * (self.beta * self.eb)).astype(dtype)
@@ -756,7 +819,7 @@ class _ChannelTiles:
 
     def __init__(self, refs, h, masks, chunk, dtype):
         q_ref, k_ref, v_ref, b_ref, bt_ref = refs
-        tril, strict, eye, joins = masks
+        tril, strict, solve = masks
         f32 = jnp.float32
         b, self.beta = b_ref[h], bt_ref[:, h:h + 1]       # (R, dk), (R, 1)
         size = b.shape[0]
@@ -779,7 +842,7 @@ class _ChannelTiles:
         self.kk = jnp.where(strict, _stack([x[:_SUB] for x in both]), 0.0)
         self.pb = jnp.where(tril, _stack([x[_SUB:] for x in both]),
                             0.0).astype(dtype)
-        self.tm = _solve(self.beta * self.kk, eye, joins)
+        self.tm = _solve(self.beta * self.kk, solve)
         self.tb = self.tm.astype(dtype)
         self.vbb = (self.vf * self.beta).astype(dtype)
         self.keb = (self.kf * self.eb * self.beta).astype(dtype)
@@ -1030,6 +1093,33 @@ def _chunks_a_step(chunk: int, chunks: int) -> int:
     while chunks % n:
         n -= 1
     return n
+
+
+def solve_rounds(chunk: int) -> dict:
+    """Where each round of a tile's solve runs, by the size s of the
+    blocks it joins (s = 2, 4, ... below the chunk; the round from D_1 = I
+    was never a computation): "vector" — inside the diagonal blocks of
+    `_BLOCK` = 8 steps, ONE forward substitution in float32 on the vector
+    units — and "mxu" — two `_dot32` products each, over the lower-half
+    rows of the blocks joined: 12 x len(mxu) float32 MXU passes a tile, 36
+    of 64 rows at a chunk of 64 where there were 60 of 128.  From the chunk
+    alone, and `_solve` reads it (through `_masks`), so the record cannot
+    drift from the code; pinned by tests/test_program_from_arguments.py.
+    Measured (PERF.md section 6, PR 69), us a head-tile: the solve ALONE
+    over 4,096 tiles of two chunks of 64, four a grid step, then the
+    forward | backward call at `qwen3_next_80b_a3b.steady`'s shape (1 x
+    16,384, 32 heads of 128 | 128).  Every round two products of 128 rows
+    (the form before): 1.80, 2.67 | 3.56.  Blocks of 4 on the vector
+    units: 1.48, 2.45 | 3.32; of 8: 1.20, 2.37 | 3.27; of 16 (round s = 8
+    too, 105 lane rolls a tile): 1.40, 2.72 | 3.61 — slower than none.
+    Blocks of 8 and the products over the lower-half rows (this): 0.93,
+    2.12 | 3.03; of 16 and the same: 1.31, 2.56 | 3.46; blocks of 8, the
+    lower-half rows AND the contraction cut to the 64 lanes that are not
+    zero: 1.46, 2.55 | 3.56.  The Olmo hybrid's and Ling's shapes order
+    them the same way."""
+    rounds = tuple(2 ** i for i in range(1, max(chunk - 1, 0).bit_length()))
+    vector = tuple(s for s in rounds if 2 * s <= _BLOCK)
+    return {"vector": vector, "mxu": rounds[len(vector):]}
 
 
 def _kernel_operands(q, k, v, g, beta, chunk, hb, n):

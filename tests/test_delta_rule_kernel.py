@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from dlrover_wuqiong_tpu.ops import delta_rule as dr
 
@@ -143,6 +144,57 @@ def test_keys_that_agree_under_a_write_gate_near_two_stay_exact(
                                atol=1e-4 * float(jnp.abs(want).max()))
 
 
+def _tile(what, chunk, n):
+    """A strictly lower (R x R) L of n chunks: keys on the unit sphere
+    under a write gate in (0, 2); keys that all but AGREE under a gate of
+    1.99; or nothing."""
+    size = n * chunk
+    ks = jax.random.split(jax.random.PRNGKey(chunk + n), 3)
+    k = jax.random.normal(ks[0], (size, 24))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[1], (size, 1)))
+    if what == "agree":
+        k, beta = k + 30.0 * jax.random.normal(ks[2], (1, 24)), 1.99
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    rows, cols = np.arange(size)[:, None], np.arange(size)[None, :]
+    strict = (rows > cols) & (rows // chunk == cols // chunk)
+    with jax.default_matmul_precision("highest"):
+        low = jnp.where(strict, beta * (k @ k.T), 0.0)
+    return jnp.zeros_like(low) if what == "zero" else low
+
+
+@pytest.mark.parametrize("what", ("random", "agree", "zero"))
+@pytest.mark.parametrize("n", (1, 2), ids=("one_chunk", "two_chunks"))
+@pytest.mark.parametrize("chunk", (16, 32, 64))
+def test_a_tiles_solve_is_the_block_substitution_in_float32(chunk, n, what):
+    """`_solve` by itself on an (R x R) tile, the rounds `solve_rounds`
+    hands the vector units among them, against `_unit_lower_inverse` with
+    float32 products: within a few float32 ulps of the largest entry at
+    random keys (the same formula, the sums in another order) and exactly
+    I at L = 0.  Where a chunk's keys agree under a gate near 2 the
+    inverse is the badly conditioned one whose nilpotent form overflows
+    (L^32 passes 1e27): there BOTH forms stand off the float64 inverse by
+    tens of ulps, the tile's by no more than the oracle's does — the form
+    is still the substitution, and no product of it runs below float32."""
+    low = _tile(what, chunk, n)
+
+    def kernel(low_ref, out_ref):
+        out_ref[...] = dr._solve(low_ref[...], dr._masks(chunk, n)[2])
+
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(jax.jit(lambda x: pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
+            interpret=True)(x))(low))
+        want = np.asarray(dr._unit_lower_inverse(low))
+    ulp = float(np.spacing(np.float32(np.abs(want).max())))
+    if what == "agree":
+        exact = np.linalg.inv(np.eye(n * chunk) + np.asarray(low, np.float64))
+        assert np.abs(exact).max() < 2.0 and np.isfinite(got).all()
+        assert np.abs(got - exact).max() \
+            <= 3 * np.abs(want - exact).max() + 4 * ulp
+    else:
+        assert np.abs(got - want).max() <= (4 * ulp if what == "random" else 0)
+
+
 @pytest.mark.parametrize("what", NAMES)
 @pytest.mark.parametrize("g_step", [BOUND, dr.CHANNEL_DECAY_FLOOR])
 def test_every_channel_at_the_bound_and_at_the_floor_stays_exact(g_step,
@@ -224,11 +276,13 @@ def test_the_statistics_stay_float32_under_a_bfloat16_dtype(phase, channel):
     """`dtype` rounds the operands the chunked form rounds — eight
     products of the forward kernel a head's decay, seven a channel's (one
     sub-block's product makes L and P both) — and nothing else: every
-    product accumulates in float32, the solve's (two a round from the
-    second on) and its cotangent's take float32 operands at the highest
-    precision, the carried state's scratch, the saved states and the
-    output are float32."""
-    chunk, rounds = 16, 4
+    product accumulates in float32, the solve's (two a round that
+    `solve_rounds` leaves on the MXU; the other rounds are float32
+    multiply-adds, no product) and its cotangent's take float32 operands
+    at the highest precision, the carried state's scratch, the
+    saved states and the output are float32."""
+    chunk = 16
+    exact_a_solve = 2 * len(dr.solve_rounds(chunk)["mxu"])
     ops = draw(7, 32, 2, 8, 24, channel=channel)
 
     def fn(*a):
@@ -238,7 +292,7 @@ def test_the_statistics_stay_float32_under_a_bfloat16_dtype(phase, channel):
     if phase == "forward":
         jaxpr = jax.make_jaxpr(fn)(*ops)
         assert jaxpr.out_avals[0].dtype == jnp.float32
-        want_rounded, want_exact = 7 if channel else 8, 2 * (rounds - 1)
+        want_rounded, want_exact = 7 if channel else 8, exact_a_solve
     else:
         jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(fn, *a)[1](
             jnp.ones(ops[2].shape, jnp.float32)))(*ops)
@@ -248,7 +302,7 @@ def test_the_statistics_stay_float32_under_a_bfloat16_dtype(phase, channel):
         # the cotangents' (a channel's: 12 and the sub-block's two); the
         # solve again and the two of -T^T dT T^T
         want_rounded = 7 + 4 + 14 if channel else 8 + 6 + 16
-        want_exact = 2 * (rounds - 1) * 2 + 2
+        want_exact = 2 * exact_a_solve + 2
     dots = _dots(jaxpr.jaxpr)
     rounded = [d for d in dots
                if all(v.aval.dtype == jnp.bfloat16 for v in d.invars)]
@@ -317,9 +371,14 @@ def test_the_scalar_pairs_traced_gradient_is_the_one_olmos_cell_was_read_on():
     """The channel pair shares the scalar pair's helpers and wrappers
     (`_masks`, `_solve`, `_specs`, the jitted calls, the `custom_vjp`):
     the gradient of `_chunk_kernels` at `olmo_hybrid_7b.steady`'s shape,
-    five heads a step, traces to the text it did before there was a
-    channel pair (PR 57's, 4,816 lines), so that cell's program did not
-    move with PR 58's and does not with the next change to a helper."""
+    five heads a step, traces to ONE pinned text, so that cell's program
+    does not move with a change meant for another pair's.  PR 57's text
+    (4,816 lines, sha256 65f20916...a9f8) held through PR 58's channel
+    pair; PR 69 moved it ON PURPOSE — `_solve` itself changed for all
+    four kernels (blocks of 8 steps by substitution on the vector units,
+    the rounds left on the MXU over the lower-half rows: 8,558 lines) —
+    and the cell was read again on this text (PERF.md section 6, PR
+    69)."""
     t, h, dk, dv = 8192, 15, 96, 192
     shapes = [jax.ShapeDtypeStruct(dims, dtype) for dims, dtype in (
         ((1, t, h, dk), jnp.float32), ((1, t, h, dk), jnp.float32),
@@ -329,4 +388,4 @@ def test_the_scalar_pairs_traced_gradient_is_the_one_olmos_cell_was_read_on():
         lambda *a: jnp.sum(dr._chunk_kernels(*a, 64, jnp.bfloat16, 5)),
         argnums=(0, 1, 2, 3, 4)))(*shapes))
     assert hashlib.sha256((text + "\n").encode()).hexdigest() == \
-        "65f20916f81d6f96e22d93d9b621ee76512bfbe9b4a27f2f47cdc05891e4a9f8"
+        "74036b8b3d39e50c6c81740675c359d93d0620c85da043980ef211165d36ca6e"

@@ -634,6 +634,7 @@ def _two_devices():
     (True, (8192, 64, 7, 96, 192), None, ("kernel", 1)),
     (True, (64, 16, 3, 8, 24), None, "chunked"),         # the nano widths
     (True, (8192, 8, 15, 96, 192), None, "chunked"),     # chunk off a tile
+    (True, (8160, 48, 15, 96, 192), None, "chunked"),    # no power of two
     (True, (8192, 64, 15, 320, 192), None, "chunked"),   # keys too wide
     (True, (8192, 2048, 15, 256, 256), None, "chunked"),  # tiles over VMEM
     # ling3_0_flash.steady: a decay a key CHANNEL (the shape's sixth
@@ -658,6 +659,32 @@ def test_the_delta_rules_route_is_its_shapes_the_backend_and_the_mesh(
     from dlrover_wuqiong_tpu.ops import delta_rule as dr
 
     assert dr.delta_route(*shape[:5], mesh and mesh(), *shape[5:]) == route
+
+
+@pytest.mark.parametrize("cell,shape,route", [
+    ("olmo_hybrid_7b.steady", (8192, 64, 15, 96, 192), ("kernel", 5)),
+    ("ling3_0_flash.steady", (8192, 64, 16, 128, 128, True), ("kernel", 4)),
+    ("qwen3_next_80b_a3b.steady", (16384, 64, 32, 128, 128), ("kernel", 4)),
+])
+def test_the_solves_rounds_are_the_chunks_at_the_three_delta_cells(
+        on_tpu, cell, shape, route):
+    """`ops/delta_rule.solve_rounds(chunk)`, which `_solve` reads, beside
+    `delta_route` (unchanged): at the three cells' chunk of 64 the rounds
+    that join blocks of 2 and of 4 steps run on the vector units (PR 69)
+    and those of 8, 16 and 32 stay two float32 products each, 36 MXU
+    passes of a tile's 60 before; a chunk of 16 (the nano models') keeps
+    one round of products, and nothing but the chunk decides.  With the
+    compiled step's count of `dwt_gdr_*` / `dwt_kda_*` calls (the cells'
+    compile tests) this is the mechanism's counter: it engages on every
+    kernel call."""
+    from dlrover_wuqiong_tpu.ops import delta_rule as dr
+
+    chunk = shape[1]
+    assert dr.delta_route(*shape[:5], None, *shape[5:]) == route, cell
+    assert dr.solve_rounds(chunk) == {"vector": (2, 4), "mxu": (8, 16, 32)}
+    assert 12 * len(dr.solve_rounds(chunk)["mxu"]) == 36
+    assert dr.solve_rounds(16) == {"vector": (2, 4), "mxu": (8,)}
+    assert dr.solve_rounds(128)["mxu"] == (8, 16, 32, 64)
 
 
 def _delta_mixer_grad_jaxpr(mesh):

@@ -49,12 +49,16 @@ select `models/moe.py` runs in its place.
 three share cells' shapes: the gather of all T*k rows through the sort's
 inverse against the kernel route's loop over the held rows in assignment
 order at three chunks a turn, and counts the entries that differ.
-`delta` reads the same way the gated delta rule at the Olmo hybrid
-cell's shape (a decay a head) and at Ling's (a decay a key channel),
-forward and forward + backward: the chunked `jax.numpy` form against the
-form's pair, `dwt_gdr_*` or `dwt_kda_*` (`ops/delta_rule.py`), at several
-heads and one or two chunks a grid step, with each one's distance from
-the chunked form; `delta_kda` is Ling's half alone.
+`delta` reads the same way the gated delta rule at its three cells'
+shapes — the Olmo hybrid's (a decay a head, 15 heads of 96 | 192), Ling's
+(a decay a key channel, 16 of 128 | 128) and Qwen3-Next's (a decay a
+head, 32 of 128 | 128 over 16,384 steps, four a grid step) — forward and
+forward + backward: first the tile solve alone in us a tile, then the
+chunked `jax.numpy` form against the form's pair, `dwt_gdr_*` or
+`dwt_kda_*` (`ops/delta_rule.py`), at several heads and one or two chunks
+a grid step, with each one's distance from the chunked form and
+`solve_rounds` (which rounds of the solve ran where); `delta_kda` is
+Ling's half alone.
 `hc` reads the same way one sublayer's hyper-connection at Xing's
 stream (four lanes of 8,192 x 3,584): the plain route's fusions against
 `dwt_hc_pre` / `_post` / `_post_bwd` / `_pre_bwd` (`ops/hc_mix.py`) at
@@ -1042,36 +1046,77 @@ def probe_rope():
                                "device_ops_ms": _device_ops_ms(f, *args)})
 
 
-# the delta rule's two forms by the decay's rank: ((heads, chunks) a grid
-# step to try, (b, T, H, dk, dv, chunk)) at the cell's shape
+# the delta rule at its cells' shapes: ((heads, chunks) a grid step to
+# try, (b, T, H, dk, dv, chunk), the decay a key CHANNEL's, the write
+# gate's top)
 DELTA_FORMS = {
     "head": (((5, 1), (1, 2), (3, 2), (5, 2), (15, 2)),
-             (1, 8192, 15, 96, 192, 64)),          # olmo_hybrid_7b.steady
+             (1, 8192, 15, 96, 192, 64), False, 2.0),   # olmo_hybrid_7b.steady
     "channel": (((4, 1), (1, 2), (2, 2), (4, 2)),
-                (1, 8192, 16, 128, 128, 64)),      # ling3_0_flash.steady
+                (1, 8192, 16, 128, 128, 64), True, 1.0),  # ling3_0_flash.steady
+    "grouped": (((4, 1), (1, 2), (2, 2), (4, 2)),   # qwen3_next_80b_a3b.steady
+                (1, 16384, 32, 128, 128, 64), False, 1.0),
 }
 
 
 def probe_delta(forms=tuple(DELTA_FORMS), interpret=False, shapes=None):
-    """The gated delta rule at its two cells' shapes (1 x 8192, chunk 64,
-    bfloat16 products; `olmo_hybrid_7b.steady`: a decay a HEAD, fifteen
-    heads, keys of 96, values of 192; `ling3_0_flash.steady`: a decay a
-    key CHANNEL in (-5, 0), sixteen heads of 128 | 128), forward and
-    forward + backward: the chunked `jax.numpy` form against the form's
-    kernel pair (`dwt_gdr_*` / `dwt_kda_*`) at several (heads, chunks) a
-    grid step, each with the kernel route's largest relative distance
-    from the chunked form (PERF.md section 6, PR 48 and PR 58)."""
+    """The gated delta rule at its three cells' shapes (chunk 64, bfloat16
+    products):
+
+    | form | cell | b x T | heads of dk | dv | decay | write gate |
+    | --- | --- | --- | --- | --- | --- | --- |
+    | `head` | `olmo_hybrid_7b.steady` | 1 x 8,192 | 15 of 96 | 192 | a head | (0, 2) |
+    | `channel` | `ling3_0_flash.steady` | 1 x 8,192 | 16 of 128 | 128 | a key channel in (-5, 0) | (0, 1) |
+    | `grouped` | `qwen3_next_80b_a3b.steady` | 1 x 16,384 | 32 of 128 | 128 | a head | (0, 1) |
+
+    forward and forward + backward: the chunked `jax.numpy` form against
+    the form's kernel pair (`dwt_gdr_*` / `dwt_kda_*`) at several (heads,
+    chunks) a grid step, each with the kernel route's largest relative
+    distance from the chunked form; first the tile solve ALONE (`_solve`
+    over the shape's head-tiles, one kernel), in us a tile.  Every line
+    carries `solve_rounds(chunk)`: which of the solve's rounds ran on the
+    vector units and which on the MXU (PERF.md section 6: PR 48, PR 58,
+    PR 69)."""
     for form in forms:
-        plans, shape = DELTA_FORMS[form]
+        plans, shape, channel, beta_top = DELTA_FORMS[form]
         _probe_delta_form(form, plans, (shapes or {}).get(form, shape),
-                          interpret)
+                          channel, beta_top, interpret)
 
 
-def _probe_delta_form(form, plans, shape, interpret):
+def _probe_solve_alone(tiles, hb, chunk, interpret):
+    """`ops/delta_rule._solve` over `tiles` (128 x 128) tiles of two
+    chunks, `hb` a grid step as the pairs take a block of heads: the
+    device's us a tile."""
+    from jax.experimental import pallas as pl
+
+    from dlrover_wuqiong_tpu.ops import delta_rule as dr
+
+    size = dr._ROWS
+
+    def kernel(low_ref, out_ref):
+        _, strict, solve = dr._masks(chunk, size // chunk)
+        for h in range(hb):
+            out_ref[h] = dr._solve(jnp.where(strict, low_ref[h], 0.0), solve)
+
+    block = pl.BlockSpec((hb, size, size), lambda i: (i, 0, 0))
+    fn = jax.jit(lambda low: pl.pallas_call(
+        kernel, grid=(tiles // hb,), in_specs=[block], out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(low.shape, jnp.float32),
+        interpret=interpret, name="dwt_solve_alone")(low))
+    low = 0.1 * jax.random.normal(jax.random.PRNGKey(0), (tiles, size, size))
+    ms = _device_ops_ms(fn, low, top=1).get("dwt_solve_alone", 0.0)
+    return round(1e3 * ms / tiles, 4)
+
+
+def _probe_delta_form(form, plans, shape, channel, beta_top, interpret):
     from dlrover_wuqiong_tpu.ops import delta_rule as dr
 
     b, t, h, dk, dv, chunk = shape
-    channel = form == "channel"
+    rounds = dr.solve_rounds(chunk)
+    tiles, hb = b * h * t // dr._ROWS, dr._heads_block(h)
+    _emit_raw({"probe": "delta", "form": form, "what": "solve_alone",
+               "tiles": tiles, "heads_a_step": hb, "solve_rounds": rounds,
+               "us_a_tile": _probe_solve_alone(tiles, hb, chunk, interpret)})
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     q = jax.random.normal(ks[0], (b, t, h, dk))
     k = jax.random.normal(ks[1], (b, t, h, dk))
@@ -1083,8 +1128,7 @@ def _probe_delta_form(form, plans, shape, interpret):
             jax.random.normal(ks[3], (b, t, h, dk)) - 2.0)
     else:
         g = -0.2 * jax.nn.softplus(jax.random.normal(ks[3], (b, t, h)))
-    beta = (1.0 if channel else 2.0) * jax.nn.sigmoid(
-        jax.random.normal(ks[4], (b, t, h)))
+    beta = beta_top * jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
     d_out = jax.random.normal(ks[5], (b, t, h, dv))
     args = (q, k, v, g, beta)
 
@@ -1109,6 +1153,7 @@ def _probe_delta_form(form, plans, shape, interpret):
         for label, f in ((what, jax.jit(fn)), (what + "_fwd_bwd", both(fn))):
             _emit_raw({"probe": "delta", "form": form, "what": label,
                        "heads_and_chunks_a_step": plan,
+                       "solve_rounds": rounds if plan else None,
                        "off_o_dq_dk_dv_dg_dbeta": [round(x, 6) for x in off],
                        "device_ops_ms": _device_ops_ms(f, *args, top=6)})
 
