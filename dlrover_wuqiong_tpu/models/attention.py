@@ -111,13 +111,21 @@ def collect_attention_stats(intermediates) -> dict:
     that hold a kept pair — data, counted from the step's own choice —
     beside the causal ones; `attn_sparse_tiles_run`, the tiles the
     implementation computes; and `index_kl`, the layers' mean KL term
-    (`collect_attention_aux_loss` is what joins the loss)."""
+    (`collect_attention_aux_loss` is what joins the loss).  Of the
+    layers under block-diffusion's mask (`LlamaConfig.
+    attn_block_diffusion`): `attn_bd_tiles_run` and `attn_bd_tiles_live`,
+    the score tiles the route computes and those that hold a kept pair,
+    `attn_bd_pairs_kept` and `attn_bd_pairs_computed`
+    (`ops/block_attention.bd_tile_count`)."""
     stats = {}
     for under, names in (
             ("attn_tiles", ("attn_tiles_window", "attn_tiles_causal")),
             ("attn_pairs", ("attn_pairs_kept", "attn_pairs_computed")),
-            ("attn_lanes", ("attn_lanes_run", "attn_lanes_model"))):
-        pairs = [v.reshape(-1, 2) for v in sown(intermediates, under)]
+            ("attn_lanes", ("attn_lanes_run", "attn_lanes_model")),
+            ("attn_bd", ("attn_bd_tiles_run", "attn_bd_tiles_live",
+                         "attn_bd_pairs_kept", "attn_bd_pairs_computed"))):
+        pairs = [v.reshape(-1, len(names))
+                 for v in sown(intermediates, under)]
         if pairs:
             with jax.named_scope(under):  # the sum's copies get an owner
                 stats.update(zip(names, jnp.concatenate(pairs).sum(0)))

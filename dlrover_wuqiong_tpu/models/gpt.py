@@ -242,3 +242,44 @@ def _ce_bwd(ignore_index, res, g):
 
 
 _ce.defvjp(_ce_fwd, _ce_bwd)
+
+
+def weighted_cross_entropy(logits, targets, weights):
+    """sum_i weights_i * -log softmax(logits_i)[targets_i] / (number of
+    tokens): the mean over ALL the tokens of a token cross-entropy each
+    weighs by its own float32 weight (a diffusion objective's m / t; 0
+    leaves a token out).  `cross_entropy_loss`'s float32 statistics and
+    its custom VJP — lse (B, T) in the forward, (softmax - onehot) *
+    weight as one fused expression in the backward, no (B, T, V) float32
+    array in either — under the same `loss` scope.  The weights are
+    data: no cotangent reaches them."""
+    return _wce(logits, targets, jax.lax.stop_gradient(weights))
+
+
+@jax.custom_vjp
+def _wce(logits, targets, weights):
+    return _wce_fwd(logits, targets, weights)[0]
+
+
+@jax.named_scope("loss")
+def _wce_fwd(logits, targets, weights):
+    target_logits = jnp.take_along_axis(
+        logits, targets[..., None], axis=-1).squeeze(-1)
+    lse = jax.scipy.special.logsumexp(
+        logits.astype(jnp.float32), axis=-1)
+    nll = lse - target_logits.astype(jnp.float32)
+    loss = (nll * weights).sum() / weights.size
+    return loss, (logits, targets, weights, lse)
+
+
+@jax.named_scope("loss")
+def _wce_bwd(res, g):
+    logits, targets, weights, lse = res
+    scale = (g * weights / weights.size).astype(jnp.float32)[..., None]
+    p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=jnp.float32)
+    dlogits = ((p - onehot) * scale).astype(logits.dtype)
+    return dlogits, None, jnp.zeros_like(weights)
+
+
+_wce.defvjp(_wce_fwd, _wce_bwd)

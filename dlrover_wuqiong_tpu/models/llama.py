@@ -22,7 +22,10 @@ keeps the top `attn_index_topk`, and `ops/sparse_attention.py` runs the
 softmax over that set and the indexer's KL term (models/keye.py's
 layers); tables with a batch axis (`mrope_tables`: M-RoPE under explicit
 positions, the sections the MODEL's to name) rotate each sequence by its
-own angles.
+own angles.  `attn_block_diffusion` (a block length L) makes the rows
+TWO copies of a sequence, `[clean ; noised]`, under block-diffusion's
+static mask (`ops/block_attention.py`; models/sdar.py's layers): the
+tables then carry a row a POSITION, each copy's own 0 .. T-1.
 
 Parity: the reference's flagship workloads are GLM/Llama-class LMs via atorch
 (`BASELINE.json` configs: Llama-3 8B auto_accelerate, Llama-3 70B Megatron
@@ -113,6 +116,12 @@ class LlamaConfig:
     attn_index_heads: int = 0
     attn_index_dim: int = 0
     attn_index_loss_weight: float = 1.0
+    # block-diffusion's mask over rows that are `[clean ; noised]`, two
+    # copies of one sequence in blocks of this many tokens: a clean query
+    # sees the clean blocks up to its own, a noised one the clean blocks
+    # BEFORE its own and its own noised block (ops/block_attention.py);
+    # 0 = one copy, causal
+    attn_block_diffusion: int = 0
 
     @classmethod
     def nano(cls):
@@ -382,8 +391,10 @@ class LlamaAttention(nn.Module):
         # nothing here cuts them to heads or repeats them
         # (models/attention.py); every other call cuts, before the
         # rotation, and repeats the kv heads, as it always did
-        direct = cfg.use_flash_attention and not cfg.attn_index_topk \
-            and goes_direct(cfg, cfg.num_heads, hd, T)
+        # (block-diffusion's entry takes that layout on both its routes)
+        direct = bool(cfg.attn_block_diffusion) or (
+            cfg.use_flash_attention and not cfg.attn_index_topk
+            and goes_direct(cfg, cfg.num_heads, hd, T))
         if not direct:
             q = q.reshape(B, T, cfg.num_heads, hd)
             k = k.reshape(B, T, cfg.num_kv_heads, hd)
@@ -431,6 +442,31 @@ class LlamaAttention(nn.Module):
             self.sow("intermediates", "attn_sparse", counted)
             return dense(cfg, C, "o_proj", use_bias=False)(
                 y.reshape(B, T, cfg.num_heads * hd))
+        if cfg.attn_block_diffusion:
+            from ..ops.block_attention import (
+                bd_route,
+                bd_tile_count,
+                block_diffusion_attention,
+            )
+            from .attention import softmax_scale
+
+            if cfg.attn_window or cfg.attn_gate or cfg.attn_out_gate \
+                    or cfg.attn_index_topk:
+                raise ValueError("attn_block_diffusion beside a window, a "
+                                 "gate or a learned choice of keys: no "
+                                 "layer has asked for both")
+            length = cfg.attn_block_diffusion
+            route = bd_route(T // 2, length, cfg.num_heads,
+                             cfg.num_kv_heads, hd, cfg.mesh)
+            # counted, not timed (static numbers): the plan's tiles and
+            # pairs of every head and sequence of this layer
+            self.sow("intermediates", "attn_bd", B * cfg.num_heads
+                     * jnp.asarray(bd_tile_count(T // 2, length, route),
+                                   jnp.float32))
+            y = block_diffusion_attention(
+                q, k, v, cfg.num_heads, cfg.num_kv_heads, length,
+                softmax_scale(cfg), cfg.mesh)
+            return dense(cfg, C, "o_proj", use_bias=False)(y)
         how, rep = kv_route(cfg.num_heads, cfg.num_kv_heads, hd)
         if rep > 1 and not (direct and how == "indexed"):  # GQA: repeat
             k, v = (jnp.repeat(t.reshape(B, T, cfg.num_kv_heads, hd), rep,

@@ -10,9 +10,22 @@ value is, by registering one function of the collection:
                   scalar}): `term` joins the loss, the scalars the metrics
     @param_steps  intermediates -> a tree over part of `params`: steps on
                   variables the optimizer leaves alone ({} for none)
+    @objective    (intermediates, batch, logits) -> None | scalar: the
+                  OBJECTIVE itself, where a model sowed its own targets
+                  and weights — the scalar that stands where the
+                  next-token `cross_entropy_loss(logits, batch["labels"])`
+                  stands, `collect`'s `ce`.  The contract: None for a
+                  model that sowed none of it (every registrant sees every
+                  model's collection); float32 statistics over the
+                  logits' own dtype and a VJP that holds no (b, T, V)
+                  float32 array, as the cross-entropy's; at most ONE
+                  registrant answers a model (`objective_of` refuses
+                  two); terms and counters join it as they join the
+                  cross-entropy
 
 and `collect` is the one place the step asks (`trainer/train_step.
-make_lm_loss`; `parallel/pipeline.py` for what a pipelined block adds).
+make_lm_loss`, which asks `objective_of` first; `parallel/pipeline.py`
+for what a pipelined block adds).
 Registration happens because the module that sows is imported by whoever
 built the model — a value cannot be sown by a module that was never
 imported — so this file imports no model file and `collect` needs no
@@ -26,7 +39,7 @@ from __future__ import annotations
 
 import jax
 
-_COUNTERS, _TERMS, _STEPS = {}, {}, {}  # qualified name -> function
+_COUNTERS, _TERMS, _STEPS, _OBJECTIVES = {}, {}, {}, {}  # by qualified name
 # float32 addition is not associative and the pinned losses read the last
 # bit: ((ce + the MoE layers' aux) + the indexers' KL) + the second
 # prediction's, whatever order the models' files were imported in; a term
@@ -52,6 +65,10 @@ def param_steps(fn):
     return _register(_STEPS, fn)
 
 
+def objective(fn):
+    return _register(_OBJECTIVES, fn)
+
+
 def sown(intermediates, name: str):
     """The leaves sown under `name`, whatever module path they sit on."""
     for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
@@ -70,6 +87,18 @@ def _merge(into: dict, more: dict) -> None:
             raise ValueError(f"two registrants of models/sown.py give {key!r}")
         else:
             into[key] = value
+
+
+def objective_of(intermediates, batch, logits):
+    """The registered objective's scalar for one forward pass, or None:
+    the step then takes the next-token cross-entropy, as it always did."""
+    given = [(key, out) for key in sorted(_OBJECTIVES)
+             if (out := _OBJECTIVES[key](intermediates, batch, logits))
+             is not None]
+    if len(given) > 1:
+        raise ValueError(f"two objectives of models/sown.py answer one "
+                         f"model: {[key for key, _ in given]}")
+    return given[0][1] if given else None
 
 
 def collect(intermediates, batch, ce):

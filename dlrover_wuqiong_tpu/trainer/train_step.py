@@ -320,22 +320,26 @@ def train_state_shardings(state_like: TrainState, planner: ShardingPlanner,
 def make_lm_loss(model_apply: Callable) -> Callable:
     """Standard causal-LM loss over a batch dict {input_ids, labels}.
 
-    To the cross-entropy it adds every term the model's layers sowed for
-    the loss, and `loss_fn.with_stats(params, batch) -> (loss, stats)` is
+    Where the model sowed an objective of its own (targets and weights:
+    `models/sown.objective`), that scalar stands where the cross-entropy
+    against `batch["labels"]` stands.  To it are added every term the
+    model's layers sowed for the loss, and `loss_fn.with_stats(params, batch) -> (loss, stats)` is
     the same loss with what the layers counted beside it ({} for a model
     that sows nothing): `models/sown.collect` is the one place that is
     asked, and the file that sows a value says there what it is.
     `make_train_step` differentiates `with_stats` and returns the
     counters in the step's metrics."""
     from ..models.gpt import cross_entropy_loss
-    from ..models.sown import collect
+    from ..models.sown import collect, objective_of
 
     def with_stats(params, batch):
         logits, updates = model_apply(
             {"params": params}, batch["input_ids"],
             mutable=["intermediates"])
-        ce = cross_entropy_loss(logits, batch["labels"])
         inter = updates.get("intermediates", {})
+        ce = objective_of(inter, batch, logits) if inter else None
+        if ce is None:
+            ce = cross_entropy_loss(logits, batch["labels"])
         return collect(inter, batch, ce) if inter else (ce, {})
 
     def loss_fn(params, batch):

@@ -35,6 +35,7 @@ from dlrover_wuqiong_tpu.models import (
     nemotron_h,
     olmo_hybrid,
     qwen3_next,
+    sdar,
     smallthinker,
 )
 from dlrover_wuqiong_tpu.models import sown as handed
@@ -60,7 +61,7 @@ def layers(*sown_by_layer):
 def registry(monkeypatch):
     """The registrants of this process on tables of the test's own: what
     a test registers, or reorders, is gone with it."""
-    for table in ("_COUNTERS", "_TERMS", "_STEPS"):
+    for table in ("_COUNTERS", "_TERMS", "_STEPS", "_OBJECTIVES"):
         monkeypatch.setattr(handed, table, dict(getattr(handed, table)))
     return handed
 
@@ -103,6 +104,8 @@ REGISTRANTS = {
         "mtp_loss_weight": (jnp.asarray(0.3, F32),)}),
     "collect_shortconv_stats": (lfm2, "counters", layers(
         dict(shortconv_calls=[1, 1]), dict(shortconv_calls=[0, 1]))),
+    "collect_diffusion_stats": (sdar, "counters", {
+        "diffusion_noise": (jnp.asarray([0.5, 0.97], F32),)}),
 }
 
 
@@ -134,12 +137,16 @@ def test_a_registrant_is_reached_through_collect(name):
 
 
 def test_the_ten_are_all_that_is_registered():
+    """Eleven since PR 70 (the diffusion's counters), and ONE objective."""
     assert {key.rsplit(".", 1)[1] for table in (
         handed._COUNTERS, handed._TERMS, handed._STEPS) for key in table} \
         == (set(REGISTRANTS) - {"collect_moe_aux_loss"}) | {"moe_aux_term"}
     assert all(key.startswith("dlrover_wuqiong_tpu.models.")
-               for table in (handed._COUNTERS, handed._TERMS, handed._STEPS)
+               for table in (handed._COUNTERS, handed._TERMS, handed._STEPS,
+                             handed._OBJECTIVES)
                for key in table)
+    assert list(handed._OBJECTIVES) == [
+        "dlrover_wuqiong_tpu.models.sdar.diffusion_objective"]
 
 
 # ------------- (B) every model class of the cells: the keys the step gets
@@ -351,6 +358,40 @@ def test_two_registrants_may_not_give_one_name(registry):
         registry.collect(REGISTRANTS["collect_moe_stats"][2], BATCH, CE)
 
 
+def test_a_registered_objective_stands_where_the_cross_entropy_stood(
+        registry):
+    """`@objective`: None from every registrant keeps the next-token
+    cross-entropy to the bit (the program every other model traced); one
+    answer replaces it and the terms join it as they joined the
+    cross-entropy."""
+    import flax.linen as nn
+
+    class Sower(nn.Module):
+        @nn.compact
+        def __call__(self, idx):
+            x = nn.Embed(16, 8, name="embed")(idx)
+            self.sow("intermediates", "own_target", idx)
+            return nn.Dense(16, name="head")(x)
+
+    model = Sower()
+    batch = {"input_ids": BATCH["labels"], "labels": BATCH["labels"][:, ::-1]}
+    params = model.init(jax.random.PRNGKey(0), batch["input_ids"])["params"]
+    loss_fn = make_lm_loss(model.apply)
+    logits = model.apply({"params": params}, batch["input_ids"])
+    assert bits(loss_fn(params, batch)) == bits(
+        gpt.cross_entropy_loss(logits, batch["labels"]))
+
+    @registry.objective
+    def own_objective(intermediates, batch, logits):
+        targets = list(registry.sown(intermediates, "own_target"))
+        return gpt.cross_entropy_loss(logits, targets[0]) if targets \
+            else None
+
+    assert registry.objective_of({}, batch, logits) is None
+    assert bits(make_lm_loss(model.apply)(params, batch)) == bits(
+        gpt.cross_entropy_loss(logits, batch["input_ids"]))
+
+
 # ----------------------------------------- (E) the arrow points one way
 
 def _imports(path):
@@ -382,7 +423,10 @@ def test_the_step_asks_models_for_the_loss_and_for_collect_alone(package):
         assert not re.search(r"collect_[a-z_]+", text), path
         assert package != "trainer" or "index_kl" not in text, path
     asks = {("models.gpt", "cross_entropy_loss"), ("models.sown", "collect")}
-    assert found == {"trainer": {"train_step.py": asks},
+    # since PR 70 the step asks for a model's own objective first, and
+    # through models/sown.py alone
+    own = {("models.sown", "objective_of")}
+    assert found == {"trainer": {"train_step.py": asks | own},
                      "parallel": {"pipeline.py": asks | PIPELINE_STACKS}
                      }[package]
 
@@ -390,7 +434,7 @@ def test_the_step_asks_models_for_the_loss_and_for_collect_alone(package):
 def test_sown_imports_nothing_of_the_package_and_no_model_keeps_a_copy():
     path = PACKAGE / "models" / "sown.py"
     assert {module for module, _ in _imports(path)} == {"__future__", "jax"}
-    assert len(path.read_text().splitlines()) < 100
+    assert len(path.read_text().splitlines()) < 140  # four registrations
     for path in sorted((PACKAGE / "models").glob("*.py")):
         assert "_sown" not in path.read_text(), path
         if path.name != "sown.py":
