@@ -457,7 +457,8 @@ class LlamaAttention(nn.Module):
                                  "layer has asked for both")
             length = cfg.attn_block_diffusion
             route = bd_route(T // 2, length, cfg.num_heads,
-                             cfg.num_kv_heads, hd, cfg.mesh)
+                             cfg.num_kv_heads, hd, cfg.mesh,
+                             q.dtype.itemsize)
             # counted, not timed (static numbers): the plan's tiles and
             # pairs of every head and sequence of this layer
             self.sow("intermediates", "attn_bd", B * cfg.num_heads

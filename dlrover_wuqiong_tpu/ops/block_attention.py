@@ -30,21 +30,21 @@ causal kernels do (`_work`): pieces below the staircase run unmasked,
 the pieces it crosses under a mask of (row block - key block) between
 two static bounds, pieces above it not at all.
 
-Three kernels, every name under `dwt_fa_` so that every reader of the
-attention kernels' time takes them: `dwt_fa_bd_fwd` (a kv head's group
-of query heads, or a part of it, a step; the list ordered by query
-block), `dwt_fa_bd_bwd_dq` (the same order) and `dwt_fa_bd_bwd_dkv` (the
-list ordered by KEY block, a kv head's whole group a step, so dk and dv
-leave the kernel summed over the group).  The layout is the projections'
-own: q (b, 2T, H*d), k and v (b, 2T, KV*d), grouped heads indexed and
-never repeated.
+Two kernels, every name under `dwt_fa_` so that every reader of the
+attention kernels' time takes them: `dwt_fa_bd_fwd` (a part of a kv
+head's group of query heads a step; the list ordered by query block) and
+`dwt_fa_bd_bwd`, the backward as ONE sweep (the list ordered by KEY
+block; p recomputed once, then dv, dk and dq from it, their sums whole in
+VMEM: `ops/sparse_attention.py`'s form).  The layout is the projections'
+own: q (b, 2T, H*d), k and v (b, 2T, KV*d), grouped heads indexed.
 
 `bd_route` says which route a call takes from what it can observe, no
 knob: the kernels on one TPU device at heads of whole 128-lane slabs, a
 copy a whole number of blocks and L a power of two that divides the
-tile; the dense `jax.numpy` lines (`_plain`) everywhere else — every CPU
-run, and the kernels' oracle.  `bd_tile_count` is the counter of what a
-route computes, as `causal_tile_count` is of the causal kernels.
+tile, and one head's whole-length dq fits VMEM; the dense `jax.numpy`
+lines (`_plain`) everywhere else — every CPU run, and the kernels'
+oracle.  `bd_tile_count` is the counter of what a route computes, as
+`causal_tile_count` is of the causal kernels.
 
 Parity: none — the reference trains next-token models only.
 """
@@ -71,16 +71,16 @@ TILE = 512     # side of the score tiles a block on the staircase is cut
 # into, and what a block length has to divide (`models/sdar.py` asks)
 _GROUP_LANES = 1024  # the most lanes of q a step's query heads span
 # (a step's preferred q rows and keys, the most query heads of a group it
-# takes) of the forward and of the two backward kernels, where the copy
-# is whole such blocks (else `TILE`).  Measured on the chip at the cell's
-# shape, 1 x 2 x 8,192 x 32/4 x 128, ms a call (PERF.md section 6, PR 70;
-# `.scratch/probe70.py`-style wall clock): forward (1024, 4) 20.85,
-# (1024 whole-block mask, 4) 23.20, (512, 4) 13.53, (512, 8) 11.39,
-# **(1024, 2) 8.33**; dq + dk/dv (1024, 4) 51.33, (1024, 2) 39.16,
-# (512, 4) 25.98, **(512, 8) 25.36**.  What a step holds as instructions
-# decides: four static variants of a (1024 x 1024) step unrolled for four
-# heads ran at under half the speed of the same step for two.
-_STEPS = {"forward": (1024, 2), "backward": (512, 8)}
+# takes), where the copy is whole such blocks (else `TILE`).  Ms a call on
+# the chip at the cell's shape, 1 x 2 x 8,192 x 32/4 x 128, by device time
+# (`tools/perf_probe.py attn_bd`; PERF.md section 6, PR 71): forward (512,
+# 4) 13.44, (1024, 1) 9.38, **(1024, 2) 8.25**, (1024, 4) 20.77, (2048, 1)
+# 8.60; backward (512, 2) 17.98, (1024, 1) 17.08, **(1024, 2) 16.59**,
+# (2048, 1) 35.53, and FOUR heads, whose sums pass `_VMEM_LIMIT`, with dk
+# and dv summed outside: (512, 4) 17.09 + 0.52, (1024, 4) 35.81.  A step's
+# instructions decide: four static variants of (1024 x 1024) unrolled for
+# four heads, or of (2048 x 2048) for one, run at half (1024, 2)'s speed.
+_STEPS = {"forward": (1024, 2), "backward": (1024, 2)}
 _FAR = 1 << 30  # a bound no difference of blocks reaches
 
 # the variants a grid step runs under: static programs, chosen by the plan
@@ -215,14 +215,29 @@ def bd_tile_count(t: int, block_length: int, route: str = "kernel",
     return run, live, t * t + t * block_length, run * side * side
 
 
+def _bwd_vmem(heads: int, s: int, d: int, itemsize: int, block: int) -> int:
+    """Bytes of VMEM the backward holds at `heads` query heads and (block
+    x block) a step: the whole-length float32 sums with their two output
+    buffers (the unit's dq, the kv head's dk and dv), the step's operands
+    twice (lse's and delta's rows on 8 sublanes), three float32 score
+    blocks.  MiB reckoned | the least Mosaic compiles under at the cell's
+    shape: (1024, 2) 79 | 75, (1024, 1) 62 | 57, (512, 4) 102 | 101."""
+    step = 4 * (itemsize * block * d * (heads + 1) + 4 * 8 * heads * block)
+    return s * d * (heads + 2) * (4 + 2 * itemsize) + step + 12 * block ** 2
+
+
 def bd_route(t: int, block_length: int, n_head: int, n_kv: int, d: int,
-             mesh=None) -> str:
+             mesh=None, itemsize: int = 2) -> str:
     """"kernel" or "plain" for a call over copies of `t` tokens, from
-    what the call can observe."""
+    what the call can observe; "plain" too where not ONE head's whole dq
+    fits VMEM beside its kv head's dk and dv (`_bwd_vmem`: past 2 x
+    14,336 positions at heads of 128 and operands of two bytes)."""
+    fit = _fit(t, block_length, None, None, "backward")
     ok = (mosaic.kernel_site(mesh) in _SITES and d % mosaic.LANES == 0
           and n_head % n_kv == 0
           and block_length & (block_length - 1) == 0
-          and _fit(t, block_length, None, None) is not None)
+          and fit is not None
+          and _bwd_vmem(1, 2 * t, d, itemsize, fit[0]) <= _VMEM_LIMIT)
     return "kernel" if ok else "plain"
 
 
@@ -327,76 +342,68 @@ def _ds_transposed(q, k, v, do, lse, delta, mask, scale: float):
     return pT, pT * (_dot_t(v, do) - delta) * scale
 
 
-def _dq_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               dq_ref, dq_scr, *, n: int, heads: int, width: int,
-               scale: float, tile: int, shift: int):
-    """dq of one entry of the forward's plan, summed in float32 scratch
-    over a query block's steps."""
-    step = pl.program_id(2)
+def _bwd_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, n: int,
+                heads: int, parts: int, width: int, scale: float, tile: int,
+                shift: int):
+    """dq, dk and dv of one entry of the plan BY KEYS from ONE p: five
+    products and one exponential a head and piece.  Every sum rests in
+    float32 scratch over the WHOLE 2T rows, its output block the whole
+    length, written once: dq at the unit's last step; dk and dv, sums
+    over the kv head's `parts` units, rounded once at the group's last."""
+    unit, step = pl.program_id(1) % parts, pl.program_id(2)
     block = q_ref.shape[1]
+    row0, key0 = plan_ref[step] * block, plan_ref[n + step] * block
 
-    @pl.when(plan_ref[3 * n + step] == 1)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
+    def each_block(when, body):  # a loop, not 2T / block copies of the code
+        def _one(r, carry):
+            body(pl.ds(pl.multiple_of(r * block, block), block))
+            return carry
 
-    def _inner(variant):
-        for q0, q1, pieces in _work(variant, block, tile, False):
-            for k0, k1, bounds in pieces:
-                k, v = k_ref[0, k0:k1], v_ref[0, k0:k1]
-                mask = None if bounds is None else _keep(
-                    q1 - q0, k1 - k0, bounds, shift, True)
-                for a in range(heads):
-                    lanes = slice(a * width, (a + 1) * width)
-                    _, dsT = _ds_transposed(
-                        q_ref[0, q0:q1, lanes], k, v, do_ref[0, q0:q1, lanes],
-                        lse_ref[0, a, :, q0:q1], delta_ref[0, a, :, q0:q1],
-                        mask, scale)
-                    dq_scr[a, q0:q1] += _dot_c0(dsT.astype(k.dtype), k)
+        @pl.when(when)
+        def _():
+            jax.lax.fori_loop(0, dq_scr.shape[0] // block, _one, 0)
 
-    _by_variant(plan_ref, n, _inner)
+    def _open_unit(rows):
+        dq_scr[rows] = jnp.zeros((block, heads * width), jnp.float32)
 
-    @pl.when(plan_ref[4 * n + step] == 1)
-    def _finalize():
-        for a in range(heads):
-            dq_ref[0, :, a * width:(a + 1) * width] = dq_scr[a].astype(
-                dq_ref.dtype)
+    def _open_group(rows):
+        dk_scr[rows] = jnp.zeros((block, width), jnp.float32)
+        dv_scr[rows] = jnp.zeros((block, width), jnp.float32)
 
-
-def _dkv_kernel(plan_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, n: int, heads: int,
-                width: int, scale: float, tile: int, shift: int):
-    """dk and dv of one entry of the plan BY KEYS: a kv head's whole
-    group of `heads` query heads against one block of its keys, summed in
-    float32 scratch over the group and over the key block's steps."""
-    step = pl.program_id(2)
-    block = q_ref.shape[1]
-
-    @pl.when(plan_ref[3 * n + step] == 1)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+    each_block(step == 0, _open_unit)
+    each_block((step == 0) & (unit == 0), _open_group)
 
     def _inner(variant):
         for k0, k1, pieces in _work(variant, block, tile, True):
             k, v = k_ref[0, k0:k1], v_ref[0, k0:k1]
+            keys = pl.ds(pl.multiple_of(key0 + k0, tile), k1 - k0)
             for q0, q1, bounds in pieces:
                 mask = None if bounds is None else _keep(
                     q1 - q0, k1 - k0, bounds, shift, True)
+                rows = pl.ds(pl.multiple_of(row0 + q0, tile), q1 - q0)
                 for a in range(heads):
                     lanes = slice(a * width, (a + 1) * width)
                     q, do = q_ref[0, q0:q1, lanes], do_ref[0, q0:q1, lanes]
                     pT, dsT = _ds_transposed(
                         q, k, v, do, lse_ref[0, a, :, q0:q1],
                         delta_ref[0, a, :, q0:q1], mask, scale)
-                    dv_scr[k0:k1] += _dot(pT.astype(do.dtype), do)
-                    dk_scr[k0:k1] += _dot(dsT.astype(q.dtype), q)
+                    dsT = dsT.astype(q.dtype)
+                    dv_scr[keys] += _dot(pT.astype(do.dtype), do)
+                    dk_scr[keys] += _dot(dsT, q)
+                    dq_scr[rows, lanes] += _dot_c0(dsT, k)
 
     _by_variant(plan_ref, n, _inner)
 
-    @pl.when(plan_ref[4 * n + step] == 1)
-    def _finalize():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+    def _close_unit(rows):
+        dq_ref[0, rows] = dq_scr[rows].astype(dq_ref.dtype)
+
+    def _close_group(rows):
+        dk_ref[0, rows] = dk_scr[rows].astype(dk_ref.dtype)
+        dv_ref[0, rows] = dv_scr[rows].astype(dv_ref.dtype)
+
+    each_block(step == n - 1, _close_unit)
+    each_block((step == n - 1) & (unit == parts - 1), _close_group)
 
 
 def _geometry(q, k, n_head: int, n_kv: int, block_length: int, block, tile,
@@ -408,15 +415,17 @@ def _geometry(q, k, n_head: int, n_kv: int, block_length: int, block, tile,
         raise ValueError(f"no block of whole {block_length}-token blocks "
                          f"divides a copy of {s // 2}, or k is no "
                          f"{n_kv} heads of {d}")
+    held = _bwd_vmem if which == "backward" else lambda *_: 0
     heads = heads or max(g for g in range(1, _STEPS[which][1] + 1)
-                         if rep % g == 0 and g * d <= _GROUP_LANES)
+                         if rep % g == 0 and g * d <= _GROUP_LANES and held(
+                             g, s, d, q.dtype.itemsize, fit[0]) <= _VMEM_LIMIT)
     return b, s, d, rep, fit[0], fit[1], heads
 
 
 def _specs(block: int, d: int, heads: int, rep: int, n: int):
     """(q-like, k-like, row) BlockSpecs of a grid (batch, unit, step):
-    the unit is a part of a kv head's group (`heads` query heads; with
-    `by_keys` the whole group), rows and keys found in the plan."""
+    the unit is a part of a kv head's group (`heads` query heads), rows
+    and keys found in the plan."""
     parts = rep // heads
 
     def rows(b, u, t, plan):
@@ -464,45 +473,35 @@ def _forward(q, k, v, n_head: int, n_kv: int, block_length: int,
 def _backward(q, k, v, o, lse, do, n_head: int, n_kv: int,
               block_length: int, scale: float, block=None, tile=None,
               heads=None, interpret: bool = False):
-    """(dq, dk, dv) in the operands' own layouts."""
+    """(dq, dk, dv) in the operands' own layouts: ONE sweep over the plan
+    by keys, `heads` query heads a grid step, a kv head's units in turn."""
     b, s, d, rep, block, tile, heads = _geometry(
         q, k, n_head, n_kv, block_length, block, tile, heads, "backward")
-    shift = block_length.bit_length() - 1
+    parts = rep // heads
     # delta = rowsum(dO . O) a head, queries in lanes as lse is
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
         b, s, n_head, d).sum(-1).transpose(0, 2, 1)[:, :, None]
-    params = _compiler_params("parallel", "parallel", "arbitrary",
-                              vmem_limit=_VMEM_LIMIT)
-    table, n = bd_plan(s // 2, block)
+    table, n = bd_plan(s // 2, block, True)
     rows, keys, row = _specs(block, d, heads, rep, n)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, n=n, heads=heads, width=d, scale=scale,
-                          tile=tile, shift=shift),
+    # the sums' blocks: the unit's and the kv head's WHOLE length
+    dq_spec = pl.BlockSpec((1, s, heads * d), lambda i, u, t, p: (i, 0, u))
+    dkv_spec = pl.BlockSpec((1, s, d), lambda i, u, t, p: (i, 0, u // parts))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, n=n, heads=heads, parts=parts, width=d,
+                          scale=scale, tile=tile,
+                          shift=block_length.bit_length() - 1),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, n_head // heads, n),
-            in_specs=[rows, keys, keys, rows, row, row], out_specs=rows,
-            scratch_shapes=[pltpu.VMEM((heads, block, d), jnp.float32)]),
-        out_shape=_out_struct(q.shape, q.dtype, q),
-        compiler_params=params, interpret=interpret,
-        name="dwt_fa_bd_bwd_dq",
-    )(jnp.asarray(table), q, k, v, do, lse, delta)
-    table, n = bd_plan(s // 2, block, True)
-    rows, keys, row = _specs(block, d, rep, rep, n)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, n=n, heads=rep, width=d, scale=scale,
-                          tile=tile, shift=shift),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, n_kv, n),
             in_specs=[rows, keys, keys, rows, row, row],
-            out_specs=(keys, keys),
-            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
-                            pltpu.VMEM((block, d), jnp.float32)]),
-        out_shape=(_out_struct(k.shape, k.dtype, k),
-                   _out_struct(v.shape, v.dtype, v)),
-        compiler_params=params, interpret=interpret,
-        name="dwt_fa_bd_bwd_dkv",
+            out_specs=(dq_spec, dkv_spec, dkv_spec),
+            scratch_shapes=[pltpu.VMEM((s, lanes), jnp.float32)
+                            for lanes in (heads * d, d, d)]),
+        out_shape=tuple(_out_struct(x.shape, x.dtype, x) for x in (q, k, v)),
+        compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary",
+                                         vmem_limit=_VMEM_LIMIT),
+        interpret=interpret,
+        name="dwt_fa_bd_bwd",
     )(jnp.asarray(table), q, k, v, do, lse, delta)
-    return dq, dk, dv
 
 
 # static plan: behind `jax.jit` a kernel body is traced and lowered to
@@ -566,6 +565,7 @@ def block_diffusion_attention(q, k, v, n_head: int, n_kv: int,
                          f"blocks of {block_length}")
     d = lanes // n_head
     scale = float(sm_scale) if sm_scale else 1.0 / math.sqrt(d)
-    if bd_route(s // 2, block_length, n_head, n_kv, d, mesh) == "kernel":
+    if bd_route(s // 2, block_length, n_head, n_kv, d, mesh,
+                q.dtype.itemsize) == "kernel":
         return _kernels(q, k, v, n_head, n_kv, block_length, scale)
     return _plain(q, k, v, n_head, n_kv, block_length, scale)

@@ -3,8 +3,9 @@ by the TPU's own compiler for a DESCRIBED v5e (no chip attached), as
 tests/test_keye_compile.py does for Keye's — whose helpers these tests
 use.
 
-Tier-1 compiles ONE layer's attention — the three kernels of
-`ops/block_attention.py`, forward and backward — at the cell's shape
+Tier-1 compiles ONE layer's attention — the two kernels of
+`ops/block_attention.py`, the forward and the one backward sweep — at
+the cell's shape
 (under half a minute).  The WHOLE step is `slow` (tier-2, `-m slow`):
 ONE module-scoped fixture compiles it, once a run.  Run `python -m
 pytest tests/test_sdar_compile.py -m slow` after a change to
@@ -33,7 +34,7 @@ from dlrover_wuqiong_tpu.ops import block_attention as ba
 from dlrover_wuqiong_tpu.telemetry.memory import compiled_memory
 
 B, T, H, KV, D, L = 1, 8192, 32, 4, 128, 4
-KERNELS = ("dwt_fa_bd_fwd", "dwt_fa_bd_bwd_dq", "dwt_fa_bd_bwd_dkv")
+KERNELS = ("dwt_fa_bd_fwd", "dwt_fa_bd_bwd")  # no `_dq`, no `_dkv`
 LIVE_GB = 13.04  # the step's described reading at the rung taken
 
 
@@ -50,12 +51,14 @@ def test_one_layers_block_attention_compiles_at_the_cells_shape(
         topo, on_tpu, _no_persistent_cache):
     """The attention of ONE layer over `[clean ; noised]`, forward and
     backward, at 2 x 8,192 positions x 32/4 heads of 128 in bfloat16 on
-    one TPU device: each of the three kernels once, the forward's grid
-    the plan's 80 steps of (1,024 x 1,024) a pair of heads, dq's and the
-    dk/dv sweep's its 288 of (512 x 512) a kv head's group; no (2T)^2
-    array of any type, nothing
-    that holds other ops, temporaries under 0.6 GB (q's, k's and v's
-    gradients, lse and delta)."""
+    one TPU device: each of the two kernels once — the forward and ONE
+    backward sweep, neither of the pair it replaced — both over the
+    plan's 80 steps of (1,024 x 1,024) a pair of heads, the forward's by
+    queries, the backward's by keys with the pair's dq and the kv head's
+    dk and dv whole in VMEM (79 of its 100 MiB reckoned); no (2T)^2
+    array of any type, nothing that holds other ops, temporaries under
+    0.6 GB (lse, delta and its product: dk and dv are summed inside the
+    kernel, no partial sum leaves it)."""
     one = SingleDeviceSharding(topo.devices[0])
     assert ba.bd_route(T, L, H, KV, D) == "kernel"
 
@@ -78,9 +81,11 @@ def test_one_layers_block_attention_compiles_at_the_cells_shape(
     assert f"{2 * T},{2 * T}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
     assert ba._fit(T, L, None, None) == (1024, 512)
-    assert ba._fit(T, L, None, None, "backward") == (512, 512)
-    assert ba.bd_plan(T, 1024)[1] == 80
-    assert ba.bd_plan(T, 512)[1] == ba.bd_plan(T, 512, True)[1] == 288
+    assert ba._fit(T, L, None, None, "backward") == (1024, 512)
+    assert ba.bd_plan(T, 1024)[1] == ba.bd_plan(T, 1024, True)[1] == 80
+    assert ba._bwd_vmem(2, 2 * T, D, 2, 1024) == 83_099_648 \
+        <= ba._VMEM_LIMIT < ba._bwd_vmem(4, 2 * T, D, 2, 1024)
+    assert text.count("s32[400]") >= 2  # both plans: 5 x 80 prefetched
 
 
 def _live_gb(step) -> float:
@@ -111,7 +116,7 @@ def test_sdar_step_holds_its_scopes_kernels_and_a_share_of_experts(
         sdar_step):
     """Every scope the cell's scopes file names is in the compiled step;
     each of the six layers runs the block-masked forward twice (forward,
-    recomputed) and the two backward kernels once, and `dwt_rope` on q
+    recomputed) and the ONE backward kernel once, and `dwt_rope` on q
     and k; no kernel of `ops/flash_attention.py` or
     `ops/sparse_attention.py` is in the step and no (2T)^2 array; a
     share's grouped products run `ops/grouped_matmul.py`'s kernels on
@@ -134,9 +139,7 @@ def test_sdar_step_holds_its_scopes_kernels_and_a_share_of_experts(
         r"%(dwt_(?:fa|idx|rope)\w*?)(?:\.\d+)? = ", text))
     layers = 6
     assert calls.pop("dwt_rope") >= 2 * 2 * layers
-    assert calls == {"dwt_fa_bd_fwd": 2 * layers,
-                     "dwt_fa_bd_bwd_dq": layers,
-                     "dwt_fa_bd_bwd_dkv": layers}
+    assert calls == {"dwt_fa_bd_fwd": 2 * layers, "dwt_fa_bd_bwd": layers}
     assert f"{2 * T},{2 * T}]" not in text
     grouped = _grouped_kernel_calls(text)
     assert grouped and "ragged-dot" not in text

@@ -6,7 +6,7 @@ host readback; iterations chain on carried values.
 A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
-Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|head|
+Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|attn_bd|head|
 model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|delta_kda|sums|hc|conv|gate] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
@@ -27,6 +27,15 @@ x keys), with each one's distance from the slab step's o and lse;
 `--shape b,heads,kv_heads,T,d [--blocks 1024x1024,512x1024] [--heads
 6,3]` runs that sweep alone at one shape (PERF.md section 6, PR 67, has
 the table).
+`attn_bd` is the block-masked attention (`ops/block_attention.py`) at
+SDAR's shape (or `--shape b,heads,kv,t,d`, t a copy's tokens, `--length`
+the block length) by device time: `dwt_fa_bd_fwd` alone and
+`dwt_fa_bd_bwd` alone under every plan of `--blocks 512x512,1024x512`
+(block x tile) x `--heads 1,2,4` (query heads a grid step), in ms a call
+and us a head-tile, with what runs beside the backward kernel (delta)
+and each plan's distance from the first; lines kept under
+`chiprun_out/attn_bd.jsonl` (PERF.md section 6, PR 71, has the table
+`_STEPS`' comment quotes).
 `gmm` reads, from a profiler trace, the device time of each grouped
 product of a chip's share of an expert layer (98,304 rows of which
 6,800 are held in 8 groups) as `ops/grouped_matmul.py`'s kernels and as
@@ -361,6 +370,73 @@ def probe_attn_grouped(shapes=None, blocks=None, heads=None):
                                          - want[0].astype(jnp.float32)).max()),
                 lse_max_diff=float(jnp.abs(got[1] - want[1]).max()),
                 device_ops_ms=ops))
+
+
+BD_SHAPE = (1, 32, 4, 8192, 128)  # `sdar_30b_a3b.steady`'s: b, heads, kv
+# heads, a copy's tokens (2 x that many positions), d
+BD_PLANS = ((512, 512), (1024, 512))  # (block, tile)
+
+
+def probe_attn_bd(shape=None, blocks=None, heads=None, length=4,
+                  interpret=False, out=None):
+    """The block-masked attention (`ops/block_attention.py`) at one shape
+    under several plans — (block, tile) of `blocks` x heads a step of
+    `heads` — by device time from a profiler trace: the forward alone
+    (`dwt_fa_bd_fwd`), then the backward alone over the forward's o and
+    lse (`dwt_fa_bd_bwd`, and beside it every other op of the call:
+    delta), each in ms a call and in us a head and (512 x 512) tile of
+    the plan, with the largest distance of o and of each gradient from
+    the first plan's.  A plan Mosaic refuses is a line with its error.
+    Lines kept under `out` (default `chiprun_out/attn_bd.jsonl`).
+    `--shape b,heads,kv,t,d` (t: a copy's tokens), `--blocks
+    512x512,1024x512`, `--heads 1,2,4`, `--length L`; `interpret`: a
+    rehearsal off the chip, whose times are no device's."""
+    from dlrover_wuqiong_tpu.ops import block_attention as ba
+
+    b, h, kv, t, d = shape or BD_SHAPE
+    ks = jax.random.split(jax.random.PRNGKey(t + h), 4)
+    q, k, v, g = (jax.random.normal(key, (b, 2 * t, n * d), jnp.bfloat16)
+                  for key, n in zip(ks, (h, kv, kv, h)))
+    kw = dict(n_head=h, n_kv=kv, block_length=length, scale=d ** -0.5,
+              interpret=interpret)
+    out = out or os.path.join("chiprun_out", "attn_bd.jsonl")
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    want = None
+    for block, tile in blocks or BD_PLANS:
+        tiles = b * h * ba.bd_tile_count(t, length, "kernel", block, tile)[0] \
+            * (tile / ba.TILE) ** 2
+        for heads_a_step in heads or (1, 2, 4):
+            plan = dict(kw, block=block, tile=tile, heads=heads_a_step)
+            line = {"probe": "attn_bd",
+                    "device": jax.devices()[0].device_kind,
+                    "shape": [b, h, kv, t, d],
+                    "length": length, "block": block, "tile": tile,
+                    "heads": heads_a_step}
+            try:
+                fwd = functools.partial(ba._forward_jit, **plan)
+                o, lse = fwd(q, k, v)
+                bwd = functools.partial(ba._backward_jit, **plan)
+                f_ops = _device_ops_ms(fwd, q, k, v, top=8)
+                b_ops = _device_ops_ms(bwd, q, k, v, o, lse, g, top=8)
+                got = (o,) + tuple(bwd(q, k, v, o, lse, g))
+            except Exception as e:  # noqa: BLE001 — a step Mosaic refuses
+                line["error"] = repr(e)[:300]
+            else:
+                want = want or got
+                f_ms, b_ms = f_ops.get("dwt_fa_bd_fwd", 0.0), b_ops.get(
+                    "dwt_fa_bd_bwd", 0.0)
+                line.update(
+                    fwd_ms=f_ms, bwd_ms=b_ms,
+                    bwd_beside_ms=round(sum(b_ops.values()) - b_ms, 4),
+                    fwd_us_a_head_tile=round(f_ms * 1e3 / tiles, 4),
+                    bwd_us_a_head_tile=round(b_ms * 1e3 / tiles, 4),
+                    off_o_dq_dk_dv=[float(jnp.abs(
+                        x.astype(jnp.float32) - y.astype(jnp.float32)).max())
+                        for x, y in zip(got, want)],
+                    bwd_device_ops_ms=b_ops)
+            _emit_raw(line)
+            with open(out, "a") as f_out:
+                f_out.write(json.dumps(line) + "\n")
 
 
 def probe_attn_sweep():
@@ -1378,7 +1454,8 @@ def probe_gate(heads=(64, 48), tiles=(64, 128, 256, 512), t=16384,
 
 ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "attn_sweep": probe_attn_sweep,
-       "attn_direct": probe_attn_direct, "lib": probe_lib,
+       "attn_direct": probe_attn_direct, "attn_bd": probe_attn_bd,
+       "lib": probe_lib,
        "remat": probe_remat,
        "splash": probe_splash, "dots": probe_dots,
        "head": probe_head, "model": probe_model, "opt": probe_opt,
@@ -1389,6 +1466,16 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "delta_kda": functools.partial(probe_delta, forms=("channel",)),
        "sums": probe_sums, "hc": probe_hc, "conv": probe_conv,
        "gate": probe_gate}
+
+
+def _sweep_of(name: str, sweep: dict) -> dict:
+    """The sweep flags a probe takes: `attn_direct` and `attn_bd` theirs
+    (`--length` is `attn_bd`'s alone), every other none."""
+    if name == "attn_bd":
+        return {k: v for k, v in sweep.items() if v is not None}
+    if name == "attn_direct":
+        return {k: v for k, v in sweep.items() if k != "length"}
+    return {}
 
 
 def main(argv=None) -> int:
@@ -1403,8 +1490,8 @@ def main(argv=None) -> int:
     def _ints(text, sep=","):
         return tuple(int(x) for x in text.split(sep))
 
-    flags = {  # `attn_direct`'s sweep
-        "--shape": _ints, "--heads": _ints,
+    flags = {  # `attn_direct`'s and `attn_bd`'s sweeps
+        "--shape": _ints, "--heads": _ints, "--length": int,
         "--blocks": lambda text: tuple(_ints(b, "x")
                                        for b in text.split(","))}
 
@@ -1421,7 +1508,7 @@ def main(argv=None) -> int:
                 f"unknown probe(s) {unknown}; have {sorted(ALL)}")
         del _EMITTED[:]
         for n in names:
-            ALL[n](**(sweep if n == "attn_direct" else {}))
+            ALL[n](**_sweep_of(n, sweep))
         return {"probes": list(_EMITTED), "emitted": len(_EMITTED)}
 
     def _no_live(addr, vals):
