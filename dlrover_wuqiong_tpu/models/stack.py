@@ -29,10 +29,18 @@ from ..ops.remat import MODEL_CHECKPOINT_NAMES, resolve_remat_policy
 
 
 def layers(block, cfg, per_layer, x, *shared, prefix: str = "layers",
-           remat_names: tuple = (), static_argnums: tuple = ()):
+           remat_names: tuple = (), static_argnums: tuple = (),
+           handed=None):
     """x through `block(cfg, *per_layer[i], name="<prefix>_<i>")(x,
     *shared)` for every i, each block recomputed in the backward pass
-    where `cfg.remat` says so.  `per_layer` holds one tuple a layer: what
+    where `cfg.remat` says so.  `handed` (a dict of arrays, `{}` to begin
+    with) is what blocks hand on BESIDE x: a block is then called `(x,
+    handed, *shared)` and returns `(x, handed)`, the dict it was given
+    or one with more in it, which every later block is given — a
+    decoder-hybrid-decoder's memory and keys and values
+    (models/phi4flash.py).  A handed array is an output of the
+    recomputed block that made it and an input of each that follows: its
+    readers' cotangents sum into it, and it is kept once.  `per_layer` holds one tuple a layer: what
     the block class takes beside the config (a hybrid's kind, the layer's
     index, nothing).  `remat_names` are the `checkpoint_name` anchors of
     the `*_names` policies where the config lets a strategy choose them
@@ -50,7 +58,11 @@ def layers(block, cfg, per_layer, x, *shared, prefix: str = "layers",
             policy=resolve_remat_policy(
                 cfg.remat_policy, remat_names or MODEL_CHECKPOINT_NAMES))
     for i, args in enumerate(per_layer):
-        x = block(cfg, *args, name=f"{prefix}_{i}")(x, *shared)
+        layer = block(cfg, *args, name=f"{prefix}_{i}")
+        if handed is None:
+            x = layer(x, *shared)
+        else:
+            x, handed = layer(x, handed, *shared)
     return x
 
 

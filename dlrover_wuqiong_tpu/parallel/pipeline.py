@@ -530,6 +530,15 @@ class PipelinedLM:
         self.config = self.inner.config
         self._n_layer = getattr(self.config, "n_layer",
                                 getattr(self.config, "num_layers", 0))
+        handed = getattr(self.config, "handed_on", ())
+        if handed:
+            # a stage takes x and returns x: a boundary behind the layer
+            # that makes them would have to carry these to every later
+            # stage, and their cotangents back (ROADMAP, Reach)
+            raise ValueError(
+                f"{type(self.inner).__name__}'s blocks hand on "
+                f"{', '.join(handed)} beside x (models/stack.layers' "
+                f"`handed`): a pipeline stage carries x alone")
         if self.head_loss_fn is not None and self.schedule != "1f1b":
             raise ValueError(
                 "head_loss_fn only applies to schedule='1f1b' — gpipe/"

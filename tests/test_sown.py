@@ -34,6 +34,7 @@ from dlrover_wuqiong_tpu.models import (
     moe,
     nemotron_h,
     olmo_hybrid,
+    phi4flash,
     qwen3_next,
     sdar,
     smallthinker,
@@ -106,6 +107,9 @@ REGISTRANTS = {
         dict(shortconv_calls=[1, 1]), dict(shortconv_calls=[0, 1]))),
     "collect_diffusion_stats": (sdar, "counters", {
         "diffusion_noise": (jnp.asarray([0.5, 0.97], F32),)}),
+    "collect_phi4flash_stats": (phi4flash, "counters", layers(
+        dict(attn_diff_lambda=0.36), dict(gmu_gate_mean=0.21),
+        dict(attn_diff_lambda=0.8))),
 }
 
 
@@ -137,7 +141,8 @@ def test_a_registrant_is_reached_through_collect(name):
 
 
 def test_the_ten_are_all_that_is_registered():
-    """Eleven since PR 70 (the diffusion's counters), and ONE objective."""
+    """Twelve since PR 72 (the differential attentions' and the memory
+    units' counters), and ONE objective."""
     assert {key.rsplit(".", 1)[1] for table in (
         handed._COUNTERS, handed._TERMS, handed._STEPS) for key in table} \
         == (set(REGISTRANTS) - {"collect_moe_aux_loss"}) | {"moe_aux_term"}
@@ -195,6 +200,8 @@ MODELS = {
     "keye": lambda: keye.Keye(keye.KeyeConfig.nano(**NANO, **HELD)),
     "qwen3_next": lambda: qwen3_next.Qwen3Next(
         qwen3_next.Qwen3NextConfig.nano(**NANO, **HELD)),
+    "phi4flash": lambda: phi4flash.Phi4Flash(
+        phi4flash.Phi4FlashConfig.nano(**NANO)),
 }
 # the parent's (18f8494) `with_stats` on these configurations, key by key
 MOE = {"moe_dropped", "moe_load_max_over_mean"}
@@ -230,6 +237,7 @@ KEYS = {
     "qwen3_next": SHARE | LANES | DELTA | {
         "attn_gate_mean", "delta_qk_rows_run", "delta_qk_rows_model",
         "moe_shared_gate_mean"},
+    "phi4flash": LANES | TILES | {"attn_diff_lambda_mean", "gmu_gate_mean"},
 }
 
 

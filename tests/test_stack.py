@@ -313,3 +313,85 @@ def test_a_new_architecture_is_a_config_a_block_and_a_five_line_module():
     for got, want in zip(jax.tree.leaves(grad_fn(True)(params, batch)),
                          jax.tree.leaves(grad_fn(False)(params, batch))):
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+
+
+# ------------------------------- blocks that hand something on beside x
+
+
+class HandingBlock(nn.Module):
+    """Layer 0 hands on `m`, a projection of its input; every later
+    layer adds `m` times its own matrix to the stream, and a shared
+    array rides behind `handed` as it rides behind x elsewhere."""
+    config: ParallelConfig
+    layer: int
+
+    @nn.compact
+    def __call__(self, x, handed, shift):
+        dense = nn.Dense(x.shape[-1], use_bias=False, dtype=x.dtype,
+                         name="proj")
+        if self.layer == 0:
+            m = jnp.tanh(dense(x))
+            return x + m, {**handed, "m": m}
+        return x + dense(handed["m"] + shift), handed
+
+
+class HandingLM(nn.Module):
+    config: ParallelConfig
+
+    @nn.compact
+    def __call__(self, x, shift):
+        from dlrover_wuqiong_tpu.models import stack
+
+        return stack.layers(HandingBlock, self.config,
+                            [(i,) for i in range(self.config.num_layers)],
+                            x, shift, handed={})
+
+
+@pytest.mark.parametrize("layers", [2, 3])
+def test_a_block_hands_values_on_beside_x_under_remat(layers):
+    """`handed`: what layer 0 makes, every later layer reads; each
+    reader's cotangent flows back into it (with three layers the sum of
+    TWO), every block is still recomputed in the backward pass (one
+    barrier a block) and named `layers_<i>`, and the rematerialised
+    gradient is the plain one — which is the gradient of the same lines
+    written without the stack."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 16))
+    shift = jnp.float32(0.25)
+
+    def model(remat):
+        return HandingLM(ParallelConfig(num_layers=layers, remat=remat))
+
+    params = model(True).init(jax.random.PRNGKey(1), x, shift)["params"]
+    assert sorted(params) == [f"layers_{i}" for i in range(layers)]
+
+    def loss(remat):
+        return lambda p, x: jnp.sum(jnp.sin(
+            model(remat).apply({"params": p}, x, shift)))
+
+    def by_hand(p, x):
+        w = [p[f"layers_{i}"]["proj"]["kernel"] for i in range(layers)]
+        m = jnp.tanh(x @ w[0])
+        out = x + m
+        for w_i in w[1:]:
+            out = out + (m + shift) @ w_i
+        return jnp.sum(jnp.sin(out))
+
+    lowered = jax.jit(jax.grad(loss(True))).lower(params, x).as_text()
+    assert lowered.count("optimization_barrier") >= layers
+    assert "optimization_barrier" not in jax.jit(
+        jax.grad(loss(False))).lower(params, x).as_text()
+    want = jax.grad(by_hand, argnums=(0, 1))(params, x)
+    for remat in (True, False):
+        got = jax.grad(loss(remat), argnums=(0, 1))(params, x)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+
+
+def test_a_model_without_handed_calls_its_blocks_as_it_did():
+    """`handed=None` (every model but the decoder-hybrid-decoder): a
+    block is called `(x, *shared)` and returns x alone — the pinned
+    digests above hold the six classes' programs to what they were."""
+    ids = jnp.arange(16, dtype=jnp.int32).reshape(2, 8)
+    model = ParallelLM(ParallelConfig())
+    params = model.init_params(jax.random.PRNGKey(0))
+    assert model.apply({"params": params}, ids).shape == (2, 8, 256)

@@ -7,7 +7,7 @@ A device trace gives the same split per op (PERF.md, "Where the time
 goes"); this probe predates one.
 
 Usage: python tools/perf_probe.py [attn|attn_bwd|attn_sweep|attn_direct|attn_bd|head|
-model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|delta_kda|sums|hc|conv|gate] ...  (no args = step/attn/head/model/opt).  One JSON line
+model|opt|step|lib|dispatch|rpc|gmm|rows_map|rope|moe_numbers|delta|delta_kda|sums|hc|conv|gate|sscan] ...  (no args = step/attn/head/model/opt).  One JSON line
 per probe as it finishes, then ONE summary line
 ``{"probes": [...], "emitted": N}`` under the shared report-CLI contract
 (common/report_cli.py; -h to stderr rc=0, unknown probe rc=1).
@@ -87,6 +87,12 @@ same product with g spread by a one-hot matrix product, as the compiler
 fuses them against `dwt_gate` / `dwt_gate_bwd` (`ops/head_gate.py`) at
 several row tiles, in ms and in GB/s over the passes the least
 implementation moves (2 forward, 5 with the gradient).
+`sscan` reads the same way one call of the selective scan
+(`ops/selective_scan.py`) at the Mamba-1 cell's shape (1 x 8,192 x 5,120
+channels x 16 states), forward alone and forward + gradient:
+`dwt_sscan_fwd` / `dwt_sscan_bwd` over channel block x chunk, in ms and
+in ns a (token, 128-channel) step, with each plan's distance from the
+first; lines kept under `chiprun_out/pr72/sscan_probe.jsonl`.
 """
 
 from __future__ import annotations
@@ -1380,6 +1386,71 @@ def probe_conv(calls=CONV_CALLS, rows=(1024, 2048, 4096), out=None):
                         f_out.write(json.dumps(line) + "\n")
 
 
+def probe_sscan(shape=(1, 8192, 5120, 16),
+                blocks=(256, 512, 1024), chunks=(64, 128, 256), out=None):
+    """One call of the selective scan at the Mamba-1 cell's shape in
+    bfloat16 (dt float32): `ops/selective_scan.py`'s pair at every
+    (channel block, chunk) the kernels' VMEM takes, forward alone and
+    forward + gradient (x, dt, A, B, C); every device op's ms a call,
+    their sum, the kernels' own ns a (token, 128-channel) step, and the
+    gradients' distance from the first plan's (PERF.md section 6,
+    PR 72).  The lines are kept in `out`."""
+    from dlrover_wuqiong_tpu.ops import selective_scan as ss
+
+    out = out or os.path.join("chiprun_out", "pr72", "sscan_probe.jsonl")
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    bsz, t, d, n = shape
+    keys = jax.random.split(jax.random.PRNGKey(d), 6)
+    x, d_out = (jax.random.normal(k, (bsz, t, d), jnp.bfloat16)
+                for k in keys[:2])
+    dt = jax.nn.softplus(jax.random.normal(keys[2], (bsz, t, d)) - 4.0)
+    a = -jnp.exp(jax.random.uniform(keys[3], (d, n), minval=0.0,
+                                    maxval=2.7))
+    bm, cm = (jax.random.normal(k, (bsz, t, n), jnp.bfloat16)
+              for k in keys[4:])
+    args = (x, dt, a, bm, cm)
+    steps = bsz * t * d // 128
+    want = None
+    for block in blocks:
+        for chunk in chunks:
+            if d % block or t % chunk or ss._vmem_bytes(
+                    n, block, chunk) > ss._VMEM_LIMIT:
+                continue
+
+            def fn(*args, block=block, chunk=chunk):
+                return ss._scan_kernels(*args, chunk, block)
+
+            def both(d_out, *args, fn=fn):  # y too, or the forward goes
+                y, vjp = jax.vjp(fn, *args)
+                return (y, *vjp(d_out.astype(jnp.float32)))
+
+            got = jax.jit(both)(d_out, *args)
+            want = want or got
+            off = [float(jnp.abs(g.astype(jnp.float32)
+                                 - w.astype(jnp.float32)).max()
+                         / jnp.abs(w.astype(jnp.float32)).max())
+                   for g, w in zip(got, want)]
+            for what, f, call in (
+                    ("fwd", jax.jit(fn), args),
+                    ("fwd_bwd", jax.jit(both), (d_out, *args))):
+                ops = _device_ops_ms(f, *call, top=256)
+                kernels = {k: v for k, v in ops.items()
+                           if k.startswith("dwt_sscan")}
+                line = {
+                    "probe": "sscan", "what": what, "shape": list(shape),
+                    "block": block, "chunk": chunk,
+                    "all_ops_ms": round(sum(ops.values()), 4),
+                    "kernels_ms": kernels,
+                    "kernel_ns_a_step": {
+                        k: round(v * 1e6 / steps, 2)
+                        for k, v in kernels.items()},
+                    "off_y_dx_ddt_da_db_dc": [round(o, 7) for o in off],
+                    "device_ops_ms": dict(list(ops.items())[:8])}
+                _emit_raw(line)
+                with open(out, "a") as f_out:
+                    f_out.write(json.dumps(line) + "\n")
+
+
 def _gate_plain(y, g):
     d = y.shape[-1] // g.shape[-1]
     return (y * jnp.repeat(g, d, axis=-1)).astype(y.dtype)
@@ -1465,7 +1536,7 @@ ALL = {"attn": probe_attn_cells, "attn_bwd": probe_attn_bwd,
        "delta": probe_delta,
        "delta_kda": functools.partial(probe_delta, forms=("channel",)),
        "sums": probe_sums, "hc": probe_hc, "conv": probe_conv,
-       "gate": probe_gate}
+       "gate": probe_gate, "sscan": probe_sscan}
 
 
 def _sweep_of(name: str, sweep: dict) -> dict:
